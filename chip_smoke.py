@@ -1,0 +1,760 @@
+#!/usr/bin/env python3
+"""Chip smoke: the trainer and the v2 ragged server on a real TPU, end to end.
+
+    python chip_smoke.py              # one chip: kernels, trainer, server
+    python chip_smoke.py --chips 4    # four chips: ZeRO-3 fsdp=4 and tensor_parallel=4,
+                                      # each against its one-device run, and nothing else
+    python chip_smoke.py --rehearse   # CPU, tiny sizes, interpreted kernels: checks this
+                                      # script's control flow, can never print the ok line
+
+Everything runs in this one process (a chip belongs to one process), at the
+full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
+Each phase prints one JSON line (name, ok, seconds, compile seconds, persistent
+compile-cache hits/misses, what was compared and the error found). A failed
+phase is reported and the remaining phases still run, but the exit code is then
+non-zero and the last line is never the success line. On success the last line
+of stdout is exactly
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "<device_kind>", "count": N}}
+
+Without a TPU the script exits non-zero before any phase. The persistent
+compile cache is ``$JAX_COMPILATION_CACHE_DIR`` when set, else
+``<checkout>/.jax_cache_tpu`` (``deepspeed_tpu/utils/compile_cache.py``).
+"""
+
+import argparse
+import dataclasses
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+
+TOL_PAGED = 0.03       # |kernel - gather reference| on O(1) bf16 attention outputs (1 bf16 ulp at 4.0)
+TOL_NORM = 0.02        # |a - b| / (1 + |b|) on bf16 layer-norm outputs and input gradients
+TOL_ADAM = 1e-3        # max|a - b| / max|b| of update/lr and of each moment, fp32 fused Adam vs the plain update
+TOL_LOSS = 0.02        # |loss_pallas - loss_xla| on a bf16 GPT-2 loss of ~10.9
+TOL_LOSS_SHARDED = 0.05  # per-step |loss_4dev - loss_1dev| over 3 bf16 Adam steps
+F32_HEADROOM = 2.5     # float32-referenced rule: err(ours vs f32) <= 2.5 x err(plain bf16 vs f32)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Shapes of one run. ``FULL`` is GPT-2-124M and the llama-7B attention
+    geometry; ``TINY`` exists only for ``--rehearse``."""
+    vocab: int = 50257
+    layers: int = 12
+    heads: int = 12
+    d_model: int = 768
+    seq: int = 1024
+    micro_bs: int = 8
+    train_steps: int = 6
+    mha: tuple = (8, 1024, 12, 12, 64)     # B, S, H, KVH, D
+    gqa: tuple = (2, 4096, 32, 4, 128)
+    kv_block: int = 128
+    pool_blocks: int = 64
+    prefill_chunk: int = 128
+    adam_leaves: tuple = ((50257, 768), (1024, 768), (768, 3072), (3072,), (768,))
+    n_requests: int = 8
+    prompt_lens: tuple = (64, 512)
+    new_tokens: int = 32
+    sharded_steps: int = 3
+    tp_requests: int = 4
+
+
+FULL = Sizes()
+TINY = Sizes(vocab=509, layers=2, heads=4, d_model=64, seq=128, micro_bs=4, train_steps=3,
+             mha=(2, 128, 4, 4, 16), gqa=(1, 256, 4, 2, 16), kv_block=16, pool_blocks=24,
+             prefill_chunk=16, adam_leaves=((509, 64), (64,)), n_requests=3, prompt_lens=(8, 40),
+             new_tokens=6, sharded_steps=2, tp_requests=2)
+
+
+def _parse_args():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU rehearsal at tiny sizes with interpreted kernels; never prints the ok line")
+    return ap.parse_args()
+
+
+ARGS = _parse_args()
+REHEARSE = ARGS.rehearse
+SZ = TINY if REHEARSE else FULL
+if REHEARSE:
+    # a rehearsal never takes a chip; four host devices stand in for --chips 4
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               f" --xla_force_host_platform_device_count={ARGS.chips}").strip()
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax import monitoring  # noqa: E402
+
+from deepspeed_tpu.utils.compile_cache import enable_compilation_cache  # noqa: E402
+
+CACHE_DIR = None if REHEARSE else enable_compilation_cache(jax)  # before the first compile
+_COMPILE_SECS = [0.0]
+monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **_: _COMPILE_SECS.__setitem__(0, _COMPILE_SECS[0] + secs)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+
+
+# ----------------------------------------------------------------------
+# phase runner
+# ----------------------------------------------------------------------
+def _cache_counts():
+    from deepspeed_tpu.telemetry import get_registry
+
+    reg = get_registry()
+    return (int(reg.peek("compile_cache_hits_total") or 0), int(reg.peek("compile_cache_misses_total") or 0))
+
+
+FAILED = []
+
+
+def run_phase(name, fn):
+    """Run one phase and print its JSON line. A failure is recorded (the run
+    then exits non-zero) and later phases still run: one call of the chip
+    shows every fault, not the first."""
+    from deepspeed_tpu.parallel.mesh import reset_mesh
+
+    reset_mesh()  # every phase builds its own mesh; kernels consult the global one (ops/pallas/_utils.on_mesh)
+    hits0, miss0 = _cache_counts()
+    c0, t0 = _COMPILE_SECS[0], time.perf_counter()
+    line = {"phase": name, "ok": True}
+    try:
+        line.update(fn() or {})
+    except Exception as e:  # noqa: BLE001 - reported, and the exit code carries it
+        FAILED.append(name)
+        line.update(ok=False, error=f"{type(e).__name__}: {e}"[:2000])
+        traceback.print_exc(file=sys.stderr)
+    hits1, miss1 = _cache_counts()
+    stats = jax.devices()[0].memory_stats() or {}
+    line.update(seconds=round(time.perf_counter() - t0, 2), compile_seconds=round(_COMPILE_SECS[0] - c0, 2),
+                cache_hits=hits1 - hits0, cache_misses=miss1 - miss0,
+                hbm_gb={"in_use": round(stats.get("bytes_in_use", 0) / 2**30, 2),
+                        "peak_so_far": round(stats.get("peak_bytes_in_use", 0) / 2**30, 2)})
+    print(json.dumps(line), flush=True)
+
+
+def check(cond, msg):
+    if not cond:
+        raise AssertionError(msg)
+
+
+def max_abs(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+
+
+def scaled_err(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(a - b) / (1.0 + jnp.abs(b))))
+
+
+def rel_err(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)), 1e-30))
+
+
+def pad_to_block(n):
+    return -(-n // SZ.kv_block) * SZ.kv_block
+
+
+def padded_ids(rows, width):
+    return jnp.asarray(np.array([r + [0] * (width - len(r)) for r in rows], np.int32))
+
+
+def f32_rule(ours, plain, truth, floor=1e-6):
+    """The float32-referenced rule (carried from the August session's triage,
+    ``tools/debug_flash_gqa.py``): both contestants are bf16, so each is judged
+    against a float32 computation of the same math, and ours fails only if its
+    error clearly exceeds the plain bf16 path's own. A structural kernel bug is
+    orders of magnitude off; a fixed absolute gate flags bf16 rounding."""
+    err_ours, err_plain = max_abs(ours, truth), max_abs(plain, truth)
+    return err_ours, err_plain, err_ours <= F32_HEADROOM * max(err_plain, floor)
+
+
+def count_custom_calls(jitted, *args):
+    """tpu_custom_call sites in the program ``jitted`` compiles for ``args``
+    (abstract arguments are enough: the module is lowered, not compiled)."""
+    return jitted.lower(*args).as_text().count("tpu_custom_call")
+
+
+def abstract(tree):
+    def one(x):
+        if not hasattr(x, "shape"):
+            return x
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
+def gpt2_config(dtype=jnp.bfloat16):
+    from deepspeed_tpu.models import TransformerConfig
+
+    return TransformerConfig(vocab_size=SZ.vocab, n_layers=SZ.layers, n_heads=SZ.heads, d_model=SZ.d_model,
+                             max_seq_len=SZ.seq, dtype=dtype)
+
+
+def init_params(model):
+    return model.init(jax.random.PRNGKey(ARGS.seed), {"input_ids": np.zeros((1, SZ.seq), np.int32)})
+
+
+class plain_ops:
+    """Force every registry op that has one onto its plain ``xla``
+    implementation for the duration — the reference side of a comparison."""
+
+    def __enter__(self):
+        from deepspeed_tpu.ops.registry import REGISTRY
+
+        self._names = [n for n, impls in REGISTRY._ops.items() if any(i.name == "xla" for i in impls)]
+        self._prev = {n: REGISTRY.set_impl(n, "xla") for n in self._names}
+
+    def __exit__(self, *exc):
+        from deepspeed_tpu.ops.registry import REGISTRY
+
+        for n in self._names:
+            REGISTRY.set_impl(n, self._prev[n])
+
+
+# ----------------------------------------------------------------------
+# step 2: kernels of the main path against the plain references
+# ----------------------------------------------------------------------
+def _flash_check(shape):
+    """flash_attention forward + gradients against ``attention_xla``, judged by
+    the float32-referenced rule. The references run one KV-head group at a
+    time (groups are independent), so the (B, H, S, S) float32 logits of the
+    GQA shape never exist at once."""
+    from deepspeed_tpu.ops.attention import attention_xla
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, S, H, KVH, D = shape
+    G = H // KVH
+    ks = jax.random.split(jax.random.PRNGKey(ARGS.seed), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, KVH, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, KVH, D), jnp.bfloat16)
+    do = jax.random.normal(ks[3], (B, S, H, D), jnp.bfloat16)
+
+    def fwd_bwd(attn):
+        def run(q, k, v, do):
+            o, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, causal=True), q, k, v)
+            return (o,) + vjp(do.astype(o.dtype))
+        return jax.jit(run)
+
+    ours = fwd_bwd(lambda q, k, v, **kw: flash_attention(q, k, v, interpret=REHEARSE, **kw))
+    n_calls = count_custom_calls(ours, q, k, v, do)
+    got = ours(q, k, v, do)
+    plain = fwd_bwd(attention_xla)
+
+    def by_group(dtype):
+        parts = []
+        for j in range(KVH):
+            sl = slice(j * G, (j + 1) * G)
+            parts.append(plain(q[:, :, sl].astype(dtype), k[:, :, j:j + 1].astype(dtype),
+                               v[:, :, j:j + 1].astype(dtype), do[:, :, sl].astype(dtype)))
+        return [jnp.concatenate([p[i] for p in parts], axis=2) for i in range(4)]
+
+    ref_bf16 = by_group(jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        ref_f32 = by_group(jnp.float32)
+    out, bad = {}, []
+    for name, a, b, t in zip(("o", "dq", "dk", "dv"), got, ref_bf16, ref_f32):
+        err_ours, err_plain, ok = f32_rule(a, b, t)
+        out[name] = {"ours_vs_f32": err_ours, "xla_bf16_vs_f32": err_plain}
+        if not ok or not bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))):
+            bad.append(name)
+    check(not bad, f"flash {shape}: {bad} exceed {F32_HEADROOM}x the plain bf16 error: {out}")
+    check(REHEARSE or n_calls >= 3, f"flash {shape}: expected fwd+dq+dkv kernels, found {n_calls} custom calls")
+    return {"shape_BSHKD": list(shape), "compared": "flash_attention fwd+bwd vs attention_xla, f32-referenced",
+            "errors": out, "custom_calls": n_calls}
+
+
+def _paged_inputs(n_rows, kvq=0):
+    """A pool of random pages at GPT-2 widths and ragged per-row contexts."""
+    from deepspeed_tpu.ops.pallas.paged_attention import quantize_kv
+
+    H, D, bs, N = SZ.heads, SZ.d_model // SZ.heads, SZ.kv_block, SZ.pool_blocks
+    pages_per_seq = SZ.seq // bs
+    rng = np.random.RandomState(ARGS.seed)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (N, bs, H, D), jnp.bfloat16)
+    vp = jax.random.normal(jax.random.PRNGKey(2), (N, bs, H, D), jnp.bfloat16)
+    if kvq:
+        kp, vp = quantize_kv(kp), quantize_kv(vp)
+    tables = jnp.asarray(rng.randint(1, N, size=(n_rows, pages_per_seq)).astype(np.int32))
+    return H, D, bs, kp, vp, tables, rng
+
+
+def _paged_decode_check(kvq):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_decode, paged_attention_ref
+
+    B = 8
+    H, D, bs, kp, vp, tables, rng = _paged_inputs(B, kvq)
+    ctx = jnp.asarray(rng.randint(1, SZ.seq + 1, size=(B,)).astype(np.int32))
+    q = jax.random.normal(jax.random.PRNGKey(3), (B, H, D), jnp.bfloat16)
+    fn = jax.jit(lambda q, kp, vp: paged_attention_decode(q, kp, vp, tables, ctx, interpret=REHEARSE))
+    n_calls = count_custom_calls(fn, q, kp, vp)
+    got = fn(q, kp, vp)
+    with jax.default_matmul_precision("highest"):
+        ref = paged_attention_ref(q[:, None], kp, vp, tables, ctx, (ctx - 1)[:, None])[:, 0]
+    err = max_abs(got, ref)
+    check(err <= TOL_PAGED, f"paged decode (kv_quant={kvq}) vs gather reference: {err} > {TOL_PAGED}")
+    check(REHEARSE or n_calls >= 1, "paged decode lowered without a kernel")
+    return {"compared": "paged_attention_decode vs paged_attention_ref", "kv_quant_bits": kvq,
+            "max_abs_err": err, "tol": TOL_PAGED, "custom_calls": n_calls}
+
+
+def _paged_chunk_inputs(n_pre):
+    """Chunked-prefill rows that continue a partially written context."""
+    S = SZ.prefill_chunk
+    H, D, bs, kp, vp, tables, rng = _paged_inputs(n_pre)
+    q0 = rng.randint(0, SZ.seq - S + 1, size=(n_pre,)).astype(np.int32)
+    qpos = jnp.asarray(q0[:, None] + np.arange(S, dtype=np.int32)[None])
+    ctx = jnp.asarray(q0 + S)
+    q = jax.random.normal(jax.random.PRNGKey(4), (n_pre, S, H, D), jnp.bfloat16)
+    return q, kp, vp, tables, ctx, qpos
+
+
+def _paged_prefill_check():
+    from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention_prefill, paged_attention_ref,
+                                                          prefill_path)
+
+    q, kp, vp, tables, ctx, qpos = _paged_chunk_inputs(4)
+    path = prefill_path(q.shape[1], q.shape[2], q.shape[3])
+    check(path == "kernel", f"a {q.shape[1]}-token chunk must take the kernel, took {path}")
+    fn = jax.jit(lambda q, kp, vp: paged_attention_prefill(q, kp, vp, tables, ctx, qpos, interpret=REHEARSE))
+    n_calls = count_custom_calls(fn, q, kp, vp)
+    got = fn(q, kp, vp)
+    with jax.default_matmul_precision("highest"):
+        ref = paged_attention_ref(q, kp, vp, tables, ctx, qpos)
+    err = max_abs(got, ref)
+    check(err <= TOL_PAGED, f"paged prefill vs gather reference: {err} > {TOL_PAGED}")
+    check(REHEARSE or n_calls >= 1, "paged prefill lowered without a kernel")
+    return {"compared": "paged_attention_prefill vs paged_attention_ref", "chunk": int(q.shape[1]),
+            "max_abs_err": err, "tol": TOL_PAGED, "custom_calls": n_calls}
+
+
+def _paged_mixed_check():
+    """The fused step's attention: decode rows and chunked-prefill rows of one
+    flat token batch, as ``model_runner._stack_body`` calls it."""
+    import functools
+
+    from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention_decode, paged_attention_mixed,
+                                                          paged_attention_prefill, paged_attention_ref)
+
+    n_dec, n_pre, S = 8, 2, SZ.prefill_chunk
+    qp, kp, vp, tables_p, ctx_p, qpos_p = _paged_chunk_inputs(n_pre)
+    H, D, _, _, _, tables_d, rng = _paged_inputs(n_dec)
+    ctx_d = jnp.asarray(rng.randint(1, SZ.seq + 1, size=(n_dec,)).astype(np.int32))
+    qd = jax.random.normal(jax.random.PRNGKey(5), (n_dec, H, D), jnp.bfloat16)
+    q = jnp.concatenate([qd, qp.reshape(n_pre * S, H, D)], axis=0)
+    tables = jnp.concatenate([tables_d, tables_p], axis=0)
+    ctx = jnp.concatenate([ctx_d, ctx_p], axis=0)
+    pos = jnp.concatenate([ctx_d - 1, qpos_p.reshape(-1)], axis=0)
+    fn = jax.jit(lambda q, kp, vp: paged_attention_mixed(
+        q, kp, vp, tables, ctx, pos, n_dec=n_dec, chunk=S,
+        decode_fn=functools.partial(paged_attention_decode, interpret=REHEARSE),
+        prefill_fn=functools.partial(paged_attention_prefill, interpret=REHEARSE)))
+    n_calls = count_custom_calls(fn, q, kp, vp)
+    got = fn(q, kp, vp)
+    with jax.default_matmul_precision("highest"):
+        ref_d = paged_attention_ref(qd[:, None], kp, vp, tables_d, ctx_d, (ctx_d - 1)[:, None])[:, 0]
+        ref_p = paged_attention_ref(qp, kp, vp, tables_p, ctx_p, qpos_p).reshape(n_pre * S, H, D)
+    err = max_abs(got, jnp.concatenate([ref_d, ref_p], axis=0))
+    check(err <= TOL_PAGED, f"paged mixed vs gather reference: {err} > {TOL_PAGED}")
+    check(REHEARSE or n_calls >= 2, f"paged mixed: expected a decode and a prefill kernel, found {n_calls}")
+    return {"compared": "paged_attention_mixed vs paged_attention_ref", "n_dec": n_dec, "n_pre": n_pre, "chunk": S,
+            "max_abs_err": err, "tol": TOL_PAGED, "custom_calls": n_calls}
+
+
+def _layer_norm_check():
+    """The kernel the v2 server's ``norm_tpu`` dispatches for GPT-2, at the
+    fused step's (1, T, d_model) bf16 shape with bf16 scale and bias."""
+    from deepspeed_tpu.ops.pallas.norms import layer_norm, layer_norm_xla
+
+    T, d = 8 + 2 * SZ.prefill_chunk, SZ.d_model
+    ks = jax.random.split(jax.random.PRNGKey(ARGS.seed + 6), 3)
+    x = (2.0 * jax.random.normal(ks[0], (1, T, d), jnp.float32) + 0.5).astype(jnp.bfloat16)
+    w = (1.0 + 0.1 * jax.random.normal(ks[1], (d,), jnp.float32)).astype(jnp.bfloat16)
+    b = (0.1 * jax.random.normal(ks[2], (d,), jnp.float32)).astype(jnp.bfloat16)
+    ours = lambda x: layer_norm(x, w, b, 1e-5, interpret=REHEARSE)  # noqa: E731
+    plain = lambda x: layer_norm_xla(x, w, b)  # noqa: E731
+    dx = lambda ln: jax.jit(jax.grad(lambda x: (ln(x).astype(jnp.float32) ** 2).sum() * 1e-3))  # noqa: E731
+    n_calls = count_custom_calls(jax.jit(ours), x)
+    x32 = x.astype(jnp.float32)
+    err_fwd = scaled_err(jax.jit(ours)(x), plain(x32))
+    err_bwd = scaled_err(dx(ours)(x), dx(plain)(x32))
+    check(err_fwd <= TOL_NORM and err_bwd <= TOL_NORM,
+          f"layer_norm vs plain float32: fwd {err_fwd}, dx {err_bwd} > {TOL_NORM}")
+    check(REHEARSE or n_calls >= 1, "layer_norm lowered without a kernel")
+    return {"compared": "layer_norm fwd + dx vs layer_norm_xla in float32", "shape": [1, T, d],
+            "scaled_err_fwd": err_fwd, "scaled_err_dx": err_bwd, "tol": TOL_NORM, "custom_calls": n_calls}
+
+
+def _fused_adam_check():
+    """fused_adam_flat at GPT-2's leaf shapes, two steps (the step is traced:
+    one program serves every step), against the plain update."""
+    from deepspeed_tpu.ops.pallas.fused_adam import adam_xla, fused_adam_flat
+
+    worst, n_calls = 0.0, 0
+    for i, shape in enumerate(SZ.adam_leaves):
+        ks = jax.random.split(jax.random.PRNGKey(ARGS.seed + 10 + i), 2)
+        p = 0.02 * jax.random.normal(ks[0], shape, jnp.float32)
+        g = 1e-3 * jax.random.normal(ks[1], shape, jnp.float32)
+        m = v = jnp.zeros(shape, jnp.float32)
+        ours = jax.jit(lambda p, g, m, v, step: fused_adam_flat(p, g, m, v, 1e-4, step, weight_decay=0.01,
+                                                                interpret=REHEARSE))
+        plain = jax.jit(lambda p, g, m, v, step: adam_xla(p, g, m, v, 1e-4, step, weight_decay=0.01))
+        n_calls += count_custom_calls(ours, p, g, m, v, jnp.int32(1))
+        a = b = (p, m, v)
+        for step in (1, 2):
+            a = ours(a[0], g, a[1], a[2], jnp.int32(step))
+            b = plain(b[0], g, b[1], b[2], jnp.int32(step))
+        # the update is ~lr against parameters of ~0.02: compare the DELTA, not the parameter
+        worst = max([worst, rel_err(a[0] - p, b[0] - p)] + [rel_err(x, y) for x, y in zip(a[1:], b[1:])])
+    check(worst <= TOL_ADAM, f"fused_adam_flat vs plain Adam: relative error {worst} > {TOL_ADAM}")
+    check(REHEARSE or n_calls >= len(SZ.adam_leaves), "fused_adam_flat lowered without a kernel")
+    return {"compared": "fused_adam_flat vs adam_xla, 2 steps, update/lr and moments", "leaves": list(SZ.adam_leaves),
+            "rel_err": worst, "tol": TOL_ADAM, "custom_calls": n_calls}
+
+
+KERNEL_PHASES = (
+    ("kernel/flash_mha", lambda: _flash_check(SZ.mha)),
+    ("kernel/flash_gqa", lambda: _flash_check(SZ.gqa)),
+    ("kernel/fused_adam", _fused_adam_check),
+    ("kernel/layer_norm", _layer_norm_check),
+    ("kernel/paged_decode", lambda: _paged_decode_check(0)),
+    ("kernel/paged_decode_int8", lambda: _paged_decode_check(8)),
+    ("kernel/paged_prefill", _paged_prefill_check),
+    ("kernel/paged_mixed", _paged_mixed_check),
+)
+
+
+# ----------------------------------------------------------------------
+# step 3: the trainer takes steps
+# ----------------------------------------------------------------------
+def _train_config(stage, micro_bs, optimizer="adam", mesh=None):
+    cfg = {"train_micro_batch_size_per_gpu": micro_bs, "gradient_accumulation_steps": 1,
+           "bf16": {"enabled": True}, "optimizer": {"type": optimizer, "params": {"lr": 1e-4}},
+           "zero_optimization": {"stage": stage}, "steps_per_print": 10**9}
+    if mesh:
+        cfg["mesh"] = mesh
+    return cfg
+
+
+def _train_batch(global_bs):
+    rng = np.random.RandomState(ARGS.seed)
+    return {"input_ids": rng.randint(0, SZ.vocab, size=(global_bs, SZ.seq)).astype(np.int32)}
+
+
+def _take_steps(engine, batch, n):
+    losses = []
+    for _ in range(n):
+        loss = engine.forward(batch)
+        engine.backward(loss)
+        engine.step()
+        losses.append(loss)
+    jax.block_until_ready((losses, engine.params))
+    return [float(x) for x in losses]
+
+
+def trainer_phase():
+    import deepspeed_tpu
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops.registry import REGISTRY
+
+    model = CausalLM(gpt2_config())
+    params = init_params(model)
+    batch = _train_batch(SZ.micro_bs)
+    # the same batch through the model on the plain XLA implementations, BEFORE
+    # the engine takes (and donates) the parameters
+    params_c = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
+    with plain_ops():
+        loss_plain = float(jax.jit(model.loss_fn)(params_c, batch))
+    del params_c
+
+    # bench.py's zero2 rung config, with the optimizer the Pallas kernel serves
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params,
+                                               config=_train_config(2, SZ.micro_bs, optimizer="fusedadam"))
+    del params
+    attention = REGISTRY.selected("attention")
+    check(REHEARSE or attention == "pallas", f"attention resolved to {attention!r}, not the Pallas kernel")
+    check(REHEARSE or REGISTRY.selected("fused_adam") == "pallas", "fused_adam did not resolve to the Pallas kernel")
+    check(engine._fused_step is not None, "the one-dispatch fused step was not built")
+    dev_batch = engine._put_batch(batch)
+    scale = engine.loss_scaler.loss_scale / engine.gradient_accumulation_steps
+    n_calls = count_custom_calls(engine._fused_step, abstract(engine.params), abstract(engine.opt_state),
+                                 abstract(dev_batch), 0, scale, 1.0 / engine.loss_scaler.loss_scale, 1e-4)
+    check(REHEARSE or n_calls > 0, "the compiled train step holds no tpu_custom_call")
+
+    losses = _take_steps(engine, dev_batch, SZ.train_steps)
+    check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    check(losses[-1] < losses[0], f"loss did not fall on a repeated batch: {losses}")
+    diff = abs(losses[0] - loss_plain)
+    check(diff <= TOL_LOSS, f"first-step loss {losses[0]} vs plain XLA {loss_plain}: {diff} > {TOL_LOSS}")
+    check(int(engine.skipped_steps) == 0, "a step overflowed and was skipped")
+    return {"model": f"gpt2 L{SZ.layers} d{SZ.d_model} H{SZ.heads} V{SZ.vocab} S{SZ.seq} bf16",
+            "config": "zero2 bf16 fusedadam", "micro_bs": SZ.micro_bs, "steps": SZ.train_steps, "losses": losses,
+            "compared": "first-step loss vs the same batch on the registry's xla implementations",
+            "loss_plain_xla": loss_plain, "abs_diff": diff, "tol": TOL_LOSS, "attention": attention,
+            "fused_adam": REGISTRY.selected("fused_adam"), "step_custom_calls": n_calls}
+
+
+# ----------------------------------------------------------------------
+# step 4: the server answers requests
+# ----------------------------------------------------------------------
+def _prompts(n):
+    rng = np.random.RandomState(ARGS.seed + 1)
+    lo, hi = SZ.prompt_lens
+    lens = [lo, hi] + rng.randint(lo, hi + 1, size=max(0, n - 2)).tolist()  # both ends of the range, then ragged
+    return [rng.randint(0, SZ.vocab, size=(m,)).tolist() for m in lens[:n]]
+
+
+class _RecordedPrograms:
+    """Record the abstract signature of every fused program the engine
+    dispatches, so each can be lowered again afterwards and searched for its
+    kernels (the engine keeps the jitted wrappers, not their arguments)."""
+
+    def __init__(self, eng):
+        self.seen = {}
+        fused_for = eng._fused_for
+
+        def recording(D, P, S, sampling):
+            fn = fused_for(D, P, S, sampling)
+
+            def dispatch(*args):
+                steps = int(args[9].shape[0]) + 1  # adv_slots: (steps - 1, rows)
+                self.seen.setdefault((D, P, S, steps), (fn, abstract(args)))
+                return fn(*args)
+
+            return dispatch
+
+        eng._fused_for = recording
+
+    def report(self):
+        out = []
+        for (D, P, S, steps), (fn, args) in sorted(self.seen.items()):
+            raw = fn
+            while not hasattr(raw, "lower"):
+                raw = raw.__wrapped__
+            out.append({"decode_rows": D, "prefill_rows": P, "chunk": S, "steps": steps,
+                        "tpu_custom_calls": count_custom_calls(raw, *args)})
+        return out
+
+
+def _reference_logits(cfg_dtype, params, ids, last):
+    """Last-prompt-position logits of ``model.apply`` for right-padded
+    prompts (a causal model: padding after a position cannot reach it)."""
+    from deepspeed_tpu.models import CausalLM
+
+    model = CausalLM(gpt2_config(cfg_dtype))
+    cast = jax.tree_util.tree_map(lambda x: x.astype(cfg_dtype), params)
+    fn = jax.jit(lambda p, ids: model.apply(p, ids)[jnp.arange(ids.shape[0]), last].astype(jnp.float32))
+    return fn(cast, ids)
+
+
+def _check_engine(eng, tp=1):
+    check(REHEARSE or eng._interpret is False, "the engine chose interpret mode: its kernels are not compiled")
+    check(eng._fused_enabled, "the fused step is off")
+    check(eng._tp == tp, f"tensor_parallel is {eng._tp}, wanted {tp}")
+
+
+def _put_logits(eng, prompts, uid0=1000):
+    """Whole-prompt prefill through ``put``: (n, V) next-token logits."""
+    uids = list(range(uid0, uid0 + len(prompts)))
+    logits = eng.put(uids, prompts)
+    eng.flush(uids)
+    return jnp.asarray(logits)
+
+
+def server_phase():
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import _next_pow2
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.ops.pallas.paged_attention import prefill_path
+
+    model = CausalLM(gpt2_config())
+    params = init_params(model)
+    hbm0 = (jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
+    eng = InferenceEngineV2(model, params, RaggedInferenceEngineConfig())  # the defaults a user gets
+    _check_engine(eng)
+    jax.block_until_ready((eng.k_pages, eng.v_pages))
+    pool = {"kv_blocks": eng._n_kv_blocks, "budget_gb": eng._config.state_manager.memory_gb,
+            "logical_gb": round((eng.k_pages.nbytes + eng.v_pages.nbytes) / 2**30, 2),
+            "engine_hbm_gb": round(((jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0) - hbm0) / 2**30, 2)}
+    programs = _RecordedPrograms(eng)
+    prompts = _prompts(SZ.n_requests)
+    outs = eng.generate(prompts, max_new_tokens=SZ.new_tokens)
+    check([len(o) for o in outs] == [SZ.new_tokens] * len(prompts),
+          f"requests returned {[len(o) for o in outs]} tokens, wanted {SZ.new_tokens} each")
+    fused = programs.report()
+    check(fused, "generate dispatched no fused program")
+    check(REHEARSE or all(p["tpu_custom_calls"] > 0 for p in fused), f"a fused program holds no kernel: {fused}")
+
+    # logits of the last prompt position: engine.put against model.apply,
+    # both judged against the float32 model on the plain implementations
+    H, D = SZ.heads, SZ.d_model // SZ.heads
+    paths = {len(p): prefill_path(max(16, _next_pow2(len(p))), H, D) for p in prompts}
+    got = _put_logits(eng, prompts)
+    ids = padded_ids(prompts, pad_to_block(max(len(p) for p in prompts)))
+    last = jnp.asarray([len(p) - 1 for p in prompts])
+    plain_bf16 = _reference_logits(jnp.bfloat16, params, ids, last)
+    with plain_ops(), jax.default_matmul_precision("highest"):
+        truth = _reference_logits(jnp.float32, params, ids, last)
+    err_eng, err_model, ok = f32_rule(got, plain_bf16, truth, floor=1e-3)
+    check(bool(jnp.all(jnp.isfinite(got))), "engine logits are not finite")
+    check(ok, f"put() logits vs float32 model: {err_eng} > {F32_HEADROOM} x model.apply's own bf16 error {err_model}")
+
+    # teacher-forced greedy: ONE dense forward over prompt + answer gives the
+    # plain greedy choice at every generated position
+    seqs = padded_ids([p + o for p, o in zip(prompts, outs)], pad_to_block(max(len(p) for p in prompts) + SZ.new_tokens))
+    params_c = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
+    greedy = np.asarray(jax.jit(lambda p, s: jnp.argmax(model.apply(p, s), axis=-1))(params_c, seqs))
+    match = [int(greedy[i, len(p) - 1 + t] == o[t]) for i, (p, o) in enumerate(zip(prompts, outs))
+             for t in range(SZ.new_tokens)]
+    first = [int(jnp.argmax(got[i])) == outs[i][0] for i in range(len(prompts))]
+    return {"model": f"gpt2 L{SZ.layers} d{SZ.d_model} H{SZ.heads} V{SZ.vocab} bf16", "requests": len(prompts),
+            "prompt_lens": [len(p) for p in prompts], "new_tokens": SZ.new_tokens,
+            "interpret": eng._interpret, "fused_enabled": eng._fused_enabled, "kv_pool": pool, "fused_programs": fused,
+            "put_prefill_path_by_prompt_len": paths,
+            "compared": "put() last-position logits vs model.apply, f32-referenced",
+            "engine_vs_f32": err_eng, "model_bf16_vs_f32": err_model, "engine_vs_model_bf16": max_abs(got, plain_bf16),
+            "rule": f"engine_vs_f32 <= {F32_HEADROOM} x max(model_bf16_vs_f32, 1e-3)",
+            "greedy_match_share": round(sum(match) / len(match), 4),
+            "first_token_matches_put_argmax": f"{sum(first)}/{len(first)}"}
+
+
+# ----------------------------------------------------------------------
+# --chips 4: sharded training and sharded serving against one device
+# ----------------------------------------------------------------------
+def _device_shares(tree):
+    """{device id: share of the tree's bytes that device holds}."""
+    per_dev, total = {}, 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if not hasattr(leaf, "addressable_shards") or leaf.ndim == 0:
+            continue
+        total += leaf.nbytes
+        for s in leaf.addressable_shards:
+            per_dev[s.device.id] = per_dev.get(s.device.id, 0) + s.data.nbytes
+    return {d: round(b / total, 4) for d, b in sorted(per_dev.items())}
+
+
+def _check_quartered(shares, what, n):
+    check(len(shares) == n, f"{what} lies on {len(shares)} devices, not {n}: {shares}")
+    check(all(0.8 / n <= s <= 1.25 / n for s in shares.values()),
+          f"{what}: a device holds far from 1/{n} of the bytes: {shares}")
+
+
+def zero3_phase():
+    import deepspeed_tpu
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.parallel.mesh import MeshTopology
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    n = ARGS.chips
+    model = CausalLM(gpt2_config())
+    batch = _train_batch(SZ.micro_bs)  # ONE global batch for both engines
+    check(SZ.micro_bs % n == 0, "global batch must split over the chips")
+
+    # the one-device run first, on an explicit mesh over jax.devices()[:1]
+    one = MeshTopology(MeshConfig.from_dict({"data": 1}), devices=jax.devices()[:1])
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=init_params(model), mesh=one,
+                                               config=_train_config(3, SZ.micro_bs))
+    losses_one = _take_steps(engine, batch, SZ.sharded_steps)
+    del engine
+    gc.collect()
+
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=init_params(model),
+                                               config=_train_config(3, SZ.micro_bs // n, mesh={"fsdp": n}))
+    check(engine.topology.axis_size("fsdp") == n and engine.topology.n_devices == n, "mesh is not fsdp=4")
+    losses = _take_steps(engine, batch, SZ.sharded_steps)
+    check(all(np.isfinite(losses + losses_one)), f"non-finite loss: {losses} / {losses_one}")
+    diffs = [abs(a - b) for a, b in zip(losses, losses_one)]
+    check(max(diffs) <= TOL_LOSS_SHARDED, f"fsdp={n} losses {losses} vs one device {losses_one}: {diffs}")
+    p_shares, o_shares = _device_shares(engine.params), _device_shares(engine.opt_state)
+    if not REHEARSE:  # every leaf of the tiny model is under stage 3's persistence threshold and stays whole
+        _check_quartered(p_shares, "parameters", n)
+    _check_quartered(o_shares, "optimizer state", n)
+    return {"config": f"zero3 bf16 adam mesh fsdp={n}", "steps": SZ.sharded_steps, "losses": losses,
+            "losses_one_device": losses_one, "compared": "per-step loss, same global batch", "abs_diffs": diffs,
+            "tol": TOL_LOSS_SHARDED, "param_byte_share_by_device": p_shares,
+            "opt_state_byte_share_by_device": o_shares}
+
+
+def tp_phase():
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2, RaggedInferenceEngineConfig
+    from deepspeed_tpu.models import CausalLM
+
+    n = ARGS.chips
+    model = CausalLM(gpt2_config())
+    params = init_params(model)
+    prompts = _prompts(SZ.tp_requests)
+    res = {}
+    for tp in (1, n):
+        eng = InferenceEngineV2(model, params, RaggedInferenceEngineConfig(tensor_parallel=tp))
+        _check_engine(eng, tp)
+        programs = _RecordedPrograms(eng)
+        outs = eng.generate(prompts, max_new_tokens=SZ.new_tokens)
+        check([len(o) for o in outs] == [SZ.new_tokens] * len(prompts), f"tp={tp}: short answers")
+        res[tp] = {"outs": outs, "logits": _put_logits(eng, prompts), "fused": programs.report()}
+        if tp > 1:
+            check(eng._tp_ctx is not None, "tensor_parallel engine did not take the shard_map stack")
+            shares = {"k": _device_shares(eng.k_pages), "v": _device_shares(eng.v_pages)}
+            for name, s in shares.items():
+                _check_quartered(s, f"{name} pool", n)
+            layer_shares = _device_shares({k: v for k, v in eng.params.items() if k.startswith("layer_")})
+            check(len(layer_shares) == n, f"layer weights lie on {len(layer_shares)} devices")
+            check(REHEARSE or all(p["tpu_custom_calls"] > 0 for p in res[tp]["fused"]),
+                  f"a tp={tp} fused program holds no kernel")
+        del eng
+        gc.collect()
+    # two bf16 runs that differ only in the order of the row-parallel sums:
+    # judge the sharded one against float32 like every other comparison here
+    ids = padded_ids(prompts, pad_to_block(max(len(p) for p in prompts)))
+    last = jnp.asarray([len(p) - 1 for p in prompts])
+    with plain_ops(), jax.default_matmul_precision("highest"):
+        truth = _reference_logits(jnp.float32, params, ids, last)
+    err_tp, err_one, ok = f32_rule(res[n]["logits"], res[1]["logits"], truth, floor=1e-3)
+    check(ok, f"tp={n} logits vs float32: {err_tp} > {F32_HEADROOM} x tp=1's own error {err_one}")
+    same = [int(a == b) for x, y in zip(res[n]["outs"], res[1]["outs"]) for a, b in zip(x, y)]
+    return {"config": f"tensor_parallel={n} vs 1", "requests": len(prompts), "new_tokens": SZ.new_tokens,
+            "compared": "put() last-position logits, f32-referenced", "tp_vs_f32": err_tp, "tp1_vs_f32": err_one,
+            "tp_vs_tp1": max_abs(res[n]["logits"], res[1]["logits"]),
+            "kv_pool_byte_share_by_device": shares, "layer_weight_byte_share_by_device": layer_shares,
+            "token_match_share_vs_tp1": round(sum(same) / len(same), 4), "fused_programs": res[n]["fused"]}
+
+
+# ----------------------------------------------------------------------
+def main():
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
+    if not REHEARSE and device["platform"] != "tpu":
+        print(f"chip_smoke: JAX found no TPU (devices: {device}); nothing can be proved here", file=sys.stderr)
+        return 2
+    if device["count"] != ARGS.chips:
+        print(f"chip_smoke: --chips {ARGS.chips} but JAX sees {device['count']} devices", file=sys.stderr)
+        return 2
+    print(json.dumps({"phase": "device", "ok": True, **device, "seed": ARGS.seed, "rehearsal": REHEARSE,
+                      "compile_cache_dir": CACHE_DIR, "jax": jax.__version__}), flush=True)
+    if ARGS.chips == 1:
+        phases = KERNEL_PHASES + (("trainer", trainer_phase), ("server", server_phase))
+    else:
+        phases = (("zero3_fsdp", zero3_phase), ("serve_tp", tp_phase))
+    for name, fn in phases:
+        run_phase(name, fn)
+        gc.collect()
+    if FAILED:
+        print(json.dumps({"ok": False, "failed": FAILED, "device": device}), flush=True)
+        return 1
+    if REHEARSE:
+        print(json.dumps({"ok": False, "rehearsal": "passed", "device": device}), flush=True)
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

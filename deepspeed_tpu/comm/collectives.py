@@ -11,7 +11,6 @@ handles — a "group" is a mesh axis name or tuple of names.
 
 from typing import Optional, Sequence, Union
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -163,13 +162,8 @@ def axis_rank(group: AxisName):
 
 
 def axis_size(group: AxisName) -> int:
-    """Static size of a bound mesh axis (``lax.axis_size`` is jax >= 0.6)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(group)
-    try:
-        return jax.core.axis_frame(group)
-    except Exception:
-        return lax.psum(1, group)
+    """Static size of a bound mesh axis."""
+    return lax.axis_size(group)
 
 
 def barrier(group: Optional[AxisName] = None):

@@ -31,14 +31,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 exposes shard_map at the top level (check_vma keyword)
-    from jax import shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-    MODERN_SHARD_MAP = True
-except ImportError:  # pragma: no cover — older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-    MODERN_SHARD_MAP = False
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...comm.collectives import tp_all_reduce
@@ -48,6 +41,8 @@ from ...ops.pallas.paged_attention import (kv_layer, kv_set_layer, paged_attenti
                                            update_kv_pages)
 from ...ops.registry import REGISTRY
 from .modules import _norm_p, _proj, build_modules
+
+_SHARD_MAP_KW = {"check_vma": False}
 
 
 def _is_moe_layer(cfg: TransformerConfig, i: int) -> bool:
@@ -126,6 +121,16 @@ def _attn_fn_builder(cfg: TransformerConfig, interpret: bool, mesh, tp: int, slo
     return attn_fns
 
 
+def _row_parallel(p: Dict, tp_reduce):
+    """(projection params, deferred bias) of a row-parallel projection.
+    Under manual TP every shard holds the whole bias but only a partial
+    product: the bias must add ONCE, after ``tp_reduce`` sums the partials
+    (inside the projection it would count ``tp`` times)."""
+    if tp_reduce is None or "bias" not in p:
+        return p, None
+    return {k: v for k, v in p.items() if k != "bias"}, p["bias"]
+
+
 def _transformer_layer(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, k_pages_i: jnp.ndarray,
                        v_pages_i: jnp.ndarray, slot_mapping: jnp.ndarray, cos, sin, positions: jnp.ndarray,
                        attn_apply, mods, moe: bool, tp_reduce=None
@@ -160,9 +165,12 @@ def _transformer_layer(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, k_pages
                              slot_mapping)
 
     attn = attn_apply(q, kp, vp)
-    attn_out = _proj(attn, lp["attn"]["o_proj"], "bshk,hkd->bsd", dtype)
+    o_proj, o_bias = _row_parallel(lp["attn"]["o_proj"], tp_reduce)
+    attn_out = _proj(attn, o_proj, "bshk,hkd->bsd", dtype)
     if tp_reduce is not None:
         attn_out = tp_reduce(attn_out)
+        if o_bias is not None:
+            attn_out = attn_out + o_bias.astype(dtype)
 
     if cfg.block_type == "parallel_shared":  # falcon-7b / phi / gpt-j
         ffn_in = h
@@ -171,9 +179,16 @@ def _transformer_layer(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, k_pages
     else:
         x = x + attn_out
         ffn_in = mods.norm(cfg, _norm_p(cfg, lp, 1), x)
-    ffn_out = mods.moe(cfg, lp["moe"], ffn_in) if moe else mods.mlp(cfg, lp["mlp"], ffn_in)
+    down_bias = None
+    if moe:
+        ffn_out = mods.moe(cfg, lp["moe"], ffn_in)
+    else:
+        down_proj, down_bias = _row_parallel(lp["mlp"]["down_proj"], tp_reduce)
+        ffn_out = mods.mlp(cfg, {**lp["mlp"], "down_proj": down_proj}, ffn_in)
     if tp_reduce is not None:
         ffn_out = tp_reduce(ffn_out)
+        if down_bias is not None:
+            ffn_out = ffn_out + down_bias.astype(dtype)
     if cfg.block_type in ("parallel", "parallel_shared"):
         x = x + attn_out + ffn_out
     else:

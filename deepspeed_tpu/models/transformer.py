@@ -764,6 +764,14 @@ class CausalLM:
             (("gate_proj", "kernel"), P(None, "tensor")),
             (("up_proj", "kernel"), P(None, "tensor")),
             (("down_proj", "kernel"), P("tensor", None)),
+            # column-parallel biases split with their kernel's output dim
+            # (GPT-2-class models; row-parallel o/down biases stay whole
+            # and add once, after the partial sums reduce)
+            (("q_proj", "bias"), P("tensor", None)),
+            (("k_proj", "bias"), P("tensor", None)),
+            (("v_proj", "bias"), P("tensor", None)),
+            (("gate_proj", "bias"), P("tensor")),
+            (("up_proj", "bias"), P("tensor")),
             (("lm_head", "kernel"), P(None, "tensor")),
         ]
 

@@ -1178,7 +1178,7 @@ def tp_shardings(params: Dict, model=None, mesh=None, tp_size: Optional[int] = N
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import get_mesh_topology
-    from ..runtime.zero.partition import match_partition_rule, specs_to_shardings
+    from ..runtime.zero.partition import fit_spec, match_partition_rule, specs_to_shardings
     from .auto_tp import get_tp_rules
 
     topo = mesh if mesh is not None else get_mesh_topology()
@@ -1190,7 +1190,8 @@ def tp_shardings(params: Dict, model=None, mesh=None, tp_size: Optional[int] = N
 
         def leaf_spec(path, leaf):
             names = tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-            s = match_partition_rule(names, rules)
+            # a dim the tensor axis does not divide (vocab 50257) stays whole
+            s = fit_spec(match_partition_rule(names, rules), tuple(getattr(leaf, "shape", ())), topo)
             return s if s is not None else P()
 
         specs = jax.tree_util.tree_map_with_path(leaf_spec, params)

@@ -3,11 +3,7 @@ from .registry import REGISTRY, get_op, register_op
 
 __all__ = ["attention", "REGISTRY", "get_op", "register_op"]
 
-try:  # Pallas kernels register themselves (interpretable on CPU, native on TPU)
-    from . import pallas  # noqa: F401
+# Pallas kernels register themselves (interpretable on CPU, native on TPU)
+from . import pallas  # noqa: F401,E402
 
-    __all__.append("pallas")
-except Exception as _e:  # pragma: no cover - pallas import should not break the package
-    from ..utils.logging import logger
-
-    logger.warning(f"pallas kernels unavailable: {_e}")
+__all__.append("pallas")

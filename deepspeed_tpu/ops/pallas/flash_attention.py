@@ -18,15 +18,17 @@ instead of materializing expanded cotangents.
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 from ...analysis import knobs
 from ..registry import REGISTRY, pallas_available
-from ._utils import block_that_divides, compiler_params as _compiler_params
+from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh
 
 NEG_INF = -1e30
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
@@ -620,6 +622,39 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
             bias = jnp.repeat(bias, bias_repeat, axis=0)
         return attention_xla(q, k, v, causal=causal, scale=scale, bias=bias, segment_ids=segment_ids,
                              kv_len=kv_len, window=window, alibi_slopes=alibi_slopes)
+    local = functools.partial(_flash_local, causal=causal, scale=scale, window=window, interpret=interpret,
+                              bias_repeat=bias_repeat)
+    if bias is not None:
+        # a collapsed bias does not split along a mesh; those callers
+        # (evoformer) run on one device or inside their own shard_map
+        return local(q, k, v, alibi_slopes, bias)
+    # several chips: the kernel sits in a shard_map over the batch axes and,
+    # where they divide the heads, the tensor axis (on_mesh says why)
+    spec = _mesh_spec(q, k)
+    if alibi_slopes is None:
+        return on_mesh(lambda q, k, v: local(q, k, v, None, None), (spec, spec, spec), spec)(q, k, v)
+    heads = P(spec[2]) if len(spec) > 2 else P()
+    return on_mesh(lambda q, k, v, sl: local(q, k, v, sl, None), (spec, spec, spec, heads), spec)(
+        q, k, v, jnp.asarray(alibi_slopes, jnp.float32))
+
+
+def _mesh_spec(q, k) -> P:
+    """How (B, S, H, D) operands split over the live mesh: batch over the
+    data axes, heads over ``tensor`` — each only where it divides (the
+    tensor axis must divide the query AND the KV heads)."""
+    from ...parallel.mesh import get_mesh_topology
+    from ...runtime.zero.partition import fit_spec, prune_spec
+
+    topo = get_mesh_topology(required=False)
+    if topo is None:
+        return P()
+    B, S, H, D = q.shape
+    return fit_spec(prune_spec(P(topo.batch_axes, None, "tensor", None), topo),
+                    (B, S, math.gcd(H, k.shape[2]), D), topo)
+
+
+def _flash_local(q, k, v, alibi_slopes, bias, *, causal, scale, window, interpret, bias_repeat):
+    """The kernel call on whole (or shard-local) operands."""
     n_rep = q.shape[2] // k.shape[2]
     if n_rep > 1 and bias is not None:
         # bias x GQA: the collapsed-bias index maps assume per-q-head KV;

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..registry import REGISTRY, pallas_available
-from ._utils import block_that_divides
+from ._utils import block_that_divides, replicated_on_mesh
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -80,7 +80,7 @@ _rms.defvjp(_rms_vjp_fwd, _rms_vjp_bwd)
 
 
 def rms_norm(x, weight, eps: float = 1e-5, interpret: bool = False):
-    return _rms(x, weight, eps, interpret)
+    return replicated_on_mesh(lambda x, w: _rms(x, w, eps, interpret))(x, weight)
 
 
 def _ln_fwd_pallas(x, weight, bias, eps, interpret):
@@ -130,7 +130,7 @@ _ln.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
 
 
 def layer_norm(x, weight, bias, eps: float = 1e-5, interpret: bool = False):
-    return _ln(x, weight, bias, eps, interpret)
+    return replicated_on_mesh(lambda x, w, b: _ln(x, w, b, eps, interpret))(x, weight, bias)
 
 
 REGISTRY.register("rms_norm", "pallas", rms_norm, is_available=pallas_available, priority=10)

@@ -37,12 +37,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only submodule; absent on CPU-only jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from ...utils.logging import logger
 from ._utils import compiler_params as _compiler_params
 
 NEG_INF = -1e30
@@ -354,11 +351,6 @@ def paged_attention_decode(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.nd
     has_alibi = alibi_slopes is not None
     quantized = isinstance(k_pages, tuple)
 
-    if pltpu is None:  # pallas TPU submodule absent: gather path covers interpret mode too
-        sl = jnp.asarray(alibi_slopes, jnp.float32) if has_alibi else None
-        return paged_attention_ref(q[:, None], k_pages, v_pages, block_tables, ctx_lens,
-                                   (ctx_lens - 1)[:, None], scale, alibi_slopes=sl, window=window)[:, 0]
-
     slopes_in = (jnp.broadcast_to(jnp.asarray(alibi_slopes, jnp.float32).reshape(H, 1), (H, 128))
                  if has_alibi else jnp.zeros((H, 128), jnp.float32))
     kernel = functools.partial(_decode_kernel, bs=bs, kvh=KVH, g=G, d=D, pages=P, scale=scale,
@@ -474,6 +466,17 @@ def _prefill_kernel(block_tables_ref, ctx_lens_ref, qpos0_ref, q_ref, k_ref, v_r
             o_ref[0, :, pl.dslice(h * g, g), :] = (acc_ref[h] / l[:, None]).reshape(s_q, g, d).astype(o_ref.dtype)
 
 
+PREFILL_MAX_CHUNK = 512
+PREFILL_MAX_ACC_BYTES = 6 * 2**20
+
+
+def prefill_path(S: int, H: int, D: int) -> str:
+    """Which implementation ``paged_attention_prefill`` takes for a chunk of
+    ``S`` query tokens: "kernel", or "gather" (the XLA reference) when the
+    fp32 (H, S, D) accumulator would not fit VMEM."""
+    return "gather" if S > PREFILL_MAX_CHUNK or H * S * D * 4 > PREFILL_MAX_ACC_BYTES else "kernel"
+
+
 def paged_attention_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                             block_tables: jnp.ndarray, ctx_lens: jnp.ndarray, q_positions: jnp.ndarray,
                             scale: Optional[float] = None, interpret: bool = False, alibi_slopes=None,
@@ -483,8 +486,8 @@ def paged_attention_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.n
 
     q: (B, S, H, D) new tokens (S static); q_positions: (B, S) absolute,
     consecutive per row; ctx_lens: (B,) total context incl. the new tokens.
-    Falls back to the gather reference when pallas-TPU is unavailable.
-    Returns (B, S, H, D).
+    Chunks too long for the VMEM accumulator take the gather reference
+    (logged once per traced shape). Returns (B, S, H, D).
     """
     B, S, H, D = q.shape
     N, bs, KVH, _ = kv_pool_shape(k_pages)
@@ -496,9 +499,11 @@ def paged_attention_prefill(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.n
 
     # the fp32 accumulator scratch is (KVH, G, S, D) — VMEM scales linearly
     # with the chunk length, so long un-chunked prompts (engine put() prefills
-    # whole prompts) fall back to the gather path rather than overflow VMEM
-    acc_bytes = KVH * G * S * D * 4
-    if pltpu is None or S > 512 or acc_bytes > 6 * 2**20:
+    # whole prompts) take the gather path rather than overflow VMEM
+    if prefill_path(S, H, D) == "gather":
+        logger.info(f"paged_attention_prefill: q {q.shape} exceeds the kernel's chunk limit "
+                    f"(S <= {PREFILL_MAX_CHUNK}, accumulator <= {PREFILL_MAX_ACC_BYTES >> 20} MiB) "
+                    "— taking the XLA gather path")
         sl = jnp.asarray(alibi_slopes, jnp.float32) if has_alibi else None
         return paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens, q_positions, scale,
                                    alibi_slopes=sl, window=window)

@@ -18,11 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-only submodule; absent on CPU-only jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import pallas_available
 from .sparsity_config import SparsityConfig
@@ -181,9 +177,7 @@ def _idx_spec(shape):
     # applies the (8, 128) tiling rule to every spec WITH a block shape —
     # even in SMEM — so a (1, 1, A) block is rejected; only full-array
     # scalar-memory specs are exempt.
-    if pltpu is not None:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))  # interpret-only fallback
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _sp_fwd(q, k, v, kidx, H, blk, scale, causal, interpret):

@@ -78,14 +78,12 @@ class MeshTopology:
 
     @staticmethod
     def _arrange_devices(devices, shape):
-        try:
+        if len(devices) > 1 and devices[0].platform == "tpu":
+            # Respect ICI physical topology on real TPU slices; a shape the
+            # slice cannot host is an error, never a silent reshape.
             from jax.experimental import mesh_utils
 
-            if devices and devices[0].platform == "tpu":
-                # Respect ICI physical topology on real TPU slices.
-                return mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception as e:  # pragma: no cover - only on exotic topologies
-            logger.warning(f"mesh_utils.create_device_mesh failed ({e}); falling back to reshape")
+            return mesh_utils.create_device_mesh(shape, devices=devices)
         return np.array(devices).reshape(shape)
 
     # ---- axis sizes ----

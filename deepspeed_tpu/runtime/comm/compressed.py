@@ -18,13 +18,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static bound-axis size; ``jax.lax.axis_size`` only exists on jax >= 0.6."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)
-
-
 def compress_1bit(x: jnp.ndarray, error: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Error-compensated sign compression, one scale per last-axis row.
 
@@ -49,7 +42,7 @@ def compressed_allreduce(x: jnp.ndarray, worker_error: jnp.ndarray, server_error
     same shape. ``server_error``: shape of one chunk (n // world).
     Returns (averaged vector, new_worker_error, new_server_error).
     """
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = x.size
     if n % world != 0:
         raise ValueError(f"compressed_allreduce needs size {n} divisible by axis size {world} (pad first)")
@@ -86,7 +79,7 @@ def all_to_all_quant_reduce(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     Reference: ``coalesced_collectives.py:81`` (+ swizzled_quantize.cu /
     quant_reduce.cu kernels, here jnp — XLA fuses the (de)quant math).
     """
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = x.size
     if n % world != 0:
         raise ValueError(f"all_to_all_quant_reduce needs size {n} divisible by axis size {world} (pad first)")
@@ -104,7 +97,7 @@ def all_to_all_quant_reduce(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 def reduce_scatter_coalesced(tensors, axis_name: str):
     """Flatten a list of tensors, reduce-scatter the concatenation, return
     this worker's shard (reference ``coalesced_collectives.py:31``)."""
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     flat = jnp.concatenate([t.reshape(-1) for t in tensors])
     pad = (-flat.size) % world
     if pad:

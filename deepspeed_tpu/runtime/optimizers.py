@@ -86,12 +86,20 @@ def create_optimizer(name: Optional[str], params: Optional[Dict] = None) -> opta
         # kernel implements decoupled AdamW only: L2 mode falls through
         # to the optax path so adam_w_mode=false keeps reference math.
         from ..ops.registry import REGISTRY
+        from ..parallel.mesh import get_mesh_topology
 
         if REGISTRY.selected("fused_adam") == "pallas":
-            a = _adam_args(params)
-            return optax.inject_hyperparams(
-                lambda learning_rate: _pallas_fused_adamw(learning_rate, a["b1"], a["b2"], a["eps"],
-                                                          a["weight_decay"]))(learning_rate=a["learning_rate"])
+            topo = get_mesh_topology(required=False)
+            if topo is None or topo.n_devices == 1:
+                a = _adam_args(params)
+                return optax.inject_hyperparams(
+                    lambda learning_rate: _pallas_fused_adamw(learning_rate, a["b1"], a["b2"], a["eps"],
+                                                              a["weight_decay"]))(learning_rate=a["learning_rate"])
+            # GSPMD cannot partition a Mosaic kernel, and the flat 1-D kernel
+            # cannot follow each leaf's ZeRO sharding: say so and take the
+            # XLA-fused update below (the same math)
+            logger.info(f"fusedadam: {topo.n_devices}-device mesh — the Pallas kernel runs on one device only; "
+                        "using the XLA-fused AdamW update")
 
     if name in (ADAM_OPTIMIZER, FUSED_ADAM, CPU_ADAM):
         a = _adam_args(params)

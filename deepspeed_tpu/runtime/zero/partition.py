@@ -70,6 +70,24 @@ def prune_spec(spec: Optional[P], topo) -> Optional[P]:
     return _norm([keep(e) for e in spec])
 
 
+def fit_spec(spec: Optional[P], shape: Tuple[int, ...], topo) -> Optional[P]:
+    """Drop from ``spec`` every entry whose axes do not divide the dimension
+    they shard: that dimension stays whole (replicated over those axes)
+    rather than failing placement. GPT-2's vocabulary of 50257 is the case:
+    no tensor degree divides it, so its embedding cannot shard over vocab."""
+    if spec is None:
+        return None
+
+    def fits(entry, dim):
+        if entry is None:
+            return None
+        names = entry if isinstance(entry, (tuple, list)) else (entry,)
+        return entry if dim % int(np.prod([topo.axis_size(a) for a in names])) == 0 else None
+
+    entries = list(spec)[:len(shape)]
+    return _norm([fits(e, d) for e, d in zip(entries, shape)])
+
+
 def match_partition_rule(path: Tuple[str, ...], rules: Sequence[Tuple[Tuple[str, ...], P]]) -> Optional[P]:
     """First rule whose key names all appear (in order) in the param path."""
     for key, spec in rules:
@@ -125,7 +143,7 @@ def plan_param_specs(param_shapes, config, topo, tp_rules=None):
 
     def leaf_spec(path, leaf):
         path_names = tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        base = prune_spec(match_partition_rule(path_names, rules), topo)
+        base = fit_spec(prune_spec(match_partition_rule(path_names, rules), topo), tuple(leaf.shape), topo)
         if stage == 3 and axes_size > 1:
             return shard_leaf_spec(tuple(leaf.shape), base, axes, axes_size, min_size=threshold)
         return base if base is not None else P()

@@ -31,15 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6 exposes shard_map at the top level (check_vma keyword)
-    from jax import shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-    MODERN_SHARD_MAP = True
-except ImportError:  # pragma: no cover — older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-    MODERN_SHARD_MAP = False
-
+from jax import shard_map
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
@@ -122,8 +114,7 @@ def _gather_leaf(local: jnp.ndarray, dim: int, dtype, qwz: bool, qgz: bool) -> j
         idx = jax.lax.axis_index("fsdp")
         g = g.astype(jnp.float32)
         if qgz:
-            k = (jax.lax.axis_size("fsdp") if hasattr(jax.lax, "axis_size")
-                 else jax.core.axis_frame("fsdp"))
+            k = jax.lax.axis_size("fsdp")
             n = g.size
             pad = (-n) % k
             flat = jnp.pad(g.reshape(-1), (0, pad)) if pad else g.reshape(-1)
@@ -248,7 +239,7 @@ def build_zeropp_fwd_bwd(loss_fn: Callable, param_specs, grad_specs, topo, confi
                 local_step, mesh=topo.mesh,
                 in_specs=(param_specs, bspecs, P(), P()),
                 out_specs=(P(), param_specs),
-                **_SHARD_MAP_KW))
+                check_vma=False))
         return cache[treedef](params32, batch, rng, scale)
 
     return stepped

@@ -24,13 +24,6 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static bound-axis size; ``lax.axis_size`` only exists on jax >= 0.6."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)
-
-
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, axis_name: str = "context",
                    causal: bool = True, scale: Optional[float] = None) -> jnp.ndarray:
     """Call inside shard_map with the sequence dim sharded over ``axis_name``.
@@ -45,7 +38,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, axis_name: st
     at 8:1 grouping that is 8x less ppermute traffic per hop, which is
     the cost this op exists to hide.
     """
-    size = _axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     KVH = k.shape[2]
     n_rep = q.shape[2] // KVH

@@ -123,7 +123,7 @@ class EventLog:
         self._sink_path = str(path)
         self._queue = queue.Queue(maxsize=self._sink_queue)
         if not self._atexit_registered:
-            # short-lived CLI runs (bench, hw_smoke) exit before the daemon
+            # short-lived CLI runs (bench, chip_smoke) exit before the daemon
             # drain thread empties its queue — flush+join at interpreter
             # shutdown so the last events reach disk. close_sink is
             # idempotent, so one registration covers any number of

@@ -30,7 +30,7 @@ from ..analysis import knobs
 
 _NAME_RE = re.compile(r"^[a-z_][a-z0-9_]*$")
 
-# generic latency buckets (seconds): span dispatch costs through tunnel RTTs
+# generic latency buckets (seconds): span dispatch costs through multi-second requests
 DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                    0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 

@@ -8,10 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.comm import collectives

@@ -33,12 +33,8 @@ def test_collectives_ladder_two_procs():
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-    _SM_KW = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-    _SM_KW = {"check_rep": False}
+from jax import shard_map
+_SM_KW = {"check_vma": False}
 from functools import partial
 from jax.experimental import multihost_utils
 from deepspeed_tpu.comm import collectives as C

@@ -418,12 +418,7 @@ class TestDecodeKernelBiasFeatures:
     @pytest.mark.parametrize("feature", ["alibi", "window", "both"])
     def test_matches_gather_reference(self, feature):
         from deepspeed_tpu.models.transformer import alibi_slopes
-        from deepspeed_tpu.ops.pallas import paged_attention as pa
         from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_decode, paged_attention_ref
-
-        if pa.pltpu is None:
-            pytest.skip("pallas TPU submodule unavailable: decode would fall back to the reference "
-                        "path and the comparison would be vacuous")
 
         q, kp, vp, tables, ctx = self._setup()
         sl = alibi_slopes(4) if feature in ("alibi", "both") else None
@@ -457,8 +452,6 @@ class TestPrefillKernel:
         from deepspeed_tpu.models.transformer import alibi_slopes
         from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-        if pa.pltpu is None:
-            pytest.skip("pallas TPU submodule unavailable")
         q, kp, vp, tables, ctx, positions = self._setup()
         sl = alibi_slopes(4) if feature in ("alibi", "both") else None
         win = 6 if feature in ("window", "both") else None
@@ -593,7 +586,7 @@ class TestQuantizedServing:
         """quant_bits=4: TRUE packed int4 storage (2 codes/byte). At this
         toy d_model the matmul takes the XLA fallback (non-conforming
         group size); the Pallas packed path is covered by
-        ops/test_quantized_matmul.py + hw_smoke."""
+        ops/test_quantized_matmul.py."""
         import dataclasses as dc
 
         model, params, cfg = v2_setup
@@ -682,3 +675,19 @@ class TestQuantizedServing:
         assert rel < 0.5, rel  # int4 on a random tiny model: loose but bounded
         out = q4.generate([[5, 9, 2]], max_new_tokens=4)[0]
         assert len(out) == 4
+
+
+@pytest.mark.parametrize("backend,tp,kvq,blocks", [("cpu", 1, 0, 910), ("tpu", 1, 0, 341), ("tpu", 4, 0, 170),
+                                                   ("tpu", 1, 8, 546)])
+def test_kv_pool_is_sized_from_tiled_bytes_on_tpu(backend, tp, kvq, blocks, monkeypatch):
+    """GPT-2 widths under the default 4 GB budget: a TPU tiles the pool's
+    minor (KVH, D) dims to (8, 128) inside programs, other backends do not.
+    (910 logical blocks asked a 16 GB v5e for 17.5 GB in the decode burst.)"""
+    import types
+
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = types.SimpleNamespace(cfg=TransformerConfig(vocab_size=128, n_layers=12, n_heads=12, d_model=768),
+                                _tp=tp, dtype=jnp.bfloat16, _kv_quant_bits=kvq)
+    assert (4 << 30) // InferenceEngineV2._device_bytes_per_block(eng, 128) == blocks

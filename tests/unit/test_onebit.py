@@ -15,12 +15,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level (check_vma keyword)
-    from jax import shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
+from jax import shard_map
 
 from deepspeed_tpu.runtime.comm.compressed import (all_to_all_quant_reduce, compress_1bit, compressed_allreduce)
 from deepspeed_tpu.runtime.fp16.onebit import onebit_adam, onebit_lamb, zero_one_adam
@@ -155,7 +150,7 @@ def test_onebit_adam_warmup_syncs_across_workers():
     state = opt.init(params)
 
     @partial(shard_map, mesh=mesh, in_specs=(P(), P(), P("data")), out_specs=P("data"),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def one_step(p, s, g):
         updates, _ = opt.update({"w": g[0]}, s, p)
         return updates["w"][None]

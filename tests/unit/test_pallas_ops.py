@@ -794,3 +794,51 @@ class TestPagedAttentionInt8:
         got = kv_layer(pool, 1)
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(quantize_kv(x)[0]))
         assert kv_layer(pool, 0)[0].shape == (4, 3, 2, 8)
+
+
+# ------------------------------------------------------------------
+# kernels on a mesh of several devices (GSPMD cannot partition a Mosaic
+# kernel: each one sits in a shard_map that is manual over every axis)
+# ------------------------------------------------------------------
+class TestKernelsOnAMesh:
+
+    @pytest.mark.parametrize("mesh,extra", [({"data": 2, "fsdp": 2, "tensor": 2}, "plain"),
+                                            ({"fsdp": 4, "data": 2}, "alibi"),
+                                            ({"data": 8}, "indivisible")])
+    def test_flash_fwd_bwd_under_sharded_jit(self, mesh, extra):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from deepspeed_tpu.parallel.mesh import initialize_mesh
+        from deepspeed_tpu.runtime.config import MeshConfig
+
+        topo = initialize_mesh(MeshConfig.from_dict(mesh), force=True)
+        B = 4 if extra == "indivisible" else 8  # 8 devices do not divide a batch of 4: it stays whole
+        rng = np.random.RandomState(0)
+        q = jnp.asarray(rng.randn(B, 64, 4, 16), jnp.float32)
+        k, v = (jnp.asarray(rng.randn(B, 64, 2, 16), jnp.float32) for _ in range(2))
+        kw = {"alibi_slopes": np.geomspace(0.25, 0.01, 4).astype(np.float32)} if extra == "alibi" else {}
+        batch = NamedSharding(topo.mesh, P(topo.batch_axes) if extra != "indivisible" else P())
+        q, k, v = (jax.device_put(x, batch) for x in (q, k, v))
+
+        def grads(attn):
+            return jax.jit(jax.value_and_grad(lambda q, k, v: (attn(q, k, v, causal=True, **kw) ** 2).sum(),
+                                              argnums=(0, 1, 2)))(q, k, v)
+
+        (l_ref, g_ref) = grads(attention_xla)
+        (l_out, g_out) = grads(lambda *a, **kws: flash_attention(*a, interpret=True, **kws))
+        np.testing.assert_allclose(float(l_out), float(l_ref), rtol=1e-5)
+        for a, b in zip(g_out, g_ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+    def test_norms_under_a_multi_device_jit(self):
+        from deepspeed_tpu.parallel.mesh import initialize_mesh
+        from deepspeed_tpu.runtime.config import MeshConfig
+
+        topo = initialize_mesh(MeshConfig.from_dict({"data": 4, "tensor": 2}), force=True)
+        rng = np.random.RandomState(0)
+        x = jax.device_put(jnp.asarray(rng.randn(2, 16, 64), jnp.float32), topo.replicated())
+        w, b = jnp.asarray(rng.randn(64), jnp.float32), jnp.asarray(rng.randn(64), jnp.float32)
+        out = jax.jit(lambda x: layer_norm(x, w, b, 1e-5, interpret=True))(x)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(layer_norm_xla(x, w, b)), atol=1e-5)
+        out = jax.jit(lambda x: rms_norm(x, w, 1e-5, interpret=True))(x)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(rms_norm_xla(x, w)), atol=1e-5)

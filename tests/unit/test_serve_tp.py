@@ -95,6 +95,16 @@ class TestTPParity:
         assert e2._tp_ctx is not None and e2._tp_ctx.tp == 2
         assert out2 == out1
 
+    def test_tp2_serves_a_vocab_the_tensor_axis_does_not_divide(self):
+        """GPT-2's 50257 has no even divisor: the embedding stays whole
+        (GSPMD, outside the shard_map region) instead of failing placement."""
+        cfg = TransformerConfig(vocab_size=131, n_layers=2, n_heads=4, d_model=32, max_seq_len=128)
+        model = CausalLM(cfg)
+        params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)})
+        prompts = [[5, 130, 7, 9], [1, 2, 3, 4, 5, 6]]
+        outs = {tp: _toks(_engine((model, params), tp=tp).generate(prompts, max_new_tokens=4)) for tp in (1, 2)}
+        assert outs[2] == outs[1]
+
     def test_tp2_equals_tp1_on_prefix_cache_reserve(self, engines):
         e1, out1, e2, out2 = engines
         # both engines run with the radix prefix cache on; a second pass

@@ -327,13 +327,10 @@ def test_watchdog_timeout_counts_and_env_default(monkeypatch):
 def test_compile_cache_listener_counts_events():
     import jax
 
+    from jax import monitoring
+
     from deepspeed_tpu.utils.compile_cache import register_cache_metrics
-    if not register_cache_metrics(jax):
-        pytest.skip("jax.monitoring unavailable")
-    try:
-        from jax import monitoring
-    except ImportError:
-        pytest.skip("jax.monitoring unavailable")
+    assert register_cache_metrics(jax)
     reg = get_registry()
     hits0 = reg.peek("compile_cache_hits_total") or 0.0
     miss0 = reg.peek("compile_cache_misses_total") or 0.0
@@ -367,12 +364,7 @@ def test_engine_train_step_telemetry(tmp_path):
     }
     model = CausalLM(gpt2_tiny())
     params = model.init(jax.random.PRNGKey(42), {"input_ids": np.zeros((1, 16), dtype=np.int32)})
-    try:
-        engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config=cfg)
-    except ImportError as e:
-        # engine construction needs jax.shard_map (ZeRO++ import chain);
-        # the seed suite fails the same way on older jax
-        pytest.skip(f"engine unavailable on this jax: {e}")
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config=cfg)
     assert engine.monitor is not None and engine.monitor.enabled
 
     reg = engine.telemetry

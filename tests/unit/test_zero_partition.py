@@ -9,8 +9,9 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel.mesh import MeshTopology
 from deepspeed_tpu.runtime.config import DeepSpeedConfig, MeshConfig
-from deepspeed_tpu.runtime.zero.partition import (plan_grad_specs, plan_opt_state_specs, plan_param_specs,
-                                                  shard_leaf_spec, zero_axes_for)
+from deepspeed_tpu.runtime.zero.partition import (fit_spec, plan_grad_specs, plan_opt_state_specs,
+                                                  plan_param_specs, shard_leaf_spec, specs_to_shardings,
+                                                  zero_axes_for)
 
 
 def _cfg(stage, mesh=None):
@@ -37,6 +38,28 @@ def test_shard_leaf_spec_respects_existing():
 
 def test_shard_leaf_spec_indivisible():
     assert shard_leaf_spec((3,), None, ("data",), 8) == P()
+
+
+@pytest.mark.parametrize("shape,spec,want", [
+    ((50257, 768), P("tensor", None), P()),               # GPT-2's vocab: no tensor degree divides it
+    ((50256, 768), P("tensor", None), P("tensor")),
+    ((50257, 768), P("tensor", "fsdp"), P(None, "fsdp")),  # only the axis that does not fit is dropped
+    ((768, 12, 64), P(None, "tensor", None), P(None, "tensor")),
+])
+def test_fit_spec_drops_axes_that_do_not_divide(shape, spec, want):
+    topo = MeshTopology(MeshConfig.from_dict({"data": 2, "fsdp": 2, "tensor": 2}))
+    assert fit_spec(spec, shape, topo) == want
+
+
+def test_param_specs_with_an_indivisible_vocab_place():
+    """A TP rule over a vocab the tensor axis does not divide plans a spec
+    device_put accepts (it raised before: 50257 % 2)."""
+    topo = MeshTopology(MeshConfig.from_dict({"data": 4, "tensor": 2}))
+    params = {"wte": jnp.zeros((131, 16)), "dense": {"kernel": jnp.zeros((16, 32))}}
+    rules = [(("wte",), P("tensor", None)), (("dense", "kernel"), P(None, "tensor"))]
+    specs = plan_param_specs(jax.eval_shape(lambda: params), _cfg(0, {"data": 4, "tensor": 2}), topo, rules)
+    assert specs["wte"] == P() and specs["dense"]["kernel"] == P(None, "tensor")
+    jax.device_put(params, specs_to_shardings(specs, topo))
 
 
 def test_stage0_replicated():

@@ -1,6 +1,6 @@
-"""On-chip triage for the GQA flash backward mismatch (hw_smoke round 5).
+"""On-chip triage for the GQA flash backward mismatch (August 2026 session).
 
-hw_smoke compares the Pallas GQA backward against the bf16 XLA oracle
+That session's smoke compared the Pallas GQA backward against the bf16 XLA oracle
 with an absolute max-diff threshold of 0.1 and saw 0.125 on the real
 chip. Both sides are bf16, so the diff could be (a) a genuine
 revisit-accumulation / index-map bug in ``_dkv_kernel_gqa`` that only

@@ -28,7 +28,7 @@ def _load_cli():
 
 def _replay_smoke() -> int:
     """Record an 8-request serving run and oracle-replay it (opt-in:
-    ``--replay-smoke``; also run directly by hw_session.sh phase A)."""
+    ``--replay-smoke``)."""
     spec = importlib.util.spec_from_file_location(
         "replay_cli", os.path.join(_TOOLS_DIR, "replay.py"))
     mod = importlib.util.module_from_spec(spec)
@@ -40,8 +40,7 @@ def _replay_smoke() -> int:
 def _profile_smoke() -> int:
     """Capture an 8-request fused serving run through the device-timeline
     profiler, parse it, and assert nonzero device time and a well-formed
-    waterfall (opt-in: ``--profile-smoke``; also run directly by
-    hw_session.sh phase A)."""
+    waterfall (opt-in: ``--profile-smoke``)."""
     spec = importlib.util.spec_from_file_location(
         "trace_report_cli", os.path.join(_TOOLS_DIR, "trace_report.py"))
     mod = importlib.util.module_from_spec(spec)

@@ -19,8 +19,7 @@ programs, and the exposed-collective summary cross-checked against the
 
 ``smoke`` captures an 8-request fused serving run end-to-end (arm →
 trace → parse) and asserts nonzero device time and a well-formed
-waterfall — run by ``tools/lint_all.py --profile-smoke`` and
-hw_session.sh phase A.
+waterfall — run by ``tools/lint_all.py --profile-smoke``.
 """
 
 import argparse
