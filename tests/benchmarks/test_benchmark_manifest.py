@@ -1,0 +1,142 @@
+"""BENCHMARK.json and the files it names: the rules a later PR breaks most
+easily, and that each kind of piece can be added as files of its own."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks.lib import flops, manifest as mf, peaks
+
+MANIFEST = mf.load_manifest()
+
+# the sources' own numbers, copied here so that a changed width fails a test
+PUBLISHED = {
+    "mistral-7b-l16": {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
+                       "num_key_value_heads": 8, "vocab_size": 32000, "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
+                       "sliding_window": 4096, "max_position_embeddings": 32768, "tie_word_embeddings": False,
+                       "num_hidden_layers": 32},
+    "olmo-1b": {"hidden_size": 2048, "intermediate_size": 8192, "num_attention_heads": 16, "num_key_value_heads": 16,
+                "vocab_size": 50304, "rope_theta": 10000.0, "max_position_embeddings": 2048,
+                "tie_word_embeddings": True, "num_hidden_layers": 16},
+}
+PROGRAM_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff", "num_attention_heads": "n_heads",
+                "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab_size", "num_hidden_layers": "n_layers",
+                "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
+                "max_position_embeddings": "max_seq_len", "sliding_window": "sliding_window"}
+
+
+def test_manifest_has_no_problems():
+    assert mf.problems(MANIFEST) == []
+
+
+def test_manifest_keys_are_the_contracts():
+    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
+    assert MANIFEST["command"] == ["python3", "benchmarks/run.py"]
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) <= max(1, len(MANIFEST["workloads"]) // 4)
+
+
+@pytest.mark.parametrize("config,key", [(c, k) for c, keys in PUBLISHED.items() for k in keys])
+def test_every_size_is_the_sources_or_listed_as_reduced(config, key):
+    cfg = mf.load_json(os.path.join(mf.BENCH, "configs", f"{config}.json"))
+    for entry in MANIFEST["configs"]:  # a listed configuration's entry says what its file says
+        if entry["name"] == config:
+            assert cfg["reduced"] == entry["reduced"] and cfg["source"] == entry["source"]
+    if key in cfg["reduced"]:
+        assert cfg[key] != PUBLISHED[config][key] and not key.endswith(("_size", "_dim", "_rank"))
+    else:
+        assert cfg[key] == PUBLISHED[config][key]
+    if key in PROGRAM_KEYS:  # what the program is built with is what the file publishes
+        assert cfg["program"][PROGRAM_KEYS[key]] == cfg[key]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_a_cells_files_exist_and_name_their_pieces(cell):
+    c = mf.cell(MANIFEST, cell)
+    assert c["config"]["kind"] in ("serve", "train")
+    assert os.path.isfile(os.path.join(mf.BENCH, "drivers", c["config"]["kind"] + ".py"))
+    assert os.path.isfile(os.path.join(mf.BENCH, "generators", c["traffic"]["generator"] + ".py"))
+    assert c["traffic"]["why"] and c["traffic"]["who"] and "rehearse" in c["config"]
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
+def test_a_per_layer_metric_moves_a_metric_its_cells_report(metric):
+    m = next(x for x in MANIFEST["per_layer"] if x["name"] == metric)
+    cells = m.get("workloads") or [w["name"] for w in MANIFEST["workloads"]]
+    for cell in cells:
+        assert m["moves"] in {e["name"] for e in mf.metrics_of(MANIFEST, cell, "end_to_end")}
+    reader = mf.metric_module(metric)
+    assert reader.read({"end_to_end": {}, "summary": {"tokens_total": 0}}) is None  # nothing to read: no number
+
+
+@pytest.mark.parametrize("breakage,needle", [
+    (lambda m: m["per_layer"][0].update(moves="ttft_p95_ms"), "does not report"),
+    (lambda m: m["workloads"][0].update(traffic="no-such-mix"), "no traffic file"),
+    (lambda m: m["end_to_end"][0].update(unit="tokens per second"), "bad unit"),
+    (lambda m: m["end_to_end"][:] and m["end_to_end"].pop(), "setup_s"),
+])
+def test_problems_are_found(breakage, needle):
+    broken = json.loads(json.dumps(MANIFEST))
+    breakage(broken)
+    assert any(needle in p for p in mf.problems(broken))
+
+
+def test_unknown_device_is_an_error_not_a_default():
+    assert peaks.peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(KeyError):
+        peaks.peaks_for("TPU v9 imaginary")
+
+
+def test_parameter_and_flop_counts_from_shapes():
+    olmo = mf.load_json(os.path.join(mf.BENCH, "configs", "olmo-1b.json"))
+    assert abs(flops.total_params(olmo) / 1e9 - 1.177) < 0.001
+    mistral = mf.load_json(os.path.join(mf.BENCH, "configs", "mistral-7b-l16.json"))
+    assert abs(flops.total_params(mistral) / 1e9 - 3.752) < 0.001
+    per_token = flops.train_flops_per_token(olmo, 2048)
+    assert 6 * flops.matmul_params(olmo) < per_token < 6.5 * flops.matmul_params(olmo)
+    fwd = flops.flash_attention_cost(2, 2048, 16, 16, 128, backward=False)
+    assert fwd["flops"] == 2 * 2 * 16 * 2048 * 2048 * 128
+    assert flops.roofline_seconds(fwd, peaks.peaks_for("TPU v5 lite"))["bound"] == "compute"
+
+
+def test_each_kind_of_piece_is_added_by_new_files_and_appended_entries(tmp_path):
+    """A throw-away configuration, traffic mix, generator, per-layer metric,
+    driver and cell, in a temporary copy: nothing that was there is edited."""
+    root = str(tmp_path)
+    shutil.copytree(mf.BENCH, os.path.join(root, "benchmarks"), ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(mf.ROOT, "deepspeed_tpu"), os.path.join(root, "deepspeed_tpu"))
+    before = {p: open(os.path.join(dp, p)).read() for dp, _, fs in os.walk(os.path.join(root, "benchmarks")) for p in fs}
+    b = os.path.join(root, "benchmarks")
+    json.dump({"kind": "toy", "source": "none", "hidden_size": 8, "reduced": [], "env": {}, "rehearse": {}},
+              open(f"{b}/configs/toy.json", "w"))
+    json.dump({"generator": "toy_gen", "why": "w", "who": "w", "params": {"n": 3}}, open(f"{b}/traffic/toy-mix.json", "w"))
+    open(f"{b}/generators/toy_gen.py", "w").write("def generate(params, seed, seconds, ctx):\n    return {'items': list(range(params['n']))}\n")
+    open(f"{b}/drivers/toy.py", "w").write(
+        "from benchmarks.lib.manifest import BENCH, load_module\n"
+        "def run(cell, opts):\n"
+        "    gen = load_module(f\"{BENCH}/generators/{cell['traffic']['generator']}.py\")\n"
+        "    n = len(gen.generate(cell['traffic']['params'], opts['seed'], opts['seconds'], {})['items'])\n"
+        "    return {'correct': True, 'attempted': n, 'failed': 0, 'end_to_end': {'toy_rate': 1.0, 'setup_s': 0.1}, 'extras': {}}\n")
+    open(f"{b}/metrics/toy_count.py", "w").write(
+        "UNIT, BETTER, SOURCE, LAYER, MOVES = 'count', 'higher', 'program_counter', 'toy layer', 'toy_rate'\n"
+        "def read(record):\n    return record['attempted']\n")
+    m = json.loads(json.dumps(MANIFEST))
+    m["configs"].append({"name": "toy", "source": "none", "file": "benchmarks/configs/toy.json", "reduced": [], "why": "w"})
+    m["workloads"].append({"name": "toy.toy-mix", "config": "toy", "traffic": "toy-mix", "chips": 1, "why": "w"})
+    m["end_to_end"].append({"name": "toy_rate", "unit": "1/s", "better": "higher", "bound": 0.01, "source": "host_clock",
+                            "workloads": ["toy.toy-mix"]})
+    m["per_layer"].append({"name": "toy_count", "unit": "count", "better": "higher", "source": "program_counter",
+                           "layer": "toy layer", "moves": "toy_rate", "workloads": ["toy.toy-mix"]})
+    json.dump(m, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    assert mf.problems(m, root) == []
+    out = subprocess.run([sys.executable, f"{b}/run.py", "--workload", "toy.toy-mix", "--rehearse", "--seconds", "1"],
+                         capture_output=True, text=True, timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["attempted"] == 3 and last["correct"] is True
+    after = {p: open(os.path.join(dp, p)).read() for dp, _, fs in os.walk(b) for p in fs if "__pycache__" not in dp}
+    assert all(after[p] == text for p, text in before.items())  # nothing that was there changed
