@@ -291,9 +291,6 @@ declare("DS_TPU_TELEMETRY_FLUSH_STEPS", "1", "int",
 declare("DS_TPU_TRACE_RING", "4096", "int",
         "Capacity of the span tracer's ring buffer.",
         "telemetry/tracing.py")
-declare("DS_TPU_TRACE_XLA", "0", "bool",
-        "Annotate spans into XLA via jax.profiler traces when profiling.",
-        "telemetry/tracing.py")
 declare("DS_TPU_EVENT_RING", "65536", "int",
         "Capacity of the request-lifecycle event ring buffer.",
         "telemetry/events.py")

@@ -27,6 +27,7 @@ from ...analysis import knobs
 from ...analysis.transfer_guard import maybe_guard
 from ...models.transformer import TransformerConfig
 from ...telemetry import get_registry as get_telemetry_registry
+from ...telemetry import get_tracer
 from ...telemetry import span as telemetry_span
 from ...telemetry.costs import get_perf_accountant
 from ...telemetry.events import get_event_log
@@ -36,6 +37,7 @@ from ...telemetry.health import (HBMPressureDetector, QueueStallDetector,
 from ...telemetry.journal import get_journal
 from ...telemetry.ops_plane import maybe_start_ops_server
 from ...telemetry import profiler as device_profiler
+from ...utils.compile_cache import register_cache_metrics
 from ...utils.logging import log_dist, logger
 from ...ops.pallas.paged_attention import make_kv_pool
 from .model_runner import (TPContext, make_burst_fn, make_fused_step_fn,
@@ -43,6 +45,11 @@ from .model_runner import (TPContext, make_burst_fn, make_fused_step_fn,
 from .ragged.manager import DSStateManager, RaggedBatchConfig
 from .scheduler import FusedQuantum, RaggedBatchScheduler, RaggedRequest
 from .spec import make_drafter
+
+
+def _burst_context_tokens(ctx: np.ndarray, steps: int) -> int:
+    """Context tokens ``steps`` decode steps read: each row's context grows by one a step."""
+    return int(ctx.sum()) * steps + len(ctx) * steps * (steps - 1) // 2
 
 
 def _next_pow2(n: int) -> int:
@@ -211,6 +218,11 @@ class InferenceEngineV2:
         self._m_prefill_fill = tele.gauge("infer_prefill_batch_fill")
         # fused serving loop: dispatches/quantum invariant + fill factor
         self._m_dispatches = tele.counter("infer_dispatches_total")
+        # sum of the context lengths the paged-attention kernels read, over
+        # real rows and steps: the count a roofline share of them needs
+        self._m_ctx_tokens = tele.counter("paged_attention_context_tokens_total")
+        self._tracer = get_tracer()
+        register_cache_metrics(jax)  # seconds of every first call, by phase (program_*_seconds_total)
         self._m_fused_quanta = tele.counter("infer_fused_quanta_total")
         self._m_fused_fill = tele.gauge("infer_fused_batch_fill")
         # tensor-parallel serving (docs/SERVING.md "Tensor-parallel
@@ -339,8 +351,10 @@ class InferenceEngineV2:
         # the accountant wraps the RAW jitted programs (innermost), so cost
         # cards trace/AOT-analyze the real executable; the JitAuditor wraps
         # outside and its recompile semantics are untouched
-        self._prefill_fn = self._acct.wrap("prefill", self._prefill_fn)
-        self._decode_fn = self._acct.wrap("decode", self._decode_fn)
+        self._prefill_fn = self._acct.wrap("prefill", self._prefill_fn, family="prefill")
+        self._decode_fn = self._acct.wrap("decode", self._decode_fn, family="decode")
+        for family in ("prefill", "decode"):
+            tele.counter("program_builds_total", family=family).inc()
         # runtime sanitizers (analysis/, all off by default): recompile audit
         # wraps every jitted serving program; the transfer guard scopes the
         # serving loops so implicit device->host syncs raise
@@ -355,6 +369,7 @@ class InferenceEngineV2:
         # program-cache capacity (burst/fused/spec families share it); an
         # autotune dimension — bigger caches trade HBM for fewer recompiles
         self._max_program_variants = max(1, knobs.get_int("DS_TPU_PROGRAM_CACHE"))
+        self._program_builds = 0  # misses of the three caches below, so far
         self._bursts: Dict[tuple, object] = {}  # sampling signature -> jitted burst
         self._fused_fns: Dict[tuple, object] = {}  # (bucket shape, sampling) -> jitted fused step
         self._cow_fn = None  # lazily-jitted donated page copy for copy-on-write
@@ -417,22 +432,32 @@ class InferenceEngineV2:
         if self._config.decode_burst < 2:
             return None
         key = (sampling or (False, 1.0, 0, 1.0)) + (self._shard_sig,)
-        if key not in self._bursts:
-            if len(self._bursts) >= getattr(self, "_max_program_variants", self._MAX_BURST_VARIANTS):
-                self._bursts.pop(next(iter(self._bursts)))
-            do, t, k, p = key[:4]
-            fn = make_burst_fn(self._run_cfg, interpret=self._interpret, mesh=self._run_mesh,
-                               tp=self._tp, tp_ctx=self._tp_ctx,
-                               do_sample=do, temperature=t, top_k=k, top_p=p)
-            fn = self._acct.wrap(f"burst{key}", fn)
+        do, t, k, p = key[:4]
+        return self._cached_program(self._bursts, "burst", key, lambda: make_burst_fn(
+            self._run_cfg, interpret=self._interpret, mesh=self._run_mesh, tp=self._tp, tp_ctx=self._tp_ctx,
+            do_sample=do, temperature=t, top_k=k, top_p=p))
+
+    def _cached_program(self, cache: Dict[tuple, object], family: str, key: tuple, build):
+        """One lookup in an LRU-bounded program cache (burst, fused and spec
+        share the discipline and the capacity): a miss builds, wraps and
+        counts the program (``program_builds_total``) and evicts the least
+        recently used one past capacity (``program_evictions_total``; its
+        executables free with the jit wrapper); a hit moves the key to the
+        tail, so a hot signature (greedy) survives a frontend cycling
+        through sampling configs."""
+        fn = cache.pop(key, None)
+        if fn is None:
+            tele = get_telemetry_registry()
+            if len(cache) >= self._max_program_variants:
+                cache.pop(next(iter(cache)))
+                tele.counter("program_evictions_total", family=family).inc()
+            fn = self._acct.wrap(f"{family}{key}", build(), family=family, bucket=key)
             if self.jit_auditor is not None:
-                fn = self.jit_auditor.wrap(f"burst{key}", fn)
-            self._bursts[key] = fn
-        else:
-            # LRU touch: keep a hot signature (e.g. greedy) from being
-            # evicted by a frontend cycling through >8 sampling configs
-            self._bursts[key] = self._bursts.pop(key)
-        return self._bursts[key]
+                fn = self.jit_auditor.wrap(f"{family}{key}", fn)
+            tele.counter("program_builds_total", family=family).inc()
+            self._program_builds += 1
+        cache[key] = fn
+        return fn
 
     def _account_tp_allreduce(self, tokens: int) -> None:
         """Analytic TP-collective traffic for one dispatch: every padded
@@ -500,6 +525,10 @@ class InferenceEngineV2:
         """
         if len(batch_uids) != len(batch_tokens):
             raise ValueError("uids and token lists must align")
+        with telemetry_span("infer/put", rows=len(batch_uids), tokens=sum(len(t) for t in batch_tokens)):
+            return self._put(batch_uids, batch_tokens, return_tokens, _defer)
+
+    def _put(self, batch_uids, batch_tokens, return_tokens: bool, _defer: bool):
         if len(set(batch_uids)) != len(batch_uids):
             # two chunks of one sequence in a single step would read the same
             # start position and overwrite each other's KV slots — the
@@ -681,6 +710,7 @@ class InferenceEngineV2:
                                                                   jnp.asarray(ctx), jnp.asarray(slots.reshape(-1)),
                                                                   jnp.asarray(last))
         self._m_dispatches.inc()
+        self._m_ctx_tokens.inc(int(ctx[:n].sum()))
         self._m_prefill_tokens.inc(sum(len(t) for t in token_lists))
         self._m_prefill_fill.set(n / B)
         for seq in seqs:
@@ -762,6 +792,7 @@ class InferenceEngineV2:
                                                                  jnp.asarray(ctx), jnp.asarray(slots[0]),
                                                                  jnp.asarray(last))
         self._m_dispatches.inc()
+        self._m_ctx_tokens.inc(int(ctx[:n].sum()))
         self._m_decode_steps.inc()
         self._m_decode_tokens.inc(n)
         self._m_decode_fill.set(n / len(ctx))
@@ -817,6 +848,7 @@ class InferenceEngineV2:
                 self.params, ids_in, jnp.asarray(positions), self.k_pages, self.v_pages,
                 jnp.asarray(bt), jnp.asarray(ctx), jnp.asarray(slots), jnp.asarray(last), burst_rng)
         self._m_dispatches.inc()
+        self._m_ctx_tokens.inc(_burst_context_tokens(ctx[:n], steps))
         self._m_bursts.inc()
         self._m_decode_steps.inc(steps)
         self._m_decode_tokens.inc(n * steps)
@@ -870,21 +902,10 @@ class InferenceEngineV2:
         step count is NOT part of the key: it rides the follow-on slot
         table's leading dim, so one wrapper serves the whole ladder."""
         key = (n_dec, n_pre, chunk) + (sampling or (False, 1.0, 0, 1.0)) + (self._shard_sig,)
-        if key not in self._fused_fns:
-            if len(self._fused_fns) >= getattr(self, "_max_program_variants", self._MAX_FUSED_VARIANTS):
-                self._fused_fns.pop(next(iter(self._fused_fns)))
-            do, t, k, p = key[3:7]
-            fn = make_fused_step_fn(self._run_cfg, interpret=self._interpret,
-                                    mesh=self._run_mesh, tp=self._tp, tp_ctx=self._tp_ctx,
-                                    n_dec=n_dec, n_pre=n_pre, chunk=chunk,
-                                    do_sample=do, temperature=t, top_k=k, top_p=p)
-            fn = self._acct.wrap(f"fused{key}", fn)
-            if self.jit_auditor is not None:
-                fn = self.jit_auditor.wrap(f"fused{key}", fn)
-            self._fused_fns[key] = fn
-        else:
-            self._fused_fns[key] = self._fused_fns.pop(key)  # LRU touch
-        return self._fused_fns[key]
+        do, t, k, p = key[3:7]
+        return self._cached_program(self._fused_fns, "fused", key, lambda: make_fused_step_fn(
+            self._run_cfg, interpret=self._interpret, mesh=self._run_mesh, tp=self._tp, tp_ctx=self._tp_ctx,
+            n_dec=n_dec, n_pre=n_pre, chunk=chunk, do_sample=do, temperature=t, top_k=k, top_p=p))
 
     def _run_fused(self, quantum: FusedQuantum, decode_carry: List, steps: int, defer: bool,
                    eos_token_id: Optional[int]) -> Dict[int, object]:
@@ -904,13 +925,69 @@ class InferenceEngineV2:
         assert steps == 1 or n_pre == 0, "multi-step bursts are pure-decode"
         max_chunk = max((len(p.tokens) for p in prefills), default=0)
         D, P, S = self._fused_bucket(n_dec, n_pre, max_chunk)
-        T = D + P * S
         N = D + P
-        bs = self.state.block_size
+        pre_tokens = sum(len(p.tokens) for p in prefills)
+        real, slot_tokens = n_dec * steps + pre_tokens, D * steps + P * S
+        # the quantum's span tree (docs/OBSERVABILITY.md): one span for the
+        # whole call, one child a phase, each with the scheduler's quantum id
+        q = self.scheduler.last_quantum_id
+        attrs = {}
+        if self._tracer.enabled:
+            attrs = dict(q=q, kind="decode" if not n_pre else ("mixed" if n_dec else "prefill"), n_dec=n_dec,
+                         n_pre=n_pre, prefill_tokens=pre_tokens, steps=steps, bucket=(D, P, S),
+                         tokens=real, slots=slot_tokens)
+        with telemetry_span("infer/fused_step", **attrs):
+            with telemetry_span("fused/validate", q=q):
+                self._validate_fused(dec_uids, prefills, steps)
+            with telemetry_span("fused/operands", q=q):
+                operands, ctx, seqs = self._fused_operands(dec_uids, prefills, decode_carry, steps, defer,
+                                                           eos_token_id, D, P, S)
+            with telemetry_span("fused/program", q=q) as sp:
+                built = self._program_builds
+                fn = self._fused_for(D, P, S, self._sampling)
+                sp.set(miss=self._program_builds != built)
+            with telemetry_span("fused/dispatch", q=q, steps=steps):
+                toks, self.k_pages, self.v_pages = fn(self.params, operands[0], operands[1], self.k_pages,
+                                                      self.v_pages, *operands[2:])
+            with telemetry_span("fused/account", q=q):
+                # while the device runs: nothing here waits for it
+                self._m_dispatches.inc()
+                self._m_fused_quanta.inc()
+                self._m_ctx_tokens.inc(_burst_context_tokens(ctx[:n_dec], steps) + int(ctx[D:D + n_pre].sum()))
+                self._m_fused_fill.set(real / max(1, slot_tokens))
+                self._account_tp_allreduce(slot_tokens)
+                if self._events.enabled and dec_uids:
+                    for uid in dec_uids:
+                        self._events.emit("decode", uid, q=q, k=steps)
+                if n_dec:
+                    self._m_decode_steps.inc(steps)
+                    self._m_decode_tokens.inc(n_dec * steps)
+                if prefills:
+                    self._m_prefill_tokens.inc(pre_tokens)
+                for seq in seqs:
+                    seq.post_forward()
+            with telemetry_span("fused/readback", q=q):
+                # non-deferred mode fetches the quantum's sampled tokens in ONE
+                # readback (N*steps ints) instead of one tiny transfer per row;
+                # the accountant's window closes at that readback
+                toks_host = None if defer else jax.device_get(toks)  # graft-lint: readback
+                self._acct.attribute(real, slot_tokens)
+                device_profiler.note_quantum("fused_step", rows=N, tokens=real, steps=steps)
+                out: Dict[int, object] = {}
+                for j, uid in enumerate(dec_uids):
+                    out[uid] = toks[j] if defer else toks_host[j]
+                for r, pf in enumerate(prefills):
+                    if pf.final:
+                        out[pf.uid] = toks[D + r] if defer else toks_host[D + r]
+                    else:
+                        out[pf.uid] = None
+        return out
 
-        # validate the WHOLE quantum before mutating any state (same
-        # discipline as _run_prefill_batch: a mid-loop allocation failure
-        # must not strand in-flight tokens or leak descriptor slots)
+    def _validate_fused(self, dec_uids: List[int], prefills, steps: int) -> None:
+        """Validate the WHOLE quantum before mutating any state (same
+        discipline as _run_prefill_batch: a mid-loop allocation failure
+        must not strand in-flight tokens or leak descriptor slots)."""
+        bs = self.state.block_size
         total_need = 0
         for uid in dec_uids:
             seq = self.state.get_sequence(uid)
@@ -932,6 +1009,15 @@ class InferenceEngineV2:
             raise RuntimeError(f"fused quantum needs {total_need} KV blocks, "
                                f"{self.state.free_blocks} free")
 
+    def _fused_operands(self, dec_uids: List[int], prefills, decode_carry: List, steps: int, defer: bool,
+                        eos_token_id: Optional[int], D: int, P: int, S: int):
+        """Allocate the quantum's KV slots and build the fused program's
+        operands on the device: ``(ids, positions, block table, ctx, slots,
+        last, follow-on slots, garbage slots, eos, rng)``; also the host
+        ``ctx`` and the sequences to ``post_forward``."""
+        n_dec = len(dec_uids)
+        T, N = D + P * S, D + P
+        bs = self.state.block_size
         ids = np.zeros((T,), np.int32)
         positions = np.zeros((T,), np.int32)
         slots0 = self._garbage_slots(T)
@@ -994,71 +1080,23 @@ class InferenceEngineV2:
             col.extend([jnp.zeros((), jnp.int32)] * (D - n_dec))  # padded rows feed the garbage page
             ids_dev = ids_dev.at[:D].set(jnp.stack(col))
 
-        fn = self._fused_for(D, P, S, self._sampling)
         self._rng, rng = jax.random.split(self._rng)
         eos = jnp.int32(-1 if eos_token_id is None else int(eos_token_id))
-        with telemetry_span("infer/fused_step", rows=N, tokens=T, steps=steps):
-            toks, self.k_pages, self.v_pages = fn(self.params, ids_dev, jnp.asarray(positions),
-                                                  self.k_pages, self.v_pages, jnp.asarray(bt),
-                                                  jnp.asarray(ctx), jnp.asarray(slots0),
-                                                  jnp.asarray(last), jnp.asarray(adv),
-                                                  jnp.asarray(gslots), eos, rng)
-        self._m_dispatches.inc()
-        self._m_fused_quanta.inc()
-        real = n_dec * steps + sum(len(p.tokens) for p in prefills)
-        self._m_fused_fill.set(real / max(1, D * steps + P * S))
-        self._account_tp_allreduce(D * steps + P * S)
-        if self._events.enabled and dec_uids:
-            q = self.scheduler.last_quantum_id
-            for uid in dec_uids:
-                self._events.emit("decode", uid, q=q, k=steps)
-        if n_dec:
-            self._m_decode_steps.inc(steps)
-            self._m_decode_tokens.inc(n_dec * steps)
-        if prefills:
-            self._m_prefill_tokens.inc(sum(len(p.tokens) for p in prefills))
-        for seq in seqs:
-            seq.post_forward()
-
-        # non-deferred mode fetches the quantum's sampled tokens in ONE
-        # readback (N*steps ints) instead of one tiny transfer per row
-        toks_host = None if defer else jax.device_get(toks)  # graft-lint: readback
-        self._acct.attribute(real, D * steps + P * S)
-        device_profiler.note_quantum("fused_step", rows=N, tokens=real, steps=steps)
-        out: Dict[int, object] = {}
-        for j, uid in enumerate(dec_uids):
-            out[uid] = toks[j] if defer else toks_host[j]
-        for r, pf in enumerate(prefills):
-            if pf.final:
-                out[pf.uid] = toks[D + r] if defer else toks_host[D + r]
-            else:
-                out[pf.uid] = None
-        return out
+        operands = (ids_dev, jnp.asarray(positions), jnp.asarray(bt), jnp.asarray(ctx), jnp.asarray(slots0),
+                    jnp.asarray(last), jnp.asarray(adv), jnp.asarray(gslots), eos, rng)
+        return operands, ctx, seqs
 
     # ---------------------------------------------------------- speculative decode
-    _MAX_SPEC_VARIANTS = 8  # class default; instances use DS_TPU_PROGRAM_CACHE
-
     def _spec_for(self, chunk: int, sampling):
         """LRU-bounded cache of spec-verify programs keyed on (window
         length, sampling signature) — same eviction discipline as
         ``_burst_for``/``_fused_for``. The padded row count rides jit's
         shape specialization; only the verify window is static."""
         key = (chunk,) + (sampling or (False, 1.0, 0, 1.0)) + (self._shard_sig,)
-        if key not in self._spec_fns:
-            if len(self._spec_fns) >= getattr(self, "_max_program_variants", self._MAX_SPEC_VARIANTS):
-                self._spec_fns.pop(next(iter(self._spec_fns)))
-            do, t, k, p = key[1:5]
-            fn = make_spec_verify_fn(self._run_cfg, interpret=self._interpret,
-                                     mesh=self._run_mesh, tp=self._tp, tp_ctx=self._tp_ctx,
-                                     chunk=chunk,
-                                     do_sample=do, temperature=t, top_k=k, top_p=p)
-            fn = self._acct.wrap(f"spec{key}", fn)
-            if self.jit_auditor is not None:
-                fn = self.jit_auditor.wrap(f"spec{key}", fn)
-            self._spec_fns[key] = fn
-        else:
-            self._spec_fns[key] = self._spec_fns.pop(key)  # LRU touch
-        return self._spec_fns[key]
+        do, t, k, p = key[1:5]
+        return self._cached_program(self._spec_fns, "spec", key, lambda: make_spec_verify_fn(
+            self._run_cfg, interpret=self._interpret, mesh=self._run_mesh, tp=self._tp, tp_ctx=self._tp_ctx,
+            chunk=chunk, do_sample=do, temperature=t, top_k=k, top_p=p))
 
     def _run_spec_step(self, uids: List[int], carries: List[int], histories: List[Sequence[int]],
                        budgets: List[int]) -> Optional[Dict[int, List[int]]]:

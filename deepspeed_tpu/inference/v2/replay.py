@@ -33,6 +33,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ...telemetry import get_registry as get_telemetry_registry
+from ...telemetry import span as telemetry_span
 from ...telemetry.events import get_event_log
 from ...telemetry.journal import Session, journal_override
 from .scheduler import RaggedRequest
@@ -222,6 +223,13 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
     deterministic, wall-clock-free, the oracle's mode. ``timing=
     "recorded"`` paces admissions by the recorded arrival seconds so
     latency percentiles are comparable — the what-if mode.
+
+    Each turn of the loop is a ``serve/admit``, ``serve/schedule`` and
+    ``serve/commit`` span around the engine's ``infer/fused_step``, or a
+    ``serve/idle_wait``, all with the id ``q`` of the quantum the turn
+    assembles; each request leaves ``enqueue`` (stamped with its recorded
+    arrival when that paces the run), ``first_token`` and ``finish`` in
+    the event log, as ``sla.run_load`` does.
     """
     if timing not in ("logical", "recorded"):
         raise ValueError(f"timing must be 'logical' or 'recorded', got {timing!r}")
@@ -240,6 +248,7 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
     results: Dict[int, List[int]] = {}
     next_i = 0
     engine._sampling = None
+    events = get_event_log()
     t0 = time.perf_counter()
 
     def now() -> float:
@@ -261,6 +270,8 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
             stats[uid].admitted = now()
             results[uid] = []
             pending.append(reqs[uid])
+            events.emit("enqueue", uid, prompt=len(recs[uid]["prompt"]),
+                        ts=t0 + stats[uid].arrival if timing == "recorded" else None)
             next_i += 1
             force = False  # force admits exactly one (the idle un-sticker)
 
@@ -274,6 +285,7 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
         t = now()
         if not results[uid]:
             stats[uid].first_token = t
+            events.emit("first_token", uid, ts=t0 + t)
         results[uid].extend(toks_out)
         stats[uid].n_new = len(results[uid])
         finished = (len(results[uid]) >= req.max_new_tokens or
@@ -281,6 +293,7 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
         if finished:
             req.done = True
             stats[uid].done = t
+            events.emit("finish", uid, ts=t0 + t, n_new=stats[uid].n_new)
             engine.flush([uid])
         else:
             decode_ready[uid] = toks_out[-1]
@@ -290,10 +303,13 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
     spec_on = bool(getattr(engine, "_spec_enabled", False))
 
     while next_i < len(order) or pending or decode_ready:
-        admit()
+        q = engine.scheduler.last_quantum_id + 1  # the quantum this turn assembles
+        with telemetry_span("serve/admit", q=q):
+            admit()
         if not pending and not decode_ready:
             if timing == "recorded":
-                time.sleep(max(0.0, float(recs[order[next_i]].get("arrival_s", 0.0)) - now()))
+                with telemetry_span("serve/idle_wait", q=q):
+                    time.sleep(max(0.0, float(recs[order[next_i]].get("arrival_s", 0.0)) - now()))
                 continue
             admit(force=True)  # logical clock can't advance while idle
             continue
@@ -310,8 +326,11 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
                     commit(uid, toks_row)
                 continue
         if fused:
-            quantum = engine.scheduler.schedule_fused([r for r in pending if r.remaining_prefill],
-                                                      list(decode_ready))
+            with telemetry_span("serve/schedule", q=q, queue=len(pending), decodes=len(decode_ready)) as sp:
+                quantum = engine.scheduler.schedule_fused([r for r in pending if r.remaining_prefill],
+                                                          list(decode_ready))
+                q = engine.scheduler.last_quantum_id  # the id it claimed (a spec step may have claimed one before)
+                sp.set(q=q, rows=quantum.n_rows, tokens=quantum.total_tokens)
             if quantum.empty:
                 raise RuntimeError("scheduler deadlock: no work schedulable (KV pool too small?)")
             for pf in quantum.prefills:
@@ -322,10 +341,11 @@ def _drive_sla(engine, session: Session, timing: str = "logical",
                 steps = max(1, engine._burst_steps({u: True for u in quantum.decode_uids}, rem))
             carry = [decode_ready.pop(u) for u in quantum.decode_uids]
             rows = engine._run_fused(quantum, carry, steps, False, eos_token_id)
-            for uid, row in rows.items():
-                if row is not None:
-                    commit(uid, row.tolist())
-            pending = [r for r in pending if not r.done and r.remaining_prefill]
+            with telemetry_span("serve/commit", q=q):
+                for uid, row in rows.items():
+                    if row is not None:
+                        commit(uid, row.tolist())
+                pending = [r for r in pending if not r.done and r.remaining_prefill]
             continue
         if not pending and not arrivals_due and decode_ready:
             cap = min(engine.scheduler.max_sequences, engine.scheduler.max_batch_tokens)

@@ -96,6 +96,11 @@ class RaggedBatchScheduler:
         # numerator of the scheduler-level goodput view (the engine's
         # dispatch buckets add pow2 padding on top of this)
         self._m_useful = tele.counter("sched_useful_tokens_total")
+        # the token budget every quantum had, and the part of it prefill
+        # chunks took: batch fill is useful / slots, prefill's share of a
+        # quantum prefill_slots / useful
+        self._m_slots = tele.counter("sched_slot_tokens_total")
+        self._m_prefill_slots = tele.counter("sched_prefill_slot_tokens_total")
         self._events = get_event_log()
         self._quantum_seq = 0  # monotone id shared by fused and unfused paths
 
@@ -175,6 +180,8 @@ class RaggedBatchScheduler:
         self._m_prefill_chunks.inc(len(prefills))
         self._m_useful.inc(self.max_batch_tokens - budget)
         if prefills or sched_decodes:
+            self._m_slots.inc(self.max_batch_tokens)
+            self._m_prefill_slots.inc(sum(len(p.tokens) for p in prefills))
             self._events.emit("quantum", q=q, prefills=len(prefills),
                               decodes=len(sched_decodes),
                               tokens=self.max_batch_tokens - budget)
@@ -217,6 +224,7 @@ class RaggedBatchScheduler:
         self._m_quantum_rows.set(len(admitted))
         self._m_useful.inc(len(admitted) * tokens_per_row)
         if admitted:
+            self._m_slots.inc(self.max_batch_tokens)
             self._events.emit("quantum", q=q, prefills=0, decodes=len(admitted),
                               tokens=len(admitted) * tokens_per_row, spec_k=tokens_per_row - 1)
             journal = get_journal()

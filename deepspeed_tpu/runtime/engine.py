@@ -39,6 +39,7 @@ from ..telemetry import get_registry as get_telemetry_registry
 from ..telemetry import span as telemetry_span
 from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
                                 get_health_monitor)
+from ..utils.compile_cache import register_cache_metrics
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER, NoopTimer,
                            SynchronizedWallClockTimer, ThroughputTimer, TRAIN_BATCH_TIMER)
@@ -103,16 +104,18 @@ class DeepSpeedEngine:
                  collate_fn=None,
                  config=None,
                  dont_change_device: bool = False):
-        if dist_init_required is None or dist_init_required:
-            dist.init_distributed(verbose=False)
+        register_cache_metrics(jax)  # seconds of every first call, by phase (program_*_seconds_total)
+        with telemetry_span("init/mesh"):
+            if dist_init_required is None or dist_init_required:
+                dist.init_distributed(verbose=False)
 
-        self.config = config if isinstance(config, DeepSpeedConfig) else DeepSpeedConfig(config)
-        self.topology: MeshTopology = mesh if isinstance(mesh, MeshTopology) else initialize_mesh(self.config.mesh)
-        from .zero.mics import validate_mics_mesh
+            self.config = config if isinstance(config, DeepSpeedConfig) else DeepSpeedConfig(config)
+            self.topology: MeshTopology = mesh if isinstance(mesh, MeshTopology) else initialize_mesh(self.config.mesh)
+            from .zero.mics import validate_mics_mesh
 
-        validate_mics_mesh(self.config, self.topology)
-        self.config.resolve_batch_sizes(self.topology.data_parallel_size)
-        dist.configure(self.config)
+            validate_mics_mesh(self.config, self.topology)
+            self.config.resolve_batch_sizes(self.topology.data_parallel_size)
+            dist.configure(self.config)
 
         self.module = model
         self.client_optimizer = optimizer
@@ -129,82 +132,84 @@ class DeepSpeedEngine:
             raise TypeError("model must be callable (params, batch, rng) -> loss, or expose .loss_fn")
 
         # --- parameters (fp32 master, sharded per plan) ---
-        if model_parameters is None:
-            raise ValueError("model_parameters (the parameter pytree, or an init fn taking a PRNG key) is required")
-        if callable(model_parameters) and not hasattr(model_parameters, "keys"):
-            # documented init-fn form, resolved HERE so every engine class
-            # (pipeline/hybrid subclasses included) honors it with the
-            # accelerator's configured seed
-            model_parameters = model_parameters(jax.random.PRNGKey(get_accelerator().initial_seed()))
-        params_host = model_parameters
-        tp_rules = model.partition_rules() if hasattr(model, "partition_rules") else []
-        self._tp_rules = tp_rules
-        params_host = _cast_tree(params_host, jnp.float32)
-        param_shapes = jax.eval_shape(lambda: params_host)
-        self.param_specs = plan_param_specs(param_shapes, self.config, self.topology, tp_rules)
-        self.param_shardings = specs_to_shardings(self.param_specs, self.topology)
+        with telemetry_span("init/shard_state"):
+            if model_parameters is None:
+                raise ValueError("model_parameters (the parameter pytree, or an init fn taking a PRNG key) is required")
+            if callable(model_parameters) and not hasattr(model_parameters, "keys"):
+                # documented init-fn form, resolved HERE so every engine class
+                # (pipeline/hybrid subclasses included) honors it with the
+                # accelerator's configured seed
+                model_parameters = model_parameters(jax.random.PRNGKey(get_accelerator().initial_seed()))
+            params_host = model_parameters
+            tp_rules = model.partition_rules() if hasattr(model, "partition_rules") else []
+            self._tp_rules = tp_rules
+            params_host = _cast_tree(params_host, jnp.float32)
+            param_shapes = jax.eval_shape(lambda: params_host)
+            self.param_specs = plan_param_specs(param_shapes, self.config, self.topology, tp_rules)
+            self.param_shardings = specs_to_shardings(self.param_specs, self.topology)
 
-        # ZeRO-3 parameter offload: large leaves stored in pinned host
-        # memory, streamed to HBM inside each compiled step (reference
-        # partitioned_param_swapper.py:36, wired at stage3.py:583)
-        from .zero.param_offload import maybe_enable_param_offload
-        from .zero.zeropp import zeropp_applicable as _zpp_applicable
+            # ZeRO-3 parameter offload: large leaves stored in pinned host
+            # memory, streamed to HBM inside each compiled step (reference
+            # partitioned_param_swapper.py:36, wired at stage3.py:583)
+            from .zero.param_offload import maybe_enable_param_offload
+            from .zero.zeropp import zeropp_applicable as _zpp_applicable
 
-        # gate on the path that will actually run: merely *requesting* ZeRO++
-        # on an ineligible topology falls back to GSPMD, where offload works
-        _zpp_active = (_zpp_applicable(self.config, self.topology)[0]
-                       and not self.config.compression_config)
-        if _zpp_active and self.config.zero_config.offload_param.device in ("cpu", "nvme"):
-            logger.warning("offload_param is incompatible with the ZeRO++ manual shard_map path — "
-                           "parameters stay in device memory")
-            self.param_store_shardings, self._param_offload = self.param_shardings, False
-        else:
-            self.param_store_shardings, self._param_offload = maybe_enable_param_offload(
-                self.config, self.topology, self.param_shardings, param_shapes)
-        self.params = jax.device_put(params_host, self.param_store_shardings)
-        del params_host
+            # gate on the path that will actually run: merely *requesting* ZeRO++
+            # on an ineligible topology falls back to GSPMD, where offload works
+            _zpp_active = (_zpp_applicable(self.config, self.topology)[0]
+                           and not self.config.compression_config)
+            if _zpp_active and self.config.zero_config.offload_param.device in ("cpu", "nvme"):
+                logger.warning("offload_param is incompatible with the ZeRO++ manual shard_map path — "
+                               "parameters stay in device memory")
+                self.param_store_shardings, self._param_offload = self.param_shardings, False
+            else:
+                self.param_store_shardings, self._param_offload = maybe_enable_param_offload(
+                    self.config, self.topology, self.param_shardings, param_shapes)
+            self.params = jax.device_put(params_host, self.param_store_shardings)
+            del params_host
 
-        self.grad_specs = plan_grad_specs(param_shapes, self.param_specs, self.config, self.topology)
-        self.grad_shardings = specs_to_shardings(self.grad_specs, self.topology)
+            self.grad_specs = plan_grad_specs(param_shapes, self.param_specs, self.config, self.topology)
+            self.grad_shardings = specs_to_shardings(self.grad_specs, self.topology)
 
         # --- optimizer ---
-        if optimizer is not None and not isinstance(optimizer, optax.GradientTransformation):
-            raise TypeError("client optimizer must be an optax.GradientTransformation")
-        self.optimizer = optimizer if optimizer is not None else create_optimizer(
-            self.config.optimizer.type, self.config.optimizer.params)
+        with telemetry_span("init/optimizer"):
+            if optimizer is not None and not isinstance(optimizer, optax.GradientTransformation):
+                raise TypeError("client optimizer must be an optax.GradientTransformation")
+            self.optimizer = optimizer if optimizer is not None else create_optimizer(
+                self.config.optimizer.type, self.config.optimizer.params)
 
-        # ZeRO-Offload: optimizer states leave the device entirely
-        # (reference stage_1_and_2.py:1182-1277 cpu, stage3.py:1877 nvme)
-        self._host_offload = None
-        off = self.config.zero_config.offload_optimizer
-        if self.config.zero_enabled and off.device in ("cpu", "nvme"):
-            opt_name = (self.config.optimizer.type or "adamw").lower()
-            if optimizer is not None:
-                logger.warning("offload_optimizer requires a config-defined adam-family optimizer; a client "
-                               "optimizer object was passed — keeping optimizer states on device")
-            elif "adam" not in opt_name:
-                logger.warning(f"offload_optimizer supports adam-family optimizers; got {opt_name} — "
-                               "keeping optimizer states on device")
+            # ZeRO-Offload: optimizer states leave the device entirely
+            # (reference stage_1_and_2.py:1182-1277 cpu, stage3.py:1877 nvme)
+            self._host_offload = None
+            off = self.config.zero_config.offload_optimizer
+            if self.config.zero_enabled and off.device in ("cpu", "nvme"):
+                opt_name = (self.config.optimizer.type or "adamw").lower()
+                if optimizer is not None:
+                    logger.warning("offload_optimizer requires a config-defined adam-family optimizer; a client "
+                                   "optimizer object was passed — keeping optimizer states on device")
+                elif "adam" not in opt_name:
+                    logger.warning(f"offload_optimizer supports adam-family optimizers; got {opt_name} — "
+                                   "keeping optimizer states on device")
+                else:
+                    from .zero.offload import HostOffloadOptimizer
+
+                    off_p = self.config.zero_config.offload_param
+                    self._host_offload = HostOffloadOptimizer(jax.device_get(self.params),
+                                                              self.config.optimizer.params, offload_device=off.device,
+                                                              nvme_path=off.nvme_path,
+                                                              aio_threads=self.config.aio.thread_count,
+                                                              pipeline=off.pipeline_read or off.pipeline_write,
+                                                              params_on_nvme=(off_p.device == "nvme"
+                                                                              and bool(self._param_offload)),
+                                                              params_nvme_path=off_p.nvme_path)
+            if self._host_offload is None:
+                opt_specs, _ = plan_opt_state_specs(self.optimizer, param_shapes, self.param_specs, self.config,
+                                                    self.topology)
+                self.opt_state_shardings = specs_to_shardings(opt_specs, self.topology)
+                self.opt_state = jax.jit(self.optimizer.init, out_shardings=self.opt_state_shardings)(self.params)
             else:
-                from .zero.offload import HostOffloadOptimizer
-
-                off_p = self.config.zero_config.offload_param
-                self._host_offload = HostOffloadOptimizer(jax.device_get(self.params),
-                                                          self.config.optimizer.params, offload_device=off.device,
-                                                          nvme_path=off.nvme_path,
-                                                          aio_threads=self.config.aio.thread_count,
-                                                          pipeline=off.pipeline_read or off.pipeline_write,
-                                                          params_on_nvme=(off_p.device == "nvme"
-                                                                          and bool(self._param_offload)),
-                                                          params_nvme_path=off_p.nvme_path)
-        if self._host_offload is None:
-            opt_specs, _ = plan_opt_state_specs(self.optimizer, param_shapes, self.param_specs, self.config,
-                                                self.topology)
-            self.opt_state_shardings = specs_to_shardings(opt_specs, self.topology)
-            self.opt_state = jax.jit(self.optimizer.init, out_shardings=self.opt_state_shardings)(self.params)
-        else:
-            self.opt_state_shardings = None
-            self.opt_state = None
+                self.opt_state_shardings = None
+                self.opt_state = None
 
         # --- lr scheduler ---
         self.lr_scheduler = lr_scheduler

@@ -9,7 +9,7 @@ startup; ``set_enabled()`` flips them at runtime.
 
 from .registry import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                        MetricsRegistry, get_registry)
-from .tracing import SpanTracer, dump_trace, get_tracer, span
+from .tracing import SpanTracer, current_span, dump_trace, get_tracer, self_times, span
 from .bridge import MonitorBridge
 from .events import (EventLog, get_event_log, latency_summary,
                      lifecycle_signature, request_metrics,
@@ -35,6 +35,7 @@ from .profiler import (DeviceProfiler, build_waterfall, get_device_profiler,
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS",
     "get_registry", "SpanTracer", "get_tracer", "span", "dump_trace",
+    "current_span", "self_times",
     "MonitorBridge", "set_enabled",
     "EventLog", "get_event_log", "request_timelines", "request_metrics",
     "latency_summary", "lifecycle_signature", "validate_timeline",

@@ -33,12 +33,16 @@ prefix cache, and COW page-copy traffic.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, Optional, Tuple
 
 from ..analysis import knobs
+from ..utils.logging import logger
+from .registry import get_registry
+from .tracing import current_span, span
 
 __all__ = [
     "CostCard",
@@ -83,6 +87,47 @@ def resolve_peaks() -> Tuple[float, float]:
                     gbps = gb
                 break
     return (max(0.0, tflops) * 1e12, max(0.0, gbps) * 1e9)
+
+
+_UNSEEN = object()
+
+
+@contextlib.contextmanager
+def first_call(family: str, bucket: Any):
+    """The first call of one (program, signature): a ``program/first_call``
+    span with ``family``, ``bucket`` and the ``q`` and ``steps`` of the span
+    it runs under, closed with the seconds JAX spent by phase inside it (the
+    process-wide counters of ``utils/compile_cache.py``, read before and
+    after) and one log line of the same. The line is what a run that is cut
+    before ``dump_trace`` leaves behind, and what an operator greps for when
+    a step recompiles in production. Yields a dict for phases of the
+    caller's own (``cost_card``). ``programs`` counts what reached the
+    backend inside the span."""
+    import jax
+
+    from ..utils.compile_cache import PHASE_COUNTERS, PHASES, register_cache_metrics
+
+    register_cache_metrics(jax)
+    reg = get_registry()
+    up = current_span()
+    inherited = {k: up.attrs[k] for k in ("q", "steps") if k in up.attrs} if up is not None and up.attrs else {}
+    counters = PHASE_COUNTERS + ("program_first_calls_total",)
+    before = [reg.peek(c) or 0.0 for c in counters]
+    phases: Dict[str, float] = {}
+    t0 = time.perf_counter()
+    with span("program/first_call", family=family, bucket=bucket, **inherited) as sp:
+        yield phases
+        total = time.perf_counter() - t0
+        *seconds, programs = [(reg.peek(c) or 0.0) - b for c, b in zip(counters, before)]
+        phases.update(zip(PHASES, seconds))
+        # JAX's compile event spans the persistent-cache fetch: "other" is what no phase covers
+        phases["other"] = total - sum(v for k, v in phases.items() if k != "cache_fetch")
+        # programs: how many reached the backend in here (this one, and helper programs it called first)
+        sp.set(programs=int(programs), total_s=total, **{k + "_s": v for k, v in phases.items()})
+    if sp.attrs is not None:  # the tracer is on
+        logger.info("program first call: family=%s bucket=%s %s", family, bucket, " ".join(
+            [f"{k}={v}" for k, v in inherited.items()] + [f"total_s={total:.3f}"]
+            + [f"{k}_s={v:.3f}" for k, v in phases.items()]))
 
 
 def _aval_bytes(avals: Iterable[Any]) -> int:
@@ -209,8 +254,6 @@ class PerfAccountant:
         self._m_goodput = self._m_mfu = None
         self._m_hbm = {}
         if use_telemetry and self.enabled:
-            from . import get_registry
-
             tele = get_registry()
             self._m_flops = tele.counter("infer_model_flops_total")
             self._m_useful = tele.counter("infer_useful_tokens_total")
@@ -233,21 +276,38 @@ class PerfAccountant:
         return self._peaks
 
     # ----------------------------------------------------------- wiring
-    def wrap(self, name: str, fn, meta: Optional[Dict[str, Any]] = None, timed: bool = True):
-        """Return ``fn`` with cost accounting; identity when disabled."""
-        if not self.enabled:
+    def wrap(self, name: str, fn, meta: Optional[Dict[str, Any]] = None, timed: bool = True,
+             family: Optional[str] = None, bucket: Any = None):
+        """Return ``fn`` with cost accounting; identity when disabled.
+
+        ``family`` marks a program of one of the engine's caches: the first
+        call of each argument signature through THIS wrapper is then a
+        ``program/first_call`` span (``first_call`` below), accounting on or
+        off. A program rebuilt after an eviction is a new wrapper and pays,
+        and shows, its first calls again."""
+        if not self.enabled and family is None:
             return fn
         static_meta = dict(meta or {})
         static_meta.update(getattr(fn, "_cost_meta", None) or {})
         from ..analysis.jit_audit import leaf_signature
 
-        def wrapped(*args, **kwargs):
-            sig = leaf_signature(args) if not kwargs else (
-                leaf_signature(args), leaf_signature(kwargs))
+        cards: Dict[Any, Optional[CostCard]] = {}  # this wrapper's signatures (None: accounting is off)
+
+        def card_for(sig, args, kwargs, phases):
+            if not self.enabled:
+                return None
             key = (name, sig)
             card = self._cards.get(key)
             if card is None:
-                card = self._build_card(key, fn, args, kwargs, static_meta)
+                t0 = time.perf_counter()
+                with span("program/cost_card", program=name):
+                    card = self._build_card(key, fn, args, kwargs, static_meta)
+                phases["cost_card"] = time.perf_counter() - t0
+            return card
+
+        def dispatch(card, args, kwargs):
+            if card is None:
+                return fn(*args, **kwargs)
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
             with self._lock:
@@ -257,6 +317,16 @@ class PerfAccountant:
                     # dispatch site's readback, closed by attribute()
                     self._open = (card, t0)
             return out
+
+        def wrapped(*args, **kwargs):
+            sig = leaf_signature(args) if not kwargs else (
+                leaf_signature(args), leaf_signature(kwargs))
+            card = cards.get(sig, _UNSEEN)  # one walk of the signature's hash a call
+            if card is not _UNSEEN:
+                return dispatch(card, args, kwargs)
+            with (first_call(family, bucket) if family is not None else contextlib.nullcontext({})) as phases:
+                card = cards[sig] = card_for(sig, args, kwargs, phases)
+                return dispatch(card, args, kwargs)
 
         wrapped.__wrapped__ = fn  # type: ignore[attr-defined]
         wrapped._perf_account_name = name  # type: ignore[attr-defined]

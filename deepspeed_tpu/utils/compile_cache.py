@@ -38,13 +38,25 @@ def enable_compilation_cache(jax, default_dir: str = CHECKOUT_CACHE_DIR, env_gat
     return cache_dir
 
 
-def register_cache_metrics(jax) -> bool:
-    """Feed jax's compilation-cache monitoring events into the telemetry
-    registry (``compile_cache_hits_total`` / ``compile_cache_misses_total``).
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# seconds of every program's first call, by phase, in the order a call passes through them
+PHASES = ("trace", "lower", "compile", "cache_fetch")
+PHASE_COUNTERS = tuple(f"program_{phase}_seconds_total" for phase in PHASES)
 
-    Idempotent; returns True once the listener is installed. jax records
-    ``/jax/compilation_cache/cache_hits`` when an executable is read back
-    and ``.../cache_misses`` when a freshly compiled one is written.
+
+def register_cache_metrics(jax) -> bool:
+    """Feed jax's monitoring events into the telemetry registry.
+
+    Idempotent; returns True once the listeners are installed. Counts:
+    ``compile_cache_hits_total`` / ``compile_cache_misses_total`` (an
+    executable read back from the persistent cache / a fresh one written).
+    Seconds, process-wide, of every program's first call by phase: the four
+    ``PHASE_COUNTERS``, and ``program_first_calls_total``, one per program that
+    reached the backend (compiled, or fetched from the persistent cache: JAX's
+    compile event spans both, so ``program_compile_seconds_total`` includes the
+    fetch). A trace nested in another program's trace is part of the outer
+    one's seconds and is not counted again.
     """
     if _METRICS_REGISTERED:
         return True
@@ -55,6 +67,15 @@ def register_cache_metrics(jax) -> bool:
     reg = get_registry()
     hits = reg.counter("compile_cache_hits_total")
     misses = reg.counter("compile_cache_misses_total")
+    # JAX records each duration when the phase ENDS, on the thread that ran it
+    seconds = {
+        TRACE_EVENT: reg.counter("program_trace_seconds_total"),
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": reg.counter("program_lower_seconds_total"),
+        COMPILE_EVENT: reg.counter("program_compile_seconds_total"),
+        "/jax/compilation_cache/cache_retrieval_time_sec": reg.counter("program_cache_fetch_seconds_total"),
+    }
+    first_calls = reg.counter("program_first_calls_total")
+    top_level = jax.core.trace_ctx.is_top_level
 
     def _listener(event, *args, **kwargs):
         if event == "/jax/compilation_cache/cache_hits":
@@ -62,6 +83,15 @@ def register_cache_metrics(jax) -> bool:
         elif event == "/jax/compilation_cache/cache_misses":
             misses.inc()
 
+    def _on_duration(event, duration, **kwargs):
+        counter = seconds.get(event)
+        if counter is None or (event == TRACE_EVENT and not top_level()):
+            return
+        counter.inc(duration)
+        if event == COMPILE_EVENT:
+            first_calls.inc()
+
     monitoring.register_event_listener(_listener)
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _METRICS_REGISTERED.append(_listener)
     return True
