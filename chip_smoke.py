@@ -268,7 +268,7 @@ def _flash_check(shape):
         if not ok or not bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))):
             bad.append(name)
     check(not bad, f"flash {shape}: {bad} exceed {F32_HEADROOM}x the plain bf16 error: {out}")
-    check(REHEARSE or n_calls >= 3, f"flash {shape}: expected fwd+dq+dkv kernels, found {n_calls} custom calls")
+    check(REHEARSE or n_calls >= 2, f"flash {shape}: expected fwd+bwd kernels, found {n_calls} custom calls")
     return {"shape_BSHKD": list(shape), "compared": "flash_attention fwd+bwd vs attention_xla, f32-referenced",
             "errors": out, "custom_calls": n_calls}
 

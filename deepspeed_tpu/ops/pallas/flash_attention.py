@@ -8,13 +8,34 @@ same as on GPU: never materialize the (S, S) probability matrix in HBM —
 blocked online softmax in VMEM feeding the MXU.
 
 Forward and backward are both Pallas kernels, stitched with
-``jax.custom_vjp``. Layout: inputs (B, S, H, D) are transposed to
-(B, H, S, D); grid is (B*H, Sq/bq) for fwd/dq and (B*KVH, Sk/bk, n_rep)
-for dkv. GQA is native: KV stays collapsed at (B, S, KVH, D) in HBM and
-the kernels route each q head to its group's KV head by BlockSpec index
-map — at llama-70B-class 8:1 grouping that is 8x less KV HBM traffic
-than pre-expanding, and dk/dv accumulate across the group in-kernel
-instead of materializing expanded cotangents.
+``jax.custom_vjp``: two calls a layer, forward and ONE backward. Layout:
+inputs (B, S, H, D) are transposed to (B, H, S, D). The forward runs a
+(B*H, Sq/bq) grid and walks the kv blocks a q block sees. The backward
+runs (B*KVH, n_rep, Sk/bk), kv blocks innermost: a head's q, do, lse and
+delta stay in VMEM over the walk and so does its dq, so s, p, dp and ds are
+formed once a block and dq, dk, dv all leave the one kernel in the input
+dtype (``_bwd_fused_kernel``). Every kernel works on kv-major (bk, bq)
+tiles, key position on sublanes: the per-row softmax statistics are (1, bq)
+rows that reduce and broadcast along sublanes, and of the backward's five
+products only dq's takes a transposed operand. Mask arithmetic runs only on
+the blocks that cross the diagonal or a window's far edge (``_kv_runs`` /
+``_q_runs``); the blocks between take the same ``_scores`` with the mask
+statically off.
+
+What the code observes to choose a path (``flash_attention_traced_total``
+counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
+the query-major dq kernels (dbias is written there) on a (B*H, Sq/bq) grid
+and a dkv kernel on (B*H, Sk/bk). Without a bias there is the fused kernel
+alone: a head whose q, do and dq do not fit ``vmem_budget()`` at once
+(about 19,000 positions at D=128 in bf16 on a v5e) is refused by name when
+its backward is traced; no caller sends one yet. GQA is native: KV stays
+collapsed at (B, S, KVH, D) in HBM and the kernels route each q head to its
+group's KV head by BlockSpec index map — at llama-70B-class 8:1 grouping
+that is 8x less KV HBM traffic than pre-expanding, and dk/dv accumulate
+across the group in-kernel, in float32, instead of materializing expanded
+cotangents. The softmax scale multiplies the float32 scores, as in
+``attention_xla``: scaling a bf16 operand first saves 4% of the forward and
+doubles the kernels' distance from float32 (PERF.md, PR 26).
 """
 
 import functools
@@ -24,11 +45,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...analysis import knobs
 from ..registry import REGISTRY, pallas_available
-from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh
+from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh, vmem_budget
 
 NEG_INF = -1e30
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
@@ -38,7 +60,9 @@ LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
 # short MXU ops — many tiny (128,128) programs are latency-bound, not
 # FLOP-bound. (512, 512) keeps the fp32 score block at 1 MB of VMEM,
 # amortizes the chain over 16x more MXU work, and stays causal-efficient
-# at the block boundary. Overridable for autotuning.
+# at the block boundary; it is also the fastest of the shapes from 256 to
+# 2048 for the forward and the fused backward alike at S=2048, D=128
+# (PERF.md, PR 26). Overridable for autotuning.
 DEFAULT_BQ = knobs.get_int("DS_TPU_FLASH_BQ")
 DEFAULT_BK = knobs.get_int("DS_TPU_FLASH_BK")
 
@@ -61,26 +85,105 @@ def _blk(seq: int, want: int) -> int:
     return got
 
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T: both operands contract their minor dim (native on the MXU)
+_NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b: the transposed-lhs products (forward: v^T p; backward: ds^T k)
 
-def _scores(q, k, slope, row0, col0, bq, bk, scale, causal, has_alibi, window, btile=None):
-    """(bq, bk) fp32 masked scores — the ONE definition of the mask/bias
-    math; fwd and both bwd kernels recompute s through this so they can
-    never drift apart. ``btile``: additive bias tile (evoformer pair/mask
-    bias, reference DS4Sci_EvoformerAttention) — added before masking so
-    masked entries stay exactly NEG_INF."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+
+def _scores(q, k, slope, row0, col0, scale, causal, has_alibi, window, btile=None, *, masked=True,
+            kv_major=False):
+    """fp32 masked scores of one block: the ONE definition of the mask/bias
+    math; fwd and every bwd kernel recompute s through this so they can
+    never drift apart.
+
+    Query-major (the dq kernels) gives (bq, bk); ``kv_major`` gives the
+    transpose (bk, bq), key position on sublanes, which is what the forward
+    and the kv-block-major backward want: the softmax's max and sum reduce
+    along sublanes, dv and dk are plain products, and the per-row lse/delta
+    broadcast along sublanes. ``masked=False`` is the same
+    function for a block the caller knows lies wholly inside the mask (below
+    the diagonal, inside the window): no iota, compare or select.
+    ``btile``: additive bias tile in the block's orientation (evoformer
+    pair/mask bias, reference DS4Sci_EvoformerAttention), added before
+    masking so masked entries stay exactly NEG_INF."""
+    if kv_major:
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+        col_dim, row_dim = 0, 1
+    else:
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
+        col_dim, row_dim = 1, 0
+    mask_here = causal and masked  # window implies causal (non-causal windows fall back to XLA)
+    if has_alibi or mask_here:
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, col_dim)
     if has_alibi:  # shift-invariant ALiBi: slope * key_position
         s = s + slope * cols.astype(jnp.float32)
     if btile is not None:
         s = s + btile.astype(jnp.float32)
-    if causal:  # window implies causal (non-causal windows fall back to XLA)
-        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    if mask_here:
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, row_dim)
         mask = cols <= rows
         if window > 0:
             mask = mask & (cols > rows - window)
         s = jnp.where(mask, s, NEG_INF)
     return s
+
+
+def _kv_runs(qi, *, bq, bk, seq_q, seq_k, causal, window):
+    """The kv blocks one q block visits, as ``(first, end, masked)`` runs.
+
+    Only a block that crosses the diagonal (or, with a window, the window's
+    far edge) has a masked element; the blocks between lie wholly inside the
+    mask and take ``_scores(masked=False)``. Queries align to the END of the
+    kv sequence (matches attention_xla); ``qi`` is traced."""
+    nk = seq_k // bk
+    if not causal:
+        return [(0, nk, False)]
+    r0 = seq_k - seq_q + qi * bq  # the block's first row, in key positions
+    end = jnp.minimum(pl.cdiv(r0 + bq, bk), nk)  # past the last block any row sees
+    first = jnp.maximum(r0 - window + 1, 0) // bk if window > 0 else 0
+    # blocks [.., full) end at or before the first row's own position
+    full = jnp.clip(jnp.maximum(r0 + 1, 0) // bk, first, end)
+    if window <= 0:
+        return [(first, full, False), (full, end, True)]
+    # blocks [inside, ..) start after the last row's window has begun
+    inside = jnp.clip(jnp.maximum(r0 + bq - 1 - window + bk, 0) // bk, first, full)
+    return [(first, inside, True), (inside, full, False), (full, end, True)]
+
+
+def _q_runs(kj, *, bq, bk, seq_q, seq_k, causal, window):
+    """``_kv_runs`` seen from a kv block: the q blocks that visit it."""
+    nq = seq_q // bq
+    if not causal:
+        return [(0, nq, False)]
+    c0 = kj * bk - (seq_k - seq_q)  # the block's first column, in query positions
+    first = jnp.maximum(c0, 0) // bq  # row r sees column c iff c <= r
+    end = nq
+    if window > 0:  # ... and c > r - window: the last column is seen up to row c0 + bk + window - 2
+        end = jnp.minimum(jnp.maximum(c0 + bk + window - 2 + bq, 0) // bq, nq)
+    # blocks [full, ..) start at or after the block's last column
+    full = jnp.clip(jnp.maximum(c0 + bk + bq - 2, 0) // bq, first, end)
+    if window <= 0:
+        return [(first, full, True), (full, end, False)]
+    # blocks [.., inside) end before the first column leaves their last row's window
+    inside = jnp.clip(jnp.maximum(c0 + window, 0) // bq, full, end)
+    return [(first, full, True), (full, inside, False), (inside, end, True)]
+
+
+def _walk(runs, body, carry):
+    """``body(block, carry, masked)`` over every block of ``runs`` in order."""
+    for first, end, masked in runs:
+        carry = jax.lax.fori_loop(first, end, functools.partial(body, masked=masked), carry)
+    return carry
+
+
+def _needs_empty_guard(seq_q: int, seq_k: int, has_bias: bool) -> bool:
+    """Whether ``p`` must be zeroed where ``s <= NEG_INF``. A row with no
+    visible column has m == lse == NEG_INF, so exp(s - m) is 1 on its masked
+    columns; such rows exist only when ``seq_q > seq_k`` (queries before the
+    first key), or when a bias of -inf hides a whole row. Everywhere else a
+    masked score underflows to exactly 0 in the exp and the per-element
+    compare-and-select is work for nothing."""
+    return has_bias or seq_q > seq_k
 
 
 # ----------------------------------------------------------------------
@@ -106,51 +209,45 @@ def _bias_bh_fn(bias_meta, H: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq: int, bk: int, seq_q: int,
-                seq_k: int, scale: float, causal: bool, has_alibi: bool, window: int, has_bias: bool):
+                seq_k: int, scale: float, causal: bool, has_alibi: bool, window: int, has_bias: bool,
+                sqb1: bool):
+    """One q block against the kv blocks it sees, on kv-major (bk, bq) tiles
+    (``_scores``): the running max and sum are (1, bq) rows that reduce and
+    broadcast along sublanes, the accumulator is (D, bq) and turned once at
+    the end, and lse leaves as the row the backward reads (``_rows``)."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (bq, D) input dtype — MXU runs bf16 operands w/ fp32 accumulation
     D = q.shape[-1]
-    slope = slopes_ref[0, 0, 0]  # per-head ALiBi slope (0 when disabled)
+    slope = slopes_ref[0, 0, 0]
+    row0 = seq_k - seq_q + qi * bq
+    guard = _needs_empty_guard(seq_q, seq_k, has_bias)
 
-    # queries align to the END of the kv sequence (matches attention_xla)
-    offset = seq_k - seq_q
-    nk = seq_k // bk
-    j0 = 0
-    if causal:
-        # last kv block that any row of this q block can see (qi is traced)
-        nk = jnp.minimum(pl.cdiv(offset + (qi + 1) * bq, bk), seq_k // bk)
-    if window > 0:
-        # first kv block any row of this q block can see: row r attends
-        # cols in (r - window, r]; the block's min row is offset + qi*bq
-        j0 = jnp.maximum(offset + qi * bq - window + 1, 0) // bk
-
-    def body(j, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.dslice(j * bk, bk), :]  # (bk, D)
+    def body(j, carry, masked):
+        acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
+        k = k_ref[0, pl.dslice(j * bk, bk), :]
         v = v_ref[0, pl.dslice(j * bk, bk), :]
-        # sq-broadcast biases carry one row that broadcasts over the block
-        btile = bias_ref[0, :, pl.dslice(j * bk, bk)] if has_bias else None
-        s = _scores(q, k, slope, offset + qi * bq, j * bk, bq, bk, scale, causal, has_alibi, window, btile)
-        bmax = jnp.max(s, axis=-1)
-        new_m = jnp.maximum(m, bmax)
-        p = jnp.exp(s - new_m[:, None])
-        # fully-masked rows (possible when seq_q > seq_k) have new_m == NEG_INF
-        # and would get p == exp(0) == 1 on masked columns; keep bwd-consistent
-        p = jnp.where(s <= NEG_INF, 0.0, p)
+        btile = None
+        if has_bias:  # the (bk, 1) column all rows share, or the (bq, bk) tile turned kv-major
+            btile = bias_ref[0, pl.dslice(j * bk, bk), :] if sqb1 else bias_ref[0, :, pl.dslice(j * bk, bk)].T
+        s = _scores(q, k, slope, row0, j * bk, scale, causal, has_alibi, window, btile, masked=masked,
+                    kv_major=True)
+        new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - new_m)
+        if guard and (masked or has_bias):
+            p = jnp.where(s <= NEG_INF, 0.0, p)
         corr = jnp.exp(m - new_m)
-        new_l = l * corr + jnp.sum(p, axis=-1)
-        new_acc = acc * corr[:, None] + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                                                           preferred_element_type=jnp.float32)
+        new_l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        new_acc = acc * corr + jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
         return new_acc, new_m, new_l
 
-    acc0 = jnp.zeros((bq, D), jnp.float32)
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(j0, nk, body, (acc0, m0, l0))
+    acc0 = jnp.zeros((D, bq), jnp.float32)
+    m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((1, bq), jnp.float32)
+    runs = _kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    acc, m, l = _walk(runs, body, (acc0, m0, l0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse = (m + jnp.log(l_safe)).astype(jnp.float32)
-    lse_ref[0] = jax.lax.broadcast_in_dim(lse, (lse.shape[0], LANES), (0,))
+    o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = m + jnp.log(l_safe)
 
 
 def _kv_of_fn(H: int, KVH: int):
@@ -165,6 +262,14 @@ def _kv_of_fn(H: int, KVH: int):
     return kv_of
 
 
+def _count_traced(pass_: str, path: str):
+    """The kernels are chosen while a program is traced, so that is where the
+    choice is counted (docs/OBSERVABILITY.md): one a call site a trace."""
+    from ...telemetry.registry import get_registry
+
+    get_registry().counter("flash_attention_traced_total", **{"pass": pass_, "path": path}).inc()
+
+
 def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: bool, has_alibi: bool,
                window: int, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
@@ -172,20 +277,24 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     bq, bk = _blk(Sq, DEFAULT_BQ), _blk(Sk, DEFAULT_BK)
-    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
-                               has_alibi=has_alibi, window=window, has_bias=has_bias)
+    _count_traced("fwd", "single")
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block
-    if has_bias:
-        bias_bh = _bias_bh_fn(bias_meta, H)
-        sq_rows = 1 if bias_meta[2] == 1 else bq
-        bias_spec = pl.BlockSpec((1, sq_rows, Sk),
-                                 lambda b, i: (bias_bh(b), 0 if sq_rows == 1 else i, 0))
+    sqb1 = has_bias and bias_meta[2] == 1
+    bias_bh = _bias_bh_fn(bias_meta, H) if has_bias else None
+    if sqb1:  # the kv-major kernel adds a row-broadcast bias as a (Sk, 1) column
+        bias = bias.reshape(-1, Sk, 1)
+        bias_spec = pl.BlockSpec((1, Sk, 1), lambda b, i: (bias_bh(b), 0, 0))
+    elif has_bias:
+        bias_spec = pl.BlockSpec((1, bq, Sk), lambda b, i: (bias_bh(b), i, 0))
     else:
         bias_spec = pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0))
+    vmem = (2 * (2 * bq * D + 2 * Sk * D) * q.dtype.itemsize + _tile_bytes(bq, bk)
+            + (2 * (LANES if sqb1 else bq) * Sk * 4 if has_bias else 0))
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
+                          has_alibi=has_alibi, window=window, has_bias=has_bias, sqb1=sqb1),
         grid=(BH, Sq // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -196,63 +305,68 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, LANES), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sq // bq, 1, bq), jnp.float32),  # one row a q block: _rows
         ],
         interpret=interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
+        compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=vmem),
     )(q, k, v, slopes, bias)
-    return o, lse
+    return o, lse.reshape(BH, Sq)
 
 
 # ----------------------------------------------------------------------
 # backward
 # ----------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, bias_ref, dq_ref, dbias_ref, *,
-               bq, bk, seq_q, seq_k, scale, causal, has_alibi, window, has_bias):
-    qi = pl.program_id(1)
-    slope = slopes_ref[0, 0, 0]
+def _dq_accumulate(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slope, bias_ref, on_dlogits, qi, *,
+                   bq, bk, seq_q, seq_k, scale, causal, has_alibi, window):
+    """(bq, D) dq of one q block under a bias: the ONE definition of the
+    query-major gradient algebra, shared by both dq kernels.
+    ``on_dlogits(j, dlogits)`` receives each visited block's (bq, bk) logit
+    gradient (dbias). A bias can hide a whole row, so the empty-row guard
+    (``_needs_empty_guard``) is always on here."""
     q = q_ref[0]
     do = do_ref[0]
     lse = lse_ref[0, :, 0]
     delta = delta_ref[0, :, 0]
-    D = q.shape[-1]
 
-    offset = seq_k - seq_q
-    nk = seq_k // bk
-    j0 = 0
-    if causal:
-        nk = jnp.minimum(pl.cdiv(offset + (qi + 1) * bq, bk), nk)
-    if window > 0:
-        j0 = jnp.maximum(offset + qi * bq - window + 1, 0) // bk
-    if has_bias:
-        # blocks the loop skips contribute zero dbias; clear the whole row
-        # band first so skipped tiles don't hold stale VMEM contents
-        dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
-
-    def body(j, dq):
+    def body(j, dq, masked):
         k = k_ref[0, pl.dslice(j * bk, bk), :]
         v = v_ref[0, pl.dslice(j * bk, bk), :]
-        btile = bias_ref[0, :, pl.dslice(j * bk, bk)] if has_bias else None
-        s = _scores(q, k, slope, offset + qi * bq, j * bk, bq, bk, scale, causal, has_alibi, window, btile)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(s <= NEG_INF, 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # (bq, bk)
+        btile = bias_ref[0, :, pl.dslice(j * bk, bk)]
+        s = _scores(q, k, slope, seq_k - seq_q + qi * bq, j * bk, scale, causal, has_alibi, window, btile,
+                    masked=masked)
+        p = jnp.where(s <= NEG_INF, 0.0, jnp.exp(s - lse[:, None]))
+        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)  # (bq, bk)
         dlogits = p * (dp - delta[:, None])
-        if has_bias:  # dbias = dlogits (bias enters the logits additively, unscaled)
-            dbias_ref[0, :, pl.dslice(j * bk, bk)] = dlogits.astype(dbias_ref.dtype)
-        ds = (dlogits * scale).astype(k.dtype)
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        on_dlogits(j, dlogits)
+        return dq + jax.lax.dot_general(dlogits.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(j0, nk, body, jnp.zeros((bq, D), jnp.float32))
+    runs = _kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    return _walk(runs, body, jnp.zeros((bq, q.shape[-1]), jnp.float32)) * scale
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, bias_ref, dq_ref, dbias_ref,
+               **statics):
+    """dq and per-program dbias tiles on a (B*H, Sq/bq) grid. The bias path
+    is its only caller (dbias is written here); without a bias dq comes out
+    of ``_bwd_fused_kernel``."""
+    # blocks the loop skips contribute zero dbias; clear the whole row
+    # band first so skipped tiles don't hold stale VMEM contents
+    dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
+
+    def on_dlogits(j, dlogits):  # dbias = dlogits (bias enters the logits additively, unscaled)
+        dbias_ref[0, :, pl.dslice(j * statics["bk"], statics["bk"])] = dlogits.astype(dbias_ref.dtype)
+
+    dq = _dq_accumulate(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0], bias_ref,
+                        on_dlogits, pl.program_id(1), **statics)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dq_kernel_collapsed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, bias_ref, dq_ref,
-                         dbias_ref, *, bq, bk, seq_q, seq_k, scale, causal, has_alibi, window, sqb1: bool):
+                         dbias_ref, *, sqb1: bool, **statics):
     """dq + ACCUMULATED dbias for a collapsed (broadcast) bias.
 
     Grid (n_bh, Sq//bq, n_rep) with the repeat dim innermost: every program
@@ -263,162 +377,241 @@ def _dq_kernel_collapsed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes
     """
     qi = pl.program_id(1)
     rep = pl.program_id(2)
-    slope = slopes_ref[0, 0, 0]
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
-    D = q.shape[-1]
-
+    bk = statics["bk"]
     first = jnp.logical_and(qi == 0, rep == 0) if sqb1 else (rep == 0)
 
     @pl.when(first)
     def _zero():
         dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
 
-    offset = seq_k - seq_q
-    nk = seq_k // bk
-    j0 = 0
-    if causal:
-        nk = jnp.minimum(pl.cdiv(offset + (qi + 1) * bq, bk), nk)
-    if window > 0:
-        j0 = jnp.maximum(offset + qi * bq - window + 1, 0) // bk
-
-    def body(j, dq):
-        k = k_ref[0, pl.dslice(j * bk, bk), :]
-        v = v_ref[0, pl.dslice(j * bk, bk), :]
-        btile = bias_ref[0, :, pl.dslice(j * bk, bk)]
-        s = _scores(q, k, slope, offset + qi * bq, j * bk, bq, bk, scale, causal, has_alibi, window, btile)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(s <= NEG_INF, 0.0, p)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        dlogits = p * (dp - delta[:, None])
+    def on_dlogits(j, dlogits):
         contrib = jnp.sum(dlogits, axis=0, keepdims=True) if sqb1 else dlogits
         cur = dbias_ref[0, :, pl.dslice(j * bk, bk)]
         dbias_ref[0, :, pl.dslice(j * bk, bk)] = cur + contrib.astype(dbias_ref.dtype)
-        ds = (dlogits * scale).astype(k.dtype)
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(j0, nk, body, jnp.zeros((bq, D), jnp.float32))
+    dq = _dq_accumulate(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0], bias_ref,
+                        on_dlogits, qi, **statics)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj, *,
-                    bq, bk, seq_q, seq_k, scale, causal, has_alibi, window):
+def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj, on_ds=None, *,
+                    bq, bk, seq_q, seq_k, scale, causal, has_alibi, window, has_bias=False):
     """(bk, D) dk/dv for one kv block — the ONE definition of the dkv
-    gradient algebra (visible-q-block bounds + ds formula), shared by the
-    per-q-head and GQA-revisit kernels so they can never drift apart.
-    ``btile_fn(i)`` returns the additive-bias tile for q block i (or None)."""
+    gradient algebra (visible-q-block runs + ds formula), shared by the
+    fused and the per-q-head (bias) kernels so they can never drift apart.
+    Works on transposed (bk, bq) tiles (``_scores``).
+    ``lse_ref`` / ``delta_ref`` hold one (1, bq) row a q block (``_rows``).
+    ``btile_fn(i)`` returns the (bk, bq) additive-bias tile for q block i (or
+    None). ``on_ds(i, ds)``, where given, receives each visited block's
+    (bk, bq) logit gradient in the input dtype: dq's share is ``ds^T @ k``
+    (times ``scale``), which the fused kernel adds up instead of a second
+    kernel recomputing s, p and dp to get there."""
     D = k.shape[-1]
-    offset = seq_k - seq_q
-    nq = seq_q // bq
-    start = 0
-    if causal:
-        # first q block that can see this kv block (row offset+r sees col c iff c <= offset+r)
-        start = jnp.maximum(kj * bk - offset, 0) // bq
-    nq_end = nq
-    if window > 0:
-        # last q block whose rows still see this kv block: row <= col + window - 1
-        last_row = jnp.minimum((kj + 1) * bk - 1 + window - 1 - offset, seq_q - 1)
-        nq_end = jnp.minimum(last_row // bq + 1, nq)
+    guard = _needs_empty_guard(seq_q, seq_k, has_bias)
 
-    def body(i, carry):
+    def body(i, carry, masked):
         dk, dv = carry
         q = q_ref[0, pl.dslice(i * bq, bq), :]
         do = do_ref[0, pl.dslice(i * bq, bq), :]
-        lse = lse_ref[0, pl.dslice(i * bq, bq), 0]
-        delta = delta_ref[0, pl.dslice(i * bq, bq), 0]
-        s = _scores(q, k, slope, offset + i * bq, kj * bk, bq, bk, scale, causal, has_alibi, window,
-                    btile_fn(i))
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(s <= NEG_INF, 0.0, p)
-        pc = p.astype(do.dtype)
-        dv = dv + jax.lax.dot_general(pc, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(q.dtype)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        s = _scores(q, k, slope, seq_k - seq_q + i * bq, kj * bk, scale, causal, has_alibi, window,
+                    btile_fn(i), masked=masked, kv_major=True)
+        p = jnp.exp(s - lse_ref[0, i])
+        if guard and (masked or has_bias):
+            p = jnp.where(s <= NEG_INF, 0.0, p)
+        dv = dv + jax.lax.dot_general(p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)  # (bk, bq)
+        ds = (p * (dp - delta_ref[0, i])).astype(q.dtype)  # the logits' gradient; ``scale`` goes onto the sums
+        dk = dk + jax.lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
+        if on_ds is not None:
+            on_ds(i, ds)
         return dk, dv
 
-    dk0 = jnp.zeros((bk, D), jnp.float32)
-    dv0 = jnp.zeros((bk, D), jnp.float32)
-    return jax.lax.fori_loop(start, nq_end, body, (dk0, dv0))
+    runs = _q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    zeros = jnp.zeros((bk, D), jnp.float32)
+    dk, dv = _walk(runs, body, (zeros, zeros))
+    return dk * scale, dv
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, bias_ref, dk_ref, dv_ref, *,
-                bq, bk, seq_q, seq_k, scale, causal, has_alibi, window, has_bias, sqb1: bool = False):
+                sqb1: bool, **statics):
+    """dk/dv on a (B*H, Sk/bk) grid: the bias path's (KV arrives expanded)."""
     kj = pl.program_id(1)
+    bq = statics["bq"]
 
-    def btile_fn(i):
-        if not has_bias:
-            return None
-        return bias_ref[0, :, :] if sqb1 else bias_ref[0, pl.dslice(i * bq, bq), :]
+    def btile_fn(i):  # kv-major: the (bq, bk) tile transposed, or the (bk, 1) column all q rows share
+        return bias_ref[0] if sqb1 else bias_ref[0, pl.dslice(i * bq, bq), :].T
 
     dk, dv = _dkv_accumulate(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0],
-                             btile_fn, kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, scale=scale,
-                             causal=causal, has_alibi=has_alibi, window=window)
+                             btile_fn, kj, has_bias=True, **statics)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _dkv_kernel_gqa(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, dk_ref, dv_ref, *,
-                    bq, bk, seq_q, seq_k, scale, causal, has_alibi, window):
-    """dk/dv with GQA collapsed: grid (B*KVH, Sk//bk, n_rep), the group
-    dim INNERMOST so every program sharing a KV head revisits the same
-    dk/dv block consecutively and accumulates in place (the same
-    revisit pattern as ``_dq_kernel_collapsed``'s dbias). n_rep == 1 is
-    plain MHA and degenerates to a single visit."""
-    kj = pl.program_id(1)
-    rep = pl.program_id(2)
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, dq_ref, dk_ref, dv_ref,
+                      dq_acc, *kv_acc, n_rep: int, **statics):
+    """dq, dk and dv in ONE walk: grid (B*KVH, n_rep, Sk//bk), kv blocks
+    innermost. A head's q, do, lse and delta stay in VMEM over the walk (as
+    in the dkv kernels) and so does its dq, as a float32 scratch that every
+    kv block adds ``ds^T @ k`` into and the last one writes out in the input
+    dtype: s, p, dp and ds are formed once a block instead of once in each of
+    two kernels. With n_rep == 1 each dk/dv block has one visit and leaves in
+    the input dtype; a GQA group adds its heads up in float32 scratch over
+    the whole (Sk, D) and writes once, after its last head."""
+    rep, kj = pl.program_id(1), pl.program_id(2)
+    last_kj = pl.num_programs(2) - 1
+    bq, bk = statics["bq"], statics["bk"]
+
+    @pl.when(kj == 0)
+    def _zero():  # a window or seq_q < seq_k leaves q blocks that kv block 0 never visits
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[0]
+
+    def on_ds(i, ds):
+        rows = pl.dslice(i * bq, bq)
+        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
+
+    dk, dv = _dkv_accumulate(q_ref, k, v_ref[0], do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0],
+                             lambda i: None, kj, on_ds, **statics)
+
+    @pl.when(kj == last_kj)
+    def _dq_out():
+        dq_ref[0] = (dq_acc[...] * statics["scale"]).astype(dq_ref.dtype)
+
+    if n_rep == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    # the float32 sum over the group's q heads: the first assigns (no zero fill), the rest add
+    rows = pl.dslice(kj * bk, bk)
 
     @pl.when(rep == 0)
-    def _zero():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
+    def _first():
+        for acc, value in zip(kv_acc, (dk, dv)):
+            acc[rows, :] = value
 
-    dk, dv = _dkv_accumulate(q_ref, k_ref[0], v_ref[0], do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0],
-                             lambda i: None, kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, scale=scale,
-                             causal=causal, has_alibi=has_alibi, window=window)
-    dk_ref[0] = dk_ref[0] + dk  # fp32 outputs: cross-group accumulation stays exact
-    dv_ref[0] = dv_ref[0] + dv
+    @pl.when(rep > 0)
+    def _add():
+        for acc, value in zip(kv_acc, (dk, dv)):
+            acc[rows, :] = acc[rows, :] + value
+
+    @pl.when(jnp.logical_and(rep == n_rep - 1, kj == last_kj))
+    def _dkv_out():
+        for ref, acc in zip((dk_ref, dv_ref), kv_acc):
+            ref[0] = acc[...].astype(ref.dtype)
+
+
+def _tile_bytes(bq: int, bk: int) -> int:
+    """VMEM for a block's (bq, bk) temporaries: s, p, dp, ds in float32 and
+    their casts; what the compiler keeps live of them is not ours to know, so
+    this is the generous count."""
+    return 8 * bq * bk * 4
+
+
+def _fused_bwd_vmem(Sq: int, Sk: int, D: int, item: int, bq: int, bk: int, n_rep: int) -> int:
+    """Bytes of VMEM the fused backward holds at once: what the grid keeps
+    resident (inputs and outputs double-buffered by the pipeline), the
+    float32 scratch, and the block temporaries."""
+    head = 2 * (2 * Sq * D * item + 2 * 8 * Sq * 4 + Sq * D * item) + Sq * D * 4  # q, do, lse, delta, dq out; dq_acc
+    kv_in = 2 * 2 * bk * D * item
+    kv_out = kv_in if n_rep == 1 else 2 * 2 * Sk * D * item + 2 * Sk * D * 4
+    return head + kv_in + kv_out + _tile_bytes(bq, bk)
+
+
+def _rows(x, bq: int):
+    """(BH, Sq) per-row statistics as one (1, bq) row a q block,
+    (BH, Sq//bq, 1, bq): what the kv-major kernels subtract from a (bk, bq)
+    tile along sublanes, at Sq floats a head in VMEM where the lane-broadcast
+    (Sq, LANES) form the query-major kernels read takes 128 times that."""
+    BH, Sq = x.shape
+    return x.reshape(BH, Sq // bq, 1, bq)
 
 
 def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, interpret: bool,
                has_alibi: bool, window: int, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    BKV, Sk, _ = k.shape  # B * KVH (GQA stays collapsed)
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     n_rep = H // KVH
     bq, bk = _blk(Sq, DEFAULT_BQ), _blk(Sk, DEFAULT_BK)
+    item = q.dtype.itemsize
+    statics = dict(bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal, has_alibi=has_alibi,
+                   window=window)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)  # (BH, Sq)
-    delta = jnp.broadcast_to(delta[..., None], (BH, Sq, LANES))
+    lse_rows, delta_rows = _rows(lse, bq), _rows(delta, bq)
+    nq = Sq // bq
 
-    if has_bias:
-        Bb, Hb, Sqb, repeat = bias_meta
-        bias_bh = _bias_bh_fn(bias_meta, H)
-        sqb1 = Sqb == 1
-        n_bh = Bb * Hb
-        collapsed = n_bh < BH or sqb1
-        sq_rows = 1 if sqb1 else bq
-        bias_spec_q3 = pl.BlockSpec((1, sq_rows, Sk),
-                                    lambda bh, i, rep: (bh, 0 if sqb1 else i, 0))
-        bias_spec_q2 = pl.BlockSpec((1, sq_rows, Sk),
-                                    lambda b, i: (bias_bh(b), 0 if sqb1 else i, 0))
-        bias_spec_k = pl.BlockSpec((1, 1 if sqb1 else Sq, bk), lambda b, j: (bias_bh(b), 0, j))
-        dbias_shape = (n_bh, 1 if sqb1 else Sq, Sk)
-    else:
-        collapsed = False
-        bias_spec_q2 = pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0))
-        bias_spec_k = pl.BlockSpec((1, 1, LANES), lambda b, j: (0, 0, 0))
-        dbias_shape = (1, 1, LANES)
+    def q_of(bkv, rep):
+        return (bkv // KVH) * H + (bkv % KVH) * n_rep + rep
+
+    if not has_bias:
+        fused_vmem = _fused_bwd_vmem(Sq, Sk, D, item, bq, bk, n_rep)
+        if fused_vmem > vmem_budget():
+            raise NotImplementedError(
+                f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "
+                f"{n_rep} q heads a KV head take {fused_vmem >> 20} MiB of VMEM, over this device's budget of "
+                f"{vmem_budget() >> 20} MiB: split the sequence over the mesh (sequence or context parallelism)")
+        _count_traced("bwd", "fused")
+        whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
+        rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
+        kv_blk = pl.BlockSpec((1, bk, D), lambda b, r, j: (b, j, 0))
+        if n_rep == 1:
+            kv_out, kv_scratch = kv_blk, []
+        else:
+            kv_out = pl.BlockSpec((1, Sk, D), lambda b, r, j: (b, 0, 0))
+            kv_scratch = [pltpu.VMEM((Sk, D), jnp.float32)] * 2
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, n_rep=n_rep, **statics),
+            grid=(BKV, n_rep, Sk // bk),
+            in_specs=[
+                pl.BlockSpec((1, Sq, D), whole_q),
+                kv_blk,
+                kv_blk,
+                pl.BlockSpec((1, Sq, D), whole_q),
+                pl.BlockSpec((1, nq, 1, bq), rows_q),
+                pl.BlockSpec((1, nq, 1, bq), rows_q),
+                pl.BlockSpec((1, 1, LANES), whole_q),
+            ],
+            out_specs=[pl.BlockSpec((1, Sq, D), whole_q), kv_out, kv_out],
+            out_shape=[
+                jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+                jax.ShapeDtypeStruct((BKV, Sk, D), k.dtype),
+                jax.ShapeDtypeStruct((BKV, Sk, D), v.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)] + kv_scratch,
+            interpret=interpret,
+            compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret,
+                                             vmem_bytes=fused_vmem),
+        )(q, k, v, do, lse_rows, delta_rows, slopes)
+        return dq, dk, dv, jnp.zeros((1, 1, LANES), jnp.float32)
+
+    # a bias: the query-major dq kernels (dbias is written there; they read the lane-broadcast form) and a
+    # dkv kernel a q head
+    _count_traced("bwd", "split")
+    lse, delta = (jnp.broadcast_to(x[..., None], (BH, Sq, LANES)) for x in (lse, delta))
+    Bb, Hb, Sqb, repeat = bias_meta
+    bias_bh = _bias_bh_fn(bias_meta, H)
+    sqb1 = Sqb == 1
+    n_bh = Bb * Hb
+    collapsed = n_bh < BH or sqb1
+    sq_rows = 1 if sqb1 else bq
+    bias_spec_q3 = pl.BlockSpec((1, sq_rows, Sk), lambda bh, i, rep: (bh, 0 if sqb1 else i, 0))
+    bias_spec_q2 = pl.BlockSpec((1, sq_rows, Sk), lambda b, i: (bias_bh(b), 0 if sqb1 else i, 0))
+    # the kv-major dkv kernel adds a row-broadcast bias as a (bk, 1) column
+    bias_k = bias.reshape(n_bh, Sk, 1) if sqb1 else bias
+    bias_spec_k = (pl.BlockSpec((1, bk, 1), lambda b, j: (bias_bh(b), j, 0)) if sqb1
+                   else pl.BlockSpec((1, Sq, bk), lambda b, j: (bias_bh(b), 0, j)))
+    dbias_shape = (n_bh, 1 if sqb1 else Sq, Sk)
+    dq_vmem = (2 * (3 * bq * D * item + 2 * Sk * D * item + 2 * bq * LANES * 4) + _tile_bytes(bq, bk)
+               + 4 * sq_rows * Sk * 4)
+    dkv_vmem = (2 * (2 * Sq * D * item + 2 * 8 * Sq * 4 + 4 * bk * D * 4) + _tile_bytes(bq, bk)
+                + 2 * (LANES if sqb1 else Sq) * bk * 4)
 
     if not collapsed:
         # one dbias block per (b, i) program — plain tiled writes
-        dbias_spec = (pl.BlockSpec((1, bq, Sk), lambda b, i: (b, i, 0)) if has_bias
-                      else pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0)))
         dq, dbias = pl.pallas_call(
-            functools.partial(_dq_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
-                              has_alibi=has_alibi, window=window, has_bias=has_bias),
+            functools.partial(_dq_kernel, **statics),
             grid=(BH, Sq // bq),
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -432,19 +625,19 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-                dbias_spec,
+                pl.BlockSpec((1, bq, Sk), lambda b, i: (b, i, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct(dbias_shape, jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
+            compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=dq_vmem),
         )(q, k, v, do, lse, delta, slopes, bias)
     else:
         # broadcast bias: repeat dim innermost so every program sharing a
         # bias row revisits its dbias block consecutively and accumulates
-        n_rep = BH // n_bh
+        n_share = BH // n_bh
 
         def q_b(bh, rep):
             if Bb == 1 and Hb == 1:
@@ -456,9 +649,8 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             return ((bh // H) * repeat + rep) * H + bh % H
 
         dq, dbias = pl.pallas_call(
-            functools.partial(_dq_kernel_collapsed, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale,
-                              causal=causal, has_alibi=has_alibi, window=window, sqb1=sqb1),
-            grid=(n_bh, Sq // bq, n_rep),
+            functools.partial(_dq_kernel_collapsed, sqb1=sqb1, **statics),
+            grid=(n_bh, Sq // bq, n_share),
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda bh, i, rep: (q_b(bh, rep), i, 0)),
                 pl.BlockSpec((1, Sk, D), lambda bh, i, rep: (q_b(bh, rep), 0, 0)),
@@ -478,70 +670,37 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
                 jax.ShapeDtypeStruct(dbias_shape, jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret),
+            compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret,
+                                             vmem_bytes=dq_vmem),
         )(q, k, v, do, lse, delta, slopes, bias)
 
-    if has_bias:
-        # bias path: KV arrives expanded (flash_attention falls back to
-        # expansion when bias x GQA combine), so the per-q-head grid stands
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
-                              has_alibi=has_alibi, window=window, has_bias=has_bias,
-                              sqb1=bias_meta[2] == 1),
-            grid=(BH, Sk // bk),
-            in_specs=[
-                pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, Sq, LANES), lambda b, j: (b, 0, 0)),
-                pl.BlockSpec((1, 1, LANES), lambda b, j: (b, 0, 0)),
-                bias_spec_k,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
-            ],
-            interpret=interpret,
-            compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
-        )(q, k, v, do, lse, delta, slopes, bias)
-        return dq, dk, dv, dbias
-
-    BKV = k.shape[0]  # B * KVH (collapsed GQA)
-
-    def q_of(bkv, rep):
-        return (bkv // KVH) * H + (bkv % KVH) * n_rep + rep
-
+    # bias path: KV arrives expanded (flash_attention falls back to
+    # expansion when bias x GQA combine), so the per-q-head grid stands
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel_gqa, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
-                          has_alibi=has_alibi, window=window),
-        grid=(BKV, Sk // bk, n_rep),
+        functools.partial(_dkv_kernel, sqb1=sqb1, **statics),
+        grid=(BH, Sk // bk),
         in_specs=[
-            pl.BlockSpec((1, Sq, D), lambda b, j, r: (q_of(b, r), 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, r: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, r: (b, j, 0)),
-            pl.BlockSpec((1, Sq, D), lambda b, j, r: (q_of(b, r), 0, 0)),
-            pl.BlockSpec((1, Sq, LANES), lambda b, j, r: (q_of(b, r), 0, 0)),
-            pl.BlockSpec((1, Sq, LANES), lambda b, j, r: (q_of(b, r), 0, 0)),
-            pl.BlockSpec((1, 1, LANES), lambda b, j, r: (q_of(b, r), 0, 0)),
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, Sq, D), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, nq, 1, bq), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, nq, 1, bq), lambda b, j: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, LANES), lambda b, j: (b, 0, 0)),
+            bias_spec_k,
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, r: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, r: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
         ],
-        out_shape=[  # fp32: cross-group revisit accumulation stays exact
-            jax.ShapeDtypeStruct((BKV, Sk, D), jnp.float32),
-            jax.ShapeDtypeStruct((BKV, Sk, D), jnp.float32),
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
         ],
         interpret=interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret),
-    )(q, k, v, do, lse, delta, slopes)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype), dbias
+        compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=dkv_vmem),
+    )(q, k, v, do, lse_rows, delta_rows, slopes, bias_k)
+    return dq, dk, dv, dbias
 
 
 # ----------------------------------------------------------------------

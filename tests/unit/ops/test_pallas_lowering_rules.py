@@ -124,7 +124,7 @@ def test_flash_attention_specs(record):
 
 def test_flash_attention_gqa_collapsed_specs(record):
     """Round-4 rewrite: GQA keeps KV collapsed at (B, S, KVH, D) through
-    fwd AND bwd (``_dkv_kernel_gqa`` runs a (B*KVH, Sk//bk, n_rep) grid).
+    fwd AND bwd (``_bwd_fused_kernel`` runs a (B*KVH, n_rep, Sk//bk) grid).
     Every collapsed-KV BlockSpec — including the ALiBi slopes table and
     window masking that broke on real Mosaic in round 3 — must satisfy
     the (8, 128) tiling rule at GQA shapes too."""

@@ -4,7 +4,8 @@ No chip is attached: the TPU compiler that ships with jax compiles for a
 topology that is only described, and refuses what the chip's compiler would
 refuse (tiling, VMEM, layouts) — what interpret mode cannot see. Shapes are
 the ones ``chip_smoke.py`` runs on the real chip: GPT-2-124M widths for the
-serving kernels, the llama-7B geometry for the GQA flash kernels.
+serving kernels, the llama-7B geometry for the GQA flash kernels, and the
+benchmark's training cell (OLMo-1B: 16 heads of 128, S=2048, micro-batch 2).
 
 The topology is described inside a fixture, never at import: only one process
 may load libtpu, and every xdist worker imports every test file.
@@ -51,7 +52,9 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(shape):
+def _flash(shape, bwd_path="fused"):
+    """Forward + vjp: 2 kernels, the forward and the fused backward; a head
+    whose q, do and dq do not fit VMEM at once is refused while tracing."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     B, Sq, Hq, KVH, Dh = shape
@@ -62,7 +65,7 @@ def _flash(shape):
 
     q = S((B, Sq, Hq, Dh), BF16)
     kv = S((B, Sq, KVH, Dh), BF16)
-    return fwd_bwd, (q, kv, kv, q), 3  # fwd, dq, dkv kernels
+    return fwd_bwd, (q, kv, kv, q), 2, bwd_path
 
 
 def _fused_adam(shape):
@@ -118,6 +121,9 @@ def _paged_mixed():
 CASES = {
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
     "flash_gqa_b2_s4096_h32_kvh4_d128": lambda: _flash((2, 4096, 32, 4, 128)),
+    "flash_mha_b2_s2048_h16_d128": lambda: _flash((2, 2048, 16, 16, 128)),  # olmo-1b.pretrain-z3, one chip's share
+    "flash_mha_b1_s8192_h16_d128": lambda: _flash((1, 8192, 16, 16, 128)),  # 24 MiB resident: fused, limit raised
+    "flash_mha_b1_s32768_h2_d128": lambda: _flash((1, 32768, 2, 2, 128), "refused"),  # 77 MiB resident: over the budget
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     "fused_adam_mlp_768x3072": lambda: _fused_adam((768, 3072)),
     "fused_adam_bias_768": lambda: _fused_adam((768,)),
@@ -132,7 +138,21 @@ CASES = {
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip):
-    fn, shapes, min_kernels = CASES[case]()
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    fn, shapes, kernels, *bwd_path = CASES[case]()
     args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
+    traced = lambda: {p: get_registry().peek("flash_attention_traced_total", **{"pass": "bwd", "path": p}) or 0.0
+                      for p in ("fused", "split")}
+    before = traced()
+    if bwd_path == ["refused"]:
+        with pytest.raises(NotImplementedError, match="seq_q=32768.*VMEM"):
+            jax.jit(fn).lower(*args)
+        return
     compiled = jax.jit(fn).lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= min_kernels
+    if not bwd_path:
+        assert compiled.as_text().count("tpu_custom_call") >= kernels
+        return
+    # the flash cases name their backward: exactly that many kernels, and the rule picked that path
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, bwd_path[0]: 1.0}

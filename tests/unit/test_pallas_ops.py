@@ -50,8 +50,9 @@ def test_flash_gqa():
 @pytest.mark.parametrize("extra", ["plain", "alibi", "window"])
 def test_flash_gqa_bwd_matches_xla(extra):
     """GQA-native backward: dk/dv accumulate across the q-head group inside
-    the kernel (grid (B*KVH, Sk/bk, n_rep), innermost revisit) and come back
-    collapsed at (B, S, KVH, D) — parity vs XLA's expand-and-reduce."""
+    the kernel (fused: grid (B*KVH, n_rep, Sk/bk), float32 scratch over the
+    group) and come back collapsed at (B, S, KVH, D) — parity vs XLA's
+    expand-and-reduce."""
     from deepspeed_tpu.models.transformer import alibi_slopes
 
     rng = np.random.RandomState(3)
@@ -92,6 +93,74 @@ def test_flash_bwd_matches_xla(causal):
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3, rtol=1e-3)
+
+
+# every static that chooses a backward path or a block's treatment: name -> (shapes, kwargs, traced path)
+_S = dict(B=1, Sq=256, Sk=256, H=2, KVH=2, D=16)
+_BWD_PATH_CASES = {
+    "mha_causal_2_blocks": (dict(_S, Sq=128, Sk=128), {}, "fused"),  # one diagonal and one interior block a row band
+    "mha_causal_4_blocks": (_S, {}, "fused"),
+    "mha_noncausal": (_S, {"causal": False}, "fused"),  # no masked block at all
+    "gqa_4_to_1": (dict(_S, H=4, KVH=1), {}, "fused"),  # dk/dv add up over the group in scratch
+    "gqa_2_to_1_window": (dict(_S, H=4, KVH=2), {"window": 40}, "fused"),
+    "window_smaller_than_block": (_S, {"window": 24}, "fused"),  # the far edge and the diagonal in one block
+    "window_larger_than_block": (_S, {"window": 100}, "fused"),  # far-edge, interior and diagonal blocks
+    "alibi": (dict(_S, H=4, KVH=4), {"alibi": True}, "fused"),
+    "seq_q_lt_seq_k": (dict(_S, Sq=64), {}, "fused"),  # whole kv blocks ahead of the first query
+    "seq_q_lt_seq_k_window": (dict(_S, Sq=128), {"window": 70}, "fused"),  # kv blocks no q block visits
+    "seq_q_gt_seq_k": (dict(_S, Sk=96), {}, "fused"),  # rows before the first key inside a visited block: the guard
+    "bias": (_S, {"bias": (1, 2, 256, 256)}, "split"),  # dbias is written by the dq kernels
+    "bias_row": (_S, {"bias": (1, 1, 1, 256), "causal": False}, "split"),
+    "bias_gqa_2_to_1": (dict(_S, H=4, KVH=2), {"bias": (1, 4, 256, 256)}, "split"),  # bias x GQA: KV arrives expanded
+    "mha_no_room_in_vmem": (_S, {"vmem_budget": 1}, "refused"),  # a head's q/do/dq too large to keep resident
+}
+
+
+@pytest.mark.parametrize("case", list(_BWD_PATH_CASES))
+def test_flash_bwd_paths_match_xla(case, monkeypatch):
+    """dq, dk, dv of every path the backward can take, against attention_xla's
+    vjp, and through ``flash_attention_traced_total`` which path was traced.
+    Blocks of 64 so that interior, diagonal and window-edge blocks all run."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    shape, kw, path = _BWD_PATH_CASES[case]
+    kw = dict(kw)
+    monkeypatch.setattr(fa, "DEFAULT_BQ", 64)
+    monkeypatch.setattr(fa, "DEFAULT_BK", 64)
+    if "vmem_budget" in kw:
+        monkeypatch.setattr(fa, "vmem_budget", lambda budget=kw.pop("vmem_budget"): budget)
+    B, Sq, Sk, H, KVH, D = (shape[n] for n in ("B", "Sq", "Sk", "H", "KVH", "D"))
+    rng = np.random.RandomState(5)
+    mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))
+    q, k, v, w = mk(B, Sq, H, D), mk(B, Sk, KVH, D), mk(B, Sk, KVH, D), mk(B, Sq, H, D)
+    if kw.pop("alibi", False):
+        kw["alibi_slopes"] = jnp.asarray(alibi_slopes(H))
+    if "bias" in kw:
+        kw["bias"] = mk(*kw["bias"])
+    kw.setdefault("causal", True)
+    # a row before the first key sees nothing: flash gives it 0 (and no gradient), plain softmax a uniform
+    # average, so the reference leaves those rows out of the loss; w != 0 there is what the guard answers to
+    seen = jnp.arange(Sq)[None, :, None, None] >= (Sq - Sk if kw["causal"] else 0)
+
+    def grads(attn, rows):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.where(rows, attn(q, k, v, **kw) * w, 0.0)), argnums=(0, 1, 2))(q, k, v)
+
+    counter = lambda p: get_registry().peek("flash_attention_traced_total", **{"pass": "bwd", "path": p}) or 0.0
+    before = {p: counter(p) for p in ("fused", "split")}
+    flash = lambda *a, **kws: flash_attention(*a, interpret=True, **kws)
+    if path == "refused":  # by name, with the shape, and before any kernel is chosen
+        with pytest.raises(NotImplementedError, match=f"seq_q={Sq}, seq_k={Sk}, D={D}.*VMEM"):
+            grads(flash, True)
+        assert {p: counter(p) - before[p] for p in before} == {"fused": 0.0, "split": 0.0}
+        return
+    got = grads(flash, True)
+    assert {p: counter(p) - before[p] for p in before} == {"fused": 0.0, "split": 0.0, path: 1.0}
+    want = grads(attention_xla, seen)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3, rtol=1e-3, err_msg=name)
 
 
 def test_fused_adam_matches_reference():

@@ -778,7 +778,7 @@ def test_watchdog_timeout_raises_structured_alert():
 _METRIC_PREFIXES = ("train_", "comm_", "infer_", "kv_", "sched_", "spec_",
                     "compile_cache_", "watchdog_", "telemetry_", "health_",
                     "journal_", "replay_", "autotune_", "program_",
-                    "paged_attention_")
+                    "paged_attention_", "flash_attention_")
 # profile_* metrics are listed explicitly: a bare "profile_" prefix would
 # also match the `profile_captures` knob-default directory name in docs
 _EXTRA_METRICS = {"last_step_completed_unix", "tp_degree",
