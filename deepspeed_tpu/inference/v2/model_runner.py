@@ -40,14 +40,10 @@ from ...ops.pallas.paged_attention import (kv_layer, kv_set_layer, paged_attenti
                                            paged_attention_mixed, paged_attention_prefill,
                                            update_kv_pages)
 from ...ops.registry import REGISTRY
+from ...utils.compile_cache import count_block_trace
 from .modules import _norm_p, _proj, build_modules
 
 _SHARD_MAP_KW = {"check_vma": False}
-
-
-def _is_moe_layer(cfg: TransformerConfig, i: int) -> bool:
-    freq = max(1, cfg.moe_layer_freq)
-    return cfg.moe_num_experts > 0 and (i % freq == freq - 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,13 +75,14 @@ class TPContext:
         return f"tp{self.tp}:{self.axis}:b{self.bits}:il{self.interleave}:mesh[{axes}]"
 
 
-def _attn_fn_builder(cfg: TransformerConfig, interpret: bool, mesh, tp: int, slopes=None):
-    """window -> (decode_attn, prefill_attn, native) — shared by the ragged
-    and fused forwards so both hot paths bake identical kernel variants.
+def _attn_fns(cfg: TransformerConfig, interpret: bool, mesh, tp: int, window, slopes=None):
+    """(decode_attn, prefill_attn, native) for one window value — shared by
+    the ragged and fused forwards so both hot paths bake identical kernel
+    variants (gpt-neo alternates global/local; qwen2 windows a layer
+    suffix: each value is its own kind of layer and bakes its own variant).
     ``slopes`` overrides the baked ALiBi slopes (the manual-TP stack bakes
     each shard's dynamic slice; tracer-valued slopes are legal in the
     kernels)."""
-    H = cfg.n_heads
     if mesh is not None and tp > 1:
         # heads split over `tensor`: each shard decodes its own heads
         # against its KV-page shard (ref v2 sharding helpers). Per-shard
@@ -96,29 +93,17 @@ def _attn_fn_builder(cfg: TransformerConfig, interpret: bool, mesh, tp: int, slo
             mesh=mesh, in_specs=(P(None, "tensor", None), P(None, None, "tensor", None),
                                  P(None, None, "tensor", None), P(None, None), P(None)),
             out_specs=P(None, "tensor", None), **_SHARD_MAP_KW)
-        return lambda window: (tp_decode_attn, None, False)
-    # one (decode, prefill) pair per distinct per-layer window value
-    # (gpt-neo alternates global/local; qwen2 windows a layer suffix) —
-    # the layer loop is unrolled, so windows are static per layer and
-    # each value bakes its own kernel variant
-    _slopes = slopes if slopes is not None else (
-        alibi_slopes(H) if cfg.pos_emb == "alibi" else None)
-    _fns = {}
-
-    def attn_fns(window):
-        if window not in _fns:
-            decode = functools.partial(paged_attention_decode, interpret=interpret, scale=cfg.attn_scale,
-                                       alibi_slopes=_slopes, window=window)
-            # interpret mode (CPU dev serving) keeps the compute-bound
-            # prefill on the fused XLA gather path — emulating the
-            # page-walk kernel there is strictly slower; on real TPU the
-            # kernel avoids the context gather
-            prefill = None if interpret else functools.partial(
-                paged_attention_prefill, scale=cfg.attn_scale, alibi_slopes=_slopes, window=window)
-            _fns[window] = (decode, prefill, True)
-        return _fns[window]
-
-    return attn_fns
+        return tp_decode_attn, None, False
+    if slopes is None and cfg.pos_emb == "alibi":
+        slopes = alibi_slopes(cfg.n_heads)
+    decode = functools.partial(paged_attention_decode, interpret=interpret, scale=cfg.attn_scale,
+                               alibi_slopes=slopes, window=window)
+    # interpret mode (CPU dev serving) keeps the compute-bound prefill on
+    # the fused XLA gather path — emulating the page-walk kernel there is
+    # strictly slower; on real TPU the kernel avoids the context gather
+    prefill = None if interpret else functools.partial(
+        paged_attention_prefill, scale=cfg.attn_scale, alibi_slopes=slopes, window=window)
+    return decode, prefill, True
 
 
 def _row_parallel(p: Dict, tp_reduce):
@@ -213,6 +198,49 @@ def _stack_body(cfg: TransformerConfig, interpret: bool, *, mixed: bool, decode:
     keeps that path: ``custom_partitioning`` matmuls cannot run inside a
     manual shard_map region)."""
     mods = build_modules()
+    tp_reduce = None
+    if tp_local is not None:
+        axis, tp_n, bits, interleave = tp_local
+        tp_reduce = functools.partial(tp_all_reduce, group=axis, bits=bits, interleave=interleave)
+
+    @functools.cache
+    def layer_fn(window, moe: bool):
+        """One KIND of layer (its window, dense or MoE) as one traced function,
+        called once a layer with that layer's parameters and pages: ``jax.jit``
+        keys its trace on the abstract arguments, so the Python below runs once
+        a kind and program, the program holds one ``jit`` equation a layer on
+        one shared jaxpr, lowering emits one function, and XLA inlines the
+        calls. Everything a layer reads of the step comes in as an argument;
+        only statics are closed over. Under the legacy GSPMD arguments XLA
+        would partition a computation with several call sites once, as a
+        function, without its callers in view (``models/transformer.py``
+        ``block_fn``): there the cached equations are replayed into the
+        program instead (``inline``), which is then the unrolled one."""
+
+        def layer(lp, x, k_pages_i, v_pages_i, block_tables, ctx_lens, slot_mapping, positions, cos, sin, slopes):
+            count_block_trace("serve")  # the Python body: once a trace, not once a call
+            # shard-local kernels bake the shard's slice of the slopes, a traced value; the others the whole table
+            decode_attn, prefill_attn, decode_native = _attn_fns(
+                cfg, interpret, mesh, tp, window, slopes if tp_local is not None else None)
+
+            if mixed:
+                def attn_apply(q, kp, vp):
+                    out = paged_attention_mixed(q[0], kp, vp, block_tables, ctx_lens, positions[0],
+                                                n_dec=n_dec, chunk=chunk, scale=cfg.attn_scale,
+                                                alibi_slopes=slopes, window=window, decode_fn=decode_attn,
+                                                prefill_fn=prefill_attn, native=decode_native)
+                    return out[None]  # (1, T, H, D)
+            else:
+                def attn_apply(q, kp, vp):
+                    return mods.attention(cfg, q, kp, vp, block_tables, ctx_lens, positions,
+                                          decode=decode, slopes=slopes, decode_attn=decode_attn,
+                                          decode_native=decode_native, prefill_attn=prefill_attn,
+                                          window=window)
+
+            return _transformer_layer(cfg, lp, x, k_pages_i, v_pages_i, slot_mapping, cos, sin, positions,
+                                      attn_apply, mods, moe, tp_reduce=tp_reduce)
+
+        return jax.jit(layer, inline=mesh is not None and tp > 1)
 
     def body(layer_params, x, k_pages, v_pages, block_tables, ctx_lens, slot_mapping, positions):
         cos = sin = None
@@ -221,41 +249,14 @@ def _stack_body(cfg: TransformerConfig, interpret: bool, *, mixed: bool, decode:
         # slopes feed the gather-based attention used for prefill and for
         # the GSPMD-sharded decode; the native decode kernels bake them
         slopes = jnp.asarray(alibi_slopes(cfg.n_heads)) if cfg.pos_emb == "alibi" else None
-        tp_reduce = None
-        if tp_local is not None:
-            axis, tp_n, bits, interleave = tp_local
-            if slopes is not None:
-                hs = cfg.n_heads // tp_n
-                slopes = jax.lax.dynamic_slice(slopes.astype(jnp.float32),
-                                               (jax.lax.axis_index(axis) * hs,), (hs,))
-            tp_reduce = functools.partial(tp_all_reduce, group=axis, bits=bits,
-                                          interleave=interleave)
-            attn_fns = _attn_fn_builder(cfg, interpret, None, 1, slopes=slopes)
-        else:
-            attn_fns = _attn_fn_builder(cfg, interpret, mesh, tp)
-        flat_pos = positions[0] if mixed else None
-
+        if tp_local is not None and slopes is not None:
+            hs = cfg.n_heads // tp_n
+            slopes = jax.lax.dynamic_slice(slopes.astype(jnp.float32),
+                                           (jax.lax.axis_index(axis) * hs,), (hs,))
         for i in range(cfg.n_layers):
-            lp = layer_params[f"layer_{i}"]
-            w_i = cfg.window_for(i)
-            decode_attn, prefill_attn, decode_native = attn_fns(w_i)
-
-            if mixed:
-                def attn_apply(q, kp, vp, *, _w=w_i, _da=decode_attn, _pa=prefill_attn, _dn=decode_native):
-                    out = paged_attention_mixed(q[0], kp, vp, block_tables, ctx_lens, flat_pos,
-                                                n_dec=n_dec, chunk=chunk, scale=cfg.attn_scale,
-                                                alibi_slopes=slopes, window=_w,
-                                                decode_fn=_da, prefill_fn=_pa, native=_dn)
-                    return out[None]  # (1, T, H, D)
-            else:
-                def attn_apply(q, kp, vp, *, _w=w_i, _da=decode_attn, _pa=prefill_attn, _dn=decode_native):
-                    return mods.attention(cfg, q, kp, vp, block_tables, ctx_lens, positions,
-                                          decode=decode, slopes=slopes, decode_attn=_da,
-                                          decode_native=_dn, prefill_attn=_pa, window=_w)
-
-            x, kp, vp = _transformer_layer(cfg, lp, x, kv_layer(k_pages, i), kv_layer(v_pages, i),
-                                           slot_mapping, cos, sin, positions, attn_apply, mods,
-                                           _is_moe_layer(cfg, i), tp_reduce=tp_reduce)
+            x, kp, vp = layer_fn(cfg.window_for(i), cfg.moe_for(i))(
+                layer_params[f"layer_{i}"], x, kv_layer(k_pages, i), kv_layer(v_pages, i), block_tables,
+                ctx_lens, slot_mapping, positions, cos, sin, slopes)
             k_pages = kv_set_layer(k_pages, i, kp)
             v_pages = kv_set_layer(v_pages, i, vp)
         return x, k_pages, v_pages

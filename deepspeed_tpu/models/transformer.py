@@ -12,7 +12,7 @@ PartitionSpec) rather than module surgery: the AutoTP analogue
 (reference ``module_inject/auto_tp.py``) consumes these rules.
 """
 
-import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -23,6 +23,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
+from ..utils.compile_cache import count_block_trace
 
 
 @dataclass(frozen=True)
@@ -130,6 +131,11 @@ class TransformerConfig:
         if self.window_layers is None:
             return self.sliding_window
         return self.sliding_window if layer_idx in self.window_layers else None
+
+    def moe_for(self, layer_idx: int) -> bool:
+        """Whether one layer's MLP slot is a MoE layer (every ``moe_layer_freq``-th block)."""
+        freq = max(1, self.moe_layer_freq)
+        return self.moe_num_experts > 0 and layer_idx % freq == freq - 1
 
     @property
     def uniform_window(self) -> bool:
@@ -308,7 +314,7 @@ def alibi_slopes(n_heads: int) -> np.ndarray:
 
 class Attention(nn.Module):
     cfg: TransformerConfig
-    layer_idx: int = 0
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, segment_ids=None):
@@ -346,7 +352,7 @@ class Attention(nn.Module):
 
         slopes = jnp.asarray(alibi_slopes(H)) if cfg.pos_emb == "alibi" else None
         out = attention(q, k, v, causal=cfg.causal, segment_ids=segment_ids, kv_len=kv_len,
-                        alibi_slopes=slopes, window=cfg.window_for(self.layer_idx), scale=cfg.attn_scale)
+                        alibi_slopes=slopes, window=self.window, scale=cfg.attn_scale)
         out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=cfg.use_attn_out_bias, name="o_proj",
                               dtype=cfg.dtype, param_dtype=jnp.float32)(out)
         return (out, new_cache) if kv_cache is not None else out
@@ -373,18 +379,18 @@ class MLP(nn.Module):
 
 
 class Block(nn.Module):
+    """One transformer block. Its fields are all a trace of it can depend on:
+    a layer's place in the stack enters only through ``window``
+    (``cfg.window_for(i)``) and ``moe`` (``cfg.moe_for(i)``), so layers of one
+    kind share one traced function (``block_fn``)."""
+
     cfg: TransformerConfig
-    layer_idx: int = 0
+    window: Optional[int] = None
+    moe: bool = False
     is_training: bool = True  # static: MoE capacity-drop is train-only
 
-    @property
-    def is_moe(self) -> bool:
-        cfg = self.cfg
-        return cfg.moe_num_experts > 0 and (self.layer_idx % max(1, cfg.moe_layer_freq)
-                                            == max(1, cfg.moe_layer_freq) - 1)
-
     def _mlp(self, cfg, h):
-        if self.is_moe:
+        if self.moe:
             from ..moe.layer import MoE
 
             return MoE(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k,
@@ -396,7 +402,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, segment_ids=None):
         cfg = self.cfg
-        attn = Attention(cfg, layer_idx=self.layer_idx, name="attn")
+        attn = Attention(cfg, window=self.window, name="attn")
 
         def run_attn(h):
             if kv_cache is not None:
@@ -456,26 +462,35 @@ class Transformer(nn.Module):
             x = make_norm(cfg)(x)
 
         new_caches = [] if kv_caches is not None else None
-        block_cls = Block
-        if cfg.remat and kv_caches is None:
-            block_cls = nn.remat(Block, static_argnums=())
+        remat = cfg.remat and kv_caches is None
         if cfg.scan_layers and kv_caches is None:
-            x = self._scan_blocks(block_cls, x, positions, segment_ids, train)
+            x = self._scan_blocks(nn.remat(Block, static_argnums=()) if remat else Block, x, positions,
+                                  segment_ids, train)
         else:
+            # one traced function a KIND of block and program, applied once a layer to that layer's
+            # parameters: the block's Python body runs once, not n_layers times
+            kinds = functools.cache(functools.partial(block_fn, cfg, train=train, remat=remat))
             for i in range(cfg.n_layers):
-                blk = block_cls(cfg, layer_idx=i, is_training=train, name=f"layer_{i}")
-                if kv_caches is not None:
-                    x, c = blk(x, positions, kv_caches[i], segment_ids)
-                    new_caches.append(c)
+                kind = (cfg.window_for(i), cfg.moe_for(i))
+                kv_cache = kv_caches[i] if kv_caches is not None else None
+                if self.is_initializing():  # makes the tree: flax has to see every layer as a submodule
+                    y = Block(cfg, *kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids)
+                    y, cache = y if kv_caches is not None else (y, None)
                 else:
-                    y = blk(x, positions, None, segment_ids)
-                    if pld_theta is not None and train:
-                        # progressive layer drop (arXiv:2010.13369): deeper
-                        # layers drop more; keep prob 1-(1-theta)*l/L
-                        pkeep = 1.0 - (1.0 - pld_theta) * (i + 1) / cfg.n_layers
-                        keep = jax.random.bernoulli(self.make_rng("pld"), pkeep)
-                        y = jnp.where(keep, y, x)
-                    x = y
+                    (y, cache), sown = kinds(*kind)(self.get_variable("params", f"layer_{i}"), x, positions,
+                                                    kv_cache, segment_ids)
+                    for col, tree in sown.items():  # what the block sowed (MoE auxiliary loss), where it was
+                        if self.is_mutable_collection(col):
+                            self.put_variable(col, f"layer_{i}", tree)
+                if kv_caches is not None:
+                    new_caches.append(cache)
+                elif pld_theta is not None and train:
+                    # progressive layer drop (arXiv:2010.13369): deeper
+                    # layers drop more; keep prob 1-(1-theta)*l/L
+                    pkeep = 1.0 - (1.0 - pld_theta) * (i + 1) / cfg.n_layers
+                    keep = jax.random.bernoulli(self.make_rng("pld"), pkeep)
+                    y = jnp.where(keep, y, x)
+                x = y
 
         if cfg.norm_scheme != "post":  # post-LN blocks already end normalized
             x = make_norm(cfg)(x)
@@ -515,13 +530,50 @@ class Transformer(nn.Module):
 
             @nn.compact
             def __call__(self, carry, _):
-                y = block_cls(self.cfg, is_training=train, name="block")(carry, positions, None, segment_ids)
+                y = block_cls(self.cfg, self.cfg.window_for(0), self.cfg.moe_for(0), is_training=train,
+                              name="block")(carry, positions, None, segment_ids)
                 return y, None
 
         scanned = nn.scan(ScanBody, variable_axes={"params": 0}, split_rngs={"params": True}, length=cfg.n_layers,
                           metadata_params={nn.PARTITION_NAME: "layers"})
         x, _ = scanned(cfg, name="layers")(x, None)
         return x
+
+
+_SOWN = ("losses", "intermediates")  # collections a block may write (``moe/layer.py``)
+
+
+def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bool, remat: bool):
+    """One kind of block as ONE traced function of (the layer's parameters,
+    activations, positions, its KV cache, segment ids) ->
+    ((activations, new cache), what the block sowed).
+
+    ``jax.jit`` keys its trace on the abstract arguments, so every layer of
+    the kind after the first reuses the jaxpr: the block's Python body (flax
+    and all) runs once a kind, and each further layer replays the cached
+    equations into the program (``inline=True``) with its constants shared.
+    Why replayed and not called: XLA's TPU pipeline inlines a computation
+    that has ONE call site before it partitions and keeps one with several,
+    then partitions that computation once, as a function, without its callers
+    in view. On four chips with ZeRO-3 that chose other collectives for the
+    sixteen-fold block and cost 9% of the step (``PERF.md``, PR 27). Inlined
+    at trace time the program is, equation for equation, the unrolled one.
+    The v2 runner, whose programs are not partitioned by XLA, calls its layer
+    (``inference/v2/model_runner.py`` ``_stack_body``).
+
+    Built once a program (a call of ``Transformer.__call__``), never kept:
+    what a trace reads of the process (the op registry, the mesh topology) is
+    read as often as before. A block draws nothing today (no dropout, no
+    router jitter); what it may draw later comes in as a key argument, as
+    what it sows goes out."""
+    block = Block(cfg, window, moe, is_training=train)
+
+    def apply(params, x, positions, kv_cache, segment_ids):
+        count_block_trace("train")  # the Python body: once a trace, not once a call
+        out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, mutable=_SOWN)
+        return (out if kv_cache is not None else (out, None)), sown
+
+    return jax.jit(jax.checkpoint(apply) if remat else apply, inline=True)
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarray:
@@ -568,7 +620,7 @@ class CausalLM:
             extra["rngs"] = {"pld": rng}
         if self.cfg.moe_num_experts > 0:
             hidden, mods = self.module.apply({"params": params}, input_ids, return_hidden=True,
-                                             mutable=["losses", "intermediates"], **extra)
+                                             mutable=_SOWN, **extra)
             aux_leaves = jax.tree_util.tree_leaves(mods.get("losses", {}))
             aux = sum(jnp.sum(l) for l in aux_leaves) if aux_leaves else 0.0
         else:
@@ -669,13 +721,10 @@ class CausalLM:
                        if not (k.startswith("layer_") or k in ("wte", "wpe") or k == embed_norm_key)}
         pipe_params = {"embed": embed_params, "stages": stages, "head": head_params}
 
-        # one block program per sub-layer: layer_idx=j reproduces the global
-        # MoE slot pattern (given the divisibility check above), and the
-        # stage-uniform window rides in via a per-sub-layer cfg
-        blocks = []
-        for j in range(layers_per_stage):
-            cfg_j = dataclasses.replace(cfg, sliding_window=window_per_sub[j], window_layers=None)
-            blocks.append(Block(cfg_j, layer_idx=j))
+        # one block program per sub-layer: sub-layer j reproduces the global
+        # MoE slot pattern (given the divisibility check above) and carries
+        # the stage-uniform window
+        blocks = [Block(cfg, window_per_sub[j], cfg.moe_for(j)) for j in range(layers_per_stage)]
         has_moe = cfg.moe_num_experts > 0
         norm_key = [k for k in head_params if "Norm" in k]
         paramless_norm = cfg.norm == "layernorm_np"
@@ -701,9 +750,8 @@ class CausalLM:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
             aux = jnp.zeros((), jnp.float32)
             for j in range(layers_per_stage):
-                if has_moe and blocks[j].is_moe:
-                    x, mods = blocks[j].apply({"params": sp[f"sub_{j}"]}, x, positions,
-                                              mutable=["losses", "intermediates"])
+                if blocks[j].moe:
+                    x, mods = blocks[j].apply({"params": sp[f"sub_{j}"]}, x, positions, mutable=_SOWN)
                     leaves = jax.tree_util.tree_leaves(mods.get("losses", {}))
                     aux = aux + sum(jnp.sum(l).astype(jnp.float32) for l in leaves)
                 else:

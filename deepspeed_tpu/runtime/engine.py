@@ -620,16 +620,6 @@ class DeepSpeedEngine:
             self._last_microbatch_tokens = _batch_tokens(batch)
             batch = self._put_batch(batch)
             scale = self.loss_scaler.loss_scale / self.gradient_accumulation_steps
-            if (self._step_flops_tokens != self._last_microbatch_tokens
-                    and knobs.get_int("DS_TPU_PERF_ACCOUNT")):
-                self._step_flops_tokens = self._last_microbatch_tokens
-                try:
-                    from ..profiling.flops_profiler import flops_of_fn
-                    self._step_flops, _ = flops_of_fn(
-                        lambda p, b, st, s: self._fwd_bwd(p, b, st, s),
-                        self.params, batch, self.micro_steps, scale)
-                except Exception:
-                    self._step_flops = 0  # MFU gauge stays dark; never block training
             profiling = (self.config.flops_profiler.enabled
                          and self.global_steps == self.config.flops_profiler.profile_step
                          and (self.micro_steps - self._accum_base) % self.gradient_accumulation_steps == 0)  # first micro-batch only
@@ -639,12 +629,15 @@ class DeepSpeedEngine:
                     and not profiling and getattr(self, "_training", True)):
                 lr = self._next_lr()
                 inv_scale = 1.0 / self.loss_scaler.loss_scale
-                loss, self.params, self.opt_state, gnorm, overflow = self._fused_step(
-                    self.params, self.opt_state, batch, self.micro_steps, scale, inv_scale, lr)
+                args = (self.params, self.opt_state, batch, self.micro_steps, scale, inv_scale, lr)
+                self._count_step_flops(self._fused_step, args)
+                loss, self.params, self.opt_state, gnorm, overflow = self._fused_step(*args)
                 self._fused_pending = (gnorm, overflow, lr)
                 self._cached_grads = _FUSED
             else:
-                loss, grads = self._fwd_bwd(self.params, batch, self.micro_steps, scale)
+                args = (self.params, batch, self.micro_steps, scale)
+                self._count_step_flops(self._fwd_bwd, args)
+                loss, grads = self._fwd_bwd(*args)
                 self._cached_grads = grads
             self._last_loss = loss
             if self.eigenvalue is not None:
@@ -655,6 +648,22 @@ class DeepSpeedEngine:
         return loss
 
     __call__ = forward
+
+    def _count_step_flops(self, program, args):
+        """FLOPs of a micro-batch for the MFU gauge, walked off the jaxpr of
+        the program that is about to run, on its own arguments: the trace is
+        the one ``jax.jit`` keeps for the call that follows, so the model is
+        traced once a shape, not once for the gauge and once for the step.
+        The fused step's count includes the optimizer's elementwise update
+        (a few operations a parameter against 6 x tokens)."""
+        if self._step_flops_tokens == self._last_microbatch_tokens or not knobs.get_int("DS_TPU_PERF_ACCOUNT"):
+            return
+        self._step_flops_tokens = self._last_microbatch_tokens
+        try:
+            from ..profiling.flops_profiler import flops_of_fn
+            self._step_flops, _ = flops_of_fn(program, *args)
+        except Exception:
+            self._step_flops = 0  # MFU gauge stays dark; never block training
 
     def backward(self, loss=None, retain_graph=False):
         """Accumulate the gradients computed by the paired ``forward``."""

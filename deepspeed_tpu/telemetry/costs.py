@@ -102,10 +102,12 @@ def first_call(family: str, bucket: Any):
     before ``dump_trace`` leaves behind, and what an operator greps for when
     a step recompiles in production. Yields a dict for phases of the
     caller's own (``cost_card``). ``programs`` counts what reached the
-    backend inside the span."""
+    backend inside the span, ``block_traces`` the times a transformer
+    block's Python body ran (``program_block_traces_total``: one a kind of
+    block and traced program, not one a layer)."""
     import jax
 
-    from ..utils.compile_cache import PHASE_COUNTERS, PHASES, register_cache_metrics
+    from ..utils.compile_cache import PHASE_COUNTERS, PHASES, block_traces, register_cache_metrics
 
     register_cache_metrics(jax)
     reg = get_registry()
@@ -113,6 +115,7 @@ def first_call(family: str, bucket: Any):
     inherited = {k: up.attrs[k] for k in ("q", "steps") if k in up.attrs} if up is not None and up.attrs else {}
     counters = PHASE_COUNTERS + ("program_first_calls_total",)
     before = [reg.peek(c) or 0.0 for c in counters]
+    traces_before = block_traces()
     phases: Dict[str, float] = {}
     t0 = time.perf_counter()
     with span("program/first_call", family=family, bucket=bucket, **inherited) as sp:
@@ -123,11 +126,13 @@ def first_call(family: str, bucket: Any):
         # JAX's compile event spans the persistent-cache fetch: "other" is what no phase covers
         phases["other"] = total - sum(v for k, v in phases.items() if k != "cache_fetch")
         # programs: how many reached the backend in here (this one, and helper programs it called first)
-        sp.set(programs=int(programs), total_s=total, **{k + "_s": v for k, v in phases.items()})
+        traced = block_traces() - traces_before
+        sp.set(programs=int(programs), block_traces=traced, total_s=total,
+               **{k + "_s": v for k, v in phases.items()})
     if sp.attrs is not None:  # the tracer is on
         logger.info("program first call: family=%s bucket=%s %s", family, bucket, " ".join(
             [f"{k}={v}" for k, v in inherited.items()] + [f"total_s={total:.3f}"]
-            + [f"{k}_s={v:.3f}" for k, v in phases.items()]))
+            + [f"{k}_s={v:.3f}" for k, v in phases.items()] + [f"block_traces={traced}"]))
 
 
 def _aval_bytes(avals: Iterable[Any]) -> int:
