@@ -12,6 +12,8 @@ PartitionSpec) rather than module surgery: the AutoTP analogue
 (reference ``module_inject/auto_tp.py``) consumes these rules.
 """
 
+import contextlib
+import contextvars
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -470,6 +472,9 @@ class Transformer(nn.Module):
             # one traced function a KIND of block and program, applied once a layer to that layer's
             # parameters: the block's Python body runs once, not n_layers times
             kinds = functools.cache(functools.partial(block_fn, cfg, train=train, remat=remat))
+            hook = _BLOCK_HOOK.get() if kv_caches is None else None
+            layers = [] if self.is_initializing() else [self.get_variable("params", f"layer_{i}")
+                                                        for i in range(cfg.n_layers)]
             for i in range(cfg.n_layers):
                 kind = (cfg.window_for(i), cfg.moe_for(i))
                 kv_cache = kv_caches[i] if kv_caches is not None else None
@@ -477,8 +482,10 @@ class Transformer(nn.Module):
                     y = Block(cfg, *kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids)
                     y, cache = y if kv_caches is not None else (y, None)
                 else:
-                    (y, cache), sown = kinds(*kind)(self.get_variable("params", f"layer_{i}"), x, positions,
-                                                    kv_cache, segment_ids)
+                    wrap = None
+                    if hook is not None:
+                        wrap, x = hook(self.path + (f"layer_{i}",), layers, i, kind[1], x)
+                    (y, cache), sown = kinds(*kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids)
                     for col, tree in sown.items():  # what the block sowed (MoE auxiliary loss), where it was
                         if self.is_mutable_collection(col):
                             self.put_variable(col, f"layer_{i}", tree)
@@ -542,8 +549,29 @@ class Transformer(nn.Module):
 
 _SOWN = ("losses", "intermediates")  # collections a block may write (``moe/layer.py``)
 
+_BLOCK_HOOK: contextvars.ContextVar = contextvars.ContextVar("transformer_block_hook", default=None)
 
-def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bool, remat: bool):
+
+@contextlib.contextmanager
+def block_hook(hook):
+    """While a ``Transformer`` is traced inside, its loop over layers asks
+    ``hook(path, layers, i, sows, x)`` before each block it applies without
+    a KV cache: the block's path in the parameter tree, every layer's
+    parameters (a list: the hook may replace those of layers still to come),
+    which layer this is, whether it may sow, and the activations it is about
+    to take. The answer is ``(wrap, x)``: the activations to feed it, and
+    ``block_fn``'s ``wrap`` for it or None. Layers that are to share one
+    trace get the same ``wrap`` object. This is how a trainer runs a block
+    some other way than XLA's partitioner would without the model knowing
+    how (``runtime/zero/overlap.py``)."""
+    token = _BLOCK_HOOK.set(hook)
+    try:
+        yield
+    finally:
+        _BLOCK_HOOK.reset(token)
+
+
+def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bool, remat: bool, wrap=None):
     """One kind of block as ONE traced function of (the layer's parameters,
     activations, positions, its KV cache, segment ids) ->
     ((activations, new cache), what the block sowed).
@@ -565,7 +593,9 @@ def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bo
     what a trace reads of the process (the op registry, the mesh topology) is
     read as often as before. A block draws nothing today (no dropout, no
     router jitter); what it may draw later comes in as a key argument, as
-    what it sows goes out."""
+    what it sows goes out. ``wrap`` takes the function and returns one of
+    the same signature that runs the block some other way (``block_hook``);
+    it is traced once a kind all the same."""
     block = Block(cfg, window, moe, is_training=train)
 
     def apply(params, x, positions, kv_cache, segment_ids):
@@ -573,7 +603,8 @@ def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bo
         out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, mutable=_SOWN)
         return (out if kv_cache is not None else (out, None)), sown
 
-    return jax.jit(jax.checkpoint(apply) if remat else apply, inline=True)
+    fn = wrap(apply) if wrap is not None else apply
+    return jax.jit(jax.checkpoint(fn) if remat else fn, inline=True)
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarray:

@@ -87,6 +87,11 @@ def _count_eqns(jaxpr) -> Tuple[float, float]:
             length = int(params.get("length", 1))
             flops += inner_f * length
             macs += inner_m * length
+        elif name == "shard_map":  # the body is ONE device's program, at its shapes: every device of the manual axes runs it
+            inner_f, inner_m = _count_eqns(_as_jaxpr(params["jaxpr"]))
+            devices = int(np.prod([params["mesh"].shape[a] for a in params["manual_axes"]]))
+            flops += inner_f * devices
+            macs += inner_m * devices
         elif name in ("while",):
             body_f, body_m = _count_eqns(_as_jaxpr(params["body_jaxpr"]))
             flops += body_f  # trip count unknowable statically; count one iteration

@@ -77,6 +77,19 @@ class ZeroConfig(DeepSpeedConfigModel):
     than tensor surgery (SURVEY.md §7): stage 1/2 shard optimizer state
     (and reduce-scatter grads) over the data axis; stage 3 additionally
     shards parameters over the ``fsdp`` axis with allgather-on-use.
+
+    ``overlap_comm`` (default: true at stage 3, false below) is read at
+    stage 3 on a TPU mesh whose one axis wider than a device is ZeRO's:
+    true makes a transformer block gather its own parameters and reduce a
+    layer's weight gradients by a ring under the next layer's backward, a
+    layer being the bucket (``runtime/zero/overlap.py``); false, any other
+    backend and any other mesh leave the reduction to XLA's partitioner.
+    Such a block keeps its gathered 16-bit weights from its forward to its
+    backward, so ``stage3_max_live_parameters`` is read there too: blocks
+    gather for themselves, first layer first, while the parameters they
+    hold stay under it (the default 1e9: 2 GB a device in bf16), and the
+    layers after that are the partitioner's; 0 is ``overlap_comm: false``.
+    ``reduce_bucket_size`` is parsed and not read.
     """
     stage: int = ds_field(0, ge=0, le=3)
     contiguous_gradients: bool = True

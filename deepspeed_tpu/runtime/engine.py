@@ -33,10 +33,12 @@ import optax
 from .. import comm as dist
 from ..accelerator import get_accelerator
 from ..analysis import knobs
+from ..analysis.jit_audit import leaf_signature
 from ..parallel.mesh import MeshTopology, get_mesh_topology, initialize_mesh
 from ..telemetry import MonitorBridge
 from ..telemetry import get_registry as get_telemetry_registry
 from ..telemetry import span as telemetry_span
+from ..telemetry.costs import first_call
 from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
                                 get_health_monitor)
 from ..utils.compile_cache import register_cache_metrics
@@ -49,6 +51,7 @@ from .dataloader import DeepSpeedDataLoader
 from .fp16.loss_scaler import create_loss_scaler
 from .lr_schedules import create_lr_scheduler
 from .optimizers import create_optimizer
+from .zero import overlap as zero_overlap
 from .zero.partition import (batch_specs, plan_grad_specs, plan_opt_state_specs, plan_param_specs, specs_to_shardings)
 
 MODEL_STATES_FILENAME = "model_states.msgpack"
@@ -278,6 +281,7 @@ class DeepSpeedEngine:
         # cost cards use; 0 means unavailable/disabled and the gauge stays 0
         self._step_flops = 0
         self._step_flops_tokens = -1
+        self._step_programs_seen = set()  # (program, batch shapes) that have had their first call
         self._peak_flops: Optional[float] = None
         self._monitor_bridge = MonitorBridge(
             tele, self.monitor,
@@ -399,11 +403,17 @@ class DeepSpeedEngine:
             # fetched device copy, so they land in device memory)
             return fetch_params(params32, store_shardings) if jit_stream else params32
 
+        # overlap_comm: how the step's backward reduces weight gradients (zero/overlap.py); None: as XLA partitions it
+        gather_plan = None
+        if comp is None and not self._param_offload:
+            gather_plan = zero_overlap.plan_for(self.config, self.topology, self.param_specs)
+
         def scaled_loss_fn(params32, batch, rng, scale, comp_state):
             params_c = _cast_tree(params32, compute_dtype)
             if comp is not None:
                 params_c = comp.apply(params_c, comp_state)
-            loss = loss_fn(params_c, batch, rng)
+            with zero_overlap.active(gather_plan):  # read by the model while its loss is traced
+                loss = loss_fn(params_c, batch, rng)
             return (loss * scale).astype(jnp.float32), loss
 
         def fwd_bwd(params32, batch, step, scale, comp_state):
@@ -630,14 +640,13 @@ class DeepSpeedEngine:
                 lr = self._next_lr()
                 inv_scale = 1.0 / self.loss_scaler.loss_scale
                 args = (self.params, self.opt_state, batch, self.micro_steps, scale, inv_scale, lr)
-                self._count_step_flops(self._fused_step, args)
-                loss, self.params, self.opt_state, gnorm, overflow = self._fused_step(*args)
+                loss, self.params, self.opt_state, gnorm, overflow = self._step_program(
+                    "fused_step", self._fused_step, args, batch)
                 self._fused_pending = (gnorm, overflow, lr)
                 self._cached_grads = _FUSED
             else:
                 args = (self.params, batch, self.micro_steps, scale)
-                self._count_step_flops(self._fwd_bwd, args)
-                loss, grads = self._fwd_bwd(*args)
+                loss, grads = self._step_program("fwd_bwd", self._fwd_bwd, args, batch)
                 self._cached_grads = grads
             self._last_loss = loss
             if self.eigenvalue is not None:
@@ -648,6 +657,26 @@ class DeepSpeedEngine:
         return loss
 
     __call__ = forward
+
+    def _step_program(self, name, program, args, batch):
+        """Run a step program; its first call with a batch of these shapes is
+        a ``program/first_call`` span and log line (``telemetry/costs.py``)
+        that also says how the step reduces weight gradients: ``bucket``
+        where blocks of the model gather their own parameters and reduce
+        their gradients by ``zero/overlap.py``'s rings (how many layers, and
+        the rings of a kind of block), else ``xla``."""
+        shapes = leaf_signature(batch)
+        if (name, shapes) in self._step_programs_seen:
+            return program(*args)
+        self._step_programs_seen.add((name, shapes))
+        before = [zero_overlap.traced("layers"), zero_overlap.traced("rings")]
+        notes = {}
+        with first_call("train", name, notes):
+            self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
+            out = program(*args)
+            layers, rings = zero_overlap.traced("layers") - before[0], zero_overlap.traced("rings") - before[1]
+            notes.update(grad_reduce="bucket" if layers else "xla", bucket_layers=layers, bucket_rings=rings)
+        return out
 
     def _count_step_flops(self, program, args):
         """FLOPs of a micro-batch for the MFU gauge, walked off the jaxpr of

@@ -93,7 +93,7 @@ _UNSEEN = object()
 
 
 @contextlib.contextmanager
-def first_call(family: str, bucket: Any):
+def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None):
     """The first call of one (program, signature): a ``program/first_call``
     span with ``family``, ``bucket`` and the ``q`` and ``steps`` of the span
     it runs under, closed with the seconds JAX spent by phase inside it (the
@@ -101,7 +101,10 @@ def first_call(family: str, bucket: Any):
     after) and one log line of the same. The line is what a run that is cut
     before ``dump_trace`` leaves behind, and what an operator greps for when
     a step recompiles in production. Yields a dict for phases of the
-    caller's own (``cost_card``). ``programs`` counts what reached the
+    caller's own (``cost_card``). ``notes`` is the caller's too: what else
+    it knows of the program by the time the call is over (the trainer: what
+    the step does with gradients); both end up on the span and the line.
+    ``programs`` counts what reached the
     backend inside the span, ``block_traces`` the times a transformer
     block's Python body ran (``program_block_traces_total``: one a kind of
     block and traced program, not one a layer)."""
@@ -128,11 +131,12 @@ def first_call(family: str, bucket: Any):
         # programs: how many reached the backend in here (this one, and helper programs it called first)
         traced = block_traces() - traces_before
         sp.set(programs=int(programs), block_traces=traced, total_s=total,
-               **{k + "_s": v for k, v in phases.items()})
+               **{k + "_s": v for k, v in phases.items()}, **(notes or {}))
     if sp.attrs is not None:  # the tracer is on
         logger.info("program first call: family=%s bucket=%s %s", family, bucket, " ".join(
             [f"{k}={v}" for k, v in inherited.items()] + [f"total_s={total:.3f}"]
-            + [f"{k}_s={v:.3f}" for k, v in phases.items()] + [f"block_traces={traced}"]))
+            + [f"{k}_s={v:.3f}" for k, v in phases.items()] + [f"block_traces={traced}"]
+            + [f"{k}={v}" for k, v in (notes or {}).items()]))
 
 
 def _aval_bytes(avals: Iterable[Any]) -> int:

@@ -12,9 +12,11 @@ may load libtpu, and every xdist worker imports every test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -156,3 +158,102 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     # the flash cases name their backward: exactly that many kernels, and the rule picked that path
     assert compiled.as_text().count("tpu_custom_call") == kernels
     assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, bwd_path[0]: 1.0}
+
+
+# ---------------------------------------------------------------- the trainer's step on four described chips
+
+_PERMUTE = re.compile(r" collective-permute-start\(.*?op_name=\"([^\"]*)\"")
+
+
+def census(hlo_text):
+    """What a compiled step does with gradients, read off its text (four
+    seconds for the 16-layer step on the chip, so not the engine's business:
+    my chip run, PR 28): the collective-permutes that are hops of a
+    weight-gradient matmul's own ring (``ring``), those issued by
+    ``zero/overlap.py``'s ``_ring_reduce_scatter`` (``bucket``), and the
+    reduce-scatter collectives, which hold the operation lane."""
+    names = _PERMUTE.findall(hlo_text)
+    in_matmul = sum(1 for n in names if "transpose(" in n and "dot_general" in n)
+    issued = sum(1 for n in names if n.endswith("ppermute"))
+    scatters = len(re.findall(r"calls=%all-reduce-scatter| reduce-scatter(?:-start)?\(", hlo_text))
+    form = "bucket" if issued else "ring" if in_matmul else "xla"
+    return {"grad_reduce": form, "permutes": len(names), "matmul_ring_permutes": in_matmul,
+            "bucket_permutes": issued, "reduce_scatters": scatters}
+
+
+PERMUTE = ('  %collective-permute-start.{i} = (bf16[8,8]{{1,0}}, bf16[8,8]{{1,0}}) collective-permute-start(bf16[8,8]{{1,0}} %x), '
+           'channel_id=1, metadata={{op_name="{op}" stack_frame_id=1}}\n')
+
+
+@pytest.mark.parametrize("ops,scatters,want", [
+    (["jit(fused_step)/transpose(jvp(Transformer))/Block_0/attn/q_proj/dot_general"] * 3, 1,
+     {"grad_reduce": "ring", "permutes": 3, "matmul_ring_permutes": 3, "bucket_permutes": 0, "reduce_scatters": 1}),
+    (["jit(fused_step)/transpose(jvp(Transformer))/shard_map/ppermute"] * 6
+     + ["jit(fused_step)/transpose(jvp())/while/body/closed_call/dot_general"], 1,
+     {"grad_reduce": "bucket", "permutes": 7, "matmul_ring_permutes": 1, "bucket_permutes": 6, "reduce_scatters": 1}),
+    ([], 2, {"grad_reduce": "xla", "permutes": 0, "matmul_ring_permutes": 0, "bucket_permutes": 0, "reduce_scatters": 2}),
+])
+def test_census_reads_the_form_of_the_reduction_off_the_text(ops, scatters, want):
+    text = "".join(PERMUTE.format(i=i, op=op) for i, op in enumerate(ops))
+    text += "  %fusion.4 = bf16[8,8]{1,0} fusion(bf16[32,8]{1,0} %g), kind=kCustom, calls=%all-reduce-scatter\n" * scatters
+    assert census(text) == want
+
+
+
+# a block of OLMo-1B: four attention weights of d^2 and three of the MLP of d x 4d, no bias and no norm parameter
+OLMO_BLOCK = 4 * 2048 * 2048 + 3 * 2048 * 8192
+
+
+@pytest.mark.parametrize("zero,taken", [({}, 2), ({"stage3_max_live_parameters": OLMO_BLOCK}, 1),
+                                        ({"overlap_comm": False}, 0)])
+def test_zero3_step_reduces_gradients_under_the_next_layer_or_in_the_matmuls_ring(topo, monkeypatch, zero, taken):
+    """The benchmark's training cell at two layers, every width as published
+    (OLMo-1B, ZeRO-3 over ``fsdp=4``, micro-batch 2 of 2048 tokens), forward
+    and backward as the engine builds them (its planners, its cast, the plan
+    of ``zero/overlap.py`` around the model's loss). Default config: no hop of
+    a block's weight-gradient matmul is left, 7 weights x 3 hops x 2 ways a
+    layer are issued by the bucket's ring, each ``dW`` is one whole matmul.
+    ``overlap_comm: false``: the partitioner's program, 21 hops a layer in
+    the matmuls' own rings (16 x 21 + 12 = the 348 of the chip's trace). A
+    bound of one block's parameters: the first layer is the plan's, the
+    second the partitioner's."""
+    from deepspeed_tpu.models import CausalLM, TransformerConfig
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.parallel.mesh import MeshTopology
+    from deepspeed_tpu.runtime import engine as E
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig, MeshConfig
+    from deepspeed_tpu.runtime.zero import overlap
+    from deepspeed_tpu.runtime.zero.partition import plan_grad_specs, plan_param_specs, specs_to_shardings
+
+    model = CausalLM(TransformerConfig(vocab_size=50304, n_layers=2, n_heads=16, n_kv_heads=16, d_model=2048, d_ff=8192,
+                                       max_seq_len=2048, norm="layernorm_np", activation="swiglu", pos_emb="rope",
+                                       tie_embeddings=True, dtype=BF16))
+    monkeypatch.setattr(overlap, "_backend", lambda: "tpu")  # jax's backend here is the CPU; the devices are not
+    monkeypatch.setattr(mesh_mod, "_TOPOLOGY", MeshTopology(MeshConfig.from_dict({"fsdp": 4}), devices=list(topo.devices)))
+    mesh = mesh_mod._TOPOLOGY
+    config = DeepSpeedConfig({"train_micro_batch_size_per_gpu": 2, "bf16": {"enabled": True},
+                              "zero_optimization": dict(zero, stage=3)}, mesh_shape=mesh.axis_sizes, world_size=4)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 16), np.int32)}))
+    specs = plan_param_specs(shapes, config, mesh, model.partition_rules())
+    plan = overlap.plan_for(config, mesh, specs)
+    assert (plan is not None) == (taken > 0)
+    params = jax.tree_util.tree_map(lambda s, sh: S(s.shape, s.dtype, sharding=sh), shapes,
+                                    specs_to_shardings(specs, mesh))
+    batch = {"input_ids": S((8, 2048), I32, sharding=mesh.batch_sharding())}
+
+    def loss(params32, batch):
+        with overlap.active(plan):
+            return model.loss_fn(E._cast_tree(params32, BF16), batch, None)
+
+    grad_shardings = specs_to_shardings(plan_grad_specs(shapes, specs, config, mesh), mesh)
+    text = jax.jit(jax.value_and_grad(loss), out_shardings=(None, grad_shardings)).lower(params, batch).compile().as_text()
+    said = census(text)
+    in_blocks = sum(1 for op in _PERMUTE.findall(text) if "/Block_" in op and "dot_general" in op)
+    # the plan's layers: 7 weights x 3 hops x 2 ways, and no matmul of theirs keeps a ring of its own; the partitioner's
+    # layers: 7 weights x 3 hops, and 6 a program that start a ring with zeros
+    assert said["bucket_permutes"] == 7 * 3 * 2 * taken, said
+    assert in_blocks == (21 * (2 - taken) + 6 if taken < 2 else 0), said
+    assert said["grad_reduce"] == ("bucket" if taken else "ring"), said
+    # outside the blocks both ways: the embedding's gradient is one reduce-scatter fusion, the head's activation
+    # gradient a ring of 5 hops in its matmul
+    assert said["reduce_scatters"] == 1 and said["matmul_ring_permutes"] - in_blocks == 5, said
