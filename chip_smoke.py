@@ -476,7 +476,7 @@ def trainer_phase():
         loss_plain = float(jax.jit(model.loss_fn)(params_c, batch))
     del params_c
 
-    # bench.py's zero2 rung config, with the optimizer the Pallas kernel serves
+    # ZeRO-2, bf16, no gradient accumulation, with the optimizer the Pallas kernel serves
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params,
                                                config=_train_config(2, SZ.micro_bs, optimizer="fusedadam"))
     del params

@@ -34,7 +34,7 @@ class Knob:
 _REGISTRY: Dict[str, Knob] = {}
 
 # Tuned-profile overlay (autotune/profile.py): knob values loaded from a
-# committed profiles/<device_kind>.json file. Precedence per knob is
+# profiles/<device_kind>.json file. Precedence per knob is
 # explicit env > profile > call-site default > declared default, so an
 # operator export always wins over the tuned operating point.
 _PROFILE: Dict[str, str] = {}

@@ -1,7 +1,7 @@
-"""Tuned device profiles: the committed output of an autotune run.
+"""Tuned device profiles: the output of an autotune run.
 
-A profile is one JSON file per device kind (``profiles/cpu.json``,
-``profiles/tpu-v4.json``, ...) holding the winning knob vector plus
+A profile is one JSON file per device kind (``profiles/tpu-v5-lite.json``,
+``profiles/cpu.json``, ...; none is committed) holding the winning knob vector plus
 enough provenance to audit it: the engine fingerprint of the session it
 was tuned on, a hash of the recorded trace, and the objective it won
 with against the default vector. The engine loads it through the

@@ -25,8 +25,7 @@ def _probe_devices(timeout_s: float = 180.0):
     """Backend facts under a watchdog: the first device query can hang
     forever (chip held by another process, a multi-host peer that never
     arrives), and a diagnostic tool must not hang on the very environment
-    it exists to diagnose. 180s matches
-    ``bench.py``'s probe budget — real pod inits can take minutes.
+    it exists to diagnose. 180s because real pod inits can take minutes.
     Returns ``(report_lines, backend_alive)``."""
     from .utils.watchdog import run_with_watchdog
 

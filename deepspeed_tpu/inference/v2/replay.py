@@ -122,8 +122,8 @@ def build_engine_from_session(session: Session, overrides: Optional[Dict] = None
     ``model``/``params`` short-circuit model construction (replaying a
     real checkpoint); otherwise the model is rebuilt from the recorded
     ``model_cfg`` and params are re-derived from ``meta.param_seed``
-    (synthetic workloads — the SLA bench and the replay smoke record
-    that seed precisely so the journal alone reproduces the session).
+    (synthetic workloads — the replay smoke records that seed precisely
+    so the journal alone reproduces the session).
     """
     import jax
     import numpy as np

@@ -10,8 +10,8 @@ bytes for the chosen mesh. The HLO is also scanned for collective
 pathologies (every all-gather re-materializing the full parameter tree at
 once would show up as temp bytes ~= the unsharded model).
 
-Used by ``tests/unit/test_memory_audit.py`` to hold the north-star config
-(BASELINE.md: ZeRO-3 Llama-2-7B on v5e) under the 16 GB HBM budget, and
+Used by ``tests/unit/test_memory_audit.py`` to hold ZeRO-3 Llama-2-7B on
+v5e under the 16 GB HBM budget, and
 available to users via ``deepspeed_tpu.runtime.memory_audit.audit_train_step``.
 """
 

@@ -496,7 +496,7 @@ class PerfAccountant:
 
     def totals(self) -> Dict[str, float]:
         """Cumulative attribution totals — cheap, for windowed deltas
-        (the bench rungs subtract a pre-window copy)."""
+        (a caller subtracts a copy taken before its window)."""
         with self._lock:
             return {
                 "flops": float(self.attributed_flops),
@@ -556,8 +556,9 @@ class PerfAccountant:
         }
 
     def snapshot(self) -> Dict[str, Any]:
-        """The BENCH_PERF.json shape: peaks, per-card roofline rows, the
-        goodput ledger, and the HBM pool gauges."""
+        """What ``GET /perf`` returns and ``tools/perf_report.py`` renders:
+        peaks, per-card roofline rows, the goodput ledger, and the HBM pool
+        gauges."""
         peak_flops, peak_bw = self.peaks()
         cards = sorted(self.cards().values(), key=lambda c: -c.time_s)
         return {
@@ -578,9 +579,9 @@ class PerfAccountant:
     # ------------------------------------------------------------ resets
     def reset_counts(self) -> None:
         """Zero all running attribution (calls, time, tokens, ledger) but
-        keep the built cards — the bench rungs call this after warmup so
-        the steady window is measured without re-tracing (and, in mode 2,
-        without re-compiling) any program."""
+        keep the built cards, so that a window after warmup is measured
+        without re-tracing (and, in mode 2, without re-compiling) any
+        program."""
         with self._lock:
             for c in self._cards.values():
                 c.calls = c.timed_calls = 0

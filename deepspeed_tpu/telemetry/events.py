@@ -123,7 +123,7 @@ class EventLog:
         self._sink_path = str(path)
         self._queue = queue.Queue(maxsize=self._sink_queue)
         if not self._atexit_registered:
-            # short-lived CLI runs (bench, chip_smoke) exit before the daemon
+            # short-lived CLI runs (chip_smoke, tools/) exit before the daemon
             # drain thread empties its queue — flush+join at interpreter
             # shutdown so the last events reach disk. close_sink is
             # idempotent, so one registration covers any number of
@@ -335,8 +335,7 @@ def _percentile(values: List[float], q: float) -> float:
 
 def latency_summary(events: List[Dict]) -> Dict[str, float]:
     """True per-request TTFT/TPOT percentiles + queue-time fraction over
-    every complete timeline in ``events`` (the bench serve rungs report
-    this into BENCH_TELEMETRY.json)."""
+    every complete timeline in ``events``."""
     timelines = request_timelines(events)
     metrics = []
     for tls in timelines.values():

@@ -495,8 +495,8 @@ class HealthMonitor:
         return self._g_status.value >= 1.0
 
     def reset(self) -> None:
-        """Re-arm every detector and clear alert state (tests, bench
-        rung boundaries). Wiring (detectors, sinks) stays."""
+        """Re-arm every detector and clear alert state (tests). Wiring
+        (detectors, sinks) stays."""
         for d in self._detectors.values():
             d.reset()
         self._external.clear()

@@ -27,10 +27,8 @@ an operable surface: set ``DS_TPU_OPS_PORT`` and a daemon-threaded
 
 Every JSON payload is rank-stamped and bounded (``MAX_BODY_BYTES``, plus
 hard caps on list lengths) so a scrape can never ship an unbounded ring.
-With the port knob unset nothing happens: no thread, no socket — the
-<3%-overhead guard in ``tests/unit/test_bench_contract.py`` measures the
-serving cost of the enabled path, and ``test_ops_plane.py`` asserts the
-disabled path starts zero threads.
+With the port knob unset nothing happens: no thread, no socket
+(``test_ops_plane.py`` asserts the disabled path starts zero threads).
 """
 
 import json

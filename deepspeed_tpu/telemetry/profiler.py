@@ -27,9 +27,8 @@ with bounded structured capture windows:
 Derived registry metrics: ``profile_collective_exposed_fraction``,
 ``profile_host_gap_fraction``, ``profile_device_busy_fraction``, and the
 ``profile_captures_total`` counter. Consumers: ``tools/trace_report.py``
-(waterfall rendering), the ops plane (``GET /profile``), the flight
-recorder (post-anomaly window summarised into the manifest), and the
-bench serve rungs (``collective_exposed_fraction`` extras).
+(waterfall rendering), the ops plane (``GET /profile``) and the flight
+recorder (post-anomaly window summarised into the manifest).
 
 Lane classification note: real accelerator traces put XLA ops on
 ``/device:*`` pids; the CPU backend puts them on host-pid threads named
@@ -338,9 +337,7 @@ class DeviceProfiler:
     States: ``idle`` → ``arm()`` → ``armed`` → first ``note_quantum``
     starts the trace (``tracing``) → after ``quanta_target`` markers the
     trace stops, parses, lands gauges, and the profiler returns to
-    ``idle``. ``note_quantum`` in ``idle`` is one attribute compare —
-    the armed-but-idle overhead guard in ``test_bench_contract.py``
-    measures exactly that path."""
+    ``idle``. ``note_quantum`` in ``idle`` is one attribute compare."""
 
     def __init__(self, out_dir: Optional[str] = None,
                  quanta: Optional[int] = None):
@@ -410,7 +407,8 @@ class DeviceProfiler:
 
     def finish(self) -> Optional[Dict]:
         """Close an in-flight capture with however many quanta arrived
-        (bench drains call this so a short run still lands a summary)."""
+        (``tools/trace_report.py smoke`` calls this so that a short run
+        still lands a summary)."""
         with self._lock:
             if self.state == "armed":
                 self.state = "idle"
@@ -582,7 +580,7 @@ def maybe_arm_profiler() -> Optional[DeviceProfiler]:
 
 
 def request_capture(quanta: Optional[int] = None) -> Tuple[DeviceProfiler, bool]:
-    """Arm a capture on demand (ops plane, bench): creates the singleton
+    """Arm a capture on demand (the ops plane): creates the singleton
     if needed; returns (profiler, armed) — armed is False while a
     capture is already tracing."""
     global _PROFILER
@@ -594,7 +592,7 @@ def request_capture(quanta: Optional[int] = None) -> Tuple[DeviceProfiler, bool]
 
 def note_quantum(program: str, **attrs) -> None:
     """Module-level dispatch hook: one global read + None check when no
-    profiler exists (the common case, measured by the overhead guard)."""
+    profiler exists (the common case)."""
     p = _PROFILER
     if p is not None:
         p.note_quantum(program, **attrs)

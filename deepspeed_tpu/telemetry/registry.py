@@ -9,8 +9,8 @@ constraints, in order:
   float add on a pre-resolved handle (``registry.counter(...)`` is called
   once at wiring time, the handle is cached by the instrumented object);
 - **disabled cheaper**: every mutator early-returns on one attribute
-  check and allocates nothing (guarded by the tier-1 overhead test in
-  ``tests/unit/test_bench_contract.py``);
+  check and allocates nothing (``test_disabled_registry_records_nothing``
+  in ``tests/unit/test_telemetry.py``);
 - **lock-free-enough**: metric *creation* takes a lock; updates are plain
   float adds on per-metric slots. Concurrent adds may rarely drop an
   increment under free-threading — acceptable for telemetry, and the
@@ -222,7 +222,7 @@ class MetricsRegistry:
                 yield f"{name}{suffix}", float(m.value)
 
     def snapshot(self) -> Dict:
-        """JSON-able dump of every series (bench artifacts, debugging)."""
+        """JSON-able dump of every series (``write_rank_snapshot``, debugging)."""
         counters: Dict[str, float] = {}
         gauges: Dict[str, float] = {}
         histograms: Dict[str, Dict] = {}
@@ -270,7 +270,7 @@ class MetricsRegistry:
     def reset(self) -> None:
         """Zero every series IN PLACE. Handles cached by long-lived objects
         (engines, the comm façade, jax event listeners) stay wired — only
-        the values reset. Intended for tests and bench-rung boundaries."""
+        the values reset. Intended for tests."""
         with self._lock:
             for m in self._metrics.values():
                 if m.kind == "histogram":

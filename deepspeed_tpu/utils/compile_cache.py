@@ -1,7 +1,7 @@
 """Persistent XLA compilation-cache policy (single definition).
 
-Used by the test conftest (CPU suite), ``chip_smoke.py``, ``bench.py`` and
-the autotuning trial runner. The directory is placed from outside: where
+Used by the test conftest (CPU suite), ``chip_smoke.py``, ``benchmarks/run.py``
+and the autotuning trial runner. The directory is placed from outside: where
 ``JAX_COMPILATION_CACHE_DIR`` is set the program uses it and sets no other;
 where it is not, the cache is the fixed ``<checkout>/.jax_cache_tpu`` (the
 path is part of the cache key, so it must never move: no temporary name,

@@ -2,9 +2,8 @@
 
 The first jax backend/device query blocks indefinitely, and cannot be
 cancelled, when the chip is held by another process or a multi-host
-init waits on a peer that never comes; everything that probes the backend
-(``bench.py``, ``env_report``) shares this one spawn/join/timeout
-protocol so the hang-handling behavior cannot drift between diagnostics.
+init waits on a peer that never comes; a diagnostic that probes the backend
+(``env_report``) goes through this spawn/join/timeout protocol instead.
 
 Telemetry: every timeout increments ``watchdog_timeouts_total``; paired
 with the engine's ``last_step_completed_unix`` heartbeat gauge this

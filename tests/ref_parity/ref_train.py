@@ -2,8 +2,7 @@
 (read-only at /root/reference) on CPU/gloo and dump the per-step loss
 trajectory as JSON.
 
-This is the reference half of the loss-curve-parity oracle
-(BASELINE.md north star: "identical loss curve"): the matching native
+This is the reference half of the loss-curve-parity oracle: the matching native
 half trains the same checkpoint through ``deepspeed_tpu.initialize``
 and asserts per-step deltas (tests/unit/test_reference_parity.py).
 

@@ -109,8 +109,8 @@ def _load_perf_report():
 
 
 def _perf_artifact(tmp_path):
-    """A BENCH_PERF.json built from a REAL accountant snapshot, so the
-    renderer is tested against the exact artifact shape bench.py dumps."""
+    """A snapshot file built from a REAL accountant snapshot, so the
+    renderer is tested against the exact shape ``snapshot()`` returns."""
     import jax
     import jax.numpy as jnp
 
@@ -123,7 +123,7 @@ def _perf_artifact(tmp_path):
     acct.note_spec(proposed=10, accepted=6)
     acct.note_cow(4096)
     acct.set_hbm(limit=10 ** 9, weights=10 ** 6, kv_pages=10 ** 5, prefix=10 ** 4)
-    p = tmp_path / "BENCH_PERF.json"
+    p = tmp_path / "PERF.json"
     p.write_text(json.dumps({"rung": "serve", "snapshots": {"serve": acct.snapshot()}}))
     return p
 
@@ -158,3 +158,15 @@ def test_perf_report_missing_file(tmp_path, capsys):
     mod = _load_perf_report()
     assert mod.main([str(tmp_path / "nope.json")]) == 1
     assert "cannot read" in capsys.readouterr().err
+
+
+def test_perf_report_diff_rows_flag_a_drop_beyond_the_threshold():
+    mod = _load_perf_report()
+    a = {"tokens_per_sec": 100.0, "mfu": 0.5, "goodput_fraction": 0.5,
+         "dispatches": 10.0}
+    b = dict(a, tokens_per_sec=93.0, dispatches=10.4)
+    by = {r["metric"]: r for r in mod.diff_rows(a, b, 0.05)}
+    assert by["tokens_per_sec"]["regressed"] is True   # -7%, higher is better
+    assert by["tokens_per_sec"]["pct"] == pytest.approx(-0.07)
+    assert not by["dispatches"]["regressed"]            # +4%, lower is better
+    assert not by["mfu"]["regressed"] and by["mfu"]["delta"] == 0.0

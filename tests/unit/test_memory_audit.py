@@ -1,6 +1,6 @@
-"""AOT memory audit: the north-star config must fit the v5e HBM budget.
+"""AOT memory audit: ZeRO-3 Llama-2-7B must fit the v5e HBM budget.
 
-BASELINE.md north star: ZeRO-3 Llama-2-7B training on v5e-256 (16 GB HBM
+The configuration: ZeRO-3 Llama-2-7B training on v5e-256 (16 GB HBM
 per chip). The audit compiles the real train step with abstract inputs on
 the virtual mesh (no parameters materialize) and reads XLA's per-chip
 memory analysis. Round-3 findings baked in as assertions:

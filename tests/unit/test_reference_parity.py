@@ -1,9 +1,8 @@
 """Loss-curve parity against the INSTALLED reference DeepSpeed.
 
-The north star (BASELINE.md:16) asks for "an identical loss curve", and
-every other oracle in this suite re-implements the reference's math;
-this one runs the real thing: the same tiny HF GPT-2 checkpoint is
-trained (a) by reference DeepSpeed 0.14.3 (`/root/reference`) on
+A port owes its users an identical loss curve, and every other oracle in
+this suite re-implements the reference's math; this one runs the real
+thing: the same tiny HF GPT-2 checkpoint is trained (a) by reference DeepSpeed 0.14.3 (`/root/reference`) on
 CPU/gloo via ``tests/ref_parity/ref_train.py`` subprocesses, and (b) by
 ``deepspeed_tpu.initialize`` on the CPU backend — same init, same data
 order, same plain-Adam hyperparameters, same shifted-mean-CE loss — and

@@ -190,3 +190,33 @@ class TestFusedScheduler:
         assert list(row[:3]) == list(seq.blocks) and all(row[3:] == 0)
         assert all(eng.state.block_table_row(None, 4, fill_block=5) == 5)
         eng.state.flush_sequence(77)
+
+
+def test_generate_is_compile_free_after_warmup(monkeypatch):
+    """A second generate() over the same prompts meets only bucket and
+    burst shapes the first one compiled, with and without speculative
+    decoding: mark the JitAuditor steady after the first pass and the
+    second must trigger zero recompiles."""
+    monkeypatch.setenv("DS_TPU_JIT_AUDIT", "1")
+    cfg_model = TransformerConfig(vocab_size=128, n_layers=2, n_heads=4, n_kv_heads=2,
+                                  d_model=32, max_seq_len=128, norm="rmsnorm",
+                                  activation="swiglu", pos_emb="rope", tie_embeddings=False)
+    model = CausalLM(cfg_model)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)})
+    rng = np.random.RandomState(0)
+    # varied prompt lengths: a ragged workload
+    prompts = [rng.randint(0, cfg_model.vocab_size, size=(int(l),)).tolist()
+               for l in rng.randint(4, 13, size=3)]
+
+    for spec in ("0", "1"):
+        monkeypatch.setenv("DS_TPU_SPEC_DECODE", spec)
+        eng = InferenceEngineV2(model, params, RaggedInferenceEngineConfig(
+            state_manager=RaggedBatchConfig(kv_block_size=8, max_context=128,
+                                            num_kv_blocks=64),
+            dtype="float32"))
+        eng.generate(prompts, max_new_tokens=8)  # warmup
+        assert eng.jit_auditor.compiles > 0
+        eng.jit_auditor.mark_steady()
+        eng.generate(prompts, max_new_tokens=8)  # the steady window
+        assert eng.jit_auditor.steady_recompiles == 0, \
+            f"DS_TPU_SPEC_DECODE={spec}: the second generate() recompiled after warmup"

@@ -138,6 +138,56 @@ def test_render_prometheus_golden():
     )
 
 
+def _parse_prometheus(text):
+    """Hold a text exposition to what a scraper depends on: every series has
+    a legal name and appears at most once, under a TYPE that follows its
+    HELP. Returns ``{family: kind}``."""
+    import re
+
+    name_re = re.compile(r"^[a-z_][a-z0-9_]*$")
+    seen = set()
+    types = {}
+    helps = set()
+    for line in text.splitlines():
+        if line.startswith("# TYPE "):
+            _, _, name, kind = line.split(" ")
+            assert name_re.match(name), line
+            assert name not in types, f"duplicate TYPE line: {line}"
+            assert name in helps, f"TYPE without preceding HELP: {line}"
+            types[name] = kind
+            continue
+        if line.startswith("# HELP "):
+            name = line.split(" ")[2]
+            assert name_re.match(name), line
+            helps.add(name)
+            continue
+        if line.startswith("#"):  # other comments: legal, ignored
+            continue
+        series, value = line.rsplit(" ", 1)
+        float(value)  # every sample value parses
+        name = series.split("{", 1)[0]
+        bare = re.sub(r"_(bucket|sum|count)$", "", name)
+        assert name_re.match(name), line
+        assert name in types or bare in types, f"sample without TYPE family: {line}"
+        assert series not in seen, f"duplicate series: {line}"
+        seen.add(series)
+    return types
+
+
+def test_render_prometheus_parses_clean():
+    """Every emitted series must use a legal Prometheus name and appear at
+    most once — the properties a scraper actually depends on."""
+    reg = MetricsRegistry()
+    reg.counter("train_steps_total").inc(3)
+    reg.counter("comm_bytes_total", op="all_reduce").inc(1 << 20)
+    reg.counter("comm_bytes_total", op="all_gather").inc(7)
+    reg.gauge("kv_block_occupancy").set(0.5)
+    reg.histogram("infer_ttft_seconds", buckets=(0.1, 1.0)).observe(0.2)
+    assert _parse_prometheus(reg.render_prometheus()) == {
+        "train_steps_total": "counter", "comm_bytes_total": "counter",
+        "kv_block_occupancy": "gauge", "infer_ttft_seconds": "histogram"}
+
+
 def test_snapshot_is_json_able():
     reg = MetricsRegistry()
     reg.counter("c_total", op="x").inc(2)
@@ -811,3 +861,86 @@ def test_metric_catalog_matches_docs():
     assert not undocumented, f"metrics registered in code but absent from docs/OBSERVABILITY.md: {sorted(undocumented)}"
     phantom = doc_names - code_names
     assert not phantom, f"metrics documented but not registered anywhere in code: {sorted(phantom)}"
+
+
+# ----------------------------------------- instrumentation left switched on
+# What a hot loop leaves behind in each piece of instrumentation that is on
+# but has nothing to do. What the pieces cost is measured on the chip
+# (PERF.md); a CPU loop's timing is not asserted anywhere.
+
+def _ring_only_event_log(tmp_path, monkeypatch):
+    from deepspeed_tpu.telemetry import EventLog
+    monkeypatch.chdir(tmp_path)
+    ev = EventLog(capacity=4096, registry=MetricsRegistry())
+    for i in range(2000):  # the two events a decode dispatch and its commit emit
+        ev.emit("decode", i, q=1, k=1)
+        ev.emit("finish", i, n_new=4)
+    assert len(ev) == 4000
+    assert [e["kind"] for e in ev.events(uid=1999)] == ["decode", "finish"]
+    # no sink was asked for: no drain thread, and no file anywhere
+    assert ev._thread is None and ev._queue is None
+    assert list(tmp_path.iterdir()) == []
+
+
+def _profiler_idle_after_finish(tmp_path, monkeypatch):
+    from deepspeed_tpu.telemetry import profiler
+    profiler._reset_for_tests()
+    try:
+        prof, armed = profiler.request_capture(quanta=1)
+        assert armed
+        assert prof.finish() is None  # armed -> idle, and no trace was started
+        assert prof.state == "idle"
+        for i in range(2000):  # the hook a fused quantum's dispatch calls
+            profiler.note_quantum("fused_step", rows=8, tokens=i)
+        status = prof.status()
+        assert status["state"] == "idle" and status["n_markers"] == 0
+        assert status["captures"] == 0
+    finally:
+        profiler._reset_for_tests()
+
+
+def _journal_reads_back_every_record(tmp_path, monkeypatch):
+    from deepspeed_tpu.telemetry.journal import Journal, read_journal
+    reg = MetricsRegistry()
+    path = tmp_path / "journal.jsonl"
+    journal = Journal(str(path), registry=reg)
+    journal.begin_session({}, kind="generate")
+    n = 2000  # far more lines than one write buffer holds
+    for i in range(n):  # what one decode quantum and its commit write
+        journal.record_quantum(i, [i % 8], [])
+        journal.record_commit(i % 8, i, [42])
+    journal.close()
+    (session,) = read_journal(str(path))
+    assert [q["q"] for q in session.quanta] == list(range(n))
+    assert len(session.commits) == n
+    assert {u: len(t) for u, t in session.tokens_by_uid().items()} == {u: n // 8 for u in range(8)}
+    assert reg.peek("journal_records_total") == 2 * n + 2  # and the session's two ends
+    assert reg.peek("journal_bytes_total") == path.stat().st_size
+
+
+def _ops_plane_scrape_of_a_populated_registry(tmp_path, monkeypatch):
+    import deepspeed_tpu.telemetry.registry as registry_mod
+    from deepspeed_tpu.telemetry.ops_plane import OpsPlane
+    reg = MetricsRegistry()
+    for i in range(64):  # the series mix a serving engine accumulates
+        reg.counter("infer_requests_total", model=f"m{i % 4}").inc(i)
+        reg.gauge("kv_block_occupancy", pool=f"p{i % 8}").set(i / 64)
+        reg.histogram("infer_ttft_seconds", buckets=(0.01, 0.1, 1.0),
+                      model=f"m{i % 4}").observe(0.02 * (i % 5 + 1))
+    monkeypatch.setattr(registry_mod, "get_registry", lambda: reg)
+    plane = OpsPlane()
+    for _ in range(2):  # a scrape changes nothing: the second reads the same
+        status, ctype, body = plane.handle("GET", "/metrics")
+        assert status == 200 and ctype.startswith("text/plain")
+        assert _parse_prometheus(body.decode()) == {
+            "infer_requests_total": "counter", "kv_block_occupancy": "gauge",
+            "infer_ttft_seconds": "histogram"}
+    assert reg.peek("infer_requests_total", model="m3") == sum(range(3, 64, 4))
+
+
+@pytest.mark.parametrize("case", [_ring_only_event_log, _profiler_idle_after_finish,
+                                  _journal_reads_back_every_record,
+                                  _ops_plane_scrape_of_a_populated_registry],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_instrumentation_left_on_keeps_its_state(case, tmp_path, monkeypatch):
+    case(tmp_path, monkeypatch)

@@ -5,7 +5,7 @@
     python tools/autotune_serve.py smoke                 # record tiny trace, tune, round-trip the profile
     python tools/autotune_serve.py tune JOURNAL --ttft-p99 0.5 --out auto
     python tools/autotune_serve.py tune JOURNAL --dim DS_TPU_SPEC_K=2,4,8 --mode grid
-    python tools/autotune_serve.py show profiles/cpu.json
+    python tools/autotune_serve.py show PROFILE.json
 
 ``tune`` searches the serving knob space over one recorded journal
 session with successive halving: analytic cost-card pruning drops

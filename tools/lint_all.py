@@ -49,33 +49,16 @@ def _profile_smoke() -> int:
     return mod.main(["smoke"])
 
 
-def _perf_gate() -> int:
-    """Gate the repo's BENCH_PERF.json against the frozen baseline with
-    committed budgets (opt-in: ``--perf-gate``; the sentinel half of
-    docs/OBSERVABILITY.md "Closing the loop")."""
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate_cli", os.path.join(_TOOLS_DIR, "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod.main(["--no-ledger"])
-
-
 def main(argv=None) -> int:
     extra = list(argv) if argv is not None else sys.argv[1:]
     smoke = "--replay-smoke" in extra
     profile_smoke = "--profile-smoke" in extra
-    perf_gate = "--perf-gate" in extra
-    if smoke or perf_gate or profile_smoke:
-        extra = [a for a in extra if a not in ("--replay-smoke", "--perf-gate",
-                                               "--profile-smoke")]
+    extra = [a for a in extra if a not in ("--replay-smoke", "--profile-smoke")]
     rc = _load_cli().main(["--checks", "all", "--strict-baseline"] + extra)
     if rc == 0 and smoke:
         rc = _replay_smoke()
     if rc == 0 and profile_smoke:
         rc = _profile_smoke()
-    if rc == 0 and perf_gate:
-        rc = _perf_gate()
     return rc
 
 

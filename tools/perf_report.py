@@ -1,13 +1,14 @@
 #!/usr/bin/env python
-"""Render BENCH_PERF.json (the bench run's performance-accounting dump)
-as per-program attribution + roofline tables.
+"""Render performance-accounting snapshots (``PerfAccountant.snapshot()``,
+what the ops plane's ``GET /perf`` returns) as per-program attribution +
+roofline tables.
 
 Usage:
-    python tools/perf_report.py [BENCH_PERF.json] [--rung serve] [--json]
+    python tools/perf_report.py PERF.json [--rung serve] [--json]
 
-Stdlib-only on purpose: the artifact is produced on the TPU host, the
-report is usually read elsewhere. Each snapshot (one per serve rung)
-renders as:
+The file holds ``{"snapshots": {<name>: <snapshot>, ...}}``. Stdlib-only on
+purpose: the artifact is produced on the TPU host, the report is usually
+read elsewhere. Each snapshot renders as:
 
 - headline: accounting mode, peak FLOP/s + bandwidth and the machine
   balance point, window totals, MFU, goodput fraction;
@@ -28,8 +29,6 @@ import argparse
 import json
 import os
 import sys
-
-_DEF_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir, "BENCH_PERF.json")
 
 
 def _num(x, unit="", precision=2):
@@ -150,77 +149,38 @@ def render(doc, rung=None):
 
 # headline metric -> direction: +1 = higher is better, -1 = lower is better
 HEADLINE_METRICS = (("tokens_per_sec", +1), ("mfu", +1),
-                    ("goodput_fraction", +1), ("dispatches", -1),
-                    ("allreduce_bytes", -1))
+                    ("goodput_fraction", +1), ("dispatches", -1))
 
 
 def snapshot_headline(snap):
-    """The comparable scalars of one rung's snapshot. Snapshots from a
-    tensor-parallel rung carry a ``tp`` section (bench.py run_serve_tp)
-    whose allreduce traffic and headline-run dispatch count override the
-    process-wide card sum — a quantized-allreduce or dispatch-count
-    regression then fails perf_gate, not just eyeballs."""
+    """The comparable scalars of one snapshot."""
     totals = snap.get("totals") or {}
     ledger = snap.get("ledger") or {}
-    tp = snap.get("tp") or {}
     time_s = float(totals.get("time_s") or 0.0)
     useful = float(totals.get("useful_tokens") or 0.0)
-    out = {
+    return {
         "tokens_per_sec": useful / time_s if time_s > 0 else 0.0,
         "mfu": snap.get("mfu"),
         "goodput_fraction": float(ledger.get("goodput_fraction") or 0.0),
         "dispatches": float(sum(int(c.get("calls", 0)) for c in snap.get("cards") or [])),
     }
-    if "allreduce_bytes" in tp:
-        out["allreduce_bytes"] = float(tp["allreduce_bytes"])
-    if "dispatches" in tp:
-        out["dispatches"] = float(tp["dispatches"])
-    return out
 
 
 def diff_rows(head_a, head_b, threshold):
     """Per-metric comparison rows; each carries a ``regressed`` verdict
-    (a drop beyond ``threshold`` in the metric's good direction).
-    ``threshold`` is a float, or a callable ``metric -> float`` for
-    per-metric budgets (see :func:`threshold_resolver`)."""
-    budget_for = threshold if callable(threshold) else (lambda _m: threshold)
+    (a relative drop beyond ``threshold`` in the metric's good direction)."""
     rows = []
     for metric, sign in HEADLINE_METRICS:
         a, b = head_a.get(metric), head_b.get(metric)
-        budget = float(budget_for(metric))
         row = {"metric": metric, "a": a, "b": b, "delta": None,
-               "pct": None, "regressed": False, "budget": budget}
+               "pct": None, "regressed": False}
         if isinstance(a, (int, float)) and isinstance(b, (int, float)):
             row["delta"] = b - a
             if a:
                 row["pct"] = (b - a) / abs(a)
-                row["regressed"] = sign * row["pct"] < -budget
+                row["regressed"] = sign * row["pct"] < -threshold
         rows.append(row)
     return rows
-
-
-def threshold_resolver(thresholds, rung, fallback):
-    """Budget lookup for one rung from a thresholds document
-    (``tools/perf_thresholds.json``):
-
-        {"default": 0.05,
-         "rungs": {"serve": {"default": 0.08,
-                             "metrics": {"dispatches": 0.0}}}}
-
-    Resolution order per metric: ``rungs[rung].metrics[metric]`` ->
-    ``rungs[rung].default`` -> file ``default`` -> ``fallback`` (the
-    ``--threshold`` flag). Returns ``metric -> float``."""
-    doc = thresholds or {}
-    rung_doc = (doc.get("rungs") or {}).get(rung) or {}
-    metrics = rung_doc.get("metrics") or {}
-
-    def budget(metric):
-        for candidate in (metrics.get(metric), rung_doc.get("default"),
-                          doc.get("default")):
-            if candidate is not None:
-                return float(candidate)
-        return float(fallback)
-    return budget
 
 
 def render_compare(rows, label_a="A", label_b="B"):
@@ -244,12 +204,9 @@ def render_compare(rows, label_a="A", label_b="B"):
     return _table(["metric", label_a, label_b, "delta", "pct", ""], table_rows)
 
 
-def render_diff(doc_a, doc_b, label_a, label_b, rung=None, threshold=0.05,
-                thresholds=None):
-    """Compare two BENCH_PERF.json artifacts per rung. Returns
-    (report text, regressed flag). ``thresholds`` is an optional
-    per-rung/per-metric budget document (see :func:`threshold_resolver`);
-    ``threshold`` is the global fallback."""
+def render_diff(doc_a, doc_b, label_a, label_b, rung=None, threshold=0.05):
+    """Compare two snapshot files, name by name. Returns (report text,
+    regressed flag)."""
     snaps_a = doc_a.get("snapshots") or {}
     snaps_b = doc_b.get("snapshots") or {}
     rungs = sorted(set(snaps_a) & set(snaps_b))
@@ -259,14 +216,10 @@ def render_diff(doc_a, doc_b, label_a, label_b, rung=None, threshold=0.05,
         rungs = [rung]
     out, regressed = [], False
     for r in rungs:
-        budget = threshold_resolver(thresholds, r, threshold)
         rows = diff_rows(snapshot_headline(snaps_a[r]), snapshot_headline(snaps_b[r]),
-                         budget)
+                         threshold)
         regressed = regressed or any(row["regressed"] for row in rows)
-        budgets = sorted({row["budget"] for row in rows})
-        label = (f"{100.0 * budgets[0]:.0f}%" if len(budgets) == 1
-                 else "per-metric")
-        out.append(f"== {r} ==  ({label_a} -> {label_b}, threshold {label})")
+        out.append(f"== {r} ==  ({label_a} -> {label_b}, threshold {100.0 * threshold:.0f}%)")
         out.append(render_compare(rows, label_a=label_a, label_b=label_b))
     only_a = sorted(set(snaps_a) - set(snaps_b))
     only_b = sorted(set(snaps_b) - set(snaps_a))
@@ -281,19 +234,15 @@ def render_diff(doc_a, doc_b, label_a, label_b, rung=None, threshold=0.05,
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("path", nargs="?", default=_DEF_PATH, help="BENCH_PERF.json path")
-    ap.add_argument("--rung", default=None, help="render one rung's snapshot only")
+    ap.add_argument("path", nargs="?", default=None, help="snapshot file (not with --diff)")
+    ap.add_argument("--rung", default=None, help="render the snapshot of this name only")
     ap.add_argument("--json", action="store_true", help="echo the (selected) raw JSON instead")
     ap.add_argument("--diff", nargs=2, metavar=("A.json", "B.json"), default=None,
-                    help="compare two BENCH_PERF.json snapshots per rung "
+                    help="compare two snapshot files name by name "
                          "(tokens/s, MFU, goodput, dispatches); exits 1 on "
                          "a regression beyond --threshold")
     ap.add_argument("--threshold", type=float, default=0.05,
                     help="relative regression threshold for --diff (default 0.05)")
-    ap.add_argument("--thresholds", metavar="JSON", default=None,
-                    help="per-rung/per-metric budget file for --diff "
-                         "(e.g. tools/perf_thresholds.json); --threshold "
-                         "remains the fallback for unlisted entries")
     args = ap.parse_args(argv)
     if args.diff is not None:
         path_a, path_b = args.diff
@@ -302,23 +251,20 @@ def main(argv=None):
                 doc_a = json.load(f)
             with open(path_b) as f:
                 doc_b = json.load(f)
-            thresholds = None
-            if args.thresholds:
-                with open(args.thresholds) as f:
-                    thresholds = json.load(f)
         except OSError as e:
             print(f"perf_report: cannot read diff input: {e}", file=sys.stderr)
             return 1
         try:
             text, regressed = render_diff(doc_a, doc_b,
                                           os.path.basename(path_a), os.path.basename(path_b),
-                                          rung=args.rung, threshold=args.threshold,
-                                          thresholds=thresholds)
+                                          rung=args.rung, threshold=args.threshold)
         except KeyError as e:
             print(f"perf_report: {e.args[0]}", file=sys.stderr)
             return 1
         print(text)
         return 1 if regressed else 0
+    if args.path is None:
+        ap.error("a snapshot file, or --diff A.json B.json")
     try:
         with open(args.path) as f:
             doc = json.load(f)
