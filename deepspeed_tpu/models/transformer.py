@@ -449,7 +449,9 @@ class Transformer(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         emb = self.param("wte", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.d_model), jnp.float32)
-        x = emb[input_ids].astype(cfg.dtype)
+        hook = _BLOCK_HOOK.get() if kv_caches is None and not self.is_initializing() else None
+        x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.moe_num_experts > 0) if hook is not None else None
+        x = (emb[input_ids] if x is None else x).astype(cfg.dtype)
         if cfg.embed_scale:  # gemma normalizer
             x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
         if cfg.pos_emb == "learned":
@@ -472,9 +474,10 @@ class Transformer(nn.Module):
             # one traced function a KIND of block and program, applied once a layer to that layer's
             # parameters: the block's Python body runs once, not n_layers times
             kinds = functools.cache(functools.partial(block_fn, cfg, train=train, remat=remat))
-            hook = _BLOCK_HOOK.get() if kv_caches is None else None
             layers = [] if self.is_initializing() else [self.get_variable("params", f"layer_{i}")
                                                         for i in range(cfg.n_layers)]
+            paths = [self.path + (f"layer_{i}",) for i in range(cfg.n_layers)]
+            may_sow = [cfg.moe_for(i) for i in range(cfg.n_layers)]
             for i in range(cfg.n_layers):
                 kind = (cfg.window_for(i), cfg.moe_for(i))
                 kv_cache = kv_caches[i] if kv_caches is not None else None
@@ -484,7 +487,7 @@ class Transformer(nn.Module):
                 else:
                     wrap = None
                     if hook is not None:
-                        wrap, x = hook(self.path + (f"layer_{i}",), layers, i, kind[1], x)
+                        wrap, x = hook(paths, layers, may_sow, i, x)
                     (y, cache), sown = kinds(*kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids)
                     for col, tree in sown.items():  # what the block sowed (MoE auxiliary loss), where it was
                         if self.is_mutable_collection(col):
@@ -555,15 +558,26 @@ _BLOCK_HOOK: contextvars.ContextVar = contextvars.ContextVar("transformer_block_
 @contextlib.contextmanager
 def block_hook(hook):
     """While a ``Transformer`` is traced inside, its loop over layers asks
-    ``hook(path, layers, i, sows, x)`` before each block it applies without
-    a KV cache: the block's path in the parameter tree, every layer's
-    parameters (a list: the hook may replace those of layers still to come),
-    which layer this is, whether it may sow, and the activations it is about
-    to take. The answer is ``(wrap, x)``: the activations to feed it, and
+    ``hook(paths, layers, sows, i, x)`` before each block it applies without
+    a KV cache: every layer's path in the parameter tree, its parameters and
+    whether it may sow (lists: the hook may replace the parameters of layers
+    still to come), which layer this is, and the activations it is about to
+    take. The answer is ``(wrap, x)``: the activations to feed it, and
     ``block_fn``'s ``wrap`` for it or None. Layers that are to share one
-    trace get the same ``wrap`` object. This is how a trainer runs a block
-    some other way than XLA's partitioner would without the model knowing
-    how (``runtime/zero/overlap.py``)."""
+    trace get the same ``wrap`` object.
+
+    The token embedding's look-up is offered as ``hook.look_up(path, table,
+    ids, sows)``: the answer is ``table[ids]`` or None. The loss head is
+    offered as ``hook.head(paths, leaves, fn, vocab_dim, sows)``: its
+    parameters' paths in the tree, the parameters (the weight first, then a
+    bias), ``fn(leaves, hidden, labels, vocab_axis=None)`` that gives the
+    summed loss and the count of tokens, each of shape (1,), and which
+    dimension of the weight is the vocabulary. The answer is None, or
+    ``run(hidden, labels)`` whose results, summed, are ``fn``'s.
+
+    This is how a trainer runs a region some other way than XLA's
+    partitioner would without the model knowing how
+    (``runtime/zero/overlap.py``)."""
     token = _BLOCK_HOOK.set(hook)
     try:
         yield
@@ -617,6 +631,21 @@ def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: i
     return jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1)
 
 
+def _head_sums(leaves, hidden, labels, dtype, vd_layout, vocab_axis=None):
+    """The loss head: the summed token cross-entropy and the count of tokens
+    that are not ignored, each of shape (1,). ``vocab_axis``: as
+    ``fused_cross_entropy_sums`` has it, the weight being this device's
+    slice of the vocabulary and a bias that slice's or whole."""
+    from ..ops.fused_ce import fused_cross_entropy_sums
+
+    w, bias = leaves[0].astype(dtype), leaves[1] if len(leaves) > 1 else None
+    n_own = w.shape[0 if vd_layout else 1]
+    if vocab_axis is not None and bias is not None and bias.shape[0] != n_own:
+        bias = jax.lax.dynamic_slice_in_dim(bias, jax.lax.axis_index(vocab_axis) * n_own, n_own)
+    total, count = fused_cross_entropy_sums(hidden, w, labels, vd_layout=vd_layout, bias=bias, vocab_axis=vocab_axis)
+    return total[None], count[None]
+
+
 class CausalLM:
     """Binds a Transformer to the engine's ``loss_fn(params, batch, rng)`` contract.
 
@@ -649,6 +678,12 @@ class CausalLM:
                 raise ValueError("progressive layer drop needs the engine's step rng")
             extra["pld_theta"] = pld_theta
             extra["rngs"] = {"pld": rng}
+        cfg = self.cfg
+        if cfg.tie_embeddings:
+            head = (("wte",),) + ((("mlm_bias",),) if cfg.mlm_head else ())
+        else:
+            head = (("lm_head", "kernel"),) + ((("lm_head", "bias"),) if cfg.lm_head_bias else ())
+        leaves = tuple(functools.reduce(lambda tree, name: tree[name], path, params) for path in head)
         if self.cfg.moe_num_experts > 0:
             hidden, mods = self.module.apply({"params": params}, input_ids, return_hidden=True,
                                              mutable=_SOWN, **extra)
@@ -657,12 +692,7 @@ class CausalLM:
         else:
             hidden = self.apply(params, input_ids, return_hidden=True, **extra)
             aux = 0.0
-        if self.cfg.tie_embeddings:
-            w, vd = params["wte"].astype(self.cfg.dtype), True
-            head_b = params["mlm_bias"] if self.cfg.mlm_head else None
-        else:
-            w, vd = params["lm_head"]["kernel"].astype(self.cfg.dtype), False
-            head_b = params["lm_head"]["bias"] if self.cfg.lm_head_bias else None
+        w = leaves[0].astype(cfg.dtype)
         if "labels" in batch:
             labels = batch["labels"]
         else:
@@ -670,7 +700,17 @@ class CausalLM:
             # CE's sequence chunking stays aligned
             labels = jnp.concatenate(
                 [input_ids[:, 1:], jnp.full((input_ids.shape[0], 1), -100, input_ids.dtype)], axis=1)
-        ce = fused_cross_entropy(hidden, w, labels, vd_layout=vd, bias=head_b)
+        hook = _BLOCK_HOOK.get()
+        by_hook = None
+        if hook is not None:
+            by_hook = hook.head(head, leaves, functools.partial(_head_sums, dtype=cfg.dtype, vd_layout=cfg.tie_embeddings),
+                                0 if cfg.tie_embeddings else 1, cfg.moe_num_experts > 0)
+        if by_hook is None:
+            ce = fused_cross_entropy(hidden, w, labels, vd_layout=cfg.tie_embeddings,
+                                     bias=leaves[1] if len(leaves) > 1 else None)
+        else:  # a share of the sum and of the count from each device
+            total, count = by_hook(hidden, labels)
+            ce = jnp.sum(total) / jnp.maximum(jnp.sum(count), 1)
         return ce + self.cfg.moe_aux_loss_coef * aux
 
     def to_pipeline(self, num_stages: int, params=None, rng=None, example_batch=None):

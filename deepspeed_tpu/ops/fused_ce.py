@@ -84,13 +84,21 @@ def _project(xs: jnp.ndarray, w: jnp.ndarray, vd_layout: bool) -> jnp.ndarray:
     return jax.lax.dot_general(xs, w, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _fused_ce_sum(x, w, b, labels, valid, vd_layout: bool, chunk: int, has_bias: bool):
-    total, _ = _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias)
+def _own_labels(labels, n_own: int, vocab_axis: str):
+    """Under ``vocab_axis``, where this device holds ``n_own`` consecutive
+    entries of the vocabulary: the labels counted from its first entry, and
+    which of them fall into its slice."""
+    own = labels - jax.lax.axis_index(vocab_axis) * n_own
+    return own, (own >= 0) & (own < n_own)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _fused_ce_sum(x, w, b, labels, valid, vd_layout: bool, chunk: int, has_bias: bool, vocab_axis: Optional[str]):
+    total, _ = _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis)
     return total
 
 
-def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias):
+def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis):
     B, S, D = x.shape
     nb = S // chunk
     xs = x.reshape(B, nb, chunk, D).transpose(1, 0, 2, 3)  # (nb, B, C, D)
@@ -103,7 +111,14 @@ def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias):
         if has_bias:
             logits = logits + b
         lse = jax.nn.logsumexp(logits, axis=-1)  # (B,C)
-        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+        if vocab_axis is None:
+            gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+        else:  # this device's slice of the vocabulary: the sum of exponentials and the gold logit are summed over devices
+            top = jax.lax.pmax(lse, vocab_axis)
+            lse = top + jnp.log(jax.lax.psum(jnp.exp(lse - top), vocab_axis))
+            own, mine = _own_labels(lc, logits.shape[-1], vocab_axis)
+            gold = jnp.take_along_axis(logits, jnp.where(mine, own, 0)[..., None], axis=-1)[..., 0]
+            gold = jax.lax.psum(jnp.where(mine, gold, 0.0), vocab_axis)
         nll = jnp.where(vc, lse - gold, 0.0)
         return acc + jnp.sum(nll), lse
 
@@ -111,13 +126,19 @@ def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias):
     return total, lses  # lses: (nb, B, C)
 
 
-def _ce_vjp_fwd(x, w, b, labels, valid, vd_layout, chunk, has_bias):
-    total, lses = _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias)
+def _ce_vjp_fwd(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis):
+    total, lses = _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis)
     return total, (x, w, b, labels, valid, lses)
 
 
-def _ce_vjp_bwd(vd_layout, chunk, has_bias, res, g):
+def _ce_vjp_bwd(vd_layout, chunk, has_bias, vocab_axis, res, g):
     x, w, b, labels, valid, lses = res
+    if vocab_axis is not None:
+        # every device holds the whole sum, so each is handed a share of its cotangent; ``dx`` comes out as this
+        # slice's part of the sum over the vocabulary, for the caller to sum over devices
+        g = jax.lax.psum(g, vocab_axis)
+        own, mine = _own_labels(labels, w.shape[0] if vd_layout else w.shape[1], vocab_axis)
+        labels = jnp.where(mine, own, -1)  # -1: no entry of this slice, a row of zeros in the one-hot
     B, S, D = x.shape
     V = w.shape[0] if vd_layout else w.shape[1]
     nb = S // chunk
@@ -159,6 +180,35 @@ def _ce_vjp_bwd(vd_layout, chunk, has_bias, res, g):
 _fused_ce_sum.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
 
 
+def fused_cross_entropy_sums(x: jnp.ndarray,
+                             w: jnp.ndarray,
+                             labels: jnp.ndarray,
+                             ignore_index: int = -100,
+                             vd_layout: bool = False,
+                             chunk: Optional[int] = None,
+                             bias: Optional[jnp.ndarray] = None,
+                             vocab_axis: Optional[str] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(summed token CE, count of positions not ignored) of
+    ``fused_cross_entropy``, for a caller that divides after summing.
+
+    ``vocab_axis``: the call is every device's own program under
+    ``shard_map`` over that mesh axis, ``w`` and ``bias`` are this device's
+    consecutive slice of the vocabulary, ``x`` and ``labels`` the same on
+    every device: the softmax's sum and the gold logit are summed over the
+    axis, every device returns the whole sums, ``dw`` is this slice's, and
+    ``dx`` is this slice's part of a sum that the caller takes over the
+    axis."""
+    B, S, D = x.shape
+    V = w.shape[0] if vd_layout else w.shape[1]
+    chunk = chunk or _pick_chunk(S, B=B, V=V)
+    valid = labels != ignore_index
+    safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
+    has_bias = bias is not None
+    b = bias.astype(jnp.float32) if has_bias else jnp.zeros((V,), jnp.float32)
+    total = _fused_ce_sum(x, w, b, safe_labels, valid, bool(vd_layout), int(chunk), has_bias, vocab_axis)
+    return total, jnp.sum(valid)
+
+
 def fused_cross_entropy(x: jnp.ndarray,
                         w: jnp.ndarray,
                         labels: jnp.ndarray,
@@ -177,12 +227,5 @@ def fused_cross_entropy(x: jnp.ndarray,
     Matches ``models.transformer.cross_entropy_loss`` numerics (fp32
     logits, mean over valid positions).
     """
-    B, S, D = x.shape
-    V = w.shape[0] if vd_layout else w.shape[1]
-    chunk = chunk or _pick_chunk(S, B=B, V=V)
-    valid = labels != ignore_index
-    safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
-    has_bias = bias is not None
-    b = bias.astype(jnp.float32) if has_bias else jnp.zeros((V,), jnp.float32)
-    total = _fused_ce_sum(x, w, b, safe_labels, valid, bool(vd_layout), int(chunk), has_bias)
-    return total / jnp.maximum(jnp.sum(valid), 1)
+    total, count = fused_cross_entropy_sums(x, w, labels, ignore_index, vd_layout, chunk, bias)
+    return total / jnp.maximum(count, 1)

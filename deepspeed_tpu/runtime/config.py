@@ -80,15 +80,20 @@ class ZeroConfig(DeepSpeedConfigModel):
 
     ``overlap_comm`` (default: true at stage 3, false below) is read at
     stage 3 on a TPU mesh whose one axis wider than a device is ZeRO's:
-    true makes a transformer block gather its own parameters and reduce a
-    layer's weight gradients by a ring under the next layer's backward, a
-    layer being the bucket (``runtime/zero/overlap.py``); false, any other
-    backend and any other mesh leave the reduction to XLA's partitioner.
-    Such a block keeps its gathered 16-bit weights from its forward to its
-    backward, so ``stage3_max_live_parameters`` is read there too: blocks
-    gather for themselves, first layer first, while the parameters they
+    true makes every transformer block gather its own parameters and reduce
+    a layer's weight gradients by a ring under the next layer's backward, a
+    layer being the bucket, and the token look-up and the loss head work on
+    each device's own rows of the embedding with the same ring for what they
+    sum over devices (``runtime/zero/overlap.py``); false, any other backend
+    and any other mesh leave the reduction to XLA's partitioner.
+    A block that gathered its 16-bit weights keeps them from its forward to
+    its backward, so ``stage3_max_live_parameters`` is read there too, with
+    the reference's meaning: the last blocks keep while the parameters they
     hold stay under it (the default 1e9: 2 GB a device in bf16), and the
-    layers after that are the partitioner's; 0 is ``overlap_comm: false``.
+    blocks before them release their weights after the forward and
+    all-gather them a second time in the backward. No block leaves the plan
+    for it; 0 keeps nothing, so every block gathers twice
+    (``overlap_comm: false`` is the way to the partitioner's program).
     ``reduce_bucket_size`` is parsed and not read.
     """
     stage: int = ds_field(0, ge=0, le=3)

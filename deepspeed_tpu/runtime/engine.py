@@ -663,19 +663,23 @@ class DeepSpeedEngine:
         a ``program/first_call`` span and log line (``telemetry/costs.py``)
         that also says how the step reduces weight gradients: ``bucket``
         where blocks of the model gather their own parameters and reduce
-        their gradients by ``zero/overlap.py``'s rings (how many layers, and
-        the rings of a kind of block), else ``xla``."""
+        their gradients by ``zero/overlap.py``'s rings (how many layers, the
+        rings of a kind of block, how many of the layers gather a second time
+        in their backward, and whether the embedding and the loss head are
+        such a region too), else ``xla``."""
         shapes = leaf_signature(batch)
         if (name, shapes) in self._step_programs_seen:
             return program(*args)
         self._step_programs_seen.add((name, shapes))
-        before = [zero_overlap.traced("layers"), zero_overlap.traced("rings")]
+        counted = ("layers", "regathers", "rings", "head")
+        before = [zero_overlap.traced(what) for what in counted]
         notes = {}
         with first_call("train", name, notes):
             self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
             out = program(*args)
-            layers, rings = zero_overlap.traced("layers") - before[0], zero_overlap.traced("rings") - before[1]
-            notes.update(grad_reduce="bucket" if layers else "xla", bucket_layers=layers, bucket_rings=rings)
+            layers, regathers, rings, head = (zero_overlap.traced(what) - was for what, was in zip(counted, before))
+            notes.update(grad_reduce="bucket" if layers or head else "xla", bucket_layers=layers, bucket_rings=rings,
+                         bucket_regather=regathers, bucket_head=int(head > 0))
         return out
 
     def _count_step_flops(self, program, args):

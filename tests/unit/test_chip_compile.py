@@ -204,19 +204,17 @@ def test_census_reads_the_form_of_the_reduction_off_the_text(ops, scatters, want
 OLMO_BLOCK = 4 * 2048 * 2048 + 3 * 2048 * 8192
 
 
-@pytest.mark.parametrize("zero,taken", [({}, 2), ({"stage3_max_live_parameters": OLMO_BLOCK}, 1),
-                                        ({"overlap_comm": False}, 0)])
-def test_zero3_step_reduces_gradients_under_the_next_layer_or_in_the_matmuls_ring(topo, monkeypatch, zero, taken):
-    """The benchmark's training cell at two layers, every width as published
-    (OLMo-1B, ZeRO-3 over ``fsdp=4``, micro-batch 2 of 2048 tokens), forward
-    and backward as the engine builds them (its planners, its cast, the plan
-    of ``zero/overlap.py`` around the model's loss). Default config: no hop of
-    a block's weight-gradient matmul is left, 7 weights x 3 hops x 2 ways a
-    layer are issued by the bucket's ring, each ``dW`` is one whole matmul.
-    ``overlap_comm: false``: the partitioner's program, 21 hops a layer in
-    the matmuls' own rings (16 x 21 + 12 = the 348 of the chip's trace). A
-    bound of one block's parameters: the first layer is the plan's, the
-    second the partitioner's."""
+ZERO3 = {"default": {}, "one_block": {"stage3_max_live_parameters": OLMO_BLOCK}, "partitioner": {"overlap_comm": False}}
+
+
+@pytest.fixture(scope="module")
+def zero3_step(topo):
+    """``step(case)``: the benchmark's training cell at two layers, every
+    width as published (OLMo-1B, ZeRO-3 over ``fsdp=4``, micro-batch 2 of 2048
+    tokens), forward and backward as the engine builds them (its planners,
+    its cast, the plan of ``zero/overlap.py`` around the model's loss),
+    compiled for the four described chips once a case of ``ZERO3``: the
+    executable's text and the bytes of its temporaries."""
     from deepspeed_tpu.models import CausalLM, TransformerConfig
     from deepspeed_tpu.parallel import mesh as mesh_mod
     from deepspeed_tpu.parallel.mesh import MeshTopology
@@ -225,35 +223,65 @@ def test_zero3_step_reduces_gradients_under_the_next_layer_or_in_the_matmuls_rin
     from deepspeed_tpu.runtime.zero import overlap
     from deepspeed_tpu.runtime.zero.partition import plan_grad_specs, plan_param_specs, specs_to_shardings
 
-    model = CausalLM(TransformerConfig(vocab_size=50304, n_layers=2, n_heads=16, n_kv_heads=16, d_model=2048, d_ff=8192,
-                                       max_seq_len=2048, norm="layernorm_np", activation="swiglu", pos_emb="rope",
-                                       tie_embeddings=True, dtype=BF16))
-    monkeypatch.setattr(overlap, "_backend", lambda: "tpu")  # jax's backend here is the CPU; the devices are not
-    monkeypatch.setattr(mesh_mod, "_TOPOLOGY", MeshTopology(MeshConfig.from_dict({"fsdp": 4}), devices=list(topo.devices)))
-    mesh = mesh_mod._TOPOLOGY
-    config = DeepSpeedConfig({"train_micro_batch_size_per_gpu": 2, "bf16": {"enabled": True},
-                              "zero_optimization": dict(zero, stage=3)}, mesh_shape=mesh.axis_sizes, world_size=4)
-    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 16), np.int32)}))
-    specs = plan_param_specs(shapes, config, mesh, model.partition_rules())
-    plan = overlap.plan_for(config, mesh, specs)
-    assert (plan is not None) == (taken > 0)
-    params = jax.tree_util.tree_map(lambda s, sh: S(s.shape, s.dtype, sharding=sh), shapes,
-                                    specs_to_shardings(specs, mesh))
-    batch = {"input_ids": S((8, 2048), I32, sharding=mesh.batch_sharding())}
+    @functools.cache
+    def step(case):
+        model = CausalLM(TransformerConfig(vocab_size=50304, n_layers=2, n_heads=16, n_kv_heads=16, d_model=2048, d_ff=8192,
+                                           max_seq_len=2048, norm="layernorm_np", activation="swiglu", pos_emb="rope",
+                                           tie_embeddings=True, dtype=BF16))
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(overlap, "_backend", lambda: "tpu")  # jax's backend here is the CPU; the devices are not
+            patch.setattr(mesh_mod, "_TOPOLOGY", MeshTopology(MeshConfig.from_dict({"fsdp": 4}), devices=list(topo.devices)))
+            mesh = mesh_mod._TOPOLOGY
+            config = DeepSpeedConfig({"train_micro_batch_size_per_gpu": 2, "bf16": {"enabled": True},
+                                      "zero_optimization": dict(ZERO3[case], stage=3)}, mesh_shape=mesh.axis_sizes, world_size=4)
+            shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 16), np.int32)}))
+            specs = plan_param_specs(shapes, config, mesh, model.partition_rules())
+            plan = overlap.plan_for(config, mesh, specs)
+            assert (plan is not None) == (case != "partitioner")
+            params = jax.tree_util.tree_map(lambda s, sh: S(s.shape, s.dtype, sharding=sh), shapes,
+                                            specs_to_shardings(specs, mesh))
+            batch = {"input_ids": S((8, 2048), I32, sharding=mesh.batch_sharding())}
 
-    def loss(params32, batch):
-        with overlap.active(plan):
-            return model.loss_fn(E._cast_tree(params32, BF16), batch, None)
+            def loss(params32, batch):
+                with overlap.active(plan):
+                    return model.loss_fn(E._cast_tree(params32, BF16), batch, None)
 
-    grad_shardings = specs_to_shardings(plan_grad_specs(shapes, specs, config, mesh), mesh)
-    text = jax.jit(jax.value_and_grad(loss), out_shardings=(None, grad_shardings)).lower(params, batch).compile().as_text()
+            grad_shardings = specs_to_shardings(plan_grad_specs(shapes, specs, config, mesh), mesh)
+            compiled = jax.jit(jax.value_and_grad(loss), out_shardings=(None, grad_shardings)).lower(params, batch).compile()
+        return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+    return step
+
+
+@pytest.mark.parametrize("case", list(ZERO3))
+def test_zero3_step_reduces_gradients_under_the_next_layer_or_in_the_matmuls_ring(zero3_step, case):
+    """Under a plan, whatever the bound on live parameters: no hop of a
+    weight-gradient matmul is left anywhere in the step and no
+    reduce-scatter; 7 weights x 3 hops x 2 ways a layer are issued by the
+    bucket's ring, each ``dW`` is one whole matmul, and two more rings of 3
+    hops x 2 ways sum the look-up's partial rows and the head's activation
+    gradient to the owner of each row of the batch (``dE`` is computed where
+    it is kept). ``overlap_comm: false``: the partitioner's program, 21 hops a
+    layer in the matmuls' own rings (16 x 21 + 12 = the 348 of PR 27's
+    trace), 6 a program that start a ring with zeros, the head's activation
+    gradient a ring of 5 hops in its matmul and the embedding's gradient one
+    reduce-scatter fusion."""
+    text, _ = zero3_step(case)
     said = census(text)
     in_blocks = sum(1 for op in _PERMUTE.findall(text) if "/Block_" in op and "dot_general" in op)
-    # the plan's layers: 7 weights x 3 hops x 2 ways, and no matmul of theirs keeps a ring of its own; the partitioner's
-    # layers: 7 weights x 3 hops, and 6 a program that start a ring with zeros
-    assert said["bucket_permutes"] == 7 * 3 * 2 * taken, said
-    assert in_blocks == (21 * (2 - taken) + 6 if taken < 2 else 0), said
-    assert said["grad_reduce"] == ("bucket" if taken else "ring"), said
-    # outside the blocks both ways: the embedding's gradient is one reduce-scatter fusion, the head's activation
-    # gradient a ring of 5 hops in its matmul
-    assert said["reduce_scatters"] == 1 and said["matmul_ring_permutes"] - in_blocks == 5, said
+    if case == "partitioner":
+        assert said["grad_reduce"] == "ring" and said["bucket_permutes"] == 0, said
+        assert in_blocks == 21 * 2 + 6 and said["matmul_ring_permutes"] - in_blocks == 5 and said["reduce_scatters"] == 1, said
+    else:
+        assert said["grad_reduce"] == "bucket" and said["bucket_permutes"] == 7 * 3 * 2 * 2 + 2 * 3 * 2, said
+        assert in_blocks == 0 and said["matmul_ring_permutes"] == 0 and said["reduce_scatters"] == 0, said
+
+
+def test_a_layer_that_gathers_again_holds_less_than_one_that_keeps(zero3_step):
+    """A bound of one block's parameters: the last layer keeps its gathered
+    weights (134 MB in bf16) from its forward to its backward, the first
+    gathers them a second time there, and XLA has not merged that gather with
+    the forward's: the step's temporaries are smaller by most of those
+    weights."""
+    kept, again = zero3_step("default")[1], zero3_step("one_block")[1]
+    assert 60e6 < kept - again < 2 * OLMO_BLOCK, (kept, again)

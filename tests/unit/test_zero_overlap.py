@@ -5,6 +5,7 @@ of the compiled step. The TPU compiler's side is in ``test_chip_compile.py``.
 """
 
 import dataclasses
+import functools
 import logging
 
 import jax
@@ -86,29 +87,140 @@ def test_ring_reduce_scatter_is_a_reduce_scatter(shape, dim):
     np.testing.assert_allclose(ring, want, rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("max_live,sows,path,taken", [
-    (10**9, False, ("layer_",), [True, True, True]),
-    (2 * 48, False, ("layer_",), [True, True, False]),      # two blocks' sharded leaves fit under the bound, not three
-    (0, False, ("layer_",), [False, False, False]),
-    (10**9, True, ("layer_",), [False, False, False]),      # a block that sows is the partitioner's
-    (10**9, False, ("body", "layer_"), [False, False, False]),  # a caller's sub-tree: not the engine's paths
+@pytest.mark.parametrize("max_live,sows,path,taken,again", [
+    (10**9, False, ("layer_",), [True, True, True], [False, False, False]),
+    (2 * 48, False, ("layer_",), [True, True, True], [True, False, False]),  # two blocks' sharded leaves fit: the LAST two keep
+    (48, False, ("layer_",), [True, True, True], [True, True, False]),
+    (47, False, ("layer_",), [True, True, True], [True, True, True]),        # none fits: every block gathers twice
+    (0, False, ("layer_",), [True, True, True], [True, True, True]),         # 0 keeps nothing; no block leaves the plan for it
+    (10**9, True, ("layer_",), [False, False, False], [False, False, False]),      # a block that sows is the partitioner's
+    (10**9, False, ("body", "layer_"), [False, False, False], [False, False, False]),  # a caller's sub-tree: not the engine's paths
 ])
-def test_hook_takes_blocks_while_their_gathered_parameters_fit(max_live, sows, path, taken):
-    """A block of 48 sharded parameters and a whole one of 3: layers are
-    taken first to last while what they gather stays under
-    ``stage3_max_live_parameters``; layers of one shape of specs get ONE
-    ``wrap``, so they share a trace."""
+def test_hook_takes_blocks_while_their_gathered_parameters_fit(max_live, sows, path, taken, again):
+    """A block of 48 sharded parameters and a whole one of 3: every block
+    with a sharded leaf is taken, and the last ones keep what they gathered
+    while that stays under ``stage3_max_live_parameters``; the ones before
+    them gather a second time in their backward. Layers of one shape of
+    specs and one way with their weights get ONE ``wrap``, so they share a
+    trace."""
     topo = _topo({"fsdp": 4}, 4)
     layer = {"w": P("fsdp"), "v": P(None, "fsdp"), "b": P()}
     params = {"w": jnp.zeros((8, 4)), "v": jnp.zeros((2, 8)), "b": jnp.zeros((3,))}
     plan = overlap.GatherPlan(topo.mesh, "fsdp", {f"layer_{i}": layer for i in range(3)}, max_live)
     hook, x = overlap.BlockGather(plan), jnp.zeros((4, 2))
-    wraps = [hook(path[:-1] + (f"layer_{i}",), [params] * 3, i, sows, x)[0] for i in range(3)]
+    paths = [path[:-1] + (f"layer_{i}",) for i in range(3)]
+    before = overlap.traced("regathers")
+    wraps = [hook(paths, [params] * 3, [sows] * 3, i, x)[0] for i in range(3)]
     assert [w is not None for w in wraps] == taken
-    assert len({id(w) for w in wraps if w is not None}) <= 1
-    assert hook.live == 48 * sum(taken)
+    assert [w is not None and not w.args[2] for w in wraps] == again  # ``gathered_block``'s ``keep``
+    assert overlap.traced("regathers") - before == sum(again)
+    assert len({id(w) for w in wraps if w is not None}) == len({a for a, t in zip(again, taken) if t})
+    assert hook.live == 48 * (sum(taken) - sum(again)) <= max_live
     whole = overlap.GatherPlan(topo.mesh, "fsdp", {"layer_0": {"w": P(), "v": P(), "b": P()}}, 10**9)
-    assert overlap.BlockGather(whole)(("layer_0",), [params], 0, False, x)[0] is None  # nothing sharded: nothing to gather
+    assert overlap.BlockGather(whole)([("layer_0",)], [params], [False], 0, x)[0] is None  # nothing sharded: nothing to gather
+
+
+@pytest.mark.parametrize("paths,specs,vocab_dim,sows,taken", [
+    ((("wte",),), (P("fsdp"),), 0, False, True),
+    ((("lm_head", "kernel"), ("lm_head", "bias")), (P(None, "fsdp"), P()), 1, False, True),  # a whole bias rides along
+    ((("lm_head", "kernel"), ("lm_head", "bias")), (P("fsdp"), P()), 1, False, False),       # sharded, but not by the vocabulary
+    ((("lm_head", "kernel"), ("lm_head", "bias")), (P(None, "fsdp"), P("fsdp")), 1, False, True),        # or its own slice
+    ((("wte",),), (P("fsdp"),), 0, True, False),             # a model that sows keeps the partitioner's head
+    ((("body", "wte"),), (P("fsdp"),), 0, False, False),     # a caller's sub-tree
+    ((("wte",),), (P(),), 0, False, False),                  # nothing sharded
+])
+def test_hook_takes_the_head_where_its_weight_is_sharded_by_the_vocabulary(paths, specs, vocab_dim, sows, taken):
+    """``head``: every device computes the whole batch's loss over its slice
+    of the vocabulary; the summed shares are the loss's sum and the count,
+    and the gradients those of the plain head, with no gather of the
+    weight."""
+    from deepspeed_tpu.models.transformer import _head_sums
+
+    topo = _topo({"fsdp": 4}, 4)
+    tree = {"wte": specs[0], "lm_head": {"kernel": specs[0], "bias": specs[-1]}}
+    hook = overlap.BlockGather(overlap.GatherPlan(topo.mesh, "fsdp", tree, 0))
+    tied = vocab_dim == 0
+    fn = functools.partial(_head_sums, dtype=jnp.float32, vd_layout=tied)
+    w = jax.random.normal(jax.random.PRNGKey(0), (32, 8) if tied else (8, 32), jnp.float32)
+    leaves = (w,) if len(paths) == 1 else (w, jax.random.normal(jax.random.PRNGKey(1), (32,), jnp.float32))
+    hidden = jax.random.normal(jax.random.PRNGKey(2), (8, 6, 8), jnp.float32)
+    labels = jax.random.randint(jax.random.PRNGKey(3), (8, 6), 0, 32).at[:, -1].set(-100)
+    before = overlap.traced("head")
+    if not taken:
+        assert hook.head(paths, leaves, fn, vocab_dim, sows) is None and overlap.traced("head") == before
+        return
+
+    def loss(run, leaves, hidden):
+        total, count = run(leaves, hidden, labels)
+        return jnp.sum(total) / jnp.maximum(jnp.sum(count), 1)
+
+    mine = jax.jit(jax.value_and_grad(lambda l, h: loss(lambda l, h, y: hook.head(paths, l, fn, vocab_dim, sows)(h, y), l, h),
+                                      argnums=(0, 1)))(leaves, hidden)
+    want = jax.value_and_grad(functools.partial(loss, fn), argnums=(0, 1))(leaves, hidden)
+    assert overlap.traced("head") - before == 1 and hook.live == 0
+    for got, plain in zip(jax.tree_util.tree_leaves(mine), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(got, plain, rtol=2e-5, atol=1e-6)
+    text = str(jax.make_jaxpr(jax.grad(lambda l, h: loss(lambda l, h, y: hook.head(paths, l, fn, vocab_dim, sows)(h, y), l, h),
+                                       argnums=(0, 1)))(leaves, hidden))
+    assert text.count(" all_gather[") == 2 and "ppermute" in text  # activations and labels: the weight stays where it is
+
+
+@pytest.mark.parametrize("spec,sows,path,taken", [
+    (P("fsdp"), False, ("wte",), True),
+    (P("fsdp"), True, ("wte",), False),          # a model that sows keeps the partitioner's look-up
+    (P("fsdp"), False, ("body", "wte"), False),  # a caller's sub-tree
+    (P(None, "fsdp"), False, ("wte",), False),   # not sharded by rows
+    (P(), False, ("wte",), False),
+])
+def test_hook_looks_tokens_up_in_a_table_sharded_by_rows(spec, sows, path, taken):
+    """``look_up``: each device finds the batch's ids that fall into its
+    rows and the ring sums the partial results to the device that owns the
+    row of the batch: ``table[ids]``, and its gradient, with no gather of
+    the table."""
+    topo = _topo({"fsdp": 4}, 4)
+    hook = overlap.BlockGather(overlap.GatherPlan(topo.mesh, "fsdp", {"wte": spec}, 0))
+    table = jax.random.normal(jax.random.PRNGKey(0), (32, 8), jnp.float32)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (8, 5), 0, 32)
+    if not taken:
+        assert hook.look_up(path, table, ids, sows) is None
+        return
+    mine = lambda t: jnp.sum(jnp.sin(hook.look_up(path, t, ids, sows)))
+    np.testing.assert_array_equal(jax.jit(lambda t: hook.look_up(path, t, ids, sows))(table), table[ids])
+    np.testing.assert_allclose(jax.jit(jax.grad(mine))(table), jax.grad(lambda t: jnp.sum(jnp.sin(t[ids])))(table), rtol=1e-6)
+    text = str(jax.make_jaxpr(jax.grad(mine))(table))
+    assert "f32[32,8] = all_gather" not in text and "ppermute" in text  # the table stays where it is
+
+
+@pytest.mark.parametrize("shape,dim", [((8, 6), 0), ((6, 16), 1)])
+def test_a_block_that_gathers_twice_computes_what_one_that_keeps_does(shape, dim):
+    """``_regathering``: the same value and the same gradient as the keeping
+    gather and as ``all_gather`` / ``psum_scatter``; what its forward keeps
+    for its backward is the shard, not the gathered weight, and its backward
+    gathers again."""
+    mesh = _topo({"fsdp": 4}, 4).mesh
+    spec = P(*([None] * dim + ["fsdp"]))
+    w = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, shape[0]), jnp.float32)
+
+    body = lambda w, x: jnp.sum(jnp.tanh(x @ w) ** 2, axis=1)
+
+    def loss(local):
+        mapped = jax.shard_map(local, mesh=mesh, in_specs=(spec, P("fsdp")), out_specs=P("fsdp"), check_vma=False)
+        return lambda w, x: jnp.sum(mapped(w, x))
+
+    plain = loss(lambda w, x: body(jax.lax.all_gather(w, "fsdp", axis=dim, tiled=True), x))
+    want = jax.jit(jax.value_and_grad(plain, argnums=(0, 1)))(w, x)
+    for again in (False, True):
+        keeping = lambda w, x: body(overlap._gather("fsdp", 4, dim)(w), x)
+        fn = loss(overlap._regathering(body, dim, "fsdp", 4) if again else keeping)
+        got = jax.jit(jax.value_and_grad(fn, argnums=(0, 1)))(w, x)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+        for g, g_want in zip(got[1], want[1]):
+            np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-6)
+        text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1)))(w, x))  # the activation's gradient is what reads the weight
+        assert text.count(" all_gather[") == (2 if again else 1) and ("optimization_barrier" in text) == again
+        # what passes from the forward's region to the backward's, stacked over the four devices: the whole weight or not
+        assert (f"f32[{4 * shape[0]},{shape[1]}]" in text) != again
 
 
 def test_tie_is_the_identity_both_ways():
@@ -120,8 +232,8 @@ def test_tie_is_the_identity_both_ways():
     assert "optimization_barrier" in str(jax.make_jaxpr(lambda x, l: vjp((x, l)))(x, later))
 
 
-def _engine(overlap_comm, gas, layers, remat, **zero):
-    cfg = dataclasses.replace(gpt2_tiny(), vocab_size=512, n_layers=layers, remat=remat)
+def _engine(overlap_comm, gas, layers, remat, model=None, **zero):
+    cfg = dataclasses.replace(gpt2_tiny(), vocab_size=512, n_layers=layers, remat=remat, **(model or {}))
     model = CausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 32), np.int32)})
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, config={
@@ -131,8 +243,8 @@ def _engine(overlap_comm, gas, layers, remat, **zero):
     return engine
 
 
-def _losses(overlap_comm, gas, layers=3, remat=False, **zero):
-    engine = _engine(overlap_comm, gas, layers, remat, **zero)
+def _losses(overlap_comm, gas, layers=3, remat=False, model=None, **zero):
+    engine = _engine(overlap_comm, gas, layers, remat, model, **zero)
     rng = np.random.default_rng(0)
     it = iter([{"input_ids": rng.integers(0, 512, (2 * N, 32)).astype(np.int32)} for _ in range(3 * gas)])
     out = []
@@ -142,20 +254,29 @@ def _losses(overlap_comm, gas, layers=3, remat=False, **zero):
     return np.array(out)
 
 
-@pytest.mark.parametrize("gas,remat,max_live", [(1, False, 10**9), (2, False, 10**9), (1, True, 10**9),
-                                                 (1, False, 2 * BLOCK_PARAMS), (2, True, BLOCK_PARAMS)])
-def test_engine_with_the_plan_trains_as_without(on_tpu, gas, remat, max_live):
+UNTIED = {"tie_embeddings": False, "lm_head_bias": True}  # its own ``lm_head.kernel`` (d x V), and a bias that stays whole
+
+
+@pytest.mark.parametrize("gas,remat,max_live,model", [
+    (1, False, 10**9, None), (2, False, 10**9, None), (1, True, 10**9, None),
+    (1, False, 2 * BLOCK_PARAMS, None), (2, True, BLOCK_PARAMS, None),
+    (1, False, 0, None),                      # nothing kept: every block gathers twice
+    (1, False, 10**9, UNTIED), (2, False, BLOCK_PARAMS, UNTIED),
+])
+def test_engine_with_the_plan_trains_as_without(on_tpu, gas, remat, max_live, model):
     """ZeRO-3 over the devices, fused step (gas 1) and accumulation (gas 2),
-    a rematerialized block (which gathers again in its backward), and a
-    bound that leaves the last one or two of three layers to the
-    partitioner: the block that gathers its own parameters and reduces their
-    gradients round a ring gives the partitioner's three losses and gradient
-    norms."""
-    laid = overlap.traced("layers")
-    with_plan = _losses(True, gas, remat=remat, stage3_max_live_parameters=max_live)
-    # the plan was live: so many blocks gathered for themselves in each of the step's programs
-    assert overlap.traced("layers") - laid == min(3, max_live // BLOCK_PARAMS)
-    without = _losses(False, gas, remat=remat)
+    a rematerialized block (which gathers again in its backward), a bound
+    under which the first one, two or all of three layers gather a second
+    time, and a tied and an untied head: blocks, look-ups and head that
+    gather their own parameters and reduce their gradients round a ring give
+    the partitioner's three losses and gradient norms."""
+    laid = [overlap.traced(what) for what in ("layers", "regathers", "head")]
+    with_plan = _losses(True, gas, remat=remat, model=model, stage3_max_live_parameters=max_live)
+    # the plan was live: every block gathered for itself in each of the step's programs, and so did the head, beside the
+    # token look-up; as many blocks as do not fit under the bound gathered twice
+    assert [overlap.traced(what) - was for what, was in zip(("layers", "regathers", "head"), laid)] == \
+        [3, 3 - min(3, max_live // BLOCK_PARAMS), 2]
+    without = _losses(False, gas, remat=remat, model=model)
     np.testing.assert_allclose(with_plan[:, 0], without[:, 0], rtol=1e-6)
     np.testing.assert_allclose(with_plan[:, 1], without[:, 1], rtol=1e-5)
 
@@ -165,21 +286,21 @@ def test_off_tpu_overlap_comm_changes_nothing():
     assert (_losses(True, 1, layers=2) == _losses(False, 1, layers=2)).all()
 
 
-def test_the_plan_reaches_the_model_and_is_traced_once_a_kind(on_tpu):
-    """Three layers of one kind: the block's body runs once under the plan
-    too, and its jaxpr has the gathers and the ring's hops."""
+def _traced_grads(cfg, nest=False):
+    """The jaxpr of the loss's gradient under the plan of a ZeRO-3 engine
+    over four devices, and how often a block's body ran for it. ``nest``: the
+    engine's tree holds the model's one level down, as for a caller that
+    applies a sub-tree."""
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.runtime.zero.partition import plan_param_specs
     from deepspeed_tpu.utils.compile_cache import block_traces
 
-    cfg = dataclasses.replace(gpt2_tiny(), vocab_size=512, n_layers=3)
     model = CausalLM(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 32), np.int32)}))
     topo = _topo({"fsdp": 4}, 4)
-    from deepspeed_tpu.parallel import mesh as mesh_mod
-    from deepspeed_tpu.runtime.zero.partition import plan_param_specs
-
     config = _config(SHARD_ALL, {"fsdp": 4})
     specs = plan_param_specs(params, config, topo, model.partition_rules())
-    plan = overlap.plan_for(config, topo, specs)
+    plan = overlap.plan_for(config, topo, {"body": specs} if nest else specs)
     batch = {"input_ids": jnp.zeros((8, 32), jnp.int32)}
 
     def grads(p):
@@ -193,10 +314,32 @@ def test_the_plan_reaches_the_model_and_is_traced_once_a_kind(on_tpu):
         text = str(jax.make_jaxpr(grads)(params))
     finally:
         mesh_mod._TOPOLOGY = prev
-    assert block_traces() - before == 1
-    assert text.count("all_gather") >= 3 and "ppermute" in text and "optimization_barrier" in text
+    return text, block_traces() - before
+
+
+def test_the_plan_reaches_the_model_and_is_traced_once_a_kind(on_tpu):
+    """Three layers of one kind: the block's body runs once under the plan
+    too, and its jaxpr has the gathers (three blocks', the head's, the
+    look-up's of the batch's ids) and the ring's hops."""
+    text, traces = _traced_grads(dataclasses.replace(gpt2_tiny(), vocab_size=512, n_layers=3))
+    assert traces == 1
+    assert text.count(" all_gather[") >= 5 and "ppermute" in text and "optimization_barrier" in text
     from deepspeed_tpu.models import transformer
     assert transformer._BLOCK_HOOK.get() is None  # the hook does not outlive the trace
+
+
+@pytest.mark.parametrize("model,nest", [
+    ({"moe_num_experts": 4, "moe_layer_freq": 1}, False),  # every block sows, and the loss has a term the head cannot carry
+    ({}, True),                                            # the engine's tree is not the model's
+])
+def test_a_model_that_sows_or_is_a_sub_tree_stays_the_partitioners(on_tpu, model, nest):
+    """Blocks, look-ups and head alike: nothing of the model is a manual
+    region, nothing is counted."""
+    counted = ("layers", "regathers", "rings", "head")
+    before = [overlap.traced(what) for what in counted]
+    text, _ = _traced_grads(dataclasses.replace(gpt2_tiny(), vocab_size=512, n_layers=2, **model), nest)
+    assert "shard_map" not in text and "ppermute" not in text
+    assert [overlap.traced(what) for what in counted] == before
 
 
 class _Lines(logging.Handler):
@@ -208,17 +351,21 @@ class _Lines(logging.Handler):
         self.lines.append(record.getMessage())
 
 
-@pytest.mark.parametrize("told,max_live,form,layers,rings,traces", [
-    ("tpu", 10**9, "bucket", 2, 16, 1),
-    ("tpu", BLOCK_PARAMS, "bucket", 1, 16, 2),  # the bound holds one block: the second is the partitioner's, a trace of its own
-    ("tpu", BLOCK_PARAMS - 1, "xla", 0, 0, 1),
-    ("cpu", 10**9, "xla", 0, 0, 1),
+@pytest.mark.parametrize("told,max_live,form,layers,rings,traces,again,head", [
+    ("tpu", 10**9, "bucket", 2, 16, 1, 0, 1),
+    # the bound holds one block: the first gathers twice, a kind of its own, traced for its value and for its backward
+    ("tpu", BLOCK_PARAMS, "bucket", 2, 32, 3, 1, 1),
+    ("tpu", BLOCK_PARAMS - 1, "bucket", 2, 16, 2, 2, 1),
+    ("tpu", 0, "bucket", 2, 16, 2, 2, 1),             # 0 keeps nothing gathered; the plan stands
+    ("cpu", 10**9, "xla", 0, 0, 1, 0, 0),
 ])
-def test_trainer_first_call_says_how_the_step_reduces_gradients(monkeypatch, told, max_live, form, layers, rings, traces):
+def test_trainer_first_call_says_how_the_step_reduces_gradients(monkeypatch, told, max_live, form, layers, rings, traces,
+                                                                again, head):
     """One ``program/first_call`` span and line a step program and batch
-    shape, with the block's traces, the layers that gather for themselves
-    and the rings laid into them: one a sharded leaf of the ONE kind of
-    block (gpt2's sixteen), whatever the depth."""
+    shape, with the block's traces, the layers that gather for themselves,
+    those of them that gather a second time in their backward, the rings
+    laid into them (one a sharded leaf of a KIND of block, gpt2's sixteen,
+    whatever the depth) and whether look-ups and head are the plan's too."""
     monkeypatch.setattr(overlap, "_backend", lambda: told)
     from deepspeed_tpu.telemetry import get_tracer
 
@@ -234,10 +381,11 @@ def test_trainer_first_call_says_how_the_step_reduces_gradients(monkeypatch, tol
     lines = [l for l in handler.lines if l.startswith("program first call: family=train")]
     assert len(lines) == 1, lines
     assert "bucket=fused_step" in lines[0] and f"block_traces={traces}" in lines[0]
-    assert lines[0].endswith(f"grad_reduce={form} bucket_layers={layers} bucket_rings={rings}")
+    assert lines[0].endswith(f"grad_reduce={form} bucket_layers={layers} bucket_rings={rings} bucket_regather={again} "
+                             f"bucket_head={head}")
     span = [s for s in tracer.spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
-    assert [span["attrs"][k] for k in ("grad_reduce", "bucket_layers", "bucket_rings", "block_traces")] == \
-        [form, layers, rings, traces]
+    assert [span["attrs"][k] for k in ("grad_reduce", "bucket_layers", "bucket_rings", "bucket_regather", "bucket_head",
+                                       "block_traces")] == [form, layers, rings, again, head, traces]
 
 
 @pytest.mark.parametrize("max_live", [10**9, BLOCK_PARAMS])
