@@ -1,0 +1,34 @@
+"""The latent-attention layer's attention kernels' share of their roofline:
+the least time for causal attention with keys of ``qk_nope_head_dim +
+qk_rope_head_dim`` and values of ``v_head_dim`` (``mla_attention_cost`` of the
+configuration's own FLOP module, forward and backward of every such layer of
+the steps in the traced stretch) over the device time of the Mosaic calls
+that carry an operand or a result of the key head size, which no other kernel
+of the step has: the forward's result is ``[heads, S, v]`` beside its
+``[heads, S / block, 1, block]`` row statistics, the backward's are dq and dk
+of ``[heads, S, qk]``. None where nothing matches."""
+
+from benchmarks.lib import flops, kernel_time
+from benchmarks.lib.peaks import peaks_for
+
+UNIT, BETTER, SOURCE = "%", "higher", "device_trace"
+LAYER = "kernels (ops/pallas/flash_attention.py)"
+MOVES = "train_tokens_per_s"
+
+
+def read(record):
+    cost = getattr(flops.for_config(record.get("config")), "mla_attention_cost", None)
+    if cost is None or not record.get("reduced"):
+        return None
+    m, t = record["published"], record["train"]
+    rows, seq = t["micro_batch"] * m["num_attention_heads"], t["seq_len"]
+    qk, v = m["qk_nope_head_dim"] + m["qk_rope_head_dim"], m["v_head_dim"]
+    forward = rf"\(bf16\[{rows},{seq},{v}\][^ ]*, f32\[{rows},[0-9]+,1,[0-9]+\]"  # o and the row statistics
+    backward = rf"bf16\[{rows},{seq},{qk}\]"                                       # dq, dk
+    steps, took = kernel_time.steps_and_seconds(record["reduced"], rf"custom-call .*({forward}|{backward}).*tpu_custom_call")
+    if not took:
+        return None
+    peaks = peaks_for(record["device"]["kind"])
+    layers = len(m["linear_attn_config"]["full_attn_layers"])
+    need = sum(flops.roofline_seconds(cost(m, t["micro_batch"], seq, backward=b), peaks)["seconds"] for b in (False, True))
+    return 100.0 * steps * layers * need / took

@@ -131,6 +131,12 @@ class InferenceEngineV2:
             config.min_decode_bucket = max(1, knobs.get_int("DS_TPU_MIN_DECODE_BUCKET"))
         self.model = model
         cfg: TransformerConfig = model.cfg
+        if not cfg.softmax_only:
+            kinds = sorted({k for pair in cfg.kinds for k in pair} - {"full", "window", "dense", "moe"})
+            raise NotImplementedError(
+                f"inference/v2 serves softmax attention over one head size with dense or capacity-gated MoE FFNs; this "
+                f"model has layers of kind {kinds}: a recurrent state beside the paged KV (kda), a latent cache (mla) and "
+                f"the routed FFN's gate are training-side only")
         self.cfg = cfg
         self.dtype = jnp.bfloat16 if config.dtype in ("bfloat16", "bf16") else jnp.float32
 

@@ -1,0 +1,117 @@
+"""Token mixers beside softmax attention over one head size: a gated
+delta-rule linear-attention layer (KDA) and latent attention without
+positions (MLA, NoPE). Both are training-side modules: a block built from
+them takes no KV cache (``inference/v2`` refuses these kinds by name).
+"""
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.attention import attention
+from ..ops.kda import kda
+from ..ops.registry import pallas_available
+from .transformer import RMSNorm, TransformerConfig
+
+
+def _uniform(low, high):
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(key, shape, dtype, low, high)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus^-1 of a step drawn log-uniformly from [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, np.log(1e-3), np.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def causal_conv(x, w, axis: int = 1):
+    """Depthwise causal convolution over the sequence (``axis`` of x): w
+    (K, ...) one filter a channel, broadcast against x without its leading
+    dimension; y_t = sum_j w_j x_{t - (K - 1) + j}."""
+    K, S = w.shape[0], x.shape[axis]
+    padded = jnp.pad(x, [(K - 1, 0) if d == axis else (0, 0) for d in range(x.ndim)])
+    return sum(jax.lax.slice_in_dim(padded, j, j + S, axis=axis) * w[j] for j in range(K))
+
+
+def l2_normalize(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention: per head, ``S_t = (I - beta_t k_t k_t^T)
+    Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``, with q, k
+    L2-normalised after a depthwise causal convolution and SiLU, a
+    per-channel decay ``alpha_t = exp(-exp(A_log) softplus(W_f2 W_f1 x +
+    dt_bias))`` and an output gate; state and gates in float32
+    (``ops/kda.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        H, D, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+        f32 = jnp.float32
+        heads = lambda name: nn.DenseGeneral((H, D), use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)
+        exact = lambda dtype: jax.lax.Precision.HIGHEST if dtype == f32 else None  # the float32 gates take no bf16 pass
+        low_rank = lambda name, dtype: nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_b", dtype=dtype, param_dtype=f32,
+                                                       precision=exact(dtype))(
+            nn.Dense(rank, use_bias=False, name=f"{name}_a", dtype=dtype, param_dtype=f32, precision=exact(dtype))(x.astype(dtype)))
+
+        heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
+
+        def conv_silu(name):
+            w = self.param(f"{name}_conv", _uniform(-cfg.kda_conv_size**-0.5, cfg.kda_conv_size**-0.5),
+                           (cfg.kda_conv_size, H, D), f32)
+            return nn.silu(causal_conv(heads_first(heads(f"{name}_proj")(x)), w.astype(cfg.dtype)[:, :, None, :], axis=2))
+
+        q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
+        k = l2_normalize(conv_silu("k")).astype(cfg.dtype)
+        v = conv_silu("v")
+        a_log = self.param("A_log", _a_log_init, (H,), f32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), f32)
+        g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
+        beta = jax.nn.sigmoid(nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)))
+        o = kda(q, k, v, g, jnp.swapaxes(beta, 1, 2))  # (B, H, S, D)
+        o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * jax.nn.sigmoid(heads_first(low_rank("g", cfg.dtype)))
+        o = heads_first(o)
+        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                               param_dtype=f32)(o)
+
+
+class MLAMixer(nn.Module):
+    """Latent attention with no positions: keys and values are expanded from
+    a latent of ``mla_kv_rank`` (no absorption: this is the training form);
+    a head's query and key are ``mla_qk_nope_dim + mla_qk_rope_dim`` wide, the
+    second part of the key ONE head shared by all and, as nothing rotates
+    either part, simply more key; values are ``mla_v_dim`` wide. The shared
+    part is broadcast into every head's key, so the attention kernel sees one
+    product of 192 beside values of 128, unpadded (``ops/pallas/flash_attention.py``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, dn, dr, dv = cfg.n_heads, cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim, cfg.mla_v_dim
+        f32 = jnp.float32
+        q = nn.DenseGeneral((H, dn + dr), use_bias=False, name="q_proj", dtype=cfg.dtype, param_dtype=f32)(x)
+        latent = nn.Dense(cfg.mla_kv_rank + dr, use_bias=False, name="kv_a_proj", dtype=cfg.dtype, param_dtype=f32)(x)
+        c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
+        c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
+        kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
+        if not pallas_available():
+            from ..telemetry.registry import get_registry
+
+            get_registry().counter("mla_attention_traced_total", **{"pass": "fwd", "path": "xla"}).inc()
+        o = attention(q, k, kv[..., dn:], causal=True, scale=(dn + dr)**-0.5)
+        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                               param_dtype=f32)(o)

@@ -94,6 +94,31 @@ class TransformerConfig:
     moe_layer_freq: int = 2  # every Nth block is MoE
     moe_aux_loss_coef: float = 0.01
     moe_min_capacity: int = 4
+    # THE per-layer specification: one (mixer, ffn) pair a layer. mixer: full | window (``sliding_window``) |
+    # kda (gated delta-rule linear attention) | mla (latent attention, no positions); ffn: dense | moe (the
+    # softmax gate with a capacity above) | routed (sigmoid scores, no capacity, a shared expert). None: the
+    # pairs that ``window_layers`` and ``moe_layer_freq`` describe (``kinds``)
+    layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
+    kda_heads: int = 0  # kda: heads of ``kda_head_dim`` keys and values, a depthwise causal convolution of
+    kda_head_dim: int = 128  # ``kda_conv_size`` on q, k and v, gates through ``kda_gate_rank``
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 128
+    mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (nothing is rotated), v of its own
+    mla_qk_nope_dim: int = 128
+    mla_qk_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # routed: ``moe_num_experts`` router outputs, ``moe_top_k`` a token, experts ``moe_d_ff`` wide
+    moe_d_ff: Optional[int] = None  # None: ``ffn_dim``
+    moe_shared_d_ff: int = 0  # width of the shared expert every token also takes; 0: none
+    moe_route_scale: float = 1.0  # the renormalised weights of a token's experts are multiplied by this
+    moe_held: Optional[Tuple[int, int]] = None  # (first, count): the experts THIS program holds; None: all
+
+    @property
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of every layer: ``layer_kinds``, or what the older
+        fields say: a window on the layers ``window_layers`` lists (all of
+        them where it is None), a MoE every ``moe_layer_freq``-th block."""
+        return _kinds_of(self)
 
     @property
     def kv_heads(self) -> int:
@@ -127,24 +152,24 @@ class TransformerConfig:
         return self.use_dense_bias if self.attn_out_bias is None else self.attn_out_bias
 
     def window_for(self, layer_idx: int) -> Optional[int]:
-        """Sliding-window width for one layer (None = full attention)."""
-        if self.sliding_window is None:
-            return None
-        if self.window_layers is None:
-            return self.sliding_window
-        return self.sliding_window if layer_idx in self.window_layers else None
+        """Sliding-window width for one layer (None = no window): a reading of ``kinds``."""
+        return self.sliding_window if self.kinds[layer_idx][0] == "window" else None
 
     def moe_for(self, layer_idx: int) -> bool:
-        """Whether one layer's MLP slot is a MoE layer (every ``moe_layer_freq``-th block)."""
-        freq = max(1, self.moe_layer_freq)
-        return self.moe_num_experts > 0 and layer_idx % freq == freq - 1
+        """Whether one layer's FFN slot holds experts: a reading of ``kinds``."""
+        return self.kinds[layer_idx][1] != "dense"
 
     @property
     def uniform_window(self) -> bool:
         """True when every layer shares one window config (scan/v2-servable)."""
-        if self.sliding_window is None or self.window_layers is None:
-            return True
-        return set(self.window_layers) in (set(), set(range(self.n_layers)))
+        return len({self.window_for(i) for i in range(self.n_layers)}) <= 1
+
+    @property
+    def softmax_only(self) -> bool:
+        """Every mixer is softmax attention over one head size and every FFN
+        dense or the capacity-gated MoE: what the scan over layers, the
+        pipeline's stacking and ``inference/v2`` can run."""
+        return all(m in ("full", "window") and f in ("dense", "moe") for m, f in self.kinds)
 
     @property
     def rotary_dim(self) -> int:
@@ -152,6 +177,28 @@ class TransformerConfig:
         if self.rotary_dims is not None:
             return self.rotary_dims
         return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)
+
+
+MIXERS = ("full", "window", "kda", "mla")
+FFNS = ("dense", "moe", "routed")
+
+
+@functools.lru_cache(maxsize=256)
+def _kinds_of(cfg: TransformerConfig) -> Tuple[Tuple[str, str], ...]:
+    """``TransformerConfig.kinds``, worked out once a configuration (it is frozen and hashable; nothing is kept on
+    the instance, whose ``__dict__`` callers copy into new configurations)."""
+    if cfg.layer_kinds is not None:
+        kinds = tuple((str(m), str(f)) for m, f in cfg.layer_kinds)
+        bad = [k for k in kinds if k[0] not in MIXERS or k[1] not in FFNS]
+        if len(kinds) != cfg.n_layers or bad:
+            raise ValueError(f"layer_kinds must give n_layers={cfg.n_layers} pairs of {MIXERS} x {FFNS}, got "
+                             f"{len(kinds)} with {bad}")
+        return kinds
+    freq = max(1, cfg.moe_layer_freq)
+    windowed = lambda i: cfg.sliding_window is not None and (cfg.window_layers is None or i in cfg.window_layers)
+    return tuple(("window" if windowed(i) else "full",
+                  "moe" if cfg.moe_num_experts > 0 and i % freq == freq - 1 else "dense")
+                 for i in range(cfg.n_layers))
 
 
 # -------------------- layers --------------------
@@ -382,29 +429,53 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     """One transformer block. Its fields are all a trace of it can depend on:
-    a layer's place in the stack enters only through ``window``
-    (``cfg.window_for(i)``) and ``moe`` (``cfg.moe_for(i)``), so layers of one
-    kind share one traced function (``block_fn``)."""
+    a layer's place in the stack enters only through ``kind``
+    (``cfg.kinds[i]``: its mixer and its FFN), so layers of one kind share
+    one traced function (``block_fn``)."""
 
     cfg: TransformerConfig
-    window: Optional[int] = None
-    moe: bool = False
+    kind: Tuple[str, str] = ("full", "dense")
     is_training: bool = True  # static: MoE capacity-drop is train-only
 
+    @property
+    def moe(self) -> bool:
+        return self.kind[1] != "dense"
+
     def _mlp(self, cfg, h):
-        if self.moe:
+        if self.kind[1] == "moe":
             from ..moe.layer import MoE
 
             return MoE(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k,
                        capacity_factor=cfg.moe_capacity_factor, min_capacity=cfg.moe_min_capacity,
                        d_ff=cfg.ffn_dim, activation=cfg.activation, dtype=cfg.dtype,
                        name="moe")(h, train=self.is_training)
+        if self.kind[1] == "routed":
+            from ..moe.layer import RoutedMoE
+
+            return RoutedMoE(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k,
+                             d_ff=cfg.moe_d_ff or cfg.ffn_dim, held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff,
+                             scale=cfg.moe_route_scale, dtype=cfg.dtype, name="routed")(h)
         return MLP(cfg, name="mlp")(h)
+
+    def _mixer(self, cfg):
+        """The layer's token mixer as ``fn(h, positions, kv_cache, segment_ids)``."""
+        if self.kind[0] in ("kda", "mla"):
+            from .mixers import KDAMixer, MLAMixer
+
+            mixer = KDAMixer(cfg, name="kda") if self.kind[0] == "kda" else MLAMixer(cfg, name="mla")
+
+            def run(h, positions, kv_cache, segment_ids):
+                if kv_cache is not None or segment_ids is not None:
+                    raise NotImplementedError(f"a {self.kind[0]} layer takes no KV cache and no packed segments yet")
+                return mixer(h)
+
+            return run
+        return Attention(cfg, window=cfg.sliding_window if self.kind[0] == "window" else None, name="attn")
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, segment_ids=None):
         cfg = self.cfg
-        attn = Attention(cfg, window=self.window, name="attn")
+        attn = self._mixer(cfg)
 
         def run_attn(h):
             if kv_cache is not None:
@@ -443,8 +514,8 @@ class Transformer(nn.Module):
         train = (kv_caches is None) if train is None else bool(train)
         if pld_theta is not None and cfg.scan_layers:
             raise ValueError("progressive layer drop needs the unrolled layer loop: set scan_layers=False")
-        if cfg.scan_layers and not cfg.uniform_window:
-            raise ValueError("per-layer window_layers needs heterogeneous blocks: set scan_layers=False")
+        if cfg.scan_layers and len({mixer for mixer, _ in cfg.kinds}) > 1:
+            raise ValueError("per-layer window_layers (layers of several mixers) needs heterogeneous blocks: set scan_layers=False")
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -479,16 +550,16 @@ class Transformer(nn.Module):
             paths = [self.path + (f"layer_{i}",) for i in range(cfg.n_layers)]
             may_sow = [cfg.moe_for(i) for i in range(cfg.n_layers)]
             for i in range(cfg.n_layers):
-                kind = (cfg.window_for(i), cfg.moe_for(i))
+                kind = cfg.kinds[i]
                 kv_cache = kv_caches[i] if kv_caches is not None else None
                 if self.is_initializing():  # makes the tree: flax has to see every layer as a submodule
-                    y = Block(cfg, *kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids)
+                    y = Block(cfg, kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids)
                     y, cache = y if kv_caches is not None else (y, None)
                 else:
                     wrap = None
                     if hook is not None:
                         wrap, x = hook(paths, layers, may_sow, i, x)
-                    (y, cache), sown = kinds(*kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids)
+                    (y, cache), sown = kinds(kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids)
                     for col, tree in sown.items():  # what the block sowed (MoE auxiliary loss), where it was
                         if self.is_mutable_collection(col):
                             self.put_variable(col, f"layer_{i}", tree)
@@ -540,7 +611,7 @@ class Transformer(nn.Module):
 
             @nn.compact
             def __call__(self, carry, _):
-                y = block_cls(self.cfg, self.cfg.window_for(0), self.cfg.moe_for(0), is_training=train,
+                y = block_cls(self.cfg, self.cfg.kinds[0], is_training=train,
                               name="block")(carry, positions, None, segment_ids)
                 return y, None
 
@@ -585,7 +656,7 @@ def block_hook(hook):
         _BLOCK_HOOK.reset(token)
 
 
-def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bool, remat: bool, wrap=None):
+def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: bool, wrap=None):
     """One kind of block as ONE traced function of (the layer's parameters,
     activations, positions, its KV cache, segment ids) ->
     ((activations, new cache), what the block sowed).
@@ -610,7 +681,7 @@ def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bo
     what it sows goes out. ``wrap`` takes the function and returns one of
     the same signature that runs the block some other way (``block_hook``);
     it is traced once a kind all the same."""
-    block = Block(cfg, window, moe, is_training=train)
+    block = Block(cfg, kind, is_training=train)
 
     def apply(params, x, positions, kv_cache, segment_ids):
         count_block_trace("train")  # the Python body: once a trace, not once a call
@@ -618,7 +689,17 @@ def block_fn(cfg: TransformerConfig, window: Optional[int], moe: bool, train: bo
         return (out if kv_cache is not None else (out, None)), sown
 
     fn = wrap(apply) if wrap is not None else apply
-    return jax.jit(jax.checkpoint(fn) if remat else fn, inline=True)
+    if remat and (kind[0] in ("kda", "mla") or kind[1] == "routed"):
+        # a hybrid block recomputes everything but what is named as too dear to make twice: the KDA scan's outputs, a
+        # latent-attention call's output and row statistics, a routed layer's sorted rows and grouped products
+        from ..moe.sharded_moe import SAVED as routed_rows
+        from ..ops.kda import SAVED as kda_scan
+        from ..ops.pallas.flash_attention import SAVED as flash_out
+
+        fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(kda_scan, routed_rows, flash_out))
+    elif remat:
+        fn = jax.checkpoint(fn)
+    return jax.jit(fn, inline=True)
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarray:
@@ -689,6 +770,10 @@ class CausalLM:
                                              mutable=_SOWN, **extra)
             aux_leaves = jax.tree_util.tree_leaves(mods.get("losses", {}))
             aux = sum(jnp.sum(l) for l in aux_leaves) if aux_leaves else 0.0
+            if any(ffn == "routed" for _, ffn in cfg.kinds):
+                from ..moe.layer import report_rows
+
+                report_rows(mods.get("intermediates", {}))  # the routed layers' rows: an output of the step
         else:
             hidden = self.apply(params, input_ids, return_hidden=True, **extra)
             aux = 0.0
@@ -732,6 +817,9 @@ class CausalLM:
             raise ValueError(f"n_layers={cfg.n_layers} must divide evenly into {num_stages} pipeline stages")
         if cfg.scan_layers:
             raise ValueError("disable scan_layers for pipeline (stages are stacked instead)")
+        if not cfg.softmax_only:
+            raise NotImplementedError("kda, mla and routed layers are not pipeline-partitionable yet: the stages' stacking "
+                                      "takes softmax attention and dense or capacity-gated MoE blocks")
         if cfg.mlm_head or cfg.type_vocab_size > 0:
             raise NotImplementedError("BERT-style models (mlm_head / token-type embeddings) are not "
                                       "pipeline-partitionable (the MLM head and segment embeddings are "
@@ -744,7 +832,7 @@ class CausalLM:
         # across stages s. MoE (every moe_layer_freq-th block, reference
         # moe/layer.py:90 under pipe/module.py:86) aligns iff
         # layers_per_stage % moe_layer_freq == 0.
-        if cfg.moe_num_experts > 0:
+        if cfg.moe_num_experts > 0 and cfg.layer_kinds is None:
             freq = max(1, cfg.moe_layer_freq)
             if layers_per_stage % freq != 0:
                 raise ValueError(
@@ -755,14 +843,17 @@ class CausalLM:
         # across stages (gpt-neo's alternating global/local pattern aligns
         # whenever layers_per_stage is even; qwen2 suffix windows only when
         # the suffix starts on a stage boundary AND covers whole stages)
-        window_per_sub = []
+        kind_per_sub = []
         for j in range(layers_per_stage):
-            ws = {cfg.window_for(s * layers_per_stage + j) for s in range(num_stages)}
-            if len(ws) > 1:
+            ks = {cfg.kinds[s * layers_per_stage + j] for s in range(num_stages)}
+            if len({k[0] for k in ks}) > 1:
                 raise NotImplementedError(
-                    f"per-layer window pattern is not stage-uniform (sub-layer {j} sees windows {ws} "
+                    f"per-layer window pattern is not stage-uniform (sub-layer {j} sees mixers {sorted(k[0] for k in ks)} "
                     f"across stages); choose num_stages so the window pattern repeats per stage")
-            window_per_sub.append(ws.pop())
+            if len(ks) > 1:
+                raise ValueError(f"MoE x pipeline needs a stage-uniform expert pattern: sub-layer {j} is {sorted(ks)} "
+                                 f"across stages")
+            kind_per_sub.append(ks.pop())
 
         if params is None:
             params = self.init(rng if rng is not None else jax.random.PRNGKey(0), example_batch)
@@ -795,7 +886,7 @@ class CausalLM:
         # one block program per sub-layer: sub-layer j reproduces the global
         # MoE slot pattern (given the divisibility check above) and carries
         # the stage-uniform window
-        blocks = [Block(cfg, window_per_sub[j], cfg.moe_for(j)) for j in range(layers_per_stage)]
+        blocks = [Block(cfg, kind_per_sub[j]) for j in range(layers_per_stage)]
         has_moe = cfg.moe_num_experts > 0
         norm_key = [k for k in head_params if "Norm" in k]
         paramless_norm = cfg.norm == "layernorm_np"
@@ -880,6 +971,8 @@ class CausalLM:
             (("k_proj", "kernel"), P(None, "tensor", None)),
             (("v_proj", "kernel"), P(None, "tensor", None)),
             (("o_proj", "kernel"), P("tensor", None, None)),
+            (("kv_b_proj", "kernel"), P(None, "tensor", None)),  # mla: heads expanded from the latent
+            (("kv_a_proj", "kernel"), P(None, None)),
             (("gate_proj", "kernel"), P(None, "tensor")),
             (("up_proj", "kernel"), P(None, "tensor")),
             (("down_proj", "kernel"), P("tensor", None)),

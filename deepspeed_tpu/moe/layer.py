@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharded_moe import combine_output, gate_and_dispatch
+from .sharded_moe import combine_output, gate_and_dispatch, routed_part, sigmoid_topk
 
 
 class Experts(nn.Module):
@@ -115,6 +115,118 @@ class MoE(nn.Module):
         return out
 
 
+def moe_path() -> str:
+    """How a routed layer's grouped products run: the Pallas grouped matmul on
+    a TPU, ``lax.ragged_dot`` elsewhere."""
+    from ..ops.registry import pallas_available
+
+    return "kernel" if pallas_available() else "xla"
+
+
+def _count_rows(rows):
+    from ..telemetry.registry import get_registry
+
+    reg = get_registry()
+    reg.counter("moe_rows_routed_here_total").inc(float(rows[:, 0].sum()))
+    reg.counter("moe_rows_dropped_total").inc(float(rows[:, 1].sum()))
+    reg.gauge("moe_expert_rows_max").set(float(rows[:, 2].max()))
+    reg.gauge("moe_expert_rows_min").set(float(rows[:, 3].min()))
+
+
+def report_rows(intermediates):
+    """What the routed layers of a model sowed as ``rows`` in a forward pass,
+    stacked and handed out of the step program for the registry
+    (``telemetry/device_counts.py``: an output of the step, no host callback)."""
+    from ..telemetry import device_counts
+
+    rows = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates)
+            if any(getattr(k, "key", None) == "rows" for k in path)]
+    if rows:
+        device_counts.report("moe_rows", jnp.stack(rows), _count_rows)
+
+
+class RoutedMoE(nn.Module):
+    """A routed FFN as the share of it that is held here, plus a shared expert.
+
+    Scores are sigmoids over ALL ``num_experts``; a token takes the top ``k``
+    of score + selection bias (``select_bias``: a parameter that takes no
+    gradient, zero at start), weighted by the chosen scores rescaled to sum to
+    one times ``scale``. ``held = (first, count)`` says which experts this
+    layer holds (None: all): it routes over all, computes its own experts'
+    part, and adds the shared expert once; what absent experts would add is
+    left out. No capacity, no drops, no auxiliary loss: cost follows the rows
+    routed here (``sharded_moe.routed_part``).
+
+    With an ``expert`` mesh axis the held experts are split over it by their
+    leading dimension (``MOE_PARTITION_RULES``): every chip of the axis
+    routes the same tokens, computes the part of its own ``count / axis``
+    experts, and the parts are summed over the axis.
+    """
+
+    hidden_size: int
+    num_experts: int
+    k: int
+    d_ff: int
+    held: Optional[tuple] = None
+    shared_ff: int = 0
+    scale: float = 1.0
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d, E = self.hidden_size, self.num_experts
+        first, count = self.held if self.held is not None else (0, E)
+        tokens = x.reshape(-1, d)
+        init = nn.initializers.normal(0.02)
+        logits = nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32))  # which experts: no bf16 pass
+        select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
+        idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
+        wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
+                      for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
+                                          ("wo", (count, self.d_ff, d))))
+        out, routed, dropped, largest, smallest = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo,
+                                                                    first, E, moe_path() == "kernel")
+        # (routed here, of them not computed, largest group, smallest group), sown: ``report_rows`` hands them on
+        self.sow("intermediates", "rows", jnp.stack([routed, dropped, largest, smallest]).astype(jnp.int32))
+        if self.shared_ff:
+            dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
+                                                 param_dtype=jnp.float32)
+            h = nn.silu(dense(self.shared_ff, "shared_gate_proj")(tokens)) * dense(self.shared_ff, "shared_up_proj")(tokens)
+            out = out + dense(d, "shared_down_proj")(h)
+        return out.reshape(x.shape).astype(x.dtype)
+
+
+def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel):
+    """``routed_part`` on one chip; on a mesh, inside a shard_map in which the
+    tokens are split over the batch axes, the experts over ``expert``, and the
+    parts are summed over ``expert``."""
+    from ..ops.pallas._utils import on_mesh
+    from ..parallel.mesh import get_mesh_topology
+    from ..runtime.zero.partition import fit_spec, prune_spec
+
+    topo = get_mesh_topology(required=False)
+    if topo is None or topo.n_devices == 1:
+        return routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel)
+    axis = topo.axis_size("expert")
+    rows = fit_spec(prune_spec(P(topo.batch_axes, None), topo), tokens.shape, topo)
+    held = P("expert", None, None) if axis > 1 and wg.shape[0] % axis == 0 else P()
+    split = rows[0] if len(rows) and rows[0] is not None else ()
+    over = (split if isinstance(split, tuple) else (split,)) + (("expert",) if held != P() else ())  # axes the pairs are spread over
+
+    def local(tokens, idx, weights, wg, wi, wo):
+        mine = first + (jax.lax.axis_index("expert") * wg.shape[0] if held != P() else 0)
+        out, routed, dropped, largest, smallest = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel)
+        if held != P():
+            out = jax.lax.psum(out, "expert")
+        if over:
+            routed, dropped = jax.lax.psum(routed, over), jax.lax.psum(dropped, over)
+            largest, smallest = jax.lax.pmax(largest, over), jax.lax.pmin(smallest, over)
+        return out, routed, dropped, largest, smallest
+
+    return on_mesh(local, (rows, rows, rows, held, held, held), (rows, P(), P(), P(), P()))(tokens, idx, weights, wg, wi, wo)
+
+
 def _mesh_has_axis(axis: str) -> bool:
     try:
         mesh = jax.sharding.get_abstract_mesh()
@@ -130,6 +242,9 @@ def _mesh_has_axis(axis: str) -> bool:
 # canonical row-parallel allreduce (verified: no weight gathers in HLO),
 # so Mixtral-class expert memory scales with tp instead of replicating.
 MOE_PARTITION_RULES = [
+    (("experts_wi",), P("expert", None, None)),  # RoutedMoE: the held experts, whole in their width
+    (("experts_wo",), P("expert", None, None)),
+    (("experts_wg",), P("expert", None, None)),
     (("experts", "wi"), P("expert", None, "tensor")),
     (("experts", "wo"), P("expert", "tensor", None)),
     (("experts", "wg"), P("expert", None, "tensor")),

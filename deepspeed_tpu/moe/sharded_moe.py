@@ -15,8 +15,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 uniform_map = {}
+SAVED = "routed_ffn"  # the name a routed layer's sorted rows and grouped products carry for a checkpoint policy
 
 
 def multiplicative_jitter(x: jnp.ndarray, rng, epsilon: float = 1e-2) -> jnp.ndarray:
@@ -129,3 +131,136 @@ def gate_and_dispatch(x: jnp.ndarray, gate_logits: jnp.ndarray, k: int, capacity
 def combine_output(expert_out: jnp.ndarray, combine: jnp.ndarray) -> jnp.ndarray:
     """expert_out: (E, C, d), combine: (N, E, C) -> (N, d)."""
     return jnp.einsum("nec,ecd->nd", combine.astype(expert_out.dtype), expert_out)
+
+
+# ----------------------------------------------------------------------
+# routing without a capacity: scores over ALL experts, the part of the ones held here
+# ----------------------------------------------------------------------
+def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float):
+    """Sigmoid scores, the top ``k`` of ``score + select_bias`` (the bias only
+    chooses: it takes no gradient and does not enter the weight), the chosen
+    scores rescaled to sum to one and multiplied by ``scale``.
+    logits (N, E) float32 -> (indices (N, k) int32, weights (N, k) float32)."""
+    scores = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
+
+
+def _grouped(xs, w, group_sizes, kernel: bool):
+    """Rows sorted by group times that group's matrix: (M, a) x (G, a, b) ->
+    (M, b); rows past the groups' total are not defined."""
+    from ..telemetry.registry import get_registry
+
+    tile = lambda n, want: max((t for t in (1024, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
+    tiling = (tile(xs.shape[0], 256), tile(w.shape[1], 768), tile(w.shape[2], 1024))
+    kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
+    get_registry().counter("moe_grouped_traced_total", path="kernel" if kernel else "xla").inc()  # the choice, where made
+    if not kernel:
+        return jax.lax.ragged_dot(xs, w, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling)
+
+
+@jax.custom_vjp
+def _rows_of(tokens, tok_of_row, row_ok, pos, take):
+    """The sorted rows' tokens: ``tokens[tok_of_row]``, zero past the routed
+    rows. Its backward is a gather too (each token sums the rows of its own
+    pairs, found by ``pos``), where XLA's transpose of a gather is a
+    scatter-add that the chip runs row by row."""
+    return jnp.where(row_ok, tokens[tok_of_row], 0)
+
+
+def _rows_of_fwd(tokens, tok_of_row, row_ok, pos, take):
+    return _rows_of(tokens, tok_of_row, row_ok, pos, take), (pos, take)
+
+
+def _rows_of_bwd(res, dxs):
+    pos, take = res
+    picked = dxs[jnp.minimum(pos, dxs.shape[0] - 1)]  # (N, k, d)
+    return (jnp.sum(jnp.where(take[..., None], picked, 0), axis=1), None, None, None, None)
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@jax.custom_vjp
+def _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take):
+    """Each token's weighted rows: ``sum_j weights[n, j] ys[pos[n, j]]`` over
+    the pairs that were taken. Backward, again by gathers: a row's gradient
+    is its own pair's weight times its token's, a weight's the product of its
+    row with its token's gradient."""
+    picked = ys[jnp.minimum(pos, ys.shape[0] - 1)]  # (N, k, d)
+    return jnp.sum(picked * jnp.where(take, weights, 0.0)[..., None].astype(picked.dtype), axis=1)
+
+
+def _back_to_tokens_fwd(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take):
+    return _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take), (ys, weights, tok_of_row, pair_of_row, row_ok, pos, take)
+
+
+def _back_to_tokens_bwd(res, dout):
+    ys, weights, tok_of_row, pair_of_row, row_ok, pos, take = res
+    d_rows = dout[tok_of_row]  # (rows, d): the gradient of the token each row belongs to
+    w_rows = weights.reshape(-1)[pair_of_row][:, None].astype(ys.dtype)
+    d_ys = jnp.where(row_ok, d_rows * w_rows, 0).astype(ys.dtype)
+    dw_rows = jnp.sum(d_rows.astype(jnp.float32) * ys.astype(jnp.float32), axis=-1)  # (rows,)
+    d_weights = jnp.where(take, dw_rows[jnp.minimum(pos, ys.shape[0] - 1)], 0.0).astype(weights.dtype)
+    return (d_ys, d_weights, None, None, None, None, None)
+
+
+_back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
+
+
+def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool):
+    """The part of a routed FFN that the experts ``first .. first + n`` add
+    (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (silu(x wg) * x wi)``), for
+    tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts.
+
+    The (token, choice) pairs are sorted by expert, the ones for experts held
+    here first; the first ``rows`` of that order are gathered and go through
+    three grouped products whose cost follows the groups' sizes, and each
+    token takes its weighted rows back by the inverse order. ``rows`` bounds
+    the buffer, not the routing: the caller gives one that holds every pair
+    routed here (``routed_part``). Returns ((N, d), pairs routed here, those
+    of them the buffer did not hold and so were not computed, the largest and
+    smallest group)."""
+    N, k = idx.shape
+    n = wg.shape[0]
+    local = idx - first
+    here = (local >= 0) & (local < n)
+    key = jnp.where(here, local, n).reshape(-1)
+    order = jnp.argsort(key)  # stable: held experts first, by expert, tokens in order
+    group_sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+    routed = jnp.sum(group_sizes)
+    row_ok = (jnp.arange(rows) < routed)[:, None]
+    pos = jnp.argsort(order).reshape(N, k)  # where each pair went
+    take = here & (pos < rows)
+    pair_of_row = order[:rows]
+    tok_of_row = pair_of_row // k
+    # named: a block under jax.checkpoint keeps the order and the rows (a few tens of MB a layer) and does not sort,
+    # gather and multiply a second time in its backward (models/transformer.py::block_fn)
+    keep = lambda x: checkpoint_name(x, SAVED)
+    pos, take, pair_of_row, tok_of_row, row_ok, group_sizes = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes))
+    xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take))  # (rows, d)
+    gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
+    ys = keep(jnp.where(row_ok, _grouped((jax.nn.silu(gate) * up).astype(xs.dtype), wo, group_sizes, kernel), 0))
+    out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take)
+    return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
+
+
+def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool):
+    """``held_experts`` with a buffer that follows the load: four times the
+    pairs a uniform router sends to ``n`` of ``num_experts`` experts, and,
+    chosen on the device when more arrive, every pair there is. No pair
+    routed to a held expert is dropped at any imbalance."""
+    N, k = idx.shape
+    n = wg.shape[0]
+    every = N * k
+    usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
+    run = lambda rows: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel)
+    if usual == every:
+        return run(every)()
+    local = idx - first
+    routed = jnp.sum((local >= 0) & (local < n))
+    return jax.lax.cond(routed <= usual, run(usual), run(every))

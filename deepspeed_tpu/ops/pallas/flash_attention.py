@@ -44,6 +44,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -53,6 +54,7 @@ from ..registry import REGISTRY, pallas_available
 from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh, vmem_budget
 
 NEG_INF = -1e30
+SAVED = "flash_attention"  # the name the forward's output and row statistics carry for a checkpoint policy
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
 
 # Default blocks are large: the grid runs sequentially on the (single)
@@ -217,7 +219,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
     the end, and lse leaves as the row the backward reads (``_rows``)."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (bq, D) input dtype — MXU runs bf16 operands w/ fp32 accumulation
-    D = q.shape[-1]
+    D = v_ref.shape[-1]  # the value head size: q and k may have another (latent attention: 192 beside 128)
     slope = slopes_ref[0, 0, 0]
     row0 = seq_k - seq_q + qi * bq
     guard = _needs_empty_guard(seq_q, seq_k, has_bias)
@@ -262,22 +264,26 @@ def _kv_of_fn(H: int, KVH: int):
     return kv_of
 
 
-def _count_traced(pass_: str, path: str):
+def _count_traced(pass_: str, path: str, unequal_heads: bool = False):
     """The kernels are chosen while a program is traced, so that is where the
-    choice is counted (docs/OBSERVABILITY.md): one a call site a trace."""
+    choice is counted (docs/OBSERVABILITY.md): one a call site a trace. A call
+    whose values have another head size than its queries and keys is latent
+    attention's, and is counted under that name too."""
     from ...telemetry.registry import get_registry
 
     get_registry().counter("flash_attention_traced_total", **{"pass": pass_, "path": path}).inc()
+    if unequal_heads:
+        get_registry().counter("mla_attention_traced_total", **{"pass": pass_, "path": "kernel"}).inc()
 
 
 def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: bool, has_alibi: bool,
                window: int, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     bq, bk = _blk(Sq, DEFAULT_BQ), _blk(Sk, DEFAULT_BK)
-    _count_traced("fwd", "single")
+    _count_traced("fwd", "single", Dv != D)
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block
@@ -290,7 +296,7 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
         bias_spec = pl.BlockSpec((1, bq, Sk), lambda b, i: (bias_bh(b), i, 0))
     else:
         bias_spec = pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0))
-    vmem = (2 * (2 * bq * D + 2 * Sk * D) * q.dtype.itemsize + _tile_bytes(bq, bk)
+    vmem = (2 * (bq * (D + Dv) + Sk * (D + Dv)) * q.dtype.itemsize + _tile_bytes(bq, bk)
             + (2 * (LANES if sqb1 else bq) * Sk * 4 if has_bias else 0))
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
@@ -299,16 +305,16 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Sk, D), lambda b, i: (kv_of(b), 0, 0)),
-            pl.BlockSpec((1, Sk, D), lambda b, i: (kv_of(b), 0, 0)),
+            pl.BlockSpec((1, Sk, Dv), lambda b, i: (kv_of(b), 0, 0)),
             pl.BlockSpec((1, 1, LANES), lambda b, i: (b, 0, 0)),
             bias_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Sq // bq, 1, bq), jnp.float32),  # one row a q block: _rows
         ],
         interpret=interpret,
@@ -406,7 +412,6 @@ def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj
     (bk, bq) logit gradient in the input dtype: dq's share is ``ds^T @ k``
     (times ``scale``), which the fused kernel adds up instead of a second
     kernel recomputing s, p and dp to get there."""
-    D = k.shape[-1]
     guard = _needs_empty_guard(seq_q, seq_k, has_bias)
 
     def body(i, carry, masked):
@@ -427,8 +432,7 @@ def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj
         return dk, dv
 
     runs = _q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
-    zeros = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = _walk(runs, body, (zeros, zeros))
+    dk, dv = _walk(runs, body, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     return dk * scale, dv
 
 
@@ -508,13 +512,14 @@ def _tile_bytes(bq: int, bk: int) -> int:
     return 8 * bq * bk * 4
 
 
-def _fused_bwd_vmem(Sq: int, Sk: int, D: int, item: int, bq: int, bk: int, n_rep: int) -> int:
+def _fused_bwd_vmem(Sq: int, Sk: int, D: int, item: int, bq: int, bk: int, n_rep: int, Dv: int = 0) -> int:
     """Bytes of VMEM the fused backward holds at once: what the grid keeps
     resident (inputs and outputs double-buffered by the pipeline), the
     float32 scratch, and the block temporaries."""
-    head = 2 * (2 * Sq * D * item + 2 * 8 * Sq * 4 + Sq * D * item) + Sq * D * 4  # q, do, lse, delta, dq out; dq_acc
-    kv_in = 2 * 2 * bk * D * item
-    kv_out = kv_in if n_rep == 1 else 2 * 2 * Sk * D * item + 2 * Sk * D * 4
+    Dv = Dv or D  # the value head size, where it is not the keys'
+    head = 2 * (Sq * (D + Dv) * item + 2 * 8 * Sq * 4 + Sq * D * item) + Sq * D * 4  # q, do, lse, delta, dq out; dq_acc
+    kv_in = 2 * bk * (D + Dv) * item
+    kv_out = kv_in if n_rep == 1 else 2 * Sk * (D + Dv) * item + Sk * (D + Dv) * 4
     return head + kv_in + kv_out + _tile_bytes(bq, bk)
 
 
@@ -531,6 +536,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
                has_alibi: bool, window: int, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
     BKV, Sk, _ = k.shape  # B * KVH (GQA stays collapsed)
+    Dv = v.shape[-1]
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     n_rep = H // KVH
@@ -546,38 +552,37 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
         return (bkv // KVH) * H + (bkv % KVH) * n_rep + rep
 
     if not has_bias:
-        fused_vmem = _fused_bwd_vmem(Sq, Sk, D, item, bq, bk, n_rep)
+        fused_vmem = _fused_bwd_vmem(Sq, Sk, D, item, bq, bk, n_rep, Dv)
         if fused_vmem > vmem_budget():
             raise NotImplementedError(
                 f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "
                 f"{n_rep} q heads a KV head take {fused_vmem >> 20} MiB of VMEM, over this device's budget of "
                 f"{vmem_budget() >> 20} MiB: split the sequence over the mesh (sequence or context parallelism)")
-        _count_traced("bwd", "fused")
+        _count_traced("bwd", "fused", Dv != D)
         whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
         rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
-        kv_blk = pl.BlockSpec((1, bk, D), lambda b, r, j: (b, j, 0))
+        kv_blk = [pl.BlockSpec((1, bk, d), lambda b, r, j: (b, j, 0)) for d in (D, Dv)]
         if n_rep == 1:
             kv_out, kv_scratch = kv_blk, []
         else:
-            kv_out = pl.BlockSpec((1, Sk, D), lambda b, r, j: (b, 0, 0))
-            kv_scratch = [pltpu.VMEM((Sk, D), jnp.float32)] * 2
+            kv_out = [pl.BlockSpec((1, Sk, d), lambda b, r, j: (b, 0, 0)) for d in (D, Dv)]
+            kv_scratch = [pltpu.VMEM((Sk, d), jnp.float32) for d in (D, Dv)]
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, n_rep=n_rep, **statics),
             grid=(BKV, n_rep, Sk // bk),
             in_specs=[
                 pl.BlockSpec((1, Sq, D), whole_q),
-                kv_blk,
-                kv_blk,
-                pl.BlockSpec((1, Sq, D), whole_q),
+                *kv_blk,
+                pl.BlockSpec((1, Sq, Dv), whole_q),
                 pl.BlockSpec((1, nq, 1, bq), rows_q),
                 pl.BlockSpec((1, nq, 1, bq), rows_q),
                 pl.BlockSpec((1, 1, LANES), whole_q),
             ],
-            out_specs=[pl.BlockSpec((1, Sq, D), whole_q), kv_out, kv_out],
+            out_specs=[pl.BlockSpec((1, Sq, D), whole_q), *kv_out],
             out_shape=[
                 jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
                 jax.ShapeDtypeStruct((BKV, Sk, D), k.dtype),
-                jax.ShapeDtypeStruct((BKV, Sk, D), v.dtype),
+                jax.ShapeDtypeStruct((BKV, Sk, Dv), v.dtype),
             ],
             scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)] + kv_scratch,
             interpret=interpret,
@@ -588,6 +593,8 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
 
     # a bias: the query-major dq kernels (dbias is written there; they read the lane-broadcast form) and a
     # dkv kernel a q head
+    if Dv != D:
+        raise NotImplementedError(f"flash_attention backward under a bias takes one head size, got {D} and {Dv}")
     _count_traced("bwd", "split")
     lse, delta = (jnp.broadcast_to(x[..., None], (BH, Sq, LANES)) for x in (lse, delta))
     Bb, Hb, Sqb, repeat = bias_meta
@@ -724,28 +731,30 @@ def _bh_slopes(slopes, B, H):
 
 
 def _flash_core(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH):
-    B, Sq, _, D = q.shape
-    to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], D)
+    B, Sq, _, _ = q.shape
+    to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], x.shape[3])
     o, lse = _flash_fwd(to_bh(q), to_bh(k), to_bh(v), _bh_slopes(slopes, B, H), bias,
                         scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
-    o = o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    o = o.reshape(B, H, Sq, v.shape[-1]).transpose(0, 2, 1, 3)
     return o, lse
 
 
 def _flash_vjp_fwd(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH):
     o, lse = _flash_core(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
+    # named: a block under jax.checkpoint whose policy lists the name keeps them and runs no second forward kernel
+    o, lse = checkpoint_name(o, SAVED), checkpoint_name(lse, SAVED)
     return o, (q, k, v, slopes, bias, o, lse)
 
 
 def _flash_vjp_bwd(scale, causal, interpret, has_alibi, window, bias_meta, H, KVH, res, do):
     q, k, v, slopes, bias, o, lse = res
-    B, Sq, _, D = q.shape
+    B, Sq, _, _ = q.shape
     Sk = k.shape[1]
-    to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], D)
+    to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], x.shape[3])
     dq, dk, dv, dbias = _flash_bwd(to_bh(q), to_bh(k), to_bh(v), to_bh(o), lse, to_bh(do),
                                    _bh_slopes(slopes, B, H), bias,
                                    scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
-    back = lambda x, S, nh: x.reshape(B, nh, S, D).transpose(0, 2, 1, 3)
+    back = lambda x, S, nh: x.reshape(B, nh, S, x.shape[-1]).transpose(0, 2, 1, 3)
     # cotangent matches the (collapsed, flat) bias argument; the outer
     # 4D->flat reshape in flash_attention transposes automatically
     dbias_out = dbias.astype(bias.dtype) if bias_meta is not None else jnp.zeros_like(bias)

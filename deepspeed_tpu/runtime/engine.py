@@ -37,6 +37,7 @@ from ..analysis.jit_audit import leaf_signature
 from ..parallel.mesh import MeshTopology, get_mesh_topology, initialize_mesh
 from ..telemetry import MonitorBridge
 from ..telemetry import get_registry as get_telemetry_registry
+from ..telemetry import device_counts
 from ..telemetry import span as telemetry_span
 from ..telemetry.costs import first_call
 from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
@@ -79,6 +80,18 @@ def _global_norm(tree):
 def _all_finite(tree):
     leaves = [jnp.all(jnp.isfinite(x)) for x in jax.tree_util.tree_leaves(tree)]
     return jnp.all(jnp.stack(leaves))
+
+
+# key on the trainer's first-call line -> the counter of the choice it reports (forward call sites, by ``path``)
+_PATHS = {"kda_path": ("kda_traced_total", {"pass": "fwd"}), "mla_path": ("mla_attention_traced_total", {"pass": "fwd"}),
+          "moe_path": ("moe_grouped_traced_total", {})}
+
+
+def _paths_traced():
+    """{key of ``_PATHS``: (call sites traced as a kernel, in XLA's form) so far}."""
+    reg = get_telemetry_registry()
+    return {key: tuple(int(reg.peek(name, path=path, **labels) or 0) for path in ("kernel", "xla"))
+            for key, (name, labels) in _PATHS.items()}
 
 
 def _batch_tokens(batch) -> int:
@@ -412,16 +425,17 @@ class DeepSpeedEngine:
             params_c = _cast_tree(params32, compute_dtype)
             if comp is not None:
                 params_c = comp.apply(params_c, comp_state)
-            with zero_overlap.active(gather_plan):  # read by the model while its loss is traced
+            # read by the model while its loss is traced: the gather plan, and who takes what it counts on the device
+            with zero_overlap.active(gather_plan), device_counts.collecting() as reported:
                 loss = loss_fn(params_c, batch, rng)
-            return (loss * scale).astype(jnp.float32), loss
+            return (loss * scale).astype(jnp.float32), ((loss, reported) if reported else loss)  # nothing counted: the loss alone
 
         def fwd_bwd(params32, batch, step, scale, comp_state):
             # rng derivation lives inside the jit: one less per-step dispatch
             rng = jax.random.fold_in(base_rng, step)
-            (scaled, raw_loss), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
+            (scaled, loss_and_reported), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
                 _fetch(params32), batch, rng, scale, comp_state)
-            return raw_loss, grads
+            return loss_and_reported, grads
 
         from .zero.zeropp import build_zeropp_fwd_bwd, zeropp_applicable, zeropp_requested
 
@@ -500,6 +514,7 @@ class DeepSpeedEngine:
         # IS a full step and no host-side stage interposes.
         self._fused_step = None
         self._fused_pending = None
+        self._reported = []  # device counts of steps dispatched and not yet read (``_take_reported``)
         if (comp is None and not use_zeropp
                 and self._host_offload is None and self.eigenvalue is None
                 and self.config.fused_step):
@@ -510,11 +525,11 @@ class DeepSpeedEngine:
             def fused_step(params32, opt_state, batch, step, scale, inv_scale, lr):
                 rng = jax.random.fold_in(base_rng, step)
                 params_dev = _fetch(params32)  # one stream-in, shared by grad + update
-                (_, raw_loss), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
+                (_, loss_and_reported), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
                     params_dev, batch, rng, scale, None)
                 new_params, new_opt_state, gnorm, overflow = apply_updates(params_dev, opt_state, grads,
                                                                            inv_scale, lr)
-                return raw_loss, new_params, new_opt_state, gnorm, overflow
+                return loss_and_reported, new_params, new_opt_state, gnorm, overflow
 
             self._fused_step = jax.jit(
                 fused_step, donate_argnums=(0, 1),
@@ -648,6 +663,7 @@ class DeepSpeedEngine:
                 args = (self.params, batch, self.micro_steps, scale)
                 loss, grads = self._step_program("fwd_bwd", self._fwd_bwd, args, batch)
                 self._cached_grads = grads
+            loss = self._take_reported(loss)
             self._last_loss = loss
             if self.eigenvalue is not None:
                 self._last_batch = batch  # retained for the gas-boundary eigenvalue pass
@@ -673,6 +689,7 @@ class DeepSpeedEngine:
         self._step_programs_seen.add((name, shapes))
         counted = ("layers", "regathers", "rings", "head")
         before = [zero_overlap.traced(what) for what in counted]
+        paths_before = _paths_traced()
         notes = {}
         with first_call("train", name, notes):
             self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
@@ -680,7 +697,44 @@ class DeepSpeedEngine:
             layers, regathers, rings, head = (zero_overlap.traced(what) - was for what, was in zip(counted, before))
             notes.update(grad_reduce="bucket" if layers or head else "xla", bucket_layers=layers, bucket_rings=rings,
                          bucket_regather=regathers, bucket_head=int(head > 0))
+            notes.update(self._layer_kind_notes(paths_before))
         return out
+
+    def _take_reported(self, first):
+        """A step program's first output is (loss, what the model counted on
+        the device: ``telemetry/device_counts.py``), or the loss alone where
+        it counted nothing. The counts go to the registry once their step
+        has ended, which is looked up here at the next dispatches and never
+        waited for (past eight steps in flight the oldest is): the counters
+        lag the device by a step or two and the host path is not held up.
+        Returns the loss."""
+        loss, reported = first if isinstance(first, tuple) else (first, None)
+        if reported:
+            self._reported.append(reported)
+            ended = lambda counts: all(v.is_ready() for v in counts.values())
+            while self._reported and (ended(self._reported[0]) or len(self._reported) > 8):
+                device_counts.count(self._reported.pop(0))
+        return loss
+
+    def _layer_kind_notes(self, traced_before):
+        """For a model whose layers are of several kinds (``TransformerConfig.
+        kinds``): how many layers of each (mixer, ffn) pair, and how the
+        delta-rule scan, latent attention and the routed FFN's grouped
+        products were traced into this program, by the counters that count
+        each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
+        fallback), ``mixed``, or no key where the program has none."""
+        kinds = getattr(getattr(self.module, "cfg", None), "kinds", None)
+        if not kinds or len(set(kinds)) == 1:
+            return {}
+        count = {}
+        for mixer, ffn in kinds:
+            count[f"{mixer}+{ffn}"] = count.get(f"{mixer}+{ffn}", 0) + 1
+        notes = dict(layer_kinds=",".join(f"{k}:{n}" for k, n in sorted(count.items())))
+        for key, (kernel, xla) in _paths_traced().items():
+            kernel, xla = kernel - traced_before[key][0], xla - traced_before[key][1]
+            if kernel or xla:
+                notes[key] = "mixed" if kernel and xla else "kernel" if kernel else "xla"
+        return notes
 
     def _count_step_flops(self, program, args):
         """FLOPs of a micro-batch for the MFU gauge, walked off the jaxpr of

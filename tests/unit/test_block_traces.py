@@ -163,7 +163,7 @@ def _plain_loss(cfg, params, ids, train=True):
     x = params["wte"][ids].astype(cfg.dtype)
     aux = 0.0
     for i in range(cfg.n_layers):
-        block = Block(cfg, window=cfg.window_for(i), moe=cfg.moe_for(i), is_training=train)
+        block = Block(cfg, cfg.kinds[i], is_training=train)
         x, sown = block.apply({"params": params[f"layer_{i}"]}, x, positions, mutable=["losses", "intermediates"])
         aux = aux + sum(jnp.sum(l) for l in jax.tree_util.tree_leaves(sown.get("losses", {})))
     norm_key = next(k for k in params if k.startswith("RMSNorm"))

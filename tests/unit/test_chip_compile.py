@@ -120,7 +120,38 @@ def _paged_mixed():
     return fn, (S((T, H, D), BF16), _pool(0), _pool(0), S((N, PAGES), I32), S((N,), I32), S((T,), I32)), 2
 
 
+def _flash_latent(shape):
+    """Latent attention's call: keys of ``Dk`` beside values of ``Dv``, unpadded, forward and the fused backward."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, Sq, Hq, Dk, Dv = shape
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        return (o,) + vjp(do)
+
+    qk, v = S((B, Sq, Hq, Dk), BF16), S((B, Sq, Hq, Dv), BF16)
+    return fwd_bwd, (qk, qk, v, v), 2, "fused"
+
+
+def _kda(shape):
+    """The chunked delta-rule scan, forward and backward: 2 kernels (heads before the sequence)."""
+    from deepspeed_tpu.ops.kda import kda_chunked
+
+    B, Hh, Sq, Dh = shape
+
+    def fwd_bwd(q, k, v, g, beta, do):
+        o, vjp = jax.vjp(kda_chunked, q, k, v, g, beta)
+        return (o,) + vjp(do)
+
+    x = S((B, Hh, Sq, Dh), BF16)
+    return fwd_bwd, (x, x, x, S((B, Hh, Sq, Dh), F32), S((B, Hh, Sq), F32), x), 2
+
+
 CASES = {
+    "flash_latent_b1_s8192_h32_d192_v128": lambda: _flash_latent((1, 8192, 32, 192, 128)),  # kimi-linear-48b-l5e8's MLA layer
+    "kda_scan_b1_h32_s8192_d128": lambda: _kda((1, 32, 8192, 128)),                         # ... and its KDA layers
+    "kda_scan_b2_h4_s1000_d128": lambda: _kda((2, 4, 1000, 128)),                          # a length that is padded to chunks
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
     "flash_gqa_b2_s4096_h32_kvh4_d128": lambda: _flash((2, 4096, 32, 4, 128)),
     "flash_mha_b2_s2048_h16_d128": lambda: _flash((2, 2048, 16, 16, 128)),  # olmo-1b.pretrain-z3, one chip's share

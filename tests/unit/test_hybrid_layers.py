@@ -1,0 +1,353 @@
+"""The hybrid layers (``models/mixers.py``, ``moe/layer.py::RoutedMoE``, the
+KDA scan kernel) against plain references, the per-layer specification, and
+the share: at tiny widths, float32, on the CPU."""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import hybrid_reference as ref
+from deepspeed_tpu.models import CausalLM, TransformerConfig, transformer as T
+from deepspeed_tpu.telemetry import get_registry
+
+KINDS = (("kda", "dense"), ("kda", "routed"), ("kda", "routed"), ("mla", "routed"), ("kda", "routed"))
+
+
+def tiny(**over):
+    base = dict(vocab_size=211, n_layers=5, n_heads=4, d_model=48, d_ff=64, max_seq_len=64, norm="rmsnorm", activation="swiglu",
+                pos_emb="none", tie_embeddings=False, layer_kinds=KINDS, kda_heads=2, kda_head_dim=16, kda_gate_rank=8,
+                mla_kv_rank=24, mla_qk_nope_dim=24, mla_qk_rope_dim=8, mla_v_dim=16, moe_num_experts=16, moe_top_k=4,
+                moe_d_ff=32, moe_shared_d_ff=32, moe_route_scale=2.446, moe_held=(4, 8), moe_aux_loss_coef=0.0)
+    return TransformerConfig(**dict(base, **over))
+
+
+@pytest.fixture(scope="module")
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert np.max(np.abs(a - b)) <= tol * (1.0 + np.max(np.abs(b))), (np.max(np.abs(a - b)), np.max(np.abs(b)))
+
+
+def _module(kind, cfg):
+    from deepspeed_tpu.models.mixers import KDAMixer, MLAMixer
+    from deepspeed_tpu.moe.layer import RoutedMoE
+
+    if kind == "kda":
+        return KDAMixer(cfg), lambda p, h: ref.kda(p, h)
+    if kind == "mla":
+        return MLAMixer(cfg), lambda p, h: ref.mla(p, h)
+    return (RoutedMoE(cfg.d_model, cfg.moe_num_experts, cfg.moe_top_k, cfg.moe_d_ff, cfg.moe_held, cfg.moe_shared_d_ff,
+                      cfg.moe_route_scale), lambda p, h: ref.routed(p, h, cfg.moe_held[0], cfg.moe_top_k, cfg.moe_route_scale))
+
+
+@pytest.mark.parametrize("kind", ["kda", "mla", "routed"])
+def test_a_layer_matches_its_plain_reference_forward_and_gradients(kind, highest):
+    """(a) q/k of 24 + 8 beside v of 16 for MLA, KDA heads of 16, a share of 8 of 16 experts."""
+    cfg = tiny()
+    module, plain = _module(kind, cfg)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 40, cfg.d_model))
+    params = module.init(jax.random.PRNGKey(2), h)["params"]
+    w = jax.random.normal(jax.random.PRNGKey(3), h.shape)
+    ours = jax.value_and_grad(lambda p, h: jnp.sum(module.apply({"params": p}, h) * w), argnums=(0, 1))
+    theirs = jax.value_and_grad(lambda p, h: jnp.sum(plain(p, h) * w), argnums=(0, 1))
+    (lo, go), (lt, gt) = ours(params, h), theirs(params, h)
+    _close(module.apply({"params": params}, h), plain(params, h))
+    _close(lo, lt)
+    flat_o, flat_t = jax.tree_util.tree_leaves_with_path(go), dict(jax.tree_util.tree_leaves_with_path(gt))
+    for path, leaf in flat_o:
+        _close(leaf, flat_t[path], 5e-5)
+    if kind == "routed":  # the selection bias chooses and takes no gradient
+        assert float(jnp.max(jnp.abs(go[0]["select_bias"]))) == 0.0
+
+
+def _scan_inputs(S, decay, seed=0, B=1, H=2, dk=16, dv=16):
+    """Heads before the sequence, as ``ops/kda.py`` takes them."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = ref.l2(jax.random.normal(ks[0], (B, H, S, dk))) * dk ** -0.5
+    k = ref.l2(jax.random.normal(ks[1], (B, H, S, dk)))
+    v = jax.random.normal(ks[2], (B, H, S, dv))
+    alpha = jnp.clip(decay + 0.01 * jax.random.uniform(ks[3], (B, H, S, dk), minval=-1.0), 1e-12, 1.0)
+    return q, k, v, jnp.log(alpha), jax.nn.sigmoid(jax.random.normal(ks[4], (B, H, S)))
+
+
+def _plain_delta_rule(q, k, v, g, beta):
+    """The test-side reference, which keeps the sequence before the heads."""
+    sw = lambda x: jnp.swapaxes(x, 1, 2)
+    return sw(ref.delta_rule(sw(q), sw(k), sw(v), jnp.exp(sw(g)), sw(beta)))
+
+
+@pytest.mark.parametrize("S,decay", [(256, 0.9), (100, 0.9), (128, 0.999999), (70, 1e-9), (260, 0.02)])
+def test_the_kda_kernel_matches_the_token_recurrence(S, decay, highest):
+    """(b) interpret mode: lengths that are and are not whole chunks, decays near 1 and near 0 (where exp(-G) overflows)."""
+    from deepspeed_tpu.ops.kda import kda_chunked, kda_recurrence
+
+    args = _scan_inputs(S, decay)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    run = lambda fn: jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    (lo, go), (lt, gt) = run(lambda *a: kda_chunked(*a, interpret=True)), run(kda_recurrence)
+    _close(kda_chunked(*args, interpret=True), kda_recurrence(*args))
+    _close(kda_recurrence(*args), _plain_delta_rule(*args))  # the oracle's oracle
+    assert np.isfinite(float(lo))
+    for a, b in zip(go, gt):
+        _close(a, b, 1e-4)
+
+
+def test_the_kda_kernel_under_bf16_operands_is_bf16_close(highest):
+    """bf16 q, k, v: the large products take bf16 operands and the triangular inverse three bf16 passes a product."""
+    from deepspeed_tpu.ops.kda import kda_chunked, kda_recurrence
+
+    q, k, v, g, beta = _scan_inputs(256, 0.9)
+    low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
+    run = lambda fn, *qkv: jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4))(*qkv, g, beta)
+    got, want = run(lambda *a: kda_chunked(*a, interpret=True), *low), run(kda_recurrence, *(x.astype(jnp.float32) for x in low))
+    for a, b in zip(got, want):
+        assert float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b)) < 2e-2
+
+
+@pytest.mark.parametrize("noise,beta,decay", [(0.0, 0.99, 1.0), (0.3, 0.9, 0.99)])
+def test_the_kda_kernel_stays_exact_where_keys_are_alike(noise, beta, decay, highest):
+    """Identical and nearly identical keys with beta near 1 and hardly any decay: the triangular system at its worst
+    (squaring a whole chunk's matrix gave 1e31 here)."""
+    from deepspeed_tpu.ops.kda import kda_chunked, kda_recurrence
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    k = ref.l2(jax.random.normal(ks[0], (1, 1, 1, 32)) + noise * jax.random.normal(ks[1], (1, 1, 256, 32)))
+    v = jax.random.normal(ks[2], (1, 1, 256, 32))
+    args = (k * 32 ** -0.5, k, v, jnp.full(k.shape, np.log(decay), jnp.float32), jnp.full((1, 1, 256), beta))
+    _close(kda_chunked(*args, interpret=True), kda_recurrence(*args), 2e-3)
+
+
+def _routed_layer(held, cfg=None, shared=32):
+    from deepspeed_tpu.moe.layer import RoutedMoE
+
+    cfg = cfg or tiny()
+    return RoutedMoE(cfg.d_model, cfg.moe_num_experts, cfg.moe_top_k, cfg.moe_d_ff, held, shared, cfg.moe_route_scale)
+
+
+def _share_of(whole, first, count):
+    """The parameters of the layer holding experts first .. first + count, cut from the one holding all."""
+    return {k: (v[first:first + count] if k.startswith("experts_") else v) for k, v in whole.items()}
+
+
+@pytest.mark.parametrize("count", [8, 4, 2])
+def test_the_shares_add_up_to_the_whole_layer(count, highest):
+    """(d) at 16 experts, the parts that shares of ``count`` experts give add up to the uncut layer's output, the shared expert once."""
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 48))
+    whole_layer = _routed_layer(None)
+    whole = whole_layer.init(jax.random.PRNGKey(5), h)["params"]
+    want = whole_layer.apply({"params": whole}, h)
+    shared_once = _routed_layer((0, count)).apply({"params": _share_of(whole, 0, count)}, h)
+    no_shared = {k: v for k, v in whole.items() if not k.startswith("shared_")}
+    rest = sum(_routed_layer((f, count), shared=0).apply({"params": _share_of(no_shared, f, count)}, h) for f in range(count, 16, count))
+    _close(shared_once + rest, want)
+    _close(want, ref.routed(whole, h, 0, 4, 2.446))
+
+
+@pytest.mark.parametrize("axis", [2, 4])
+def test_an_expert_axis_gives_the_one_device_layers_output(axis, highest):
+    """(d) with an ``expert`` mesh axis of 2 and of 4 virtual devices the layer's output equals the one-device layer holding all."""
+    from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    h = jax.random.normal(jax.random.PRNGKey(6), (2, 24, 48))
+    layer = _routed_layer(None)
+    params = layer.init(jax.random.PRNGKey(7), h)["params"]
+    reset_mesh()
+    want, grads_want = jax.value_and_grad(lambda p: jnp.sum(layer.apply({"params": p}, h) ** 2))(params)
+    try:
+        topo = initialize_mesh(MeshConfig.from_dict({"expert": axis}), devices=jax.devices()[:axis], force=True)
+        with topo.mesh:
+            got, grads = jax.jit(jax.value_and_grad(lambda p: jnp.sum(layer.apply({"params": p}, h) ** 2)))(params)
+    finally:
+        reset_mesh()
+    _close(got, want)
+    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_want)):
+        _close(a, b, 1e-4)
+
+
+def test_no_row_is_dropped_when_every_token_picks_one_held_expert(highest):
+    """(e) a router biased so that every token's first choice is expert 5 of the held 4..7: every row comes back."""
+    cfg = tiny(moe_held=(4, 4))
+    layer = _routed_layer(cfg.moe_held, cfg)
+    h = jax.random.normal(jax.random.PRNGKey(8), (3, 32, 48))
+    params = layer.init(jax.random.PRNGKey(9), h)["params"]
+    params = dict(params, select_bias=params["select_bias"].at[5].set(10.0))
+    out, sown = jax.jit(lambda p: layer.apply({"params": p}, h, mutable=["intermediates"]))(params)
+    routed, dropped, largest, _ = (int(v) for v in sown["intermediates"]["rows"][0])
+    assert routed >= 3 * 32 and dropped == 0  # every token's pair for expert 5, and whatever else fell on 4..7
+    assert largest == 3 * 32  # all 96 tokens in one group: 4 x the uniform load, past the usual buffer
+    _close(out, ref.routed(params, h, 4, cfg.moe_top_k, cfg.moe_route_scale))
+
+
+def test_five_layers_trace_three_blocks(highest):
+    """(f) ``block_fn``'s key is the kind: KDA+dense, KDA+routed, MLA+routed."""
+    model = CausalLM(tiny())
+    ids = np.zeros((1, 32), np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    from deepspeed_tpu.telemetry import device_counts
+
+    reg = get_registry()
+    before = reg.peek("program_block_traces_total", site="train") or 0
+    rows = [reg.peek(n) or 0.0 for n in ("moe_rows_routed_here_total", "moe_rows_dropped_total")]
+
+    def loss_and_counts(p):
+        with device_counts.collecting() as reported:
+            return model.loss_fn(p, {"input_ids": ids}), reported
+
+    compiled = jax.jit(loss_and_counts).lower(params).compile()
+    assert "callback" not in compiled.as_text()  # nothing the persistent compile cache would refuse to keep
+    _, reported = compiled(params)
+    assert reg.peek("program_block_traces_total", site="train") - before == 3
+    assert reported["moe_rows"].shape == (4, 4) and reg.peek("moe_rows_routed_here_total") in (None, rows[0])  # not yet counted
+    device_counts.count(reported)
+    # the four routed layers' rows leave the program as an output: 32 tokens x 4 choices, 8 of 16 held
+    assert 4 * 32 <= reg.peek("moe_rows_routed_here_total") - rows[0] <= 4 * 32 * 4 and reg.peek("moe_rows_dropped_total") == rows[1]
+    jax.block_until_ready(jax.jit(lambda p: model.loss_fn(p, {"input_ids": ids}))(params))  # nobody collects: nothing is reported
+    assert model.cfg.kinds == KINDS and [model.cfg.moe_for(i) for i in range(5)] == [False, True, True, True, True]
+
+
+# the parameter trees the presets and the benchmark's OLMo ``program`` block built BEFORE the per-layer field
+# (leaves, sha256 of the sorted "path shape dtype" lines; made from the parent commit by the same code as below)
+GOLDEN = {
+    "gpt2_tiny": (36, "a3f17807e10a3b20"), "gpt2_125m": (196, "5dbc31949410fefb"), "gpt2_1_3b": (388, "1630c0070f04b940"),
+    "llama_tiny": (21, "57591dcb7215d7fc"), "llama2_7b": (291, "40b0b6b4cfaf01ca"), "llama3_8b": (291, "ed8c2104724c6448"),
+    "olmo-1b.program": (113, "275453e661e320ac"), "llama_tiny.moe": (22, "46ce87439e34811e"),
+    "gpt2_tiny.windows": (36, "a3f17807e10a3b20"),
+}
+OLMO_PROGRAM = dict(vocab_size=50304, n_layers=16, n_heads=16, n_kv_heads=16, d_model=2048, d_ff=8192, max_seq_len=2048,
+                    norm="layernorm_np", activation="swiglu", pos_emb="rope", rope_theta=10000.0, tie_embeddings=True,
+                    norm_eps=1e-05, remat=False)
+
+
+def _golden_case(name):
+    if name == "olmo-1b.program":
+        return TransformerConfig(**OLMO_PROGRAM)
+    if name == "llama_tiny.moe":
+        return T.llama_tiny(moe_num_experts=4, moe_top_k=2)
+    if name == "gpt2_tiny.windows":
+        return T.gpt2_tiny(sliding_window=8, window_layers=(1,))
+    return getattr(T, name)()
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN))
+def test_the_older_fields_build_the_same_parameter_tree(name):
+    """(g) every preset and OLMo's ``program`` block: the same paths and shapes as before ``layer_kinds``."""
+    cfg = _golden_case(name)
+    shapes = jax.eval_shape(lambda: CausalLM(cfg).init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)}))
+    lines = sorted(f"{jax.tree_util.keystr(p)} {tuple(l.shape)} {l.dtype}" for p, l in jax.tree_util.tree_flatten_with_path(shapes)[0])
+    assert (len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16]) == GOLDEN[name]
+
+
+@pytest.mark.parametrize("cfg,kinds", [
+    (T.gpt2_tiny(), (("full", "dense"),) * 2),
+    (T.gpt2_tiny(sliding_window=8), (("window", "dense"),) * 2),
+    (T.gpt2_tiny(sliding_window=8, window_layers=(1,)), (("full", "dense"), ("window", "dense"))),
+    (T.llama_tiny(moe_num_experts=4), (("full", "dense"), ("full", "moe"))),
+    (T.llama_tiny(moe_num_experts=4, moe_layer_freq=1), (("full", "moe"),) * 2),
+])
+def test_window_for_and_moe_for_are_readings_of_the_kinds(cfg, kinds):
+    assert cfg.kinds == kinds
+    assert [cfg.window_for(i) for i in range(2)] == [cfg.sliding_window if k[0] == "window" else None for k in kinds]
+    assert [cfg.moe_for(i) for i in range(2)] == [k[1] != "dense" for k in kinds]
+    same = dataclasses.replace(cfg, layer_kinds=kinds)  # the same model, said the new way
+    assert same.kinds == kinds and same.uniform_window == cfg.uniform_window and hash(same) != hash(cfg)
+
+
+def test_layer_kinds_of_the_wrong_length_or_name_are_refused():
+    with pytest.raises(ValueError, match="layer_kinds"):
+        tiny(n_layers=4).kinds
+    with pytest.raises(ValueError, match="layer_kinds"):
+        tiny(layer_kinds=(("kda", "dense"),) * 4 + (("mamba", "dense"),)).kinds
+
+
+def test_the_old_gate_keeps_its_renormalisation():
+    """The capacity-gated layer's k chosen gates still sum to one; without, they sum to less (``topkgating``'s argument,
+    which no layer kind sets: the routed kind has scores of its own, ``sigmoid_topk``)."""
+    from deepspeed_tpu.moe.sharded_moe import gate_and_dispatch, topkgating
+
+    x, logits = jnp.ones((8, 4)), jax.random.normal(jax.random.PRNGKey(0), (8, 4))
+    on = gate_and_dispatch(x, logits, 2, 4.0, 4, drop_tokens=False)[2]
+    off = topkgating(logits, 2, 4.0, 4, drop_tokens=False, normalize_weights=False)[1]
+    np.testing.assert_allclose(np.asarray(jnp.sum(on, axis=(1, 2))), 1.0, rtol=1e-5)
+    assert float(jnp.max(jnp.sum(off, axis=(1, 2)))) < 1.0
+
+
+def test_a_buffer_too_small_shows_as_dropped_rows():
+    """``moe_rows_dropped_total`` is computed, not a constant: pairs routed here less pairs taken. ``routed_part`` always
+    gives ``held_experts`` a buffer that holds every pair; one that does not is seen."""
+    from deepspeed_tpu.moe.sharded_moe import held_experts, routed_part
+
+    key = jax.random.PRNGKey(3)
+    tokens = jax.random.normal(key, (512, 16))
+    idx = jnp.tile(jnp.array([[0, 1]], jnp.int32), (512, 1))  # every token picks the two held experts: 1,024 pairs
+    weights = jnp.full((512, 2), 0.5)
+    wg, wi, wo = (jax.random.normal(jax.random.fold_in(key, i), shape) for i, shape in enumerate(((2, 16, 8), (2, 16, 8), (2, 8, 16))))
+    _, routed, dropped, largest, smallest = held_experts(tokens, idx, weights, wg, wi, wo, 0, 768, False)
+    assert (int(routed), int(dropped), int(largest), int(smallest)) == (1024, 256, 512, 512)
+    _, routed, dropped, _, _ = jax.jit(lambda *a: routed_part(*a, 0, 64, False))(tokens, idx, weights, wg, wi, wo)
+    assert (int(routed), int(dropped)) == (1024, 0)  # 32 x the uniform load, past the usual buffer of 512: the branch that holds every pair
+
+
+@pytest.mark.parametrize("stage,mesh,n", [(0, {"data": 1}, 1), (3, {"fsdp": 4}, 4)])
+def test_the_five_layer_pattern_trains_through_initialize(stage, mesh, n):
+    """(c) stage 0 on one device and stage 3 on four virtual devices: the same first loss and the same loss after 3
+    steps, within 2e-3 of the recorded float32 values (the two differ in the order of every reduction)."""
+    import deepspeed_tpu
+    from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    from deepspeed_tpu.telemetry import get_tracer
+
+    model = CausalLM(tiny(max_seq_len=32))
+    ids = np.random.default_rng(0).integers(0, 211, (4, 32)).astype(np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids[:1]})
+    reg = get_registry()
+    rows = [reg.peek(n) or 0.0 for n in ("moe_rows_routed_here_total", "moe_rows_dropped_total")]
+    reset_mesh()
+    try:
+        topo = initialize_mesh(MeshConfig.from_dict(mesh), devices=jax.devices()[:n], force=True)
+        engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, mesh=topo, config={
+            "train_micro_batch_size_per_gpu": 4 // n, "gradient_accumulation_steps": 1, "steps_per_print": 10**9,
+            "optimizer": {"type": "adam", "params": {"lr": 1e-3}}, "zero_optimization": {"stage": stage}})
+        losses = []
+        for _ in range(4):
+            loss = engine.forward({"input_ids": ids})
+            engine.backward(loss)
+            engine.step()
+            losses.append(float(loss))
+    finally:
+        reset_mesh()
+    # the routed layers' rows are an output of the step, counted once the step has ended: three or all four of the
+    # steps' by now, each 128 tokens x 4 choices x 4 layers with 8 of 16 experts held; and the first-call span says
+    # what was traced
+    counted = reg.peek("moe_rows_routed_here_total") - rows[0]
+    assert 3 * 4 * 128 <= counted <= 4 * 4 * 128 * 4 and reg.peek("moe_rows_dropped_total") == rows[1]
+    said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
+    assert said["layer_kinds"] == "kda+dense:1,kda+routed:3,mla+routed:1"
+    assert (said["kda_path"], said["mla_path"], said["moe_path"]) == ("xla", "xla", "xla")  # off the TPU: the counters' word
+    _TRAINED.setdefault("losses", losses)
+    assert np.isfinite(losses).all() and losses[3] < losses[0]
+    np.testing.assert_allclose([losses[0], losses[3]], [_TRAINED["losses"][0], _TRAINED["losses"][3]], atol=2e-3)
+
+
+_TRAINED = {}
+
+
+def test_serving_refuses_the_new_layer_kinds_by_name():
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+
+    model = CausalLM(tiny())
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)}))
+    with pytest.raises(NotImplementedError, match="kda"):
+        InferenceEngineV2(model, params)
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        model.to_pipeline(1, params=params)
