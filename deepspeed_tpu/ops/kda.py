@@ -63,11 +63,10 @@ def _scan_fwd(q, k, kb, vb, g, interpret):
     from .pallas import kda as kernel
 
     _count_traced("fwd", "kernel")
-    o, states = kernel.scan_fwd(q, k, kb, vb, g, interpret)
-    # named, so that a block under jax.checkpoint keeps them (models/transformer.py::block_fn) and its
-    # backward does not run the scan a second time to get them back
-    o, states = checkpoint_name(o, SAVED), checkpoint_name(states, SAVED)
-    return o, (q, k, kb, vb, g, states)
+    # named, all three (outputs, every chunk's incoming state and its (I + A)^-1), so that a block under jax.checkpoint
+    # keeps them (models/transformer.py::block_fn) and its backward does not run the scan a second time to get them back
+    o, states, inverses = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(q, k, kb, vb, g, interpret))
+    return o, (q, k, kb, vb, g, states, inverses)
 
 
 def _scan_bwd(interpret, res, do):

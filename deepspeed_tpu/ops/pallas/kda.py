@@ -27,11 +27,20 @@ float32 at the highest precision, instead of a substitution row by row.
 
 The backward kernel walks the chunks from the last to the first with the
 state's cotangent in VMEM, and gets a chunk's gradients as ``jax.vjp`` of the
-SAME chunk function on the state the forward saved for it: one definition of
-the mathematics, differentiated by JAX inside the kernel body and lowered by
-Mosaic like any other kernel code. What enters is q, k, beta*k, beta*v and
-the log-decay, so beta's own gradient, the normalisations, convolutions and
-gates around the scan are XLA's to differentiate (``ops/kda.py``).
+SAME chunk function on what the forward saved for that chunk: the state it
+started from and its ``(I + A)^-1``. JAX differentiates, inside the kernel
+body, everything but the solve: the decays, the pairs on the diagonal, the
+blocks below it, the state's products in and out; Mosaic lowers that like
+any other kernel code. The solve's adjoint is written out
+(``solve_unit_lower``): with ``T = (I + A)^-1`` and ``u = T r``, ``dU = T dr -
+T dA u``, so ``r_bar = T^T u_bar`` and ``A_bar = -r_bar u^T`` below the
+diagonal. So the twelve products that make ``T`` are run once a chunk, in the
+forward, and differentiated nowhere; the backward makes ``u = T r`` again and
+those two products (each three bf16 passes under bf16 operands). ``T`` costs
+``CHUNK^2`` float32 a chunk and head beside the state's ``d_v d_k``. What
+enters is q, k, beta*k, beta*v and the log-decay, so beta's own gradient, the
+normalisations, convolutions and gates around the scan are XLA's to
+differentiate (``ops/kda.py``).
 """
 
 import functools
@@ -56,15 +65,23 @@ def _beside(*parts):
     return jnp.concatenate([p for p in parts if p.shape[1]], axis=1)
 
 
-def _dot3(a, b):
+def _dot3(a, b, dims=_NN):
     """A product of float32 matrices in three bf16 passes where full float32
     takes six: each factor is its bf16 rounding plus a bf16 remainder, and the
     product of the two remainders (2^-16 of the result) is left out."""
     bf16, f32 = jnp.bfloat16, jnp.float32
     a_hi, b_hi = a.astype(bf16), b.astype(bf16)
     a_lo, b_lo = (a - a_hi.astype(f32)).astype(bf16), (b - b_hi.astype(f32)).astype(bf16)
-    dot = lambda x, y: jax.lax.dot_general(x, y, _NN, preferred_element_type=f32)
+    dot = lambda x, y: jax.lax.dot_general(x, y, dims, preferred_element_type=f32)
     return dot(a_hi, b_hi) + dot(a_hi, b_lo) + dot(a_lo, b_hi)
+
+
+def _dot32(a, b, mm, dims=_NN):
+    """A product around the triangular inverse: float32's own under float32
+    operands (``mm``), 2^-16 of it under bf16 ones."""
+    if mm == jnp.float32:
+        return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32, precision=_HIGHEST)
+    return _dot3(a, b, dims)
 
 
 def _inverse_unit_lower(A, dot):
@@ -97,15 +114,46 @@ def _inverse_unit_lower(A, dot):
     return M
 
 
-def chunk_fn(q, k, kb, vb, g, St0, mm):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def solve_unit_lower(A, r, T, mm):
+    """``(u, T)``: ``u = (I + A)^-1 r`` for a strictly lower triangular ``A``
+    (n, n) and ``T = (I + A)^-1``, made here by ``_inverse_unit_lower`` where
+    ``T`` is None and taken as given otherwise (the forward's own, which is a
+    value and carries no gradient). The adjoint is written out, so that no
+    product of the inverse's construction is ever differentiated: from ``dU =
+    T dr - T dA u``, ``r_bar = T^T u_bar`` and ``A_bar = -r_bar u^T``, kept
+    where ``A`` has entries at all, below the diagonal."""
+    if T is None:
+        T = _inverse_unit_lower(A, functools.partial(_dot32, mm=mm))
+    return _dot32(T, r, mm), T
+
+
+def _solve_fwd(A, r, T, mm):
+    u, T = solve_unit_lower(A, r, T, mm)
+    return (u, T), (u, T)
+
+
+def _solve_bwd(mm, saved, bars):
+    u, T = saved
+    r_bar = _dot32(T, bars[0], mm, _TN)  # contracts T's rows: no float32 transpose
+    row, col = (jax.lax.broadcasted_iota(jnp.int32, T.shape, d) for d in (0, 1))
+    return jnp.where(row > col, -_dot32(r_bar, u, mm, _NT), 0.0), r_bar, None
+
+
+solve_unit_lower.defvjp(_solve_fwd, _solve_bwd)
+
+
+def chunk_fn(q, k, kb, vb, g, St0, mm, T=None):
     """One chunk: ``q, k, kb = beta * k`` (C, d_k), ``vb = beta * v`` (C, d_v),
     ``g`` (C, d_k) float32, each token's log-decay (<= 0), ``St0`` (d_v, d_k)
     float32, the incoming state TRANSPOSED (the decay then scales lanes).
-    Returns the outputs (C, d_v) and the outgoing state, float32. ``mm``: the
-    operand type of the large products; the triangular inverse and the
-    system's solution are float32 matrices, multiplied exactly where ``mm``
-    is float32 and by ``_dot3`` under bf16 (the inverse is made by
-    substitution, so 2^-16 a product is 2^-16 of it)."""
+    Returns the outputs (C, d_v), the outgoing state and ``(I + A)^-1``,
+    float32. ``mm``: the operand type of the large products; the triangular
+    inverse and the system's solution are float32 matrices, multiplied exactly
+    where ``mm`` is float32 and by ``_dot3`` under bf16 (the inverse is made
+    by substitution, so 2^-16 a product is 2^-16 of it). ``T``: the inverse
+    as an earlier call on the same operands returned it, which is then not
+    made again."""
     f32 = jnp.float32
     C = q.shape[0]
     q, k, kb, vb = (x.astype(f32) for x in (q, k, kb, vb))
@@ -113,9 +161,6 @@ def chunk_fn(q, k, kb, vb, g, St0, mm):
     def dot(a, b, dims):
         return jax.lax.dot_general(a.astype(mm), b.astype(mm), dims, preferred_element_type=f32,
                                    precision=_HIGHEST if mm == f32 else None)
-
-    def dot32(a, b):  # around the triangular inverse: float32's own under float32 operands, 2^-16 under bf16 ones
-        return jax.lax.dot_general(a, b, _NN, preferred_element_type=f32, precision=_HIGHEST) if mm == f32 else _dot3(a, b)
 
     row, col = (jax.lax.broadcasted_iota(jnp.int32, (C, C), d) for d in (0, 1))
     # G_t = sum_{s <= t} g_s, the cumulative log-decay from the chunk's start: a triangle of ones times g, exactly
@@ -148,35 +193,42 @@ def chunk_fn(q, k, kb, vb, g, St0, mm):
         b_rows.append(_beside(below[SUB:], b_ii, after))
     A, B = jnp.concatenate(a_rows, axis=0), jnp.concatenate(b_rows, axis=0)  # (C, C)
 
-    T = _inverse_unit_lower(A, dot32)
-    u = dot32(T, r)  # (C, d_v)
+    u, T = solve_unit_lower(A, r, T, mm)  # (C, d_v), (C, C)
     o = o_in + dot(B, u, _NN)
     last = G[C - 1:C]
     St1 = St0 * jnp.exp(last) + dot(u, k * jnp.exp(last - G), _TN)  # (d_v, d_k)
-    return o, St1
+    return o, St1, T
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, state, *, mm):
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, t_ref, state, *, mm):
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         state[...] = jnp.zeros_like(state)
 
     st0 = state[...]
     st_ref[0, 0] = st0  # what this chunk started from: the backward's residual
-    o, st1 = chunk_fn(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st0, mm)
+    o, st1, T = chunk_fn(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st0, mm)
     o_ref[0] = o.astype(o_ref.dtype)
+    t_ref[0, 0] = T  # and the inverse it made: the backward neither makes nor differentiates it again
     state[...] = st1
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
+def chunk_bwd(q, k, kb, vb, g, St0, T, do, dSt1, mm):
+    """A chunk's gradients to q, k, kb, vb, g and the incoming state, from the
+    cotangents of its outputs (float32) and of its outgoing state: ``jax.vjp``
+    of ``chunk_fn`` on the state and the inverse the forward saved for it."""
+    _, vjp = jax.vjp(lambda *operands: chunk_fn(*operands, mm, T)[:2], q, k, kb, vb, g, St0)
+    return vjp((do, dSt1))
+
+
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
                 dstate, *, mm):
     @pl.when(pl.program_id(1) == 0)
     def _zero():  # the last chunk: nothing reads the state after it
         dstate[...] = jnp.zeros_like(dstate)
 
-    _, vjp = jax.vjp(functools.partial(chunk_fn, mm=mm), q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0],
-                     st_ref[0, 0])
-    dq, dk, dkb, dvb, dg, dst0 = vjp((do_ref[0].astype(jnp.float32), dstate[...]))
+    dq, dk, dkb, dvb, dg, dst0 = chunk_bwd(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st_ref[0, 0], t_ref[0, 0],
+                                           do_ref[0].astype(jnp.float32), dstate[...], mm)
     for ref, value in ((dq_ref, dq), (dk_ref, dk), (dkb_ref, dkb), (dvb_ref, dvb), (dg_ref, dg)):
         ref[0] = value.astype(ref.dtype)
     dstate[...] = dst0
@@ -188,41 +240,46 @@ def _mm_dtype(x):
 
 def scan_fwd(q, k, kb, vb, g, interpret: bool):
     """(BH, S, d) operands, S a multiple of ``CHUNK`` -> outputs (BH, S, d_v)
-    in ``vb``'s type and every chunk's incoming state (BH, S/CHUNK, d_v, d_k)."""
+    in ``vb``'s type, and for the backward every chunk's incoming state (BH,
+    S/CHUNK, d_v, d_k) and its ``(I + A)^-1`` (BH, S/CHUNK, CHUNK, CHUNK),
+    float32."""
     BH, S, dk = q.shape
     dv = vb.shape[-1]
     nc = S // CHUNK
     rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, c, 0))
+    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, c, 0, 0))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, mm=_mm_dtype(q)),
         name="kda_scan_fwd",
         grid=(BH, nc),
         in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk)],
-        out_specs=[rows(dv), pl.BlockSpec((1, 1, dv, dk), lambda b, c: (b, c, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((BH, S, dv), vb.dtype), jax.ShapeDtypeStruct((BH, nc, dv, dk), jnp.float32)],
+        out_specs=[rows(dv), whole(dv, dk), whole(CHUNK, CHUNK)],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, dv), vb.dtype), jax.ShapeDtypeStruct((BH, nc, dv, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, nc, CHUNK, CHUNK), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
     )(q, k, kb, vb, g)
 
 
-def scan_bwd(q, k, kb, vb, g, states, do, interpret: bool):
+def scan_bwd(q, k, kb, vb, g, states, inverses, do, interpret: bool):
     """Gradients of ``scan_fwd``'s outputs' cotangent ``do`` to q, k, kb, vb
-    (their types) and g (float32), chunks walked from the last to the first."""
+    (their types) and g (float32), chunks walked from the last to the first,
+    each on the state and the inverse ``scan_fwd`` returned for it."""
     BH, S, dk = q.shape
     dv = vb.shape[-1]
     nc = S // CHUNK
     rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, nc - 1 - c, 0))
+    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, nc - 1 - c, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, mm=_mm_dtype(q)),
         name="kda_scan_bwd",
         grid=(BH, nc),
-        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk),
-                  pl.BlockSpec((1, 1, dv, dk), lambda b, c: (b, nc - 1 - c, 0, 0)), rows(dv)],
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk), whole(dv, dk), whole(CHUNK, CHUNK), rows(dv)],
         out_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk)],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, kb, vb)]
         + [jax.ShapeDtypeStruct(g.shape, jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
-    )(q, k, kb, vb, g, states, do)
+    )(q, k, kb, vb, g, states, inverses, do)

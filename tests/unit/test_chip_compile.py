@@ -134,6 +134,11 @@ def _flash_latent(shape):
     return fwd_bwd, (qk, qk, v, v), 2, "fused"
 
 
+# generated code of the scan's forward and backward with the triangular inverse made and differentiated a second time
+# INSIDE the backward kernel (the tree before PR 33, same compiler); without it: 1,282,048 and 1,673,216 bytes
+KDA_CODE_WITH_THE_INVERSE_IN_THE_BACKWARD = {(1, 32, 8192, 128): 1_598_976, (2, 4, 1000, 128): 1_989_120}
+
+
 def _kda(shape):
     """The chunked delta-rule scan, forward and backward: 2 kernels (heads before the sequence)."""
     from deepspeed_tpu.ops.kda import kda_chunked
@@ -183,12 +188,30 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             jax.jit(fn).lower(*args)
         return
     compiled = jax.jit(fn).lower(*args).compile()
+    if case.startswith("kda_scan"):
+        _kda_scan_hands_its_inverses_on(compiled, shapes[0].shape)
     if not bwd_path:
         assert compiled.as_text().count("tpu_custom_call") >= kernels
         return
     # the flash cases name their backward: exactly that many kernels, and the rule picked that path
     assert compiled.as_text().count("tpu_custom_call") == kernels
     assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, bwd_path[0]: 1.0}
+
+
+def _kda_scan_hands_its_inverses_on(compiled, shape):
+    """Exactly the two kernels; the forward's result holds, beside the outputs, two float32 residuals a (head, chunk):
+    the incoming state (d_v, d_k) and ``(I + A)^-1`` (CHUNK, CHUNK); and the program is smaller than it was with the
+    inverse's construction in the backward kernel."""
+    from deepspeed_tpu.ops.pallas.kda import CHUNK
+
+    B, Hh, Sq, Dh = shape
+    heads, chunks = B * Hh, -(-Sq // CHUNK)
+    calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and "kda_scan_fwd" in calls[0] and "kda_scan_bwd" in calls[1]
+    assert Dh == CHUNK  # heads of 128: a state and an inverse have one shape
+    residual = f"f32[{heads},{chunks},{CHUNK},{CHUNK}]"
+    assert calls[0].split(" custom-call(")[0].count(residual) == 2 and calls[1].split(" custom-call(")[1].count(residual) == 2
+    assert compiled.memory_analysis().generated_code_size_in_bytes < KDA_CODE_WITH_THE_INVERSE_IN_THE_BACKWARD[shape]
 
 
 # ---------------------------------------------------------------- the trainer's step on four described chips

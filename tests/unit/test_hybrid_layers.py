@@ -25,6 +25,18 @@ def tiny(**over):
     return TransformerConfig(**dict(base, **over))
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _routed_rows_counters_left_as_found():
+    """The routed layers' counters are the process's, and a benchmark reader that is handed no counter falls back to the
+    process's totals (``benchmarks/lib/program.py::counter``): on a worker that ran this file first,
+    ``tests/benchmarks/test_benchmark_hybrid.py`` would read these tests' rows where it expects none."""
+    names = ("moe_rows_routed_here_total", "moe_rows_dropped_total")
+    found = {name: get_registry().peek(name) or 0.0 for name in names}
+    yield
+    for name in names:
+        get_registry().counter(name).value = found[name]
+
+
 @pytest.fixture(scope="module")
 def highest():
     with jax.default_matmul_precision("highest"):
@@ -124,6 +136,85 @@ def test_the_kda_kernel_stays_exact_where_keys_are_alike(noise, beta, decay, hig
     v = jax.random.normal(ks[2], (1, 1, 256, 32))
     args = (k * 32 ** -0.5, k, v, jnp.full(k.shape, np.log(decay), jnp.float32), jnp.full((1, 1, 256), beta))
     _close(kda_chunked(*args, interpret=True), kda_recurrence(*args), 2e-3)
+    # the gradients too, through the saved inverse and the solve's own adjoint. 5e-4: three times what this reads (1.7e-4
+    # at most over the five operands for identical keys, 5e-5 for the noisy ones; differentiating through the inverse's
+    # twelve products, as the kernel did before, read 3.4e-4 and 7e-5)
+    w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
+    run = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(run(lambda *a: kda_chunked(*a, interpret=True)), run(kda_recurrence)):
+        _close(a, b, 5e-4)
+
+
+def _strictly_lower(case, n):
+    """A chunk's ``A``: random, or ``beta k_t . k_s decay^(t - s)`` of keys that are alike (as the test above has them)."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    t, s = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    if case == "random":
+        return jnp.where(t > s, 0.3 * jax.random.normal(ks[0], (n, n)), 0.0)
+    noise, beta, decay = case
+    k = ref.l2(jax.random.normal(ks[0], (1, 32)) + noise * jax.random.normal(ks[1], (n, 32)))
+    return jnp.where(t > s, beta * (k @ k.T) * decay ** jnp.maximum(t - s, 0).astype(jnp.float32), 0.0)
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["made", "given"])
+@pytest.mark.parametrize("case", ["random", (0.0, 0.99, 1.0), (0.3, 0.9, 0.99)], ids=["random", "identical", "alike"])
+def test_the_triangular_solve_has_the_adjoint_of_a_solve(case, given, highest):
+    """``solve_unit_lower``'s written-out adjoint alone, float32, with the inverse made in the call and handed in,
+    against ``jax.grad`` of ``jnp.linalg.solve(I + A, r)``. Tolerance 7e-4: keys that are alike read 2.3e-4 (all of it
+    the inverse by blocks, as the forward has it: against float64 the library's solve is exact to 3e-7 there), a random
+    ``A``, whose gradient is 3,000 large, 5e-5 (all of it the library's: ours is exact to 4e-7)."""
+    from deepspeed_tpu.ops.pallas.kda import CHUNK, solve_unit_lower
+
+    A = _strictly_lower(case, CHUNK)
+    r, w = (jax.random.normal(jax.random.PRNGKey(i), (CHUNK, 16)) for i in (5, 6))
+    T = solve_unit_lower(A, r, None, jnp.float32)[1] if given else None
+    ours = jax.grad(lambda A, r: jnp.sum(solve_unit_lower(A, r, T, jnp.float32)[0] * w), argnums=(0, 1))(A, r)
+    theirs = jax.grad(lambda A, r: jnp.sum(jnp.linalg.solve(jnp.eye(CHUNK) + A, r) * w), argnums=(0, 1))(A, r)
+    _close(solve_unit_lower(A, r, T, jnp.float32)[0], jnp.linalg.solve(jnp.eye(CHUNK) + A, r), 7e-4)
+    _close(ours[0], jnp.tril(theirs[0], -1), 7e-4)  # A has no entry on or above its diagonal, so no gradient there
+    _close(ours[1], theirs[1], 7e-4)
+    assert float(jnp.max(jnp.abs(jnp.triu(ours[0])))) == 0.0
+
+
+def _count(jaxpr, primitive):
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name == primitive
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                found += _count(inner, primitive) if hasattr(inner, "eqns") else 0
+    return found
+
+
+@pytest.mark.parametrize("mm,most,forward", [(jnp.bfloat16, 41, 50), (jnp.float32, 35, 24)], ids=["bf16", "f32"])
+def test_the_kda_backward_does_not_differentiate_the_triangular_inverse(mm, most, forward):
+    """The backward kernel's body as a jaxpr: 14 products of the chunk forward with ``u = T r`` (three passes under bf16
+    operands, one under float32), 21 cotangent products of the 11 that JAX differentiates, and the solve's adjoint, two
+    more of three passes or of one. With the inverse's construction inside (12 products, 24 cotangent ones) it was 149
+    and 71: an edit that lets it back in fails here and not on the chip. The forward kernel's body keeps its 50 / 24."""
+    from deepspeed_tpu.ops.pallas import kda as K
+
+    x, g = jnp.zeros((K.CHUNK, 128), mm), jnp.zeros((K.CHUNK, 128), jnp.float32)
+    state, inverse = jnp.zeros((128, 128), jnp.float32), jnp.zeros((K.CHUNK, K.CHUNK), jnp.float32)
+    body = jax.make_jaxpr(lambda *a: K.chunk_bwd(*a, mm))(x, x, x, x, g, state, inverse, g, state)
+    assert 0 < _count(body.jaxpr, "dot_general") <= most
+    assert _count(jax.make_jaxpr(lambda *a: K.chunk_fn(*a, mm))(x, x, x, x, g, state).jaxpr, "dot_general") == forward
+
+
+def test_the_kda_forward_saves_the_inverse_of_each_chunk(highest):
+    """``scan_fwd``'s third output times ``I + A``, ``A`` from its definition, is the identity, on two chunks of two heads."""
+    from deepspeed_tpu.ops.pallas.kda import CHUNK, scan_fwd
+
+    q, k, v, g, beta = (x[0] for x in _scan_inputs(2 * CHUNK, 0.9))  # (H, S, d), (H, S)
+    _, states, inverses = scan_fwd(q, k, beta[..., None] * k, beta[..., None] * v, g, interpret=True)
+    assert states.shape == (2, 2, 16, 16) and inverses.shape == (2, 2, CHUNK, CHUNK) and inverses.dtype == jnp.float32
+    chunks = lambda x: x.reshape(2, 2, CHUNK, *x.shape[2:])
+    k, g, beta = chunks(k), chunks(g), chunks(beta)
+    G = jnp.cumsum(g, axis=2)  # from the chunk's start
+    decay = jnp.exp(jnp.minimum(G[:, :, :, None] - G[:, :, None, :], 0.0))  # (H, chunk, t, s, d): only s < t is kept
+    A = jnp.tril(beta[..., None] * jnp.einsum("hctd,hcsd,hctsd->hcts", k, k, decay), -1)
+    _close(jnp.einsum("hcts,hcsr->hctr", inverses, jnp.eye(CHUNK) + A), jnp.broadcast_to(jnp.eye(CHUNK), A.shape), 1e-5)
 
 
 def _routed_layer(held, cfg=None, shared=32):
