@@ -1,7 +1,8 @@
 """Token mixers beside softmax attention over one head size: a gated
-delta-rule linear-attention layer (KDA) and latent attention without
-positions (MLA, NoPE). Both are training-side modules: a block built from
-them takes no KV cache (``inference/v2`` refuses these kinds by name).
+delta-rule linear-attention layer (KDA) and latent attention (MLA), without
+positions or with its shared key part rotated. Both are training-side
+modules: a block built from them takes no KV cache (``inference/v2`` refuses
+these kinds by name).
 """
 
 import flax.linen as nn
@@ -12,7 +13,8 @@ import numpy as np
 from ..ops.attention import attention
 from ..ops.kda import kda
 from ..ops.registry import pallas_available
-from .transformer import RMSNorm, TransformerConfig
+from ..telemetry.registry import get_registry
+from .transformer import RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
 
 
 def _uniform(low, high):
@@ -86,18 +88,22 @@ class KDAMixer(nn.Module):
 
 
 class MLAMixer(nn.Module):
-    """Latent attention with no positions: keys and values are expanded from
-    a latent of ``mla_kv_rank`` (no absorption: this is the training form);
-    a head's query and key are ``mla_qk_nope_dim + mla_qk_rope_dim`` wide, the
-    second part of the key ONE head shared by all and, as nothing rotates
-    either part, simply more key; values are ``mla_v_dim`` wide. The shared
+    """Latent attention: keys and values are expanded from a latent of
+    ``mla_kv_rank`` (no absorption: this is the training form); a head's
+    query and key are ``mla_qk_nope_dim + mla_qk_rope_dim`` wide, the second
+    part of the key ONE head shared by all; values are ``mla_v_dim`` wide.
+    The model's ``pos_emb`` says which of two forms this is. ``"rope"``: the
+    second part of every head's query and the shared key part are rotated by
+    position (``rope_theta``, ``rope_style``, the table ``mla_qk_rope_dim``
+    wide), the first parts are not. Anything else: no positions, nothing
+    rotates, and the shared part is simply more key. Either way the shared
     part is broadcast into every head's key, so the attention kernel sees one
     product of 192 beside values of 128, unpadded (``ops/pallas/flash_attention.py``)."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.cfg
         B, S, _ = x.shape
         H, dn, dr, dv = cfg.n_heads, cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim, cfg.mla_v_dim
@@ -107,10 +113,15 @@ class MLAMixer(nn.Module):
         c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
         c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
         kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
+        if cfg.pos_emb == "rope":
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+            cos, sin = scaled_rope_frequencies(cfg, dr)
+            q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions, style=cfg.rope_style)], axis=-1)
+            k_shared = apply_rope(k_shared[:, :, None, :], cos, sin, positions, style=cfg.rope_style)[:, :, 0, :]  # once, as one head
+            get_registry().counter("mla_rope_traced_total", path="xla").inc()  # rotated by XLA, ahead of the attention call
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
         if not pallas_available():
-            from ..telemetry.registry import get_registry
-
             get_registry().counter("mla_attention_traced_total", **{"pass": "fwd", "path": "xla"}).inc()
         o = attention(q, k, kv[..., dn:], causal=True, scale=(dn + dr)**-0.5)
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,

@@ -95,21 +95,24 @@ class TransformerConfig:
     moe_aux_loss_coef: float = 0.01
     moe_min_capacity: int = 4
     # THE per-layer specification: one (mixer, ffn) pair a layer. mixer: full | window (``sliding_window``) |
-    # kda (gated delta-rule linear attention) | mla (latent attention, no positions); ffn: dense | moe (the
-    # softmax gate with a capacity above) | routed (sigmoid scores, no capacity, a shared expert). None: the
+    # kda (gated delta-rule linear attention) | mla (latent attention: its shared key part rotated where ``pos_emb``
+    # is "rope", else no positions); ffn: dense | moe (the softmax gate with a capacity above) | routed (sigmoid
+    # scores, no capacity, a shared expert). None: the
     # pairs that ``window_layers`` and ``moe_layer_freq`` describe (``kinds``)
     layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
     kda_heads: int = 0  # kda: heads of ``kda_head_dim`` keys and values, a depthwise causal convolution of
     kda_head_dim: int = 128  # ``kda_conv_size`` on q, k and v, gates through ``kda_gate_rank``
     kda_conv_size: int = 4
     kda_gate_rank: int = 128
-    mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (nothing is rotated), v of its own
+    mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (the rope dims rotated under
+    # ``pos_emb="rope"`` by ``rope_theta`` / ``rope_style``, else nothing is), v of its own
     mla_qk_nope_dim: int = 128
     mla_qk_rope_dim: int = 64
     mla_v_dim: int = 128
     # routed: ``moe_num_experts`` router outputs, ``moe_top_k`` a token, experts ``moe_d_ff`` wide
     moe_d_ff: Optional[int] = None  # None: ``ffn_dim``
-    moe_shared_d_ff: int = 0  # width of the shared expert every token also takes; 0: none
+    moe_shared_d_ff: int = 0  # width of the shared expert every token also takes (n shared SwiGLUs of f added are one
+    # of n * f, their columns side by side: give the sum); 0: none
     moe_route_scale: float = 1.0  # the renormalised weights of a token's experts are multiplied by this
     moe_held: Optional[Tuple[int, int]] = None  # (first, count): the experts THIS program holds; None: all
 
@@ -265,7 +268,15 @@ def scaled_rope_frequencies(cfg: "TransformerConfig", head_dim: int) -> Tuple[jn
     (``transformers/modeling_rope_utils.py`` — the parity oracle the
     interop tests check against). Precomputed with numpy: frequencies are
     static per config, and fp64 intermediate math avoids compounding the
-    pow/log chain in fp32."""
+    pow/log chain in fp32. The table is worked out once a configuration and
+    width (``_rope_table``): every kind of block that is traced into a
+    program, and every program, shares it."""
+    cos, sin = _rope_table(cfg, head_dim)
+    return jnp.asarray(cos), jnp.asarray(sin)
+
+
+@functools.lru_cache(maxsize=32)
+def _rope_table(cfg: "TransformerConfig", head_dim: int) -> Tuple[np.ndarray, np.ndarray]:
     rd, theta, factor = head_dim, cfg.rope_theta, cfg.rope_factor
     inv = 1.0 / (theta**(np.arange(0, rd, 2, dtype=np.float64) / rd))
     attn_factor = 1.0
@@ -313,8 +324,7 @@ def scaled_rope_frequencies(cfg: "TransformerConfig", head_dim: int) -> Tuple[jn
         raise NotImplementedError(f"rope_scaling={kind!r} (supported: linear/dynamic/llama3/yarn)")
     t = np.arange(cfg.max_seq_len, dtype=np.float64)
     freqs = np.outer(t, inv)  # (L, rd/2)
-    return (jnp.asarray(np.cos(freqs) * attn_factor, jnp.float32),
-            jnp.asarray(np.sin(freqs) * attn_factor, jnp.float32))
+    return (np.cos(freqs) * attn_factor).astype(np.float32), (np.sin(freqs) * attn_factor).astype(np.float32)
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, positions: jnp.ndarray,
@@ -467,7 +477,7 @@ class Block(nn.Module):
             def run(h, positions, kv_cache, segment_ids):
                 if kv_cache is not None or segment_ids is not None:
                     raise NotImplementedError(f"a {self.kind[0]} layer takes no KV cache and no packed segments yet")
-                return mixer(h)
+                return mixer(h) if self.kind[0] == "kda" else mixer(h, positions)
 
             return run
         return Attention(cfg, window=cfg.sliding_window if self.kind[0] == "window" else None, name="attn")

@@ -154,7 +154,9 @@ class RoutedMoE(nn.Module):
     one times ``scale``. ``held = (first, count)`` says which experts this
     layer holds (None: all): it routes over all, computes its own experts'
     part, and adds the shared expert once; what absent experts would add is
-    left out. No capacity, no drops, no auxiliary loss: cost follows the rows
+    left out. ``shared_ff`` is the shared part's whole width: a model with n
+    shared experts of f gives n * f, since n SwiGLUs added are one with their
+    columns side by side. No capacity, no drops, no auxiliary loss: cost follows the rows
     routed here (``sharded_moe.routed_part``).
 
     With an ``expert`` mesh axis the held experts are split over it by their

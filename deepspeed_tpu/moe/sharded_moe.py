@@ -152,7 +152,12 @@ def _grouped(xs, w, group_sizes, kernel: bool):
     (M, b); rows past the groups' total are not defined."""
     from ..telemetry.registry import get_registry
 
-    tile = lambda n, want: max((t for t in (1024, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
+    def tile(n, want):
+        """The widest listed tile that divides ``n``; a width that only 128 divides (1,408 = 11 x 128) is its own tile
+        while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work."""
+        t = max((t for t in (1024, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
+        return n if t == 128 and n <= 1536 else t
+
     tiling = (tile(xs.shape[0], 256), tile(w.shape[1], 768), tile(w.shape[2], 1024))
     kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
     get_registry().counter("moe_grouped_traced_total", path="kernel" if kernel else "xla").inc()  # the choice, where made
@@ -212,7 +217,7 @@ def _back_to_tokens_bwd(res, dout):
 _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
 
 
-def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool):
+def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True):
     """The part of a routed FFN that the experts ``first .. first + n`` add
     (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (silu(x wg) * x wi)``), for
     tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts.
@@ -222,8 +227,11 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     three grouped products whose cost follows the groups' sizes, and each
     token takes its weighted rows back by the inverse order. ``rows`` bounds
     the buffer, not the routing: the caller gives one that holds every pair
-    routed here (``routed_part``). Returns ((N, d), pairs routed here, those
-    of them the buffer did not hold and so were not computed, the largest and
+    routed here (``routed_part``). ``named``: whether the order, the rows and
+    the products carry their names for a checkpoint policy; without them a
+    block under ``jax.checkpoint`` keeps nothing of this call and makes it
+    again in its backward. Returns ((N, d), pairs routed here, those of them
+    the buffer did not hold and so were not computed, the largest and
     smallest group)."""
     N, k = idx.shape
     n = wg.shape[0]
@@ -240,7 +248,7 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     tok_of_row = pair_of_row // k
     # named: a block under jax.checkpoint keeps the order and the rows (a few tens of MB a layer) and does not sort,
     # gather and multiply a second time in its backward (models/transformer.py::block_fn)
-    keep = lambda x: checkpoint_name(x, SAVED)
+    keep = lambda x: checkpoint_name(x, SAVED) if named else x
     pos, take, pair_of_row, tok_of_row, row_ok, group_sizes = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes))
     xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take))  # (rows, d)
     gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
@@ -253,14 +261,20 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     """``held_experts`` with a buffer that follows the load: four times the
     pairs a uniform router sends to ``n`` of ``num_experts`` experts, and,
     chosen on the device when more arrive, every pair there is. No pair
-    routed to a held expert is dropped at any imbalance."""
+    routed to a held expert is dropped at any imbalance. Only the usual
+    branch names what a checkpointed block keeps: a ``lax.cond`` hands on
+    the residuals of BOTH its branches (zeros for the one not taken), so
+    with the branch that holds every pair named too each layer kept that
+    branch's rows and products as well, most of a GB a layer at 8,192
+    tokens for a branch that a sound router never takes; unnamed, it is
+    made again in the backward if it ever runs."""
     N, k = idx.shape
     n = wg.shape[0]
     every = N * k
     usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
-    run = lambda rows: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel)
+    run = lambda rows, named=True: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
     if usual == every:
         return run(every)()
     local = idx - first
     routed = jnp.sum((local >= 0) & (local < n))
-    return jax.lax.cond(routed <= usual, run(usual), run(every))
+    return jax.lax.cond(routed <= usual, run(usual), run(every, named=False))

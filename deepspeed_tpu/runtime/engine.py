@@ -84,7 +84,7 @@ def _all_finite(tree):
 
 # key on the trainer's first-call line -> the counter of the choice it reports (forward call sites, by ``path``)
 _PATHS = {"kda_path": ("kda_traced_total", {"pass": "fwd"}), "mla_path": ("mla_attention_traced_total", {"pass": "fwd"}),
-          "moe_path": ("moe_grouped_traced_total", {})}
+          "mla_rope": ("mla_rope_traced_total", {}), "moe_path": ("moe_grouped_traced_total", {})}
 
 
 def _paths_traced():
@@ -719,8 +719,9 @@ class DeepSpeedEngine:
     def _layer_kind_notes(self, traced_before):
         """For a model whose layers are of several kinds (``TransformerConfig.
         kinds``): how many layers of each (mixer, ffn) pair, and how the
-        delta-rule scan, latent attention and the routed FFN's grouped
-        products were traced into this program, by the counters that count
+        delta-rule scan, latent attention, the rotation of its shared key
+        part (``mla_rope``; no key where the model has no positions) and the
+        routed FFN's grouped products were traced into this program, by the counters that count
         each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
         fallback), ``mixed``, or no key where the program has none."""
         kinds = getattr(getattr(self.module, "cfg", None), "kinds", None)

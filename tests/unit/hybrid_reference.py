@@ -49,7 +49,16 @@ def kda(p, h, eps=1e-5):
     return jnp.einsum("bshk,hkd->bsd", rms(o, p["o_norm"]["scale"], eps) * gate, p["o_proj"]["kernel"])
 
 
-def mla(p, h, eps=1e-5):
+def rotate_pairs(x, theta):
+    """x (B, S, heads, D) at positions 0 .. S-1: the pair (2i, 2i + 1) of token t turned by t * theta^(-2i / D)."""
+    S, D = x.shape[1], x.shape[-1]
+    angle = (jnp.arange(S, dtype=jnp.float32)[:, None] * theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D))[None, :, None, :]
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * jnp.cos(angle) - odd * jnp.sin(angle), odd * jnp.cos(angle) + even * jnp.sin(angle)], axis=-1).reshape(x.shape)
+
+
+def mla(p, h, eps=1e-5, theta=None):
+    """``theta``: rotate the second part of every head's query and the one shared key part; None: no positions."""
     B, S, _ = h.shape
     q = jnp.einsum("bsd,dhk->bshk", h, p["q_proj"]["kernel"])
     latent = h @ p["kv_a_proj"]["kernel"]
@@ -57,7 +66,10 @@ def mla(p, h, eps=1e-5):
     rope = latent.shape[-1] - rank
     nope = q.shape[-1] - rope
     kv = jnp.einsum("bsr,rhk->bshk", rms(latent[..., :rank], p["kv_a_norm"]["scale"], eps), p["kv_b_proj"]["kernel"])
-    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(latent[:, :, None, rank:], (B, S, q.shape[2], rope))], axis=-1)
+    shared = latent[:, :, None, rank:]
+    if theta is not None:
+        q, shared = jnp.concatenate([q[..., :nope], rotate_pairs(q[..., nope:], theta)], axis=-1), rotate_pairs(shared, theta)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(shared, (B, S, q.shape[2], rope))], axis=-1)
     s = jnp.einsum("bqhk,bthk->bhqt", q, k) * q.shape[-1] ** -0.5
     keep = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
     a = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
@@ -68,16 +80,20 @@ def swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
-def routed(p, h, first, top_k, scale, shared=True):
+def routed(p, h, first, top_k, scale, shared=1):
     """The part of the routed FFN the experts ``first ..`` (as many as the
-    tree holds) add, as a loop over them with a dense mask, plus the shared expert."""
+    tree holds) add, as a loop over them with a dense mask, plus ``shared``
+    shared experts, each a SwiGLU of its own on its columns of the tree's one."""
     x = h.reshape(-1, h.shape[-1])
     scores = jax.nn.sigmoid(x @ p["gate"]["kernel"])
     _, idx = jax.lax.top_k(scores + p["select_bias"], top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
-    y = swiglu(x, p["shared_gate_proj"]["kernel"], p["shared_up_proj"]["kernel"], p["shared_down_proj"]["kernel"]) \
-        if shared else jnp.zeros_like(x)
+    y = jnp.zeros_like(x)
+    for i in range(int(shared)):
+        f = p["shared_gate_proj"]["kernel"].shape[1] // int(shared)
+        cols = slice(i * f, (i + 1) * f)
+        y = y + swiglu(x, p["shared_gate_proj"]["kernel"][:, cols], p["shared_up_proj"]["kernel"][:, cols], p["shared_down_proj"]["kernel"][cols])
     for e in range(p["experts_wg"].shape[0]):
         w_e = jnp.sum(jnp.where(idx == first + e, weights, 0.0), axis=-1, keepdims=True)
         y = y + w_e * swiglu(x, p["experts_wg"][e], p["experts_wi"][e], p["experts_wo"][e])

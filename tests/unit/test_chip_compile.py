@@ -155,6 +155,7 @@ def _kda(shape):
 
 CASES = {
     "flash_latent_b1_s8192_h32_d192_v128": lambda: _flash_latent((1, 8192, 32, 192, 128)),  # kimi-linear-48b-l5e8's MLA layer
+    "flash_latent_b1_s8192_h16_d192_v128": lambda: _flash_latent((1, 8192, 16, 192, 128)),  # kimi-vl-a3b-l6e8's, every layer
     "kda_scan_b1_h32_s8192_d128": lambda: _kda((1, 32, 8192, 128)),                         # ... and its KDA layers
     "kda_scan_b2_h4_s1000_d128": lambda: _kda((2, 4, 1000, 128)),                          # a length that is padded to chunks
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
