@@ -165,43 +165,66 @@ def _grouped(xs, w, group_sizes, kernel: bool):
         return jax.lax.ragged_dot(xs, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling)
+    return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling, interpret=jax.default_backend() != "tpu")
+
+
+def _sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int):
+    """Each token's weighted sum of its own rows in the sorted buffer, by the
+    kernel that walks token tiles (``ops/pallas/moe_sum_rows.py``)."""
+    from ..ops.pallas.moe_sum_rows import sum_rows
+
+    # the forward's calls see no abstract mesh and the backward's an empty one: named here, both are one tracing
+    # context, and ``jax.jit`` traces and lowers the kernel once a shape, not once a context (0.5 s each on the chip's host)
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        return sum_rows(rows, tok_of_row, w_row, spans, n_tokens, interpret=jax.default_backend() != "tpu")
 
 
 @jax.custom_vjp
-def _rows_of(tokens, tok_of_row, row_ok, pos, take):
+def _rows_of(tokens, tok_of_row, row_ok, pos, take, spans):
     """The sorted rows' tokens: ``tokens[tok_of_row]``, zero past the routed
-    rows. Its backward is a gather too (each token sums the rows of its own
-    pairs, found by ``pos``), where XLA's transpose of a gather is a
-    scatter-add that the chip runs row by row."""
+    rows. Its backward is no scatter-add (XLA's transpose of a gather, which
+    the chip runs row by row): each token sums the rows of its own pairs that
+    were taken. With ``spans`` (``held_experts``) that sum is read off the
+    sorted buffer a token tile at a time (``_sum_rows``); without, every
+    pair's row is gathered by ``pos`` into (N, k, d), masked by ``take`` and
+    summed over k."""
     return jnp.where(row_ok, tokens[tok_of_row], 0)
 
 
-def _rows_of_fwd(tokens, tok_of_row, row_ok, pos, take):
-    return _rows_of(tokens, tok_of_row, row_ok, pos, take), (pos, take)
+def _rows_of_fwd(tokens, tok_of_row, row_ok, pos, take, spans):
+    return _rows_of(tokens, tok_of_row, row_ok, pos, take, spans), (tok_of_row, pos, take, spans)
 
 
 def _rows_of_bwd(res, dxs):
-    pos, take = res
+    tok_of_row, pos, take, spans = res
+    if spans is not None:
+        return (_sum_rows(dxs, tok_of_row, jnp.ones(tok_of_row.shape, jnp.float32), spans, pos.shape[0]), None, None, None, None, None)
     picked = dxs[jnp.minimum(pos, dxs.shape[0] - 1)]  # (N, k, d)
-    return (jnp.sum(jnp.where(take[..., None], picked, 0), axis=1), None, None, None, None)
+    return (jnp.sum(jnp.where(take[..., None], picked, 0), axis=1), None, None, None, None, None)
 
 
 _rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
 
 
 @jax.custom_vjp
-def _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take):
+def _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans):
     """Each token's weighted rows: ``sum_j weights[n, j] ys[pos[n, j]]`` over
-    the pairs that were taken. Backward, again by gathers: a row's gradient
-    is its own pair's weight times its token's, a weight's the product of its
-    row with its token's gradient."""
+    the pairs that were taken. With ``spans`` the same sum is read off the
+    sorted buffer a token tile at a time, each row times its own pair's
+    weight (in the rows' type, as the backward below applies it: the product
+    is exact in float32) and summed in float32 (``_sum_rows``); without,
+    every pair's row is gathered into (N, k, d), weighted and summed in the
+    rows' type. Backward, by gathers: a row's gradient is its own pair's
+    weight times its token's, a weight's the product of its row with its
+    token's gradient."""
+    if spans is not None:
+        return _sum_rows(ys, tok_of_row, weights.reshape(-1)[pair_of_row], spans, pos.shape[0])
     picked = ys[jnp.minimum(pos, ys.shape[0] - 1)]  # (N, k, d)
     return jnp.sum(picked * jnp.where(take, weights, 0.0)[..., None].astype(picked.dtype), axis=1)
 
 
-def _back_to_tokens_fwd(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take):
-    return _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take), (ys, weights, tok_of_row, pair_of_row, row_ok, pos, take)
+def _back_to_tokens_fwd(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans):
+    return _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans), (ys, weights, tok_of_row, pair_of_row, row_ok, pos, take)
 
 
 def _back_to_tokens_bwd(res, dout):
@@ -211,7 +234,7 @@ def _back_to_tokens_bwd(res, dout):
     d_ys = jnp.where(row_ok, d_rows * w_rows, 0).astype(ys.dtype)
     dw_rows = jnp.sum(d_rows.astype(jnp.float32) * ys.astype(jnp.float32), axis=-1)  # (rows,)
     d_weights = jnp.where(take, dw_rows[jnp.minimum(pos, ys.shape[0] - 1)], 0.0).astype(weights.dtype)
-    return (d_ys, d_weights, None, None, None, None, None)
+    return (d_ys, d_weights, None, None, None, None, None, None)
 
 
 _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
@@ -220,19 +243,30 @@ _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
 def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True):
     """The part of a routed FFN that the experts ``first .. first + n`` add
     (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (silu(x wg) * x wi)``), for
-    tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts.
+    tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts, a
+    token's ``k`` experts distinct (``lax.top_k``'s).
 
     The (token, choice) pairs are sorted by expert, the ones for experts held
     here first; the first ``rows`` of that order are gathered and go through
     three grouped products whose cost follows the groups' sizes, and each
-    token takes its weighted rows back by the inverse order. ``rows`` bounds
-    the buffer, not the routing: the caller gives one that holds every pair
-    routed here (``routed_part``). ``named``: whether the order, the rows and
-    the products carry their names for a checkpoint policy; without them a
-    block under ``jax.checkpoint`` keeps nothing of this call and makes it
-    again in its backward. Returns ((N, d), pairs routed here, those of them
-    the buffer did not hold and so were not computed, the largest and
-    smallest group)."""
+    token sums its weighted rows. The sort is stable and a pair's index is
+    ``token * k + choice``, so inside an expert's group the rows' tokens
+    ascend strictly: a tile of consecutive tokens has, an expert, ONE
+    contiguous span of rows. On the kernel's path, where the shapes fit it
+    (``ops/pallas/moe_sum_rows.py``), a token's sum, and in the backward the
+    sum of its rows' gradients, is read off those spans a tile at a time, and
+    only the rows routed here are read; elsewhere every pair's row is
+    gathered by the inverse order into (N, k, d), masked and summed over k.
+    ``rows`` bounds the buffer, not the routing: the caller gives one that
+    holds every pair routed here (``routed_part``). ``named``: whether the
+    order, the rows and the products carry their names for a checkpoint
+    policy; without them a block under ``jax.checkpoint`` keeps nothing of
+    this call and makes it again in its backward. Returns ((N, d), pairs
+    routed here, those of them the buffer did not hold and so were not
+    computed, the largest and smallest group)."""
+    from ..ops.pallas import moe_sum_rows
+    from ..telemetry.registry import get_registry
+
     N, k = idx.shape
     n = wg.shape[0]
     local = idx - first
@@ -246,14 +280,17 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     take = here & (pos < rows)
     pair_of_row = order[:rows]
     tok_of_row = pair_of_row // k
+    tiled = kernel and moe_sum_rows.fits(N, rows, tokens.shape[1], n, tokens.dtype)  # off the TPU, or a shape its tiles do not take: the gathers
+    get_registry().counter("moe_combine_traced_total", path="kernel" if tiled else "xla").inc()  # the choice, where made
+    spans = moe_sum_rows.spans(key, n, k, rows) if tiled else None
     # named: a block under jax.checkpoint keeps the order and the rows (a few tens of MB a layer) and does not sort,
     # gather and multiply a second time in its backward (models/transformer.py::block_fn)
-    keep = lambda x: checkpoint_name(x, SAVED) if named else x
-    pos, take, pair_of_row, tok_of_row, row_ok, group_sizes = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes))
-    xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take))  # (rows, d)
+    keep = lambda x: checkpoint_name(x, SAVED) if named and x is not None else x
+    pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans))
+    xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
     gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
     ys = keep(jnp.where(row_ok, _grouped((jax.nn.silu(gate) * up).astype(xs.dtype), wo, group_sizes, kernel), 0))
-    out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take)
+    out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)
     return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 

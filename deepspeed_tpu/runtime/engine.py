@@ -84,7 +84,8 @@ def _all_finite(tree):
 
 # key on the trainer's first-call line -> the counter of the choice it reports (forward call sites, by ``path``)
 _PATHS = {"kda_path": ("kda_traced_total", {"pass": "fwd"}), "mla_path": ("mla_attention_traced_total", {"pass": "fwd"}),
-          "mla_rope": ("mla_rope_traced_total", {}), "moe_path": ("moe_grouped_traced_total", {})}
+          "mla_rope": ("mla_rope_traced_total", {}), "moe_path": ("moe_grouped_traced_total", {}),
+          "moe_combine": ("moe_combine_traced_total", {})}
 
 
 def _paths_traced():

@@ -153,7 +153,21 @@ def _kda(shape):
     return fwd_bwd, (x, x, x, S((B, Hh, Sq, Dh), F32), S((B, Hh, Sq), F32), x), 2
 
 
+def _moe_sum_rows(shape):
+    """A routed layer's tokens sum their own rows off the expert-sorted buffer, each row times its weight: the combine,
+    and with weights of one the backward of the rows' gather."""
+    from deepspeed_tpu.ops.pallas.moe_sum_rows import TOKENS, sum_rows
+
+    N, d, held, rows = shape
+
+    return (lambda buffer, tok_of_row, w_row, spans: sum_rows(buffer, tok_of_row, w_row, spans, N),
+            (S((rows, d), BF16), S((rows,), I32), S((rows,), F32), S((2, N // TOKENS * held), I32)), 1)
+
+
 CASES = {
+    "moe_sum_rows_t8192_d2048_e8_r24576": lambda: _moe_sum_rows((8192, 2048, 8, 24576)),  # kimi-vl-a3b-l6e8's routed layers, the usual buffer
+    "moe_sum_rows_t8192_d2304_e8_r8192": lambda: _moe_sum_rows((8192, 2304, 8, 8192)),    # kimi-linear-48b-l5e8's
+    "moe_sum_rows_t8192_d2304_e8_r65536": lambda: _moe_sum_rows((8192, 2304, 8, 65536)),  # ... and the buffer of every pair (the cond's other branch)
     "flash_latent_b1_s8192_h32_d192_v128": lambda: _flash_latent((1, 8192, 32, 192, 128)),  # kimi-linear-48b-l5e8's MLA layer
     "flash_latent_b1_s8192_h16_d192_v128": lambda: _flash_latent((1, 8192, 16, 192, 128)),  # kimi-vl-a3b-l6e8's, every layer
     "kda_scan_b1_h32_s8192_d128": lambda: _kda((1, 32, 8192, 128)),                         # ... and its KDA layers

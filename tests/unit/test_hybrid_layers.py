@@ -532,10 +532,10 @@ def test_the_five_layer_pattern_trains_through_initialize(pattern, stage, mesh, 
     said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
     if pattern == "linear":
         assert said["layer_kinds"] == "kda+dense:1,kda+routed:3,mla+routed:1" and "mla_rope" not in said  # no positions: no key
-        assert (said["kda_path"], said["mla_path"], said["moe_path"]) == ("xla", "xla", "xla")  # off the TPU: the counters' word
+        assert (said["kda_path"], said["mla_path"], said["moe_path"], said["moe_combine"]) == ("xla",) * 4  # off the TPU: the counters' word
     else:
         assert said["layer_kinds"] == "mla+dense:1,mla+routed:5" and said["block_traces"] == 2 and "kda_path" not in said
-        assert (said["mla_path"], said["mla_rope"], said["moe_path"]) == ("xla", "xla", "xla")
+        assert (said["mla_path"], said["mla_rope"], said["moe_path"], said["moe_combine"]) == ("xla",) * 4
     _TRAINED.setdefault(pattern, losses)
     assert np.isfinite(losses).all() and losses[3] < losses[0]
     np.testing.assert_allclose([losses[0], losses[3]], [_TRAINED[pattern][0], _TRAINED[pattern][3]], atol=2e-3)
