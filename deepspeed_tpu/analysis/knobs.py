@@ -344,10 +344,11 @@ declare("DS_TPU_FLIGHT_PROFILE_MAX_MB", "64", "float",
         "telemetry/flight.py")
 declare("DS_TPU_PROFILE", "0", "bool",
         "Arm a one-shot device-timeline capture at engine construction: "
-        "the next DS_TPU_PROFILE_QUANTA serving quanta are wrapped in a "
-        "jax.profiler trace and parsed into a per-quantum waterfall "
-        "(compute / exposed-vs-overlapped collective / transfer / host "
-        "gap).",
+        "the next DS_TPU_PROFILE_QUANTA serving quanta, or training "
+        "steps, are wrapped in a jax.profiler trace and parsed into a "
+        "per-quantum waterfall (compute / exposed-vs-overlapped "
+        "collective / transfer / host gap) and, for a training step, "
+        "its device time by region and phase.",
         "telemetry/profiler.py")
 declare("DS_TPU_PROFILE_DIR", "profile_captures", "str",
         "Directory for device-timeline capture output (raw trace plus "
@@ -355,7 +356,8 @@ declare("DS_TPU_PROFILE_DIR", "profile_captures", "str",
         "telemetry/profiler.py")
 declare("DS_TPU_PROFILE_QUANTA", "32", "int",
         "Quanta per device-timeline capture window: the trace stops and "
-        "parses after this many dispatch readback boundaries.",
+        "parses after this many dispatch readback boundaries (training: "
+        "optimizer steps).",
         "telemetry/profiler.py")
 declare("DS_TPU_STRAGGLER_X", "4", "float",
         "Straggler detector threshold: flag a rank whose pooled "

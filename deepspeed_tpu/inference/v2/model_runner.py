@@ -40,7 +40,7 @@ from ...ops.pallas.paged_attention import (kv_layer, kv_set_layer, paged_attenti
                                            paged_attention_mixed, paged_attention_prefill,
                                            update_kv_pages)
 from ...ops.registry import REGISTRY
-from ...utils.compile_cache import count_block_trace
+from ...telemetry.tracing import region
 from .modules import _norm_p, _proj, build_modules
 
 _SHARD_MAP_KW = {"check_vma": False}
@@ -218,7 +218,10 @@ def _stack_body(cfg: TransformerConfig, interpret: bool, *, mixed: bool, decode:
         program instead (``inline``), which is then the unrolled one."""
 
         def layer(lp, x, k_pages_i, v_pages_i, block_tables, ctx_lens, slot_mapping, positions, cos, sin, slopes):
-            count_block_trace("serve")  # the Python body: once a trace, not once a call
+            with region("block", site="serve"):  # the Python body: once a trace, not once a call
+                return layer_body(lp, x, k_pages_i, v_pages_i, block_tables, ctx_lens, slot_mapping, positions, cos, sin, slopes)
+
+        def layer_body(lp, x, k_pages_i, v_pages_i, block_tables, ctx_lens, slot_mapping, positions, cos, sin, slopes):
             # shard-local kernels bake the shard's slice of the slopes, a traced value; the others the whole table
             decode_attn, prefill_attn, decode_native = _attn_fns(
                 cfg, interpret, mesh, tp, window, slopes if tp_local is not None else None)

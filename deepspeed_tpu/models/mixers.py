@@ -13,7 +13,7 @@ import numpy as np
 from ..ops.attention import attention
 from ..ops.kda import kda
 from ..ops.registry import pallas_available
-from ..telemetry.registry import get_registry
+from ..telemetry.tracing import region
 from .transformer import RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
 
 
@@ -73,18 +73,20 @@ class KDAMixer(nn.Module):
                            (cfg.kda_conv_size, H, D), f32)
             return nn.silu(causal_conv(heads_first(heads(f"{name}_proj")(x)), w.astype(cfg.dtype)[:, :, None, :], axis=2))
 
-        q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
-        k = l2_normalize(conv_silu("k")).astype(cfg.dtype)
-        v = conv_silu("v")
-        a_log = self.param("A_log", _a_log_init, (H,), f32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), f32)
-        g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
-        beta = jax.nn.sigmoid(nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)))
+        with region("mixer/proj"):  # the projections, their convolutions and the gates
+            q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
+            k = l2_normalize(conv_silu("k")).astype(cfg.dtype)
+            v = conv_silu("v")
+            a_log = self.param("A_log", _a_log_init, (H,), f32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), f32)
+            g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
+            beta = jax.nn.sigmoid(nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)))
         o = kda(q, k, v, g, jnp.swapaxes(beta, 1, 2))  # (B, H, S, D)
-        o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * jax.nn.sigmoid(heads_first(low_rank("g", cfg.dtype)))
-        o = heads_first(o)
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
-                               param_dtype=f32)(o)
+        with region("mixer/proj"):
+            o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * jax.nn.sigmoid(heads_first(low_rank("g", cfg.dtype)))
+            o = heads_first(o)
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                                   param_dtype=f32)(o)
 
 
 class MLAMixer(nn.Module):
@@ -108,21 +110,25 @@ class MLAMixer(nn.Module):
         B, S, _ = x.shape
         H, dn, dr, dv = cfg.n_heads, cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim, cfg.mla_v_dim
         f32 = jnp.float32
-        q = nn.DenseGeneral((H, dn + dr), use_bias=False, name="q_proj", dtype=cfg.dtype, param_dtype=f32)(x)
-        latent = nn.Dense(cfg.mla_kv_rank + dr, use_bias=False, name="kv_a_proj", dtype=cfg.dtype, param_dtype=f32)(x)
-        c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
-        c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
-        kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
-        if cfg.pos_emb == "rope":
-            if positions is None:
-                positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-            cos, sin = scaled_rope_frequencies(cfg, dr)
-            q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions, style=cfg.rope_style)], axis=-1)
-            k_shared = apply_rope(k_shared[:, :, None, :], cos, sin, positions, style=cfg.rope_style)[:, :, 0, :]  # once, as one head
-            get_registry().counter("mla_rope_traced_total", path="xla").inc()  # rotated by XLA, ahead of the attention call
-        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
-        if not pallas_available():
-            get_registry().counter("mla_attention_traced_total", **{"pass": "fwd", "path": "xla"}).inc()
-        o = attention(q, k, kv[..., dn:], causal=True, scale=(dn + dr)**-0.5)
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
-                               param_dtype=f32)(o)
+        with region("mixer/proj"):
+            q = nn.DenseGeneral((H, dn + dr), use_bias=False, name="q_proj", dtype=cfg.dtype, param_dtype=f32)(x)
+            latent = nn.Dense(cfg.mla_kv_rank + dr, use_bias=False, name="kv_a_proj", dtype=cfg.dtype, param_dtype=f32)(x)
+            c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
+            c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
+            kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
+        # the rotation, where the model has positions (``path``: XLA's, ahead of the attention call, is the one form),
+        # and the shared key part's way into every head
+        with region("mixer/rope", **({"path": "xla"} if cfg.pos_emb == "rope" else {})):
+            if cfg.pos_emb == "rope":
+                if positions is None:
+                    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+                cos, sin = scaled_rope_frequencies(cfg, dr)
+                q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions, style=cfg.rope_style)], axis=-1)
+                k_shared = apply_rope(k_shared[:, :, None, :], cos, sin, positions, style=cfg.rope_style)[:, :, 0, :]  # once, as one head
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
+        # a call of unequal head sizes is latent attention's: the flash kernels count it so; off the TPU it is counted here
+        with region("mixer/kernel", **({} if pallas_available() else {"op": "mla", "pass": "fwd", "path": "xla"})):
+            o = attention(q, k, kv[..., dn:], causal=True, scale=(dn + dr)**-0.5)
+        with region("mixer/proj"):
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                                   param_dtype=f32)(o)

@@ -25,7 +25,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
-from ..utils.compile_cache import count_block_trace
+from ..telemetry.tracing import region
 
 
 @dataclass(frozen=True)
@@ -256,6 +256,12 @@ def make_norm(cfg: TransformerConfig):
     return LayerNorm(eps=cfg.norm_eps, dtype=cfg.dtype)
 
 
+def _norm(cfg: TransformerConfig, x):
+    """A block's norms and the model's final one, under their region's name."""
+    with region("norm"):
+        return make_norm(cfg)(x)
+
+
 def rope_frequencies(head_dim: int, max_len: int, theta: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
     inv = 1.0 / (theta**(jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     t = jnp.arange(max_len, dtype=jnp.float32)
@@ -382,21 +388,23 @@ class Attention(nn.Module):
         H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         dense = lambda feats, name: nn.DenseGeneral(feats, axis=-1, use_bias=cfg.use_qkv_bias, name=name,
                                                     dtype=cfg.dtype, param_dtype=jnp.float32)
-        q = dense((H, D), "q_proj")(x)
-        k = dense((KVH, D), "k_proj")(x)
-        v = dense((KVH, D), "v_proj")(x)
-        if cfg.clip_qkv is not None:  # olmo: clamp projections before rope
-            c = cfg.clip_qkv
-            q, k, v = (jnp.clip(t, -c, c) for t in (q, k, v))
-        if cfg.qk_norm:  # qwen3: head-dim RMSNorm before rope
-            q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="k_norm")(k)
+        with region("mixer/proj"):
+            q = dense((H, D), "q_proj")(x)
+            k = dense((KVH, D), "k_proj")(x)
+            v = dense((KVH, D), "v_proj")(x)
+            if cfg.clip_qkv is not None:  # olmo: clamp projections before rope
+                c = cfg.clip_qkv
+                q, k, v = (jnp.clip(t, -c, c) for t in (q, k, v))
+            if cfg.qk_norm:  # qwen3: head-dim RMSNorm before rope
+                q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="k_norm")(k)
 
         if cfg.pos_emb == "rope":
-            rd = cfg.rotary_dim
-            cos, sin = scaled_rope_frequencies(cfg, rd)
-            q = apply_rope(q, cos, sin, positions, rotary_dim=rd, style=cfg.rope_style)
-            k = apply_rope(k, cos, sin, positions, rotary_dim=rd, style=cfg.rope_style)
+            with region("mixer/rope"):
+                rd = cfg.rotary_dim
+                cos, sin = scaled_rope_frequencies(cfg, rd)
+                q = apply_rope(q, cos, sin, positions, rotary_dim=rd, style=cfg.rope_style)
+                k = apply_rope(k, cos, sin, positions, rotary_dim=rd, style=cfg.rope_style)
 
         new_cache = None
         kv_len = None
@@ -412,8 +420,9 @@ class Attention(nn.Module):
         slopes = jnp.asarray(alibi_slopes(H)) if cfg.pos_emb == "alibi" else None
         out = attention(q, k, v, causal=cfg.causal, segment_ids=segment_ids, kv_len=kv_len,
                         alibi_slopes=slopes, window=self.window, scale=cfg.attn_scale)
-        out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=cfg.use_attn_out_bias, name="o_proj",
-                              dtype=cfg.dtype, param_dtype=jnp.float32)(out)
+        with region("mixer/proj"):
+            out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=cfg.use_attn_out_bias, name="o_proj",
+                                  dtype=cfg.dtype, param_dtype=jnp.float32)(out)
         return (out, new_cache) if kv_cache is not None else out
 
 
@@ -422,19 +431,20 @@ class MLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
-        bias = cfg.use_dense_bias
-        if cfg.activation in ("swiglu", "geglu"):
-            gate = nn.Dense(cfg.ffn_dim, use_bias=bias, name="gate_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
-            up = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
-            h = (nn.gelu(gate) if cfg.activation == "geglu" else nn.silu(gate)) * up
-        else:
-            h = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
-            if cfg.activation == "relu":
-                h = nn.relu(h)
-            else:  # HF "gelu" is the exact erf form; "gelu_new"/tanh is our default
-                h = nn.gelu(h, approximate=cfg.activation != "gelu_exact")
-        return nn.Dense(cfg.d_model, use_bias=bias, name="down_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(h)
+        with region("ffn/dense"):
+            cfg = self.cfg
+            bias = cfg.use_dense_bias
+            if cfg.activation in ("swiglu", "geglu"):
+                gate = nn.Dense(cfg.ffn_dim, use_bias=bias, name="gate_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+                up = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+                h = (nn.gelu(gate) if cfg.activation == "geglu" else nn.silu(gate)) * up
+            else:
+                h = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+                if cfg.activation == "relu":
+                    h = nn.relu(h)
+                else:  # HF "gelu" is the exact erf form; "gelu_new"/tanh is our default
+                    h = nn.gelu(h, approximate=cfg.activation != "gelu_exact")
+            return nn.Dense(cfg.d_model, use_bias=bias, name="down_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(h)
 
 
 class Block(nn.Module):
@@ -493,20 +503,20 @@ class Block(nn.Module):
             return attn(h, positions, None, segment_ids), None
 
         if cfg.block_type == "parallel_shared":  # falcon-7b / phi / gpt-j
-            h = make_norm(cfg)(x)
+            h = _norm(cfg, x)
             a, new_cache = run_attn(h)
             x = x + a + self._mlp(cfg, h)
         elif cfg.block_type == "parallel":  # gpt-neox use_parallel_residual
-            a, new_cache = run_attn(make_norm(cfg)(x))
-            x = x + a + self._mlp(cfg, make_norm(cfg)(x))
+            a, new_cache = run_attn(_norm(cfg, x))
+            x = x + a + self._mlp(cfg, _norm(cfg, x))
         elif cfg.norm_scheme == "post":  # BERT: norm AFTER each residual add
             a, new_cache = run_attn(x)
-            x = make_norm(cfg)(x + a)
-            x = make_norm(cfg)(x + self._mlp(cfg, x))
+            x = _norm(cfg, x + a)
+            x = _norm(cfg, x + self._mlp(cfg, x))
         else:
-            a, new_cache = run_attn(make_norm(cfg)(x))
+            a, new_cache = run_attn(_norm(cfg, x))
             x = x + a
-            x = x + self._mlp(cfg, make_norm(cfg)(x))
+            x = x + self._mlp(cfg, _norm(cfg, x))
         return (x, new_cache) if kv_cache is not None else x
 
 
@@ -531,20 +541,21 @@ class Transformer(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         emb = self.param("wte", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.d_model), jnp.float32)
         hook = _BLOCK_HOOK.get() if kv_caches is None and not self.is_initializing() else None
-        x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.moe_num_experts > 0) if hook is not None else None
-        x = (emb[input_ids] if x is None else x).astype(cfg.dtype)
-        if cfg.embed_scale:  # gemma normalizer
-            x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
-        if cfg.pos_emb == "learned":
-            wpe = self.param("wpe", nn.initializers.normal(0.02), (cfg.max_seq_len, cfg.d_model), jnp.float32)
-            x = x + wpe[positions].astype(cfg.dtype)
-        if cfg.type_vocab_size > 0:  # BERT segment embeddings
-            tte = self.param("type_emb", nn.initializers.normal(0.02),
-                             (cfg.type_vocab_size, cfg.d_model), jnp.float32)
-            tti = token_type_ids if token_type_ids is not None else jnp.zeros_like(input_ids)
-            x = x + tte[tti].astype(cfg.dtype)
+        with region("embed"):
+            x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.moe_num_experts > 0) if hook is not None else None
+            x = (emb[input_ids] if x is None else x).astype(cfg.dtype)
+            if cfg.embed_scale:  # gemma normalizer
+                x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
+            if cfg.pos_emb == "learned":
+                wpe = self.param("wpe", nn.initializers.normal(0.02), (cfg.max_seq_len, cfg.d_model), jnp.float32)
+                x = x + wpe[positions].astype(cfg.dtype)
+            if cfg.type_vocab_size > 0:  # BERT segment embeddings
+                tte = self.param("type_emb", nn.initializers.normal(0.02),
+                                 (cfg.type_vocab_size, cfg.d_model), jnp.float32)
+                tti = token_type_ids if token_type_ids is not None else jnp.zeros_like(input_ids)
+                x = x + tte[tti].astype(cfg.dtype)
         if cfg.embedding_norm:  # bloom word_embeddings_layernorm / BERT embeddings.LayerNorm
-            x = make_norm(cfg)(x)
+            x = _norm(cfg, x)
 
         new_caches = [] if kv_caches is not None else None
         remat = cfg.remat and kv_caches is None
@@ -584,7 +595,7 @@ class Transformer(nn.Module):
                 x = y
 
         if cfg.norm_scheme != "post":  # post-LN blocks already end normalized
-            x = make_norm(cfg)(x)
+            x = _norm(cfg, x)
         if cfg.mlm_head:
             # BERT cls.predictions.transform: dense + act + LN before the
             # tied decoder — part of the hidden pipeline so the fused-CE
@@ -595,7 +606,7 @@ class Transformer(nn.Module):
                 x = nn.relu(x)
             else:
                 x = nn.gelu(x, approximate=cfg.activation != "gelu_exact")
-            x = make_norm(cfg)(x)
+            x = _norm(cfg, x)
             # created unconditionally (not only on the logits path) so the
             # param tree is identical between loss and logits calls
             mlm_bias = self.param("mlm_bias", nn.initializers.zeros, (cfg.vocab_size,), jnp.float32)
@@ -603,14 +614,15 @@ class Transformer(nn.Module):
             # loss path: the head projection happens inside the fused CE
             # (ops/fused_ce.py) so full (B,S,V) logits never hit HBM
             return (x, new_caches) if kv_caches is not None else x
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(cfg.dtype))
-            if cfg.mlm_head:  # BERT cls.predictions.bias rides the tied decoder
-                logits = logits + mlm_bias.astype(cfg.dtype)
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, name="lm_head", dtype=cfg.dtype,
-                              param_dtype=jnp.float32)(x)
-        logits = logits.astype(jnp.float32)
+        with region("head"):
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bsd,vd->bsv", x, emb.astype(cfg.dtype))
+                if cfg.mlm_head:  # BERT cls.predictions.bias rides the tied decoder
+                    logits = logits + mlm_bias.astype(cfg.dtype)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=cfg.lm_head_bias, name="lm_head", dtype=cfg.dtype,
+                                  param_dtype=jnp.float32)(x)
+            logits = logits.astype(jnp.float32)
         return (logits, new_caches) if kv_caches is not None else logits
 
     def _scan_blocks(self, block_cls, x, positions, segment_ids, train=True):
@@ -694,8 +706,10 @@ def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: 
     block = Block(cfg, kind, is_training=train)
 
     def apply(params, x, positions, kv_cache, segment_ids):
-        count_block_trace("train")  # the Python body: once a trace, not once a call
-        out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, mutable=_SOWN)
+        # the Python body runs once a trace, not once a call: its count is the kinds of block a program traced. What a
+        # block does outside its parts (the residual adds) falls to this region
+        with region("block", site="train"):
+            out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, mutable=_SOWN)
         return (out if kv_cache is not None else (out, None)), sown
 
     fn = wrap(apply) if wrap is not None else apply
@@ -787,26 +801,27 @@ class CausalLM:
         else:
             hidden = self.apply(params, input_ids, return_hidden=True, **extra)
             aux = 0.0
-        w = leaves[0].astype(cfg.dtype)
-        if "labels" in batch:
-            labels = batch["labels"]
-        else:
-            # shift left; keep S intact (last position ignored) so the fused
-            # CE's sequence chunking stays aligned
-            labels = jnp.concatenate(
-                [input_ids[:, 1:], jnp.full((input_ids.shape[0], 1), -100, input_ids.dtype)], axis=1)
-        hook = _BLOCK_HOOK.get()
-        by_hook = None
-        if hook is not None:
-            by_hook = hook.head(head, leaves, functools.partial(_head_sums, dtype=cfg.dtype, vd_layout=cfg.tie_embeddings),
-                                0 if cfg.tie_embeddings else 1, cfg.moe_num_experts > 0)
-        if by_hook is None:
-            ce = fused_cross_entropy(hidden, w, labels, vd_layout=cfg.tie_embeddings,
-                                     bias=leaves[1] if len(leaves) > 1 else None)
-        else:  # a share of the sum and of the count from each device
-            total, count = by_hook(hidden, labels)
-            ce = jnp.sum(total) / jnp.maximum(jnp.sum(count), 1)
-        return ce + self.cfg.moe_aux_loss_coef * aux
+        with region("head"):
+            w = leaves[0].astype(cfg.dtype)
+            if "labels" in batch:
+                labels = batch["labels"]
+            else:
+                # shift left; keep S intact (last position ignored) so the fused
+                # CE's sequence chunking stays aligned
+                labels = jnp.concatenate(
+                    [input_ids[:, 1:], jnp.full((input_ids.shape[0], 1), -100, input_ids.dtype)], axis=1)
+            hook = _BLOCK_HOOK.get()
+            by_hook = None
+            if hook is not None:
+                by_hook = hook.head(head, leaves, functools.partial(_head_sums, dtype=cfg.dtype, vd_layout=cfg.tie_embeddings),
+                                    0 if cfg.tie_embeddings else 1, cfg.moe_num_experts > 0)
+            if by_hook is None:
+                ce = fused_cross_entropy(hidden, w, labels, vd_layout=cfg.tie_embeddings,
+                                         bias=leaves[1] if len(leaves) > 1 else None)
+            else:  # a share of the sum and of the count from each device
+                total, count = by_hook(hidden, labels)
+                ce = jnp.sum(total) / jnp.maximum(jnp.sum(count), 1)
+            return ce + self.cfg.moe_aux_loss_coef * aux
 
     def to_pipeline(self, num_stages: int, params=None, rng=None, example_batch=None):
         """Split the model into (embed, S stacked stages, head) for the

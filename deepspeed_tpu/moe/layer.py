@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..telemetry.tracing import region
 from .sharded_moe import combine_output, gate_and_dispatch, routed_part, sigmoid_topk
 
 
@@ -180,10 +181,11 @@ class RoutedMoE(nn.Module):
         first, count = self.held if self.held is not None else (0, E)
         tokens = x.reshape(-1, d)
         init = nn.initializers.normal(0.02)
-        logits = nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
-                          precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32))  # which experts: no bf16 pass
-        select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
-        idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
+        with region("ffn/router"):
+            logits = nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32))  # which experts: no bf16 pass
+            select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
+            idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
@@ -192,10 +194,11 @@ class RoutedMoE(nn.Module):
         # (routed here, of them not computed, largest group, smallest group), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack([routed, dropped, largest, smallest]).astype(jnp.int32))
         if self.shared_ff:
-            dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
-                                                 param_dtype=jnp.float32)
-            h = nn.silu(dense(self.shared_ff, "shared_gate_proj")(tokens)) * dense(self.shared_ff, "shared_up_proj")(tokens)
-            out = out + dense(d, "shared_down_proj")(h)
+            with region("ffn/shared"):
+                dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
+                                                     param_dtype=jnp.float32)
+                h = nn.silu(dense(self.shared_ff, "shared_gate_proj")(tokens)) * dense(self.shared_ff, "shared_up_proj")(tokens)
+                out = out + dense(d, "shared_down_proj")(h)
         return out.reshape(x.shape).astype(x.dtype)
 
 

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..telemetry.tracing import region
+
 uniform_map = {}
 SAVED = "routed_ffn"  # the name a routed layer's sorted rows and grouped products carry for a checkpoint policy
 
@@ -150,8 +152,6 @@ def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, s
 def _grouped(xs, w, group_sizes, kernel: bool):
     """Rows sorted by group times that group's matrix: (M, a) x (G, a, b) ->
     (M, b); rows past the groups' total are not defined."""
-    from ..telemetry.registry import get_registry
-
     def tile(n, want):
         """The widest listed tile that divides ``n``; a width that only 128 divides (1,408 = 11 x 128) is its own tile
         while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work."""
@@ -160,12 +160,12 @@ def _grouped(xs, w, group_sizes, kernel: bool):
 
     tiling = (tile(xs.shape[0], 256), tile(w.shape[1], 768), tile(w.shape[2], 1024))
     kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
-    get_registry().counter("moe_grouped_traced_total", path="kernel" if kernel else "xla").inc()  # the choice, where made
-    if not kernel:
-        return jax.lax.ragged_dot(xs, w, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    with region("ffn/experts", path="kernel" if kernel else "xla"):  # the choice, counted where it is made
+        if not kernel:
+            return jax.lax.ragged_dot(xs, w, group_sizes)
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling, interpret=jax.default_backend() != "tpu")
+        return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling, interpret=jax.default_backend() != "tpu")
 
 
 def _sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int):
@@ -265,33 +265,38 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     routed here, those of them the buffer did not hold and so were not
     computed, the largest and smallest group)."""
     from ..ops.pallas import moe_sum_rows
-    from ..telemetry.registry import get_registry
 
     N, k = idx.shape
     n = wg.shape[0]
-    local = idx - first
-    here = (local >= 0) & (local < n)
-    key = jnp.where(here, local, n).reshape(-1)
-    order = jnp.argsort(key)  # stable: held experts first, by expert, tokens in order
-    group_sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
-    routed = jnp.sum(group_sizes)
-    row_ok = (jnp.arange(rows) < routed)[:, None]
-    pos = jnp.argsort(order).reshape(N, k)  # where each pair went
-    take = here & (pos < rows)
-    pair_of_row = order[:rows]
-    tok_of_row = pair_of_row // k
-    tiled = kernel and moe_sum_rows.fits(N, rows, tokens.shape[1], n, tokens.dtype)  # off the TPU, or a shape its tiles do not take: the gathers
-    get_registry().counter("moe_combine_traced_total", path="kernel" if tiled else "xla").inc()  # the choice, where made
-    spans = moe_sum_rows.spans(key, n, k, rows) if tiled else None
-    # named: a block under jax.checkpoint keeps the order and the rows (a few tens of MB a layer) and does not sort,
-    # gather and multiply a second time in its backward (models/transformer.py::block_fn)
     keep = lambda x: checkpoint_name(x, SAVED) if named and x is not None else x
-    pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans))
-    xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
-    gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
-    ys = keep(jnp.where(row_ok, _grouped((jax.nn.silu(gate) * up).astype(xs.dtype), wo, group_sizes, kernel), 0))
-    out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)
-    return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
+    tiled = kernel and moe_sum_rows.fits(N, rows, tokens.shape[1], n, tokens.dtype)  # off the TPU, or a shape its tiles do not take: the gathers
+    with region("ffn/router"):  # the bookkeeping: the two sorts of every pair, the counts, the spans
+        local = idx - first
+        here = (local >= 0) & (local < n)
+        key = jnp.where(here, local, n).reshape(-1)
+        order = jnp.argsort(key)  # stable: held experts first, by expert, tokens in order
+        group_sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+        routed = jnp.sum(group_sizes)
+        row_ok = (jnp.arange(rows) < routed)[:, None]
+        pos = jnp.argsort(order).reshape(N, k)  # where each pair went
+        take = here & (pos < rows)
+        pair_of_row = order[:rows]
+        tok_of_row = pair_of_row // k
+        spans = moe_sum_rows.spans(key, n, k, rows) if tiled else None
+        # named: a block under jax.checkpoint keeps the order and the rows (a few tens of MB a layer) and does not sort,
+        # gather and multiply a second time in its backward (models/transformer.py::block_fn)
+        pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans))
+    with region("ffn/rows", path="kernel" if tiled else "xla"):  # how a token sums its rows: the choice, counted where it is made
+        xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
+    with region("ffn/experts"):
+        gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
+        hidden = (jax.nn.silu(gate) * up).astype(xs.dtype)
+        product = _grouped(hidden, wo, group_sizes, kernel)
+    with region("ffn/rows"):
+        ys = keep(jnp.where(row_ok, product, 0))
+        out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)
+    with region("ffn/router"):
+        return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 
 def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool):
@@ -309,9 +314,18 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     n = wg.shape[0]
     every = N * k
     usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
-    run = lambda rows, named=True: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
+    def run(branch, rows, named=True):
+        def held():
+            with region(branch):
+                return held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
+
+        return held
+
     if usual == every:
-        return run(every)()
-    local = idx - first
-    routed = jnp.sum((local >= 0) & (local < n))
-    return jax.lax.cond(routed <= usual, run(usual), run(every, named=False))
+        return run("branch/usual", every)()
+    # what the conditional itself adds (the zeros the usual branch writes for the other's residuals) has no inner region
+    # and so falls to ``ffn/cond``; the branches' scopes tell their ``ffn/rows`` and ``ffn/experts`` apart
+    with region("ffn/cond"):
+        local = idx - first
+        routed = jnp.sum((local >= 0) & (local < n))
+        return jax.lax.cond(routed <= usual, run("branch/usual", usual), run("branch/every_pair", every, named=False))

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.tracing import region
 from .registry import get_op, register_op
 
 
@@ -191,4 +192,5 @@ def attention_chunked(q: jnp.ndarray,
 
 def attention(q, k, v, **kwargs):
     """Dispatch through the kernel registry (Pallas flash on TPU, XLA otherwise)."""
-    return get_op("attention")(q, k, v, **kwargs)
+    with region("mixer/kernel"):  # the call and the transposes around it; the kernels count which path they took
+        return get_op("attention")(q, k, v, **kwargs)

@@ -13,7 +13,8 @@ For each head, from a zero state::
 On a TPU it is the chunked Pallas kernel (``ops/pallas/kda.py``, forward and
 backward); elsewhere ``kda_recurrence``, a ``lax.scan`` over tokens, which is
 also the kernel's oracle. The choice is counted where it is made, while a
-program is traced (``kda_traced_total{pass,path}``).
+program is traced (``program_regions_traced_total{region="mixer/kernel",
+op="kda", pass, path}``).
 """
 
 import functools
@@ -23,15 +24,15 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..telemetry.tracing import region
 from .registry import pallas_available
 
 SAVED = "kda_scan"  # the name the kernel's outputs carry for a checkpoint policy
 
 
-def _count_traced(pass_: str, path: str):
-    from ..telemetry.registry import get_registry
-
-    get_registry().counter("kda_traced_total", **{"pass": pass_, "path": path}).inc()
+def _traced(pass_: str, path: str):
+    """The region of a scan that was traced as ``path``, counted."""
+    return region("mixer/kernel", op="kda", path=path, **{"pass": pass_})
 
 
 def kda_recurrence(q, k, v, g, beta):
@@ -62,18 +63,18 @@ def _scan(q, k, kb, vb, g, interpret):
 def _scan_fwd(q, k, kb, vb, g, interpret):
     from .pallas import kda as kernel
 
-    _count_traced("fwd", "kernel")
     # named, all three (outputs, every chunk's incoming state and its (I + A)^-1), so that a block under jax.checkpoint
     # keeps them (models/transformer.py::block_fn) and its backward does not run the scan a second time to get them back
-    o, states, inverses = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(q, k, kb, vb, g, interpret))
+    with _traced("fwd", "kernel"):
+        o, states, inverses = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(q, k, kb, vb, g, interpret))
     return o, (q, k, kb, vb, g, states, inverses)
 
 
 def _scan_bwd(interpret, res, do):
     from .pallas import kda as kernel
 
-    _count_traced("bwd", "kernel")
-    return tuple(kernel.scan_bwd(*res, do, interpret))
+    with _traced("bwd", "kernel"):
+        return tuple(kernel.scan_bwd(*res, do, interpret))
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
@@ -101,8 +102,8 @@ def kda_chunked(q, k, v, g, beta, interpret: bool = False):
 
 def kda(q, k, v, g, beta):
     if not pallas_available():
-        _count_traced("fwd", "xla")
-        return kda_recurrence(q, k, v, g, beta)
+        with _traced("fwd", "xla"):
+            return kda_recurrence(q, k, v, g, beta)
     from ..parallel.mesh import get_mesh_topology
     from ..runtime.zero.partition import fit_spec, prune_spec
     from .pallas._utils import on_mesh
@@ -110,4 +111,5 @@ def kda(q, k, v, g, beta):
     # several chips: the kernel sits in a shard_map over the batch axes and, where it divides the heads, the tensor axis
     topo = get_mesh_topology(required=False)
     spec = P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, "tensor", None, None), topo), q.shape, topo)
-    return on_mesh(kda_chunked, (spec, spec, spec, spec, P(*spec[:3])), spec)(q, k, v, g, beta)
+    with region("mixer/kernel"):  # the call with the padding and reshapes around it; ``_scan_fwd`` / ``_scan_bwd`` count the path
+        return on_mesh(kda_chunked, (spec, spec, spec, spec, P(*spec[:3])), spec)(q, k, v, g, beta)

@@ -22,8 +22,8 @@ the blocks that cross the diagonal or a window's far edge (``_kv_runs`` /
 ``_q_runs``); the blocks between take the same ``_scores`` with the mask
 statically off.
 
-What the code observes to choose a path (``flash_attention_traced_total``
-counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
+What the code observes to choose a path (``program_regions_traced_total{region=
+"mixer/kernel", op, pass, path}`` counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
 the query-major dq kernels (dbias is written there) on a (B*H, Sq/bq) grid
 and a dkv kernel on (B*H, Sk/bk). Without a bias there is the fused kernel
 alone: a head whose q, do and dq do not fit ``vmem_budget()`` at once
@@ -266,14 +266,15 @@ def _kv_of_fn(H: int, KVH: int):
 
 def _count_traced(pass_: str, path: str, unequal_heads: bool = False):
     """The kernels are chosen while a program is traced, so that is where the
-    choice is counted (docs/OBSERVABILITY.md): one a call site a trace. A call
-    whose values have another head size than its queries and keys is latent
-    attention's, and is counted under that name too."""
-    from ...telemetry.registry import get_registry
+    choice is counted (docs/OBSERVABILITY.md): one a call site a trace, as
+    ``op="flash"``, or ``"mla"`` for a call whose values have another head size
+    than its queries and keys (latent attention's). The scope is the one the
+    call already runs under (``ops/attention.py``; a ``custom_vjp``'s backward
+    is traced under its forward's name stack)."""
+    from ...telemetry.tracing import region
 
-    get_registry().counter("flash_attention_traced_total", **{"pass": pass_, "path": path}).inc()
-    if unequal_heads:
-        get_registry().counter("mla_attention_traced_total", **{"pass": pass_, "path": "kernel"}).inc()
+    with region("mixer/kernel", op="mla" if unequal_heads else "flash", path=path, **{"pass": pass_}):
+        pass
 
 
 def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: bool, has_alibi: bool,
@@ -318,6 +319,7 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
             jax.ShapeDtypeStruct((BH, Sq // bq, 1, bq), jnp.float32),  # one row a q block: _rows
         ],
         interpret=interpret,
+        name="flash_fwd",  # the custom call's name on the device's clock
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=vmem),
     )(q, k, v, slopes, bias)
     return o, lse.reshape(BH, Sq)
@@ -586,6 +588,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             ],
             scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)] + kv_scratch,
             interpret=interpret,
+            name="flash_bwd",  # the custom call's name on the device's clock
             compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret,
                                              vmem_bytes=fused_vmem),
         )(q, k, v, do, lse_rows, delta_rows, slopes)
@@ -639,6 +642,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
                 jax.ShapeDtypeStruct(dbias_shape, jnp.float32),
             ],
             interpret=interpret,
+            name="flash_dq",  # the custom call's name on the device's clock
             compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=dq_vmem),
         )(q, k, v, do, lse, delta, slopes, bias)
     else:
@@ -677,6 +681,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
                 jax.ShapeDtypeStruct(dbias_shape, jnp.float32),
             ],
             interpret=interpret,
+            name="flash_dq",  # the custom call's name on the device's clock
             compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret,
                                              vmem_bytes=dq_vmem),
         )(q, k, v, do, lse, delta, slopes, bias)
@@ -705,6 +710,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
         ],
         interpret=interpret,
+        name="flash_dkv",  # the custom call's name on the device's clock
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=dkv_vmem),
     )(q, k, v, do, lse_rows, delta_rows, slopes, bias_k)
     return dq, dk, dv, dbias

@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from ...telemetry.tracing import phase_of
 from ...utils.logging import logger
 
 # primitives counted as one FLOP per output element
@@ -57,83 +58,103 @@ def _as_jaxpr(obj):
     return getattr(obj, "jaxpr", obj)
 
 
-def _count_eqns(jaxpr) -> Tuple[float, float]:
-    """Return (flops, macs) for one (open) jaxpr."""
+def _count_eqns(jaxpr, phases: Optional[Dict[str, float]] = None, scale: float = 1.0, stack: str = "") -> Tuple[float, float]:
+    """Return (flops, macs) for one (open) jaxpr. ``phases``, where given,
+    also takes every equation's FLOPs under the phase its name stack says
+    (``telemetry/tracing.py::phase_of``: forward, recomputed, backward,
+    update), ``scale`` times (the trips of an enclosing scan, the devices of
+    a shard_map); ``stack`` is the enclosing equations' name stack, which an
+    equation inside a ``checkpoint`` or a ``jit`` does not repeat."""
     flops = 0.0
     macs = 0.0
+
+    def inner(sub, times=1.0):
+        return _count_eqns(_as_jaxpr(sub), phases, scale * times, here)
+
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         params = eqn.params
+        here = f"{stack}/{eqn.source_info.name_stack}" if phases is not None else ""
+        own = 0.0  # this equation's own FLOPs, what it encloses apart
         if name == "dot_general":
             ((lhs_c, rhs_c), (lhs_b, rhs_b)) = params["dimension_numbers"]
             lhs_shape = eqn.invars[0].aval.shape
             k = int(np.prod([lhs_shape[i] for i in lhs_c])) if lhs_c else 1
             out_elems = _size(eqn.outvars[0])
             macs += out_elems * k
-            flops += 2.0 * out_elems * k
+            own = 2.0 * out_elems * k
         elif name == "conv_general_dilated":
             rhs_shape = eqn.invars[1].aval.shape
             dn = params["dimension_numbers"]
-            groups = int(params.get("feature_group_count", 1))
-            in_features = rhs_shape[dn.rhs_spec[1]]
+            in_features = rhs_shape[dn.rhs_spec[1]]  # feature_group_count is already reflected here
             kernel_spatial = int(np.prod([rhs_shape[i] for i in dn.rhs_spec[2:]])) if len(dn.rhs_spec) > 2 else 1
             out_elems = _size(eqn.outvars[0])
             per_out = in_features * kernel_spatial
             macs += out_elems * per_out
-            flops += 2.0 * out_elems * per_out
-            del groups  # feature_group already reflected in rhs in_features
+            own = 2.0 * out_elems * per_out
         elif name in ("scan",):
-            inner_f, inner_m = _count_eqns(_as_jaxpr(params["jaxpr"]))
             length = int(params.get("length", 1))
+            inner_f, inner_m = inner(params["jaxpr"], length)
             flops += inner_f * length
             macs += inner_m * length
         elif name == "shard_map":  # the body is ONE device's program, at its shapes: every device of the manual axes runs it
-            inner_f, inner_m = _count_eqns(_as_jaxpr(params["jaxpr"]))
             devices = int(np.prod([params["mesh"].shape[a] for a in params["manual_axes"]]))
+            inner_f, inner_m = inner(params["jaxpr"], devices)
             flops += inner_f * devices
             macs += inner_m * devices
         elif name in ("while",):
-            body_f, body_m = _count_eqns(_as_jaxpr(params["body_jaxpr"]))
+            body_f, body_m = inner(params["body_jaxpr"])
             flops += body_f  # trip count unknowable statically; count one iteration
             macs += body_m
-        elif name in ("cond",):
-            branch_counts = [_count_eqns(_as_jaxpr(b)) for b in params["branches"]]
-            bf, bm = max(branch_counts, key=lambda t: t[0]) if branch_counts else (0.0, 0.0)
-            flops += bf
-            macs += bm
+        elif name in ("cond",):  # the dearest branch, and its phases
+            best = (0.0, 0.0, {})
+            for branch in params["branches"]:
+                took: Dict[str, float] = {}
+                bf, bm = _count_eqns(_as_jaxpr(branch), took if phases is not None else None, scale, here)
+                best = max(best, (bf, bm, took), key=lambda t: t[0])
+            flops += best[0]
+            macs += best[1]
+            for phase, f in best[2].items():
+                phases[phase] = phases.get(phase, 0.0) + f
         elif name in _ELEMENTWISE:
-            flops += _size(eqn.outvars[0])
+            own = _size(eqn.outvars[0])
         elif name in _REDUCTIONS:
-            flops += _size(eqn.invars[0])
+            own = _size(eqn.invars[0])
         elif name == "custom_jvp_call" or name == "custom_vjp_call" or name == "custom_vjp_call_jaxpr":
             sub = params.get("call_jaxpr") or params.get("fun_jaxpr")
             if sub is not None:
-                f, m = _count_eqns(_as_jaxpr(sub))
+                f, m = inner(sub)
                 flops += f
                 macs += m
         else:
             counted = False
             for sub in _sub_jaxprs(params):
-                f, m = _count_eqns(_as_jaxpr(sub))
+                f, m = inner(sub)
                 flops += f
                 macs += m
                 counted = True
             if not counted and name in ("pallas_call",):
                 # Pallas kernels are opaque here; approximate by output size
-                flops += sum(_size(v) for v in eqn.outvars)
+                own = sum(_size(v) for v in eqn.outvars)
+        flops += own
+        if own and phases is not None:
+            phase = phase_of(here)
+            phases[phase] = phases.get(phase, 0.0) + own * scale
     return flops, macs
 
 
-def flops_of_jaxpr(closed_jaxpr) -> Tuple[int, int]:
-    """(flops, macs) of a ``ClosedJaxpr`` by structural walk."""
-    f, m = _count_eqns(_as_jaxpr(closed_jaxpr))
+def flops_of_jaxpr(closed_jaxpr, phases: Optional[Dict[str, float]] = None) -> Tuple[int, int]:
+    """(flops, macs) of a ``ClosedJaxpr`` by structural walk; ``phases``, a
+    dict, is filled with the same FLOPs by phase (``_count_eqns``)."""
+    f, m = _count_eqns(_as_jaxpr(closed_jaxpr), phases)
     return int(f), int(m)
 
 
-def flops_of_fn(fn: Callable, *args, **kwargs) -> Tuple[int, int]:
-    """Trace ``fn`` abstractly and count (flops, macs). Works on jitted fns."""
+def flops_of_fn(fn: Callable, *args, phases: Optional[Dict[str, float]] = None, **kwargs) -> Tuple[int, int]:
+    """Trace ``fn`` abstractly and count (flops, macs). Works on jitted fns.
+    ``phases``: as ``flops_of_jaxpr`` (the same walk, the same trace)."""
     jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
-    return flops_of_jaxpr(jaxpr)
+    return flops_of_jaxpr(jaxpr, phases)
 
 
 def breakdown_of_fn(fn: Callable, *args, **kwargs) -> Tuple[int, int, Dict[str, int]]:

@@ -38,8 +38,10 @@ from ..parallel.mesh import MeshTopology, get_mesh_topology, initialize_mesh
 from ..telemetry import MonitorBridge
 from ..telemetry import get_registry as get_telemetry_registry
 from ..telemetry import device_counts
+from ..telemetry import profiler as device_profiler
 from ..telemetry import span as telemetry_span
 from ..telemetry.costs import first_call
+from ..telemetry.tracing import PHASES, region, regions_traced
 from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
                                 get_health_monitor)
 from ..utils.compile_cache import register_cache_metrics
@@ -82,17 +84,34 @@ def _all_finite(tree):
     return jnp.all(jnp.stack(leaves))
 
 
-# key on the trainer's first-call line -> the counter of the choice it reports (forward call sites, by ``path``)
-_PATHS = {"kda_path": ("kda_traced_total", {"pass": "fwd"}), "mla_path": ("mla_attention_traced_total", {"pass": "fwd"}),
-          "mla_rope": ("mla_rope_traced_total", {}), "moe_path": ("moe_grouped_traced_total", {}),
-          "moe_combine": ("moe_combine_traced_total", {})}
+# key on the trainer's first-call line -> the region whose choice it reports, as ``program_regions_traced_total`` labels
+# it (forward call sites, by ``path``)
+_PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}),
+          "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {})}
 
 
 def _paths_traced():
     """{key of ``_PATHS``: (call sites traced as a kernel, in XLA's form) so far}."""
-    reg = get_telemetry_registry()
-    return {key: tuple(int(reg.peek(name, path=path, **labels) or 0) for path in ("kernel", "xla"))
-            for key, (name, labels) in _PATHS.items()}
+    xla = {key: int(regions_traced(name, path="xla", **labels)) for key, (name, labels) in _PATHS.items()}
+    return {key: (int(regions_traced(name, **labels)) - xla[key], xla[key]) for key, (name, labels) in _PATHS.items()}
+
+
+def _program_text(program, args):
+    """A function that gives the text of the executable ``program`` runs on
+    ``args`` (``telemetry/profiler.py::region_card`` reads the regions off its
+    instructions' metadata). Nothing is lowered until it is called: it keeps
+    the arguments' shapes and shardings, not the arrays, and ``jax.jit``
+    answers from the lowering and the executable it already has."""
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding) if isinstance(x, jax.Array) else x, args)
+
+    def text():
+        jitted = program
+        while not hasattr(jitted, "lower") and hasattr(jitted, "__wrapped__"):
+            jitted = jitted.__wrapped__
+        return jitted.lower(*shapes).compile().as_text()
+
+    return text
 
 
 def _batch_tokens(batch) -> int:
@@ -122,6 +141,7 @@ class DeepSpeedEngine:
                  config=None,
                  dont_change_device: bool = False):
         register_cache_metrics(jax)  # seconds of every first call, by phase (program_*_seconds_total)
+        device_profiler.maybe_arm_profiler()  # DS_TPU_PROFILE=1: the first steps that make no first call are captured
         with telemetry_span("init/mesh"):
             if dist_init_required is None or dist_init_required:
                 dist.init_distributed(verbose=False)
@@ -294,7 +314,9 @@ class DeepSpeedEngine:
         # shape (keyed on token count) via the same jaxpr walk the serving
         # cost cards use; 0 means unavailable/disabled and the gauge stays 0
         self._step_flops = 0
+        self._step_flops_by_phase = {}
         self._step_flops_tokens = -1
+        self._made_first_call = False
         self._step_programs_seen = set()  # (program, batch shapes) that have had their first call
         self._peak_flops: Optional[float] = None
         self._monitor_bridge = MonitorBridge(
@@ -423,7 +445,8 @@ class DeepSpeedEngine:
             gather_plan = zero_overlap.plan_for(self.config, self.topology, self.param_specs)
 
         def scaled_loss_fn(params32, batch, rng, scale, comp_state):
-            params_c = _cast_tree(params32, compute_dtype)
+            with region("optimizer"):  # the cast of the master weights to the compute copy, and in the backward of the gradients back
+                params_c = _cast_tree(params32, compute_dtype)
             if comp is not None:
                 params_c = comp.apply(params_c, comp_state)
             # read by the model while its loss is traced: the gather plan, and who takes what it counts on the device
@@ -484,6 +507,10 @@ class DeepSpeedEngine:
         opt = self.optimizer
 
         def apply_updates(params32, opt_state, acc_grads, inv_scale, lr):
+            with region("optimizer"):  # unscale, global norm, the update, the cast back
+                return _apply_updates(params32, opt_state, acc_grads, inv_scale, lr)
+
+        def _apply_updates(params32, opt_state, acc_grads, inv_scale, lr):
             params32 = _fetch(params32)
             grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) * inv_scale, acc_grads)
             finite = _all_finite(grads)
@@ -688,12 +715,20 @@ class DeepSpeedEngine:
         if (name, shapes) in self._step_programs_seen:
             return program(*args)
         self._step_programs_seen.add((name, shapes))
+        self._made_first_call = True  # a capture of the device's time passes this step by (``_note_profiled_step``)
+        prof = device_profiler.get_device_profiler()
+        if prof is not None and name in ("fused_step", "fwd_bwd"):
+            prof.describe(_program_text(program, args))
         counted = ("layers", "regathers", "rings", "head")
         before = [zero_overlap.traced(what) for what in counted]
         paths_before = _paths_traced()
         notes = {}
         with first_call("train", name, notes):
             self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
+            # what share of the executed products is the second forward (``remat``): the FLOPs the gauge's walk counted,
+            # by the phase each equation's name stack says
+            notes.update({f"flops_{phase}": int(self._step_flops_by_phase.get(phase, 0)) for phase in PHASES}
+                         if self._step_flops_by_phase else {})
             out = program(*args)
             layers, regathers, rings, head = (zero_overlap.traced(what) - was for what, was in zip(counted, before))
             notes.update(grad_reduce="bucket" if layers or head else "xla", bucket_layers=layers, bucket_rings=rings,
@@ -748,9 +783,10 @@ class DeepSpeedEngine:
         if self._step_flops_tokens == self._last_microbatch_tokens or not knobs.get_int("DS_TPU_PERF_ACCOUNT"):
             return
         self._step_flops_tokens = self._last_microbatch_tokens
+        self._step_flops_by_phase = {}
         try:
             from ..profiling.flops_profiler import flops_of_fn
-            self._step_flops, _ = flops_of_fn(program, *args)
+            self._step_flops, _ = flops_of_fn(program, *args, phases=self._step_flops_by_phase)
         except Exception:
             self._step_flops = 0  # MFU gauge stays dark; never block training
 
@@ -869,6 +905,9 @@ class DeepSpeedEngine:
                     self._m_mfu.set(self._step_flops * self.gradient_accumulation_steps
                                     / (now_pc - self._last_step_pc) / self._peak_flops)
         self._last_step_pc = now_pc
+        prof = device_profiler.get_device_profiler()  # None unless a capture was ever armed (DS_TPU_PROFILE, the ops plane)
+        if prof is not None:
+            self._note_profiled_step(prof)
         if self.global_steps % self.config.steps_per_print == 0:
             self._report(lr)
         if self.monitor is not None:
@@ -881,6 +920,21 @@ class DeepSpeedEngine:
                 self.health.observe_loss(loss_host)
                 extra.append(("Train/Samples/train_loss", loss_host, self.global_samples))
             self._monitor_bridge.maybe_flush(self.global_steps, extra_events=extra)
+
+    def _note_profiled_step(self, prof):
+        """A step's end as the device profiler's quantum. A step that made a
+        first call is passed by: the capture is of the program running, not
+        of its compilation, so it starts at the first step that made none.
+        Steps are dispatched ahead of the device, so before the marker that
+        starts the capture and before the one that closes it the host waits
+        for the last step's results: the trace then holds whole steps, and
+        every captured step's device time."""
+        if self._made_first_call:
+            self._made_first_call = False
+            return
+        if prof.state == "armed" or prof.closes_next():
+            jax.block_until_ready((self._last_loss, self.params))
+        prof.note_quantum("train/step", step=self.global_steps, tokens=self._last_microbatch_tokens)
 
     def _start_flops_profile(self, batch, step, scale):
         """Reference ``engine.py:1800,1817``: flops profiler on a configured step.

@@ -60,6 +60,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...models.transformer import block_hook
 from ...telemetry.registry import get_registry
+from ...telemetry.tracing import region
 from .partition import zero_axes_for
 
 
@@ -114,7 +115,6 @@ def _ring_reduce_scatter(g, axis: str, size: int, dim: int):
     chunk and an add. The two halves of a chunk travel opposite ways round
     the ring, so both directions of a link carry half a hop."""
     rows = g.shape[dim] // size
-    me = jax.lax.axis_index(axis)
 
     def ring(step, offset, length):
         piece = lambda k: jax.lax.dynamic_slice_in_dim(g, (k % size) * rows + offset, length, axis=dim)
@@ -124,9 +124,11 @@ def _ring_reduce_scatter(g, axis: str, size: int, dim: int):
             acc = jax.lax.ppermute(acc, axis, perm) + piece(me + step * (size - 1 - hop))
         return acc
 
-    if rows % 2:
-        return ring(1, 0, rows)
-    return jax.lax.concatenate([ring(1, 0, rows // 2), ring(-1, rows // 2, rows // 2)], dim)
+    with region("zero/reduce"):
+        me = jax.lax.axis_index(axis)
+        if rows % 2:
+            return ring(1, 0, rows)
+        return jax.lax.concatenate([ring(1, 0, rows // 2), ring(-1, rows // 2, rows // 2)], dim)
 
 
 @functools.cache
@@ -139,7 +141,8 @@ def _ring(axis: str, size: int, dim: int):
 def _gather(axis: str, size: int, dim: int):
     @jax.custom_vjp
     def gather(w):
-        return jax.lax.all_gather(w, axis, axis=dim, tiled=True)
+        with region("zero/gather"):
+            return jax.lax.all_gather(w, axis, axis=dim, tiled=True)
 
     gather.defvjp(lambda w: (gather(w), None), lambda _, g: (_ring(axis, size, dim)(g),))
     return gather
@@ -173,7 +176,8 @@ def _regathering(body, dims, axis: str, size: int):
     gather = lambda w, dim: w if dim is None else jax.lax.all_gather(w, axis, axis=dim, tiled=True)
 
     def whole(params):
-        return jax.tree_util.tree_unflatten(treedef, [gather(w, dim) for w, dim in zip(treedef.flatten_up_to(params), leaf_dims)])
+        with region("zero/gather"):
+            return jax.tree_util.tree_unflatten(treedef, [gather(w, dim) for w, dim in zip(treedef.flatten_up_to(params), leaf_dims)])
 
     @jax.custom_vjp
     def local(params, *rows):
@@ -191,7 +195,8 @@ def _regathering(body, dims, axis: str, size: int):
         params, kept, where = residuals
         slots, kept_def = where.at, where.kept_def
         shards, g = jax.lax.optimization_barrier((treedef.flatten_up_to(params), g))
-        again = {k: gather(shards[k], leaf_dims[k]) for k in set(slots) - {None}}
+        with region("zero/regather"):
+            again = {k: gather(shards[k], leaf_dims[k]) for k in set(slots) - {None}}
         vjp = jax.tree_util.tree_unflatten(kept_def, [leaf if slot is None else again[slot] for leaf, slot in zip(kept, slots)])
         grads, *rest = vjp(g)
         grads = [dw if dim is None else _ring(axis, size, dim)(dw) for dw, dim in zip(treedef.flatten_up_to(grads), leaf_dims)]
@@ -333,7 +338,9 @@ def gathered_block(plan: GatherPlan, layer_specs, keep: bool, apply):
     mapped = jax.shard_map(local, mesh=plan.mesh, in_specs=(layer_specs, rows, rows, rows), out_specs=rows, check_vma=False)
 
     def call(params, x, positions, kv_cache, segment_ids):  # a plan is a training matter: there is no cache
-        return (mapped(params, x, positions, segment_ids), None), {}
+        # what the manual region adds itself, outside the block's parts: ``shard_map``'s sum of the whole leaves' gradients
+        with region("zero/reduce"):
+            return (mapped(params, x, positions, segment_ids), None), {}
 
     return call
 
@@ -354,7 +361,8 @@ def sharded_head(plan: GatherPlan, specs, fn):
 
     def local(leaves, hidden, labels):
         hidden = _gather(axis, size, 0)(hidden)
-        labels = jax.lax.all_gather(labels, axis, axis=0, tiled=True)
+        with region("zero/gather"):
+            labels = jax.lax.all_gather(labels, axis, axis=0, tiled=True)
         return tuple(whole / size for whole in fn(leaves, hidden, labels, vocab_axis=axis))
 
     return jax.shard_map(local, mesh=plan.mesh, in_specs=(specs, P(axis), P(axis)), out_specs=P(axis), check_vma=False)
@@ -376,10 +384,14 @@ def sharded_look_up(plan: GatherPlan, spec: P):
     def to_owner(part):
         return _ring_reduce_scatter(part, axis, size, 0)
 
-    to_owner.defvjp(lambda part: (to_owner(part), None), lambda _, g: (jax.lax.all_gather(g, axis, axis=0, tiled=True),))
+    def gather_rows(x):
+        with region("zero/gather"):
+            return jax.lax.all_gather(x, axis, axis=0, tiled=True)
+
+    to_owner.defvjp(lambda part: (to_owner(part), None), lambda _, g: (gather_rows(g),))
 
     def local(shard, ids):
-        ids = jax.lax.all_gather(ids, axis, axis=0, tiled=True) - jax.lax.axis_index(axis) * shard.shape[0]
+        ids = gather_rows(ids) - jax.lax.axis_index(axis) * shard.shape[0]
         mine = (ids >= 0) & (ids < shard.shape[0])
         # an id of another device's rows is out of bounds: it reads a zero, and its gradient is dropped
         return to_owner(shard.at[jnp.where(mine, ids, shard.shape[0])].get(mode="fill", fill_value=0))

@@ -106,8 +106,10 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
     the step does with gradients); both end up on the span and the line.
     ``programs`` counts what reached the
     backend inside the span, ``block_traces`` the times a transformer
-    block's Python body ran (``program_block_traces_total``: one a kind of
-    block and traced program, not one a layer)."""
+    block's Python body ran (``program_regions_traced_total{region="block"}``:
+    one a kind of block and traced program, not one a layer). The regions
+    traced inside add ``region_trace_s`` (``telemetry/tracing.py::region``:
+    Python seconds by region, on the span and in short on the line)."""
     import jax
 
     from ..utils.compile_cache import PHASE_COUNTERS, PHASES, block_traces, register_cache_metrics
@@ -133,9 +135,12 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
         sp.set(programs=int(programs), block_traces=traced, total_s=total,
                **{k + "_s": v for k, v in phases.items()}, **(notes or {}))
     if sp.attrs is not None:  # the tracer is on
+        by_region = sorted(sp.attrs.get("region_trace_s", {}).items(), key=lambda kv: -kv[1])
+        # the regions' Python seconds in all and the three dearest: a kernel that traces slowly at many sites shows here
+        regions = [f"region_trace_s={sum(v for _, v in by_region):.3f}({','.join(f'{k}:{v:.3f}' for k, v in by_region[:3])})"]
         logger.info("program first call: family=%s bucket=%s %s", family, bucket, " ".join(
             [f"{k}={v}" for k, v in inherited.items()] + [f"total_s={total:.3f}"]
-            + [f"{k}_s={v:.3f}" for k, v in phases.items()] + [f"block_traces={traced}"]
+            + [f"{k}_s={v:.3f}" for k, v in phases.items()] + [f"block_traces={traced}"] + (regions if by_region else [])
             + [f"{k}={v}" for k, v in (notes or {}).items()]))
 
 

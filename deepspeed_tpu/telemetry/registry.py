@@ -209,6 +209,12 @@ class MetricsRegistry:
             return None
         return float(m.count) if m.kind == "histogram" else float(m.value)
 
+    def total(self, name: str, **labels) -> float:
+        """Sum of a counter family's series whose labels include ``labels``
+        (a family whose sites label what they know: ``program_regions_traced_total``)."""
+        want = set(_label_key(labels))
+        return float(sum(m.value for (n, key), m in list(self._metrics.items()) if n == name and want <= set(key)))
+
     def series(self) -> Iterator[Tuple[str, float]]:
         """Flat (dotted_name, value) pairs for every series — the shape the
         MonitorBridge feeds to event writers (dots, not braces, so CSV

@@ -20,6 +20,15 @@ microsecond (PERF.md, PR 25).
 ``dump_trace(path)`` exports the ring as Chrome trace-event JSON
 (load in Perfetto / ``chrome://tracing``) or, for ``*.jsonl`` paths,
 one span per line.
+
+``region("ffn/experts", path="kernel")`` is the other instrument, for code
+that runs while a program is being TRACED, where a span is for code that runs
+each step on the host. It is ``jax.named_scope``: the name reaches the
+``op_name`` of every HLO instruction traced under it, through ``jax.jit``'s
+replay of cached equations, ``jax.checkpoint``, ``custom_vjp``, ``shard_map``
+and ``lax.cond``, and so onto the device's clock at no run-time cost
+(``telemetry/profiler.py`` reads it back: ``region_card``, ``region_times``).
+The names are a closed list in docs/OBSERVABILITY.md, "Regions".
 """
 
 import itertools
@@ -30,6 +39,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from ..analysis import knobs
@@ -179,6 +189,93 @@ def span(name: str, **attrs):
 
 def dump_trace(path) -> str:
     return get_tracer().dump_trace(path)
+
+
+PHASES = ("forward", "recomputed", "backward", "update")
+
+
+def phase_of(op_name: str) -> str:
+    """The phase JAX's transforms wrote into a name stack (an HLO
+    instruction's ``op_name``, or an equation's stack under its enclosing
+    equations'). ``rematted_computation``: the forward made a second time
+    under ``jax.checkpoint`` (``recomputed``); else ``transpose(``: the
+    ``backward``; else ``jvp(``: the ``forward``; what has none of them (the
+    optimizer, and whatever is not differentiated) is the ``update``. The
+    phase is no region: nobody names it."""
+    return ("recomputed" if "rematted_computation" in op_name else "backward" if "transpose(" in op_name
+            else "forward" if "jvp(" in op_name else "update")
+
+
+_REGIONS_SEEN = set()  # every name ``region`` was given in this process: what ``region_card`` looks for in an ``op_name``
+
+
+def regions_seen() -> frozenset:
+    return frozenset(_REGIONS_SEEN)
+
+
+class _TimedRegion:
+    """A region traced inside a program's first call: the scope, and the
+    body's Python seconds less the regions nested in it, summed by name into
+    the first-call span's ``region_trace_s``."""
+    __slots__ = ("name", "_into", "_scope", "_t0", "_nested", "_up")
+
+    def __init__(self, name, into):
+        self.name, self._into = name, into
+
+    def __enter__(self):
+        self._up, _TLS.region, self._nested = getattr(_TLS, "region", None), self, 0.0
+        self._scope = jax.named_scope(self.name)
+        self._scope.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc_val, exc_tb):
+        took = time.perf_counter() - self._t0
+        self._scope.__exit__(exc_type, exc_val, exc_tb)
+        _TLS.region = self._up
+        if self._up is not None:
+            self._up._nested += took
+        self._into[self.name] = self._into.get(self.name, 0.0) + took - self._nested
+        return False
+
+
+def region(name: str, **choice):
+    """``jax.named_scope(name)`` around code that is being traced into a
+    program, and nothing else at run time (the body does not run then).
+    While tracing it also counts ``program_regions_traced_total{region,
+    **choice}`` where the site made a ``choice`` (``path="kernel"`` /
+    ``"xla"``, ``pass`` and ``op`` where a site has them: which form of the
+    part was traced, counted where it is chosen), and, inside a program's
+    first call, adds the body's Python self time to that
+    ``program/first_call`` span's ``region_trace_s`` (a dict by region; a
+    late attribute, so the ring's copy alone). With ``DS_TPU_TELEMETRY=0`` it
+    is the scope alone."""
+    tracer = _TRACER
+    if tracer is None:
+        tracer = get_tracer()
+    _REGIONS_SEEN.add(name)
+    if not tracer.enabled:
+        return jax.named_scope(name)
+    if choice:
+        from .registry import get_registry
+
+        get_registry().counter("program_regions_traced_total", region=name, **choice).inc()
+    up = getattr(_TLS, "top", None)
+    while up is not None and up.name != "program/first_call":
+        up = up._up
+    if up is None:
+        return jax.named_scope(name)
+    if up.attrs is None or "region_trace_s" not in up.attrs:
+        up.set(region_trace_s={})
+    return _TimedRegion(name, up.attrs["region_trace_s"])
+
+
+def regions_traced(region: str, **labels) -> float:
+    """The sum of ``program_regions_traced_total`` over every series of
+    ``region`` whose labels include ``labels``."""
+    from .registry import get_registry
+
+    return get_registry().total("program_regions_traced_total", region=region, **labels)
 
 
 def current_span():

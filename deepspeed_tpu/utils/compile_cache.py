@@ -21,27 +21,16 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 _METRICS_REGISTERED = []
 
-# traces of a transformer block's Python body, by call site: a program pays one a KIND of block, not one a layer
-BLOCK_TRACES = "program_block_traces_total"
-BLOCK_SITES = ("train", "serve")
-
-
-def count_block_trace(site: str) -> None:
-    """Called from the Python body of a block (``models/transformer.py``
-    ``block_fn``, ``inference/v2/model_runner.py`` ``_stack_body``): it runs
-    when the block is traced, not when it is called."""
-    from ..telemetry.registry import get_registry
-
-    # the name as a literal: the docs' catalog test (tests/unit/test_telemetry.py) scans for it
-    get_registry().counter("program_block_traces_total", site=site).inc()
-
-
 def block_traces() -> int:
-    """Block-body traces so far in this process, all sites together."""
-    from ..telemetry.registry import get_registry
+    """Traces of a transformer block's Python body so far in this process:
+    ``program_regions_traced_total{region="block", site}``, counted by the
+    ``region`` that body opens (``models/transformer.py`` ``block_fn``: site
+    ``train``; ``inference/v2/model_runner.py`` ``_stack_body``: ``serve``). It
+    runs when the block is traced, not when it is called: a program pays one a
+    KIND of block, not one a layer. All sites together."""
+    from ..telemetry.tracing import regions_traced
 
-    reg = get_registry()
-    return int(sum(reg.peek(BLOCK_TRACES, site=site) or 0 for site in BLOCK_SITES))
+    return int(regions_traced("block"))
 
 
 def enable_compilation_cache(jax, default_dir: str = CHECKOUT_CACHE_DIR, env_gate: str = "DS_BENCH_NO_CACHE",

@@ -1,6 +1,6 @@
 """A transformer block is traced once a KIND of block and program, not once a
-layer: ``program_block_traces_total{site}`` is incremented in the Python body
-of the block (``models/transformer.py`` ``block_fn``, the v2 runner's
+layer: ``program_regions_traced_total{region="block", site}`` is counted by the
+region the Python body of the block opens (``models/transformer.py`` ``block_fn``, the v2 runner's
 ``_stack_body``), so it counts traces and not calls. And the numbers hold: the
 cached block gives what a plain Python loop over ``Block.apply`` gives.
 """
@@ -20,7 +20,7 @@ from deepspeed_tpu.models import CausalLM, TransformerConfig
 from deepspeed_tpu.models.transformer import Block, make_norm
 from deepspeed_tpu.parallel.mesh import reset_mesh
 from deepspeed_tpu.telemetry.registry import get_registry
-from deepspeed_tpu.utils.compile_cache import BLOCK_TRACES, block_traces
+from deepspeed_tpu.utils.compile_cache import block_traces
 
 LAYERS = 6
 # what makes two layers different kinds -> how many kinds the 6-layer model then has
@@ -40,7 +40,7 @@ def _model(dtype=jnp.float32, **kw):
 
 
 def _traces(site):
-    return int(get_registry().peek(BLOCK_TRACES, site=site) or 0)
+    return int(get_registry().peek("program_regions_traced_total", region="block", site=site) or 0)
 
 
 @pytest.mark.parametrize("kind", KINDS)

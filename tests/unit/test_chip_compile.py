@@ -191,11 +191,11 @@ CASES = {
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip):
-    from deepspeed_tpu.telemetry.registry import get_registry
+    from deepspeed_tpu.telemetry.tracing import regions_traced
 
     fn, shapes, kernels, *bwd_path = CASES[case]()
     args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
-    traced = lambda: {p: get_registry().peek("flash_attention_traced_total", **{"pass": "bwd", "path": p}) or 0.0
+    traced = lambda: {p: regions_traced("mixer/kernel", **{"pass": "bwd", "path": p})  # whichever ``op``
                       for p in ("fused", "split")}
     before = traced()
     if bwd_path == ["refused"]:

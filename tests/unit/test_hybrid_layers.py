@@ -344,7 +344,7 @@ def test_five_layers_trace_three_blocks(highest):
     from deepspeed_tpu.telemetry import device_counts
 
     reg = get_registry()
-    before = reg.peek("program_block_traces_total", site="train") or 0
+    before = reg.peek("program_regions_traced_total", region="block", site="train") or 0
     rows = [reg.peek(n) or 0.0 for n in ("moe_rows_routed_here_total", "moe_rows_dropped_total")]
 
     def loss_and_counts(p):
@@ -354,7 +354,7 @@ def test_five_layers_trace_three_blocks(highest):
     compiled = jax.jit(loss_and_counts).lower(params).compile()
     assert "callback" not in compiled.as_text()  # nothing the persistent compile cache would refuse to keep
     _, reported = compiled(params)
-    assert reg.peek("program_block_traces_total", site="train") - before == 3
+    assert reg.peek("program_regions_traced_total", region="block", site="train") - before == 3
     assert reported["moe_rows"].shape == (4, 4) and reg.peek("moe_rows_routed_here_total") in (None, rows[0])  # not yet counted
     device_counts.count(reported)
     # the four routed layers' rows leave the program as an output: 32 tokens x 4 choices, 8 of 16 held
@@ -371,11 +371,11 @@ def test_six_layers_of_rotated_latent_attention_trace_two_blocks_and_build_one_t
     ids = np.zeros((1, 32), np.int32)
     params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
     reg = get_registry()
-    before = [reg.peek("program_block_traces_total", site="train") or 0, reg.peek("mla_rope_traced_total", path="xla") or 0]
+    before = [reg.peek("program_regions_traced_total", region="block", site="train") or 0, reg.peek("program_regions_traced_total", region="mixer/rope", path="xla") or 0]
     built = T._rope_table.cache_info().misses
     jax.block_until_ready(jax.jit(lambda p: model.loss_fn(p, {"input_ids": ids}))(params))
-    assert reg.peek("program_block_traces_total", site="train") - before[0] == 2
-    assert reg.peek("mla_rope_traced_total", path="xla") - before[1] == 2
+    assert reg.peek("program_regions_traced_total", region="block", site="train") - before[0] == 2
+    assert reg.peek("program_regions_traced_total", region="mixer/rope", path="xla") - before[1] == 2
     info = T._rope_table.cache_info()
     assert (info.misses - built, info.currsize) == (0, 1) and info.hits >= 2  # ``init`` built it; the traces found it
     cos, sin = T.scaled_rope_frequencies(model.cfg, 8)

@@ -120,14 +120,14 @@ def test_the_kernels_path_builds_no_tokens_by_k_by_d_array():
 
 @pytest.mark.parametrize("n_tokens,d,kernel,path", [(N, D, True, "kernel"), (N, D, False, "xla"), (N - 128, D, True, "xla"), (N, D - 64, True, "xla")])
 def test_the_choice_is_counted_where_it_is_made(n_tokens, d, kernel, path):
-    """``moe_combine_traced_total{path}``: the kernel where it is asked for and the shapes fit it (whole tiles of 256
-    tokens, whole lanes), else the gathers; one count a traced ``held_experts``."""
+    """``program_regions_traced_total{region="ffn/rows", path}``: the kernel where it is asked for and the shapes fit it
+    (whole tiles of 256 tokens, whole lanes), else the gathers; one count a traced ``held_experts``."""
     reg = get_registry()
-    before = {p: reg.peek("moe_combine_traced_total", path=p) or 0 for p in ("kernel", "xla")}
+    before = {p: reg.peek("program_regions_traced_total", region="ffn/rows", path=p) or 0 for p in ("kernel", "xla")}
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((n_tokens, d), (n_tokens, 6), (HELD, d, 128), (HELD, d, 128), (HELD, 128, d))]
     idx = jnp.zeros((n_tokens, 6), jnp.int32)
     jax.eval_shape(lambda *a: held_experts(a[0], idx, *a[1:], FIRST, 1024, kernel), *shapes)
-    rose = {p: (reg.peek("moe_combine_traced_total", path=p) or 0) - before[p] for p in before}
+    rose = {p: (reg.peek("program_regions_traced_total", region="ffn/rows", path=p) or 0) - before[p] for p in before}
     assert rose == {"kernel": float(path == "kernel"), "xla": float(path == "xla")}
 
 

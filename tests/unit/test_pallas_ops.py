@@ -119,11 +119,11 @@ _BWD_PATH_CASES = {
 @pytest.mark.parametrize("case", list(_BWD_PATH_CASES))
 def test_flash_bwd_paths_match_xla(case, monkeypatch):
     """dq, dk, dv of every path the backward can take, against attention_xla's
-    vjp, and through ``flash_attention_traced_total`` which path was traced.
+    vjp, and through ``program_regions_traced_total{region="mixer/kernel", pass, path}`` which path was traced.
     Blocks of 64 so that interior, diagonal and window-edge blocks all run."""
     import deepspeed_tpu.ops.pallas.flash_attention as fa
     from deepspeed_tpu.models.transformer import alibi_slopes
-    from deepspeed_tpu.telemetry.registry import get_registry
+    from deepspeed_tpu.telemetry.tracing import regions_traced
 
     shape, kw, path = _BWD_PATH_CASES[case]
     kw = dict(kw)
@@ -147,7 +147,7 @@ def test_flash_bwd_paths_match_xla(case, monkeypatch):
     def grads(attn, rows):
         return jax.grad(lambda q, k, v: jnp.sum(jnp.where(rows, attn(q, k, v, **kw) * w, 0.0)), argnums=(0, 1, 2))(q, k, v)
 
-    counter = lambda p: get_registry().peek("flash_attention_traced_total", **{"pass": "bwd", "path": p}) or 0.0
+    counter = lambda p: regions_traced("mixer/kernel", **{"pass": "bwd", "path": p})  # whichever ``op``
     before = {p: counter(p) for p in ("fused", "split")}
     flash = lambda *a, **kws: flash_attention(*a, interpret=True, **kws)
     if path == "refused":  # by name, with the shape, and before any kernel is chosen

@@ -4,29 +4,44 @@ ops-plane capture endpoint round-trip, the flight recorder's
 manifest-linked + size-bounded profile section, and the telemetry_merge
 profiler-summary path.
 
-The fixture ``fixtures/tiny_device_trace.trace.json`` is hand-written so
-every category total is exact arithmetic:
+The fixture ``fixtures/tiny_device_trace.xspace.txt`` is a hand-written
+XSpace in text form with the lanes a TPU v5e trace has (one plane a chip;
+``XLA Modules``, ``XLA Ops``, ``Async XLA Ops``; the host's threads on
+``/host:CPU``), made into the ``.xplane.pb`` a profiler session leaves by
+``jax.profiler.ProfileData`` itself, so that every case reads it the way a
+capture on the chip is read. Every category total is exact arithmetic:
 
 - compute  [0,1000] + [1500,2000] + [2100,2200]  = 1600 us
-- collective [800,1200] + [2500,2800]            =  700 us
+- collective [800,1200] (an all-reduce from its start to its done, of which
+  the ``-done`` holds the lane for [1000,1200]) + [2500,2800] (synchronous) = 700 us
   exposed (minus compute union): [1000,1200] + [2500,2800] = 500 us
-- transfer [3000,3200]                           =  200 us
-- device busy union                              = 2300 us
-- infra (ThreadpoolListener) and host-lane events are excluded
+- transfer [3000,3200] (a copy's start-to-done span and its ``-done``)  =  200 us
+- device busy (what holds the operation lane)    = 2300 us
+- the whole program's event (``XLA Modules``), the plane's other lines and
+  the host's threads are excluded; a host event that is no span of the
+  program's is not even read
 """
 
 import importlib.util
 import json
 import os
-import shutil
 import sys
 import time
 
 import pytest
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
-                       "tiny_device_trace.trace.json")
+                       "tiny_device_trace.xspace.txt")
 US = 1e-6
+
+
+def _land_fixture(trace_dir, stamp="2026_01_01"):
+    """The fixture where and as jax would put a session's trace."""
+    from jax.profiler import ProfileData
+    dst = os.path.join(trace_dir, "plugins", "profile", stamp)
+    os.makedirs(dst, exist_ok=True)
+    with open(FIXTURE) as f, open(os.path.join(dst, "host.xplane.pb"), "wb") as out:
+        out.write(ProfileData.text_proto_to_serialized_xspace(f.read()))
 
 
 def _load_tool(name):
@@ -49,19 +64,24 @@ def _fresh_profiler_singleton():
 
 # ------------------------------------------------------------------ parsing
 class TestTraceParsing:
+    @pytest.fixture(autouse=True)
+    def _landed(self, tmp_path):
+        _land_fixture(str(tmp_path))
+        self.trace_dir = str(tmp_path)
+
     def _parsed(self):
         from deepspeed_tpu.telemetry import profiler
-        return profiler.parse_trace_events(profiler.load_trace(FIXTURE))
+        return profiler.parse_trace_events(profiler.load_xplane(profiler.find_xplane(self.trace_dir)))
 
     def test_fixture_classifies_every_lane(self):
         parsed = self._parsed()
         cats = {}
         for e in parsed["events"]:
             cats[e["cat"]] = cats.get(e["cat"], 0) + 1
-        # 3 compute + 2 collective + 1 transfer on the device lane,
-        # 1 infra (ThreadpoolListener), 1 host-lane python frame
-        assert cats == {"compute": 3, "collective": 2, "transfer": 1,
-                        "infra": 1, "host": 1}
+        # on the operation lane 3 compute, 2 collective (a -done and a synchronous one), 1 transfer (a -done); the two
+        # start-to-done spans; the program's event; the plane's "Steps" line; of the host's two events the one span
+        assert cats == {"compute": 3, "collective": 3, "transfer": 2,
+                        "module": 1, "other": 1, "host": 1}
 
     def test_golden_waterfall_totals_exact(self):
         from deepspeed_tpu.telemetry import profiler
@@ -110,7 +130,7 @@ class TestTraceParsing:
     def test_empty_trace_yields_zeroed_waterfall(self):
         from deepspeed_tpu.telemetry import profiler
         summary = profiler.build_waterfall(
-            profiler.parse_trace_events({"traceEvents": []}),
+            profiler.parse_trace_events({"planes": []}),
             markers=[], window_s=1.0)
         assert summary["totals"]["device_busy_s"] == 0.0
         assert summary["fractions"]["host_gap"] == 1.0
@@ -130,11 +150,7 @@ class TestTraceParsing:
 def _fake_trace_seams(prof):
     """Swap the jax.profiler seams for a backend that lands the fixture
     where jax would put it."""
-    def start(trace_dir):
-        dst = os.path.join(trace_dir, "plugins", "profile", "2026_01_01")
-        os.makedirs(dst, exist_ok=True)
-        shutil.copy(FIXTURE, os.path.join(dst, "host.trace.json"))
-    prof._start_trace = start
+    prof._start_trace = _land_fixture
     prof._stop_trace = lambda: None
     return prof
 
@@ -270,11 +286,7 @@ class TestFlightProfileSection:
 
         from deepspeed_tpu.telemetry.flight import FlightRecorder
 
-        def fake_start(trace_dir):
-            dst = os.path.join(trace_dir, "plugins", "profile", "t")
-            os.makedirs(dst, exist_ok=True)
-            shutil.copy(FIXTURE, os.path.join(dst, "host.trace.json"))
-        monkeypatch.setattr(jax.profiler, "start_trace", fake_start)
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda trace_dir: _land_fixture(trace_dir, "t"))
         monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
         return FlightRecorder(str(tmp_path), max_captures=4,
                               profile_s=profile_s)

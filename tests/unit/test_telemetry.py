@@ -828,12 +828,11 @@ def test_watchdog_timeout_raises_structured_alert():
 _METRIC_PREFIXES = ("train_", "comm_", "infer_", "kv_", "sched_", "spec_",
                     "compile_cache_", "watchdog_", "telemetry_", "health_",
                     "journal_", "replay_", "autotune_", "program_",
-                    "paged_attention_", "flash_attention_")
+                    "paged_attention_")
 # profile_* metrics are listed explicitly: a bare "profile_" prefix would
 # also match the `profile_captures` knob-default directory name in docs
 _EXTRA_METRICS = {"last_step_completed_unix", "tp_degree",
-                  "kda_traced_total", "mla_attention_traced_total", "mla_rope_traced_total", "moe_rows_routed_here_total",
-                  "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min", "moe_grouped_traced_total", "moe_combine_traced_total",
+                  "moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min",
                   "profile_captures_total",
                   "profile_collective_exposed_fraction",
                   "profile_device_busy_fraction",

@@ -10,12 +10,15 @@ Usage:
 ``DS_TPU_PROFILE_DIR`` holding ``capture-*`` subdirectories (the newest
 summarised capture is picked), a raw profiler output directory (e.g. a
 flight capture's ``profile/`` — parsed on the fly as one window), a
-``summary.json`` file, or a raw ``.trace.json[.gz]`` file.
+``summary.json`` file, or a raw ``.xplane.pb`` file.
 
 Output: the waterfall table (per-quantum device compute / collective
 split exposed-vs-overlapped / transfer / host gap), the top-N device
-programs, and the exposed-collective summary cross-checked against the
-``tp_all_reduce`` ledger. ``--json`` dumps the summary document instead.
+operations, the exposed-collective summary cross-checked against the
+``tp_all_reduce`` ledger and, for a capture of a training run
+(``DS_TPU_PROFILE=1``: docs/OBSERVABILITY.md, "Regions"), the compiled
+step's device time by region and phase. ``--json`` dumps the summary
+document instead.
 
 ``smoke`` captures an 8-request fused serving run end-to-end (arm →
 trace → parse) and asserts nonzero device time and a well-formed
@@ -37,9 +40,8 @@ def _load_summary(target):
     from deepspeed_tpu.telemetry import profiler as prof
 
     if os.path.isfile(target):
-        if target.endswith((".trace.json", ".trace.json.gz")):
-            summary = prof.build_waterfall(
-                prof.parse_trace_events(prof.load_trace(target)), markers=[])
+        if target.endswith(".xplane.pb"):
+            summary = prof.build_waterfall(prof.parse_trace_events(prof.load_xplane(target)), markers=[])
             summary["trace"] = "ok"
             return summary
         with open(target) as f:
@@ -113,6 +115,57 @@ def render(summary, top=8):
         lines.append(f"  tp_all_reduce ledger: {json.dumps(ledger, sort_keys=True)}")
     if "error" in summary:
         lines.append(f"  note: {summary['error']}")
+    if summary.get("idle_by_span"):
+        lines.append("")
+        lines.append("idle time of the first device, by the host span it lay under (ms): "
+                     + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in summary["idle_by_span"].items()))
+    if summary.get("capture_cost_s"):
+        lines.append("the capture cost (s): " + ", ".join(f"{k} {v}" for k, v in summary["capture_cost_s"].items()))
+    if summary.get("regions"):
+        lines += ["", render_regions(summary["regions"], top=top)]
+    return "\n".join(lines)
+
+
+PHASES = ("forward", "recomputed", "backward", "update")
+
+
+def render_regions(regions, top=8):
+    """The compiled step's device time by region and phase, ms a step."""
+    if "table" not in regions:
+        return f"regions: {regions.get('error', 'none')}"
+    ms = lambda s: f"{float(s) * 1e3:10.3f}"
+    table = regions["table"]
+    lines = [f"regions of {regions['module']}: {regions['steps']} steps on {regions['devices']} device(s), ms a step; "
+             f"device self time {float(regions['step_self_s']) * 1e3:.3f}, the program's events {float(regions['step_module_s']) * 1e3:.3f}, "
+             f"start to start {float(regions.get('step_period_s', 0.0)) * 1e3:.3f}",
+             f"  {'region':<20}" + "".join(f"{p:>11}" for p in PHASES) + f"{'all':>11}{'share':>8}"]
+    total = float(regions["step_self_s"]) or 1.0
+    for region, row in sorted(table.items(), key=lambda kv: -sum(kv[1].values())):
+        lines.append(f"  {region:<20}" + "".join(ms(row.get(p, 0.0)) + " " for p in PHASES) + ms(sum(row.values()))
+                     + f" {100 * sum(row.values()) / total:6.2f}%")
+    by_phase = [sum(row.get(p, 0.0) for row in table.values()) for p in PHASES]
+    lines.append(f"  {'all':<20}" + "".join(ms(v) + " " for v in by_phase) + ms(sum(by_phase)) + f" {100 * sum(by_phase) / total:6.2f}%")
+    lines.append(f"  unattributed {100 * regions['unattributed_share']:.2f}% of the device self time; in fusions that span "
+                 f"several regions {100 * regions['mixed_share']:.2f}% ({float(regions['mixed_s']) * 1e3:.3f} ms), charged to "
+                 f"the region of their product, else of their root")
+    for row in regions.get("mixed", [])[:top]:
+        lines.append(f"    {ms(row['s'])} ms  {row['fusion']} -> {row['region']}/{row['phase']}  holds "
+                     + ", ".join(f"{r} x{n}" for r, n in sorted(row["members"].items())))
+    if regions.get("unattributed"):
+        lines.append("  the longest with no region: " + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in sorted(regions["unattributed"].items(), key=lambda kv: -kv[1])[:top]))
+    if regions.get("compiler_made"):
+        lines.append("  what the compiler made itself, by opcode and the region it was given (ms a step): "
+                     + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in sorted(regions["compiler_made"].items(), key=lambda kv: -kv[1])[:2 * top]))
+    if regions.get("within"):
+        lines.append("  by what encloses them (ms a step):")
+        for outer, row in regions["within"].items():
+            if outer != "block":
+                lines.append(f"    {outer}: " + ", ".join(f"{r} {float(v) * 1e3:.3f}" for r, v in sorted(row.items(), key=lambda kv: -kv[1])))
+    if regions.get("kernels"):
+        lines.append("  Pallas calls (ms a step): " + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in sorted(regions["kernels"].items(), key=lambda kv: -kv[1])))
+    lines.append("  how the card found the region (ms a step): " + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in regions.get("found", {}).items()))
+    if regions.get("note"):
+        lines.append(f"  NOTE: {regions['note']}")
     return "\n".join(lines)
 
 
@@ -180,7 +233,8 @@ def cmd_smoke(args) -> int:
               file=sys.stderr)
         return 1
     print(render(summary))
-    failures = check_waterfall(summary, require_device_time=True)
+    # device lanes are a TPU's planes: on the CPU the capture is checked for its form alone
+    failures = check_waterfall(summary, require_device_time=jax.default_backend() == "tpu")
     for msg in failures:
         print(f"smoke: FAIL — {msg}", file=sys.stderr)
     if not failures:
@@ -198,7 +252,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("target",
                     help="capture dir, DS_TPU_PROFILE_DIR, raw profiler dir, "
-                         "summary.json, or .trace.json[.gz] — or 'smoke'")
+                         "summary.json, or .xplane.pb — or 'smoke'")
     ap.add_argument("--json", action="store_true",
                     help="dump the summary document instead of tables")
     ap.add_argument("--top", type=int, default=8,
