@@ -132,6 +132,7 @@ def _count_rows(rows):
     reg.counter("moe_rows_dropped_total").inc(float(rows[:, 1].sum()))
     reg.gauge("moe_expert_rows_max").set(float(rows[:, 2].max()))
     reg.gauge("moe_expert_rows_min").set(float(rows[:, 3].min()))
+    reg.counter("moe_fallback_layers_total").inc(float(rows[:, 4].sum()))
 
 
 def report_rows(intermediates):
@@ -189,10 +190,10 @@ class RoutedMoE(nn.Module):
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
-        out, routed, dropped, largest, smallest = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo,
-                                                                    first, E, moe_path() == "kernel")
-        # (routed here, of them not computed, largest group, smallest group), sown: ``report_rows`` hands them on
-        self.sow("intermediates", "rows", jnp.stack([routed, dropped, largest, smallest]).astype(jnp.int32))
+        out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, moe_path() == "kernel")
+        # (routed here, of them not computed, largest group, smallest group, whether the branch that holds every pair
+        # ran), sown: ``report_rows`` hands them on
+        self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
         if self.shared_ff:
             with region("ffn/shared"):
                 dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
@@ -221,15 +222,15 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
 
     def local(tokens, idx, weights, wg, wi, wo):
         mine = first + (jax.lax.axis_index("expert") * wg.shape[0] if held != P() else 0)
-        out, routed, dropped, largest, smallest = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel)
+        out, routed, dropped, largest, smallest, fallback = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel)
         if held != P():
             out = jax.lax.psum(out, "expert")
         if over:
-            routed, dropped = jax.lax.psum(routed, over), jax.lax.psum(dropped, over)
+            routed, dropped, fallback = (jax.lax.psum(x, over) for x in (routed, dropped, fallback))
             largest, smallest = jax.lax.pmax(largest, over), jax.lax.pmin(smallest, over)
-        return out, routed, dropped, largest, smallest
+        return out, routed, dropped, largest, smallest, fallback
 
-    return on_mesh(local, (rows, rows, rows, held, held, held), (rows, P(), P(), P(), P()))(tokens, idx, weights, wg, wi, wo)
+    return on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 5)(tokens, idx, weights, wg, wi, wo)
 
 
 def _mesh_has_axis(axis: str) -> bool:

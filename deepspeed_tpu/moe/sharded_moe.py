@@ -299,33 +299,72 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 
+def _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel):
+    """``routed_part``'s fallback: ``held_experts`` with a buffer that holds
+    every pair. As ``_every_pair`` it keeps NOTHING for its backward but its
+    operands, which the conditional's caller holds anyway: if the branch is
+    ever taken, its backward makes the call again and differentiates it
+    there. A ``lax.cond`` under differentiation hands on the residuals of
+    BOTH its branches, so whatever this one kept (its sorted rows and grouped
+    products, each of every pair's row count), the usual branch wrote zeros
+    for, a layer and a step."""
+    with region("branch/every_pair"):
+        return held_experts(tokens, idx, weights, wg, wi, wo, first, idx.size, kernel, named=False)
+
+
+_every_pair = jax.custom_vjp(_hold_every_pair, nondiff_argnums=(7,))
+
+
+def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, kernel):
+    return _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel), (tokens, idx, weights, wg, wi, wo, first)
+
+
+def _every_pair_bwd(kernel, res, cotangents):
+    tokens, idx, weights, wg, wi, wo, first = res
+    _, back = jax.vjp(lambda t, w, g, i, o: _hold_every_pair(t, idx, w, g, i, o, first, kernel)[0], tokens, weights, wg, wi, wo)
+    d_tokens, d_weights, d_wg, d_wi, d_wo = back(cotangents[0])  # the four counts take no cotangent
+    return d_tokens, None, d_weights, d_wg, d_wi, d_wo, None
+
+
+_every_pair.defvjp(_every_pair_fwd, _every_pair_bwd)
+
+
 def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool):
     """``held_experts`` with a buffer that follows the load: four times the
     pairs a uniform router sends to ``n`` of ``num_experts`` experts, and,
     chosen on the device when more arrive, every pair there is. No pair
-    routed to a held expert is dropped at any imbalance. Only the usual
-    branch names what a checkpointed block keeps: a ``lax.cond`` hands on
-    the residuals of BOTH its branches (zeros for the one not taken), so
-    with the branch that holds every pair named too each layer kept that
-    branch's rows and products as well, most of a GB a layer at 8,192
-    tokens for a branch that a sound router never takes; unnamed, it is
-    made again in the backward if it ever runs."""
+    routed to a held expert is dropped at any imbalance.
+
+    A ``lax.cond`` under differentiation hands on the residuals of BOTH its
+    branches (zeros for the one not taken). So the usual branch alone names
+    what a checkpointed block keeps, and the branch that holds every pair
+    keeps nothing at all (``_every_pair``): the conditional's residuals are
+    the usual branch's and the fallback's operands, and nothing of every
+    pair's row count is written for a branch that a sound router takes in
+    a step out of hundreds, if ever. What the fallback costs when it does
+    run: its forward a second time inside its backward. Under
+    ``jax.checkpoint`` (the block's) that is what an unnamed branch cost
+    before; without, it is a slower backward in a step whose router sent
+    more than four times the uniform load to the held experts.
+    ``moe_fallback_layers_total`` counts such steps.
+
+    Returns ``held_experts``'s five values and whether the fallback ran
+    (0 or 1)."""
     N, k = idx.shape
     n = wg.shape[0]
     every = N * k
     usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
-    def run(branch, rows, named=True):
-        def held():
-            with region(branch):
-                return held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
 
-        return held
+    def held():
+        with region("branch/usual"):
+            return held_experts(tokens, idx, weights, wg, wi, wo, first, usual, kernel)
 
     if usual == every:
-        return run("branch/usual", every)()
-    # what the conditional itself adds (the zeros the usual branch writes for the other's residuals) has no inner region
-    # and so falls to ``ffn/cond``; the branches' scopes tell their ``ffn/rows`` and ``ffn/experts`` apart
-    with region("ffn/cond"):
+        return *held(), jnp.zeros((), jnp.int32)
+    # what the conditional itself adds (whatever one branch writes for the other's residuals) has no inner region and so
+    # falls to ``ffn/cond``; the branches' scopes tell their ``ffn/rows`` and ``ffn/experts`` apart
+    with region("ffn/cond", path="fallback_keeps_nothing"):
         local = idx - first
         routed = jnp.sum((local >= 0) & (local < n))
-        return jax.lax.cond(routed <= usual, run("branch/usual", usual), run("branch/every_pair", every, named=False))
+        fits = routed <= usual
+        return *jax.lax.cond(fits, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, kernel)), (~fits).astype(jnp.int32)

@@ -85,9 +85,10 @@ def _all_finite(tree):
 
 
 # key on the trainer's first-call line -> the region whose choice it reports, as ``program_regions_traced_total`` labels
-# it (forward call sites, by ``path``)
+# it (forward call sites, by ``path``); the line's word for any ``path`` but ``"xla"`` is ``kernel`` but for ``_PATH_WORDS``
 _PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}),
-          "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {})}
+          "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
+_PATH_WORDS = {"moe_cond": "fallback_keeps_nothing"}  # the one form ``routed_part``'s conditional has
 
 
 def _paths_traced():
@@ -759,7 +760,9 @@ class DeepSpeedEngine:
         part (``mla_rope``; no key where the model has no positions) and the
         routed FFN's grouped products were traced into this program, by the counters that count
         each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
-        fallback), ``mixed``, or no key where the program has none."""
+        fallback), ``mixed``, or no key where the program has none; and, where
+        a routed layer's buffer is smaller than every pair, which form its
+        conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``)."""
         kinds = getattr(getattr(self.module, "cfg", None), "kinds", None)
         if not kinds or len(set(kinds)) == 1:
             return {}
@@ -770,7 +773,7 @@ class DeepSpeedEngine:
         for key, (kernel, xla) in _paths_traced().items():
             kernel, xla = kernel - traced_before[key][0], xla - traced_before[key][1]
             if kernel or xla:
-                notes[key] = "mixed" if kernel and xla else "kernel" if kernel else "xla"
+                notes[key] = "mixed" if kernel and xla else _PATH_WORDS.get(key, "kernel") if kernel else "xla"
         return notes
 
     def _count_step_flops(self, program, args):

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.moe.sharded_moe import held_experts, routed_part
+from deepspeed_tpu.moe.sharded_moe import SAVED, held_experts, routed_part
 from deepspeed_tpu.ops.pallas import moe_sum_rows
 from deepspeed_tpu.telemetry.registry import get_registry
 
@@ -100,6 +100,66 @@ def test_both_branches_of_the_cond_take_the_kernel():
         _same(got, want)
 
 
+ONE_HELD, ALL_HELD = _one_held_expert(6).astype(jnp.int32), jnp.broadcast_to(jnp.array([2, 3, 4, 5, 9, 10], jnp.int32), (N, 6))
+EVERY, USUAL = N * 6, 1024  # 4 of 64 held at 6 a token: 3,072 pairs, a usual buffer of 1,024 rows
+
+
+def _both_branches_plain(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel):
+    """``routed_part`` as it was while its fallback was a plain branch (unnamed, so a checkpointed block kept none of
+    it, but differentiated by the ``lax.cond`` like the other): what the pin below must tell from today's."""
+    n, every = wg.shape[0], idx.size
+    usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
+    run = lambda rows, named: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
+    local = idx - first
+    return jax.lax.cond(jnp.sum((local >= 0) & (local < n)) <= usual, run(usual, True), run(every, False))
+
+
+def _rows_out_of_conds(jaxpr, seen):
+    """The leading dimension of every output of every ``cond`` equation, at any depth."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            seen.update(v.aval.shape[0] for v in eqn.outvars if getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _rows_out_of_conds(sub, seen)
+    return seen
+
+
+def _checkpointed(part, idx, kernel, cot):
+    """The loss of ``part`` under the block's policy: what is named is kept, the rest made again in the backward."""
+    return jax.checkpoint(lambda *a: jnp.sum(part(a[0], idx, *a[1:], FIRST, 4 * E, kernel)[0] * cot),
+                          policy=jax.checkpoint_policies.save_only_these_names(SAVED))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_the_conditional_hands_on_nothing_of_every_pairs_row_count(kernel):
+    """A ``lax.cond`` under differentiation returns the residuals of BOTH branches, the branch not taken writing zeros
+    for the other's. The fallback keeps nothing but its operands (``_every_pair``), so in the gradient of a
+    checkpointed ``routed_part`` no conditional returns an array of every pair's 3,072 rows: the usual branch has none
+    to write zeros for. With both branches plain the recomputed conditional returns the fallback's sorted rows and
+    products at that size, which is what this test would see again if the rule were lost."""
+    operands, cot = _operands(6)
+    rows = {part: _rows_out_of_conds(jax.make_jaxpr(jax.grad(_checkpointed(part, ONE_HELD, kernel, cot), argnums=(0, 1, 2, 3, 4)))(*operands).jaxpr, set())
+            for part in (routed_part, _both_branches_plain)}
+    assert USUAL in rows[routed_part] and EVERY not in rows[routed_part]
+    assert {USUAL, EVERY} <= rows[_both_branches_plain]
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("idx,rows,pairs,fallback", [(ALL_HELD, EVERY, 2048, 1), (ONE_HELD, USUAL, 512, 0)], ids=["every_pair", "usual"])
+def test_either_branch_is_held_experts_at_its_buffer_differentiated_directly(idx, rows, pairs, fallback, kernel):
+    """Every token picking all four held experts is 2,048 pairs, past the usual buffer: output and the five gradients
+    are those of ``held_experts(..., rows=every)`` differentiated with no conditional and no rule around it, though
+    the fallback kept nothing and made its forward again in its backward; one held expert a token is 512 pairs, and
+    they are the usual buffer's. Plainly and under the block's checkpoint policy; no pair dropped either way."""
+    operands, cot = _operands(6, seed=3)
+    want, routed, _ = _value_and_grads(idx, rows, kernel, operands, cot)
+    got, routed_here, dropped = _value_and_grads(idx, None, kernel, operands, cot, part=routed_part)
+    assert (routed, routed_here, dropped) == (pairs, pairs, 0)
+    assert int(routed_part(operands[0], idx, *operands[1:], FIRST, 4 * E, kernel)[5]) == fallback
+    _same(got, want)
+    _same(jax.grad(_checkpointed(routed_part, idx, kernel, cot), argnums=(0, 1, 2, 3, 4))(*operands), want[1:])
+
+
 def _shapes(jaxpr, seen):
     for eqn in jaxpr.eqns:
         seen.update(tuple(v.aval.shape) for v in eqn.outvars if hasattr(v.aval, "shape"))
@@ -129,6 +189,21 @@ def test_the_choice_is_counted_where_it_is_made(n_tokens, d, kernel, path):
     jax.eval_shape(lambda *a: held_experts(a[0], idx, *a[1:], FIRST, 1024, kernel), *shapes)
     rose = {p: (reg.peek("program_regions_traced_total", region="ffn/rows", path=p) or 0) - before[p] for p in before}
     assert rose == {"kernel": float(path == "kernel"), "xla": float(path == "xla")}
+
+
+def test_the_conditionals_form_is_counted_once_a_trace_and_has_its_word_for_the_first_call_line():
+    """``program_regions_traced_total{region="ffn/cond", path="fallback_keeps_nothing"}``: one a traced ``routed_part``
+    that has a conditional (none where the usual buffer holds every pair), and the trainer's first-call line reads it
+    as ``moe_cond``."""
+    from deepspeed_tpu.runtime import engine
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((N, D), (N, 6), (HELD, D, F), (HELD, D, F), (HELD, F, D))]
+    rose = []
+    for experts in (4 * E, E):  # 4 of 64: a buffer of 1,024 under 3,072 pairs; 4 of 16: the usual buffer is every pair
+        before = engine._paths_traced()["moe_cond"]
+        jax.eval_shape(lambda *a: routed_part(a[0], ONE_HELD, *a[1:], FIRST, experts, False), *shapes)
+        rose.append(tuple(now - was for now, was in zip(engine._paths_traced()["moe_cond"], before)))
+    assert rose == [(1, 0), (0, 0)] and engine._PATH_WORDS["moe_cond"] == "fallback_keeps_nothing"
 
 
 def test_spans_are_where_each_tile_and_expert_lies_in_the_sorted_buffer():
