@@ -137,6 +137,8 @@ class InferenceEngineV2:
                 f"inference/v2 serves softmax attention over one head size with dense or capacity-gated MoE FFNs; this "
                 f"model has layers of kind {kinds}: a recurrent state beside the paged KV (kda), a latent cache (mla) and "
                 f"the routed FFN's gate are training-side only")
+        if cfg.attn_output_gate:
+            raise NotImplementedError("inference/v2 has no output gate on its attention (attn_output_gate): training-side only")
         self.cfg = cfg
         self.dtype = jnp.bfloat16 if config.dtype in ("bfloat16", "bf16") else jnp.float32
 

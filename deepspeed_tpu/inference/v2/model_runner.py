@@ -139,8 +139,9 @@ def _transformer_layer(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, k_pages
         q, k, v = (jnp.clip(t, -cfg.clip_qkv, cfg.clip_qkv) for t in (q, k, v))
     if cfg.qk_norm:  # qwen3: per-head rms before rope
         rms = REGISTRY.get("rms_norm")
-        q = rms(q, lp["attn"]["q_norm"]["scale"], cfg.norm_eps).astype(dtype)
-        k = rms(k, lp["attn"]["k_norm"]["scale"], cfg.norm_eps).astype(dtype)
+        scale = lambda p: 1.0 + p["scale"].astype(jnp.float32) if cfg.rms_offset else p["scale"]  # as ``Attention`` has them
+        q = rms(q, scale(lp["attn"]["q_norm"]), cfg.norm_eps).astype(dtype)
+        k = rms(k, scale(lp["attn"]["k_norm"]), cfg.norm_eps).astype(dtype)
     if cfg.pos_emb == "rope":
         q = apply_rope(q, cos, sin, positions, rotary_dim=cfg.rotary_dim, style=cfg.rope_style)
         k = apply_rope(k, cos, sin, positions, rotary_dim=cfg.rotary_dim, style=cfg.rope_style)

@@ -1,6 +1,7 @@
-"""Token mixers beside softmax attention over one head size: a gated
-delta-rule linear-attention layer (KDA) and latent attention (MLA), without
-positions or with its shared key part rotated. Both are training-side
+"""Token mixers beside softmax attention over one head size: gated
+delta-rule linear-attention layers with a decay a channel (KDA) or a head
+(Gated DeltaNet), and latent attention (MLA), without positions or with its
+shared key part rotated. All are training-side
 modules: a block built from them takes no KV cache (``inference/v2`` refuses
 these kinds by name).
 """
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import attention
-from ..ops.kda import kda
+from ..ops.kda import gdn, kda
 from ..ops.registry import pallas_available
 from ..telemetry.tracing import region
 from .transformer import RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
@@ -87,6 +88,50 @@ class KDAMixer(nn.Module):
             o = heads_first(o)
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=f32)(o)
+
+
+class GDNMixer(nn.Module):
+    """Gated DeltaNet: KDA's rule with ONE decay a head and token, ``S_t = (I -
+    beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T
+    q_t``. ``gdn_key_heads`` heads of q and k, each serving ``gdn_value_heads /
+    gdn_key_heads`` consecutive value heads; q, k, v go through a depthwise
+    causal convolution and SiLU, q and k are L2-normalised (q also divided by
+    sqrt(d)); per value head ``beta = sigmoid(x w_b)`` and ``g = -exp(A_log)
+    softplus(x w_a + dt_bias)`` in float32; the output is RMS-normalised a
+    head (a plain weight) and multiplied by ``silu(z)``, z a projection of its
+    own (``ops/kda.py::gdn``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        Hk, Hv, D = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
+        f32 = jnp.float32
+        heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
+        heads = lambda name, H: heads_first(nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_proj", dtype=cfg.dtype,
+                                                            param_dtype=f32)(x))
+
+        def conv_silu(name, H):
+            w = self.param(f"{name}_conv", _uniform(-cfg.gdn_conv_size**-0.5, cfg.gdn_conv_size**-0.5),
+                           (cfg.gdn_conv_size, H, D), f32)
+            return nn.silu(causal_conv(heads(name, H), w.astype(cfg.dtype)[:, :, None, :], axis=2))
+
+        with region("mixer/proj"):  # the projections, their convolutions and the gates
+            q = (l2_normalize(conv_silu("q", Hk)) * D**-0.5).astype(cfg.dtype)
+            k = l2_normalize(conv_silu("k", Hk)).astype(cfg.dtype)
+            v = conv_silu("v", Hv)
+            a_log = self.param("A_log", _a_log_init, (Hv,), f32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (Hv,), f32)
+            ba = nn.Dense(2 * Hv, use_bias=False, name="ba_proj", dtype=f32, param_dtype=f32,
+                          precision=jax.lax.Precision.HIGHEST)(x.astype(f32))  # the float32 gates take no bf16 pass
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)  # (B, S, Hv)
+        o = gdn(q, k, v, jnp.swapaxes(g, 1, 2), jnp.swapaxes(beta, 1, 2))  # (B, Hv, S, D)
+        with region("mixer/proj"):
+            o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * nn.silu(heads("z", Hv))
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                                   param_dtype=f32)(heads_first(o))
 
 
 class MLAMixer(nn.Module):

@@ -63,7 +63,10 @@ class TransformerConfig:
     block_type: str = "sequential"
     dense_bias: Optional[bool] = None  # default: norm == "layernorm" (falcon: LN but bias-free)
     qkv_bias: Optional[bool] = None  # override for q/k/v projections only (qwen2)
-    qk_norm: bool = False  # qwen3: per-head RMSNorm on q/k before rope
+    qk_norm: bool = False  # qwen3: per-head RMSNorm on q/k before rope (zero-centered weights under ``rms_offset``)
+    # qwen3-next: q_proj is twice as wide, a head's columns its query and then a gate, and the attention's output is
+    # multiplied by sigmoid(gate) ahead of o_proj: out = (softmax(q k^T / sqrt(D)) v * sigmoid(gate)) W_o
+    attn_output_gate: bool = False
     attn_out_bias: Optional[bool] = None  # override for o_proj only (gpt-j: biased MLP, bias-free attn)
     lm_head_bias: bool = False  # phi / gpt-j carry a bias on the untied head
     embedding_norm: bool = False  # bloom: layernorm directly after the token embedding
@@ -95,15 +98,22 @@ class TransformerConfig:
     moe_aux_loss_coef: float = 0.01
     moe_min_capacity: int = 4
     # THE per-layer specification: one (mixer, ffn) pair a layer. mixer: full | window (``sliding_window``) |
-    # kda (gated delta-rule linear attention) | mla (latent attention: its shared key part rotated where ``pos_emb``
-    # is "rope", else no positions); ffn: dense | moe (the softmax gate with a capacity above) | routed (sigmoid
-    # scores, no capacity, a shared expert). None: the
-    # pairs that ``window_layers`` and ``moe_layer_freq`` describe (``kinds``)
+    # kda (gated delta-rule linear attention, a decay a channel) | gdn (the same rule with a decay a head: Gated
+    # DeltaNet) | mla (latent attention: its shared key part rotated where ``pos_emb`` is "rope", else no positions);
+    # ffn: dense | moe (the softmax gate with a capacity above) | routed (``moe_scoring`` scores, no capacity, a
+    # shared expert). None: the pairs that ``window_layers`` and ``moe_layer_freq`` describe (``kinds``)
     layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
     kda_heads: int = 0  # kda: heads of ``kda_head_dim`` keys and values, a depthwise causal convolution of
     kda_head_dim: int = 128  # ``kda_conv_size`` on q, k and v, gates through ``kda_gate_rank``
     kda_conv_size: int = 4
     kda_gate_rank: int = 128
+    # gdn: ``gdn_key_heads`` heads of q and k, each serving ``gdn_value_heads / gdn_key_heads`` value heads, all of
+    # ``gdn_head_dim``; a depthwise causal convolution of ``gdn_conv_size`` on q, k and v; per value head and token
+    # beta = sigmoid(x w_b), g = -exp(A_log) softplus(x w_a + dt_bias), S_t = (I - beta k k^T) exp(g) S_{t-1} + beta k v^T
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_head_dim: int = 128
+    gdn_conv_size: int = 4
     mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (the rope dims rotated under
     # ``pos_emb="rope"`` by ``rope_theta`` / ``rope_style``, else nothing is), v of its own
     mla_qk_nope_dim: int = 128
@@ -115,6 +125,11 @@ class TransformerConfig:
     # of n * f, their columns side by side: give the sum); 0: none
     moe_route_scale: float = 1.0  # the renormalised weights of a token's experts are multiplied by this
     moe_held: Optional[Tuple[int, int]] = None  # (first, count): the experts THIS program holds; None: all
+    # routed: a token's scores over all experts. "sigmoid": s = sigmoid(x W_r), the top k of s + selection bias;
+    # "softmax": p = softmax(x W_r), the top k of p; either way the chosen ones rescaled to sum to one, times
+    # ``moe_route_scale``
+    moe_scoring: str = "sigmoid"
+    moe_shared_gate: bool = False  # routed: the shared expert's output is multiplied by sigmoid(x w_s), w_s (d_model, 1)
 
     @property
     def kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -182,7 +197,7 @@ class TransformerConfig:
         return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)
 
 
-MIXERS = ("full", "window", "kda", "mla")
+MIXERS = ("full", "window", "kda", "gdn", "mla")
 FFNS = ("dense", "moe", "routed")
 
 
@@ -389,15 +404,17 @@ class Attention(nn.Module):
         dense = lambda feats, name: nn.DenseGeneral(feats, axis=-1, use_bias=cfg.use_qkv_bias, name=name,
                                                     dtype=cfg.dtype, param_dtype=jnp.float32)
         with region("mixer/proj"):
-            q = dense((H, D), "q_proj")(x)
+            q = dense((H, 2 * D if cfg.attn_output_gate else D), "q_proj")(x)
+            if cfg.attn_output_gate:
+                q, gate = q[..., :D], q[..., D:]
             k = dense((KVH, D), "k_proj")(x)
             v = dense((KVH, D), "v_proj")(x)
             if cfg.clip_qkv is not None:  # olmo: clamp projections before rope
                 c = cfg.clip_qkv
                 q, k, v = (jnp.clip(t, -c, c) for t in (q, k, v))
             if cfg.qk_norm:  # qwen3: head-dim RMSNorm before rope
-                q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="q_norm")(q)
-                k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="k_norm")(k)
+                q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="k_norm")(k)
 
         if cfg.pos_emb == "rope":
             with region("mixer/rope"):
@@ -421,6 +438,8 @@ class Attention(nn.Module):
         out = attention(q, k, v, causal=cfg.causal, segment_ids=segment_ids, kv_len=kv_len,
                         alibi_slopes=slopes, window=self.window, scale=cfg.attn_scale)
         with region("mixer/proj"):
+            if cfg.attn_output_gate:
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
             out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=cfg.use_attn_out_bias, name="o_proj",
                                   dtype=cfg.dtype, param_dtype=jnp.float32)(out)
         return (out, new_cache) if kv_cache is not None else out
@@ -474,20 +493,21 @@ class Block(nn.Module):
 
             return RoutedMoE(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k,
                              d_ff=cfg.moe_d_ff or cfg.ffn_dim, held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff,
-                             scale=cfg.moe_route_scale, dtype=cfg.dtype, name="routed")(h)
+                             scale=cfg.moe_route_scale, scoring=cfg.moe_scoring, shared_gate=cfg.moe_shared_gate,
+                             dtype=cfg.dtype, name="routed")(h)
         return MLP(cfg, name="mlp")(h)
 
     def _mixer(self, cfg):
         """The layer's token mixer as ``fn(h, positions, kv_cache, segment_ids)``."""
-        if self.kind[0] in ("kda", "mla"):
-            from .mixers import KDAMixer, MLAMixer
+        if self.kind[0] in ("kda", "gdn", "mla"):
+            from . import mixers
 
-            mixer = KDAMixer(cfg, name="kda") if self.kind[0] == "kda" else MLAMixer(cfg, name="mla")
+            mixer = {"kda": mixers.KDAMixer, "gdn": mixers.GDNMixer, "mla": mixers.MLAMixer}[self.kind[0]](cfg, name=self.kind[0])
 
             def run(h, positions, kv_cache, segment_ids):
                 if kv_cache is not None or segment_ids is not None:
                     raise NotImplementedError(f"a {self.kind[0]} layer takes no KV cache and no packed segments yet")
-                return mixer(h) if self.kind[0] == "kda" else mixer(h, positions)
+                return mixer(h, positions) if self.kind[0] == "mla" else mixer(h)
 
             return run
         return Attention(cfg, window=cfg.sliding_window if self.kind[0] == "window" else None, name="attn")
@@ -713,8 +733,8 @@ def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: 
         return (out if kv_cache is not None else (out, None)), sown
 
     fn = wrap(apply) if wrap is not None else apply
-    if remat and (kind[0] in ("kda", "mla") or kind[1] == "routed"):
-        # a hybrid block recomputes everything but what is named as too dear to make twice: the KDA scan's outputs, a
+    if remat and (kind[0] in ("kda", "gdn", "mla") or kind[1] == "routed"):
+        # a hybrid block recomputes everything but what is named as too dear to make twice: the delta-rule scan's outputs, a
         # latent-attention call's output and row statistics, a routed layer's sorted rows and grouped products
         from ..moe.sharded_moe import SAVED as routed_rows
         from ..ops.kda import SAVED as kda_scan
@@ -843,7 +863,7 @@ class CausalLM:
         if cfg.scan_layers:
             raise ValueError("disable scan_layers for pipeline (stages are stacked instead)")
         if not cfg.softmax_only:
-            raise NotImplementedError("kda, mla and routed layers are not pipeline-partitionable yet: the stages' stacking "
+            raise NotImplementedError("kda, gdn, mla and routed layers are not pipeline-partitionable yet: the stages' stacking "
                                       "takes softmax attention and dense or capacity-gated MoE blocks")
         if cfg.mlm_head or cfg.type_vocab_size > 0:
             raise NotImplementedError("BERT-style models (mlm_head / token-type embeddings) are not "

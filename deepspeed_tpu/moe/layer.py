@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry.tracing import region
-from .sharded_moe import combine_output, gate_and_dispatch, routed_part, sigmoid_topk
+from .sharded_moe import combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
 
 class Experts(nn.Module):
@@ -150,10 +150,12 @@ def report_rows(intermediates):
 class RoutedMoE(nn.Module):
     """A routed FFN as the share of it that is held here, plus a shared expert.
 
-    Scores are sigmoids over ALL ``num_experts``; a token takes the top ``k``
-    of score + selection bias (``select_bias``: a parameter that takes no
-    gradient, zero at start), weighted by the chosen scores rescaled to sum to
-    one times ``scale``. ``held = (first, count)`` says which experts this
+    ``scoring="sigmoid"``: scores are sigmoids over ALL ``num_experts``; a
+    token takes the top ``k`` of score + selection bias (``select_bias``: a
+    parameter that takes no gradient, zero at start), weighted by the chosen
+    scores rescaled to sum to one times ``scale``. ``scoring="softmax"``: a
+    softmax over all of them, its top ``k``, rescaled the same way; no bias.
+    ``shared_gate``: the shared expert's output times ``sigmoid(x w_s)``. ``held = (first, count)`` says which experts this
     layer holds (None: all): it routes over all, computes its own experts'
     part, and adds the shared expert once; what absent experts would add is
     left out. ``shared_ff`` is the shared part's whole width: a model with n
@@ -174,19 +176,26 @@ class RoutedMoE(nn.Module):
     held: Optional[tuple] = None
     shared_ff: int = 0
     scale: float = 1.0
+    scoring: str = "sigmoid"
+    shared_gate: bool = False
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         d, E = self.hidden_size, self.num_experts
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"a routed layer scores by sigmoid or softmax, got {self.scoring!r}")
         first, count = self.held if self.held is not None else (0, E)
         tokens = x.reshape(-1, d)
         init = nn.initializers.normal(0.02)
-        with region("ffn/router"):
+        with region("ffn/router", path=self.scoring):  # the scoring, counted where it is chosen
             logits = nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32))  # which experts: no bf16 pass
-            select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
-            idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
+            if self.scoring == "softmax":
+                idx, weights = softmax_topk(logits, self.k, self.scale)
+            else:
+                select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
+                idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
@@ -195,11 +204,14 @@ class RoutedMoE(nn.Module):
         # ran), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
         if self.shared_ff:
-            with region("ffn/shared"):
+            with region("ffn/shared", **({"path": "gated"} if self.shared_gate else {})):
                 dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
                                                      param_dtype=jnp.float32)
                 h = nn.silu(dense(self.shared_ff, "shared_gate_proj")(tokens)) * dense(self.shared_ff, "shared_up_proj")(tokens)
-                out = out + dense(d, "shared_down_proj")(h)
+                shared = dense(d, "shared_down_proj")(h)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(dense(1, "shared_expert_gate")(tokens).astype(jnp.float32)).astype(shared.dtype)
+                out = out + shared
         return out.reshape(x.shape).astype(x.dtype)
 
 

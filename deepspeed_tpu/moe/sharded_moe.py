@@ -138,6 +138,11 @@ def combine_output(expert_out: jnp.ndarray, combine: jnp.ndarray) -> jnp.ndarray
 # ----------------------------------------------------------------------
 # routing without a capacity: scores over ALL experts, the part of the ones held here
 # ----------------------------------------------------------------------
+def _renormalised(chosen, scale: float):
+    """A token's chosen scores (N, k) rescaled to sum to one, times ``scale``."""
+    return chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
+
+
 def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float):
     """Sigmoid scores, the top ``k`` of ``score + select_bias`` (the bias only
     chooses: it takes no gradient and does not enter the weight), the chosen
@@ -145,8 +150,15 @@ def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, s
     logits (N, E) float32 -> (indices (N, k) int32, weights (N, k) float32)."""
     scores = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx, _renormalised(jnp.take_along_axis(scores, idx, axis=-1), scale)
+
+
+def softmax_topk(logits: jnp.ndarray, k: int, scale: float):
+    """Softmax over ALL experts in float32, its ``k`` largest, rescaled to sum
+    to one and multiplied by ``scale``. logits (N, E) -> (indices (N, k)
+    int32, weights (N, k) float32)."""
+    chosen, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), k)
+    return idx, _renormalised(chosen, scale)
 
 
 def _grouped(xs, w, group_sizes, kernel: bool):

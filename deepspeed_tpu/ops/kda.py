@@ -1,4 +1,5 @@
-"""The gated delta rule with a per-channel decay (Kimi Delta Attention's scan).
+"""The gated delta rule with a per-channel decay (Kimi Delta Attention's scan),
+and with one decay a head (Gated DeltaNet's: ``gdn``, at the end).
 
 ``kda(q, k, v, g, beta)``, heads before the sequence: q, k (B, H, S, d_k), v
 (B, H, S, d_v), g (B, H, S, d_k) float32 log-decay (<= 0), beta (B, H, S)
@@ -30,9 +31,9 @@ from .registry import pallas_available
 SAVED = "kda_scan"  # the name the kernel's outputs carry for a checkpoint policy
 
 
-def _traced(pass_: str, path: str):
-    """The region of a scan that was traced as ``path``, counted."""
-    return region("mixer/kernel", op="kda", path=path, **{"pass": pass_})
+def _traced(pass_: str, path: str, op: str = "kda"):
+    """The region of a scan (``op``: "kda", or "gdn" for one decay a head) that was traced as ``path``, counted."""
+    return region("mixer/kernel", op=op, path=path, **{"pass": pass_})
 
 
 def kda_recurrence(q, k, v, g, beta):
@@ -53,28 +54,32 @@ def kda_recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 2).astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _scan(q, k, kb, vb, g, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _scan(q, k, kb, vb, g, op, interpret):
     from .pallas import kda as kernel
 
     return kernel.scan_fwd(q, k, kb, vb, g, interpret)[0]
 
 
-def _scan_fwd(q, k, kb, vb, g, interpret):
+def _scan_fwd(q, k, kb, vb, g, op, interpret):
     from .pallas import kda as kernel
 
     # named, all three (outputs, every chunk's incoming state and its (I + A)^-1), so that a block under jax.checkpoint
     # keeps them (models/transformer.py::block_fn) and its backward does not run the scan a second time to get them back
-    with _traced("fwd", "kernel"):
+    with _traced("fwd", "kernel", op):
         o, states, inverses = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(q, k, kb, vb, g, interpret))
     return o, (q, k, kb, vb, g, states, inverses)
 
 
-def _scan_bwd(interpret, res, do):
+def _scan_bwd(op, interpret, res, do):
     from .pallas import kda as kernel
 
-    with _traced("bwd", "kernel"):
-        return tuple(kernel.scan_bwd(*res, do, interpret))
+    with _traced("bwd", "kernel", op):
+        dq, dk, *rest = kernel.scan_bwd(*res, do, interpret)
+        q = res[0]
+        if dq.shape != q.shape:  # value heads that share a key head: each gave its own dq and dk
+            dq, dk = (x.reshape(q.shape[0], -1, *q.shape[1:]).astype(jnp.float32).sum(1).astype(q.dtype) for x in (dq, dk))
+        return (dq, dk, *rest)
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
@@ -96,7 +101,7 @@ def kda_chunked(q, k, v, g, beta, interpret: bool = False):
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
         return x.reshape(B * H, S + pad, x.shape[-1])
 
-    o = _scan(rows(q), rows(k), rows(kb), rows(vb), rows(g.astype(jnp.float32)), interpret)
+    o = _scan(rows(q), rows(k), rows(kb), rows(vb), rows(g.astype(jnp.float32)), "kda", interpret)
     return o.reshape(B, H, S + pad, -1)[:, :, :S]
 
 
@@ -113,3 +118,62 @@ def kda(q, k, v, g, beta):
     spec = P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, "tensor", None, None), topo), q.shape, topo)
     with region("mixer/kernel"):  # the call with the padding and reshapes around it; ``_scan_fwd`` / ``_scan_bwd`` count the path
         return on_mesh(kda_chunked, (spec, spec, spec, spec, P(*spec[:3])), spec)(q, k, v, g, beta)
+
+
+# ---------------------------------------------------------------------------
+# One decay a head (Gated DeltaNet): ``Diag(exp(g_t))`` is ``exp(g_t) I``
+# ---------------------------------------------------------------------------
+def _to_value_heads(x, heads: int):
+    return x if x.shape[1] == heads else jnp.repeat(x, heads // x.shape[1], axis=1)
+
+
+def gdn_recurrence(q, k, v, g, beta):
+    """Token by token: ``kda_recurrence`` with the one decay for every channel
+    and a key head's q and k for each of its value heads."""
+    H = v.shape[1]
+    return kda_recurrence(_to_value_heads(q, H), _to_value_heads(k, H), v, g[..., None], beta)
+
+
+def gdn_chunked(q, k, v, g, beta, interpret: bool = False):
+    """``kda_chunked`` for one decay a head and token: q and k keep their own
+    (fewer) heads, the kernel reads a key head's blocks for each of its value
+    heads, and the log-decay goes in along the lanes, a chunk a row."""
+    from .pallas.kda import CHUNK
+
+    B, H, S, _ = v.shape
+    pad = -S % CHUNK
+    beta = beta.astype(jnp.float32)[..., None]
+    kb = (beta * _to_value_heads(k, H).astype(jnp.float32)).astype(k.dtype)
+    vb = (beta * v.astype(jnp.float32)).astype(v.dtype)
+
+    def rows(x):
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+        return x.reshape(-1, S + pad, x.shape[-1])
+
+    g = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, 0), (0, pad))) if pad else g.astype(jnp.float32)
+    o = _scan(rows(q), rows(k), rows(kb), rows(vb), g.reshape(-1, 1, CHUNK), "gdn", interpret)
+    return o.reshape(B, H, S + pad, -1)[:, :, :S]
+
+
+def gdn(q, k, v, g, beta):
+    """The gated delta rule with ONE decay a head and token: q, k (B, H_k, S,
+    d_k), v (B, H_v, S, d_v) with ``H_v`` a multiple of ``H_k`` (value head h
+    reads key head ``h // (H_v / H_k)``), g and beta (B, H_v, S) float32;
+    returns (B, H_v, S, d_v). For each value head, from a zero state::
+
+        S_t = (I - beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T,    o_t = S_t^T q_t
+
+    On a TPU the chunked kernel's per-head form (``ops/pallas/kda.py``:
+    ``gdn_scan_fwd`` / ``gdn_scan_bwd``), elsewhere the recurrence; the choice
+    is counted as ``kda``'s is, under ``op="gdn"``."""
+    if not pallas_available():
+        with _traced("fwd", "xla", "gdn"):
+            return gdn_recurrence(q, k, v, g, beta)
+    from ..parallel.mesh import get_mesh_topology
+    from ..runtime.zero.partition import fit_spec, prune_spec
+    from .pallas._utils import on_mesh
+
+    topo = get_mesh_topology(required=False)  # several chips: a shard_map over the batch axes
+    spec = P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, None, None, None), topo), q.shape, topo)
+    with region("mixer/kernel"):
+        return on_mesh(gdn_chunked, (spec, spec, spec, P(*spec[:3]), P(*spec[:3])), spec)(q, k, v, g, beta)

@@ -27,8 +27,12 @@ What the code observes to choose a path (``program_regions_traced_total{region=
 the query-major dq kernels (dbias is written there) on a (B*H, Sq/bq) grid
 and a dkv kernel on (B*H, Sk/bk). Without a bias there is the fused kernel
 alone: a head whose q, do and dq do not fit ``vmem_budget()`` at once
-(about 19,000 positions at D=128 in bf16 on a v5e) is refused by name when
-its backward is traced; no caller sends one yet. GQA is native: KV stays
+(about 19,000 positions at D=128 in bf16 on a v5e, about 9,500 at D=256) is
+refused by name when its backward is traced; no caller sends one yet. Under
+GQA the kernel also holds a group's dk and dv of the whole sequence (past
+about 9,000 positions at D=128, 4,700 at D=256): where that does not fit and
+a head alone does, the backward runs a head at a time on copies of its KV
+head and the group's gradients are added outside (``_flash_bwd``). GQA is native: KV stays
 collapsed at (B, S, KVH, D) in HBM and the kernels route each q head to its
 group's KV head by BlockSpec index map — at llama-70B-class 8:1 grouping
 that is 8x less KV HBM traffic than pre-expanding, and dk/dv accumulate
@@ -555,6 +559,15 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
 
     if not has_bias:
         fused_vmem = _fused_bwd_vmem(Sq, Sk, D, item, bq, bk, n_rep, Dv)
+        if fused_vmem > vmem_budget() >= _fused_bwd_vmem(Sq, Sk, D, item, bq, bk, 1, Dv) and n_rep > 1:
+            # a group's dk and dv of the whole sequence, in float32 and as outputs, do not fit beside a head's q, do
+            # and dq, and a head alone does (16 q heads on 2 KV heads of 256 at 8,192 positions: 74 MiB against 43):
+            # every q head gets a copy of its KV head, and the group's dk and dv are added outside the kernel
+            per_head = lambda x: jnp.repeat(x, n_rep, axis=0)
+            dq, dk, dv, dbias = _flash_bwd(q, per_head(k), per_head(v), o, lse, do, slopes, bias, scale, causal, interpret,
+                                           has_alibi, window, bias_meta, H, H)
+            group = lambda x, like: x.reshape(BKV, n_rep, *x.shape[1:]).astype(jnp.float32).sum(1).astype(like.dtype)
+            return dq, group(dk, k), group(dv, v), dbias
         if fused_vmem > vmem_budget():
             raise NotImplementedError(
                 f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "

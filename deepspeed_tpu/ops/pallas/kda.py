@@ -41,6 +41,19 @@ those two products (each three bf16 passes under bf16 operands). ``T`` costs
 enters is q, k, beta*k, beta*v and the log-decay, so beta's own gradient, the
 normalisations, convolutions and gates around the scan are XLA's to
 differentiate (``ops/kda.py``).
+
+**One decay a head and token** (Gated DeltaNet; ``chunk_fn_per_head``, calls
+named ``gdn_scan_fwd`` / ``gdn_scan_bwd``): ``Diag(exp(g_t))`` is ``exp(g_t) I``,
+so the pairs' decays are ONE (CHUNK, CHUNK) matrix ``exp(G_t - G_s)`` (masked to
+t >= s, so no exponent is positive and no reference decay is needed) that
+multiplies ``[beta K; Q] K^T``, one product on the MXU, where the per-channel
+form makes (SUB, SUB, d_k) tensors on the VPU; the log-decay arrives along the
+lanes, (1, CHUNK) float32 a chunk, CHUNK floats where the per-channel form reads
+CHUNK x d_k. The solve, its adjoint, the saved state and inverse and the
+backward by ``jax.vjp`` are the same code. Several value heads may share a key
+head's q and k (``q.shape[0]`` divides ``vb.shape[0]``): the grid walks value
+heads, a head's q and k blocks are its key head's, and dq, dk leave a value head
+each for the caller to add.
 """
 
 import functools
@@ -200,35 +213,68 @@ def chunk_fn(q, k, kb, vb, g, St0, mm, T=None):
     return o, St1, T
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, t_ref, state, *, mm):
+def chunk_fn_per_head(q, k, kb, vb, g, St0, mm, T=None):
+    """``chunk_fn`` for a decay that is one number a token: ``g`` (1, C)
+    float32, the chunk's log-decays along the lanes; everything else as there.
+    ``A`` and ``B`` are ``[kb; q] k^T`` (exact under bf16 operands: float32
+    accumulation of bf16 products) times ``exp(G_t - G_s)``."""
+    f32 = jnp.float32
+    C = q.shape[0]
+    q, k, kb, vb = (x.astype(f32) for x in (q, k, kb, vb))
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a.astype(mm), b.astype(mm), dims, preferred_element_type=f32,
+                                   precision=_HIGHEST if mm == f32 else None)
+
+    row, col = (jax.lax.broadcasted_iota(jnp.int32, (C, C), d) for d in (0, 1))
+    # G_t down the sublanes (a masked sum along the lanes, exact) and the same numbers along the lanes (the diagonal of
+    # their broadcast, summed down): no transpose, no product
+    G = jnp.sum(jnp.where(row >= col, jnp.broadcast_to(g, (C, C)), 0.0), axis=1, keepdims=True)  # (C, 1)
+    G_lanes = jnp.sum(jnp.where(row == col, jnp.broadcast_to(G, (C, C)), 0.0), axis=0, keepdims=True)  # (1, C)
+    decay = jnp.exp(jnp.where(row >= col, G - G_lanes, 0.0))  # exp(G_t - G_s) for s <= t
+    gam = jnp.exp(G)
+    from_state = dot(jnp.concatenate([q * gam, kb * gam], axis=0), St0, _NT)  # (2C, d_v)
+    o_in, r = from_state[:C], vb - from_state[C:]
+    pairs = dot(jnp.concatenate([kb, q], axis=0), k, _NT)  # (2C, C)
+    A = jnp.where(row > col, pairs[:C] * decay, 0.0)
+    B = jnp.where(row >= col, pairs[C:] * decay, 0.0)
+
+    u, T = solve_unit_lower(A, r, T, mm)
+    o = o_in + dot(B, u, _NN)
+    last = jnp.sum(g, axis=1, keepdims=True)  # G_C, (1, 1)
+    St1 = St0 * jnp.exp(last) + dot(u, k * jnp.exp(last - G), _TN)
+    return o, St1, T
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, t_ref, state, *, chunk, mm):
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         state[...] = jnp.zeros_like(state)
 
     st0 = state[...]
     st_ref[0, 0] = st0  # what this chunk started from: the backward's residual
-    o, st1, T = chunk_fn(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st0, mm)
+    o, st1, T = chunk(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st0, mm)
     o_ref[0] = o.astype(o_ref.dtype)
     t_ref[0, 0] = T  # and the inverse it made: the backward neither makes nor differentiates it again
     state[...] = st1
 
 
-def chunk_bwd(q, k, kb, vb, g, St0, T, do, dSt1, mm):
+def chunk_bwd(q, k, kb, vb, g, St0, T, do, dSt1, mm, chunk=chunk_fn):
     """A chunk's gradients to q, k, kb, vb, g and the incoming state, from the
     cotangents of its outputs (float32) and of its outgoing state: ``jax.vjp``
-    of ``chunk_fn`` on the state and the inverse the forward saved for it."""
-    _, vjp = jax.vjp(lambda *operands: chunk_fn(*operands, mm, T)[:2], q, k, kb, vb, g, St0)
+    of ``chunk`` on the state and the inverse the forward saved for it."""
+    _, vjp = jax.vjp(lambda *operands: chunk(*operands, mm, T)[:2], q, k, kb, vb, g, St0)
     return vjp((do, dSt1))
 
 
 def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
-                dstate, *, mm):
+                dstate, *, chunk, mm):
     @pl.when(pl.program_id(1) == 0)
     def _zero():  # the last chunk: nothing reads the state after it
         dstate[...] = jnp.zeros_like(dstate)
 
     dq, dk, dkb, dvb, dg, dst0 = chunk_bwd(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st_ref[0, 0], t_ref[0, 0],
-                                           do_ref[0].astype(jnp.float32), dstate[...], mm)
+                                           do_ref[0].astype(jnp.float32), dstate[...], mm, chunk)
     for ref, value in ((dq_ref, dq), (dk_ref, dk), (dkb_ref, dkb), (dvb_ref, dvb), (dg_ref, dg)):
         ref[0] = value.astype(ref.dtype)
     dstate[...] = dst0
@@ -238,21 +284,38 @@ def _mm_dtype(x):
     return jnp.float32 if x.dtype == jnp.float32 else jnp.bfloat16
 
 
+def _form(q, vb, g, chunk_at):
+    """What tells the two forms apart is the decay's shape: (BH, S, d_k), a
+    number a channel, or (BH * S / CHUNK, 1, CHUNK), a number a token along
+    the lanes. -> (the chunk function, the calls' name, the BlockSpecs of
+    (BH, S, d) rows of a key head and of a value head, of a (BH, S / CHUNK,
+    m, n) float32 save, and of the decay), for a grid (value head, step)
+    whose step works on chunk ``chunk_at(step)``."""
+    nc, rep = q.shape[1] // CHUNK, vb.shape[0] // q.shape[0]
+    rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, chunk_at(c), 0))
+    key_rows = rows if rep == 1 else lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b // rep, chunk_at(c), 0))
+    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, chunk_at(c), 0, 0))
+    if g.shape[1:] == (1, CHUNK):
+        return chunk_fn_per_head, "gdn_scan", key_rows, rows, whole, \
+            pl.BlockSpec((1, 1, CHUNK), lambda b, c: (b * nc + chunk_at(c), 0, 0))
+    return chunk_fn, "kda_scan", key_rows, rows, whole, rows(q.shape[-1])
+
+
 def scan_fwd(q, k, kb, vb, g, interpret: bool):
     """(BH, S, d) operands, S a multiple of ``CHUNK`` -> outputs (BH, S, d_v)
     in ``vb``'s type, and for the backward every chunk's incoming state (BH,
     S/CHUNK, d_v, d_k) and its ``(I + A)^-1`` (BH, S/CHUNK, CHUNK, CHUNK),
-    float32."""
-    BH, S, dk = q.shape
-    dv = vb.shape[-1]
+    float32. ``g``: (BH, S, d_k), or (BH * S/CHUNK, 1, CHUNK) for one decay
+    a token, where q and k may have fewer heads than kb and vb (``_form``)."""
+    BH, S, dv = vb.shape
+    dk = q.shape[-1]
     nc = S // CHUNK
-    rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, c, 0))
-    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, c, 0, 0))
+    chunk, name, key_rows, rows, whole, decay = _form(q, vb, g, lambda c: c)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, mm=_mm_dtype(q)),
-        name="kda_scan_fwd",
+        functools.partial(_fwd_kernel, chunk=chunk, mm=_mm_dtype(q)),
+        name=f"{name}_fwd",
         grid=(BH, nc),
-        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk)],
+        in_specs=[key_rows(dk), key_rows(dk), rows(dk), rows(dv), decay],
         out_specs=[rows(dv), whole(dv, dk), whole(CHUNK, CHUNK)],
         out_shape=[jax.ShapeDtypeStruct((BH, S, dv), vb.dtype), jax.ShapeDtypeStruct((BH, nc, dv, dk), jnp.float32),
                    jax.ShapeDtypeStruct((BH, nc, CHUNK, CHUNK), jnp.float32)],
@@ -265,20 +328,20 @@ def scan_fwd(q, k, kb, vb, g, interpret: bool):
 def scan_bwd(q, k, kb, vb, g, states, inverses, do, interpret: bool):
     """Gradients of ``scan_fwd``'s outputs' cotangent ``do`` to q, k, kb, vb
     (their types) and g (float32), chunks walked from the last to the first,
-    each on the state and the inverse ``scan_fwd`` returned for it."""
-    BH, S, dk = q.shape
-    dv = vb.shape[-1]
+    each on the state and the inverse ``scan_fwd`` returned for it. dq and dk
+    have kb's heads: where value heads share a key head, one each."""
+    BH, S, dv = vb.shape
+    dk = q.shape[-1]
     nc = S // CHUNK
-    rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, nc - 1 - c, 0))
-    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, nc - 1 - c, 0, 0))
+    chunk, name, key_rows, rows, whole, decay = _form(q, vb, g, lambda c: nc - 1 - c)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, mm=_mm_dtype(q)),
-        name="kda_scan_bwd",
+        functools.partial(_bwd_kernel, chunk=chunk, mm=_mm_dtype(q)),
+        name=f"{name}_bwd",
         grid=(BH, nc),
-        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk), whole(dv, dk), whole(CHUNK, CHUNK), rows(dv)],
-        out_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk)],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, kb, vb)]
-        + [jax.ShapeDtypeStruct(g.shape, jnp.float32)],
+        in_specs=[key_rows(dk), key_rows(dk), rows(dk), rows(dv), decay, whole(dv, dk), whole(CHUNK, CHUNK), rows(dv)],
+        out_specs=[rows(dk), rows(dk), rows(dk), rows(dv), decay],
+        out_shape=[jax.ShapeDtypeStruct(kb.shape, x.dtype) for x in (q, k, kb)]
+        + [jax.ShapeDtypeStruct(vb.shape, vb.dtype), jax.ShapeDtypeStruct(g.shape, jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         interpret=interpret,
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),

@@ -10,7 +10,10 @@ over the rows r of token t of w[r] * rows[r]`` is then, a token tile, one
 short read an expert: no (tokens, k, d) tensor, and no scatter.
 
 A grid step is a token tile. It copies, for each of the ``n`` held experts, the
-``ROWS`` rows from the span's start (rounded down to ``SHIFT`` rows, a bf16
+``ROWS`` rows (``window_rows(n)``: 128 up to eight held experts, fewer beyond, so
+that the placing product stays (TOKENS, 1024) x (1024, d) and the windows of
+32 experts take the VMEM that those of 8 do; a tile of 256 tokens sends an
+expert a few tens of rows at most where the router is near balance) from the span's start (rounded down to ``SHIFT`` rows, a bf16
 tile's sublanes) into its own slice of one (n * ROWS, d) buffer in VMEM, and
 places every row by ONE product on the MXU: a (TOKENS, n * ROWS) matrix that
 holds a row's weight where the row is the token's and inside the span, times
@@ -39,19 +42,25 @@ from ._utils import compiler_params as _compiler_params
 from ._utils import vmem_budget
 
 TOKENS = 256  # tokens a tile
-ROWS = 128    # rows a window
 SHIFT = 16    # a window starts at a multiple of this many rows
+LANES = 128   # a block of rows' tokens and weights along the lanes: the most rows a window has
+
+
+def window_rows(n: int) -> int:
+    """Rows a window, for ``n`` held experts: 128 while the experts' windows side by side are at most 1024 rows (n <=
+    8), then the power of two that keeps them so, and no fewer than two shifts."""
+    return max(2 * SHIFT, min(LANES, 1 << max(0, (1024 // n).bit_length() - 1)))
 
 
 def _vmem_bytes(n: int, d: int, itemsize: int) -> int:
     """Two sets of windows, the output's two blocks, the accumulator, and the placing matrix with its int32 makings."""
-    return 2 * n * ROWS * d * itemsize + 2 * TOKENS * d * itemsize + TOKENS * d * 4 + 4 * TOKENS * n * ROWS * 4
+    return 2 * n * window_rows(n) * d * itemsize + 2 * TOKENS * d * itemsize + TOKENS * d * 4 + 4 * TOKENS * n * window_rows(n) * 4
 
 
 def fits(n_tokens: int, rows: int, d: int, n: int, dtype) -> bool:
     """Whether the kernel takes these shapes: whole token tiles (their indices exact in float32), whole windows, whole
     lanes, and VMEM for the windows of ``n`` experts."""
-    return (n_tokens % TOKENS == 0 and n_tokens <= 1 << 24 and rows % ROWS == 0 and d % 128 == 0
+    return (n_tokens % TOKENS == 0 and n_tokens <= 1 << 24 and rows % LANES == 0 and d % 128 == 0
             and _vmem_bytes(n, d, jnp.dtype(dtype).itemsize) <= vmem_budget())
 
 
@@ -68,37 +77,38 @@ def spans(key, n: int, k: int, rows: int):
 
 
 def _along_lanes(tok_of_row, w_row):
-    """(R,) tokens and weights -> (ROWS / SHIFT, R / ROWS, 2, ROWS) float32:
-    copy s holds both from row ``SHIFT * s`` on, so the window that starts at
-    any multiple of ``SHIFT`` is one (2, ROWS) block of one copy, its tokens
-    above its weights (a token index is exact in float32: ``fits``)."""
+    """(R,) tokens and weights -> (LANES / SHIFT, R / LANES, 2, LANES) float32:
+    copy s holds both from row ``SHIFT * s`` on, so the ``LANES`` rows from
+    any multiple of ``SHIFT`` are one (2, LANES) block of one copy, their
+    tokens above their weights (a token index is exact in float32: ``fits``);
+    a window is the first ``ROWS`` lanes of its block."""
     both = jnp.stack([tok_of_row.astype(jnp.float32), w_row.astype(jnp.float32)])
-    shifted = lambda s: jnp.pad(both[:, SHIFT * s:], ((0, 0), (0, SHIFT * s))).reshape(2, -1, ROWS).swapaxes(0, 1)
-    return jnp.stack([shifted(s) for s in range(ROWS // SHIFT)])
+    shifted = lambda s: jnp.pad(both[:, SHIFT * s:], ((0, 0), (0, SHIFT * s))).reshape(2, -1, LANES).swapaxes(0, 1)
+    return jnp.stack([shifted(s) for s in range(LANES // SHIFT)])
 
 
-def _first_row(lo, R: int):
+def _first_row(lo, R: int, ROWS: int):
     """Where a span's first window starts: the span's start rounded down to ``SHIFT`` rows, kept inside the buffer.
     Every count here is >= 0, so the truncating division is the floor, and lowers to one operation."""
     return jnp.minimum(jax.lax.div(lo, SHIFT) * SHIFT, R - ROWS)
 
 
 def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, sems, acc, *, n, tiles):
-    R = rows_hbm.shape[0]
+    R, ROWS = rows_hbm.shape[0], window_rows(n)
     i = pl.program_id(0)
     half = jax.lax.rem(i, 2)  # which set of windows this tile's were copied into
     routed = hi_ref[tiles * n - 1]  # the last span's end: past it no row of the buffer is defined
 
     def span(tile, e):
         lo, hi = lo_ref[tile * n + e], hi_ref[tile * n + e]
-        return lo, hi, _first_row(lo, R)
+        return lo, hi, _first_row(lo, R, ROWS)
 
     def window(at):
         return pl.ds(pl.multiple_of(at * ROWS, ROWS), ROWS)
 
     def copies(start, at):
         start = pl.multiple_of(start, SHIFT)
-        shift, block = jax.lax.div(jax.lax.rem(start, ROWS), SHIFT), jax.lax.div(start, ROWS)
+        shift, block = jax.lax.div(jax.lax.rem(start, LANES), SHIFT), jax.lax.div(start, LANES)
         return (pltpu.make_async_copy(rows_hbm.at[pl.ds(start, ROWS)], xs.at[window(at)], sems.at[0, at]),
                 pltpu.make_async_copy(lanes_hbm.at[shift, block], tw.at[at], sems.at[1, at]))
 
@@ -142,7 +152,8 @@ def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, se
 
     dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
                             precision=jax.lax.Precision.HIGHEST if xs.dtype == jnp.float32 else None)
-    beside = jnp.concatenate([tw[half * n + e] for e in range(n)], axis=1)  # the experts' (2, ROWS) blocks, along the lanes
+    lanes = (lambda at: tw[at]) if ROWS == LANES else (lambda at: tw[at][:, :ROWS])  # a window's rows lead its block
+    beside = jnp.concatenate([lanes(half * n + e) for e in range(n)], axis=1)  # the experts' (2, ROWS) blocks, along the lanes
     acc[...] = dot(places(inside_ref[...] != 0, beside), xs[pl.ds(pl.multiple_of(half * n * ROWS, ROWS), n * ROWS), :])
 
     def rest_of(e, carry):  # a span that passes its first window: the rest of it, a window at a time
@@ -155,7 +166,7 @@ def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, se
             start(begin, at)
             arrived(begin, at)
             r = begin + jax.lax.broadcasted_iota(jnp.int32, (1, ROWS), 1)
-            acc[...] += dot(places((r >= nominal) & (r < hi), tw[at]), xs[window(at), :])
+            acc[...] += dot(places((r >= nominal) & (r < hi), lanes(at)), xs[window(at), :])
             return carry
 
         return jax.lax.fori_loop(1, jnp.where(hi > lo, jax.lax.div(hi - first + ROWS - 1, ROWS), 0), more, carry)
@@ -173,8 +184,9 @@ def sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int, interpret: bool = Fa
     R, d = rows.shape
     tiles = n_tokens // TOKENS
     n = spans.shape[1] // tiles
+    ROWS = window_rows(n)
     lo, hi = spans[0][:, None], spans[1][:, None]
-    r = _first_row(lo, R) + jnp.arange(ROWS)  # (tiles * n, ROWS): the rows of every span's first window ...
+    r = _first_row(lo, R, ROWS) + jnp.arange(ROWS)  # (tiles * n, ROWS): the rows of every span's first window ...
     inside = ((r >= lo) & (r < hi)).astype(jnp.int32).reshape(tiles, 1, n * ROWS)  # ... and whether each is in its span
     return pl.pallas_call(
         functools.partial(_kernel, n=n, tiles=tiles),
@@ -185,7 +197,7 @@ def sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int, interpret: bool = Fa
             in_specs=[pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec((None, 1, n * ROWS), lambda i, lo_ref, hi_ref: (i, 0, 0))],
             out_specs=pl.BlockSpec((TOKENS, d), lambda i, lo_ref, hi_ref: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((2 * n * ROWS, d), rows.dtype), pltpu.VMEM((2 * n, 2, ROWS), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2 * n * ROWS, d), rows.dtype), pltpu.VMEM((2 * n, 2, LANES), jnp.float32),
                             pltpu.SemaphoreType.DMA((2, 2 * n)), pltpu.VMEM((TOKENS, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((n_tokens, d), rows.dtype),
         interpret=interpret,

@@ -86,15 +86,18 @@ def _all_finite(tree):
 
 # key on the trainer's first-call line -> the region whose choice it reports, as ``program_regions_traced_total`` labels
 # it (forward call sites, by ``path``); the line's word for any ``path`` but ``"xla"`` is ``kernel`` but for ``_PATH_WORDS``
-_PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}),
+_PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"}),
+          "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}),
           "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
 _PATH_WORDS = {"moe_cond": "fallback_keeps_nothing"}  # the one form ``routed_part``'s conditional has
+_ROUTER_WORDS = ("sigmoid", "softmax")  # ``path`` of ``ffn/router`` where a routed layer scores its tokens: the line's ``moe_router``
 
 
 def _paths_traced():
     """{key of ``_PATHS``: (call sites traced as a kernel, in XLA's form) so far}."""
     xla = {key: int(regions_traced(name, path="xla", **labels)) for key, (name, labels) in _PATHS.items()}
-    return {key: (int(regions_traced(name, **labels)) - xla[key], xla[key]) for key, (name, labels) in _PATHS.items()}
+    traced = {key: (int(regions_traced(name, **labels)) - xla[key], xla[key]) for key, (name, labels) in _PATHS.items()}
+    return dict(traced, moe_router=tuple(int(regions_traced("ffn/router", path=word)) for word in _ROUTER_WORDS))
 
 
 def _program_text(program, args):
@@ -760,7 +763,9 @@ class DeepSpeedEngine:
         part (``mla_rope``; no key where the model has no positions) and the
         routed FFN's grouped products were traced into this program, by the counters that count
         each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
-        fallback), ``mixed``, or no key where the program has none; and, where
+        fallback), ``mixed``, or no key where the program has none (``gdn_path``:
+        the delta-rule scan with a decay a head); how a routed layer scores its
+        tokens (``moe_router``: ``sigmoid`` or ``softmax``); and, where
         a routed layer's buffer is smaller than every pair, which form its
         conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``)."""
         kinds = getattr(getattr(self.module, "cfg", None), "kinds", None)
@@ -770,10 +775,14 @@ class DeepSpeedEngine:
         for mixer, ffn in kinds:
             count[f"{mixer}+{ffn}"] = count.get(f"{mixer}+{ffn}", 0) + 1
         notes = dict(layer_kinds=",".join(f"{k}:{n}" for k, n in sorted(count.items())))
-        for key, (kernel, xla) in _paths_traced().items():
+        traced = _paths_traced()
+        router = [word for word, now, before in zip(_ROUTER_WORDS, traced.pop("moe_router"), traced_before["moe_router"]) if now > before]
+        for key, (kernel, xla) in traced.items():
             kernel, xla = kernel - traced_before[key][0], xla - traced_before[key][1]
             if kernel or xla:
                 notes[key] = "mixed" if kernel and xla else _PATH_WORDS.get(key, "kernel") if kernel else "xla"
+        if router:
+            notes["moe_router"] = "+".join(router)
         return notes
 
     def _count_step_flops(self, program, args):

@@ -98,3 +98,73 @@ def routed(p, h, first, top_k, scale, shared=1):
         w_e = jnp.sum(jnp.where(idx == first + e, weights, 0.0), axis=-1, keepdims=True)
         y = y + w_e * swiglu(x, p["experts_wg"][e], p["experts_wi"][e], p["experts_wo"][e])
     return y.reshape(h.shape)
+
+
+# ---- the fourth configuration's layers: Gated DeltaNet, output-gated grouped-query attention, softmax routing ----
+def rms_offset(x, scale, eps=1e-6):
+    """x / rms * (1 + w): the zero-centered weight."""
+    return rms(x, 1.0 + scale, eps)
+
+
+def gdn(p, h, eps=1e-6, decay=True):
+    """One decay a value head and token; a key head's q and k for each of its value heads."""
+    heads = lambda name: conv_silu(jnp.einsum("bsd,dhk->bshk", h, p[f"{name}_proj"]["kernel"]), p[f"{name}_conv"])
+    D = p["q_conv"].shape[-1]
+    q, k, v = l2(heads("q")) * D ** -0.5, l2(heads("k")), heads("v")
+    Hv = v.shape[2]
+    q, k = (jnp.repeat(x, Hv // x.shape[2], axis=2) for x in (q, k))
+    ba = h @ p["ba_proj"]["kernel"]
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., Hv:] + p["dt_bias"])
+    alpha = jnp.broadcast_to((jnp.exp(g) if decay else jnp.ones_like(g))[..., None], q.shape)
+    o = delta_rule(q, k, v, alpha, jax.nn.sigmoid(ba[..., :Hv]))
+    z = jnp.einsum("bsd,dhk->bshk", h, p["z_proj"]["kernel"])
+    return jnp.einsum("bshk,hkd->bsd", rms(o, p["o_norm"]["scale"], eps) * jax.nn.silu(z), p["o_proj"]["kernel"])
+
+
+def rotate_half_leading(x, theta, rotated):
+    """The first ``rotated`` dims of each head, halves (i, i + rotated / 2) turned by t * theta^(-2i / rotated)."""
+    S = x.shape[1]
+    angle = (jnp.arange(S, dtype=jnp.float32)[:, None] * theta ** (-jnp.arange(0, rotated, 2, dtype=jnp.float32) / rotated))[None, :, None, :]
+    a, b = x[..., :rotated // 2], x[..., rotated // 2:rotated]
+    return jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle), b * jnp.cos(angle) + a * jnp.sin(angle), x[..., rotated:]], axis=-1)
+
+
+def gated_attention(p, h, theta, rotary, eps=1e-6, gated=True):
+    """q_proj's columns a head: the query, then the gate; q/k norms (1 + w); grouped keys and values."""
+    S = h.shape[1]
+    qg = jnp.einsum("bsd,dhk->bshk", h, p["q_proj"]["kernel"])
+    D = qg.shape[-1] // 2
+    q, gate = qg[..., :D], qg[..., D:]
+    k, v = (jnp.einsum("bsd,dhk->bshk", h, p[f"{n}_proj"]["kernel"]) for n in ("k", "v"))
+    q = rotate_half_leading(rms_offset(q, p["q_norm"]["scale"], eps), theta, int(D * rotary))
+    k = rotate_half_leading(rms_offset(k, p["k_norm"]["scale"], eps), theta, int(D * rotary))
+    k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
+    s = jnp.einsum("bqhk,bthk->bhqt", q, k) * D ** -0.5
+    a = jax.nn.softmax(jnp.where(jnp.arange(S)[:, None] >= jnp.arange(S)[None, :], s, -1e30), axis=-1)
+    o = jnp.einsum("bhqt,bthk->bqhk", a, v)
+    return jnp.einsum("bqhk,hkd->bqd", o * jax.nn.sigmoid(gate) if gated else o, p["o_proj"]["kernel"])
+
+
+def routed_softmax(p, h, first, top_k):
+    """Softmax over all experts, the top k renormalised; the held experts' part plus the shared expert times its sigmoid gate."""
+    x = h.reshape(-1, h.shape[-1])
+    chosen, idx = jax.lax.top_k(jax.nn.softmax(x @ p["gate"]["kernel"], axis=-1), top_k)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    y = swiglu(x, *(p[f"shared_{n}_proj"]["kernel"] for n in ("gate", "up", "down"))) * jax.nn.sigmoid(x @ p["shared_expert_gate"]["kernel"])
+    for e in range(p["experts_wg"].shape[0]):
+        w_e = jnp.sum(jnp.where(idx == first + e, weights, 0.0), axis=-1, keepdims=True)
+        y = y + w_e * swiglu(x, p["experts_wg"][e], p["experts_wi"][e], p["experts_wo"][e])
+    return y.reshape(h.shape)
+
+
+def deltanet_model_loss(params, ids, kinds, first, top_k, theta, rotary, eps=1e-6):
+    """Mean next-token loss of pre-norm blocks of the layers above, every norm (1 + w), an untied head."""
+    x = params["wte"][ids]
+    for i, (mixer, _) in enumerate(kinds):
+        p = params[f"layer_{i}"]
+        h = rms_offset(x, p["RMSNorm_0"]["scale"], eps)
+        x = x + (gdn(p["gdn"], h, eps) if mixer == "gdn" else gated_attention(p["attn"], h, theta, rotary, eps))
+        x = x + routed_softmax(p["routed"], rms_offset(x, p["RMSNorm_1"]["scale"], eps), first, top_k)
+    logits = rms_offset(x, params["RMSNorm_0"]["scale"], eps) @ params["lm_head"]["kernel"]
+    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1))

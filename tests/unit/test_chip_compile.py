@@ -153,6 +153,20 @@ def _kda(shape):
     return fwd_bwd, (x, x, x, S((B, Hh, Sq, Dh), F32), S((B, Hh, Sq), F32), x), 2
 
 
+def _gdn(shape):
+    """The same scan with one decay a head and token, value heads sharing a key head's q and k: 2 kernels."""
+    from deepspeed_tpu.ops.kda import gdn_chunked
+
+    B, Hk, Hv, Sq, Dh = shape
+
+    def fwd_bwd(q, k, v, g, beta, do):
+        o, vjp = jax.vjp(gdn_chunked, q, k, v, g, beta)
+        return (o,) + vjp(do)
+
+    qk, v, gate = S((B, Hk, Sq, Dh), BF16), S((B, Hv, Sq, Dh), BF16), S((B, Hv, Sq), F32)
+    return fwd_bwd, (qk, qk, v, gate, gate, v), 2
+
+
 def _moe_sum_rows(shape):
     """A routed layer's tokens sum their own rows off the expert-sorted buffer, each row times its weight: the combine,
     and with weights of one the backward of the rows' gather."""
@@ -168,14 +182,19 @@ CASES = {
     "moe_sum_rows_t8192_d2048_e8_r24576": lambda: _moe_sum_rows((8192, 2048, 8, 24576)),  # kimi-vl-a3b-l6e8's routed layers, the usual buffer
     "moe_sum_rows_t8192_d2304_e8_r8192": lambda: _moe_sum_rows((8192, 2304, 8, 8192)),    # kimi-linear-48b-l5e8's
     "moe_sum_rows_t8192_d2304_e8_r65536": lambda: _moe_sum_rows((8192, 2304, 8, 65536)),  # ... and the buffer of every pair (the cond's other branch)
+    "moe_sum_rows_t8192_d2048_e32_r20480": lambda: _moe_sum_rows((8192, 2048, 32, 20480)),  # qwen3-next-80b-l4e32's: windows of 32 rows
+    "moe_sum_rows_t8192_d2048_e32_r81920": lambda: _moe_sum_rows((8192, 2048, 32, 81920)),  # ... and every pair
     "flash_latent_b1_s8192_h32_d192_v128": lambda: _flash_latent((1, 8192, 32, 192, 128)),  # kimi-linear-48b-l5e8's MLA layer
     "flash_latent_b1_s8192_h16_d192_v128": lambda: _flash_latent((1, 8192, 16, 192, 128)),  # kimi-vl-a3b-l6e8's, every layer
     "kda_scan_b1_h32_s8192_d128": lambda: _kda((1, 32, 8192, 128)),                         # ... and its KDA layers
     "kda_scan_b2_h4_s1000_d128": lambda: _kda((2, 4, 1000, 128)),                          # a length that is padded to chunks
+    "gdn_scan_b1_h16_v32_s8192_d128": lambda: _gdn((1, 16, 32, 8192, 128)),                 # qwen3-next-80b-l4e32's DeltaNet layers
+    "gdn_scan_b2_h2_v4_s1000_d128": lambda: _gdn((2, 2, 4, 1000, 128)),
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
     "flash_gqa_b2_s4096_h32_kvh4_d128": lambda: _flash((2, 4096, 32, 4, 128)),
     "flash_mha_b2_s2048_h16_d128": lambda: _flash((2, 2048, 16, 16, 128)),  # olmo-1b.pretrain-z3, one chip's share
     "flash_mha_b1_s8192_h16_d128": lambda: _flash((1, 8192, 16, 16, 128)),  # 24 MiB resident: fused, limit raised
+    "flash_gqa_b1_s8192_h16_kvh2_d256": lambda: _flash((1, 8192, 16, 2, 256)),  # qwen3-next-80b-l4e32's full layer: a head at a time in the backward
     "flash_mha_b1_s32768_h2_d128": lambda: _flash((1, 32768, 2, 2, 128), "refused"),  # 77 MiB resident: over the budget
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     "fused_adam_mlp_768x3072": lambda: _fused_adam((768, 3072)),
