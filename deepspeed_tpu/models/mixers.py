@@ -10,12 +10,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
 from ..ops.kda import gdn, kda
 from ..ops.registry import pallas_available
 from ..telemetry.tracing import region
-from .transformer import RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
+from .transformer import SAVED, RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
 
 
 def _uniform(low, high):
@@ -61,18 +62,23 @@ class KDAMixer(nn.Module):
         cfg = self.cfg
         H, D, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
         f32 = jnp.float32
+        # named (``SAVED``: what a checkpointed block keeps) are the results of the products over the model width, each in
+        # the layout its consumer reads; the convolutions, SiLUs, l2 norms and the gates' second products (over ``rank``)
+        # follow from them by elementwise work
         heads = lambda name: nn.DenseGeneral((H, D), use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)
         exact = lambda dtype: jax.lax.Precision.HIGHEST if dtype == f32 else None  # the float32 gates take no bf16 pass
-        low_rank = lambda name, dtype: nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_b", dtype=dtype, param_dtype=f32,
-                                                       precision=exact(dtype))(
-            nn.Dense(rank, use_bias=False, name=f"{name}_a", dtype=dtype, param_dtype=f32, precision=exact(dtype))(x.astype(dtype)))
+
+        def low_rank(name, dtype):
+            a = nn.Dense(rank, use_bias=False, name=f"{name}_a", dtype=dtype, param_dtype=f32, precision=exact(dtype))(x.astype(dtype))
+            return nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_b", dtype=dtype, param_dtype=f32, precision=exact(dtype))(
+                checkpoint_name(a, SAVED))
 
         heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
 
         def conv_silu(name):
             w = self.param(f"{name}_conv", _uniform(-cfg.kda_conv_size**-0.5, cfg.kda_conv_size**-0.5),
                            (cfg.kda_conv_size, H, D), f32)
-            return nn.silu(causal_conv(heads_first(heads(f"{name}_proj")(x)), w.astype(cfg.dtype)[:, :, None, :], axis=2))
+            return nn.silu(causal_conv(checkpoint_name(heads_first(heads(f"{name}_proj")(x)), SAVED), w.astype(cfg.dtype)[:, :, None, :], axis=2))
 
         with region("mixer/proj"):  # the projections, their convolutions and the gates
             q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
@@ -81,7 +87,8 @@ class KDAMixer(nn.Module):
             a_log = self.param("A_log", _a_log_init, (H,), f32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), f32)
             g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
-            beta = jax.nn.sigmoid(nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)))
+            beta = jax.nn.sigmoid(checkpoint_name(
+                nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)), SAVED))
         o = kda(q, k, v, g, jnp.swapaxes(beta, 1, 2))  # (B, H, S, D)
         with region("mixer/proj"):
             o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * jax.nn.sigmoid(heads_first(low_rank("g", cfg.dtype)))
@@ -109,8 +116,9 @@ class GDNMixer(nn.Module):
         Hk, Hv, D = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
         f32 = jnp.float32
         heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
-        heads = lambda name, H: heads_first(nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_proj", dtype=cfg.dtype,
-                                                            param_dtype=f32)(x))
+        # named (``SAVED``: what a checkpointed block keeps): the four projections in the scan's layout and the gates' one
+        heads = lambda name, H: checkpoint_name(heads_first(nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_proj", dtype=cfg.dtype,
+                                                                            param_dtype=f32)(x)), SAVED)
 
         def conv_silu(name, H):
             w = self.param(f"{name}_conv", _uniform(-cfg.gdn_conv_size**-0.5, cfg.gdn_conv_size**-0.5),
@@ -123,8 +131,8 @@ class GDNMixer(nn.Module):
             v = conv_silu("v", Hv)
             a_log = self.param("A_log", _a_log_init, (Hv,), f32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (Hv,), f32)
-            ba = nn.Dense(2 * Hv, use_bias=False, name="ba_proj", dtype=f32, param_dtype=f32,
-                          precision=jax.lax.Precision.HIGHEST)(x.astype(f32))  # the float32 gates take no bf16 pass
+            ba = checkpoint_name(nn.Dense(2 * Hv, use_bias=False, name="ba_proj", dtype=f32, param_dtype=f32,
+                                          precision=jax.lax.Precision.HIGHEST)(x.astype(f32)), SAVED)  # the float32 gates take no bf16 pass
             beta = jax.nn.sigmoid(ba[..., :Hv])
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)  # (B, S, Hv)
         o = gdn(q, k, v, jnp.swapaxes(g, 1, 2), jnp.swapaxes(beta, 1, 2))  # (B, Hv, S, D)
@@ -157,7 +165,9 @@ class MLAMixer(nn.Module):
         f32 = jnp.float32
         with region("mixer/proj"):
             q = nn.DenseGeneral((H, dn + dr), use_bias=False, name="q_proj", dtype=cfg.dtype, param_dtype=f32)(x)
-            latent = nn.Dense(cfg.mla_kv_rank + dr, use_bias=False, name="kv_a_proj", dtype=cfg.dtype, param_dtype=f32)(x)
+            # named (``SAVED``: what a checkpointed block keeps): the latent, from which the norm and ``kv_b_proj``'s backward follow
+            latent = checkpoint_name(
+                nn.Dense(cfg.mla_kv_rank + dr, use_bias=False, name="kv_a_proj", dtype=cfg.dtype, param_dtype=f32)(x), SAVED)
             c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
             c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
             kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
@@ -171,9 +181,13 @@ class MLAMixer(nn.Module):
                 q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions, style=cfg.rope_style)], axis=-1)
                 k_shared = apply_rope(k_shared[:, :, None, :], cos, sin, positions, style=cfg.rope_style)[:, :, 0, :]  # once, as one head
             k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
+            # named too: the attention call's own operands, which its backward reads. Everything between the three products
+            # and them (the slices, the rotation, the shared part's way into every head) is linear, so a checkpointed
+            # block that keeps these makes neither ``q_proj`` nor ``kv_b_proj`` nor the rotation a second time
+            q, k, v = checkpoint_name(q, SAVED), checkpoint_name(k, SAVED), checkpoint_name(kv[..., dn:], SAVED)
         # a call of unequal head sizes is latent attention's: the flash kernels count it so; off the TPU it is counted here
         with region("mixer/kernel", **({} if pallas_available() else {"op": "mla", "pass": "fwd", "path": "xla"})):
-            o = attention(q, k, kv[..., dn:], causal=True, scale=(dn + dr)**-0.5)
+            o = attention(q, k, v, causal=True, scale=(dn + dr)**-0.5)
         with region("mixer/proj"):
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=f32)(o)

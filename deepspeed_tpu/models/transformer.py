@@ -22,6 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
@@ -199,6 +200,13 @@ class TransformerConfig:
 
 MIXERS = ("full", "window", "kda", "gdn", "mla")
 FFNS = ("dense", "moe", "routed")
+
+# The name a projection's result carries for a checkpoint policy: what a product over the model width gives (a mixer's
+# q/k/v/gate projections, the dense FFN's gate and up), what one onto it gives where a backward reads it (the mixer's
+# output added to the block's input), or a value after such a product from which the backward's needs follow elementwise.
+# A checkpointed hybrid block keeps it (``remat_keeps``), so its backward makes no such product a second time; outside such
+# a policy (serving, ``remat: false``, a ``full``/``dense`` block) ``checkpoint_name`` is an identity
+SAVED = "projection"
 
 
 @functools.lru_cache(maxsize=256)
@@ -401,14 +409,15 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, S, _ = x.shape
         H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-        dense = lambda feats, name: nn.DenseGeneral(feats, axis=-1, use_bias=cfg.use_qkv_bias, name=name,
-                                                    dtype=cfg.dtype, param_dtype=jnp.float32)
+        # named (``SAVED``): clipping, the norms, the rotation and the output gate follow from these by elementwise work
+        dense = lambda feats, name: checkpoint_name(nn.DenseGeneral(feats, axis=-1, use_bias=cfg.use_qkv_bias, name=name,
+                                                                    dtype=cfg.dtype, param_dtype=jnp.float32)(x), SAVED)
         with region("mixer/proj"):
-            q = dense((H, 2 * D if cfg.attn_output_gate else D), "q_proj")(x)
+            q = dense((H, 2 * D if cfg.attn_output_gate else D), "q_proj")
             if cfg.attn_output_gate:
                 q, gate = q[..., :D], q[..., D:]
-            k = dense((KVH, D), "k_proj")(x)
-            v = dense((KVH, D), "v_proj")(x)
+            k = dense((KVH, D), "k_proj")
+            v = dense((KVH, D), "v_proj")
             if cfg.clip_qkv is not None:  # olmo: clamp projections before rope
                 c = cfg.clip_qkv
                 q, k, v = (jnp.clip(t, -c, c) for t in (q, k, v))
@@ -453,12 +462,14 @@ class MLP(nn.Module):
         with region("ffn/dense"):
             cfg = self.cfg
             bias = cfg.use_dense_bias
+            # named (``SAVED``): the activation and the down projection's operand follow from these by elementwise work
+            wide = lambda name: checkpoint_name(
+                nn.Dense(cfg.ffn_dim, use_bias=bias, name=name, dtype=cfg.dtype, param_dtype=jnp.float32)(x), SAVED)
             if cfg.activation in ("swiglu", "geglu"):
-                gate = nn.Dense(cfg.ffn_dim, use_bias=bias, name="gate_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
-                up = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+                gate, up = wide("gate_proj"), wide("up_proj")
                 h = (nn.gelu(gate) if cfg.activation == "geglu" else nn.silu(gate)) * up
             else:
-                h = nn.Dense(cfg.ffn_dim, use_bias=bias, name="up_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+                h = wide("up_proj")
                 if cfg.activation == "relu":
                     h = nn.relu(h)
                 else:  # HF "gelu" is the exact erf form; "gelu_new"/tanh is our default
@@ -535,7 +546,9 @@ class Block(nn.Module):
             x = _norm(cfg, x + self._mlp(cfg, x))
         else:
             a, new_cache = run_attn(_norm(cfg, x))
-            x = x + a
+            # named (``SAVED``): the FFN half's backward starts from this sum, so a checkpointed block that keeps it does not
+            # make the mixer's output projection again to get it back
+            x = checkpoint_name(x + a, SAVED)
             x = x + self._mlp(cfg, _norm(cfg, x))
         return (x, new_cache) if kv_cache is not None else x
 
@@ -733,17 +746,44 @@ def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: 
         return (out if kv_cache is not None else (out, None)), sown
 
     fn = wrap(apply) if wrap is not None else apply
-    if remat and (kind[0] in ("kda", "gdn", "mla") or kind[1] == "routed"):
-        # a hybrid block recomputes everything but what is named as too dear to make twice: the delta-rule scan's outputs, a
-        # latent-attention call's output and row statistics, a routed layer's sorted rows and grouped products
-        from ..moe.sharded_moe import SAVED as routed_rows
-        from ..ops.kda import SAVED as kda_scan
-        from ..ops.pallas.flash_attention import SAVED as flash_out
-
-        fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(kda_scan, routed_rows, flash_out))
-    elif remat:
-        fn = jax.checkpoint(fn)
+    if remat:
+        # a hybrid block keeps, by name, its kernels' outputs and every projection's result, so its backward makes again
+        # only the elementwise work between them (``remat_keeps``); any other kind keeps its inputs alone
+        keeps = remat_keeps(kind)
+        fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(*keeps)) if keeps else jax.checkpoint(fn)
     return jax.jit(fn, inline=True)
+
+
+def remat_keeps(kind: Tuple[str, str]) -> Tuple[str, ...]:
+    """The names a checkpointed block of this kind keeps (``block_fn``'s policy); none: plain ``jax.checkpoint``, which
+    keeps the block's inputs alone.
+
+    The rule for a hybrid block (a ``kda``, ``gdn`` or ``mla`` mixer, or a routed FFN): its backward runs no kernel, no
+    product over or onto the model width, no top-k and no sort a second time. Kept by name are the kernels' outputs (the
+    scan's with its states and inverses, the flash call's with its row statistics), every projection's result (``SAVED``)
+    and the routed layer's scores, choice, sorted rows and grouped products; what lies between them is elementwise (and
+    the low-rank gates' second products, over 128) and is made again.
+
+    What it costs (``PERF.md`` section 6, PR 40): the projections are kept for every layer at once, not inside one
+    layer's peak, so they grow with depth and tokens: 0.16 (``gdn``), 0.29 (``mla``) and 0.30 GB (``kda``, routed FFNs
+    with a shared expert) of the step's temporaries a layer at 8,192 tokens, for 7-10% more tokens a second at 4 to 6
+    layers. Qwen3-Next's four layers at 16,384 tokens, which the kernels' outputs alone let compile for a 16 GB chip,
+    are refused with them by 2.0 GB. Where a step is refused, the names to give up are the widest a millisecond saved,
+    by the block's own shapes: latent attention's assembled k and v (``kv_b_proj`` contracts the latent's 512, a quarter
+    of the width: keep the latent alone), then the shared and dense FFN's gate and up (two values of ``d_ff`` a token for
+    two products), then the mixers' q/k/v; the gates', the router's and the latent's few MB stay to the last.
+
+    A kept value is rounded to the dtype the model states: ``jax.checkpoint`` puts a ``reduce_precision`` on every
+    residual's producer, so forward and backward read the same number, where XLA's excess precision may carry a value
+    that is made again on in float32 (``xla_allow_excess_precision``); the gradients are those of the block without a
+    checkpoint."""
+    if kind[0] not in ("kda", "gdn", "mla") and kind[1] != "routed":
+        return ()
+    from ..moe.sharded_moe import SAVED as routed_ffn
+    from ..ops.kda import SAVED as kda_scan
+    from ..ops.pallas.flash_attention import SAVED as flash_attention
+
+    return (kda_scan, routed_ffn, flash_attention, SAVED)
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarray:

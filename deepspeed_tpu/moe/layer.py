@@ -15,10 +15,11 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry.tracing import region
-from .sharded_moe import combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
+from .sharded_moe import SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
 
 class Experts(nn.Module):
@@ -189,8 +190,10 @@ class RoutedMoE(nn.Module):
         tokens = x.reshape(-1, d)
         init = nn.initializers.normal(0.02)
         with region("ffn/router", path=self.scoring):  # the scoring, counted where it is chosen
-            logits = nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32))  # which experts: no bf16 pass
+            # which experts: no bf16 pass. Named, as the shared expert's products are (``SAVED``: a checkpointed block keeps
+            # them): the scores and the activation follow by elementwise work
+            logits = checkpoint_name(nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
+                                              precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32)), SAVED)
             if self.scoring == "softmax":
                 idx, weights = softmax_topk(logits, self.k, self.scale)
             else:
@@ -207,10 +210,12 @@ class RoutedMoE(nn.Module):
             with region("ffn/shared", **({"path": "gated"} if self.shared_gate else {})):
                 dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
                                                      param_dtype=jnp.float32)
-                h = nn.silu(dense(self.shared_ff, "shared_gate_proj")(tokens)) * dense(self.shared_ff, "shared_up_proj")(tokens)
+                of_tokens = lambda feats, name: checkpoint_name(dense(feats, name)(tokens), SAVED)
+                h = nn.silu(of_tokens(self.shared_ff, "shared_gate_proj")) * of_tokens(self.shared_ff, "shared_up_proj")
                 shared = dense(d, "shared_down_proj")(h)
-                if self.shared_gate:
-                    shared = shared * jax.nn.sigmoid(dense(1, "shared_expert_gate")(tokens).astype(jnp.float32)).astype(shared.dtype)
+                if self.shared_gate:  # the gate's backward reads the shared expert's output: named with the rest
+                    gate = jax.nn.sigmoid(of_tokens(1, "shared_expert_gate").astype(jnp.float32))
+                    shared = checkpoint_name(shared, SAVED) * gate.astype(shared.dtype)
                 out = out + shared
         return out.reshape(x.shape).astype(x.dtype)
 

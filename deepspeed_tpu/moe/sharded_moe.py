@@ -20,7 +20,12 @@ from jax.ad_checkpoint import checkpoint_name
 from ..telemetry.tracing import region
 
 uniform_map = {}
-SAVED = "routed_ffn"  # the name a routed layer's sorted rows and grouped products carry for a checkpoint policy
+# The name a routed layer's values carry for a checkpoint policy: the router's scores' product and its choice
+# (``sigmoid_topk`` / ``softmax_topk``), the order, the sorted rows and the grouped products (``held_experts``), the shared
+# expert's gate and up products (``moe/layer.py``). A checkpointed hybrid block keeps them
+# (``models/transformer.py::remat_keeps``), so its backward runs no product over the model width, no top-k, no sort and
+# no grouped product a second time; the activations between them are elementwise and are made again
+SAVED = "routed_ffn"
 
 
 def multiplicative_jitter(x: jnp.ndarray, rng, epsilon: float = 1e-2) -> jnp.ndarray:
@@ -143,21 +148,32 @@ def _renormalised(chosen, scale: float):
     return chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
 
 
+def _chosen(scores, ranked, k: int):
+    """The columns of the ``k`` largest of ``ranked`` in each row, and ``scores`` there: (N, E) -> ((N, k) int32, (N, k)).
+    Both are named, so a checkpointed block keeps them and its backward runs neither the top-k nor the gather again.
+    The values ``lax.top_k`` itself returns are left unused: its own rule reads the indices of the call it
+    differentiates, which no name reaches, and a backward through them would make the top-k a second time."""
+    _, idx = jax.lax.top_k(ranked, k)
+    idx = checkpoint_name(idx, SAVED)
+    return idx, checkpoint_name(jnp.take_along_axis(scores, idx, axis=-1), SAVED)
+
+
 def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float):
     """Sigmoid scores, the top ``k`` of ``score + select_bias`` (the bias only
     chooses: it takes no gradient and does not enter the weight), the chosen
     scores rescaled to sum to one and multiplied by ``scale``.
     logits (N, E) float32 -> (indices (N, k) int32, weights (N, k) float32)."""
     scores = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
-    return idx, _renormalised(jnp.take_along_axis(scores, idx, axis=-1), scale)
+    idx, chosen = _chosen(scores, scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+    return idx, _renormalised(chosen, scale)
 
 
 def softmax_topk(logits: jnp.ndarray, k: int, scale: float):
     """Softmax over ALL experts in float32, its ``k`` largest, rescaled to sum
     to one and multiplied by ``scale``. logits (N, E) -> (indices (N, k)
     int32, weights (N, k) float32)."""
-    chosen, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), axis=-1), k)
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    idx, chosen = _chosen(scores, scores, k)
     return idx, _renormalised(chosen, scale)
 
 

@@ -28,7 +28,10 @@ from jax.sharding import PartitionSpec as P
 from ..telemetry.tracing import region
 from .registry import pallas_available
 
-SAVED = "kda_scan"  # the name the kernel's outputs carry for a checkpoint policy
+# The name the scan kernel's outputs carry for a checkpoint policy: the scan's result, every chunk's incoming state and its
+# (I + A)^-1. A checkpointed hybrid block keeps them (``models/transformer.py::remat_keeps``), so its backward runs no
+# second scan; the kernel's operands carry no name and follow, elementwise, from the projections the block keeps
+SAVED = "kda_scan"
 
 
 def _traced(pass_: str, path: str, op: str = "kda"):

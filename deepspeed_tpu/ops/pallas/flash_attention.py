@@ -58,7 +58,10 @@ from ..registry import REGISTRY, pallas_available
 from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh, vmem_budget
 
 NEG_INF = -1e30
-SAVED = "flash_attention"  # the name the forward's output and row statistics carry for a checkpoint policy
+# The name the forward's output and row statistics carry for a checkpoint policy. A checkpointed hybrid block keeps them
+# (``models/transformer.py::remat_keeps``), so its backward runs no second forward kernel; q, k and v carry no name here:
+# the caller keeps them, or the projections they follow from elementwise
+SAVED = "flash_attention"
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
 
 # Default blocks are large: the grid runs sequentially on the (single)

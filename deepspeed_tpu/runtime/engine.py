@@ -767,14 +767,23 @@ class DeepSpeedEngine:
         the delta-rule scan with a decay a head); how a routed layer scores its
         tokens (``moe_router``: ``sigmoid`` or ``softmax``); and, where
         a routed layer's buffer is smaller than every pair, which form its
-        conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``)."""
-        kinds = getattr(getattr(self.module, "cfg", None), "kinds", None)
-        if not kinds or len(set(kinds)) == 1:
+        conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``). Whatever the kinds, under ``remat``:
+        what a checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
+        cfg = getattr(self.module, "cfg", None)
+        kinds = getattr(cfg, "kinds", None)
+        if not kinds:
             return {}
+        notes = {}
+        if cfg.remat:  # under ``scan_layers`` the blocks go through ``nn.remat(Block)``, which has no policy
+            from ..models.transformer import remat_keeps
+            names = () if cfg.scan_layers else sorted({name for kind in kinds for name in remat_keeps(kind)})
+            notes["remat_keeps"] = "+".join(names) or "inputs"
+        if len(set(kinds)) == 1:
+            return notes
         count = {}
         for mixer, ffn in kinds:
             count[f"{mixer}+{ffn}"] = count.get(f"{mixer}+{ffn}", 0) + 1
-        notes = dict(layer_kinds=",".join(f"{k}:{n}" for k, n in sorted(count.items())))
+        notes["layer_kinds"] = ",".join(f"{k}:{n}" for k, n in sorted(count.items()))
         traced = _paths_traced()
         router = [word for word, now, before in zip(_ROUTER_WORDS, traced.pop("moe_router"), traced_before["moe_router"]) if now > before]
         for key, (kernel, xla) in traced.items():

@@ -385,14 +385,19 @@ def test_six_layers_of_rotated_latent_attention_trace_two_blocks_and_build_one_t
     assert model.cfg.kinds == VL_KINDS and tiny().pos_emb == "none"
 
 
-def test_the_unrotated_latent_mixer_is_the_program_it_was():
+def test_the_unrotated_latent_mixer_is_the_program_it_was(monkeypatch):
     """The no-positions form, equation for equation: the mixer's jaxpr at this size is the text the parent commit's
-    mixer gave (sha256 of ``str(jaxpr)``, made from the parent by the same lines), and positions handed to it change nothing."""
+    mixer gave (sha256 of ``str(jaxpr)``, made from the parent by the same lines), and positions handed to it change nothing.
+    What a checkpointed block keeps of it (PR 40) is four ``name`` equations, which compile to nothing: the text is
+    taken without them."""
+    from deepspeed_tpu.models import mixers
     from deepspeed_tpu.models.mixers import MLAMixer
 
     mixer = MLAMixer(tiny(n_layers=1, layer_kinds=(("mla", "dense"),)))
     x = jnp.zeros((2, 40, 48))
     params = jax.eval_shape(lambda: mixer.init(jax.random.PRNGKey(0), x))
+    assert str(jax.make_jaxpr(lambda p, x: mixer.apply(p, x))(params, x)).count("name[name=projection]") == 4
+    monkeypatch.setattr(mixers, "checkpoint_name", lambda value, name: value)
     positions = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), (2, 40))
     with jax.default_matmul_precision("highest"):  # said here: the module's fixture may or may not be live on this worker
         text = str(jax.make_jaxpr(lambda p, x: mixer.apply(p, x))(params, x))

@@ -175,7 +175,11 @@ def test_the_first_call_line_says_what_share_of_the_products_is_the_second_forwa
         said = _step(name)[3]
         flops = {phase: said[f"flops_{phase}"] for phase in tracing.PHASES}
         assert flops["forward"] > 0 and flops["backward"] > flops["forward"] and flops["update"] > 0
-        assert (flops["recomputed"] > 0) == cell["remat"] and flops["recomputed"] <= flops["forward"]
+        # a checkpointed hybrid block keeps its projections' results (PR 40): the second forward is still a phase, of
+        # elementwise work and, at this width off the chip, the attention fallback's own products over 64 positions (which
+        # the flash kernel, whose output is kept, does not make again): 21% and 26% of the forward here where 78% and 81% were
+        assert (flops["recomputed"] > 0) == cell["remat"] and flops["recomputed"] <= 0.3 * flops["forward"]
+        assert said.get("remat_keeps") == ("flash_attention+kda_scan+projection+routed_ffn" if cell["remat"] else None)
         assert set(said["region_trace_s"]) <= REGIONS and said["region_trace_s"]["optimizer"] > 0
         assert sum(said["region_trace_s"].values()) < said["total_s"]
 
