@@ -1,0 +1,28 @@
+"""The indexer's scores and the choice of keys: their kernels' share of their
+roofline. The least time for the score products over every visible pair,
+forward and backward, and for reading the indexer's projections and writing
+the choice (``index_cost`` of the configuration's own FLOP module, times its
+``sparse_layers(published)``, of the steps in the traced stretch), over the
+device time of ``index_scores``, ``index_select`` and ``index_scores_bwd`` (the
+Pallas calls ``ops/indexed_attention.py`` makes on a TPU). None where the
+configuration names no such cost, or nothing matches."""
+
+from benchmarks.lib import flops, kernel_time
+from benchmarks.lib.peaks import peaks_for
+
+UNIT, BETTER, SOURCE = "%", "higher", "device_trace"
+LAYER = "kernels (ops/pallas/indexed_attention.py)"
+MOVES = "train_tokens_per_s"
+KERNELS = r"^(?=.*custom-call)(?=.*\bindex_(scores|select|scores_bwd)\b)"
+
+
+def read(record):
+    counts = flops.for_config(record.get("config"))
+    cost, layers = getattr(counts, "index_cost", None), getattr(counts, "sparse_layers", None)
+    steps, took = kernel_time.steps_and_seconds(record.get("reduced"), KERNELS)
+    if cost is None or layers is None or not took:
+        return None
+    m, t = record["published"], record["train"]
+    peaks = peaks_for(record["device"]["kind"])
+    need = sum(flops.roofline_seconds(cost(m, t["micro_batch"], t["seq_len"], backward=b), peaks)["seconds"] for b in (False, True))
+    return 100.0 * steps * layers(m) * need / took

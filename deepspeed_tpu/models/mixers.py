@@ -1,7 +1,8 @@
 """Token mixers beside softmax attention over one head size: gated
 delta-rule linear-attention layers with a decay a channel (KDA) or a head
-(Gated DeltaNet), and latent attention (MLA), without positions or with its
-shared key part rotated. All are training-side
+(Gated DeltaNet), latent attention (MLA), without positions or with its
+shared key part rotated, and grouped-query attention over the keys a learned
+indexer chooses for each query (``SparseMixer``). All are training-side
 modules: a block built from them takes no KV cache (``inference/v2`` refuses
 these kinds by name).
 """
@@ -12,11 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import indexed_attention as sparse
 from ..ops.attention import attention
 from ..ops.kda import gdn, kda
 from ..ops.registry import pallas_available
 from ..telemetry.tracing import region
-from .transformer import SAVED, RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
+from .transformer import SAVED, LayerNorm, RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
 
 
 def _uniform(low, high):
@@ -191,3 +193,73 @@ class MLAMixer(nn.Module):
         with region("mixer/proj"):
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=f32)(o)
+
+
+class SparseMixer(nn.Module):
+    """Grouped-query attention whose keys a learned indexer chooses, a query at a time (the DeepSeek-Sparse-Attention
+    family). With ``h`` the block's normed input, ``t`` a query and ``s <= t`` a key position:
+
+    - main heads as ``Attention`` has them: q, k, v projections, the per-head q/k norms under ``qk_norm``, the rotation;
+    - indexer, on ``stop_gradient(h)``: ``qI[t, j] = rope(h[t] Wq_j)`` for ``index_heads`` heads of ``index_head_dim``,
+      ``kI[s] = rope(LayerNorm(h[s] Wk))`` (one head), ``w[t] = h[t] Ww * index_heads^-0.5 * index_head_dim^-0.5``;
+      ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``, accumulated in float32;
+    - choice: ``S_t`` = the ``min(index_topk, t + 1)`` visible positions of largest ``I[t, s]``, ties to the lower
+      index: discrete, no gradient, one choice a position for every head;
+    - attention: head i's output at t is ``softmax_{s in S_t}(q_i[t] . k_g(i)[s] / sqrt(D))`` over ``v_g(i)[s]``;
+    - the indexer's own loss, sown as ``index_loss`` (``CausalLM.loss_fn`` adds its gradient and not its value):
+      ``mean_t KL(p[t, S_t] || softmax_{S_t} I[t, .])`` with ``p`` the heads' probabilities summed and renormalised
+      over ``S_t``, under ``stop_gradient``. So the main leaves learn from the model's loss alone and the indexer's
+      (``index_*``) from this alone.
+
+    A sequence no longer than ``index_topk`` leaves nothing to choose: the program is then dense causal attention
+    through the registry, the indexer is not traced (its leaves take a zero gradient) and nothing is sown. Sown
+    beside the loss: ``sparse_keys``, (chosen, visible) pairs of the call (``ops/indexed_attention.py`` has the forms a
+    backend takes), and ``choice``, the key-major mask."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        J, Di, f32 = cfg.index_heads, cfg.index_head_dim, jnp.float32
+        dense = lambda feats, name, of: checkpoint_name(
+            nn.DenseGeneral(feats, axis=-1, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)(of), SAVED)
+        with region("mixer/proj"):
+            q, k, v = dense((H, D), "q_proj", x), dense((KVH, D), "k_proj", x), dense((KVH, D), "v_proj", x)
+            if cfg.qk_norm:
+                q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="k_norm")(k)
+        rotate = lambda t, width: apply_rope(t, *scaled_rope_frequencies(cfg, width), positions, style=cfg.rope_style)
+        if cfg.pos_emb == "rope":
+            with region("mixer/rope"):
+                q, k = rotate(q, D), rotate(k, D)
+        scale = cfg.attn_scale or D**-0.5
+        if S <= cfg.index_topk and not self.is_initializing():
+            out = attention(q, k, v, causal=True, scale=scale)  # every visible key is chosen: the dense mixer's program
+        else:
+            path = sparse.path_for(S, cfg.index_topk)
+            with region("mixer/index"):  # the indexer's projections and scores, on an input cut from the graph
+                h = jax.lax.stop_gradient(x)
+                q_i, k_i = dense((J, Di), "index_q_proj", h), dense(Di, "index_k_proj", h)
+                k_i = LayerNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="index_k_norm")(k_i)[:, :, None, :]
+                w = dense(J, "index_w_proj", h).astype(f32) * (J**-0.5 * Di**-0.5)
+                if cfg.pos_emb == "rope":
+                    q_i, k_i = rotate(q_i, Di), rotate(k_i, Di)
+                q_i, k_i, w = jnp.swapaxes(q_i, 1, 2), k_i[:, :, 0, :], jnp.swapaxes(w, 1, 2)  # heads first: (B, J, S, .)
+                scores_t = sparse.index_scores(q_i, k_i, w, path=path)
+            mask_t = sparse.select_keys(scores_t, cfg.index_topk, path=path)
+            with region("mixer/kernel"):
+                out, lse = sparse.sparse_attention(q, k, v, mask_t, scale=scale, path=path)
+            with region("mixer/index_loss"):
+                probs_t = sparse.head_probs(q, k, lse, mask_t, scale=scale, path=path)
+                loss = sparse.index_loss(q_i, k_i, w, scores_t, probs_t, mask_t, dtype=cfg.dtype, path=path)
+                chosen = jnp.sum(mask_t.astype(f32))
+            self.sow("intermediates", "index_loss", loss)
+            self.sow("intermediates", "choice", mask_t)  # key-major (B, Sk, Sq) int8: for a caller that asks; a step drops it
+            self.sow("intermediates", "sparse_keys", jnp.stack([chosen, jnp.asarray(B * S * (S + 1) / 2, f32)]))
+        with region("mixer/proj"):
+            init = nn.initializers.variance_scaling(cfg.sparse_out_init_scale**2, "fan_in", "truncated_normal")  # 1: flax's own
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                                   param_dtype=f32, kernel_init=init)(out)

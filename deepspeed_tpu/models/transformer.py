@@ -100,7 +100,8 @@ class TransformerConfig:
     moe_min_capacity: int = 4
     # THE per-layer specification: one (mixer, ffn) pair a layer. mixer: full | window (``sliding_window``) |
     # kda (gated delta-rule linear attention, a decay a channel) | gdn (the same rule with a decay a head: Gated
-    # DeltaNet) | mla (latent attention: its shared key part rotated where ``pos_emb`` is "rope", else no positions);
+    # DeltaNet) | mla (latent attention: its shared key part rotated where ``pos_emb`` is "rope", else no positions) |
+    # sparse (grouped-query attention over the ``index_topk`` keys a learned indexer chooses for each query);
     # ffn: dense | moe (the softmax gate with a capacity above) | routed (``moe_scoring`` scores, no capacity, a
     # shared expert). None: the pairs that ``window_layers`` and ``moe_layer_freq`` describe (``kinds``)
     layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
@@ -115,6 +116,15 @@ class TransformerConfig:
     gdn_value_heads: int = 0
     gdn_head_dim: int = 128
     gdn_conv_size: int = 4
+    # sparse: an indexer of ``index_heads`` heads of ``index_head_dim`` on one key head scores every visible key; a
+    # query attends the ``index_topk`` best (every key, through the dense program, where the sequence is no longer)
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
+    # sparse: the attention's output projection starts at this times its usual standard deviation. At a random start
+    # attention averages its keys, so every position gets nearly the same vector and the stream collapses onto it layer
+    # by layer; a random router turns that into a load a seed decides. A small start leaves the stream the tokens' own
+    sparse_out_init_scale: float = 1.0
     mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (the rope dims rotated under
     # ``pos_emb="rope"`` by ``rope_theta`` / ``rope_style``, else nothing is), v of its own
     mla_qk_nope_dim: int = 128
@@ -191,6 +201,12 @@ class TransformerConfig:
         return all(m in ("full", "window") and f in ("dense", "moe") for m, f in self.kinds)
 
     @property
+    def sows(self) -> bool:
+        """Whether a block of this model may sow (an expert layer's auxiliary loss and rows, a sparse mixer's index
+        loss and key counts): its loss is then traced with those collections mutable."""
+        return self.moe_num_experts > 0 or any(mixer == "sparse" for mixer, _ in self.kinds)
+
+    @property
     def rotary_dim(self) -> int:
         # even; partial rotary rotates the leading dims
         if self.rotary_dims is not None:
@@ -198,7 +214,7 @@ class TransformerConfig:
         return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)
 
 
-MIXERS = ("full", "window", "kda", "gdn", "mla")
+MIXERS = ("full", "window", "kda", "gdn", "mla", "sparse")
 FFNS = ("dense", "moe", "routed")
 
 # The name a projection's result carries for a checkpoint policy: what a product over the model width gives (a mixer's
@@ -510,15 +526,16 @@ class Block(nn.Module):
 
     def _mixer(self, cfg):
         """The layer's token mixer as ``fn(h, positions, kv_cache, segment_ids)``."""
-        if self.kind[0] in ("kda", "gdn", "mla"):
+        if self.kind[0] in ("kda", "gdn", "mla", "sparse"):
             from . import mixers
 
-            mixer = {"kda": mixers.KDAMixer, "gdn": mixers.GDNMixer, "mla": mixers.MLAMixer}[self.kind[0]](cfg, name=self.kind[0])
+            mixer = {"kda": mixers.KDAMixer, "gdn": mixers.GDNMixer, "mla": mixers.MLAMixer,
+                     "sparse": mixers.SparseMixer}[self.kind[0]](cfg, name=self.kind[0])
 
             def run(h, positions, kv_cache, segment_ids):
                 if kv_cache is not None or segment_ids is not None:
                     raise NotImplementedError(f"a {self.kind[0]} layer takes no KV cache and no packed segments yet")
-                return mixer(h, positions) if self.kind[0] == "mla" else mixer(h)
+                return mixer(h, positions) if self.kind[0] in ("mla", "sparse") else mixer(h)
 
             return run
         return Attention(cfg, window=cfg.sliding_window if self.kind[0] == "window" else None, name="attn")
@@ -575,7 +592,7 @@ class Transformer(nn.Module):
         emb = self.param("wte", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.d_model), jnp.float32)
         hook = _BLOCK_HOOK.get() if kv_caches is None and not self.is_initializing() else None
         with region("embed"):
-            x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.moe_num_experts > 0) if hook is not None else None
+            x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.sows) if hook is not None else None
             x = (emb[input_ids] if x is None else x).astype(cfg.dtype)
             if cfg.embed_scale:  # gemma normalizer
                 x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
@@ -602,7 +619,7 @@ class Transformer(nn.Module):
             layers = [] if self.is_initializing() else [self.get_variable("params", f"layer_{i}")
                                                         for i in range(cfg.n_layers)]
             paths = [self.path + (f"layer_{i}",) for i in range(cfg.n_layers)]
-            may_sow = [cfg.moe_for(i) for i in range(cfg.n_layers)]
+            may_sow = [cfg.moe_for(i) or cfg.kinds[i][0] == "sparse" for i in range(cfg.n_layers)]
             for i in range(cfg.n_layers):
                 kind = cfg.kinds[i]
                 kv_cache = kv_caches[i] if kv_caches is not None else None
@@ -758,7 +775,7 @@ def remat_keeps(kind: Tuple[str, str]) -> Tuple[str, ...]:
     """The names a checkpointed block of this kind keeps (``block_fn``'s policy); none: plain ``jax.checkpoint``, which
     keeps the block's inputs alone.
 
-    The rule for a hybrid block (a ``kda``, ``gdn`` or ``mla`` mixer, or a routed FFN): its backward runs no kernel, no
+    The rule for a hybrid block (a ``kda``, ``gdn``, ``mla`` or ``sparse`` mixer, or a routed FFN): its backward runs no kernel, no
     product over or onto the model width, no top-k and no sort a second time. Kept by name are the kernels' outputs (the
     scan's with its states and inverses, the flash call's with its row statistics), every projection's result (``SAVED``)
     and the routed layer's scores, choice, sorted rows and grouped products; what lies between them is elementwise (and
@@ -777,13 +794,15 @@ def remat_keeps(kind: Tuple[str, str]) -> Tuple[str, ...]:
     residual's producer, so forward and backward read the same number, where XLA's excess precision may carry a value
     that is made again on in float32 (``xla_allow_excess_precision``); the gradients are those of the block without a
     checkpoint."""
-    if kind[0] not in ("kda", "gdn", "mla") and kind[1] != "routed":
+    if kind[0] not in ("kda", "gdn", "mla", "sparse") and kind[1] != "routed":
         return ()
     from ..moe.sharded_moe import SAVED as routed_ffn
     from ..ops.kda import SAVED as kda_scan
     from ..ops.pallas.flash_attention import SAVED as flash_attention
+    from ..ops.indexed_attention import SAVED as sparse_attention
 
-    return (kda_scan, routed_ffn, flash_attention, SAVED)
+    # a sparse mixer's own: the choice, its attention call's output and row statistics, the index loss's cotangent
+    return (kda_scan, routed_ffn, flash_attention, SAVED) + ((sparse_attention,) if kind[0] == "sparse" else ())
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = -100) -> jnp.ndarray:
@@ -809,6 +828,33 @@ def _head_sums(leaves, hidden, labels, dtype, vd_layout, vocab_axis=None):
         bias = jax.lax.dynamic_slice_in_dim(bias, jax.lax.axis_index(vocab_axis) * n_own, n_own)
     total, count = fused_cross_entropy_sums(hidden, w, labels, vd_layout=vd_layout, bias=bias, vocab_axis=vocab_axis)
     return total[None], count[None]
+
+
+def _sown(intermediates, name):
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates)
+            if any(getattr(k, "key", None) == name for k in path)]
+
+
+def _count_sparse(counts):
+    from ..telemetry.registry import get_registry
+
+    reg = get_registry()
+    reg.counter("sparse_keys_chosen_total").inc(float(counts[:, 0].sum()))
+    reg.counter("sparse_keys_visible_total").inc(float(counts[:, 1].sum()))
+    reg.gauge("sparse_index_loss").set(float(counts[:, 2].mean()))
+
+
+def _index_loss(intermediates):
+    """The sum over the sparse layers of their indexer's loss (0.0 where none chose: a sequence no longer than
+    ``index_topk``); a layer's (chosen pairs, visible pairs, loss) leave the step program for the registry
+    (``telemetry/device_counts.py``: an output of the step, no host callback)."""
+    from ..telemetry import device_counts
+
+    losses, keys = _sown(intermediates, "index_loss"), _sown(intermediates, "sparse_keys")
+    if not losses:
+        return 0.0
+    device_counts.report("sparse_keys", jnp.stack([jnp.concatenate([k, l[None]]) for k, l in zip(keys, losses)]), _count_sparse)
+    return sum(losses)
 
 
 class CausalLM:
@@ -849,7 +895,8 @@ class CausalLM:
         else:
             head = (("lm_head", "kernel"),) + ((("lm_head", "bias"),) if cfg.lm_head_bias else ())
         leaves = tuple(functools.reduce(lambda tree, name: tree[name], path, params) for path in head)
-        if self.cfg.moe_num_experts > 0:
+        index_loss = 0.0
+        if cfg.sows:
             hidden, mods = self.module.apply({"params": params}, input_ids, return_hidden=True,
                                              mutable=_SOWN, **extra)
             aux_leaves = jax.tree_util.tree_leaves(mods.get("losses", {}))
@@ -858,6 +905,8 @@ class CausalLM:
                 from ..moe.layer import report_rows
 
                 report_rows(mods.get("intermediates", {}))  # the routed layers' rows: an output of the step
+            if any(mixer == "sparse" for mixer, _ in cfg.kinds):
+                index_loss = _index_loss(mods.get("intermediates", {}))
         else:
             hidden = self.apply(params, input_ids, return_hidden=True, **extra)
             aux = 0.0
@@ -874,14 +923,16 @@ class CausalLM:
             by_hook = None
             if hook is not None:
                 by_hook = hook.head(head, leaves, functools.partial(_head_sums, dtype=cfg.dtype, vd_layout=cfg.tie_embeddings),
-                                    0 if cfg.tie_embeddings else 1, cfg.moe_num_experts > 0)
+                                    0 if cfg.tie_embeddings else 1, cfg.sows)
             if by_hook is None:
                 ce = fused_cross_entropy(hidden, w, labels, vd_layout=cfg.tie_embeddings,
                                          bias=leaves[1] if len(leaves) > 1 else None)
             else:  # a share of the sum and of the count from each device
                 total, count = by_hook(hidden, labels)
                 ce = jnp.sum(total) / jnp.maximum(jnp.sum(count), 1)
-            return ce + self.cfg.moe_aux_loss_coef * aux
+            # the indexer's own loss adds its gradient, which reaches the indexer's leaves alone, and not its value:
+            # the step's loss stays the language model's (the value leaves the step as a device count)
+            return ce + self.cfg.moe_aux_loss_coef * aux + (index_loss - jax.lax.stop_gradient(index_loss))
 
     def to_pipeline(self, num_stages: int, params=None, rng=None, example_batch=None):
         """Split the model into (embed, S stacked stages, head) for the
@@ -903,7 +954,7 @@ class CausalLM:
         if cfg.scan_layers:
             raise ValueError("disable scan_layers for pipeline (stages are stacked instead)")
         if not cfg.softmax_only:
-            raise NotImplementedError("kda, gdn, mla and routed layers are not pipeline-partitionable yet: the stages' stacking "
+            raise NotImplementedError("kda, gdn, mla, sparse and routed layers are not pipeline-partitionable yet: the stages' stacking "
                                       "takes softmax attention and dense or capacity-gated MoE blocks")
         if cfg.mlm_head or cfg.type_vocab_size > 0:
             raise NotImplementedError("BERT-style models (mlm_head / token-type embeddings) are not "

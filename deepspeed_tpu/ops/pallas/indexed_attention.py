@@ -1,0 +1,434 @@
+"""Pallas kernels for attention over keys the model chose (TPU): a learned
+indexer scores every visible key of a query, the ``topk`` best are its key
+set, and softmax attention runs over that set alone (the DeepSeek-Sparse-
+Attention family; ``models/mixers.py::SparseMixer`` has the equations).
+
+Everything that is square in the sequence is kept KEY-MAJOR, ``(B, Sk, Sq)``:
+the flash kernels this file follows work on (bk, bq) tiles with the key
+position on sublanes, so a query's statistics (its threshold, its softmax's
+max and sum) are (1, bq) rows that reduce and broadcast along sublanes. Six
+calls, named apart from the flash kernels' on the device's clock:
+
+- ``index_scores``: ``I^T[s, t] = sum_j w[t, j] relu(kI[s] . qI[t, j])`` in
+  float32, the blocks above the diagonal skipped and filled with ``NEG_INF``;
+- ``index_scores_bwd``: its transpose, from the cotangent of ``I^T``: dqI and
+  dw stay in VMEM over a query block's walk along the keys, dkI leaves as one
+  partial sum a query block, added outside;
+- ``index_select``: a query's ``min(topk, t + 1)`` best visible keys as an
+  int8 mask. A band of 128 queries' scores stays in VMEM; the k-th largest
+  is found bit by bit on the scores' order-preserving integer form (32 counts
+  over the band), ties at the threshold go to the lower index (14 more);
+- ``sparse_fwd`` / ``sparse_bwd``: the flash forward and fused backward with
+  the mask, cut into the kernels' own (bk, bq) tiles, in place of the causal
+  compare: every block at or below the diagonal is visited (a learned choice
+  leaves no block empty), the chosen pairs alone enter the softmax;
+- ``sparse_probs``: the head-summed probabilities over the chosen pairs, from
+  the forward's row statistics: the target of the indexer's own loss.
+
+A masked score is ``NEG_INF`` (finite): a query whose first visited blocks hold
+none of its keys carries ``m = NEG_INF`` and a sum of ones until its first key
+arrives, whose correction ``exp(NEG_INF - m)`` is exactly zero.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._utils import block_that_divides, compiler_params as _compiler_params, vmem_budget
+from .flash_attention import _NN, _NT, _TN, NEG_INF, _fused_bwd_vmem, _rows, _tile_bytes
+
+BLOCK = 512  # the (bk, bq) tile of the attention and indexer kernels, and of the mask's tiled form
+BAND = 128   # queries whose scores ``index_select`` holds at once: (Sk, 128) float32 are 4 MB at 8,192 keys
+INT_MIN = -2**31
+
+
+def block_for(seq: int, want: int = 0) -> int:
+    return block_that_divides(seq, want or BLOCK)
+
+
+def kernels_take(seq: int, topk: int) -> bool:
+    """Whether these kernels take a sequence: whole 128-lane tiles."""
+    return seq % BAND == 0 and block_for(seq) % BAND == 0 and topk >= 1
+
+
+def tiled(mask_t, blk: int):
+    """(B, Sk, Sq) -> (B, Sk/blk, Sq/blk, blk, blk): one leading index a (bk, bq) tile."""
+    B, Sk, Sq = mask_t.shape
+    return mask_t.reshape(B, Sk // blk, blk, Sq // blk, blk).transpose(0, 1, 3, 2, 4)
+
+
+# ----------------------------------------------------------------------
+# the indexer's scores
+# ----------------------------------------------------------------------
+def _index_scores_kernel(q_ref, k_ref, w_ref, o_ref, *, blk: int, heads: int):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j > i)
+    def _above():
+        o_ref[0] = jnp.full((blk, blk), NEG_INF, jnp.float32)
+
+    @pl.when(j <= i)
+    def _scores():
+        k = k_ref[0]
+
+        def head(h, acc):
+            s = jax.lax.dot_general(k, q_ref[0, h], _NT, preferred_element_type=jnp.float32)  # (bk, bq)
+            return acc + jnp.maximum(s, 0.0) * w_ref[0, h]
+
+        acc = jax.lax.fori_loop(0, heads, head, jnp.zeros((blk, blk), jnp.float32))
+        keys = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+        queries = i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+        o_ref[0] = jnp.where(keys <= queries, acc, NEG_INF)
+
+
+def index_scores(q_i, k_i, w, *, interpret: bool = False, blk: int = 0):
+    """q_i (B, J, S, Di), k_i (B, S, Di), w (B, J, S) float32 -> I^T (B, S, S) float32, key-major."""
+    B, J, S, Di = q_i.shape
+    blk = block_for(S, blk)
+    n = S // blk
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, blk=blk, heads=J),
+        grid=(B, n, n),
+        in_specs=[
+            pl.BlockSpec((1, J, blk, Di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, blk, Di), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, J, 1, blk), lambda b, i, j: (b, 0, 0, i)),
+        ],
+        out_specs=pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, j, i)),
+        out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.float32),
+        interpret=interpret,
+        name="index_scores",
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret,
+                                         vmem_bytes=2 * (J * blk * Di * q_i.dtype.itemsize + blk * blk * 4) + _tile_bytes(blk, blk)),
+    )(q_i, k_i, w.reshape(B, J, 1, S))
+
+
+def _index_scores_bwd_kernel(g_ref, q_ref, k_ref, w_ref, dq_ref, dw_ref, dk_ref, dq_acc, dw_acc, *, blk: int, heads: int):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _zero():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(j > i)
+    def _above():
+        dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
+
+    @pl.when(j <= i)
+    def _block():
+        k = k_ref[0]
+        g0 = g_ref[0].astype(jnp.float32)  # (bk, bq): zero off the chosen pairs
+
+        def head(h, dk):
+            q = q_ref[0, h]
+            s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+            dw_acc[h] = dw_acc[h] + jnp.sum(g0 * jnp.maximum(s, 0.0), axis=0, keepdims=True)
+            g = jnp.where(s > 0.0, g0 * w_ref[0, h], 0.0).astype(k.dtype)
+            dq_acc[h] = dq_acc[h] + jax.lax.dot_general(g, k, _TN, preferred_element_type=jnp.float32)
+            return dk + jax.lax.dot_general(g, q, _NN, preferred_element_type=jnp.float32)
+
+        dk_ref[0, 0] = jax.lax.fori_loop(0, heads, head, jnp.zeros(k.shape, jnp.float32))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _out():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[0] = dw_acc[...]
+
+
+def index_scores_bwd(g, q_i, k_i, w, *, interpret: bool = False, blk: int = 0):
+    """The cotangent ``g`` of I^T (B, S, S) -> (dq_i, dk_i, dw), each its operand's shape; dw float32."""
+    B, J, S, Di = q_i.shape
+    blk = block_for(S, blk)
+    n = S // blk
+    dq, dw, dk = pl.pallas_call(
+        functools.partial(_index_scores_bwd_kernel, blk=blk, heads=J),
+        grid=(B, n, n),
+        in_specs=[
+            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, j, i)),
+            pl.BlockSpec((1, J, blk, Di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, blk, Di), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, J, 1, blk), lambda b, i, j: (b, 0, 0, i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, J, blk, Di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, J, 1, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, 1, blk, Di), lambda b, i, j: (b, i, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, J, S, Di), q_i.dtype),
+            jax.ShapeDtypeStruct((B, J, 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, n, S, Di), jnp.float32),  # a partial sum a query block
+        ],
+        scratch_shapes=[pltpu.VMEM((J, blk, Di), jnp.float32), pltpu.VMEM((J, 1, blk), jnp.float32)],
+        interpret=interpret,
+        name="index_scores_bwd",
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret,
+                                         vmem_bytes=4 * J * blk * Di * q_i.dtype.itemsize + J * blk * Di * 4
+                                         + 2 * blk * blk * g.dtype.itemsize + _tile_bytes(blk, blk)),
+    )(g, q_i, k_i, w.reshape(B, J, 1, S))
+    return dq, jnp.sum(dk, axis=1).astype(k_i.dtype), dw.reshape(B, J, S)
+
+
+# ----------------------------------------------------------------------
+# the choice
+# ----------------------------------------------------------------------
+def _ordered(x):
+    """float32 -> int32 with the same order (-0.0 taken as 0.0)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _count(hit):
+    """How many of a band's keys ``hit`` a query: (Sk, band) bool -> (1, band) float32 (exact to 2**24)."""
+    return jnp.sum(hit.astype(jnp.float32), axis=0, keepdims=True)
+
+
+def _index_select_kernel(s_ref, o_ref, key_ref, *, topk: int, seq: int, band: int):
+    i = pl.program_id(1)
+    keys = jax.lax.broadcasted_iota(jnp.int32, (seq, band), 0)
+    queries = i * band + jax.lax.broadcasted_iota(jnp.int32, (seq, band), 1)
+    visible = keys <= queries
+    key_ref[...] = jnp.where(visible, _ordered(s_ref[0]), INT_MIN)
+    want = jnp.minimum(queries[:1] + 1, topk).astype(jnp.float32)  # (1, band): min(topk, t + 1)
+
+    # the largest T with count(key >= T) >= want: the sign first, then bit 30 down to 0
+    t0 = jnp.where(_count(key_ref[...] >= 0) >= want, 0, INT_MIN).astype(jnp.int32)
+
+    def bit(b, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - b)
+        return jnp.where(_count(key_ref[...] >= cand) >= want, cand, t)
+
+    thr = jax.lax.fori_loop(0, 31, bit, t0)
+    above = key_ref[...] > thr
+    ties = key_ref[...] == thr
+    need = want - _count(above)  # of the ties, the ones of lowest index: at least one
+
+    # the largest P with count(ties below P) < need is the need-th tie's index
+    def index_bit(b, p):
+        cand = p | jnp.left_shift(jnp.int32(1), (seq - 1).bit_length() - 1 - b)
+        return jnp.where(_count(ties & (keys < cand)) < need, cand, p)
+
+    last = jax.lax.fori_loop(0, (seq - 1).bit_length(), index_bit, jnp.zeros((1, band), jnp.int32))
+    o_ref[0] = (visible & (above | (ties & (keys <= last)))).astype(jnp.int32).astype(jnp.int8)
+
+
+def index_select(scores_t, topk: int, *, interpret: bool = False, band: int = 0):
+    """I^T (B, Sk, Sq) float32 -> the choice as an int8 mask (B, Sk, Sq): a query's ``min(topk, t + 1)``
+    largest visible scores, ties to the lower index."""
+    B, Sk, Sq = scores_t.shape
+    band = band or min(BAND, Sq)
+    return pl.pallas_call(
+        functools.partial(_index_select_kernel, topk=int(topk), seq=Sk, band=band),
+        grid=(B, Sq // band),
+        in_specs=[pl.BlockSpec((1, Sk, band), lambda b, i: (b, 0, i))],
+        out_specs=pl.BlockSpec((1, Sk, band), lambda b, i: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((B, Sk, Sq), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((Sk, band), jnp.int32)],
+        interpret=interpret,
+        name="index_select",
+        compiler_params=_compiler_params("parallel", "parallel", interpret=interpret, vmem_bytes=Sk * band * (2 * 4 + 4 + 2 + 6 * 4)),
+    )(scores_t)
+
+
+# ----------------------------------------------------------------------
+# attention over the chosen keys
+# ----------------------------------------------------------------------
+def _chosen(mask_tile):
+    return mask_tile.astype(jnp.int32) != 0
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, blk: int, scale: float):
+    i = pl.program_id(1)
+    q = q_ref[0]
+    D = v_ref.shape[-1]
+
+    def body(j, carry):
+        acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
+        rows = pl.dslice(j * blk, blk)
+        k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(_chosen(mask_ref[0, j, 0]), s, NEG_INF)
+        new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - new_m)
+        corr = jnp.exp(m - new_m)
+        new_l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        new_acc = acc * corr + jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+        return new_acc, new_m, new_l
+
+    init = (jnp.zeros((D, blk), jnp.float32), jnp.full((1, blk), NEG_INF, jnp.float32), jnp.zeros((1, blk), jnp.float32))
+    acc, m, l = jax.lax.fori_loop(0, i + 1, body, init)
+    o_ref[0] = (acc / l).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = m + jnp.log(l)
+
+
+def sparse_fwd(q, k, v, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
+    """q (B*H, S, D), k and v (B*KVH, S, D), the mask in tiles (B, S/blk, S/blk, blk, blk) -> o, lse (B*H, S)."""
+    BH, S, D = q.shape
+    blk, n, n_rep = mask_tiles.shape[-1], mask_tiles.shape[1], H // KVH
+    kv_of = lambda b, h: b * KVH + h // n_rep
+    o, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, blk=blk, scale=scale),
+        grid=(BH // H, n, H),  # heads innermost: a query block's mask stays, a KV head's keys change every n_rep steps
+        in_specs=[
+            pl.BlockSpec((1, blk, D), lambda b, i, h: (b * H + h, i, 0)),
+            pl.BlockSpec((1, S, D), lambda b, i, h: (kv_of(b, h), 0, 0)),
+            pl.BlockSpec((1, S, D), lambda b, i, h: (kv_of(b, h), 0, 0)),
+            pl.BlockSpec((1, n, 1, blk, blk), lambda b, i, h: (b, 0, i, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, blk, D), lambda b, i, h: (b * H + h, i, 0)),
+            pl.BlockSpec((1, 1, 1, blk), lambda b, i, h: (b * H + h, i, 0, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype), jax.ShapeDtypeStruct((BH, n, 1, blk), jnp.float32)],
+        interpret=interpret,
+        name="sparse_fwd",
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret,
+                                         vmem_bytes=4 * (blk + S) * D * q.dtype.itemsize + 2 * n * blk * blk + _tile_bytes(blk, blk)),
+    )(q, k, v, mask_tiles)
+    return o, lse.reshape(BH, S)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc,
+                n_rep: int, blk: int, scale: float):
+    """``flash_attention._bwd_fused_kernel`` with the mask's tiles for the causal compare: grid (B*KVH, n_rep, S/blk),
+    kv blocks innermost; a head's q, do, lse, delta and dq stay in VMEM over the walk."""
+    rep, kj = pl.program_id(1), pl.program_id(2)
+    last_kj = pl.num_programs(2) - 1
+
+    @pl.when(kj == 0)
+    def _zero():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k, v = k_ref[0], v_ref[0]
+
+    def body(i, carry):
+        dk, dv = carry
+        rows = pl.dslice(i * blk, blk)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+        p = jnp.exp(jnp.where(_chosen(mask_ref[0, 0, i]), s, NEG_INF) - lse_ref[0, i])
+        dv = dv + jax.lax.dot_general(p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, i])).astype(q.dtype)
+        dk = dk + jax.lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
+        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
+        return dk, dv
+
+    dk, dv = jax.lax.fori_loop(kj, pl.num_programs(2), body, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
+    dk = dk * scale
+
+    @pl.when(kj == last_kj)
+    def _dq_out():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    if n_rep == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    rows = pl.dslice(kj * blk, blk)
+
+    @pl.when(rep == 0)
+    def _first():
+        for acc, value in zip(kv_acc, (dk, dv)):
+            acc[rows, :] = value
+
+    @pl.when(rep > 0)
+    def _add():
+        for acc, value in zip(kv_acc, (dk, dv)):
+            acc[rows, :] = acc[rows, :] + value
+
+    @pl.when(jnp.logical_and(rep == n_rep - 1, kj == last_kj))
+    def _dkv_out():
+        for ref, acc in zip((dk_ref, dv_ref), kv_acc):
+            ref[0] = acc[...].astype(ref.dtype)
+
+
+def bwd_vmem(S: int, D: int, item: int, blk: int, n_rep: int) -> int:
+    """What ``sparse_bwd`` holds in VMEM at once: the fused flash backward's count and a kv block's mask tiles, twice."""
+    return _fused_bwd_vmem(S, S, D, item, blk, blk, n_rep) + 2 * S * blk
+
+
+def bwd_budget() -> int:
+    """Half the core's VMEM, where the flash kernels plan with three eighths: at 8,192 positions, 8 query heads a KV
+    head of 128 and 512-blocks the fused backward's own count is 41.5 MiB and the mask's tiles add 8."""
+    return vmem_budget() * 4 // 3
+
+
+def sparse_bwd(q, k, v, o, lse, do, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
+    BH, S, D = q.shape
+    BKV = k.shape[0]
+    blk, n, n_rep = mask_tiles.shape[-1], mask_tiles.shape[1], H // KVH
+    need = bwd_vmem(S, D, q.dtype.itemsize, blk, n_rep)
+    if need > bwd_budget():
+        raise NotImplementedError(f"sparse_attention backward: a head's q, do and dq and its group's dk and dv at {S} positions, D={D}, "
+                                  f"{n_rep} q heads a KV head take {need >> 20} MiB of VMEM, over {bwd_budget() >> 20} MiB")
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    q_of = lambda b, r: (b // KVH) * H + (b % KVH) * n_rep + r
+    whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
+    rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
+    kv_blk = pl.BlockSpec((1, blk, D), lambda b, r, j: (b, j, 0))
+    if n_rep == 1:
+        kv_out, kv_scratch = [kv_blk, kv_blk], []
+    else:
+        kv_out = [pl.BlockSpec((1, S, D), lambda b, r, j: (b, 0, 0))] * 2
+        kv_scratch = [pltpu.VMEM((S, D), jnp.float32)] * 2
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, n_rep=n_rep, blk=blk, scale=scale),
+        grid=(BKV, n_rep, n),
+        in_specs=[
+            pl.BlockSpec((1, S, D), whole_q), kv_blk, kv_blk, pl.BlockSpec((1, S, D), whole_q),
+            pl.BlockSpec((1, n, 1, blk), rows_q), pl.BlockSpec((1, n, 1, blk), rows_q),
+            pl.BlockSpec((1, 1, n, blk, blk), lambda b, r, j: (b // KVH, j, 0, 0, 0)),
+        ],
+        out_specs=[pl.BlockSpec((1, S, D), whole_q), *kv_out],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype), jax.ShapeDtypeStruct((BKV, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((BKV, S, D), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)] + kv_scratch,
+        interpret=interpret,
+        name="sparse_bwd",
+        compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret, vmem_bytes=need),
+    )(q, k, v, do, _rows(lse, blk), _rows(delta, blk), mask_tiles)
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, o_ref, *, heads: int, n_rep: int, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j > i)
+    def _above():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(j <= i)
+    def _block():
+        chosen = _chosen(mask_ref[0, 0, 0])
+
+        def head(h, acc):
+            s = jax.lax.dot_general(k_ref[h // n_rep], q_ref[h], _NT, preferred_element_type=jnp.float32) * scale
+            return acc + jnp.exp(jnp.where(chosen, s, NEG_INF) - lse_ref[h, 0])
+
+        o_ref[0] = jax.lax.fori_loop(0, heads, head, jnp.zeros(o_ref.shape[1:], jnp.float32))
+
+
+def sparse_probs(q, k, lse, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
+    """The probabilities of every head summed, key-major (B, S, S) float32: exp(score - lse) over the chosen pairs."""
+    BH, S, D = q.shape
+    blk, n = mask_tiles.shape[-1], mask_tiles.shape[1]
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, heads=H, n_rep=H // KVH, scale=scale),
+        grid=(BH // H, n, n),
+        in_specs=[
+            pl.BlockSpec((H, blk, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((KVH, blk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((H, 1, 1, blk), lambda b, i, j: (b, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, blk, blk), lambda b, i, j: (b, j, i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, j, i)),
+        out_shape=jax.ShapeDtypeStruct((BH // H, S, S), jnp.float32),
+        interpret=interpret,
+        name="sparse_probs",
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret,
+                                         vmem_bytes=2 * (H + KVH) * blk * D * q.dtype.itemsize + 2 * blk * blk * 5 + _tile_bytes(blk, blk)),
+    )(q, k, _rows(lse, blk), mask_tiles)

@@ -87,7 +87,7 @@ def _all_finite(tree):
 # key on the trainer's first-call line -> the region whose choice it reports, as ``program_regions_traced_total`` labels
 # it (forward call sites, by ``path``); the line's word for any ``path`` but ``"xla"`` is ``kernel`` but for ``_PATH_WORDS``
 _PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"}),
-          "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}),
+          "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"}),
           "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
 _PATH_WORDS = {"moe_cond": "fallback_keeps_nothing"}  # the one form ``routed_part``'s conditional has
 _ROUTER_WORDS = ("sigmoid", "softmax")  # ``path`` of ``ffn/router`` where a routed layer scores its tokens: the line's ``moe_router``
@@ -764,7 +764,8 @@ class DeepSpeedEngine:
         routed FFN's grouped products were traced into this program, by the counters that count
         each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
         fallback), ``mixed``, or no key where the program has none (``gdn_path``:
-        the delta-rule scan with a decay a head); how a routed layer scores its
+        the delta-rule scan with a decay a head; ``sparse_path``: attention over the keys an indexer chose, which a model
+        has in every layer or in none, so this key is given whatever the kinds); how a routed layer scores its
         tokens (``moe_router``: ``sigmoid`` or ``softmax``); and, where
         a routed layer's buffer is smaller than every pair, which form its
         conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``). Whatever the kinds, under ``remat``:
@@ -778,7 +779,7 @@ class DeepSpeedEngine:
             from ..models.transformer import remat_keeps
             names = () if cfg.scan_layers else sorted({name for kind in kinds for name in remat_keeps(kind)})
             notes["remat_keeps"] = "+".join(names) or "inputs"
-        if len(set(kinds)) == 1:
+        if len(set(kinds)) == 1 and kinds[0][0] != "sparse":  # one kind of plain block: nothing was chosen
             return notes
         count = {}
         for mixer, ffn in kinds:

@@ -178,6 +178,25 @@ def _moe_sum_rows(shape):
             (S((rows, d), BF16), S((rows,), I32), S((rows,), F32), S((2, N // TOKENS * held), I32)), 1)
 
 
+def _indexed(which):
+    """One of the six calls of attention over an indexer's choice, at ``keye-vl2-30b-l4e16``'s shapes: 32 query heads on
+    4 KV heads of 128, an indexer of 16 heads of 64, 8,192 positions, 2,048 keys a query, the mask in 512 x 512 tiles."""
+    from deepspeed_tpu.ops.pallas import indexed_attention as K
+
+    Sq, Hq, KVH, Dh, J, Di, n = 8192, 32, 4, 128, 16, 64, 16
+    q, kv, rows, tiles = S((Hq, Sq, Dh), BF16), S((KVH, Sq, Dh), BF16), S((Hq, Sq), F32), S((1, n, n, 512, 512), jnp.int8)
+    q_i, k_i, w, square = S((1, J, Sq, Di), BF16), S((1, Sq, Di), BF16), S((1, J, Sq), F32), S((1, Sq, Sq), F32)
+    scale = Dh ** -0.5
+    return {
+        "index_scores": (lambda q_i, k_i, w: K.index_scores(q_i, k_i, w), (q_i, k_i, w), 1),
+        "index_select": (lambda s: K.index_select(s, 2048), (square,), 1),
+        "sparse_fwd": (lambda q, k, v, m: K.sparse_fwd(q, k, v, m, scale, Hq, KVH), (q, kv, kv, tiles), 1),
+        "sparse_bwd": (lambda q, k, v, o, lse, do, m: K.sparse_bwd(q, k, v, o, lse, do, m, scale, Hq, KVH), (q, kv, kv, q, rows, q, tiles), 1),
+        "sparse_probs": (lambda q, k, lse, m: K.sparse_probs(q, k, lse, m, scale, Hq, KVH), (q, kv, rows, tiles), 1),
+        "index_scores_bwd": (lambda g, q_i, k_i, w: K.index_scores_bwd(g, q_i, k_i, w), (S((1, Sq, Sq), BF16), q_i, k_i, w), 1),
+    }[which]
+
+
 CASES = {
     "moe_sum_rows_t8192_d2048_e8_r24576": lambda: _moe_sum_rows((8192, 2048, 8, 24576)),  # kimi-vl-a3b-l6e8's routed layers, the usual buffer
     "moe_sum_rows_t8192_d2304_e8_r8192": lambda: _moe_sum_rows((8192, 2304, 8, 8192)),    # kimi-linear-48b-l5e8's
@@ -197,6 +216,8 @@ CASES = {
     "flash_gqa_b1_s8192_h16_kvh2_d256": lambda: _flash((1, 8192, 16, 2, 256)),  # qwen3-next-80b-l4e32's full layer: a head at a time in the backward
     "flash_mha_b1_s32768_h2_d128": lambda: _flash((1, 32768, 2, 2, 128), "refused"),  # 77 MiB resident: over the budget
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
+    **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
+       for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "sparse_probs", "index_scores_bwd")},
     "fused_adam_mlp_768x3072": lambda: _fused_adam((768, 3072)),
     "fused_adam_bias_768": lambda: _fused_adam((768,)),
     "layer_norm_t264_d768": lambda: _norm("layer_norm"),

@@ -1,0 +1,367 @@
+"""Attention over the keys a learned indexer chooses (``models/mixers.py::SparseMixer``, ``ops/indexed_attention.py``,
+``ops/pallas/indexed_attention.py``): each Pallas kernel in interpret mode against the XLA form, forward and backward;
+the mixer's two learners kept apart; exactly ``min(k, t + 1)`` keys a query and none ahead of it; a sequence no longer
+than ``k`` is the dense mixer's program; what it counts; the share of eight; and the flash calls the older cells make,
+pinned. Tiny widths, float32, CPU."""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models import CausalLM, TransformerConfig
+from deepspeed_tpu.models.transformer import cross_entropy_loss
+from deepspeed_tpu.ops import indexed_attention as ops
+from deepspeed_tpu.ops.pallas import indexed_attention as kernel
+from deepspeed_tpu.telemetry import device_counts, get_registry
+from deepspeed_tpu.telemetry.tracing import regions_traced
+
+B, S, H, KVH, D, J, DI, TOPK, BLK = 2, 256, 4, 2, 32, 3, 16, 40, 128
+SCALE = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def operands(highest):
+    rng = np.random.default_rng(0)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    t = dict(q=f(B, S, H, D), k=f(B, S, KVH, D), v=f(B, S, KVH, D), do=f(B, S, H, D), q_i=f(B, J, S, DI), k_i=f(B, S, DI), w=f(B, J, S) * 0.2)
+    t["scores"] = ops.index_scores_xla(t["q_i"], t["k_i"], t["w"])
+    t["mask"] = ops.select_xla(t["scores"], TOPK)
+    t["tiles"] = kernel.tiled(t["mask"], BLK)
+    (t["o"], t["lse"]), t["vjp"] = jax.vjp(lambda q, k, v: ops.sparse_attention_xla(q, k, v, t["mask"], SCALE), t["q"], t["k"], t["v"])
+    t["probs"] = ops.head_probs_xla(t["q"], t["k"], t["lse"], t["mask"], SCALE)
+    return t
+
+
+def _close(a, b, tol=2e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert np.max(np.abs(a - b)) <= tol * (1.0 + np.max(np.abs(b))), (np.max(np.abs(a - b)), np.max(np.abs(b)))
+
+
+def chosen_pairs(seq, topk, batch=1):
+    return batch * sum(min(topk, t + 1) for t in range(seq))
+
+
+def test_the_score_kernel_is_the_xla_form(operands):
+    t = operands
+    got = kernel.index_scores(t["q_i"], t["k_i"], t["w"], interpret=True, blk=BLK)
+    _close(got, t["scores"])
+    assert float(jnp.max(jnp.where(jnp.arange(S)[:, None] > jnp.arange(S)[None, :], got, kernel.NEG_INF))) <= -9e29  # a key ahead of its query scores NEG_INF
+
+
+@pytest.mark.parametrize("what", ["as_scored", "many_ties", "all_equal", "negative_zero"])
+def test_the_choice_kernel_is_top_k_with_ties_to_the_lower_index(operands, what):
+    scores = operands["scores"]
+    seen = scores > -1e29
+    if what == "many_ties":  # half-integers: dozens of keys share a query's threshold
+        scores = jnp.where(seen, jnp.round(scores * 2) / 2, scores)
+    elif what == "all_equal":  # every key ties: the choice is the first ``TOPK`` positions
+        scores = jnp.where(seen, 1.0, scores)
+    elif what == "negative_zero":  # -0.0 and 0.0 are one score
+        scores = jnp.where(seen, jnp.where(jnp.arange(S)[:, None] % 2 == 0, -0.0, 0.0), scores)
+    want, got = ops.select_xla(scores, TOPK), kernel.index_select(scores, TOPK, interpret=True, band=128)
+    assert got.dtype == jnp.int8 and int(jnp.sum(want != got)) == 0
+    assert int(jnp.sum(got)) == chosen_pairs(S, TOPK, B)
+    per_query = jnp.sum(got.astype(jnp.int32), axis=1)  # key-major: a query's keys lie along axis 1
+    assert (np.asarray(per_query) == np.minimum(np.arange(S) + 1, TOPK)[None, :]).all()
+    assert int(jnp.sum(jnp.where(jnp.arange(S)[:, None] > jnp.arange(S)[None, :], got, 0))) == 0  # none ahead of its query
+    if what in ("all_equal", "negative_zero"):
+        assert (np.asarray(got[0, :TOPK, S - 1]) == 1).all()  # the last query's keys: the lowest indices
+
+
+def test_the_forward_kernel_is_masked_softmax_attention(operands):
+    t = operands
+    o, lse = kernel.sparse_fwd(ops._to_bh(t["q"]), ops._to_bh(t["k"]), ops._to_bh(t["v"]), t["tiles"], SCALE, H, KVH, interpret=True)
+    _close(o.reshape(B, H, S, D).transpose(0, 2, 1, 3), t["o"])
+    _close(lse.reshape(B, H, S), t["lse"])
+
+
+@pytest.mark.parametrize("kv_heads", [KVH, H])
+def test_the_backward_kernel_is_the_xla_forms_vjp(operands, kv_heads):
+    """A group of two query heads a KV head (dk and dv added up in the kernel's scratch), and one."""
+    t = operands
+    k, v = (jnp.repeat(t[x], kv_heads // KVH, axis=2) for x in ("k", "v"))
+    (o, lse), vjp = jax.vjp(lambda q, k, v: ops.sparse_attention_xla(q, k, v, t["mask"], SCALE), t["q"], k, v)
+    want = vjp((t["do"], jnp.zeros_like(lse)))
+    got = kernel.sparse_bwd(ops._to_bh(t["q"]), ops._to_bh(k), ops._to_bh(v), ops._to_bh(o), lse.reshape(B * H, S), ops._to_bh(t["do"]),
+                            t["tiles"], SCALE, H, kv_heads, interpret=True)
+    for a, b, heads in zip(got, want, (H, kv_heads, kv_heads)):
+        _close(a.reshape(B, heads, S, D).transpose(0, 2, 1, 3), b)
+
+
+def test_the_probabilities_kernel_sums_the_heads_over_the_chosen_pairs(operands):
+    t = operands
+    got = kernel.sparse_probs(ops._to_bh(t["q"]), ops._to_bh(t["k"]), t["lse"].reshape(B * H, S), t["tiles"], SCALE, H, KVH, interpret=True)
+    _close(got, t["probs"])
+    _close(jnp.sum(got, axis=1), jnp.full((B, S), float(H)))  # a query's probabilities sum to one a head
+    assert float(jnp.max(jnp.where(t["mask"] == 0, got, 0.0))) == 0.0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_index_loss_carries_the_indexers_gradient_through_the_backward_kernel(operands, dtype):
+    """The kernels' form keeps the loss's gradient in the scores (in the model's dtype) and ``index_scores_bwd`` carries it
+    to qI, kI and w: against autodiff of the XLA forms."""
+    t = operands
+    args = (t["q_i"], t["k_i"], t["w"])
+    want_loss, want = jax.value_and_grad(lambda *a: ops._index_loss_and_grad(ops.index_scores_xla(*a), t["probs"], t["mask"])[0], argnums=(0, 1, 2))(*args)
+    got_loss, got = jax.value_and_grad(lambda *a: ops._index_loss_kernel(*a, t["scores"], t["probs"], t["mask"], dtype, True), argnums=(0, 1, 2))(*args)
+    _close(got_loss, want_loss, 1e-6)
+    for a, b in zip(got, want):
+        _close(a, b, 2e-5 if dtype == jnp.float32 else 2e-2)
+    grad = ops._index_loss_and_grad(t["scores"], t["probs"], t["mask"])[1]
+    by_blocks = kernel.index_scores_bwd(grad, *args, interpret=True, blk=BLK)  # four blocks a batch: the walk along the keys
+    for a, b in zip(by_blocks, (want[0], want[1], want[2])):
+        _close(a, b)
+
+
+def test_the_kernels_are_named_apart_from_the_calls_other_readers_match(operands):
+    """The benchmark's readers match custom calls by name: ``flash_fwd``, ``flash_bwd``, ``kda_scan_*``, ``gdn_scan_*``,
+    ``gmm``, ``tgmm``, ``moe_sum_rows`` must not see these six, and these readers' patterns must see their own alone."""
+    t = operands
+    bh = ops._to_bh
+    q, k, v, o, do, lse = bh(t["q"]), bh(t["k"]), bh(t["v"]), bh(t["o"]), bh(t["do"]), t["lse"].reshape(B * H, S)
+    calls = (lambda: kernel.index_scores(t["q_i"], t["k_i"], t["w"], interpret=True),
+             lambda: kernel.index_select(t["scores"], TOPK, interpret=True),
+             lambda: kernel.sparse_fwd(q, k, v, t["tiles"], SCALE, H, KVH, interpret=True),
+             lambda: kernel.sparse_bwd(q, k, v, o, lse, do, t["tiles"], SCALE, H, KVH, interpret=True),
+             lambda: kernel.sparse_probs(q, k, lse, t["tiles"], SCALE, H, KVH, interpret=True),
+             lambda: kernel.index_scores_bwd(t["probs"], t["q_i"], t["k_i"], t["w"], interpret=True))
+    names = {name for call in calls for name in re.findall(r"name=(\w+)", str(jax.make_jaxpr(call)()))}
+    assert {"index_scores", "index_select", "sparse_fwd", "sparse_bwd", "sparse_probs", "index_scores_bwd"} <= names
+    others = r"flash_(fwd|bwd|dq|dkv)|kda_scan|gdn_scan|\bt?gmm\b|moe_sum_rows"
+    assert not [n for n in names if re.search(others, n)]
+
+
+# ---------------------------------------------------------------------- the mixer
+def tiny(**over):
+    base = dict(vocab_size=97, n_layers=2, n_heads=4, n_kv_heads=2, head_dims=16, d_model=32, max_seq_len=64, norm="rmsnorm",
+                activation="swiglu", pos_emb="rope", rope_theta=1e7, qk_norm=True, tie_embeddings=False, norm_eps=1e-6,
+                layer_kinds=(("sparse", "dense"),) * 2, index_heads=3, index_head_dim=8, index_topk=16)
+    return TransformerConfig(**dict(base, **over))
+
+
+@pytest.fixture(scope="module")
+def model(highest):
+    m = CausalLM(tiny())
+    ids = np.random.default_rng(1).integers(0, 97, (2, 64)).astype(np.int32)
+    params = m.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    leaves, tree = jax.tree_util.tree_flatten(params)  # the norms' weights start at one and the biases at zero: moved
+    return m, jax.tree_util.tree_unflatten(tree, [x + 0.05 * jax.random.normal(jax.random.PRNGKey(7 + i), x.shape) for i, x in enumerate(leaves)]), ids
+
+
+def _is_indexer(path):
+    return any(str(getattr(k, "key", "")).startswith("index_") for k in path)
+
+
+def _sown(m, params, ids):
+    logits, mods = m.module.apply({"params": params}, ids, mutable=("intermediates", "losses"))
+    return logits, [mods["intermediates"][f"layer_{i}"]["sparse"] for i in range(m.cfg.n_layers)]
+
+
+def test_the_two_learners_are_kept_apart(model):
+    """``d L_I / d(main leaves) = 0`` and ``d CE / d(indexer leaves) = 0``; the step's loss is the cross-entropy's value
+    and its gradient the two side by side."""
+    m, params, ids = model
+    labels = jnp.concatenate([ids[:, 1:], jnp.full((2, 1), -100, ids.dtype)], axis=1)
+    ce = lambda p: cross_entropy_loss(m.apply(p, ids), labels)
+    index = lambda p: sum(layer["index_loss"][0] for layer in _sown(m, p, ids)[1])
+    (ce_value, g_ce), (index_value, g_index) = jax.value_and_grad(ce)(params), jax.value_and_grad(index)(params)
+    step_value, g_step = jax.value_and_grad(lambda p: m.loss_fn(p, {"input_ids": ids}))(params)
+    _close(step_value, ce_value, 1e-6)
+    assert float(index_value) > 0.01
+    for (path, a), b, both in zip(jax.tree_util.tree_leaves_with_path(g_ce), jax.tree_util.tree_leaves(g_index), jax.tree_util.tree_leaves(g_step)):
+        if _is_indexer(path):
+            assert float(jnp.max(jnp.abs(a))) == 0.0 and float(jnp.max(jnp.abs(b))) > 0.0, path
+        else:
+            assert float(jnp.max(jnp.abs(b))) == 0.0 and float(jnp.max(jnp.abs(a))) > 0.0, path
+        _close(both, a + b, 1e-5)
+    assert sum(_is_indexer(path) for path, _ in jax.tree_util.tree_leaves_with_path(params)) == 2 * 5  # qI, kI, its norm's two, w
+
+
+def test_a_query_takes_exactly_its_keys_and_none_ahead_of_it(model):
+    m, params, ids = model
+    for layer in _sown(m, params, ids)[1]:
+        choice = np.asarray(layer["choice"][0])  # key-major (B, Sk, Sq)
+        assert (choice.sum(axis=1) == np.minimum(np.arange(64) + 1, 16)[None, :]).all()
+        assert np.triu(choice.transpose(0, 2, 1), k=1).sum() == 0
+        assert tuple(np.asarray(layer["sparse_keys"][0])) == (chosen_pairs(64, 16, 2), 2 * 64 * 65 / 2)
+
+
+def test_a_sequence_no_longer_than_the_keys_a_query_takes_is_the_dense_mixers_program(model):
+    """Values equal the ``full`` mixer's on the same weights; no mask, no sort, no indexer traced; the indexer's leaves
+    take a zero gradient and nothing is sown."""
+    m, params, ids = model
+    short = CausalLM(dataclasses.replace(m.cfg, index_topk=64))
+    dense = CausalLM(dataclasses.replace(m.cfg, layer_kinds=(("full", "dense"),) * 2))
+    as_dense = {k: ({"attn": {n: x for n, x in v["sparse"].items() if not n.startswith("index_")}, **{n: x for n, x in v.items() if n != "sparse"}}
+                    if k.startswith("layer_") else v) for k, v in params.items()}
+    assert float(jnp.max(jnp.abs(short.apply(params, ids) - dense.apply(as_dense, ids)))) == 0.0
+    text = str(jax.make_jaxpr(lambda p: short.loss_fn(p, {"input_ids": ids}))(params))
+    traced = lambda text: [word for word in ("top_k", " sort", "scatter") if word in text]  # the choice and its mask
+    assert traced(text) == [] and "top_k" in traced(str(jax.make_jaxpr(lambda p: m.loss_fn(p, {"input_ids": ids}))(params)))
+    grads = jax.grad(lambda p: short.loss_fn(p, {"input_ids": ids}))(params)
+    assert all(float(jnp.max(jnp.abs(g))) == 0.0 for path, g in jax.tree_util.tree_leaves_with_path(grads) if _is_indexer(path))
+    assert "intermediates" not in short.module.apply({"params": params}, ids, mutable=("intermediates", "losses"))[1]
+
+
+def test_the_kernels_path_is_the_xla_path_through_the_whole_model_under_remat(highest, monkeypatch):
+    """The model at 256 positions with every square part a Pallas call (interpreted), blocks checkpointed with the named
+    saves: loss and every leaf's gradient equal the XLA forms'."""
+    m = CausalLM(tiny(max_seq_len=256, index_topk=48, remat=True))
+    ids = np.random.default_rng(2).integers(0, 97, (1, 256)).astype(np.int32)
+    params = m.init(jax.random.PRNGKey(3), {"input_ids": ids})
+    f = lambda p: m.loss_fn(p, {"input_ids": ids})
+    want_loss, want = jax.value_and_grad(f)(params)
+    monkeypatch.setattr(ops, "path_for", lambda seq, topk: "kernel")
+    before = regions_traced("mixer/kernel", op="sparse", path="kernel")
+    got_loss, got = jax.value_and_grad(f)(params)
+    assert regions_traced("mixer/kernel", op="sparse", path="kernel") - before == 5  # index, fwd, probs, bwd, index_bwd: one block trace
+    _close(got_loss, want_loss, 1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        _close(a, b, 1e-5)
+
+
+def test_a_checkpointed_sparse_block_keeps_its_names():
+    from deepspeed_tpu.models.transformer import remat_keeps
+
+    assert remat_keeps(("sparse", "dense")) == ("kda_scan", "routed_ffn", "flash_attention", "projection", "sparse_attention")
+    assert remat_keeps(("gdn", "routed")) == remat_keeps(("full", "routed")) == ("kda_scan", "routed_ffn", "flash_attention", "projection")
+    assert remat_keeps(("full", "dense")) == ()
+
+
+def test_what_the_mixer_counts(model):
+    """Regions ``mixer/index``, ``mixer/select``, ``mixer/index_loss`` and the attention under ``mixer/kernel``; the one
+    counter's ``op="sparse"`` series; the three device counts, an output of the traced loss."""
+    m, params, ids = model
+    reg = get_registry()
+    before = {p: regions_traced("mixer/kernel", op="sparse", path="xla", **{"pass": p}) for p in ("index", "fwd", "probs")}
+    select = regions_traced("mixer/select", path="xla")
+    with device_counts.collecting() as reported:
+        text = jax.jit(lambda p: m.loss_fn(p, {"input_ids": ids})).lower(params).as_text(debug_info=True)
+        assert set(reported) == {"sparse_keys"}
+    for name in ("mixer/index", "mixer/select", "mixer/index_loss", "mixer/kernel"):
+        assert name in text  # the scope reaches the lowered operations' names
+    assert all(regions_traced("mixer/kernel", op="sparse", path="xla", **{"pass": p}) == n + 1 for p, n in before.items())  # one block trace
+    assert regions_traced("mixer/select", path="xla") == select + 1
+    with device_counts.collecting() as reported:
+        m.loss_fn(params, {"input_ids": ids})
+    was = [reg.peek(name) or 0.0 for name in ("sparse_keys_chosen_total", "sparse_keys_visible_total")]
+    device_counts.count(reported)
+    chosen, visible = (reg.peek(name) - before for name, before in zip(("sparse_keys_chosen_total", "sparse_keys_visible_total"), was))
+    assert (chosen, visible) == (2 * chosen_pairs(64, 16, 2), 2 * 2 * 64 * 65 / 2) and chosen / visible == pytest.approx(904 / 2080)
+    assert 0.01 < reg.peek("sparse_index_loss") < 5.0
+
+
+def test_the_first_call_line_says_which_path_the_attention_took(tmp_path):
+    """A sparse model has one kind of layer: its trainer's line still says ``layer_kinds`` and ``sparse_path``."""
+    import deepspeed_tpu
+    from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    reset_mesh()
+    topo = initialize_mesh(MeshConfig.from_dict({"data": 1}), devices=jax.devices()[:1], force=True)
+    m = CausalLM(tiny(remat=True))
+    ids = np.random.default_rng(3).integers(0, 97, (1, 64)).astype(np.int32)
+    params = m.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    engine, _, _, _ = deepspeed_tpu.initialize(model=m, model_parameters=params, mesh=topo, config={
+        "train_micro_batch_size_per_gpu": 1, "optimizer": {"type": "adam", "params": {"lr": 1e-3}}, "zero_optimization": {"stage": 0},
+        "mesh": {"data": 1}, "steps_per_print": 10**9})
+    from deepspeed_tpu.runtime.engine import _paths_traced
+
+    blocks, paths_before = regions_traced("block", site="train"), _paths_traced()
+    loss = engine.forward({"input_ids": ids})
+    engine.backward(loss)
+    engine.step()
+    assert regions_traced("block", site="train") == blocks + 1  # one block trace for both layers
+    notes = engine._layer_kind_notes(paths_before)
+    assert notes["layer_kinds"] == "sparse+dense:2" and notes["sparse_path"] == "xla" and "sparse_attention" in notes["remat_keeps"]
+    reset_mesh()
+
+
+# ---------------------------------------------------------------------- the start of the output projection
+@pytest.mark.parametrize("scale", [1.0, 0.25, 0.02])
+def test_the_output_projection_starts_at_the_scale_asked_for(scale):
+    """``sparse_out_init_scale`` times flax's own start for ``o_proj`` (1: the same numbers), and no other leaf moves."""
+    cfg = TransformerConfig(vocab_size=64, n_layers=1, n_heads=4, n_kv_heads=2, head_dims=8, d_model=32, max_seq_len=16, pos_emb="rope",
+                            norm="rmsnorm", activation="swiglu", layer_kinds=(("sparse", "dense"),), index_heads=2, index_head_dim=8,
+                            index_topk=8, tie_embeddings=False)
+    start = lambda cfg: CausalLM(cfg).init(jax.random.PRNGKey(3), {"input_ids": np.zeros((1, 16), np.int32)})
+    usual, asked = start(cfg), start(dataclasses.replace(cfg, sparse_out_init_scale=scale))
+    flat = lambda tree: {jax.tree_util.keystr(path): leaf for path, leaf in jax.tree_util.tree_leaves_with_path(tree)}
+    for name, leaf in flat(usual).items():
+        np.testing.assert_allclose(flat(asked)[name], leaf * (scale if "o_proj" in name else 1.0), rtol=1e-6, atol=0)
+    import flax.linen as nn
+
+    own = nn.DenseGeneral(32, axis=(-2, -1), use_bias=False, param_dtype=jnp.float32).init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 8)))
+    ours = nn.DenseGeneral(32, axis=(-2, -1), use_bias=False, param_dtype=jnp.float32,
+                           kernel_init=nn.initializers.variance_scaling(1.0, "fan_in", "truncated_normal")).init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 8)))
+    np.testing.assert_array_equal(own["params"]["kernel"], ours["params"]["kernel"])
+
+
+@pytest.mark.parametrize("scale,even", [(1.0, False), (0.02, True)])
+def test_a_small_start_of_the_output_projection_keeps_the_routers_load_even(scale, even):
+    """Why the Keye-VL cell starts ``o_proj`` small: at the usual random start attention averages its keys, every
+    position gets nearly the same vector, the stream collapses onto it layer by layer and a random router sends a share
+    here that the seed decides (0.004 to 2.7 times the uniform 512 pairs over these layers); with the attention's part
+    small the stream stays the tokens' own and every layer of every seed stays within 0.35 of uniform."""
+    seq = 512
+    cfg = TransformerConfig(vocab_size=1024, n_layers=4, n_heads=4, n_kv_heads=2, head_dims=32, d_model=256, max_seq_len=seq, pos_emb="rope",
+                            norm="rmsnorm", activation="swiglu", qk_norm=True, layer_kinds=(("sparse", "routed"),) * 4, index_heads=2,
+                            index_head_dim=16, index_topk=seq, tie_embeddings=False, moe_num_experts=32, moe_top_k=4, moe_d_ff=64,
+                            moe_held=(0, 8), moe_scoring="softmax", moe_aux_loss_coef=0.0, sparse_out_init_scale=scale)
+    model, furthest = CausalLM(cfg), 0.0
+    for seed in range(3):
+        params = model.init(jax.random.PRNGKey(seed), {"input_ids": np.zeros((1, seq), np.int32)})
+        ids = np.random.default_rng(seed).integers(0, 1024, (1, seq)).astype(np.int32)
+        _, mods = model.module.apply({"params": params}, ids, return_hidden=True, mutable=("losses", "intermediates"))
+        here = [int(np.asarray(leaf).reshape(-1)[0]) for path, leaf in jax.tree_util.tree_leaves_with_path(mods["intermediates"])
+                if any(getattr(k, "key", None) == "rows" for k in path)]
+        assert len(here) == 4
+        furthest = max(furthest, max(abs(n / (seq * 4 * 8 / 32) - 1.0) for n in here))
+    assert (furthest <= 0.35) if even else (furthest >= 0.9), furthest
+
+
+# ---------------------------------------------------------------------- the share, and the older cells' calls
+def test_eight_shares_add_up_to_the_whole_routed_layer(highest):
+    """THE SHARE TEST: 32 experts, 8 a token by softmax, renormalised, no shared expert: eight chips each hold 4 and
+    give their experts' part; the parts add up to the uncut layer. What every chip of the group computes alike (the
+    attention over the indexer's choice, the norms, the router) is one program on the same weights, counted once."""
+    from deepspeed_tpu.moe.layer import RoutedMoE
+
+    layer = lambda held: RoutedMoE(hidden_size=48, num_experts=32, k=8, d_ff=24, held=held, shared_ff=0, scale=1.0, scoring="softmax", dtype=jnp.float32)
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 48))
+    whole = layer(None).init(jax.random.PRNGKey(5), h)["params"]
+    whole = {k: v + 0.05 * jax.random.normal(jax.random.PRNGKey(i), jnp.shape(v)) if not isinstance(v, dict) else v for i, (k, v) in enumerate(whole.items())}
+    share = lambda first: {k: (v[first:first + 4] if k.startswith("experts_") else v) for k, v in whole.items()}
+    parts = sum(layer((first, 4)).apply({"params": share(first)}, h) for first in range(0, 32, 4))
+    _close(parts, layer(None).apply({"params": whole}, h), 1e-5)
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,seq,calls", [
+    (16, 16, 128, 2048, ["flash_fwd", "flash_bwd"]),        # OLMo-1B's attention: the fused backward
+    (16, 2, 256, 8192, ["flash_fwd", "flash_bwd"]),         # Qwen3-Next's: a head at a time on copies of its KV head, one call still
+])
+def test_the_older_cells_attention_lowers_to_the_calls_it_lowered_to(heads, kv_heads, head_dim, seq, calls):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, seq, heads, head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads, head_dim), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=True), q, k, v)
+        return (o,) + vjp(do)
+
+    text = str(jax.make_jaxpr(fwd_bwd)(q, kv, kv, q))
+    assert re.findall(r"name=(flash_(?:fwd|bwd|dq|dkv)|sparse_\w+|index_\w+)", text) == calls
+    # the forward a (head, q block) grid; the backward (KV head, q heads a KV head, kv block): Qwen3-Next's group of 8 does
+    # not fit VMEM at 8,192 positions, so every q head gets a copy of its KV head and the grid is a head at a time
+    assert re.findall(r"grid=\(([0-9, ]+)\)", text) == [f"{heads}, {seq // 512}", f"{heads}, 1, {seq // 512}"]

@@ -26,7 +26,7 @@ FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 CELLS = {"olmo-1b": dict(remat=False, zero=True), "kimi-linear-48b-l5e8": dict(remat=True, zero=False),
          "kimi-vl-a3b-l6e8": dict(remat=True, zero=False)}
 # the closed list (docs/OBSERVABILITY.md, "Regions")
-REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "ffn/dense", "ffn/shared", "ffn/router", "ffn/rows",
+REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/index", "mixer/select", "mixer/index_loss", "ffn/dense", "ffn/shared", "ffn/router", "ffn/rows",
            "ffn/cond", "branch/usual", "branch/every_pair", "ffn/experts", "head", "optimizer", "zero/gather", "zero/reduce",
            "zero/regather", "block"}
 HEAVY = ("dot", "convolution", "custom-call")

@@ -831,7 +831,7 @@ _METRIC_PREFIXES = ("train_", "comm_", "infer_", "kv_", "sched_", "spec_",
                     "paged_attention_")
 # profile_* metrics are listed explicitly: a bare "profile_" prefix would
 # also match the `profile_captures` knob-default directory name in docs
-_EXTRA_METRICS = {"last_step_completed_unix", "tp_degree",
+_EXTRA_METRICS = {"last_step_completed_unix", "tp_degree", "sparse_keys_chosen_total", "sparse_keys_visible_total", "sparse_index_loss",
                   "moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min",
                   "moe_fallback_layers_total",
                   "profile_captures_total",
