@@ -150,12 +150,18 @@ def _renormalised(chosen, scale: float):
 
 def _chosen(scores, ranked, k: int):
     """The columns of the ``k`` largest of ``ranked`` in each row, and ``scores`` there: (N, E) -> ((N, k) int32, (N, k)).
-    Both are named, so a checkpointed block keeps them and its backward runs neither the top-k nor the gather again.
+    The scores are taken by comparison, not by a gather: each choice's column against an iota over the expert axis, the
+    row's scores where they meet and zero elsewhere, summed over that axis: one term and zeros, so the value is the
+    gathered one to the last bit, and what JAX derives for the backward is a select by the same mask summed over ``k``
+    (a token's ``k`` experts are distinct, so again one term an entry) where a gather's transpose is a scatter-add,
+    which the chip runs a scalar at a time. Both results are named, so a checkpointed block keeps them and its backward
+    runs neither the top-k nor the sum again; the mask's only residual is ``idx``.
     The values ``lax.top_k`` itself returns are left unused: its own rule reads the indices of the call it
     differentiates, which no name reaches, and a backward through them would make the top-k a second time."""
     _, idx = jax.lax.top_k(ranked, k)
     idx = checkpoint_name(idx, SAVED)
-    return idx, checkpoint_name(jnp.take_along_axis(scores, idx, axis=-1), SAVED)
+    met = idx[..., None] == jnp.arange(scores.shape[-1], dtype=idx.dtype)  # (N, k, E), fused into the sum
+    return idx, checkpoint_name(jnp.sum(jnp.where(met, scores[..., None, :], 0), axis=-1), SAVED)
 
 
 def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float):
@@ -175,6 +181,14 @@ def softmax_topk(logits: jnp.ndarray, k: int, scale: float):
     scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     idx, chosen = _chosen(scores, scores, k)
     return idx, _renormalised(chosen, scale)
+
+
+def _rows_a_group(key, n: int):
+    """How many of the pairs ``key`` (P,), each the held expert of a pair or ``n`` for one not held here, each of the
+    ``n`` groups has: (n,) int32, ``bincount(key)[:n]``. By comparison, as ``_chosen`` takes its scores and
+    ``moe_sum_rows.spans`` counts a tile's rows: each key against an iota over the groups, summed over the pairs (a pair
+    not held meets no column); ``bincount`` is a scatter-add of one scalar a pair, which the chip adds one by one."""
+    return jnp.sum(key[:, None] == jnp.arange(n, dtype=key.dtype), axis=0, dtype=jnp.int32)
 
 
 def _grouped(xs, w, group_sizes, kernel: bool):
@@ -298,12 +312,14 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     n = wg.shape[0]
     keep = lambda x: checkpoint_name(x, SAVED) if named and x is not None else x
     tiled = kernel and moe_sum_rows.fits(N, rows, tokens.shape[1], n, tokens.dtype)  # off the TPU, or a shape its tiles do not take: the gathers
-    with region("ffn/router"):  # the bookkeeping: the two sorts of every pair, the counts, the spans
+    # the bookkeeping: the two sorts of every pair, the counts, the spans. ``compare_sum``: the expert axis is indexed by
+    # comparison against an iota and a sum (here the counts, in ``_chosen`` the scores), never by a gather or a scatter
+    with region("ffn/router", path="compare_sum"):
         local = idx - first
         here = (local >= 0) & (local < n)
         key = jnp.where(here, local, n).reshape(-1)
         order = jnp.argsort(key)  # stable: held experts first, by expert, tokens in order
-        group_sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+        group_sizes = _rows_a_group(key, n)
         routed = jnp.sum(group_sizes)
         row_ok = (jnp.arange(rows) < routed)[:, None]
         pos = jnp.argsort(order).reshape(N, k)  # where each pair went

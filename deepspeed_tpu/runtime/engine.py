@@ -90,7 +90,9 @@ _PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "gdn_path"
           "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"}),
           "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
 _PATH_WORDS = {"moe_cond": "fallback_keeps_nothing"}  # the one form ``routed_part``'s conditional has
-_ROUTER_WORDS = ("sigmoid", "softmax")  # ``path`` of ``ffn/router`` where a routed layer scores its tokens: the line's ``moe_router``
+# ``path`` of ``ffn/router``: how a routed layer scores its tokens, and how its bookkeeping indexes the expert axis
+# (``compare_sum``: by comparison and a sum, ``moe/sharded_moe.py::held_experts``); the line's ``moe_router`` joins those that rose
+_ROUTER_WORDS = ("sigmoid", "softmax", "compare_sum")
 
 
 def _paths_traced():
@@ -766,7 +768,7 @@ class DeepSpeedEngine:
         fallback), ``mixed``, or no key where the program has none (``gdn_path``:
         the delta-rule scan with a decay a head; ``sparse_path``: attention over the keys an indexer chose, which a model
         has in every layer or in none, so this key is given whatever the kinds); how a routed layer scores its
-        tokens (``moe_router``: ``sigmoid`` or ``softmax``); and, where
+        tokens and indexes the expert axis (``moe_router``: ``sigmoid`` or ``softmax``, ``+compare_sum``); and, where
         a routed layer's buffer is smaller than every pair, which form its
         conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``). Whatever the kinds, under ``remat``:
         what a checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
