@@ -6,7 +6,9 @@ call of ``ops/pallas/indexed_attention.py``; elsewhere (the CPU tests, a
 sequence the kernels do not take) it is the plain XLA form in this file, which
 holds the whole square and is the kernels' oracle. Which one a call site took
 is counted where it is chosen (``program_regions_traced_total{region=
-"mixer/kernel", op="sparse", pass, path}``, ``{region="mixer/select", path}``).
+"mixer/kernel", op="sparse", pass, path}``, ``{region="mixer/select", path}``;
+the kernels' choice also says, as ``counted``, the share of a band's rows its
+count passes walk).
 
 Square arrays are key-major, ``(B, Sk, Sq)``, as the kernels keep them
 (``scores_t``, ``mask_t``, ``probs_t``): nothing outside this file and the
@@ -88,8 +90,11 @@ def select_xla(scores_t, topk: int):
 
 
 def select_keys(scores_t, topk: int, *, path: str):
-    """The choice: discrete, no gradient. Named (``SAVED``)."""
-    with region("mixer/select", path=path):
+    """The choice: discrete, no gradient. Named (``SAVED``). The kernel's count passes walk the rows at or below a
+    band's diagonal, in the bands that search: which share of all rows that is follows the shapes and is the label
+    ``counted``, to three digits."""
+    walked = {"counted": f"{kernel.share_walked(scores_t.shape[1], topk):.3f}"} if path == "kernel" else {}
+    with region("mixer/select", path=path, **walked):
         scores_t = jax.lax.stop_gradient(scores_t)
         mask_t = select_xla(scores_t, topk) if path != "kernel" else kernel.index_select(scores_t, topk, interpret=_interpret())
         return checkpoint_name(mask_t, SAVED)

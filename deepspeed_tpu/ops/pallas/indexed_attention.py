@@ -16,8 +16,13 @@ calls, named apart from the flash kernels' on the device's clock:
   partial sum a query block, added outside;
 - ``index_select``: a query's ``min(topk, t + 1)`` best visible keys as an
   int8 mask. A band of 128 queries' scores stays in VMEM; the k-th largest
-  is found bit by bit on the scores' order-preserving integer form (32 counts
-  over the band), ties at the threshold go to the lower index (14 more);
+  is found bit by bit on the scores' order-preserving integer form (32
+  counts), ties at the threshold go to the lower index (14 more). A count
+  walks, a chunk of rows at a time, the rows at or below the band's diagonal
+  and no others (band ``i`` sees its first ``128 (i + 1)`` keys), and a band
+  whose every query takes every visible key (``128 (i + 1) <= topk``) makes
+  no count at all: ``share_walked`` of all rows, 0.480 at 8,192 positions,
+  2,048 keys a query and chunks of 256 rows;
 - ``sparse_fwd`` / ``sparse_bwd``: the flash forward and fused backward with
   the mask, cut into the kernels' own (bk, bq) tiles, in place of the causal
   compare: every block at or below the diagonal is visited (a learned choice
@@ -42,6 +47,7 @@ from .flash_attention import _NN, _NT, _TN, NEG_INF, _fused_bwd_vmem, _rows, _ti
 
 BLOCK = 512  # the (bk, bq) tile of the attention and indexer kernels, and of the mask's tiled form
 BAND = 128   # queries whose scores ``index_select`` holds at once: (Sk, 128) float32 are 4 MB at 8,192 keys
+CHUNK = 256  # rows of a band a count pass of ``index_select`` takes at a time: 32 vregs (128 and 512 rows are slower: PERF.md, PR 44)
 INT_MIN = -2**31
 
 
@@ -182,47 +188,97 @@ def _ordered(x):
     return bits ^ ((bits >> 31) & 0x7FFFFFFF)
 
 
-def _count(hit):
-    """How many of a band's keys ``hit`` a query: (Sk, band) bool -> (1, band) float32 (exact to 2**24)."""
-    return jnp.sum(hit.astype(jnp.float32), axis=0, keepdims=True)
+def chunk_for(seq: int, rows: int = 0) -> int:
+    """The rows of a band that ``index_select`` walks at a time."""
+    return block_that_divides(seq, rows or CHUNK)
 
 
-def _index_select_kernel(s_ref, o_ref, key_ref, *, topk: int, seq: int, band: int):
+def chunks_walked(seq: int, topk: int, band: int, rows: int):
+    """The chunks of ``rows`` keys a count pass of ``index_select`` walks, a band of ``band`` queries: the ones that hold
+    a key the band's last query sees, and none in a band whose every query takes every visible key."""
+    return [-(-last // rows) if last > topk else 0 for last in range(band, seq + 1, band)]
+
+
+def share_walked(seq: int, topk: int, band: int = 0, rows: int = 0) -> float:
+    """The rows a count pass walks over the rows the bands hold, summed over the bands: at 8,192 positions, 2,048 keys
+    a query and bands of 128 queries 0.475 in chunks of 128 rows, 0.480 in chunks of 256."""
+    rows = chunk_for(seq, rows)
+    walked = chunks_walked(seq, topk, band or min(BAND, seq), rows)
+    return sum(walked) * rows / (len(walked) * seq)
+
+
+def _index_select_kernel(s_ref, o_ref, key_ref, *, topk: int, seq: int, band: int, rows: int):
     i = pl.program_id(1)
-    keys = jax.lax.broadcasted_iota(jnp.int32, (seq, band), 0)
-    queries = i * band + jax.lax.broadcasted_iota(jnp.int32, (seq, band), 1)
-    visible = keys <= queries
-    key_ref[...] = jnp.where(visible, _ordered(s_ref[0]), INT_MIN)
+    last = band * (i + 1)  # the keys the band's last query sees
+    n = pl.cdiv(last, rows)  # the chunks that hold one of them
+    queries = i * band + jax.lax.broadcasted_iota(jnp.int32, (rows, band), 1)
     want = jnp.minimum(queries[:1] + 1, topk).astype(jnp.float32)  # (1, band): min(topk, t + 1)
 
-    # the largest T with count(key >= T) >= want: the sign first, then bit 30 down to 0
-    t0 = jnp.where(_count(key_ref[...] >= 0) >= want, 0, INT_MIN).astype(jnp.int32)
+    def chunk(c):
+        return pl.ds(pl.multiple_of(c * rows, rows), rows)
 
-    def bit(b, t):
-        cand = t | jnp.left_shift(jnp.int32(1), 30 - b)
-        return jnp.where(_count(key_ref[...] >= cand) >= want, cand, t)
+    def keys(c):
+        return c * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, band), 0)
 
-    thr = jax.lax.fori_loop(0, 31, bit, t0)
-    above = key_ref[...] > thr
-    ties = key_ref[...] == thr
-    need = want - _count(above)  # of the ties, the ones of lowest index: at least one
+    def write(c, chosen):
+        o_ref[0, chunk(c), :] = chosen.astype(jnp.int32).astype(jnp.int8)
 
-    # the largest P with count(ties below P) < need is the need-th tie's index
-    def index_bit(b, p):
-        cand = p | jnp.left_shift(jnp.int32(1), (seq - 1).bit_length() - 1 - b)
-        return jnp.where(_count(ties & (keys < cand)) < need, cand, p)
+    def count(hit):
+        """How many of the band's keys ``hit(their ordered scores, their positions)`` a query: (1, band) float32, exact
+        to 2**24. The walk is over the chunks at or below the band's diagonal; a chunk adds its rows eight at a time
+        and the eight sublanes are summed once a pass."""
+        def add(c, acc):
+            return acc + jnp.sum(hit(key_ref[chunk(c), :], keys(c)).astype(jnp.float32).reshape(rows // 8, 8, band), axis=0)
 
-    last = jax.lax.fori_loop(0, (seq - 1).bit_length(), index_bit, jnp.zeros((1, band), jnp.int32))
-    o_ref[0] = (visible & (above | (ties & (keys <= last)))).astype(jnp.int32).astype(jnp.int8)
+        return jnp.sum(jax.lax.fori_loop(0, n, add, jnp.zeros((8, band), jnp.float32)), axis=0, keepdims=True)
+
+    def each(first, stop, body):
+        jax.lax.fori_loop(first, stop, lambda c, _: body(c), None)
+
+    each(n, seq // rows, lambda c: write(c, jnp.zeros((rows, band), jnp.bool_)))  # no key past the diagonal
+
+    @pl.when(last <= topk)
+    def _every_visible_key():  # want == t + 1 for every query of the band
+        each(0, n, lambda c: write(c, keys(c) <= queries))
+
+    @pl.when(last > topk)
+    def _search():
+        def ordered(c):  # an entry past the diagonal in the last chunk: INT_MIN, which no pass counts
+            key_ref[chunk(c), :] = jnp.where(keys(c) <= queries, _ordered(s_ref[0, chunk(c), :]), INT_MIN)
+
+        each(0, n, ordered)
+        # the largest T with count(key >= T) >= want: the sign first, then bit 30 down to 0
+        t0 = jnp.where(count(lambda key, _: key >= 0) >= want, 0, INT_MIN).astype(jnp.int32)
+
+        def bit(b, t):
+            cand = t | jnp.left_shift(jnp.int32(1), 30 - b)
+            return jnp.where(count(lambda key, _: key >= cand) >= want, cand, t)
+
+        thr = jax.lax.fori_loop(0, 31, bit, t0)
+        need = want - count(lambda key, _: key > thr)  # of the ties, the ones of lowest index: at least one
+
+        # the largest P with count(ties below P) < need is the need-th tie's index
+        def index_bit(b, p):
+            cand = p | jnp.left_shift(jnp.int32(1), (seq - 1).bit_length() - 1 - b)
+            return jnp.where(count(lambda key, at: (key == thr) & (at < cand)) < need, cand, p)
+
+        tie = jax.lax.fori_loop(0, (seq - 1).bit_length(), index_bit, jnp.zeros((1, band), jnp.int32))
+
+        def choose(c):
+            key, at = key_ref[chunk(c), :], keys(c)
+            write(c, (at <= queries) & ((key > thr) | ((key == thr) & (at <= tie))))
+
+        each(0, n, choose)
 
 
-def index_select(scores_t, topk: int, *, interpret: bool = False, band: int = 0):
+def index_select(scores_t, topk: int, *, interpret: bool = False, band: int = 0, rows: int = 0):
     """I^T (B, Sk, Sq) float32 -> the choice as an int8 mask (B, Sk, Sq): a query's ``min(topk, t + 1)``
     largest visible scores, ties to the lower index."""
     B, Sk, Sq = scores_t.shape
     band = band or min(BAND, Sq)
+    rows = chunk_for(Sk, rows)
     return pl.pallas_call(
-        functools.partial(_index_select_kernel, topk=int(topk), seq=Sk, band=band),
+        functools.partial(_index_select_kernel, topk=int(topk), seq=Sk, band=band, rows=rows),
         grid=(B, Sq // band),
         in_specs=[pl.BlockSpec((1, Sk, band), lambda b, i: (b, 0, i))],
         out_specs=pl.BlockSpec((1, Sk, band), lambda b, i: (b, 0, i)),
@@ -230,7 +286,8 @@ def index_select(scores_t, topk: int, *, interpret: bool = False, band: int = 0)
         scratch_shapes=[pltpu.VMEM((Sk, band), jnp.int32)],
         interpret=interpret,
         name="index_select",
-        compiler_params=_compiler_params("parallel", "parallel", interpret=interpret, vmem_bytes=Sk * band * (2 * 4 + 4 + 2 + 6 * 4)),
+        # the scores and the mask twice (the pipeline's), their integer form once, and a chunk's temporaries
+        compiler_params=_compiler_params("parallel", "parallel", interpret=interpret, vmem_bytes=Sk * band * (2 * 4 + 2 + 4) + 8 * rows * band * 4),
     )(scores_t)
 
 

@@ -5,6 +5,7 @@ than ``k`` is the dense mixer's program; what it counts; the share of eight; and
 pinned. Tiny widths, float32, CPU."""
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -58,24 +59,56 @@ def test_the_score_kernel_is_the_xla_form(operands):
     assert float(jnp.max(jnp.where(jnp.arange(S)[:, None] > jnp.arange(S)[None, :], got, kernel.NEG_INF))) <= -9e29  # a key ahead of its query scores NEG_INF
 
 
-@pytest.mark.parametrize("what", ["as_scored", "many_ties", "all_equal", "negative_zero"])
-def test_the_choice_kernel_is_top_k_with_ties_to_the_lower_index(operands, what):
-    scores = operands["scores"]
+# (positions, keys a query takes, rows a chunk; 0: the kernel's own) at bands of 128 queries. The first has the band in which
+# ``t + 1`` passes ``topk`` and one past it; the others add a band wholly under ``topk``, which runs no search, and the
+# last walks chunks of 256 rows, which divide the visible rows of no odd band: its last chunk reaches past the diagonal
+CHOICES = {"two_bands": (S, TOPK, 128), "every_kind_of_band": (512, 160, 0), "chunks_past_the_diagonal": (512, 160, 256)}
+
+
+@functools.lru_cache(maxsize=None)
+def _scores(seq):
+    rng = np.random.default_rng(seq)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return ops.index_scores_xla(f(B, J, seq, DI), f(B, seq, DI), f(B, J, seq) * 0.2)
+
+
+@pytest.mark.parametrize("what", ["as_scored", "many_ties", "all_equal", "negative_zero", "ties_across_a_chunks_edge"])
+@pytest.mark.parametrize("shape", list(CHOICES))
+def test_the_choice_kernel_is_top_k_with_ties_to_the_lower_index(highest, shape, what):
+    seq, topk, rows = CHOICES[shape]
+    scores = _scores(seq)
     seen = scores > -1e29
+    key = jnp.arange(seq)[:, None]
+    edge = -(-(topk + 10) // 128) * 128  # the first row past the keys above the threshold that starts a chunk of 128 or of 256 rows
     if what == "many_ties":  # half-integers: dozens of keys share a query's threshold
         scores = jnp.where(seen, jnp.round(scores * 2) / 2, scores)
-    elif what == "all_equal":  # every key ties: the choice is the first ``TOPK`` positions
+    elif what == "all_equal":  # every key ties: the choice is the first ``topk`` positions
         scores = jnp.where(seen, 1.0, scores)
     elif what == "negative_zero":  # -0.0 and 0.0 are one score
-        scores = jnp.where(seen, jnp.where(jnp.arange(S)[:, None] % 2 == 0, -0.0, 0.0), scores)
-    want, got = ops.select_xla(scores, TOPK), kernel.index_select(scores, TOPK, interpret=True, band=128)
+        scores = jnp.where(seen, jnp.where(key % 2 == 0, -0.0, 0.0), scores)
+    elif what == "ties_across_a_chunks_edge":  # ``topk - 14`` keys above the threshold, twenty ties around the edge, 14 needed
+        scores = jnp.where(seen, jnp.where(key < topk - 14, 3.0 + key, jnp.where(abs(key - edge + 0.5) < 10, 1.0, -1.0 - abs(scores))), scores)
+    want, got = ops.select_xla(scores, topk), kernel.index_select(scores, topk, interpret=True, band=128, rows=rows)
     assert got.dtype == jnp.int8 and int(jnp.sum(want != got)) == 0
-    assert int(jnp.sum(got)) == chosen_pairs(S, TOPK, B)
+    assert int(jnp.sum(got)) == chosen_pairs(seq, topk, B)
     per_query = jnp.sum(got.astype(jnp.int32), axis=1)  # key-major: a query's keys lie along axis 1
-    assert (np.asarray(per_query) == np.minimum(np.arange(S) + 1, TOPK)[None, :]).all()
-    assert int(jnp.sum(jnp.where(jnp.arange(S)[:, None] > jnp.arange(S)[None, :], got, 0))) == 0  # none ahead of its query
+    assert (np.asarray(per_query) == np.minimum(np.arange(seq) + 1, topk)[None, :]).all()
+    assert int(jnp.sum(jnp.where(key > jnp.arange(seq)[None, :], got, 0))) == 0  # none ahead of its query
+    last = np.asarray(got[0, :, seq - 1])  # the last query's keys
     if what in ("all_equal", "negative_zero"):
-        assert (np.asarray(got[0, :TOPK, S - 1]) == 1).all()  # the last query's keys: the lowest indices
+        assert (last[:topk] == 1).all()  # the lowest indices
+    if what == "ties_across_a_chunks_edge":
+        assert (np.flatnonzero(last) == np.r_[0:topk - 14, edge - 10:edge + 4]).all()  # four of the ties lie in the next chunk
+
+
+@pytest.mark.parametrize("rows,chunks,share", [(128, 1944, "0.475"), (256, 984, "0.480"), (512, 504, "0.492")])
+def test_the_choice_walks_under_half_of_the_rows_at_the_keye_cells_shape(rows, chunks, share):
+    """8,192 positions, 2,048 keys a query, bands of 128: bands 0-15 search nothing, band ``i`` of the others walks the
+    chunks that hold one of its ``128 (i + 1)`` visible keys; 4,096 band-chunks of 128 rows in all."""
+    walked = kernel.chunks_walked(8192, 2048, 128, rows)
+    assert walked[:16] == [0] * 16 and walked[16:] == [-(-128 * (i + 1) // rows) for i in range(16, 64)] and sum(walked) == chunks
+    assert f"{kernel.share_walked(8192, 2048, rows=rows):.3f}" == share and kernel.share_walked(8192, 2048, rows=rows) <= 0.5
+    assert kernel.share_walked(8192, 8192, rows=rows) == 0.0  # every query takes every visible key: no band searches
 
 
 def test_the_forward_kernel_is_masked_softmax_attention(operands):
@@ -259,6 +292,25 @@ def test_what_the_mixer_counts(model):
     chosen, visible = (reg.peek(name) - before for name, before in zip(("sparse_keys_chosen_total", "sparse_keys_visible_total"), was))
     assert (chosen, visible) == (2 * chosen_pairs(64, 16, 2), 2 * 2 * 64 * 65 / 2) and chosen / visible == pytest.approx(904 / 2080)
     assert 0.01 < reg.peek("sparse_index_loss") < 5.0
+
+
+def test_the_kernels_choice_says_which_share_of_the_rows_it_counts(highest, monkeypatch):
+    """``mixer/select`` on the kernels' path carries ``counted``: at 512 positions, 160 keys a query and bands of 128 the
+    three bands past the first search, over the 2, 3 and 4 chunks of 128 rows (or the one of 512) at or below their
+    diagonals, of the 4 x 4 (4 x 1) the bands hold (9 of 16; 3 of 4). XLA's path has no such label and counts as before."""
+    scores = _scores(512)
+    rows = kernel.chunk_for(512)
+    by_hand = f"{sum(-(-128 * (i + 1) // rows) for i in (1, 2, 3)) * rows / (4 * 512):.3f}"
+    assert by_hand == {128: "0.562", 256: "0.625", 512: "0.750"}[rows]
+    reg = get_registry()
+    series = lambda **labels: reg.peek("program_regions_traced_total", region="mixer/select", **labels) or 0.0
+    kernels, xla, counted = regions_traced("mixer/select", path="kernel"), regions_traced("mixer/select", path="xla"), series(path="kernel", counted=by_hand)
+    got = ops.select_keys(scores, 160, path="kernel")
+    assert series(path="kernel", counted=by_hand) == counted + 1 and regions_traced("mixer/select", path="kernel") == kernels + 1
+    assert regions_traced("mixer/select", path="xla") == xla == series(path="xla")
+    assert int(jnp.sum(got != ops.select_keys(scores, 160, path="xla"))) == 0
+    assert regions_traced("mixer/select", path="xla") == xla + 1 == series(path="xla") and regions_traced("mixer/select", path="kernel") == kernels + 1
+    assert regions_traced("mixer/select") == kernels + xla + 2  # the sum over the series, whatever their labels
 
 
 def test_the_first_call_line_says_which_path_the_attention_took(tmp_path):
