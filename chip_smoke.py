@@ -174,8 +174,7 @@ def padded_ids(rows, width):
 
 
 def f32_rule(ours, plain, truth, floor=1e-6):
-    """The float32-referenced rule (carried from the August session's triage,
-    ``tools/debug_flash_gqa.py``): both contestants are bf16, so each is judged
+    """The float32-referenced rule: both contestants are bf16, so each is judged
     against a float32 computation of the same math, and ours fails only if its
     error clearly exceeds the plain bf16 path's own. A structural kernel bug is
     orders of magnitude off; a fixed absolute gate flags bf16 rounding."""
@@ -754,9 +753,13 @@ HYBRID_LIMITS = {        # the program's readings | the control's
 }
 
 
+HYBRID_OWNERS = {"A_log": ("layer_1", "kda"), "k_conv": ("layer_1", "kda"), "experts_wg": ("layer_1", "routed"),
+                 "kv_b_proj": ("layer_3", "mla")}  # a judged leaf -> (its layer, the kind of layer part that owns it)
+
+
 def _hybrid_leaf(tree, name):
-    layer = tree["layer_3"]["mla"] if name == "kv_b_proj" else tree["layer_1"]["routed" if name == "experts_wg" else "kda"]
-    leaf = layer[name]
+    layer, kind = HYBRID_OWNERS[name]
+    leaf = tree[layer][kind][name]
     return (leaf["kernel"] if isinstance(leaf, dict) else leaf).astype(jnp.float32)
 
 
@@ -811,10 +814,13 @@ DELTANET_LIMITS = {              # the program's readings | ``low_state``'s | ``
 }
 
 
+DELTANET_OWNERS = {"A_log": "gdn", "k_conv": "gdn", "experts_wg": "routed", "shared_expert_gate": "routed"}  # of layer 1
+
+
 def _deltanet_leaf(tree, name):
     if name == "attn_q_proj":
         return tree["layer_3"]["attn"]["q_proj"]["kernel"].astype(jnp.float32)  # every head's query and its gate
-    leaf = tree["layer_1"]["gdn" if name in ("A_log", "k_conv") else "routed"][name]
+    leaf = tree["layer_1"][DELTANET_OWNERS[name]][name]
     return (leaf["kernel"] if isinstance(leaf, dict) else leaf).astype(jnp.float32)
 
 
@@ -854,8 +860,11 @@ SPARSE_CONTROLS = {"no_indexer": ({"no_indexer": True}, False), "no_index_loss":
 SPARSE_CONFIG = "benchmarks/configs/keye-vl2-30b-l4e16.json"
 
 
+SPARSE_OWNERS = dict({name: "sparse" for name in ("q_proj", "o_proj", "index_q_proj", "index_k_proj", "index_w_proj")}, experts_wg="routed")  # of layer 1
+
+
 def _sparse_leaf(tree, name):
-    leaf = tree["layer_1"]["routed" if name == "experts_wg" else "sparse"][name]
+    leaf = tree["layer_1"][SPARSE_OWNERS[name]][name]
     return (leaf["kernel"] if isinstance(leaf, dict) else leaf).astype(jnp.float32)
 
 

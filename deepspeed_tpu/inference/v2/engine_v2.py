@@ -131,12 +131,11 @@ class InferenceEngineV2:
             config.min_decode_bucket = max(1, knobs.get_int("DS_TPU_MIN_DECODE_BUCKET"))
         self.model = model
         cfg: TransformerConfig = model.cfg
-        if not cfg.softmax_only:
-            kinds = sorted({k for pair in cfg.kinds for k in pair} - {"full", "window", "dense", "moe"})
+        if cfg.unstackable:  # by the kinds' records (``models/layers.py::LayerKind.stackable``)
             raise NotImplementedError(
                 f"inference/v2 serves softmax attention over one head size with dense or capacity-gated MoE FFNs; this "
-                f"model has layers of kind {kinds}: a recurrent state beside the paged KV (kda), a latent cache (mla) and "
-                f"the routed FFN's gate are training-side only")
+                f"model has layers of kind {list(cfg.unstackable)}: a recurrent state beside the paged KV, a latent cache, a "
+                f"choice of keys and a routed FFN's gate are training-side only")
         if cfg.attn_output_gate:
             raise NotImplementedError("inference/v2 has no output gate on its attention (attn_output_gate): training-side only")
         self.cfg = cfg

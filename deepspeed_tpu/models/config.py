@@ -1,0 +1,157 @@
+"""Every field of a transformer's configuration and what follows from the fields alone. This module imports nothing of
+the package: the layers that read a configuration sit above it, and what it says of its layers' KINDS needs the table
+above them (``transformer.py``, whose ``TransformerConfig`` is this class and those readings)."""
+
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import jax.numpy as jnp
+
+
+@dataclass(frozen=True)
+class TransformerFields:
+    vocab_size: int = 32000
+    n_layers: int = 2
+    n_heads: int = 4
+    n_kv_heads: Optional[int] = None  # < n_heads => GQA (llama-70b style)
+    head_dims: Optional[int] = None  # explicit head dim (gemma: != d_model/n_heads)
+    d_model: int = 128
+    d_ff: Optional[int] = None  # default: 4*d_model (gelu) or 8/3*d_model (swiglu)
+    max_seq_len: int = 2048
+    norm: str = "layernorm"  # layernorm | rmsnorm | layernorm_np (olmo: no affine params)
+    activation: str = "gelu"  # gelu (tanh approx) | gelu_exact (erf) | swiglu | relu
+    pos_emb: str = "learned"  # learned | rope | alibi | none
+    rope_theta: float = 10000.0
+    rotary_pct: float = 1.0  # fraction of head_dim rotated (gpt-neox/phi partial rotary)
+    rotary_dims: Optional[int] = None  # exact rotated dim count (gpt-j rotary_dim); overrides rotary_pct
+    rope_style: str = "neox"  # neox (rotate-half) | gptj (interleaved pairs)
+    # HF rope_scaling variants (transformers modeling_rope_utils.py):
+    # linear (position interpolation), dynamic (NTK-by-parts at max_seq_len),
+    # llama3 (frequency-banded interpolation — llama-3.1+), yarn
+    rope_scaling: Optional[str] = None  # linear | dynamic | llama3 | yarn
+    rope_factor: float = 1.0
+    rope_orig_max_seq: Optional[int] = None  # original_max_position_embeddings
+    rope_low_freq_factor: float = 1.0   # llama3
+    rope_high_freq_factor: float = 4.0  # llama3
+    rope_beta_fast: float = 32.0        # yarn extrapolation boundary
+    rope_beta_slow: float = 1.0         # yarn interpolation boundary
+    rope_attn_factor: Optional[float] = None  # yarn cos/sin scale; None = 0.1*ln(factor)+1
+    clip_qkv: Optional[float] = None  # olmo: clamp q/k/v activations to [-c, c]
+    # block wiring: sequential (gpt2/llama), parallel (gpt-neox: two norms,
+    # x + attn(ln1 x) + mlp(ln2 x)), parallel_shared (falcon-7b/phi/gpt-j:
+    # one norm feeds both attn and mlp)
+    block_type: str = "sequential"
+    dense_bias: Optional[bool] = None  # default: norm == "layernorm" (falcon: LN but bias-free)
+    qkv_bias: Optional[bool] = None  # override for q/k/v projections only (qwen2)
+    qk_norm: bool = False  # qwen3: per-head RMSNorm on q/k before rope (zero-centered weights under ``rms_offset``)
+    # qwen3-next: q_proj is twice as wide, a head's columns its query and then a gate, and the attention's output is
+    # multiplied by sigmoid(gate) ahead of o_proj: out = (softmax(q k^T / sqrt(D)) v * sigmoid(gate)) W_o
+    attn_output_gate: bool = False
+    attn_out_bias: Optional[bool] = None  # override for o_proj only (gpt-j: biased MLP, bias-free attn)
+    lm_head_bias: bool = False  # phi / gpt-j carry a bias on the untied head
+    embedding_norm: bool = False  # bloom: layernorm directly after the token embedding
+    embed_scale: bool = False  # gemma: scale embeddings by sqrt(d_model)
+    rms_offset: bool = False  # gemma: rmsnorm weights stored zero-centered, applied as (1 + w)
+    sliding_window: Optional[int] = None  # mistral: query i attends keys in (i - w, i]
+    # per-layer window selection: tuple of layer indices that apply
+    # ``sliding_window``; None = every layer (gpt-neo alternating
+    # global/local layers, qwen2 ``max_window_layers`` suffix windows)
+    window_layers: Optional[Tuple[int, ...]] = None
+    attn_scale: Optional[float] = None  # softmax scale override; None = 1/sqrt(head_dim) (gpt-neo: 1.0)
+    # encoder family (BERT): bidirectional attention, post-LN blocks,
+    # token-type embeddings, MLM transform head (ref module_inject/containers/bert.py)
+    causal: bool = True  # False: bidirectional encoder
+    norm_scheme: str = "pre"  # pre (gpt/llama) | post (BERT: norm after residual add)
+    type_vocab_size: int = 0  # >0: token_type embeddings added to the input
+    mlm_head: bool = False  # BERT cls.predictions transform (dense+act+LN) before the tied decoder
+    tie_embeddings: bool = True
+    dtype: Any = jnp.float32  # activation/compute dtype
+    norm_eps: float = 1e-5
+    dropout: float = 0.0
+    remat: bool = False  # jax.checkpoint each block (activation checkpointing)
+    scan_layers: bool = False  # lax.scan over layers (fast compile, pipeline-friendly)
+    # MoE (reference deepspeed/moe): >0 experts turns MLP slots into MoE layers
+    moe_num_experts: int = 0
+    moe_top_k: int = 1
+    moe_capacity_factor: float = 1.25
+    moe_layer_freq: int = 2  # every Nth block is MoE
+    moe_aux_loss_coef: float = 0.01
+    moe_min_capacity: int = 4
+    # THE per-layer specification: one (mixer, ffn) pair a layer, each a name of the table of kinds (``transformer.py::
+    # MIXERS``, ``FFNS``: a kind's module says what it computes). None: the pairs that ``window_layers`` and
+    # ``moe_layer_freq`` describe (``kinds``)
+    layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
+    kda_heads: int = 0  # kda: heads of ``kda_head_dim`` keys and values, a depthwise causal convolution of
+    kda_head_dim: int = 128  # ``kda_conv_size`` on q, k and v, gates through ``kda_gate_rank``
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 128
+    # gdn: ``gdn_key_heads`` heads of q and k, each serving ``gdn_value_heads / gdn_key_heads`` value heads, all of
+    # ``gdn_head_dim``; a depthwise causal convolution of ``gdn_conv_size`` on q, k and v; per value head and token
+    # beta = sigmoid(x w_b), g = -exp(A_log) softplus(x w_a + dt_bias), S_t = (I - beta k k^T) exp(g) S_{t-1} + beta k v^T
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_head_dim: int = 128
+    gdn_conv_size: int = 4
+    # sparse: an indexer of ``index_heads`` heads of ``index_head_dim`` on one key head scores every visible key; a
+    # query attends the ``index_topk`` best (every key, through the dense program, where the sequence is no longer)
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
+    # sparse: the attention's output projection starts at this times its usual standard deviation. At a random start
+    # attention averages its keys, so every position gets nearly the same vector and the stream collapses onto it layer
+    # by layer; a random router turns that into a load a seed decides. A small start leaves the stream the tokens' own
+    sparse_out_init_scale: float = 1.0
+    mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (the rope dims rotated under
+    # ``pos_emb="rope"`` by ``rope_theta`` / ``rope_style``, else nothing is), v of its own
+    mla_qk_nope_dim: int = 128
+    mla_qk_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # routed: ``moe_num_experts`` router outputs, ``moe_top_k`` a token, experts ``moe_d_ff`` wide
+    moe_d_ff: Optional[int] = None  # None: ``ffn_dim``
+    moe_shared_d_ff: int = 0  # width of the shared expert every token also takes (n shared SwiGLUs of f added are one
+    # of n * f, their columns side by side: give the sum); 0: none
+    moe_route_scale: float = 1.0  # the renormalised weights of a token's experts are multiplied by this
+    moe_held: Optional[Tuple[int, int]] = None  # (first, count): the experts THIS program holds; None: all
+    # routed: a token's scores over all experts. "sigmoid": s = sigmoid(x W_r), the top k of s + selection bias;
+    # "softmax": p = softmax(x W_r), the top k of p; either way the chosen ones rescaled to sum to one, times
+    # ``moe_route_scale``
+    moe_scoring: str = "sigmoid"
+    moe_shared_gate: bool = False  # routed: the shared expert's output is multiplied by sigmoid(x w_s), w_s (d_model, 1)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def ffn_dim(self) -> int:
+        if self.d_ff is not None:
+            return self.d_ff
+        if self.activation in ("swiglu", "geglu"):  # gated MLPs get the 8/3 sizing
+            return int(8 * self.d_model / 3 + 127) // 128 * 128 if self.d_model >= 128 else 2 * self.d_model
+        return 4 * self.d_model
+
+    @property
+    def head_dim(self) -> int:
+        if self.head_dims is not None:
+            return self.head_dims
+        assert self.d_model % self.n_heads == 0
+        return self.d_model // self.n_heads
+
+    @property
+    def use_dense_bias(self) -> bool:
+        return self.norm == "layernorm" if self.dense_bias is None else self.dense_bias
+
+    @property
+    def use_qkv_bias(self) -> bool:
+        return self.use_dense_bias if self.qkv_bias is None else self.qkv_bias
+
+    @property
+    def use_attn_out_bias(self) -> bool:
+        return self.use_dense_bias if self.attn_out_bias is None else self.attn_out_bias
+
+    @property
+    def rotary_dim(self) -> int:
+        # even; partial rotary rotates the leading dims
+        if self.rotary_dims is not None:
+            return self.rotary_dims
+        return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)

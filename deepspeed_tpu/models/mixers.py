@@ -3,8 +3,9 @@ delta-rule linear-attention layers with a decay a channel (KDA) or a head
 (Gated DeltaNet), latent attention (MLA), without positions or with its
 shared key part rotated, and grouped-query attention over the keys a learned
 indexer chooses for each query (``SparseMixer``). All are training-side
-modules: a block built from them takes no KV cache (``inference/v2`` refuses
-these kinds by name).
+modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
+``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
+carries its kind's record (``layers.py::LayerKind``).
 """
 
 import flax.linen as nn
@@ -15,10 +16,13 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import indexed_attention as sparse
 from ..ops.attention import attention
-from ..ops.kda import gdn, kda
+from ..ops.kda import SAVED as SCAN_SAVED, gdn, kda
 from ..ops.registry import pallas_available
+from ..telemetry import device_counts
+from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
-from .transformer import SAVED, LayerNorm, RMSNorm, TransformerConfig, apply_rope, scaled_rope_frequencies
+from .config import TransformerFields
+from .layers import FLASH_SAVED, SAVED, LayerKind, LayerNorm, RMSNorm, apply_rope, scaled_rope_frequencies
 
 
 def _uniform(low, high):
@@ -49,7 +53,7 @@ def l2_normalize(x, eps: float = 1e-6):
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
 
-class KDAMixer(nn.Module):
+class KDAMixer(LayerKind, nn.Module):
     """Kimi Delta Attention: per head, ``S_t = (I - beta_t k_t k_t^T)
     Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``, with q, k
     L2-normalised after a depthwise causal convolution and SiLU, a
@@ -57,10 +61,13 @@ class KDAMixer(nn.Module):
     dt_bias))`` and an output gate; state and gates in float32
     (``ops/kda.py``)."""
 
-    cfg: TransformerConfig
+    cfg: TransformerFields
+    keeps, hybrid = (SCAN_SAVED, SAVED), True
+    paths = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"})}
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
         H, D, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
         f32 = jnp.float32
@@ -99,7 +106,7 @@ class KDAMixer(nn.Module):
                                    param_dtype=f32)(o)
 
 
-class GDNMixer(nn.Module):
+class GDNMixer(LayerKind, nn.Module):
     """Gated DeltaNet: KDA's rule with ONE decay a head and token, ``S_t = (I -
     beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T
     q_t``. ``gdn_key_heads`` heads of q and k, each serving ``gdn_value_heads /
@@ -110,10 +117,13 @@ class GDNMixer(nn.Module):
     head (a plain weight) and multiplied by ``silu(z)``, z a projection of its
     own (``ops/kda.py::gdn``)."""
 
-    cfg: TransformerConfig
+    cfg: TransformerFields
+    keeps, hybrid = (SCAN_SAVED, SAVED), True
+    paths = {"gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"})}
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
         Hk, Hv, D = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
         f32 = jnp.float32
@@ -144,7 +154,7 @@ class GDNMixer(nn.Module):
                                    param_dtype=f32)(heads_first(o))
 
 
-class MLAMixer(nn.Module):
+class MLAMixer(LayerKind, nn.Module):
     """Latent attention: keys and values are expanded from a latent of
     ``mla_kv_rank`` (no absorption: this is the training form); a head's
     query and key are ``mla_qk_nope_dim + mla_qk_rope_dim`` wide, the second
@@ -157,10 +167,14 @@ class MLAMixer(nn.Module):
     part is broadcast into every head's key, so the attention kernel sees one
     product of 192 beside values of 128, unpadded (``ops/pallas/flash_attention.py``)."""
 
-    cfg: TransformerConfig
+    cfg: TransformerFields
+    keeps, hybrid = (FLASH_SAVED, SAVED), True
+    # ``mla_rope``: the rotation of the shared key part (no key where the model has no positions)
+    paths = {"mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "mla_rope": ("mixer/rope", {})}
 
     @nn.compact
-    def __call__(self, x, positions=None):
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
         B, S, _ = x.shape
         H, dn, dr, dv = cfg.n_heads, cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim, cfg.mla_v_dim
@@ -195,7 +209,19 @@ class MLAMixer(nn.Module):
                                    param_dtype=f32)(o)
 
 
-class SparseMixer(nn.Module):
+def _sown(intermediates, name):
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates)
+            if any(getattr(k, "key", None) == name for k in path)]
+
+
+def _count_sparse(counts):
+    reg = get_registry()
+    reg.counter("sparse_keys_chosen_total").inc(float(counts[:, 0].sum()))
+    reg.counter("sparse_keys_visible_total").inc(float(counts[:, 1].sum()))
+    reg.gauge("sparse_index_loss").set(float(counts[:, 2].mean()))
+
+
+class SparseMixer(LayerKind, nn.Module):
     """Grouped-query attention whose keys a learned indexer chooses, a query at a time (the DeepSeek-Sparse-Attention
     family). With ``h`` the block's normed input, ``t`` a query and ``s <= t`` a key position:
 
@@ -216,10 +242,25 @@ class SparseMixer(nn.Module):
     beside the loss: ``sparse_keys``, (chosen, visible) pairs of the call (``ops/indexed_attention.py`` has the forms a
     backend takes), and ``choice``, the key-major mask."""
 
-    cfg: TransformerConfig
+    cfg: TransformerFields
+    # kept: the choice, its attention call's output and row statistics, the index loss's cotangent (the flash call's where
+    # every visible key is chosen); a model has this mixer in every layer or in none, so its key is given though ``alone``
+    sows, keeps, hybrid = ("intermediates",), (sparse.SAVED, FLASH_SAVED, SAVED), True
+    paths, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, True
+
+    @staticmethod
+    def report(intermediates):
+        """The sum over the sparse layers of their indexer's loss (None where none chose: a sequence no longer than
+        ``index_topk``); a layer's (chosen pairs, visible pairs, loss) leave the step as an output for the registry."""
+        losses, keys = _sown(intermediates, "index_loss"), _sown(intermediates, "sparse_keys")
+        if not losses:
+            return None
+        device_counts.report("sparse_keys", jnp.stack([jnp.concatenate([k, l[None]]) for k, l in zip(keys, losses)]), _count_sparse)
+        return sum(losses)
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
         B, S, _ = x.shape
         H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim

@@ -18,6 +18,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..ops.registry import pallas_available
+from ..telemetry import device_counts
+from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
 from .sharded_moe import SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
@@ -73,6 +76,16 @@ class MoE(nn.Module):
     d_ff: Optional[int] = None
     activation: str = "gelu"
     dtype: Any = jnp.float32
+    # its record as the layer kind ``moe`` (``models/layers.py::LayerKind``, which is not mixed in: ``models/`` imports
+    # this module for its table, so this one imports nothing of it and says every name)
+    sows, stackable = ("losses", "intermediates"), True
+    report, keeps, hybrid, paths, path_words, joined, alone = None, (), False, {}, {}, {}, False
+
+    @classmethod
+    def from_config(cls, cfg, kind):
+        return cls(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k,
+                   capacity_factor=cfg.moe_capacity_factor, min_capacity=cfg.moe_min_capacity, d_ff=cfg.ffn_dim,
+                   activation=cfg.activation, dtype=cfg.dtype, name="moe")
 
     @nn.compact
     def __call__(self, x, train: bool = True, rng=None):
@@ -120,14 +133,10 @@ class MoE(nn.Module):
 def moe_path() -> str:
     """How a routed layer's grouped products run: the Pallas grouped matmul on
     a TPU, ``lax.ragged_dot`` elsewhere."""
-    from ..ops.registry import pallas_available
-
     return "kernel" if pallas_available() else "xla"
 
 
 def _count_rows(rows):
-    from ..telemetry.registry import get_registry
-
     reg = get_registry()
     reg.counter("moe_rows_routed_here_total").inc(float(rows[:, 0].sum()))
     reg.counter("moe_rows_dropped_total").inc(float(rows[:, 1].sum()))
@@ -140,8 +149,6 @@ def report_rows(intermediates):
     """What the routed layers of a model sowed as ``rows`` in a forward pass,
     stacked and handed out of the step program for the registry
     (``telemetry/device_counts.py``: an output of the step, no host callback)."""
-    from ..telemetry import device_counts
-
     rows = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates)
             if any(getattr(k, "key", None) == "rows" for k in path)]
     if rows:
@@ -180,9 +187,23 @@ class RoutedMoE(nn.Module):
     scoring: str = "sigmoid"
     shared_gate: bool = False
     dtype: Any = jnp.float32
+    # its record as the layer kind ``routed`` (as ``MoE`` says its own). The line's keys: how the grouped products and the
+    # rows' sum were traced, the conditional's form where the buffer is smaller than every pair (``routed_part``), and how
+    # the router scores its tokens and indexes the expert axis (``compare_sum``: ``held_experts``)
+    sows, keeps, hybrid, alone, stackable = ("intermediates",), (SAVED,), True, False, False
+    paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
+    path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
+    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum"))}
+    report = staticmethod(report_rows)
+
+    @classmethod
+    def from_config(cls, cfg, kind):
+        return cls(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k, d_ff=cfg.moe_d_ff or cfg.ffn_dim,
+                   held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff, scale=cfg.moe_route_scale, scoring=cfg.moe_scoring,
+                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, name="routed")
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, train: bool = True):
         d, E = self.hidden_size, self.num_experts
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"a routed layer scores by sigmoid or softmax, got {self.scoring!r}")

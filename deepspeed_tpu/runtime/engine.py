@@ -20,6 +20,7 @@ compute in bf16/fp16, fp32 grad accumulation, dynamic loss scaling for
 fp16 with overflow-skip (``stage_1_and_2.py:1995``).
 """
 
+import collections
 import json
 import os
 import time
@@ -34,6 +35,7 @@ from .. import comm as dist
 from ..accelerator import get_accelerator
 from ..analysis import knobs
 from ..analysis.jit_audit import leaf_signature
+from ..models import transformer as layer_kinds
 from ..parallel.mesh import MeshTopology, get_mesh_topology, initialize_mesh
 from ..telemetry import MonitorBridge
 from ..telemetry import get_registry as get_telemetry_registry
@@ -84,22 +86,25 @@ def _all_finite(tree):
     return jnp.all(jnp.stack(leaves))
 
 
-# key on the trainer's first-call line -> the region whose choice it reports, as ``program_regions_traced_total`` labels
-# it (forward call sites, by ``path``); the line's word for any ``path`` but ``"xla"`` is ``kernel`` but for ``_PATH_WORDS``
-_PATHS = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), "gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"}),
-          "mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"}),
-          "mla_rope": ("mixer/rope", {}), "moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
-_PATH_WORDS = {"moe_cond": "fallback_keeps_nothing"}  # the one form ``routed_part``'s conditional has
-# ``path`` of ``ffn/router``: how a routed layer scores its tokens, and how its bookkeeping indexes the expert axis
-# (``compare_sum``: by comparison and a sum, ``moe/sharded_moe.py::held_experts``); the line's ``moe_router`` joins those that rose
-_ROUTER_WORDS = ("sigmoid", "softmax", "compare_sum")
+def _declared(what, records=None):
+    """What the kinds' records declare for the first-call line (``LayerKind.paths``, ``path_words`` or ``joined``) as one dict."""
+    return {key: value for record in (layer_kinds.records() if records is None else records) for key, value in getattr(record, what).items()}
 
 
-def _paths_traced():
-    """{key of ``_PATHS``: (call sites traced as a kernel, in XLA's form) so far}."""
-    xla = {key: int(regions_traced(name, path="xla", **labels)) for key, (name, labels) in _PATHS.items()}
-    traced = {key: (int(regions_traced(name, **labels)) - xla[key], xla[key]) for key, (name, labels) in _PATHS.items()}
-    return dict(traced, moe_router=tuple(int(regions_traced("ffn/router", path=word)) for word in _ROUTER_WORDS))
+def _paths_traced(records=None):
+    """{key of the records' ``paths``: (forward call sites traced as a kernel, in XLA's form) so far; key of their
+    ``joined``: the count of each of its words so far}."""
+    traced = {}
+    for key, (name, labels) in _declared("paths", records).items():
+        xla = int(regions_traced(name, path="xla", **labels))
+        traced[key] = (int(regions_traced(name, **labels)) - xla, xla)
+    for key, (name, words) in _declared("joined", records).items():
+        traced[key] = tuple(int(regions_traced(name, path=word)) for word in words)
+    return traced
+
+
+# of the table as imported: a key's word for its kernel form, and ``moe_router``'s words as ``_paths_traced`` counts them
+_PATH_WORDS, _ROUTER_WORDS = _declared("path_words"), _declared("joined")["moe_router"][1]
 
 
 def _program_text(program, args):
@@ -759,42 +764,33 @@ class DeepSpeedEngine:
         return loss
 
     def _layer_kind_notes(self, traced_before):
-        """For a model whose layers are of several kinds (``TransformerConfig.
-        kinds``): how many layers of each (mixer, ffn) pair, and how the
-        delta-rule scan, latent attention, the rotation of its shared key
-        part (``mla_rope``; no key where the model has no positions) and the
-        routed FFN's grouped products were traced into this program, by the counters that count
-        each choice where it is made: ``kernel`` (Pallas), ``xla`` (the
-        fallback), ``mixed``, or no key where the program has none (``gdn_path``:
-        the delta-rule scan with a decay a head; ``sparse_path``: attention over the keys an indexer chose, which a model
-        has in every layer or in none, so this key is given whatever the kinds); how a routed layer scores its
-        tokens and indexes the expert axis (``moe_router``: ``sigmoid`` or ``softmax``, ``+compare_sum``); and, where
-        a routed layer's buffer is smaller than every pair, which form its
-        conditional has (``moe_cond``: ``moe/sharded_moe.py::routed_part``). Whatever the kinds, under ``remat``:
-        what a checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
+        """For a model whose layers are of several kinds (``TransformerConfig.kinds``), or of one whose record says so
+        (``LayerKind.alone``): how many layers of each (mixer, ffn) pair, and the keys its kinds' records declare
+        (``LayerKind.paths``, ``joined``), by the counters that count each choice where it is made: ``kernel`` (Pallas),
+        ``xla`` (the fallback), ``mixed``, a word of the record's own, or no key where this program traced no such call
+        site; a joined key's word is the labels that rose, ``+`` between. Whatever the kinds, under ``remat``: what a
+        checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
         cfg = getattr(self.module, "cfg", None)
         kinds = getattr(cfg, "kinds", None)
         if not kinds:
             return {}
         notes = {}
         if cfg.remat:  # under ``scan_layers`` the blocks go through ``nn.remat(Block)``, which has no policy
-            from ..models.transformer import remat_keeps
-            names = () if cfg.scan_layers else sorted({name for kind in kinds for name in remat_keeps(kind)})
+            names = () if cfg.scan_layers else sorted({name for kind in kinds for name in layer_kinds.remat_keeps(kind)})
             notes["remat_keeps"] = "+".join(names) or "inputs"
-        if len(set(kinds)) == 1 and kinds[0][0] != "sparse":  # one kind of plain block: nothing was chosen
+        records = layer_kinds.records(kinds)
+        if len(set(kinds)) == 1 and not any(record.alone for record in records):  # one kind of plain block: nothing was chosen
             return notes
-        count = {}
-        for mixer, ffn in kinds:
-            count[f"{mixer}+{ffn}"] = count.get(f"{mixer}+{ffn}", 0) + 1
-        notes["layer_kinds"] = ",".join(f"{k}:{n}" for k, n in sorted(count.items()))
-        traced = _paths_traced()
-        router = [word for word, now, before in zip(_ROUTER_WORDS, traced.pop("moe_router"), traced_before["moe_router"]) if now > before]
-        for key, (kernel, xla) in traced.items():
-            kernel, xla = kernel - traced_before[key][0], xla - traced_before[key][1]
+        notes["layer_kinds"] = ",".join(f"{k}:{n}" for k, n in sorted(collections.Counter(f"{mixer}+{ffn}" for mixer, ffn in kinds).items()))
+        traced, words = _paths_traced(records), _declared("path_words", records)
+        for key in _declared("paths", records):
+            kernel, xla = (now - was for now, was in zip(traced[key], traced_before[key]))
             if kernel or xla:
-                notes[key] = "mixed" if kernel and xla else _PATH_WORDS.get(key, "kernel") if kernel else "xla"
-        if router:
-            notes["moe_router"] = "+".join(router)
+                notes[key] = "mixed" if kernel and xla else words.get(key, "kernel") if kernel else "xla"
+        for key, (_, labels) in _declared("joined", records).items():
+            rose = [label for label, now, was in zip(labels, traced[key], traced_before[key]) if now > was]
+            if rose:
+                notes[key] = "+".join(rose)
         return notes
 
     def _count_step_flops(self, program, args):

@@ -173,18 +173,3 @@ def _pallas_fused_adamw(learning_rate, b1, b2, eps, weight_decay) -> optax.Gradi
         return pick(0), {"count": count, "m": pick(1), "v": pick(2)}
 
     return optax.GradientTransformation(init, update)
-
-
-def set_learning_rate(opt_state, lr: float):
-    """Write the LR hyperparam into an inject_hyperparams state (in place pytree update)."""
-    import jax.numpy as jnp
-
-    if hasattr(opt_state, "hyperparams") and "learning_rate" in opt_state.hyperparams:
-        opt_state.hyperparams["learning_rate"] = jnp.asarray(lr, dtype=jnp.float32)
-    return opt_state
-
-
-def get_learning_rate(opt_state) -> float:
-    if hasattr(opt_state, "hyperparams") and "learning_rate" in opt_state.hyperparams:
-        return float(opt_state.hyperparams["learning_rate"])
-    return 0.0

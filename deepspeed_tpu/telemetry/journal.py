@@ -302,28 +302,6 @@ class Session:
                 return int(c.get("q", -1))
         return None
 
-    def commit_stats(self) -> List:
-        """Per-request (arrival, first-commit ts, last-commit ts, n_new)
-        derived from the recorded streams — the what-if baseline when the
-        end record carries no SLA summary."""
-        first: Dict[int, float] = {}
-        last: Dict[int, float] = {}
-        n: Dict[int, int] = {}
-        for c in self.commits:
-            uid, ts = int(c["uid"]), float(c.get("ts", 0.0))
-            first.setdefault(uid, ts)
-            last[uid] = ts
-            n[uid] = n.get(uid, 0) + len(c["tokens"])
-        rows = []
-        for uid in sorted(self.requests):
-            if uid not in first:
-                continue
-            rows.append({"uid": uid,
-                         "arrival": float(self.requests[uid].get("arrival_s", 0.0)),
-                         "first_token": first[uid], "done": last[uid],
-                         "n_new": n[uid]})
-        return rows
-
 
 def sessions_from_records(records: List[Dict]) -> List[Session]:
     out: List[Session] = []

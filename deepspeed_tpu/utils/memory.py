@@ -27,14 +27,3 @@ def see_memory_usage(message: str, force: bool = False, ranks=(0,)):
     except Exception:
         host = "host: n/a"
     logger.info(f"{message} | device allocated: {ga:.2f} GB | peak: {peak:.2f} GB | limit: {limit:.2f} GB | {host}")
-
-
-def get_memory_status() -> dict:
-    from ..accelerator import get_accelerator
-
-    acc = get_accelerator()
-    return {
-        "allocated_bytes": acc.memory_allocated(),
-        "peak_bytes": acc.max_memory_allocated(),
-        "limit_bytes": acc.total_memory(),
-    }

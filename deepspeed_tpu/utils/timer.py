@@ -196,15 +196,3 @@ class ThroughputTimer:
         if counted > 0 and self.total_elapsed_time > 0:
             return self.batch_size * counted / self.total_elapsed_time
         return 0.0
-
-
-def trim_mean(data: List[float], trim_percent: float) -> float:
-    """Mean after trimming ``trim_percent`` from both tails."""
-    if not data:
-        return 0.0
-    assert 0.0 <= trim_percent <= 1.0
-    n = len(data)
-    k = int(n * trim_percent)
-    s = sorted(data)
-    trimmed = s[k:n - k] if n - 2 * k > 0 else s
-    return sum(trimmed) / len(trimmed)

@@ -1,4 +1,4 @@
-"""Regression tests for the round-2 advisor findings (ADVICE.md)."""
+"""Regression tests for the round-2 advisor findings."""
 
 import jax
 import jax.numpy as jnp

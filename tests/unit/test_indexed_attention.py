@@ -266,8 +266,8 @@ def test_the_kernels_path_is_the_xla_path_through_the_whole_model_under_remat(hi
 def test_a_checkpointed_sparse_block_keeps_its_names():
     from deepspeed_tpu.models.transformer import remat_keeps
 
-    assert remat_keeps(("sparse", "dense")) == ("kda_scan", "routed_ffn", "flash_attention", "projection", "sparse_attention")
-    assert remat_keeps(("gdn", "routed")) == remat_keeps(("full", "routed")) == ("kda_scan", "routed_ffn", "flash_attention", "projection")
+    assert remat_keeps(("sparse", "dense")) == ("sparse_attention", "flash_attention", "projection")
+    assert remat_keeps(("gdn", "routed")) == ("kda_scan", "projection", "routed_ffn") and remat_keeps(("full", "routed")) == ("flash_attention", "projection", "routed_ffn")
     assert remat_keeps(("full", "dense")) == ()
 
 

@@ -162,6 +162,6 @@ def test_the_first_call_line_says_inputs_where_the_layers_are_scanned():
 
     notes = lambda **over: DeepSpeedEngine._layer_kind_notes(
         types.SimpleNamespace(module=types.SimpleNamespace(cfg=tiny(n_layers=2, layer_kinds=(("mla", "dense"),) * 2, **over))), None)
-    assert notes(remat=True) == {"remat_keeps": "flash_attention+kda_scan+projection+routed_ffn"}
+    assert notes(remat=True) == {"remat_keeps": "flash_attention+projection"}
     assert notes(remat=True, scan_layers=True) == {"remat_keeps": "inputs"}
     assert notes() == {}
