@@ -11,6 +11,7 @@
     python chip_smoke.py --only latent   # ... and its third's: rotated latent attention in every layer, 6 of 64 experts
     python chip_smoke.py --only deltanet  # ... and its fourth's: Gated DeltaNet 3:1 with gated GQA, softmax top-10 of 512
     python chip_smoke.py --only sparse   # ... and its fifth's: GQA over the keys a learned indexer chooses, 8 of 128 experts
+    python chip_smoke.py --only sambay   # ... and its sixth's: Mamba-1 scans, differential attention, a second half that reads the first's
 
 Everything runs in this one process (a chip belongs to one process), at the
 full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
@@ -946,6 +947,60 @@ def sparse_phase():
     return dict(facts, compared=report, control_failed=failed_controls)
 
 
+# Phi-4-mini-flash-reasoning's six layers (``--only sambay``): two Mamba-1 scan layers, differential attention under a
+# window and full, a gated memory unit and differential cross-attention, which read the scan's output and the full layer's
+# keys and values. EVERY leaf of the gradient is read (90: a leaf's name is its path) and 78 are judged, a limit a class of
+# leaf (its path without the layer: ``SAMBAY_CLASS_LIMITS``, else ``SAMBAY_LEAF_LIMIT``). NOT judged, only reported (a limit
+# of None): the twelve lambda vectors. A layer's four are ONE scalar, dL/dlambda, times fixed vectors; at a random start the
+# two maps are nearly equal and that scalar is a sum over 8,192 tokens x 20 heads x 128 that cancels to near zero, so its
+# relative error reads the cancellation and not the arithmetic: 0.002 to 0.61 for the program over five seeds, 0.0008 to
+# 0.57 for the bf16-state control (the CPU tests hold these leaves in float32, where nothing cancels into rounding).
+# Five controls, each the plain bf16 reference with one thing wrong, and each has to break a limit on every seed: the
+# window layer attending every earlier key (``no_window``), lambda = 0 (``no_lambda``), the memory taken after the ``z``
+# gate (``gated_memory``), a cross layer attending layer 17's projections of its OWN input (``own_keys``): each of these
+# four reads 0.08 to 1.1 in the logits and in 77 or more of the 78 leaves; and the scan's state, step and decay in bf16
+# (``low_state``), the precision below the one the configuration states, which moves the scan's own small leaves and
+# nothing else: ``A_log`` 0.131 to 0.797 where the program reads 0.037 to 0.047, so its limit lies between the two with
+# 1.6 times of room on either side and is the limit ``low_state`` breaks on every seed (it also breaks the general limit in
+# ``x_proj``, ``dt_proj`` or ``dt_bias`` of one or both scan layers on every seed read). Limits from four seeds (0, 11,
+# 101, 2024; my chip runs, PR 46: published widths, 6 layers, 1 x 8192), checked on a fifth: the program's 78 judged
+# leaves read 0.0026 to 0.0519 (most 0.034 to 0.048: bf16's rounding through six layers; the final norm's bias 0.003)
+# and the logits 0.0259 to 0.0270; the general limit holds the largest with a quarter of room.
+SAMBAY_PARTS = {"ssm": ("in_proj", "conv_kernel", "conv_bias", "x_proj", "dt_proj", "dt_bias", "A_log", "D", "out_proj"),
+                "diff": ("q_proj", "k_proj", "v_proj", "o_proj", "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "subln"),
+                "gmu": ("in_proj", "out_proj"), "diff_cross": ("q_proj", "o_proj", "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "subln")}
+SAMBAY_PARTS["diff_window"] = SAMBAY_PARTS["diff"]
+SAMBAY_MIXERS = ("ssm", "diff_window", "ssm", "diff", "gmu", "diff_cross")
+SAMBAY_LEAF_LIMIT = 0.065    # the program's readings | the four structural controls' smallest | ``low_state``'s
+SAMBAY_CLASS_LIMITS = {
+    "logits": 0.04,          # 0.0259-0.0270 | own_keys 0.081, gated_memory 0.152, no_lambda 0.385, no_window 0.862 | 0.0254-0.0265
+    "ssm/A_log": 0.08,       # 0.0371-0.0467 | 0.124-0.99 | 0.1308-0.7969
+}
+
+
+def _sambay_names():
+    names = ["wte", "LayerNorm_0/scale", "LayerNorm_0/bias"]
+    for i, mixer in enumerate(SAMBAY_MIXERS):
+        names += [f"layer_{i}/{norm}/{leaf}" for norm in ("LayerNorm_0", "LayerNorm_1") for leaf in ("scale", "bias")]
+        names += [f"layer_{i}/{mixer}/{leaf}" for leaf in SAMBAY_PARTS[mixer]] + [f"layer_{i}/mlp/{leaf}" for leaf in ("gate_proj", "up_proj", "down_proj")]
+    return names
+
+
+def _sambay_class(name):
+    return name.split("/", 1)[1] if name.startswith("layer_") else name
+
+
+SAMBAY_LIMITS = {"logits": SAMBAY_CLASS_LIMITS["logits"],
+                 **{name: None if "/lambda_" in name else SAMBAY_CLASS_LIMITS.get(_sambay_class(name), SAMBAY_LEAF_LIMIT) for name in _sambay_names()}}
+
+
+def _sambay_leaf(tree, name):
+    leaf = functools.reduce(lambda node, key: node[key], name.split("/"), tree)
+    if isinstance(leaf, dict):  # a product's kernel, or the sub-norm's scale
+        leaf = leaf["kernel"] if "kernel" in leaf else leaf["scale"]
+    return leaf.astype(jnp.float32)
+
+
 # a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, and its controls
 # (a name and what is wrong with the plain bf16 reference under it)
 SMOKE_MODELS = {
@@ -954,6 +1009,9 @@ SMOKE_MODELS = {
                {"no_rope": {"no_rope": True}, "low_state": {"low_state": True}}),
     "deltanet": ("benchmarks/configs/qwen3-next-80b-l4e32.json", DELTANET_LIMITS, _deltanet_leaf,
                  {"no_decay": {"no_decay_layer": 2}, "no_gate": {"no_output_gate": True}, "low_state": {"low_state": True}}),
+    "sambay": ("benchmarks/configs/phi4-mini-flash-l6.json", SAMBAY_LIMITS, _sambay_leaf,
+               {"no_window": {"no_window": True}, "no_lambda": {"no_lambda": True}, "gated_memory": {"gated_memory": True},
+                "own_keys": {"own_keys": True}, "low_state": {"low_state": True}}),
 }
 
 
@@ -982,8 +1040,8 @@ def f32_readings(which, seed):
 
     rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32)) / jnp.maximum(jnp.linalg.norm(b.astype(jnp.float32)), 1e-30))
 
-    def leaves_of(grads):  # the leaves compared, and nothing else of a 2.4 GB tree
-        return {name: leaf_of(grads, name) for name in judged}
+    def leaves_of(grads):  # the leaves compared, and nothing else of a 2.4 GB tree; on the host, where every leaf is judged
+        return {name: np.asarray(leaf_of(grads, name)) for name in judged}
 
     def plain(dtype, **over):
         rc = dict(cfg["reference"], **over)
@@ -1015,7 +1073,7 @@ def f32_phase(which):
     readings, seq = f32_readings(which, ARGS.seed)
     limits, controls = SMOKE_MODELS[which][1], SMOKE_MODELS[which][3]
     report = {name: dict(readings[name], limit=limit) for name, limit in limits.items()}
-    over = lambda who: [k for k, v in report.items() if not v[who] <= v["limit"]]
+    over = lambda who: [k for k, v in report.items() if v["limit"] is not None and not v[who] <= v["limit"]]  # None: read, not judged
     failed_ours, failed_controls = over("ours"), {name: over(name) for name in controls}
     if not REHEARSE:  # the limits are the published widths': at a tiny width bf16 flips routes and proves nothing
         check(not failed_ours, f"the {which} model lies further from its float32 reference than allowed in {failed_ours}: {report}")

@@ -136,6 +136,9 @@ class InferenceEngineV2:
                 f"inference/v2 serves softmax attention over one head size with dense or capacity-gated MoE FFNs; this "
                 f"model has layers of kind {list(cfg.unstackable)}: a recurrent state beside the paged KV, a latent cache, a "
                 f"choice of keys and a routed FFN's gate are training-side only")
+        if cfg.shares:  # (``LayerKind.gives``, ``takes``)
+            raise NotImplementedError(f"inference/v2's stacked layers carry activations alone; this model's layers give or take "
+                                      f"{list(cfg.shares)} between blocks: training-side only")
         if cfg.attn_output_gate:
             raise NotImplementedError("inference/v2 has no output gate on its attention (attn_output_gate): training-side only")
         self.cfg = cfg

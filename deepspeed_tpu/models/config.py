@@ -117,6 +117,15 @@ class TransformerFields:
     # ``moe_route_scale``
     moe_scoring: str = "sigmoid"
     moe_shared_gate: bool = False  # routed: the shared expert's output is multiplied by sigmoid(x w_s), w_s (d_model, 1)
+    # ssm (a Mamba-1 layer) and gmu (a gate on a scan's output): ``ssm_inner`` channels, each with a state of ``ssm_state``
+    # columns; a depthwise causal convolution of ``ssm_conv`` with a bias; the step through a product of ``ssm_dt_rank``
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    # where the stack is a cut of a deeper model: the published index of each layer, which a kind that takes ``layer`` reads
+    # (differential attention's lambda starts from it); None: 0 .. n_layers - 1
+    layer_numbers: Optional[Tuple[int, ...]] = None
 
     @property
     def kv_heads(self) -> int:

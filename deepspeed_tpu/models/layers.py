@@ -41,6 +41,11 @@ class LayerKind:
     # ``alone``: a model whose layers are all of one kind says nothing of kinds on that line, unless this
     paths, path_words, joined, alone = {}, {}, {}, False
     stackable = False  # the scan over layers, ``to_pipeline`` and ``inference/v2`` can run it
+    # values between blocks, by name (a mixer's: no FFN has any). ``gives``: its call returns ``(out, {name: value})`` and
+    # the model's loop over layers carries each value on; ``takes``: it is called with ``name=value`` of the nearest
+    # earlier layer that gave the name (``layer``, the layer's own published index as an int32 scalar, is the model's to
+    # give). The gradient flows back through them. A model with either is run by the unrolled loop alone
+    gives, takes = (), ()
 
     @classmethod
     def from_config(cls, cfg, kind: str):

@@ -2,11 +2,18 @@
 delta-rule linear-attention layers with a decay a channel (KDA) or a head
 (Gated DeltaNet), latent attention (MLA), without positions or with its
 shared key part rotated, and grouped-query attention over the keys a learned
-indexer chooses for each query (``SparseMixer``). All are training-side
+indexer chooses for each query (``SparseMixer``); and the four of a
+decoder-hybrid-decoder stack (SambaY): a Mamba-1 selective scan (``SSMMixer``),
+differential attention (``DiffAttention``), and the second half's two, which
+read what the first half made instead of making their own: a gate on the scan's
+output (``GatedMemory``) and differential attention over the first half's keys
+and values (``DiffCrossAttention``). All are training-side
 modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
 ``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
 carries its kind's record (``layers.py::LayerKind``).
 """
+
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -18,6 +25,7 @@ from ..ops import indexed_attention as sparse
 from ..ops.attention import attention
 from ..ops.kda import SAVED as SCAN_SAVED, gdn, kda
 from ..ops.registry import pallas_available
+from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
@@ -304,3 +312,146 @@ class SparseMixer(LayerKind, nn.Module):
             init = nn.initializers.variance_scaling(cfg.sparse_out_init_scale**2, "fan_in", "truncated_normal")  # 1: flax's own
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=f32, kernel_init=init)(out)
+
+
+def _s4d_init(key, shape, dtype=jnp.float32):
+    """log(1 .. N) for every channel (S4D-real): the state's columns decay at rates 1 to N times the step."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
+
+
+class SSMMixer(LayerKind, nn.Module):
+    """A Mamba-1 layer. ``[u, z] = x W_in``; ``u = silu(causal_conv(u) + b_c)``; ``[r, B, C] = u W_x`` (``ssm_dt_rank`` +
+    2 ``ssm_state`` wide); ``delta = softplus(r W_dt + b_dt)`` in float32; ``A = -exp(A_log)``; per channel ``d``, from a
+    zero state, ``h_t = exp(delta_t[d] A[d]) h_{t-1} + delta_t[d] u_t[d] B_t`` and ``y_t[d] = h_t . C_t + D[d] u_t[d]``
+    (``ops/ssm.py``); ``out = (y * silu(z)) W_out``. It hands on ``y`` (the scan's output with the ``D`` term, before the
+    ``z`` gate) as ``scan_out``: a later ``gmu`` layer gates it instead of scanning."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (SSM_SAVED, SAVED), True
+    paths = {"ssm_path": ("mixer/kernel", {"op": "ssm", "pass": "fwd"})}
+    gives = ("scan_out",)
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        inner, N, K, rank, f32 = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_dt_rank, jnp.float32
+        # named (``SAVED``: what a checkpointed block keeps): the three products' results; the convolution, the SiLUs and
+        # the softplus follow from them by elementwise work
+        dense = lambda feats, name, of: checkpoint_name(
+            nn.Dense(feats, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)(of), SAVED)
+        with region("mixer/proj"):
+            uz = dense(2 * inner, "in_proj", x)
+            u, z = uz[..., :inner], uz[..., inner:]
+        with region("mixer/conv"):
+            w = self.param("conv_kernel", _uniform(-K**-0.5, K**-0.5), (K, inner), f32)
+            bias = self.param("conv_bias", nn.initializers.zeros, (inner,), f32)
+            u = nn.silu(causal_conv(u, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
+        with region("mixer/proj"):
+            r_b_c = dense(rank + 2 * N, "x_proj", u)
+            r, B, C = r_b_c[..., :rank], r_b_c[..., rank:rank + N], r_b_c[..., rank + N:]
+            dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32)
+            delta = jax.nn.softplus(dense(inner, "dt_proj", r).astype(f32) + dt_bias)
+            A = -jnp.exp(self.param("A_log", _s4d_init, (inner, N), f32))
+            D = self.param("D", nn.initializers.ones, (inner,), f32)
+        y = selective_scan(u, delta, A, B, C, D)
+        with region("mixer/proj"):
+            out = nn.Dense(cfg.d_model, use_bias=False, name="out_proj", dtype=cfg.dtype, param_dtype=f32)(y * nn.silu(z))
+        return out, {"scan_out": y}
+
+
+# both kinds of differential attention count their layer under one key of the trainer's first-call line
+_DIFF_PATHS = {"diff_path": ("mixer/kernel", {"op": "diff", "pass": "fwd"})}
+
+
+def _differential(mod, q, k, v, layer, window):
+    """Differential attention's two maps and what follows them, for ``mod`` (whose parameters the lambdas, the sub-norm and
+    ``o_proj`` are): q (B, S, H, D) is H / 2 pairs (the first half of the heads with the second), k (B, S, KVH, D) KVH / 2
+    pairs likewise, v (B, S, KVH / 2, 2 D). ``A_j = softmax(q_j k_j^T / sqrt(D)) v`` under the causal mask (and the
+    ``window``); ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + l0``, ``l0 = 0.8 - 0.6 exp(-0.3 layer)`` with ``layer`` the
+    layer's published index; ``O = RMSNorm_2D(A_1 - lambda A_2) (1 - l0)``; ``out = concat(O) W_o``. Two calls of the one
+    attention op (on a TPU the flash kernel at D beside 2 D, grouped): the difference, its norm and the lambdas are
+    elementwise work after them, in float32."""
+    cfg = mod.cfg
+    H, KVH, D, f32 = cfg.n_heads, cfg.kv_heads, cfg.head_dim, jnp.float32
+    with region("mixer/kernel", op="diff", path="kernel" if pallas_available() else "xla", **{"pass": "fwd"}):
+        pass  # counted a layer; the two calls count themselves where they choose (``ops/attention.py``)
+    maps = [attention(q[:, :, half], k[:, :, kv_half], v, causal=True, window=window, scale=D**-0.5)
+            for half, kv_half in ((slice(0, H // 2), slice(0, KVH // 2)), (slice(H // 2, H), slice(KVH // 2, KVH)))]
+    with region("mixer/diff"):
+        lq1, lk1, lq2, lk2 = (mod.param(name, nn.initializers.normal(0.1), (D,), f32)
+                              for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+        l0 = 0.8 - 0.6 * jnp.exp(-0.3 * layer.astype(f32))
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + l0
+        o = maps[0].astype(f32) - lam * maps[1].astype(f32)
+        o = (RMSNorm(eps=cfg.norm_eps, dtype=f32, name="subln")(o) * (1.0 - l0)).astype(cfg.dtype)
+    with region("mixer/proj"):
+        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype, param_dtype=f32)(o)
+
+
+class DiffAttention(LayerKind, nn.Module):
+    """Differential attention (``_differential``) over the layer's own keys and values, under ``sliding_window`` (the kind
+    ``diff_window``) or none (``diff``): ``n_heads`` query heads and ``n_kv_heads`` key heads of ``head_dim``, values as
+    ``n_kv_heads / 2`` heads twice as wide; no positions, no biases. It hands its keys and values on (``shared_k``,
+    ``shared_v``: a later ``diff_cross`` layer attends the nearest earlier giver's) and takes its own published index."""
+
+    cfg: TransformerFields
+    window: Optional[int] = None
+    keeps, hybrid = (FLASH_SAVED, SAVED), True
+    paths = _DIFF_PATHS
+    gives, takes = ("shared_k", "shared_v"), ("layer",)
+
+    @classmethod
+    def from_config(cls, cfg, kind):
+        return cls(cfg, window=cfg.sliding_window if kind == "diff_window" else None, name=kind)
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None, layer=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        heads = lambda feats, name: checkpoint_name(
+            nn.DenseGeneral(feats, axis=-1, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=jnp.float32)(x), SAVED)
+        with region("mixer/proj"):
+            q, k, v = heads((H, D), "q_proj"), heads((KVH, D), "k_proj"), heads((KVH // 2, 2 * D), "v_proj")
+        return _differential(self, q, k, v, layer, self.window), {"shared_k": k, "shared_v": v}
+
+
+class DiffCrossAttention(LayerKind, nn.Module):
+    """Differential attention whose keys and values are an earlier layer's (``shared_k``, ``shared_v``): its own query
+    projection, lambdas, sub-norm and ``o_proj``; full causal."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (FLASH_SAVED, SAVED), True
+    paths = _DIFF_PATHS
+    takes = ("shared_k", "shared_v", "layer")
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None, shared_k=None, shared_v=None, layer=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        with region("mixer/proj"):
+            q = checkpoint_name(nn.DenseGeneral((cfg.n_heads, cfg.head_dim), axis=-1, use_bias=False, name="q_proj", dtype=cfg.dtype,
+                                                param_dtype=jnp.float32)(x), SAVED)
+        return _differential(self, q, shared_k, shared_v, layer, None)
+
+
+class GatedMemory(LayerKind, nn.Module):
+    """A gated memory unit: ``out = (M * silu(x W_g)) W_out`` with ``M`` an earlier layer's scan output (``scan_out``): no
+    scan, no convolution and no state of its own."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (SAVED,), True
+    takes = ("scan_out",)
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None, scan_out=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=jnp.float32)
+        with region("mixer/proj"):
+            gate = checkpoint_name(dense(cfg.ssm_inner, "in_proj")(x), SAVED)
+        with region("mixer/memory"):
+            gated = scan_out * nn.silu(gate)
+        with region("mixer/proj"):
+            return dense(cfg.d_model, "out_proj")(gated)

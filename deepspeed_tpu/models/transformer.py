@@ -32,13 +32,14 @@ from .config import TransformerFields
 # (much of what moved below this module is imported from here all the same)
 from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, _norm, _rope_table, alibi_slopes, apply_rope,  # noqa: F401
                      make_norm, rope_frequencies, scaled_rope_frequencies)
-from .mixers import GDNMixer, KDAMixer, MLAMixer, SparseMixer
+from .mixers import DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, SparseMixer, SSMMixer
 
 # THE table of layer kinds. A kind is declared once: its flax module carries its record (``layers.py::LayerKind``) and has
 # one line here; ``Block``, ``block_fn``, ``CausalLM.loss_fn``, ``runtime/engine.py`` and ``inference/v2/engine_v2.py`` read
 # the record and name no kind. Adding a kind: its module, one line here, its files under ``benchmarks/configs/``. Imports
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module
 MIXERS = {"full": Attention, "window": Attention, "kda": KDAMixer, "gdn": GDNMixer, "mla": MLAMixer, "sparse": SparseMixer}
+MIXERS |= {"ssm": SSMMixer, "diff": DiffAttention, "diff_window": DiffAttention, "gmu": GatedMemory, "diff_cross": DiffCrossAttention}
 FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE}
 
 
@@ -113,6 +114,12 @@ class TransformerConfig(TransformerFields):
         return tuple(sorted({name for pair in self.kinds for name, table in zip(pair, (MIXERS, FFNS)) if not table[name].stackable}))
 
     @property
+    def shares(self) -> Tuple[str, ...]:
+        """The names of the values this model's layers give to or take from one another (``LayerKind.gives``, ``takes``):
+        the unrolled loop over layers carries them beside the activations; the stacked forms carry activations alone."""
+        return tuple(sorted({name for mixer, _ in self.kinds for name in MIXERS[mixer].gives + MIXERS[mixer].takes}))
+
+    @property
     def sows(self) -> bool:
         """Whether a block of this model may sow (an expert layer's auxiliary loss and rows, a sparse mixer's index
         loss and key counts): its loss is then traced with those collections mutable."""
@@ -151,15 +158,23 @@ class Block(nn.Module):
         return FFNS[self.kind[1]].from_config(cfg, self.kind[1])(h, self.is_training)
 
     @nn.compact
-    def __call__(self, x, positions, kv_cache=None, segment_ids=None):
+    def __call__(self, x, positions, kv_cache=None, segment_ids=None, taken=None):
+        """``taken``: the values the mixer's record says it ``takes``, by name; a mixer that ``gives`` makes the result
+        ``(x, {name: value})``."""
         cfg = self.cfg
         # the layer's two parts are built by their kinds' records (``LayerKind.from_config``), under their names in the tree
-        attn = MIXERS[self.kind[0]].from_config(cfg, self.kind[0])
+        mixer = MIXERS[self.kind[0]]
+        attn = mixer.from_config(cfg, self.kind[0])
+        given = {}
 
         def run_attn(h):
             if kv_cache is not None:
                 return attn(h, positions, kv_cache, segment_ids)
-            return attn(h, positions, None, segment_ids), None
+            out = attn(h, positions, None, segment_ids, **{name: (taken or {})[name] for name in mixer.takes})
+            if mixer.gives:
+                out, values = out
+                given.update(values)
+            return out, None
 
         if cfg.block_type == "parallel_shared":  # falcon-7b / phi / gpt-j
             h = _norm(cfg, x)
@@ -178,7 +193,29 @@ class Block(nn.Module):
             # make the mixer's output projection again to get it back
             x = checkpoint_name(x + a, SAVED)
             x = x + self._mlp(cfg, _norm(cfg, x))
-        return (x, new_cache) if kv_cache is not None else x
+        if kv_cache is not None:
+            return x, new_cache
+        return (x, given) if mixer.gives else x
+
+
+def _parts(out, cached: bool, kind: Tuple[str, str]):
+    """What a ``Block`` of ``kind`` returned, as ((activations, new cache or None), the values its mixer gave by name)."""
+    if cached:
+        return out, {}
+    return ((out[0], None), out[1]) if MIXERS[kind[0]].gives else ((out, None), {})
+
+
+def _taken(cfg: TransformerConfig, i: int, shared: Dict) -> Dict:
+    """What layer ``i``'s mixer takes (``LayerKind.takes``), by name: the model's own ``layer`` (the layer's published
+    index, an int32 scalar: a value, so that layers of one kind share one trace) and what earlier layers gave."""
+    takes = MIXERS[cfg.kinds[i][0]].takes
+    have = dict(shared)
+    if "layer" in takes:
+        have["layer"] = jnp.asarray(i if cfg.layer_numbers is None else cfg.layer_numbers[i], jnp.int32)
+    missing = [name for name in takes if name not in have]
+    if missing:
+        raise ValueError(f"layer {i} ({cfg.kinds[i][0]}) takes {', '.join(missing)}, which no earlier layer gives")
+    return {name: have[name] for name in takes}
 
 
 class Transformer(nn.Module):
@@ -197,6 +234,9 @@ class Transformer(nn.Module):
             raise ValueError("progressive layer drop needs the unrolled layer loop: set scan_layers=False")
         if cfg.scan_layers and len({mixer for mixer, _ in cfg.kinds}) > 1:
             raise ValueError("per-layer window_layers (layers of several mixers) needs heterogeneous blocks: set scan_layers=False")
+        if cfg.scan_layers and cfg.shares:
+            raise ValueError(f"layers that give or take values between blocks ({', '.join(cfg.shares)}) need the unrolled loop over "
+                             f"layers, which carries them: set scan_layers=False")
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -231,20 +271,23 @@ class Transformer(nn.Module):
                                                         for i in range(cfg.n_layers)]
             paths = [self.path + (f"layer_{i}",) for i in range(cfg.n_layers)]
             may_sow = [kinds_sow((kind,)) for kind in cfg.kinds]
+            shared = {}  # the values layers have given so far, by name: a later giver of a name replaces an earlier one's
             for i in range(cfg.n_layers):
                 kind = cfg.kinds[i]
                 kv_cache = kv_caches[i] if kv_caches is not None else None
+                taken = {} if kv_caches is not None else _taken(cfg, i, shared)
                 if self.is_initializing():  # makes the tree: flax has to see every layer as a submodule
-                    y = Block(cfg, kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids)
-                    y, cache = y if kv_caches is not None else (y, None)
+                    out = Block(cfg, kind, is_training=train, name=f"layer_{i}")(x, positions, kv_cache, segment_ids, taken)
+                    (y, cache), given = _parts(out, kv_cache is not None, kind)
                 else:
                     wrap = None
                     if hook is not None:
                         wrap, x = hook(paths, layers, may_sow, i, x)
-                    (y, cache), sown = kinds(kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids)
+                    (y, cache), sown, given = kinds(kind, wrap=wrap)(layers[i], x, positions, kv_cache, segment_ids, taken)
                     for col, tree in sown.items():  # what the block sowed (MoE auxiliary loss), where it was
                         if self.is_mutable_collection(col):
                             self.put_variable(col, f"layer_{i}", tree)
+                shared.update(given)
                 if kv_caches is not None:
                     new_caches.append(cache)
                 elif pld_theta is not None and train:
@@ -342,8 +385,11 @@ def block_hook(hook):
 
 def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: bool, wrap=None):
     """One kind of block as ONE traced function of (the layer's parameters,
-    activations, positions, its KV cache, segment ids) ->
-    ((activations, new cache), what the block sowed).
+    activations, positions, its KV cache, segment ids, the values its mixer
+    takes by name) -> ((activations, new cache), what the block sowed, the
+    values its mixer gives by name). The two dicts are empty for a kind whose
+    record names none, and an empty dict is no argument and no result of the
+    traced function: such a kind's equations are what they were without them.
 
     ``jax.jit`` keys its trace on the abstract arguments, so every layer of
     the kind after the first reuses the jaxpr: the block's Python body (flax
@@ -367,12 +413,13 @@ def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: 
     it is traced once a kind all the same."""
     block = Block(cfg, kind, is_training=train)
 
-    def apply(params, x, positions, kv_cache, segment_ids):
+    def apply(params, x, positions, kv_cache, segment_ids, taken):
         # the Python body runs once a trace, not once a call: its count is the kinds of block a program traced. What a
         # block does outside its parts (the residual adds) falls to this region
         with region("block", site="train"):
-            out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, mutable=_SOWN)
-        return (out if kv_cache is not None else (out, None)), sown
+            out, sown = block.apply({"params": params}, x, positions, kv_cache, segment_ids, taken, mutable=_SOWN)
+        out, given = _parts(out, kv_cache is not None, kind)
+        return out, sown, given
 
     fn = wrap(apply) if wrap is not None else apply
     if remat:
@@ -501,6 +548,9 @@ class CausalLM:
         if cfg.unstackable:
             raise NotImplementedError(f"layers of kind {', '.join(cfg.unstackable)} are not pipeline-partitionable yet: the stages' "
                                       f"stacking takes softmax attention and dense or capacity-gated MoE blocks")
+        if cfg.shares:
+            raise NotImplementedError(f"layers give or take {', '.join(cfg.shares)} between blocks, which the stages' stacking does "
+                                      f"not carry: a stage is handed its activations alone")
         if cfg.mlm_head or cfg.type_vocab_size > 0:
             raise NotImplementedError("BERT-style models (mlm_head / token-type embeddings) are not "
                                       "pipeline-partitionable (the MLM head and segment embeddings are "

@@ -318,29 +318,34 @@ def _whole(plan: GatherPlan, params, specs):
 
 
 def gathered_block(plan: GatherPlan, layer_specs, keep: bool, apply):
-    """``apply(params, x, positions, kv_cache, segment_ids)`` of one block
-    (``models/transformer.py::block_fn``) as every device's own program on
-    its rows of the batch, the parameters gathered at its top, and again in
-    its backward unless it is to ``keep`` them."""
+    """``apply(params, x, positions, kv_cache, segment_ids, taken)`` of one
+    block (``models/transformer.py::block_fn``) as every device's own program
+    on its rows of the batch, the parameters gathered at its top, and again
+    in its backward unless it is to ``keep`` them. The values a block takes
+    from or gives to other blocks pass through by their rows too (a scalar
+    whole): none, for a kind whose record names none."""
     rows = P(plan.axis)
     dims = jax.tree_util.tree_map(lambda spec: _sharded_dim(spec, plan.axis), layer_specs, is_leaf=lambda s: isinstance(s, P))
     # once a KIND of block and program (``block_fn``): one a sharded leaf
     get_registry().counter("train_bucket_rings_traced_total").inc(len(jax.tree_util.tree_leaves(dims)))
 
-    def body(params, x, positions, segment_ids):
-        (y, _), _ = apply(params, x, positions, None, segment_ids)
-        return y
+    def body(params, x, positions, segment_ids, taken):
+        (y, _), _, given = apply(params, x, positions, None, segment_ids, taken)
+        return y, given
 
     if keep:
         local = lambda params, *rest: body(_whole(plan, params, layer_specs), *rest)
     else:
         local = _regathering(body, dims, plan.axis, plan.size)
-    mapped = jax.shard_map(local, mesh=plan.mesh, in_specs=(layer_specs, rows, rows, rows), out_specs=rows, check_vma=False)
 
-    def call(params, x, positions, kv_cache, segment_ids):  # a plan is a training matter: there is no cache
+    def call(params, x, positions, kv_cache, segment_ids, taken):  # a plan is a training matter: there is no cache
+        by_rows = jax.tree_util.tree_map(lambda value: rows if jnp.ndim(value) else P(), taken)
+        mapped = jax.shard_map(local, mesh=plan.mesh, in_specs=(layer_specs, rows, rows, rows, by_rows), out_specs=(rows, rows),
+                               check_vma=False)
         # what the manual region adds itself, outside the block's parts: ``shard_map``'s sum of the whole leaves' gradients
         with region("zero/reduce"):
-            return (mapped(params, x, positions, segment_ids), None), {}
+            y, given = mapped(params, x, positions, segment_ids, taken)
+        return (y, None), {}, given
 
     return call
 

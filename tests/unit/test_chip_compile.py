@@ -167,6 +167,33 @@ def _gdn(shape):
     return fwd_bwd, (qk, qk, v, gate, gate, v), 2
 
 
+def _ssm(shape):
+    """The selective scan, forward and backward: 2 kernels (channels along the lanes, the whole state in VMEM)."""
+    from deepspeed_tpu.ops.ssm import ssm_chunked
+
+    B, Sq, channels, N = shape
+
+    def fwd_bwd(u, delta, A, Bm, Cm, D, dy):
+        y, vjp = jax.vjp(ssm_chunked, u, delta, A, Bm, Cm, D)
+        return (y,) + vjp(dy)
+
+    x, cols = S((B, Sq, channels), BF16), S((B, Sq, N), BF16)
+    return fwd_bwd, (x, S((B, Sq, channels), F32), S((channels, N), F32), cols, cols, S((channels,), F32), x), 2
+
+
+def _flash_diff(shape, window):
+    """One of differential attention's two calls: keys of 64 beside values of 128, grouped, under a window or none."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, Sq, Hq, KVH, Dk, Dv = shape
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, window=window, scale=Dk ** -0.5), q, k, v)
+        return (o,) + vjp(do)
+
+    return fwd_bwd, (S((B, Sq, Hq, Dk), BF16), S((B, Sq, KVH, Dk), BF16), S((B, Sq, KVH, Dv), BF16), S((B, Sq, Hq, Dv), BF16)), 2, "fused"
+
+
 def _moe_sum_rows(shape):
     """A routed layer's tokens sum their own rows off the expert-sorted buffer, each row times its weight: the combine,
     and with weights of one the backward of the rows' gather."""
@@ -209,6 +236,10 @@ CASES = {
     "kda_scan_b2_h4_s1000_d128": lambda: _kda((2, 4, 1000, 128)),                          # a length that is padded to chunks
     "gdn_scan_b1_h16_v32_s8192_d128": lambda: _gdn((1, 16, 32, 8192, 128)),                 # qwen3-next-80b-l4e32's DeltaNet layers
     "gdn_scan_b2_h2_v4_s1000_d128": lambda: _gdn((2, 2, 4, 1000, 128)),
+    "ssm_scan_b1_s8192_c5120_n16": lambda: _ssm((1, 8192, 5120, 16)),                      # phi4-mini-flash-l6's two scan layers
+    "ssm_scan_b2_s1000_c256_n16": lambda: _ssm((2, 1000, 256, 16)),                        # a length that is padded to chunks
+    "flash_diff_b1_s8192_h20_kvh10_d64_v128": lambda: _flash_diff((1, 8192, 20, 10, 64, 128), None),     # ... its full and cross layers' calls
+    "flash_diff_b1_s8192_h20_kvh10_d64_v128_w512": lambda: _flash_diff((1, 8192, 20, 10, 64, 128), 512),  # ... and its window layer's
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
     "flash_gqa_b2_s4096_h32_kvh4_d128": lambda: _flash((2, 4096, 32, 4, 128)),
     "flash_mha_b2_s2048_h16_d128": lambda: _flash((2, 2048, 16, 16, 128)),  # olmo-1b.pretrain-z3, one chip's share

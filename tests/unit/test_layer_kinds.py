@@ -3,7 +3,7 @@ the table (``models/transformer.py``), and the model, the trainer and the server
 
 (a) what a new kind costs: a mixer defined HERE, one line of the table, and a model with it trains through
 ``deepspeed_tpu.initialize`` under ``remat`` with its own sown count reported and its own key on the first-call line;
-(b) the nine kinds' records against what a traced block of each sows, names and counts, and against what the stacked
+(b) the fourteen kinds' records against what a traced block of each sows, names and counts, and against what the stacked
 forms take; (c) the hosts' sources spell no kind; and what must not move: ``TransformerConfig``'s fields, the five
 cells' parameter trees and their checkpointed blocks' programs."""
 
@@ -35,7 +35,7 @@ from deepspeed_tpu.telemetry import device_counts, get_registry, get_tracer
 from deepspeed_tpu.telemetry.tracing import region
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-KINDS = ("kda", "gdn", "mla", "sparse", "routed")  # the names no host may spell
+KINDS = ("kda", "gdn", "mla", "sparse", "routed", "ssm", "diff", "diff_window", "gmu", "diff_cross")  # the names no host may spell
 sha = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
@@ -101,8 +101,22 @@ def tiny(mixer, ffn, **over):
     base = dict(vocab_size=97, n_layers=1, n_heads=4, n_kv_heads=2, d_model=32, d_ff=48, max_seq_len=64, norm="rmsnorm", activation="swiglu",
                 pos_emb="rope", tie_embeddings=False, layer_kinds=((mixer, ffn),), sliding_window=16, kda_heads=2, kda_head_dim=16,
                 kda_gate_rank=8, gdn_key_heads=2, gdn_value_heads=4, gdn_head_dim=16, mla_kv_rank=24, mla_qk_nope_dim=24, mla_qk_rope_dim=8,
-                mla_v_dim=16, index_heads=2, index_head_dim=8, index_topk=16, moe_num_experts=8, moe_top_k=2, moe_d_ff=16, moe_shared_d_ff=16)
+                mla_v_dim=16, index_heads=2, index_head_dim=8, index_topk=16, moe_num_experts=8, moe_top_k=2, moe_d_ff=16, moe_shared_d_ff=16,
+                ssm_inner=128, ssm_dt_rank=4)
     return TransformerConfig(**dict(base, **over))
+
+
+def _taken_by(record, cfg, x, positions):
+    """What a mixer of this record takes, by name: ``layer`` as the model gives it, the rest as zeros of the shapes the
+    table's giver of the name hands on."""
+    taken = {"layer": jnp.asarray(3, jnp.int32)} if "layer" in record.takes else {}
+    for name in set(record.takes) - {"layer"}:
+        giver = next(kind for kind, cls in table.MIXERS.items() if name in cls.gives)
+        block = Block(cfg, (giver, "dense"))
+        args = (x, positions, None, None, _taken_by(table.MIXERS[giver], cfg, x, positions))
+        shapes = jax.eval_shape(lambda: block.apply(block.init(jax.random.PRNGKey(0), *args), *args))[1]
+        taken[name] = jnp.zeros(shapes[name].shape, shapes[name].dtype)
+    return taken
 
 
 def _names(jaxpr, found):
@@ -119,7 +133,7 @@ def _names(jaxpr, found):
 
 
 # a kernel's custom_vjp gives these where the kernel runs: off the TPU no trace shows them (``tests/unit/test_chip_compile.py``)
-KERNELS_ALONE = {"kda_scan", "flash_attention"}
+KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
 WHEN = {"moe_cond": "the buffer is smaller than every pair"}  # a key that rises only then (``tests/unit/test_moe_sum_rows.py``)
 
 
@@ -133,9 +147,15 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     cfg = tiny(*kind)
     x, positions = jnp.zeros((2, 64, 32)), jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32), (2, 64))
     block = Block(cfg, kind)
-    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, positions))["params"]
+    mixer = table.MIXERS[kind[0]]  # the values between blocks are a mixer's
+    taken = _taken_by(mixer, cfg, x, positions)
+    params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, positions, None, None, taken))["params"]
     assert {n for n in params if "Norm" not in n} == {{"full": "attn", "window": "attn", "dense": "mlp"}.get(n, n) for n in kind}  # its name in the tree
-    run = lambda p, x: block.apply({"params": p}, x, positions, mutable=_SOWN)
+    run = lambda p, x: block.apply({"params": p}, x, positions, None, None, taken, mutable=_SOWN)
+    if mixer.gives:  # the block's result is then (activations, the values by name): exactly the names the record gives
+        given = jax.eval_shape(run, params, x)[0][1]
+        assert set(given) == set(mixer.gives)
+        run = lambda p, x, run=run: (lambda out, sown: (out[0], sown))(*run(p, x))
     before = trainer._paths_traced()
     jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(run(p, x)[0])))(params, x)
     rose = {key for key, now in trainer._paths_traced().items() if tuple(now) != tuple(before[key])}
@@ -150,6 +170,10 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     # the stacked forms: the configuration's word, the pipeline's and the server's refusals
     assert cfg.unstackable == (() if record.stackable else (name,))
     model = CausalLM(cfg)
+    if set(mixer.takes) - {"layer"}:  # a model of such a layer alone has no giver: refused in words before anything is built
+        with pytest.raises(ValueError, match=f"layer 0 .{name}. takes .*, which no earlier layer gives"):
+            jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)}))
+        return
     shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)}))
     if record.stackable:
         assert set(model.to_pipeline(1, params=model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)}))[0]["stages"]) == {"sub_0"}
@@ -182,10 +206,10 @@ def _strings(path):
 @pytest.mark.parametrize("path", ["deepspeed_tpu/runtime/engine.py", "deepspeed_tpu/models/transformer.py", "deepspeed_tpu/inference/v2/engine_v2.py"])
 def test_no_host_names_a_kind(path):
     named = [(line, s) for line, s in _strings(path) if any(re.search(rf"\b{kind}\b", s) for kind in KINDS)]
-    if path.endswith("transformer.py"):  # the table's own two lines
+    if path.endswith("transformer.py"):  # the table's own three lines
         with open(os.path.join(ROOT, path)) as f:
             lines = f.read().splitlines()
-        named = [(line, s) for line, s in named if not lines[line - 1].startswith(("MIXERS = ", "FFNS = "))]
+        named = [(line, s) for line, s in named if not lines[line - 1].startswith(("MIXERS = ", "MIXERS |= ", "FFNS = "))]
     assert not named
     if path.endswith("runtime/engine.py"):  # not in a comment or a docstring either
         with open(os.path.join(ROOT, path)) as f:
@@ -196,7 +220,9 @@ def test_no_host_names_a_kind(path):
 def test_the_configurations_fields_are_the_parents():
     """Names, order and defaults (made from the parent commit by the same line), and the class a caller gets adds none."""
     fields = dataclasses.fields(TransformerConfig)
-    assert (len(fields), sha(repr([(f.name, repr(f.default)) for f in fields]))) == (76, "02e66817e969c9f2")
+    assert sha(repr([(f.name, repr(f.default)) for f in fields[:76]])) == "02e66817e969c9f2"  # PR 45's 76, as they were
+    assert [(f.name, f.default) for f in fields[76:]] == [("ssm_inner", 0), ("ssm_state", 16), ("ssm_conv", 4), ("ssm_dt_rank", 0),
+                                                          ("layer_numbers", None)]  # PR 46: appended, nothing moved
     assert [f.name for f in fields] == [f.name for f in dataclasses.fields(TransformerFields)]
     cfg = TransformerConfig(n_layers=3)
     assert TransformerConfig(**cfg.__dict__) == cfg == dataclasses.replace(cfg) and hash(cfg) == hash(dataclasses.replace(cfg))
@@ -250,7 +276,7 @@ def test_a_checkpointed_block_is_the_program_it_was_under_the_parents_list(name,
     params = jax.eval_shape(lambda: Block(cfg, kind).init(jax.random.PRNGKey(0), x, positions))["params"]
 
     def program():
-        loss = lambda p, x: jnp.sum(block_fn(cfg, kind, True, True)(p, x, positions, None, None)[0][0].astype(jnp.float32))
+        loss = lambda p, x: jnp.sum(block_fn(cfg, kind, True, True)(p, x, positions, None, None, {})[0][0].astype(jnp.float32))
         return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)))
 
     ours = program()

@@ -26,7 +26,7 @@ FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
 CELLS = {"olmo-1b": dict(remat=False, zero=True), "kimi-linear-48b-l5e8": dict(remat=True, zero=False),
          "kimi-vl-a3b-l6e8": dict(remat=True, zero=False)}
 # the closed list (docs/OBSERVABILITY.md, "Regions")
-REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/index", "mixer/select", "mixer/index_loss", "ffn/dense", "ffn/shared", "ffn/router", "ffn/rows",
+REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/index", "mixer/select", "mixer/index_loss", "mixer/conv", "mixer/diff", "mixer/memory", "ffn/dense", "ffn/shared", "ffn/router", "ffn/rows",
            "ffn/cond", "branch/usual", "branch/every_pair", "ffn/experts", "head", "optimizer", "zero/gather", "zero/reduce",
            "zero/regather", "block"}
 HEAVY = ("dot", "convolution", "custom-call")
@@ -163,7 +163,7 @@ def test_replayed_layers_carry_the_names_of_the_traced_one(name):
     want = collections.Counter()
     for kind, n in collections.Counter(cfg.kinds).items():
         i = cfg.kinds.index(kind)
-        one = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(block_fn(cfg, kind, True, False)(p, x, positions, None, None)[0][0].astype(jnp.float32))))(
+        one = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(block_fn(cfg, kind, True, False)(p, x, positions, None, None, {})[0][0].astype(jnp.float32))))(
             layers[f"layer_{i}"])
         for region, products in _products_by_region(one.jaxpr, "forward").items():
             want[region] += n * products

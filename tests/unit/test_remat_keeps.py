@@ -37,7 +37,7 @@ def _block(kind, cfg, remat):
     params = jax.tree_util.tree_unflatten(tree, [p + 0.05 * jax.random.normal(jax.random.PRNGKey(7 + i), p.shape) for i, p in enumerate(leaves)])
     w = jax.random.normal(jax.random.PRNGKey(3), x.shape)
     fn = block_fn(cfg, kind, True, remat)
-    return (lambda p, x: jnp.sum(fn(p, x, positions, None, None)[0][0] * w)), params, x
+    return (lambda p, x: jnp.sum(fn(p, x, positions, None, None, {})[0][0] * w)), params, x
 
 
 def _equations(jaxpr, stack=""):
