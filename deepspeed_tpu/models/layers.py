@@ -37,7 +37,8 @@ class LayerKind:
     hybrid = False  # a checkpointed block with it keeps, by name, what its two parts' ``keeps`` say; else its inputs alone
     # the trainer's first-call line. ``paths``: key -> (region, labels) of ``program_regions_traced_total``; the key's
     # word is ``xla`` where only ``path="xla"`` call sites rose, ``mixed``, else ``kernel`` (or ``path_words[key]``).
-    # ``joined``: key -> (region, the ``path`` labels of it that may rise): the word is those that rose, "+" between.
+    # ``joined``: key -> (region, the ``path`` labels of it that may rise[, another label than ``path`` whose values they
+    # are]): the word is those that rose, "+" between.
     # ``alone``: a model whose layers are all of one kind says nothing of kinds on that line, unless this
     paths, path_words, joined, alone = {}, {}, {}, False
     stackable = False  # the scan over layers, ``to_pipeline`` and ``inference/v2`` can run it

@@ -23,7 +23,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import indexed_attention as sparse
 from ..ops.attention import attention
-from ..ops.kda import SAVED as SCAN_SAVED, gdn, kda
+from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, kda
 from ..ops.registry import pallas_available
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
@@ -71,7 +71,7 @@ class KDAMixer(LayerKind, nn.Module):
 
     cfg: TransformerFields
     keeps, hybrid = (SCAN_SAVED, SAVED), True
-    paths = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"})}
+    paths, joined = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"})}, {"kda_heads_a_step": HEADS_A_STEP}
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
@@ -127,7 +127,7 @@ class GDNMixer(LayerKind, nn.Module):
 
     cfg: TransformerFields
     keeps, hybrid = (SCAN_SAVED, SAVED), True
-    paths = {"gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"})}
+    paths, joined = {"gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"})}, {"gdn_heads_a_step": HEADS_A_STEP}
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):

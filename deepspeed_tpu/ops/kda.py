@@ -15,7 +15,9 @@ On a TPU it is the chunked Pallas kernel (``ops/pallas/kda.py``, forward and
 backward); elsewhere ``kda_recurrence``, a ``lax.scan`` over tokens, which is
 also the kernel's oracle. The choice is counted where it is made, while a
 program is traced (``program_regions_traced_total{region="mixer/kernel",
-op="kda", pass, path}``).
+op="kda", pass, path}``), and with the kernel how many heads a grid step of
+it works on (``heads_a_step``: the kernel's own rule on the shard-local
+operands, ``ops/pallas/kda.py::heads_a_step``).
 """
 
 import functools
@@ -32,11 +34,21 @@ from .registry import pallas_available
 # (I + A)^-1. A checkpointed hybrid block keeps them (``models/transformer.py::remat_keeps``), so its backward runs no
 # second scan; the kernel's operands carry no name and follow, elementwise, from the projections the block keeps
 SAVED = "kda_scan"
+# for a kind's record (``LayerKind.joined``): the series that say how many heads a grid step of the kernel works on (the
+# values ``ops/pallas/kda.py::heads_a_step`` can give)
+HEADS_A_STEP = ("mixer/kernel", ("1", "2", "4"), "heads_a_step")
 
 
-def _traced(pass_: str, path: str, op: str = "kda"):
+def _traced(pass_: str, path: str, op: str = "kda", **choice):
     """The region of a scan (``op``: "kda", or "gdn" for one decay a head) that was traced as ``path``, counted."""
-    return region("mixer/kernel", op=op, path=path, **{"pass": pass_})
+    return region("mixer/kernel", op=op, path=path, **{"pass": pass_}, **choice)
+
+
+def _kernel_traced(pass_: str, op: str, q, vb, g):
+    """... as the kernel, with the heads a grid step of this call works on."""
+    from .pallas.kda import heads_a_step
+
+    return _traced(pass_, "kernel", op, heads_a_step=str(heads_a_step(q, vb, g, pass_ == "bwd")))
 
 
 def kda_recurrence(q, k, v, g, beta):
@@ -69,7 +81,7 @@ def _scan_fwd(q, k, kb, vb, g, op, interpret):
 
     # named, all three (outputs, every chunk's incoming state and its (I + A)^-1), so that a block under jax.checkpoint
     # keeps them (models/transformer.py::block_fn) and its backward does not run the scan a second time to get them back
-    with _traced("fwd", "kernel", op):
+    with _kernel_traced("fwd", op, q, vb, g):
         o, states, inverses = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(q, k, kb, vb, g, interpret))
     return o, (q, k, kb, vb, g, states, inverses)
 
@@ -77,9 +89,9 @@ def _scan_fwd(q, k, kb, vb, g, op, interpret):
 def _scan_bwd(op, interpret, res, do):
     from .pallas import kda as kernel
 
-    with _traced("bwd", "kernel", op):
+    q, _, _, vb, g = res[:5]
+    with _kernel_traced("bwd", op, q, vb, g):
         dq, dk, *rest = kernel.scan_bwd(*res, do, interpret)
-        q = res[0]
         if dq.shape != q.shape:  # value heads that share a key head: each gave its own dq and dk
             dq, dk = (x.reshape(q.shape[0], -1, *q.shape[1:]).astype(jnp.float32).sum(1).astype(q.dtype) for x in (dq, dk))
         return (dq, dk, *rest)

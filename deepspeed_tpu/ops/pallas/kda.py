@@ -54,6 +54,30 @@ backward by ``jax.vjp`` are the same code. Several value heads may share a key
 head's q and k (``q.shape[0]`` divides ``vb.shape[0]``): the grid walks value
 heads, a head's q and k blocks are its key head's, and dq, dk leave a value head
 each for the caller to add.
+
+**Several heads a grid step.** A chunk of one head is a chain: the state's
+product in, the blocks under the diagonal, twelve products of (CHUNK, CHUNK)
+float32 matrices for the inverse, ``T r``, ``B u``, the state's product out,
+each waiting for the one before and each far too small to fill the MXU (0.14 us
+a product of which 0.06 is the MXU's; 3.4 us a chunk). The next CHUNK of the
+same head cannot help: it needs this one's state. Another HEAD needs nothing
+of it: heads share no state, no inverse and no operand but a key head's q and
+k. So the grid is (heads / H, chunks), a block holds H heads' rows, the state
+scratch is (H, d_v, d_k), and the body runs H chains abreast: a chunk function
+is a generator that yields after each link, ``_abreast`` steps the H generators
+in turn, and the traced program has link i of every head before link i + 1 of
+any (the backward is ``jax.vjp`` of that same function, so its transpose is
+abreast too). The scheduler then has an independent product to issue while
+one is on its way back. Written head after head, without the yields, the
+same H heads in one step ran no faster than one a step (per-channel forward
+6.93 ms a layer at H = 1, 6.68 at 2, 6.53 at 4; abreast 4.46 and 3.63); under
+``jax.vmap`` the forward ran slower than abreast (5.76 and 4.70) and the
+backward of four a little faster (6.95 beside 7.14), the per-head form brought
+Mosaic down, and the CPU's batched products round otherwise than one head's,
+so no test could hold it to the kernel of one head (PERF.md, PR 47). Nothing in a head's arithmetic moves: the same products in
+the same order and precision, so every output, save and gradient is, bit for
+bit, the kernel of one head a step. ``heads_a_step`` chooses H from the
+operands of the call; H = 1 is the kernel as it was.
 """
 
 import functools
@@ -97,6 +121,33 @@ def _dot32(a, b, mm, dims=_NN):
     return _dot3(a, b, dims)
 
 
+def _abreast(heads):
+    """Run generators in turn, each to its next ``yield``, until all have returned -> their return values. A head's
+    chunk is written as a generator that yields after each link of its chain (a product that the next one waits for),
+    so the program's text has link i of every head before link i + 1 of any: the heads share nothing, and the MXU
+    finds one's product to run while another's is on its way back."""
+    heads = list(heads)
+    results, live = [None] * len(heads), list(range(len(heads)))
+    while live:
+        for i in list(live):
+            try:
+                next(heads[i])
+            except StopIteration as done:
+                results[i] = done.value
+                live.remove(i)
+    return results
+
+
+def _links(chain):
+    """A chain written as a generator -> the plain function of one head; the generator stays at ``.links``."""
+    @functools.wraps(chain)
+    def alone(*args, **kwargs):
+        return _abreast([chain(*args, **kwargs)])[0]
+
+    alone.links = chain
+    return alone
+
+
 def _inverse_unit_lower(A, dot):
     """``(I + A)^-1`` for a strictly lower triangular ``A`` (n, n), n a power
     of two times ``SUB``, in products of whole (n, n) matrices. First the
@@ -118,11 +169,16 @@ def _inverse_unit_lower(A, dot):
     power, M = -D, (row == col).astype(A.dtype) - D
     for _ in range(SUB.bit_length() - 2):
         power = dot(power, power)
+        yield
         M = M + dot(M, power)
+        yield
     b = SUB
     while b < n:
         L = jnp.where(same(2 * b) & ~same(b), A, 0.0)
-        M = M - dot(M, dot(L, M))
+        LM = dot(L, M)
+        yield
+        M = M - dot(M, LM)
+        yield
         b *= 2
     return M
 
@@ -137,7 +193,7 @@ def solve_unit_lower(A, r, T, mm):
     T dr - T dA u``, ``r_bar = T^T u_bar`` and ``A_bar = -r_bar u^T``, kept
     where ``A`` has entries at all, below the diagonal."""
     if T is None:
-        T = _inverse_unit_lower(A, functools.partial(_dot32, mm=mm))
+        T, = _abreast([_inverse_unit_lower(A, functools.partial(_dot32, mm=mm))])
     return _dot32(T, r, mm), T
 
 
@@ -156,6 +212,17 @@ def _solve_bwd(mm, saved, bars):
 solve_unit_lower.defvjp(_solve_fwd, _solve_bwd)
 
 
+def _solve_links(A, r, T, mm):
+    """``solve_unit_lower`` as links of a chunk's chain: the inverse, where it is not given, a product a link (it is
+    a value: the solve's adjoint needs no gradient through its construction), then ``u = T r``."""
+    if T is None:
+        T = jax.lax.stop_gradient((yield from _inverse_unit_lower(A, functools.partial(_dot32, mm=mm))))
+    result = solve_unit_lower(A, r, T, mm)
+    yield
+    return result
+
+
+@_links
 def chunk_fn(q, k, kb, vb, g, St0, mm, T=None):
     """One chunk: ``q, k, kb = beta * k`` (C, d_k), ``vb = beta * v`` (C, d_v),
     ``g`` (C, d_k) float32, each token's log-decay (<= 0), ``St0`` (d_v, d_k)
@@ -181,6 +248,7 @@ def chunk_fn(q, k, kb, vb, g, St0, mm, T=None):
     gam = jnp.exp(G)
     from_state = dot(jnp.concatenate([q * gam, kb * gam], axis=0), St0, _NT)  # (2C, d_v)
     o_in, r = from_state[:C], vb - from_state[C:]
+    yield
 
     G_ref = jax.lax.stop_gradient(G)  # a reference cancels in exp(G_t - ref) exp(ref - G_s): no gradient is its
     t = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
@@ -205,14 +273,19 @@ def chunk_fn(q, k, kb, vb, g, St0, mm, T=None):
         a_rows.append(_beside(below[:SUB], a_ii, after))
         b_rows.append(_beside(below[SUB:], b_ii, after))
     A, B = jnp.concatenate(a_rows, axis=0), jnp.concatenate(b_rows, axis=0)  # (C, C)
+    # no link ends in these eight blocks, nor between them and the solve: the pairs' tensors fill the vector unit by
+    # themselves and the blocks below wait for nothing, and with four heads' ``A`` and ``B`` held at once the backward
+    # took 8.06 ms a layer where it takes 7.15 (a link a block: 8.41; PERF.md, PR 47)
 
-    u, T = solve_unit_lower(A, r, T, mm)  # (C, d_v), (C, C)
+    u, T = yield from _solve_links(A, r, T, mm)  # (C, d_v), (C, C)
     o = o_in + dot(B, u, _NN)
+    yield
     last = G[C - 1:C]
     St1 = St0 * jnp.exp(last) + dot(u, k * jnp.exp(last - G), _TN)  # (d_v, d_k)
     return o, St1, T
 
 
+@_links
 def chunk_fn_per_head(q, k, kb, vb, g, St0, mm, T=None):
     """``chunk_fn`` for a decay that is one number a token: ``g`` (1, C)
     float32, the chunk's log-decays along the lanes; everything else as there.
@@ -235,15 +308,24 @@ def chunk_fn_per_head(q, k, kb, vb, g, St0, mm, T=None):
     gam = jnp.exp(G)
     from_state = dot(jnp.concatenate([q * gam, kb * gam], axis=0), St0, _NT)  # (2C, d_v)
     o_in, r = from_state[:C], vb - from_state[C:]
+    yield
     pairs = dot(jnp.concatenate([kb, q], axis=0), k, _NT)  # (2C, C)
     A = jnp.where(row > col, pairs[:C] * decay, 0.0)
     B = jnp.where(row >= col, pairs[C:] * decay, 0.0)
+    yield
 
-    u, T = solve_unit_lower(A, r, T, mm)
-    o = o_in + dot(B, u, _NN)
+    u, T = yield from _solve_links(A, r, T, mm)
+    o = o_in + dot(B, u, _NN)  # and the state's product out with it, one link (a link each: 2.47 ms a layer forward, so 2.42)
     last = jnp.sum(g, axis=1, keepdims=True)  # G_C, (1, 1)
     St1 = St0 * jnp.exp(last) + dot(u, k * jnp.exp(last - G), _TN)
     return o, St1, T
+
+
+def _head(ref, i, heads):
+    """Head ``i`` of a step of ``heads`` in a block: a save's block has a chunk axis of one, and a key head's block
+    fewer heads than the step has value heads (each serves ``heads // ref.shape[0]`` of them, in order)."""
+    at = i * ref.shape[0] // heads
+    return ref[at, 0] if len(ref.shape) == 4 else ref[at]
 
 
 def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, t_ref, state, *, chunk, mm):
@@ -251,20 +333,31 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, t_ref, state
     def _zero():
         state[...] = jnp.zeros_like(state)
 
-    st0 = state[...]
-    st_ref[0, 0] = st0  # what this chunk started from: the backward's residual
-    o, st1, T = chunk(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st0, mm)
-    o_ref[0] = o.astype(o_ref.dtype)
-    t_ref[0, 0] = T  # and the inverse it made: the backward neither makes nor differentiates it again
-    state[...] = st1
+    H = state.shape[0]
+    for i in range(H):
+        st_ref[i, 0] = state[i]  # what this chunk started from: the backward's residual
+    operands = [[_head(ref, i, H) for ref in (q_ref, k_ref, kb_ref, vb_ref, g_ref)] + [state[i]] for i in range(H)]
+    for i, (o, st1, T) in enumerate(_abreast(chunk.links(*xs, mm) for xs in operands)):
+        o_ref[i] = o.astype(o_ref.dtype)
+        t_ref[i, 0] = T  # and the inverse it made: the backward neither makes nor differentiates it again
+        state[i] = st1
+
+
+def heads_bwd(heads, mm, chunk=chunk_fn):
+    """For each head of a grid step, ``(q, k, kb, vb, g, St0, T, do, dSt1)``: its chunk's gradients to q, k, kb, vb, g
+    and the incoming state, from the cotangents of its outputs (float32) and of its outgoing state: ``jax.vjp`` of
+    ``chunk`` on the state and the inverse the forward saved for it, the heads' chains abreast (``_abreast``) in the
+    function and so in its transpose."""
+    def forward(*operands):
+        return [out[:2] for out in _abreast(chunk.links(*xs, mm, head[6]) for xs, head in zip(operands, heads))]
+
+    _, vjp = jax.vjp(forward, *(head[:6] for head in heads))
+    return vjp([head[7:] for head in heads])
 
 
 def chunk_bwd(q, k, kb, vb, g, St0, T, do, dSt1, mm, chunk=chunk_fn):
-    """A chunk's gradients to q, k, kb, vb, g and the incoming state, from the
-    cotangents of its outputs (float32) and of its outgoing state: ``jax.vjp``
-    of ``chunk`` on the state and the inverse the forward saved for it."""
-    _, vjp = jax.vjp(lambda *operands: chunk(*operands, mm, T)[:2], q, k, kb, vb, g, St0)
-    return vjp((do, dSt1))
+    """``heads_bwd`` of one head."""
+    return heads_bwd([(q, k, kb, vb, g, St0, T, do, dSt1)], mm, chunk)[0]
 
 
 def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
@@ -273,32 +366,76 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref, do_ref, dq_r
     def _zero():  # the last chunk: nothing reads the state after it
         dstate[...] = jnp.zeros_like(dstate)
 
-    dq, dk, dkb, dvb, dg, dst0 = chunk_bwd(q_ref[0], k_ref[0], kb_ref[0], vb_ref[0], g_ref[0], st_ref[0, 0], t_ref[0, 0],
-                                           do_ref[0].astype(jnp.float32), dstate[...], mm, chunk)
-    for ref, value in ((dq_ref, dq), (dk_ref, dk), (dkb_ref, dkb), (dvb_ref, dvb), (dg_ref, dg)):
-        ref[0] = value.astype(ref.dtype)
-    dstate[...] = dst0
+    H = dstate.shape[0]
+    heads = [tuple(_head(ref, i, H) for ref in (q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, t_ref))
+             + (do_ref[i].astype(jnp.float32), dstate[i]) for i in range(H)]
+    for i, (*to_blocks, dst0) in enumerate(heads_bwd(heads, mm, chunk)):
+        for ref, value in zip((dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref), to_blocks):
+            ref[(i, 0) if len(ref.shape) == 4 else i] = value.astype(ref.dtype)  # dg is walked as a save is
+        dstate[i] = dst0
 
 
 def _mm_dtype(x):
     return jnp.float32 if x.dtype == jnp.float32 else jnp.bfloat16
 
 
-def _form(q, vb, g, chunk_at):
-    """What tells the two forms apart is the decay's shape: (BH, S, d_k), a
-    number a channel, or (BH * S / CHUNK, 1, CHUNK), a number a token along
-    the lanes. -> (the chunk function, the calls' name, the BlockSpecs of
-    (BH, S, d) rows of a key head and of a value head, of a (BH, S / CHUNK,
-    m, n) float32 save, and of the decay), for a grid (value head, step)
-    whose step works on chunk ``chunk_at(step)``."""
-    nc, rep = q.shape[1] // CHUNK, vb.shape[0] // q.shape[0]
-    rows = lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b, chunk_at(c), 0))
-    key_rows = rows if rep == 1 else lambda d: pl.BlockSpec((1, CHUNK, d), lambda b, c: (b // rep, chunk_at(c), 0))
-    whole = lambda m, n: pl.BlockSpec((1, 1, m, n), lambda b, c: (b, chunk_at(c), 0, 0))
-    if g.shape[1:] == (1, CHUNK):
-        return chunk_fn_per_head, "gdn_scan", key_rows, rows, whole, \
-            pl.BlockSpec((1, 1, CHUNK), lambda b, c: (b * nc + chunk_at(c), 0, 0))
-    return chunk_fn, "kda_scan", key_rows, rows, whole, rows(q.shape[-1])
+def _per_head(g):
+    """What tells the two forms apart is the decay's shape: (BH, S, d_k), a number a channel, or (BH * S / CHUNK, 1,
+    CHUNK), a number a token along the lanes."""
+    return g.shape[1:] == (1, CHUNK)
+
+
+def _vmem_a_head(q, vb, g, backward: bool) -> int:
+    """What a head of a grid step holds in VMEM, from the shapes: its blocks, each twice (the pipeline fetches the next
+    step's while this one's are worked on), and the body's temporaries, (CHUNK, CHUNK) float32 matrices that have left
+    the 64 registers: 24 of them in the forward and 72 in the ``vjp`` (at the cells' shapes the compiler asked 22.5 MB
+    for four heads of the per-channel backward, 4.4 of them blocks)."""
+    dk, dv, f32 = q.shape[-1], vb.shape[-1], 4
+    rows = CHUNK * q.dtype.itemsize
+    decay = CHUNK * f32 if _per_head(g) else CHUNK * dk * f32
+    blocks = rows * (3 * dk + 2 * dv) + decay + (dv * dk + CHUNK * CHUNK) * f32  # q, k, kb, vb, o or do, g, the two saves
+    if backward:
+        blocks += rows * (3 * dk + dv) + decay  # dq, dk, dkb, dvb, dg
+    return 2 * blocks + (72 if backward else 24) * CHUNK * max(CHUNK, dk, dv) * f32
+
+
+def heads_a_step(q, vb, g, backward: bool) -> int:
+    """How many (value) heads a grid step of the call on these (shard-local) operands works on: the most of 4, 2 and
+    1 that divides the heads, keeps a step's value heads with whole key heads (one key head's where its repetition
+    allows, so that its q and k are fetched once a step) and fits the kernel's share of VMEM. Chosen here, from what
+    the call can see, by nobody else: the table this was written from (TPU v5e, ms a layer at 32 heads x 8,192 x 128,
+    H = 1 | 2 | 4; PERF.md, PR 47) falls with H in all four calls, per-channel 6.93 | 4.46 | 3.63 forward and 8.98 |
+    7.61 | 7.14 backward, per-head 5.74 | 3.40 | 2.42 and 3.80 | 2.11 | 1.75; at 8 the per-head calls gain 7-10% more,
+    the per-channel ones nothing, and the bodies compile for more than twice as long. 1 is the kernel as it was: the
+    same code, one chain a step."""
+    from ._utils import vmem_budget
+
+    heads, rep = vb.shape[0], vb.shape[0] // q.shape[0]
+    for H in (4, 2):
+        if heads % H == 0 and (rep % H == 0 or H % rep == 0) and H * _vmem_a_head(q, vb, g, backward) <= vmem_budget():
+            return H
+    return 1
+
+
+def _form(q, vb, g, chunk_at, H, backward):
+    """-> (the chunk function, the calls' name, the BlockSpecs of (BH, S, d) rows of the step's key heads and of its
+    ``H`` value heads, of a (BH, S / CHUNK, m, n) float32 save and of the decay as the grid walks it (``_walked``),
+    the compiler's parameters), for a grid (step's value heads, step) whose step works on chunk ``chunk_at(step)``."""
+    rep = vb.shape[0] // q.shape[0]
+    key_heads = max(1, H // rep)  # of one step: H value heads are one key head's, or H / rep whole key heads'
+    rows = lambda d: pl.BlockSpec((H, CHUNK, d), lambda b, c: (b, chunk_at(c), 0))
+    key_rows = lambda d: pl.BlockSpec((key_heads, CHUNK, d), lambda b, c: (b * H // (rep * key_heads), chunk_at(c), 0))
+    whole = lambda m, n: pl.BlockSpec((H, 1, m, n), lambda b, c: (b, chunk_at(c), 0, 0))
+    params = functools.partial(_compiler_params, "parallel", "arbitrary", vmem_bytes=H * _vmem_a_head(q, vb, g, backward))
+    if _per_head(g):
+        return chunk_fn_per_head, "gdn_scan", key_rows, rows, whole, whole(1, CHUNK), params
+    return chunk_fn, "kda_scan", key_rows, rows, whole, rows(q.shape[-1]), params
+
+
+def _walked(g, nc):
+    """A decay that is a number a token, (value head, chunk, 1, CHUNK) as a save is: a head's chunks lie ``nc`` rows
+    apart, and a step's heads are one block."""
+    return g.reshape(-1, nc, 1, CHUNK) if _per_head(g) else g
 
 
 def scan_fwd(q, k, kb, vb, g, interpret: bool):
@@ -307,22 +444,31 @@ def scan_fwd(q, k, kb, vb, g, interpret: bool):
     S/CHUNK, d_v, d_k) and its ``(I + A)^-1`` (BH, S/CHUNK, CHUNK, CHUNK),
     float32. ``g``: (BH, S, d_k), or (BH * S/CHUNK, 1, CHUNK) for one decay
     a token, where q and k may have fewer heads than kb and vb (``_form``)."""
+    return _scan_fwd(q, k, kb, vb, g, heads_a_step(q, vb, g, False), interpret)
+
+
+# jitted for the trace's sake, not the program's (it is inlined where it is called): a body of four heads is four times
+# the Python to trace, and a model's step meets the call once a kind of block and again wherever its loss is traced
+# anew: the layers of one shape then share ONE trace of the body a process (the hybrid cell's set-up: 49 s with one head
+# a step, 75 with four traced at every call, PERF.md, PR 47). H is an argument, so whoever asks the rule is the caller.
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _scan_fwd(q, k, kb, vb, g, H, interpret):
     BH, S, dv = vb.shape
     dk = q.shape[-1]
     nc = S // CHUNK
-    chunk, name, key_rows, rows, whole, decay = _form(q, vb, g, lambda c: c)
+    chunk, name, key_rows, rows, whole, decay, params = _form(q, vb, g, lambda c: c, H, False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, mm=_mm_dtype(q)),
         name=f"{name}_fwd",
-        grid=(BH, nc),
+        grid=(BH // H, nc),
         in_specs=[key_rows(dk), key_rows(dk), rows(dk), rows(dv), decay],
         out_specs=[rows(dv), whole(dv, dk), whole(CHUNK, CHUNK)],
         out_shape=[jax.ShapeDtypeStruct((BH, S, dv), vb.dtype), jax.ShapeDtypeStruct((BH, nc, dv, dk), jnp.float32),
                    jax.ShapeDtypeStruct((BH, nc, CHUNK, CHUNK), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((H, dv, dk), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
-    )(q, k, kb, vb, g)
+        compiler_params=params(interpret=interpret),
+    )(q, k, kb, vb, _walked(g, nc))
 
 
 def scan_bwd(q, k, kb, vb, g, states, inverses, do, interpret: bool):
@@ -330,19 +476,26 @@ def scan_bwd(q, k, kb, vb, g, states, inverses, do, interpret: bool):
     (their types) and g (float32), chunks walked from the last to the first,
     each on the state and the inverse ``scan_fwd`` returned for it. dq and dk
     have kb's heads: where value heads share a key head, one each."""
+    return _scan_bwd(q, k, kb, vb, g, states, inverses, do, heads_a_step(q, vb, g, True), interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _scan_bwd(q, k, kb, vb, g, states, inverses, do, H, interpret):
     BH, S, dv = vb.shape
     dk = q.shape[-1]
     nc = S // CHUNK
-    chunk, name, key_rows, rows, whole, decay = _form(q, vb, g, lambda c: nc - 1 - c)
-    return pl.pallas_call(
+    chunk, name, key_rows, rows, whole, decay, params = _form(q, vb, g, lambda c: nc - 1 - c, H, True)
+    walked = _walked(g, nc)
+    *grads, dg = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, mm=_mm_dtype(q)),
         name=f"{name}_bwd",
-        grid=(BH, nc),
+        grid=(BH // H, nc),
         in_specs=[key_rows(dk), key_rows(dk), rows(dk), rows(dv), decay, whole(dv, dk), whole(CHUNK, CHUNK), rows(dv)],
         out_specs=[rows(dk), rows(dk), rows(dk), rows(dv), decay],
         out_shape=[jax.ShapeDtypeStruct(kb.shape, x.dtype) for x in (q, k, kb)]
-        + [jax.ShapeDtypeStruct(vb.shape, vb.dtype), jax.ShapeDtypeStruct(g.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        + [jax.ShapeDtypeStruct(vb.shape, vb.dtype), jax.ShapeDtypeStruct(walked.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((H, dv, dk), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret),
-    )(q, k, kb, vb, g, states, inverses, do)
+        compiler_params=params(interpret=interpret),
+    )(q, k, kb, vb, walked, states, inverses, do)
+    return (*grads, dg.reshape(g.shape))

@@ -98,8 +98,9 @@ def _paths_traced(records=None):
     for key, (name, labels) in _declared("paths", records).items():
         xla = int(regions_traced(name, path="xla", **labels))
         traced[key] = (int(regions_traced(name, **labels)) - xla, xla)
-    for key, (name, words) in _declared("joined", records).items():
-        traced[key] = tuple(int(regions_traced(name, path=word)) for word in words)
+    for key, (name, words, *label) in _declared("joined", records).items():
+        label = label[0] if label else "path"
+        traced[key] = tuple(int(regions_traced(name, **{label: word})) for word in words)
     return traced
 
 
@@ -787,7 +788,7 @@ class DeepSpeedEngine:
             kernel, xla = (now - was for now, was in zip(traced[key], traced_before[key]))
             if kernel or xla:
                 notes[key] = "mixed" if kernel and xla else words.get(key, "kernel") if kernel else "xla"
-        for key, (_, labels) in _declared("joined", records).items():
+        for key, (_, labels, *_) in _declared("joined", records).items():
             rose = [label for label, now, was in zip(labels, traced[key], traced_before[key]) if now > was]
             if rose:
                 notes[key] = "+".join(rose)

@@ -38,6 +38,38 @@ def delta_rule(q, k, v, alpha, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
+def scan_kernel_results(q, k, v, g, beta, heads_a_step=None):
+    """What the scan kernel (interpreted) gives on a batch of one as ``kda_chunked`` / ``gdn_chunked`` would hand it over
+    (``g`` a number a channel, or a number a head and token: the per-head form, q and k with their own fewer heads):
+    ``scan_fwd``'s outputs, saved states and inverses, then ``scan_bwd``'s five gradients for a fixed cotangent, and the
+    heads a grid step the rule gave the two calls. ``heads_a_step``: a number that takes the rule's place."""
+    from unittest import mock
+
+    from deepspeed_tpu.ops.pallas import kda as K
+
+    q, k, v, g, beta = (x[0] for x in (q, k, v, g, beta))
+    pad = [(0, 0), (0, -q.shape[1] % K.CHUNK)]
+    q, k, v, g, beta = (jnp.pad(x, pad + [(0, 0)] * (x.ndim - 2)) for x in (q, k, v, g, beta))
+    kb, vb = beta[..., None] * jnp.repeat(k, v.shape[0] // k.shape[0], axis=0), beta[..., None] * v
+    g = g if g.ndim == 3 else g.reshape(-1, 1, K.CHUNK)
+    do = jax.random.normal(jax.random.PRNGKey(9), vb.shape, vb.dtype)
+    chosen = [K.heads_a_step(q, vb, g, backward) for backward in (False, True)]
+    with mock.patch.object(K, "heads_a_step", K.heads_a_step if heads_a_step is None else lambda *a: heads_a_step):
+        o, states, inverses = K.scan_fwd(q, k, kb, vb, g, interpret=True)
+        return (o, states, inverses, *K.scan_bwd(q, k, kb, vb, g, states, inverses, do, interpret=True)), chosen
+
+
+def kernel_of_one_head_a_step(args, heads_a_step):
+    """The rule gave ``heads_a_step`` to forward and backward on ``args`` (q, k, v, g, beta of a batch of one), and every
+    result of the kernel (outputs, saved states, inverses, the five gradients) is, bit for bit, what one head a grid step
+    gives on the same operands. (As the suite compiles for the CPU: with ``DS_TEST_XLA_OPT=1`` XLA fuses the interpreted
+    bodies of two heads otherwise than one's and the last bit of dk moves; on the chip all eight are equal, PERF.md, PR 47.)"""
+    got, chosen = scan_kernel_results(*args)
+    assert chosen == [heads_a_step, heads_a_step]
+    for a, b in zip(got, scan_kernel_results(*args, heads_a_step=1)[0]):
+        assert a.shape == b.shape and a.dtype == b.dtype and bool(jnp.array_equal(a, b))
+
+
 def kda(p, h, eps=1e-5):
     heads = lambda name: conv_silu(jnp.einsum("bsd,dhk->bshk", h, p[f"{name}_proj"]["kernel"]), p[f"{name}_conv"])
     D = p["q_conv"].shape[-1]

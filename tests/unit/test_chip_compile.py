@@ -134,11 +134,6 @@ def _flash_latent(shape):
     return fwd_bwd, (qk, qk, v, v), 2, "fused"
 
 
-# generated code of the scan's forward and backward with the triangular inverse made and differentiated a second time
-# INSIDE the backward kernel (the tree before PR 33, same compiler); without it: 1,282,048 and 1,673,216 bytes
-KDA_CODE_WITH_THE_INVERSE_IN_THE_BACKWARD = {(1, 32, 8192, 128): 1_598_976, (2, 4, 1000, 128): 1_989_120}
-
-
 def _kda(shape):
     """The chunked delta-rule scan, forward and backward: 2 kernels (heads before the sequence)."""
     from deepspeed_tpu.ops.kda import kda_chunked
@@ -274,8 +269,8 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             jax.jit(fn).lower(*args)
         return
     compiled = jax.jit(fn).lower(*args).compile()
-    if case.startswith("kda_scan"):
-        _kda_scan_hands_its_inverses_on(compiled, shapes[0].shape)
+    if case.startswith(("kda_scan", "gdn_scan")):
+        _the_scan_hands_its_inverses_on_and_walks_its_heads_by_the_rule(compiled, jax.make_jaxpr(fn)(*args), case[:8], shapes[2].shape)
     if not bwd_path:
         assert compiled.as_text().count("tpu_custom_call") >= kernels
         return
@@ -284,20 +279,40 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, bwd_path[0]: 1.0}
 
 
-def _kda_scan_hands_its_inverses_on(compiled, shape):
-    """Exactly the two kernels; the forward's result holds, beside the outputs, two float32 residuals a (head, chunk):
-    the incoming state (d_v, d_k) and ``(I + A)^-1`` (CHUNK, CHUNK); and the program is smaller than it was with the
-    inverse's construction in the backward kernel."""
-    from deepspeed_tpu.ops.pallas.kda import CHUNK
+def _pallas_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, inner jaxprs too, in order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
 
-    B, Hh, Sq, Dh = shape
-    heads, chunks = B * Hh, -(-Sq // CHUNK)
+
+def _the_scan_hands_its_inverses_on_and_walks_its_heads_by_the_rule(compiled, jaxpr, name, shape):
+    """Exactly the two kernels; the forward's result holds, beside the outputs, two float32 residuals a (value head,
+    chunk): the incoming state (d_v, d_k) and ``(I + A)^-1`` (CHUNK, CHUNK), and the backward call TAKES both as operands
+    (it makes no inverse: with the construction in it the program of one head a step was a quarter larger, which the
+    size of a body of several heads no longer shows); and each call's grid is (heads / H, chunks) for the H that
+    ``heads_a_step`` gives that call at this shape: 4 at the cells' 32 heads and at the padded cases' 8."""
+    from deepspeed_tpu.ops.pallas import kda as K
+
+    B, Hv, Sq, Dh = shape  # v's: the value heads
+    heads, chunks = B * Hv, -(-Sq // K.CHUNK)
     calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 2 and "kda_scan_fwd" in calls[0] and "kda_scan_bwd" in calls[1]
-    assert Dh == CHUNK  # heads of 128: a state and an inverse have one shape
-    residual = f"f32[{heads},{chunks},{CHUNK},{CHUNK}]"
+    assert len(calls) == 2 and f"{name}_fwd" in calls[0] and f"{name}_bwd" in calls[1]
+    assert Dh == K.CHUNK  # heads of 128: a state and an inverse have one shape
+    residual = f"f32[{heads},{chunks},{K.CHUNK},{K.CHUNK}]"
     assert calls[0].split(" custom-call(")[0].count(residual) == 2 and calls[1].split(" custom-call(")[1].count(residual) == 2
-    assert compiled.memory_analysis().generated_code_size_in_bytes < KDA_CODE_WITH_THE_INVERSE_IN_THE_BACKWARD[shape]
+    fwd, bwd = _pallas_calls(jaxpr.jaxpr)
+    for call, backward in ((fwd, False), (bwd, True)):
+        q, _, _, vb, g = (v.aval for v in call.invars[:5])
+        g = g if g.ndim == 3 else S((heads * chunks, 1, K.CHUNK), g.dtype)  # a decay a token, as ``scan_fwd`` is handed it
+        H = K.heads_a_step(q, vb, g, backward)
+        assert H == 4 and call.params["grid_mapping"].grid == (heads // H, chunks)
+        assert [v.aval.shape for v in call.invars[5:7]] == [(heads, chunks, K.CHUNK, K.CHUNK)] * 2 if backward else len(call.invars) == 5
 
 
 # ---------------------------------------------------------------- the trainer's step on four described chips

@@ -130,33 +130,61 @@ def _scan_inputs(S, decay, seed=0, B=1, Hk=2, Hv=4, d=16):
     return q, k, v, jnp.log(alpha), jax.nn.sigmoid(jax.random.normal(ks[4], (B, Hv, S)))
 
 
-@pytest.mark.parametrize("S,decay", [(256, 0.9), (100, 0.9), (128, 0.999999), (70, 1e-9), (260, 0.02)])
-def test_the_per_head_kernel_matches_the_token_recurrence(S, decay, highest):
-    """Interpret mode, two value heads a key head: lengths that are and are not whole chunks, decays near 1 and near 0
-    (no exponent in the kernel is positive), forward and the gradients of all five operands (dq and dk added over the
-    value heads that share a key head)."""
+@pytest.mark.parametrize("S,decay,Hk,Hv,heads_a_step", [
+    (256, 0.9, 2, 4, 4), (100, 0.9, 2, 4, 4), (128, 0.999999, 2, 4, 4), (70, 1e-9, 2, 4, 4), (260, 0.02, 2, 4, 4),
+    (1000, 0.9, 2, 4, 4), (256, 0.9, 3, 6, 2), (100, 0.9, 4, 4, 4), (260, 0.02, 6, 6, 2), (100, 0.9, 3, 3, 1), (256, 0.9, 1, 4, 4)])
+def test_the_per_head_kernel_matches_the_token_recurrence(S, decay, Hk, Hv, heads_a_step, highest):
+    """Interpret mode, one, two or four value heads a key head: lengths that are and are not whole chunks, decays near 1
+    and near 0 (no exponent in the kernel is positive), forward and the gradients of all five operands (dq and dk added
+    over the value heads that share a key head); 4, 6 and 3 value heads, which the rule walks 4, 2 and 1 a grid step."""
     from deepspeed_tpu.ops.kda import gdn_chunked, gdn_recurrence
 
-    args = _scan_inputs(S, decay)
+    args = _scan_inputs(S, decay, Hk=Hk, Hv=Hv)
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     run = lambda fn: jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
     (lo, go), (lt, gt) = run(lambda *a: gdn_chunked(*a, interpret=True)), run(gdn_recurrence)
     _close(gdn_chunked(*args, interpret=True), gdn_recurrence(*args))
     sw = lambda x: jnp.swapaxes(x, 1, 2)  # the oracle's oracle keeps the sequence before the heads
     q, k, v, g, beta = args
-    rep = lambda x: jnp.repeat(x, 2, axis=1)
+    rep = lambda x: jnp.repeat(x, Hv // Hk, axis=1)
     plain = sw(ref.delta_rule(sw(rep(q)), sw(rep(k)), sw(v), jnp.broadcast_to(jnp.exp(sw(g))[..., None], sw(v).shape), sw(beta)))
     _close(gdn_recurrence(*args), plain)
     assert np.isfinite(float(lo))
     for a, b in zip(go, gt):
         _close(a, b, 1e-4)
+    ref.kernel_of_one_head_a_step(args, heads_a_step)
 
 
-def test_the_per_head_kernel_under_bf16_operands_is_bf16_close(highest):
+@pytest.mark.parametrize("heads,rep,dim,forward,backward,key_heads", [
+    (32, 1, 128, 4, 4, 4), (32, 2, 128, 4, 4, 2), (8, 4, 128, 4, 4, 1), (6, 2, 128, 2, 2, 1), (2, 2, 128, 2, 2, 1), (6, 3, 128, 1, 1, 1),
+    (15, 1, 128, 1, 1, 1), (16, 2, 512, 4, 2, 2), (16, 2, 1024, 2, 1, 1)])
+def test_the_heads_of_a_grid_step_are_whole_key_heads_and_fit_in_vmem(heads, rep, dim, forward, backward, key_heads):
+    """``heads_a_step`` from the operands alone, both forms: the most of 4, 2, 1 that divides the value heads, is a key
+    head's repetition or whole repetitions, and holds its blocks and temporaries in the kernel's share of VMEM (heads of
+    512 and 1,024 channels take fewer in the backward); the step's q and k block is ONE key head's where the repetition
+    allows, fetched once, and the grid is (heads / H, chunks)."""
+    from deepspeed_tpu.ops.pallas import kda as K
+
+    S = jax.ShapeDtypeStruct
+    q, vb = S((heads // rep, 2 * K.CHUNK, dim), jnp.bfloat16), S((heads, 2 * K.CHUNK, dim), jnp.bfloat16)
+    for g in (S((heads * 2, 1, K.CHUNK), jnp.float32),) + ((S(vb.shape, jnp.float32),) if rep == 1 else ()):
+        assert [K.heads_a_step(q, vb, g, False), K.heads_a_step(q, vb, g, True)] == [forward, backward]
+        H, block = forward, K._form(q, vb, g, lambda c: c, forward, False)[2](dim)
+        assert block.block_shape == (key_heads, K.CHUNK, dim)
+        # grid step b works on value heads b H .. b H + H - 1, whose key heads are (b H) // rep .. : the block that holds them
+        assert [block.index_map(b, 1)[0] * key_heads for b in range(heads // H)] == [b * H // rep for b in range(heads // H)]
+    if dim == 128:
+        assert f"grid=({heads // forward}, 2)" in str(jax.make_jaxpr(lambda *a: K.scan_fwd(*a, True))(q, q, vb, vb, g))
+
+
+@pytest.mark.parametrize("Hk,Hv,heads_a_step", [(2, 4, 4), (3, 6, 2), (3, 3, 1)])
+def test_the_per_head_kernel_under_bf16_operands_is_bf16_close(Hk, Hv, heads_a_step, highest):
+    """... and several heads a grid step give one head's bits under bf16 operands too."""
     from deepspeed_tpu.ops.kda import gdn_chunked, gdn_recurrence
 
-    q, k, v, g, beta = _scan_inputs(256, 0.9)
+    q, k, v, g, beta = _scan_inputs(256, 0.9, Hk=Hk, Hv=Hv)
     low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    ref.kernel_of_one_head_a_step((*low, g, beta.astype(jnp.bfloat16)), heads_a_step)
     w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
     run = lambda fn, *qkv: jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4))(*qkv, g, beta)
     got, want = run(lambda *a: gdn_chunked(*a, interpret=True), *low), run(gdn_recurrence, *(x.astype(jnp.float32) for x in low))

@@ -113,12 +113,15 @@ def _plain_delta_rule(q, k, v, g, beta):
     return sw(ref.delta_rule(sw(q), sw(k), sw(v), jnp.exp(sw(g)), sw(beta)))
 
 
-@pytest.mark.parametrize("S,decay", [(256, 0.9), (100, 0.9), (128, 0.999999), (70, 1e-9), (260, 0.02)])
-def test_the_kda_kernel_matches_the_token_recurrence(S, decay, highest):
-    """(b) interpret mode: lengths that are and are not whole chunks, decays near 1 and near 0 (where exp(-G) overflows)."""
+@pytest.mark.parametrize("S,decay,heads,heads_a_step", [
+    (256, 0.9, 2, 2), (100, 0.9, 2, 2), (128, 0.999999, 2, 2), (70, 1e-9, 2, 2), (260, 0.02, 2, 2),
+    (1000, 0.9, 4, 4), (256, 0.9, 6, 2), (100, 0.9, 3, 1), (260, 0.02, 4, 4)])
+def test_the_kda_kernel_matches_the_token_recurrence(S, decay, heads, heads_a_step, highest):
+    """(b) interpret mode: lengths that are and are not whole chunks, decays near 1 and near 0 (where exp(-G) overflows),
+    and 2, 4, 6 and 3 heads, which the rule walks 2, 4, 2 and 1 a grid step (an odd count is the kernel of one head)."""
     from deepspeed_tpu.ops.kda import kda_chunked, kda_recurrence
 
-    args = _scan_inputs(S, decay)
+    args = _scan_inputs(S, decay, H=heads)
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     run = lambda fn: jax.value_and_grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
     (lo, go), (lt, gt) = run(lambda *a: kda_chunked(*a, interpret=True)), run(kda_recurrence)
@@ -127,14 +130,40 @@ def test_the_kda_kernel_matches_the_token_recurrence(S, decay, highest):
     assert np.isfinite(float(lo))
     for a, b in zip(go, gt):
         _close(a, b, 1e-4)
+    ref.kernel_of_one_head_a_step(args, heads_a_step)
 
 
-def test_the_kda_kernel_under_bf16_operands_is_bf16_close(highest):
-    """bf16 q, k, v: the large products take bf16 operands and the triangular inverse three bf16 passes a product."""
+@pytest.mark.parametrize("form,heads,word", [("kda", 4, "4"), ("kda", 6, "2"), ("kda", 3, "1"), ("gdn", 4, "4")])
+def test_the_heads_a_grid_step_are_counted_where_the_kernel_is_traced(form, heads, word):
+    """``program_regions_traced_total{region="mixer/kernel", op, pass, path="kernel", heads_a_step}`` rises once a traced
+    call site, forward and backward, and the trainer's first-call key (the mixers' ``joined`` entry, whose third word
+    names the label) reads the same series."""
+    from deepspeed_tpu.models.mixers import GDNMixer, KDAMixer
+    from deepspeed_tpu.ops.kda import gdn_chunked, kda_chunked
+    from deepspeed_tpu.runtime import engine as trainer
+
+    q, k, v, g, beta = _scan_inputs(128, 0.9, H=heads)
+    chunked, g, record = (kda_chunked, g, KDAMixer) if form == "kda" else (gdn_chunked, g[..., 0], GDNMixer)
+    key, (region, words, label) = next(iter(record.joined.items()))
+    assert (key, region, label) == (f"{form}_heads_a_step", "mixer/kernel", "heads_a_step") and word in words
+    series = lambda pass_: get_registry().total("program_regions_traced_total", region=region, op=form, path="kernel",
+                                                heads_a_step=word, **{"pass": pass_})
+    before, said = [series("fwd"), series("bwd")], trainer._paths_traced([record])[key]
+    jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(chunked(*a, interpret=True)), argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    assert [series("fwd") - before[0], series("bwd") - before[1]] == [1, 1]
+    rose = [w for w, now, was in zip(words, trainer._paths_traced([record])[key], said) if now > was]
+    assert rose == [word]
+
+
+@pytest.mark.parametrize("heads", [2, 4, 3])
+def test_the_kda_kernel_under_bf16_operands_is_bf16_close(heads, highest):
+    """bf16 q, k, v: the large products take bf16 operands and the triangular inverse three bf16 passes a product;
+    several heads a grid step give one head's bits under them too."""
     from deepspeed_tpu.ops.kda import kda_chunked, kda_recurrence
 
-    q, k, v, g, beta = _scan_inputs(256, 0.9)
+    q, k, v, g, beta = _scan_inputs(256, 0.9, H=heads)
     low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v))
+    ref.kernel_of_one_head_a_step((*low, g, beta.astype(jnp.bfloat16)), {2: 2, 4: 4, 3: 1}[heads])
     w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
     run = lambda fn, *qkv: jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4))(*qkv, g, beta)
     got, want = run(lambda *a: kda_chunked(*a, interpret=True), *low), run(kda_recurrence, *(x.astype(jnp.float32) for x in low))

@@ -134,7 +134,8 @@ def _names(jaxpr, found):
 
 # a kernel's custom_vjp gives these where the kernel runs: off the TPU no trace shows them (``tests/unit/test_chip_compile.py``)
 KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
-WHEN = {"moe_cond": "the buffer is smaller than every pair"}  # a key that rises only then (``tests/unit/test_moe_sum_rows.py``)
+# a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
+WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])
