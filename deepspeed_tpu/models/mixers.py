@@ -251,8 +251,9 @@ class SparseMixer(LayerKind, nn.Module):
     backend takes), and ``choice``, the key-major mask."""
 
     cfg: TransformerFields
-    # kept: the choice, its attention call's output and row statistics, the index loss's cotangent (the flash call's where
-    # every visible key is chosen); a model has this mixer in every layer or in none, so its key is given though ``alone``
+    # kept: the choice, its attention call's output and row statistics, the index loss's cotangent, which the loss's one
+    # call writes beside the loss (the flash call's where every visible key is chosen); a model has this mixer in every
+    # layer or in none, so its key is given though ``alone``
     sows, keeps, hybrid = ("intermediates",), (sparse.SAVED, FLASH_SAVED, SAVED), True
     paths, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, True
 
@@ -302,8 +303,7 @@ class SparseMixer(LayerKind, nn.Module):
             with region("mixer/kernel"):
                 out, lse = sparse.sparse_attention(q, k, v, mask_t, scale=scale, path=path)
             with region("mixer/index_loss"):
-                probs_t = sparse.head_probs(q, k, lse, mask_t, scale=scale, path=path)
-                loss = sparse.index_loss(q_i, k_i, w, scores_t, probs_t, mask_t, dtype=cfg.dtype, path=path)
+                loss = sparse.index_loss(q_i, k_i, w, scores_t, q, k, lse, mask_t, scale=scale, dtype=cfg.dtype, path=path)
                 chosen = jnp.sum(mask_t.astype(f32))
             self.sow("intermediates", "index_loss", loss)
             self.sow("intermediates", "choice", mask_t)  # key-major (B, Sk, Sq) int8: for a caller that asks; a step drops it

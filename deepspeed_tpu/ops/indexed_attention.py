@@ -11,8 +11,9 @@ the kernels' choice also says, as ``counted``, the share of a band's rows its
 count passes walk).
 
 Square arrays are key-major, ``(B, Sk, Sq)``, as the kernels keep them
-(``scores_t``, ``mask_t``, ``probs_t``): nothing outside this file and the
-mixer reads them.
+(``scores_t``, ``mask_t``, the index loss's gradient in the scores; XLA's
+form has the loss's target ``probs_t`` too, which the kernels never write):
+nothing outside this file and the mixer reads them.
 """
 
 import functools
@@ -26,9 +27,10 @@ from .pallas import indexed_attention as kernel
 from .registry import pallas_available
 
 NEG_INF = kernel.NEG_INF
-# The name the choice, the attention call's output and row statistics and the index loss's cotangent carry for a
-# checkpoint policy (``models/transformer.py::remat_keeps``): a checkpointed block that keeps them runs neither the
-# indexer's scores, nor the choice, nor a forward kernel, nor the head-summed probabilities a second time
+# The name the choice, the attention call's output and row statistics and the index loss's cotangent (the loss's
+# gradient in the scores, an output of the ``index_loss`` call) carry for a checkpoint policy (``models/transformer.py::
+# remat_keeps``): a checkpointed block that keeps them runs neither the indexer's scores, nor the choice, nor a
+# forward kernel, nor the index loss's call a second time
 SAVED = "sparse_attention"
 
 
@@ -170,17 +172,6 @@ def sparse_attention(q, k, v, mask_t, *, scale: float, path: str):
     return _sparse_kernel(q, k, v, mask_t, scale, _interpret())
 
 
-def head_probs(q, k, lse, mask_t, *, scale: float, path: str):
-    """The index loss's target before it is normalised: no gradient reaches q, k or the statistics through it."""
-    _count_traced("probs", path)
-    q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
-    if path != "kernel":
-        return head_probs_xla(q, k, lse, mask_t, scale)
-    S, H = q.shape[1:3]
-    tiles = kernel.tiled(mask_t, kernel.block_for(S))
-    return kernel.sparse_probs(_to_bh(q), _to_bh(k), lse.reshape(-1, S), tiles, scale, H, k.shape[2], interpret=_interpret())
-
-
 # ----------------------------------------------------------------------
 # the indexer's loss
 # ----------------------------------------------------------------------
@@ -194,33 +185,38 @@ def _index_loss_and_grad(scores_t, probs_t, mask_t):
     return jnp.mean(kl), (jnp.where(chosen, jnp.exp(log_q), 0.0) - p) / kl.size
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _index_loss_kernel(q_i, k_i, w, scores_t, probs_t, mask_t, dtype, interpret):
-    return _index_loss_and_grad(scores_t, probs_t, mask_t)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _index_loss_kernel(q_i, k_i, w, scores_t, q, k, lse, mask_t, scale, dtype, interpret):
+    return _index_loss_fwd(q_i, k_i, w, scores_t, q, k, lse, mask_t, scale, dtype, interpret)[0]
 
 
-def _index_loss_fwd(q_i, k_i, w, scores_t, probs_t, mask_t, dtype, interpret):
-    loss, grad = _index_loss_and_grad(scores_t, probs_t, mask_t)
-    return loss, (q_i, k_i, w, checkpoint_name(grad.astype(dtype), SAVED))
+def _index_loss_fwd(q_i, k_i, w, scores_t, q, k, lse, mask_t, scale, dtype, interpret):
+    S, H = q.shape[1:3]
+    tiles = kernel.tiled(mask_t, kernel.block_for(S))
+    kl, grad = kernel.index_loss(_to_bh(q), _to_bh(k), lse.reshape(-1, S), tiles, scores_t, scale, H, k.shape[2], dtype, interpret=interpret)
+    return jnp.mean(kl), (q_i, k_i, w, checkpoint_name(grad, SAVED))
 
 
-def _index_loss_bwd(dtype, interpret, res, g):
+def _index_loss_bwd(scale, dtype, interpret, res, g):
     q_i, k_i, w, grad = res
     _count_traced("index_bwd", "kernel")
     dq, dk, dw = kernel.index_scores_bwd(grad, q_i, k_i, w, interpret=interpret)
-    return (g * dq).astype(q_i.dtype), (g * dk).astype(k_i.dtype), g * dw, None, None, None
+    return (g * dq).astype(q_i.dtype), (g * dk).astype(k_i.dtype), g * dw, None, None, None, None, None
 
 
 _index_loss_kernel.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
-def index_loss(q_i, k_i, w, scores_t, probs_t, mask_t, *, dtype, path: str):
-    """The indexer's own loss, ``mean_t KL(p[t, S_t] || softmax_{S_t} I[t, .])``, from the key-major scores,
-    head-summed probabilities (no gradient: the target) and choice. In XLA's form the gradient reaches the indexer
-    through ``scores_t``. The kernels' form keeps ONE square array for its backward, the loss's gradient in the scores
-    in ``dtype`` (the model's: a cotangent like any other) under the name ``SAVED``, and ``index_scores_bwd`` carries
-    it to ``q_i``, ``k_i`` and ``w``."""
-    probs_t = jax.lax.stop_gradient(probs_t)
+def index_loss(q_i, k_i, w, scores_t, q, k, lse, mask_t, *, scale: float, dtype, path: str):
+    """The indexer's own loss, ``mean_t KL(p[t, S_t] || softmax_{S_t} I[t, .])``: ``p`` is the heads' probabilities
+    over the chosen pairs, summed and renormalised, from the main heads' (B, S, H, D) ``q`` and ``k`` and the forward's
+    ``lse`` (the target: no gradient reaches them), ``I`` the key-major scores. In XLA's form the target is a square
+    array and the gradient reaches the indexer through ``scores_t``. The kernels' form is ONE call that makes the
+    target a tile at a time and finishes the loss there: it keeps ONE square array for its backward, the loss's
+    gradient in the scores in ``dtype`` (the model's: a cotangent like any other) under the name ``SAVED``, and
+    ``index_scores_bwd`` carries it to ``q_i``, ``k_i`` and ``w``."""
+    _count_traced("loss", path)
+    q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
     if path != "kernel":
-        return _index_loss_and_grad(scores_t, probs_t, mask_t)[0]
-    return _index_loss_kernel(q_i, k_i, w, jax.lax.stop_gradient(scores_t), probs_t, mask_t, dtype, _interpret())
+        return _index_loss_and_grad(scores_t, head_probs_xla(q, k, lse, mask_t, scale), mask_t)[0]
+    return _index_loss_kernel(q_i, k_i, w, jax.lax.stop_gradient(scores_t), q, k, lse, mask_t, scale, dtype, _interpret())

@@ -27,8 +27,18 @@ calls, named apart from the flash kernels' on the device's clock:
   the mask, cut into the kernels' own (bk, bq) tiles, in place of the causal
   compare: every block at or below the diagonal is visited (a learned choice
   leaves no block empty), the chosen pairs alone enter the softmax;
-- ``sparse_probs``: the head-summed probabilities over the chosen pairs, from
-  the forward's row statistics: the target of the indexer's own loss.
+- ``index_loss``: the indexer's own loss, finished where its target is made.
+  A query block walks its key blocks twice. The first sweep makes the target
+  a tile at a time, ``P[s, t] = sum_h exp(q_h[t] . k[s] * scale - lse_h[t])``
+  over the chosen pairs (from the forward's row statistics), keeps the
+  block's strip of it in VMEM, (Sk, bq) float32, and adds up a query's
+  statistics along its keys: ``Z = sum P``, the chosen scores' online max and
+  sum (``lse_I``) and ``sum P (log P - I)``, from which
+  ``KL_t = sum P (log P - I) / Z - log Z + lse_I``. The second sweep reads
+  the strip and the scores' and mask's tiles again and writes the gradient of
+  the queries' mean in the scores, ``(softmax_{S_t} I - P / Z) / queries``,
+  rounded once to the model's dtype: the one square array the backward
+  keeps. The head-summed probabilities never reach HBM.
 
 A masked score is ``NEG_INF`` (finite): a query whose first visited blocks hold
 none of its keys carries ``m = NEG_INF`` and a sum of ones until its first key
@@ -451,41 +461,94 @@ def sparse_bwd(q, k, v, o, lse, do, mask_tiles, scale: float, H: int, KVH: int, 
     )(q, k, v, do, _rows(lse, blk), _rows(delta, blk), mask_tiles)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, o_ref, *, heads: int, n_rep: int, scale: float):
+def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, g_ref, strip, z_ref, m_ref, l_ref, d_ref, *,
+                 heads: int, n_rep: int, scale: float, queries: int):
+    """A query block's walk along its key blocks, twice: grid (B, S/blk, 2 S/blk). Steps ``j < n`` make the target's
+    tiles ``P`` (kept in ``strip``) and the rows' statistics; steps ``n + j`` write the gradient's tiles."""
     i, j = pl.program_id(1), pl.program_id(2)
+    n = pl.num_programs(2) // 2
 
-    @pl.when(j > i)
-    def _above():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
+    @pl.when(j == 0)
+    def _start():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        for ref in (z_ref, l_ref, d_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     @pl.when(j <= i)
-    def _block():
+    def _target():
         chosen = _chosen(mask_ref[0, 0, 0])
 
         def head(h, acc):
             s = jax.lax.dot_general(k_ref[h // n_rep], q_ref[h], _NT, preferred_element_type=jnp.float32) * scale
             return acc + jnp.exp(jnp.where(chosen, s, NEG_INF) - lse_ref[h, 0])
 
-        o_ref[0] = jax.lax.fori_loop(0, heads, head, jnp.zeros(o_ref.shape[1:], jnp.float32))
+        p = jax.lax.fori_loop(0, heads, head, jnp.zeros(strip.shape[1:], jnp.float32))
+        strip[j] = p
+        scores = jnp.where(chosen, s_ref[0], NEG_INF)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
+        l_ref[...] = l_ref[...] * jnp.exp(m - new_m) + jnp.sum(jnp.exp(scores - new_m), axis=0, keepdims=True)
+        m_ref[...] = new_m
+        z_ref[...] += jnp.sum(p, axis=0, keepdims=True)
+        some = p > 0.0  # a chosen pair's p can underflow: it adds nothing, as in XLA's form
+        d_ref[...] += jnp.sum(jnp.where(some, p * (jnp.log(jnp.where(some, p, 1.0)) - scores), 0.0), axis=0, keepdims=True)
+
+    @pl.when(j == n)
+    def _loss():  # sum_s p (log p - log softmax I) with p = P / Z
+        z = z_ref[...]
+        kl_ref[0, 0] = d_ref[...] / z - jnp.log(z) + m_ref[...] + jnp.log(l_ref[...])
+
+    @pl.when(jnp.logical_and(j >= n, j - n <= i))
+    def _gradient():
+        lse_i = m_ref[...] + jnp.log(l_ref[...])
+        soft = jnp.where(_chosen(mask_ref[0, 0, 0]), jnp.exp(s_ref[0] - lse_i), 0.0)
+        g_ref[0] = ((soft - strip[j - n] * (1.0 / z_ref[...])) * (1.0 / queries)).astype(g_ref.dtype)
+
+    @pl.when(j - n > i)
+    def _above():
+        g_ref[0] = jnp.zeros_like(g_ref[0])
 
 
-def sparse_probs(q, k, lse, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
-    """The probabilities of every head summed, key-major (B, S, S) float32: exp(score - lse) over the chosen pairs."""
+def loss_vmem(S: int, D: int, H: int, KVH: int, item: int, blk: int) -> int:
+    """What ``index_loss`` holds in VMEM at once: a query block's strip of ``P``, the heads' q and the KV heads' block
+    twice (the pipeline's), the tiles of the scores, the mask and the gradient twice, and a tile's temporaries."""
+    return S * blk * 4 + 2 * (H + KVH) * blk * D * item + 2 * blk * blk * (4 + 1 + 4) + _tile_bytes(blk, blk)
+
+
+def index_loss(q, k, lse, mask_tiles, scores_t, scale: float, H: int, KVH: int, dtype, *, interpret: bool = False):
+    """The indexer's loss where its target is made: q (B*H, S, D), k (B*KVH, S, D), the forward's lse (B*H, S), the mask
+    in tiles and I^T (B, S, S) float32 -> every query's ``KL(p || softmax_{S_t} I)`` (B, S) float32, ``p`` the heads'
+    probabilities over the chosen pairs summed and renormalised, and the gradient of the queries' MEAN in I^T, (softmax
+    - p) / queries, key-major (B, S, S) in ``dtype``. The head-summed probabilities never leave VMEM."""
     BH, S, D = q.shape
+    B = BH // H
     blk, n = mask_tiles.shape[-1], mask_tiles.shape[1]
-    return pl.pallas_call(
-        functools.partial(_probs_kernel, heads=H, n_rep=H // KVH, scale=scale),
-        grid=(BH // H, n, n),
+    need = loss_vmem(S, D, H, KVH, q.dtype.itemsize, blk)
+    if need > vmem_budget():
+        raise NotImplementedError(f"index_loss: a query block's strip of probabilities at {S} positions and {H} heads' q of D={D} "
+                                  f"take {need >> 20} MiB of VMEM, over {vmem_budget() >> 20} MiB")
+    # the key block a step works on: ``j`` in the first sweep, ``j - n`` in the second, and the diagonal's where it has
+    # passed the diagonal (a step that fetches nothing new)
+    key = lambda i, j: jnp.minimum(j % n, i)
+    kl, grad = pl.pallas_call(
+        functools.partial(_loss_kernel, heads=H, n_rep=H // KVH, scale=scale, queries=B * S),
+        grid=(B, n, 2 * n),
         in_specs=[
             pl.BlockSpec((H, blk, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((KVH, blk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((KVH, blk, D), lambda b, i, j: (b, jnp.minimum(j, i), 0)),  # the first sweep's alone
             pl.BlockSpec((H, 1, 1, blk), lambda b, i, j: (b, i, 0, 0)),
-            pl.BlockSpec((1, 1, 1, blk, blk), lambda b, i, j: (b, j, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, blk, blk), lambda b, i, j: (b, key(i, j), i, 0, 0)),
+            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, key(i, j), i)),
         ],
-        out_specs=pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, j, i)),
-        out_shape=jax.ShapeDtypeStruct((BH // H, S, S), jnp.float32),
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, blk), lambda b, i, j: (b, i, 0, 0)),
+            # the first sweep stays on the tile the second writes first: no tile leaves before it is made
+            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, jnp.maximum(j - n, 0), i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, n, 1, blk), jnp.float32), jax.ShapeDtypeStruct((B, S, S), dtype)],
+        scratch_shapes=[pltpu.VMEM((n, blk, blk), jnp.float32)] + [pltpu.VMEM((1, blk), jnp.float32)] * 4,
         interpret=interpret,
-        name="sparse_probs",
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret,
-                                         vmem_bytes=2 * (H + KVH) * blk * D * q.dtype.itemsize + 2 * blk * blk * 5 + _tile_bytes(blk, blk)),
-    )(q, k, _rows(lse, blk), mask_tiles)
+        name="index_loss",
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary", interpret=interpret, vmem_bytes=need),
+    )(q, k, _rows(lse, blk), mask_tiles, scores_t)
+    return kl.reshape(B, S), grad

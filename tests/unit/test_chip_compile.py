@@ -214,7 +214,7 @@ def _indexed(which):
         "index_select": (lambda s: K.index_select(s, 2048), (square,), 1),
         "sparse_fwd": (lambda q, k, v, m: K.sparse_fwd(q, k, v, m, scale, Hq, KVH), (q, kv, kv, tiles), 1),
         "sparse_bwd": (lambda q, k, v, o, lse, do, m: K.sparse_bwd(q, k, v, o, lse, do, m, scale, Hq, KVH), (q, kv, kv, q, rows, q, tiles), 1),
-        "sparse_probs": (lambda q, k, lse, m: K.sparse_probs(q, k, lse, m, scale, Hq, KVH), (q, kv, rows, tiles), 1),
+        "index_loss": (lambda q, k, lse, m, s: K.index_loss(q, k, lse, m, s, scale, Hq, KVH, BF16), (q, kv, rows, tiles, square), 1),
         "index_scores_bwd": (lambda g, q_i, k_i, w: K.index_scores_bwd(g, q_i, k_i, w), (S((1, Sq, Sq), BF16), q_i, k_i, w), 1),
     }[which]
 
@@ -243,7 +243,7 @@ CASES = {
     "flash_mha_b1_s32768_h2_d128": lambda: _flash((1, 32768, 2, 2, 128), "refused"),  # 77 MiB resident: over the budget
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
-       for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "sparse_probs", "index_scores_bwd")},
+       for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},
     "fused_adam_mlp_768x3072": lambda: _fused_adam((768, 3072)),
     "fused_adam_bias_768": lambda: _fused_adam((768,)),
     "layer_norm_t264_d768": lambda: _norm("layer_norm"),
