@@ -12,6 +12,7 @@
     python chip_smoke.py --only deltanet  # ... and its fourth's: Gated DeltaNet 3:1 with gated GQA, softmax top-10 of 512
     python chip_smoke.py --only sparse   # ... and its fifth's: GQA over the keys a learned indexer chooses, 8 of 128 experts
     python chip_smoke.py --only sambay   # ... and its sixth's: Mamba-1 scans, differential attention, a second half that reads the first's
+    python chip_smoke.py --only blockdiff  # ... and its seventh's: block-diffusion training, GQA under the block mask over a doubled row, 8 of 128 experts
 
 Everything runs in this one process (a chip belongs to one process), at the
 full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
@@ -1001,8 +1002,58 @@ def _sambay_leaf(tree, name):
     return leaf.astype(jnp.float32)
 
 
-# a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, and its controls
-# (a name and what is wrong with the plain bf16 reference under it)
+# SDAR-30B-A3B's four layers in block-diffusion TRAINING (``--only blockdiff``): grouped-query attention under the block
+# mask over a doubled row ``[noised ; clean]`` of 2 x 8,192 ids (the traffic's generator makes the row and its noise), every
+# FFN softmax-routed (8 of 128, 16 held), a masked-token loss weighted a block over the noised half. The logits compared
+# are the noised half's; EVERY leaf of the gradient is read (a leaf's name is its path, as in the sambay phase). Four
+# controls, each the plain bf16 reference with one thing wrong, and each has to break a limit on every seed: a causal mask
+# over the 2 L row (``causal``), the noised half blind to the clean one (``blind``), every masked position weighing one in
+# place of B / m (``uniform``: the logits are the reference's own, its gradient is another loss's) and a target shifted
+# by one (``shift``: likewise). The limits and the readings they were set from: PERF.md section 6, PR 49.
+BLOCKDIFF_PARTS = {"blockdiff": ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm", "k_norm"),
+                   "routed": ("gate", "experts_wg", "experts_wi", "experts_wo")}
+# Limits from five seeds (0, 11, 101, set from these three; 2024, 31337, which then passed; my chip runs, PR 49: published
+# widths, 4 layers, 2 x 8,192 positions, the usual start), each between the LARGEST reading of the program and the SMALLEST
+# of a control, by class of leaf (its path without the layer). NOT judged, only reported (a limit of None): the routers' own
+# gradients, which flips of the top 8 of 128 rule in the program and in the plain bf16 path alike (HYBRID_LIMITS says the
+# same of its router): 0.015 to 0.53 for the program, 0.39 at most for the plain bf16 reference with nothing wrong
+# (``plain_bf16``, reported beside the program, judged by nothing), where the ``uniform`` control reads 0.19 to 0.59. The
+# routed layer's other leaves (a held expert's three matrices and the norm ahead of the router) read the same flips through
+# the experts' rows, a seed at a time: the program 0.017 to 0.148, the plain bf16 reference up to 0.202, the smallest control
+# 0.457.
+BLOCKDIFF_LEAF_LIMIT = 0.1   # every other leaf: the program 0.0068-0.0509 (q/k projections and norms 0.016-0.051, the rest under 0.017) | plain bf16 0.054 at most | the smallest control 0.405
+BLOCKDIFF_CLASS_LIMITS = {
+    "logits": 0.05,          # the noised half's: 0.0075-0.0091 | plain bf16 0.0083 | causal 1.32, blind 1.37 (``uniform`` and ``shift`` have the reference's own logits)
+    "routed/gate": None,
+    **{f"routed/experts_{m}": 0.27 for m in ("wg", "wi", "wo")},  # 0.0173-0.1481 | plain bf16 0.200 | 0.504 (the limit: sqrt of the two ends' product)
+    "RMSNorm_1/scale": 0.27,  # the norm ahead of the router: 0.0206-0.1217 | plain bf16 0.202 | 0.457
+}
+
+
+def _blockdiff_names():
+    names = ["wte", "lm_head", "RMSNorm_0/scale"]
+    for i in range(4):
+        names += [f"layer_{i}/{norm}/scale" for norm in ("RMSNorm_0", "RMSNorm_1")]
+        names += [f"layer_{i}/{part}/{leaf}" for part, leaves in BLOCKDIFF_PARTS.items() for leaf in leaves]
+    return names
+
+
+BLOCKDIFF_LIMITS = {"logits": BLOCKDIFF_CLASS_LIMITS["logits"],
+                    **{name: BLOCKDIFF_CLASS_LIMITS.get(_sambay_class(name), BLOCKDIFF_LEAF_LIMIT) for name in _blockdiff_names()}}
+
+
+def _blockdiff_rows(cfg, seed):
+    """One row ``[xt ; x0]`` of the cell's traffic (its generator's noise) at the program's length."""
+    from benchmarks.lib import manifest as mf
+
+    gen = mf.load_module(f"{mf.BENCH}/generators/block_diffusion_batches.py")
+    params = {"seq_len": cfg["program"]["max_seq_len"], "block_len": cfg["program"]["block_length"], "n_batches": 1}
+    return gen.generate(params, seed, 0.0, {"vocab_size": cfg["program"]["vocab_size"], "global_batch": 1})["batches"][0]["input_ids"]
+
+
+# a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, its controls
+# (a name and what is wrong with the plain bf16 reference under it) and, where the rows are not uniform ids of the
+# program's length, what makes them
 SMOKE_MODELS = {
     "hybrid": ("benchmarks/configs/kimi-linear-48b-l5e8.json", HYBRID_LIMITS, _hybrid_leaf, {"control": {"low_state": True}}),
     "latent": ("benchmarks/configs/kimi-vl-a3b-l6e8.json", LATENT_LIMITS, _latent_leaf,
@@ -1012,6 +1063,13 @@ SMOKE_MODELS = {
     "sambay": ("benchmarks/configs/phi4-mini-flash-l6.json", SAMBAY_LIMITS, _sambay_leaf,
                {"no_window": {"no_window": True}, "no_lambda": {"no_lambda": True}, "gated_memory": {"gated_memory": True},
                 "own_keys": {"own_keys": True}, "low_state": {"low_state": True}}),
+    "blockdiff": ("benchmarks/configs/sdar-30b-a3b-l4e16.json", BLOCKDIFF_LIMITS, _sambay_leaf,
+                  {"causal": {"mask": "causal"}, "blind": {"mask": "blind"}, "uniform": {"uniform_weights": True}, "shift": {"shift": True}},
+                  # the usual start (q/k norms at one), not the cell's (at 3, which keeps the router's load even): where every
+                  # position's vector is its own, a position's output hangs on its own top 8 of 128 and on the one or two keys
+                  # its query picks, which bf16 rounding flips for a few positions in a hundred, in the program and in the
+                  # plain bf16 reference alike: a measure that reads the flips reads no arithmetic
+                  {"rows": _blockdiff_rows, "program": {"blockdiff_qk_init_scale": 1.0}, "report_plain": True}),
 }
 
 
@@ -1024,15 +1082,21 @@ def f32_readings(which, seed):
     of the judged leaves, every one |x - x_f32| / |x_f32|."""
     from benchmarks.lib import manifest as mf, reference, weights
 
-    path, limits, leaf_of, controls = SMOKE_MODELS[which]
+    path, limits, leaf_of, controls, *own = SMOKE_MODELS[which]
+    own = own[0] if own else {}  # what is the model's own: its rows, fields of the program that differ from the cell's
+    rows_of = own.get("rows")  # what makes the rows, where they are not uniform ids
     cfg = mf.load_json(os.path.join(os.path.dirname(os.path.abspath(__file__)), path))
     if REHEARSE:
         r = dict(cfg["rehearse"])
         cfg = dict(cfg, **{k: v for k, v in r.pop("published", {}).items()})
         cfg.update(program=dict(cfg["program"], **r["program"]), reference=r["reference"])
+    cfg = dict(cfg, program=dict(cfg["program"], **own.get("program", {})))
     model = weights.build_model(cfg)
     seq = cfg["program"]["max_seq_len"]
-    ids = jnp.asarray(np.random.default_rng([seed, 5]).integers(0, cfg["program"]["vocab_size"], (1, seq), np.int32))
+    if rows_of:
+        ids = jnp.asarray(rows_of(cfg, seed))
+    else:
+        ids = jnp.asarray(np.random.default_rng([seed, 5]).integers(0, cfg["program"]["vocab_size"], (1, seq), np.int32))
     params = jax.jit(lambda k: model.init(k, {"input_ids": np.zeros((1, seq), np.int32)}))(weights.seed_key(seed))
     ref_logits, ref_loss = reference.for_config(cfg)
     pub = mf.published(cfg)
@@ -1043,19 +1107,28 @@ def f32_readings(which, seed):
     def leaves_of(grads):  # the leaves compared, and nothing else of a 2.4 GB tree; on the host, where every leaf is judged
         return {name: np.asarray(leaf_of(grads, name)) for name in judged}
 
+    ref_module = mf.load_module(os.path.join(mf.ROOT, cfg["reference"]["module"]))
+
     def plain(dtype, **over):
         rc = dict(cfg["reference"], **over)
         logits = ref_logits(params, ids, pub, rc, dtype)
+        if rows_of:  # a model with rows of its own has a loss of its own, which reads its controls (weights, targets) from ``rc``
+            return logits, leaves_of(ref_module.loss_and_grads(params, ids, pub, rc, dtype)[1])
         return logits, leaves_of(jax.grad(lambda p: ref_loss(ref_logits(p, ids, pub, rc, dtype), ids))(params))
 
+    # the positions the model's objective runs its head over (``TransformerConfig.objective``): every one, or the noised half
+    head = model.cfg.objective.targets(model.cfg, ids)[0] if model.cfg.objective is not None else slice(None)
+
     def ours():
-        logits = jax.jit(lambda p: model.apply(p, ids))(params)
+        logits = jax.jit(lambda p: model.apply(p, ids)[:, head])(params)
         return logits, leaves_of(jax.jit(jax.grad(lambda p: model.loss_fn(p, {"input_ids": ids})))(params))
 
     # one contestant at a time: the float32 reference's gradient alone takes most of the chip at 8192 tokens
     truth_logits, truth = plain(jnp.float32)
     readings = {name: {} for name in limits}
     contestants = [("ours", ours)] + [(name, functools.partial(plain, jnp.bfloat16, **wrong)) for name, wrong in controls.items()]
+    if own.get("report_plain"):  # the plain bf16 reference with nothing wrong: read beside the program, judged by nothing
+        contestants.append(("plain_bf16", functools.partial(plain, jnp.bfloat16)))
     for who, run in contestants:
         logits, leaves = run()
         readings["logits"][who] = rel(logits, truth_logits)
@@ -1075,6 +1148,7 @@ def f32_phase(which):
     report = {name: dict(readings[name], limit=limit) for name, limit in limits.items()}
     over = lambda who: [k for k, v in report.items() if v["limit"] is not None and not v[who] <= v["limit"]]  # None: read, not judged
     failed_ours, failed_controls = over("ours"), {name: over(name) for name in controls}
+    print(json.dumps({"phase": f"{which}_readings", "seed": ARGS.seed, "compared": report}), flush=True)  # whole, whatever the verdict
     if not REHEARSE:  # the limits are the published widths': at a tiny width bf16 flips routes and proves nothing
         check(not failed_ours, f"the {which} model lies further from its float32 reference than allowed in {failed_ours}: {report}")
         passed = [name for name, failed in failed_controls.items() if not failed]

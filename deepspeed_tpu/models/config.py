@@ -126,6 +126,16 @@ class TransformerFields:
     # where the stack is a cut of a deeper model: the published index of each layer, which a kind that takes ``layer`` reads
     # (differential attention's lambda starts from it); None: 0 .. n_layers - 1
     layer_numbers: Optional[Tuple[int, ...]] = None
+    # blockdiff (block-diffusion training): a row is ``[noised ; clean]``, each half a whole number of blocks of
+    # ``block_length`` positions; ``mask_token_id`` is the id a noised position carries (an ordinary row of the embedding).
+    # The mixer's positions and mask, and the loss's targets and weights, follow from these and the row's ids alone
+    block_length: int = 0
+    mask_token_id: int = 0
+    # blockdiff: the per-head q/k norms' weights start at this (1: flax's ones). At one, attention over thousands of keys
+    # is an average and a third of a row's positions carry the SAME mask token, so they reach a random router as one
+    # vector and its load is a seed's draw; at 3 the scores' deviation is 9, a query picks a few keys as a trained model's
+    # does, every position receives a vector of its own and the router's load is even
+    blockdiff_qk_init_scale: float = 1.0
 
     @property
     def kv_heads(self) -> int:

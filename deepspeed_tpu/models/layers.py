@@ -38,7 +38,8 @@ class LayerKind:
     # the trainer's first-call line. ``paths``: key -> (region, labels) of ``program_regions_traced_total``; the key's
     # word is ``xla`` where only ``path="xla"`` call sites rose, ``mixed``, else ``kernel`` (or ``path_words[key]``).
     # ``joined``: key -> (region, the ``path`` labels of it that may rise[, another label than ``path`` whose values they
-    # are]): the word is those that rose, "+" between.
+    # are]): the word is those that rose, "+" between; ``None`` for the labels: whatever values the sites gave (a number
+    # a site worked out, as the tiles a mask's walk visits).
     # ``alone``: a model whose layers are all of one kind says nothing of kinds on that line, unless this
     paths, path_words, joined, alone = {}, {}, {}, False
     stackable = False  # the scan over layers, ``to_pipeline`` and ``inference/v2`` can run it
@@ -47,6 +48,10 @@ class LayerKind:
     # earlier layer that gave the name (``layer``, the layer's own published index as an int32 scalar, is the model's to
     # give). The gradient flows back through them. A model with either is run by the unrolled loop alone
     gives, takes = (), ()
+    # ``targets(cfg, input_ids)``: a kind whose OBJECTIVE is not next-token prediction over the whole row gives the loss
+    # head (the hidden states' positions it runs over, their targets, a float32 weight a target, what the weighted sum is
+    # divided by), all made from the ids (``CausalLM.loss_fn``); None: the model's own (every position predicts the next)
+    targets = None
 
     @classmethod
     def from_config(cls, cfg, kind: str):
@@ -63,10 +68,11 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.float32
     offset: bool = False  # gemma: weights zero-centered, applied as (1 + w)
+    init_scale: float = 1.0  # what the weights start at (with ``offset``: zero, whatever this says)
 
     @nn.compact
     def __call__(self, x):
-        init = nn.initializers.zeros if self.offset else nn.initializers.ones
+        init = nn.initializers.zeros if self.offset else nn.initializers.constant(self.init_scale)
         scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)

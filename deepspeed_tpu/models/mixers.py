@@ -7,7 +7,9 @@ decoder-hybrid-decoder stack (SambaY): a Mamba-1 selective scan (``SSMMixer``),
 differential attention (``DiffAttention``), and the second half's two, which
 read what the first half made instead of making their own: a gate on the scan's
 output (``GatedMemory``) and differential attention over the first half's keys
-and values (``DiffCrossAttention``). All are training-side
+and values (``DiffCrossAttention``); and grouped-query attention under the
+block-diffusion mask over a doubled row, whose objective is a masked-token loss
+(``BlockDiffMixer``). All are training-side
 modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
 ``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
 carries its kind's record (``layers.py::LayerKind``).
@@ -22,6 +24,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import indexed_attention as sparse
+from ..ops import masks
 from ..ops.attention import attention
 from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, kda
 from ..ops.registry import pallas_available
@@ -455,3 +458,88 @@ class GatedMemory(LayerKind, nn.Module):
             gated = scan_out * nn.silu(gate)
         with region("mixer/proj"):
             return dense(cfg.d_model, "out_proj")(gated)
+
+
+def _count_diffusion(counts):
+    reg = get_registry()
+    reg.counter("diffusion_masked_positions_total").inc(float(counts[0]))
+    reg.counter("diffusion_positions_total").inc(float(counts[1]))
+    reg.gauge("diffusion_weight_sum").set(float(counts[2]))
+
+
+class BlockDiffMixer(LayerKind, nn.Module):
+    """Grouped-query attention of a block-diffusion model in TRAINING (BD3-LM's vectorised form, which SDAR's follows).
+    A row of the batch is ``[xt ; x0]``: ``L`` noised ids, then the ``L`` clean ones, each half ``L / block_length``
+    whole blocks. Index ``p`` of the ``2 L``: ``pos(p) = p mod L`` (the rotation's position, whatever ``positions`` the
+    model hands in: they count the row), ``blk(p) = pos(p) // block_length``. The main heads are ``Attention``'s (q, k,
+    v projections, the per-head q/k norms under ``qk_norm``, the rotation at ``pos``); a query keeps, of the keys
+    (``ops/masks.py::BlockDiffusion``): noised -> noised of its OWN block; noised -> clean of EARLIER blocks; clean ->
+    clean of its own and earlier blocks; clean -> noised never. ``L^2 + L block_length`` pairs of ``4 L^2``.
+
+    The kind's objective is not next-token prediction (``targets``): the head runs over the noised half alone, position
+    i predicts ``x0[i]`` (no shift), and a masked position weighs ``block_length / m`` with ``m`` the masked positions
+    of its block, all read from the ids: ``loss = (1 / L) sum_{i masked} (block_length / m_blk(i)) CE_i``, the mean over
+    the batch's rows.
+
+    No KV cache and no packed segments (generation denoises a block in several steps against the clean prefix's cache:
+    ``inference/v2`` has no such step); ``blockdiff_qk_init_scale`` is where the q/k norms' weights start (``config.py``
+    says why a routed model wants them above one)."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (FLASH_SAVED, SAVED), True
+    paths, alone = {"blockdiff_path": ("mixer/kernel", {"op": "blockdiff", "pass": "fwd"})}, True
+    # static at trace time, counted where the kernel's walk is chosen: tiles visited / tiles of the square, pairs kept
+    joined = {"blockdiff_tiles": ("mixer/kernel", None, "tiles"), "blockdiff_pairs": ("mixer/kernel", None, "pairs")}
+
+    @staticmethod
+    def halves(cfg, S: int) -> int:
+        """L of a row of ``S`` ids, or in words what a row must be."""
+        if cfg.block_length < 1 or S % 2 or (S // 2) % cfg.block_length:
+            raise ValueError(f"a block-diffusion row is [noised ; clean]: an even count of ids, each half a whole number of "
+                             f"blocks of block_length={cfg.block_length}; got {S}")
+        return S // 2
+
+    @staticmethod
+    def targets(cfg, input_ids):
+        """-> (the hidden states' positions the head runs over, their targets, a weight a target), all from the ids:
+        compare with the mask token, count a block, divide. The counts leave the step for the registry
+        (``diffusion_masked_positions_total`` / ``diffusion_positions_total``, and the weights' sum a row, which is L
+        whenever every block masks a position)."""
+        B, S = input_ids.shape
+        L, Bl = BlockDiffMixer.halves(cfg, S), cfg.block_length
+        masked = (input_ids[:, :L] == cfg.mask_token_id).reshape(B, L // Bl, Bl)
+        m = jnp.sum(masked, axis=-1, keepdims=True).astype(jnp.float32)  # masked positions a block
+        weights = jnp.where(masked, Bl / jnp.maximum(m, 1.0), 0.0).reshape(B, L)
+        device_counts.report("diffusion", jnp.stack([jnp.sum(m), jnp.asarray(B * L, jnp.float32), jnp.sum(weights) / B]),
+                             _count_diffusion)
+        return slice(0, L), input_ids[:, L:], weights, B * L
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        if kv_cache is not None or segment_ids is not None:
+            raise NotImplementedError("a blockdiff layer is block-diffusion TRAINING over a doubled row: it takes no KV cache "
+                                      "(generation denoises a block in several steps, which inference/v2 does not run) and no "
+                                      "packed segments")
+        cfg = self.cfg
+        B, S, _ = x.shape
+        L = self.halves(cfg, S)
+        H, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        dense = lambda feats, name: checkpoint_name(
+            nn.DenseGeneral(feats, axis=-1, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=jnp.float32)(x), SAVED)
+        with region("mixer/proj"):
+            q, k, v = dense((H, D), "q_proj"), dense((KVH, D), "k_proj"), dense((KVH, D), "v_proj")
+            if cfg.qk_norm:
+                norm = lambda name: RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset,
+                                            init_scale=cfg.blockdiff_qk_init_scale, name=name)
+                q, k = norm("q_norm")(q), norm("k_norm")(k)
+        if cfg.pos_emb == "rope":
+            with region("mixer/rope"):
+                pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32) % L, (B, S))
+                cos, sin = scaled_rope_frequencies(cfg, D)
+                q, k = (apply_rope(t, cos, sin, pos, style=cfg.rope_style) for t in (q, k))
+        # the kernels count themselves where they choose their walk; off the TPU XLA's form is counted here
+        with region("mixer/kernel", **({} if pallas_available() else {"op": "blockdiff", "pass": "fwd", "path": "xla"})):
+            out = attention(q, k, v, mask=masks.BlockDiffusion(cfg.block_length, L), scale=cfg.attn_scale or D**-0.5)
+        with region("mixer/proj"):
+            return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
+                                   param_dtype=jnp.float32)(out)

@@ -32,7 +32,8 @@ from .config import TransformerFields
 # (much of what moved below this module is imported from here all the same)
 from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, _norm, _rope_table, alibi_slopes, apply_rope,  # noqa: F401
                      make_norm, rope_frequencies, scaled_rope_frequencies)
-from .mixers import DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, SparseMixer, SSMMixer
+from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, SparseMixer,
+                     SSMMixer)
 
 # THE table of layer kinds. A kind is declared once: its flax module carries its record (``layers.py::LayerKind``) and has
 # one line here; ``Block``, ``block_fn``, ``CausalLM.loss_fn``, ``runtime/engine.py`` and ``inference/v2/engine_v2.py`` read
@@ -40,6 +41,7 @@ from .mixers import DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KD
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module
 MIXERS = {"full": Attention, "window": Attention, "kda": KDAMixer, "gdn": GDNMixer, "mla": MLAMixer, "sparse": SparseMixer}
 MIXERS |= {"ssm": SSMMixer, "diff": DiffAttention, "diff_window": DiffAttention, "gmu": GatedMemory, "diff_cross": DiffCrossAttention}
+MIXERS |= {"blockdiff": BlockDiffMixer}
 FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE}
 
 
@@ -118,6 +120,15 @@ class TransformerConfig(TransformerFields):
         """The names of the values this model's layers give to or take from one another (``LayerKind.gives``, ``takes``):
         the unrolled loop over layers carries them beside the activations; the stacked forms carry activations alone."""
         return tuple(sorted({name for mixer, _ in self.kinds for name in MIXERS[mixer].gives + MIXERS[mixer].takes}))
+
+    @property
+    def objective(self):
+        """The record of the one kind of this model's layers that states the objective (``LayerKind.targets``: which
+        positions the loss head runs over, their targets and weights), or None: next-token prediction over the row."""
+        stating = [record for record in records(self.kinds) if getattr(record, "targets", None) is not None]  # (a mixer's)
+        if len(stating) > 1:
+            raise ValueError(f"layers of {len(stating)} kinds each state an objective of their own: a model has one")
+        return stating[0] if stating else None
 
     @property
     def sows(self) -> bool:
@@ -237,6 +248,10 @@ class Transformer(nn.Module):
         if cfg.scan_layers and cfg.shares:
             raise ValueError(f"layers that give or take values between blocks ({', '.join(cfg.shares)}) need the unrolled loop over "
                              f"layers, which carries them: set scan_layers=False")
+        if cfg.scan_layers and cfg.unstackable:  # by the kinds' records (``LayerKind.stackable``)
+            raise NotImplementedError(f"the scan over layers stacks softmax attention over one head size with dense or "
+                                      f"capacity-gated MoE blocks; layers of kind {', '.join(cfg.unstackable)} need the unrolled "
+                                      f"loop: set scan_layers=False")
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -504,7 +519,15 @@ class CausalLM:
         own = sum(own[1:], own[0]) if own else 0.0  # no ``0 +`` ahead of the one there is today
         with region("head"):
             w = leaves[0].astype(cfg.dtype)
-            if "labels" in batch:
+            weights = None
+            if cfg.objective is not None:
+                # a kind with an objective of its own (block diffusion's masked-token loss): the head runs over the
+                # positions it names, against its targets, each weighed; all made here from the ids, elementwise
+                if "labels" in batch:
+                    raise ValueError(f"a {cfg.objective.__name__} model makes its targets from input_ids: give no labels")
+                keep, labels, weights, divisor = cfg.objective.targets(cfg, input_ids)
+                hidden = hidden[:, keep]
+            elif "labels" in batch:
                 labels = batch["labels"]
             else:
                 # shift left; keep S intact (last position ignored) so the fused
@@ -516,7 +539,14 @@ class CausalLM:
             if hook is not None:
                 by_hook = hook.head(head, leaves, functools.partial(_head_sums, dtype=cfg.dtype, vd_layout=cfg.tie_embeddings),
                                     0 if cfg.tie_embeddings else 1, cfg.sows)
-            if by_hook is None:
+            if weights is not None:
+                if by_hook is not None:
+                    raise NotImplementedError("ZeRO-3's sliced loss head (zero/overlap.py) sums unweighted targets: a model "
+                                              "whose objective weighs them (block diffusion) runs stage 0-2 or overlap_comm off")
+                total, _ = fused_cross_entropy_sums(hidden, w, labels, vd_layout=cfg.tie_embeddings,
+                                                    bias=leaves[1] if len(leaves) > 1 else None, weights=weights)
+                ce = total / divisor
+            elif by_hook is None:
                 ce = fused_cross_entropy(hidden, w, labels, vd_layout=cfg.tie_embeddings,
                                          bias=leaves[1] if len(leaves) > 1 else None)
             else:  # a share of the sum and of the count from each device

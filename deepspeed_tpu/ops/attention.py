@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ..telemetry.tracing import region
+from . import masks
 from .registry import get_op, register_op
 
 
@@ -35,9 +36,13 @@ def attention_xla(q: jnp.ndarray,
                   segment_ids: Optional[jnp.ndarray] = None,
                   kv_len=None,
                   window: Optional[int] = None,
-                  alibi_slopes: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                  alibi_slopes: Optional[jnp.ndarray] = None,
+                  mask=None) -> jnp.ndarray:
     """Multi-head attention, shapes (B, S, H, D) / KV may have fewer heads (GQA).
 
+    ``mask``: a record of ``ops/masks.py`` in place of ``causal`` / ``window``
+    (which are its two oldest instances): the flash kernels, this form and the
+    chunked one apply the one elementwise test, ``mask.keep(rows, cols)``.
     ``kv_len``: number of valid KV positions (for padded decode caches) —
     queries are placed at absolute positions [kv_len - sq, kv_len).
     ``window``: sliding-window width (mistral): query i attends keys in
@@ -65,24 +70,22 @@ def attention_xla(q: jnp.ndarray,
     if bias is not None:
         logits = logits + bias
     sq, sk = q.shape[1], k.shape[1]
-    if causal or kv_len is not None or window is not None:
+    # window means '(i - window, i]': it implies the causal upper bound even when causal=False, matching the flash kernel
+    record = mask if mask is not None else masks.of(causal, window)
+    if record.masks or kv_len is not None:
         # offset supports decode where q is a suffix of the (valid) kv sequence
         valid = kv_len if kv_len is not None else sk
         offset = valid - sq
         qi = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0) + offset
         ki = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        mask = ki < valid
-        if causal:
-            mask = mask & (ki <= qi)
-        if window is not None:
-            # window means '(i - window, i]' — it implies the causal upper
-            # bound even when causal=False, matching the flash kernel
-            mask = mask & (ki > qi - window) & (ki <= qi)
-        logits = jnp.where(mask[None, None], logits, jnp.finfo(jnp.float32).min)
+        kept = ki < valid
+        if record.masks:
+            kept = kept & record.keep(qi, ki)
+        logits = jnp.where(kept[None, None], logits, jnp.finfo(jnp.float32).min)
     if segment_ids is not None:
         seg_q, seg_k = segment_ids if isinstance(segment_ids, tuple) else (segment_ids, segment_ids)
-        mask = seg_q[:, :, None] == seg_k[:, None, :]
-        logits = jnp.where(mask[:, None], logits, jnp.finfo(jnp.float32).min)
+        same = seg_q[:, :, None] == seg_k[:, None, :]
+        logits = jnp.where(same[:, None], logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
     return out.astype(orig_dtype)
@@ -100,7 +103,8 @@ def attention_chunked(q: jnp.ndarray,
                       kv_len=None,
                       window: Optional[int] = None,
                       alibi_slopes: Optional[jnp.ndarray] = None,
-                      chunk: int = 512) -> jnp.ndarray:
+                      chunk: int = 512,
+                      mask=None) -> jnp.ndarray:
     """Online-softmax attention over KV chunks — O(S·chunk) peak memory.
 
     The pure-XLA analogue of the flash kernel's memory behaviour (reference
@@ -117,9 +121,10 @@ def attention_chunked(q: jnp.ndarray,
     if segment_ids is not None:
         # packing: take the materializing oracle
         return attention_xla(q, k, v, causal=causal, scale=scale, bias=bias, segment_ids=segment_ids,
-                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes)
+                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask)
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1 (got {window}); pass None to disable the sliding window")
+    record = mask if mask is not None else masks.of(causal, window)
     orig_dtype = q.dtype
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -162,19 +167,16 @@ def attention_chunked(q: jnp.ndarray,
             logits = logits + sl[None, :, None, None] * ki.astype(jnp.float32)[None, None, None, :]
         if bias is not None:
             logits = logits + jax.lax.dynamic_slice_in_dim(bias_full, base, c, axis=3).astype(jnp.float32)
-        mask = (ki[None, :] < valid)  # (sq?,c) -> broadcast below
-        mask = jnp.broadcast_to(mask, (sq, c))
-        if causal:
-            mask = mask & (ki[None, :] <= qi[:, None])
-        if window is not None:
-            mask = mask & (ki[None, :] > qi[:, None] - window) & (ki[None, :] <= qi[:, None])
+        kept = jnp.broadcast_to(ki[None, :] < valid, (sq, c))
+        if record.masks:
+            kept = kept & record.keep(qi[:, None], ki[None, :])
         neg = jnp.finfo(jnp.float32).min
-        logits = jnp.where(mask[None, None], logits, neg)
+        logits = jnp.where(kept[None, None], logits, neg)
         m_chunk = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m, m_chunk)
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(logits - m_new[..., None])
-        p = jnp.where(mask[None, None], p, 0.0)  # rows with no valid keys yet
+        p = jnp.where(kept[None, None], p, 0.0)  # rows with no valid keys yet
         acc = acc * alpha[..., None] + jnp.einsum("bhqk,bkhd->bhqd", p, vcb.astype(jnp.float32))
         denom = denom * alpha + jnp.sum(p, axis=-1)
         return (acc, m_new, denom), None

@@ -18,6 +18,13 @@ the weight gradient as usual.
 All matmuls run in the input dtype (bf16 on TPU) with fp32 accumulation
 (``preferred_element_type``) — MXU-friendly. The weight cotangent is
 accumulated in fp32 across chunks and cast to ``w.dtype`` once at the end.
+
+A target's part in the sum is a weight: ``valid`` below is either the
+boolean "not ``ignore_index``" (weights one and zero, the older callers'
+program, equation for equation) or a float32 weight a target (a masked-token
+diffusion loss weighs a target by its block's noise); the forward multiplies
+the token's loss by it and the hand-written backward the token's
+``dlogits``. Weights take no gradient.
 """
 
 import functools
@@ -84,6 +91,11 @@ def _project(xs: jnp.ndarray, w: jnp.ndarray, vd_layout: bool) -> jnp.ndarray:
     return jax.lax.dot_general(xs, w, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
+def _weighed(valid, x):
+    """``x`` a target, times its weight: ``valid`` boolean (kept or dropped) or a float32 weight."""
+    return jnp.where(valid, x, 0.0) if valid.dtype == jnp.bool_ else valid * x
+
+
 def _own_labels(labels, n_own: int, vocab_axis: str):
     """Under ``vocab_axis``, where this device holds ``n_own`` consecutive
     entries of the vocabulary: the labels counted from its first entry, and
@@ -119,7 +131,7 @@ def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis)
             own, mine = _own_labels(lc, logits.shape[-1], vocab_axis)
             gold = jnp.take_along_axis(logits, jnp.where(mine, own, 0)[..., None], axis=-1)[..., 0]
             gold = jax.lax.psum(jnp.where(mine, gold, 0.0), vocab_axis)
-        nll = jnp.where(vc, lse - gold, 0.0)
+        nll = _weighed(vc, lse - gold)
         return acc + jnp.sum(nll), lse
 
     total, lses = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ls, vs))
@@ -154,7 +166,7 @@ def _ce_vjp_bwd(vd_layout, chunk, has_bias, vocab_axis, res, g):
             logits = logits + b
         p = jnp.exp(logits - lse[..., None])  # softmax, (B,C,V) fp32
         onehot = jax.nn.one_hot(lc, V, dtype=jnp.float32)
-        dlogits = (p - onehot) * jnp.where(vc, g, 0.0)[..., None]  # (B,C,V) fp32
+        dlogits = (p - onehot) * _weighed(vc, g)[..., None]  # (B,C,V) fp32
         dlogits_c = dlogits.astype(xc.dtype)
         if vd_layout:
             # w: (V,D); dxc = dlogits @ w ; dw += dlogits^T @ xc
@@ -187,9 +199,14 @@ def fused_cross_entropy_sums(x: jnp.ndarray,
                              vd_layout: bool = False,
                              chunk: Optional[int] = None,
                              bias: Optional[jnp.ndarray] = None,
-                             vocab_axis: Optional[str] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                             vocab_axis: Optional[str] = None,
+                             weights: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(summed token CE, count of positions not ignored) of
     ``fused_cross_entropy``, for a caller that divides after summing.
+
+    ``weights``: (B, S) float32, a weight a target: the sum is ``sum w_i
+    CE_i`` (``ignore_index`` is the weight-0 case of it) and the count the
+    targets of non-zero weight; the caller divides by what its loss says.
 
     ``vocab_axis``: the call is every device's own program under
     ``shard_map`` over that mesh axis, ``w`` and ``bias`` are this device's
@@ -205,8 +222,10 @@ def fused_cross_entropy_sums(x: jnp.ndarray,
     safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
     has_bias = bias is not None
     b = bias.astype(jnp.float32) if has_bias else jnp.zeros((V,), jnp.float32)
+    if weights is not None:
+        valid = jax.lax.stop_gradient(jnp.where(valid, weights.astype(jnp.float32), 0.0))
     total = _fused_ce_sum(x, w, b, safe_labels, valid, bool(vd_layout), int(chunk), has_bias, vocab_axis)
-    return total, jnp.sum(valid)
+    return total, jnp.sum(valid) if weights is None else jnp.sum(valid != 0)
 
 
 def fused_cross_entropy(x: jnp.ndarray,

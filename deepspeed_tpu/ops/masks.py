@@ -1,0 +1,188 @@
+"""What an attention call keeps of the (query, key) square, as a small record that every form of the op reads: the
+flash kernels (``ops/pallas/flash_attention.py``), XLA's form and the chunked one (``ops/attention.py``).
+
+A record gives the elementwise test (``keep(rows, cols)``: the ONE definition of the mask, which the kernels apply on
+the tiles that cross an edge and XLA's forms on the whole square) and, for a kernel's walk, the tiles a q tile or a kv
+tile visits as ``(first, end, masked)`` runs: a run's tiles are consecutive, and only a ``masked`` run has a tile with
+an element the test throws away. A walk visits no tile that lies wholly outside the mask.
+
+Rows are counted in key positions: a call's queries align to the END of its keys (``row = seq_k - seq_q + query``).
+Records are frozen and hashable: they are static arguments of the kernels' ``custom_vjp``.
+
+``kernel`` is the prefix of the Mosaic calls' names on the device's clock (``flash_fwd``, ``blockdiff_bwd``) and ``op``
+the label the calls are counted under (``program_regions_traced_total{region="mixer/kernel", op}``).
+"""
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas import cdiv as _cdiv
+
+
+class _Record:
+    """What the older masks share: the flash kernels' own names, and tiles chosen from the sequence itself."""
+
+    kernel, op, masks = "flash", "flash", True
+
+    def tile(self, seq: int, want: int, divides):
+        """The kernels' tile along a sequence of ``seq``: ``divides(n, want)``, the largest tile that divides n."""
+        return divides(seq, want)
+
+
+@dataclass(frozen=True)
+class Full(_Record):
+    """Every pair: bidirectional attention (an encoder's)."""
+
+    masks = False
+
+    def keep(self, rows, cols):
+        return None
+
+    def kv_runs(self, qi, *, bq, bk, seq_q, seq_k):
+        return [(0, seq_k // bk, False)]
+
+    def q_runs(self, kj, *, bq, bk, seq_q, seq_k):
+        return [(0, seq_q // bq, False)]
+
+
+@dataclass(frozen=True)
+class Causal(_Record):
+    """A key at or before its query; with ``window`` > 0 also after ``query - window`` (a sliding window implies the
+    causal bound)."""
+
+    window: int = 0
+
+    def keep(self, rows, cols):
+        mask = cols <= rows
+        if self.window > 0:
+            mask = mask & (cols > rows - self.window)
+        return mask
+
+    def kv_runs(self, qi, *, bq, bk, seq_q, seq_k):
+        """Only a block that crosses the diagonal (or, with a window, the window's far edge) has a masked element; the
+        blocks between lie wholly inside the mask. ``qi`` is traced."""
+        nk, window = seq_k // bk, self.window
+        r0 = seq_k - seq_q + qi * bq  # the block's first row, in key positions
+        end = jnp.minimum(_cdiv(r0 + bq, bk), nk)  # past the last block any row sees
+        first = jnp.maximum(r0 - window + 1, 0) // bk if window > 0 else 0
+        # blocks [.., full) end at or before the first row's own position
+        full = jnp.clip(jnp.maximum(r0 + 1, 0) // bk, first, end)
+        if window <= 0:
+            return [(first, full, False), (full, end, True)]
+        # blocks [inside, ..) start after the last row's window has begun
+        inside = jnp.clip(jnp.maximum(r0 + bq - 1 - window + bk, 0) // bk, first, full)
+        return [(first, inside, True), (inside, full, False), (full, end, True)]
+
+    def q_runs(self, kj, *, bq, bk, seq_q, seq_k):
+        """``kv_runs`` seen from a kv block: the q blocks that visit it."""
+        nq, window = seq_q // bq, self.window
+        c0 = kj * bk - (seq_k - seq_q)  # the block's first column, in query positions
+        first = jnp.maximum(c0, 0) // bq  # row r sees column c iff c <= r
+        end = nq
+        if window > 0:  # ... and c > r - window: the last column is seen up to row c0 + bk + window - 2
+            end = jnp.minimum(jnp.maximum(c0 + bk + window - 2 + bq, 0) // bq, nq)
+        # blocks [full, ..) start at or after the block's last column
+        full = jnp.clip(jnp.maximum(c0 + bk + bq - 2, 0) // bq, first, end)
+        if window <= 0:
+            return [(first, full, True), (full, end, False)]
+        # blocks [.., inside) end before the first column leaves their last row's window
+        inside = jnp.clip(jnp.maximum(c0 + window, 0) // bq, full, end)
+        return [(first, full, True), (full, inside, False), (inside, end, True)]
+
+
+@dataclass(frozen=True)
+class BlockDiffusion(_Record):
+    """Block-diffusion training over a doubled row ``[noised ; clean]`` of ``2 * seq_len`` positions (BD3-LM's vectorised
+    form): index p has ``pos = p mod seq_len`` and ``blk = pos // block``; a noised query keeps the noised keys of its
+    own block and the clean keys of EARLIER blocks, a clean query the clean keys of its own and earlier blocks, and no
+    query a noised key of another block. ``seq_len**2 + seq_len * block`` of the ``4 seq_len**2`` pairs.
+
+    The kernels' tiles are chosen from ``seq_len`` (``tile``), so no tile straddles the two halves and every piece of
+    the mask is a staircase in a tile's own half: per query an interval of keys whose ends do not fall as the query
+    advances."""
+
+    block: int
+    seq_len: int  # L: a half of the row
+    kernel, op = "blockdiff", "blockdiff"
+
+    def __post_init__(self):
+        if self.block < 1 or self.seq_len % self.block:
+            raise ValueError(f"a block-diffusion row's halves are whole blocks: {self.seq_len} positions are not a multiple of "
+                             f"block_length={self.block}")
+
+    def keep(self, rows, cols):
+        L, B = self.seq_len, self.block
+        q_clean, k_clean = rows >= L, cols >= L
+        qb, kb = (rows - jnp.where(q_clean, L, 0)) // B, (cols - jnp.where(k_clean, L, 0)) // B
+        # (no select between boolean vectors: Mosaic has none) a clean key while blk(k) < blk(q), or <= for a clean query
+        return (k_clean & (kb < qb + jnp.where(q_clean, 1, 0))) | ~(k_clean | q_clean) & (kb == qb)
+
+    def tile(self, seq: int, want: int, divides):
+        return divides(self.seq_len, want)  # of a HALF: no tile straddles the two
+
+    def _check(self, bq, bk, seq_q, seq_k):
+        L = self.seq_len
+        if seq_q != 2 * L or seq_k != 2 * L or L % bq or L % bk:
+            raise ValueError(f"the block-diffusion mask of {L} positions a half takes {2 * L} queries and keys in tiles that "
+                             f"divide a half, got {seq_q} x {seq_k} in tiles of {bq} x {bk}")
+
+    def _own_masked(self, bq, bk) -> bool:
+        """Whether a noised tile of a q tile's own blocks can cross a block's edge: not where both tiles divide a block."""
+        return bool(self.block % bq or self.block % bk)
+
+    def kv_runs(self, qi, *, bq, bk, seq_q, seq_k):
+        """A noised q tile: the noised tiles its rows' own blocks lie in (masked), then the clean tiles wholly ahead of
+        its first row's block (unmasked) and those that reach into its rows' blocks (masked). A clean q tile: the last
+        two, one block further."""
+        self._check(bq, bk, seq_q, seq_k)
+        L, B = self.seq_len, self.block
+        nq, nk = L // bq, L // bk
+        noised = qi < nq
+        p0 = (qi - jnp.where(noised, 0, nq)) * bq  # the tile's first position in its half; its last is p0 + bq - 1
+        b0, b1 = p0 // B, (p0 + bq - 1) // B
+        own_first = b0 * B // bk
+        own_end = jnp.where(noised, jnp.minimum(_cdiv((b1 + 1) * B, bk), nk), own_first)
+        reach = jnp.where(noised, 0, 1)  # a clean key is kept while blk(k) < blk(q) + reach
+        full = jnp.minimum((b0 + reach) * B // bk, nk)
+        end = jnp.clip(_cdiv((b1 + reach) * B, bk), full, nk)
+        return [(own_first, own_end, self._own_masked(bq, bk)), (nk, nk + full, False), (nk + full, nk + end, True)]
+
+    def q_runs(self, kj, *, bq, bk, seq_q, seq_k):
+        """``kv_runs`` seen from a kv tile. A noised one: the noised q tiles of its columns' blocks. A clean one: of each
+        half the q tiles that reach into its columns' blocks (masked) and those wholly past them (unmasked)."""
+        self._check(bq, bk, seq_q, seq_k)
+        L, B = self.seq_len, self.block
+        nq, nk = L // bq, L // bk
+        noised = kj < nk
+        c0 = (kj - jnp.where(noised, 0, nk)) * bk
+        b0, b1 = c0 // B, (c0 + bk - 1) // B
+        own_first = b0 * B // bq
+        own_end = jnp.where(noised, jnp.minimum(_cdiv((b1 + 1) * B, bq), nq), own_first)
+        runs = [(own_first, own_end, self._own_masked(bq, bk))]
+        for half, reach in ((0, 1), (nq, 0)):  # noised queries keep blk(k) < blk(q), clean ones blk(k) <= blk(q)
+            first = jnp.minimum((b0 + reach) * B // bq, nq)
+            full = jnp.clip(_cdiv((b1 + reach) * B, bq), first, nq)
+            first, full = (jnp.where(noised, nq, x) for x in (first, full))  # a noised key: none of these
+            runs += [(half + first, half + full, True), (half + full, half + nq, False)]
+        return runs
+
+    @property
+    def pairs(self) -> int:
+        return self.seq_len**2 + self.seq_len * self.block
+
+
+def of(causal: bool, window=None):
+    """The record of the older arguments: ``causal`` with an optional sliding ``window`` (which implies it)."""
+    return Causal(int(window or 0)) if causal or window else Full()
+
+
+def tiles_visited(mask, *, bq, bk, seq_q, seq_k) -> int:
+    """How many tiles a forward walk visits, by the record's own runs (static shapes: worked out on the host)."""
+    total = 0
+    with jax.ensure_compile_time_eval():  # called while a program is traced: the runs' arithmetic is the host's here
+        for qi in range(seq_q // bq):
+            for first, end, _ in mask.kv_runs(np.int32(qi), bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k):
+                total += max(int(end) - int(first), 0)
+    return total

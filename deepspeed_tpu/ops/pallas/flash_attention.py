@@ -17,10 +17,13 @@ formed once a block and dq, dk, dv all leave the one kernel in the input
 dtype (``_bwd_fused_kernel``). Every kernel works on kv-major (bk, bq)
 tiles, key position on sublanes: the per-row softmax statistics are (1, bq)
 rows that reduce and broadcast along sublanes, and of the backward's five
-products only dq's takes a transposed operand. Mask arithmetic runs only on
-the blocks that cross the diagonal or a window's far edge (``_kv_runs`` /
-``_q_runs``); the blocks between take the same ``_scores`` with the mask
-statically off.
+products only dq's takes a transposed operand. A call's mask is a record
+(``ops/masks.py``: none, causal, causal under a window, block diffusion over
+a doubled row) that gives the elementwise test and, from both sides, the
+tiles a walk visits as ``(first, end, masked)`` runs: mask arithmetic runs
+only on the tiles that cross an edge of the mask (the diagonal, a window's
+far edge, a block's), the tiles between take the same ``_scores`` with the
+mask statically off, and a tile wholly outside is never visited.
 
 What the code observes to choose a path (``program_regions_traced_total{region=
 "mixer/kernel", op, pass, path}`` counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
@@ -54,6 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...analysis import knobs
+from .. import masks
 from ..registry import REGISTRY, pallas_available
 from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh, vmem_budget
 
@@ -99,8 +103,7 @@ _NN = (((1,), (0,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b: the transposed-lhs products (forward: v^T p; backward: ds^T k)
 
 
-def _scores(q, k, slope, row0, col0, scale, causal, has_alibi, window, btile=None, *, masked=True,
-            kv_major=False):
+def _scores(q, k, slope, row0, col0, scale, mask, has_alibi, btile=None, *, masked=True, kv_major=False):
     """fp32 masked scores of one block: the ONE definition of the mask/bias
     math; fwd and every bwd kernel recompute s through this so they can
     never drift apart.
@@ -111,7 +114,8 @@ def _scores(q, k, slope, row0, col0, scale, causal, has_alibi, window, btile=Non
     along sublanes, dv and dk are plain products, and the per-row lse/delta
     broadcast along sublanes. ``masked=False`` is the same
     function for a block the caller knows lies wholly inside the mask (below
-    the diagonal, inside the window): no iota, compare or select.
+    the diagonal, inside the window): no iota, compare or select. ``mask``:
+    the call's record (``ops/masks.py``), whose ``keep`` is the test.
     ``btile``: additive bias tile in the block's orientation (evoformer
     pair/mask bias, reference DS4Sci_EvoformerAttention), added before
     masking so masked entries stay exactly NEG_INF."""
@@ -121,7 +125,7 @@ def _scores(q, k, slope, row0, col0, scale, causal, has_alibi, window, btile=Non
     else:
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
         col_dim, row_dim = 1, 0
-    mask_here = causal and masked  # window implies causal (non-causal windows fall back to XLA)
+    mask_here = mask.masks and masked
     if has_alibi or mask_here:
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, col_dim)
     if has_alibi:  # shift-invariant ALiBi: slope * key_position
@@ -130,52 +134,8 @@ def _scores(q, k, slope, row0, col0, scale, causal, has_alibi, window, btile=Non
         s = s + btile.astype(jnp.float32)
     if mask_here:
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, row_dim)
-        mask = cols <= rows
-        if window > 0:
-            mask = mask & (cols > rows - window)
-        s = jnp.where(mask, s, NEG_INF)
+        s = jnp.where(mask.keep(rows, cols), s, NEG_INF)
     return s
-
-
-def _kv_runs(qi, *, bq, bk, seq_q, seq_k, causal, window):
-    """The kv blocks one q block visits, as ``(first, end, masked)`` runs.
-
-    Only a block that crosses the diagonal (or, with a window, the window's
-    far edge) has a masked element; the blocks between lie wholly inside the
-    mask and take ``_scores(masked=False)``. Queries align to the END of the
-    kv sequence (matches attention_xla); ``qi`` is traced."""
-    nk = seq_k // bk
-    if not causal:
-        return [(0, nk, False)]
-    r0 = seq_k - seq_q + qi * bq  # the block's first row, in key positions
-    end = jnp.minimum(pl.cdiv(r0 + bq, bk), nk)  # past the last block any row sees
-    first = jnp.maximum(r0 - window + 1, 0) // bk if window > 0 else 0
-    # blocks [.., full) end at or before the first row's own position
-    full = jnp.clip(jnp.maximum(r0 + 1, 0) // bk, first, end)
-    if window <= 0:
-        return [(first, full, False), (full, end, True)]
-    # blocks [inside, ..) start after the last row's window has begun
-    inside = jnp.clip(jnp.maximum(r0 + bq - 1 - window + bk, 0) // bk, first, full)
-    return [(first, inside, True), (inside, full, False), (full, end, True)]
-
-
-def _q_runs(kj, *, bq, bk, seq_q, seq_k, causal, window):
-    """``_kv_runs`` seen from a kv block: the q blocks that visit it."""
-    nq = seq_q // bq
-    if not causal:
-        return [(0, nq, False)]
-    c0 = kj * bk - (seq_k - seq_q)  # the block's first column, in query positions
-    first = jnp.maximum(c0, 0) // bq  # row r sees column c iff c <= r
-    end = nq
-    if window > 0:  # ... and c > r - window: the last column is seen up to row c0 + bk + window - 2
-        end = jnp.minimum(jnp.maximum(c0 + bk + window - 2 + bq, 0) // bq, nq)
-    # blocks [full, ..) start at or after the block's last column
-    full = jnp.clip(jnp.maximum(c0 + bk + bq - 2, 0) // bq, first, end)
-    if window <= 0:
-        return [(first, full, True), (full, end, False)]
-    # blocks [.., inside) end before the first column leaves their last row's window
-    inside = jnp.clip(jnp.maximum(c0 + window, 0) // bq, full, end)
-    return [(first, full, True), (full, inside, False), (inside, end, True)]
 
 
 def _walk(runs, body, carry):
@@ -218,8 +178,7 @@ def _bias_bh_fn(bias_meta, H: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq: int, bk: int, seq_q: int,
-                seq_k: int, scale: float, causal: bool, has_alibi: bool, window: int, has_bias: bool,
-                sqb1: bool):
+                seq_k: int, scale: float, mask, has_alibi: bool, has_bias: bool, sqb1: bool):
     """One q block against the kv blocks it sees, on kv-major (bk, bq) tiles
     (``_scores``): the running max and sum are (1, bq) rows that reduce and
     broadcast along sublanes, the accumulator is (D, bq) and turned once at
@@ -238,8 +197,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
         btile = None
         if has_bias:  # the (bk, 1) column all rows share, or the (bq, bk) tile turned kv-major
             btile = bias_ref[0, pl.dslice(j * bk, bk), :] if sqb1 else bias_ref[0, :, pl.dslice(j * bk, bk)].T
-        s = _scores(q, k, slope, row0, j * bk, scale, causal, has_alibi, window, btile, masked=masked,
-                    kv_major=True)
+        s = _scores(q, k, slope, row0, j * bk, scale, mask, has_alibi, btile, masked=masked, kv_major=True)
         new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - new_m)
         if guard and (masked or has_bias):
@@ -252,7 +210,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
     acc0 = jnp.zeros((D, bq), jnp.float32)
     m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
-    runs = _kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    runs = mask.kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
     acc, m, l = _walk(runs, body, (acc0, m0, l0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
@@ -271,27 +229,35 @@ def _kv_of_fn(H: int, KVH: int):
     return kv_of
 
 
-def _count_traced(pass_: str, path: str, unequal_heads: bool = False):
+def _count_traced(pass_: str, path: str, unequal_heads: bool = False, mask=masks.Causal(), **choice):
     """The kernels are chosen while a program is traced, so that is where the
     choice is counted (docs/OBSERVABILITY.md): one a call site a trace, as
-    ``op="flash"``, or ``"mla"`` for a call whose values have another head size
-    than its queries and keys (latent attention's). The scope is the one the
-    call already runs under (``ops/attention.py``; a ``custom_vjp``'s backward
-    is traced under its forward's name stack)."""
+    ``op="flash"``, ``"mla"`` for a call whose values have another head size
+    than its queries and keys (latent attention's), or the mask's own
+    (``"blockdiff"``, whose ``path`` is ``"kernel"``: XLA's form is counted by
+    its caller). The scope is the one the call already runs under
+    (``ops/attention.py``; a ``custom_vjp``'s backward is traced under its
+    forward's name stack)."""
     from ...telemetry.tracing import region
 
-    with region("mixer/kernel", op="mla" if unequal_heads else "flash", path=path, **{"pass": pass_}):
+    op = "mla" if unequal_heads else mask.op
+    with region("mixer/kernel", op=op, path=path if mask.op == "flash" else "kernel", **{"pass": pass_}, **choice):
         pass
 
 
-def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: bool, has_alibi: bool,
-               window: int, bias_meta, H: int, KVH: int):
+def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_alibi: bool, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
-    bq, bk = _blk(Sq, DEFAULT_BQ), _blk(Sk, DEFAULT_BK)
-    _count_traced("fwd", "single", Dv != D)
+    bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
+    # a mask with a walk of its own also says, static at trace time, what the walk visits: a walk that visits more
+    # shows on the trainer's first-call line without a capture (``tiles`` = visited/of the square, ``pairs`` = kept)
+    walk = {}
+    if mask.op != "flash":
+        visited = masks.tiles_visited(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)
+        walk = {"tiles": f"{visited}/{(Sq // bq) * (Sk // bk)}", "pairs": str(mask.pairs)}
+    _count_traced("fwd", "single", Dv != D, mask, **walk)
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block
@@ -307,8 +273,8 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
     vmem = (2 * (bq * (D + Dv) + Sk * (D + Dv)) * q.dtype.itemsize + _tile_bytes(bq, bk)
             + (2 * (LANES if sqb1 else bq) * Sk * 4 if has_bias else 0))
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal,
-                          has_alibi=has_alibi, window=window, has_bias=has_bias, sqb1=sqb1),
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask,
+                          has_alibi=has_alibi, has_bias=has_bias, sqb1=sqb1),
         grid=(BH, Sq // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -326,7 +292,7 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
             jax.ShapeDtypeStruct((BH, Sq // bq, 1, bq), jnp.float32),  # one row a q block: _rows
         ],
         interpret=interpret,
-        name="flash_fwd",  # the custom call's name on the device's clock
+        name=f"{mask.kernel}_fwd",  # the custom call's name on the device's clock
         compiler_params=_compiler_params("parallel", "arbitrary", interpret=interpret, vmem_bytes=vmem),
     )(q, k, v, slopes, bias)
     return o, lse.reshape(BH, Sq)
@@ -336,7 +302,7 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, causal: bool, interpret: boo
 # backward
 # ----------------------------------------------------------------------
 def _dq_accumulate(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slope, bias_ref, on_dlogits, qi, *,
-                   bq, bk, seq_q, seq_k, scale, causal, has_alibi, window):
+                   bq, bk, seq_q, seq_k, scale, mask, has_alibi):
     """(bq, D) dq of one q block under a bias: the ONE definition of the
     query-major gradient algebra, shared by both dq kernels.
     ``on_dlogits(j, dlogits)`` receives each visited block's (bq, bk) logit
@@ -351,15 +317,14 @@ def _dq_accumulate(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slope, bias_
         k = k_ref[0, pl.dslice(j * bk, bk), :]
         v = v_ref[0, pl.dslice(j * bk, bk), :]
         btile = bias_ref[0, :, pl.dslice(j * bk, bk)]
-        s = _scores(q, k, slope, seq_k - seq_q + qi * bq, j * bk, scale, causal, has_alibi, window, btile,
-                    masked=masked)
+        s = _scores(q, k, slope, seq_k - seq_q + qi * bq, j * bk, scale, mask, has_alibi, btile, masked=masked)
         p = jnp.where(s <= NEG_INF, 0.0, jnp.exp(s - lse[:, None]))
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)  # (bq, bk)
         dlogits = p * (dp - delta[:, None])
         on_dlogits(j, dlogits)
         return dq + jax.lax.dot_general(dlogits.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    runs = _kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    runs = mask.kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
     return _walk(runs, body, jnp.zeros((bq, q.shape[-1]), jnp.float32)) * scale
 
 
@@ -410,7 +375,7 @@ def _dq_kernel_collapsed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes
 
 
 def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj, on_ds=None, *,
-                    bq, bk, seq_q, seq_k, scale, causal, has_alibi, window, has_bias=False):
+                    bq, bk, seq_q, seq_k, scale, mask, has_alibi, has_bias=False):
     """(bk, D) dk/dv for one kv block — the ONE definition of the dkv
     gradient algebra (visible-q-block runs + ds formula), shared by the
     fused and the per-q-head (bias) kernels so they can never drift apart.
@@ -427,8 +392,8 @@ def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj
         dk, dv = carry
         q = q_ref[0, pl.dslice(i * bq, bq), :]
         do = do_ref[0, pl.dslice(i * bq, bq), :]
-        s = _scores(q, k, slope, seq_k - seq_q + i * bq, kj * bk, scale, causal, has_alibi, window,
-                    btile_fn(i), masked=masked, kv_major=True)
+        s = _scores(q, k, slope, seq_k - seq_q + i * bq, kj * bk, scale, mask, has_alibi, btile_fn(i), masked=masked,
+                    kv_major=True)
         p = jnp.exp(s - lse_ref[0, i])
         if guard and (masked or has_bias):
             p = jnp.where(s <= NEG_INF, 0.0, p)
@@ -440,7 +405,7 @@ def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj
             on_ds(i, ds)
         return dk, dv
 
-    runs = _q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k, causal=causal, window=window)
+    runs = mask.q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
     dk, dv = _walk(runs, body, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     return dk * scale, dv
 
@@ -541,18 +506,17 @@ def _rows(x, bq: int):
     return x.reshape(BH, Sq // bq, 1, bq)
 
 
-def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, interpret: bool,
-               has_alibi: bool, window: int, bias_meta, H: int, KVH: int):
+def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret: bool, has_alibi: bool, bias_meta,
+               H: int, KVH: int):
     BH, Sq, D = q.shape
     BKV, Sk, _ = k.shape  # B * KVH (GQA stays collapsed)
     Dv = v.shape[-1]
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     n_rep = H // KVH
-    bq, bk = _blk(Sq, DEFAULT_BQ), _blk(Sk, DEFAULT_BK)
+    bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
     item = q.dtype.itemsize
-    statics = dict(bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, causal=causal, has_alibi=has_alibi,
-                   window=window)
+    statics = dict(bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask, has_alibi=has_alibi)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)  # (BH, Sq)
     lse_rows, delta_rows = _rows(lse, bq), _rows(delta, bq)
     nq = Sq // bq
@@ -567,8 +531,8 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             # and dq, and a head alone does (16 q heads on 2 KV heads of 256 at 8,192 positions: 74 MiB against 43):
             # every q head gets a copy of its KV head, and the group's dk and dv are added outside the kernel
             per_head = lambda x: jnp.repeat(x, n_rep, axis=0)
-            dq, dk, dv, dbias = _flash_bwd(q, per_head(k), per_head(v), o, lse, do, slopes, bias, scale, causal, interpret,
-                                           has_alibi, window, bias_meta, H, H)
+            dq, dk, dv, dbias = _flash_bwd(q, per_head(k), per_head(v), o, lse, do, slopes, bias, scale, mask, interpret,
+                                           has_alibi, bias_meta, H, H)
             group = lambda x, like: x.reshape(BKV, n_rep, *x.shape[1:]).astype(jnp.float32).sum(1).astype(like.dtype)
             return dq, group(dk, k), group(dv, v), dbias
         if fused_vmem > vmem_budget():
@@ -576,7 +540,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
                 f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "
                 f"{n_rep} q heads a KV head take {fused_vmem >> 20} MiB of VMEM, over this device's budget of "
                 f"{vmem_budget() >> 20} MiB: split the sequence over the mesh (sequence or context parallelism)")
-        _count_traced("bwd", "fused", Dv != D)
+        _count_traced("bwd", "fused", Dv != D, mask)
         whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
         rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
         kv_blk = [pl.BlockSpec((1, bk, d), lambda b, r, j: (b, j, 0)) for d in (D, Dv)]
@@ -604,7 +568,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
             ],
             scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32)] + kv_scratch,
             interpret=interpret,
-            name="flash_bwd",  # the custom call's name on the device's clock
+            name=f"{mask.kernel}_bwd",  # the custom call's name on the device's clock
             compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary", interpret=interpret,
                                              vmem_bytes=fused_vmem),
         )(q, k, v, do, lse_rows, delta_rows, slopes)
@@ -735,9 +699,9 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, causal: bool, in
 # ----------------------------------------------------------------------
 # public op: (B, S, H, D) layout + GQA + custom_vjp
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
-def _flash(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH):
-    o, _ = _flash_core(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, slopes, bias, scale, mask, interpret, has_alibi, bias_meta, H, KVH):
+    o, _ = _flash_core(q, k, v, slopes, bias, scale, mask, interpret, has_alibi, bias_meta, H, KVH)
     return o
 
 
@@ -752,30 +716,30 @@ def _bh_slopes(slopes, B, H):
     return jnp.broadcast_to(flat[:, None, None], (B * H, 1, LANES))
 
 
-def _flash_core(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH):
+def _flash_core(q, k, v, slopes, bias, scale, mask, interpret, has_alibi, bias_meta, H, KVH):
     B, Sq, _, _ = q.shape
     to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], x.shape[3])
     o, lse = _flash_fwd(to_bh(q), to_bh(k), to_bh(v), _bh_slopes(slopes, B, H), bias,
-                        scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
+                        scale, mask, interpret, has_alibi, bias_meta, H, KVH)
     o = o.reshape(B, H, Sq, v.shape[-1]).transpose(0, 2, 1, 3)
     return o, lse
 
 
-def _flash_vjp_fwd(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH):
-    o, lse = _flash_core(q, k, v, slopes, bias, scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
+def _flash_vjp_fwd(q, k, v, slopes, bias, scale, mask, interpret, has_alibi, bias_meta, H, KVH):
+    o, lse = _flash_core(q, k, v, slopes, bias, scale, mask, interpret, has_alibi, bias_meta, H, KVH)
     # named: a block under jax.checkpoint whose policy lists the name keeps them and runs no second forward kernel
     o, lse = checkpoint_name(o, SAVED), checkpoint_name(lse, SAVED)
     return o, (q, k, v, slopes, bias, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, interpret, has_alibi, window, bias_meta, H, KVH, res, do):
+def _flash_vjp_bwd(scale, mask, interpret, has_alibi, bias_meta, H, KVH, res, do):
     q, k, v, slopes, bias, o, lse = res
     B, Sq, _, _ = q.shape
     Sk = k.shape[1]
     to_bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(B * x.shape[2], x.shape[1], x.shape[3])
     dq, dk, dv, dbias = _flash_bwd(to_bh(q), to_bh(k), to_bh(v), to_bh(o), lse, to_bh(do),
                                    _bh_slopes(slopes, B, H), bias,
-                                   scale, causal, interpret, has_alibi, window, bias_meta, H, KVH)
+                                   scale, mask, interpret, has_alibi, bias_meta, H, KVH)
     back = lambda x, S, nh: x.reshape(B, nh, S, x.shape[-1]).transpose(0, 2, 1, 3)
     # cotangent matches the (collapsed, flat) bias argument; the outer
     # 4D->flat reshape in flash_attention transposes automatically
@@ -788,10 +752,17 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None, bias=None, segment_ids=None,
                     kv_len=None, window=None, alibi_slopes=None, interpret: bool = False,
-                    bias_repeat: int = 1):
-    """Drop-in for ``attention_xla`` on the fast path; handles ALiBi,
-    causal sliding windows, and additive bias natively, and falls back to
-    XLA for the rest (segments, padded kv, non-causal windows).
+                    bias_repeat: int = 1, mask=None):
+    """Drop-in for ``attention_xla`` on the fast path. The kernels take these
+    masks natively, each by a walk that visits no tile wholly outside it and
+    masks only the tiles that cross an edge (``ops/masks.py``): none
+    (``causal=False``), causal, causal under a sliding ``window``, and a
+    ``mask`` record of its own walk (``masks.BlockDiffusion``, whose calls are
+    named ``blockdiff_fwd`` / ``blockdiff_bwd``). ALiBi and an additive bias
+    ride on the first three (a bias walks the same tiles and takes the split
+    backward). Falling back to XLA's form, with the same record: packed
+    segments, a padded kv (``kv_len``), a window or ALiBi without ``causal``,
+    and a ``mask`` record together with a bias, ALiBi, segments or ``kv_len``.
 
     ``bias``: additive logits bias broadcastable to ``(B, H, Sq, Sk)`` —
     the batch/head/row dims may each be 1 and stay COLLAPSED in HBM (the
@@ -801,8 +772,12 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
     ``bias.shape[0] * bias_repeat`` (consecutive q-batch groups share one
     bias slice — evoformer MSA rows over one pair bias).
     """
-    if segment_ids is not None or kv_len is not None or (
-            alibi_slopes is not None and not causal) or (window is not None and not causal):
+    falls = segment_ids is not None or kv_len is not None
+    if mask is not None and mask.op != "flash":  # a record with a walk of its own (``causal`` and ``window`` are not read beside it)
+        falls = falls or bias is not None or alibi_slopes is not None
+    else:
+        falls = falls or (not causal and (alibi_slopes is not None or window is not None))
+    if falls:
         from ..attention import attention_xla
 
         if bias is not None and bias_repeat != 1:
@@ -811,9 +786,11 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
                 bias = bias[None]
             bias = jnp.repeat(bias, bias_repeat, axis=0)
         return attention_xla(q, k, v, causal=causal, scale=scale, bias=bias, segment_ids=segment_ids,
-                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes)
-    local = functools.partial(_flash_local, causal=causal, scale=scale, window=window, interpret=interpret,
-                              bias_repeat=bias_repeat)
+                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask)
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1 (got {window}); pass None to disable the sliding window")
+    local = functools.partial(_flash_local, mask=mask if mask is not None else masks.of(causal, window), scale=scale,
+                              interpret=interpret, bias_repeat=bias_repeat)
     if bias is not None:
         # a collapsed bias does not split along a mesh; those callers
         # (evoformer) run on one device or inside their own shard_map
@@ -843,7 +820,7 @@ def _mesh_spec(q, k) -> P:
                     (B, S, math.gcd(H, k.shape[2]), D), topo)
 
 
-def _flash_local(q, k, v, alibi_slopes, bias, *, causal, scale, window, interpret, bias_repeat):
+def _flash_local(q, k, v, alibi_slopes, bias, *, mask, scale, interpret, bias_repeat):
     """The kernel call on whole (or shard-local) operands."""
     n_rep = q.shape[2] // k.shape[2]
     if n_rep > 1 and bias is not None:
@@ -855,8 +832,6 @@ def _flash_local(q, k, v, alibi_slopes, bias, *, causal, scale, window, interpre
         k = jnp.broadcast_to(k[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(b, s, h * n_rep, d)
         v = jnp.broadcast_to(v[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(b, s, h * n_rep, d)
     scale = scale if scale is not None else 1.0 / (q.shape[-1]**0.5)
-    if window is not None and window < 1:
-        raise ValueError(f"window must be >= 1 (got {window}); pass None to disable the sliding window")
     has_alibi = alibi_slopes is not None
     slopes = jnp.asarray(alibi_slopes, jnp.float32) if has_alibi else jnp.zeros((q.shape[2],), jnp.float32)
     B, Sq, H, _ = q.shape
@@ -875,8 +850,7 @@ def _flash_local(q, k, v, alibi_slopes, bias, *, causal, scale, window, interpre
     else:
         bias_meta = None
         bias_flat = jnp.zeros((1, 1, LANES), jnp.float32)
-    return _flash(q, k, v, slopes, bias_flat, scale, causal, interpret, has_alibi, int(window or 0),
-                  bias_meta, H, k.shape[2])
+    return _flash(q, k, v, slopes, bias_flat, scale, mask, interpret, has_alibi, bias_meta, H, k.shape[2])
 
 
 REGISTRY.register("attention", "pallas", flash_attention, is_available=pallas_available, priority=10)

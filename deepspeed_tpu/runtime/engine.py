@@ -43,7 +43,7 @@ from ..telemetry import device_counts
 from ..telemetry import profiler as device_profiler
 from ..telemetry import span as telemetry_span
 from ..telemetry.costs import first_call
-from ..telemetry.tracing import PHASES, region, regions_traced
+from ..telemetry.tracing import PHASES, region, regions_traced, regions_traced_by
 from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
                                 get_health_monitor)
 from ..utils.compile_cache import register_cache_metrics
@@ -100,7 +100,10 @@ def _paths_traced(records=None):
         traced[key] = (int(regions_traced(name, **labels)) - xla, xla)
     for key, (name, words, *label) in _declared("joined", records).items():
         label = label[0] if label else "path"
-        traced[key] = tuple(int(regions_traced(name, **{label: word})) for word in words)
+        if words is None:  # whatever values the sites gave the label: a number worked out where it is counted
+            traced[key] = regions_traced_by(name, label)
+        else:
+            traced[key] = tuple(int(regions_traced(name, **{label: word})) for word in words)
     return traced
 
 
@@ -789,7 +792,10 @@ class DeepSpeedEngine:
             if kernel or xla:
                 notes[key] = "mixed" if kernel and xla else words.get(key, "kernel") if kernel else "xla"
         for key, (_, labels, *_) in _declared("joined", records).items():
-            rose = [label for label, now, was in zip(labels, traced[key], traced_before[key]) if now > was]
+            if labels is None:
+                rose = sorted(value for value, now in traced[key].items() if now > traced_before[key].get(value, 0))
+            else:
+                rose = [label for label, now, was in zip(labels, traced[key], traced_before[key]) if now > was]
             if rose:
                 notes[key] = "+".join(rose)
         return notes

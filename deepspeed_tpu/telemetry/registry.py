@@ -215,6 +215,14 @@ class MetricsRegistry:
         want = set(_label_key(labels))
         return float(sum(m.value for (n, key), m in list(self._metrics.items()) if n == name and want <= set(key)))
 
+    def by_label(self, name: str, label: str, **labels) -> Dict[str, float]:
+        """``total`` split by the values of ``label``: {value: the sum over the series that carry it}."""
+        want, out = set(_label_key(labels)), {}
+        for (n, key), m in list(self._metrics.items()):
+            if n == name and want <= set(key) and label in dict(key):
+                out[dict(key)[label]] = out.get(dict(key)[label], 0.0) + float(m.value)
+        return out
+
     def series(self) -> Iterator[Tuple[str, float]]:
         """Flat (dotted_name, value) pairs for every series — the shape the
         MonitorBridge feeds to event writers (dots, not braces, so CSV

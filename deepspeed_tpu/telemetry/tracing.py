@@ -278,6 +278,14 @@ def regions_traced(region: str, **labels) -> float:
     return get_registry().total("program_regions_traced_total", region=region, **labels)
 
 
+def regions_traced_by(region: str, label: str) -> Dict[str, float]:
+    """``regions_traced`` of ``region`` by the values of ``label``, for a site whose label is a number it worked out
+    (the tiles a walk visits) and not one of a closed list of words."""
+    from .registry import get_registry
+
+    return get_registry().by_label("program_regions_traced_total", label, region=region)
+
+
 def current_span():
     """The innermost span open on this thread, or None: how a seam deep in a
     call (a program's first call) learns the ``q`` of the quantum it is in."""

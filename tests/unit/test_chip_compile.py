@@ -189,6 +189,23 @@ def _flash_diff(shape, window):
     return fwd_bwd, (S((B, Sq, Hq, Dk), BF16), S((B, Sq, KVH, Dk), BF16), S((B, Sq, KVH, Dv), BF16), S((B, Sq, Hq, Dv), BF16)), 2, "fused"
 
 
+def _flash_blockdiff(shape, block):
+    """Attention under the block-diffusion mask over a doubled row (``ops/masks.py::BlockDiffusion``): forward and the
+    fused backward, which at 16,384 rows and 8 query heads a KV head runs a head at a time on copies of the KV heads."""
+    from deepspeed_tpu.ops import masks
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, Sq, Hq, KVH, Dh = shape
+    mask = masks.BlockDiffusion(block, Sq // 2)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, mask=mask), q, k, v)
+        return (o,) + vjp(do)
+
+    q, kv = S((B, Sq, Hq, Dh), BF16), S((B, Sq, KVH, Dh), BF16)
+    return fwd_bwd, (q, kv, kv, q), 2, "kernel"
+
+
 def _moe_sum_rows(shape):
     """A routed layer's tokens sum their own rows off the expert-sorted buffer, each row times its weight: the combine,
     and with weights of one the backward of the rows' gather."""
@@ -235,6 +252,9 @@ CASES = {
     "ssm_scan_b2_s1000_c256_n16": lambda: _ssm((2, 1000, 256, 16)),                        # a length that is padded to chunks
     "flash_diff_b1_s8192_h20_kvh10_d64_v128": lambda: _flash_diff((1, 8192, 20, 10, 64, 128), None),     # ... its full and cross layers' calls
     "flash_diff_b1_s8192_h20_kvh10_d64_v128_w512": lambda: _flash_diff((1, 8192, 20, 10, 64, 128), 512),  # ... and its window layer's
+    "flash_blockdiff_b1_s16384_h32_kvh4_d128_blk4": lambda: _flash_blockdiff((1, 16384, 32, 4, 128), 4),    # sdar-30b-a3b-l4e16's every layer
+    "flash_blockdiff_b1_s16384_h32_kvh4_d128_blk16": lambda: _flash_blockdiff((1, 16384, 32, 4, 128), 16),  # ... another block length
+    "flash_blockdiff_b1_s6144_h8_kvh2_d128_blk12": lambda: _flash_blockdiff((1, 6144, 8, 2, 128), 12),      # ... one that is no power of two, grouped in the kernel
     "flash_mha_b8_s1024_h12_d64": lambda: _flash((8, 1024, 12, 12, 64)),
     "flash_gqa_b2_s4096_h32_kvh4_d128": lambda: _flash((2, 4096, 32, 4, 128)),
     "flash_mha_b2_s2048_h16_d128": lambda: _flash((2, 2048, 16, 16, 128)),  # olmo-1b.pretrain-z3, one chip's share
@@ -262,7 +282,7 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, shapes, kernels, *bwd_path = CASES[case]()
     args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
     traced = lambda: {p: regions_traced("mixer/kernel", **{"pass": "bwd", "path": p})  # whichever ``op``
-                      for p in ("fused", "split")}
+                      for p in ("fused", "split", "kernel")}  # (``kernel``: a mask with a walk of its own counts its calls so)
     before = traced()
     if bwd_path == ["refused"]:
         with pytest.raises(NotImplementedError, match="seq_q=32768.*VMEM"):
@@ -276,7 +296,7 @@ def test_kernel_compiles_for_v5e(case, one_chip):
         return
     # the flash cases name their backward: exactly that many kernels, and the rule picked that path
     assert compiled.as_text().count("tpu_custom_call") == kernels
-    assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, bwd_path[0]: 1.0}
+    assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, "kernel": 0.0, bwd_path[0]: 1.0}
 
 
 def _pallas_calls(jaxpr):

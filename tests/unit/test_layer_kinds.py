@@ -3,7 +3,7 @@ the table (``models/transformer.py``), and the model, the trainer and the server
 
 (a) what a new kind costs: a mixer defined HERE, one line of the table, and a model with it trains through
 ``deepspeed_tpu.initialize`` under ``remat`` with its own sown count reported and its own key on the first-call line;
-(b) the fourteen kinds' records against what a traced block of each sows, names and counts, and against what the stacked
+(b) the fifteen kinds' records against what a traced block of each sows, names and counts, and against what the stacked
 forms take; (c) the hosts' sources spell no kind; and what must not move: ``TransformerConfig``'s fields, the five
 cells' parameter trees and their checkpointed blocks' programs."""
 
@@ -35,7 +35,7 @@ from deepspeed_tpu.telemetry import device_counts, get_registry, get_tracer
 from deepspeed_tpu.telemetry.tracing import region
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-KINDS = ("kda", "gdn", "mla", "sparse", "routed", "ssm", "diff", "diff_window", "gmu", "diff_cross")  # the names no host may spell
+KINDS = ("kda", "gdn", "mla", "sparse", "routed", "ssm", "diff", "diff_window", "gmu", "diff_cross", "blockdiff")  # the names no host may spell
 sha = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
@@ -102,7 +102,7 @@ def tiny(mixer, ffn, **over):
                 pos_emb="rope", tie_embeddings=False, layer_kinds=((mixer, ffn),), sliding_window=16, kda_heads=2, kda_head_dim=16,
                 kda_gate_rank=8, gdn_key_heads=2, gdn_value_heads=4, gdn_head_dim=16, mla_kv_rank=24, mla_qk_nope_dim=24, mla_qk_rope_dim=8,
                 mla_v_dim=16, index_heads=2, index_head_dim=8, index_topk=16, moe_num_experts=8, moe_top_k=2, moe_d_ff=16, moe_shared_d_ff=16,
-                ssm_inner=128, ssm_dt_rank=4)
+                ssm_inner=128, ssm_dt_rank=4, block_length=4, mask_token_id=96)
     return TransformerConfig(**dict(base, **over))
 
 
@@ -135,7 +135,8 @@ def _names(jaxpr, found):
 # a kernel's custom_vjp gives these where the kernel runs: off the TPU no trace shows them (``tests/unit/test_chip_compile.py``)
 KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
 # a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
-WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel"}
+WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel",
+        "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])
@@ -159,7 +160,7 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
         run = lambda p, x, run=run: (lambda out, sown: (out[0], sown))(*run(p, x))
     before = trainer._paths_traced()
     jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(run(p, x)[0])))(params, x)
-    rose = {key for key, now in trainer._paths_traced().items() if tuple(now) != tuple(before[key])}
+    rose = {key for key, now in trainer._paths_traced().items() if now != before[key]}
     sown = jax.eval_shape(run, params, x)[1]
     assert {col for col, tree in sown.items() if jax.tree_util.tree_leaves(tree)} == set(record.sows)
     assert table.kinds_sow((kind,)) == bool(record.sows)
@@ -187,10 +188,17 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
 def test_a_model_of_one_kind_says_its_keys_only_where_the_record_asks():
     """The trainer's first-call line by the records alone (no step is run: the counters are what the traces above, or
     none, left): ``alone`` is the sparse mixer's."""
-    notes = lambda mixer: trainer.DeepSpeedEngine._layer_kind_notes(
-        type("E", (), {"module": type("M", (), {"cfg": tiny(mixer, "dense", n_layers=2, layer_kinds=((mixer, "dense"),) * 2)})}), trainer._paths_traced())
-    assert [name for name, record in table.MIXERS.items() if record.alone] == ["sparse"] and not any(r.alone for r in table.FFNS.values())
-    assert notes("mla") == {} and notes("sparse") == {"layer_kinds": "sparse+dense:2"}
+    notes_since = lambda mixer, before: trainer.DeepSpeedEngine._layer_kind_notes(
+        type("E", (), {"module": type("M", (), {"cfg": tiny(mixer, "dense", n_layers=2, layer_kinds=((mixer, "dense"),) * 2)})}), before)
+    notes = lambda mixer: notes_since(mixer, trainer._paths_traced())
+    assert [name for name, record in table.MIXERS.items() if record.alone] == ["sparse", "blockdiff"] and not any(r.alone for r in table.FFNS.values())
+    assert notes("mla") == {} and notes("sparse") == {"layer_kinds": "sparse+dense:2"} and notes("blockdiff") == {"layer_kinds": "blockdiff+dense:2"}
+    # a key whose words are whatever its sites gave (``joined`` with None): the values that rose since, sorted
+    assert table.MIXERS["blockdiff"].joined["blockdiff_tiles"] == ("mixer/kernel", None, "tiles")
+    before = trainer._paths_traced()
+    with region("mixer/kernel", op="blockdiff", path="kernel", tiles="3/4", pairs="80", **{"pass": "fwd"}):
+        pass
+    assert notes_since("blockdiff", before) == {"layer_kinds": "blockdiff+dense:2", "blockdiff_path": "kernel", "blockdiff_tiles": "3/4", "blockdiff_pairs": "80"}
     assert set(trainer._ROUTER_WORDS) == {"sigmoid", "softmax", "compare_sum"} and trainer._PATH_WORDS == {"moe_cond": "fallback_keeps_nothing"}
 
 
@@ -223,7 +231,8 @@ def test_the_configurations_fields_are_the_parents():
     fields = dataclasses.fields(TransformerConfig)
     assert sha(repr([(f.name, repr(f.default)) for f in fields[:76]])) == "02e66817e969c9f2"  # PR 45's 76, as they were
     assert [(f.name, f.default) for f in fields[76:]] == [("ssm_inner", 0), ("ssm_state", 16), ("ssm_conv", 4), ("ssm_dt_rank", 0),
-                                                          ("layer_numbers", None)]  # PR 46: appended, nothing moved
+                                                          ("layer_numbers", None),  # PR 46: appended, nothing moved
+                                                          ("block_length", 0), ("mask_token_id", 0), ("blockdiff_qk_init_scale", 1.0)]  # PR 49: likewise
     assert [f.name for f in fields] == [f.name for f in dataclasses.fields(TransformerFields)]
     cfg = TransformerConfig(n_layers=3)
     assert TransformerConfig(**cfg.__dict__) == cfg == dataclasses.replace(cfg) and hash(cfg) == hash(dataclasses.replace(cfg))
