@@ -12,7 +12,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
-from ..ops.pallas.flash_attention import SAVED as FLASH_SAVED
+from ..ops.pallas.flash_attention import SAVED as FLASH_SAVED, TILES_A_TRIP
 from ..telemetry.tracing import region
 from .config import TransformerFields
 
@@ -38,8 +38,9 @@ class LayerKind:
     # the trainer's first-call line. ``paths``: key -> (region, labels) of ``program_regions_traced_total``; the key's
     # word is ``xla`` where only ``path="xla"`` call sites rose, ``mixed``, else ``kernel`` (or ``path_words[key]``).
     # ``joined``: key -> (region, the ``path`` labels of it that may rise[, another label than ``path`` whose values they
-    # are]): the word is those that rose, "+" between; ``None`` for the labels: whatever values the sites gave (a number
-    # a site worked out, as the tiles a mask's walk visits).
+    # are[, labels a series must also carry to be read]]): the word is those that rose, "+" between; ``None`` for the
+    # labels: whatever values the sites gave (a number a site worked out, as the tiles a mask's walk visits). A model of
+    # one plain kind says its joined keys too, where they rose (the flash kernels' ``tiles_a_trip_fwd``).
     # ``alone``: a model whose layers are all of one kind says nothing of kinds on that line, unless this
     paths, path_words, joined, alone = {}, {}, {}, False
     stackable = False  # the scan over layers, ``to_pipeline`` and ``inference/v2`` can run it
@@ -240,7 +241,7 @@ def alibi_slopes(n_heads: int) -> np.ndarray:
 class Attention(LayerKind, nn.Module):
     cfg: TransformerFields
     window: Optional[int] = None  # the kind ``window``: ``sliding_window`` keys; None: ``full``
-    keeps, stackable = (FLASH_SAVED, SAVED), True
+    keeps, stackable, joined = (FLASH_SAVED, SAVED), True, TILES_A_TRIP
 
     @classmethod
     def from_config(cls, cfg, kind):

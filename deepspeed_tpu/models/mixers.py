@@ -33,7 +33,7 @@ from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
 from .config import TransformerFields
-from .layers import FLASH_SAVED, SAVED, LayerKind, LayerNorm, RMSNorm, apply_rope, scaled_rope_frequencies
+from .layers import FLASH_SAVED, SAVED, TILES_A_TRIP, LayerKind, LayerNorm, RMSNorm, apply_rope, scaled_rope_frequencies
 
 
 def _uniform(low, high):
@@ -181,7 +181,7 @@ class MLAMixer(LayerKind, nn.Module):
     cfg: TransformerFields
     keeps, hybrid = (FLASH_SAVED, SAVED), True
     # ``mla_rope``: the rotation of the shared key part (no key where the model has no positions)
-    paths = {"mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "mla_rope": ("mixer/rope", {})}
+    paths, joined = {"mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "mla_rope": ("mixer/rope", {})}, TILES_A_TRIP
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
@@ -401,7 +401,7 @@ class DiffAttention(LayerKind, nn.Module):
     cfg: TransformerFields
     window: Optional[int] = None
     keeps, hybrid = (FLASH_SAVED, SAVED), True
-    paths = _DIFF_PATHS
+    paths, joined = _DIFF_PATHS, TILES_A_TRIP
     gives, takes = ("shared_k", "shared_v"), ("layer",)
 
     @classmethod
@@ -426,7 +426,7 @@ class DiffCrossAttention(LayerKind, nn.Module):
 
     cfg: TransformerFields
     keeps, hybrid = (FLASH_SAVED, SAVED), True
-    paths = _DIFF_PATHS
+    paths, joined = _DIFF_PATHS, TILES_A_TRIP
     takes = ("shared_k", "shared_v", "layer")
 
     @nn.compact
@@ -489,7 +489,7 @@ class BlockDiffMixer(LayerKind, nn.Module):
     keeps, hybrid = (FLASH_SAVED, SAVED), True
     paths, alone = {"blockdiff_path": ("mixer/kernel", {"op": "blockdiff", "pass": "fwd"})}, True
     # static at trace time, counted where the kernel's walk is chosen: tiles visited / tiles of the square, pairs kept
-    joined = {"blockdiff_tiles": ("mixer/kernel", None, "tiles"), "blockdiff_pairs": ("mixer/kernel", None, "pairs")}
+    joined = {"blockdiff_tiles": ("mixer/kernel", None, "tiles"), "blockdiff_pairs": ("mixer/kernel", None, "pairs"), **TILES_A_TRIP}
 
     @staticmethod
     def halves(cfg, S: int) -> int:

@@ -13,6 +13,7 @@ Records are frozen and hashable: they are static arguments of the kernels' ``cus
 the label the calls are counted under (``program_regions_traced_total{region="mixer/kernel", op}``).
 """
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -178,11 +179,21 @@ def of(causal: bool, window=None):
     return Causal(int(window or 0)) if causal or window else Full()
 
 
+@functools.lru_cache(maxsize=None)
+def _walks(mask, bq, bk, seq_q, seq_k):
+    """Every q tile's forward runs as host integers, by the record's own ``kv_runs`` (static shapes: worked out while a
+    program is traced, where the runs' arithmetic is the host's; once a record and shape)."""
+    with jax.ensure_compile_time_eval():
+        return tuple(tuple((int(first), int(end), masked) for first, end, masked in mask.kv_runs(np.int32(qi), bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k))
+                     for qi in range(seq_q // bq))
+
+
 def tiles_visited(mask, *, bq, bk, seq_q, seq_k) -> int:
-    """How many tiles a forward walk visits, by the record's own runs (static shapes: worked out on the host)."""
-    total = 0
-    with jax.ensure_compile_time_eval():  # called while a program is traced: the runs' arithmetic is the host's here
-        for qi in range(seq_q // bq):
-            for first, end, _ in mask.kv_runs(np.int32(qi), bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k):
-                total += max(int(end) - int(first), 0)
-    return total
+    """How many tiles a forward walk visits."""
+    return sum(max(end - first, 0) for runs in _walks(mask, bq, bk, seq_q, seq_k) for first, end, _ in runs)
+
+
+def longest_whole_run(mask, *, bq, bk, seq_q, seq_k) -> int:
+    """The most tiles any unmasked run of a forward walk has: tiles that lie wholly inside the mask, one after another
+    (none under a window of a tile's width, whose every visited tile crosses an edge)."""
+    return max((end - first for runs in _walks(mask, bq, bk, seq_q, seq_k) for first, end, masked in runs if not masked), default=0)

@@ -26,7 +26,7 @@ far edge, a block's), the tiles between take the same ``_scores`` with the
 mask statically off, and a tile wholly outside is never visited.
 
 What the code observes to choose a path (``program_regions_traced_total{region=
-"mixer/kernel", op, pass, path}`` counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
+"mixer/kernel", op, pass, path, tiles_a_trip}`` counts the choice, docs/OBSERVABILITY.md): a bias takes the split backward,
 the query-major dq kernels (dbias is written there) on a (B*H, Sq/bq) grid
 and a dkv kernel on (B*H, Sk/bk). Without a bias there is the fused kernel
 alone: a head whose q, do and dq do not fit ``vmem_budget()`` at once
@@ -43,6 +43,37 @@ across the group in-kernel, in float32, instead of materializing expanded
 cotangents. The softmax scale multiplies the float32 scores, as in
 ``attention_xla``: scaling a bf16 operand first saves 4% of the forward and
 doubles the kernels' distance from float32 (PERF.md, PR 26).
+
+**Two tiles a trip in the forward.** A tile of the forward's walk is a chain:
+``s = k q^T`` -> the column max over ALL of s -> ``exp`` -> the column sum ->
+``v^T p``, every link waiting for the one before, and a loop trip a tile
+gives Mosaic's scheduler nothing else to issue meanwhile: it overlaps what
+the program's text puts side by side in one loop body and little else
+(``ops/pallas/kda.py``, PR 47: heads written one after the other ran no
+faster than one a step, 6.93 -> 6.53 ms, the same heads interleaved 3.63). So
+over an unmasked run a trip takes tiles j and j + 1 (``_walk``, ``pair`` in
+``_fwd_kernel``): both score products first, then tile j's online-softmax
+update, then tile j + 1's against the updated m, l, acc. The second product
+stands beside the first chain and ``v1^T p1`` beside the second; nothing in a
+tile's arithmetic or in the order of the updates moves, so o and lse are, bit
+for bit, one tile a trip's (on the chip too: the same digests at nine
+shapes). A run's odd last tile and the masked runs (a mask's edges: the
+diagonal's tile, a window's, a block's) take the body of one tile.
+``tiles_a_trip`` chooses from what the call can see (the mask's longest
+unmasked run at these shapes, the VMEM count) and counts its choice
+(``program_regions_traced_total{tiles_a_trip}``). What it buys, by the
+compiler's own bundles for the described v5e at SDAR's shape (PERF.md, PR 50):
+an unmasked tile is 1,453 bundles alone (a masked one 2,435) and 1,270 in a
+pair, where the two products' 1,024 MXU cycles are the floor: the pair's body
+is ``s1 | s2 beside chain 1 | v1^T p1 beside chain 2 | v2^T p2`` at about
+350 | 650 | 700 | 800 bundles, and ``v^T p``, whose weights are the sixteen
+(128, 128) pieces of p with 128 rows of v^T each, is the long link. On the
+chip the forward call falls by 9% at SDAR's shape (12.45 -> 11.31 ms), 5-11%
+under a causal mask at 8,192, 2% at 2,048. The fused backward keeps one tile:
+its unmasked tile is 2,517 bundles for five products (2,560 MXU cycles), two
+abreast 2,297 each, and on the chip two ran no faster (21.78 | 22.06 ms at
+SDAR's shape, 7.996 | 7.91 at Kimi-VL's, 0.840 | 0.918 at OLMo's): it is
+bound by its products as Mosaic lowers them, not by its chain.
 """
 
 import functools
@@ -67,15 +98,19 @@ NEG_INF = -1e30
 # the caller keeps them, or the projections they follow from elementwise
 SAVED = "flash_attention"
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
+# for a kind's record (``LayerKind.joined``): the series that say how many tiles a trip of the kernels' walks takes, a pass
+# (``tiles_a_trip`` gives the forward's; the backward's walk keeps one)
+TILES_A_TRIP = {f"tiles_a_trip_{pass_}": ("mixer/kernel", ("1", "2"), "tiles_a_trip", {"pass": pass_}) for pass_ in ("fwd", "bwd")}
 
 # Default blocks are large: the grid runs sequentially on the (single)
-# tensor core, and every program pays the VPU online-softmax chain between
-# short MXU ops — many tiny (128,128) programs are latency-bound, not
-# FLOP-bound. (512, 512) keeps the fp32 score block at 1 MB of VMEM,
-# amortizes the chain over 16x more MXU work, and stays causal-efficient
-# at the block boundary; it is also the fastest of the shapes from 256 to
-# 2048 for the forward and the fused backward alike at S=2048, D=128
-# (PERF.md, PR 26). Overridable for autotuning.
+# tensor core, and a tile's VPU online-softmax chain stands between its two
+# MXU products (one tile a trip: nothing runs beside it; two a trip, the
+# forward's unmasked runs since PR 50: the other tile's product does) — many
+# tiny (128,128) programs are latency-bound, not FLOP-bound. (512, 512) keeps
+# the fp32 score block at 1 MB of VMEM, amortizes the chain over 16x more MXU
+# work, and stays causal-efficient at the block boundary; it is also the
+# fastest of the shapes from 256 to 2048 for the forward and the fused
+# backward alike at S=2048, D=128 (PERF.md, PR 26). Overridable for autotuning.
 DEFAULT_BQ = knobs.get_int("DS_TPU_FLASH_BQ")
 DEFAULT_BK = knobs.get_int("DS_TPU_FLASH_BK")
 
@@ -138,9 +173,17 @@ def _scores(q, k, slope, row0, col0, scale, mask, has_alibi, btile=None, *, mask
     return s
 
 
-def _walk(runs, body, carry):
-    """``body(block, carry, masked)`` over every block of ``runs`` in order."""
+def _walk(runs, body, carry, pair=None):
+    """``body(block, carry, masked)`` over every block of ``runs`` in order. With ``pair``, an unmasked run goes two
+    tiles a trip, ``pair(block, carry)`` taking ``block`` and ``block + 1``, and its odd last tile alone (a run's bounds
+    are traced values: a loop over its pairs, then the loop over what is left, one trip at most). The masked runs are a
+    mask's edges, a tile or two each, and keep one tile a trip."""
     for first, end, masked in runs:
+        if pair is not None and not masked:
+            length = end - first  # a run may end before it starts (no tile)
+            trips = (max(length, 0) if isinstance(length, int) else jnp.maximum(length, 0)) // 2
+            carry = jax.lax.fori_loop(0, trips, lambda t, c, first=first: pair(first + 2 * t, c), carry)
+            first = first + 2 * trips
         carry = jax.lax.fori_loop(first, end, functools.partial(body, masked=masked), carry)
     return carry
 
@@ -178,11 +221,14 @@ def _bias_bh_fn(bias_meta, H: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq: int, bk: int, seq_q: int,
-                seq_k: int, scale: float, mask, has_alibi: bool, has_bias: bool, sqb1: bool):
+                seq_k: int, scale: float, mask, has_alibi: bool, has_bias: bool, sqb1: bool, tiles_a_trip: int):
     """One q block against the kv blocks it sees, on kv-major (bk, bq) tiles
     (``_scores``): the running max and sum are (1, bq) rows that reduce and
     broadcast along sublanes, the accumulator is (D, bq) and turned once at
-    the end, and lse leaves as the row the backward reads (``_rows``)."""
+    the end, and lse leaves as the row the backward reads (``_rows``). With
+    ``tiles_a_trip`` = 2 a trip of an unmasked run takes two tiles (the
+    module's docstring): both score products stand first in the text, then the
+    two online-softmax updates, one after the other as two trips make them."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (bq, D) input dtype — MXU runs bf16 operands w/ fp32 accumulation
     D = v_ref.shape[-1]  # the value head size: q and k may have another (latent attention: 192 beside 128)
@@ -190,14 +236,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
     row0 = seq_k - seq_q + qi * bq
     guard = _needs_empty_guard(seq_q, seq_k, has_bias)
 
-    def body(j, carry, masked):
-        acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
+    def scored(j, masked):
         k = k_ref[0, pl.dslice(j * bk, bk), :]
-        v = v_ref[0, pl.dslice(j * bk, bk), :]
         btile = None
         if has_bias:  # the (bk, 1) column all rows share, or the (bq, bk) tile turned kv-major
             btile = bias_ref[0, pl.dslice(j * bk, bk), :] if sqb1 else bias_ref[0, :, pl.dslice(j * bk, bk)].T
-        s = _scores(q, k, slope, row0, j * bk, scale, mask, has_alibi, btile, masked=masked, kv_major=True)
+        return _scores(q, k, slope, row0, j * bk, scale, mask, has_alibi, btile, masked=masked, kv_major=True)
+
+    def update(j, s, carry, masked):
+        acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
+        v = v_ref[0, pl.dslice(j * bk, bk), :]
         new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - new_m)
         if guard and (masked or has_bias):
@@ -207,11 +255,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
         new_acc = acc * corr + jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
         return new_acc, new_m, new_l
 
+    def body(j, carry, masked):
+        return update(j, scored(j, masked), carry, masked)
+
+    def pair(j, carry):  # tiles j and j + 1 of an unmasked run: the second score product beside the first chain
+        s0, s1 = scored(j, False), scored(j + 1, False)
+        return update(j + 1, s1, update(j, s0, carry, False), False)
+
     acc0 = jnp.zeros((D, bq), jnp.float32)
     m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
     runs = mask.kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
-    acc, m, l = _walk(runs, body, (acc0, m0, l0))
+    acc, m, l = _walk(runs, body, (acc0, m0, l0), pair if tiles_a_trip == 2 else None)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l_safe)
@@ -257,7 +312,6 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_a
     if mask.op != "flash":
         visited = masks.tiles_visited(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)
         walk = {"tiles": f"{visited}/{(Sq // bq) * (Sk // bk)}", "pairs": str(mask.pairs)}
-    _count_traced("fwd", "single", Dv != D, mask, **walk)
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block
@@ -272,9 +326,11 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_a
         bias_spec = pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0))
     vmem = (2 * (bq * (D + Dv) + Sk * (D + Dv)) * q.dtype.itemsize + _tile_bytes(bq, bk)
             + (2 * (LANES if sqb1 else bq) * Sk * 4 if has_bias else 0))
+    tiles = tiles_a_trip(masks.longest_whole_run(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk), vmem)
+    _count_traced("fwd", "single", Dv != D, mask, tiles_a_trip=str(tiles), **walk)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask,
-                          has_alibi=has_alibi, has_bias=has_bias, sqb1=sqb1),
+                          has_alibi=has_alibi, has_bias=has_bias, sqb1=sqb1, tiles_a_trip=tiles),
         grid=(BH, Sq // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -480,10 +536,25 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_re
 
 
 def _tile_bytes(bq: int, bk: int) -> int:
-    """VMEM for a block's (bq, bk) temporaries: s, p, dp, ds in float32 and
-    their casts; what the compiler keeps live of them is not ours to know, so
-    this is the generous count."""
+    """VMEM for a trip's (bq, bk) temporaries: s, p, dp, ds in float32 and
+    their casts. The generous count for one tile, and room for the forward's
+    two: by Mosaic's own allocation for the described v5e at (512, 512) (the
+    least limit a call compiles under, one tile a trip | two; PERF.md, PR 50)
+    the second tile adds 2.0 MiB to a first of about 2 (SDAR's forward 19.2 |
+    21.2 MiB beside our count of 24, OLMo's 4.7 | 5.9 beside 10.5)."""
     return 8 * bq * bk * 4
+
+
+def tiles_a_trip(whole_run: int, vmem: int) -> int:
+    """How many tiles a trip of the forward's walk takes over an unmasked run, from what the call can see: 2 where the
+    mask has an unmasked run of two tiles or more at these shapes (``whole_run``: ``masks.longest_whole_run``) and the
+    call's count of VMEM (``_tile_bytes`` has room for the pair) is within ``vmem_budget()``; else 1, the kernel as it
+    was (the same code, one tile a trip). Written from this table (TPU v5e, the forward call alone in ms, one | two;
+    PERF.md, PR 50): SDAR's mask at 32 x 16,384 x 128, runs of up to 16: 12.45 | 11.31; causal at 16 x 8,192: 2.745 |
+    2.43 at 128, 3.421 | 3.251 at 192/128, 3.447 | 3.09 at 64/128 under GQA 20/10, 4.195 | 4.012 at 256 under GQA 16/2;
+    at 4,096 (runs of up to 7) 0.833 | 0.78; OLMo's 32 x 2,048 (up to 3) 0.4564 | 0.4463. Under a window of one tile
+    (512 at 8,192: no unmasked run) the pair's loops are never entered and cost 1.115 | 1.137: there the rule says 1."""
+    return 2 if whole_run >= 2 and vmem <= vmem_budget() else 1
 
 
 def _fused_bwd_vmem(Sq: int, Sk: int, D: int, item: int, bq: int, bk: int, n_rep: int, Dv: int = 0) -> int:
@@ -540,7 +611,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret:
                 f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "
                 f"{n_rep} q heads a KV head take {fused_vmem >> 20} MiB of VMEM, over this device's budget of "
                 f"{vmem_budget() >> 20} MiB: split the sequence over the mesh (sequence or context parallelism)")
-        _count_traced("bwd", "fused", Dv != D, mask)
+        _count_traced("bwd", "fused", Dv != D, mask, tiles_a_trip="1")
         whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
         rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
         kv_blk = [pl.BlockSpec((1, bk, d), lambda b, r, j: (b, j, 0)) for d in (D, Dv)]
@@ -578,7 +649,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret:
     # dkv kernel a q head
     if Dv != D:
         raise NotImplementedError(f"flash_attention backward under a bias takes one head size, got {D} and {Dv}")
-    _count_traced("bwd", "split")
+    _count_traced("bwd", "split", tiles_a_trip="1")
     lse, delta = (jnp.broadcast_to(x[..., None], (BH, Sq, LANES)) for x in (lse, delta))
     Bb, Hb, Sqb, repeat = bias_meta
     bias_bh = _bias_bh_fn(bias_meta, H)

@@ -98,12 +98,12 @@ def _paths_traced(records=None):
     for key, (name, labels) in _declared("paths", records).items():
         xla = int(regions_traced(name, path="xla", **labels))
         traced[key] = (int(regions_traced(name, **labels)) - xla, xla)
-    for key, (name, words, *label) in _declared("joined", records).items():
-        label = label[0] if label else "path"
+    for key, (name, words, *rest) in _declared("joined", records).items():
+        label, also = rest[0] if rest else "path", rest[1] if len(rest) > 1 else {}
         if words is None:  # whatever values the sites gave the label: a number worked out where it is counted
             traced[key] = regions_traced_by(name, label)
         else:
-            traced[key] = tuple(int(regions_traced(name, **{label: word})) for word in words)
+            traced[key] = tuple(int(regions_traced(name, **{label: word}, **also)) for word in words)
     return traced
 
 
@@ -772,8 +772,9 @@ class DeepSpeedEngine:
         (``LayerKind.alone``): how many layers of each (mixer, ffn) pair, and the keys its kinds' records declare
         (``LayerKind.paths``, ``joined``), by the counters that count each choice where it is made: ``kernel`` (Pallas),
         ``xla`` (the fallback), ``mixed``, a word of the record's own, or no key where this program traced no such call
-        site; a joined key's word is the labels that rose, ``+`` between. Whatever the kinds, under ``remat``: what a
-        checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
+        site; a joined key's word is the labels that rose, ``+`` between. A model of ONE plain kind says the joined keys
+        alone (what its kernels chose for themselves: the flash kernels' tiles a trip). Whatever the kinds, under
+        ``remat``: what a checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
         cfg = getattr(self.module, "cfg", None)
         kinds = getattr(cfg, "kinds", None)
         if not kinds:
@@ -783,14 +784,15 @@ class DeepSpeedEngine:
             names = () if cfg.scan_layers else sorted({name for kind in kinds for name in layer_kinds.remat_keeps(kind)})
             notes["remat_keeps"] = "+".join(names) or "inputs"
         records = layer_kinds.records(kinds)
-        if len(set(kinds)) == 1 and not any(record.alone for record in records):  # one kind of plain block: nothing was chosen
-            return notes
-        notes["layer_kinds"] = ",".join(f"{k}:{n}" for k, n in sorted(collections.Counter(f"{mixer}+{ffn}" for mixer, ffn in kinds).items()))
+        # one kind of plain block: no form of a layer was chosen, only what its kernels chose for themselves (``joined``)
+        plain = len(set(kinds)) == 1 and not any(record.alone for record in records)
         traced, words = _paths_traced(records), _declared("path_words", records)
-        for key in _declared("paths", records):
-            kernel, xla = (now - was for now, was in zip(traced[key], traced_before[key]))
-            if kernel or xla:
-                notes[key] = "mixed" if kernel and xla else words.get(key, "kernel") if kernel else "xla"
+        if not plain:
+            notes["layer_kinds"] = ",".join(f"{k}:{n}" for k, n in sorted(collections.Counter(f"{mixer}+{ffn}" for mixer, ffn in kinds).items()))
+            for key in _declared("paths", records):
+                kernel, xla = (now - was for now, was in zip(traced[key], traced_before[key]))
+                if kernel or xla:
+                    notes[key] = "mixed" if kernel and xla else words.get(key, "kernel") if kernel else "xla"
         for key, (_, labels, *_) in _declared("joined", records).items():
             if labels is None:
                 rose = sorted(value for value, now in traced[key].items() if now > traced_before[key].get(value, 0))

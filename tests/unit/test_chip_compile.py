@@ -283,7 +283,8 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
     traced = lambda: {p: regions_traced("mixer/kernel", **{"pass": "bwd", "path": p})  # whichever ``op``
                       for p in ("fused", "split", "kernel")}  # (``kernel``: a mask with a walk of its own counts its calls so)
-    before = traced()
+    trips = lambda: [regions_traced("mixer/kernel", **{"pass": p, "tiles_a_trip": n}) for p, n in (("fwd", "1"), ("fwd", "2"), ("bwd", "1"), ("bwd", "2"))]
+    before, trips_before = traced(), trips()
     if bwd_path == ["refused"]:
         with pytest.raises(NotImplementedError, match="seq_q=32768.*VMEM"):
             jax.jit(fn).lower(*args)
@@ -297,6 +298,45 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     # the flash cases name their backward: exactly that many kernels, and the rule picked that path
     assert compiled.as_text().count("tpu_custom_call") == kernels
     assert {p: n - before[p] for p, n in traced().items()} == {"fused": 0.0, "split": 0.0, "kernel": 0.0, bwd_path[0]: 1.0}
+    # ... in the new form (PR 50): the forward takes two tiles a trip over its unmasked runs at every cell's shape but
+    # under Phi-4's window of one tile's width, which has no unmasked run (and at GPT-2's 1,024 positions, two tiles a
+    # side: no run of two); the backward keeps one
+    pairs = 0.0 if case.endswith("_w512") or "_s1024_" in case else 1.0
+    assert [now - was for now, was in zip(trips(), trips_before)] == [1.0 - pairs, pairs, 1.0, 0.0]
+
+
+@pytest.mark.parametrize("case,heads", [("flash_blockdiff_b1_s16384_h32_kvh4_d128_blk4", (32, 4)), ("flash_gqa_b1_s8192_h16_kvh2_d256", (16, 2))])
+def test_the_longest_heads_fit_their_own_vmem_count_with_two_tiles_in_the_forward(case, heads, one_chip, monkeypatch):
+    """SDAR's rows of 16,384 at 128 and Qwen3-Next's of 8,192 at 256, the two calls nearest ``vmem_budget()`` (48 MiB): the
+    backward's count for a head alone is 43.0 MiB in both, under it, and its group's sums over the whole sequence do not fit
+    (a head at a time, as before PR 50: a second tile in the backward was measured and not taken); the forward's count,
+    whose 8 MiB of temporaries hold the pair, is 24.5 and 25 MiB and the rule gives it two tiles. And the counts are enough:
+    both calls compile for the described v5e under a limit of the count ITSELF, without the 16 MiB ``compiler_params`` adds
+    (Mosaic's own allocation: forward 21.2 MiB with two tiles, backward 22.2 and 24.4)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepspeed_tpu.ops.pallas import flash_attention as F
+    from deepspeed_tpu.telemetry.tracing import regions_traced
+
+    fn, shapes, *_ = CASES[case]()
+    (_, Sq, H, D), KVH = shapes[0].shape, shapes[1].shape[2]
+    assert (H, KVH) == heads
+    head, group = (F._fused_bwd_vmem(Sq, Sq, D, 2, 512, 512, n_rep, D) for n_rep in (1, H // KVH))
+    forward = 2 * (512 + Sq) * 2 * D * 2 + F._tile_bytes(512, 512)
+    assert (head >> 10, forward >> 10) == {128: (44032, 25088), 256: (44032, 25600)}[D]  # KiB
+    assert forward < head <= F.vmem_budget() < group
+    limits = []
+
+    def exactly(*semantics, interpret, vmem_bytes=0):
+        limits.append(vmem_bytes)
+        return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=vmem_bytes)
+
+    monkeypatch.setattr(F, "_compiler_params", exactly)
+    two = lambda: regions_traced("mixer/kernel", **{"pass": "fwd", "tiles_a_trip": "2"})
+    before = two()
+    args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
+    assert jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call") == 2
+    assert limits == [forward, head] and two() == before + 1
 
 
 def _pallas_calls(jaxpr):

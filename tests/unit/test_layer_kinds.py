@@ -136,7 +136,8 @@ def _names(jaxpr, found):
 KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
 # a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
 WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel",
-        "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same"}
+        "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same",
+        "tiles_a_trip_fwd": "the attention is the flash kernel (tests/unit/test_pallas_ops.py, test_regions.py)", "tiles_a_trip_bwd": "the same"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])

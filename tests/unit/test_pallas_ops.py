@@ -163,6 +163,93 @@ def test_flash_bwd_paths_match_xla(case, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3, rtol=1e-3, err_msg=name)
 
 
+# two tiles a trip against one: name -> (mask, Sq, Sk, H, KVH, D, Dv, tile, how the backward runs a GQA group[, the
+# rule's word where it is not 2]). Tiles of 32 under sequences of 2 to 10 tiles: a row band's unmasked runs are of every
+# length from none to seven, so even, odd and single runs all occur in a walk (a causal row band i has i whole tiles and
+# the diagonal's one)
+def _pair_cases():
+    from deepspeed_tpu.ops import masks
+
+    return {
+        "causal_mha": (masks.Causal(), 256, 256, 2, 2, 32, 32, 32, "fused"),
+        "causal_mha_two_tiles": (masks.Causal(), 64, 64, 2, 2, 32, 32, 32, "fused", 1),  # every run a single tile: no pair to take
+        "causal_gqa_summed_in_the_kernel": (masks.Causal(), 256, 256, 4, 2, 32, 32, 32, "fused"),
+        "causal_gqa_a_head_at_a_time": (masks.Causal(), 256, 256, 4, 1, 32, 32, 32, "per_head"),
+        "causal_latent_192_128": (masks.Causal(), 256, 256, 2, 2, 192, 128, 64, "fused"),  # kimi's head sizes
+        "window_of_a_tile_64_128_gqa": (masks.Causal(64), 320, 320, 4, 2, 64, 128, 64, "fused", 1),  # phi-4's: window = tile, as 512 at 512: every tile crosses an edge
+        "window_of_four_tiles_64_128_gqa": (masks.Causal(256), 640, 640, 4, 2, 64, 128, 64, "fused"),  # ... and one with whole tiles between its edges
+        "window_of_three_tiles": (masks.Causal(96), 256, 256, 2, 2, 32, 32, 32, "fused"),  # masked, whole and masked runs
+        "blockdiff_4_over_a_doubled_row": (masks.BlockDiffusion(4, 160), 320, 320, 4, 2, 32, 32, 32, "fused"),
+        "blockdiff_4_a_head_at_a_time": (masks.BlockDiffusion(4, 128), 256, 256, 4, 1, 32, 32, 32, "per_head"),  # sdar's backward
+        "no_mask": (masks.Full(), 128, 192, 2, 2, 32, 32, 32, "fused"),  # runs of 6 (forward) and 4 (backward), their bounds static
+        # runs of 7 and 5: a static last trip. Op by op (``disable_jit``): jitted, XLA's CPU compiler inlines a loop of one
+        # static trip beside the kernel's last lines and rounds one lse of 320 another way (1 ulp at optimisation level 0)
+        "no_mask_odd_runs": (masks.Full(), 160, 224, 2, 2, 32, 32, 32, "eager"),
+        "no_mask_gqa_64_128": (masks.Full(), 128, 128, 4, 2, 64, 128, 32, "fused"),
+        "seq_q_lt_seq_k": (masks.Causal(), 128, 256, 2, 2, 32, 32, 32, "fused"),  # whole kv tiles ahead of the first query
+        "seq_q_lt_seq_k_window": (masks.Causal(120), 128, 288, 2, 1, 32, 32, 32, "fused"),  # kv tiles no q tile visits
+        "seq_q_gt_seq_k": (masks.Causal(), 256, 160, 2, 2, 32, 32, 32, "fused"),  # rows before the first key: the guard, and runs that end before they start
+    }
+
+
+@pytest.mark.parametrize("case", list(_pair_cases()))
+def test_two_tiles_a_trip_are_one_tile_a_trip_bit_for_bit(case, monkeypatch):
+    """``_flash_fwd`` on bf16 operands (the cells'), two tiles a trip over its unmasked runs against one, the kernel
+    as it was: o and lse are the same bits, since a pair changes no product, no chain and no order of an update; and so
+    are dq, dk, dv of ``_flash_bwd``, which reads them and keeps one tile a trip (PERF.md, PR 50: its pair gained
+    nothing on the chip). ``program_regions_traced_total{tiles_a_trip}`` says what the rule gave: 2 where the mask has
+    an unmasked run of two tiles; where it has none the pair is forced, and its loops of no trip change nothing."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+    from deepspeed_tpu.telemetry.tracing import regions_traced
+
+    mask, Sq, Sk, H, KVH, D, Dv, tile, group, *word = _pair_cases()[case]
+    monkeypatch.setattr(fa, "DEFAULT_BQ", tile)
+    monkeypatch.setattr(fa, "DEFAULT_BK", tile)
+    if group == "per_head":  # a budget that holds a head's backward and not its group's sums over the whole sequence
+        monkeypatch.setattr(fa, "vmem_budget", lambda: fa._fused_bwd_vmem(Sq, Sk, D, 2, tile, tile, 1, Dv))
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    bf16 = lambda key, *shape: jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+    q, k, v, do = bf16(ks[0], H, Sq, D), bf16(ks[1], KVH, Sk, D), bf16(ks[2], KVH, Sk, Dv), bf16(ks[3], H, Sq, Dv)
+    slopes, bias = jnp.zeros((H, 1, fa.LANES), jnp.float32), jnp.zeros((1, 1, fa.LANES), jnp.float32)
+    rest = (D ** -0.5, mask, True, False, None, H, KVH)
+
+    def results(tiles=None):
+        if tiles:
+            monkeypatch.setattr(fa, "tiles_a_trip", lambda *a: tiles)
+        o, lse = fa._flash_fwd(q, k, v, slopes, bias, *rest)
+        return (o, lse) + tuple(fa._flash_bwd(q, k, v, o, lse, do, slopes, bias, *rest)[:3])
+
+    counted = lambda: [regions_traced("mixer/kernel", **{"pass": p, "tiles_a_trip": n}) for p in ("fwd", "bwd") for n in ("1", "2")]
+    before = counted()
+    with jax.disable_jit(group == "eager"):
+        pair = results()
+        took_two = float(word != [1])
+        assert [now - was for now, was in zip(counted(), before)] == [1.0 - took_two, took_two, 1.0, 0.0]  # the backward keeps one
+        if not took_two:
+            pair = results(2)
+        single = results(1)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), pair, single):
+        assert a.dtype == b.dtype and np.array_equal(np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32))), name
+    assert float(jnp.abs(pair[2].astype(jnp.float32)).max()) > 0 and bool(jnp.all(jnp.isfinite(pair[1])))
+
+
+def test_the_rule_takes_two_tiles_where_a_whole_run_has_two_and_they_fit():
+    """``tiles_a_trip`` from what a call can see: the mask's longest unmasked run at the call's shapes
+    (``masks.longest_whole_run``) and the call's own count of VMEM."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+    from deepspeed_tpu.ops import masks
+
+    run = lambda mask, S, tile=512: masks.longest_whole_run(mask, bq=tile, bk=tile, seq_q=S, seq_k=S)
+    assert [run(masks.Causal(), S) for S in (512, 1024, 2048, 8192)] == [0, 1, 3, 15]  # row band i: i whole tiles, then the diagonal's
+    assert run(masks.Causal(512), 8192) == 0 and run(masks.Causal(1024), 8192) == 1 and run(masks.Causal(2048), 8192) == 3  # phi-4's window: none
+    assert run(masks.Full(), 8192) == 16 and run(masks.BlockDiffusion(4, 8192), 16384) == 15 and run(masks.BlockDiffusion(4, 512), 1024) == 0
+    assert masks.longest_whole_run(masks.Causal(), bq=512, bk=512, seq_q=512, seq_k=8192) == 15  # a chunk of queries at the end of its keys
+    assert fa.tiles_a_trip(2, 24 << 20) == 2 and fa.tiles_a_trip(16, 24 << 20) == 2
+    assert fa.tiles_a_trip(1, 24 << 20) == 1 and fa.tiles_a_trip(0, 24 << 20) == 1  # no run holds a pair: its loops would cost and hide nothing
+    assert fa.tiles_a_trip(16, fa.vmem_budget()) == 2 and fa.tiles_a_trip(16, fa.vmem_budget() + 1) == 1  # no room
+    assert fa._tile_bytes(512, 512) == 8 << 20  # ``ops/pallas/indexed_attention.py`` counts with it too
+
+
 def test_fused_adam_matches_reference():
     rng = np.random.RandomState(0)
     n = 1000

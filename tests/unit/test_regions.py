@@ -305,6 +305,39 @@ def test_a_region_outside_a_first_call_is_the_scope_alone(monkeypatch):
     assert type(scope) is type(jax.named_scope("x")) and tracing.regions_traced("ffn/experts") == counted
 
 
+def test_the_flash_kernels_say_how_many_tiles_a_trip_takes_and_the_first_call_line_repeats_it(monkeypatch):
+    """``program_regions_traced_total{region="mixer/kernel", tiles_a_trip}``: the forward's choice (``tiles_a_trip``: 2
+    where the mask has an unmasked run of two tiles, and they fit) and the backward's one, a call site a pass; the
+    trainer's line reads the two series that rose as ``tiles_a_trip_fwd`` / ``tiles_a_trip_bwd``, for a model of one
+    plain kind too (OLMo's), which says nothing else of its kinds."""
+    from deepspeed_tpu.ops import masks
+    from deepspeed_tpu.ops.pallas import flash_attention as F
+    from deepspeed_tpu.runtime import engine as trainer
+
+    assert F.TILES_A_TRIP == {f"tiles_a_trip_{p}": ("mixer/kernel", ("1", "2"), "tiles_a_trip", {"pass": p}) for p in ("fwd", "bwd")}
+    cfg = TransformerConfig(vocab_size=64, n_layers=2, n_heads=2, n_kv_heads=2, d_model=32, d_ff=48, max_seq_len=64)
+    notes_since = lambda cfg, before: trainer.DeepSpeedEngine._layer_kind_notes(type("E", (), {"module": type("M", (), {"cfg": cfg})}), before)
+    assert set(cfg.kinds) == {("full", "dense")} and notes_since(cfg, trainer._paths_traced()) == {}
+    monkeypatch.setattr(F, "DEFAULT_BQ", 16)
+    monkeypatch.setattr(F, "DEFAULT_BK", 16)
+    q = jnp.ones((1, 64, 2, 8), jnp.bfloat16)
+    cases = [(p, n) for p in ("fwd", "bwd") for n in ("1", "2")]
+    series = lambda pass_, n, op="flash": tracing.regions_traced("mixer/kernel", op=op, tiles_a_trip=n, **{"pass": pass_})
+    grad = lambda q, **kw: jax.grad(lambda q: jnp.sum(F.flash_attention(q, q, q, interpret=True, **kw).astype(jnp.float32)))(q)
+    before, was = trainer._paths_traced(), [series(*case) for case in cases]
+    grad(q)  # a causal walk of four tiles: whole runs of up to three, so the forward pairs; the fused backward keeps one
+    assert [series(*case) - w for case, w in zip(cases, was)] == [0, 1, 1, 0]
+    assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "2", "tiles_a_trip_bwd": "1"}
+    before = trainer._paths_traced()
+    grad(q, window=16)  # a window of a tile's width: every visited tile crosses an edge, one tile a trip
+    assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "1", "tiles_a_trip_bwd": "1"}
+    before, blockdiff = trainer._paths_traced(), series("fwd", "2", op="blockdiff")
+    grad(jnp.ones((1, 128, 2, 8), jnp.bfloat16), mask=masks.BlockDiffusion(4, 64))  # under a mask of its own walk the label rides on that op's series
+    grad(q[:, :32], bias=jnp.zeros((1, 1, 32, 32)))  # a bias of two tiles a side: no run of two, and the split backward
+    assert series("fwd", "2", op="blockdiff") == blockdiff + 1
+    assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "1+2", "tiles_a_trip_bwd": "1"}  # the series that rose, "+" between
+
+
 def test_regions_inside_a_first_call_add_their_python_seconds_to_its_span():
     tracer = get_tracer()
     with tracer.span("program/first_call", family="toy") as sp:

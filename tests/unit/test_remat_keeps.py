@@ -158,10 +158,10 @@ def test_the_first_call_line_says_inputs_where_the_layers_are_scanned():
     ``nn.remat(Block)``, which has no policy and keeps a block's inputs alone, whatever the kind."""
     import types
 
-    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine, _paths_traced
 
     notes = lambda **over: DeepSpeedEngine._layer_kind_notes(
-        types.SimpleNamespace(module=types.SimpleNamespace(cfg=tiny(n_layers=2, layer_kinds=(("mla", "dense"),) * 2, **over))), None)
+        types.SimpleNamespace(module=types.SimpleNamespace(cfg=tiny(n_layers=2, layer_kinds=(("mla", "dense"),) * 2, **over))), _paths_traced())
     assert notes(remat=True) == {"remat_keeps": "flash_attention+projection"}
     assert notes(remat=True, scan_layers=True) == {"remat_keeps": "inputs"}
     assert notes() == {}
