@@ -342,13 +342,17 @@ declare("DS_TPU_FLIGHT_PROFILE_MAX_MB", "64", "float",
         "over this many MB the raw trace is dropped (drop-and-count in "
         "the manifest) and only the parsed waterfall summary survives.",
         "telemetry/flight.py")
-declare("DS_TPU_PROFILE", "0", "bool",
-        "Arm a one-shot device-timeline capture at engine construction: "
+declare("DS_TPU_PROFILE", "0", "str",
+        "A word: 0 (off), 1, or stall. "
+        "1 arms a one-shot device-timeline capture at engine construction: "
         "the next DS_TPU_PROFILE_QUANTA serving quanta, or training "
         "steps, are wrapped in a jax.profiler trace and parsed into a "
         "per-quantum waterfall (compute / exposed-vs-overlapped "
         "collective / transfer / host gap) and, for a training step, "
-        "its device time by region and phase.",
+        "its device time by region and phase. stall hunts for a stalled "
+        "step: captures back to back, each dropped unread unless one of "
+        "its quanta took 1.5 medians; the first that holds one is kept, "
+        "with a `stall` section in its summary, and the hunt ends.",
         "telemetry/profiler.py")
 declare("DS_TPU_PROFILE_DIR", "profile_captures", "str",
         "Directory for device-timeline capture output (raw trace plus "

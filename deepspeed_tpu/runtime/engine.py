@@ -43,8 +43,8 @@ from ..telemetry import device_counts
 from ..telemetry import profiler as device_profiler
 from ..telemetry import span as telemetry_span
 from ..telemetry.costs import first_call
-from ..telemetry.tracing import PHASES, region, regions_traced, regions_traced_by
-from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector,
+from ..telemetry.tracing import PHASES, get_tracer, open_span, region, regions_traced, regions_traced_by, self_times
+from ..telemetry.health import (GradNormSpikeDetector, NonFiniteLossDetector, StepStallDetector,
                                 get_health_monitor)
 from ..utils.compile_cache import register_cache_metrics
 from ..utils.logging import log_dist, logger
@@ -127,6 +127,28 @@ def _program_text(program, args):
         return jitted.lower(*shapes).compile().as_text()
 
     return text
+
+
+def _period_split(since: float, period_s: float) -> Dict:
+    """Where a step's period went, off the span ring: the trainer's spans that
+    began at or after ``since``, in seconds by span and phase (``forward.self``:
+    the span's self time less its phases), ``first_calls`` (the first calls
+    that began in it) and ``outside_s``: the period less every trainer span,
+    which is the caller's code, its data and its wait on a loss."""
+    spans = [s for s in get_tracer().spans() if s["start_s"] >= since]
+    own, split, spent = self_times(spans), {}, 0.0
+    for s in spans:
+        if not s["name"].startswith("train/"):
+            continue
+        part, phases = s["name"][len("train/"):], s["attrs"].get("phase_s") or {}
+        spent += s["dur_s"]
+        for name, sec in phases.items():
+            split[f"{part}.{name}"] = split.get(f"{part}.{name}", 0.0) + sec
+        rest = part if part == "backward" else part + ".self"  # backward has no phases
+        split[rest] = split.get(rest, 0.0) + max(0.0, own[s["id"]] - sum(phases.values()))
+    split = {k: round(v, 6) for k, v in split.items()}
+    split.update(first_calls=sum(s["name"] == "program/first_call" for s in spans), outside_s=round(period_s - spent, 6))
+    return split
 
 
 def _batch_tokens(batch) -> int:
@@ -311,8 +333,6 @@ class DeepSpeedEngine:
         tele = get_telemetry_registry()
         self.telemetry = tele
         self._m_steps = tele.counter("train_steps_total")
-        self._m_micro = tele.counter("train_microbatches_total")
-        self._m_samples = tele.counter("train_samples_total")
         self._m_tokens = tele.counter("train_tokens_total")
         self._m_overflow = tele.counter("train_overflow_steps_total")
         self._m_loss_scale = tele.gauge("train_loss_scale")
@@ -325,6 +345,7 @@ class DeepSpeedEngine:
         self._m_grad_sync_bytes = tele.counter("comm_bytes_total", op="grad_sync_estimated")
         self._last_microbatch_tokens = 0
         self._last_step_pc = None
+        self._step_end_pc = None  # the end of the last ``step()``: a step's period, for the stall detector, runs from there
         # analytic fwd+bwd FLOPs for the MFU gauge: traced once per batch
         # shape (keyed on token count) via the same jaxpr walk the serving
         # cost cards use; 0 means unavailable/disabled and the gauge stays 0
@@ -342,6 +363,7 @@ class DeepSpeedEngine:
         self.health = get_health_monitor()
         self.health.ensure_detector(NonFiniteLossDetector())
         self.health.ensure_detector(GradNormSpikeDetector())
+        self.health.ensure_detector(StepStallDetector()).reset()  # a new engine's periods are a new series
         # live ops plane: introspection server (DS_TPU_OPS_PORT) and
         # flight recorder (DS_TPU_FLIGHT_DIR) — a NaN loss mid-run leaves
         # a black-box capture behind. Both default off.
@@ -677,7 +699,7 @@ class DeepSpeedEngine:
             # not leave the forward timer running across the exception
             raise RuntimeError("fused_step: forward() called again before step() consumed the previous one")
         self.timers(FORWARD_GLOBAL_TIMER).start()
-        with telemetry_span("train/forward"):
+        with telemetry_span("train/forward") as sp:
             if self.curriculum_scheduler is not None:
                 batch = self._apply_curriculum(batch)
             if self.progressive_layer_drop is not None and isinstance(batch, dict):
@@ -686,7 +708,8 @@ class DeepSpeedEngine:
                 batch = dict(batch)
                 batch["pld_theta"] = np.asarray(self.progressive_layer_drop.get_theta(), np.float32)
             self._last_microbatch_tokens = _batch_tokens(batch)
-            batch = self._put_batch(batch)
+            with sp.phase("put_batch"):
+                batch = self._put_batch(batch)
             scale = self.loss_scaler.loss_scale / self.gradient_accumulation_steps
             profiling = (self.config.flops_profiler.enabled
                          and self.global_steps == self.config.flops_profiler.profile_step
@@ -706,7 +729,7 @@ class DeepSpeedEngine:
                 args = (self.params, batch, self.micro_steps, scale)
                 loss, grads = self._step_program("fwd_bwd", self._fwd_bwd, args, batch)
                 self._cached_grads = grads
-            loss = self._take_reported(loss)
+            loss = self._take_reported(loss, sp)
             self._last_loss = loss
             if self.eigenvalue is not None:
                 self._last_batch = batch  # retained for the gas-boundary eigenvalue pass
@@ -727,8 +750,10 @@ class DeepSpeedEngine:
         in their backward, and whether the embedding and the loss head are
         such a region too), else ``xla``."""
         shapes = leaf_signature(batch)
+        sp = open_span("train/forward")
         if (name, shapes) in self._step_programs_seen:
-            return program(*args)
+            with sp.phase("dispatch"):
+                return program(*args)
         self._step_programs_seen.add((name, shapes))
         self._made_first_call = True  # a capture of the device's time passes this step by (``_note_profiled_step``)
         prof = device_profiler.get_device_profiler()
@@ -744,27 +769,33 @@ class DeepSpeedEngine:
             # by the phase each equation's name stack says
             notes.update({f"flops_{phase}": int(self._step_flops_by_phase.get(phase, 0)) for phase in PHASES}
                          if self._step_flops_by_phase else {})
-            out = program(*args)
+            with sp.phase("dispatch"):
+                out = program(*args)
             layers, regathers, rings, head = (zero_overlap.traced(what) - was for what, was in zip(counted, before))
             notes.update(grad_reduce="bucket" if layers or head else "xla", bucket_layers=layers, bucket_rings=rings,
                          bucket_regather=regathers, bucket_head=int(head > 0))
             notes.update(self._layer_kind_notes(paths_before))
         return out
 
-    def _take_reported(self, first):
+    def _take_reported(self, first, span):
         """A step program's first output is (loss, what the model counted on
         the device: ``telemetry/device_counts.py``), or the loss alone where
         it counted nothing. The counts go to the registry once their step
         has ended, which is looked up here at the next dispatches and never
-        waited for (past eight steps in flight the oldest is): the counters
-        lag the device by a step or two and the host path is not held up.
-        Returns the loss."""
+        waited for (past eight steps in flight the oldest is, and ``span``
+        then says ``waited=1``): the counters lag the device by a step or two
+        and the host path is not held up. Returns the loss."""
         loss, reported = first if isinstance(first, tuple) else (first, None)
         if reported:
             self._reported.append(reported)
             ended = lambda counts: all(v.is_ready() for v in counts.values())
-            while self._reported and (ended(self._reported[0]) or len(self._reported) > 8):
-                device_counts.count(self._reported.pop(0))
+            with span.phase("device_counts"):
+                while self._reported:
+                    if not ended(self._reported[0]):
+                        if len(self._reported) <= 8:
+                            break
+                        span.set(waited=1)
+                    device_counts.count(self._reported.pop(0))
         return loss
 
     def _layer_kind_notes(self, traced_before):
@@ -835,8 +866,6 @@ class DeepSpeedEngine:
             self._cached_grads = None
             self.micro_steps += 1
             self.global_samples += self.train_micro_batch_size_per_gpu * self.topology.data_parallel_size
-            self._m_micro.inc()
-            self._m_samples.inc(self.train_micro_batch_size_per_gpu * self.topology.data_parallel_size)
             if self._last_microbatch_tokens:
                 self._m_tokens.inc(self._last_microbatch_tokens)
         self.timers(BACKWARD_GLOBAL_TIMER).stop()
@@ -852,7 +881,7 @@ class DeepSpeedEngine:
             self._last_overflow = None  # no-op step (reference was_step_applied contract)
             return
         self.timers(STEP_GLOBAL_TIMER).start()
-        with telemetry_span("train/step"):
+        with telemetry_span("train/step") as sp:  # to the end of ``step()``: the report's and the monitor's reads of a loss are its phases
             if (self.eigenvalue is not None
                     and self.global_steps % self.eigenvalue.gas_boundary_resolution == 0
                     and getattr(self, "_last_batch", None) is not None):
@@ -872,16 +901,17 @@ class DeepSpeedEngine:
                 # grads were pre-scaled by loss_scale/gas in forward; undo loss_scale
                 # here (the 1/gas factor stays: summed micro-grads become the mean)
                 inv_scale = 1.0 / self.loss_scaler.loss_scale
-                if self._host_offload is not None:
-                    new_params, gnorm, overflow = self._host_offload.step(jax.device_get(self._grad_acc), lr,
-                                                                          inv_scale=inv_scale,
-                                                                          grad_clip=self.config.gradient_clipping,
-                                                                          shardings=self.param_store_shardings)
-                    if not overflow:
-                        self.params = new_params
-                else:
-                    self.params, self.opt_state, gnorm, overflow = self._apply_updates(
-                        self.params, self.opt_state, self._grad_acc, inv_scale, lr)
+                with sp.phase("apply"):
+                    if self._host_offload is not None:
+                        new_params, gnorm, overflow = self._host_offload.step(jax.device_get(self._grad_acc), lr,
+                                                                              inv_scale=inv_scale,
+                                                                              grad_clip=self.config.gradient_clipping,
+                                                                              shardings=self.param_store_shardings)
+                        if not overflow:
+                            self.params = new_params
+                    else:
+                        self.params, self.opt_state, gnorm, overflow = self._apply_updates(
+                            self.params, self.opt_state, self._grad_acc, inv_scale, lr)
             self._grad_acc = None
             self._global_grad_norm = gnorm
             self._last_overflow = overflow
@@ -889,7 +919,8 @@ class DeepSpeedEngine:
                 # dynamic fp16 scaling needs the overflow bit on the host NOW
                 # (the scale feeds the next step) — this device->host sync is
                 # inherent to the algorithm, as in the reference
-                overflow_host = bool(overflow)
+                with sp.phase("overflow_sync"):
+                    overflow_host = bool(overflow)
                 self.loss_scaler.update_scale(overflow_host)
                 if overflow_host:
                     self._skipped_host += 1
@@ -910,60 +941,69 @@ class DeepSpeedEngine:
                 self.progressive_layer_drop.update_state(self.global_steps)
             if self.compression_engine is not None:
                 self.compression_engine.scheduler.step()
-        self.timers(STEP_GLOBAL_TIMER).stop()
-        # dispatch-boundary telemetry: counters, gauges, heartbeat. No device
-        # reads here — loss/grad-norm gauges update where a sync already
-        # happens (_report, monitor flush).
-        self._m_steps.inc()
-        self._m_loss_scale.set(self.loss_scaler.loss_scale)
-        self._m_lr.set(lr)
-        self._m_heartbeat.set(time.time())
-        if self._grad_sync_bytes:
-            self._m_grad_sync_bytes.inc(self._grad_sync_bytes)
-        now_pc = time.perf_counter()
-        if self._last_step_pc is not None and now_pc > self._last_step_pc and self._last_microbatch_tokens:
-            # dispatch rate, not device rate: honest once the pipeline is
-            # deep enough that dispatch tracks execution
-            self._m_tps.set(self._last_microbatch_tokens * self.gradient_accumulation_steps
-                            / (now_pc - self._last_step_pc))
-            if self._step_flops:
-                if self._peak_flops is None:
-                    from ..telemetry.costs import resolve_peaks
-                    self._peak_flops = resolve_peaks()[0]
-                if self._peak_flops > 0:
-                    self._m_mfu.set(self._step_flops * self.gradient_accumulation_steps
-                                    / (now_pc - self._last_step_pc) / self._peak_flops)
-        self._last_step_pc = now_pc
+            self.timers(STEP_GLOBAL_TIMER).stop()
+            # dispatch-boundary telemetry: counters, gauges, heartbeat. No device
+            # reads here — loss/grad-norm gauges update where a sync already
+            # happens (_report, monitor flush).
+            self._m_steps.inc()
+            self._m_loss_scale.set(self.loss_scaler.loss_scale)
+            self._m_lr.set(lr)
+            self._m_heartbeat.set(time.time())
+            if self._grad_sync_bytes:
+                self._m_grad_sync_bytes.inc(self._grad_sync_bytes)
+            now_pc = time.perf_counter()
+            if self._last_step_pc is not None and now_pc > self._last_step_pc and self._last_microbatch_tokens:
+                # dispatch rate, not device rate: honest once the pipeline is
+                # deep enough that dispatch tracks execution
+                self._m_tps.set(self._last_microbatch_tokens * self.gradient_accumulation_steps
+                                / (now_pc - self._last_step_pc))
+                if self._step_flops:
+                    if self._peak_flops is None:
+                        from ..telemetry.costs import resolve_peaks
+                        self._peak_flops = resolve_peaks()[0]
+                    if self._peak_flops > 0:
+                        self._m_mfu.set(self._step_flops * self.gradient_accumulation_steps
+                                        / (now_pc - self._last_step_pc) / self._peak_flops)
+            self._last_step_pc = now_pc
+            if self.global_steps % self.config.steps_per_print == 0:
+                with sp.phase("report"):
+                    self._report(lr)
+            if self.monitor is not None:
+                # registry -> monitor bridge; the legacy Train/Samples/* series
+                # ride along verbatim (same host sync the old write_events paid)
+                with sp.phase("monitor_flush"):
+                    extra = [("Train/Samples/lr", lr, self.global_samples)]
+                    if self._last_loss is not None:
+                        loss_host = float(self._last_loss)
+                        self._m_loss.set(loss_host)
+                        self.health.observe_loss(loss_host)
+                        extra.append(("Train/Samples/train_loss", loss_host, self.global_samples))
+                    self._monitor_bridge.maybe_flush(self.global_steps, extra_events=extra)
+        # the step's period on the host, this ``step()``'s end from the last one's, to the stall detector: a step that made
+        # a first call, or in which a capture's ends waited for the device, is passed by
+        passed_by, self._made_first_call = self._made_first_call, False
         prof = device_profiler.get_device_profiler()  # None unless a capture was ever armed (DS_TPU_PROFILE, the ops plane)
-        if prof is not None:
-            self._note_profiled_step(prof)
-        if self.global_steps % self.config.steps_per_print == 0:
-            self._report(lr)
-        if self.monitor is not None:
-            # registry -> monitor bridge; the legacy Train/Samples/* series
-            # ride along verbatim (same host sync the old write_events paid)
-            extra = [("Train/Samples/lr", lr, self.global_samples)]
-            if self._last_loss is not None:
-                loss_host = float(self._last_loss)
-                self._m_loss.set(loss_host)
-                self.health.observe_loss(loss_host)
-                extra.append(("Train/Samples/train_loss", loss_host, self.global_samples))
-            self._monitor_bridge.maybe_flush(self.global_steps, extra_events=extra)
+        if prof is not None and not passed_by:
+            passed_by = self._note_profiled_step(prof)
+        since, self._step_end_pc = self._step_end_pc, time.perf_counter()
+        if since is not None:
+            period = self._step_end_pc - since
+            self.health.observe_step_period(period, step=self.global_steps, passed_by=passed_by,
+                                            split=lambda: _period_split(since, period))
 
-    def _note_profiled_step(self, prof):
-        """A step's end as the device profiler's quantum. A step that made a
+    def _note_profiled_step(self, prof) -> bool:
+        """A step's end as the device profiler's quantum (a step that made a
         first call is passed by: the capture is of the program running, not
-        of its compilation, so it starts at the first step that made none.
-        Steps are dispatched ahead of the device, so before the marker that
-        starts the capture and before the one that closes it the host waits
-        for the last step's results: the trace then holds whole steps, and
-        every captured step's device time."""
-        if self._made_first_call:
-            self._made_first_call = False
-            return
-        if prof.state == "armed" or prof.closes_next():
+        of its compilation). Steps are dispatched ahead of the device, so
+        before the marker that starts the capture and before the one that
+        closes it the host waits for the last step's results: the trace then
+        holds whole steps, and every captured step's device time. Returns
+        whether this was such an end."""
+        edge = prof.state == "armed" or prof.closes_next()
+        if edge:
             jax.block_until_ready((self._last_loss, self.params))
         prof.note_quantum("train/step", step=self.global_steps, tokens=self._last_microbatch_tokens)
+        return edge
 
     def _start_flops_profile(self, batch, step, scale):
         """Reference ``engine.py:1800,1817``: flops profiler on a configured step.

@@ -18,7 +18,7 @@ from .health import (Alert, CallbackAlertSink, Detector,
                      GradNormSpikeDetector, HBMPressureDetector,
                      HealthMonitor, JsonlAlertSink, LoggerAlertSink,
                      NonFiniteLossDetector, QueueStallDetector,
-                     SLOBurnRateDetector, StragglerDetector,
+                     SLOBurnRateDetector, StepStallDetector, StragglerDetector,
                      get_health_monitor)
 from .costs import CostCard, PerfAccountant, get_perf_accountant, resolve_peaks
 from .agg import (detect_stragglers, histogram_quantile, merge_snapshot_files,
@@ -41,7 +41,7 @@ __all__ = [
     "latency_summary", "lifecycle_signature", "validate_timeline",
     "Alert", "Detector", "HealthMonitor", "get_health_monitor",
     "NonFiniteLossDetector", "GradNormSpikeDetector", "QueueStallDetector",
-    "SLOBurnRateDetector", "HBMPressureDetector", "StragglerDetector",
+    "SLOBurnRateDetector", "HBMPressureDetector", "StragglerDetector", "StepStallDetector",
     "LoggerAlertSink", "JsonlAlertSink", "CallbackAlertSink",
     "CostCard", "PerfAccountant", "get_perf_accountant", "resolve_peaks",
     "rank_stamp", "write_rank_snapshot", "merge_snapshots",

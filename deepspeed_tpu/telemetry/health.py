@@ -23,10 +23,13 @@ Detector semantics shared by all built-ins:
 Built-ins: ``NonFiniteLossDetector`` / ``GradNormSpikeDetector``
 (training, wired into ``runtime/engine.py``'s host-sync points) and
 ``QueueStallDetector`` / ``SLOBurnRateDetector`` (serving, fed by the
-event stream and polled from the generate/SLA loops and the watchdog).
+event stream and polled from the generate/SLA loops and the watchdog), and
+``StepStallDetector`` (training: a step whose period on the host's clock is
+``STALL_X`` medians long, fed by the engine at the end of every ``step()``).
 """
 
 import math
+import statistics
 import threading
 import time
 from collections import deque
@@ -197,6 +200,63 @@ class GradNormSpikeDetector(Detector):
     def reset(self) -> None:
         super().reset()
         self._ema, self._n = None, 0
+
+
+STALL_X = 1.5     # a step is stalled when its period passes this many medians of the STALL_KEEP kept before it: no knob,
+STALL_KEEP = 32   # the benchmark's clean steps spread by under 0.5% and the stall sought is 3-6 medians (PERF.md, PR 51)
+STALL_WARMUP = 8  # an engine's first periods are passed by (a caller's warm-up, a pipeline filling), and as many kept before one is judged
+
+
+class StepStallDetector(Detector):
+    """Counts a training step whose period on the host (one ``step()``'s end
+    to the next one's) exceeds ``STALL_X`` medians of the last ``STALL_KEEP``:
+    ``train_step_stalls_total``, ``train_step_stall_seconds_total`` (period
+    less median), both 0 from construction, gauge
+    ``train_step_period_median_seconds``. A step ``passed_by`` (it made a
+    first call, or the device profiler waited in it) is neither kept nor
+    judged. One alert an incident (the usual period re-arms), with
+    ``split``'s account of where the period went; no device read."""
+
+    name = "step_stall"
+    severity = "warning"
+
+    def __init__(self, registry=None, **kw):
+        super().__init__(**{"cooldown_s": 0.0, **kw})
+        reg = registry if registry is not None else get_registry()
+        self._m_stalls = reg.counter("train_step_stalls_total")
+        self._m_seconds = reg.counter("train_step_stall_seconds_total")
+        self._m_median = reg.gauge("train_step_period_median_seconds")
+        self._kept = deque(maxlen=STALL_KEEP)
+        self._seen = 0
+
+    def observe(self, period_s: float, step: int = 0, passed_by: bool = False,
+                split: Optional[Callable[[], Dict]] = None) -> Optional[Alert]:
+        self._seen += not passed_by
+        if passed_by or self._seen <= STALL_WARMUP:
+            return None
+        kept = self._kept
+        median = statistics.median(kept) if len(kept) >= STALL_WARMUP else None
+        kept.append(period_s)
+        if median is None:
+            return None
+        self._m_median.set(median)
+        if period_s <= STALL_X * median:
+            self._rearm()
+            return None
+        self._m_stalls.inc()
+        self._m_seconds.inc(period_s - median)
+        if self.firing:
+            return None  # one alert an incident: its split is read off the ring once
+        parts = split() if split is not None else {}
+        worst = max(((k, v) for k, v in parts.items() if k != "first_calls"), key=lambda kv: kv[1], default=("?", 0.0))
+        return self._maybe_alert(f"step {step} took {period_s:.3f} s, {period_s / median:.1f} x the median "
+                                 f"{median:.3f}: most of it in {worst[0]} ({worst[1]:.3f} s)",
+                                 step=int(step), period_s=round(period_s, 6), median_s=round(median, 6), split=parts)
+
+    def reset(self) -> None:
+        super().reset()
+        self._kept.clear()
+        self._seen = 0
 
 
 class QueueStallDetector(Detector):
@@ -411,6 +471,11 @@ class HealthMonitor:
         d = self._detectors.get(GradNormSpikeDetector.name)
         if d is not None:
             self._dispatch(d.observe(float(gnorm)))
+
+    def observe_step_period(self, period_s: float, **kw) -> None:
+        d = self._detectors.get(StepStallDetector.name)
+        if d is not None:
+            self._dispatch(d.observe(float(period_s), **kw))
 
     def observe_request(self, ttft_s: float, tpot_s: float) -> None:
         d = self._detectors.get(SLOBurnRateDetector.name)

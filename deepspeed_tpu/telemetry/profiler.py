@@ -16,6 +16,11 @@ with bounded structured capture windows:
   trainer's ``step()``; the trainer passes by steps that make a first call);
   after ``DS_TPU_PROFILE_QUANTA`` markers the trace stops and is reduced
   in-process.
+- ``DS_TPU_PROFILE=stall`` hunts: captures back to back, each DROPPED unread
+  (``profile_captures_dropped_total``) unless one of its quanta took more
+  than ``health.STALL_X`` medians by the host's stamps; the first that holds
+  one is kept, the hunt ends, and its summary gains ``stall``
+  (``stall_section``: the stalled quantum's name, as far as a trace has one).
 - The trace is read from the ``.xplane.pb`` through
   ``jax.profiler.ProfileData`` (four chips make 240,000 device events a
   second and the Chrome JSON is an export of it that may be cut), with the
@@ -60,6 +65,8 @@ caps quantum rows and program lists so an ops-plane scrape stays small.
 import json
 import os
 import re
+import shutil
+import statistics
 import threading
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
@@ -67,6 +74,8 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 from jax.profiler import TraceAnnotation
 
 from ..analysis import knobs
+from ..utils.logging import logger
+from .health import STALL_X
 from .tracing import phase_of as _phase
 
 SUMMARY_SCHEMA = 2
@@ -83,6 +92,8 @@ CALLS_COLLECTIVE = re.compile(r"calls=%?(?:" + COLLECTIVE.pattern + ")")  # a co
 TRANSFER = re.compile(r"^(?:copy-start|copy-done|infeed|outfeed|send|send-done|recv|recv-done)$")
 HOST_SPAN = re.compile(r"^[a-z][a-z0-9_]*(/[a-z0-9_]+)+$")  # the program's spans (docs/OBSERVABILITY.md, "Span convention")
 QUANTUM_MARK = "profile/quantum"
+LONG_IDLE_S = 0.010       # an idle stretch of the first device this long is worth a name: the host's events over it are kept
+TOP_HOST_EVENTS = 5
 
 _DTYPE_BYTES = {"float32": 4, "f32": 4, "float64": 8, "f64": 8,
                 "bfloat16": 2, "bf16": 2, "float16": 2, "f16": 2,
@@ -101,25 +112,49 @@ def find_xplane(root: str) -> Optional[str]:
     return sorted(found)[-1] if found else None
 
 
+def _device_planes(trace: Dict) -> List[Dict]:
+    """The device planes of a trace in the plain form, by chip number."""
+    return [p for _, p in sorted((int(DEVICE_PLANE.match(p["name"]).group(1)), p) for p in trace.get("planes", [])
+                                 if DEVICE_PLANE.match(p["name"]))]
+
+
+def _first_device_ops(trace: Dict) -> List[list]:
+    return [ev for plane in _device_planes(trace)[:1] for line in plane["lines"] if line["name"] == OPS_LINE for ev in line["events"]]
+
+
+def idle_intervals(trace: Dict, at_least_ns: float = 0.0) -> List[Tuple[float, float]]:
+    """The first device's idle stretches between its first and its last
+    operation, in the trace's nanoseconds."""
+    ops = _first_device_ops(trace)
+    if not ops:
+        return []
+    busy = _merge([(s, s + d) for _, s, d, *_ in ops])
+    return [(lo, hi) for lo, hi in _subtract([(busy[0][0], busy[-1][1])], busy) if hi - lo >= at_least_ns]
+
+
 def load_xplane(path: str) -> Dict:
     """An ``.xplane.pb`` as plain data, ``{"planes": [{"name", "lines":
     [{"name", "events": [[name, start_ns, dur_ns, {stat: value}], ...]}]}]}``:
     every line of a device plane, and of the host's threads the events named
-    as the program's spans are."""
+    as the program's spans are, and every other event (the runtime's own
+    threads) that lies over an idle stretch of ``LONG_IDLE_S`` of the first
+    device: what a stall's name is read from, and nothing in a clean trace."""
     from jax.profiler import ProfileData
 
-    planes = []
+    planes, hosts = [], []
     for plane in ProfileData.from_file(path).planes:
-        device = bool(DEVICE_PLANE.match(plane.name))
-        if not device and not plane.name.startswith("/host:"):
-            continue
+        if DEVICE_PLANE.match(plane.name):  # no statistic is read
+            lines = [{"name": line.name, "events": [[ev.name, float(ev.start_ns), float(ev.duration_ns), {}] for ev in line.events]}
+                     for line in plane.lines]
+            planes.append({"name": plane.name, "lines": [line for line in lines if line["events"]]})
+        elif plane.name.startswith("/host:"):
+            hosts.append(plane)
+    idle = idle_intervals({"planes": planes}, LONG_IDLE_S * 1e9)
+    for plane in hosts:
         lines = []
         for line in plane.lines:
-            events = []
-            for ev in line.events:
-                if not device and not HOST_SPAN.match(ev.name):
-                    continue
-                events.append([ev.name, float(ev.start_ns), float(ev.duration_ns), {}])  # no statistic is read
+            events = [[ev.name, float(ev.start_ns), float(ev.duration_ns), {}] for ev in line.events
+                      if HOST_SPAN.match(ev.name) or any(ev.start_ns < hi and ev.start_ns + ev.duration_ns > lo for lo, hi in idle)]
             if events:
                 lines.append({"name": line.name, "events": events})
         planes.append({"name": plane.name, "lines": lines})
@@ -186,13 +221,12 @@ def parse_trace_events(trace: Dict) -> Dict:
     collective or transfer time, never as busy); whole programs (``XLA
     Modules``) are ``module`` and the plane's other lines ``other``.
     Everything on ``/host:*`` is ``host``."""
-    devices = sorted((int(DEVICE_PLANE.match(p["name"]).group(1)), p) for p in trace.get("planes", [])
-                     if DEVICE_PLANE.match(p["name"]))
+    devices = _device_planes(trace)
     found: List[Tuple[str, str, str, float, float]] = []  # (name, cat, lane, start_ns, dur_ns)
     for plane in trace.get("planes", []):
         if plane["name"].startswith("/host:"):
             found += [(n, "host", line["name"], s, d) for line in plane["lines"] for n, s, d, *_ in line["events"]]
-    for line in (devices[0][1]["lines"] if devices else []):
+    for line in (devices[0]["lines"] if devices else []):
         if line["name"] == OPS_LINE:
             found += [(parse_op(n)[0], _classify(n) if own >= 0.999 * (e - s) else "enclosing", "ops", s, e - s)
                       for n, s, e, own in self_times(line["events"])]
@@ -593,21 +627,19 @@ def region_times(trace: Dict, card: Dict) -> Dict:
     return out
 
 
-def idle_by_span(trace: Dict) -> Dict[str, float]:
+def idle_by_span(trace: Dict, within: Optional[Tuple[float, float]] = None) -> Dict[str, float]:
     """The first device's idle seconds between its first and its last
-    operation, by the innermost of the program's host spans they lie under
-    (``train/forward``: the host had not yet enqueued the step), else
-    ``between spans``: the spans are ``TraceAnnotation``s on the profiler's
-    clock, the device's own."""
-    devices = sorted((int(DEVICE_PLANE.match(p["name"]).group(1)), p) for p in trace.get("planes", [])
-                     if DEVICE_PLANE.match(p["name"]))
-    ops = [ev for line in (devices[0][1]["lines"] if devices else []) if line["name"] == OPS_LINE for ev in line["events"]]
-    if not ops:
-        return {}
-    busy = _merge([(s, s + d) for _, s, d, *_ in ops])
-    idle = _subtract([(busy[0][0], busy[-1][1])], busy)
+    operation (``within``: inside that stretch of the trace's nanoseconds
+    alone), by the innermost of the program's host spans or phases they lie
+    under (``train/forward/dispatch``: the host had not yet enqueued the
+    step), else ``between spans``: the spans are ``TraceAnnotation``s on the
+    profiler's clock, the device's own."""
+    idle = idle_intervals(trace)
+    if within is not None:
+        idle = _clip(idle, *within)
     spans = sorted(((n, s, s + d) for p in trace["planes"] if p["name"].startswith("/host:") for line in p["lines"]
-                    for n, s, d, *_ in line["events"] if n != QUANTUM_MARK), key=lambda sp: sp[2] - sp[1])  # shortest first
+                    for n, s, d, *_ in line["events"] if n != QUANTUM_MARK and HOST_SPAN.match(n)),
+                   key=lambda sp: sp[2] - sp[1])  # shortest first
     out: Dict[str, float] = {}
     covered: List[Tuple[float, float]] = []
     for name, s, e in spans:
@@ -619,6 +651,60 @@ def idle_by_span(trace: Dict) -> Dict[str, float]:
     if rest > 0:
         out["between spans"] = rest / 1e9
     return {k: round(v, 9) for k, v in sorted(out.items(), key=lambda kv: -kv[1])}
+
+
+def stalled_quantum(periods: List[float]) -> Optional[Tuple[int, float]]:
+    """(index, median) of the longest of ``periods`` that exceeds ``STALL_X``
+    medians, the first and the last apart (a capture's own waits for the
+    device lengthen them), or None."""
+    judged = periods[1:-1]
+    if len(judged) < 3:
+        return None
+    median = statistics.median(judged)
+    worst = max(range(1, len(periods) - 1), key=lambda i: periods[i])
+    return (worst, median) if periods[worst] > STALL_X * median else None
+
+
+def stall_section(trace: Dict, bounds_ns: List[float], quantum: int, median_s: float) -> Dict:
+    """A stalled quantum's name, as far as the trace has one (``bounds_ns``:
+    the quanta's edges on the trace's clock). Its period; the first device's
+    busy and idle seconds in it; ``idle``: for each idle stretch of
+    ``LONG_IDLE_S`` or more, when it began (seconds into the quantum), how
+    long it was, ``under`` (``idle_by_span`` cut to it), ``quiet_s`` (the part
+    of it in which NO host thread was in an event that is no span of the
+    program's) and ``host``: the ``TOP_HOST_EVENTS`` such events, of any
+    thread, with the most overlap, as [line, event, overlap seconds];
+    ``long_ops``: [name, seconds, median] of the operations one execution of
+    which took over ``STALL_X`` of the same instruction's median in the other
+    quanta (a kernel or a copy that ran long), the largest excess first."""
+    lo, hi = bounds_ns[quantum], bounds_ns[quantum + 1]
+    idle = _clip(idle_intervals(trace), lo, hi)
+    hosts = [(line["name"], ev) for p in trace["planes"] if p["name"].startswith("/host:") for line in p["lines"]
+             for ev in line["events"] if not HOST_SPAN.match(ev[0])]
+    stretches = []
+    for a, b in idle:
+        if b - a < LONG_IDLE_S * 1e9:
+            continue
+        over: Dict[Tuple[str, str], float] = {}
+        ran = []
+        for line, (name, s, d, *_) in hosts:
+            if s < b and s + d > a:
+                over[(line, name)] = over.get((line, name), 0.0) + min(b, s + d) - max(a, s)
+                ran.append((max(a, s), min(b, s + d)))
+        stretches.append({"start_s": round((a - lo) / 1e9, 6), "dur_s": round((b - a) / 1e9, 6), "under": idle_by_span(trace, (a, b)),
+                          "quiet_s": round((b - a - _total(_merge(ran))) / 1e9, 6),  # no thread of the process was in any event of its own
+                          "host": [[line, name[:120], round(ns / 1e9, 6)]
+                                   for (line, name), ns in sorted(over.items(), key=lambda kv: -kv[1])[:TOP_HOST_EVENTS]]})
+    here: Dict[str, List[float]] = {}
+    usual: Dict[str, List[float]] = {}
+    for name, s, _e, own in self_times(_first_device_ops(trace)):  # an execution is judged against the same instruction's in the other quanta
+        (here if lo <= s < hi else usual).setdefault(parse_op(name)[0], []).append(own / 1e9)
+    long_ops = [[label, round(max(secs), 6), round(median, 6)] for label, secs in here.items()
+                for median in [statistics.median(usual.get(label) or secs)] if max(secs) > STALL_X * median and max(secs) - median >= 1e-3]
+    idle_s = _total(idle) / 1e9
+    return {"quantum": quantum, "period_s": round((hi - lo) / 1e9, 6), "median_s": round(median_s, 6),
+            "device_busy_s": round((hi - lo) / 1e9 - idle_s, 6), "device_idle_s": round(idle_s, 6), "idle": stretches,
+            "long_ops": sorted(long_ops, key=lambda row: row[2] - row[1])[:TOP_PROGRAMS]}
 
 
 def summarize_trace_dir(trace_dir: str,
@@ -650,10 +736,13 @@ class DeviceProfiler:
     ``idle``. ``note_quantum`` in ``idle`` is one attribute compare.
     ``describe(text)`` hands it the program whose regions the capture is to
     be split by: a function that gives the text of the executable that runs,
-    called when a capture is reduced and not before."""
+    called when a capture is reduced and not before. With ``hunt`` a capture
+    that holds no stalled quantum is dropped unread and the profiler arms
+    itself again; the first that holds one is kept and ends the hunt
+    (``hunted``: every capture's cost, kept or dropped)."""
 
     def __init__(self, out_dir: Optional[str] = None,
-                 quanta: Optional[int] = None):
+                 quanta: Optional[int] = None, hunt: bool = False):
         self.out_dir = str(out_dir
                            or knobs.get_str("DS_TPU_PROFILE_DIR", "")
                            or "profile_captures")
@@ -662,6 +751,8 @@ class DeviceProfiler:
             else knobs.get_int("DS_TPU_PROFILE_QUANTA")))
         self.state = "idle"
         self.captures = 0
+        self.hunt = hunt
+        self.hunted: List[Dict] = []
         self._lock = threading.Lock()
         self._markers: List[Dict] = []
         self._host_t0 = self._start_s = 0.0
@@ -680,11 +771,15 @@ class DeviceProfiler:
     # depending on a live jax profiler (which is process-global)
     def _start_trace(self, trace_dir: str) -> None:
         import jax
-        jax.profiler.start_trace(trace_dir)
+        opts = jax.profiler.ProfileOptions()  # the runtime's own threads, and no Python frames: as the benchmark's tracer
+        opts.python_tracer_level, opts.host_tracer_level = 0, 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
 
     def _stop_trace(self) -> None:
         import jax
         jax.profiler.stop_trace()
+
+    _now = staticmethod(time.perf_counter)  # the clock of the quanta's stamps
 
     # ------------------------------------------------------------ control
     def arm(self, quanta: Optional[int] = None) -> bool:
@@ -711,7 +806,7 @@ class DeviceProfiler:
             if self.state != "tracing":
                 return
             with TraceAnnotation(QUANTUM_MARK, index=len(self._markers)):  # the same boundary on the profiler's clock
-                rel_s = time.perf_counter() - self._host_t0
+                rel_s = self._now() - self._host_t0
             self._markers.append({
                 "index": len(self._markers), "program": str(program), "rel_s": rel_s,
                 "attrs": {k: v for k, v in attrs.items()
@@ -770,12 +865,12 @@ class DeviceProfiler:
             self._audit_mark = len(auditor.entries()) if auditor else 0
         except Exception:
             self._audit_mark = 0
-        self._host_t0 = time.perf_counter()
+        self._host_t0 = self._now()
         self.state = "tracing"
 
     def _finalize(self) -> None:
+        window_s = self._now() - self._host_t0
         t_stop = time.perf_counter()
-        window_s = t_stop - self._host_t0
         trace_state = "ok" if self._trace_ok else "unavailable"
         if self._trace_ok:
             try:
@@ -783,6 +878,25 @@ class DeviceProfiler:
             except Exception:
                 trace_state = "unavailable"
         t_reduce = time.perf_counter()
+        rel = [0.0] + [m["rel_s"] for m in self._markers]
+        stalled = stalled_quantum([b - a for a, b in zip(rel, rel[1:])]) if self.hunt else None
+        if self.hunt:
+            self.hunted.append({"capture": self.captures, "kept": stalled is not None, "start": round(self._start_s, 6),
+                                "stop": round(t_reduce - t_stop, 6), "window_s": round(window_s, 6)})
+            del self.hunted[:-256]
+            if stalled is None:  # nothing in it: the raw trace goes unread, and the hunt goes on
+                shutil.rmtree(self._trace_dir or "", ignore_errors=True)
+                self.hunted[-1]["drop"] = round(time.perf_counter() - t_reduce, 6)
+                logger.info(f"profile hunt: capture {self.captures} of {len(self._markers)} quanta held no stall and was dropped: {self.hunted[-1]}")
+                from .registry import get_registry
+                get_registry().counter("profile_captures_dropped_total").inc()
+                with self._lock:
+                    self._summary = {"schema": SUMMARY_SCHEMA, "trace": "dropped", "hunted": self.hunted}
+                    self.captures += 1
+                    self.state = "idle"
+                self.arm()
+                return
+            self.hunt = False
         trace = parsed = None
         path = find_xplane(self._trace_dir) if trace_state == "ok" and self._trace_dir else None
         if path is not None:
@@ -802,6 +916,9 @@ class DeviceProfiler:
         summary["quanta_target"] = self.quanta_target
         if trace is not None:
             summary["idle_by_span"] = idle_by_span(trace)
+            if stalled is not None:
+                bounds = [parsed["t0_ns"] + m["rel_s"] * 1e9 for m in [{"rel_s": 0.0}] + self._markers]
+                summary["stall"] = stall_section(trace, bounds, *stalled)
             if self._program_text is not None:
                 try:  # the text of the executable that ran, asked for now and not at set-up; kept beside the raw trace
                     text = self._program_text()
@@ -814,6 +931,8 @@ class DeviceProfiler:
         # trace, to stop it, and to read and reduce what it left
         summary["capture_cost_s"] = {"start": round(self._start_s, 6), "stop": round(t_reduce - t_stop, 6),
                                      "reduce": round(time.perf_counter() - t_reduce, 6)}
+        if self.hunted:
+            summary["hunted"] = self.hunted
         self._land_metrics(summary)
         if self._trace_dir:
             try:
@@ -917,15 +1036,17 @@ def get_device_profiler() -> Optional[DeviceProfiler]:
 
 def maybe_arm_profiler() -> Optional[DeviceProfiler]:
     """Engine-constructor hook: with ``DS_TPU_PROFILE`` unset this is one
-    bool read; set, it creates the singleton and arms the one-shot
+    read; set, it creates the singleton and arms the one-shot
     capture (only if it has never fired — a finished capture is not
-    re-armed by the next engine build; ``request_capture`` re-arms)."""
+    re-armed by the next engine build; ``request_capture`` re-arms).
+    ``DS_TPU_PROFILE=stall``: the hunt (``DeviceProfiler``)."""
     global _PROFILER
-    if not knobs.get_bool("DS_TPU_PROFILE"):
+    hunt = (knobs.get_str("DS_TPU_PROFILE") or "").strip().lower() == "stall"
+    if not hunt and not knobs.get_bool("DS_TPU_PROFILE"):
         return _PROFILER
     with _PROFILER_LOCK:
         if _PROFILER is None:
-            _PROFILER = DeviceProfiler()
+            _PROFILER = DeviceProfiler(hunt=hunt)
             # JAX leaves metadata out of the persistent compile cache's key, so a cache written by another tree can hand
             # back an executable whose instructions carry other names or none; a process that is to read regions off its
             # executables keys the cache on them (one compile the first time, a fetch after)

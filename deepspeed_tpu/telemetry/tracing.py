@@ -10,6 +10,8 @@ Spans form a tree: each carries an ``id`` and its ``parent``'s id (0 at
 the root of a thread), so a layer's self time is its duration less its
 children's (``self_times``). ``q`` (the scheduler's quantum id) and
 ``uid`` attributes are the identifiers a span shares with the event log.
+``span.phase(name)`` times a named PART of an open span without a record of
+its own (``phase_s`` in the span's attributes; ``_Phase``).
 
 Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
 and entry attributes: in any profiler session the program's spans lie on
@@ -62,8 +64,38 @@ class _NullSpan:
     def set(self, **attrs):
         pass
 
+    def phase(self, name):
+        return self
+
 
 _NULL_SPAN = _NullSpan()
+
+
+class _Phase:
+    """A named part of an open span: a bare annotation ``<span>/<name>`` (a
+    device idle gap under it is named by the part) whose seconds are added to
+    the span's late attribute ``phase_s[name]``. No ring record, no span id:
+    the span's self time and its readers stay as they were."""
+    __slots__ = ("_span", "_name", "_ann", "_t0")
+
+    def __init__(self, span, name):
+        self._span, self._name = span, name
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(f"{self._span.name}/{self._name}")
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc_val, exc_tb):
+        took = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc_val, exc_tb)
+        span = self._span
+        if span.attrs is None:
+            span.attrs = {}
+        by = span.attrs.setdefault("phase_s", {})
+        by[self._name] = by.get(self._name, 0.0) + took
+        return False
 
 
 class _ActiveSpan:
@@ -81,6 +113,9 @@ class _ActiveSpan:
             self.attrs = attrs
         else:
             self.attrs.update(attrs)
+
+    def phase(self, name):
+        return _Phase(self, name)
 
     def __enter__(self):
         up = self._up = getattr(_TLS, "top", None)
@@ -260,10 +295,8 @@ def region(name: str, **choice):
         from .registry import get_registry
 
         get_registry().counter("program_regions_traced_total", region=name, **choice).inc()
-    up = getattr(_TLS, "top", None)
-    while up is not None and up.name != "program/first_call":
-        up = up._up
-    if up is None:
+    up = open_span("program/first_call")
+    if up is _NULL_SPAN:
         return jax.named_scope(name)
     if up.attrs is None or "region_trace_s" not in up.attrs:
         up.set(region_trace_s={})
@@ -290,6 +323,16 @@ def current_span():
     """The innermost span open on this thread, or None: how a seam deep in a
     call (a program's first call) learns the ``q`` of the quantum it is in."""
     return getattr(_TLS, "top", None)
+
+
+def open_span(name: str):
+    """The nearest open span called ``name`` on this thread, else the null
+    span: how code under a span (the trainer's dispatch, inside a first
+    call's own span) adds a phase to it."""
+    up = getattr(_TLS, "top", None)
+    while up is not None and up.name != name:
+        up = up._up
+    return up if up is not None else _NULL_SPAN
 
 
 def self_times(spans: List[Dict]) -> Dict[int, float]:

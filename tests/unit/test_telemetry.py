@@ -419,8 +419,7 @@ def test_engine_train_step_telemetry(tmp_path):
 
     reg = engine.telemetry
     base = {n: reg.peek(n) or 0.0 for n in
-            ("train_steps_total", "train_microbatches_total", "train_samples_total",
-             "train_tokens_total")}
+            ("train_steps_total", "train_tokens_total")}
     comm_base = reg.peek("comm_bytes_total", op="grad_sync_estimated") or 0.0
 
     tracer = get_tracer()
@@ -435,8 +434,7 @@ def test_engine_train_step_telemetry(tmp_path):
 
     dp = engine.topology.data_parallel_size
     assert reg.peek("train_steps_total") == base["train_steps_total"] + 2
-    assert reg.peek("train_microbatches_total") == base["train_microbatches_total"] + 4
-    assert reg.peek("train_samples_total") == base["train_samples_total"] + 4 * dp
+    assert engine.micro_steps == 4 and engine.global_samples == 4 * dp  # micro-batches and samples are the engine's own counts
     assert reg.peek("train_tokens_total") == base["train_tokens_total"] + 4 * dp * 16
     assert (reg.peek("last_step_completed_unix") or 0.0) > 0
     assert (reg.peek("train_loss_scale") or 0.0) >= 1.0
@@ -834,7 +832,7 @@ _METRIC_PREFIXES = ("train_", "comm_", "infer_", "kv_", "sched_", "spec_",
 _EXTRA_METRICS = {"last_step_completed_unix", "tp_degree", "sparse_keys_chosen_total", "sparse_keys_visible_total", "sparse_index_loss",
                   "moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min",
                   "moe_fallback_layers_total", "diffusion_masked_positions_total", "diffusion_positions_total", "diffusion_weight_sum",
-                  "profile_captures_total",
+                  "profile_captures_total", "profile_captures_dropped_total",
                   "profile_collective_exposed_fraction",
                   "profile_device_busy_fraction",
                   "profile_host_gap_fraction"}

@@ -17,7 +17,8 @@ split exposed-vs-overlapped / transfer / host gap), the top-N device
 operations, the exposed-collective summary cross-checked against the
 ``tp_all_reduce`` ledger and, for a capture of a training run
 (``DS_TPU_PROFILE=1``: docs/OBSERVABILITY.md, "Regions"), the compiled
-step's device time by region and phase. ``--json`` dumps the summary
+step's device time by region and phase, and for a capture that a hunt kept
+(``DS_TPU_PROFILE=stall``) the stalled step. ``--json`` dumps the summary
 document instead.
 
 ``smoke`` captures an 8-request fused serving run end-to-end (arm →
@@ -121,8 +122,28 @@ def render(summary, top=8):
                      + ", ".join(f"{k} {float(v) * 1e3:.3f}" for k, v in summary["idle_by_span"].items()))
     if summary.get("capture_cost_s"):
         lines.append("the capture cost (s): " + ", ".join(f"{k} {v}" for k, v in summary["capture_cost_s"].items()))
+    if summary.get("hunted"):
+        lines.append(f"the hunt: {sum(not h['kept'] for h in summary['hunted'])} captures dropped before this one; each capture's cost (s): "
+                     + "; ".join(", ".join(f"{k} {v}" for k, v in h.items()) for h in summary["hunted"][-top:]))
+    if summary.get("stall"):
+        lines += ["", render_stall(summary["stall"])]
     if summary.get("regions"):
         lines += ["", render_regions(summary["regions"], top=top)]
+    return "\n".join(lines)
+
+
+def render_stall(stall):
+    """The stalled quantum of a capture the hunt kept (``DS_TPU_PROFILE=stall``)."""
+    ms = lambda s: f"{float(s) * 1e3:.3f}"
+    lines = [f"the stalled step: quantum {stall['quantum']} took {ms(stall['period_s'])} ms against a median of {ms(stall['median_s'])}; "
+             f"the first device busy {ms(stall['device_busy_s'])}, idle {ms(stall['device_idle_s'])}"]
+    for gap in stall.get("idle", []):
+        lines.append(f"  idle for {ms(gap['dur_s'])} ms from {ms(gap['start_s'])} ms into it ({ms(gap.get('quiet_s', 0.0))} of them with no host thread "
+                     "in an event of the runtime's), under " + ", ".join(f"{k} {ms(v)}" for k, v in gap["under"].items()))
+        lines += [f"    {ms(sec):>10} ms  {line}: {name}" for line, name, sec in gap["host"]]
+    if stall.get("long_ops"):
+        lines.append("  operations over 1.5 x their own median of the other quanta (ms here | usual): "
+                     + ", ".join(f"{name} {ms(here)} | {ms(usual)}" for name, here, usual in stall["long_ops"]))
     return "\n".join(lines)
 
 
