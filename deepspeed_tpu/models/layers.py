@@ -194,29 +194,81 @@ def _rope_table(cfg: TransformerFields, head_dim: int) -> Tuple[np.ndarray, np.n
     return (np.cos(freqs) * attn_factor).astype(np.float32), (np.sin(freqs) * attn_factor).astype(np.float32)
 
 
-def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, positions: jnp.ndarray,
-               rotary_dim: Optional[int] = None, style: str = "neox") -> jnp.ndarray:
-    """x: (B,S,H,D); positions: (B,S) absolute token positions.
+@functools.lru_cache(maxsize=32)
+def _rope_partners(D: int, rd: int, offset: int, style: str) -> np.ndarray:
+    """The (D, D) matrix of zeros and ones that brings every rotated lane its partner: ``(x @ R)[..., j]`` is ``x`` at the
+    other lane of ``j``'s pair (``neox``: ``(i, i + rd/2)``; ``gptj``: ``(2i, 2i + 1)``) for ``j`` in ``[offset, offset +
+    rd)`` and zero elsewhere. A pair swaps, so ``R`` is its own transpose. Built once a width, span and style."""
+    R = np.zeros((D, D), np.float32)
+    pairs = np.arange(rd // 2)
+    a, b = (2 * pairs, 2 * pairs + 1) if style == "gptj" else (pairs, pairs + rd // 2)
+    R[offset + a, offset + b] = R[offset + b, offset + a] = 1.0
+    return R
 
-    ``rotary_dim < D`` rotates only the leading dims (gpt-neox ``rotary_pct``,
-    phi ``partial_rotary_factor``, gpt-j ``rotary_dim``); the tail passes
-    through. ``style``: "neox" rotates half-split pairs (llama/neox/phi),
-    "gptj" rotates adjacent interleaved pairs (gpt-j ``rotate_every_two``).
-    """
+
+def _rotate(x, cos, sin, positions, rd, offset, style, back=False):
+    """``x * C + (x @ R) * S`` in float32, rounded once to ``x``'s dtype: ``C`` holds a pair's cosine at both of its lanes
+    and one outside the rotated span, ``S`` minus its sine at the pair's first lane, plus its sine at the second and zero
+    outside (``back``: the signs the other way round, the rotation by the opposite angle). The product is exact: an
+    output is ONE input times one, summed in float32 (bf16 operands as they are; any other dtype in as many passes as
+    it takes). The compiler makes one fusion of it, over ``x`` at its full last axis."""
     D = x.shape[-1]
-    rd = D if rotary_dim is None else rotary_dim
-    xr, xp = (x, None) if rd == D else (x[..., :rd], x[..., rd:])
-    c = cos[positions][:, :, None, :]  # (B,S,1,rd/2)
-    s = sin[positions][:, :, None, :]
-    xr32 = xr.astype(jnp.float32)
+    sin = jnp.asarray(sin)  # a backward's kept table is a constant of the trace, which has no unary minus
+    first, second = (sin, -sin) if back else (-sin, sin)
     if style == "gptj":
-        x1, x2 = xr32[..., 0::2], xr32[..., 1::2]
-        out = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).reshape(xr.shape)
+        C, S = jnp.repeat(cos, 2, axis=-1), jnp.stack([first, second], axis=-1).reshape(sin.shape[0], rd)
     else:
-        x1, x2 = jnp.split(xr32, 2, axis=-1)
-        out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    out = out.astype(x.dtype)
-    return out if xp is None else jnp.concatenate([out, xp], axis=-1)
+        C, S = jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([first, second], axis=-1)
+    if rd != D:
+        outside = ((0, 0), (offset, D - rd - offset))
+        C, S = jnp.pad(C, outside, constant_values=1.0), jnp.pad(S, outside)
+    partners = jnp.einsum("...d,de->...e", x, jnp.asarray(_rope_partners(D, rd, offset, style), x.dtype),
+                          preferred_element_type=jnp.float32,
+                          precision=None if x.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST)
+    out = x.astype(jnp.float32) * C[positions][:, :, None, :] + partners * S[positions][:, :, None, :]
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _rope(x, cos, sin, positions, rd, offset, style):
+    return _rotate(x, cos, sin, positions, rd, offset, style)
+
+
+def _rope_fwd(x, cos, sin, positions, rd, offset, style):
+    return _rotate(x, cos, sin, positions, rd, offset, style), (cos, sin, positions)
+
+
+def _rope_bwd(rd, offset, style, kept, g):
+    # a rotation's transpose is the rotation by the opposite angle: the same pass over the cotangent, and nothing kept
+    # but the tables and the positions. The tables are constants of a configuration and get no gradient
+    cos, sin, positions = kept
+    return _rotate(g, cos, sin, positions, rd, offset, style, back=True), None, None, None
+
+
+_rope.defvjp(_rope_fwd, _rope_bwd)
+ROPE_PATH = "xla"  # the form ``apply_rope`` traces: XLA's own code, one fusion a call
+# for a kind's record (``LayerKind.joined``): the series that say which form of the rotation its sites traced
+ROPE_FORM = {"rope": ("mixer/rope", (ROPE_PATH,), "path", {"op": "qk"})}
+
+
+def rope_region(op: str = "qk"):
+    """A rotating site's region, counted by the form traced (``path``) and by what is rotated (``op``: ``qk``, whole
+    heads of q and k; ``mla``, a head's second part and the shared key part)."""
+    return region("mixer/rope", path=ROPE_PATH, op=op)
+
+
+def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, positions: jnp.ndarray,
+               rotary_dim: Optional[int] = None, style: str = "neox", offset: int = 0) -> jnp.ndarray:
+    """x: (B,S,H,D); positions: (B,S) absolute token positions; cos, sin: (L, rotary_dim/2) tables.
+
+    ``rotary_dim < D`` rotates only the ``rotary_dim`` dims from ``offset`` on (gpt-neox ``rotary_pct``, phi
+    ``partial_rotary_factor``, gpt-j ``rotary_dim``: the leading ones; latent attention: the trailing ones); the
+    rest pass through. ``style``: "neox" rotates half-split pairs (llama/neox/phi), "gptj" rotates adjacent
+    interleaved pairs (gpt-j ``rotate_every_two``). One pass over ``x`` at its full width, forward and backward
+    (``_rotate``); the values are ``x1 * c - x2 * s`` and ``x2 * c + x1 * s`` in float32, rounded once.
+    """
+    rd = x.shape[-1] if rotary_dim is None else rotary_dim
+    return _rope(x, cos, sin, positions, rd, offset, style)
 
 
 def alibi_slopes(n_heads: int) -> np.ndarray:
@@ -241,7 +293,7 @@ def alibi_slopes(n_heads: int) -> np.ndarray:
 class Attention(LayerKind, nn.Module):
     cfg: TransformerFields
     window: Optional[int] = None  # the kind ``window``: ``sliding_window`` keys; None: ``full``
-    keeps, stackable, joined = (FLASH_SAVED, SAVED), True, TILES_A_TRIP
+    keeps, stackable, joined = (FLASH_SAVED, SAVED), True, {**TILES_A_TRIP, **ROPE_FORM}
 
     @classmethod
     def from_config(cls, cfg, kind):
@@ -269,7 +321,7 @@ class Attention(LayerKind, nn.Module):
                 k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="k_norm")(k)
 
         if cfg.pos_emb == "rope":
-            with region("mixer/rope"):
+            with rope_region():
                 rd = cfg.rotary_dim
                 cos, sin = scaled_rope_frequencies(cfg, rd)
                 q = apply_rope(q, cos, sin, positions, rotary_dim=rd, style=cfg.rope_style)

@@ -33,7 +33,8 @@ from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
 from .config import TransformerFields
-from .layers import FLASH_SAVED, SAVED, TILES_A_TRIP, LayerKind, LayerNorm, RMSNorm, apply_rope, scaled_rope_frequencies
+from .layers import (FLASH_SAVED, ROPE_FORM, SAVED, TILES_A_TRIP, LayerKind, LayerNorm, RMSNorm, apply_rope, rope_region,
+                     scaled_rope_frequencies)
 
 
 def _uniform(low, high):
@@ -181,7 +182,7 @@ class MLAMixer(LayerKind, nn.Module):
     cfg: TransformerFields
     keeps, hybrid = (FLASH_SAVED, SAVED), True
     # ``mla_rope``: the rotation of the shared key part (no key where the model has no positions)
-    paths, joined = {"mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "mla_rope": ("mixer/rope", {})}, TILES_A_TRIP
+    paths, joined = {"mla_path": ("mixer/kernel", {"op": "mla", "pass": "fwd"}), "mla_rope": ("mixer/rope", {"op": "mla"})}, TILES_A_TRIP
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
@@ -198,14 +199,14 @@ class MLAMixer(LayerKind, nn.Module):
             c, k_shared = latent[..., :cfg.mla_kv_rank], latent[..., cfg.mla_kv_rank:]
             c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="kv_a_norm")(c)
             kv = nn.DenseGeneral((H, dn + dv), use_bias=False, name="kv_b_proj", dtype=cfg.dtype, param_dtype=f32)(c)
-        # the rotation, where the model has positions (``path``: XLA's, ahead of the attention call, is the one form),
-        # and the shared key part's way into every head
-        with region("mixer/rope", **({"path": "xla"} if cfg.pos_emb == "rope" else {})):
+        # the rotation, where the model has positions (``path``: the one form ``apply_rope`` traces, ahead of the attention
+        # call), and the shared key part's way into every head
+        with rope_region("mla") if cfg.pos_emb == "rope" else region("mixer/rope"):
             if cfg.pos_emb == "rope":
                 if positions is None:
                     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
                 cos, sin = scaled_rope_frequencies(cfg, dr)
-                q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions, style=cfg.rope_style)], axis=-1)
+                q = apply_rope(q, cos, sin, positions, rotary_dim=dr, style=cfg.rope_style, offset=dn)  # a head's second part, in place
                 k_shared = apply_rope(k_shared[:, :, None, :], cos, sin, positions, style=cfg.rope_style)[:, :, 0, :]  # once, as one head
             k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (B, S, H, dr))], axis=-1)
             # named too: the attention call's own operands, which its backward reads. Everything between the three products
@@ -258,7 +259,7 @@ class SparseMixer(LayerKind, nn.Module):
     # call writes beside the loss (the flash call's where every visible key is chosen); a model has this mixer in every
     # layer or in none, so its key is given though ``alone``
     sows, keeps, hybrid = ("intermediates",), (sparse.SAVED, FLASH_SAVED, SAVED), True
-    paths, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, True
+    paths, joined, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, ROPE_FORM, True
 
     @staticmethod
     def report(intermediates):
@@ -286,7 +287,7 @@ class SparseMixer(LayerKind, nn.Module):
                 k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="k_norm")(k)
         rotate = lambda t, width: apply_rope(t, *scaled_rope_frequencies(cfg, width), positions, style=cfg.rope_style)
         if cfg.pos_emb == "rope":
-            with region("mixer/rope"):
+            with rope_region():
                 q, k = rotate(q, D), rotate(k, D)
         scale = cfg.attn_scale or D**-0.5
         if S <= cfg.index_topk and not self.is_initializing():
@@ -489,7 +490,8 @@ class BlockDiffMixer(LayerKind, nn.Module):
     keeps, hybrid = (FLASH_SAVED, SAVED), True
     paths, alone = {"blockdiff_path": ("mixer/kernel", {"op": "blockdiff", "pass": "fwd"})}, True
     # static at trace time, counted where the kernel's walk is chosen: tiles visited / tiles of the square, pairs kept
-    joined = {"blockdiff_tiles": ("mixer/kernel", None, "tiles"), "blockdiff_pairs": ("mixer/kernel", None, "pairs"), **TILES_A_TRIP}
+    joined = {"blockdiff_tiles": ("mixer/kernel", None, "tiles"), "blockdiff_pairs": ("mixer/kernel", None, "pairs"), **TILES_A_TRIP,
+              **ROPE_FORM}
 
     @staticmethod
     def halves(cfg, S: int) -> int:
@@ -533,7 +535,7 @@ class BlockDiffMixer(LayerKind, nn.Module):
                                             init_scale=cfg.blockdiff_qk_init_scale, name=name)
                 q, k = norm("q_norm")(q), norm("k_norm")(k)
         if cfg.pos_emb == "rope":
-            with region("mixer/rope"):
+            with rope_region():
                 pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32) % L, (B, S))
                 cos, sin = scaled_rope_frequencies(cfg, D)
                 q, k = (apply_rope(t, cos, sin, pos, style=cfg.rope_style) for t in (q, k))

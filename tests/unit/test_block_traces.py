@@ -260,4 +260,5 @@ def test_flops_of_jaxpr_counts_every_layer_of_the_cached_block(site):
     mlp = 3 * B * S * d * f  # gate, up, down
     keys = S if site == "train" else 8 * 8  # attention_xla forms all S x S scores; paged prefill gathers 8 pages of 8
     attn = 2 * B * H * S * keys * D  # q k^T and p v
-    assert macs(4) - macs(2) == 2 * (proj + mlp + attn)
+    rope = B * S * (H + KVH) * D * D  # the rotation's partners: every head of q and k times a (D, D) matrix of zeros and ones
+    assert macs(4) - macs(2) == 2 * (proj + mlp + attn + rope)

@@ -305,6 +305,29 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert [now - was for now, was in zip(trips(), trips_before)] == [1.0 - pairs, pairs, 1.0, 0.0]
 
 
+def test_the_rotation_is_one_pass_over_q_at_its_full_width(one_chip):
+    """``apply_rope`` forward + vjp at SDAR's q, ``(1, 16384, 32, 128)`` bf16, compiled for the described v5e: TWO fusions
+    write an array of q's size, the rotation and its transpose, each with the partners' product inside (``kOutput``: a
+    convolution's fusion), and nothing over the 16,384 positions has a last axis of 64: neither a half of q (which fills
+    half of every 128-lane tile, so it takes q's full bytes) nor a half-width table. The halves written out
+    (``x1 * c - x2 * s`` joined to ``x2 * c + x1 * s``) compiled to a pass that wrote two such halves and a pass that
+    joined them, forward and backward; XLA's code alone, so no Mosaic call for a roofline reader to mistake."""
+    from deepspeed_tpu.models.layers import apply_rope
+
+    rd, rows = 128, 16384
+
+    def fwd_bwd(x, cos, sin, positions, g):
+        out, pull = jax.vjp(lambda x: apply_rope(x, cos, sin, positions), x)
+        return out, pull(g)[0]
+
+    x, table = S((1, rows, 32, rd), BF16, sharding=one_chip), S((8192, rd // 2), F32, sharding=one_chip)
+    text = jax.jit(fwd_bwd).lower(x, table, table, S((1, rows), I32, sharding=one_chip), x).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(rf"\[(?:\d+,)*{rows},(?:\d+,)*{rd // 2}\]", text) and "tpu_custom_call" not in text
+    passes = re.findall(rf"= bf16\[1,{rows},32,{rd}\]\S* fusion\(.*kind=(\w+)", entry)
+    assert passes == ["kOutput", "kOutput"]
+
+
 @pytest.mark.parametrize("case,heads", [("flash_blockdiff_b1_s16384_h32_kvh4_d128_blk4", (32, 4)), ("flash_gqa_b1_s8192_h16_kvh2_d256", (16, 2))])
 def test_the_longest_heads_fit_their_own_vmem_count_with_two_tiles_in_the_forward(case, heads, one_chip, monkeypatch):
     """SDAR's rows of 16,384 at 128 and Qwen3-Next's of 8,192 at 256, the two calls nearest ``vmem_budget()`` (48 MiB): the

@@ -400,11 +400,11 @@ def test_six_layers_of_rotated_latent_attention_trace_two_blocks_and_build_one_t
     ids = np.zeros((1, 32), np.int32)
     params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
     reg = get_registry()
-    before = [reg.peek("program_regions_traced_total", region="block", site="train") or 0, reg.peek("program_regions_traced_total", region="mixer/rope", path="xla") or 0]
+    before = [reg.peek("program_regions_traced_total", region="block", site="train") or 0, reg.peek("program_regions_traced_total", region="mixer/rope", path="xla", op="mla") or 0]
     built = T._rope_table.cache_info().misses
     jax.block_until_ready(jax.jit(lambda p: model.loss_fn(p, {"input_ids": ids}))(params))
     assert reg.peek("program_regions_traced_total", region="block", site="train") - before[0] == 2
-    assert reg.peek("program_regions_traced_total", region="mixer/rope", path="xla") - before[1] == 2
+    assert reg.peek("program_regions_traced_total", region="mixer/rope", path="xla", op="mla") - before[1] == 2
     info = T._rope_table.cache_info()
     assert (info.misses - built, info.currsize) == (0, 1) and info.hits >= 2  # ``init`` built it; the traces found it
     cos, sin = T.scaled_rope_frequencies(model.cfg, 8)

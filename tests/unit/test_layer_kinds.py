@@ -168,7 +168,8 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     declared = set(record.keeps) | set(other.keeps) | {SAVED}
     assert _names(jaxpr.jaxpr, set()) <= declared and declared - _names(jaxpr.jaxpr, set()) <= KERNELS_ALONE
     assert table.remat_keeps(kind) == (tuple(dict.fromkeys((record, other)[part].keeps + (other, record)[part].keeps + (SAVED,))) if record.hybrid else ())
-    assert rose <= set(record.paths) | set(record.joined) and (set(record.paths) | set(record.joined)) - rose <= set(WHEN)
+    keys = set(record.paths) | set(record.joined)  # ``full`` beside an FFN rotates, and says so (``rope``)
+    assert rose - set(other.joined) <= keys and keys - rose <= set(WHEN)
     assert set(record.path_words) <= set(record.paths)
     # the stacked forms: the configuration's word, the pipeline's and the server's refusals
     assert cfg.unstackable == (() if record.stackable else (name,))

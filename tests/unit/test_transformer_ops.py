@@ -121,6 +121,46 @@ def test_apply_rotary_pos_emb_partial():
     np.testing.assert_allclose(np.asarray(qr[..., 4:]), np.asarray(q[..., 4:]), rtol=1e-7)
 
 
+def _rope_by_halves(x, cos, sin, positions, rd, offset, style):
+    """The rotation as it was written before the one-pass form, the plain reference: the rotated span split in halves
+    (``neox``) or in even and odd lanes (``gptj``), ``x1 * c - x2 * s`` and ``x2 * c + x1 * s`` in float32, joined."""
+    xr = x[..., offset:offset + rd].astype(jnp.float32)
+    c, s = cos[positions][:, :, None, :], sin[positions][:, :, None, :]
+    if style == "gptj":
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+        out = jnp.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).reshape(xr.shape)
+    else:
+        x1, x2 = jnp.split(xr, 2, axis=-1)
+        out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return jnp.concatenate([x[..., :offset], out.astype(x.dtype), x[..., offset + rd:]], axis=-1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("style", ["neox", "gptj"])
+@pytest.mark.parametrize("D,rd,offset", [(128, 128, 0), (256, 64, 0), (192, 64, 128)], ids=["whole_head", "first_quarter_of_256", "last_64_of_192"])
+def test_the_one_pass_rotation_is_the_split_and_joined_one(D, rd, offset, style, dtype):
+    """``apply_rope`` (``x * C + (x @ R) * S``, one pass over x at its full width) against the halves written out, value
+    and vjp: the whole head (SDAR, Keye, OLMo), Qwen3-Next's 64 of 256, latent attention's trailing 64 of 192 (Kimi-VL's,
+    ``gptj``); positions out of order (SDAR's ``arange(S) % L`` and a shuffled row). Op by op, so that no compiler
+    contracts one form's multiply-add and not the other's: bit for bit in bf16, float32 to 1e-6."""
+    from deepspeed_tpu.models.transformer import apply_rope, rope_frequencies
+
+    B, Sq, Hq, L = 2, 48, 3, 24
+    rng = np.random.RandomState(D + rd + len(style))
+    x, g = (jnp.asarray(rng.randn(B, Sq, Hq, D), dtype) for _ in range(2))
+    positions = jnp.asarray(np.stack([np.arange(Sq) % L, rng.permutation(Sq) % L]), jnp.int32)
+    cos, sin = rope_frequencies(rd, L, 10000.0)
+    got, pull = jax.vjp(lambda x: apply_rope(x, cos, sin, positions, rotary_dim=rd, style=style, offset=offset), x)
+    want, pull_halves = jax.vjp(lambda x: _rope_by_halves(x, cos, sin, positions, rd, offset, style), x)
+    back, back_halves = pull(g)[0], pull_halves(g)[0]
+    assert got.dtype == want.dtype == back.dtype == dtype
+    tol = dict(rtol=0, atol=0) if dtype == jnp.bfloat16 else dict(rtol=1e-6, atol=1e-6)
+    for ours, halves in ((got, want), (back, back_halves)):
+        np.testing.assert_allclose(np.asarray(ours, np.float32), np.asarray(halves, np.float32), **tol)
+    outside = np.r_[0:offset, offset + rd:D]
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[..., outside], np.asarray(x, np.float32)[..., outside])  # passes through
+
+
 def test_moe_helpers():
     res, out = r(2, 3, 8), r(2, 3, 8)
     coef = r(2, 3, 16)
