@@ -13,6 +13,7 @@
     python chip_smoke.py --only sparse   # ... and its fifth's: GQA over the keys a learned indexer chooses, 8 of 128 experts
     python chip_smoke.py --only sambay   # ... and its sixth's: Mamba-1 scans, differential attention, a second half that reads the first's
     python chip_smoke.py --only blockdiff  # ... and its seventh's: block-diffusion training, GQA under the block mask over a doubled row, 8 of 128 experts
+    python chip_smoke.py --only mixed    # ... and its eighth's: one full layer without positions to three rotated window layers at 16,384 rows, ReGLU experts behind an early router
 
 Everything runs in this one process (a chip belongs to one process), at the
 full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
@@ -1030,16 +1031,18 @@ BLOCKDIFF_CLASS_LIMITS = {
 }
 
 
-def _blockdiff_names():
+def _decoder_names(parts, layers=4):
+    """Every leaf of a routed decoder's tree, a leaf's name its path: the untied tables and the final norm, then a layer's two
+    norms and the leaves of its ``parts`` (part -> its leaves)."""
     names = ["wte", "lm_head", "RMSNorm_0/scale"]
-    for i in range(4):
+    for i in range(layers):
         names += [f"layer_{i}/{norm}/scale" for norm in ("RMSNorm_0", "RMSNorm_1")]
-        names += [f"layer_{i}/{part}/{leaf}" for part, leaves in BLOCKDIFF_PARTS.items() for leaf in leaves]
+        names += [f"layer_{i}/{part}/{leaf}" for part, leaves in parts.items() for leaf in leaves]
     return names
 
 
 BLOCKDIFF_LIMITS = {"logits": BLOCKDIFF_CLASS_LIMITS["logits"],
-                    **{name: BLOCKDIFF_CLASS_LIMITS.get(_sambay_class(name), BLOCKDIFF_LEAF_LIMIT) for name in _blockdiff_names()}}
+                    **{name: BLOCKDIFF_CLASS_LIMITS.get(_sambay_class(name), BLOCKDIFF_LEAF_LIMIT) for name in _decoder_names(BLOCKDIFF_PARTS)}}
 
 
 def _blockdiff_rows(cfg, seed):
@@ -1049,6 +1052,42 @@ def _blockdiff_rows(cfg, seed):
     gen = mf.load_module(f"{mf.BENCH}/generators/block_diffusion_batches.py")
     params = {"seq_len": cfg["program"]["max_seq_len"], "block_len": cfg["program"]["block_length"], "n_batches": 1}
     return gen.generate(params, seed, 0.0, {"vocab_size": cfg["program"]["vocab_size"], "global_batch": 1})["batches"][0]["input_ids"]
+
+
+# SmallThinker-21BA3B's layers 0-3 (``--only mixed``): one full layer without positions and three rotated window layers of
+# 4,096 at 16,384 rows, every FFN routed (6 of 64, 8 held) by a router that reads the attention's input, the experts
+# ReGLU. EVERY leaf of the gradient is read (43: a leaf's name is its path). Four controls, each the plain bf16 reference
+# with one thing wrong, and each has to break a limit on every seed: window layers that attend every earlier key
+# (``no_window``), the full layer rotated too (``rotated_full``), the router on the experts' own input (``late_router``)
+# and ``silu`` for ``relu`` (``silu_gate``). At the usual start of ``o_proj`` (the cell starts it small, which keeps the
+# router's load even and would hide the attention's part of the logits at 0.02 of its size).
+# Limits from three seeds (0, 11, 101) and checked on two more (2024, 31337, from the committed files alone, which passed;
+# my chip runs, PR 53: published widths, 4 layers, 1 x 16,384), by class of leaf (its path without the layer). The logits and
+# the attention's, the norms' and the tables' leaves separate cleanly: each limit lies between the LARGEST reading of the
+# program and the SMALLEST of the controls' largest. NOT judged, only reported (a limit of None): the routers' own
+# gradients, which flips of the top 6 of 64 rule in the program and in the plain bf16 path alike (0.041 to 0.233 for the
+# program, 0.038 to 0.322 for ``plain_bf16``, the reference with nothing wrong, reported beside the program and judged by
+# nothing). The routed layer's other leaves (a held expert's three matrices and the norm ahead of the experts) read the
+# same flips through the experts' rows, a seed and a layer at a time: the program 0.026 to 0.241, the plain bf16 reference
+# 0.026 to 0.233; a control's reading there may lie under the program's on another seed (``no_window`` 0.174,
+# ``rotated_full`` 0.217 at the least), so their limit, the blockdiff phase's 0.27, guards against a gross fault and
+# separates nothing: the logits and the other leaves are where every control fails. NOT a control, read on the first three
+# seeds and taken out: the softmaxes' and the router's statistics in bf16 (``low_state``, the precision below the stated
+# one) reads what the program reads, logits 0.0099 to 0.0119 and no leaf apart from it by more than the seeds are: at a
+# random start a softmax over thousands of keys is an average, and bf16 statistics move it by rounding (PERF.md section 6,
+# PR 53).
+MIXED_PARTS = {"attn": ("q_proj", "k_proj", "v_proj", "o_proj"), "routed": ("gate", "experts_wg", "experts_wi", "experts_wo")}
+MIXED_LEAF_LIMIT = 0.1       # every other leaf: the program 0.0061-0.0677 (layer 0's q_proj the largest) | plain bf16 0.0662 at most | the controls over these leaves: silu_gate 0.063-0.320, rotated_full 0.057-1.096, late_router 0.120-1.195, no_window 0.456-1.690, each control's largest 0.31 and more on every seed
+MIXED_CLASS_LIMITS = {
+    "logits": 0.03,          # 0.0092-0.0108 | plain bf16 0.0094-0.0109 | silu_gate 0.067-0.081, rotated_full 0.098-0.111, late_router 0.149-0.301, no_window 0.516-0.546
+    "routed/gate": None,
+    **{f"routed/experts_{m}": 0.27 for m in ("wg", "wi", "wo")},  # 0.026-0.241 | plain bf16 0.233 | (see above)
+    "RMSNorm_1/scale": 0.27,  # the norm ahead of the experts: the same flips
+}
+
+
+MIXED_LIMITS = {"logits": MIXED_CLASS_LIMITS["logits"],
+                **{name: MIXED_CLASS_LIMITS.get(_sambay_class(name), MIXED_LEAF_LIMIT) for name in _decoder_names(MIXED_PARTS)}}
 
 
 # a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, its controls
@@ -1070,6 +1109,9 @@ SMOKE_MODELS = {
                   # its query picks, which bf16 rounding flips for a few positions in a hundred, in the program and in the
                   # plain bf16 reference alike: a measure that reads the flips reads no arithmetic
                   {"rows": _blockdiff_rows, "program": {"blockdiff_qk_init_scale": 1.0}, "report_plain": True}),
+    "mixed": ("benchmarks/configs/smallthinker-21b-l4e8.json", MIXED_LIMITS, _sambay_leaf,
+              {"no_window": {"windows": "none"}, "rotated_full": {"rotation": "all"}, "late_router": {"router": "late"}, "silu_gate": {"gate": "silu"}},
+              {"program": {"sparse_out_init_scale": 1.0}, "report_plain": True}),
 }
 
 

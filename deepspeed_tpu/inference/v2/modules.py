@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ...models.config import GATED
 from ...models.transformer import TransformerConfig
 from ...ops.pallas.paged_attention import paged_attention_ref
 from ...ops.registry import REGISTRY
@@ -158,9 +159,9 @@ def attention_tpu(cfg: TransformerConfig, q, kp, vp, block_tables, ctx_lens, pos
 def mlp_tpu(cfg: TransformerConfig, p: Dict[str, Any], x):
     """ref ``implementations/linear/*``: the dense FFN pair."""
     dtype = cfg.dtype
-    if cfg.activation in ("swiglu", "geglu"):
+    if cfg.activation in GATED:
         g = _proj(x, p["gate_proj"], "bsd,df->bsf", dtype)
-        g = jax.nn.gelu(g) if cfg.activation == "geglu" else jax.nn.silu(g)
+        g = getattr(jax.nn, GATED[cfg.activation])(g)
         h = g * _proj(x, p["up_proj"], "bsd,df->bsf", dtype)
     else:
         h = _proj(x, p["up_proj"], "bsd,df->bsf", dtype)

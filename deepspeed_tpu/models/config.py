@@ -8,6 +8,9 @@ from typing import Any, Optional, Tuple
 import jax.numpy as jnp
 
 
+GATED = {"swiglu": "silu", "geglu": "gelu", "reglu": "relu"}  # ``activation``: a gated FFN's, and its gate's name in ``jax.nn``
+
+
 @dataclass(frozen=True)
 class TransformerFields:
     vocab_size: int = 32000
@@ -19,7 +22,8 @@ class TransformerFields:
     d_ff: Optional[int] = None  # default: 4*d_model (gelu) or 8/3*d_model (swiglu)
     max_seq_len: int = 2048
     norm: str = "layernorm"  # layernorm | rmsnorm | layernorm_np (olmo: no affine params)
-    activation: str = "gelu"  # gelu (tanh approx) | gelu_exact (erf) | swiglu | relu
+    # gelu (tanh approx) | gelu_exact (erf) | swiglu | geglu | reglu (relu(gate) * up: the gated FFNs', dense and routed) | relu
+    activation: str = "gelu"
     pos_emb: str = "learned"  # learned | rope | alibi | none
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0  # fraction of head_dim rotated (gpt-neox/phi partial rotary)
@@ -97,9 +101,10 @@ class TransformerFields:
     index_heads: int = 16
     index_head_dim: int = 64
     index_topk: int = 2048
-    # sparse: the attention's output projection starts at this times its usual standard deviation. At a random start
-    # attention averages its keys, so every position gets nearly the same vector and the stream collapses onto it layer
-    # by layer; a random router turns that into a load a seed decides. A small start leaves the stream the tokens' own
+    # sparse, and softmax attention over one head size (full, window, nope): the attention's output projection starts at
+    # this times its usual standard deviation. At a random start attention averages its keys, so every position gets
+    # nearly the same vector and the stream collapses onto it layer by layer; a random router turns that into a load a
+    # seed decides. A small start leaves the stream the tokens' own
     sparse_out_init_scale: float = 1.0
     mla_kv_rank: int = 512  # mla: ``n_heads`` heads; q and k of nope + rope dims (the rope dims rotated under
     # ``pos_emb="rope"`` by ``rope_theta`` / ``rope_style``, else nothing is), v of its own
@@ -145,7 +150,7 @@ class TransformerFields:
     def ffn_dim(self) -> int:
         if self.d_ff is not None:
             return self.d_ff
-        if self.activation in ("swiglu", "geglu"):  # gated MLPs get the 8/3 sizing
+        if self.activation in GATED:  # gated MLPs get the 8/3 sizing
             return int(8 * self.d_model / 3 + 127) // 128 * 128 if self.d_model >= 128 else 2 * self.d_model
         return 4 * self.d_model
 

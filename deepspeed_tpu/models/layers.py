@@ -13,8 +13,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import attention
 from ..ops.pallas.flash_attention import SAVED as FLASH_SAVED, TILES_A_TRIP
+from ..ops.registry import pallas_available
 from ..telemetry.tracing import region
-from .config import TransformerFields
+from .config import GATED, TransformerFields
 
 # The name a projection's result carries for a checkpoint policy: what a product over the model width gives (a mixer's
 # q/k/v/gate projections, the dense FFN's gate and up), what one onto it gives where a backward reads it (the mixer's
@@ -44,10 +45,14 @@ class LayerKind:
     # ``alone``: a model whose layers are all of one kind says nothing of kinds on that line, unless this
     paths, path_words, joined, alone = {}, {}, {}, False
     stackable = False  # the scan over layers, ``to_pipeline`` and ``inference/v2`` can run it
-    # values between blocks, by name (a mixer's: no FFN has any). ``gives``: its call returns ``(out, {name: value})`` and
-    # the model's loop over layers carries each value on; ``takes``: it is called with ``name=value`` of the nearest
+    # values handed to a part, by name. A MIXER's lie between blocks. ``gives``: its call returns ``(out, {name: value})``
+    # and the model's loop over layers carries each value on; ``takes``: it is called with ``name=value`` of the nearest
     # earlier layer that gave the name (``layer``, the layer's own published index as an int32 scalar, is the model's to
-    # give). The gradient flows back through them. A model with either is run by the unrolled loop alone
+    # give). The gradient flows back through them. A model with either is run by the unrolled loop alone. An FFN gives
+    # nothing and ``takes`` what its OWN block made ahead of the mixer (``transformer.py::Block``: ``mixer_input``, the
+    # first norm's output, which a router placed ahead of the attention scores): a value inside one block's trace, so
+    # ``block_fn``, a checkpointed block (which makes the norm again from its input) and ZeRO-3's gathered block carry it
+    # with no argument of their own, and the stacked forms are not concerned
     gives, takes = (), ()
     # ``targets(cfg, input_ids)``: a kind whose OBJECTIVE is not next-token prediction over the whole row gives the loss
     # head (the hidden states' positions it runs over, their targets, a float32 weight a target, what the weighted sum is
@@ -291,13 +296,26 @@ def alibi_slopes(n_heads: int) -> np.ndarray:
 
 
 class Attention(LayerKind, nn.Module):
+    """Softmax attention over one head size, under its name ``attn`` in the tree whatever the kind: ``full`` (every
+    earlier key), ``window`` (the last ``sliding_window`` keys) and ``nope`` (``UnrotatedAttention``: every earlier key,
+    q and k as they are). A layer's mask AND its rotation follow its kind, so one stack may mix them."""
+
     cfg: TransformerFields
-    window: Optional[int] = None  # the kind ``window``: ``sliding_window`` keys; None: ``full``
-    keeps, stackable, joined = (FLASH_SAVED, SAVED), True, {**TILES_A_TRIP, **ROPE_FORM}
+    window: Optional[int] = None  # the kind ``window``: ``sliding_window`` keys; None: every earlier key
+    rotates: bool = True  # False (the kind ``nope``): q and k are not rotated, whatever ``pos_emb`` says of the others
+    keeps, stackable = (FLASH_SAVED, SAVED), True
+    # the first-call line, a kind: how its call was traced (``op`` is the kind's own name, counted at the call below) and,
+    # of a window layer's call, the window and the tiles the forward's walk visits of the square's
+    paths = {f"{op}_path": ("mixer/kernel", {"op": op, "pass": "fwd"}) for op in ("full", "window")}
+    joined = {**TILES_A_TRIP, **ROPE_FORM, "window_keys": ("mixer/kernel", None, "window"), "window_tiles": ("mixer/kernel", None, "window_tiles")}
 
     @classmethod
     def from_config(cls, cfg, kind):
         return cls(cfg, window=cfg.sliding_window if kind == "window" else None, name="attn")
+
+    @property
+    def op(self) -> str:
+        return "window" if self.window else "full" if self.rotates else "nope"
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, segment_ids=None):
@@ -320,7 +338,7 @@ class Attention(LayerKind, nn.Module):
                 q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="q_norm")(q)
                 k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, offset=cfg.rms_offset, name="k_norm")(k)
 
-        if cfg.pos_emb == "rope":
+        if cfg.pos_emb == "rope" and self.rotates:
             with rope_region():
                 rd = cfg.rotary_dim
                 cos, sin = scaled_rope_frequencies(cfg, rd)
@@ -339,14 +357,32 @@ class Attention(LayerKind, nn.Module):
             new_cache = (ck, cv, kv_len)
 
         slopes = jnp.asarray(alibi_slopes(H)) if cfg.pos_emb == "alibi" else None
+        # the kind's own count, a call site a trace (an empty scope: the call's regions are what they were)
+        with region("mixer/kernel", op=self.op, path="kernel" if pallas_available() else "xla", **{"pass": "fwd"},
+                    **({"window": str(self.window)} if self.window else {})):
+            pass
         out = attention(q, k, v, causal=cfg.causal, segment_ids=segment_ids, kv_len=kv_len,
                         alibi_slopes=slopes, window=self.window, scale=cfg.attn_scale)
         with region("mixer/proj"):
             if cfg.attn_output_gate:
                 out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+            init = nn.initializers.variance_scaling(cfg.sparse_out_init_scale**2, "fan_in", "truncated_normal")  # 1: flax's own
             out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=cfg.use_attn_out_bias, name="o_proj",
-                                  dtype=cfg.dtype, param_dtype=jnp.float32)(out)
+                                  dtype=cfg.dtype, param_dtype=jnp.float32, kernel_init=init)(out)
         return (out, new_cache) if kv_cache is not None else out
+
+
+class UnrotatedAttention(Attention):
+    """The kind ``nope``: causal attention over every earlier key with no positions at all, beside layers that rotate, in
+    one stack and one parameter tree (``attn``). A record of its own because the stacked forms cannot run it:
+    ``inference/v2``'s runner rotates by the model's ``pos_emb``, a layer whatever its kind."""
+
+    stackable = False
+    paths, joined = {"nope_path": ("mixer/kernel", {"op": "nope", "pass": "fwd"})}, TILES_A_TRIP  # no rotation, no window: no such key
+
+    @classmethod
+    def from_config(cls, cfg, kind):
+        return cls(cfg, rotates=False, name="attn")
 
 
 class MLP(LayerKind, nn.Module):
@@ -365,9 +401,9 @@ class MLP(LayerKind, nn.Module):
             # named (``SAVED``): the activation and the down projection's operand follow from these by elementwise work
             wide = lambda name: checkpoint_name(
                 nn.Dense(cfg.ffn_dim, use_bias=bias, name=name, dtype=cfg.dtype, param_dtype=jnp.float32)(x), SAVED)
-            if cfg.activation in ("swiglu", "geglu"):
+            if cfg.activation in GATED:
                 gate, up = wide("gate_proj"), wide("up_proj")
-                h = (nn.gelu(gate) if cfg.activation == "geglu" else nn.silu(gate)) * up
+                h = getattr(nn, GATED[cfg.activation])(gate) * up
             else:
                 h = wide("up_proj")
                 if cfg.activation == "relu":

@@ -24,14 +24,14 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..moe.layer import MOE_PARTITION_RULES, MoE, RoutedMoE
+from ..moe.layer import MOE_PARTITION_RULES, EarlyRoutedMoE, MoE, RoutedMoE
 from ..ops.fused_ce import fused_cross_entropy, fused_cross_entropy_sums
 from ..telemetry.tracing import region
 from ..utils.init_on_device import on_device_init
 from .config import TransformerFields
 # (much of what moved below this module is imported from here all the same)
-from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, _norm, _rope_table, alibi_slopes, apply_rope,  # noqa: F401
-                     make_norm, rope_frequencies, scaled_rope_frequencies)
+from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, UnrotatedAttention, _norm, _rope_table, alibi_slopes,  # noqa: F401
+                     apply_rope, make_norm, rope_frequencies, scaled_rope_frequencies)
 from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, SparseMixer,
                      SSMMixer)
 
@@ -41,8 +41,8 @@ from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMem
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module
 MIXERS = {"full": Attention, "window": Attention, "kda": KDAMixer, "gdn": GDNMixer, "mla": MLAMixer, "sparse": SparseMixer}
 MIXERS |= {"ssm": SSMMixer, "diff": DiffAttention, "diff_window": DiffAttention, "gmu": GatedMemory, "diff_cross": DiffCrossAttention}
-MIXERS |= {"blockdiff": BlockDiffMixer}
-FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE}
+MIXERS |= {"blockdiff": BlockDiffMixer, "nope": UnrotatedAttention}
+FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE, "routed_early": EarlyRoutedMoE}
 
 
 def records(kinds=None) -> Tuple[type, ...]:
@@ -165,8 +165,13 @@ class Block(nn.Module):
     kind: Tuple[str, str] = ("full", "dense")
     is_training: bool = True  # static: MoE capacity-drop is train-only
 
-    def _mlp(self, cfg, h):
-        return FFNS[self.kind[1]].from_config(cfg, self.kind[1])(h, self.is_training)
+    def _mlp(self, cfg, h, made=None):
+        """The FFN on ``h``, handed by name what its record says it ``takes`` of the values this block ``made``."""
+        ffn = FFNS[self.kind[1]]
+        if ffn.takes and made is None:
+            raise NotImplementedError(f"a {self.kind[1]} FFN takes {', '.join(ffn.takes)} of a sequential pre-norm block; this "
+                                      f"block is block_type={cfg.block_type!r}, norm_scheme={cfg.norm_scheme!r}")
+        return ffn.from_config(cfg, self.kind[1])(h, self.is_training, **{name: made[name] for name in ffn.takes})
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, segment_ids=None, taken=None):
@@ -199,11 +204,13 @@ class Block(nn.Module):
             x = _norm(cfg, x + a)
             x = _norm(cfg, x + self._mlp(cfg, x))
         else:
-            a, new_cache = run_attn(_norm(cfg, x))
+            h = _norm(cfg, x)
+            a, new_cache = run_attn(h)
             # named (``SAVED``): the FFN half's backward starts from this sum, so a checkpointed block that keeps it does not
             # make the mixer's output projection again to get it back
             x = checkpoint_name(x + a, SAVED)
-            x = x + self._mlp(cfg, _norm(cfg, x))
+            # what the block made ahead of its mixer, for an FFN whose record asks (``LayerKind.takes``)
+            x = x + self._mlp(cfg, _norm(cfg, x), {"mixer_input": h})
         if kv_cache is not None:
             return x, new_cache
         return (x, given) if mixer.gives else x

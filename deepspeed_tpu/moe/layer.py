@@ -22,7 +22,7 @@ from ..ops.registry import pallas_available
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
-from .sharded_moe import SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
+from .sharded_moe import GATES, SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
 
 class Experts(nn.Module):
@@ -79,7 +79,7 @@ class MoE(nn.Module):
     # its record as the layer kind ``moe`` (``models/layers.py::LayerKind``, which is not mixed in: ``models/`` imports
     # this module for its table, so this one imports nothing of it and says every name)
     sows, stackable = ("losses", "intermediates"), True
-    report, keeps, hybrid, paths, path_words, joined, alone = None, (), False, {}, {}, {}, False
+    report, keeps, hybrid, paths, path_words, joined, alone, takes = None, (), False, {}, {}, {}, False, ()
 
     @classmethod
     def from_config(cls, cfg, kind):
@@ -169,7 +169,14 @@ class RoutedMoE(nn.Module):
     left out. ``shared_ff`` is the shared part's whole width: a model with n
     shared experts of f gives n * f, since n SwiGLUs added are one with their
     columns side by side. No capacity, no drops, no auxiliary loss: cost follows the rows
-    routed here (``sharded_moe.routed_part``).
+    routed here (``sharded_moe.routed_part``). An expert is ``wo (act(x wg) *
+    x wi)``, the gate ``act`` by the configuration's ``activation``: ``relu``
+    for ``"reglu"``, ``silu`` for every other value.
+
+    The router scores ``x``, the FFN's own input, unless the call is handed
+    ``mixer_input`` (the kind ``routed_early``, ``EarlyRoutedMoE``): a router
+    placed ahead of the attention reads the block's FIRST norm's output for
+    ``idx`` / ``weights``, and the experts (and a shared one) still read ``x``.
 
     With an ``expert`` mesh axis the held experts are split over it by their
     leading dimension (``MOE_PARTITION_RULES``): every chip of the axis
@@ -187,34 +194,38 @@ class RoutedMoE(nn.Module):
     scoring: str = "sigmoid"
     shared_gate: bool = False
     dtype: Any = jnp.float32
+    act: str = "silu"  # the experts' gate (``sharded_moe.GATES``)
     # its record as the layer kind ``routed`` (as ``MoE`` says its own). The line's keys: how the grouped products and the
     # rows' sum were traced, the conditional's form where the buffer is smaller than every pair (``routed_part``), and how
     # the router scores its tokens and indexes the expert axis (``compare_sum``: ``held_experts``)
-    sows, keeps, hybrid, alone, stackable = ("intermediates",), (SAVED,), True, False, False
+    sows, keeps, hybrid, alone, stackable, takes = ("intermediates",), (SAVED,), True, False, False, ()
     paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
     path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
-    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum"))}
+    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu",), "act")}
     report = staticmethod(report_rows)
 
     @classmethod
     def from_config(cls, cfg, kind):
         return cls(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k, d_ff=cfg.moe_d_ff or cfg.ffn_dim,
                    held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff, scale=cfg.moe_route_scale, scoring=cfg.moe_scoring,
-                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, name="routed")
+                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, act="relu" if cfg.activation == "reglu" else "silu",
+                   name="routed")
 
     @nn.compact
-    def __call__(self, x, train: bool = True):
+    def __call__(self, x, train: bool = True, mixer_input=None):
         d, E = self.hidden_size, self.num_experts
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"a routed layer scores by sigmoid or softmax, got {self.scoring!r}")
         first, count = self.held if self.held is not None else (0, E)
         tokens = x.reshape(-1, d)
+        scored = tokens if mixer_input is None else mixer_input.reshape(-1, d)
         init = nn.initializers.normal(0.02)
-        with region("ffn/router", path=self.scoring):  # the scoring, counted where it is chosen
+        # the scoring, counted where it is chosen, and the input it read where that is not the FFN's own
+        with region("ffn/router", path=self.scoring, **({} if mixer_input is None else {"input": "mixer_input"})):
             # which experts: no bf16 pass. Named, as the shared expert's products are (``SAVED``: a checkpointed block keeps
             # them): the scores and the activation follow by elementwise work
             logits = checkpoint_name(nn.Dense(E, use_bias=False, name="gate", dtype=jnp.float32, param_dtype=jnp.float32,
-                                              precision=jax.lax.Precision.HIGHEST)(tokens.astype(jnp.float32)), SAVED)
+                                              precision=jax.lax.Precision.HIGHEST)(scored.astype(jnp.float32)), SAVED)
             if self.scoring == "softmax":
                 idx, weights = softmax_topk(logits, self.k, self.scale)
             else:
@@ -223,7 +234,7 @@ class RoutedMoE(nn.Module):
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
-        out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, moe_path() == "kernel")
+        out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, moe_path() == "kernel", self.act)
         # (routed here, of them not computed, largest group, smallest group, whether the branch that holds every pair
         # ran), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
@@ -232,7 +243,7 @@ class RoutedMoE(nn.Module):
                 dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
                                                      param_dtype=jnp.float32)
                 of_tokens = lambda feats, name: checkpoint_name(dense(feats, name)(tokens), SAVED)
-                h = nn.silu(of_tokens(self.shared_ff, "shared_gate_proj")) * of_tokens(self.shared_ff, "shared_up_proj")
+                h = GATES[self.act](of_tokens(self.shared_ff, "shared_gate_proj")) * of_tokens(self.shared_ff, "shared_up_proj")
                 shared = dense(d, "shared_down_proj")(h)
                 if self.shared_gate:  # the gate's backward reads the shared expert's output: named with the rest
                     gate = jax.nn.sigmoid(of_tokens(1, "shared_expert_gate").astype(jnp.float32))
@@ -241,7 +252,15 @@ class RoutedMoE(nn.Module):
         return out.reshape(x.shape).astype(x.dtype)
 
 
-def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel):
+class EarlyRoutedMoE(RoutedMoE):
+    """The kind ``routed_early``: ``RoutedMoE`` whose router is placed ahead of the attention. Its record asks the block
+    for the first norm's output (``LayerKind.takes``) and scores THAT; the parameter tree is ``routed``'s."""
+
+    takes = ("mixer_input",)
+    joined = {**RoutedMoE.joined, "moe_router_input": ("ffn/router", ("mixer_input",), "input")}
+
+
+def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act="silu"):
     """``routed_part`` on one chip; on a mesh, inside a shard_map in which the
     tokens are split over the batch axes, the experts over ``expert``, and the
     parts are summed over ``expert``."""
@@ -251,7 +270,7 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
 
     topo = get_mesh_topology(required=False)
     if topo is None or topo.n_devices == 1:
-        return routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel)
+        return routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
     axis = topo.axis_size("expert")
     rows = fit_spec(prune_spec(P(topo.batch_axes, None), topo), tokens.shape, topo)
     held = P("expert", None, None) if axis > 1 and wg.shape[0] % axis == 0 else P()
@@ -260,7 +279,7 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
 
     def local(tokens, idx, weights, wg, wi, wo):
         mine = first + (jax.lax.axis_index("expert") * wg.shape[0] if held != P() else 0)
-        out, routed, dropped, largest, smallest, fallback = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel)
+        out, routed, dropped, largest, smallest, fallback = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel, act)
         if held != P():
             out = jax.lax.psum(out, "expert")
         if over:

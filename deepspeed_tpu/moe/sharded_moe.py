@@ -191,9 +191,10 @@ def _rows_a_group(key, n: int):
     return jnp.sum(key[:, None] == jnp.arange(n, dtype=key.dtype), axis=0, dtype=jnp.int32)
 
 
-def _grouped(xs, w, group_sizes, kernel: bool):
+def _grouped(xs, w, group_sizes, kernel: bool, **choice):
     """Rows sorted by group times that group's matrix: (M, a) x (G, a, b) ->
-    (M, b); rows past the groups' total are not defined."""
+    (M, b); rows past the groups' total are not defined. ``choice``: what else
+    the caller chose for these products, counted with their path."""
     def tile(n, want):
         """The widest listed tile that divides ``n``; a width that only 128 divides (1,408 = 11 x 128) is its own tile
         while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work."""
@@ -202,7 +203,7 @@ def _grouped(xs, w, group_sizes, kernel: bool):
 
     tiling = (tile(xs.shape[0], 256), tile(w.shape[1], 768), tile(w.shape[2], 1024))
     kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
-    with region("ffn/experts", path="kernel" if kernel else "xla"):  # the choice, counted where it is made
+    with region("ffn/experts", path="kernel" if kernel else "xla", **choice):  # the choice, counted where it is made
         if not kernel:
             return jax.lax.ragged_dot(xs, w, group_sizes)
         from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -282,9 +283,14 @@ def _back_to_tokens_bwd(res, dout):
 _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
 
 
-def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True):
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # an expert's gate, by ``act``: SwiGLU's, ReGLU's
+
+
+def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True, act: str = "silu"):
     """The part of a routed FFN that the experts ``first .. first + n`` add
-    (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (silu(x wg) * x wi)``), for
+    (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (act(x wg) * x wi)``, ``act``
+    the configuration's: ``silu``, or ``relu`` for ReGLU experts; the one line
+    below where it is applied is differentiated by autodiff), for
     tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts, a
     token's ``k`` experts distinct (``lax.top_k``'s).
 
@@ -332,10 +338,11 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, group_sizes, spans))
     with region("ffn/rows", path="kernel" if tiled else "xla"):  # how a token sums its rows: the choice, counted where it is made
         xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
+    said = {} if act == "silu" else {"act": act}  # every older configuration's series are what they were
     with region("ffn/experts"):
-        gate, up = keep(_grouped(xs, wg, group_sizes, kernel)), keep(_grouped(xs, wi, group_sizes, kernel))
-        hidden = (jax.nn.silu(gate) * up).astype(xs.dtype)
-        product = _grouped(hidden, wo, group_sizes, kernel)
+        gate, up = keep(_grouped(xs, wg, group_sizes, kernel, **said)), keep(_grouped(xs, wi, group_sizes, kernel, **said))
+        hidden = (GATES[act](gate) * up).astype(xs.dtype)
+        product = _grouped(hidden, wo, group_sizes, kernel, **said)
     with region("ffn/rows"):
         ys = keep(jnp.where(row_ok, product, 0))
         out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)
@@ -343,7 +350,7 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 
-def _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel):
+def _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act):
     """``routed_part``'s fallback: ``held_experts`` with a buffer that holds
     every pair. As ``_every_pair`` it keeps NOTHING for its backward but its
     operands, which the conditional's caller holds anyway: if the branch is
@@ -353,19 +360,19 @@ def _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel):
     products, each of every pair's row count), the usual branch wrote zeros
     for, a layer and a step."""
     with region("branch/every_pair"):
-        return held_experts(tokens, idx, weights, wg, wi, wo, first, idx.size, kernel, named=False)
+        return held_experts(tokens, idx, weights, wg, wi, wo, first, idx.size, kernel, named=False, act=act)
 
 
-_every_pair = jax.custom_vjp(_hold_every_pair, nondiff_argnums=(7,))
+_every_pair = jax.custom_vjp(_hold_every_pair, nondiff_argnums=(7, 8))
 
 
-def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, kernel):
-    return _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel), (tokens, idx, weights, wg, wi, wo, first)
+def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, kernel, act):
+    return _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act), (tokens, idx, weights, wg, wi, wo, first)
 
 
-def _every_pair_bwd(kernel, res, cotangents):
+def _every_pair_bwd(kernel, act, res, cotangents):
     tokens, idx, weights, wg, wi, wo, first = res
-    _, back = jax.vjp(lambda t, w, g, i, o: _hold_every_pair(t, idx, w, g, i, o, first, kernel)[0], tokens, weights, wg, wi, wo)
+    _, back = jax.vjp(lambda t, w, g, i, o: _hold_every_pair(t, idx, w, g, i, o, first, kernel, act)[0], tokens, weights, wg, wi, wo)
     d_tokens, d_weights, d_wg, d_wi, d_wo = back(cotangents[0])  # the four counts take no cotangent
     return d_tokens, None, d_weights, d_wg, d_wi, d_wo, None
 
@@ -373,7 +380,7 @@ def _every_pair_bwd(kernel, res, cotangents):
 _every_pair.defvjp(_every_pair_fwd, _every_pair_bwd)
 
 
-def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool):
+def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool, act: str = "silu"):
     """``held_experts`` with a buffer that follows the load: four times the
     pairs a uniform router sends to ``n`` of ``num_experts`` experts, and,
     chosen on the device when more arrive, every pair there is. No pair
@@ -401,7 +408,7 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
 
     def held():
         with region("branch/usual"):
-            return held_experts(tokens, idx, weights, wg, wi, wo, first, usual, kernel)
+            return held_experts(tokens, idx, weights, wg, wi, wo, first, usual, kernel, act=act)
 
     if usual == every:
         return *held(), jnp.zeros((), jnp.int32)
@@ -411,4 +418,4 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
         local = idx - first
         routed = jnp.sum((local >= 0) & (local < n))
         fits = routed <= usual
-        return *jax.lax.cond(fits, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, kernel)), (~fits).astype(jnp.int32)
+        return *jax.lax.cond(fits, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act)), (~fits).astype(jnp.int32)

@@ -31,6 +31,12 @@ class _Record:
         """The kernels' tile along a sequence of ``seq``: ``divides(n, want)``, the largest tile that divides n."""
         return divides(seq, want)
 
+    def walk_labels(self, tiles: str) -> dict:
+        """What a forward call under this mask adds to its count (``program_regions_traced_total{region="mixer/kernel"}``),
+        given ``tiles``, "visited/of the square" at the call's shapes: nothing, for a mask whose walk is the whole or half
+        the square; a mask with a walk of its own says it, so a walk that visits more shows without a capture."""
+        return {}
+
 
 @dataclass(frozen=True)
 class Full(_Record):
@@ -60,6 +66,9 @@ class Causal(_Record):
         if self.window > 0:
             mask = mask & (cols > rows - self.window)
         return mask
+
+    def walk_labels(self, tiles: str) -> dict:
+        return {"window_tiles": tiles} if self.window > 0 else {}  # a band's walk, under a label of its own
 
     def kv_runs(self, qi, *, bq, bk, seq_q, seq_k):
         """Only a block that crosses the diagonal (or, with a window, the window's far edge) has a masked element; the
@@ -172,6 +181,9 @@ class BlockDiffusion(_Record):
     @property
     def pairs(self) -> int:
         return self.seq_len**2 + self.seq_len * self.block
+
+    def walk_labels(self, tiles: str) -> dict:
+        return {"tiles": tiles, "pairs": str(self.pairs)}
 
 
 def of(causal: bool, window=None):

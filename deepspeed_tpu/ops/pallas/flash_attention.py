@@ -306,12 +306,9 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_a
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
-    # a mask with a walk of its own also says, static at trace time, what the walk visits: a walk that visits more
-    # shows on the trainer's first-call line without a capture (``tiles`` = visited/of the square, ``pairs`` = kept)
-    walk = {}
-    if mask.op != "flash":
-        visited = masks.tiles_visited(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)
-        walk = {"tiles": f"{visited}/{(Sq // bq) * (Sk // bk)}", "pairs": str(mask.pairs)}
+    # a mask with a walk of its own also says, static at trace time, what the walk visits (``masks.py::walk_labels``): a
+    # walk that visits more shows on the trainer's first-call line without a capture
+    walk = mask.walk_labels(f"{masks.tiles_visited(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)}/{(Sq // bq) * (Sk // bk)}")
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block

@@ -54,7 +54,7 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(shape, bwd_path="fused"):
+def _flash(shape, bwd_path="fused", window=None):
     """Forward + vjp: 2 kernels, the forward and the fused backward; a head
     whose q, do and dq do not fit VMEM at once is refused while tracing."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -62,7 +62,7 @@ def _flash(shape, bwd_path="fused"):
     B, Sq, Hq, KVH, Dh = shape
 
     def fwd_bwd(q, k, v, do):
-        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, window=window), q, k, v)
         return (o,) + vjp(do)
 
     q = S((B, Sq, Hq, Dh), BF16)
@@ -261,6 +261,10 @@ CASES = {
     "flash_mha_b1_s8192_h16_d128": lambda: _flash((1, 8192, 16, 16, 128)),  # 24 MiB resident: fused, limit raised
     "flash_gqa_b1_s8192_h16_kvh2_d256": lambda: _flash((1, 8192, 16, 2, 256)),  # qwen3-next-80b-l4e32's full layer: a head at a time in the backward
     "flash_mha_b1_s32768_h2_d128": lambda: _flash((1, 32768, 2, 2, 128), "refused"),  # 77 MiB resident: over the budget
+    "flash_gqa_b1_s16384_h28_kvh4_d128": lambda: _flash((1, 16384, 28, 4, 128)),  # smallthinker-21b-l4e8's full layer: a head at a time in the backward
+    "flash_gqa_b1_s16384_h28_kvh4_d128_w4096": lambda: _flash((1, 16384, 28, 4, 128), window=4096),  # ... and its three window layers
+    "moe_sum_rows_t16384_d2560_e8_r49152": lambda: _moe_sum_rows((16384, 2560, 8, 49152)),  # ... and its routed layers, the usual buffer
+    "moe_sum_rows_t16384_d2560_e8_r98304": lambda: _moe_sum_rows((16384, 2560, 8, 98304)),  # ... and every pair
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
        for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},

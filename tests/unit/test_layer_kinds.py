@@ -137,7 +137,11 @@ KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
 # a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
 WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel",
         "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same",
-        "tiles_a_trip_fwd": "the attention is the flash kernel (tests/unit/test_pallas_ops.py, test_regions.py)", "tiles_a_trip_bwd": "the same"}
+        "tiles_a_trip_fwd": "the attention is the flash kernel (tests/unit/test_pallas_ops.py, test_regions.py)", "tiles_a_trip_bwd": "the same",
+        # (PR 53) softmax attention's kinds share one record, each key its own kind's; the window's walk is the kernel's
+        "full_path": "the layer is of the kind full", "window_path": "the layer is of the kind window", "window_keys": "the same",
+        "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)",
+        "moe_activation": "the experts' gate is relu (activation reglu)"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])
@@ -146,14 +150,14 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     block wrote, the names its values carry, the line keys whose counters rose, and what the stacked forms say."""
     kind = (name, "dense") if part == 0 else ("full", name)
     record, other = (table.MIXERS, table.FFNS)[part][name], (table.FFNS["dense"], table.MIXERS["full"])[part]
-    assert not other.sows and not other.paths and not other.hybrid and other.stackable
+    assert not other.sows and not other.hybrid and other.stackable  # (``full`` says how its own call was traced: ``full_path``)
     cfg = tiny(*kind)
     x, positions = jnp.zeros((2, 64, 32)), jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32), (2, 64))
     block = Block(cfg, kind)
     mixer = table.MIXERS[kind[0]]  # the values between blocks are a mixer's
     taken = _taken_by(mixer, cfg, x, positions)
     params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, positions, None, None, taken))["params"]
-    assert {n for n in params if "Norm" not in n} == {{"full": "attn", "window": "attn", "dense": "mlp"}.get(n, n) for n in kind}  # its name in the tree
+    assert {n for n in params if "Norm" not in n} == {{"full": "attn", "window": "attn", "nope": "attn", "dense": "mlp", "routed_early": "routed"}.get(n, n) for n in kind}  # its name in the tree
     run = lambda p, x: block.apply({"params": p}, x, positions, None, None, taken, mutable=_SOWN)
     if mixer.gives:  # the block's result is then (activations, the values by name): exactly the names the record gives
         given = jax.eval_shape(run, params, x)[0][1]
@@ -169,7 +173,7 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     assert _names(jaxpr.jaxpr, set()) <= declared and declared - _names(jaxpr.jaxpr, set()) <= KERNELS_ALONE
     assert table.remat_keeps(kind) == (tuple(dict.fromkeys((record, other)[part].keeps + (other, record)[part].keeps + (SAVED,))) if record.hybrid else ())
     keys = set(record.paths) | set(record.joined)  # ``full`` beside an FFN rotates, and says so (``rope``)
-    assert rose - set(other.joined) <= keys and keys - rose <= set(WHEN)
+    assert rose - set(other.joined) - set(other.paths) <= keys and keys - rose <= set(WHEN)
     assert set(record.path_words) <= set(record.paths)
     # the stacked forms: the configuration's word, the pipeline's and the server's refusals
     assert cfg.unstackable == (() if record.stackable else (name,))
