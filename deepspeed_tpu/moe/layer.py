@@ -22,7 +22,7 @@ from ..ops.registry import pallas_available
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
-from .sharded_moe import GATES, SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
+from .sharded_moe import GATES, RUNGS, SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
 
 class Experts(nn.Module):
@@ -142,7 +142,10 @@ def _count_rows(rows):
     reg.counter("moe_rows_dropped_total").inc(float(rows[:, 1].sum()))
     reg.gauge("moe_expert_rows_max").set(float(rows[:, 2].max()))
     reg.gauge("moe_expert_rows_min").set(float(rows[:, 3].min()))
-    reg.counter("moe_fallback_layers_total").inc(float(rows[:, 4].sum()))
+    reg.counter("moe_fallback_layers_total").inc(float((rows[:, 4] > 0).sum()))  # the first rung did not hold
+    for rung, name in enumerate(RUNGS):
+        reg.counter("moe_buffer_rung_layers_total", rung=name).inc(float((rows[:, 4] == rung).sum()))
+    reg.gauge("moe_rows_over_uniform_max").set(float(rows[:, 5].max()) / 1000)
 
 
 def report_rows(intermediates):
@@ -196,8 +199,9 @@ class RoutedMoE(nn.Module):
     dtype: Any = jnp.float32
     act: str = "silu"  # the experts' gate (``sharded_moe.GATES``)
     # its record as the layer kind ``routed`` (as ``MoE`` says its own). The line's keys: how the grouped products and the
-    # rows' sum were traced, the conditional's form where the buffer is smaller than every pair (``routed_part``), and how
-    # the router scores its tokens and indexes the expert axis (``compare_sum``: ``held_experts``)
+    # rows' sum were traced, the conditional's form where the buffer's first rung is smaller than every pair
+    # (``routed_part``: the rungs above it keep nothing), and how the router scores its tokens and indexes the expert axis
+    # (``compare_sum``: ``held_experts``)
     sows, keeps, hybrid, alone, stackable, takes = ("intermediates",), (SAVED,), True, False, False, ()
     paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
     path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
@@ -235,8 +239,8 @@ class RoutedMoE(nn.Module):
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
         out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, moe_path() == "kernel", self.act)
-        # (routed here, of them not computed, largest group, smallest group, whether the branch that holds every pair
-        # ran), sown: ``report_rows`` hands them on
+        # (routed here, of them not computed, largest group, smallest group, the buffer's rung, routed here over the
+        # uniform load in thousandths), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
         if self.shared_ff:
             with region("ffn/shared", **({"path": "gated"} if self.shared_gate else {})):
@@ -268,9 +272,15 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
     from ..parallel.mesh import get_mesh_topology
     from ..runtime.zero.partition import fit_spec, prune_spec
 
+    def part(tokens, idx, weights, wg, wi, wo, first):
+        """``routed_part``, and the pairs it found routed here over a uniform router's, in thousandths."""
+        out, routed, *counts = routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
+        uniform = idx.size * wg.shape[0] / num_experts
+        return out, routed, *counts, jnp.round(routed.astype(jnp.float32) * (1000 / uniform)).astype(jnp.int32)
+
     topo = get_mesh_topology(required=False)
     if topo is None or topo.n_devices == 1:
-        return routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
+        return part(tokens, idx, weights, wg, wi, wo, first)
     axis = topo.axis_size("expert")
     rows = fit_spec(prune_spec(P(topo.batch_axes, None), topo), tokens.shape, topo)
     held = P("expert", None, None) if axis > 1 and wg.shape[0] % axis == 0 else P()
@@ -279,15 +289,16 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
 
     def local(tokens, idx, weights, wg, wi, wo):
         mine = first + (jax.lax.axis_index("expert") * wg.shape[0] if held != P() else 0)
-        out, routed, dropped, largest, smallest, fallback = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel, act)
+        out, routed, dropped, largest, smallest, rung, over_uniform = part(tokens, idx, weights, wg, wi, wo, mine)
         if held != P():
             out = jax.lax.psum(out, "expert")
-        if over:
-            routed, dropped, fallback = (jax.lax.psum(x, over) for x in (routed, dropped, fallback))
-            largest, smallest = jax.lax.pmax(largest, over), jax.lax.pmin(smallest, over)
-        return out, routed, dropped, largest, smallest, fallback
+        if over:  # the rung and the load are the fullest shard's: a (layer, step) pair counts once
+            routed, dropped = (jax.lax.psum(x, over) for x in (routed, dropped))
+            largest, rung, over_uniform = (jax.lax.pmax(x, over) for x in (largest, rung, over_uniform))
+            smallest = jax.lax.pmin(smallest, over)
+        return out, routed, dropped, largest, smallest, rung, over_uniform
 
-    return on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 5)(tokens, idx, weights, wg, wi, wo)
+    return on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
 
 
 def _mesh_has_axis(axis: str) -> bool:

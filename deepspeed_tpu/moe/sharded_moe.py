@@ -10,6 +10,7 @@ explicit ``all_to_all_single`` calls needed under GSPMD (the shard_map
 path in ``layer.py`` shows the explicit-collective equivalent).
 """
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -305,8 +306,9 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     sum of its rows' gradients, is read off those spans a tile at a time, and
     only the rows routed here are read; elsewhere every pair's row is
     gathered by the inverse order into (N, k, d), masked and summed over k.
-    ``rows`` bounds the buffer, not the routing: the caller gives one that
-    holds every pair routed here (``routed_part``). ``named``: whether the
+    ``rows`` bounds the buffer, not the routing, and every pass between the
+    grouped products runs over all of it: the caller gives the smallest of a
+    ladder that holds every pair routed here (``routed_part``). ``named``: whether the
     order, the rows and the products carry their names for a checkpoint
     policy; without them a block under ``jax.checkpoint`` keeps nothing of
     this call and makes it again in its backward. Returns ((N, d), pairs
@@ -350,61 +352,114 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 
-def _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act):
-    """``routed_part``'s fallback: ``held_experts`` with a buffer that holds
-    every pair. As ``_every_pair`` it keeps NOTHING for its backward but its
-    operands, which the conditional's caller holds anyway: if the branch is
-    ever taken, its backward makes the call again and differentiates it
-    there. A ``lax.cond`` under differentiation hands on the residuals of
-    BOTH its branches, so whatever this one kept (its sorted rows and grouped
-    products, each of every pair's row count), the usual branch wrote zeros
-    for, a layer and a step."""
-    with region("branch/every_pair"):
-        return held_experts(tokens, idx, weights, wg, wi, wo, first, idx.size, kernel, named=False, act=act)
+def _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
+    """``routed_part``'s fallback, which sizes itself: ``held_experts`` at
+    the smallest of ``rungs`` (buffer sizes, ascending, the last one every
+    pair) that holds the pairs routed here, chosen on the device. As
+    ``_every_pair`` it keeps NOTHING for its backward but its operands, which
+    the conditional's caller holds anyway: if the branch is ever taken, its
+    backward picks the same rung from the same count, makes that rung's call
+    again and differentiates it there (``_unkept_back``: a ``jax.vjp`` inside
+    each arm of the choice, so no conditional is differentiated and no rung
+    writes zeros for another's residuals). A ``lax.cond`` under
+    differentiation hands on the residuals of BOTH its branches, so whatever
+    this one kept (its sorted rows and grouped products, each of a larger
+    rung's row count), the first rung wrote zeros for, a layer and a step."""
+    return _at_rung(idx, first, wg.shape[0], rungs,
+                    lambda rows: _unkept(tokens, idx, weights, wg, wi, wo, first, rows=rows, kernel=kernel, act=act))
 
 
-_every_pair = jax.custom_vjp(_hold_every_pair, nondiff_argnums=(7, 8))
+def _at_rung(idx, first, n: int, rungs, run):
+    """``run(rows)`` at the smaller of the one or two ``rungs`` that holds the
+    pairs ``idx`` routes to the experts ``first .. first + n``."""
+    # one tracing context for the jitted arms, whoever calls (``_sum_rows`` says why)
+    with region("branch/every_pair"), jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        if len(rungs) == 1:
+            return run(rungs[0])
+        return jax.lax.cond(_routed_here(idx, first, n) <= rungs[0], lambda: run(rungs[0]), lambda: run(rungs[1]))
 
 
-def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, kernel, act):
-    return _hold_every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act), (tokens, idx, weights, wg, wi, wo, first)
+def _routed_here(idx, first, n: int):
+    """How many of the pairs ``idx`` go to the experts ``first .. first + n``."""
+    local = idx - first
+    return jnp.sum((local >= 0) & (local < n))
 
 
-def _every_pair_bwd(kernel, act, res, cotangents):
+# The fallback's arms are jitted for the trace's sake, not the program's (the compiler inlines them): a rung's
+# forward is wanted by the fallback's value, by its rule's forward and by every kind of block that has a routed layer, its
+# backward by each of those kinds, and a trace of ``held_experts`` with its kernels is 0.3-0.5 s on the chip's host. Traced
+# and lowered once a shape and a rung for the whole model, whatever the rungs above the first cost a step that takes them
+@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act"))
+def _unkept(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act):
+    """``held_experts`` with nothing named for a checkpoint policy."""
+    return held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named=False, act=act)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act"))
+def _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangent, rows, kernel, act):
+    """``_unkept``'s forward again and its five gradients for the output's ``cotangent`` (its four counts take none)."""
+    part = lambda t, w, g, i, o: held_experts(t, idx, w, g, i, o, first, rows, kernel, named=False, act=act)[0]
+    return jax.vjp(part, tokens, weights, wg, wi, wo)[1](cotangent)
+
+
+_every_pair = jax.custom_vjp(_above_first, nondiff_argnums=(7, 8, 9))
+
+
+def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
+    return _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act), (tokens, idx, weights, wg, wi, wo, first)
+
+
+def _every_pair_bwd(rungs, kernel, act, res, cotangents):
     tokens, idx, weights, wg, wi, wo, first = res
-    _, back = jax.vjp(lambda t, w, g, i, o: _hold_every_pair(t, idx, w, g, i, o, first, kernel, act)[0], tokens, weights, wg, wi, wo)
-    d_tokens, d_weights, d_wg, d_wi, d_wo = back(cotangents[0])  # the four counts take no cotangent
+    back = lambda rows: _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangents[0], rows=rows, kernel=kernel, act=act)
+    d_tokens, d_weights, d_wg, d_wi, d_wo = _at_rung(idx, first, wg.shape[0], rungs, back)
     return d_tokens, None, d_weights, d_wg, d_wi, d_wo, None
 
 
 _every_pair.defvjp(_every_pair_fwd, _every_pair_bwd)
 
 
+def buffer_rungs(every: int, n: int, num_experts: int):
+    """The sizes ``routed_part``'s buffer takes, ascending: twice and four
+    times the pairs a uniform router sends to ``n`` of ``num_experts``
+    experts (``every * n / num_experts``), each rounded up to 512 rows and
+    capped at ``every``, the count of all pairs, which is the last."""
+    up = lambda times: min(every, -(-times * every * n // num_experts // 512) * 512)
+    return up(2), up(4), every
+
+
+RUNGS = ("first", "four", "every")  # the rung a layer took in a step, by ``routed_part``'s sixth value
+
+
 def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool, act: str = "silu"):
-    """``held_experts`` with a buffer that follows the load: four times the
-    pairs a uniform router sends to ``n`` of ``num_experts`` experts, and,
-    chosen on the device when more arrive, every pair there is. No pair
-    routed to a held expert is dropped at any imbalance.
+    """``held_experts`` with a buffer that follows the load, chosen on the
+    device from a ladder (``buffer_rungs``) by the count of pairs routed
+    here: twice the pairs a uniform router sends to ``n`` of ``num_experts``
+    experts, then four times, then every pair there is. No pair routed to a
+    held expert is dropped at any imbalance. Every XLA pass between the
+    grouped products runs over the buffer's static rows, so the first rung
+    is what a sound router's step pays for.
 
     A ``lax.cond`` under differentiation hands on the residuals of BOTH its
-    branches (zeros for the one not taken). So the usual branch alone names
-    what a checkpointed block keeps, and the branch that holds every pair
-    keeps nothing at all (``_every_pair``): the conditional's residuals are
-    the usual branch's and the fallback's operands, and nothing of every
-    pair's row count is written for a branch that a sound router takes in
-    a step out of hundreds, if ever. What the fallback costs when it does
-    run: its forward a second time inside its backward. Under
-    ``jax.checkpoint`` (the block's) that is what an unnamed branch cost
-    before; without, it is a slower backward in a step whose router sent
-    more than four times the uniform load to the held experts.
-    ``moe_fallback_layers_total`` counts such steps.
+    branches (zeros for the one not taken). So there is ONE conditional that
+    is differentiated, the first rung alone names what a checkpointed block
+    keeps, and the branch above it keeps nothing at all and chooses between
+    the two larger buffers inside itself, forward and backward
+    (``_every_pair``): the conditional's residuals are the first rung's and
+    the fallback's operands, and nothing of a larger row count is written
+    for a branch that a sound router takes in a step out of hundreds, if
+    ever. What the fallback costs when it does run: its forward a second
+    time inside its backward. Under ``jax.checkpoint`` (the block's) that is
+    what an unnamed branch costs anyway; without, it is a slower backward in
+    a step whose router sent more than twice the uniform load to the held
+    experts. ``moe_fallback_layers_total`` counts such (layer, step) pairs,
+    ``moe_buffer_rung_layers_total{rung}`` each rung's.
 
-    Returns ``held_experts``'s five values and whether the fallback ran
-    (0 or 1)."""
+    Returns ``held_experts``'s five values and the rung taken (0, 1 or 2:
+    ``RUNGS``; 1 where four times the uniform load is every pair)."""
     N, k = idx.shape
     n = wg.shape[0]
-    every = N * k
-    usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
+    usual, four, every = buffer_rungs(N * k, n, num_experts)
 
     def held():
         with region("branch/usual"):
@@ -412,10 +467,10 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
 
     if usual == every:
         return *held(), jnp.zeros((), jnp.int32)
+    above = (four, every) if usual < four < every else (every,)
     # what the conditional itself adds (whatever one branch writes for the other's residuals) has no inner region and so
     # falls to ``ffn/cond``; the branches' scopes tell their ``ffn/rows`` and ``ffn/experts`` apart
     with region("ffn/cond", path="fallback_keeps_nothing"):
-        local = idx - first
-        routed = jnp.sum((local >= 0) & (local < n))
-        fits = routed <= usual
-        return *jax.lax.cond(fits, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, kernel, act)), (~fits).astype(jnp.int32)
+        routed = _routed_here(idx, first, n)
+        rung = (routed > usual).astype(jnp.int32) + (routed > four).astype(jnp.int32)
+        return *jax.lax.cond(routed <= usual, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, above, kernel, act)), rung

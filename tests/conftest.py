@@ -42,6 +42,22 @@ def _reset_global_state():
     reset_mesh()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _routed_rows_counters_left_as_found():
+    """The routed layers' counters are the process's, and a benchmark reader that is handed no counter falls back to the
+    process's totals (``benchmarks/lib/program.py::counter``): on a worker that ran a module with a routed layer first,
+    ``tests/benchmarks/test_benchmark_hybrid.py`` and ``test_benchmark_latent.py`` read that module's rows where they
+    expect none. Which modules share a worker follows their run times, so every module leaves them as it found them."""
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    names = ("moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_fallback_layers_total")
+    found = {name: get_registry().peek(name) for name in names}
+    yield
+    for name, value in found.items():
+        if get_registry().peek(name) != value:
+            get_registry().counter(name).value = value or 0.0
+
+
 @pytest.fixture
 def mesh8():
     """A pipe=1, data=8 default mesh over the 8 faked devices."""

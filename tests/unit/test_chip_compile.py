@@ -238,7 +238,9 @@ def _indexed(which):
 
 CASES = {
     "moe_sum_rows_t8192_d2048_e8_r24576": lambda: _moe_sum_rows((8192, 2048, 8, 24576)),  # kimi-vl-a3b-l6e8's routed layers, the usual buffer
+    "moe_sum_rows_t8192_d2048_e8_r12288": lambda: _moe_sum_rows((8192, 2048, 8, 12288)),  # ... and since PR 54 the ladder's first rung, twice the uniform load
     "moe_sum_rows_t8192_d2304_e8_r8192": lambda: _moe_sum_rows((8192, 2304, 8, 8192)),    # kimi-linear-48b-l5e8's
+    "moe_sum_rows_t8192_d2304_e8_r4096": lambda: _moe_sum_rows((8192, 2304, 8, 4096)),    # ... its first rung
     "moe_sum_rows_t8192_d2304_e8_r65536": lambda: _moe_sum_rows((8192, 2304, 8, 65536)),  # ... and the buffer of every pair (the cond's other branch)
     "moe_sum_rows_t8192_d2048_e32_r20480": lambda: _moe_sum_rows((8192, 2048, 32, 20480)),  # qwen3-next-80b-l4e32's: windows of 32 rows
     "moe_sum_rows_t8192_d2048_e32_r81920": lambda: _moe_sum_rows((8192, 2048, 32, 81920)),  # ... and every pair
@@ -264,6 +266,8 @@ CASES = {
     "flash_gqa_b1_s16384_h28_kvh4_d128": lambda: _flash((1, 16384, 28, 4, 128)),  # smallthinker-21b-l4e8's full layer: a head at a time in the backward
     "flash_gqa_b1_s16384_h28_kvh4_d128_w4096": lambda: _flash((1, 16384, 28, 4, 128), window=4096),  # ... and its three window layers
     "moe_sum_rows_t16384_d2560_e8_r49152": lambda: _moe_sum_rows((16384, 2560, 8, 49152)),  # ... and its routed layers, the usual buffer
+    "moe_sum_rows_t16384_d2560_e8_r24576": lambda: _moe_sum_rows((16384, 2560, 8, 24576)),  # ... the first rung
+    "moe_sum_rows_t16384_d2048_e16_r32768": lambda: _moe_sum_rows((16384, 2048, 16, 32768)),  # sdar-30b-a3b-l4e16's first rung
     "moe_sum_rows_t16384_d2560_e8_r98304": lambda: _moe_sum_rows((16384, 2560, 8, 98304)),  # ... and every pair
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls

@@ -333,9 +333,10 @@ def test_no_row_is_dropped_when_every_token_picks_one_held_expert(highest):
     params = layer.init(jax.random.PRNGKey(9), h)["params"]
     params = dict(params, select_bias=params["select_bias"].at[5].set(10.0))
     out, sown = jax.jit(lambda p: layer.apply({"params": p}, h, mutable=["intermediates"]))(params)
-    routed, dropped, largest, _, fallback = (int(v) for v in sown["intermediates"]["rows"][0])
+    routed, dropped, largest, _, rung, over_uniform = (int(v) for v in sown["intermediates"]["rows"][0])
     assert routed >= 3 * 32 and dropped == 0  # every token's pair for expert 5, and whatever else fell on 4..7
-    assert largest == 3 * 32 and fallback == 0  # all 96 tokens in one group; every pair is 384, and so is the usual buffer: no conditional
+    assert largest == 3 * 32 and rung == 0  # all 96 tokens in one group; every pair is 384, and so is the first rung: no conditional
+    assert over_uniform == round(1000 * routed / 96)  # a uniform router sends 384 x 4 / 16 pairs here
     _close(out, ref.routed(params, h, 4, cfg.moe_top_k, cfg.moe_route_scale))
 
 
@@ -384,7 +385,7 @@ def test_five_layers_trace_three_blocks(highest):
     assert "callback" not in compiled.as_text()  # nothing the persistent compile cache would refuse to keep
     _, reported = compiled(params)
     assert reg.peek("program_regions_traced_total", region="block", site="train") - before == 3
-    assert reported["moe_rows"].shape == (4, 5) and reg.peek("moe_rows_routed_here_total") in (None, rows[0])  # not yet counted
+    assert reported["moe_rows"].shape == (4, 6) and reg.peek("moe_rows_routed_here_total") in (None, rows[0])  # not yet counted
     device_counts.count(reported)
     # the four routed layers' rows leave the program as an output: 32 tokens x 4 choices, 8 of 16 held
     assert 4 * 32 <= reg.peek("moe_rows_routed_here_total") - rows[0] <= 4 * 32 * 4 and reg.peek("moe_rows_dropped_total") == rows[1]
@@ -524,15 +525,17 @@ def test_a_buffer_too_small_shows_as_dropped_rows():
     _, routed, dropped, largest, smallest = held_experts(tokens, idx, weights, wg, wi, wo, 0, 768, False)
     assert (int(routed), int(dropped), int(largest), int(smallest)) == (1024, 256, 512, 512)
     _, routed, dropped, _, _, fallback = jax.jit(lambda *a: routed_part(*a, 0, 64, False))(tokens, idx, weights, wg, wi, wo)
-    assert (int(routed), int(dropped), int(fallback)) == (1024, 0, 1)  # 32 x the uniform load, past the usual buffer of 512: the branch that holds every pair
+    assert (int(routed), int(dropped), int(fallback)) == (1024, 0, 2)  # 32 x the uniform load, past the 512 rows that are the first rung and four times the load alike: the rung that holds every pair
 
 
 @pytest.mark.parametrize("held_biased,fallbacks", [((4, 5), 1), ((), 0)], ids=["every_pair", "usual"])
 def test_a_step_that_takes_the_fallback_is_counted_a_layer(held_biased, fallbacks, highest):
-    """``moe_fallback_layers_total``: 2 of 16 held at 2 a token, a usual buffer of 512 rows under 1,024 pairs. A router
-    biased to both held experts sends every pair here and the branch that holds every pair runs: one more (layer,
-    step), read off the ``rows`` the layer sows as the other four counts are; an unbiased one stays in the usual
-    buffer and counts nothing. No row dropped either way."""
+    """``moe_fallback_layers_total``: 2 of 16 held at 2 a token, a first rung of 512 rows (twice the uniform load and
+    four times it round to the same buffer) under 1,024 pairs. A router biased to both held experts sends every pair
+    here and the rung that holds every pair runs: one more (layer, step), read off the ``rows`` the layer sows as the
+    other counts are, and one more of ``moe_buffer_rung_layers_total{rung="every"}``; an unbiased one stays on the first
+    rung, counts no fallback and one more of ``{rung="first"}``. No row dropped either way, and
+    ``moe_rows_over_uniform_max`` is the pairs that arrived over the 128 of a uniform router."""
     from deepspeed_tpu.moe.layer import report_rows
     from deepspeed_tpu.telemetry import device_counts
 
@@ -551,10 +554,14 @@ def test_a_step_that_takes_the_fallback_is_counted_a_layer(held_biased, fallback
 
     reg, names = get_registry(), ("moe_fallback_layers_total", "moe_rows_dropped_total", "moe_rows_routed_here_total")
     before = [reg.peek(n) or 0.0 for n in names]
+    rungs = lambda: [reg.peek("moe_buffer_rung_layers_total", rung=r) or 0.0 for r in ("first", "four", "every")]
+    rungs_before = rungs()
     out, reported = jax.jit(layer_and_counts)(params)
     device_counts.count(reported)
     rose = [(reg.peek(n) or 0.0) - b for n, b in zip(names, before)]
     assert rose[:2] == [fallbacks, 0] and (rose[2] == 1024 if fallbacks else 0 < rose[2] <= 512)
+    assert [now - was for now, was in zip(rungs(), rungs_before)] == [1 - fallbacks, 0, fallbacks]
+    assert reg.peek("moe_rows_over_uniform_max") == pytest.approx(rose[2] / 128, abs=1e-3)
     _close(out, ref.routed(params, h, 4, 2, cfg.moe_route_scale))
 
 

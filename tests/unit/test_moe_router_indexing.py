@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.moe.sharded_moe import _renormalised, _rows_a_group, held_experts, routed_part, sigmoid_topk, softmax_topk
-from tests.unit.test_moe_sum_rows import FIRST, HELD, ROUTINGS, E, N, _operands
+from tests.unit.test_moe_sum_rows import FIRST, HELD, K_LADDER, LADDER, ON_RUNG, ROUTINGS, E, N, _operands
 from tests.unit.test_remat_keeps import _equations
 
 INDEXING = {"gather", "scatter", "scatter-add"}
@@ -38,7 +38,7 @@ def test_a_routers_value_and_gradient_hold_no_gather_and_no_scatter(scoring):
     assert {"gather", "scatter-add"} <= found, found
 
 
-@pytest.mark.parametrize("part", ["held_experts, gathered rows", "held_experts, tiled rows", "routed_part, both branches"])
+@pytest.mark.parametrize("part", ["held_experts, gathered rows", "held_experts, tiled rows", "routed_part, both branches", "routed_part, three rungs"])
 def test_the_routed_parts_bookkeeping_indexes_by_comparison(part):
     """Forward and backward of the routed part: nothing under ``ffn/router`` indexes; the sorts are there; every
     indexing equation of the whole lies under ``ffn/rows`` or, with the kernels (interpreted off the TPU), in the
@@ -47,7 +47,8 @@ def test_the_routed_parts_bookkeeping_indexes_by_comparison(part):
     if part.startswith("held_experts"):
         call = lambda *a: held_experts(a[0], idx, *a[1:], FIRST, N * K, part.endswith("tiled rows"))[0]
     else:
-        call = lambda *a: routed_part(a[0], idx, *a[1:], FIRST, 4 * E, False)[0]
+        # 4 of 16: four times the uniform load is every pair, the fallback has one buffer; 4 of 64: it chooses between two
+        call = lambda *a: routed_part(a[0], idx, *a[1:], FIRST, 4 * E if part.endswith("three rungs") else E, False)[0]
     jaxpr = jax.make_jaxpr(jax.value_and_grad(lambda *a: jnp.sum(call(*a) ** 2), argnums=(0, 1, 2, 3, 4)))(*operands).jaxpr
     router = [eqn.primitive.name for eqn, stack in _equations(jaxpr) if "ffn/router" in stack]
     assert router.count("sort") >= 2 and not _indexing(jaxpr, "ffn/router"), _indexing(jaxpr, "ffn/router")
@@ -106,16 +107,76 @@ def test_the_rows_a_group_under_an_expert_axis_are_each_chips_own_experts(axis):
 
 
 def test_the_form_is_counted_once_a_traced_held_experts_and_is_a_word_of_the_first_call_line():
-    """``program_regions_traced_total{region="ffn/router", path="compare_sum"}``: one a traced ``held_experts``, two a
-    traced ``routed_part`` with a conditional (its usual branch and the one that holds every pair); the trainer's
-    first-call line joins it to the scoring as ``moe_router`` (``runtime/engine.py::_ROUTER_WORDS``)."""
+    """``program_regions_traced_total{region="ffn/router", path="compare_sum"}``: one a traced ``held_experts``; a traced
+    ``routed_part`` with a conditional counts its first rung each time and the rungs above it once a shape for the
+    process (the fallback's arms are jitted: a second kind of block with a routed layer of the same shapes traces the
+    first rung alone); the trainer's first-call line joins it to the scoring as ``moe_router``
+    (``runtime/engine.py::_ROUTER_WORDS``)."""
     from deepspeed_tpu.runtime import engine
 
     rose = lambda before: engine._paths_traced()["moe_router"][engine._ROUTER_WORDS.index("compare_sum")] - before
-    idx, (operands, _) = ROUTINGS["uniform"](K), _operands(K)
-    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in operands]
+    idx = ROUTINGS["uniform"](K)
+    d = 3 * 128  # a width no other test of the process traces the fallback's arms at
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((N, d), (N, K), (HELD, d, 128), (HELD, d, 128), (HELD, 128, d))]
     before = rose(0)
     jax.eval_shape(lambda *a: held_experts(a[0], idx, *a[1:], FIRST, N * K, False), *shapes)
     assert rose(before) == 1
     jax.eval_shape(lambda *a: routed_part(a[0], idx, *a[1:], FIRST, 4 * E, False), *shapes)
-    assert rose(before) == 3
+    assert rose(before) == 4  # 4 of 64: the first rung, and the arms at four times the uniform load and at every pair
+    jax.eval_shape(lambda *a: routed_part(a[0], idx, *a[1:], FIRST, 4 * E, False), *shapes)
+    assert rose(before) == 5  # the first rung again; the arms are traced already
+    jax.eval_shape(lambda *a: routed_part(a[0], idx, *a[1:], FIRST, E, False), *shapes)
+    assert rose(before) == 6  # 4 of 16: four times the uniform load is every pair, whose arm is traced already
+
+
+@pytest.mark.parametrize("axis", [1, 2], ids=["one_device", "expert_axis_of_2"])
+@pytest.mark.parametrize("rung", list(ON_RUNG))
+def test_the_rung_a_layer_took_and_its_load_reach_the_registry(rung, axis):
+    """What a routed layer sows as ``rows`` for a crafted routing a rung (``tests/unit/test_moe_sum_rows.py::ON_RUNG``),
+    handed out of a jitted program and counted on the host: ``moe_buffer_rung_layers_total{rung}`` rises by one for the
+    rung that was crafted and by none for the others, ``moe_fallback_layers_total`` by one above the first rung
+    whichever it was, ``moe_rows_over_uniform_max`` is the pairs routed here over the 341.3 of a uniform router, and no
+    row is dropped. Under an ``expert`` axis each chip holds two of the four experts and has its own ladder (512, 1,024,
+    4,096 rows against a uniform 170.7): the pair counts once, at the rung and the load of its fullest chip."""
+    from deepspeed_tpu.moe.layer import _over_expert_axis, report_rows
+    from deepspeed_tpu.moe.sharded_moe import RUNGS
+    from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+    from deepspeed_tpu.telemetry import device_counts
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    idx, _, pairs, taken = ON_RUNG[rung]
+    operands, _ = _operands(K_LADDER, seed=5)
+    # a chip's experts 2, 3 or 4, 5: the fuller one has every token's pair for each of its experts that the routing names
+    fullest = 512 if rung == "under_first" else 1024
+    taken, load = (taken, pairs * 3 / 1024) if axis == 1 else (int(fullest > 512), fullest * 3 / 512)
+
+    def counted(*a):
+        with device_counts.collecting() as reported:
+            out, *counts = _over_expert_axis(a[0], idx, *a[1:], FIRST, LADDER, False)
+            report_rows({"layer": {"rows": (jnp.stack(counts).astype(jnp.int32),)}})
+        return out, reported
+
+    reg = get_registry()
+    read = lambda: [reg.peek("moe_buffer_rung_layers_total", rung=r) or 0.0 for r in RUNGS] + \
+        [reg.peek(n) or 0.0 for n in ("moe_fallback_layers_total", "moe_rows_dropped_total", "moe_rows_routed_here_total")]
+    before = read()
+    reset_mesh()
+    try:
+        if axis > 1:
+            topo = initialize_mesh(MeshConfig.from_dict({"expert": axis}), devices=jax.devices()[:axis], force=True)
+            with topo.mesh:
+                out, reported = jax.jit(counted)(*operands)
+        else:
+            out, reported = jax.jit(counted)(*operands)
+    finally:
+        reset_mesh()
+    device_counts.count(reported)
+    rose = [now - was for now, was in zip(read(), before)]
+    # left as found: a benchmark reader that is handed no counter falls back to the process's totals (``tests/unit/test_hybrid_layers.py``)
+    for name, found in zip(("moe_fallback_layers_total", "moe_rows_dropped_total", "moe_rows_routed_here_total"), before[3:]):
+        reg.counter(name).value = found
+    assert rose == [float(taken == r) for r in range(3)] + [float(taken > 0), 0.0, float(pairs)]
+    assert reg.peek("moe_rows_over_uniform_max") == pytest.approx(load, abs=1e-3)
+    want = held_experts(operands[0], idx, *operands[1:], FIRST, N * K_LADDER, False)[0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5 * float(jnp.max(jnp.abs(want))))

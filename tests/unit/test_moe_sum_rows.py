@@ -6,11 +6,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.moe.sharded_moe import SAVED, held_experts, routed_part
+from deepspeed_tpu.moe.sharded_moe import SAVED, buffer_rungs, held_experts, routed_part
 from deepspeed_tpu.ops.pallas import moe_sum_rows
 from deepspeed_tpu.telemetry.registry import get_registry
 
 N, D, F, E, HELD, FIRST = 512, 256, 128, 16, 4, 2  # two token tiles; experts 2..5 of 16 held
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compiled_programs_dropped():
+    """These tests compile some hundred large CPU programs (conditionals whose branches hold interpreted kernels). A
+    process that had run ``test_moe_router_indexing.py`` and ``test_regions.py`` before them died inside XLA's CPU
+    compiler (a segmentation fault in ``backend_compile_and_load``, at whichever test came next once enough was
+    compiled; not with what the process held dropped first), so it is dropped before this module and after it."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _uniform(k):
@@ -46,12 +57,20 @@ def _operands(k, seed=0):
     return (tokens, weights, wg, wi, wo), jax.random.normal(jax.random.fold_in(key, 9), (N, D))
 
 
-def _value_and_grads(idx, rows, kernel, operands, cot, part=held_experts):
+def _value_and_grads(idx, rows, kernel, operands, cot, part=held_experts, experts=4 * E):
+    """Output and the five gradients, pairs routed here, pairs dropped and whatever else ``part`` counts: ONE compiled
+    program a call (run op by op, a conditional whose branches hold interpreted kernels is a large program of its own
+    for its value and another for its gradient)."""
     call = (lambda *a: held_experts(a[0], idx, *a[1:], FIRST, rows, kernel)) if part is held_experts else \
-        (lambda *a: routed_part(a[0], idx, *a[1:], FIRST, 4 * E, kernel))
-    out, routed, dropped, *_ = call(*operands)
-    grads = jax.grad(lambda *a: jnp.sum(call(*a)[0] * cot), argnums=(0, 1, 2, 3, 4))(*operands)
-    return (out,) + grads, int(routed), int(dropped)
+        (lambda *a: part(a[0], idx, *a[1:], FIRST, experts, kernel))
+
+    @jax.jit
+    def both(*a):
+        out, back, counts = jax.vjp(lambda *b: (lambda o, *c: (o, c))(*call(*b)), *a, has_aux=True)
+        return (out,) + back(cot), counts
+
+    got, (routed, dropped, *rest) = both(*operands)
+    return got, int(routed), int(dropped), *(int(x) for x in rest[2:])
 
 
 def _same(got, want):
@@ -88,30 +107,68 @@ def test_a_buffer_that_drops_rows_sums_the_taken_ones_only():
     assert not np.asarray(got[0][384:]).any() and np.asarray(got[0][:384]).any()
 
 
-def test_both_branches_of_the_cond_take_the_kernel():
-    """``routed_part``: 4 of 64 held at 6 a token gives a usual buffer of 1,024 rows; every token picking one held
-    expert is 512 pairs (the usual branch), every token picking all four is 2,048 (the branch that holds every pair).
-    Same numbers as the gathers' either way."""
-    for idx, want_routed in ((_one_held_expert(6), 512), (jnp.broadcast_to(jnp.array([2, 3, 4, 5, 9, 10]), (N, 6)), 2048)):
-        operands, cot = _operands(6, seed=2)
-        got, routed, dropped = _value_and_grads(idx.astype(jnp.int32), None, True, operands, cot, part=routed_part)
-        want, *_ = _value_and_grads(idx.astype(jnp.int32), None, False, operands, cot, part=routed_part)
-        assert (routed, dropped) == (want_routed, 0)
-        _same(got, want)
+K_LADDER = 8
 
 
-ONE_HELD, ALL_HELD = _one_held_expert(6).astype(jnp.int32), jnp.broadcast_to(jnp.array([2, 3, 4, 5, 9, 10], jnp.int32), (N, 6))
-EVERY, USUAL = N * 6, 1024  # 4 of 64 held at 6 a token: 3,072 pairs, a usual buffer of 1,024 rows
+def _held_by(held, every=1, k=K_LADDER):
+    """``k`` experts a token: the first ``held`` of the held experts 2..5, then experts held elsewhere; with ``every``
+    above 1 only every ``every``-th token takes its last held one."""
+    picks = jnp.broadcast_to(jnp.array([2, 3, 4, 5][:held] + list(range(9, 9 + k - held)), jnp.int32), (N, k))
+    return picks.at[:, held - 1].set(jnp.where(jnp.arange(N) % every == 0, picks[:, held - 1], 9 + k))
 
 
-def _both_branches_plain(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel):
-    """``routed_part`` as it was while its fallback was a plain branch (unnamed, so a checkpointed block kept none of
-    it, but differentiated by the ``lax.cond`` like the other): what the pin below must tell from today's."""
-    n, every = wg.shape[0], idx.size
-    usual = min(every, -(-4 * every * n // num_experts // 512) * 512)
-    run = lambda rows, named: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
-    local = idx - first
-    return jax.lax.cond(jnp.sum((local >= 0) & (local < n)) <= usual, run(usual, True), run(every, False))
+ONE_HELD, ALL_HELD = _one_held_expert(6).astype(jnp.int32), _held_by(4, k=6)
+# 4 of 48 held at 8 a token: 4,096 pairs of which a uniform router sends 341 here. The ladder (``buffer_rungs``): twice
+# that rounded up to 512 rows, four times, every pair; none of them N, so a leading dimension says which buffer an array is of
+LADDER, FIRST_RUNG, FOUR, EVERY = 3 * E, 1024, 1536, N * K_LADDER
+# a crafted routing a rung: (idx, the buffer that holds it, pairs routed here, the rung)
+ON_RUNG = {"under_first": (_held_by(1), FIRST_RUNG, 512, 0), "first_to_its_last_row": (_held_by(2), FIRST_RUNG, 1024, 0),
+           "between_first_and_four": (_held_by(3, every=2), FOUR, 1280, 1), "four_to_its_last_row": (_held_by(3), FOUR, 1536, 1),
+           "above_four": (_held_by(4), EVERY, 2048, 2)}
+
+
+def test_the_ladder_is_twice_and_four_times_the_uniform_load_in_rows_of_512_under_every_pair():
+    """``buffer_rungs``: where a rung rounds past every pair it IS every pair (the small shapes of every rehearsal), and
+    the two lower rungs may round to the same buffer (a share of 1/32 at 1,024 pairs)."""
+    assert buffer_rungs(N * K_LADDER, HELD, LADDER) == (FIRST_RUNG, FOUR, EVERY)
+    assert buffer_rungs(N * 6, HELD, 4 * E) == (512, 1024, N * 6) and buffer_rungs(N * 6, HELD, E) == (1536, N * 6, N * 6)
+    assert buffer_rungs(N * 6, HELD, 2 * E) == (1024, 1536, N * 6)
+    assert buffer_rungs(1024, 2, 64) == (512, 512, 1024) and buffer_rungs(96 * 4, 4, 16) == (96 * 4,) * 3
+    # the benchmark's cells: SDAR's 16 of 128 at 2 x 8,192 x 8 pairs, SmallThinker's and Kimi-VL's 8 of 64, Keye's 16 of 128,
+    # Qwen3-Next's 32 of 512, Kimi-Linear's 8 of 256
+    assert buffer_rungs(2 * 8192 * 8, 16, 128) == (32768, 65536, 131072) and buffer_rungs(16384 * 6, 8, 64) == (24576, 49152, 98304)
+    assert buffer_rungs(8192 * 6, 8, 64) == (12288, 24576, 49152) and buffer_rungs(8192 * 8, 16, 128) == (16384, 32768, 65536)
+    assert buffer_rungs(8192 * 10, 32, 512) == (10240, 20480, 81920) and buffer_rungs(8192 * 8, 8, 256) == (4096, 8192, 65536)
+
+
+@pytest.mark.parametrize("rung", list(ON_RUNG))
+def test_every_rung_of_the_ladder_takes_the_kernel(rung):
+    """``routed_part`` on each rung (``ON_RUNG``): same numbers as the gathers', no pair dropped."""
+    idx, _, want_routed, _ = ON_RUNG[rung]
+    operands, cot = _operands(K_LADDER, seed=2)
+    got, routed, dropped, _ = _value_and_grads(idx, None, True, operands, cot, part=routed_part, experts=LADDER)
+    want, *_ = _value_and_grads(idx, None, False, operands, cot, part=routed_part, experts=LADDER)
+    assert (routed, dropped) == (want_routed, 0)
+    _same(got, want)
+
+
+def _plain_branches(ladder):
+    """``routed_part`` with every branch differentiated by its ``lax.cond`` (unnamed above the first rung, so a
+    checkpointed block keeps none of it): as it was before its fallback kept nothing (``ladder`` false: the first rung,
+    else every pair), and the ladder as one would write it first (a conditional a rung). What the pin below must tell
+    from today's."""
+    def part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel):
+        usual, four, every = buffer_rungs(idx.size, wg.shape[0], num_experts)
+        run = lambda rows, named: lambda: held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named)
+        local = idx - first
+        routed = jnp.sum((local >= 0) & (local < wg.shape[0]))
+        above = (lambda: jax.lax.cond(routed <= four, run(four, False), run(every, False))) if ladder else run(every, False)
+        return jax.lax.cond(routed <= usual, run(usual, True), above)
+
+    return part
+
+
+_TWO_PLAIN, _LADDER_PLAIN = _plain_branches(False), _plain_branches(True)
 
 
 def _rows_out_of_conds(jaxpr, seen):
@@ -124,40 +181,85 @@ def _rows_out_of_conds(jaxpr, seen):
     return seen
 
 
-def _checkpointed(part, idx, kernel, cot):
+def _checkpointed(part, idx, kernel, cot, experts=LADDER):
     """The loss of ``part`` under the block's policy: what is named is kept, the rest made again in the backward."""
-    return jax.checkpoint(lambda *a: jnp.sum(part(a[0], idx, *a[1:], FIRST, 4 * E, kernel)[0] * cot),
+    return jax.checkpoint(lambda *a: jnp.sum(part(a[0], idx, *a[1:], FIRST, experts, kernel)[0] * cot),
                           policy=jax.checkpoint_policies.save_only_these_names(SAVED))
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-def test_the_conditional_hands_on_nothing_of_every_pairs_row_count(kernel):
+def test_the_conditional_hands_on_nothing_of_a_larger_rungs_row_count(kernel):
     """A ``lax.cond`` under differentiation returns the residuals of BOTH branches, the branch not taken writing zeros
-    for the other's. The fallback keeps nothing but its operands (``_every_pair``), so in the gradient of a
-    checkpointed ``routed_part`` no conditional returns an array of every pair's 3,072 rows: the usual branch has none
-    to write zeros for. With both branches plain the recomputed conditional returns the fallback's sorted rows and
-    products at that size, which is what this test would see again if the rule were lost."""
-    operands, cot = _operands(6)
-    rows = {part: _rows_out_of_conds(jax.make_jaxpr(jax.grad(_checkpointed(part, ONE_HELD, kernel, cot), argnums=(0, 1, 2, 3, 4)))(*operands).jaxpr, set())
-            for part in (routed_part, _both_branches_plain)}
-    assert USUAL in rows[routed_part] and EVERY not in rows[routed_part]
-    assert {USUAL, EVERY} <= rows[_both_branches_plain]
+    for the other's. The fallback keeps nothing but its operands (``_every_pair``) and chooses its own buffer inside
+    its rule, forward and backward, so in the gradient of a checkpointed ``routed_part`` no conditional returns an
+    array of the 1,536 rows of four times the uniform load or of every pair's 3,072: the first rung, whose 1,024 rows
+    are kept, has none to write zeros for. With the branches plain (the old two, or a conditional a rung) the
+    recomputed conditional returns the larger rungs' sorted rows and products at those sizes, which is what this test
+    would see again if the rule were lost."""
+    operands, cot = _operands(K_LADDER)
+    rows = {part: _rows_out_of_conds(jax.make_jaxpr(jax.grad(_checkpointed(part, ON_RUNG["under_first"][0], kernel, cot), argnums=(0, 1, 2, 3, 4)))(*operands).jaxpr, set())
+            for part in (routed_part, _TWO_PLAIN, _LADDER_PLAIN)}
+    assert FIRST_RUNG in rows[routed_part] and not {FOUR, EVERY} & rows[routed_part]
+    assert {FIRST_RUNG, EVERY} <= rows[_TWO_PLAIN] and {FIRST_RUNG, FOUR, EVERY} <= rows[_LADDER_PLAIN]
+
+
+def _kept(jaxpr, seen):
+    """The shape of every value a ``jax.checkpoint`` names for keeping (``name`` equations), at any depth."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            seen.update(tuple(v.aval.shape) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kept(sub, seen)
+    return seen
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("idx,rows,pairs,fallback", [(ALL_HELD, EVERY, 2048, 1), (ONE_HELD, USUAL, 512, 0)], ids=["every_pair", "usual"])
-def test_either_branch_is_held_experts_at_its_buffer_differentiated_directly(idx, rows, pairs, fallback, kernel):
-    """Every token picking all four held experts is 2,048 pairs, past the usual buffer: output and the five gradients
-    are those of ``held_experts(..., rows=every)`` differentiated with no conditional and no rule around it, though
-    the fallback kept nothing and made its forward again in its backward; one held expert a token is 512 pairs, and
-    they are the usual buffer's. Plainly and under the block's checkpoint policy; no pair dropped either way."""
-    operands, cot = _operands(6, seed=3)
+def test_a_checkpointed_block_keeps_arrays_of_the_first_rungs_rows_alone(kernel):
+    """What carries the block's name in a traced ``routed_part``: the first rung's sorted rows, its three products and
+    its order, each of 1,024 rows; nothing of the rungs above it, which are traced (their ``held_experts`` unnamed)
+    but keep nothing."""
+    operands, cot = _operands(K_LADDER)
+    jaxpr = jax.make_jaxpr(jax.grad(_checkpointed(routed_part, ON_RUNG["under_first"][0], kernel, cot), argnums=(0, 1, 2, 3, 4)))(*operands).jaxpr
+    leading = {shape[0] for shape in _kept(jaxpr, set()) if shape}
+    assert {(FIRST_RUNG, D), (FIRST_RUNG, F)} <= _kept(jaxpr, set()) and not {FOUR, EVERY} & leading
+    assert {FOUR, EVERY} <= {shape[0] for shape in _shapes(jaxpr, set()) if shape}  # they are in the program all the same
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("rung", list(ON_RUNG))
+def test_every_rung_is_held_experts_at_its_buffer_differentiated_directly(rung, kernel):
+    """A crafted routing a rung (``ON_RUNG``: under the first, the first full to its last row, between the first and
+    four times, that one full to its last row, above it): output and the five gradients are those of
+    ``held_experts(..., rows=the rung's)`` differentiated with no conditional and no rule around it, and so those of
+    ``held_experts`` with every pair, though above the first rung the fallback kept nothing and made its forward again
+    in its backward. Plainly and under the block's checkpoint policy; no pair dropped on any rung, and the sixth value
+    says which was taken."""
+    idx, rows, pairs, taken = ON_RUNG[rung]
+    operands, cot = _operands(K_LADDER, seed=3)
     want, routed, _ = _value_and_grads(idx, rows, kernel, operands, cot)
-    got, routed_here, dropped = _value_and_grads(idx, None, kernel, operands, cot, part=routed_part)
-    assert (routed, routed_here, dropped) == (pairs, pairs, 0)
-    assert int(routed_part(operands[0], idx, *operands[1:], FIRST, 4 * E, kernel)[5]) == fallback
+    got, routed_here, dropped, rung_taken = _value_and_grads(idx, None, kernel, operands, cot, part=routed_part, experts=LADDER)
+    assert (routed, routed_here, dropped, rung_taken) == (pairs, pairs, 0, taken)
     _same(got, want)
-    _same(jax.grad(_checkpointed(routed_part, idx, kernel, cot), argnums=(0, 1, 2, 3, 4))(*operands), want[1:])
+    if rows != EVERY:
+        _same(got, _value_and_grads(idx, EVERY, kernel, operands, cot)[0])
+    _same(jax.jit(jax.grad(_checkpointed(routed_part, idx, kernel, cot), argnums=(0, 1, 2, 3, 4)))(*operands), want[1:])
+
+
+@pytest.mark.parametrize("experts,idx,rows,taken", [(4 * E, ALL_HELD, N * 6, 2), (4 * E, _held_by(2, k=6), 1024, 1), (4 * E, ONE_HELD, 512, 0),
+                                                    (2 * E, _held_by(3, every=2, k=6), 1536, 1), (2 * E, _held_by(2, k=6), 1024, 0),
+                                                    (E, ALL_HELD, N * 6, 1), (E, ONE_HELD, 1536, 0)],
+                         ids=["1/16_every", "1/16_four", "1/16_first", "1/8_four", "1/8_first", "1/4_four_is_every", "1/4_first"])
+def test_the_ladder_at_other_shares_of_the_experts(experts, idx, rows, taken):
+    """Six a token. 4 of 64 held (what these tests pinned while the buffer was four times the uniform load: 1,024 rows,
+    then every pair; now 512 before them); 4 of 32, the benchmark's share of an eighth (1,024, 1,536 and 3,072 rows);
+    4 of 16, where four times the uniform load is every pair and the fallback has one buffer. The rung taken and
+    ``held_experts`` at its buffer, plainly and checkpointed."""
+    operands, cot = _operands(6, seed=4)
+    want, routed, _ = _value_and_grads(idx, rows, False, operands, cot)
+    got, routed_here, dropped, rung_taken = _value_and_grads(idx, None, False, operands, cot, part=routed_part, experts=experts)
+    assert (routed_here, dropped, rung_taken) == (routed, 0, taken)
+    _same(got, want)
+    _same(jax.jit(jax.grad(_checkpointed(routed_part, idx, False, cot, experts), argnums=(0, 1, 2, 3, 4)))(*operands), want[1:])
 
 
 def _shapes(jaxpr, seen):
@@ -193,17 +295,17 @@ def test_the_choice_is_counted_where_it_is_made(n_tokens, d, kernel, path):
 
 def test_the_conditionals_form_is_counted_once_a_trace_and_has_its_word_for_the_first_call_line():
     """``program_regions_traced_total{region="ffn/cond", path="fallback_keeps_nothing"}``: one a traced ``routed_part``
-    that has a conditional (none where the usual buffer holds every pair), and the trainer's first-call line reads it
+    that has a conditional (one whatever the rungs above the first; none where the first rung holds every pair), and the trainer's first-call line reads it
     as ``moe_cond``."""
     from deepspeed_tpu.runtime import engine
 
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((N, D), (N, 6), (HELD, D, F), (HELD, D, F), (HELD, F, D))]
     rose = []
-    for experts in (4 * E, E):  # 4 of 64: a buffer of 1,024 under 3,072 pairs; 4 of 16: the usual buffer is every pair
+    for experts in (4 * E, E, HELD):  # 4 of 64: a first rung of 512 under 3,072 pairs; 4 of 16: 1,536; 4 of 4: it is every pair
         before = engine._paths_traced()["moe_cond"]
         jax.eval_shape(lambda *a: routed_part(a[0], ONE_HELD, *a[1:], FIRST, experts, False), *shapes)
         rose.append(tuple(now - was for now, was in zip(engine._paths_traced()["moe_cond"], before)))
-    assert rose == [(1, 0), (0, 0)] and engine._PATH_WORDS["moe_cond"] == "fallback_keeps_nothing"
+    assert rose == [(1, 0), (1, 0), (0, 0)] and engine._PATH_WORDS["moe_cond"] == "fallback_keeps_nothing"
 
 
 def test_spans_are_where_each_tile_and_expert_lies_in_the_sorted_buffer():
