@@ -14,6 +14,7 @@
     python chip_smoke.py --only sambay   # ... and its sixth's: Mamba-1 scans, differential attention, a second half that reads the first's
     python chip_smoke.py --only blockdiff  # ... and its seventh's: block-diffusion training, GQA under the block mask over a doubled row, 8 of 128 experts
     python chip_smoke.py --only mixed    # ... and its eighth's: one full layer without positions to three rotated window layers at 16,384 rows, ReGLU experts behind an early router
+    python chip_smoke.py --only shortconv  # ... and its ninth's: gated short convolutions three layers in four beside GQA 32/8 of 64 at 16,384 rows, a biased sigmoid router of 4 in 32, a tied head
 
 Everything runs in this one process (a chip belongs to one process), at the
 full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
@@ -1031,13 +1032,14 @@ BLOCKDIFF_CLASS_LIMITS = {
 }
 
 
-def _decoder_names(parts, layers=4):
-    """Every leaf of a routed decoder's tree, a leaf's name its path: the untied tables and the final norm, then a layer's two
-    norms and the leaves of its ``parts`` (part -> its leaves)."""
-    names = ["wte", "lm_head", "RMSNorm_0/scale"]
-    for i in range(layers):
+def _decoder_names(parts, layers=4, kinds=None, tied=False):
+    """Every leaf of a routed decoder's tree, a leaf's name its path: the tables (one where the head is ``tied`` to the
+    embedding) and the final norm, then a layer's two norms and the leaves of its ``parts`` (part -> its leaves; ``kinds``: the
+    parts each layer has, where the layers differ)."""
+    names = ["wte"] + ([] if tied else ["lm_head"]) + ["RMSNorm_0/scale"]
+    for i, own in enumerate(kinds or [tuple(parts)] * layers):
         names += [f"layer_{i}/{norm}/scale" for norm in ("RMSNorm_0", "RMSNorm_1")]
-        names += [f"layer_{i}/{part}/{leaf}" for part, leaves in parts.items() for leaf in leaves]
+        names += [f"layer_{i}/{part}/{leaf}" for part in own for leaf in parts[part]]
     return names
 
 
@@ -1090,6 +1092,38 @@ MIXED_LIMITS = {"logits": MIXED_CLASS_LIMITS["logits"],
                 **{name: MIXED_CLASS_LIMITS.get(_sambay_class(name), MIXED_LEAF_LIMIT) for name in _decoder_names(MIXED_PARTS)}}
 
 
+# LFM2-8B-A1B's published layers 1-5 (``--only shortconv``): a gated short convolution over the dense SwiGLU, then GQA 32/8
+# of 64 with q/k norms and three more convolutions over a routed FFN (sigmoid scores, 4 of 32, 8 held), the head tied to
+# the embedding, at 16,384 rows. EVERY leaf of the gradient is read but the selection bias, a buffer whose gradient is zero
+# on both sides (49 of the 53: a leaf's name is its path). Four controls, each the plain bf16 reference with one thing wrong, and
+# each has to break a limit on every seed: a SiLU after the filter (``silu_filter``: what the scan layers' convolutions
+# have), W_in's first two chunks the other way round (``chunks_cbu``), no q/k norm (``no_qk_norm``) and the chosen scores
+# not rescaled (``no_renorm``). At the usual start of ``o_proj`` (the cell starts it small, which would hide the
+# attention's part).
+# Limits from three seeds (0, 11, 101) and checked on two more (2024, 31337, which passed: the program under all 46 judged limits, `silu_filter`, `chunks_cbu` and `no_renorm` over all 46 and `no_qk_norm` over 7 on all five; my chip runs, PR 55: published
+# widths, 5 layers, 1 x 16,384; 4.4 minutes a seed), by class of leaf (its path without the layer). The program reads what
+# the plain bf16 reference reads, leaf by leaf (two gates around a filter multiply their operands' rounding, so both lie
+# further from float32 than a stack of attention layers does: logits 0.047-0.048). NOT judged, only reported (a limit of
+# None): the routers' own gradients, which flips of the top 4 of 32 rule in the program and in the plain bf16 path alike
+# (0.194-0.340 | 0.194-0.331). The routed layer's other leaves read the same flips through the experts' rows. ``no_qk_norm``
+# breaks the attention layer's leaves alone (its logits read 0.055: one layer in five, at a start where attention is an
+# average), the three others every class.
+SHORTCONV_KINDS = (("conv", "mlp"), ("attn", "routed"), ("conv", "routed"), ("conv", "routed"), ("conv", "routed"))
+SHORTCONV_PARTS = {"conv": ("in_proj", "conv_kernel", "out_proj"), "attn": ("q_proj", "k_proj", "v_proj", "o_proj", "q_norm", "k_norm"),
+                   "mlp": ("gate_proj", "up_proj", "down_proj"), "routed": ("gate", "experts_wg", "experts_wi", "experts_wo")}
+SHORTCONV_LEAF_LIMIT = 0.15  # every other leaf: the program 0.018-0.085 (a q_norm the largest) | plain bf16 0.018-0.077 | no_qk_norm 0.199-0.376 over the attention's products and 1.0 over its norms, no_renorm 0.217-0.999, silu_filter 0.370-1.505, chunks_cbu 0.484-1.507
+SHORTCONV_CLASS_LIMITS = {
+    "logits": 0.1,           # 0.0470-0.0482 | plain bf16 0.0473-0.0481 | no_qk_norm 0.0548-0.0557 (under it: see above), no_renorm 0.601-0.606, silu_filter 1.012-1.014, chunks_cbu 1.349-1.352
+    "routed/gate": None,
+    **{f"routed/experts_{m}": 0.35 for m in ("wg", "wi", "wo")},  # 0.138-0.229 | plain bf16 0.132-0.227 | no_qk_norm 0.152-0.251, silu_filter 1.328-1.692, chunks_cbu 1.408-1.418, no_renorm 2.467-2.653
+    "RMSNorm_1/scale": 0.35,  # the norm ahead of the experts: the same flips: 0.069-0.232 | 0.067-0.231 | no_qk_norm 0.080-0.260, no_renorm 0.851-2.687 (layer 0's is the dense FFN's and reads 0.07)
+}
+
+
+SHORTCONV_LIMITS = {"logits": SHORTCONV_CLASS_LIMITS["logits"],
+                    **{name: SHORTCONV_CLASS_LIMITS.get(_sambay_class(name), SHORTCONV_LEAF_LIMIT) for name in _decoder_names(SHORTCONV_PARTS, kinds=SHORTCONV_KINDS, tied=True)}}
+
+
 # a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, its controls
 # (a name and what is wrong with the plain bf16 reference under it) and, where the rows are not uniform ids of the
 # program's length, what makes them
@@ -1112,6 +1146,9 @@ SMOKE_MODELS = {
     "mixed": ("benchmarks/configs/smallthinker-21b-l4e8.json", MIXED_LIMITS, _sambay_leaf,
               {"no_window": {"windows": "none"}, "rotated_full": {"rotation": "all"}, "late_router": {"router": "late"}, "silu_gate": {"gate": "silu"}},
               {"program": {"sparse_out_init_scale": 1.0}, "report_plain": True}),
+    "shortconv": ("benchmarks/configs/lfm2-8b-a1b-l5e8.json", SHORTCONV_LIMITS, _sambay_leaf,
+                  {"silu_filter": {"filter_act": "silu"}, "chunks_cbu": {"chunks": "cbu"}, "no_qk_norm": {"qk_norm": "none"}, "no_renorm": {"renorm": "none"}},
+                  {"program": {"sparse_out_init_scale": 1.0}, "report_plain": True}),
 }
 
 

@@ -141,6 +141,11 @@ class TransformerFields:
     # vector and its load is a seed's draw; at 3 the scores' deviation is 9, a query picks a few keys as a trained model's
     # does, every position receives a vector of its own and the router's load is even
     blockdiff_qk_init_scale: float = 1.0
+    # conv (a gated short convolution): [B, C, u] = x W_in, three chunks of ``d_model``; out = (C * conv(B * u)) W_out, the
+    # convolution depthwise and causal over ``conv_kernel`` tokens, one filter a channel, no bias and no activation
+    conv_kernel: int = 3
+    # routed, sigmoid scoring: what is added to the sum a token's chosen scores are divided by (the families differ: 1e-20, 1e-6)
+    moe_renorm_eps: float = 1e-20
 
     @property
     def kv_heads(self) -> int:

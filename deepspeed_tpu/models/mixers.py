@@ -9,7 +9,8 @@ read what the first half made instead of making their own: a gate on the scan's
 output (``GatedMemory``) and differential attention over the first half's keys
 and values (``DiffCrossAttention``); and grouped-query attention under the
 block-diffusion mask over a doubled row, whose objective is a masked-token loss
-(``BlockDiffMixer``). All are training-side
+(``BlockDiffMixer``); and a gated short convolution, whose time-mixing is neither a softmax, a scan nor a delta rule
+(``ShortConvMixer``). All are training-side
 modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
 ``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
 carries its kind's record (``layers.py::LayerKind``).
@@ -27,6 +28,7 @@ from ..ops import indexed_attention as sparse
 from ..ops import masks
 from ..ops.attention import attention
 from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, kda
+from ..ops.pallas import short_conv
 from ..ops.registry import pallas_available
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
@@ -58,6 +60,14 @@ def causal_conv(x, w, axis: int = 1):
     K, S = w.shape[0], x.shape[axis]
     padded = jnp.pad(x, [(K - 1, 0) if d == axis else (0, 0) for d in range(x.ndim)])
     return sum(jax.lax.slice_in_dim(padded, j, j + S, axis=axis) * w[j] for j in range(K))
+
+
+def gated_conv(x, w):
+    """A ``conv`` layer between its two products: ``x = [B, C, u]`` (Bt, S, 3 D), ``w`` (K, D) -> ``C * causal_conv(B * u)``
+    (Bt, S, D) in x's type, float32 inside: XLA's fusions, and the oracle of ``ops/pallas/short_conv.py``."""
+    D = w.shape[1]
+    B, C, u = (x[..., n * D:(n + 1) * D].astype(jnp.float32) for n in range(3))
+    return (C * causal_conv(B * u, w.astype(jnp.float32))).astype(x.dtype)
 
 
 def l2_normalize(x, eps: float = 1e-6):
@@ -459,6 +469,33 @@ class GatedMemory(LayerKind, nn.Module):
             gated = scan_out * nn.silu(gate)
         with region("mixer/proj"):
             return dense(cfg.d_model, "out_proj")(gated)
+
+
+class ShortConvMixer(LayerKind, nn.Module):
+    """A gated short convolution (the LFM2 family's ``conv`` operator): ``[B, C, u] = x W_in`` (three chunks of
+    ``d_model`` in that order, no bias); ``g = B * u``; ``c_t = sum_j w_j g_{t - (K - 1) + j}`` (depthwise, causal,
+    ``K = conv_kernel`` taps, one filter a channel, no bias, NO activation); ``out = (C * c) W_out``. Two gates around a
+    filter between two products: bandwidth-bound. What lies between the products is one Pallas call each way on one TPU
+    chip (``ops/pallas/short_conv.py``) and ``gated_conv``, XLA's fusions over ``causal_conv``, elsewhere; float32 inside either way."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (short_conv.SAVED, SAVED), True
+    paths = {"conv_path": ("mixer/conv", {"op": "short_conv", "pass": "fwd"})}
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        D, K, f32 = cfg.d_model, cfg.conv_kernel, jnp.float32
+        dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)
+        with region("mixer/proj"):  # named (``SAVED``: what a checkpointed block keeps): the one product over the model width
+            bcu = checkpoint_name(dense(3 * D, "in_proj")(x), SAVED)
+        w = self.param("conv_kernel", _uniform(-K**-0.5, K**-0.5), (K, D), f32)
+        path = short_conv.path_for(x.shape[1], D, K)
+        with region("mixer/conv", op="short_conv", path=path, **{"pass": "fwd"}):  # the gates and the filter
+            gated = short_conv.short_conv(bcu, w) if path == "kernel" else gated_conv(bcu, w)
+        with region("mixer/proj"):
+            return dense(D, "out_proj")(gated)
 
 
 def _count_diffusion(counts):

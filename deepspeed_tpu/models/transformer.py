@@ -32,8 +32,8 @@ from .config import TransformerFields
 # (much of what moved below this module is imported from here all the same)
 from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, UnrotatedAttention, _norm, _rope_table, alibi_slopes,  # noqa: F401
                      apply_rope, make_norm, rope_frequencies, scaled_rope_frequencies)
-from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, SparseMixer,
-                     SSMMixer)
+from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, ShortConvMixer,
+                     SparseMixer, SSMMixer)
 
 # THE table of layer kinds. A kind is declared once: its flax module carries its record (``layers.py::LayerKind``) and has
 # one line here; ``Block``, ``block_fn``, ``CausalLM.loss_fn``, ``runtime/engine.py`` and ``inference/v2/engine_v2.py`` read
@@ -41,7 +41,7 @@ from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMem
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module
 MIXERS = {"full": Attention, "window": Attention, "kda": KDAMixer, "gdn": GDNMixer, "mla": MLAMixer, "sparse": SparseMixer}
 MIXERS |= {"ssm": SSMMixer, "diff": DiffAttention, "diff_window": DiffAttention, "gmu": GatedMemory, "diff_cross": DiffCrossAttention}
-MIXERS |= {"blockdiff": BlockDiffMixer, "nope": UnrotatedAttention}
+MIXERS |= {"blockdiff": BlockDiffMixer, "nope": UnrotatedAttention, "conv": ShortConvMixer}
 FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE, "routed_early": EarlyRoutedMoE}
 
 

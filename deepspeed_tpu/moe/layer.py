@@ -197,6 +197,7 @@ class RoutedMoE(nn.Module):
     scoring: str = "sigmoid"
     shared_gate: bool = False
     dtype: Any = jnp.float32
+    renorm_eps: float = 1e-20  # sigmoid scoring: what is added to the sum the chosen scores are divided by
     act: str = "silu"  # the experts' gate (``sharded_moe.GATES``)
     # its record as the layer kind ``routed`` (as ``MoE`` says its own). The line's keys: how the grouped products and the
     # rows' sum were traced, the conditional's form where the buffer's first rung is smaller than every pair
@@ -212,7 +213,7 @@ class RoutedMoE(nn.Module):
     def from_config(cls, cfg, kind):
         return cls(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k, d_ff=cfg.moe_d_ff or cfg.ffn_dim,
                    held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff, scale=cfg.moe_route_scale, scoring=cfg.moe_scoring,
-                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, act="relu" if cfg.activation == "reglu" else "silu",
+                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, renorm_eps=cfg.moe_renorm_eps, act="relu" if cfg.activation == "reglu" else "silu",
                    name="routed")
 
     @nn.compact
@@ -234,7 +235,7 @@ class RoutedMoE(nn.Module):
                 idx, weights = softmax_topk(logits, self.k, self.scale)
             else:
                 select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
-                idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale)
+                idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale, self.renorm_eps)
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))

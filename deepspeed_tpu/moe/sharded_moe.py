@@ -144,9 +144,9 @@ def combine_output(expert_out: jnp.ndarray, combine: jnp.ndarray) -> jnp.ndarray
 # ----------------------------------------------------------------------
 # routing without a capacity: scores over ALL experts, the part of the ones held here
 # ----------------------------------------------------------------------
-def _renormalised(chosen, scale: float):
-    """A token's chosen scores (N, k) rescaled to sum to one, times ``scale``."""
-    return chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
+def _renormalised(chosen, scale: float, eps: float = 1e-20):
+    """A token's chosen scores (N, k) rescaled to sum to one (``eps`` added to the sum), times ``scale``."""
+    return chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps) * scale
 
 
 def _chosen(scores, ranked, k: int):
@@ -165,14 +165,15 @@ def _chosen(scores, ranked, k: int):
     return idx, checkpoint_name(jnp.sum(jnp.where(met, scores[..., None, :], 0), axis=-1), SAVED)
 
 
-def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float):
+def sigmoid_topk(scores_logits: jnp.ndarray, select_bias: jnp.ndarray, k: int, scale: float, eps: float = 1e-20):
     """Sigmoid scores, the top ``k`` of ``score + select_bias`` (the bias only
     chooses: it takes no gradient and does not enter the weight), the chosen
-    scores rescaled to sum to one and multiplied by ``scale``.
+    scores rescaled to sum to one (their sum plus ``eps``: the families
+    differ, 1e-20 and 1e-6) and multiplied by ``scale``.
     logits (N, E) float32 -> (indices (N, k) int32, weights (N, k) float32)."""
     scores = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
     idx, chosen = _chosen(scores, scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
-    return idx, _renormalised(chosen, scale)
+    return idx, _renormalised(chosen, scale, eps)
 
 
 def softmax_topk(logits: jnp.ndarray, k: int, scale: float):
@@ -192,17 +193,21 @@ def _rows_a_group(key, n: int):
     return jnp.sum(key[:, None] == jnp.arange(n, dtype=key.dtype), axis=0, dtype=jnp.int32)
 
 
-def _grouped(xs, w, group_sizes, kernel: bool, **choice):
+def _grouped(xs, w, group_sizes, kernel: bool, row_tile: int = 256, **choice):
     """Rows sorted by group times that group's matrix: (M, a) x (G, a, b) ->
-    (M, b); rows past the groups' total are not defined. ``choice``: what else
-    the caller chose for these products, counted with their path."""
+    (M, b); rows past the groups' total are not defined. ``row_tile``: the
+    rows of a kernel's tile (``routed_part`` says when 512). ``choice``: what
+    else the caller chose for these products, counted with their path."""
     def tile(n, want):
         """The widest listed tile that divides ``n``; a width that only 128 divides (1,408 = 11 x 128) is its own tile
-        while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work."""
-        t = max((t for t in (1024, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
+        while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work. 896 = 7 x 128
+        is listed for 1,792 = 2 x 896, whose next divisor down is 256: a product of 2,048 onto 1,792 over 8 groups of
+        2,048 rows, forward and backward, 5.61 ms at (256, 512, 256) and 3.90 at (256, 512, 896); 1,792 onto 2,048 3.95
+        at (256, 256, 1024) and 3.35 at (256, 896, 1024) (TPU v5e; ``PERF.md``, PR 55). It divides no other cell's width."""
+        t = max((t for t in (1024, 896, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
         return n if t == 128 and n <= 1536 else t
 
-    tiling = (tile(xs.shape[0], 256), tile(w.shape[1], 768), tile(w.shape[2], 1024))
+    tiling = (tile(xs.shape[0], row_tile), tile(w.shape[1], 896), tile(w.shape[2], 1024))
     kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
     with region("ffn/experts", path="kernel" if kernel else "xla", **choice):  # the choice, counted where it is made
         if not kernel:
@@ -287,7 +292,7 @@ _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
 GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # an expert's gate, by ``act``: SwiGLU's, ReGLU's
 
 
-def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True, act: str = "silu"):
+def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True, act: str = "silu", row_tile: int = 256):
     """The part of a routed FFN that the experts ``first .. first + n`` add
     (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (act(x wg) * x wi)``, ``act``
     the configuration's: ``silu``, or ``relu`` for ReGLU experts; the one line
@@ -306,6 +311,7 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     sum of its rows' gradients, is read off those spans a tile at a time, and
     only the rows routed here are read; elsewhere every pair's row is
     gathered by the inverse order into (N, k, d), masked and summed over k.
+    ``row_tile``: the rows of the grouped kernels' tile (``_grouped``).
     ``rows`` bounds the buffer, not the routing, and every pass between the
     grouped products runs over all of it: the caller gives the smallest of a
     ladder that holds every pair routed here (``routed_part``). ``named``: whether the
@@ -342,9 +348,9 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
     said = {} if act == "silu" else {"act": act}  # every older configuration's series are what they were
     with region("ffn/experts"):
-        gate, up = keep(_grouped(xs, wg, group_sizes, kernel, **said)), keep(_grouped(xs, wi, group_sizes, kernel, **said))
+        gate, up = keep(_grouped(xs, wg, group_sizes, kernel, row_tile, **said)), keep(_grouped(xs, wi, group_sizes, kernel, row_tile, **said))
         hidden = (GATES[act](gate) * up).astype(xs.dtype)
-        product = _grouped(hidden, wo, group_sizes, kernel, **said)
+        product = _grouped(hidden, wo, group_sizes, kernel, row_tile, **said)
     with region("ffn/rows"):
         ys = keep(jnp.where(row_ok, product, 0))
         out = _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)
@@ -455,15 +461,25 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     experts. ``moe_fallback_layers_total`` counts such (layer, step) pairs,
     ``moe_buffer_rung_layers_total{rung}`` each rung's.
 
+    The first rung's grouped kernels take rows in tiles of 512 where a
+    uniform router fills an expert's group with 2,048 rows or more (``N * k /
+    num_experts``: what this call can see of the load), else 256: at full
+    groups a wider tile crosses fewer group boundaries a row (three products,
+    forward and backward, 8 groups of 2,048 rows at 2,048 x 1,792: 11.14 ms at
+    256, 9.91 at 512; TPU v5e, ``PERF.md``, PR 55), at groups of a few hundred
+    rows it multiplies padding. The rungs above keep 256: they run in a step
+    out of hundreds and their shapes are every layer's to compile.
+
     Returns ``held_experts``'s five values and the rung taken (0, 1 or 2:
     ``RUNGS``; 1 where four times the uniform load is every pair)."""
     N, k = idx.shape
     n = wg.shape[0]
     usual, four, every = buffer_rungs(N * k, n, num_experts)
+    row_tile = 512 if N * k >= 2048 * num_experts else 256
 
     def held():
         with region("branch/usual"):
-            return held_experts(tokens, idx, weights, wg, wi, wo, first, usual, kernel, act=act)
+            return held_experts(tokens, idx, weights, wg, wi, wo, first, usual, kernel, act=act, row_tile=row_tile)
 
     if usual == every:
         return *held(), jnp.zeros((), jnp.int32)

@@ -557,8 +557,12 @@ def tiles_a_trip(whole_run: int, vmem: int) -> int:
 def _fused_bwd_vmem(Sq: int, Sk: int, D: int, item: int, bq: int, bk: int, n_rep: int, Dv: int = 0) -> int:
     """Bytes of VMEM the fused backward holds at once: what the grid keeps
     resident (inputs and outputs double-buffered by the pipeline), the
-    float32 scratch, and the block temporaries."""
-    Dv = Dv or D  # the value head size, where it is not the keys'
+    float32 scratch, and the block temporaries. A head size is counted as the
+    whole vregs of lanes it takes there: a block of 64 columns fills 128 (this
+    count holds whole sequences and decides the backward's form; the
+    forward's and the split kernels' hold a q tile and stay far under)."""
+    lanes = lambda d: -(-d // LANES) * LANES
+    D, Dv = lanes(D), lanes(Dv or D)  # the value head size, where it is not the keys'
     head = 2 * (Sq * (D + Dv) * item + 2 * 8 * Sq * 4 + Sq * D * item) + Sq * D * 4  # q, do, lse, delta, dq out; dq_acc
     kv_in = 2 * bk * (D + Dv) * item
     kv_out = kv_in if n_rep == 1 else 2 * Sk * (D + Dv) * item + Sk * (D + Dv) * 4

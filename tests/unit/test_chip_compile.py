@@ -206,6 +206,37 @@ def _flash_blockdiff(shape, block):
     return fwd_bwd, (q, kv, kv, q), 2, "kernel"
 
 
+def _short_conv(shape):
+    """The gated short convolution between a ``conv`` layer's two products, forward and backward: 2 kernels, a tile of
+    rows at the full width a grid step, the rows it reaches back to (and ahead) as halo blocks."""
+    from deepspeed_tpu.ops.pallas.short_conv import short_conv
+
+    B, Sq, Dm, K = shape
+
+    def fwd_bwd(x, w, dy):
+        y, vjp = jax.vjp(short_conv, x, w)
+        return (y,) + vjp(dy)
+
+    return fwd_bwd, (S((B, Sq, 3 * Dm), BF16), S((K, Dm), F32), S((B, Sq, Dm), BF16)), 2
+
+
+def _grouped_products(shape, tilings):
+    """A routed layer's up and down products through the grouped matmul at the tiles ``moe/sharded_moe.py::_grouped`` picks
+    for them (``tests/unit/test_short_conv_layers.py`` holds it to these), forward and backward: gmm, gmm and tgmm each."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    rows, n, d, f = shape
+
+    def fwd_bwd(xs, wg, wo, sizes, dy):
+        def part(xs, wg, wo):
+            hidden = gmm(xs, wg, sizes, preferred_element_type=xs.dtype, tiling=tilings[0])
+            return gmm(hidden, wo, sizes, preferred_element_type=xs.dtype, tiling=tilings[1])
+        y, vjp = jax.vjp(part, xs, wg, wo)
+        return (y,) + vjp(dy)
+
+    return fwd_bwd, (S((rows, d), BF16), S((n, d, f), BF16), S((n, f, d), BF16), S((n,), I32), S((rows, d), BF16)), 6
+
+
 def _moe_sum_rows(shape):
     """A routed layer's tokens sum their own rows off the expert-sorted buffer, each row times its weight: the combine,
     and with weights of one the backward of the rows' gather."""
@@ -269,6 +300,13 @@ CASES = {
     "moe_sum_rows_t16384_d2560_e8_r24576": lambda: _moe_sum_rows((16384, 2560, 8, 24576)),  # ... the first rung
     "moe_sum_rows_t16384_d2048_e16_r32768": lambda: _moe_sum_rows((16384, 2048, 16, 32768)),  # sdar-30b-a3b-l4e16's first rung
     "moe_sum_rows_t16384_d2560_e8_r98304": lambda: _moe_sum_rows((16384, 2560, 8, 98304)),  # ... and every pair
+    "short_conv_b1_s16384_d2048_k3": lambda: _short_conv((1, 16384, 2048, 3)),  # lfm2-8b-a1b-l5e8's four conv layers
+    "short_conv_b2_s1008_d384_k4": lambda: _short_conv((2, 1008, 384, 4)),  # tiles of 16 rows, lanes of 128, four taps
+    "flash_gqa_b1_s16384_h32_kvh8_d64": lambda: _flash((1, 16384, 32, 8, 64)),  # ... its one attention layer: heads of 64
+    "moe_sum_rows_t16384_d2048_e8_r32768": lambda: _moe_sum_rows((16384, 2048, 8, 32768)),  # ... and its routed layers' first rung
+    "moe_sum_rows_t16384_d2048_e8_r65536": lambda: _moe_sum_rows((16384, 2048, 8, 65536)),  # ... four times the uniform load is every pair
+    "gmm_r32768_e8_d2048_f1792_rows512": lambda: _grouped_products((32768, 8, 2048, 1792), ((512, 512, 896), (512, 896, 1024))),  # ... its grouped products: 1,792 in tiles of 896, full groups in rows of 512
+    "gmm_r65536_e8_d2048_f1792_rows256": lambda: _grouped_products((65536, 8, 2048, 1792), ((256, 512, 896), (256, 896, 1024))),  # ... and on the rung above
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
        for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},

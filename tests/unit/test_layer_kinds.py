@@ -133,7 +133,7 @@ def _names(jaxpr, found):
 
 
 # a kernel's custom_vjp gives these where the kernel runs: off the TPU no trace shows them (``tests/unit/test_chip_compile.py``)
-KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan"}
+KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan", "short_conv"}
 # a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
 WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel",
         "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same",
@@ -238,7 +238,8 @@ def test_the_configurations_fields_are_the_parents():
     assert sha(repr([(f.name, repr(f.default)) for f in fields[:76]])) == "02e66817e969c9f2"  # PR 45's 76, as they were
     assert [(f.name, f.default) for f in fields[76:]] == [("ssm_inner", 0), ("ssm_state", 16), ("ssm_conv", 4), ("ssm_dt_rank", 0),
                                                           ("layer_numbers", None),  # PR 46: appended, nothing moved
-                                                          ("block_length", 0), ("mask_token_id", 0), ("blockdiff_qk_init_scale", 1.0)]  # PR 49: likewise
+                                                          ("block_length", 0), ("mask_token_id", 0), ("blockdiff_qk_init_scale", 1.0),  # PR 49: likewise
+                                                          ("conv_kernel", 3), ("moe_renorm_eps", 1e-20)]  # PR 55: likewise
     assert [f.name for f in fields] == [f.name for f in dataclasses.fields(TransformerFields)]
     cfg = TransformerConfig(n_layers=3)
     assert TransformerConfig(**cfg.__dict__) == cfg == dataclasses.replace(cfg) and hash(cfg) == hash(dataclasses.replace(cfg))
