@@ -496,8 +496,9 @@ def trainer_phase():
     check(engine._fused_step is not None, "the one-dispatch fused step was not built")
     dev_batch = engine._put_batch(batch)
     scale = engine.loss_scaler.loss_scale / engine.gradient_accumulation_steps
-    n_calls = count_custom_calls(engine._fused_step, abstract(engine.params), abstract(engine.opt_state),
-                                 abstract(dev_batch), 0, scale, 1.0 / engine.loss_scaler.loss_scale, 1e-4)
+    n_calls = count_custom_calls(engine._fused_step, abstract(engine.params), abstract(engine._compute_params()),
+                                 abstract(engine.opt_state), abstract(dev_batch), 0, scale,
+                                 1.0 / engine.loss_scaler.loss_scale, 1e-4)
     check(REHEARSE or n_calls > 0, "the compiled train step holds no tpu_custom_call")
 
     losses = _take_steps(engine, dev_batch, SZ.train_steps)

@@ -96,7 +96,7 @@ def measure_memory(engine, batch) -> Optional[int]:
         fwd_bwd = engine._fwd_bwd
         if not hasattr(fwd_bwd, "lower"):
             return None
-        compiled = fwd_bwd.lower(engine.params, engine._put_batch(batch), 0, 1.0).compile()
+        compiled = fwd_bwd.lower(engine._compute_params(), engine._put_batch(batch), 0, 1.0).compile()
         mem = compiled.memory_analysis()
         if mem is None:
             return None

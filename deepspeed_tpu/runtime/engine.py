@@ -451,6 +451,28 @@ class DeepSpeedEngine:
             f"micro_bs={self.train_micro_batch_size_per_gpu} gas={self.gradient_accumulation_steps} "
             f"global_bs={self.train_batch_size} mesh={self.topology.axis_sizes}", ranks=[0])
 
+    @property
+    def params(self):
+        """The float32 master weights."""
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        # whoever replaces the master from outside a step (construction, a checkpoint's load, the host optimizer) drops
+        # the compute copy that was cast from the old one; a step that carries the copy installs both (``_install``)
+        self._params, self._params_c = tree, None
+
+    def _install(self, params, params_c):
+        """A step's outputs: the new master and the compute copy its update wrote from it (None where none is carried)."""
+        self._params, self._params_c = params, params_c
+
+    def _compute_params(self):
+        """What a step differentiates at and an evaluation runs on: the carried compute copy, cast from the master once
+        when there is none yet; the master itself where no copy is carried (the program then casts it)."""
+        if self._params_c is None and self._cast_copy is not None:
+            self._params_c = self._cast_copy(self._params)
+        return self._params if self._params_c is None else self._params_c
+
     # ------------------------------------------------------------------
     # compiled functions
     # ------------------------------------------------------------------
@@ -481,23 +503,6 @@ class DeepSpeedEngine:
         if comp is None and not self._param_offload:
             gather_plan = zero_overlap.plan_for(self.config, self.topology, self.param_specs)
 
-        def scaled_loss_fn(params32, batch, rng, scale, comp_state):
-            with region("optimizer"):  # the cast of the master weights to the compute copy, and in the backward of the gradients back
-                params_c = _cast_tree(params32, compute_dtype)
-            if comp is not None:
-                params_c = comp.apply(params_c, comp_state)
-            # read by the model while its loss is traced: the gather plan, and who takes what it counts on the device
-            with zero_overlap.active(gather_plan), device_counts.collecting() as reported:
-                loss = loss_fn(params_c, batch, rng)
-            return (loss * scale).astype(jnp.float32), ((loss, reported) if reported else loss)  # nothing counted: the loss alone
-
-        def fwd_bwd(params32, batch, step, scale, comp_state):
-            # rng derivation lives inside the jit: one less per-step dispatch
-            rng = jax.random.fold_in(base_rng, step)
-            (scaled, loss_and_reported), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
-                _fetch(params32), batch, rng, scale, comp_state)
-            return loss_and_reported, grads
-
         from .zero.zeropp import build_zeropp_fwd_bwd, zeropp_applicable, zeropp_requested
 
         use_zeropp, zeropp_reason = zeropp_applicable(self.config, self.topology)
@@ -506,22 +511,22 @@ class DeepSpeedEngine:
             zeropp_reason = "compression_training and ZeRO++ manual path are mutually exclusive"
         if zeropp_requested(self.config) and not use_zeropp:
             log_dist(f"ZeRO++ requested but falling back to GSPMD path: {zeropp_reason}", ranks=[0])
-        if use_zeropp:
-            zpp = build_zeropp_fwd_bwd(loss_fn, self.param_specs, self.grad_specs,
-                                       self.topology, self.config, compute_dtype)
-            self._fwd_bwd = lambda p, b, step, s: zpp(p, b, jax.random.fold_in(base_rng, step), s)
-        elif comp is None:
-            self._fwd_bwd = jax.jit(lambda p, b, step, s: fwd_bwd(p, b, step, s, None),
-                                    out_shardings=(None, self.grad_shardings))
-        else:
-            self._fwd_bwd_comp = jax.jit(fwd_bwd, out_shardings=(None, self.grad_shardings))
-            self._fwd_bwd = lambda p, b, step, s: self._fwd_bwd_comp(p, b, step, s, comp.comp_state())
 
-        def accumulate(acc, grads):
-            return jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), acc, grads)
-
-        self._accumulate = jax.jit(accumulate, donate_argnums=(0,), out_shardings=self.grad_shardings)
-
+        # A parameter's bytes cross between master and compute precision once a step, inside the update's own fusion: the
+        # step differentiates AT the compute copy (the gradient has its dtype, and its consumers upcast inside their own
+        # fusions) and the update writes the next step's copy beside the new master. The copy is carried where one exists
+        # and the master lies on the device: not under fp32 compute (no copy), parameter or optimizer offload (the master
+        # is kept OFF the device on purpose, or is replaced on the host), compression (it rewrites the copy every step),
+        # nor where ZeRO stage 1 or 2 spreads the optimizer's state over chips that each hold the whole master: the update
+        # then runs on a shard and its results are all-gathered, the copy's 2 bytes on top of the master's 4 (seen in the
+        # executable for four described chips), which costs more than casting the gathered master as the step always did.
+        cast = lambda tree: _cast_tree(tree, compute_dtype)
+        state_on_shards = self.config.zero_config.stage in (1, 2) and self.topology.data_parallel_size > 1
+        self._cast_copy = None  # master -> the carried compute copy (``_compute_params``); None: nothing is carried
+        if (compute_dtype != jnp.float32 and comp is None and not use_zeropp and not self._param_offload
+                and self._host_offload is None and not state_on_shards):
+            self._cast_copy = jax.jit(cast, out_shardings=self.param_shardings)
+        copy_shardings = None if self._cast_copy is None else self.param_shardings
         # grad-accumulation dtype (reference data_types.grad_accum_dtype,
         # config.py:898): bf16 halves the accumulator's HBM footprint and
         # add bandwidth across the gas window; the optimizer math still
@@ -540,14 +545,59 @@ class DeepSpeedEngine:
                 lambda g: jax.tree_util.tree_map(lambda x: x.astype(self._grad_acc_dtype), g),
                 out_shardings=self.grad_shardings)
 
+        def scaled_loss_fn(params_c, batch, rng, scale, comp_state):
+            if comp is not None:
+                params_c = comp.apply(params_c, comp_state)
+            # read by the model while its loss is traced: the gather plan, and who takes what it counts on the device
+            with zero_overlap.active(gather_plan), device_counts.collecting() as reported:
+                loss = loss_fn(params_c, batch, rng)
+            return (loss * scale).astype(jnp.float32), ((loss, reported) if reported else loss)  # nothing counted: the loss alone
+
+        copy_path = "cast" if self._cast_copy is None else "carried_copy"
+
+        def differentiate(params, batch, step, scale, comp_state, grads_dtype):
+            """(loss and what the model reported, gradients in ``grads_dtype``) at the compute copy. ``params`` is the
+            carried copy, or the master, which is cast here, OUTSIDE the differentiated function (a copy is cast to
+            itself: nothing), so the cotangent that comes back has the compute dtype."""
+            rng = jax.random.fold_in(base_rng, step)  # rng derivation lives inside the jit: one less per-step dispatch
+            with region("optimizer", path=copy_path, grads=jnp.dtype(grads_dtype).name):
+                params_c = cast(params)
+            (_, loss_and_reported), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
+                params_c, batch, rng, scale, comp_state)
+            with region("optimizer"):
+                return loss_and_reported, _cast_tree(grads, grads_dtype)
+
+        def fwd_bwd(params, batch, step, scale, comp_state):
+            # A gradient that LEAVES its program is float32, widened inside the program that made it, ahead of the
+            # sharding its outputs ask for: op for op the program that differentiated at the master through the cast, so
+            # no cross-chip reduction's dtype falls and the accumulator sums what it always did. (Inside ``fused_step``
+            # it never leaves: the update reads the cotangent as the backward made it, in the compute dtype.)
+            return differentiate(_fetch(params), batch, step, scale, comp_state, jnp.float32)
+
+        if use_zeropp:
+            zpp = build_zeropp_fwd_bwd(loss_fn, self.param_specs, self.grad_specs,
+                                       self.topology, self.config, compute_dtype)
+            self._fwd_bwd = lambda p, b, step, s: zpp(p, b, jax.random.fold_in(base_rng, step), s)
+        elif comp is None:
+            self._fwd_bwd = jax.jit(lambda p, b, step, s: fwd_bwd(p, b, step, s, None),
+                                    out_shardings=(None, self.grad_shardings))
+        else:
+            self._fwd_bwd_comp = jax.jit(fwd_bwd, out_shardings=(None, self.grad_shardings))
+            self._fwd_bwd = lambda p, b, step, s: self._fwd_bwd_comp(p, b, step, s, comp.comp_state())
+
+        def accumulate(acc, grads):
+            return jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), acc, grads)
+
+        self._accumulate = jax.jit(accumulate, donate_argnums=(0,), out_shardings=self.grad_shardings)
+
         clip = self.config.gradient_clipping
         opt = self.optimizer
 
-        def apply_updates(params32, opt_state, acc_grads, inv_scale, lr):
-            with region("optimizer"):  # unscale, global norm, the update, the cast back
-                return _apply_updates(params32, opt_state, acc_grads, inv_scale, lr)
+        def apply_updates(params32, params_c, opt_state, acc_grads, inv_scale, lr):
+            with region("optimizer"):  # unscale, global norm, the update, the next step's compute copy
+                return _apply_updates(params32, params_c, opt_state, acc_grads, inv_scale, lr)
 
-        def _apply_updates(params32, opt_state, acc_grads, inv_scale, lr):
+        def _apply_updates(params32, params_c, opt_state, acc_grads, inv_scale, lr):
             params32 = _fetch(params32)
             grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) * inv_scale, acc_grads)
             finite = _all_finite(grads)
@@ -563,13 +613,17 @@ class DeepSpeedEngine:
             # overflow => skip the step entirely (reference stage_1_and_2.py:1995)
             pick = lambda new, old: jax.tree_util.tree_map(
                 lambda n, o: jnp.where(finite, n, o), new, old)
-            return pick(new_params, params32), pick(new_opt_state, opt_state), gnorm, ~finite
+            new_params = pick(new_params, params32)
+            # the next step's compute copy, from the value that is written as the new master (a skipped step's is the old
+            # master's, and the old copy, donated for its buffer, is not read); None where none is carried
+            new_params_c = None if params_c is None else cast(new_params)
+            return new_params, new_params_c, pick(new_opt_state, opt_state), gnorm, ~finite
 
-        # donate params+opt_state only: their buffers alias the outputs
-        # one-to-one (donating grads too leaves an unusable donated buffer —
-        # XLA's "Some donated buffers were not usable" warning)
-        self._apply_updates = jax.jit(apply_updates, donate_argnums=(0, 1),
-                                      out_shardings=(param_out_shardings, self.opt_state_shardings,
+        # donate params, the copy and opt_state only: their buffers alias the
+        # outputs one-to-one (donating grads too leaves an unusable donated
+        # buffer — XLA's "Some donated buffers were not usable" warning)
+        self._apply_updates = jax.jit(apply_updates, donate_argnums=(0, 1, 2),
+                                      out_shardings=(param_out_shardings, copy_shardings, self.opt_state_shardings,
                                                      None, None))
 
         # one-dispatch fused step: fwd+bwd+optimizer in a single XLA module.
@@ -587,24 +641,20 @@ class DeepSpeedEngine:
             # only while gas == 1 — set_train_batch_size can move gas in
             # either direction at runtime
 
-            def fused_step(params32, opt_state, batch, step, scale, inv_scale, lr):
-                rng = jax.random.fold_in(base_rng, step)
+            def fused_step(params32, params_c, opt_state, batch, step, scale, inv_scale, lr):
                 params_dev = _fetch(params32)  # one stream-in, shared by grad + update
-                (_, loss_and_reported), grads = jax.value_and_grad(scaled_loss_fn, has_aux=True)(
-                    params_dev, batch, rng, scale, None)
-                new_params, new_opt_state, gnorm, overflow = apply_updates(params_dev, opt_state, grads,
-                                                                           inv_scale, lr)
-                return loss_and_reported, new_params, new_opt_state, gnorm, overflow
+                loss_and_reported, grads = differentiate(params_dev if params_c is None else params_c, batch, step, scale, None,
+                                                         compute_dtype)
+                return (loss_and_reported, *apply_updates(params_dev, params_c, opt_state, grads, inv_scale, lr))
 
             self._fused_step = jax.jit(
-                fused_step, donate_argnums=(0, 1),
-                out_shardings=(None, param_out_shardings, self.opt_state_shardings, None, None))
+                fused_step, donate_argnums=(0, 1, 2),
+                out_shardings=(None, param_out_shardings, copy_shardings, self.opt_state_shardings, None, None))
             if self.config.wall_clock_breakdown and self.gradient_accumulation_steps == 1:
                 self._log_fused_timer_note()
 
-        def eval_loss(params32, batch, rng):
-            params_c = _cast_tree(_fetch(params32), compute_dtype)
-            return loss_fn(params_c, batch, rng)
+        def eval_loss(params, batch, rng):  # the carried copy, or the master
+            return loss_fn(cast(_fetch(params)), batch, rng)
 
         self._eval_loss = jax.jit(eval_loss)
 
@@ -631,21 +681,18 @@ class DeepSpeedEngine:
             self._fwd_bwd = lambda p, b, step, s: base_fwd_bwd(jax.device_put(p, dev_sh), b, step, s)
             self._eval_loss = lambda p, b, rng: base_eval(jax.device_put(p, dev_sh), b, rng)
 
-            def apply_with_swap(params_host, opt_state, acc_grads, inv_scale, lr):
-                new_p, new_opt, gnorm, ovf = base_apply(jax.device_put(params_host, dev_sh),
-                                                        opt_state, acc_grads, inv_scale, lr)
-                return jax.device_put(new_p, host_sh), new_opt, gnorm, ovf
+            def apply_with_swap(params_host, params_c, *rest):
+                new_p, new_c, *out = base_apply(jax.device_put(params_host, dev_sh), params_c, *rest)
+                return (jax.device_put(new_p, host_sh), new_c, *out)
 
             self._apply_updates = apply_with_swap
 
             if self._fused_step is not None:
                 base_fused = self._fused_step
 
-                def fused_with_swap(params_host, opt_state, batch, step, scale, inv_scale, lr):
-                    loss, new_p, new_opt, gnorm, ovf = base_fused(jax.device_put(params_host, dev_sh),
-                                                                  opt_state, batch, step, scale,
-                                                                  inv_scale, lr)
-                    return loss, jax.device_put(new_p, host_sh), new_opt, gnorm, ovf
+                def fused_with_swap(params_host, params_c, *rest):
+                    loss, new_p, *out = base_fused(jax.device_put(params_host, dev_sh), params_c, *rest)
+                    return (loss, jax.device_put(new_p, host_sh), *out)
 
                 self._fused_step = fused_with_swap
 
@@ -720,13 +767,15 @@ class DeepSpeedEngine:
                     and not profiling and getattr(self, "_training", True)):
                 lr = self._next_lr()
                 inv_scale = 1.0 / self.loss_scaler.loss_scale
-                args = (self.params, self.opt_state, batch, self.micro_steps, scale, inv_scale, lr)
-                loss, self.params, self.opt_state, gnorm, overflow = self._step_program(
+                self._compute_params()
+                args = (self.params, self._params_c, self.opt_state, batch, self.micro_steps, scale, inv_scale, lr)
+                loss, params, params_c, self.opt_state, gnorm, overflow = self._step_program(
                     "fused_step", self._fused_step, args, batch)
+                self._install(params, params_c)
                 self._fused_pending = (gnorm, overflow, lr)
                 self._cached_grads = _FUSED
             else:
-                args = (self.params, batch, self.micro_steps, scale)
+                args = (self._compute_params(), batch, self.micro_steps, scale)
                 loss, grads = self._step_program("fwd_bwd", self._fwd_bwd, args, batch)
                 self._cached_grads = grads
             loss = self._take_reported(loss, sp)
@@ -762,6 +811,7 @@ class DeepSpeedEngine:
         counted = ("layers", "regathers", "rings", "head")
         before = [zero_overlap.traced(what) for what in counted]
         paths_before = _paths_traced()
+        copy_before = {label: regions_traced_by("optimizer", label) for label in ("path", "grads")}
         notes = {}
         with first_call("train", name, notes):
             self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
@@ -771,6 +821,12 @@ class DeepSpeedEngine:
                          if self._step_flops_by_phase else {})
             with sp.phase("dispatch"):
                 out = program(*args)
+            # where the step took its compute copy from, and the dtype its gradient reaches the update (``fused_step``) or
+            # leaves the program (``fwd_bwd``) in, as ``_build_compiled_fns::differentiate`` counted them in this trace
+            rose = {label: "+".join(sorted(word for word, n in regions_traced_by("optimizer", label).items() if n > was.get(word, 0)))
+                    for label, was in copy_before.items()}
+            if rose["path"]:
+                notes.update(compute_copy=rose["path"].replace("carried_copy", "carried"), grads=rose["grads"])
             layers, regathers, rings, head = (zero_overlap.traced(what) - was for what, was in zip(counted, before))
             notes.update(grad_reduce="bucket" if layers or head else "xla", bucket_layers=layers, bucket_rings=rings,
                          bucket_regather=regathers, bucket_head=int(head > 0))
@@ -910,8 +966,10 @@ class DeepSpeedEngine:
                         if not overflow:
                             self.params = new_params
                     else:
-                        self.params, self.opt_state, gnorm, overflow = self._apply_updates(
-                            self.params, self.opt_state, self._grad_acc, inv_scale, lr)
+                        self._compute_params()  # a copy dropped since the forward is made again: the update takes its buffer
+                        params, params_c, self.opt_state, gnorm, overflow = self._apply_updates(
+                            self.params, self._params_c, self.opt_state, self._grad_acc, inv_scale, lr)
+                        self._install(params, params_c)
             self._grad_acc = None
             self._global_grad_norm = gnorm
             self._last_overflow = overflow
@@ -1013,7 +1071,7 @@ class DeepSpeedEngine:
         self.flops_profiler = FlopsProfiler(ds_engine=self,
                                             recompute_fwd_factor=self.config.flops_profiler.recompute_fwd_factor)
         self.flops_profiler.analyze_fn(lambda p, b, st, s: self._fwd_bwd(p, b, st, s),
-                                       self.params, batch, step, scale, params_tree=self.params)
+                                       self._compute_params(), batch, step, scale, params_tree=self.params)
         self.flops_profiler.start_profile()
 
     def _stop_flops_profile(self):
@@ -1090,7 +1148,7 @@ class DeepSpeedEngine:
         # disjoint from the train-step folds, which use micro_steps directly
         # (fold_in data must be non-negative: it coerces to uint32)
         rng = rng if rng is not None else jax.random.fold_in(self._rng, (1 << 30) + self.micro_steps)
-        return self._eval_loss(self.params, batch, rng)
+        return self._eval_loss(self._compute_params(), batch, rng)
 
     def zero_grad(self):
         if self._fused_pending is not None:

@@ -569,3 +569,151 @@ def test_a_layer_that_gathers_again_holds_less_than_one_that_keeps(zero3_step):
     weights."""
     kept, again = zero3_step("default")[1], zero3_step("one_block")[1]
     assert 60e6 < kept - again < 2 * OLMO_BLOCK, (kept, again)
+
+
+# ---------------------------------------------------------------- the casts around Adam (PR 56)
+
+_ENTRY_INST = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$")
+
+
+def _entry(hlo_text):
+    """{own name: (result type less its layout, opcode, operands)} of the executable's ENTRY computation."""
+    insts = {}
+    for line in hlo_text[hlo_text.index("\nENTRY "):].splitlines():
+        m = _ENTRY_INST.match(line)
+        if m:
+            own, shape, opcode, rest = m.groups()
+            insts[own] = (re.sub(r"\{[^}]*\}", "", shape), opcode, re.findall(r"%([\w.\-]+)", rest.split(", metadata=")[0].split(", calls=")[0]))
+    return insts
+
+
+def _engine_step(engine):
+    """The Python function under the engine's jitted ``fused_step`` and the shapes of its state, as the engine hands them."""
+    fn = engine._fused_step
+    while not hasattr(fn, "lower") and hasattr(fn, "__wrapped__"):
+        fn = fn.__wrapped__
+    engine._compute_params()
+    return fn.__wrapped__, (engine.params, engine._params_c, engine.opt_state)
+
+
+def test_the_master_crosses_to_the_compute_dtype_inside_the_update_and_nowhere_else(one_chip, monkeypatch):
+    """The engine's ``fused_step`` of a small routed decoder (lane-wide widths, the grouped matmul, the row sum and the
+    flash kernels as custom calls, as on the chip), compiled for the described v5e. The float32 master is read by the
+    update's fusions alone, which write the next step's bf16 copy beside the new master and moments: no ``convert`` of a
+    whole parameter stands by itself ahead of the forward (at the parent one a leaf did), and the carried copy is what
+    the forward's products read. No gradient is widened by a pass of its own either: whatever makes a float32 array of
+    a weight's shape is an update fusion (it reads that leaf's moments)."""
+    import types
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models import CausalLM, TransformerConfig
+    from deepspeed_tpu.ops.pallas import _utils
+    from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    seq = 1024
+    model = CausalLM(TransformerConfig(vocab_size=1536, n_layers=2, n_heads=2, n_kv_heads=2, head_dims=128, d_model=384, max_seq_len=seq,
+                                       norm="rmsnorm", activation="swiglu", pos_emb="rope", tie_embeddings=False, dtype=BF16,
+                                       layer_kinds=(("full", "routed"),) * 2, moe_num_experts=8, moe_top_k=2, moe_d_ff=256,
+                                       moe_shared_d_ff=256, moe_aux_loss_coef=0.0))
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, seq), np.int32)})
+    reset_mesh()
+    try:
+        topo = initialize_mesh(MeshConfig.from_dict({"data": 1}), devices=jax.devices()[:1], force=True)
+        engine = deepspeed_tpu.initialize(model=model, model_parameters=params, mesh=topo, config={
+            "train_micro_batch_size_per_gpu": 1, "bf16": {"enabled": True}, "optimizer": {"type": "adam", "params": {"lr": 1e-4}},
+            "zero_optimization": {"stage": 0}, "steps_per_print": 10**9})[0]
+        step, state = _engine_step(engine)
+        shapes = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype, sharding=one_chip), state)
+        batch = {"input_ids": S((1, seq), I32, sharding=one_chip)}
+        # the model asks the backend which form of a kernel to take, and the kernels ask the attached TPU for its VMEM
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(_utils.pltpu, "get_tpu_info", lambda: types.SimpleNamespace(vmem_capacity_bytes=128 << 20))
+        text = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*shapes, batch, 0, 1.0, 1.0, 1e-4).compile().as_text()
+    finally:
+        reset_mesh()
+    assert "Some donated buffers were not usable" not in text and text.count("tpu_custom_call") >= 8
+    insts = _entry(text)
+    weights = {tuple(x.shape) for x in jax.tree_util.tree_leaves(state[0]) if x.ndim >= 2}
+    big = lambda own, dtype: any(insts[own][0] == f"{dtype}[{','.join(map(str, w))}]" for w in weights)
+    plumbing = ("parameter", "tuple", "get-tuple-element", "bitcast", "copy", "copy-start", "copy-done", "slice-start", "slice-done", "custom-call")
+    masters = {own for own, (_, opcode, _) in insts.items() if opcode == "parameter" and own.startswith("params32") and big(own, "f32")}
+    assert len(masters) == len([x for x in jax.tree_util.tree_leaves(state[0]) if x.ndim >= 2])
+    through = set(masters)  # ... and what only hands a master on (a layout copy, a tuple's element)
+    for own, (_, opcode, operands) in insts.items():  # the text lists an instruction after its operands
+        if opcode in plumbing and through & set(operands):
+            through.add(own)
+    readers = {own for own, (_, opcode, operands) in insts.items() if opcode not in plumbing and through & set(operands)}
+    kind = lambda dtype: [f"{dtype}[{','.join(map(str, w))}]" for w in weights]
+    # an update fusion: the new copy, the new master and both new moments of one weight (and its share of the norm)
+    update = lambda own: insts[own][1] == "fusion" and any(insts[own][0].count(f32) == 3 and bf16 in insts[own][0] for f32, bf16 in zip(kind("f32"), kind("bf16")))
+    assert len(readers) >= len(masters) and all(update(own) for own in readers), {own: insts[own][:2] for own in readers if not update(own)}
+    widened = {own: insts[own][:2] for own, (shape, opcode, operands) in insts.items()
+               if opcode in ("convert", "fusion") and shape in kind("f32") and any(insts[o][0] == shape.replace("f32", "bf16") for o in operands if o in insts)}
+    assert not widened, widened
+
+
+_REDUCTION = re.compile(r"= (\(.*?\)|\S+) (?:all-reduce|reduce-scatter)(?:-start)?\(|= (\(.*?\)|\S+) fusion\(.*calls=%all-reduce-scatter")
+
+
+def reduced(hlo_text, shapes):
+    """{dtype: how many arrays of one of ``shapes`` the executable's all-reduces and reduce-scatters (the fused form
+    too) produce}: the weight gradients' reductions across chips, by the dtype they run in. A reduce-scatter's result is
+    a shard: a shape counts with any ONE dimension divided by the four chips too."""
+    shards = {tuple(d // 4 if j == i else d for j, d in enumerate(s)) for s in shapes for i in range(len(s)) if s[i] % 4 == 0}
+    out = {}
+    for m in _REDUCTION.finditer(hlo_text):
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", re.sub(r"\{[^}]*\}", "", m.group(1) or m.group(2))):
+            if tuple(map(int, dims.split(","))) in set(shapes) | shards:
+                out[dtype] = out.get(dtype, 0) + 1
+    return out
+
+
+@pytest.mark.parametrize("stage,carried", [(0, True), (1, False)])
+def test_no_gradient_reduction_across_chips_runs_in_a_lower_dtype_than_the_parents(topo, monkeypatch, stage, carried):
+    """``data: 4`` under the partitioner (no plan of ``zero/overlap.py``), the engine's ``fused_step`` and ``fwd_bwd``
+    compiled for the four described chips. The partitioner reduces a weight's gradient where its product makes the
+    partial sums, in the product's dtype: bf16 for the decoder's matmuls, float32 for the tied embedding (its two
+    cotangents are summed first) and the norms' scales; the counts below are the PARENT's executables' (compiled from its
+    tree at PR 56), where the step differentiated at the master through the cast. Stage 0 carries the compute copy
+    (every chip updates the whole state); at stage 1 the update runs on a shard of the state and its results are
+    all-gathered, so nothing is carried and no bf16 copy is gathered beside the master."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import CausalLM, TransformerConfig
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.parallel.mesh import MeshTopology, initialize_mesh, reset_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    model = CausalLM(TransformerConfig(vocab_size=384, n_layers=2, n_heads=2, d_model=256, max_seq_len=128, dtype=BF16, tie_embeddings=True))
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 16), np.int32)})
+    reset_mesh()
+    try:
+        here = initialize_mesh(MeshConfig.from_dict({"data": 4}), devices=jax.devices()[:4], force=True)
+        engine = deepspeed_tpu.initialize(model=model, model_parameters=params, mesh=here, config={
+            "train_micro_batch_size_per_gpu": 2, "bf16": {"enabled": True}, "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": stage}, "steps_per_print": 10**9})[0]
+        step, state = _engine_step(engine)
+        assert (state[1] is not None) == carried
+        there = MeshTopology(MeshConfig.from_dict({"data": 4}), devices=list(topo.devices))
+        monkeypatch.setattr(mesh_mod, "_TOPOLOGY", there)  # what the model asks for its mesh while it is traced
+        move = lambda tree: jax.tree_util.tree_map(lambda sh: jax.sharding.NamedSharding(there.mesh, sh.spec), tree)
+        shapes = jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype, sharding=move(x.sharding)), state)
+        batch = {"input_ids": S((8, 96), I32, sharding=there.batch_sharding())}  # 768 rows: no activation has a weight's shape
+        outs = (None, move(engine.param_shardings), move(engine.param_shardings) if carried else None, move(engine.opt_state_shardings), None, None)
+        fused = jax.jit(step, donate_argnums=(0, 1, 2), out_shardings=outs).lower(*shapes, batch, 0, 1.0, 1.0, 1e-3).compile().as_text()
+        fwd_bwd = engine._fwd_bwd
+        while not hasattr(fwd_bwd, "lower"):
+            fwd_bwd = fwd_bwd.__wrapped__
+        split = jax.jit(fwd_bwd.__wrapped__, out_shardings=(None, move(engine.grad_shardings))).lower(
+            shapes[1] if carried else shapes[0], batch, 0, 1.0).compile().as_text()
+    finally:
+        reset_mesh()
+    weights = {tuple(x.shape) for x in jax.tree_util.tree_leaves(state[0])}
+    assert (reduced(fused, weights), reduced(split, weights)) == PARENTS_REDUCTIONS[stage]
+    # (the tied embedding's rows are gathered in bf16 for the look-up and the head at the parent too: not the copy)
+    gathered = [shape for shape in re.findall(r"= (\S+) all-gather(?:-start)?\(", fused) if shape.startswith("bf16")]
+    assert not [s for s in gathered if any(s.startswith(f"bf16[{','.join(map(str, w))}]") for w in weights if len(w) >= 2 and w[0] != 384)], gathered
+
+
+# (fused_step, fwd_bwd) by stage: _scratch-style, the parent's tree through this file's ``reduced`` (PR 56)
+PARENTS_REDUCTIONS = {0: ({"bf16": 37}, {"bf16": 37}), 1: ({"f32": 16, "bf16": 49}, {"bf16": 37})}

@@ -22,6 +22,20 @@ def test_chip_smoke_fails_without_a_chip():
     assert "no TPU" in proc.stderr
 
 
+def test_the_rehearsal_drives_the_trainer_phase_to_its_end():
+    """``--rehearse --only trainer`` lowers the engine's ``fused_step`` with the smoke's own arguments and takes its
+    steps: a change of that program's signature (PR 56: the compute copy beside the master) fails here and not first
+    on the chip."""
+    import json
+
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py"), "--rehearse", "--only", "trainer"],
+                          cwd=REPO, env=dict(os.environ), capture_output=True, text=True, timeout=600)
+    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
+    phase = [l for l in lines if l.get("phase") == "trainer"]
+    assert proc.returncode == 0 and len(phase) == 1 and phase[0]["ok"], (proc.stdout[-2000:], proc.stderr[-2000:])
+    assert lines[-1] == {"ok": False, "rehearsal": "passed", "device": lines[-1]["device"]}  # never the chip's ok line
+
+
 @pytest.mark.parametrize("env_dir", [None, "/some/where/placed/from/outside"])
 def test_compile_cache_dir_is_placed_from_outside(env_dir, monkeypatch):
     """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the one fixed

@@ -535,3 +535,135 @@ def test_initialize_with_init_fn():
     engine.backward(loss)
     engine.step()
     assert engine.was_step_applied()
+
+
+# ------------------------------------------------------------------ the compute copy and the gradient's dtype (PR 56)
+def _small_decoder(dtype):
+    from deepspeed_tpu.models import TransformerConfig
+
+    model = CausalLM(TransformerConfig(vocab_size=64, n_layers=2, n_heads=2, d_model=32, max_seq_len=32, dtype=dtype))
+    return model, model.init(jax.random.PRNGKey(3), {"input_ids": np.zeros((1, 16), np.int32)})
+
+
+def _one_device_engine(model, params, extra):
+    from deepspeed_tpu.parallel.mesh import initialize_mesh
+    from deepspeed_tpu.runtime.config import MeshConfig
+
+    topo = initialize_mesh(MeshConfig.from_dict({"data": 1}), devices=jax.devices()[:1], force=True)
+    config = {"train_micro_batch_size_per_gpu": 2, "optimizer": {"type": "adamw", "params": {"lr": 1e-2, "weight_decay": 0.01}},
+              "zero_optimization": {"stage": 0}, "steps_per_print": 10**9, **extra}
+    return deepspeed_tpu.initialize(model=model, model_parameters=params, mesh=topo, config=config)[0]
+
+
+def _bits(tree):
+    return [np.asarray(x).view(np.uint32 if x.dtype == jnp.float32 else np.uint16) for x in jax.tree_util.tree_leaves(tree)]
+
+
+def _same_bits(ours, theirs):
+    return all(np.array_equal(a, b) for a, b in zip(_bits(ours), _bits(theirs), strict=True))
+
+
+COPY_CASES = {
+    "fused": dict(extra={"bf16": {"enabled": True}}, dtype=jnp.bfloat16),
+    "gas2": dict(extra={"bf16": {"enabled": True}, "gradient_accumulation_steps": 2}, dtype=jnp.bfloat16, gas=2),
+    "fp16_overflow": dict(extra={"fp16": {"enabled": True, "initial_scale_power": 19, "hysteresis": 1}}, dtype=jnp.float16, steps=6),
+    "fp32": dict(extra={}, dtype=jnp.float32, copy=False),
+    "offload_param": dict(extra={"bf16": {"enabled": True}, "zero_optimization": {
+        "stage": 3, "stage3_param_persistence_threshold": 0, "offload_param": {"device": "cpu"}}}, dtype=jnp.bfloat16, copy=False),
+    "load_checkpoint": dict(extra={"bf16": {"enabled": True}}, dtype=jnp.bfloat16, reload="native"),
+    "load_universal": dict(extra={"bf16": {"enabled": True}}, dtype=jnp.bfloat16, reload="universal"),
+}
+
+
+@pytest.mark.parametrize("case", list(COPY_CASES))
+def test_the_step_is_bit_for_bit_the_one_that_differentiates_at_the_master(case, tmp_path):
+    """The engine differentiates at the compute copy and carries that copy from
+    one step's update to the next step's forward. Against a plain reference
+    written here (the gradient taken at the float32 master through an inline
+    cast, optax's ``adamw``, ``where(finite, new, old)``): the master, both
+    moments and every loss are the same TO THE BIT, and after every step the
+    carried copy is ``cast(master)``: fused, under gradient accumulation (whose
+    accumulator keeps ``grad_accum_dtype``), through a skipped fp16 step (old
+    master, and a copy equal to its cast); no copy is held under fp32 compute
+    nor with the master offloaded; and a load between steps drops the copy (the
+    next loss is a fresh engine's from the same checkpoint)."""
+    import optax
+
+    from deepspeed_tpu.checkpoint.universal import load_universal_checkpoint, save_universal_checkpoint
+
+    spec = COPY_CASES[case]
+    dtype, gas, carried = spec["dtype"], spec.get("gas", 1), spec.get("copy", True)
+    model, params = _small_decoder(dtype)
+    params = jax.tree_util.tree_map(np.asarray, params)  # on the host: the engine's steps donate what it was handed
+    engine = _one_device_engine(model, params, spec["extra"])
+    assert (engine._cast_copy is not None) == carried and engine._params_c is None
+    assert engine._fused_step is not None
+
+    cast = lambda tree: jax.tree_util.tree_map(lambda x: x.astype(dtype), tree)
+    opt = optax.inject_hyperparams(optax.adamw)(learning_rate=1e-2, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.01)
+    base_rng = engine._rng
+
+    @jax.jit
+    def grads_at_master(master, batch, step, scale):
+        def scaled(p32):
+            loss = model.loss_fn(cast(p32), batch, jax.random.fold_in(base_rng, step))
+            return (loss * scale).astype(jnp.float32), loss
+        (_, loss), grads = jax.value_and_grad(scaled, has_aux=True)(master)
+        return loss, grads
+
+    @jax.jit
+    def update(master, state, grads, inv_scale):
+        grads = jax.tree_util.tree_map(lambda g: g * inv_scale, grads)
+        finite = jnp.all(jnp.stack([jnp.all(jnp.isfinite(g)) for g in jax.tree_util.tree_leaves(grads)]))
+        updates, new_state = opt.update(grads, state, master)
+        pick = lambda new, old: jax.tree_util.tree_map(lambda n, o: jnp.where(finite, n, o), new, old)
+        return pick(optax.apply_updates(master, updates), master), pick(new_state, state), finite
+
+    master = jax.tree_util.tree_map(lambda x: jnp.asarray(x, jnp.float32), params)
+    state = opt.init(master)
+    rng, skipped, micro = np.random.RandomState(1), 0, 0
+    for step in range(spec.get("steps", 4)):
+        scale = engine.loss_scaler.loss_scale
+        acc = None
+        for _ in range(gas):
+            batch = {"input_ids": rng.randint(0, 64, (2, 16)).astype(np.int32)}
+            loss = engine.forward(batch)
+            engine.backward(loss)
+            want, grads = grads_at_master(master, batch, micro, scale / gas)
+            acc = grads if acc is None else jax.tree_util.tree_map(jnp.add, acc, grads)
+            micro += 1
+            assert np.asarray(loss).tobytes() == np.asarray(want).tobytes(), (case, step)
+            if gas > 1:
+                assert {x.dtype for x in jax.tree_util.tree_leaves(engine._grad_acc)} == {jnp.dtype(jnp.float32)}
+        before = master
+        engine.step()
+        master, state, finite = update(master, state, acc, 1.0 / scale)
+        skipped += int(not finite)
+        if not finite:
+            assert _same_bits(master, before) and not engine.was_step_applied()
+        assert _same_bits(engine.params, master), (case, step)
+        for moment in ("mu", "nu"):
+            assert _same_bits(optax.tree_utils.tree_get(engine.opt_state, moment), optax.tree_utils.tree_get(state, moment)), (case, step, moment)
+        if carried:
+            assert {x.dtype for x in jax.tree_util.tree_leaves(engine._params_c)} == {jnp.dtype(dtype)}
+            assert _same_bits(engine._params_c, cast(master)), (case, step)
+        else:
+            assert engine._params_c is None
+        if spec.get("reload") and step == 1:  # a checkpoint of step 2, loaded over step 3's state further down
+            if spec["reload"] == "native":
+                engine.save_checkpoint(str(tmp_path / "ckpt"), tag="two")
+            else:
+                save_universal_checkpoint(engine, str(tmp_path / "ckpt"), tag="two")
+    assert (0 < skipped < 6 if case == "fp16_overflow" else skipped == 0) and engine.skipped_steps == skipped, skipped
+    if spec.get("reload"):
+        fresh = _one_device_engine(model, params, spec["extra"])
+        for e in (engine, fresh):
+            if spec["reload"] == "native":
+                e.load_checkpoint(str(tmp_path / "ckpt"), tag="two")
+            else:
+                load_universal_checkpoint(e, str(tmp_path / "ckpt"), tag="two")
+            assert e._params_c is None  # the master was replaced from outside a step: the copy cast from the old one is gone
+        batch = {"input_ids": rng.randint(0, 64, (2, 16)).astype(np.int32)}
+        losses = [e.forward(batch) for e in (engine, fresh)]
+        assert np.asarray(losses[0]).tobytes() == np.asarray(losses[1]).tobytes()
+        assert _same_bits(engine._params_c, cast(engine.params)) and _same_bits(engine.params, fresh.params)

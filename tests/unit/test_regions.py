@@ -373,3 +373,53 @@ def test_an_unarmed_trainer_step_touches_the_profiler_once(monkeypatch):
         assert len(asked) == (2 if step == 0 else 1), (step, asked)
         asked.clear()
     reset_mesh()
+
+
+# ------------------------------------------------------------------ the compute copy and the gradient's dtype (PR 56)
+@pytest.mark.parametrize("case,extra,chips,want", [
+    ("carried", {"bf16": {"enabled": True}}, 1, ("fused_step", "carried", "bfloat16")),
+    # the split path differentiates at the carried copy too, and its gradient leaves the program in float32, widened inside it
+    ("accumulated", {"bf16": {"enabled": True}, "gradient_accumulation_steps": 2}, 1, ("fwd_bwd", "carried", "float32")),
+    ("fp32", {}, 1, ("fused_step", "cast", "float32")),  # one dtype: there is no copy
+    # the optimizer's state on shards of chips that each hold the whole master: the update's results are gathered, no copy with them
+    ("state_on_shards", {"bf16": {"enabled": True}, "zero_optimization": {"stage": 1}}, 4, ("fused_step", "cast", "bfloat16")),
+])
+def test_the_first_call_line_says_where_the_compute_copy_came_from_and_the_gradients_dtype(case, extra, chips, want):
+    """``program_regions_traced_total{region="optimizer", path, grads}`` is counted where ``_build_compiled_fns``
+    takes the copy it differentiates at (once a trace of a step program), and the trainer's first-call line and span
+    say it as ``compute_copy=carried|cast grads=<dtype>``, ahead of ``grad_reduce=``."""
+    import logging
+
+    class Lines(logging.Handler):
+        lines = []
+
+        def emit(self, record):
+            self.lines.append(record.getMessage())
+
+    model = CausalLM(TransformerConfig(vocab_size=128, n_layers=1, n_heads=2, d_model=32, max_seq_len=32,
+                                       dtype=jnp.bfloat16 if "bf16" in extra else jnp.float32))
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 16), np.int32)})
+    bucket, copy, grads = want
+    series = lambda: get_registry().total("program_regions_traced_total", region="optimizer", path=copy + "_copy" * (copy == "carried"), grads=grads)
+    every = lambda: tracing.regions_traced("optimizer")
+    handler, logger = Lines(), logging.getLogger("deepspeed_tpu")
+    reset_mesh()
+    logger.addHandler(handler)
+    try:
+        topo = initialize_mesh(MeshConfig.from_dict({"data": chips}), devices=jax.devices()[:chips], force=True)
+        engine, _, _, _ = deepspeed_tpu.initialize(model=model, model_parameters=params, mesh=topo, config={
+            "train_micro_batch_size_per_gpu": 1, "optimizer": {"type": "adam", "params": {"lr": 1e-3}}, "steps_per_print": 10**9, **extra})
+        before, all_before = series(), every()
+        for _ in range(2 * engine.gradient_accumulation_steps):
+            loss = engine.forward({"input_ids": np.zeros((chips, 16), np.int32)})
+            engine.backward(loss)
+            engine.step()
+    finally:
+        logger.removeHandler(handler)
+        reset_mesh()
+    assert (series() - before, every() - all_before) == (1.0, 1.0)  # one trace of one step program, and no other series rose
+    assert (engine._params_c is not None) == (copy == "carried")
+    lines = [l for l in handler.lines if l.startswith("program first call: family=train")]
+    assert len(lines) == 1 and f"bucket={bucket} " in lines[0] and f" compute_copy={copy} grads={grads} grad_reduce=xla " in lines[0], lines
+    said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
+    assert (said["bucket"], said["compute_copy"], said["grads"]) == want
