@@ -132,7 +132,7 @@ def run_phase(name, fn):
     shows every fault, not the first."""
     from deepspeed_tpu.parallel.mesh import reset_mesh
 
-    reset_mesh()  # every phase builds its own mesh; kernels consult the global one (ops/pallas/_utils.on_mesh)
+    reset_mesh()  # every phase builds its own mesh; kernels consult the global one (ops/placement.py)
     hits0, miss0 = _cache_counts()
     c0, t0 = _COMPILE_SECS[0], time.perf_counter()
     line = {"phase": name, "ok": True}

@@ -131,7 +131,7 @@ class InferenceEngineV2:
             config.min_decode_bucket = max(1, knobs.get_int("DS_TPU_MIN_DECODE_BUCKET"))
         self.model = model
         cfg: TransformerConfig = model.cfg
-        if cfg.unstackable:  # by the kinds' records (``models/layers.py::LayerKind.stackable``)
+        if cfg.unstackable:  # by the kinds' records (``layer_kind.py::LayerKind.stackable``)
             raise NotImplementedError(
                 f"inference/v2 serves softmax attention over one head size with dense or capacity-gated MoE FFNs; this "
                 f"model has layers of kind {list(cfg.unstackable)}: a recurrent state beside the paged KV, a latent cache, a "

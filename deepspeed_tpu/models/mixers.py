@@ -13,7 +13,7 @@ block-diffusion mask over a doubled row, whose objective is a masked-token loss
 (``ShortConvMixer``). All are training-side
 modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
 ``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
-carries its kind's record (``layers.py::LayerKind``).
+carries its kind's record (``../layer_kind.py::LayerKind``).
 """
 
 from typing import Optional
@@ -29,7 +29,6 @@ from ..ops import masks
 from ..ops.attention import attention
 from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, kda
 from ..ops.pallas import short_conv
-from ..ops.registry import pallas_available
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
@@ -223,9 +222,8 @@ class MLAMixer(LayerKind, nn.Module):
             # and them (the slices, the rotation, the shared part's way into every head) is linear, so a checkpointed
             # block that keeps these makes neither ``q_proj`` nor ``kv_b_proj`` nor the rotation a second time
             q, k, v = checkpoint_name(q, SAVED), checkpoint_name(k, SAVED), checkpoint_name(kv[..., dn:], SAVED)
-        # a call of unequal head sizes is latent attention's: the flash kernels count it so; off the TPU it is counted here
-        with region("mixer/kernel", **({} if pallas_available() else {"op": "mla", "pass": "fwd", "path": "xla"})):
-            o = attention(q, k, v, causal=True, scale=(dn + dr)**-0.5)
+        # a call of unequal head sizes is latent attention's: the form that takes it counts it so (``op="mla"``)
+        o = attention(q, k, v, causal=True, scale=(dn + dr)**-0.5)
         with region("mixer/proj"):
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=f32)(o)
@@ -388,9 +386,8 @@ def _differential(mod, q, k, v, layer, window):
     elementwise work after them, in float32."""
     cfg = mod.cfg
     H, KVH, D, f32 = cfg.n_heads, cfg.kv_heads, cfg.head_dim, jnp.float32
-    with region("mixer/kernel", op="diff", path="kernel" if pallas_available() else "xla", **{"pass": "fwd"}):
-        pass  # counted a layer; the two calls count themselves where they choose (``ops/attention.py``)
-    maps = [attention(q[:, :, half], k[:, :, kv_half], v, causal=True, window=window, scale=D**-0.5)
+    # the two calls are counted as the layer's (``op="diff"``) by the form that takes them (``ops/attention.py``)
+    maps = [attention(q[:, :, half], k[:, :, kv_half], v, causal=True, window=window, scale=D**-0.5, count_as={"op": "diff"})
             for half, kv_half in ((slice(0, H // 2), slice(0, KVH // 2)), (slice(H // 2, H), slice(KVH // 2, KVH)))]
     with region("mixer/diff"):
         lq1, lk1, lq2, lk2 = (mod.param(name, nn.initializers.normal(0.1), (D,), f32)
@@ -576,9 +573,8 @@ class BlockDiffMixer(LayerKind, nn.Module):
                 pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32) % L, (B, S))
                 cos, sin = scaled_rope_frequencies(cfg, D)
                 q, k = (apply_rope(t, cos, sin, pos, style=cfg.rope_style) for t in (q, k))
-        # the kernels count themselves where they choose their walk; off the TPU XLA's form is counted here
-        with region("mixer/kernel", **({} if pallas_available() else {"op": "blockdiff", "pass": "fwd", "path": "xla"})):
-            out = attention(q, k, v, mask=masks.BlockDiffusion(cfg.block_length, L), scale=cfg.attn_scale or D**-0.5)
+        # the form that takes the call counts it under the mask's own name (``op="blockdiff"``)
+        out = attention(q, k, v, mask=masks.BlockDiffusion(cfg.block_length, L), scale=cfg.attn_scale or D**-0.5)
         with region("mixer/proj"):
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,
                                    param_dtype=jnp.float32)(out)

@@ -35,7 +35,7 @@ from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, Unr
 from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, ShortConvMixer,
                      SparseMixer, SSMMixer)
 
-# THE table of layer kinds. A kind is declared once: its flax module carries its record (``layers.py::LayerKind``) and has
+# THE table of layer kinds. A kind is declared once: its flax module carries its record (``../layer_kind.py::LayerKind``) and has
 # one line here; ``Block``, ``block_fn``, ``CausalLM.loss_fn``, ``runtime/engine.py`` and ``inference/v2/engine_v2.py`` read
 # the record and name no kind. Adding a kind: its module, one line here, its files under ``benchmarks/configs/``. Imports
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops.registry import pallas_available
+from ..layer_kind import LayerKind
+from ..ops import placement
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
@@ -56,7 +57,7 @@ class Experts(nn.Module):
         return jnp.einsum("ecf,efd->ecd", h, wo.astype(self.dtype))
 
 
-class MoE(nn.Module):
+class MoE(LayerKind, nn.Module):
     """Reference ``moe/layer.py:17``. Gated expert-parallel FFN layer.
 
     Input (B, S, d) or (N, d); output same shape. The auxiliary
@@ -76,10 +77,7 @@ class MoE(nn.Module):
     d_ff: Optional[int] = None
     activation: str = "gelu"
     dtype: Any = jnp.float32
-    # its record as the layer kind ``moe`` (``models/layers.py::LayerKind``, which is not mixed in: ``models/`` imports
-    # this module for its table, so this one imports nothing of it and says every name)
-    sows, stackable = ("losses", "intermediates"), True
-    report, keeps, hybrid, paths, path_words, joined, alone, takes = None, (), False, {}, {}, {}, False, ()
+    sows, stackable = ("losses", "intermediates"), True  # its record as the layer kind ``moe``
 
     @classmethod
     def from_config(cls, cfg, kind):
@@ -130,12 +128,6 @@ class MoE(nn.Module):
         return out
 
 
-def moe_path() -> str:
-    """How a routed layer's grouped products run: the Pallas grouped matmul on
-    a TPU, ``lax.ragged_dot`` elsewhere."""
-    return "kernel" if pallas_available() else "xla"
-
-
 def _count_rows(rows):
     reg = get_registry()
     reg.counter("moe_rows_routed_here_total").inc(float(rows[:, 0].sum()))
@@ -158,7 +150,7 @@ def report_rows(intermediates):
         device_counts.report("moe_rows", jnp.stack(rows), _count_rows)
 
 
-class RoutedMoE(nn.Module):
+class RoutedMoE(LayerKind, nn.Module):
     """A routed FFN as the share of it that is held here, plus a shared expert.
 
     ``scoring="sigmoid"``: scores are sigmoids over ALL ``num_experts``; a
@@ -199,11 +191,10 @@ class RoutedMoE(nn.Module):
     dtype: Any = jnp.float32
     renorm_eps: float = 1e-20  # sigmoid scoring: what is added to the sum the chosen scores are divided by
     act: str = "silu"  # the experts' gate (``sharded_moe.GATES``)
-    # its record as the layer kind ``routed`` (as ``MoE`` says its own). The line's keys: how the grouped products and the
-    # rows' sum were traced, the conditional's form where the buffer's first rung is smaller than every pair
-    # (``routed_part``: the rungs above it keep nothing), and how the router scores its tokens and indexes the expert axis
-    # (``compare_sum``: ``held_experts``)
-    sows, keeps, hybrid, alone, stackable, takes = ("intermediates",), (SAVED,), True, False, False, ()
+    # its record as the layer kind ``routed``. The line's keys: how the grouped products and the rows' sum were traced,
+    # the conditional's form where the buffer's first rung is smaller than every pair (``routed_part``: the rungs above
+    # it keep nothing), and how the router scores its tokens and indexes the expert axis (``compare_sum``: ``held_experts``)
+    sows, keeps, hybrid = ("intermediates",), (SAVED,), True
     paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
     path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
     joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu",), "act")}
@@ -239,7 +230,9 @@ class RoutedMoE(nn.Module):
         wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
                       for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
                                           ("wo", (count, self.d_ff, d))))
-        out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, moe_path() == "kernel", self.act)
+        # the rule's word; the products' own ``fits`` are ``sharded_moe._grouped``'s tiles and ``moe_sum_rows.fits``
+        kernel = placement.kernel_path() == "kernel"
+        out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, kernel, self.act)
         # (routed here, of them not computed, largest group, smallest group, the buffer's rung, routed here over the
         # uniform load in thousandths), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
@@ -268,22 +261,15 @@ class EarlyRoutedMoE(RoutedMoE):
 def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act="silu"):
     """``routed_part`` on one chip; on a mesh, inside a shard_map in which the
     tokens are split over the batch axes, the experts over ``expert``, and the
-    parts are summed over ``expert``."""
-    from ..ops.pallas._utils import on_mesh
-    from ..parallel.mesh import get_mesh_topology
-    from ..runtime.zero.partition import fit_spec, prune_spec
-
+    parts are summed over ``expert`` (``placement.on_mesh``: on one chip ``local`` is ``part``)."""
     def part(tokens, idx, weights, wg, wi, wo, first):
         """``routed_part``, and the pairs it found routed here over a uniform router's, in thousandths."""
         out, routed, *counts = routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
         uniform = idx.size * wg.shape[0] / num_experts
         return out, routed, *counts, jnp.round(routed.astype(jnp.float32) * (1000 / uniform)).astype(jnp.int32)
 
-    topo = get_mesh_topology(required=False)
-    if topo is None or topo.n_devices == 1:
-        return part(tokens, idx, weights, wg, wi, wo, first)
-    axis = topo.axis_size("expert")
-    rows = fit_spec(prune_spec(P(topo.batch_axes, None), topo), tokens.shape, topo)
+    axis = placement.axis_size("expert")
+    rows = placement.batch_spec(tokens.shape, None)
     held = P("expert", None, None) if axis > 1 and wg.shape[0] % axis == 0 else P()
     split = rows[0] if len(rows) and rows[0] is not None else ()
     over = (split if isinstance(split, tuple) else (split,)) + (("expert",) if held != P() else ())  # axes the pairs are spread over
@@ -299,7 +285,7 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
             smallest = jax.lax.pmin(smallest, over)
         return out, routed, dropped, largest, smallest, rung, over_uniform
 
-    return on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
+    return placement.on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
 
 
 def _mesh_has_axis(axis: str) -> bool:

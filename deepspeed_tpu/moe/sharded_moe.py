@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import placement
 from ..telemetry.tracing import region
 
 uniform_map = {}
@@ -214,7 +215,7 @@ def _grouped(xs, w, group_sizes, kernel: bool, row_tile: int = 256, **choice):
             return jax.lax.ragged_dot(xs, w, group_sizes)
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling, interpret=jax.default_backend() != "tpu")
+        return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling, interpret=placement.interpret())
 
 
 def _sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int):
@@ -225,7 +226,7 @@ def _sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int):
     # the forward's calls see no abstract mesh and the backward's an empty one: named here, both are one tracing
     # context, and ``jax.jit`` traces and lowers the kernel once a shape, not once a context (0.5 s each on the chip's host)
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
-        return sum_rows(rows, tok_of_row, w_row, spans, n_tokens, interpret=jax.default_backend() != "tpu")
+        return sum_rows(rows, tok_of_row, w_row, spans, n_tokens, interpret=placement.interpret())
 
 
 @jax.custom_vjp

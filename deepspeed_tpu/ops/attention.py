@@ -13,8 +13,13 @@ import jax
 import jax.numpy as jnp
 
 from ..telemetry.tracing import region
-from . import masks
+from . import masks, placement
 from .registry import get_op, register_op
+
+
+def _count_xla(q, v, record, count_as):
+    """XLA's forms count themselves as the flash kernels do: ``path="xla"`` under the caller's words or the ``op`` the kernels derive."""
+    placement.count(path="xla", **(count_as or {"op": masks.counted_op(record, v.shape[-1] != q.shape[-1])}))
 
 
 def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -37,7 +42,8 @@ def attention_xla(q: jnp.ndarray,
                   kv_len=None,
                   window: Optional[int] = None,
                   alibi_slopes: Optional[jnp.ndarray] = None,
-                  mask=None) -> jnp.ndarray:
+                  mask=None,
+                  count_as: Optional[dict] = None) -> jnp.ndarray:
     """Multi-head attention, shapes (B, S, H, D) / KV may have fewer heads (GQA).
 
     ``mask``: a record of ``ops/masks.py`` in place of ``causal`` / ``window``
@@ -49,6 +55,7 @@ def attention_xla(q: jnp.ndarray,
     (i - window, i].
     ``alibi_slopes``: (H,) per-head slopes — shift-invariant ALiBi bias
     ``slope_h * key_position`` (bloom).
+    ``count_as``: the caller's words for the count of this call site (``_count_xla``).
     Computed in fp32 accumulation regardless of input dtype (softmax
     numerics), returned in the input dtype. XLA fuses the whole block.
     """
@@ -72,6 +79,7 @@ def attention_xla(q: jnp.ndarray,
     sq, sk = q.shape[1], k.shape[1]
     # window means '(i - window, i]': it implies the causal upper bound even when causal=False, matching the flash kernel
     record = mask if mask is not None else masks.of(causal, window)
+    _count_xla(q, v, record, count_as)
     if record.masks or kv_len is not None:
         # offset supports decode where q is a suffix of the (valid) kv sequence
         valid = kv_len if kv_len is not None else sk
@@ -104,7 +112,8 @@ def attention_chunked(q: jnp.ndarray,
                       window: Optional[int] = None,
                       alibi_slopes: Optional[jnp.ndarray] = None,
                       chunk: int = 512,
-                      mask=None) -> jnp.ndarray:
+                      mask=None,
+                      count_as: Optional[dict] = None) -> jnp.ndarray:
     """Online-softmax attention over KV chunks — O(S·chunk) peak memory.
 
     The pure-XLA analogue of the flash kernel's memory behaviour (reference
@@ -121,10 +130,11 @@ def attention_chunked(q: jnp.ndarray,
     if segment_ids is not None:
         # packing: take the materializing oracle
         return attention_xla(q, k, v, causal=causal, scale=scale, bias=bias, segment_ids=segment_ids,
-                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask)
+                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask, count_as=count_as)
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1 (got {window}); pass None to disable the sliding window")
     record = mask if mask is not None else masks.of(causal, window)
+    _count_xla(q, v, record, count_as)
     orig_dtype = q.dtype
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -193,6 +203,7 @@ def attention_chunked(q: jnp.ndarray,
 
 
 def attention(q, k, v, **kwargs):
-    """Dispatch through the kernel registry (Pallas flash on TPU, XLA otherwise)."""
-    with region("mixer/kernel"):  # the call and the transposes around it; the kernels count which path they took
+    """Dispatch through the kernel registry (Pallas flash on TPU, XLA otherwise). ``count_as``: the words the caller alone knows for this call
+    site's count (``{"op": its kind's name, ...}``); the form that takes the call counts them with the path it is (``mixer/kernel``, ``pass="fwd"``)."""
+    with region("mixer/kernel"):  # the call and the transposes around it; the form that runs counts which path it is
         return get_op("attention")(q, k, v, **kwargs)

@@ -23,8 +23,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry.tracing import region
+from . import placement
 from .pallas import indexed_attention as kernel
-from .registry import pallas_available
 
 NEG_INF = kernel.NEG_INF
 # The name the choice, the attention call's output and row statistics and the index loss's cotangent (the loss's
@@ -35,24 +35,9 @@ SAVED = "sparse_attention"
 
 
 def path_for(seq: int, topk: int) -> str:
-    """``kernel`` where the Pallas calls take this sequence on this backend, else ``xla``. A mesh of several chips
-    takes the XLA forms too: the kernels sit in no ``shard_map`` yet (ROADMAP.md, Reach), and GSPMD cannot partition
-    a Mosaic call."""
-    from ..parallel.mesh import get_mesh_topology
-
-    topo = get_mesh_topology(required=False)
-    one_chip = topo is None or topo.n_devices == 1
-    return "kernel" if pallas_available() and one_chip and kernel.kernels_take(seq, topk) else "xla"
-
-
-def _interpret() -> bool:
-    """Off the TPU a kernel can only be interpreted (a test that steers a call onto the kernels' path)."""
-    return not pallas_available()
-
-
-def _count_traced(pass_: str, path: str):
-    with region("mixer/kernel", op="sparse", path=path, **{"pass": pass_}):
-        pass
+    """The rule's word (``placement.kernel_path``) for the four parts of a layer at this sequence: the kernels sit in no
+    ``shard_map`` yet (ROADMAP.md, Reach)."""
+    return placement.kernel_path(kernel.kernels_take(seq, topk), has_specs=False)
 
 
 # ----------------------------------------------------------------------
@@ -70,10 +55,10 @@ def index_scores_xla(q_i, k_i, w):
 def index_scores(q_i, k_i, w, *, path: str):
     """The scores the choice and ``index_loss`` read. The kernel's take no gradient: ``index_loss`` carries the
     indexer's gradient itself, from the one array its backward keeps."""
-    _count_traced("index", path)
+    placement.count("sparse", path, "index")
     if path != "kernel":
         return index_scores_xla(q_i, k_i, w)
-    return kernel.index_scores(*(jax.lax.stop_gradient(x) for x in (q_i, k_i, w)), interpret=_interpret())
+    return kernel.index_scores(*(jax.lax.stop_gradient(x) for x in (q_i, k_i, w)), interpret=placement.interpret())
 
 
 # ----------------------------------------------------------------------
@@ -98,7 +83,7 @@ def select_keys(scores_t, topk: int, *, path: str):
     walked = {"counted": f"{kernel.share_walked(scores_t.shape[1], topk):.3f}"} if path == "kernel" else {}
     with region("mixer/select", path=path, **walked):
         scores_t = jax.lax.stop_gradient(scores_t)
-        mask_t = select_xla(scores_t, topk) if path != "kernel" else kernel.index_select(scores_t, topk, interpret=_interpret())
+        mask_t = select_xla(scores_t, topk) if path != "kernel" else kernel.index_select(scores_t, topk, interpret=placement.interpret())
         return checkpoint_name(mask_t, SAVED)
 
 
@@ -153,7 +138,7 @@ def _sparse_bwd(scale, interpret, res, cotangents):
     do, _ = cotangents  # the row statistics feed the index loss's target alone, which takes no gradient
     B, S, H, D = q.shape
     KVH = k.shape[2]
-    _count_traced("bwd", "kernel")
+    placement.count("sparse", "kernel", "bwd")
     tiles = kernel.tiled(mask_t, kernel.block_for(S))
     dq, dk, dv = kernel.sparse_bwd(_to_bh(q), _to_bh(k), _to_bh(v), _to_bh(o), lse.reshape(B * H, S), _to_bh(do), tiles,
                                    scale, H, KVH, interpret=interpret)
@@ -166,10 +151,10 @@ _sparse_kernel.defvjp(_sparse_fwd, _sparse_bwd)
 
 def sparse_attention(q, k, v, mask_t, *, scale: float, path: str):
     """Softmax attention of (B, S, H, D) queries over the keys ``mask_t`` gives each -> (o, lse (B, H, S))."""
-    _count_traced("fwd", path)
+    placement.count("sparse", path)
     if path != "kernel":
         return sparse_attention_xla(q, k, v, mask_t, scale)
-    return _sparse_kernel(q, k, v, mask_t, scale, _interpret())
+    return _sparse_kernel(q, k, v, mask_t, scale, placement.interpret())
 
 
 # ----------------------------------------------------------------------
@@ -199,7 +184,7 @@ def _index_loss_fwd(q_i, k_i, w, scores_t, q, k, lse, mask_t, scale, dtype, inte
 
 def _index_loss_bwd(scale, dtype, interpret, res, g):
     q_i, k_i, w, grad = res
-    _count_traced("index_bwd", "kernel")
+    placement.count("sparse", "kernel", "index_bwd")
     dq, dk, dw = kernel.index_scores_bwd(grad, q_i, k_i, w, interpret=interpret)
     return (g * dq).astype(q_i.dtype), (g * dk).astype(k_i.dtype), g * dw, None, None, None, None, None
 
@@ -215,8 +200,8 @@ def index_loss(q_i, k_i, w, scores_t, q, k, lse, mask_t, *, scale: float, dtype,
     target a tile at a time and finishes the loss there: it keeps ONE square array for its backward, the loss's
     gradient in the scores in ``dtype`` (the model's: a cotangent like any other) under the name ``SAVED``, and
     ``index_scores_bwd`` carries it to ``q_i``, ``k_i`` and ``w``."""
-    _count_traced("loss", path)
+    placement.count("sparse", path, "loss")
     q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
     if path != "kernel":
         return _index_loss_and_grad(scores_t, head_probs_xla(q, k, lse, mask_t, scale), mask_t)[0]
-    return _index_loss_kernel(q_i, k_i, w, jax.lax.stop_gradient(scores_t), q, k, lse, mask_t, scale, dtype, _interpret())
+    return _index_loss_kernel(q_i, k_i, w, jax.lax.stop_gradient(scores_t), q, k, lse, mask_t, scale, dtype, placement.interpret())

@@ -28,7 +28,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry.tracing import region
-from .registry import pallas_available
+from . import placement
 
 # The name the scan kernel's outputs carry for a checkpoint policy: the scan's result, every chunk's incoming state and its
 # (I + A)^-1. A checkpointed hybrid block keeps them (``models/transformer.py::remat_keeps``), so its backward runs no
@@ -39,16 +39,12 @@ SAVED = "kda_scan"
 HEADS_A_STEP = ("mixer/kernel", ("1", "2", "4"), "heads_a_step")
 
 
-def _traced(pass_: str, path: str, op: str = "kda", **choice):
-    """The region of a scan (``op``: "kda", or "gdn" for one decay a head) that was traced as ``path``, counted."""
-    return region("mixer/kernel", op=op, path=path, **{"pass": pass_}, **choice)
-
-
 def _kernel_traced(pass_: str, op: str, q, vb, g):
-    """... as the kernel, with the heads a grid step of this call works on."""
+    """The region of a scan (``op``: "kda", or "gdn" for one decay a head) that was traced as the kernel, counted with
+    the heads a grid step of this call works on."""
     from .pallas.kda import heads_a_step
 
-    return _traced(pass_, "kernel", op, heads_a_step=str(heads_a_step(q, vb, g, pass_ == "bwd")))
+    return placement.counted(op, "kernel", pass_, heads_a_step=str(heads_a_step(q, vb, g, pass_ == "bwd")))
 
 
 def kda_recurrence(q, k, v, g, beta):
@@ -121,18 +117,13 @@ def kda_chunked(q, k, v, g, beta, interpret: bool = False):
 
 
 def kda(q, k, v, g, beta):
-    if not pallas_available():
-        with _traced("fwd", "xla"):
+    if placement.kernel_path() == "xla":
+        with placement.counted("kda", "xla"):
             return kda_recurrence(q, k, v, g, beta)
-    from ..parallel.mesh import get_mesh_topology
-    from ..runtime.zero.partition import fit_spec, prune_spec
-    from .pallas._utils import on_mesh
-
     # several chips: the kernel sits in a shard_map over the batch axes and, where it divides the heads, the tensor axis
-    topo = get_mesh_topology(required=False)
-    spec = P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, "tensor", None, None), topo), q.shape, topo)
+    spec = placement.batch_spec(q.shape, "tensor", None, None)
     with region("mixer/kernel"):  # the call with the padding and reshapes around it; ``_scan_fwd`` / ``_scan_bwd`` count the path
-        return on_mesh(kda_chunked, (spec, spec, spec, spec, P(*spec[:3])), spec)(q, k, v, g, beta)
+        return placement.on_mesh(kda_chunked, (spec, spec, spec, spec, P(*spec[:3])), spec)(q, k, v, g, beta)
 
 
 # ---------------------------------------------------------------------------
@@ -181,14 +172,9 @@ def gdn(q, k, v, g, beta):
     On a TPU the chunked kernel's per-head form (``ops/pallas/kda.py``:
     ``gdn_scan_fwd`` / ``gdn_scan_bwd``), elsewhere the recurrence; the choice
     is counted as ``kda``'s is, under ``op="gdn"``."""
-    if not pallas_available():
-        with _traced("fwd", "xla", "gdn"):
+    if placement.kernel_path() == "xla":
+        with placement.counted("gdn", "xla"):
             return gdn_recurrence(q, k, v, g, beta)
-    from ..parallel.mesh import get_mesh_topology
-    from ..runtime.zero.partition import fit_spec, prune_spec
-    from .pallas._utils import on_mesh
-
-    topo = get_mesh_topology(required=False)  # several chips: a shard_map over the batch axes
-    spec = P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, None, None, None), topo), q.shape, topo)
+    spec = placement.batch_spec(q.shape, None, None, None)  # several chips: a shard_map over the batch axes
     with region("mixer/kernel"):
-        return on_mesh(gdn_chunked, (spec, spec, spec, P(*spec[:3]), P(*spec[:3])), spec)(q, k, v, g, beta)
+        return placement.on_mesh(gdn_chunked, (spec, spec, spec, P(*spec[:3]), P(*spec[:3])), spec)(q, k, v, g, beta)

@@ -22,6 +22,11 @@ import numpy as np
 from jax.experimental.pallas import cdiv as _cdiv
 
 
+def counted_op(mask, unequal_heads: bool) -> str:
+    """The ``op`` a call under ``mask`` is counted under by whichever form takes it: ``mla`` where its values have another head size than its q and k."""
+    return "mla" if unequal_heads else mask.op
+
+
 class _Record:
     """What the older masks share: the flash kernels' own names, and tiles chosen from the sequence itself."""
 

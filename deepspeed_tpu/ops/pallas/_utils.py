@@ -1,8 +1,7 @@
-"""Shared Pallas kernel helpers."""
+"""Shared Pallas kernel helpers (how a kernel is chosen and sits on a mesh: ``ops/placement.py``)."""
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec as P
 
 
 def block_that_divides(n: int, want: int) -> int:
@@ -38,32 +37,3 @@ def compiler_params(*semantics, interpret, vmem_bytes: int = 0):
     if vmem_bytes > _VMEM_DEFAULT * 3 // 4:
         limit = vmem_bytes + _VMEM_DEFAULT
     return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=limit)
-
-
-def on_mesh(fn, in_specs, out_specs):
-    """``fn`` made safe to trace under a multi-device jit.
-
-    GSPMD cannot partition a Mosaic kernel (the TPU lowering raises "Mosaic
-    kernels cannot be automatically partitioned. Please wrap the call in a
-    shard_map"): on a mesh of several chips the kernel has to sit in a
-    ``shard_map`` that is manual over every mesh axis, with specs that say
-    how its operands split. Returns ``fn`` unchanged where that does not
-    apply: no mesh, one device, or already inside a manual region (the
-    ZeRO++ and tensor-parallel serving stacks).
-    """
-    from ...parallel.mesh import get_mesh_topology
-
-    topo = get_mesh_topology(required=False)
-    if topo is None or topo.n_devices == 1 or jax.sharding.get_abstract_mesh().manual_axes:
-        return fn
-    return jax.shard_map(fn, mesh=topo.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-
-
-def replicated_on_mesh(fn):
-    """``on_mesh`` for a kernel whose operands are whole on every device
-    (the serving norms outside the tensor-parallel region): each device
-    runs it on its own copy, which is what GSPMD does with replicated
-    operands anyway."""
-    def call(*args):
-        return on_mesh(fn, jax.tree_util.tree_map(lambda _: P(), args), P())(*args)
-    return call

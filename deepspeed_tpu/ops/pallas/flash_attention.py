@@ -88,9 +88,9 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...analysis import knobs
-from .. import masks
-from ..registry import REGISTRY, pallas_available
-from ._utils import block_that_divides, compiler_params as _compiler_params, on_mesh, vmem_budget
+from .. import masks, placement
+from ..registry import REGISTRY
+from ._utils import block_that_divides, compiler_params as _compiler_params, vmem_budget
 
 NEG_INF = -1e30
 # The name the forward's output and row statistics carry for a checkpoint policy. A checkpointed hybrid block keeps them
@@ -293,11 +293,7 @@ def _count_traced(pass_: str, path: str, unequal_heads: bool = False, mask=masks
     its caller). The scope is the one the call already runs under
     (``ops/attention.py``; a ``custom_vjp``'s backward is traced under its
     forward's name stack)."""
-    from ...telemetry.tracing import region
-
-    op = "mla" if unequal_heads else mask.op
-    with region("mixer/kernel", op=op, path=path if mask.op == "flash" else "kernel", **{"pass": pass_}, **choice):
-        pass
+    placement.count(masks.counted_op(mask, unequal_heads), path if mask.op == "flash" else "kernel", pass_, **choice)
 
 
 def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_alibi: bool, bias_meta, H: int, KVH: int):
@@ -824,7 +820,7 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None, bias=None, segment_ids=None,
                     kv_len=None, window=None, alibi_slopes=None, interpret: bool = False,
-                    bias_repeat: int = 1, mask=None):
+                    bias_repeat: int = 1, mask=None, count_as=None):
     """Drop-in for ``attention_xla`` on the fast path. The kernels take these
     masks natively, each by a walk that visits no tile wholly outside it and
     masks only the tiles that cross an edge (``ops/masks.py``): none
@@ -835,6 +831,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
     backward). Falling back to XLA's form, with the same record: packed
     segments, a padded kv (``kv_len``), a window or ALiBi without ``causal``,
     and a ``mask`` record together with a bias, ALiBi, segments or ``kv_len``.
+    ``count_as`` (``ops/attention.py::attention``): the caller's words for this call site's count,
+    made here as ``path="kernel"`` where the kernels take the call and by XLA's form where it falls.
 
     ``bias``: additive logits bias broadcastable to ``(B, H, Sq, Sk)`` —
     the batch/head/row dims may each be 1 and stay COLLAPSED in HBM (the
@@ -858,38 +856,26 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = No
                 bias = bias[None]
             bias = jnp.repeat(bias, bias_repeat, axis=0)
         return attention_xla(q, k, v, causal=causal, scale=scale, bias=bias, segment_ids=segment_ids,
-                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask)
+                             kv_len=kv_len, window=window, alibi_slopes=alibi_slopes, mask=mask, count_as=count_as)
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1 (got {window}); pass None to disable the sliding window")
+    if count_as:  # a call without the caller's words is counted by the kernels alone, with what they chose (``_count_traced``)
+        placement.count(path="kernel", **count_as)
     local = functools.partial(_flash_local, mask=mask if mask is not None else masks.of(causal, window), scale=scale,
                               interpret=interpret, bias_repeat=bias_repeat)
     if bias is not None:
         # a collapsed bias does not split along a mesh; those callers
         # (evoformer) run on one device or inside their own shard_map
         return local(q, k, v, alibi_slopes, bias)
-    # several chips: the kernel sits in a shard_map over the batch axes and,
-    # where they divide the heads, the tensor axis (on_mesh says why)
-    spec = _mesh_spec(q, k)
-    if alibi_slopes is None:
-        return on_mesh(lambda q, k, v: local(q, k, v, None, None), (spec, spec, spec), spec)(q, k, v)
-    heads = P(spec[2]) if len(spec) > 2 else P()
-    return on_mesh(lambda q, k, v, sl: local(q, k, v, sl, None), (spec, spec, spec, heads), spec)(
-        q, k, v, jnp.asarray(alibi_slopes, jnp.float32))
-
-
-def _mesh_spec(q, k) -> P:
-    """How (B, S, H, D) operands split over the live mesh: batch over the
-    data axes, heads over ``tensor`` — each only where it divides (the
-    tensor axis must divide the query AND the KV heads)."""
-    from ...parallel.mesh import get_mesh_topology
-    from ...runtime.zero.partition import fit_spec, prune_spec
-
-    topo = get_mesh_topology(required=False)
-    if topo is None:
-        return P()
+    # several chips: the kernel sits in a shard_map over the batch axes and, where it divides the query AND the KV heads,
+    # the tensor axis (``placement.on_mesh`` says why)
     B, S, H, D = q.shape
-    return fit_spec(prune_spec(P(topo.batch_axes, None, "tensor", None), topo),
-                    (B, S, math.gcd(H, k.shape[2]), D), topo)
+    spec = placement.batch_spec((B, S, math.gcd(H, k.shape[2]), D), None, "tensor", None)
+    if alibi_slopes is None:
+        return placement.on_mesh(lambda q, k, v: local(q, k, v, None, None), (spec, spec, spec), spec)(q, k, v)
+    heads = P(spec[2]) if len(spec) > 2 else P()
+    return placement.on_mesh(lambda q, k, v, sl: local(q, k, v, sl, None), (spec, spec, spec, heads), spec)(
+        q, k, v, jnp.asarray(alibi_slopes, jnp.float32))
 
 
 def _flash_local(q, k, v, alibi_slopes, bias, *, mask, scale, interpret, bias_repeat):
@@ -925,4 +911,4 @@ def _flash_local(q, k, v, alibi_slopes, bias, *, mask, scale, interpret, bias_re
     return _flash(q, k, v, slopes, bias_flat, scale, mask, interpret, has_alibi, bias_meta, H, k.shape[2])
 
 
-REGISTRY.register("attention", "pallas", flash_attention, is_available=pallas_available, priority=10)
+REGISTRY.register("attention", "pallas", flash_attention, is_available=placement.pallas_available, priority=10)

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..registry import REGISTRY, pallas_available
-from ._utils import block_that_divides, replicated_on_mesh
+from ..placement import replicated_on_mesh
+from ._utils import block_that_divides
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):

@@ -25,6 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import placement
 from ._utils import compiler_params
 
 # The name the forward call's output carries for a checkpoint policy: a checkpointed block keeps it
@@ -45,12 +46,8 @@ def fits(S: int, D: int, K: int) -> bool:
 
 
 def path_for(S: int, D: int, K: int) -> str:
-    """``kernel`` on one TPU chip at shapes the kernels take, else ``xla``."""
-    from ...parallel.mesh import get_mesh_topology
-    from ..registry import pallas_available
-
-    topo = get_mesh_topology(required=False)
-    return "kernel" if pallas_available() and (topo is None or topo.n_devices == 1) and fits(S, D, K) else "xla"
+    """The rule's word (``placement.kernel_path``) at these shapes: the kernels sit in no ``shard_map`` yet."""
+    return placement.kernel_path(fits(S, D, K), has_specs=False)
 
 
 def _lanes(D: int) -> int:

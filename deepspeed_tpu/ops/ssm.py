@@ -20,16 +20,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry.tracing import region
-from .registry import pallas_available
+from . import placement
 
 # The name the scan kernel's outputs carry for a checkpoint policy: y and every chunk's incoming state. A checkpointed
 # hybrid block keeps them (``models/transformer.py::remat_keeps``), so its backward runs no second forward scan
 SAVED = "ssm_scan"
 STRETCH = 64  # the recurrence: tokens between two states the backward keeps
-
-
-def _traced(pass_: str, path: str):
-    return region("mixer/kernel", op="ssm", path=path, **{"pass": pass_})
 
 
 def ssm_recurrence(u, delta, A, B, C, D, state_dtype=jnp.float32):
@@ -67,7 +63,7 @@ def _scan_fwd(u, delta, a_t, b_t, c_t, d, interpret):
 
     # named, both (the outputs and every chunk's incoming state), so that a block under jax.checkpoint keeps them
     # (models/transformer.py::block_fn) and its backward does not run the forward scan a second time to get them back
-    with _traced("fwd", "kernel"):
+    with placement.counted("ssm", "kernel"):
         y, states = (checkpoint_name(x, SAVED) for x in kernel.scan_fwd(u, delta, a_t, b_t, c_t, d, interpret))
     return y, (u, delta, a_t, b_t, c_t, d, states)
 
@@ -76,7 +72,7 @@ def _scan_bwd(interpret, res, dy):
     from .pallas import ssm as kernel
 
     u, delta, a_t, b_t, c_t, d, states = res
-    with _traced("bwd", "kernel"):
+    with placement.counted("ssm", "kernel", "bwd"):
         du, ddelta, da, dbp, dcp, dd = kernel.scan_bwd(u, delta, a_t, b_t, c_t, d, states, dy, interpret)
         # B's and C's gradients left the kernel as partial sums along the lanes, a token a row: (Bt, S, N, 128) -> (Bt, N, S)
         cols = lambda partial, like: jnp.swapaxes(jnp.sum(partial, axis=-1), 1, 2).astype(like.dtype)
@@ -101,11 +97,8 @@ def ssm_chunked(u, delta, A, B, C, D, interpret: bool = False):
 
 
 def selective_scan(u, delta, A, B, C, D):
-    from ..parallel.mesh import get_mesh_topology
-
-    topo = get_mesh_topology(required=False)
-    if not pallas_available() or (topo is not None and topo.n_devices > 1):
-        with _traced("fwd", "xla"):
+    if placement.kernel_path(has_specs=False) == "xla":  # the kernels sit in no ``shard_map`` yet
+        with placement.counted("ssm", "xla"):
             return ssm_recurrence(u, delta, A, B, C, D)
     with region("mixer/kernel"):  # the call with the padding and transposes around it; ``_scan_fwd`` / ``_scan_bwd`` count the path
         return ssm_chunked(u, delta, A, B, C, D)

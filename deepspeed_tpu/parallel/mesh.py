@@ -21,7 +21,7 @@ Axes (sizes from ``MeshConfig``):
 """
 
 import os
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -194,3 +194,49 @@ def get_mesh_topology(required: bool = True) -> Optional[MeshTopology]:
 def reset_mesh():
     global _TOPOLOGY
     _TOPOLOGY = None
+
+
+# A spec against a topology, pure functions of both: the ZeRO planner's (``runtime/zero/partition.py``) and the kernels'
+# placement's (``ops/placement.py``)
+def prune_spec(spec: Optional[PartitionSpec], topo) -> Optional[PartitionSpec]:
+    """Drop axes of size 1 from a spec (they're no-ops that would block
+    further sharding of the dim by the ZeRO planner)."""
+    if spec is None:
+        return None
+
+    def keep(entry):
+        if entry is None:
+            return None
+        names = entry if isinstance(entry, (tuple, list)) else (entry,)
+        names = tuple(a for a in names if topo.axis_size(a) > 1)
+        if not names:
+            return None
+        return names if len(names) > 1 else names[0]
+
+    return _norm([keep(e) for e in spec])
+
+
+def fit_spec(spec: Optional[PartitionSpec], shape: Tuple[int, ...], topo) -> Optional[PartitionSpec]:
+    """Drop from ``spec`` every entry whose axes do not divide the dimension
+    they shard: that dimension stays whole (replicated over those axes)
+    rather than failing placement. GPT-2's vocabulary of 50257 is the case:
+    no tensor degree divides it, so its embedding cannot shard over vocab."""
+    if spec is None:
+        return None
+
+    def fits(entry, dim):
+        if entry is None:
+            return None
+        names = entry if isinstance(entry, (tuple, list)) else (entry,)
+        return entry if dim % int(np.prod([topo.axis_size(a) for a in names])) == 0 else None
+
+    entries = list(spec)[:len(shape)]
+    return _norm([fits(e, d) for e, d in zip(entries, shape)])
+
+
+def _norm(entries) -> PartitionSpec:
+    """Strip trailing Nones so equal specs compare equal (P(None,None)==P())."""
+    entries = list(entries)
+    while entries and entries[-1] is None:
+        entries.pop()
+    return PartitionSpec(*entries)

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ...parallel.mesh import _norm, fit_spec, prune_spec  # noqa: F401 (pure functions of a spec and a topology: they live beside it)
 from ...utils.logging import logger
 
 
@@ -52,42 +53,6 @@ def _axes_in_spec(spec: P) -> set:
     return used
 
 
-def prune_spec(spec: Optional[P], topo) -> Optional[P]:
-    """Drop axes of size 1 from a spec (they're no-ops that would block
-    further sharding of the dim by the ZeRO planner)."""
-    if spec is None:
-        return None
-
-    def keep(entry):
-        if entry is None:
-            return None
-        names = entry if isinstance(entry, (tuple, list)) else (entry,)
-        names = tuple(a for a in names if topo.axis_size(a) > 1)
-        if not names:
-            return None
-        return names if len(names) > 1 else names[0]
-
-    return _norm([keep(e) for e in spec])
-
-
-def fit_spec(spec: Optional[P], shape: Tuple[int, ...], topo) -> Optional[P]:
-    """Drop from ``spec`` every entry whose axes do not divide the dimension
-    they shard: that dimension stays whole (replicated over those axes)
-    rather than failing placement. GPT-2's vocabulary of 50257 is the case:
-    no tensor degree divides it, so its embedding cannot shard over vocab."""
-    if spec is None:
-        return None
-
-    def fits(entry, dim):
-        if entry is None:
-            return None
-        names = entry if isinstance(entry, (tuple, list)) else (entry,)
-        return entry if dim % int(np.prod([topo.axis_size(a) for a in names])) == 0 else None
-
-    entries = list(spec)[:len(shape)]
-    return _norm([fits(e, d) for e, d in zip(entries, shape)])
-
-
 def match_partition_rule(path: Tuple[str, ...], rules: Sequence[Tuple[Tuple[str, ...], P]]) -> Optional[P]:
     """First rule whose key names all appear (in order) in the param path."""
     for key, spec in rules:
@@ -95,14 +60,6 @@ def match_partition_rule(path: Tuple[str, ...], rules: Sequence[Tuple[Tuple[str,
         if all(any(k == p for p in it) for k in key):
             return spec
     return None
-
-
-def _norm(entries) -> P:
-    """Strip trailing Nones so equal specs compare equal (P(None,None)==P())."""
-    entries = list(entries)
-    while entries and entries[-1] is None:
-        entries.pop()
-    return P(*entries)
 
 
 def shard_leaf_spec(shape: Tuple[int, ...], base_spec: Optional[P], axes: Tuple[str, ...], axes_size: int,

@@ -15,7 +15,7 @@ import pytest
 
 from deepspeed_tpu.models import CausalLM, TransformerConfig
 from deepspeed_tpu.models.transformer import cross_entropy_loss
-from deepspeed_tpu.ops import indexed_attention as ops
+from deepspeed_tpu.ops import indexed_attention as ops, placement
 from deepspeed_tpu.ops.pallas import indexed_attention as kernel
 from deepspeed_tpu.telemetry import device_counts, get_registry
 from deepspeed_tpu.telemetry.tracing import regions_traced
@@ -316,7 +316,7 @@ def test_the_kernels_path_is_the_xla_path_through_the_whole_model_under_remat(hi
     params = m.init(jax.random.PRNGKey(3), {"input_ids": ids})
     f = lambda p: m.loss_fn(p, {"input_ids": ids})
     want_loss, want = jax.value_and_grad(f)(params)
-    monkeypatch.setattr(ops, "path_for", lambda seq, topk: "kernel")
+    monkeypatch.setattr(placement, "kernel_path", lambda fits=True, has_specs=True: "kernel")  # the one rule, steered: its kernels are interpreted here
     before = regions_traced("mixer/kernel", op="sparse", path="kernel")
     got_loss, got = jax.value_and_grad(f)(params)
     assert regions_traced("mixer/kernel", op="sparse", path="kernel") - before == 5  # index, fwd, loss, bwd, index_bwd: one block trace
@@ -347,7 +347,7 @@ def test_no_square_array_of_the_index_loss_is_made_outside_a_kernel(highest, mon
     m = CausalLM(tiny(max_seq_len=seq, index_topk=48, remat=True, n_layers=layers))
     ids = np.random.default_rng(2).integers(0, 97, (1, seq)).astype(np.int32)
     params = m.init(jax.random.PRNGKey(3), {"input_ids": ids})
-    monkeypatch.setattr(ops, "path_for", lambda s, topk: "kernel")
+    monkeypatch.setattr(placement, "kernel_path", lambda fits=True, has_specs=True: "kernel")
     counted = lambda: regions_traced("mixer/kernel", op="sparse", path="kernel", **{"pass": "loss"})
     before, probs = counted(), regions_traced("mixer/kernel", op="sparse", **{"pass": "probs"})
     eqns = list(_equations(jax.make_jaxpr(jax.value_and_grad(lambda p: m.loss_fn(p, {"input_ids": ids})))(params).jaxpr))
@@ -359,7 +359,7 @@ def test_no_square_array_of_the_index_loss_is_made_outside_a_kernel(highest, mon
               for eqn in eqns if eqn.primitive.name not in ("pallas_call", "name", "stop_gradient")  # the two last lower to nothing
               for v in eqn.outvars if getattr(v.aval, "size", 0) == seq * seq}
     assert square == {("reshape", "int8", ("int8",)), ("transpose", "int8", ("int8",)), ("convert_element_type", "float32", ("int8",))}, square
-    monkeypatch.setattr(ops, "path_for", lambda s, topk: "xla")  # the test of the test: XLA's form makes its squares in the open
+    monkeypatch.setattr(placement, "kernel_path", lambda fits=True, has_specs=True: "xla")  # the test of the test: XLA's form makes its squares in the open
     eqns = list(_equations(jax.make_jaxpr(lambda p: m.loss_fn(p, {"input_ids": ids}))(params).jaxpr))
     assert len({eqn.primitive.name for eqn in eqns for v in eqn.outvars if getattr(v.aval, "shape", ())[-2:] == (seq, seq) and v.aval.dtype == jnp.float32}) > 5
 

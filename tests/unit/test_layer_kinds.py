@@ -1,4 +1,4 @@
-"""A layer kind is declared once: its flax module carries its record (``models/layers.py::LayerKind``) under one name in
+"""A layer kind is declared once: its flax module carries its record (``deepspeed_tpu/layer_kind.py::LayerKind``) under one name in
 the table (``models/transformer.py``), and the model, the trainer and the server read the record and name no kind.
 
 (a) what a new kind costs: a mixer defined HERE, one line of the table, and a model with it trains through
@@ -189,6 +189,21 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
         for refused in (lambda: model.to_pipeline(1, params=shapes), lambda: InferenceEngineV2(model, shapes)):
             with pytest.raises(NotImplementedError, match=name):
                 refused()
+
+
+@pytest.mark.parametrize("name", list(table.MIXERS) + list(table.FFNS))
+def test_every_entry_of_the_table_mixes_the_record_in(name):
+    """``moe/`` mixes the record in as ``models/`` does (it lives outside both: ``deepspeed_tpu/layer_kind.py``), so a field the
+    record gains is every kind's at once: no entry spells a default by hand, and each has every name the hosts read."""
+    from deepspeed_tpu import layer_kind
+
+    cls = {**table.MIXERS, **table.FFNS}[name]
+    assert LayerKind is layer_kind.LayerKind and issubclass(cls, LayerKind) and issubclass(cls, nn.Module)
+    fields = [f for f in vars(LayerKind) if not f.startswith("_")]
+    assert {"sows", "report", "keeps", "hybrid", "paths", "path_words", "joined", "alone", "stackable", "gives", "takes", "targets"} <= set(fields)
+    assert all(hasattr(cls, f) for f in fields)
+    if cls.__module__.startswith("deepspeed_tpu.moe"):  # said only where they differ: what the parent's hand-spelled lines said
+        assert (cls.gives, cls.targets, cls.alone, cls.path_words) == ((), None, False, {"moe_cond": "fallback_keeps_nothing"} if "routed" in name else {})
 
 
 def test_a_model_of_one_kind_says_its_keys_only_where_the_record_asks():
