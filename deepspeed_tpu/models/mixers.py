@@ -26,9 +26,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import indexed_attention as sparse
 from ..ops import masks
+from ..ops import placement
 from ..ops.attention import attention
-from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, kda
-from ..ops.pallas import short_conv
+from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, gdn_scan, kda, kda_scan
+from ..ops.pallas import scan_operands, short_conv
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
@@ -74,6 +75,11 @@ def l2_normalize(x, eps: float = 1e-6):
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
 
+# both delta-rule kinds count what makes their scan's operands under these keys of the trainer's first-call line (the
+# backward's only where that is a call of its own: XLA differentiates the plain lines)
+SCAN_OPERANDS = {f"scan_operands_{pass_}": ("mixer/proj", {"op": "scan_operands", "pass": pass_}) for pass_ in ("fwd", "bwd")}
+
+
 class KDAMixer(LayerKind, nn.Module):
     """Kimi Delta Attention: per head, ``S_t = (I - beta_t k_t k_t^T)
     Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``, with q, k
@@ -84,13 +90,13 @@ class KDAMixer(LayerKind, nn.Module):
 
     cfg: TransformerFields
     keeps, hybrid = (SCAN_SAVED, SAVED), True
-    paths, joined = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"})}, {"kda_heads_a_step": HEADS_A_STEP}
+    paths, joined = {"kda_path": ("mixer/kernel", {"op": "kda", "pass": "fwd"}), **SCAN_OPERANDS}, {"kda_heads_a_step": HEADS_A_STEP}
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
         self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
-        H, D, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+        H, D, K, rank = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv_size, cfg.kda_gate_rank
         f32 = jnp.float32
         # named (``SAVED``: what a checkpointed block keeps) are the results of the products over the model width, each in
         # the layout its consumer reads; the convolutions, SiLUs, l2 norms and the gates' second products (over ``rank``)
@@ -104,22 +110,37 @@ class KDAMixer(LayerKind, nn.Module):
                 checkpoint_name(a, SAVED))
 
         heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
+        kept = lambda name: checkpoint_name(heads_first(heads(f"{name}_proj")(x)), SAVED)
+        filt = lambda name: self.param(f"{name}_conv", _uniform(-K**-0.5, K**-0.5), (K, H, D), f32)
 
         def conv_silu(name):
-            w = self.param(f"{name}_conv", _uniform(-cfg.kda_conv_size**-0.5, cfg.kda_conv_size**-0.5),
-                           (cfg.kda_conv_size, H, D), f32)
-            return nn.silu(causal_conv(checkpoint_name(heads_first(heads(f"{name}_proj")(x)), SAVED), w.astype(cfg.dtype)[:, :, None, :], axis=2))
+            w = filt(name)
+            return nn.silu(causal_conv(kept(name), w.astype(cfg.dtype)[:, :, None, :], axis=2))
 
-        with region("mixer/proj"):  # the projections, their convolutions and the gates
-            q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
-            k = l2_normalize(conv_silu("k")).astype(cfg.dtype)
-            v = conv_silu("v")
-            a_log = self.param("A_log", _a_log_init, (H,), f32)
-            dt_bias = self.param("dt_bias", _dt_bias_init, (H, D), f32)
-            g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
-            beta = jax.nn.sigmoid(checkpoint_name(
-                nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)), SAVED))
-        o = kda(q, k, v, g, jnp.swapaxes(beta, 1, 2))  # (B, H, S, D)
+        decay = lambda: (self.param("A_log", _a_log_init, (H,), f32), self.param("dt_bias", _dt_bias_init, (H, D), f32))
+        beta_of = lambda: checkpoint_name(
+            nn.Dense(H, use_bias=False, name="b_proj", dtype=f32, param_dtype=f32, precision=exact(f32))(x.astype(f32)), SAVED)
+        # what lies between the kept projections and the scan: one Pallas pass each way on one TPU chip
+        # (``ops/pallas/scan_operands.py``), the lines below it elsewhere, which are also that kernel's oracle
+        path = scan_operands.path_for(x.shape[1], D, K)
+        with placement.counted("scan_operands", path, name="mixer/proj"):  # the projections, their convolutions and the gates
+            if path == "kernel":
+                projections, filters = zip(*[(kept(name), filt(name)) for name in "qkv"])
+                a_log, dt_bias = decay()
+                operands = scan_operands.scan_operands(*projections, beta_of(), *filters, heads_first(low_rank("f", f32)), a_log, dt_bias,
+                                                        interpret=placement.interpret())
+            else:
+                q = (l2_normalize(conv_silu("q")) * D**-0.5).astype(cfg.dtype)
+                k = l2_normalize(conv_silu("k")).astype(cfg.dtype)
+                v = conv_silu("v")
+                a_log, dt_bias = decay()
+                g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(heads_first(low_rank("f", f32)) + dt_bias[:, None, :])  # (B, H, S, D)
+                beta = jax.nn.sigmoid(beta_of())
+        if path == "kernel":
+            with region("mixer/kernel"):
+                o = kda_scan(*operands, interpret=placement.interpret())  # (B, H, S, D)
+        else:
+            o = kda(q, k, v, g, jnp.swapaxes(beta, 1, 2))
         with region("mixer/proj"):
             o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * jax.nn.sigmoid(heads_first(low_rank("g", cfg.dtype)))
             o = heads_first(o)
@@ -140,35 +161,52 @@ class GDNMixer(LayerKind, nn.Module):
 
     cfg: TransformerFields
     keeps, hybrid = (SCAN_SAVED, SAVED), True
-    paths, joined = {"gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"})}, {"gdn_heads_a_step": HEADS_A_STEP}
+    paths, joined = {"gdn_path": ("mixer/kernel", {"op": "gdn", "pass": "fwd"}), **SCAN_OPERANDS}, {"gdn_heads_a_step": HEADS_A_STEP}
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
         self.no_cache(kv_cache, segment_ids)
         cfg = self.cfg
-        Hk, Hv, D = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
+        Hk, Hv, D, K = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim, cfg.gdn_conv_size
         f32 = jnp.float32
         heads_first = lambda t: jnp.swapaxes(t, 1, 2)  # (B, S, H, .) -> (B, H, S, .): the scan's layout, beside the projection
         # named (``SAVED``: what a checkpointed block keeps): the four projections in the scan's layout and the gates' one
         heads = lambda name, H: checkpoint_name(heads_first(nn.DenseGeneral((H, D), use_bias=False, name=f"{name}_proj", dtype=cfg.dtype,
                                                                             param_dtype=f32)(x)), SAVED)
 
+        filt = lambda name, H: self.param(f"{name}_conv", _uniform(-K**-0.5, K**-0.5), (K, H, D), f32)
+
         def conv_silu(name, H):
-            w = self.param(f"{name}_conv", _uniform(-cfg.gdn_conv_size**-0.5, cfg.gdn_conv_size**-0.5),
-                           (cfg.gdn_conv_size, H, D), f32)
+            w = filt(name, H)
             return nn.silu(causal_conv(heads(name, H), w.astype(cfg.dtype)[:, :, None, :], axis=2))
 
-        with region("mixer/proj"):  # the projections, their convolutions and the gates
-            q = (l2_normalize(conv_silu("q", Hk)) * D**-0.5).astype(cfg.dtype)
-            k = l2_normalize(conv_silu("k", Hk)).astype(cfg.dtype)
-            v = conv_silu("v", Hv)
+        def gates():  # -> (beta's pre-activation, the log-decay when asked), each a number a value head and token (B, S, Hv), float32
             a_log = self.param("A_log", _a_log_init, (Hv,), f32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (Hv,), f32)
             ba = checkpoint_name(nn.Dense(2 * Hv, use_bias=False, name="ba_proj", dtype=f32, param_dtype=f32,
                                           precision=jax.lax.Precision.HIGHEST)(x.astype(f32)), SAVED)  # the float32 gates take no bf16 pass
-            beta = jax.nn.sigmoid(ba[..., :Hv])
-            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)  # (B, S, Hv)
-        o = gdn(q, k, v, jnp.swapaxes(g, 1, 2), jnp.swapaxes(beta, 1, 2))  # (B, Hv, S, D)
+            return ba[..., :Hv], lambda: -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
+
+        # as in ``KDAMixer``: the scan's q, k, beta k, beta v by one Pallas pass each way, or the lines below it
+        path = scan_operands.path_for(x.shape[1], D, K)
+        with placement.counted("scan_operands", path, name="mixer/proj"):  # the projections, their convolutions and the gates
+            if path == "kernel":
+                projections, filters = zip(*[(heads(name, H), filt(name, H)) for name, H in (("q", Hk), ("k", Hk), ("v", Hv))])
+                b, decay = gates()
+                operands = scan_operands.scan_operands(*projections, b, *filters, interpret=placement.interpret())
+                g = decay()
+            else:
+                q = (l2_normalize(conv_silu("q", Hk)) * D**-0.5).astype(cfg.dtype)
+                k = l2_normalize(conv_silu("k", Hk)).astype(cfg.dtype)
+                v = conv_silu("v", Hv)
+                b, decay = gates()
+                beta = jax.nn.sigmoid(b)
+                g = decay()  # (B, S, Hv)
+        if path == "kernel":
+            with region("mixer/kernel"):
+                o = gdn_scan(*operands, jnp.swapaxes(g, 1, 2), interpret=placement.interpret())  # (B, Hv, S, D)
+        else:
+            o = gdn(q, k, v, jnp.swapaxes(g, 1, 2), jnp.swapaxes(beta, 1, 2))
         with region("mixer/proj"):
             o = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name="o_norm")(o) * nn.silu(heads("z", Hv))
             return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj", dtype=cfg.dtype,

@@ -96,17 +96,14 @@ def _scan_bwd(op, interpret, res, do):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def kda_chunked(q, k, v, g, beta, interpret: bool = False):
-    """The kernel path on whole (or shard-local) operands: pad the sequence
+def kda_scan(q, k, kb, vb, g, interpret: bool = False):
+    """The kernel on operands made already (``kb``, ``vb``: beta*k, beta*v), whole or shard-local: pad the sequence
     to whole chunks (a padded token has k = 0 and g = 0, so it leaves the
-    state as it was), hand the kernel beta*k and beta*v, which XLA
-    differentiates, and fold the heads into the batch."""
+    state as it was) and fold the heads into the batch."""
     from .pallas.kda import CHUNK
 
     B, H, S, _ = q.shape
     pad = -S % CHUNK
-    beta = beta.astype(jnp.float32)[..., None]
-    kb, vb = (beta * k.astype(jnp.float32)).astype(k.dtype), (beta * v.astype(jnp.float32)).astype(v.dtype)
 
     def rows(x):
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
@@ -114,6 +111,14 @@ def kda_chunked(q, k, v, g, beta, interpret: bool = False):
 
     o = _scan(rows(q), rows(k), rows(kb), rows(vb), rows(g.astype(jnp.float32)), "kda", interpret)
     return o.reshape(B, H, S + pad, -1)[:, :, :S]
+
+
+def kda_chunked(q, k, v, g, beta, interpret: bool = False):
+    """The kernel path on whole (or shard-local) operands: hand ``kda_scan`` beta*k and beta*v, which XLA
+    differentiates."""
+    beta = beta.astype(jnp.float32)[..., None]
+    kb, vb = (beta * k.astype(jnp.float32)).astype(k.dtype), (beta * v.astype(jnp.float32)).astype(v.dtype)
+    return kda_scan(q, k, kb, vb, g, interpret)
 
 
 def kda(q, k, v, g, beta):
@@ -140,17 +145,14 @@ def gdn_recurrence(q, k, v, g, beta):
     return kda_recurrence(_to_value_heads(q, H), _to_value_heads(k, H), v, g[..., None], beta)
 
 
-def gdn_chunked(q, k, v, g, beta, interpret: bool = False):
-    """``kda_chunked`` for one decay a head and token: q and k keep their own
+def gdn_scan(q, k, kb, vb, g, interpret: bool = False):
+    """``kda_scan`` for one decay a head and token (g (B, H_v, S)): q and k keep their own
     (fewer) heads, the kernel reads a key head's blocks for each of its value
     heads, and the log-decay goes in along the lanes, a chunk a row."""
     from .pallas.kda import CHUNK
 
-    B, H, S, _ = v.shape
+    B, H, S, _ = vb.shape
     pad = -S % CHUNK
-    beta = beta.astype(jnp.float32)[..., None]
-    kb = (beta * _to_value_heads(k, H).astype(jnp.float32)).astype(k.dtype)
-    vb = (beta * v.astype(jnp.float32)).astype(v.dtype)
 
     def rows(x):
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
@@ -159,6 +161,15 @@ def gdn_chunked(q, k, v, g, beta, interpret: bool = False):
     g = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, 0), (0, pad))) if pad else g.astype(jnp.float32)
     o = _scan(rows(q), rows(k), rows(kb), rows(vb), g.reshape(-1, 1, CHUNK), "gdn", interpret)
     return o.reshape(B, H, S + pad, -1)[:, :, :S]
+
+
+def gdn_chunked(q, k, v, g, beta, interpret: bool = False):
+    """``kda_chunked`` for one decay a head and token: beta*k a VALUE head (its key head's k) and beta*v."""
+    H = v.shape[1]
+    beta = beta.astype(jnp.float32)[..., None]
+    kb = (beta * _to_value_heads(k, H).astype(jnp.float32)).astype(k.dtype)
+    vb = (beta * v.astype(jnp.float32)).astype(v.dtype)
+    return gdn_scan(q, k, kb, vb, g, interpret)
 
 
 def gdn(q, k, v, g, beta):

@@ -162,6 +162,23 @@ def _gdn(shape):
     return fwd_bwd, (qk, qk, v, gate, gate, v), 2
 
 
+def _scan_operands(shape, decay):
+    """What the delta-rule scans read, made from the kept projections, forward and backward: 2 kernels, a key head's tile
+    of rows a grid step with its halo blocks; ``decay``: a channel (KDA: ``g`` is made too), else the caller's (Gated DeltaNet)."""
+    from deepspeed_tpu.ops.pallas.scan_operands import scan_operands
+
+    B, Hk, Hv, Sq, Dh, K = shape
+    key, value, w = S((B, Hk, Sq, Dh), BF16), S((B, Hv, Sq, Dh), BF16), lambda H: S((K, H, Dh), F32)
+    args = [key, key, value, S((B, Sq, Hv), F32), w(Hk), w(Hk), w(Hv)] + ([S((B, Hk, Sq, Dh), F32), S((Hk,), F32), S((Hk, Dh), F32)] if decay else [])
+    cts = (key, key, value, value) + ((S((B, Hk, Sq, Dh), F32),) if decay else ())
+
+    def fwd_bwd(args, cts):
+        out, vjp = jax.vjp(scan_operands, *args)
+        return out, vjp(cts)
+
+    return fwd_bwd, (args, cts), 2
+
+
 def _ssm(shape):
     """The selective scan, forward and backward: 2 kernels (channels along the lanes, the whole state in VMEM)."""
     from deepspeed_tpu.ops.ssm import ssm_chunked
@@ -307,6 +324,9 @@ CASES = {
     "moe_sum_rows_t16384_d2048_e8_r65536": lambda: _moe_sum_rows((16384, 2048, 8, 65536)),  # ... four times the uniform load is every pair
     "gmm_r32768_e8_d2048_f1792_rows512": lambda: _grouped_products((32768, 8, 2048, 1792), ((512, 512, 896), (512, 896, 1024))),  # ... its grouped products: 1,792 in tiles of 896, full groups in rows of 512
     "gmm_r65536_e8_d2048_f1792_rows256": lambda: _grouped_products((65536, 8, 2048, 1792), ((256, 512, 896), (256, 896, 1024))),  # ... and on the rung above
+    "scan_operands_b1_h32_s8192_d128_k4_decay": lambda: _scan_operands((1, 32, 32, 8192, 128, 4), True),  # kimi-linear-48b-l5e8's four KDA layers
+    "scan_operands_b1_h16_v32_s8192_d128_k4": lambda: _scan_operands((1, 16, 32, 8192, 128, 4), False),  # qwen3-next-80b-l4e32's three DeltaNet layers
+    "scan_operands_b2_h2_v4_s384_d256_k2": lambda: _scan_operands((2, 2, 4, 384, 256, 2), False),  # tiles of 128 rows, two vregs of lanes, two taps
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
        for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},

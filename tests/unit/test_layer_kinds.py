@@ -141,7 +141,9 @@ WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step":
         # (PR 53) softmax attention's kinds share one record, each key its own kind's; the window's walk is the kernel's
         "full_path": "the layer is of the kind full", "window_path": "the layer is of the kind window", "window_keys": "the same",
         "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)",
-        "moe_activation": "the experts' gate is relu (activation reglu)"}
+        "moe_activation": "the experts' gate is relu (activation reglu)",
+        # (PR 58) XLA differentiates the plain lines: the backward is a call site of its own on the kernels' path alone
+        "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])
