@@ -15,6 +15,7 @@
     python chip_smoke.py --only blockdiff  # ... and its seventh's: block-diffusion training, GQA under the block mask over a doubled row, 8 of 128 experts
     python chip_smoke.py --only mixed    # ... and its eighth's: one full layer without positions to three rotated window layers at 16,384 rows, ReGLU experts behind an early router
     python chip_smoke.py --only shortconv  # ... and its ninth's: gated short convolutions three layers in four beside GQA 32/8 of 64 at 16,384 rows, a biased sigmoid router of 4 in 32, a tied head
+    python chip_smoke.py --only mamba2   # ... and its tenth's: Mamba-2 (SSD) scans, blocks of ONE part, ungated relu^2 experts 6 of 128 behind a biased sigmoid router, GQA 32/2 without positions
 
 Everything runs in this one process (a chip belongs to one process), at the
 full width and depth of GPT-2-124M, on weights and data made from ``--seed``.
@@ -1125,6 +1126,40 @@ SHORTCONV_LIMITS = {"logits": SHORTCONV_CLASS_LIMITS["logits"],
                     **{name: SHORTCONV_CLASS_LIMITS.get(_sambay_class(name), SHORTCONV_LEAF_LIMIT) for name in _decoder_names(SHORTCONV_PARTS, kinds=SHORTCONV_KINDS, tied=True)}}
 
 
+# Nemotron-3-Nano-30B-A3B's published layers 0-8 (``--only mamba2``), MEMEM*EME: four Mamba-2 mixers, four routed FFNs of
+# ungated relu^2 experts (6 of 128, 8 held, one shared) and one GQA 32/2 layer without positions, every layer ONE part
+# under one norm, an untied head, at 8,192 rows; the float32 reference runs the recurrence token by token. EVERY leaf of the
+# gradient is read but the selection bias, a buffer whose gradient is zero on both sides (68 of the 72: a leaf's name is its
+# path). Five controls, each the plain bf16 reference with one thing wrong, and each has to break a limit on every seed:
+# experts that are a gated SiLU on the one product there is (``gated_silu``), the decay dropped, ``A = 0`` (``no_decay``),
+# the group norm before the gate (``norm_first``), no ``D x`` (``no_skip``) and the recurrence's state, step and statistics
+# in bf16 (``low_state``: the precision below the stated one). At the usual start of every product, ``o_proj`` too, which is the cell's own start (``assumed.start`` in its file).
+# Limits from three seeds (0, 11, 101; my chip runs, PR 59: published widths, 9 layers, 1 x 8,192; 215-405 s a seed) and checked on two more (2024, 31337, which passed: the program under all 65 judged limits, the four structural controls over all 65 and `low_state` over the logits' on all five),
+# by class of leaf (its path without the layer). The program reads what the plain bf16 reference reads, leaf by leaf (four
+# layers of gate-norm-scan multiply their operands' rounding: logits 0.047-0.051 beside 0.045-0.050). The four structural
+# controls read six times the program and more in EVERY class (the least, ``gated_silu``: logits 0.300-0.305, a leaf 0.15 at
+# the least, the final norm's scale on one seed). ``low_state`` lies a quarter above the program and is held to the LOGITS
+# alone, whose limit is the square root of the two ends' product (0.0514 | 0.0638). NOT judged, only reported (a limit of
+# None): the routers' own gradients, which flips of the top 6 of 128 rule in the program and in the plain bf16 path alike
+# (0.166-0.311 | 0.144-0.302); a held expert's two matrices read the same flips through the experts' rows.
+MAMBA2_KINDS = tuple({"M": ("ssd",), "E": ("routed",), "*": ("attn",)}[c] for c in "MEMEM*EME")
+MAMBA2_PARTS = {"ssd": ("in_proj", "conv_kernel", "conv_bias", "dt_bias", "A_log", "D", "norm_scale", "out_proj"),
+                "attn": ("q_proj", "k_proj", "v_proj", "o_proj"),
+                "routed": ("gate", "experts_wi", "experts_wo", "shared_up_proj", "shared_down_proj")}
+MAMBA2_LEAF_LIMIT = 0.15     # every other leaf: the program 0.023-0.102 (the attention's k_proj the largest) | plain bf16 0.022-0.095 | low_state 0.031-0.129, gated_silu 0.153-0.584, norm_first 0.269-0.949, no_decay 0.629-4.556, no_skip 0.596-16.98
+MAMBA2_CLASS_LIMITS = {
+    "logits": 0.057,         # 0.0472-0.0514 | plain bf16 0.0452-0.0504 | low_state 0.0638-0.0686, gated_silu 0.300-0.305, norm_first 0.516-0.522, no_skip 1.225-1.233, no_decay 1.239-1.243
+    "ssd/dt_bias": 0.25,     # one number a head, 64 a layer: 0.060-0.160 | plain bf16 0.051-0.139 | low_state 0.077-0.175, gated_silu 0.388-0.759, norm_first 0.611-1.106, no_skip 4.90-11.9, no_decay 5.82-13.5
+    "routed/gate": None,
+    "routed/experts_wi": 0.35,  # 0.131-0.244 | plain bf16 0.112-0.231 | low_state 0.187-0.295, gated_silu 0.433-0.796, norm_first 0.787-1.139, no_decay 1.276-1.709, no_skip 1.349-1.828
+    "routed/experts_wo": 0.35,  # 0.131-0.243 | plain bf16 0.112-0.230 | (as above)
+}
+MAMBA2_NAMES = ["wte", "lm_head", "RMSNorm_0/scale"] + [f"layer_{i}/{leaf}" for i, (part,) in enumerate(MAMBA2_KINDS)
+                                                          for leaf in ("RMSNorm_0/scale",) + tuple(f"{part}/{l}" for l in MAMBA2_PARTS[part])]
+MAMBA2_LIMITS = {"logits": MAMBA2_CLASS_LIMITS["logits"],
+                 **{name: MAMBA2_CLASS_LIMITS.get(_sambay_class(name), MAMBA2_LEAF_LIMIT) for name in MAMBA2_NAMES}}
+
+
 # a phase's model: its configuration, the limits, where a judged leaf lies in the gradient tree, its controls
 # (a name and what is wrong with the plain bf16 reference under it) and, where the rows are not uniform ids of the
 # program's length, what makes them
@@ -1150,6 +1185,10 @@ SMOKE_MODELS = {
     "shortconv": ("benchmarks/configs/lfm2-8b-a1b-l5e8.json", SHORTCONV_LIMITS, _sambay_leaf,
                   {"silu_filter": {"filter_act": "silu"}, "chunks_cbu": {"chunks": "cbu"}, "no_qk_norm": {"qk_norm": "none"}, "no_renorm": {"renorm": "none"}},
                   {"program": {"sparse_out_init_scale": 1.0}, "report_plain": True}),
+    "mamba2": ("benchmarks/configs/nemotron3-nano-30b-l9e8.json", MAMBA2_LIMITS, _sambay_leaf,
+               {"gated_silu": {"expert_act": "silu_gated"}, "no_decay": {"decay": "none"}, "norm_first": {"norm": "before_gate"},
+                "no_skip": {"skip": "none"}, "low_state": {"low_state": True}},
+               {"report_plain": True}),
 }
 
 

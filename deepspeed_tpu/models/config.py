@@ -22,7 +22,8 @@ class TransformerFields:
     d_ff: Optional[int] = None  # default: 4*d_model (gelu) or 8/3*d_model (swiglu)
     max_seq_len: int = 2048
     norm: str = "layernorm"  # layernorm | rmsnorm | layernorm_np (olmo: no affine params)
-    # gelu (tanh approx) | gelu_exact (erf) | swiglu | geglu | reglu (relu(gate) * up: the gated FFNs', dense and routed) | relu
+    # gelu (tanh approx) | gelu_exact (erf) | swiglu | geglu | reglu (relu(gate) * up: the gated FFNs', dense and routed) | relu |
+    # relu2 (relu(up)^2: an FFN of TWO matrices, dense, routed and shared alike: the routed layer then has no ``experts_wg``)
     activation: str = "gelu"
     pos_emb: str = "learned"  # learned | rope | alibi | none
     rope_theta: float = 10000.0
@@ -146,6 +147,14 @@ class TransformerFields:
     conv_kernel: int = 3
     # routed, sigmoid scoring: what is added to the sum a token's chosen scores are divided by (the families differ: 1e-20, 1e-6)
     moe_renorm_eps: float = 1e-20
+    # ssd (a Mamba-2 layer): ``ssd_heads`` heads of ``ssd_head_dim`` channels, each with a state of ``ssd_state`` columns and ONE
+    # decay a head and token; B and C are shared by the ``ssd_heads / ssd_groups`` heads of a group, and the gated norm ahead of
+    # the output product runs over a group's channels; a depthwise causal convolution of ``ssd_conv`` with a bias on x, B and C
+    ssd_heads: int = 0
+    ssd_head_dim: int = 64
+    ssd_state: int = 128
+    ssd_groups: int = 1
+    ssd_conv: int = 4
 
     @property
     def kv_heads(self) -> int:

@@ -362,6 +362,8 @@ class MLP(LayerKind, nn.Module):
                 h = wide("up_proj")
                 if cfg.activation == "relu":
                     h = nn.relu(h)
+                elif cfg.activation == "relu2":
+                    h = jnp.square(nn.relu(h))
                 else:  # HF "gelu" is the exact erf form; "gelu_new"/tanh is our default
                     h = nn.gelu(h, approximate=cfg.activation != "gelu_exact")
             return nn.Dense(cfg.d_model, use_bias=bias, name="down_proj", dtype=cfg.dtype, param_dtype=jnp.float32)(h)

@@ -10,7 +10,8 @@ output (``GatedMemory``) and differential attention over the first half's keys
 and values (``DiffCrossAttention``); and grouped-query attention under the
 block-diffusion mask over a doubled row, whose objective is a masked-token loss
 (``BlockDiffMixer``); and a gated short convolution, whose time-mixing is neither a softmax, a scan nor a delta rule
-(``ShortConvMixer``). All are training-side
+(``ShortConvMixer``); and a Mamba-2 layer, whose scan has one decay a head and runs as products of chunks on the MXU
+(``SSDMixer``). All are training-side
 modules: a block built from them takes no KV cache (``LayerKind.no_cache``) and
 ``inference/v2`` refuses these kinds (``LayerKind.stackable``). Each class
 carries its kind's record (``../layer_kind.py::LayerKind``).
@@ -30,6 +31,7 @@ from ..ops import placement
 from ..ops.attention import attention
 from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, gdn_scan, kda, kda_scan
 from ..ops.pallas import scan_operands, short_conv
+from ..ops.ssd import SAVED as SSD_SAVED, ssd
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
@@ -531,6 +533,47 @@ class ShortConvMixer(LayerKind, nn.Module):
             gated = short_conv.short_conv(bcu, w) if path == "kernel" else gated_conv(bcu, w)
         with region("mixer/proj"):
             return dense(D, "out_proj")(gated)
+
+
+class SSDMixer(LayerKind, nn.Module):
+    """A Mamba-2 layer (state-space duality). ``[z, xBC, dt] = h W_in`` (``inner`` + ``inner + 2 G N`` + ``H`` columns,
+    ``inner = ssd_heads * ssd_head_dim``, no bias); ``xBC = silu(causal_conv(xBC) + b_c)``, depthwise over ``ssd_conv`` tokens;
+    split x (``H`` heads of ``P``), B, C (``G = ssd_groups`` vectors of ``N = ssd_state``; head ``h`` reads group ``h // (H /
+    G)``); ``delta = softplus(dt + dt_bias)`` in float32, one a head and token; ``A = -exp(A_log)``, one a head; per head,
+    from a zero state, ``S_t = exp(delta_t A) S_{t-1} + delta_t x_t B_t^T`` (``P x N``, float32) and ``y_t = S_t C_t + D
+    x_t`` (``ops/ssd.py``); ``y = GroupRMSNorm(y * silu(z)) * w``: the gate FIRST, then the norm over each group's ``inner /
+    G`` channels; ``out = y W_out``. The scan is the chunked Pallas kernel on one TPU chip (``ops/pallas/ssd.py``) and the
+    token-by-token recurrence elsewhere; the convolution, the softplus and the gated norm are XLA's fusions."""
+
+    cfg: TransformerFields
+    keeps, hybrid = (SSD_SAVED, SAVED), True
+    paths = {"ssd_path": ("mixer/kernel", {"op": "ssd", "pass": "fwd"})}
+
+    @nn.compact
+    def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
+        self.no_cache(kv_cache, segment_ids)
+        cfg = self.cfg
+        H, P, N, G, K, f32 = cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state, cfg.ssd_groups, cfg.ssd_conv, jnp.float32
+        inner, Bt, S = H * P, *x.shape[:2]
+        dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=f32)
+        with region("mixer/proj"):  # named (``SAVED``: what a checkpointed block keeps): the one product over the model width
+            zxbcdt = checkpoint_name(dense(2 * inner + 2 * G * N + H, "in_proj")(x), SAVED)
+            z, xbc, dt = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * G * N], zxbcdt[..., 2 * inner + 2 * G * N:]
+        with region("mixer/conv"):
+            w = self.param("conv_kernel", _uniform(-K**-0.5, K**-0.5), (K, inner + 2 * G * N), f32)
+            bias = self.param("conv_bias", nn.initializers.zeros, (inner + 2 * G * N,), f32)
+            xbc = nn.silu(causal_conv(xbc, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
+            xs, B, C = xbc[..., :inner], xbc[..., inner:inner + G * N], xbc[..., inner + G * N:]
+        with region("mixer/proj"):
+            delta = jax.nn.softplus(dt.astype(f32) + self.param("dt_bias", _dt_bias_init, (H,), f32))
+            A = -jnp.exp(self.param("A_log", _a_log_init, (H,), f32))
+            D = self.param("D", nn.initializers.ones, (H,), f32)
+        y = ssd(xs.reshape(Bt, S, H, P), delta, A, B.reshape(Bt, S, G, N), C.reshape(Bt, S, G, N), D)
+        with region("mixer/proj"):  # the gate, then the norm a group (elementwise on what the block keeps), and the product out
+            gated = (y.reshape(Bt, S, G, inner // G).astype(f32) * nn.silu(z.astype(f32)).reshape(Bt, S, G, inner // G))
+            normed = gated * jax.lax.rsqrt(jnp.mean(gated * gated, axis=-1, keepdims=True) + cfg.norm_eps)
+            scale = self.param("norm_scale", nn.initializers.ones, (inner,), f32)
+            return dense(cfg.d_model, "out_proj")((normed.reshape(Bt, S, inner) * scale).astype(cfg.dtype))
 
 
 def _count_diffusion(counts):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..layer_kind import LayerKind
 from ..moe.layer import MOE_PARTITION_RULES, EarlyRoutedMoE, MoE, RoutedMoE
 from ..ops.fused_ce import fused_cross_entropy, fused_cross_entropy_sums
 from ..telemetry.tracing import region
@@ -33,7 +34,18 @@ from .config import TransformerFields
 from .layers import (MLP, SAVED, Attention, LayerNorm, LayerNormNP, RMSNorm, UnrotatedAttention, _norm, _rope_table, alibi_slopes,  # noqa: F401
                      apply_rope, make_norm, rope_frequencies, scaled_rope_frequencies)
 from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMemory, GDNMixer, KDAMixer, MLAMixer, ShortConvMixer,
-                     SparseMixer, SSMMixer)
+                     SparseMixer, SSDMixer, SSMMixer)
+
+
+class Absent(LayerKind):
+    """The kind ``none``, in either table: the half of a block that is not there (a stack whose layers are a mixer OR an FFN
+    alone, ``y = x + Part(norm(x))``). No module, no norm, no residual add, no parameters, nothing kept; the block's one
+    part is built, normed and added as it is in a block of two. ``hybrid``: a block of one part keeps by name what that
+    part's ``keeps`` say (its kernel's outputs and its projections), whatever the part: it has no other half whose
+    products its inputs alone would have it make again. No stacked form runs it."""
+
+    hybrid = True
+
 
 # THE table of layer kinds. A kind is declared once: its flax module carries its record (``../layer_kind.py::LayerKind``) and has
 # one line here; ``Block``, ``block_fn``, ``CausalLM.loss_fn``, ``runtime/engine.py`` and ``inference/v2/engine_v2.py`` read
@@ -41,8 +53,8 @@ from .mixers import (BlockDiffMixer, DiffAttention, DiffCrossAttention, GatedMem
 # point one way: ``config.py`` (nothing of the package) <- ``layers.py`` <- ``mixers.py``, ``moe/layer.py`` <- this module
 MIXERS = {"full": Attention, "window": Attention, "kda": KDAMixer, "gdn": GDNMixer, "mla": MLAMixer, "sparse": SparseMixer}
 MIXERS |= {"ssm": SSMMixer, "diff": DiffAttention, "diff_window": DiffAttention, "gmu": GatedMemory, "diff_cross": DiffCrossAttention}
-MIXERS |= {"blockdiff": BlockDiffMixer, "nope": UnrotatedAttention, "conv": ShortConvMixer}
-FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE, "routed_early": EarlyRoutedMoE}
+MIXERS |= {"blockdiff": BlockDiffMixer, "nope": UnrotatedAttention, "conv": ShortConvMixer, "ssd": SSDMixer, "none": Absent}
+FFNS = {"dense": MLP, "moe": MoE, "routed": RoutedMoE, "routed_early": EarlyRoutedMoE, "none": Absent}
 
 
 def records(kinds=None) -> Tuple[type, ...]:
@@ -102,7 +114,7 @@ class TransformerConfig(TransformerFields):
 
     def moe_for(self, layer_idx: int) -> bool:
         """Whether one layer's FFN slot holds experts: a reading of ``kinds``."""
-        return self.kinds[layer_idx][1] != "dense"
+        return self.kinds[layer_idx][1] not in ("dense", "none")
 
     @property
     def uniform_window(self) -> bool:
@@ -147,6 +159,9 @@ def _kinds_of(cfg: TransformerConfig) -> Tuple[Tuple[str, str], ...]:
         if len(kinds) != cfg.n_layers or bad:
             raise ValueError(f"layer_kinds must give n_layers={cfg.n_layers} pairs of {tuple(MIXERS)} x {tuple(FFNS)}, got "
                              f"{len(kinds)} with {bad}")
+        if ("none", "none") in kinds:
+            raise ValueError("a layer of kind ('none', 'none') has neither a mixer nor an FFN: a block is one part or two; leave the "
+                             "layer out of layer_kinds and lower n_layers")
         return kinds
     freq = max(1, cfg.moe_layer_freq)
     windowed = lambda i: cfg.sliding_window is not None and (cfg.window_layers is None or i in cfg.window_layers)
@@ -180,7 +195,11 @@ class Block(nn.Module):
         cfg = self.cfg
         # the layer's two parts are built by their kinds' records (``LayerKind.from_config``), under their names in the tree
         mixer = MIXERS[self.kind[0]]
-        attn = mixer.from_config(cfg, self.kind[0])
+        one_part = Absent in (mixer, FFNS[self.kind[1]])
+        if one_part and (kv_cache is not None or cfg.block_type != "sequential" or cfg.norm_scheme != "pre"):
+            raise NotImplementedError(f"a block of one part ({self.kind}) is a sequential pre-norm block in training: it takes no KV "
+                                      f"cache, and block_type={cfg.block_type!r}, norm_scheme={cfg.norm_scheme!r} wire two parts")
+        attn = None if mixer is Absent else mixer.from_config(cfg, self.kind[0])
         given = {}
 
         def run_attn(h):
@@ -203,6 +222,9 @@ class Block(nn.Module):
             a, new_cache = run_attn(x)
             x = _norm(cfg, x + a)
             x = _norm(cfg, x + self._mlp(cfg, x))
+        elif one_part:  # y = x + Part(norm(x)): the one part's norm and add, nothing for the half that is not there
+            h = _norm(cfg, x)
+            x = x + (self._mlp(cfg, h, {"mixer_input": h}) if attn is None else run_attn(h)[0])
         else:
             h = _norm(cfg, x)
             a, new_cache = run_attn(h)

@@ -23,7 +23,7 @@ from ..ops import placement
 from ..telemetry import device_counts
 from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
-from .sharded_moe import GATES, RUNGS, SAVED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
+from .sharded_moe import GATES, RUNGS, SAVED, UNGATED, combine_output, gate_and_dispatch, routed_part, sigmoid_topk, softmax_topk
 
 
 class Experts(nn.Module):
@@ -164,9 +164,13 @@ class RoutedMoE(LayerKind, nn.Module):
     left out. ``shared_ff`` is the shared part's whole width: a model with n
     shared experts of f gives n * f, since n SwiGLUs added are one with their
     columns side by side. No capacity, no drops, no auxiliary loss: cost follows the rows
-    routed here (``sharded_moe.routed_part``). An expert is ``wo (act(x wg) *
-    x wi)``, the gate ``act`` by the configuration's ``activation``: ``relu``
-    for ``"reglu"``, ``silu`` for every other value.
+    routed here (``sharded_moe.routed_part``). An expert has one of two forms,
+    by the configuration's ``activation``. Gated, three matrices: ``wo (act(x
+    wg) * x wi)``, the gate ``act`` ``relu`` for ``"reglu"`` and ``silu`` for
+    every other value but the next. Ungated, two matrices (``"relu2"``,
+    ``sharded_moe.UNGATED``): ``wo relu(x wi)^2``, no ``experts_wg`` and no
+    ``shared_gate_proj`` in the parameter tree, through the same sort, ladder,
+    grouped products and rows' sum; the shared expert has its experts' form.
 
     The router scores ``x``, the FFN's own input, unless the call is handed
     ``mixer_input`` (the kind ``routed_early``, ``EarlyRoutedMoE``): a router
@@ -190,21 +194,21 @@ class RoutedMoE(LayerKind, nn.Module):
     shared_gate: bool = False
     dtype: Any = jnp.float32
     renorm_eps: float = 1e-20  # sigmoid scoring: what is added to the sum the chosen scores are divided by
-    act: str = "silu"  # the experts' gate (``sharded_moe.GATES``)
+    act: str = "silu"  # the experts' gate (``sharded_moe.GATES``), or their activation where they have no gate (``UNGATED``)
     # its record as the layer kind ``routed``. The line's keys: how the grouped products and the rows' sum were traced,
     # the conditional's form where the buffer's first rung is smaller than every pair (``routed_part``: the rungs above
     # it keep nothing), and how the router scores its tokens and indexes the expert axis (``compare_sum``: ``held_experts``)
     sows, keeps, hybrid = ("intermediates",), (SAVED,), True
     paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
     path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
-    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu",), "act")}
+    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu", "relu2"), "act")}
     report = staticmethod(report_rows)
 
     @classmethod
     def from_config(cls, cfg, kind):
         return cls(hidden_size=cfg.d_model, num_experts=cfg.moe_num_experts, k=cfg.moe_top_k, d_ff=cfg.moe_d_ff or cfg.ffn_dim,
                    held=cfg.moe_held, shared_ff=cfg.moe_shared_d_ff, scale=cfg.moe_route_scale, scoring=cfg.moe_scoring,
-                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, renorm_eps=cfg.moe_renorm_eps, act="relu" if cfg.activation == "reglu" else "silu",
+                   shared_gate=cfg.moe_shared_gate, dtype=cfg.dtype, renorm_eps=cfg.moe_renorm_eps, act={"reglu": "relu", "relu2": "relu2"}.get(cfg.activation, "silu"),
                    name="routed")
 
     @nn.compact
@@ -227,9 +231,10 @@ class RoutedMoE(LayerKind, nn.Module):
             else:
                 select_bias = self.param("select_bias", nn.initializers.zeros, (E,), jnp.float32)
                 idx, weights = sigmoid_topk(logits, select_bias, self.k, self.scale, self.renorm_eps)
-        wg, wi, wo = (self.param(f"experts_{name}", init, shape, jnp.float32).astype(self.dtype)
-                      for name, shape in (("wg", (count, d, self.d_ff)), ("wi", (count, d, self.d_ff)),
-                                          ("wo", (count, self.d_ff, d))))
+        gated = self.act not in UNGATED
+        held = lambda name, *shape: self.param(f"experts_{name}", init, (count, *shape), jnp.float32).astype(self.dtype)
+        wg = held("wg", d, self.d_ff) if gated else None  # an expert of two matrices has no such leaf
+        wi, wo = held("wi", d, self.d_ff), held("wo", self.d_ff, d)
         # the rule's word; the products' own ``fits`` are ``sharded_moe._grouped``'s tiles and ``moe_sum_rows.fits``
         kernel = placement.kernel_path() == "kernel"
         out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, kernel, self.act)
@@ -241,7 +246,10 @@ class RoutedMoE(LayerKind, nn.Module):
                 dense = lambda feats, name: nn.Dense(feats, use_bias=False, name=name, dtype=self.dtype,
                                                      param_dtype=jnp.float32)
                 of_tokens = lambda feats, name: checkpoint_name(dense(feats, name)(tokens), SAVED)
-                h = GATES[self.act](of_tokens(self.shared_ff, "shared_gate_proj")) * of_tokens(self.shared_ff, "shared_up_proj")
+                if gated:
+                    h = GATES[self.act](of_tokens(self.shared_ff, "shared_gate_proj")) * of_tokens(self.shared_ff, "shared_up_proj")
+                else:
+                    h = UNGATED[self.act](of_tokens(self.shared_ff, "shared_up_proj"))
                 shared = dense(d, "shared_down_proj")(h)
                 if self.shared_gate:  # the gate's backward reads the shared expert's output: named with the rest
                     gate = jax.nn.sigmoid(of_tokens(1, "shared_expert_gate").astype(jnp.float32))
@@ -265,17 +273,17 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
     def part(tokens, idx, weights, wg, wi, wo, first):
         """``routed_part``, and the pairs it found routed here over a uniform router's, in thousandths."""
         out, routed, *counts = routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
-        uniform = idx.size * wg.shape[0] / num_experts
+        uniform = idx.size * wo.shape[0] / num_experts
         return out, routed, *counts, jnp.round(routed.astype(jnp.float32) * (1000 / uniform)).astype(jnp.int32)
 
     axis = placement.axis_size("expert")
     rows = placement.batch_spec(tokens.shape, None)
-    held = P("expert", None, None) if axis > 1 and wg.shape[0] % axis == 0 else P()
+    held = P("expert", None, None) if axis > 1 and wo.shape[0] % axis == 0 else P()
     split = rows[0] if len(rows) and rows[0] is not None else ()
     over = (split if isinstance(split, tuple) else (split,)) + (("expert",) if held != P() else ())  # axes the pairs are spread over
 
     def local(tokens, idx, weights, wg, wi, wo):
-        mine = first + (jax.lax.axis_index("expert") * wg.shape[0] if held != P() else 0)
+        mine = first + (jax.lax.axis_index("expert") * wo.shape[0] if held != P() else 0)
         out, routed, dropped, largest, smallest, rung, over_uniform = part(tokens, idx, weights, wg, wi, wo, mine)
         if held != P():
             out = jax.lax.psum(out, "expert")
@@ -285,7 +293,8 @@ def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kern
             smallest = jax.lax.pmin(smallest, over)
         return out, routed, dropped, largest, smallest, rung, over_uniform
 
-    return placement.on_mesh(local, (rows, rows, rows, held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
+    # (an ungated expert's ``wg`` is None: no operand, and no spec for it)
+    return placement.on_mesh(local, (rows, rows, rows, None if wg is None else held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
 
 
 def _mesh_has_axis(axis: str) -> bool:

@@ -199,16 +199,23 @@ def _grouped(xs, w, group_sizes, kernel: bool, row_tile: int = 256, **choice):
     (M, b); rows past the groups' total are not defined. ``row_tile``: the
     rows of a kernel's tile (``routed_part`` says when 512). ``choice``: what
     else the caller chose for these products, counted with their path."""
-    def tile(n, want):
+    def tile(n, want, rows=False):
         """The widest listed tile that divides ``n``; a width that only 128 divides (1,408 = 11 x 128) is its own tile
         while it is small enough to stay in VMEM: eleven times fewer grid steps of eleven times the work. 896 = 7 x 128
         is listed for 1,792 = 2 x 896, whose next divisor down is 256: a product of 2,048 onto 1,792 over 8 groups of
         2,048 rows, forward and backward, 5.61 ms at (256, 512, 256) and 3.90 at (256, 512, 896); 1,792 onto 2,048 3.95
-        at (256, 256, 1024) and 3.35 at (256, 896, 1024) (TPU v5e; ``PERF.md``, PR 55). It divides no other cell's width."""
+        at (256, 256, 1024) and 3.35 at (256, 896, 1024) (TPU v5e; ``PERF.md``, PR 55). It divides no other cell's width.
+        A weight's width over 1,024 that NO listed tile divides (1,856 = 29 x 64) goes in tiles of 1,024, the last part
+        empty: the kernel masks a contraction's remainder and drops a result's. Two products of 2,688 x 1,856 over 8 groups
+        of some 384 rows, forward and backward: 3.29 ms so, 3.34 in tiles of 384 (five, less padding), 3.36 of 512, 5.04 of
+        128, and 12.46 as XLA's ragged product, which is what such a width fell to (TPU v5e; ``PERF.md``, PR 59). The rows
+        have no such tile: the kernel wants them whole."""
         t = max((t for t in (1024, 896, 768, 512, 384, 256, 128) if t <= want and n % t == 0), default=0)
+        if t == 0 and n > 1024 and not rows:
+            return 1024
         return n if t == 128 and n <= 1536 else t
 
-    tiling = (tile(xs.shape[0], row_tile), tile(w.shape[1], 896), tile(w.shape[2], 1024))
+    tiling = (tile(xs.shape[0], row_tile, rows=True), tile(w.shape[1], 896), tile(w.shape[2], 1024))
     kernel = kernel and all(tiling)  # off the TPU, or a width no tile of the kernel divides: XLA's ragged product
     with region("ffn/experts", path="kernel" if kernel else "xla", **choice):  # the choice, counted where it is made
         if not kernel:
@@ -290,13 +297,17 @@ def _back_to_tokens_bwd(res, dout):
 _back_to_tokens.defvjp(_back_to_tokens_fwd, _back_to_tokens_bwd)
 
 
-GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # an expert's gate, by ``act``: SwiGLU's, ReGLU's
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # a gated expert's gate, by ``act``: SwiGLU's, ReGLU's
+UNGATED = {"relu2": lambda x: jnp.square(jax.nn.relu(x))}  # an expert of TWO matrices, ``wo act(x wi)``: its activation, by ``act``
 
 
 def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True, act: str = "silu", row_tile: int = 256):
     """The part of a routed FFN that the experts ``first .. first + n`` add
     (``wg, wi`` (n, d, f), ``wo`` (n, f, d): ``wo (act(x wg) * x wi)``, ``act``
-    the configuration's: ``silu``, or ``relu`` for ReGLU experts; the one line
+    the configuration's: ``silu``, or ``relu`` for ReGLU experts; ``wg`` None:
+    an expert of two matrices, ``wo act(x wi)``, ``act`` one of ``UNGATED``,
+    through the same sort, rows and sums with two grouped products where a
+    gated expert has three; the one line
     below where it is applied is differentiated by autodiff), for
     tokens (N, d) routed by ``idx`` / ``weights`` (N, k) over ALL experts, a
     token's ``k`` experts distinct (``lax.top_k``'s).
@@ -324,7 +335,7 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
     from ..ops.pallas import moe_sum_rows
 
     N, k = idx.shape
-    n = wg.shape[0]
+    n = wo.shape[0]
     keep = lambda x: checkpoint_name(x, SAVED) if named and x is not None else x
     tiled = kernel and moe_sum_rows.fits(N, rows, tokens.shape[1], n, tokens.dtype)  # off the TPU, or a shape its tiles do not take: the gathers
     # the bookkeeping: the two sorts of every pair, the counts, the spans. ``compare_sum``: the expert axis is indexed by
@@ -349,8 +360,11 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         xs = keep(_rows_of(tokens, tok_of_row, row_ok, pos, take, spans))  # (rows, d)
     said = {} if act == "silu" else {"act": act}  # every older configuration's series are what they were
     with region("ffn/experts"):
-        gate, up = keep(_grouped(xs, wg, group_sizes, kernel, row_tile, **said)), keep(_grouped(xs, wi, group_sizes, kernel, row_tile, **said))
-        hidden = (GATES[act](gate) * up).astype(xs.dtype)
+        if wg is None:
+            hidden = UNGATED[act](keep(_grouped(xs, wi, group_sizes, kernel, row_tile, **said))).astype(xs.dtype)
+        else:
+            gate, up = keep(_grouped(xs, wg, group_sizes, kernel, row_tile, **said)), keep(_grouped(xs, wi, group_sizes, kernel, row_tile, **said))
+            hidden = (GATES[act](gate) * up).astype(xs.dtype)
         product = _grouped(hidden, wo, group_sizes, kernel, row_tile, **said)
     with region("ffn/rows"):
         ys = keep(jnp.where(row_ok, product, 0))
@@ -372,7 +386,7 @@ def _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
     differentiation hands on the residuals of BOTH its branches, so whatever
     this one kept (its sorted rows and grouped products, each of a larger
     rung's row count), the first rung wrote zeros for, a layer and a step."""
-    return _at_rung(idx, first, wg.shape[0], rungs,
+    return _at_rung(idx, first, wo.shape[0], rungs,
                     lambda rows: _unkept(tokens, idx, weights, wg, wi, wo, first, rows=rows, kernel=kernel, act=act))
 
 
@@ -419,7 +433,7 @@ def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act)
 def _every_pair_bwd(rungs, kernel, act, res, cotangents):
     tokens, idx, weights, wg, wi, wo, first = res
     back = lambda rows: _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangents[0], rows=rows, kernel=kernel, act=act)
-    d_tokens, d_weights, d_wg, d_wi, d_wo = _at_rung(idx, first, wg.shape[0], rungs, back)
+    d_tokens, d_weights, d_wg, d_wi, d_wo = _at_rung(idx, first, wo.shape[0], rungs, back)
     return d_tokens, None, d_weights, d_wg, d_wi, d_wo, None
 
 
@@ -474,7 +488,7 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     Returns ``held_experts``'s five values and the rung taken (0, 1 or 2:
     ``RUNGS``; 1 where four times the uniform load is every pair)."""
     N, k = idx.shape
-    n = wg.shape[0]
+    n = wo.shape[0]
     usual, four, every = buffer_rungs(N * k, n, num_experts)
     row_tile = 512 if N * k >= 2048 * num_experts else 256
 

@@ -6,7 +6,7 @@ For one sequence, with a state ``h`` in R^{channels x N} that starts at zero (``
     y_t[d]    = sum_n h_t[d, n] C_t[n] + D[d] u_t[d]
 
 The decay differs by channel, state column AND token, so nothing here is a matrix product (the delta rules of
-``kda.py`` are MXU work chunk by chunk; Mamba-2 made the decay one number a head to get there): it is one multiply-add
+``kda.py`` are MXU work chunk by chunk; Mamba-2 made the decay one number a head to get there: ``ssd.py``, beside this file): it is one multiply-add
 a state entry and token on the vector unit, ``exp`` on the transcendental unit beside it. The token-by-token form is
 ``ops/ssm.py::ssm_recurrence`` (the XLA path and this kernel's oracle).
 

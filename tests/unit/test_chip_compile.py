@@ -193,6 +193,21 @@ def _ssm(shape):
     return fwd_bwd, (x, S((B, Sq, channels), F32), S((channels, N), F32), cols, cols, S((channels,), F32), x), 2
 
 
+def _ssd(shape):
+    """The Mamba-2 (SSD) scan, forward and backward: 2 kernels (a group's heads along the lanes, its state in VMEM); the
+    cumulative sums and the per-token floats around them are XLA's."""
+    from deepspeed_tpu.ops.ssd import ssd_chunked
+
+    B, Sq, Hh, P, G, N = shape
+
+    def fwd_bwd(x, delta, A, Bm, Cm, D, dy):
+        y, vjp = jax.vjp(ssd_chunked, x, delta, A, Bm, Cm, D)
+        return (y,) + vjp(dy)
+
+    x, cols, head = S((B, Sq, Hh, P), BF16), S((B, Sq, G, N), BF16), S((Hh,), F32)
+    return fwd_bwd, (x, S((B, Sq, Hh), F32), head, cols, cols, head, x), 2
+
+
 def _flash_diff(shape, window):
     """One of differential attention's two calls: keys of 64 beside values of 128, grouped, under a window or none."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -239,7 +254,8 @@ def _short_conv(shape):
 
 def _grouped_products(shape, tilings):
     """A routed layer's up and down products through the grouped matmul at the tiles ``moe/sharded_moe.py::_grouped`` picks
-    for them (``tests/unit/test_short_conv_layers.py`` holds it to these), forward and backward: gmm, gmm and tgmm each."""
+    for them (``tests/unit/test_short_conv_layers.py`` and ``test_mamba2_layers.py`` hold it to these), forward and backward:
+    gmm, gmm and tgmm each."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     rows, n, d, f = shape
@@ -327,6 +343,12 @@ CASES = {
     "scan_operands_b1_h32_s8192_d128_k4_decay": lambda: _scan_operands((1, 32, 32, 8192, 128, 4), True),  # kimi-linear-48b-l5e8's four KDA layers
     "scan_operands_b1_h16_v32_s8192_d128_k4": lambda: _scan_operands((1, 16, 32, 8192, 128, 4), False),  # qwen3-next-80b-l4e32's three DeltaNet layers
     "scan_operands_b2_h2_v4_s384_d256_k2": lambda: _scan_operands((2, 2, 4, 384, 256, 2), False),  # tiles of 128 rows, two vregs of lanes, two taps
+    "ssd_scan_b1_s8192_h64_p64_g8_n128": lambda: _ssd((1, 8192, 64, 64, 8, 128)),  # nemotron3-nano-30b-l9e8's four Mamba-2 layers: 8 heads a group
+    "ssd_scan_b2_s1000_h4_p64_g2_n128": lambda: _ssd((2, 1000, 4, 64, 2, 128)),  # a length that is padded to chunks, two heads a group (one tile)
+    "flash_gqa_b1_s8192_h32_kvh2_d128": lambda: _flash((1, 8192, 32, 2, 128)),  # ... its one attention layer: SIXTEEN query heads a key head
+    "moe_sum_rows_t8192_d2688_e8_r6144": lambda: _moe_sum_rows((8192, 2688, 8, 6144)),  # ... its routed layers' first rung: 384 rows an expert, twice over
+    "moe_sum_rows_t8192_d2688_e8_r49152": lambda: _moe_sum_rows((8192, 2688, 8, 49152)),  # ... and every pair
+    "gmm_r6144_e8_d2688_f1856_rows256": lambda: _grouped_products((6144, 8, 2688, 1856), ((256, 896, 1024), (256, 1024, 896))),  # ... its two grouped products: 1,856 = 29 x 64 in two tiles of 1,024, the second part empty
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
        for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},

@@ -102,7 +102,7 @@ def tiny(mixer, ffn, **over):
                 pos_emb="rope", tie_embeddings=False, layer_kinds=((mixer, ffn),), sliding_window=16, kda_heads=2, kda_head_dim=16,
                 kda_gate_rank=8, gdn_key_heads=2, gdn_value_heads=4, gdn_head_dim=16, mla_kv_rank=24, mla_qk_nope_dim=24, mla_qk_rope_dim=8,
                 mla_v_dim=16, index_heads=2, index_head_dim=8, index_topk=16, moe_num_experts=8, moe_top_k=2, moe_d_ff=16, moe_shared_d_ff=16,
-                ssm_inner=128, ssm_dt_rank=4, block_length=4, mask_token_id=96)
+                ssm_inner=128, ssm_dt_rank=4, block_length=4, mask_token_id=96, ssd_heads=4, ssd_head_dim=8, ssd_state=16, ssd_groups=2)
     return TransformerConfig(**dict(base, **over))
 
 
@@ -133,7 +133,7 @@ def _names(jaxpr, found):
 
 
 # a kernel's custom_vjp gives these where the kernel runs: off the TPU no trace shows them (``tests/unit/test_chip_compile.py``)
-KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan", "short_conv"}
+KERNELS_ALONE = {"kda_scan", "flash_attention", "ssm_scan", "short_conv", "ssd_scan"}
 # a key that rises only then (``tests/unit/test_moe_sum_rows.py``; ``test_hybrid_layers.py`` and ``test_deltanet_layers.py``)
 WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step": "the scan is the kernel", "gdn_heads_a_step": "the scan is the kernel",
         "blockdiff_tiles": "the attention is the kernel, whose walk it counts (tests/unit/test_blockdiff.py)", "blockdiff_pairs": "the same",
@@ -141,7 +141,7 @@ WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step":
         # (PR 53) softmax attention's kinds share one record, each key its own kind's; the window's walk is the kernel's
         "full_path": "the layer is of the kind full", "window_path": "the layer is of the kind window", "window_keys": "the same",
         "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)",
-        "moe_activation": "the experts' gate is relu (activation reglu)",
+        "moe_activation": "the experts' gate is relu (activation reglu) or they have none (relu2: tests/unit/test_mamba2_layers.py)",
         # (PR 58) XLA differentiates the plain lines: the backward is a call site of its own on the kernels' path alone
         "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)"}
 
@@ -159,7 +159,9 @@ def test_a_kinds_record_is_what_a_traced_block_of_it_does(part, name):
     mixer = table.MIXERS[kind[0]]  # the values between blocks are a mixer's
     taken = _taken_by(mixer, cfg, x, positions)
     params = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, positions, None, None, taken))["params"]
-    assert {n for n in params if "Norm" not in n} == {{"full": "attn", "window": "attn", "nope": "attn", "dense": "mlp", "routed_early": "routed"}.get(n, n) for n in kind}  # its name in the tree
+    in_tree = {"full": "attn", "window": "attn", "nope": "attn", "dense": "mlp", "routed_early": "routed"}
+    assert {n for n in params if "Norm" not in n} == {in_tree.get(n, n) for n in kind if n != "none"}  # its name in the tree; ``none`` has none
+    assert len([n for n in params if "Norm" in n]) == (1 if "none" in kind else 2)  # (PR 59) and no norm of its own: a block of one part has one
     run = lambda p, x: block.apply({"params": p}, x, positions, None, None, taken, mutable=_SOWN)
     if mixer.gives:  # the block's result is then (activations, the values by name): exactly the names the record gives
         given = jax.eval_shape(run, params, x)[0][1]
@@ -200,7 +202,8 @@ def test_every_entry_of_the_table_mixes_the_record_in(name):
     from deepspeed_tpu import layer_kind
 
     cls = {**table.MIXERS, **table.FFNS}[name]
-    assert LayerKind is layer_kind.LayerKind and issubclass(cls, LayerKind) and issubclass(cls, nn.Module)
+    assert LayerKind is layer_kind.LayerKind and issubclass(cls, LayerKind)
+    assert issubclass(cls, nn.Module) != (name == "none")  # (PR 59) the half that is not there is a record and no module
     fields = [f for f in vars(LayerKind) if not f.startswith("_")]
     assert {"sows", "report", "keeps", "hybrid", "paths", "path_words", "joined", "alone", "stackable", "gives", "takes", "targets"} <= set(fields)
     assert all(hasattr(cls, f) for f in fields)
@@ -256,7 +259,8 @@ def test_the_configurations_fields_are_the_parents():
     assert [(f.name, f.default) for f in fields[76:]] == [("ssm_inner", 0), ("ssm_state", 16), ("ssm_conv", 4), ("ssm_dt_rank", 0),
                                                           ("layer_numbers", None),  # PR 46: appended, nothing moved
                                                           ("block_length", 0), ("mask_token_id", 0), ("blockdiff_qk_init_scale", 1.0),  # PR 49: likewise
-                                                          ("conv_kernel", 3), ("moe_renorm_eps", 1e-20)]  # PR 55: likewise
+                                                          ("conv_kernel", 3), ("moe_renorm_eps", 1e-20),  # PR 55: likewise
+                                                          ("ssd_heads", 0), ("ssd_head_dim", 64), ("ssd_state", 128), ("ssd_groups", 1), ("ssd_conv", 4)]  # PR 59: likewise
     assert [f.name for f in fields] == [f.name for f in dataclasses.fields(TransformerFields)]
     cfg = TransformerConfig(n_layers=3)
     assert TransformerConfig(**cfg.__dict__) == cfg == dataclasses.replace(cfg) and hash(cfg) == hash(dataclasses.replace(cfg))

@@ -261,7 +261,7 @@ def test_a_window_calls_walk_says_its_window_and_its_tiles():
     assert next(iter(rose)) == "7/16"  # four q tiles of 512: 1 + 2 + 2 + 2 where the causal mask visits 10
     assert Attention.joined["window_tiles"] == ("mixer/kernel", None, "window_tiles") and Attention.joined["window_keys"] == ("mixer/kernel", None, "window")
     assert UnrotatedAttention.paths == {"nope_path": ("mixer/kernel", {"op": "nope", "pass": "fwd"})}
-    assert EarlyRoutedMoE.joined["moe_router_input"] == ("ffn/router", ("mixer_input",), "input") and RoutedMoE.joined["moe_activation"] == ("ffn/experts", ("relu",), "act")
+    assert EarlyRoutedMoE.joined["moe_router_input"] == ("ffn/router", ("mixer_input",), "input") and RoutedMoE.joined["moe_activation"] == ("ffn/experts", ("relu", "relu2"), "act")  # (PR 59) and the ungated experts' word
     assert "moe_router_input" not in RoutedMoE.joined
 
 
