@@ -30,7 +30,7 @@ from ..ops import masks
 from ..ops import placement
 from ..ops.attention import attention
 from ..ops.kda import HEADS_A_STEP, SAVED as SCAN_SAVED, gdn, gdn_scan, kda, kda_scan
-from ..ops.pallas import scan_operands, short_conv
+from ..ops.pallas import conv_silu, scan_operands, short_conv
 from ..ops.ssd import SAVED as SSD_SAVED, ssd
 from ..ops.ssm import SAVED as SSM_SAVED, selective_scan
 from ..telemetry import device_counts
@@ -76,6 +76,9 @@ def l2_normalize(x, eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
+
+# both Mamba kinds count how their convolution, bias and SiLU ran under this key of the trainer's first-call line
+CONV_SILU = {"conv_silu_path": ("mixer/conv", {"op": "conv_silu", "pass": "fwd"})}
 
 # both delta-rule kinds count what makes their scan's operands under these keys of the trainer's first-call line (the
 # backward's only where that is a call of its own: XLA differentiates the plain lines)
@@ -376,11 +379,13 @@ class SSMMixer(LayerKind, nn.Module):
     2 ``ssm_state`` wide); ``delta = softplus(r W_dt + b_dt)`` in float32; ``A = -exp(A_log)``; per channel ``d``, from a
     zero state, ``h_t = exp(delta_t[d] A[d]) h_{t-1} + delta_t[d] u_t[d] B_t`` and ``y_t[d] = h_t . C_t + D[d] u_t[d]``
     (``ops/ssm.py``); ``out = (y * silu(z)) W_out``. It hands on ``y`` (the scan's output with the ``D`` term, before the
-    ``z`` gate) as ``scan_out``: a later ``gmu`` layer gates it instead of scanning."""
+    ``z`` gate) as ``scan_out``: a later ``gmu`` layer gates it instead of scanning. The convolution, its bias and the SiLU
+    are one Pallas call each way on one TPU chip, from the first half of the kept ``W_in`` product in place
+    (``ops/pallas/conv_silu.py``), and the plain line, XLA's fusions, elsewhere."""
 
     cfg: TransformerFields
     keeps, hybrid = (SSM_SAVED, SAVED), True
-    paths = {"ssm_path": ("mixer/kernel", {"op": "ssm", "pass": "fwd"})}
+    paths = {"ssm_path": ("mixer/kernel", {"op": "ssm", "pass": "fwd"}), **CONV_SILU}
     gives = ("scan_out",)
 
     @nn.compact
@@ -395,10 +400,14 @@ class SSMMixer(LayerKind, nn.Module):
         with region("mixer/proj"):
             uz = dense(2 * inner, "in_proj", x)
             u, z = uz[..., :inner], uz[..., inner:]
-        with region("mixer/conv"):
+        path = conv_silu.path_for(x.shape[1], 0, (inner,), K)
+        with placement.counted("conv_silu", path, name="mixer/conv"):
             w = self.param("conv_kernel", _uniform(-K**-0.5, K**-0.5), (K, inner), f32)
             bias = self.param("conv_bias", nn.initializers.zeros, (inner,), f32)
-            u = nn.silu(causal_conv(u, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
+            if path == "kernel":  # from the kept product's first half in place, float32 inside
+                u, = conv_silu.conv_silu(uz, w, bias, 0, (inner,), placement.interpret())
+            else:
+                u = nn.silu(causal_conv(u, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
         with region("mixer/proj"):
             r_b_c = dense(rank + 2 * N, "x_proj", u)
             r, B, C = r_b_c[..., :rank], r_b_c[..., rank:rank + N], r_b_c[..., rank + N:]
@@ -543,11 +552,13 @@ class SSDMixer(LayerKind, nn.Module):
     from a zero state, ``S_t = exp(delta_t A) S_{t-1} + delta_t x_t B_t^T`` (``P x N``, float32) and ``y_t = S_t C_t + D
     x_t`` (``ops/ssd.py``); ``y = GroupRMSNorm(y * silu(z)) * w``: the gate FIRST, then the norm over each group's ``inner /
     G`` channels; ``out = y W_out``. The scan is the chunked Pallas kernel on one TPU chip (``ops/pallas/ssd.py``) and the
-    token-by-token recurrence elsewhere; the convolution, the softplus and the gated norm are XLA's fusions."""
+    token-by-token recurrence elsewhere; the convolution, its bias and the SiLU are one Pallas call each way there, which
+    reads xBC's columns of the kept ``W_in`` product in place and writes x, B and C as the scan takes them
+    (``ops/pallas/conv_silu.py``), and the plain lines, XLA's fusions, elsewhere; the softplus and the gated norm are XLA's."""
 
     cfg: TransformerFields
     keeps, hybrid = (SSD_SAVED, SAVED), True
-    paths = {"ssd_path": ("mixer/kernel", {"op": "ssd", "pass": "fwd"})}
+    paths = {"ssd_path": ("mixer/kernel", {"op": "ssd", "pass": "fwd"}), **CONV_SILU}
 
     @nn.compact
     def __call__(self, x, positions=None, kv_cache=None, segment_ids=None):
@@ -559,11 +570,18 @@ class SSDMixer(LayerKind, nn.Module):
         with region("mixer/proj"):  # named (``SAVED``: what a checkpointed block keeps): the one product over the model width
             zxbcdt = checkpoint_name(dense(2 * inner + 2 * G * N + H, "in_proj")(x), SAVED)
             z, xbc, dt = zxbcdt[..., :inner], zxbcdt[..., inner:2 * inner + 2 * G * N], zxbcdt[..., 2 * inner + 2 * G * N:]
-        with region("mixer/conv"):
+        widths = (inner, G * N, G * N)
+        path = conv_silu.path_for(S, inner, widths, K)
+        with placement.counted("conv_silu", path, name="mixer/conv"):
             w = self.param("conv_kernel", _uniform(-K**-0.5, K**-0.5), (K, inner + 2 * G * N), f32)
             bias = self.param("conv_bias", nn.initializers.zeros, (inner + 2 * G * N,), f32)
-            xbc = nn.silu(causal_conv(xbc, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
-            xs, B, C = xbc[..., :inner], xbc[..., inner:inner + G * N], xbc[..., inner + G * N:]
+            if path == "kernel":
+                # x, B and C from the kept product's middle columns in place, float32 inside; kept under the scan's name,
+                # whose residuals they are: the block's backward runs no second forward call (``PERF.md`` section 6, PR 60)
+                xs, B, C = (checkpoint_name(v, SSD_SAVED) for v in conv_silu.conv_silu(zxbcdt, w, bias, inner, widths, placement.interpret()))
+            else:
+                xbc = nn.silu(causal_conv(xbc, w.astype(cfg.dtype)) + bias.astype(cfg.dtype))
+                xs, B, C = xbc[..., :inner], xbc[..., inner:inner + G * N], xbc[..., inner + G * N:]
         with region("mixer/proj"):
             delta = jax.nn.softplus(dt.astype(f32) + self.param("dt_bias", _dt_bias_init, (H,), f32))
             A = -jnp.exp(self.param("A_log", _a_log_init, (H,), f32))

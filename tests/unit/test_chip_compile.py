@@ -179,6 +179,21 @@ def _scan_operands(shape, decay):
     return fwd_bwd, (args, cts), 2
 
 
+def _conv_silu(shape, start, widths):
+    """A Mamba layer's convolution, bias and SiLU over a column range of the kept product, forward and backward: 2
+    kernels, a tile of rows of a part of every output a grid step, the product's columns reached in place."""
+    from deepspeed_tpu.ops.pallas.conv_silu import conv_silu
+
+    B, Sq, columns, K = shape
+    args = (S((B, Sq, columns), BF16), S((K, sum(widths)), F32), S((sum(widths),), F32))
+
+    def fwd_bwd(args, cts):
+        out, vjp = jax.vjp(lambda x, w, b: conv_silu(x, w, b, start, widths), *args)
+        return out, vjp(cts)
+
+    return fwd_bwd, (args, tuple(S((B, Sq, W), BF16) for W in widths)), 2
+
+
 def _ssm(shape):
     """The selective scan, forward and backward: 2 kernels (channels along the lanes, the whole state in VMEM)."""
     from deepspeed_tpu.ops.ssm import ssm_chunked
@@ -344,11 +359,13 @@ CASES = {
     "scan_operands_b1_h16_v32_s8192_d128_k4": lambda: _scan_operands((1, 16, 32, 8192, 128, 4), False),  # qwen3-next-80b-l4e32's three DeltaNet layers
     "scan_operands_b2_h2_v4_s384_d256_k2": lambda: _scan_operands((2, 2, 4, 384, 256, 2), False),  # tiles of 128 rows, two vregs of lanes, two taps
     "ssd_scan_b1_s8192_h64_p64_g8_n128": lambda: _ssd((1, 8192, 64, 64, 8, 128)),  # nemotron3-nano-30b-l9e8's four Mamba-2 layers: 8 heads a group
+    "conv_silu_b1_s8192_c10304_at4096_4096_1024_1024_k4": lambda: _conv_silu((1, 8192, 10304, 4), 4096, (4096, 1024, 1024)),  # ... their convolution, bias and SiLU: x, B and C from [z, xBC, dt]
     "ssd_scan_b2_s1000_h4_p64_g2_n128": lambda: _ssd((2, 1000, 4, 64, 2, 128)),  # a length that is padded to chunks, two heads a group (one tile)
     "flash_gqa_b1_s8192_h32_kvh2_d128": lambda: _flash((1, 8192, 32, 2, 128)),  # ... its one attention layer: SIXTEEN query heads a key head
     "moe_sum_rows_t8192_d2688_e8_r6144": lambda: _moe_sum_rows((8192, 2688, 8, 6144)),  # ... its routed layers' first rung: 384 rows an expert, twice over
     "moe_sum_rows_t8192_d2688_e8_r49152": lambda: _moe_sum_rows((8192, 2688, 8, 49152)),  # ... and every pair
     "gmm_r6144_e8_d2688_f1856_rows256": lambda: _grouped_products((6144, 8, 2688, 1856), ((256, 896, 1024), (256, 1024, 896))),  # ... its two grouped products: 1,856 = 29 x 64 in two tiles of 1,024, the second part empty
+    "conv_silu_b1_s8192_c10240_at0_5120_k4": lambda: _conv_silu((1, 8192, 10240, 4), 0, (5120,)),  # phi4-mini-flash-l6's two scan layers: u from [u, z]
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),
     **{f"indexed_{which}_s8192_h32_kv4_d128": (lambda which=which: _indexed(which))  # keye-vl2-30b-l4e16's six calls
        for which in ("index_scores", "index_select", "sparse_fwd", "sparse_bwd", "index_loss", "index_scores_bwd")},

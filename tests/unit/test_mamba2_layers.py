@@ -97,7 +97,8 @@ def test_a_block_of_one_part_holds_one_norm_and_nothing_for_the_absent_half():
     assert set(params["layer_5"]["attn"]) == {"q_proj", "k_proj", "v_proj", "o_proj"} and "wpe" not in params and "lm_head" in params
     record = table.MIXERS["ssd"]
     assert record is SSDMixer and record.hybrid and not record.stackable and record.gives == record.takes == () and not record.sows
-    assert record.keeps == ("ssd_scan", "projection") and record.paths == {"ssd_path": ("mixer/kernel", {"op": "ssd", "pass": "fwd"})}
+    assert record.keeps == ("ssd_scan", "projection") and record.paths == {"ssd_path": ("mixer/kernel", {"op": "ssd", "pass": "fwd"}),
+                                                                      "conv_silu_path": ("mixer/conv", {"op": "conv_silu", "pass": "fwd"})}
     absent = table.MIXERS["none"]
     assert absent is table.FFNS["none"] and absent.keeps == () and absent.hybrid and not absent.stackable and not absent.sows and not absent.paths
     assert table.remat_keeps(("ssd", "none")) == ("ssd_scan", "projection") and table.remat_keeps(("none", "routed")) == ("routed_ffn", "projection")
