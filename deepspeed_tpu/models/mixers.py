@@ -310,7 +310,7 @@ class SparseMixer(LayerKind, nn.Module):
     # call writes beside the loss (the flash call's where every visible key is chosen); a model has this mixer in every
     # layer or in none, so its key is given though ``alone``
     sows, keeps, hybrid = ("intermediates",), (sparse.SAVED, FLASH_SAVED, SAVED), True
-    paths, joined, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, ROPE_FORM, True
+    paths, joined, alone = {"sparse_path": ("mixer/kernel", {"op": "sparse", "pass": "fwd"})}, {**ROPE_FORM, **sparse.INDEX_STRIP}, True
 
     @staticmethod
     def report(intermediates):

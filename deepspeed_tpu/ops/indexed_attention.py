@@ -8,7 +8,8 @@ holds the whole square and is the kernels' oracle. Which one a call site took
 is counted where it is chosen (``program_regions_traced_total{region=
 "mixer/kernel", op="sparse", pass, path}``, ``{region="mixer/select", path}``;
 the kernels' choice also says, as ``counted``, the share of a band's rows its
-count passes walk).
+count passes walk, and the two kernels with a loop over heads, as
+``index_strip``, the strip of a tile their sums over heads are made in).
 
 Square arrays are key-major, ``(B, Sk, Sq)``, as the kernels keep them
 (``scores_t``, ``mask_t``, the index loss's gradient in the scores; XLA's
@@ -32,6 +33,15 @@ NEG_INF = kernel.NEG_INF
 # remat_keeps``): a checkpointed block that keeps them runs neither the indexer's scores, nor the choice, nor a
 # forward kernel, nor the index loss's call a second time
 SAVED = "sparse_attention"
+# for a kind's record (``LayerKind.joined``): the strip in which the kernels with a loop over heads (``index_scores``,
+# ``index_loss``) walk a tile (``ops/pallas/indexed_attention.py::strip_for``), as their call sites counted it
+INDEX_STRIP = {"index_strip": ("mixer/kernel", None, "index_strip")}
+
+
+def _strip(path: str, seq: int) -> dict:
+    """The label ``index_strip="<rows>x<lanes>"`` of a site that took a kernel with a loop over heads at this sequence
+    (static: the rule's word for the call's block); nothing on XLA's path."""
+    return {"index_strip": "{}x{}".format(*kernel.strip_for(kernel.block_for(seq)))} if path == "kernel" else {}
 
 
 def path_for(seq: int, topk: int) -> str:
@@ -55,7 +65,7 @@ def index_scores_xla(q_i, k_i, w):
 def index_scores(q_i, k_i, w, *, path: str):
     """The scores the choice and ``index_loss`` read. The kernel's take no gradient: ``index_loss`` carries the
     indexer's gradient itself, from the one array its backward keeps."""
-    placement.count("sparse", path, "index")
+    placement.count("sparse", path, "index", **_strip(path, q_i.shape[2]))
     if path != "kernel":
         return index_scores_xla(q_i, k_i, w)
     return kernel.index_scores(*(jax.lax.stop_gradient(x) for x in (q_i, k_i, w)), interpret=placement.interpret())
@@ -200,7 +210,7 @@ def index_loss(q_i, k_i, w, scores_t, q, k, lse, mask_t, *, scale: float, dtype,
     target a tile at a time and finishes the loss there: it keeps ONE square array for its backward, the loss's
     gradient in the scores in ``dtype`` (the model's: a cotangent like any other) under the name ``SAVED``, and
     ``index_scores_bwd`` carries it to ``q_i``, ``k_i`` and ``w``."""
-    placement.count("sparse", path, "loss")
+    placement.count("sparse", path, "loss", **_strip(path, q.shape[1]))
     q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
     if path != "kernel":
         return _index_loss_and_grad(scores_t, head_probs_xla(q, k, lse, mask_t, scale), mask_t)[0]

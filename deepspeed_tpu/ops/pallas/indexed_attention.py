@@ -31,14 +31,40 @@ calls, named apart from the flash kernels' on the device's clock:
   A query block walks its key blocks twice. The first sweep makes the target
   a tile at a time, ``P[s, t] = sum_h exp(q_h[t] . k[s] * scale - lse_h[t])``
   over the chosen pairs (from the forward's row statistics), keeps the
-  block's strip of it in VMEM, (Sk, bq) float32, and adds up a query's
+  block's column of it in VMEM, (Sk, bq) float32, and adds up a query's
   statistics along its keys: ``Z = sum P``, the chosen scores' online max and
   sum (``lse_I``) and ``sum P (log P - I)``, from which
   ``KL_t = sum P (log P - I) / Z - log Z + lse_I``. The second sweep reads
-  the strip and the scores' and mask's tiles again and writes the gradient of
+  the column and the scores' and mask's tiles again and writes the gradient of
   the queries' mean in the scores, ``(softmax_{S_t} I - P / Z) / queries``,
   rounded once to the model's dtype: the one square array the backward
   keeps. The head-summed probabilities never reach HBM.
+
+How the two calls with a sum over heads (``index_scores``, ``index_loss``) walk
+a (bk, bq) tile: in STRIPS of every row by 128 lanes (``strip_for``), the heads
+innermost, eight a trip (``_sum_over_heads``). A strip's 128 queries are one
+weight tile of ``q_h^T`` in the MXU, which then serves the longest stream of
+key rows the tile has; a trip's eight products go to the four MXUs abreast and
+each head's term is added as it leaves its MXU; the sum is read from and
+written to VMEM (the output block, the column of ``P``) ONCE A TRIP, and a
+strip's statistics along the keys are made when its heads are done. Carried
+over the heads as one (bk, bq) value, 256 vregs of the file's 64, the sum went
+through the one store slot a bundle once a HEAD, beside a product made whole
+before anything read it (667 spill stores in the 994 bundles a head and tile
+of ``index_scores``; 1.91 -> 1.23 ms a call and 4.24 -> 2.78 for
+``index_loss`` at 8,192 positions: PERF.md section 6, PR 62). The strips are a
+rolled loop and the heads another, never Python copies of a body: sixteen and
+thirty-two copies of a head, traced and lowered at every call site, cost the
+cell 5 s of every warm start (PR 61), and a trip of 1, 2 or 4 heads waits on
+its own product's way through the MXU (2.25, 1.66 ms a call). The sums over
+heads keep their order (``index_scores`` is bit for bit what it was), and so
+do a strip's sums along the keys. ``index_scores_bwd`` keeps its whole-tile
+products, one head a trip: its three products a head are what bounds it
+(1,536 MXU cycles of its 1,774 bundles a head and tile), and every form of it
+in strips under a rolled loop is slower (PERF.md, PR 62). The six entry points
+are jitted for the trace's sake, not the program's (they are inlined where
+they are called): ``model.init``'s plain loop over the layers and the step's
+checkpointed block share one trace of a body a shape.
 
 A masked score is ``NEG_INF`` (finite): a query whose first visited blocks hold
 none of its keys carries ``m = NEG_INF`` and a sum of ones until its first key
@@ -59,10 +85,46 @@ BLOCK = 512  # the (bk, bq) tile of the attention and indexer kernels, and of th
 BAND = 128   # queries whose scores ``index_select`` holds at once: (Sk, 128) float32 are 4 MB at 8,192 keys
 CHUNK = 256  # rows of a band a count pass of ``index_select`` takes at a time: 32 vregs (128 and 512 rows are slower: PERF.md, PR 44)
 INT_MIN = -2**31
+HEADS_A_TRIP = 8  # heads a trip of the rolled loop over heads writes out: two a v5e's MXU, their products abreast (1, 2 and 4 a trip: PERF.md, PR 62)
 
 
 def block_for(seq: int, want: int = 0) -> int:
     return block_that_divides(seq, want or BLOCK)
+
+
+def strip_for(blk: int) -> tuple:
+    """The (rows, lanes) strip in which a loop over heads walks a (blk, blk) tile, read off the block: one vreg row of
+    lanes across, which is one weight tile of ``q_h^T`` in the MXU, and EVERY row of the tile, the longest stream a
+    weight tile can serve here (a tile's load costs the MXU what 128 streamed rows cost)."""
+    return blk, block_that_divides(blk, BAND)
+
+
+def _strips(blk: int, body):
+    """``body(lanes)`` on every strip of a (blk, blk) tile: ONE rolled loop whatever the count (sixteen copies of a body
+    are sixteen bodies to trace, lower and compile on every start)."""
+    L = strip_for(blk)[1]
+
+    def step(t, _):
+        body(pl.ds(pl.multiple_of(t * L, L), L))
+
+    jax.lax.fori_loop(0, blk // L, step, None)
+
+
+def _sum_over_heads(heads: int, add, ref, at):
+    """``ref[at] = term(0) + term(1) + ...``, ``add(h, acc)`` adding head ``h``'s term, in the heads' order, as ONE
+    rolled loop of ``HEADS_A_TRIP`` heads a trip written out: within a trip the heads' products go to the MXUs abreast
+    and each term is added as it leaves its MXU, and the sum is read from and written to VMEM once a TRIP (carried over
+    the heads as a whole tile it went through the one store slot once a head). The code is a trip's whatever the heads."""
+    a = next(a for a in (HEADS_A_TRIP, 4, 2, 1) if heads % a == 0)
+    ref[at] = jnp.zeros_like(ref[at])
+
+    def trip(t, _):
+        acc = ref[at]
+        for g in range(a):
+            acc = add(t * a + g, acc)
+        ref[at] = acc
+
+    jax.lax.fori_loop(0, heads // a, trip, None)
 
 
 def kernels_take(seq: int, topk: int) -> bool:
@@ -88,18 +150,20 @@ def _index_scores_kernel(q_ref, k_ref, w_ref, o_ref, *, blk: int, heads: int):
 
     @pl.when(j <= i)
     def _scores():
-        k = k_ref[0]
+        def walk(lanes):
+            def add(h, acc):  # the one key head's rows serve every indexer head
+                s = jax.lax.dot_general(k_ref[0], q_ref[0, h, lanes, :], _NT, preferred_element_type=jnp.float32)  # (bk, L)
+                return acc + jnp.maximum(s, 0.0) * w_ref[0, h, :, lanes]
 
-        def head(h, acc):
-            s = jax.lax.dot_general(k, q_ref[0, h], _NT, preferred_element_type=jnp.float32)  # (bk, bq)
-            return acc + jnp.maximum(s, 0.0) * w_ref[0, h]
+            _sum_over_heads(heads, add, o_ref, (0, slice(None), lanes))
+            keys = j * blk + jax.lax.broadcasted_iota(jnp.int32, strip_for(blk), 0)
+            queries = i * blk + lanes.start + jax.lax.broadcasted_iota(jnp.int32, strip_for(blk), 1)
+            o_ref[0, :, lanes] = jnp.where(keys <= queries, o_ref[0, :, lanes], NEG_INF)
 
-        acc = jax.lax.fori_loop(0, heads, head, jnp.zeros((blk, blk), jnp.float32))
-        keys = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-        queries = i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-        o_ref[0] = jnp.where(keys <= queries, acc, NEG_INF)
+        _strips(blk, walk)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret", "blk"))
 def index_scores(q_i, k_i, w, *, interpret: bool = False, blk: int = 0):
     """q_i (B, J, S, Di), k_i (B, S, Di), w (B, J, S) float32 -> I^T (B, S, S) float32, key-major."""
     B, J, S, Di = q_i.shape
@@ -155,6 +219,7 @@ def _index_scores_bwd_kernel(g_ref, q_ref, k_ref, w_ref, dq_ref, dw_ref, dk_ref,
         dw_ref[0] = dw_acc[...]
 
 
+@functools.partial(jax.jit, static_argnames=("interpret", "blk"))
 def index_scores_bwd(g, q_i, k_i, w, *, interpret: bool = False, blk: int = 0):
     """The cotangent ``g`` of I^T (B, S, S) -> (dq_i, dk_i, dw), each its operand's shape; dw float32."""
     B, J, S, Di = q_i.shape
@@ -281,6 +346,7 @@ def _index_select_kernel(s_ref, o_ref, key_ref, *, topk: int, seq: int, band: in
         each(0, n, choose)
 
 
+@functools.partial(jax.jit, static_argnames=("topk", "interpret", "band", "rows"))
 def index_select(scores_t, topk: int, *, interpret: bool = False, band: int = 0, rows: int = 0):
     """I^T (B, Sk, Sq) float32 -> the choice as an int8 mask (B, Sk, Sq): a query's ``min(topk, t + 1)``
     largest visible scores, ties to the lower index."""
@@ -332,6 +398,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *, blk: int, scal
     lse_ref[0, 0] = m + jnp.log(l)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "H", "KVH", "interpret"))
 def sparse_fwd(q, k, v, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
     """q (B*H, S, D), k and v (B*KVH, S, D), the mask in tiles (B, S/blk, S/blk, blk, blk) -> o, lse (B*H, S)."""
     BH, S, D = q.shape
@@ -425,6 +492,7 @@ def bwd_budget() -> int:
     return vmem_budget() * 4 // 3
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "H", "KVH", "interpret"))
 def sparse_bwd(q, k, v, o, lse, do, mask_tiles, scale: float, H: int, KVH: int, *, interpret: bool = False):
     BH, S, D = q.shape
     BKV = k.shape[0]
@@ -461,10 +529,11 @@ def sparse_bwd(q, k, v, o, lse, do, mask_tiles, scale: float, H: int, KVH: int, 
     )(q, k, v, do, _rows(lse, blk), _rows(delta, blk), mask_tiles)
 
 
-def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, g_ref, strip, z_ref, m_ref, l_ref, d_ref, *,
-                 heads: int, n_rep: int, scale: float, queries: int):
+def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, g_ref, kept, z_ref, m_ref, l_ref, d_ref, *,
+                 blk: int, heads: int, n_rep: int, scale: float, queries: int):
     """A query block's walk along its key blocks, twice: grid (B, S/blk, 2 S/blk). Steps ``j < n`` make the target's
-    tiles ``P`` (kept in ``strip``) and the rows' statistics; steps ``n + j`` write the gradient's tiles."""
+    tiles ``P`` (in ``kept``, summed over the heads a strip at a time) and the rows' statistics; steps ``n + j`` write
+    the gradient's tiles."""
     i, j = pl.program_id(1), pl.program_id(2)
     n = pl.num_programs(2) // 2
 
@@ -476,22 +545,24 @@ def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, g_ref, strip, z
 
     @pl.when(j <= i)
     def _target():
-        chosen = _chosen(mask_ref[0, 0, 0])
+        def walk(lanes):
+            def add(h, p):  # a KV head's keys serve its ``n_rep`` query heads
+                s = jax.lax.dot_general(k_ref[h // n_rep], q_ref[h, lanes, :], _NT, preferred_element_type=jnp.float32) * scale
+                return p + jnp.exp(jnp.where(_chosen(mask_ref[0, 0, 0, :, lanes]), s, NEG_INF) - lse_ref[h, 0, :, lanes])
 
-        def head(h, acc):
-            s = jax.lax.dot_general(k_ref[h // n_rep], q_ref[h], _NT, preferred_element_type=jnp.float32) * scale
-            return acc + jnp.exp(jnp.where(chosen, s, NEG_INF) - lse_ref[h, 0])
+            _sum_over_heads(heads, add, kept, (j, slice(None), lanes))
+            # the strip's statistics, over all its rows at once: a sum along the keys keeps the order it had a tile
+            p = kept[j, :, lanes]
+            scores = jnp.where(_chosen(mask_ref[0, 0, 0, :, lanes]), s_ref[0, :, lanes], NEG_INF)
+            m = m_ref[:, lanes]
+            new_m = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
+            l_ref[:, lanes] = l_ref[:, lanes] * jnp.exp(m - new_m) + jnp.sum(jnp.exp(scores - new_m), axis=0, keepdims=True)
+            m_ref[:, lanes] = new_m
+            z_ref[:, lanes] += jnp.sum(p, axis=0, keepdims=True)
+            some = p > 0.0  # a chosen pair's p can underflow: it adds nothing, as in XLA's form
+            d_ref[:, lanes] += jnp.sum(jnp.where(some, p * (jnp.log(jnp.where(some, p, 1.0)) - scores), 0.0), axis=0, keepdims=True)
 
-        p = jax.lax.fori_loop(0, heads, head, jnp.zeros(strip.shape[1:], jnp.float32))
-        strip[j] = p
-        scores = jnp.where(chosen, s_ref[0], NEG_INF)
-        m = m_ref[...]
-        new_m = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
-        l_ref[...] = l_ref[...] * jnp.exp(m - new_m) + jnp.sum(jnp.exp(scores - new_m), axis=0, keepdims=True)
-        m_ref[...] = new_m
-        z_ref[...] += jnp.sum(p, axis=0, keepdims=True)
-        some = p > 0.0  # a chosen pair's p can underflow: it adds nothing, as in XLA's form
-        d_ref[...] += jnp.sum(jnp.where(some, p * (jnp.log(jnp.where(some, p, 1.0)) - scores), 0.0), axis=0, keepdims=True)
+        _strips(blk, walk)
 
     @pl.when(j == n)
     def _loss():  # sum_s p (log p - log softmax I) with p = P / Z
@@ -500,9 +571,12 @@ def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, g_ref, strip, z
 
     @pl.when(jnp.logical_and(j >= n, j - n <= i))
     def _gradient():
-        lse_i = m_ref[...] + jnp.log(l_ref[...])
-        soft = jnp.where(_chosen(mask_ref[0, 0, 0]), jnp.exp(s_ref[0] - lse_i), 0.0)
-        g_ref[0] = ((soft - strip[j - n] * (1.0 / z_ref[...])) * (1.0 / queries)).astype(g_ref.dtype)
+        def gradient(lanes):
+            lse_i = m_ref[:, lanes] + jnp.log(l_ref[:, lanes])
+            soft = jnp.where(_chosen(mask_ref[0, 0, 0, :, lanes]), jnp.exp(s_ref[0, :, lanes] - lse_i), 0.0)
+            g_ref[0, :, lanes] = ((soft - kept[j - n, :, lanes] * (1.0 / z_ref[:, lanes])) * (1.0 / queries)).astype(g_ref.dtype)
+
+        _strips(blk, gradient)
 
     @pl.when(j - n > i)
     def _above():
@@ -515,6 +589,7 @@ def loss_vmem(S: int, D: int, H: int, KVH: int, item: int, blk: int) -> int:
     return S * blk * 4 + 2 * (H + KVH) * blk * D * item + 2 * blk * blk * (4 + 1 + 4) + _tile_bytes(blk, blk)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "H", "KVH", "dtype", "interpret"))
 def index_loss(q, k, lse, mask_tiles, scores_t, scale: float, H: int, KVH: int, dtype, *, interpret: bool = False):
     """The indexer's loss where its target is made: q (B*H, S, D), k (B*KVH, S, D), the forward's lse (B*H, S), the mask
     in tiles and I^T (B, S, S) float32 -> every query's ``KL(p || softmax_{S_t} I)`` (B, S) float32, ``p`` the heads'
@@ -531,7 +606,7 @@ def index_loss(q, k, lse, mask_tiles, scores_t, scale: float, H: int, KVH: int, 
     # passed the diagonal (a step that fetches nothing new)
     key = lambda i, j: jnp.minimum(j % n, i)
     kl, grad = pl.pallas_call(
-        functools.partial(_loss_kernel, heads=H, n_rep=H // KVH, scale=scale, queries=B * S),
+        functools.partial(_loss_kernel, blk=blk, heads=H, n_rep=H // KVH, scale=scale, queries=B * S),
         grid=(B, n, 2 * n),
         in_specs=[
             pl.BlockSpec((H, blk, D), lambda b, i, j: (b, i, 0)),

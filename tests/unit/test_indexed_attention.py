@@ -52,11 +52,80 @@ def chosen_pairs(seq, topk, batch=1):
     return batch * sum(min(topk, t + 1) for t in range(seq))
 
 
-def test_the_score_kernel_is_the_xla_form(operands):
-    t = operands
-    got = kernel.index_scores(t["q_i"], t["k_i"], t["w"], interpret=True, blk=BLK)
+# (positions, the tile) of the cases in which the loops over heads have something to walk: ``strips_and_tiles`` has four
+# strips a tile (``strip_for(512)`` is (512, 128)), two tiles a row (the diagonal's, one below, one above), 16 indexer
+# heads (two trips of eight) and 32 query heads on 4 KV heads (four trips; a trip's heads share one KV head's keys);
+# ``a_strip_a_tile`` is the block that IS one strip, three indexer heads (trips of one head) and 4 query heads (one trip)
+WALKS = {"a_strip_a_tile": (S, BLK), "strips_and_tiles": (1024, 512)}
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_case(case):
+    """The three kernels' operands of a walk's case with XLA's forms of everything: the oracle."""
+    seq, blk = WALKS[case]
+    b, heads, kv_heads, index_heads, topk = (B, H, KVH, J, TOPK) if case == "a_strip_a_tile" else (1, 32, 4, 16, 96)
+    rng = np.random.default_rng(sorted(WALKS).index(case) + 100)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        t = dict(q=f(b, seq, heads, D), k=f(b, seq, kv_heads, D), v=f(b, seq, kv_heads, D), q_i=f(b, index_heads, seq, DI), k_i=f(b, seq, DI),
+                 w=f(b, index_heads, seq) * 0.1, heads=(heads, kv_heads))
+        t["scores"] = ops.index_scores_xla(t["q_i"], t["k_i"], t["w"])
+        t["mask"] = ops.select_xla(t["scores"], topk)
+        _, t["lse"] = ops.sparse_attention_xla(t["q"], t["k"], t["v"], t["mask"], SCALE)
+        t["loss"], t["grad"] = ops._index_loss_and_grad(t["scores"], ops.head_probs_xla(t["q"], t["k"], t["lse"], t["mask"], SCALE), t["mask"])
+        t["vjp"] = jax.vjp(ops.index_scores_xla, t["q_i"], t["k_i"], t["w"])[1](t["grad"])
+    return t
+
+
+def _parents_index_scores(q_i, k_i, w, blk):
+    """``index_scores`` as it stood before PR 62, interpreted: a tile's sum over heads carried whole through one loop."""
+    from jax.experimental import pallas as pl
+
+    b, heads, seq, di = q_i.shape
+    n = seq // blk
+
+    def body(q_ref, k_ref, w_ref, o_ref):
+        i, j = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j > i)
+        def _above():
+            o_ref[0] = jnp.full((blk, blk), kernel.NEG_INF, jnp.float32)
+
+        @pl.when(j <= i)
+        def _scores():
+            k = k_ref[0]
+
+            def head(h, acc):
+                s = jax.lax.dot_general(k, q_ref[0, h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                return acc + jnp.maximum(s, 0.0) * w_ref[0, h]
+
+            acc = jax.lax.fori_loop(0, heads, head, jnp.zeros((blk, blk), jnp.float32))
+            keys = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+            queries = i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+            o_ref[0] = jnp.where(keys <= queries, acc, kernel.NEG_INF)
+
+    return pl.pallas_call(body, grid=(b, n, n), interpret=True, out_shape=jax.ShapeDtypeStruct((b, seq, seq), jnp.float32),
+                          in_specs=[pl.BlockSpec((1, heads, blk, di), lambda b, i, j: (b, 0, i, 0)), pl.BlockSpec((1, blk, di), lambda b, i, j: (b, j, 0)),
+                                    pl.BlockSpec((1, heads, 1, blk), lambda b, i, j: (b, 0, 0, i))],
+                          out_specs=pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, j, i)))(q_i, k_i, w.reshape(b, heads, 1, seq))
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_score_kernel_is_the_xla_form(highest, case):
+    """... and, bit for bit, what it gave before its sum over heads was made a strip at a time, eight heads a trip: the
+    float32 sums over the heads keep their order."""
+    (seq, blk), t = WALKS[case], _walk_case(case)
+    got = kernel.index_scores(t["q_i"], t["k_i"], t["w"], interpret=True, blk=blk)
     _close(got, t["scores"])
-    assert float(jnp.max(jnp.where(jnp.arange(S)[:, None] > jnp.arange(S)[None, :], got, kernel.NEG_INF))) <= -9e29  # a key ahead of its query scores NEG_INF
+    assert float(jnp.max(jnp.where(jnp.arange(seq)[:, None] > jnp.arange(seq)[None, :], got, kernel.NEG_INF))) <= -9e29  # a key ahead of its query scores NEG_INF
+    assert int(jnp.sum(got != _parents_index_scores(t["q_i"], t["k_i"], t["w"], blk))) == 0
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_scores_backward_kernel_is_the_xla_forms_vjp(highest, case):
+    (seq, blk), t = WALKS[case], _walk_case(case)
+    for got, want in zip(kernel.index_scores_bwd(t["grad"], t["q_i"], t["k_i"], t["w"], interpret=True, blk=blk), t["vjp"]):
+        _close(got, want)
 
 
 # (positions, keys a query takes, rows a chunk; 0: the kernel's own) at bands of 128 queries. The first has the band in which
@@ -142,6 +211,8 @@ LOSSES = {
     "every_visible_key": (1, 256, 4, 2, 160, 128),
     "a_chosen_pair_underflows": (2, 256, 4, 2, 40, 128),
     "equal_scores_at_the_threshold": (1, 256, 4, 2, 40, 128),
+    # (PR 62) four strips a tile and two tiles a row, 32 heads on 4 KV heads: four trips of eight heads a strip
+    "strips_and_tiles_32_heads_on_4": (1, 1024, 32, 4, 96, 512),
 }
 ONE_ULP_SHARE = 1e-3  # of the nonzero pairs, after both gradients are rounded to bf16: 0 to 1.5e-4 in these cases (4 of 28,239 at most)
 
@@ -234,6 +305,91 @@ def test_the_kernels_are_named_apart_from_the_calls_other_readers_match(operands
     assert not re.search(r"\bsparse_(fwd|bwd)\b|\bindex_(scores|select|scores_bwd)\b", "index_loss")
     others = r"flash_(fwd|bwd|dq|dkv)|kda_scan|gdn_scan|\bt?gmm\b|moe_sum_rows"
     assert not [n for n in names if re.search(others, n)]
+
+
+# ---------------------------------------------------------------------- what a start pays for the loops over heads
+def _kernel_dots(call, *shapes):
+    """The ``dot_general`` equations in the body of the one Pallas call ``call`` traces, loops' bodies entered."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+
+    bodies = [eqn.params["jaxpr"] for eqn in walk(jax.make_jaxpr(call)(*shapes).jaxpr) if eqn.primitive.name == "pallas_call"]
+    assert len(bodies) == 1
+    return sum(eqn.primitive.name == "dot_general" for eqn in walk(bodies[0]))
+
+
+@pytest.mark.parametrize("call,dots", [("index_scores", kernel.HEADS_A_TRIP), ("index_loss", kernel.HEADS_A_TRIP), ("index_scores_bwd", 3)])
+def test_a_kernels_code_does_not_grow_with_its_strips_nor_its_heads(monkeypatch, call, dots):
+    """The strips of a tile are ONE rolled loop and the heads another, of ``HEADS_A_TRIP`` heads a trip written out (the
+    backward's, one head a trip of whole-tile products): the body of a kernel holds as many ``dot_general`` at 2 strips
+    a tile as at 16, and at 16 heads as at 32. Copies of a body are what a start pays for: traced, lowered and compiled
+    every time (sixteen and thirty-two copies of a head cost the Keye cell 5 s of every warm start: PERF.md, PR 61, 62)."""
+    monkeypatch.setattr(kernel, "vmem_budget", lambda: 1 << 40)  # a trace: a tile of sixteen strips holds in no VMEM
+    seq, sds = 2048, jax.ShapeDtypeStruct
+
+    def traced(blk, heads):
+        if call == "index_loss":
+            shapes = (sds((heads, seq, D), jnp.float32), sds((heads // 4, seq, D), jnp.float32), sds((heads, seq), jnp.float32),
+                      sds((1, seq // blk, seq // blk, blk, blk), jnp.int8), sds((1, seq, seq), jnp.float32))
+            return _kernel_dots(lambda *a: kernel.index_loss(*a, SCALE, heads, heads // 4, jnp.float32, interpret=True), *shapes)
+        shapes = (sds((1, heads, seq, DI), jnp.float32), sds((1, seq, DI), jnp.float32), sds((1, heads, seq), jnp.float32))
+        if call == "index_scores":
+            return _kernel_dots(lambda *a: kernel.index_scores(*a, interpret=True, blk=blk), *shapes)
+        return _kernel_dots(lambda *a: kernel.index_scores_bwd(*a, interpret=True, blk=blk), sds((1, seq, seq), jnp.float32), *shapes)
+
+    assert [seq // kernel.strip_for(blk)[1] * blk // seq for blk in (256, 2048)] == [2, 16]  # strips a tile
+    assert {traced(blk, heads) for blk in (256, 2048) for heads in (16, 32)} == {dots}
+
+
+def test_the_six_calls_are_traced_once_a_shape_whatever_the_layers(highest, monkeypatch):
+    """``model.init`` runs its four sparse layers in a plain loop and the step traces a checkpointed block for its value
+    and its backward: the six entry points are jitted, so each one's Python body (and with it its kernel's) is entered
+    ONCE for the process at a shape, where the four that ``init`` reaches were entered four times there and once more in
+    the step. (A jitted call inside a ``custom_vjp`` is traced anew under ``jax.checkpoint``'s gradient: the two forwards
+    that have a backward are entered a second time there, once, whatever the layers.)"""
+    entered = []
+    call = kernel.pl.pallas_call
+    monkeypatch.setattr(kernel.pl, "pallas_call", lambda body, *a, **kw: entered.append(kw.get("name")) or call(body, *a, **kw))
+    monkeypatch.setattr(placement, "kernel_path", lambda fits=True, has_specs=True: "kernel")
+    # widths of this test's own: a shape some other test of the process has traced would be entered no more
+    m = CausalLM(tiny(n_layers=4, layer_kinds=(("sparse", "dense"),) * 4, max_seq_len=384, index_topk=72, remat=True, head_dims=24, d_model=48,
+                      index_heads=5, index_head_dim=24))
+    ids = np.zeros((1, 384), np.int32)
+    ours = lambda: {name: entered.count(name) for name in entered if name and re.match(r"index_|sparse_", name)}
+    params = jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), {"input_ids": ids}))
+    assert ours() == {"index_scores": 1, "index_select": 1, "sparse_fwd": 1, "index_loss": 1}  # four layers' forwards, one entry each
+    jax.make_jaxpr(jax.value_and_grad(lambda p: m.loss_fn(p, {"input_ids": ids})))(params)
+    assert ours() == {"index_scores": 1, "index_select": 1, "sparse_fwd": 2, "index_loss": 2, "sparse_bwd": 1, "index_scores_bwd": 1}
+    jax.make_jaxpr(jax.value_and_grad(lambda p: m.loss_fn(p, {"input_ids": ids})))(params)  # the step again, as a second program of the shape would
+    assert sum(ours().values()) == 8
+
+
+def test_the_kernels_call_sites_say_the_strip_they_walk(operands, monkeypatch):
+    """``program_regions_traced_total{region="mixer/kernel", op="sparse", pass, path="kernel", index_strip="RxL"}`` at the
+    two call sites with a loop over heads; the backward's whole-tile products and XLA's path carry no such label."""
+    t = operands
+    assert kernel.strip_for(kernel.block_for(8192)) == (512, 128) and kernel.strip_for(128) == (128, 128) and kernel.strip_for(64) == (64, 64)
+    series = lambda pass_, **labels: get_registry().peek("program_regions_traced_total", region="mixer/kernel", op="sparse", **{"pass": pass_}, **labels) or 0.0
+    word = "{}x{}".format(*kernel.strip_for(kernel.block_for(S)))
+    assert word == "256x128"
+    before = {p: series(p, path="kernel", index_strip=word) for p in ("index", "loss")}
+    xla = {p: (regions_traced("mixer/kernel", op="sparse", path="xla", **{"pass": p}), series(p, path="xla")) for p in ("index", "loss")}
+    monkeypatch.setattr(placement, "interpret", lambda: True)
+    scores = ops.index_scores(t["q_i"], t["k_i"], t["w"], path="kernel")
+    loss = lambda *a: ops.index_loss(*a, scores, t["q"], t["k"], t["lse"], t["mask"], scale=SCALE, dtype=jnp.float32, path="kernel")
+    jax.grad(loss, argnums=(0, 1, 2))(t["q_i"], t["k_i"], t["w"])
+    assert {p: series(p, path="kernel", index_strip=word) - n for p, n in before.items()} == {"index": 1.0, "loss": 1.0}
+    assert not get_registry().by_label("program_regions_traced_total", "index_strip", region="mixer/kernel", **{"pass": "index_bwd"})
+    ops.index_scores(t["q_i"], t["k_i"], t["w"], path="xla")
+    ops.index_loss(t["q_i"], t["k_i"], t["w"], scores, t["q"], t["k"], t["lse"], t["mask"], scale=SCALE, dtype=jnp.float32, path="xla")
+    for p, (every, unlabelled) in xla.items():  # the series without the label is the whole of XLA's path
+        assert regions_traced("mixer/kernel", op="sparse", path="xla", **{"pass": p}) == every + 1 == series(p, path="xla") and every == unlabelled
 
 
 # ---------------------------------------------------------------------- the mixer
@@ -415,7 +571,8 @@ def test_the_kernels_choice_says_which_share_of_the_rows_it_counts(highest, monk
 
 
 def test_the_first_call_line_says_which_path_the_attention_took(tmp_path):
-    """A sparse model has one kind of layer: its trainer's line still says ``layer_kinds`` and ``sparse_path``."""
+    """A sparse model has one kind of layer: its trainer's line still says ``layer_kinds`` and ``sparse_path``, and
+    ``index_strip`` where its kernels ran (the strip their loops over heads walk a tile in, as the call sites counted it)."""
     import deepspeed_tpu
     from deepspeed_tpu.parallel.mesh import initialize_mesh, reset_mesh
     from deepspeed_tpu.runtime.config import MeshConfig
@@ -437,6 +594,11 @@ def test_the_first_call_line_says_which_path_the_attention_took(tmp_path):
     assert regions_traced("block", site="train") == blocks + 1  # one block trace for both layers
     notes = engine._layer_kind_notes(paths_before)
     assert notes["layer_kinds"] == "sparse+dense:2" and notes["sparse_path"] == "xla" and "sparse_attention" in notes["remat_keeps"]
+    assert "index_strip" not in notes  # XLA's forms walk nothing
+    before = _paths_traced()  # ... and where the call sites took the kernels, the strip they counted since: ``index_strip=512x128``
+    for pass_ in ("index", "loss"):
+        placement.count("sparse", "kernel", pass_, index_strip="512x128")
+    assert engine._layer_kind_notes(before)["index_strip"] == "512x128"
     reset_mesh()
 
 

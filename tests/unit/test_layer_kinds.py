@@ -143,7 +143,9 @@ WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step":
         "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)",
         "moe_activation": "the experts' gate is relu (activation reglu) or they have none (relu2: tests/unit/test_mamba2_layers.py)",
         # (PR 58) XLA differentiates the plain lines: the backward is a call site of its own on the kernels' path alone
-        "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)"}
+        "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)",
+        # (PR 62) the strip the indexer's loops over heads walk a tile in: a label of the kernels' call sites alone
+        "index_strip": "the indexer's calls are the kernels (tests/unit/test_indexed_attention.py)"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])
