@@ -66,7 +66,9 @@ class TransformerFields:
     # encoder family (BERT): bidirectional attention, post-LN blocks,
     # token-type embeddings, MLM transform head (ref module_inject/containers/bert.py)
     causal: bool = True  # False: bidirectional encoder
-    norm_scheme: str = "pre"  # pre (gpt/llama) | post (BERT: norm after residual add)
+    # pre (gpt/llama) | post (BERT: norm after residual add) | sandwich (a norm before AND after each sublayer, the second
+    # on the sublayer's output inside the residual branch: h + N2(Attn(N1(h))), then h + N4(FFN(N3(h))); four weights a layer)
+    norm_scheme: str = "pre"
     type_vocab_size: int = 0  # >0: token_type embeddings added to the input
     mlm_head: bool = False  # BERT cls.predictions transform (dense+act+LN) before the tied decoder
     tie_embeddings: bool = True
@@ -155,6 +157,29 @@ class TransformerFields:
     ssd_state: int = 128
     ssd_groups: int = 1
     ssd_conv: int = 4
+    # a looped (weight-shared) stack: the ``n_layers`` blocks are run ``loop_steps`` times over the SAME parameters, the final
+    # norm at the end of every pass (pass t + 1 starts from the normed state), and ``return_hidden`` gives the ``loop_steps``
+    # normed states stacked. The passes are a ``lax.scan`` whose body is the stack: its equations are in the program once
+    loop_steps: int = 1
+    # with a loop: after every pass the one head gives that pass's logits and a gate lambda_t = sigmoid(x(t) . w_g + b_g) says
+    # how much of what is left exits there (``exit_gate/{kernel,bias}``, one for all passes; the last pass takes what is left).
+    # The loss is the mean over targets of sum_t p_t nll_t - ``exit_entropy_coef`` H(p): the weights p_t carry a gradient
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
+
+    def __post_init__(self):
+        """What the fields alone rule out (what they say of the layers' KINDS is ``transformer.py::_kinds_of``'s)."""
+        if self.norm_scheme not in ("pre", "post", "sandwich"):
+            raise ValueError(f"norm_scheme={self.norm_scheme!r}: pre, post or sandwich")
+        if self.norm_scheme == "sandwich" and self.block_type != "sequential":
+            raise ValueError(f"norm_scheme='sandwich' norms each sublayer's output inside its own residual branch: it needs "
+                             f"block_type='sequential', not {self.block_type!r}")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps={self.loop_steps}: the stack is run at least once")
+        if self.exit_gate and self.loop_steps == 1:
+            raise ValueError("exit_gate=True with loop_steps=1: a gate chooses among the passes of a loop, and one pass leaves no choice")
+        if self.exit_entropy_coef and not self.exit_gate:
+            raise ValueError(f"exit_entropy_coef={self.exit_entropy_coef} without exit_gate: the entropy is the exit distribution's")
 
     @property
     def kv_heads(self) -> int:

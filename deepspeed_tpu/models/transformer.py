@@ -26,7 +26,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..layer_kind import LayerKind
 from ..moe.layer import MOE_PARTITION_RULES, EarlyRoutedMoE, MoE, RoutedMoE
-from ..ops.fused_ce import fused_cross_entropy, fused_cross_entropy_sums
+from ..ops.fused_ce import fused_cross_entropy, fused_cross_entropy_sums, fused_cross_entropy_tokens
+from ..telemetry import device_counts
+from ..telemetry.registry import get_registry
 from ..telemetry.tracing import region
 from ..utils.init_on_device import on_device_init
 from .config import TransformerFields
@@ -222,6 +224,11 @@ class Block(nn.Module):
             a, new_cache = run_attn(x)
             x = _norm(cfg, x + a)
             x = _norm(cfg, x + self._mlp(cfg, x))
+        elif cfg.norm_scheme == "sandwich":  # a norm before AND after each sublayer, the second inside the residual branch
+            h = _norm(cfg, x)
+            a, new_cache = run_attn(h)
+            x = checkpoint_name(x + _norm(cfg, a), SAVED)
+            x = x + _norm(cfg, self._mlp(cfg, _norm(cfg, x), {"mixer_input": h}))
         elif one_part:  # y = x + Part(norm(x)): the one part's norm and add, nothing for the half that is not there
             h = _norm(cfg, x)
             x = x + (self._mlp(cfg, h, {"mixer_input": h}) if attn is None else run_attn(h)[0])
@@ -281,11 +288,19 @@ class Transformer(nn.Module):
             raise NotImplementedError(f"the scan over layers stacks softmax attention over one head size with dense or "
                                       f"capacity-gated MoE blocks; layers of kind {', '.join(cfg.unstackable)} need the unrolled "
                                       f"loop: set scan_layers=False")
+        T = cfg.loop_steps
+        if T > 1:
+            for what, asked in (("scan_layers", cfg.scan_layers), ("kv_caches", kv_caches is not None),
+                                ("pld_theta", pld_theta is not None), ("mlm_head", cfg.mlm_head)):
+                if asked:
+                    _refuse_loop(cfg, what)
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         emb = self.param("wte", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.d_model), jnp.float32)
         hook = _BLOCK_HOOK.get() if kv_caches is None and not self.is_initializing() else None
+        if T > 1 and hook is not None:
+            _refuse_loop(cfg, "block_hook")
         with region("embed"):
             x = hook.look_up(self.path + ("wte",), emb, input_ids, cfg.sows) if hook is not None else None
             x = (emb[input_ids] if x is None else x).astype(cfg.dtype)
@@ -304,7 +319,9 @@ class Transformer(nn.Module):
 
         new_caches = [] if kv_caches is not None else None
         remat = cfg.remat and kv_caches is None
-        if cfg.scan_layers and kv_caches is None:
+        if T > 1:
+            x = self._loop_blocks(x, positions, segment_ids, train, remat)  # the passes' normed states, (T, B, S, d)
+        elif cfg.scan_layers and kv_caches is None:
             x = self._scan_blocks(nn.remat(Block, static_argnums=()) if remat else Block, x, positions,
                                   segment_ids, train)
         else:
@@ -342,7 +359,13 @@ class Transformer(nn.Module):
                     y = jnp.where(keep, y, x)
                 x = y
 
-        if cfg.norm_scheme != "post":  # post-LN blocks already end normalized
+        if cfg.exit_gate and self.is_initializing():  # ``exit_gate/{kernel, bias}``, at zero: the loss alone reads them (``_loop_loss``)
+            nn.Dense(1, name="exit_gate", param_dtype=jnp.float32, kernel_init=nn.initializers.zeros)(x[0, :1, :1].astype(jnp.float32))
+        if T > 1:
+            if return_hidden:
+                return x
+            x = x[-1]  # a caller that asks for logits gets the last pass's
+        elif cfg.norm_scheme != "post":  # post-LN blocks already end normalized
             x = _norm(cfg, x)
         if cfg.mlm_head:
             # BERT cls.predictions.transform: dense + act + LN before the
@@ -373,6 +396,36 @@ class Transformer(nn.Module):
             logits = logits.astype(jnp.float32)
         return (logits, new_caches) if kv_caches is not None else logits
 
+    def _loop_blocks(self, x, positions, segment_ids, train, remat):
+        """``cfg.loop_steps`` passes of the ``n_layers`` blocks over the SAME parameter trees, the final norm at the end of each
+        pass; the passes' normed states, stacked. ONE ``lax.scan`` over passes whose body, under ``region("loop_step")``, is
+        the stack and the norm as functions of their parameters: nothing is kept a pass but what ``block_fn`` keeps a block
+        (with ``remat`` its input). Unrolled, the same passes made a step 1.3% longer, its compile twice as long and its
+        temporaries 3 GB larger (``PERF.md`` section 6, PR 63)."""
+        cfg = self.cfg
+        if cfg.shares or cfg.sows or cfg.norm_scheme == "post":
+            _refuse_loop(cfg, "layers that give, take or sow" if cfg.shares or cfg.sows else "norm_scheme='post'")
+        norm = make_norm(cfg)  # ONE final norm, applied at the end of every pass
+        if self.is_initializing():  # makes the tree: every parameter is reached in one pass
+            for i, kind in enumerate(cfg.kinds):
+                x = Block(cfg, kind, is_training=train, name=f"layer_{i}")(x, positions, None, segment_ids, {})
+            return norm(x)[None]
+        kinds = functools.cache(functools.partial(block_fn, cfg, train=train, remat=remat))
+        layers = [self.get_variable("params", f"layer_{i}") for i in range(cfg.n_layers)]
+
+        # a flax module may not be called inside the scan: the norm there is a function of its parameters, found by its name
+        scale = self.variables["params"].get(norm.name, {})
+
+        def one_pass(x, nothing):
+            with region("loop_step"):  # on every instruction of a pass: what the step spends in the loop, beside the head and the gate
+                for i, kind in enumerate(cfg.kinds):
+                    (x, _), _, _ = kinds(kind)(layers[i], x, positions, None, segment_ids, {})
+                with region("norm"):
+                    x = make_norm(cfg).apply({"params": scale}, x)
+            return x, x
+
+        return jax.lax.scan(one_pass, x, None, length=cfg.loop_steps)[1]
+
     def _scan_blocks(self, block_cls, x, positions, segment_ids, train=True):
         cfg = self.cfg
 
@@ -389,6 +442,39 @@ class Transformer(nn.Module):
                           metadata_params={nn.PARTITION_NAME: "layers"})
         x, _ = scanned(cfg, name="layers")(x, None)
         return x
+
+
+def _refuse_loop(cfg, what):
+    """A looped stack (``loop_steps > 1``) runs through the unrolled loop over layers in training alone; ``what`` cannot."""
+    why = {"scan_layers": "the scan over layers stacks the parameters a layer, and a pass would scan the stack anew: set scan_layers=False "
+                          "(the loop scans the PASSES)",
+           "kv_caches": "a looped model needs loop_steps caches a layer (or the last pass's alone) and an exit decided a token at "
+                        "decode: training-side only",
+           "block_hook": "ZeRO-3's per-layer gather (zero/overlap.py) would gather a layer once a pass: run stage 0-2 or overlap_comm off",
+           "to_pipeline": "a stage would be handed the activations loop_steps times round the pipe, which the schedule does not do"}
+    raise NotImplementedError(f"loop_steps={cfg.loop_steps} does not run with {what}: "
+                              f"{why.get(what, 'the loop over passes carries activations alone, to one head after every pass')}")
+
+
+def exit_distribution(logits):
+    """``(log p, p)``, each (T, ...): the exit distribution over T passes from the T - 1 gates' logits (T - 1, ...), float32.
+    ``lambda_t = sigmoid(logits[t])``, ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` and the last pass takes what is left, so the
+    ``p_t`` sum to one and the last pass has no gate."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-logits), axis=0)  # log prod_{j<=t}(1 - lambda_j)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(logits) + before, stay[-1:]], axis=0)
+    return log_p, jnp.exp(log_p)
+
+
+def _count_loop(values):
+    """On the host, a step's ``[mean p_t] + [mean nll_t] + [H(p), sum_t t p_t, block applications]``."""
+    reg, T = get_registry(), (len(values) - 3) // 2
+    for t in range(T):
+        reg.gauge("train_loop_exit_mass", step=str(t + 1)).set(float(values[t]))
+        reg.gauge("train_loop_step_loss", step=str(t + 1)).set(float(values[T + t]))
+    reg.gauge("train_loop_exit_entropy").set(float(values[-3]))
+    reg.gauge("train_loop_expected_steps").set(float(values[-2]))
+    reg.counter("train_loop_block_applications_total").inc(float(values[-1]))
 
 
 _SOWN = ("losses", "intermediates")  # collections a block may write (``moe/layer.py``)
@@ -546,12 +632,18 @@ class CausalLM:
             aux = 0.0
         own = [loss for loss in reported if loss is not None]
         own = sum(own[1:], own[0]) if own else 0.0  # no ``0 +`` ahead of the one there is today
+        if cfg.loop_steps > 1:
+            if cfg.objective is not None:
+                _refuse_loop(cfg, "an objective of a kind's own")
+            return self._loop_loss(params, hidden, leaves, batch)
         with region("head"):
             w = leaves[0].astype(cfg.dtype)
             weights = None
             if cfg.objective is not None:
                 # a kind with an objective of its own (block diffusion's masked-token loss): the head runs over the
-                # positions it names, against its targets, each weighed; all made here from the ids, elementwise
+                # positions it names, against its targets, each weighed by a CONSTANT (``fused_cross_entropy_sums`` gives
+                # a weight no gradient; weights that carry one are the exit distribution's, ``_loop_loss``); all made here
+                # from the ids, elementwise
                 if "labels" in batch:
                     raise ValueError(f"a {cfg.objective.__name__} model makes its targets from input_ids: give no labels")
                 keep, labels, weights, divisor = cfg.objective.targets(cfg, input_ids)
@@ -585,6 +677,40 @@ class CausalLM:
             # not its value: the step's loss stays the language model's (the value leaves the step as a device count)
             return ce + self.cfg.moe_aux_loss_coef * aux + (own - jax.lax.stop_gradient(own))
 
+    def _loop_loss(self, params, hidden, leaves, batch):
+        """A looped stack's loss from its ``T`` passes' normed states (T, B, S, d): the ONE head on every pass's state, a
+        token's cross-entropy a pass (``fused_cross_entropy_tokens``: the logits never reach HBM, and the cotangent it is
+        handed is a weight a token). Without a gate the loss is the last pass's. With one, in float32: the gate's logit a pass and
+        token, the exit distribution ``p`` (``exit_distribution``) and the mean over targets of ``sum_t p_t nll_t -
+        exit_entropy_coef H(p)``: ``p`` is DIFFERENTIATED, into the gate and through the states into the stack, as ``nll``
+        is. What the step counts of it leaves as a device count (``_count_loop``)."""
+        cfg, input_ids = self.cfg, batch["input_ids"]
+        T = cfg.loop_steps
+        labels = batch["labels"] if "labels" in batch else jnp.concatenate(
+            [input_ids[:, 1:], jnp.full((input_ids.shape[0], 1), -100, input_ids.dtype)], axis=1)
+        counted = jnp.maximum(jnp.sum(labels != -100), 1)
+        w, bias = leaves[0].astype(cfg.dtype), leaves[1] if len(leaves) > 1 else None
+        # ONE call over the passes it reads, stacked along the batch: the head's weight gradient is accumulated once, in one
+        # float32 buffer, where a call a pass kept four alive (1.6 GB at 2,048 x 49,152). Ignored targets read 0
+        read = hidden if cfg.exit_gate else hidden[-1:]
+        with region("head", passes=str(read.shape[0])):
+            nll = fused_cross_entropy_tokens(read.reshape(-1, *read.shape[2:]), w, jnp.tile(labels, (read.shape[0], 1)),
+                                             vd_layout=cfg.tie_embeddings, bias=bias).reshape(read.shape[:3])
+        if not cfg.exit_gate:
+            return jnp.sum(nll) / counted
+        with region("exit_gate"):
+            gate = params["exit_gate"]
+            logits = jnp.einsum("tbsd,d->tbs", hidden[:-1].astype(jnp.float32), gate["kernel"][:, 0].astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST) + gate["bias"].astype(jnp.float32)
+            log_p, p = exit_distribution(logits)
+            entropy = -jnp.sum(p * log_p, axis=0)
+            mean = lambda per_token: jnp.sum(jnp.where(labels != -100, per_token, 0.0), axis=(-2, -1)) / counted
+            steps = jnp.arange(1, T + 1, dtype=jnp.float32)
+            device_counts.report("train_loop", jnp.concatenate([
+                mean(p), jnp.sum(nll, axis=(-2, -1)) / counted,
+                jnp.stack([mean(entropy), mean(jnp.einsum("t,tbs->bs", steps, p)), jnp.float32(cfg.n_layers * T)])]), _count_loop)
+            return mean(jnp.sum(p * nll, axis=0) - cfg.exit_entropy_coef * entropy)
+
     def to_pipeline(self, num_stages: int, params=None, rng=None, example_batch=None):
         """Split the model into (embed, S stacked stages, head) for the
         pipeline engine. Stage params get a leading stage dim sharded over
@@ -600,6 +726,8 @@ class CausalLM:
         the reference's tied-grad allreduce (``pipe/engine.py:264``).
         """
         cfg = self.cfg
+        if cfg.loop_steps > 1:
+            _refuse_loop(cfg, "to_pipeline")
         if cfg.n_layers % num_stages != 0:
             raise ValueError(f"n_layers={cfg.n_layers} must divide evenly into {num_stages} pipeline stages")
         if cfg.scan_layers:
@@ -734,6 +862,8 @@ class CausalLM:
     def init_kv_caches(self, batch_size: int, max_len: int, dtype=None):
         """Preallocated per-layer KV caches for incremental decoding."""
         cfg = self.cfg
+        if cfg.loop_steps > 1:
+            _refuse_loop(cfg, "kv_caches")
         dtype = dtype or cfg.dtype
         zeros = lambda: jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype)
         return [(zeros(), zeros(), jnp.asarray(0, jnp.int32)) for _ in range(cfg.n_layers)]

@@ -24,7 +24,16 @@ boolean "not ``ignore_index``" (weights one and zero, the older callers'
 program, equation for equation) or a float32 weight a target (a masked-token
 diffusion loss weighs a target by its block's noise); the forward multiplies
 the token's loss by it and the hand-written backward the token's
-``dlogits``. Weights take no gradient.
+``dlogits``. Such a weight is a CONSTANT of the objective and takes no
+gradient (``fused_cross_entropy_sums`` stops it).
+
+A weight that is itself differentiated (a looped model's exit distribution:
+``sum_t p_t nll_t`` with a gradient into ``p_t``) is the caller's own, outside
+this op: ``fused_cross_entropy_tokens`` hands back every token's loss, the
+caller multiplies and sums, and the cotangent that comes back a token is the
+weight the backward multiplies that token's ``dlogits`` by. The weight's own
+gradient, ``nll_i``, is then plain autodiff of the caller's product. Same
+chunks, same residuals (the hidden states and a logsumexp a token).
 """
 
 import functools
@@ -110,7 +119,8 @@ def _fused_ce_sum(x, w, b, labels, valid, vd_layout: bool, chunk: int, has_bias:
     return total
 
 
-def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis):
+def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis, per_token=False):
+    """(the weighed sum, a logsumexp a token (nb, B, C)); ``per_token``: and every token's weighed loss (nb, B, C)."""
     B, S, D = x.shape
     nb = S // chunk
     xs = x.reshape(B, nb, chunk, D).transpose(1, 0, 2, 3)  # (nb, B, C, D)
@@ -132,7 +142,7 @@ def _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis)
             gold = jnp.take_along_axis(logits, jnp.where(mine, own, 0)[..., None], axis=-1)[..., 0]
             gold = jax.lax.psum(jnp.where(mine, gold, 0.0), vocab_axis)
         nll = _weighed(vc, lse - gold)
-        return acc + jnp.sum(nll), lse
+        return acc + jnp.sum(nll), ((lse, nll) if per_token else lse)
 
     total, lses = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ls, vs))
     return total, lses  # lses: (nb, B, C)
@@ -144,6 +154,7 @@ def _ce_vjp_fwd(x, w, b, labels, valid, vd_layout, chunk, has_bias, vocab_axis):
 
 
 def _ce_vjp_bwd(vd_layout, chunk, has_bias, vocab_axis, res, g):
+    """``g``: the sum's cotangent, a scalar, or (``fused_cross_entropy_tokens``) a cotangent a token, (B, S)."""
     x, w, b, labels, valid, lses = res
     if vocab_axis is not None:
         # every device holds the whole sum, so each is handed a share of its cotangent; ``dx`` comes out as this
@@ -157,16 +168,17 @@ def _ce_vjp_bwd(vd_layout, chunk, has_bias, vocab_axis, res, g):
     xs = x.reshape(B, nb, chunk, D).transpose(1, 0, 2, 3)
     ls = labels.reshape(B, nb, chunk).transpose(1, 0, 2)
     vs = valid.reshape(B, nb, chunk).transpose(1, 0, 2)
+    gs = (g.reshape(B, nb, chunk).transpose(1, 0, 2),) if jnp.ndim(g) else ()  # a cotangent a token rides the scan with its chunk
 
     def body(carry, inp):
         dw_acc, db_acc = carry
-        xc, lc, vc, lse = inp
+        xc, lc, vc, lse, *gc = inp
         logits = _project(xc, w, vd_layout)
         if has_bias:
             logits = logits + b
         p = jnp.exp(logits - lse[..., None])  # softmax, (B,C,V) fp32
         onehot = jax.nn.one_hot(lc, V, dtype=jnp.float32)
-        dlogits = (p - onehot) * _weighed(vc, g)[..., None]  # (B,C,V) fp32
+        dlogits = (p - onehot) * _weighed(vc, gc[0] if gc else g)[..., None]  # (B,C,V) fp32
         dlogits_c = dlogits.astype(xc.dtype)
         if vd_layout:
             # w: (V,D); dxc = dlogits @ w ; dw += dlogits^T @ xc
@@ -184,12 +196,52 @@ def _ce_vjp_bwd(vd_layout, chunk, has_bias, vocab_axis, res, g):
 
     dw0 = jnp.zeros(w.shape, jnp.float32)
     db0 = jnp.zeros((V,), jnp.float32)
-    (dw, db), dxs = jax.lax.scan(body, (dw0, db0), (xs, ls, vs, lses))
+    (dw, db), dxs = jax.lax.scan(body, (dw0, db0), (xs, ls, vs, lses) + gs)
     dx = dxs.transpose(1, 0, 2, 3).reshape(B, S, D).astype(x.dtype)
     return dx, dw.astype(w.dtype), db.astype(b.dtype), None, None
 
 
 _fused_ce_sum.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _fused_ce_tokens(x, w, b, labels, valid, vd_layout: bool, chunk: int, has_bias: bool):
+    return _ce_tokens_fwd(x, w, b, labels, valid, vd_layout, chunk, has_bias)[0]
+
+
+def _ce_tokens_fwd(x, w, b, labels, valid, vd_layout, chunk, has_bias):
+    _, (lses, nll) = _ce_fwd_scan(x, w, b, labels, valid, vd_layout, chunk, has_bias, None, per_token=True)
+    return nll.transpose(1, 0, 2).reshape(labels.shape), (x, w, b, labels, valid, lses)
+
+
+_fused_ce_tokens.defvjp(_ce_tokens_fwd, lambda vd_layout, chunk, has_bias, res, g: _ce_vjp_bwd(vd_layout, chunk, has_bias, None, res, g))
+
+
+def fused_cross_entropy_tokens(x: jnp.ndarray,
+                               w: jnp.ndarray,
+                               labels: jnp.ndarray,
+                               ignore_index: int = -100,
+                               vd_layout: bool = False,
+                               chunk: Optional[int] = None,
+                               bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """(B, S) float32: every token's cross-entropy of ``x @ w (+ bias)`` against ``labels`` (0 where the label is
+    ``ignore_index``), without the logits in HBM: for a caller whose objective weighs a token by something it
+    differentiates. Its sum is ``fused_cross_entropy_sums``'s; the cotangent a token is that token's weight in the
+    backward, which is the sums' own."""
+    chunk, valid, safe_labels, has_bias, b = _operands(x, w, labels, ignore_index, vd_layout, chunk, bias)
+    return _fused_ce_tokens(x, w, b, safe_labels, valid, bool(vd_layout), chunk, has_bias)
+
+
+def _operands(x, w, labels, ignore_index, vd_layout, chunk, bias):
+    """(chunk, which targets count, the labels with the ignored ones at 0, whether there is a bias, the bias or zeros)."""
+    B, S, D = x.shape
+    V = w.shape[0] if vd_layout else w.shape[1]
+    chunk = chunk or _pick_chunk(S, B=B, V=V)
+    valid = labels != ignore_index
+    safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
+    has_bias = bias is not None
+    b = bias.astype(jnp.float32) if has_bias else jnp.zeros((V,), jnp.float32)
+    return int(chunk), valid, safe_labels, has_bias, b
 
 
 def fused_cross_entropy_sums(x: jnp.ndarray,
@@ -215,16 +267,10 @@ def fused_cross_entropy_sums(x: jnp.ndarray,
     axis, every device returns the whole sums, ``dw`` is this slice's, and
     ``dx`` is this slice's part of a sum that the caller takes over the
     axis."""
-    B, S, D = x.shape
-    V = w.shape[0] if vd_layout else w.shape[1]
-    chunk = chunk or _pick_chunk(S, B=B, V=V)
-    valid = labels != ignore_index
-    safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
-    has_bias = bias is not None
-    b = bias.astype(jnp.float32) if has_bias else jnp.zeros((V,), jnp.float32)
+    chunk, valid, safe_labels, has_bias, b = _operands(x, w, labels, ignore_index, vd_layout, chunk, bias)
     if weights is not None:
         valid = jax.lax.stop_gradient(jnp.where(valid, weights.astype(jnp.float32), 0.0))
-    total = _fused_ce_sum(x, w, b, safe_labels, valid, bool(vd_layout), int(chunk), has_bias, vocab_axis)
+    total = _fused_ce_sum(x, w, b, safe_labels, valid, bool(vd_layout), chunk, has_bias, vocab_axis)
     return total, jnp.sum(valid) if weights is None else jnp.sum(valid != 0)
 
 

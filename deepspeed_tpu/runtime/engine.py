@@ -870,6 +870,8 @@ class DeepSpeedEngine:
         if cfg.remat:  # under ``scan_layers`` the blocks go through ``nn.remat(Block)``, which has no policy
             names = () if cfg.scan_layers else sorted({name for kind in kinds for name in layer_kinds.remat_keeps(kind)})
             notes["remat_keeps"] = "+".join(names) or "inputs"
+        if cfg.loop_steps > 1:  # a looped stack: how many passes over the same layers
+            notes["loop_steps"] = cfg.loop_steps
         records = layer_kinds.records(kinds)
         # one kind of plain block: no form of a layer was chosen, only what its kernels chose for themselves (``joined``)
         plain = len(set(kinds)) == 1 and not any(record.alone for record in records)

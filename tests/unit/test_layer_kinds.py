@@ -262,7 +262,8 @@ def test_the_configurations_fields_are_the_parents():
                                                           ("layer_numbers", None),  # PR 46: appended, nothing moved
                                                           ("block_length", 0), ("mask_token_id", 0), ("blockdiff_qk_init_scale", 1.0),  # PR 49: likewise
                                                           ("conv_kernel", 3), ("moe_renorm_eps", 1e-20),  # PR 55: likewise
-                                                          ("ssd_heads", 0), ("ssd_head_dim", 64), ("ssd_state", 128), ("ssd_groups", 1), ("ssd_conv", 4)]  # PR 59: likewise
+                                                          ("ssd_heads", 0), ("ssd_head_dim", 64), ("ssd_state", 128), ("ssd_groups", 1), ("ssd_conv", 4),  # PR 59: likewise
+                                                          ("loop_steps", 1), ("exit_gate", False), ("exit_entropy_coef", 0.0)]  # PR 63: likewise
     assert [f.name for f in fields] == [f.name for f in dataclasses.fields(TransformerFields)]
     cfg = TransformerConfig(n_layers=3)
     assert TransformerConfig(**cfg.__dict__) == cfg == dataclasses.replace(cfg) and hash(cfg) == hash(dataclasses.replace(cfg))

@@ -29,6 +29,7 @@ CELLS = {"olmo-1b": dict(remat=False, zero=True), "kimi-linear-48b-l5e8": dict(r
 REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/index", "mixer/select", "mixer/index_loss", "mixer/conv", "mixer/diff", "mixer/memory", "ffn/dense", "ffn/shared", "ffn/router", "ffn/rows",
            "ffn/cond", "branch/usual", "branch/every_pair", "ffn/experts", "head", "optimizer", "zero/gather", "zero/reduce",
            "zero/regather", "block"}
+REGIONS |= {"exit_gate", "loop_step"}  # PR 63: a looped stack's passes (the one body of the scan over them) and its gate
 HEAVY = ("dot", "convolution", "custom-call")
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute", "all-to-all")
 
