@@ -12,7 +12,9 @@ class LayerKind:
     # device_counts.py`` and returns the kind's own loss (the step's loss takes its gradient and not its value) or None
     report = None
     keeps = ()  # the names its own ``checkpoint_name``s give, its kernels' among them
-    hybrid = False  # a checkpointed block with it keeps, by name, what its two parts' ``keeps`` say; else its inputs alone
+    # a checkpointed block with it keeps, by name, what its two parts' ``keeps`` say; else what they say without the
+    # projections' name: its kernels' outputs, beside its inputs (``models/transformer.py::remat_keeps``)
+    hybrid = False
     # the trainer's first-call line. ``paths``: key -> (region, labels) of ``program_regions_traced_total``; the key's
     # word is ``xla`` where only ``path="xla"`` call sites rose, ``mixed``, else ``kernel`` (or ``path_words[key]``).
     # ``joined``: key -> (region, the ``path`` labels of it that may rise[, another label than ``path`` whose values they

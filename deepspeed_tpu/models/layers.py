@@ -21,7 +21,8 @@ from .config import GATED, TransformerFields
 # q/k/v/gate projections, the dense FFN's gate and up), what one onto it gives where a backward reads it (the mixer's
 # output added to the block's input), or a value after such a product from which the backward's needs follow elementwise.
 # A checkpointed hybrid block keeps it (``remat_keeps``), so its backward makes no such product a second time; outside such
-# a policy (serving, ``remat: false``, a ``full``/``dense`` block) ``checkpoint_name`` is an identity
+# a policy (serving, ``remat: false``, a ``full``/``dense`` block, whose policy lists its kernel's name alone)
+# ``checkpoint_name`` is an identity
 SAVED = "projection"
 
 

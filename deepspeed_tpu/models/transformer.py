@@ -70,19 +70,33 @@ def kinds_sow(kinds) -> bool:  # whether a block of one of these (mixer, ffn) pa
 
 
 def remat_keeps(kind: Tuple[str, str]) -> Tuple[str, ...]:
-    """The names a checkpointed block of this kind keeps (``block_fn``'s policy); none: plain ``jax.checkpoint``, which
-    keeps the block's inputs alone.
+    """The names a checkpointed block of this kind keeps beside its inputs (``remat_policy``); none: plain
+    ``jax.checkpoint``, which keeps the block's inputs alone.
 
-    By the records: a block is hybrid where either of its parts says so (``LayerKind.hybrid``: every mixer but softmax
-    attention over one head size, and the routed FFN), and keeps what its two parts declare (``keeps``) and the one name
-    the block itself gives (``SAVED``: the mixer's output added to its input); a name that no value of a block carries
-    does nothing in ``save_only_these_names``. So a hybrid block's backward runs no kernel, no product over or onto the
-    model width, no top-k and no sort a second time: kept are the kernels' outputs (the scan's with its states and
-    inverses, the flash call's with its row statistics), every projection's result and the routed layer's scores,
-    choice, sorted rows and grouped products; what lies between them is elementwise (and the low-rank gates' second
-    products, over 128) and is made again.
+    The rule, read off the records: EVERY checkpointed block keeps its kernels' outputs; a hybrid block keeps its
+    projections' results too. A block is hybrid where either of its parts says so (``LayerKind.hybrid``: every mixer but
+    softmax attention over one head size, and the routed FFN). It keeps what its two parts declare (``keeps``) and the one
+    name the block itself gives (``SAVED``: the mixer's output added to its input); any other block keeps what its parts
+    declare WITHOUT ``SAVED``, so a part whose ``keeps`` is ``SAVED`` alone adds nothing. A name that no value of a block
+    carries does nothing in ``save_only_these_names``: where the flash kernel is not the path taken (the CPU,
+    ``attention_xla``) a plain block keeps its inputs alone.
 
-    What it costs (``PERF.md`` section 6, PR 40): the projections are kept for every layer at once, not inside one
+    A plain block (softmax attention over one head size beside a dense or capacity-gated FFN, the decoder most users of
+    ``remat: true`` train) so keeps the flash call's ``o`` and ``lse``, and its backward runs no second forward kernel.
+    What that costs a layer, held for every layer at once: ONE more value of the block's input size (``o``: tokens x
+    heads x head size, in the model's dtype) and 4 bytes a head and row (``lse``, float32). Its six products over the
+    model width are made again. Why they are not kept there (``PERF.md`` section 6, PR 64; 32 block applications of
+    8,192 x 2,048 in bf16, ms of second forward saved a GB kept): ``o`` and ``lse`` 1.09 GB for 74 ms, 68 ms a GB;
+    q, k, v 3.2 GB for about 40 ms, 12 ms a GB; the FFN's gate and up 5.9 GB for about 75 ms, 13 ms a GB. The kernel's
+    outputs are five times cheaper a millisecond than any product's result; all the products' results together are
+    11 GB, which a 16 GB chip that holds the state of such a model does not have.
+
+    A hybrid block's backward runs no kernel, no product over or onto the model width, no top-k and no sort a second
+    time: kept are the kernels' outputs (the scan's with its states and inverses, the flash call's with its row
+    statistics), every projection's result and the routed layer's scores, choice, sorted rows and grouped products; what
+    lies between them is elementwise (and the low-rank gates' second products, over 128) and is made again.
+
+    What that costs (``PERF.md`` section 6, PR 40): the projections are kept for every layer at once, not inside one
     layer's peak, so they grow with depth and tokens: 0.16 (``gdn``), 0.29 (``mla``) and 0.30 GB (``kda``, routed FFNs
     with a shared expert) of the step's temporaries a layer at 8,192 tokens, for 7-10% more tokens a second at 4 to 6
     layers. Qwen3-Next's four layers at 16,384 tokens, which the kernels' outputs alone let compile for a 16 GB chip,
@@ -96,7 +110,15 @@ def remat_keeps(kind: Tuple[str, str]) -> Tuple[str, ...]:
     that is made again on in float32 (``xla_allow_excess_precision``); the gradients are those of the block without a
     checkpoint."""
     mixer, ffn = MIXERS[kind[0]], FFNS[kind[1]]
-    return tuple(dict.fromkeys(mixer.keeps + ffn.keeps + (SAVED,))) if mixer.hybrid or ffn.hybrid else ()
+    names = tuple(dict.fromkeys(mixer.keeps + ffn.keeps + (SAVED,)))
+    return names if mixer.hybrid or ffn.hybrid else tuple(name for name in names if name != SAVED)
+
+
+def remat_policy(kind: Tuple[str, str]):
+    """``jax.checkpoint``'s (and ``flax.linen.remat``'s) ``policy`` for a block of this kind: ``remat_keeps``'s names,
+    or None (the block's inputs alone) where it has none."""
+    keeps = remat_keeps(kind)
+    return jax.checkpoint_policies.save_only_these_names(*keeps) if keeps else None
 
 
 @dataclass(frozen=True)
@@ -322,8 +344,9 @@ class Transformer(nn.Module):
         if T > 1:
             x = self._loop_blocks(x, positions, segment_ids, train, remat)  # the passes' normed states, (T, B, S, d)
         elif cfg.scan_layers and kv_caches is None:
-            x = self._scan_blocks(nn.remat(Block, static_argnums=()) if remat else Block, x, positions,
-                                  segment_ids, train)
+            # the stacked layers are of one kind (``unstackable``): its policy, as ``block_fn`` gives the unrolled ones
+            x = self._scan_blocks(nn.remat(Block, static_argnums=(), policy=remat_policy(cfg.kinds[0])) if remat else Block, x,
+                                  positions, segment_ids, train)
         else:
             # one traced function a KIND of block and program, applied once a layer to that layer's
             # parameters: the block's Python body runs once, not n_layers times
@@ -400,8 +423,8 @@ class Transformer(nn.Module):
         """``cfg.loop_steps`` passes of the ``n_layers`` blocks over the SAME parameter trees, the final norm at the end of each
         pass; the passes' normed states, stacked. ONE ``lax.scan`` over passes whose body, under ``region("loop_step")``, is
         the stack and the norm as functions of their parameters: nothing is kept a pass but what ``block_fn`` keeps a block
-        (with ``remat`` its input). Unrolled, the same passes made a step 1.3% longer, its compile twice as long and its
-        temporaries 3 GB larger (``PERF.md`` section 6, PR 63)."""
+        (with ``remat`` its input and its kernel's outputs, ``remat_keeps``: the scan stacks them a pass). Unrolled, the same
+        passes made a step 1.3% longer, its compile twice as long and its temporaries 3 GB larger (``PERF.md`` section 6, PR 63)."""
         cfg = self.cfg
         if cfg.shares or cfg.sows or cfg.norm_scheme == "post":
             _refuse_loop(cfg, "layers that give, take or sow" if cfg.shares or cfg.sows else "norm_scheme='post'")
@@ -553,10 +576,9 @@ def block_fn(cfg: TransformerConfig, kind: Tuple[str, str], train: bool, remat: 
 
     fn = wrap(apply) if wrap is not None else apply
     if remat:
-        # a hybrid block keeps, by name, its kernels' outputs and every projection's result, so its backward makes again
-        # only the elementwise work between them (``remat_keeps``); any other kind keeps its inputs alone
-        keeps = remat_keeps(kind)
-        fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(*keeps)) if keeps else jax.checkpoint(fn)
+        # every block keeps, by name, its kernels' outputs, so its backward runs no kernel's forward again; a hybrid block
+        # every projection's result too, and makes again only the elementwise work between them (``remat_keeps``)
+        fn = jax.checkpoint(fn, policy=remat_policy(kind))
     return jax.jit(fn, inline=True)
 
 

@@ -93,9 +93,9 @@ from ..registry import REGISTRY
 from ._utils import block_that_divides, compiler_params as _compiler_params, vmem_budget
 
 NEG_INF = -1e30
-# The name the forward's output and row statistics carry for a checkpoint policy. A checkpointed hybrid block keeps them
+# The name the forward's output and row statistics carry for a checkpoint policy. EVERY checkpointed block keeps them
 # (``models/transformer.py::remat_keeps``), so its backward runs no second forward kernel; q, k and v carry no name here:
-# the caller keeps them, or the projections they follow from elementwise
+# a hybrid block keeps the projections they follow from elementwise, any other makes them again from its input
 SAVED = "flash_attention"
 LANES = 128  # min lane width for fp32 stores (canonical TPU l/m layout)
 # for a kind's record (``LayerKind.joined``): the series that say how many tiles a trip of the kernels' walks takes, a pass

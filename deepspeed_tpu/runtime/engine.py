@@ -861,14 +861,14 @@ class DeepSpeedEngine:
         ``xla`` (the fallback), ``mixed``, a word of the record's own, or no key where this program traced no such call
         site; a joined key's word is the labels that rose, ``+`` between. A model of ONE plain kind says the joined keys
         alone (what its kernels chose for themselves: the flash kernels' tiles a trip). Whatever the kinds, under
-        ``remat``: what a checkpointed block keeps (``remat_keeps``: the names of ``block_fn``'s policy, or its inputs alone)."""
+        ``remat``: what a checkpointed block keeps beside its inputs (``remat_keeps``: its policy's names, or ``inputs``)."""
         cfg = getattr(self.module, "cfg", None)
         kinds = getattr(cfg, "kinds", None)
         if not kinds:
             return {}
         notes = {}
-        if cfg.remat:  # under ``scan_layers`` the blocks go through ``nn.remat(Block)``, which has no policy
-            names = () if cfg.scan_layers else sorted({name for kind in kinds for name in layer_kinds.remat_keeps(kind)})
+        if cfg.remat:  # unrolled, looped or stacked (``nn.remat(Block, policy=...)``): one rule
+            names = sorted({name for kind in kinds for name in layer_kinds.remat_keeps(kind)})
             notes["remat_keeps"] = "+".join(names) or "inputs"
         if cfg.loop_steps > 1:  # a looped stack: how many passes over the same layers
             notes["loop_steps"] = cfg.loop_steps

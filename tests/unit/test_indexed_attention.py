@@ -525,7 +525,7 @@ def test_a_checkpointed_sparse_block_keeps_its_names():
 
     assert remat_keeps(("sparse", "dense")) == ("sparse_attention", "flash_attention", "projection")
     assert remat_keeps(("gdn", "routed")) == ("kda_scan", "projection", "routed_ffn") and remat_keeps(("full", "routed")) == ("flash_attention", "projection", "routed_ffn")
-    assert remat_keeps(("full", "dense")) == ()
+    assert remat_keeps(("full", "dense")) == ("flash_attention",)  # a plain block: its kernel's outputs, no projection
 
 
 def test_what_the_mixer_counts(model):

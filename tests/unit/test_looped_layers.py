@@ -397,4 +397,4 @@ def test_the_loop_trains_through_initialize_and_says_and_counts_what_it_ran():
     assert 0.9 < reg.peek("train_loop_exit_entropy") < np.log(T) + 1e-3
     assert all(np.log(VOCAB) - 3 < reg.peek("train_loop_step_loss", step=str(t)) < np.log(VOCAB) + 1 for t in range(1, T + 1))
     said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
-    assert (said["loop_steps"], said["block_traces"], said["remat_keeps"]) == (T, 1, "inputs")
+    assert (said["loop_steps"], said["block_traces"], said["remat_keeps"]) == (T, 1, "flash_attention")

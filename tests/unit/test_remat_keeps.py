@@ -1,14 +1,20 @@
-"""What a checkpointed block keeps (``models/transformer.py::remat_keeps``, ``block_fn``'s policy): a hybrid block's
+"""What a checkpointed block keeps (``models/transformer.py::remat_keeps``, ``remat_policy``): a hybrid block's
 backward makes no product over or onto the model width, no top-k, no sort and no sum of the router's chosen scores a
-second time, and gives the gradients of the same block without a checkpoint; a ``full``/``dense`` block under plain ``jax.checkpoint`` keeps its inputs alone. Tiny
-widths, float32, CPU; ``d_model`` (48) is the width of nothing else in these configurations."""
+second time, and gives the gradients of the same block without a checkpoint; a plain block (``full``, ``window`` or
+``nope`` beside ``dense`` or ``moe``) keeps its inputs and the flash call's output and row statistics, runs ONE forward
+kernel call and makes its products again, unrolled, looped or stacked. Tiny widths, float32, CPU; ``d_model`` (48) is
+the width of nothing else in these configurations."""
+
+import collections
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models import CausalLM, TransformerConfig, layers
 from deepspeed_tpu.models.transformer import SAVED, Block, block_fn, remat_keeps
+from deepspeed_tpu.ops.pallas.flash_attention import SAVED as FLASH_SAVED, flash_attention
 from tests.unit.test_deltanet_layers import tiny as tiny_next
 from tests.unit.test_hybrid_layers import tiny, tiny_vl
 
@@ -104,20 +110,92 @@ def test_a_hybrid_blocks_backward_makes_no_product_over_the_model_width_again(ca
     assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree_util.tree_leaves(got[1]) if g.shape != (cfg.moe_num_experts,))  # select_bias takes none
 
 
-def test_a_full_dense_block_under_plain_checkpoint_keeps_what_it_kept():
-    """The names act only under ``save_only_these_names``: a ``full``/``dense`` block has no policy, keeps its inputs
-    and nothing made inside it, and makes its projections again."""
+PLAIN = [("full", "dense"), ("window", "dense"), ("nope", "dense"), ("full", "moe")]  # neither part is hybrid
+
+
+def _on_the_flash_kernels(monkeypatch):
+    """Softmax attention's layers call the flash kernels, interpreted: the path the chip takes."""
+    monkeypatch.setattr(layers, "attention", lambda q, k, v, **kw: flash_attention(q, k, v, interpret=True, **kw))
+
+
+def _kernel_calls(jaxpr):
+    """How often a jaxpr holds each Pallas call, by the call's name, sub-jaxprs too."""
+    return collections.Counter(eqn.params["name"] for eqn, _ in _equations(jaxpr) if eqn.primitive.name == "pallas_call")
+
+
+@pytest.mark.parametrize("kind", PLAIN, ids="+".join)
+def test_a_plain_block_keeps_its_kernels_outputs_and_nothing_else_it_made(kind, monkeypatch):
+    """Every checkpointed block keeps its kernels' outputs; the projections' name is a hybrid block's. On XLA's attention
+    no value carries the flash call's name and the block keeps its inputs alone. On the flash kernels it keeps ``o`` and
+    ``lse`` and nothing else made inside it, its gradient holds ONE forward call (under plain ``jax.checkpoint``, the rule
+    before PR 64: two), the products over the model width are made again as they were, and values and gradients are the
+    unchecked block's to the last bit."""
     from jax._src.ad_checkpoint import saved_residuals  # what print_saved_residuals prints, as a list
 
-    kind, cfg = ("full", "dense"), tiny_next(n_layers=1, layer_kinds=(("full", "dense"),), moe_num_experts=0, d_ff=64)
-    assert remat_keeps(kind) == () and remat_keeps(("window", "moe")) == ()
+    cfg = tiny_next(n_layers=1, layer_kinds=(kind,), moe_num_experts=4 if kind[1] == "moe" else 0, d_ff=64, sliding_window=16)
+    assert remat_keeps(kind) == (FLASH_SAVED,)
+    made_inside = lambda loss: [(aval.shape, why.split(" from ")[0]) for aval, why in saved_residuals(loss, params, x)
+                                if not why.startswith(("from the argument", "from a constant"))]
     loss, params, x = _block(kind, cfg, remat=True)
-    kept = saved_residuals(loss, params, x)
-    assert kept and all(why.startswith(("from the argument", "from a constant")) for _, why in kept), kept
-    wide, _, _ = _made_again(loss, params, x, cfg)
-    assert len(wide) == 6  # q, k, v, o (the FFN half starts from its sum with the input), gate, up
+    assert saved_residuals(loss, params, x) and not made_inside(loss)
+    _on_the_flash_kernels(monkeypatch)
+    loss, params, x = _block(kind, cfg, remat=True)
+    # (a kept value is listed by the last thing done to it: ``o`` by the rounding ``jax.checkpoint`` gives a residual)
+    assert sorted(made_inside(loss)) == [((B, S, cfg.n_heads, cfg.head_dim), "output of reduce_precision"), ((B * cfg.n_heads, S), f"named '{FLASH_SAVED}'")]
     plain, _, _ = _block(kind, cfg, remat=False)
+    grad = lambda f: jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(params, x).jaxpr
+    assert _kernel_calls(grad(loss)) == _kernel_calls(grad(plain)) == {"flash_fwd": 1, "flash_bwd": 1}
+    assert _kernel_calls(grad(jax.checkpoint(plain))) == {"flash_fwd": 2, "flash_bwd": 1}
+    wide, _, _ = _made_again(loss, params, x, cfg)
+    assert len(wide) == len(_made_again(jax.checkpoint(plain), params, x, cfg)[0]) == (6 if kind[1] == "dense" else 9)  # q, k, v, o, gate, up
     assert not _made_again(plain, params, x, cfg)[2]
+    got, want = (jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(params, x) for f in (loss, plain))
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(a)).all() and np.array_equal(np.asarray(a), np.asarray(b))
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree_util.tree_leaves(got[1]))
+
+
+def _stack(**over):
+    """(the model, its parameters off their start, what makes a model's loss of its parameters) of a two-layer plain
+    decoder under ``remat``."""
+    cfg = TransformerConfig(**dict(dict(vocab_size=97, n_layers=2, n_heads=4, n_kv_heads=2, head_dims=16, d_model=48, d_ff=64, max_seq_len=S,
+                                        norm="rmsnorm", activation="swiglu", pos_emb="rope", tie_embeddings=False, remat=True), **over))
+    ids = np.random.default_rng(0).integers(0, 97, (B, S)).astype(np.int32)
+    params = CausalLM(cfg).init(jax.random.PRNGKey(0), {"input_ids": ids})
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    params = jax.tree_util.tree_unflatten(tree, [p + 0.05 * jax.random.normal(jax.random.PRNGKey(7 + i), p.shape) for i, p in enumerate(leaves)])
+    return CausalLM(cfg), params, lambda model: (lambda p: model.loss_fn(p, {"input_ids": ids}))
+
+
+def test_a_looped_stack_runs_one_forward_call_an_application(monkeypatch):
+    """``loop_steps=4`` over two layers: the scan over passes holds the two blocks' calls once, forward and backward, and
+    stacks what they keep; loss and gradients are the unchecked stack's to the last bit."""
+    _on_the_flash_kernels(monkeypatch)
+    model, params, loss = _stack(loop_steps=4, norm_scheme="sandwich", exit_gate=True, exit_entropy_coef=0.05)
+    assert _kernel_calls(jax.make_jaxpr(jax.grad(loss(model)))(params).jaxpr) == {"flash_fwd": 2, "flash_bwd": 2}
+    unchecked = CausalLM(TransformerConfig(**dict(model.cfg.__dict__, remat=False)))
+    got, want = (jax.jit(jax.value_and_grad(loss(m)))(params) for m in (model, unchecked))
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(a)).all() and np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_stacked_form_has_the_unrolled_forms_policy(monkeypatch):
+    """``scan_layers`` wraps ``Block`` in ``nn.remat`` with the policy of the stack's one kind: the scanned body holds one
+    forward call (a layer), where the unrolled form holds one a layer, and the gradients are the unrolled form's."""
+    _on_the_flash_kernels(monkeypatch)
+    unrolled, params, loss = _stack()
+    stacked = CausalLM(TransformerConfig(**dict(unrolled.cfg.__dict__, scan_layers=True)))
+    # a tree of the unrolled form as the stacked form holds it: the two layers' leaves stacked under ``layers/block``
+    together = lambda tree: {**{name: leaf for name, leaf in tree.items() if not name.startswith("layer_")},
+                             "layers": {"block": jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), tree["layer_0"], tree["layer_1"])}}
+    shapes = jax.eval_shape(lambda: stacked.init(jax.random.PRNGKey(0), {"input_ids": np.zeros((B, S), np.int32)}))
+    assert jax.tree_util.tree_structure(together(params)) == jax.tree_util.tree_structure(shapes)
+    assert _kernel_calls(jax.make_jaxpr(jax.grad(loss(unrolled)))(params).jaxpr) == {"flash_fwd": 2, "flash_bwd": 2}
+    assert _kernel_calls(jax.make_jaxpr(jax.grad(loss(stacked)))(together(params)).jaxpr) == {"flash_fwd": 1, "flash_bwd": 1}  # the scan's body, a layer
+    (got_loss, got), (want_loss, want) = jax.jit(jax.value_and_grad(loss(stacked)))(together(params)), jax.jit(jax.value_and_grad(loss(unrolled)))(params)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(together(want))):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6 * float(jnp.max(jnp.abs(b))), err_msg=jax.tree_util.keystr(path))
 
 
 # (scoring, experts, chosen a token, whether ``select_bias`` is non-zero): the two small cases this test began with, then
@@ -153,15 +231,15 @@ def test_a_routers_choice_has_lax_top_ks_values_and_gradient(scoring, E, k, bias
         assert np.array_equal(np.asarray(got), np.asarray(jax.grad(lambda x: jnp.sum(plain(x) * w))(logits)))
 
 
-def test_the_first_call_line_says_inputs_where_the_layers_are_scanned():
-    """``remat_keeps`` on the trainer's first-call line is the policy the program's blocks had: scanned layers go through
-    ``nn.remat(Block)``, which has no policy and keeps a block's inputs alone, whatever the kind."""
+def test_the_first_call_line_says_one_thing_for_one_rule():
+    """``remat_keeps`` on the trainer's first-call line is the policy the program's blocks had: unrolled through
+    ``block_fn`` or scanned through ``nn.remat(Block)``, the same names; ``inputs`` where a block's parts declare none."""
     import types
 
     from deepspeed_tpu.runtime.engine import DeepSpeedEngine, _paths_traced
 
-    notes = lambda **over: DeepSpeedEngine._layer_kind_notes(
-        types.SimpleNamespace(module=types.SimpleNamespace(cfg=tiny(n_layers=2, layer_kinds=(("mla", "dense"),) * 2, **over))), _paths_traced())
+    notes = lambda kind=("mla", "dense"), **over: DeepSpeedEngine._layer_kind_notes(
+        types.SimpleNamespace(module=types.SimpleNamespace(cfg=tiny(n_layers=2, layer_kinds=(kind,) * 2, **over))), _paths_traced())
     assert notes(remat=True) == {"remat_keeps": "flash_attention+projection"}
-    assert notes(remat=True, scan_layers=True) == {"remat_keeps": "inputs"}
+    assert notes(("full", "dense"), remat=True) == notes(("full", "dense"), remat=True, scan_layers=True) == {"remat_keeps": "flash_attention"}
     assert notes() == {}
