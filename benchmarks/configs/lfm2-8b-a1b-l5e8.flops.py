@@ -56,3 +56,18 @@ def expert_matmul_cost(m: dict, rows: float, backward: bool) -> dict:
     weights = m["num_experts"] * 3 * d * f
     acts = rows * (2 * d + 3 * f)
     return {"flops": flops, "bytes": 2.0 * (weights + acts) * (2 if backward else 1)}
+
+
+def mixed_attention_cost(m: dict, batch: int, seq_len: int, kind, backward: bool) -> dict:
+    """Least work of one layer's attention call, for ``mixed_attention_roofline``, which sums it over ``kinds(m)``: nothing
+    for a ``conv`` layer; for the ``full`` one, as every flash reader counts it, forward QK^T and PV over the half of the
+    square the causal mask keeps, every query head over ``head_dim`` (32 heads of 64 on 8 key heads); backward dV, dP, dQ,
+    dK (the recomputed QK^T is not required work). Bytes: q, k, v and o in bf16 and the row statistics (a float32 a head and
+    query) moved once; in the backward those again with the output's cotangent, and dq, dk, dv written once."""
+    if kind[0] != "full":
+        return {"flops": 0.0, "bytes": 0.0}
+    heads, kv, hd = m["num_attention_heads"], m["num_key_value_heads"], head_dim(m)
+    flops = 4.0 * heads * hd * batch * seq_len * (seq_len + 1) / 2.0 * (2 if backward else 1)
+    q, kvs, stats = batch * seq_len * heads * hd, batch * seq_len * kv * hd, batch * seq_len * heads
+    moved = 2.0 * (2 * q + 2 * kvs) + 4.0 * stats
+    return {"flops": float(flops), "bytes": moved + (moved + 2.0 * q if backward else 0.0)}

@@ -75,3 +75,19 @@ def ssd_cost(m: dict, batch: int, seq_len: int, backward: bool) -> dict:
     flops = ssd_scan_flops_per_token(m) * tokens * (2 if backward else 1)
     moved = 2 * operands + tokens * 2 * H * P + states if backward else operands + tokens * 2 * H * P + states
     return {"flops": float(flops), "bytes": float(moved)}
+
+
+def mixed_attention_cost(m: dict, batch: int, seq_len: int, kind, backward: bool) -> dict:
+    """Least work of one layer's attention call, for ``mixed_attention_roofline``, which sums it over ``kinds(m)``: nothing
+    for a Mamba-2 layer or a routed FFN's; for the ``nope`` one, as every flash reader counts it, forward QK^T and PV over
+    the half of the square the causal mask keeps, every query head over ``head_dim`` (32 heads of 128 on 2 key heads);
+    backward dV, dP, dQ, dK (the recomputed QK^T is not required work). Bytes: q, k, v and o in bf16 and the row statistics
+    (a float32 a head and query) moved once; in the backward those again with the output's cotangent, and dq, dk, dv
+    written once."""
+    if kind[0] != "nope":
+        return {"flops": 0.0, "bytes": 0.0}
+    heads, kv, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
+    flops = 4.0 * heads * hd * batch * seq_len * (seq_len + 1) / 2.0 * (2 if backward else 1)
+    q, kvs, stats = batch * seq_len * heads * hd, batch * seq_len * kv * hd, batch * seq_len * heads
+    moved = 2.0 * (2 * q + 2 * kvs) + 4.0 * stats
+    return {"flops": float(flops), "bytes": moved + (moved + 2.0 * q if backward else 0.0)}

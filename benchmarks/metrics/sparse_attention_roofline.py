@@ -6,8 +6,9 @@ the steps in the traced stretch), over the device time of the attention calls
 (``sparse_fwd`` / ``sparse_bwd``, the Pallas calls ``ops/indexed_attention.py``
 makes on a TPU). The calls visit every causal pair and mask, so at the flash
 kernels' efficiency this reads chosen / visible of theirs; a gathered kernel
-would be read by the same count. The indexer's loss's second pass
-(``sparse_probs``) is in neither this nor ``index_select_roofline``. None where
+would be read by the same count. The indexer's loss's own walk over the
+pairs (``index_loss``, one call since PR 48; the ``sparse_probs`` pass it
+replaced is gone) is in neither this nor ``index_select_roofline``. None where
 the configuration names no such cost, or nothing matches."""
 
 from benchmarks.lib import flops, kernel_time

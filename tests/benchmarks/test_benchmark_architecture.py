@@ -13,6 +13,7 @@ import sys
 import pytest
 
 from benchmarks.lib import flops, manifest as mf, program, reference
+from tests.benchmarks.test_benchmark_hybrid import _one_cell_base  # the first cell alone and n more: a case counts what it builds
 
 MANIFEST = mf.load_manifest()
 
@@ -188,29 +189,17 @@ def test_every_configuration_file_here_has_no_problems(name):
     assert mf.config_problems(mf.load_json(os.path.join(mf.BENCH, "configs", f"{name}.json")), entry) == []
 
 
-def _more_cells(n, four=0):
-    """The manifest with ``n`` more cells, the last ``four`` of them on four chips."""
-    m = json.loads(json.dumps(MANIFEST))
-    for i in range(n):
-        cell = dict(m["workloads"][0], name=f"more.{i}", chips=4 if i >= n - four else 1)
-        m["workloads"].append(cell)
-        for group in ("end_to_end", "per_layer"):
-            for metric in m[group]:
-                metric.get("workloads", []).append(cell["name"])
-    return m
-
-
 @pytest.mark.parametrize("manifest,needle", [
-    (_more_cells(24), "more than 24"),
-    (_more_cells(1, four=1), "more than 1 of 2 cells ask for four chips"),
-    (_more_cells(6, four=1), "more than 1 of 7 cells ask for four chips"),
+    (_one_cell_base(24), "more than 24"),
+    (_one_cell_base(1, four=1), "more than 1 of 2 cells ask for four chips"),
+    (_one_cell_base(6, four=1), "more than 1 of 7 cells ask for four chips"),
 ])
 def test_too_many_cells_and_a_second_four_chip_cell_are_found(manifest, needle):
     assert any(needle in p for p in mf.problems(manifest))
 
 
 def test_a_second_four_chip_cell_has_room_among_eight():
-    assert mf.problems(_more_cells(7, four=1)) == []
+    assert mf.problems(_one_cell_base(7, four=1)) == []
     entry = dict(MANIFEST["configs"][0], reduced=["num_hidden_layers"])
     assert any("differs between" in p for p in mf.problems(dict(MANIFEST, configs=[entry])))
 

@@ -32,8 +32,8 @@ def test_the_manifest_and_the_configuration_have_no_problems():
     assert traffic["generator"] == "fixed_batches" and traffic["params"] == {"seq_len": 8192, "n_batches": 8}
     assert CONFIG["trainer"]["train_micro_batch_size_per_gpu"] == 1 and CONFIG["trainer"]["zero_optimization"]["stage"] == 0
     reported = {m["name"] for g in ("end_to_end", "per_layer") for m in mf.metrics_of(MANIFEST, CELL, g)}
-    assert reported == {"train_tokens_per_s", "setup_s", "mfu.train", "kda_scan_roofline", "mla_attention_roofline",
-                        "moe_expert_matmul_roofline"}
+    assert {"train_tokens_per_s", "setup_s", "mfu.train", "kda_scan_roofline", "mla_attention_roofline",
+            "moe_expert_matmul_roofline"} <= reported  # at least what its PR brought: a later reader may list the cell
 
 
 @pytest.mark.skipif(not os.path.isfile(CATALOG), reason="the catalog of published configurations is not on this machine")
@@ -172,10 +172,15 @@ def test_the_quarter_rule_on_a_one_cell_base(n, four, needle):
 
 
 def test_the_manifest_as_it_stands_leaves_room_for_no_second_four_chip_cell():
+    """The title was true of three cells. The rule it states is: a four-chip cell more has no problem while a quarter of
+    the cells, rounded down, allows it (``test_benchmark_manifest.py`` shows that for every cell there is), and a
+    one-cell base with two more cells still refuses the second."""
+    assert any("more than 1 of 3 cells ask for four chips" in p for p in mf.problems(_one_cell_base(2, 2)))
     m = json.loads(json.dumps(MANIFEST))
     m["workloads"].append(dict(m["workloads"][0], name="more.0", chips=4))
     for group in ("end_to_end", "per_layer"):
         for metric in m[group]:
             if m["workloads"][0]["name"] in metric.get("workloads", []):
                 metric["workloads"].append("more.0")
-    assert any("more than 1 of 3 cells ask for four chips" in p for p in mf.problems(m))
+    n, four, room = len(m["workloads"]), sum(w["chips"] == 4 for w in m["workloads"]), max(1, len(m["workloads"]) // 4)
+    assert mf.problems(m) == ([f"workloads: more than {room} of {n} cells ask for four chips"] if four > room else [])

@@ -32,11 +32,12 @@ def test_the_manifest_and_the_configuration_have_no_problems():
     assert mf.config_problems(CONFIG, entry) == []
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "pretrain-8k", NAME)
-    assert MANIFEST["workloads"][-1] == cell and MANIFEST["configs"][-1] == entry  # new entries go last
+    assert (entry["file"], entry["source"]) == (f"benchmarks/configs/{NAME}.json", CONFIG["source"])  # found by name: later entries follow
     assert CONFIG["trainer"]["train_micro_batch_size_per_gpu"] == 1 and CONFIG["trainer"]["zero_optimization"]["stage"] == 0
     reported = {m["name"] for g in ("end_to_end", "per_layer") for m in mf.metrics_of(MANIFEST, CELL, g)}
-    assert reported == {"train_tokens_per_s", "setup_s", "mfu.train", "latent_attention_roofline", "moe_expert_matmul_roofline"}
-    assert MANIFEST["per_layer"][-1]["name"] == "latent_attention_roofline" and MANIFEST["per_layer"][-1]["workloads"] == [CELL]
+    assert {"train_tokens_per_s", "setup_s", "mfu.train", "latent_attention_roofline", "moe_expert_matmul_roofline"} <= reported
+    metric = next(m for m in MANIFEST["per_layer"] if m["name"] == "latent_attention_roofline")
+    assert CELL in metric["workloads"] and (metric["unit"], metric["source"], metric["moves"]) == ("%", "device_trace", "train_tokens_per_s")
 
 
 @pytest.mark.skipif(not os.path.isfile(CATALOG), reason="the catalog of published configurations is not on this machine")

@@ -40,6 +40,51 @@ def test_manifest_keys_are_the_contracts():
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) <= max(1, len(MANIFEST["workloads"]) // 4)
 
 
+def _with_four_chip_copies(of, k):
+    """The manifest as it stands with ``k`` more cells on four chips, copies of the cell ``of``, each listed in every metric
+    that lists the FIRST cell (metrics any cell of its kind can report: what ``manifest.problems`` checks is the bookkeeping)."""
+    m = json.loads(json.dumps(MANIFEST))
+    first, base = m["workloads"][0]["name"], next(w for w in m["workloads"] if w["name"] == of)
+    for i in range(k):
+        m["workloads"].append(dict(base, name=f"more.{i}", chips=4))
+        for group in ("end_to_end", "per_layer"):
+            for metric in m[group]:
+                if first in metric.get("workloads", []):
+                    metric["workloads"].append(f"more.{i}")
+    return m
+
+
+def _named_in_one_more_reader(m, cell):
+    """``cell`` in the list of the first per-layer metric that does not name it yet and moves an end-to-end metric the cell
+    reports; None where every reader already names it."""
+    reports = {e["name"] for e in mf.metrics_of(m, cell, "end_to_end")}
+    metric = next((x for x in m["per_layer"] if cell not in x.get("workloads", [cell]) and x["moves"] in reports), None)
+    if metric is not None:
+        metric["workloads"].append(cell)
+    return metric
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_the_manifest_as_it_stands_has_room_by_the_rule_and_by_no_count(cell):
+    """What a later PR may do without editing a test: add a cell, on four chips while a quarter of the cells (rounded down,
+    one always) allows it, and list a cell in a reader that is there. No other test counts the cells, places an entry or
+    pins a cell's set of metrics; this one states the rule over whatever the manifest holds, a case a cell."""
+    n, four = len(MANIFEST["workloads"]), sum(w["chips"] == 4 for w in MANIFEST["workloads"])
+    for k in range(1, mf.MAX_CELLS - n + 1):  # (a) four-chip copies of this cell, one more at a time, until the rule refuses one
+        room = max(1, (n + k) // 4)
+        refused = [f"workloads: more than {room} of {n + k} cells ask for four chips"] if four + k > room else []
+        assert mf.problems(_with_four_chip_copies(cell, k)) == refused, k
+        if refused:
+            break
+    m = json.loads(json.dumps(MANIFEST))  # (b) this cell in one more reader's list
+    metric = _named_in_one_more_reader(m, cell)
+    assert mf.problems(m) == [] and (metric is None or metric in mf.metrics_of(m, cell, "per_layer"))
+    if four + 1 <= max(1, (n + 1) // 4):  # and the four-chip cell of (a) in a reader that the first cell is not in
+        m = _with_four_chip_copies(cell, 1)
+        metric = _named_in_one_more_reader(m, "more.0")
+        assert mf.problems(m) == [] and (metric is None or metric in mf.metrics_of(m, "more.0", "per_layer"))
+
+
 @pytest.mark.parametrize("config,key", [(c, k) for c, keys in PUBLISHED.items() for k in keys])
 def test_every_size_is_the_sources_or_listed_as_reduced(config, key):
     cfg = mf.load_json(os.path.join(mf.BENCH, "configs", f"{config}.json"))

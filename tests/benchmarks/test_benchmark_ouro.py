@@ -41,14 +41,14 @@ def test_the_configuration_and_its_cell_have_no_problems():
     assert CONFIG["trainer"]["optimizer"]["type"] == "adam" and CONFIG["program"]["remat"] is True
     assert (CONFIG["warmup_steps"], CONFIG["trace_steps"]) == (3, 4)
     reported = {m["name"] for g in ("end_to_end", "per_layer") for m in mf.metrics_of(MANIFEST, CELL, g)}
-    assert reported == {"train_tokens_per_s", "setup_s", "mfu.train", READER}
+    assert {"train_tokens_per_s", "setup_s", "mfu.train", READER} <= reported  # at least what its PR brought: a later reader may list the cell
     assert TRAFFIC["generator"] == "fixed_batches" and TRAFFIC["params"] == {"seq_len": 8192, "n_batches": 8}  # the file the benchmark has
     assert "first_loss_tol" in CONFIG["correct_why"] and 0 < CONFIG["correct"]["first_loss_tol"] <= 0.05
 
 
 def test_the_new_metric_is_this_cells_alone():
     metric = next(m for m in MANIFEST["per_layer"] if m["name"] == READER)
-    assert metric["workloads"] == [CELL] and (metric["unit"], metric["better"], metric["source"], metric["moves"]) == \
+    assert CELL in metric["workloads"] and (metric["unit"], metric["better"], metric["source"], metric["moves"]) == \
         ("%", "higher", "device_trace", "train_tokens_per_s")
     mod = mf.metric_module(READER)
     assert (mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER, mod.MOVES) == tuple(metric[k] for k in ("unit", "better", "source", "layer", "moves"))
@@ -56,8 +56,6 @@ def test_the_new_metric_is_this_cells_alone():
     for shared in ("train_tokens_per_s", "mfu.train"):  # appended to, nothing else changed
         listed = next(m for g in ("end_to_end", "per_layer") for m in MANIFEST[g] if m["name"] == shared)["workloads"]
         assert CELL in listed and listed.index(CELL) > listed.index("nemotron3-nano-30b-l9e8.pretrain-8k")
-    for other in MANIFEST["per_layer"]:  # no other reader was handed the cell
-        assert (CELL in other.get("workloads", ())) == (other["name"] in ("mfu.train", READER)), other["name"]
 
 
 @pytest.mark.parametrize("case,needle", [("as_it_is", None), ("a_width_reduced", "reduced names a width"), ("the_head_size_reduced", "reduced names a width"),
@@ -255,7 +253,9 @@ def test_the_rehearsal_ends_correct_and_says_and_counts_its_passes():
     applied = counters["train_loop_block_applications_total"]
     assert steps > 0 and applied % 8 == 0 and abs(applied / 8 - steps) <= 3  # 2 layers x 4 passes a step, counted a step or two late
     line = next(l for l in lines if "program first call: family=train" in l)
-    for word in ("block_traces=1", "loop_steps=4", "remat_keeps=inputs", "rope=xla"):
+    # ``remat_keeps`` is ``models/transformer.py::remat_keeps`` over the stack's kinds, as ``runtime/engine.py`` joins it: since
+    # PR 64 a plain block keeps its flash call's outputs under that one name (``inputs`` before, and where no kind keeps any)
+    for word in ("block_traces=1", "loop_steps=4", "remat_keeps=flash_attention", "rope=xla"):
         assert word in line, word
 
 

@@ -124,9 +124,11 @@ def test_a_new_reader_states_the_facts_the_manifest_needs(metric):
     assert mod.SOURCE in ("program_span", "program_counter") and mod.BETTER == "lower" and mod.MOVES and mod.LAYER
     assert mod.read({"end_to_end": {}, "summary": {"tokens_total": 0}}) is None
     listed = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert (metric in listed) == (metric in ("setup_program_s", "host_step_share.train"))
-    if metric in listed:
-        assert listed[metric]["workloads"] == ["olmo-1b.pretrain-z3"]
+    if metric in ("setup_program_s", "host_step_share.train"):  # the two PR 25 listed: still listed, OLMo's cell among their cells
+        assert metric in listed and "olmo-1b.pretrain-z3" in listed[metric]["workloads"]
+    if metric in listed:  # wherever a later PR lists one of these, the manifest says what the reader says
+        assert tuple(listed[metric][k] for k in ("unit", "better", "source", "layer", "moves")) == \
+            (mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER, mod.MOVES)
 
 
 # ------------------------------------------------------- a rehearsal's record
