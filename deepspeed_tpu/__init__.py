@@ -31,7 +31,9 @@ def initialize(args=None,
                **kwargs):
     """Build a training engine. Reference: ``deepspeed/__init__.py:70``.
 
-    Returns ``(engine, optimizer, dataloader, lr_scheduler)``.
+    Returns ``(engine, optimizer, dataloader, lr_scheduler)``. At ZeRO stage 3
+    on several chips the divided leaves of ``model_parameters`` are deleted
+    once their shards stand (the tree is consumed: ``runtime/engine.py::initialize``).
     """
     from .runtime.engine import initialize as _initialize
 

@@ -141,7 +141,7 @@ class InferenceEngineV2:
                                       f"{list(cfg.shares)} between blocks: training-side only")
         if cfg.attn_output_gate:
             raise NotImplementedError("inference/v2 has no output gate on its attention (attn_output_gate): training-side only")
-        if cfg.loop_steps > 1 or cfg.norm_scheme == "sandwich":
+        if cfg.loop_steps > 1 or cfg.norm_scheme in ("sandwich", "output"):
             raise NotImplementedError(f"inference/v2's step is ONE pass of pre- or post-norm blocks over one cache a layer; loop_steps="
                                       f"{cfg.loop_steps} (a looped model needs a cache a pass and an exit decided a token at decode) and "
                                       f"norm_scheme={cfg.norm_scheme!r} are training-side only")

@@ -68,6 +68,8 @@ class TransformerFields:
     causal: bool = True  # False: bidirectional encoder
     # pre (gpt/llama) | post (BERT: norm after residual add) | sandwich (a norm before AND after each sublayer, the second
     # on the sublayer's output inside the residual branch: h + N2(Attn(N1(h))), then h + N4(FFN(N3(h))); four weights a layer)
+    # | output (a norm on each sublayer's OUTPUT alone, inside the residual branch, and none on its input: h + N1(Attn(h)),
+    # then h + N2(FFN(h)); two weights a layer, a last norm before the head as under ``pre``)
     norm_scheme: str = "pre"
     type_vocab_size: int = 0  # >0: token_type embeddings added to the input
     mlm_head: bool = False  # BERT cls.predictions transform (dense+act+LN) before the tied decoder
@@ -169,10 +171,10 @@ class TransformerFields:
 
     def __post_init__(self):
         """What the fields alone rule out (what they say of the layers' KINDS is ``transformer.py::_kinds_of``'s)."""
-        if self.norm_scheme not in ("pre", "post", "sandwich"):
-            raise ValueError(f"norm_scheme={self.norm_scheme!r}: pre, post or sandwich")
-        if self.norm_scheme == "sandwich" and self.block_type != "sequential":
-            raise ValueError(f"norm_scheme='sandwich' norms each sublayer's output inside its own residual branch: it needs "
+        if self.norm_scheme not in ("pre", "post", "sandwich", "output"):
+            raise ValueError(f"norm_scheme={self.norm_scheme!r}: pre, post, sandwich or output")
+        if self.norm_scheme in ("sandwich", "output") and self.block_type != "sequential":
+            raise ValueError(f"norm_scheme={self.norm_scheme!r} norms each sublayer's output inside its own residual branch: it needs "
                              f"block_type='sequential', not {self.block_type!r}")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps={self.loop_steps}: the stack is run at least once")

@@ -251,6 +251,13 @@ class Block(nn.Module):
             a, new_cache = run_attn(h)
             x = checkpoint_name(x + _norm(cfg, a), SAVED)
             x = x + _norm(cfg, self._mlp(cfg, _norm(cfg, x), {"mixer_input": h}))
+        elif cfg.norm_scheme == "output":  # a norm on each sublayer's OUTPUT alone, inside the residual branch: none on its input
+            # named (``SAVED``): a norm's backward starts from its INPUT, so a checkpointed block that keeps the two sublayers'
+            # outputs does not make the output projection, the routed rows' return and the shared expert again to get them back;
+            # the sums are the block's input plus two norms, elementwise, and are made again
+            a, new_cache = run_attn(x)
+            x = x + _norm(cfg, checkpoint_name(a, SAVED))
+            x = x + _norm(cfg, checkpoint_name(self._mlp(cfg, x, {"mixer_input": x}), SAVED))
         elif one_part:  # y = x + Part(norm(x)): the one part's norm and add, nothing for the half that is not there
             h = _norm(cfg, x)
             x = x + (self._mlp(cfg, h, {"mixer_input": h}) if attn is None else run_attn(h)[0])

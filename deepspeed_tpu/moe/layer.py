@@ -15,6 +15,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
@@ -138,6 +139,12 @@ def _count_rows(rows):
     for rung, name in enumerate(RUNGS):
         reg.counter("moe_buffer_rung_layers_total", rung=name).inc(float((rows[:, 4] == rung).sum()))
     reg.gauge("moe_rows_over_uniform_max").set(float(rows[:, 5].max()) / 1000)
+    # the rows that crossed chips (``exchanged_experts``: as many arrive somewhere as leave; 0 where no row travels) and
+    # what the fullest and the emptiest chip of the host computed in a layer: the straggler
+    sent, most, least = (rows[:, 6], rows[:, 7], rows[:, 8]) if rows.shape[1] > 6 else (0 * rows[:, 0], rows[:, 0], rows[:, 0])  # one chip: its own
+    reg.counter("moe_rows_sent_total").inc(float(sent.sum()))
+    reg.gauge("moe_chip_rows_max").set(float(most.max()))
+    reg.gauge("moe_chip_rows_min").set(float(least.min()))
 
 
 def report_rows(intermediates):
@@ -177,10 +184,13 @@ class RoutedMoE(LayerKind, nn.Module):
     placed ahead of the attention reads the block's FIRST norm's output for
     ``idx`` / ``weights``, and the experts (and a shared one) still read ``x``.
 
-    With an ``expert`` mesh axis the held experts are split over it by their
-    leading dimension (``MOE_PARTITION_RULES``): every chip of the axis
-    routes the same tokens, computes the part of its own ``count / axis``
-    experts, and the parts are summed over the axis.
+    On a mesh the held experts are split by their leading dimension over its
+    ``expert`` and ``fsdp`` axes (``MOE_PARTITION_RULES``), by one rule
+    (``_over_expert_axis``): over an axis the rows are split over too
+    (``fsdp``) each row goes to the chip that holds its expert and its result
+    comes back (``sharded_moe.exchanged_experts``); over one they are not
+    (``expert``) every chip routes the same tokens, computes the part of its
+    own experts, and the parts are summed.
     """
 
     hidden_size: int
@@ -201,7 +211,8 @@ class RoutedMoE(LayerKind, nn.Module):
     sows, keeps, hybrid = ("intermediates",), (SAVED,), True
     paths = {"moe_path": ("ffn/experts", {}), "moe_combine": ("ffn/rows", {}), "moe_cond": ("ffn/cond", {})}
     path_words = {"moe_cond": "fallback_keeps_nothing"}  # the one form the conditional has
-    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu", "relu2"), "act")}
+    joined = {"moe_router": ("ffn/router", ("sigmoid", "softmax", "compare_sum")), "moe_activation": ("ffn/experts", ("relu", "relu2"), "act"),
+              "moe_exchange": ("ffn/exchange", ("rows", "sum"), "form")}  # how held experts split over a mesh meet their rows (``_over_expert_axis``); no key: nothing crosses chips
     report = staticmethod(report_rows)
 
     @classmethod
@@ -239,7 +250,8 @@ class RoutedMoE(LayerKind, nn.Module):
         kernel = placement.kernel_path() == "kernel"
         out, *counts = _over_expert_axis(tokens.astype(self.dtype), idx, weights, wg, wi, wo, first, E, kernel, self.act)
         # (routed here, of them not computed, largest group, smallest group, the buffer's rung, routed here over the
-        # uniform load in thousandths), sown: ``report_rows`` hands them on
+        # uniform load in thousandths; on several chips also the rows that crossed chips and the fullest and the
+        # emptiest chip's rows), sown: ``report_rows`` hands them on
         self.sow("intermediates", "rows", jnp.stack(counts).astype(jnp.int32))
         if self.shared_ff:
             with region("ffn/shared", **({"path": "gated"} if self.shared_gate else {})):
@@ -267,34 +279,45 @@ class EarlyRoutedMoE(RoutedMoE):
 
 
 def _over_expert_axis(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act="silu"):
-    """``routed_part`` on one chip; on a mesh, inside a shard_map in which the
-    tokens are split over the batch axes, the experts over ``expert``, and the
-    parts are summed over ``expert`` (``placement.on_mesh``: on one chip ``local`` is ``part``)."""
-    def part(tokens, idx, weights, wg, wi, wo, first):
-        """``routed_part``, and the pairs it found routed here over a uniform router's, in thousandths."""
-        out, routed, *counts = routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act)
-        uniform = idx.size * wo.shape[0] / num_experts
-        return out, routed, *counts, jnp.round(routed.astype(jnp.float32) * (1000 / uniform)).astype(jnp.int32)
-
-    axis = placement.axis_size("expert")
+    """``routed_part`` on one chip; on a mesh, inside a shard_map in which the tokens are split over the batch axes and
+    the held experts over ``placement.held_axes`` (``expert`` and ``fsdp``), by ONE rule: over an axis of those that the
+    ROWS are split over too (``fsdp``: every chip its own tokens, the expert-parallel group a slice of the data-parallel
+    one) a row travels to the chip that holds its expert and its result travels back (``exchanged_experts``); over an
+    axis the rows are NOT split over (``expert``: every chip of it routes the same tokens) each chip computes its own
+    experts' part and the parts are summed (``placement.on_mesh``: on one chip ``local`` is ``part``)."""
+    axes = placement.held_axes(wo.shape)
     rows = placement.batch_spec(tokens.shape, None)
-    held = P("expert", None, None) if axis > 1 and wo.shape[0] % axis == 0 else P()
     split = rows[0] if len(rows) and rows[0] is not None else ()
-    over = (split if isinstance(split, tuple) else (split,)) + (("expert",) if held != P() else ())  # axes the pairs are spread over
+    split = split if isinstance(split, tuple) else (split,)
+    exchanged = tuple(a for a in axes if a in split)  # the rows travel
+    summed = tuple(a for a in axes if a not in split)  # the parts are summed
+    held = P(axes if len(axes) > 1 else axes[0], None, None) if axes else P()
+    over = tuple(dict.fromkeys(split + axes))  # axes the pairs are spread over
+    placed = placement.placed()  # several chips: the sown counts carry three more (what crossed chips, the fullest and the emptiest chip)
 
     def local(tokens, idx, weights, wg, wi, wo):
-        mine = first + (jax.lax.axis_index("expert") * wo.shape[0] if held != P() else 0)
-        out, routed, dropped, largest, smallest, rung, over_uniform = part(tokens, idx, weights, wg, wi, wo, mine)
-        if held != P():
-            out = jax.lax.psum(out, "expert")
+        n, axis = wo.shape[0], (exchanged or (None,))[0]
+        block = lambda names: sum(jax.lax.axis_index(a) * int(np.prod([jax.lax.axis_size(b) for b in axes[axes.index(a) + 1:]])) for a in names)
+        mine = first + block(summed) * n  # the first expert of this chip's block, or of its ``exchanged`` axis' blocks
+        out, routed, dropped, largest, smallest, *sent, rung = routed_part(tokens, idx, weights, wg, wi, wo, mine, num_experts, kernel, act, axis=axis)
+        uniform = idx.size * n * (jax.lax.axis_size(axis) if axis else 1) / num_experts  # what a uniform router sends a chip's experts
+        over_uniform = jnp.round(routed.astype(jnp.float32) * (1000 / uniform)).astype(jnp.int32)
+        if summed:
+            with region("ffn/exchange", path="sum", form="sum"):
+                out = jax.lax.psum(out, summed)
+        sums, most, least = [routed, dropped], [largest, rung, over_uniform], [smallest]
+        if placed:  # ... and what this chip computed: the straggler is the fullest
+            sums, most, least = sums + [sent[0] if sent else jnp.zeros((), jnp.int32)], most + [routed], least + [routed]
         if over:  # the rung and the load are the fullest shard's: a (layer, step) pair counts once
-            routed, dropped = (jax.lax.psum(x, over) for x in (routed, dropped))
-            largest, rung, over_uniform = (jax.lax.pmax(x, over) for x in (largest, rung, over_uniform))
-            smallest = jax.lax.pmin(smallest, over)
-        return out, routed, dropped, largest, smallest, rung, over_uniform
+            # (gathered and reduced: a count that came through an exchange carries a tangent of no type, and ``pmax`` has no rule)
+            sums = [jax.lax.psum(x, over) for x in sums]
+            most = [jnp.max(jax.lax.all_gather(x, over)) for x in most]
+            least = [jnp.min(jax.lax.all_gather(x, over)) for x in least]
+        (routed, dropped, *sent), (largest, rung, over_uniform, *most), (smallest, *least) = sums, most, least
+        return out, routed, dropped, largest, smallest, rung, over_uniform, *sent, *most, *least
 
     # (an ungated expert's ``wg`` is None: no operand, and no spec for it)
-    return placement.on_mesh(local, (rows, rows, rows, None if wg is None else held, held, held), (rows,) + (P(),) * 6)(tokens, idx, weights, wg, wi, wo)
+    return placement.on_mesh(local, (rows, rows, rows, None if wg is None else held, held, held), (rows,) + (P(),) * 9)(tokens, idx, weights, wg, wi, wo)
 
 
 def _mesh_has_axis(axis: str) -> bool:
@@ -312,9 +335,9 @@ def _mesh_has_axis(axis: str) -> bool:
 # canonical row-parallel allreduce (verified: no weight gathers in HLO),
 # so Mixtral-class expert memory scales with tp instead of replicating.
 MOE_PARTITION_RULES = [
-    (("experts_wi",), P("expert", None, None)),  # RoutedMoE: the held experts, whole in their width
-    (("experts_wo",), P("expert", None, None)),
-    (("experts_wg",), P("expert", None, None)),
+    (("experts_wi",), placement.HELD),  # RoutedMoE: the held experts, whole in their width, over ``expert`` AND ``fsdp``:
+    (("experts_wo",), placement.HELD),  # ZeRO's axis holds them BY EXPERT and never gathers them (``_over_expert_axis``)
+    (("experts_wg",), placement.HELD),
     (("experts", "wi"), P("expert", None, "tensor")),
     (("experts", "wo"), P("expert", "tensor", None)),
     (("experts", "wg"), P("expert", None, "tensor")),

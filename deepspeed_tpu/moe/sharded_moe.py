@@ -373,7 +373,76 @@ def held_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: boo
         return out, routed, routed - jnp.sum(take), jnp.max(group_sizes), jnp.min(group_sizes)
 
 
-def _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
+def _rows_to_each(idx, first, n: int, axis):
+    """For the chips of ``axis``, chip ``c`` holding the experts ``first + c * n .. first + (c + 1) * n``: each pair's chip
+    ((P,) int32, the axis' size for a pair whose expert none of them holds) and how many pairs go to each chip."""
+    chips = jax.lax.axis_size(axis)
+    local = (idx - first).reshape(-1)
+    dest = jnp.where((local >= 0) & (local < chips * n), local // n, chips).astype(jnp.int32)
+    return dest, _rows_a_group(dest, chips)
+
+
+def exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel: bool, named: bool = True, act: str = "silu", row_tile: int = 256,
+                      axis="fsdp", slab: int = None):
+    """``held_experts`` where the experts are split over the chips of ``axis`` AND the tokens are (a deployment's layout:
+    the expert-parallel group is a slice of the data-parallel one): chip ``c`` holds ``first + c * n .. first + (c + 1) * n``
+    (``wg, wi, wo`` are its own ``n``) and has its own ``tokens`` (N, d), routed by ``idx`` / ``weights`` over ALL experts.
+
+    A chip sorts its pairs by the chip that holds the expert (stable: a chip's rows keep their tokens' order) into a
+    buffer of ``slab`` slots a chip, (chips, slab, d); ``lax.all_to_all`` hands slab ``c`` to chip ``c`` with each row's
+    expert; ``held_experts`` runs there on what arrived, one pair a row with weight one, in a buffer of ``rows`` rows
+    that the arrivals of all the chips are packed into (its sort puts the empty slots last); the results return by the
+    reverse exchange into the slots they left from, and each token sums its own, by weight. The backward is the same two
+    exchanges the other way (``all_to_all``'s transpose). Two sizes, because two loads spread differently
+    (``exchange_rungs``): ``slab`` bounds what one chip sends ONE other, the popularity of a few experts among one chip's
+    tokens, and is wide; ``rows`` bounds what a chip RECEIVES from all, which the senders' skews mostly average out in,
+    and is what every pass between the grouped products costs. A slab travels whole, its empty slots too (tried: its second half
+    under a ``lax.cond`` on whether any pair fills the first, a linear branch that keeps nothing; XLA:CPU's four virtual
+    devices then gave a wrong input gradient or aborted, one run in two, so tier-1 could not hold it, and one form on the
+    CPU and the chip is the design). Both sizes are the same on every chip of the axis: the caller takes a rung that
+    holds the fullest pair's and the fullest chip's load (``routed_part``). ``slab`` None: ``rows`` over the chips, a
+    buffer that holds every slot. Rows that stay on their chip pass through the same buffer and are no traffic.
+    Returns (the tokens' sums, the rows that arrived here, those of a chip's pairs for held experts that the slab or the
+    buffer here did not hold, the largest and smallest group here, the rows this chip sent to ANOTHER chip)."""
+    N, k = idx.shape
+    n, d = wo.shape[0], tokens.shape[1]
+    chips = jax.lax.axis_size(axis)
+    slab = rows // chips if slab is None else slab
+    keep = lambda x: checkpoint_name(x, SAVED) if named else x
+    with region("ffn/router", path="compare_sum"):
+        me = jax.lax.axis_index(axis)
+        dest, counts = _rows_to_each(idx, first, n, axis)
+        order = jnp.argsort(dest)  # stable: by chip, tokens in order
+        starts = jnp.cumsum(counts) - counts
+        at = dest[:, None] == jnp.arange(chips, dtype=dest.dtype)  # (P, chips): by comparison, as ``_rows_a_group`` counts
+        place = jnp.argsort(order) - jnp.sum(jnp.where(at, starts, 0), axis=1)  # a pair's place among its chip's rows
+        take = ((dest < chips) & (place < slab)).reshape(N, k)
+        pos = (jnp.minimum(dest, chips - 1) * slab + place).reshape(N, k)  # the slot each pair went to
+        slot = jnp.arange(slab, dtype=jnp.int32)
+        row_ok = (slot[None, :] < counts[:, None]).reshape(-1, 1)
+        pair_of_row = order[jnp.minimum(starts[:, None] + slot[None, :], N * k - 1).reshape(-1)]
+        tok_of_row = pair_of_row // k
+        # a row's expert among its chip's own, ``n`` for an empty slot: it travels with the row
+        expert_of_row = jnp.where(row_ok[:, 0], (idx.reshape(-1)[pair_of_row] - first) % n, n).astype(jnp.int32)
+        sent = jnp.sum(jnp.where(jnp.arange(chips) == me, 0, jnp.minimum(counts, slab)))
+        pos, take, pair_of_row, tok_of_row, row_ok = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok))
+    with region("ffn/rows", path="xla"):
+        xs = _rows_of(tokens, tok_of_row, row_ok, pos, take, None)  # (chips * slab, d)
+    swap = lambda x: jax.lax.all_to_all(x.reshape(chips, slab, *x.shape[1:]), axis, 0, 0).reshape(x.shape)
+    with region("ffn/exchange", path="dispatch", form="rows"):
+        arrived, expert_arrived = swap(xs), swap(expert_of_row)
+    mine = first + me * n
+    ys, routed, unheld, largest, smallest = held_experts(arrived, (mine + expert_arrived)[:, None], jnp.ones((chips * slab, 1), weights.dtype), wg, wi, wo,
+                                                        mine, rows, kernel, named, act, row_tile)
+    with region("ffn/exchange", path="return", form="rows"):
+        back = swap(ys)
+    with region("ffn/rows"):
+        out = _back_to_tokens(back, weights, tok_of_row, pair_of_row, row_ok, pos, take, None)  # (an empty slot comes back zero: no pair of the other chip's took it)
+    with region("ffn/router"):
+        return out, routed, jnp.sum(counts) - jnp.sum(take) + unheld, largest, smallest, sent
+
+
+def _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act, axis=None):
     """``routed_part``'s fallback, which sizes itself: ``held_experts`` at
     the smallest of ``rungs`` (buffer sizes, ascending, the last one every
     pair) that holds the pairs routed here, chosen on the device. As
@@ -387,17 +456,18 @@ def _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
     this one kept (its sorted rows and grouped products, each of a larger
     rung's row count), the first rung wrote zeros for, a layer and a step."""
     return _at_rung(idx, first, wo.shape[0], rungs,
-                    lambda rows: _unkept(tokens, idx, weights, wg, wi, wo, first, rows=rows, kernel=kernel, act=act))
+                    lambda rows: _unkept(tokens, idx, weights, wg, wi, wo, first, rows=rows, kernel=kernel, act=act, axis=axis))
 
 
 def _at_rung(idx, first, n: int, rungs, run):
     """``run(rows)`` at the smaller of the one or two ``rungs`` that holds the
-    pairs ``idx`` routes to the experts ``first .. first + n``."""
+    pairs ``idx`` routes to the experts ``first .. first + n`` (an exchange
+    has ONE rung above its first, which holds any load: ``_in_chunks``)."""
     # one tracing context for the jitted arms, whoever calls (``_sum_rows`` says why)
     with region("branch/every_pair"), jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         if len(rungs) == 1:
             return run(rungs[0])
-        return jax.lax.cond(_routed_here(idx, first, n) <= rungs[0], lambda: run(rungs[0]), lambda: run(rungs[1]))
+        return jax.lax.cond(_load(idx, first, n) <= rungs[0], lambda: run(rungs[0]), lambda: run(rungs[1]))
 
 
 def _routed_here(idx, first, n: int):
@@ -406,33 +476,77 @@ def _routed_here(idx, first, n: int):
     return jnp.sum((local >= 0) & (local < n))
 
 
+def _load(idx, first, n: int, axis=None):
+    """What a rung has to hold: the pairs routed here; with the rows exchanged over ``axis`` (``exchanged_experts``), the
+    most that any chip of the axis sends any one chip and the most that any chip receives from all, each the same number
+    on every chip, so that all take one rung."""
+    if axis is None:
+        return _routed_here(idx, first, n)
+    counts = _rows_to_each(idx, first, n, axis)[1]
+    return jax.lax.pmax(jnp.max(counts), axis), jnp.max(jax.lax.psum(counts, axis))
+
+
+def _one_rung(axis):
+    """A rung's call: ``held_experts``, or ``exchanged_experts`` over ``axis``."""
+    return held_experts if axis is None else functools.partial(exchanged_experts, axis=axis)
+
+
 # The fallback's arms are jitted for the trace's sake, not the program's (the compiler inlines them): a rung's
 # forward is wanted by the fallback's value, by its rule's forward and by every kind of block that has a routed layer, its
 # backward by each of those kinds, and a trace of ``held_experts`` with its kernels is 0.3-0.5 s on the chip's host. Traced
 # and lowered once a shape and a rung for the whole model, whatever the rungs above the first cost a step that takes them
-@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act"))
-def _unkept(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act):
-    """``held_experts`` with nothing named for a checkpoint policy."""
-    return held_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, named=False, act=act)
+def _in_chunks(N: int, most: int, rows: int, chips: int):
+    """An exchange's LAST rung, ``(rows, chunks)``: the chip's ``N`` tokens in the fewest equal chunks whose every pair fits
+    a slab of ``rows // chips`` slots (a token sends one chip ``most`` rows at most), so that the first rung's buffer of
+    ``rows`` holds whatever arrives, one chunk after another through that one buffer, forward and backward (``_unkept``,
+    ``_unkept_back``). A buffer for every pair the chips might send one of them would be ``chips * N * most`` rows,
+    65,536 of 6,144 where the first rung has 8,192: memory a step that never takes the rung would still be compiled to hold."""
+    return rows, next(c for c in range(1, N + 1) if N % c == 0 and N // c * most <= rows // chips)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act"))
-def _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangent, rows, kernel, act):
-    """``_unkept``'s forward again and its five gradients for the output's ``cotangent`` (its four counts take none)."""
-    part = lambda t, w, g, i, o: held_experts(t, idx, w, g, i, o, first, rows, kernel, named=False, act=act)[0]
-    return jax.vjp(part, tokens, weights, wg, wi, wo)[1](cotangent)
+@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act", "axis"))
+def _unkept(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act, axis=None):
+    """``held_experts`` (``exchanged_experts`` over ``axis``) with nothing named for a checkpoint policy. ``rows`` a pair
+    (``_in_chunks``: the buffer and how many chunks): the tokens a chunk at a time; the counts are the chunks' sums, the groups' the chunks' extremes."""
+    one = functools.partial(_one_rung(axis), kernel=kernel, named=False, act=act)
+    if not isinstance(rows, tuple):
+        return one(tokens, idx, weights, wg, wi, wo, first, rows)
+    split = lambda x: x.reshape(rows[1], x.shape[0] // rows[1], *x.shape[1:])
+    out, routed, dropped, largest, smallest, *sent = jax.lax.map(lambda c: one(*c, wg, wi, wo, first, rows[0]), (split(tokens), split(idx), split(weights)))
+    return out.reshape(tokens.shape), jnp.sum(routed), jnp.sum(dropped), jnp.max(largest), jnp.min(smallest), *(jnp.sum(x) for x in sent)
 
 
-_every_pair = jax.custom_vjp(_above_first, nondiff_argnums=(7, 8, 9))
+@functools.partial(jax.jit, static_argnames=("rows", "kernel", "act", "axis"))
+def _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangent, rows, kernel, act, axis=None):
+    """``_unkept``'s forward again and its five gradients for the output's ``cotangent`` (its counts take none). In
+    chunks (``rows`` a pair): each chunk's forward and backward in its own turn, the experts' gradients summed in float32."""
+    one = functools.partial(_one_rung(axis), kernel=kernel, named=False, act=act)
+    per = rows[0] if isinstance(rows, tuple) else rows
+    back = lambda t, ix, w, ct: jax.vjp(lambda t, w, g, i, o: one(t, ix, w, g, i, o, first, per)[0], t, w, wg, wi, wo)[1](ct)
+    if not isinstance(rows, tuple):
+        return back(tokens, idx, weights, cotangent)
+    split = lambda x: x.reshape(rows[1], x.shape[0] // rows[1], *x.shape[1:])
+    mats = tuple(m for m in (wg, wi, wo) if m is not None)
+
+    def chunk(sums, c):
+        d_t, d_w, *d_mats = back(*c)
+        return tuple(a + d.astype(a.dtype) for a, d in zip(sums, (d for d in d_mats if d is not None))), (d_t, d_w)
+
+    sums, (d_tokens, d_weights) = jax.lax.scan(chunk, tuple(jnp.zeros(m.shape, jnp.float32) for m in mats), tuple(split(x) for x in (tokens, idx, weights, cotangent)))
+    d_mats = iter(a.astype(m.dtype) for a, m in zip(sums, mats))
+    return d_tokens.reshape(tokens.shape), d_weights.reshape(weights.shape), *(None if m is None else next(d_mats) for m in (wg, wi, wo))
 
 
-def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act):
-    return _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act), (tokens, idx, weights, wg, wi, wo, first)
+_every_pair = jax.custom_vjp(_above_first, nondiff_argnums=(7, 8, 9, 10))
 
 
-def _every_pair_bwd(rungs, kernel, act, res, cotangents):
+def _every_pair_fwd(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act, axis):
+    return _above_first(tokens, idx, weights, wg, wi, wo, first, rungs, kernel, act, axis), (tokens, idx, weights, wg, wi, wo, first)
+
+
+def _every_pair_bwd(rungs, kernel, act, axis, res, cotangents):
     tokens, idx, weights, wg, wi, wo, first = res
-    back = lambda rows: _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangents[0], rows=rows, kernel=kernel, act=act)
+    back = lambda rows: _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangents[0], rows=rows, kernel=kernel, act=act, axis=axis)
     d_tokens, d_weights, d_wg, d_wi, d_wo = _at_rung(idx, first, wo.shape[0], rungs, back)
     return d_tokens, None, d_weights, d_wg, d_wi, d_wo, None
 
@@ -452,7 +566,25 @@ def buffer_rungs(every: int, n: int, num_experts: int):
 RUNGS = ("first", "four", "every")  # the rung a layer took in a step, by ``routed_part``'s sixth value
 
 
-def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool, act: str = "silu"):
+def exchange_rungs(N: int, k: int, n: int, num_experts: int, chips: int):
+    """The first rung where the rows are exchanged (``exchanged_experts``), ``(slab, rows)``, from ``buffer_rungs``' two
+    multiples of what a uniform router sends from a chip's ``N`` tokens to the ``n`` experts another holds, each rounded
+    up to 512 rows and capped at every pair that can go one way (a token's ``k`` experts are distinct: ``min(k, n)`` of
+    them at most on one chip). The SLAB one chip sends another holds FOUR times that: a pair of chips' load is the
+    popularity of ``n`` experts among ONE chip's tokens (one sequence, at 8k), which a router that is not uniform takes
+    past twice the uniform load in a tenth to a fifth of a model's (layer, step) pairs and past four times in none of 160
+    (K-EXAONE's layers 0-4 at a random start, my chip runs, PR 66). The BUFFER a chip computes in holds TWICE what a
+    uniform router sends it from all ``chips``, PR 54's first rung of the host's load: every pass between the grouped
+    products runs over the buffer's rows, so it is what a sound router's step pays for. Where the senders' skews are
+    their own rows' they average out at the receiver (the fullest chip of four received 0.9 to 1.3 times its uniform
+    load there); after a full-attention layer they are not (its normed output is all but the same vector for every row
+    of every chip, so all the routers lean one way): one chip received over twice its uniform load in one (layer, step)
+    pair in a hundred, which then goes in token chunks (``_exchanged_part``; ``PERF.md`` section 6, PR 66)."""
+    up = lambda times: min(N * min(k, n), -(-times * N * k * n // num_experts // 512) * 512)
+    return up(4), chips * up(2)
+
+
+def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool, act: str = "silu", axis=None):
     """``held_experts`` with a buffer that follows the load, chosen on the
     device from a ladder (``buffer_rungs``) by the count of pairs routed
     here: twice the pairs a uniform router sends to ``n`` of ``num_experts``
@@ -485,10 +617,20 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     rows it multiplies padding. The rungs above keep 256: they run in a step
     out of hundreds and their shapes are every layer's to compile.
 
-    Returns ``held_experts``'s five values and the rung taken (0, 1 or 2:
-    ``RUNGS``; 1 where four times the uniform load is every pair)."""
+    ``axis``: the experts are split over the chips of that mesh axis and the
+    tokens are too (``exchanged_experts``: ``wg, wi, wo`` are this chip's own
+    ``n``, ``first`` the axis' first expert): one rung that keeps, sized for a
+    pair of chips' skew and for what a chip receives (``exchange_rungs``), and
+    every pair in token chunks above it, taken by every chip of the axis
+    together (``_exchanged_part``).
+
+    Returns ``held_experts``'s five values (``exchanged_experts``'s six) and
+    the rung taken (0, 1 or 2: ``RUNGS``; 1 where four times the uniform load
+    is every pair)."""
     N, k = idx.shape
     n = wo.shape[0]
+    if axis is not None:
+        return _exchanged_part(tokens, idx, weights, wg, wi, wo, first, num_experts, kernel, act, axis)
     usual, four, every = buffer_rungs(N * k, n, num_experts)
     row_tile = 512 if N * k >= 2048 * num_experts else 256
 
@@ -502,6 +644,30 @@ def routed_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kerne
     # what the conditional itself adds (whatever one branch writes for the other's residuals) has no inner region and so
     # falls to ``ffn/cond``; the branches' scopes tell their ``ffn/rows`` and ``ffn/experts`` apart
     with region("ffn/cond", path="fallback_keeps_nothing"):
-        routed = _routed_here(idx, first, n)
+        routed = _load(idx, first, n)
         rung = (routed > usual).astype(jnp.int32) + (routed > four).astype(jnp.int32)
-        return *jax.lax.cond(routed <= usual, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, above, kernel, act)), rung
+        return *jax.lax.cond(routed <= usual, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, above, kernel, act, None)), rung
+
+
+def _exchanged_part(tokens, idx, weights, wg, wi, wo, first, num_experts: int, kernel: bool, act: str, axis):
+    """``routed_part`` where the rows are exchanged over ``axis``: ONE rung that keeps what a checkpointed block keeps
+    (``exchange_rungs``: a slab of four times the uniform load a pair of chips, a buffer of twice what a chip receives),
+    and above it every pair, the chip's tokens a chunk at a time through the same buffer (``_in_chunks``), which keeps
+    nothing. The rung taken is 0 or 2 (``RUNGS``: ``first``, ``every``)."""
+    N, k = idx.shape
+    n, chips = wo.shape[0], jax.lax.axis_size(axis)
+    slab, rows = exchange_rungs(N, k, n, num_experts, chips)
+    row_tile = 512 if chips * N * k >= 2048 * num_experts else 256
+
+    def held():
+        with region("branch/usual"):
+            return exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act=act, row_tile=row_tile, axis=axis, slab=slab)
+
+    most = N * min(k, n)  # every pair a chip can send one other
+    if slab == most and rows == chips * most:
+        return *held(), jnp.zeros((), jnp.int32)
+    above = (_in_chunks(N, min(k, n), rows, chips),)
+    with region("ffn/cond", path="fallback_keeps_nothing"):
+        pair, chip = _load(idx, first, n, axis)
+        holds = (pair <= slab) & (chip <= rows)
+        return *jax.lax.cond(holds, held, lambda: _every_pair(tokens, idx, weights, wg, wi, wo, first, above, kernel, act, axis)), 2 * (1 - holds.astype(jnp.int32))

@@ -52,6 +52,27 @@ def batch_spec(shape, *rest) -> P:
     return P() if topo is None else fit_spec(prune_spec(P(topo.batch_axes, *rest), topo), shape, topo)
 
 
+HELD = P(("expert", "fsdp"), None, None)  # a routed layer's experts, by their leading dimension: ``held_axes``
+
+
+def held_axes(shape) -> tuple:
+    """The live mesh's axes that a routed layer's held experts (a leaf of ``shape``, experts leading) are split over, in
+    the order their blocks are numbered: ``expert`` and ``fsdp`` (``HELD``, the leaves' partition rule), each where it is
+    wider than one, and all or none by whether their product divides the experts. ``()`` without a mesh, and inside
+    another's manual region, which has gathered what it divided."""
+    if not placed():
+        return ()
+    topo = get_mesh_topology()
+    first = (tuple(fit_spec(prune_spec(HELD, topo), shape, topo)) + (None,))[0]
+    return () if first is None else first if isinstance(first, tuple) else (first,)
+
+
+def placed() -> bool:
+    """Whether ``on_mesh`` wraps a function here: a live mesh of several chips, and not inside another's manual region."""
+    topo = get_mesh_topology(required=False)
+    return topo is not None and topo.n_devices > 1 and not jax.sharding.get_abstract_mesh().manual_axes
+
+
 def on_mesh(fn, in_specs, out_specs):
     """``fn`` made safe to trace under a multi-device jit.
 
@@ -63,10 +84,9 @@ def on_mesh(fn, in_specs, out_specs):
     apply: no mesh, one device, or already inside a manual region (the
     ZeRO++ and tensor-parallel serving stacks).
     """
-    topo = get_mesh_topology(required=False)
-    if topo is None or topo.n_devices == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+    if not placed():
         return fn
-    return jax.shard_map(fn, mesh=topo.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=get_mesh_topology().mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def replicated_on_mesh(fn):

@@ -76,6 +76,22 @@ def _cast_tree(tree, dtype):
     return jax.tree_util.tree_map(lambda x: x.astype(dtype) if hasattr(x, "astype") else x, tree)
 
 
+def _put_divided(tree, shardings, release: bool):
+    """``jax.device_put(tree, shardings, donate=release)`` that holds to the donation whatever the backend makes of the
+    hint (a put that slices one chip's array onto several takes none): with ``release``, a leaf that was a committed array
+    on FEWER devices than its sharding names is deleted once its shards are there, unless a shard IS its buffer (a small
+    leaf that stays whole on every chip: the first chip's copy is the array handed in). A leaf that already lies where it
+    is to lie, a numpy array and a leaf put onto as many devices as it had (one chip: an alias) are left alone."""
+    put = jax.device_put(tree, shardings, donate=release)
+    for was, now in zip(jax.tree_util.tree_leaves(tree) if release else (), jax.tree_util.tree_leaves(put)):
+        if isinstance(was, jax.Array) and was is not now and not was.is_deleted() and len(was.sharding.device_set) < len(now.sharding.device_set):
+            now.block_until_ready()
+            held = {shard.data.unsafe_buffer_pointer() for shard in now.addressable_shards}
+            if not any(shard.data.unsafe_buffer_pointer() in held for shard in was.addressable_shards):
+                was.delete()
+    return put
+
+
 def _global_norm(tree):
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree_util.tree_leaves(tree)]
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
@@ -239,7 +255,11 @@ class DeepSpeedEngine:
             else:
                 self.param_store_shardings, self._param_offload = maybe_enable_param_offload(
                     self.config, self.topology, self.param_shardings, param_shapes)
-            self.params = jax.device_put(params_host, self.param_store_shardings)
+            # the tree handed in is the engine's from here on, as the reference's ZeRO-3 partitions a module's parameters in
+            # place: where it is DIVIDED (a tree that lies on one chip, put over several), each leaf's whole copy is let go
+            # as its shards stand, so the first chip never holds the tree beside its share of it, the moments and the carried
+            # copy (``_put_divided``). On one chip the put is an alias and nothing is let go
+            self.params = _put_divided(params_host, self.param_store_shardings, release=self.config.zero_config.stage == 3)
             del params_host
 
             self.grad_specs = plan_grad_specs(param_shapes, self.param_specs, self.config, self.topology)
@@ -1530,7 +1550,15 @@ class DeepSpeedEngine:
 def initialize(args=None, model=None, optimizer=None, model_parameters=None, training_data=None, lr_scheduler=None,
                mesh=None, mpu=None, dist_init_required=None, collate_fn=None, config=None, **kwargs):
     """Reference ``deepspeed/__init__.py:70``. Returns (engine, optimizer,
-    dataloader, lr_scheduler)."""
+    dataloader, lr_scheduler).
+
+    At ZeRO stage 3 on several chips ``model_parameters`` is CONSUMED, as the
+    reference's stage 3 partitions a module's parameters in place: every leaf
+    that is divided over the mesh is deleted once its shards stand
+    (``_put_divided``), so that no chip holds the whole tree beside its share.
+    A caller that reads its tree after this call meets deleted arrays there;
+    ``engine.params`` is the tree from then on. At the other stages, and on one
+    chip, the tree handed in is left as it was."""
     if model is None:
         raise ValueError("deepspeed_tpu.initialize: model is required")
     if model_parameters is None and hasattr(model, "init_params"):

@@ -49,6 +49,42 @@ def enable_compilation_cache(jax, default_dir: str = CHECKOUT_CACHE_DIR, env_gat
     return cache_dir
 
 
+def write_entries_through_a_rename() -> bool:
+    """JAX's file cache writes an entry IN PLACE (``LRUCache.put``: ``Path.write_bytes``) and, with no size bound set,
+    under no lock: a second process that looks the same program up at that moment reads a torn file, and the executable
+    it deserialises takes the process down (a tier-1 worker, once in two whole runs with six workers on one
+    ``tests/.jax_cache``; ``PERF.md`` section 7, open after PR 65, (d)). Here such an entry is written under a name of the
+    writing process's own beside it and renamed: a reader finds the whole entry or none. A cache WITH a size bound keeps
+    JAX's own path, which reads and writes under the cache's lock. Idempotent; False where this JAX has no such class.
+    It replaces a method of a PRIVATE JAX class, so nothing in the library calls it: ``tests/conftest.py`` does, for the
+    one place several processes are known to share a cache directory with no size bound."""
+    try:
+        from jax._src import lru_cache
+    except ImportError:
+        return False
+    plain = lru_cache.LRUCache.put
+    if getattr(plain, "through_a_rename", False):
+        return True
+
+    def put(self, key: str, val: bytes) -> None:
+        if not key or self.eviction_enabled:
+            return plain(self, key, val)
+        entry = self.path / f"{key}{lru_cache._CACHE_SUFFIX}"
+        if entry.exists():
+            return
+        mine = self.path / f"{key}.{os.getpid()}.tmp"
+        try:
+            mine.write_bytes(val)
+            os.replace(mine, entry)
+        finally:
+            if mine.exists():  # the write failed part of the way: no entry, and nothing left beside it
+                mine.unlink()
+
+    put.through_a_rename = True
+    lru_cache.LRUCache.put = put
+    return True
+
+
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # seconds of every program's first call, by phase, in the order a call passes through them

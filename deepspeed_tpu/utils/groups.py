@@ -39,12 +39,23 @@ def get_tensor_model_parallel_world_size() -> int:
     return _topo().model_parallel_size
 
 
-def get_expert_parallel_world_size(group_name: str = "") -> int:
-    return _topo().expert_parallel_size
+def get_expert_parallel_world_size(group_name: str = "", held: Optional[int] = None) -> int:
+    """The ``expert`` axis' size; with ``held``, the count of experts a routed layer holds: the chips THOSE are spread
+    over, by the layer's own rule (``ops/placement.py::held_axes``: ``expert`` and ``fsdp`` where they divide the count,
+    else 1), so that the getter and ``moe/layer.py::_over_expert_axis`` cannot disagree. A model with no routed layer
+    has no such count, and a ZeRO axis alone is no expert parallelism."""
+    if held is None:
+        return _topo().expert_parallel_size
+    from ..ops.placement import held_axes
+
+    chips = 1
+    for axis in held_axes((held, 1, 1)):
+        chips *= _topo().axis_size(axis)
+    return chips
 
 
-def get_expert_data_parallel_world_size(group_name: str = "") -> int:
-    return max(1, get_data_parallel_world_size() // get_expert_parallel_world_size())
+def get_expert_data_parallel_world_size(group_name: str = "", held: Optional[int] = None) -> int:
+    return max(1, get_data_parallel_world_size() // get_expert_parallel_world_size(group_name, held))
 
 
 def get_sequence_parallel_world_size() -> int:
@@ -73,6 +84,9 @@ def get_model_parallel_axis() -> str:
 
 
 def get_expert_parallel_axis() -> str:
+    """The axis over which every chip routes the SAME tokens and the experts' parts are summed. A routed layer's held
+    experts also lie by expert over ``get_fsdp_axis()``, over which the rows are split too and travel to their experts
+    (``moe/layer.py::_over_expert_axis``); ``get_expert_parallel_world_size(held=...)`` counts both."""
     return "expert"
 
 

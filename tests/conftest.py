@@ -26,8 +26,11 @@ import jax  # noqa: E402
 # CI reruns recompile identical toy HLO — warm runs cut test wall time ~2x
 # (measured 24s -> 12s on the heaviest zeropp oracle). Keyed by HLO hash, so
 # code changes re-compile exactly what changed. DS_TEST_NO_CACHE=1 disables.
-from deepspeed_tpu.utils.compile_cache import enable_compilation_cache  # noqa: E402
+from deepspeed_tpu.utils.compile_cache import enable_compilation_cache, write_entries_through_a_rename  # noqa: E402
 
+# six xdist workers share the directory: an entry is written beside its name and renamed, so that no worker reads one
+# another is half way through writing (the suite's own patch of a private JAX class; the library never applies it)
+write_entries_through_a_rename()
 enable_compilation_cache(jax, os.path.join(os.path.dirname(__file__), ".jax_cache"),
                          env_gate="DS_TEST_NO_CACHE")
 

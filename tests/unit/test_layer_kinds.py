@@ -145,7 +145,9 @@ WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step":
         # (PR 58) XLA differentiates the plain lines: the backward is a call site of its own on the kernels' path alone
         "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)",
         # (PR 62) the strip the indexer's loops over heads walk a tile in: a label of the kernels' call sites alone
-        "index_strip": "the indexer's calls are the kernels (tests/unit/test_indexed_attention.py)"}
+        "index_strip": "the indexer's calls are the kernels (tests/unit/test_indexed_attention.py)",
+        # (PR 66) how held experts split over a mesh meet their rows: on one chip nothing crosses chips and the line has no such key
+        "moe_exchange": "the held experts are split over a mesh axis (tests/unit/test_exchanged_experts.py, tests/benchmarks/test_benchmark_kexaone.py)"}
 
 
 @pytest.mark.parametrize("part,name", [(0, name) for name in table.MIXERS] + [(1, name) for name in table.FFNS])

@@ -30,6 +30,7 @@ REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/i
            "ffn/cond", "branch/usual", "branch/every_pair", "ffn/experts", "head", "optimizer", "zero/gather", "zero/reduce",
            "zero/regather", "block"}
 REGIONS |= {"exit_gate", "loop_step"}  # PR 63: a looped stack's passes (the one body of the scan over them) and its gate
+REGIONS |= {"ffn/exchange"}  # PR 66: how a routed layer's held experts meet their rows on a mesh: the rows' two exchanges, or the parts' sum
 HEAVY = ("dot", "convolution", "custom-call")
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute", "all-to-all")
 

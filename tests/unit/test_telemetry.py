@@ -831,7 +831,8 @@ _METRIC_PREFIXES = ("train_", "comm_", "infer_", "kv_", "sched_", "spec_",
 # also match the `profile_captures` knob-default directory name in docs
 _EXTRA_METRICS = {"last_step_completed_unix", "tp_degree", "sparse_keys_chosen_total", "sparse_keys_visible_total", "sparse_index_loss",
                   "moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min",
-                  "moe_fallback_layers_total", "moe_buffer_rung_layers_total", "moe_rows_over_uniform_max", "diffusion_masked_positions_total", "diffusion_positions_total", "diffusion_weight_sum",
+                  "moe_fallback_layers_total", "moe_buffer_rung_layers_total", "moe_rows_over_uniform_max", "moe_rows_sent_total", "moe_chip_rows_max", "moe_chip_rows_min",
+                  "diffusion_masked_positions_total", "diffusion_positions_total", "diffusion_weight_sum",
                   "profile_captures_total", "profile_captures_dropped_total",
                   "profile_collective_exposed_fraction",
                   "profile_device_busy_fraction",
