@@ -241,10 +241,11 @@ def _rows_of(tokens, tok_of_row, row_ok, pos, take, spans):
     """The sorted rows' tokens: ``tokens[tok_of_row]``, zero past the routed
     rows. Its backward is no scatter-add (XLA's transpose of a gather, which
     the chip runs row by row): each token sums the rows of its own pairs that
-    were taken. With ``spans`` (``held_experts``) that sum is read off the
-    sorted buffer a token tile at a time (``_sum_rows``); without, every
-    pair's row is gathered by ``pos`` into (N, k, d), masked by ``take`` and
-    summed over k."""
+    were taken. With ``spans`` (``held_experts``' packed buffer, or the slabs
+    of ``exchanged_experts``, a group every ``slab`` rows:
+    ``moe_sum_rows.spans``) that sum is read off the sorted buffer a token
+    tile at a time (``_sum_rows``); without, every pair's row is gathered by
+    ``pos`` into (N, k, d), masked by ``take`` and summed over k."""
     return jnp.where(row_ok, tokens[tok_of_row], 0)
 
 
@@ -266,14 +267,14 @@ _rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
 @jax.custom_vjp
 def _back_to_tokens(ys, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans):
     """Each token's weighted rows: ``sum_j weights[n, j] ys[pos[n, j]]`` over
-    the pairs that were taken. With ``spans`` the same sum is read off the
-    sorted buffer a token tile at a time, each row times its own pair's
-    weight (in the rows' type, as the backward below applies it: the product
-    is exact in float32) and summed in float32 (``_sum_rows``); without,
-    every pair's row is gathered into (N, k, d), weighted and summed in the
-    rows' type. Backward, by gathers: a row's gradient is its own pair's
-    weight times its token's, a weight's the product of its row with its
-    token's gradient."""
+    the pairs that were taken. With ``spans`` (either layout, as ``_rows_of``)
+    the same sum is read off the sorted buffer a token tile at a time, each
+    row times its own pair's weight (in the rows' type, as the backward below
+    applies it: the product is exact in float32) and summed in float32
+    (``_sum_rows``); without, every pair's row is gathered into (N, k, d),
+    weighted and summed in the rows' type. Backward, by gathers: a row's
+    gradient is its own pair's weight times its token's, a weight's the
+    product of its row with its token's gradient."""
     if spans is not None:
         return _sum_rows(ys, tok_of_row, weights.reshape(-1)[pair_of_row], spans, pos.shape[0])
     picked = ys[jnp.minimum(pos, ys.shape[0] - 1)]  # (N, k, d)
@@ -389,7 +390,14 @@ def exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel
     (``wg, wi, wo`` are its own ``n``) and has its own ``tokens`` (N, d), routed by ``idx`` / ``weights`` over ALL experts.
 
     A chip sorts its pairs by the chip that holds the expert (stable: a chip's rows keep their tokens' order) into a
-    buffer of ``slab`` slots a chip, (chips, slab, d); ``lax.all_to_all`` hands slab ``c`` to chip ``c`` with each row's
+    buffer of ``slab`` slots a chip, (chips, slab, d): group ``c`` begins at ``c * slab`` whatever the others hold, inside
+    it the rows' tokens ascend (not strictly: a token may send one chip ``min(k, n)`` rows, side by side), and the slots
+    past a chip's rows are empty. So a tile of consecutive tokens has, a destination chip, ONE contiguous span of rows, as
+    it has an expert in ``held_experts``' packed buffer, and on the kernel's path, where the shapes fit it, a token's sum
+    over its own rows of the slabs (the combine, and the backward of the rows' gather) is read off those spans a tile at
+    a time by the same kernel (``ops/pallas/moe_sum_rows.py``: the groups are the chips; ``spans`` is told the slab);
+    elsewhere every pair's slot is gathered into (N, k, d), 15 of 16 of them for absent experts where a host holds a
+    sixteenth of them. ``lax.all_to_all`` hands slab ``c`` to chip ``c`` with each row's
     expert; ``held_experts`` runs there on what arrived, one pair a row with weight one, in a buffer of ``rows`` rows
     that the arrivals of all the chips are packed into (its sort puts the empty slots last); the results return by the
     reverse exchange into the slots they left from, and each token sums its own, by weight. The backward is the same two
@@ -404,11 +412,14 @@ def exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel
     buffer that holds every slot. Rows that stay on their chip pass through the same buffer and are no traffic.
     Returns (the tokens' sums, the rows that arrived here, those of a chip's pairs for held experts that the slab or the
     buffer here did not hold, the largest and smallest group here, the rows this chip sent to ANOTHER chip)."""
+    from ..ops.pallas import moe_sum_rows
+
     N, k = idx.shape
     n, d = wo.shape[0], tokens.shape[1]
     chips = jax.lax.axis_size(axis)
     slab = rows // chips if slab is None else slab
-    keep = lambda x: checkpoint_name(x, SAVED) if named else x
+    keep = lambda x: checkpoint_name(x, SAVED) if named and x is not None else x
+    tiled = kernel and moe_sum_rows.fits(N, chips * slab, d, chips, tokens.dtype)  # as ``held_experts``: the groups are the chips, the buffer the slabs
     with region("ffn/router", path="compare_sum"):
         me = jax.lax.axis_index(axis)
         dest, counts = _rows_to_each(idx, first, n, axis)
@@ -425,9 +436,10 @@ def exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel
         # a row's expert among its chip's own, ``n`` for an empty slot: it travels with the row
         expert_of_row = jnp.where(row_ok[:, 0], (idx.reshape(-1)[pair_of_row] - first) % n, n).astype(jnp.int32)
         sent = jnp.sum(jnp.where(jnp.arange(chips) == me, 0, jnp.minimum(counts, slab)))
-        pos, take, pair_of_row, tok_of_row, row_ok = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok))
-    with region("ffn/rows", path="xla"):
-        xs = _rows_of(tokens, tok_of_row, row_ok, pos, take, None)  # (chips * slab, d)
+        spans = moe_sum_rows.spans(dest, chips, k, chips * slab, slab) if tiled else None
+        pos, take, pair_of_row, tok_of_row, row_ok, spans = (keep(x) for x in (pos, take, pair_of_row, tok_of_row, row_ok, spans))
+    with region("ffn/rows", path="kernel" if tiled else "xla"):  # how a token sums its rows of the slabs, counted as ``held_experts`` counts its own
+        xs = _rows_of(tokens, tok_of_row, row_ok, pos, take, spans)  # (chips * slab, d)
     swap = lambda x: jax.lax.all_to_all(x.reshape(chips, slab, *x.shape[1:]), axis, 0, 0).reshape(x.shape)
     with region("ffn/exchange", path="dispatch", form="rows"):
         arrived, expert_arrived = swap(xs), swap(expert_of_row)
@@ -437,7 +449,7 @@ def exchanged_experts(tokens, idx, weights, wg, wi, wo, first, rows: int, kernel
     with region("ffn/exchange", path="return", form="rows"):
         back = swap(ys)
     with region("ffn/rows"):
-        out = _back_to_tokens(back, weights, tok_of_row, pair_of_row, row_ok, pos, take, None)  # (an empty slot comes back zero: no pair of the other chip's took it)
+        out = _back_to_tokens(back, weights, tok_of_row, pair_of_row, row_ok, pos, take, spans)  # (an empty slot comes back zero: no pair of the other chip's took it)
     with region("ffn/router"):
         return out, routed, jnp.sum(counts) - jnp.sum(take) + unheld, largest, smallest, sent
 
@@ -504,6 +516,15 @@ def _in_chunks(N: int, most: int, rows: int, chips: int):
     return rows, next(c for c in range(1, N + 1) if N % c == 0 and N // c * most <= rows // chips)
 
 
+def _stacked(chunk):
+    """A chunk's results on their way into the loop's stacked outputs, behind a barrier. A chunk's sums come straight out
+    of ``moe_sum_rows``; XLA:TPU fuses a custom call whose result is written into a slice of a loop's buffer with that
+    write, and the fusion runs under the default 16 MiB of scoped VMEM whatever limit the kernel asked for: at four
+    groups of 6,144 (18 MiB of windows and accumulator) the step did not compile for the described chips (``PERF.md``
+    section 6, PR 67). Behind the barrier the kernel stands alone, with its own limit, and the write is a copy."""
+    return jax.lax.optimization_barrier(chunk)
+
+
 @functools.partial(jax.jit, static_argnames=("rows", "kernel", "act", "axis"))
 def _unkept(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act, axis=None):
     """``held_experts`` (``exchanged_experts`` over ``axis``) with nothing named for a checkpoint policy. ``rows`` a pair
@@ -512,7 +533,7 @@ def _unkept(tokens, idx, weights, wg, wi, wo, first, rows, kernel, act, axis=Non
     if not isinstance(rows, tuple):
         return one(tokens, idx, weights, wg, wi, wo, first, rows)
     split = lambda x: x.reshape(rows[1], x.shape[0] // rows[1], *x.shape[1:])
-    out, routed, dropped, largest, smallest, *sent = jax.lax.map(lambda c: one(*c, wg, wi, wo, first, rows[0]), (split(tokens), split(idx), split(weights)))
+    out, routed, dropped, largest, smallest, *sent = jax.lax.map(lambda c: _stacked(one(*c, wg, wi, wo, first, rows[0])), (split(tokens), split(idx), split(weights)))
     return out.reshape(tokens.shape), jnp.sum(routed), jnp.sum(dropped), jnp.max(largest), jnp.min(smallest), *(jnp.sum(x) for x in sent)
 
 
@@ -529,7 +550,7 @@ def _unkept_back(tokens, idx, weights, wg, wi, wo, first, cotangent, rows, kerne
     mats = tuple(m for m in (wg, wi, wo) if m is not None)
 
     def chunk(sums, c):
-        d_t, d_w, *d_mats = back(*c)
+        d_t, d_w, *d_mats = _stacked(back(*c))
         return tuple(a + d.astype(a.dtype) for a, d in zip(sums, (d for d in d_mats if d is not None))), (d_t, d_w)
 
     sums, (d_tokens, d_weights) = jax.lax.scan(chunk, tuple(jnp.zeros(m.shape, jnp.float32) for m in mats), tuple(split(x) for x in (tokens, idx, weights, cotangent)))

@@ -1,4 +1,4 @@
-"""Each token's sum of its own rows in a buffer sorted by expert.
+"""Each token's sum of its own rows in a buffer sorted by group: by held expert, or by the chip a row travels to.
 
 A routed FFN (``moe/sharded_moe.py::held_experts``) sorts its (token, choice)
 pairs by expert with a stable sort, and a pair's index is ``token * k +
@@ -9,7 +9,20 @@ of at most ``TOKENS`` rows and a few tens in the usual case. ``out[t] = sum
 over the rows r of token t of w[r] * rows[r]`` is then, a token tile, one
 short read an expert: no (tokens, k, d) tensor, and no scatter.
 
-A grid step is a token tile. It copies, for each of the ``n`` held experts, the
+Where the rows are exchanged between chips (``exchanged_experts``) the sender
+sorts its pairs the same way by the CHIP that holds the expert, and the
+groups are the chips: inside a chip's rows the tokens ascend, not strictly (a
+token may send one chip as many rows as it has experts there, side by side:
+two weights in its row of the placing matrix), and a tile's rows are again one
+span, of ``TOKENS`` times that many rows at most. Two layouts, which differ in
+where a group begins and in nothing the kernel reads but ``spans``: PACKED
+(``held_experts``), a group begins where the ones before it end and the rows
+past the last group are not defined; PADDED (the exchange's slabs), group
+``c`` begins at ``c * slab`` and is cut at ``(c + 1) * slab``, and every slot
+holds numbers, an empty one too (what came back for it, or its cotangent, is
+the other chip's sum over no row: zeros).
+
+A grid step is a token tile. It copies, for each of the ``n`` groups (held experts, or chips), the
 ``ROWS`` rows (``window_rows(n)``: 128 up to eight held experts, fewer beyond, so
 that the placing product stays (TOKENS, 1024) x (1024, d) and the windows of
 32 experts take the VMEM that those of 8 do; a tile of 256 tokens sends an
@@ -64,16 +77,25 @@ def fits(n_tokens: int, rows: int, d: int, n: int, dtype) -> bool:
             and _vmem_bytes(n, d, jnp.dtype(dtype).itemsize) <= vmem_budget())
 
 
-def spans(key, n: int, k: int, rows: int):
-    """For pairs (tokens * k,) keyed by held expert (``n`` for one not held
-    here), in token order: where in the sorted buffer the rows of each (token
-    tile, expert) begin and end, (2, tiles * n) int32, cut at the buffer's
-    ``rows``. A group begins where the ones before it end; inside it a tile's
-    rows come after those of the tiles before."""
+def spans(key, n: int, k: int, rows: int, slab: int = None):
+    """For pairs (tokens * k,) keyed by group (a held expert, or the chip a
+    pair travels to; ``n`` for a pair of no group), in token order: where in
+    the sorted buffer the rows of each (token tile, group) begin and end,
+    (2, tiles * n) int32. Inside a group a tile's rows come after those of
+    the tiles before. ``slab`` None, the packed buffer (``held_experts``): a
+    group begins where the ones before it end, and the spans are cut at the
+    buffer's ``rows``. ``slab``, the padded one (``exchanged_experts``,
+    ``rows == n * slab``): group ``c`` begins at ``c * slab`` whatever the
+    others hold and is cut at ``(c + 1) * slab``, where the next begins."""
     count = jnp.sum(key.reshape(-1, TOKENS * k, 1) == jnp.arange(n), axis=1, dtype=jnp.int32)  # (tiles, n)
-    sizes = jnp.sum(count, axis=0)
-    lo = (jnp.cumsum(sizes) - sizes)[None] + jnp.cumsum(count, axis=0) - count
-    return jnp.minimum(jnp.stack([lo, lo + count]).reshape(2, -1), rows)
+    if slab is None:
+        sizes = jnp.sum(count, axis=0)
+        begins, ends = jnp.cumsum(sizes) - sizes, rows
+    else:
+        begins = slab * jnp.arange(n, dtype=jnp.int32)
+        ends = jnp.tile(begins + slab, count.shape[0])  # (tiles * n,): each span's own group's
+    lo = begins[None] + jnp.cumsum(count, axis=0) - count
+    return jnp.minimum(jnp.stack([lo, lo + count]).reshape(2, -1), ends)
 
 
 def _along_lanes(tok_of_row, w_row):
@@ -97,7 +119,9 @@ def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, se
     R, ROWS = rows_hbm.shape[0], window_rows(n)
     i = pl.program_id(0)
     half = jax.lax.rem(i, 2)  # which set of windows this tile's were copied into
-    routed = hi_ref[tiles * n - 1]  # the last span's end: past it no row of the buffer is defined
+    # the last span's end (the last group's rows end it in either layout): no span reaches past it, and past it a
+    # PACKED buffer is not defined (a PADDED one is, and the slots zeroed there are empty ones)
+    routed = hi_ref[tiles * n - 1]
 
     def span(tile, e):
         lo, hi = lo_ref[tile * n + e], hi_ref[tile * n + e]
@@ -126,7 +150,7 @@ def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, se
             copy.start()
 
     def arrived(first, at):
-        """Wait for the window; past the routed rows the buffer is not defined
+        """Wait for the window; past the routed rows a packed buffer is not defined
         (a grouped product writes its groups' rows only): zeros, not 0 x NaN."""
         for copy in copies(first, at):
             copy.wait()
@@ -178,9 +202,10 @@ def _kernel(lo_ref, hi_ref, rows_hbm, lanes_hbm, inside_ref, out_ref, xs, tw, se
 @functools.partial(jax.jit, static_argnames=("n_tokens", "interpret"))  # a step calls it a dozen times at two shapes: traced and lowered once a shape
 def sum_rows(rows, tok_of_row, w_row, spans, n_tokens: int, interpret: bool = False):
     """``out[t] = sum of w_row[r] * rows[r]`` over the rows r of token t inside
-    the spans (``spans`` above; ``fits`` holds): rows (R, d) sorted by expert,
-    ``tok_of_row`` (R,) int32, ``w_row`` (R,) float32 -> (n_tokens, d) in the
-    rows' type, summed in float32."""
+    the spans (``spans`` above; ``fits`` holds): rows (R, d) sorted by group
+    in either layout, ``tok_of_row`` (R,) int32, ``w_row`` (R,) float32 (both
+    finite in every slot, whatever an empty one holds) -> (n_tokens, d) in
+    the rows' type, summed in float32."""
     R, d = rows.shape
     tiles = n_tokens // TOKENS
     n = spans.shape[1] // tiles

@@ -364,6 +364,8 @@ CASES = {
     "flash_gqa_b1_s8192_h32_kvh2_d128": lambda: _flash((1, 8192, 32, 2, 128)),  # ... its one attention layer: SIXTEEN query heads a key head
     "moe_sum_rows_t8192_d2688_e8_r6144": lambda: _moe_sum_rows((8192, 2688, 8, 6144)),  # ... its routed layers' first rung: 384 rows an expert, twice over
     "moe_sum_rows_t8192_d2688_e8_r49152": lambda: _moe_sum_rows((8192, 2688, 8, 49152)),  # ... and every pair
+    "moe_sum_rows_t8192_d6144_e4_r16384": lambda: _moe_sum_rows((8192, 6144, 4, 16384)),  # k-exaone-236b-l5e8's SENDER: the groups are the four chips, a slab of 4,096 slots each
+    "moe_sum_rows_t1024_d6144_e4_r8192": lambda: _moe_sum_rows((1024, 6144, 4, 8192)),    # ... and its last rung's chunk of 1,024 tokens through slabs of 2,048
     "gmm_r6144_e8_d2688_f1856_rows256": lambda: _grouped_products((6144, 8, 2688, 1856), ((256, 896, 1024), (256, 1024, 896))),  # ... its two grouped products: 1,856 = 29 x 64 in two tiles of 1,024, the second part empty
     "conv_silu_b1_s8192_c10240_at0_5120_k4": lambda: _conv_silu((1, 8192, 10240, 4), 0, (5120,)),  # phi4-mini-flash-l6's two scan layers: u from [u, z]
     "fused_adam_wte_50257x768": lambda: _fused_adam((50257, 768)),

@@ -43,10 +43,10 @@ def _no_mesh_left_behind():
     reset_mesh()
 
 
-def _layer(router, shared=16):
+def _layer(router, shared=16, hidden=32):
     E, held, bias, rung, lean = ROUTERS[router]
-    layer = RoutedMoE(hidden_size=32, num_experts=E, k=2, d_ff=16, held=held, shared_ff=shared, scale=2.5, dtype=f32)
-    h = jax.random.normal(jax.random.PRNGKey(6), (4, 2048, 32))
+    layer = RoutedMoE(hidden_size=hidden, num_experts=E, k=2, d_ff=16, held=held, shared_ff=shared, scale=2.5, dtype=f32)
+    h = jax.random.normal(jax.random.PRNGKey(6), (4, 2048, hidden))
     params = layer.init(jax.random.PRNGKey(7), h)["params"]
     if lean is not None:  # along the router's column of that expert: the chip's every token scores it highest
         chip, expert, strength = lean
@@ -59,21 +59,27 @@ def _layer(router, shared=16):
 
 
 def _value_grads_rows(layer, params, h, mesh=None):
-    value = lambda p, x: jnp.sum(layer.apply({"params": p}, x, mutable=["intermediates"])[0] ** 2)
-    rows = lambda p, x: layer.apply({"params": p}, x, mutable=["intermediates"])[1]["intermediates"]["rows"][0]
+    def value_and_rows(p, x):  # one program: the sown counts are the differentiated call's own
+        out, sown = layer.apply({"params": p}, x, mutable=["intermediates"])
+        return jnp.sum(out ** 2), sown["intermediates"]["rows"][0]
+
+    both = jax.value_and_grad(value_and_rows, argnums=(0, 1), has_aux=True)
     with jax.default_matmul_precision("highest"):
         if mesh is None:
-            return (*jax.value_and_grad(value, argnums=(0, 1))(params, h), rows(params, h))
+            (value, rows), grads = both(params, h)
+            return value, grads, rows
         topo = initialize_mesh(MeshConfig.from_dict(mesh), devices=jax.devices()[:4], force=True)
         with topo.mesh:
-            out = (*jax.jit(jax.value_and_grad(value, argnums=(0, 1)))(params, h), jax.jit(rows)(params, h))
+            (value, rows), grads = jax.jit(both)(params, h)
         reset_mesh()
-        return out
+        return value, grads, rows
 
 
-@pytest.mark.parametrize("router,mesh", [("uniform", "fsdp4"), ("uniform", "expert2_fsdp2"), ("one_chip_most_rows_one_none", "fsdp4"),
-                                         ("every_pair_to_one_chip", "fsdp4"), ("a_chip_receives_over_its_buffer", "fsdp4"), ("one_pair_past_twice_uniform", "fsdp4"),
-                                         ("one_chip_most_rows_one_none", "expert2_fsdp2")])
+ROUTINGS = [("uniform", "fsdp4"), ("uniform", "expert2_fsdp2"), ("one_chip_most_rows_one_none", "fsdp4"), ("every_pair_to_one_chip", "fsdp4"),
+            ("a_chip_receives_over_its_buffer", "fsdp4"), ("one_pair_past_twice_uniform", "fsdp4"), ("one_chip_most_rows_one_none", "expert2_fsdp2")]
+
+
+@pytest.mark.parametrize("router,mesh", ROUTINGS)
 def test_the_exchange_gives_one_chips_output_and_gradients_and_the_sum_forms(router, mesh):
     """The layer on four virtual devices, its rows exchanged over ``fsdp`` (and its parts summed over ``expert`` where the
     mesh has both), against the layer on one device and against the sum form on ``expert=4``: the value, every leaf's
@@ -99,6 +105,29 @@ def test_the_exchange_gives_one_chips_output_and_gradients_and_the_sum_forms(rou
         assert 1024 + 3 * 500 < most <= 4096
     elif router != "uniform" and mesh == "fsdp4":  # chip 0 computed most rows, and where every pair is its own some chip none
         assert most > 2 * routed / 4 and (least == 0) == (router == "every_pair_to_one_chip")
+
+
+@pytest.mark.parametrize("router,mesh", ROUTINGS)
+def test_the_senders_sums_off_the_slabs_are_the_gathered_ones(router, mesh, monkeypatch):
+    """The same seven routings at a width of whole lanes (128), the rule's word steered to ``kernel`` (its kernels
+    interpreted): a token's sum over its own rows of the slabs, the combine and the backward of the rows' gather, read a
+    token tile at a time (``ops/pallas/moe_sum_rows.py``, a group every ``slab`` rows) against the (tokens, k, d) gathers,
+    on the sender's side and on the receiver's: the value, every leaf's gradient and the input's as close as the exchange
+    is to one chip, the counts bit for bit, and the rung each routing takes the one it takes by the gathers (the chunked
+    last rung, 512 tokens a chunk through the first rung's slabs, runs the same function and so the same kernel). The
+    series ``ffn/rows`` says which was traced: the sender's call and the receiver's ``held_experts`` both ``kernel``."""
+    layer, params, h, rung = _layer(router, hidden=128)
+    want, (g_params, g_h), rows_gathered = _value_grads_rows(layer, params, h, MESHES[mesh])
+    traced = lambda: {p: get_registry().peek("program_regions_traced_total", region="ffn/rows", path=p) or 0 for p in ("kernel", "xla")}
+    monkeypatch.setattr(placement, "kernel_path", lambda *a, **k: "kernel")
+    before = traced()
+    got, (k_params, k_h), rows = _value_grads_rows(layer, params, h, MESHES[mesh])
+    assert traced()["kernel"] > before["kernel"] and traced()["xla"] == before["xla"]
+    close = lambda a, b: float(jnp.max(jnp.abs(a - b))) <= 2e-6 * float(jnp.max(jnp.abs(b))) + 1e-12
+    assert close(got, want) and close(k_h, g_h)
+    for a, b in zip(jax.tree_util.tree_leaves(k_params), jax.tree_util.tree_leaves(g_params)):
+        assert close(a, b)
+    assert [int(x) for x in rows] == [int(x) for x in rows_gathered] and int(rows[1]) == 0 and (mesh != "fsdp4" or int(rows[4]) == rung)
 
 
 def test_the_last_rung_walks_token_chunks_through_the_first_rungs_slab():

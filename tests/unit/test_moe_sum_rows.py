@@ -1,12 +1,13 @@
 """A routed layer's tokens sum only the rows routed here (``ops/pallas/moe_sum_rows.py``): the kernel, interpreted on the
-CPU, against the gather form it replaces, through ``held_experts`` (forward value and every gradient)."""
+CPU, against the gather form it replaces, through ``held_experts`` (forward value and every gradient), and over an
+exchange's slabs (a group every ``slab`` rows: ``exchanged_experts``' sender), laid out by hand and through the call."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.moe.sharded_moe import SAVED, buffer_rungs, held_experts, routed_part
+from deepspeed_tpu.moe.sharded_moe import SAVED, buffer_rungs, exchanged_experts, held_experts, routed_part
 from deepspeed_tpu.ops.pallas import moe_sum_rows
 from deepspeed_tpu.telemetry.registry import get_registry
 
@@ -78,13 +79,46 @@ def _same(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5 * float(jnp.max(jnp.abs(b)) + 1e-6))
 
 
+CHIPS, PER, SLAB = 3, 2, 384  # an exchange's sender by hand: experts 2..7 lie two a chip on three chips, a slab of 384 slots each
+
+
+def _slabs(idx, k):
+    """What ``exchanged_experts`` lays out before it sends: the pairs sorted by the chip that holds the expert (stable),
+    chip ``c``'s from slot ``c * SLAB`` on and cut at its slab. Every slot holds numbers, its token's index too, the
+    empty ones whatever they like (here: random rows, weights and tokens of the tiles the kernel walks).
+    Returns (each pair's chip, the rows, their tokens, their weights, which slots are filled, every token's sum)."""
+    rng = np.random.default_rng(k)
+    local = np.asarray(idx) - FIRST
+    dest = np.where((local >= 0) & (local < CHIPS * PER), local // PER, CHIPS).reshape(-1).astype(np.int32)
+    order = np.argsort(dest, kind="stable")
+    rows, w_row = rng.standard_normal((CHIPS * SLAB, D)).astype(np.float32), rng.uniform(size=CHIPS * SLAB).astype(np.float32)
+    tok_of_row, filled = rng.integers(0, N, CHIPS * SLAB).astype(np.int32), np.zeros(CHIPS * SLAB, bool)
+    for c in range(CHIPS):
+        before, count = int(np.sum(dest < c)), int(np.sum(dest == c))
+        kept = slice(c * SLAB, c * SLAB + min(count, SLAB))
+        tok_of_row[kept], filled[kept] = order[before:before + min(count, SLAB)] // k, True
+    want = np.zeros((N, D), np.float32)
+    np.add.at(want, tok_of_row[filled], w_row[filled, None] * rows[filled])
+    return dest, rows, tok_of_row, w_row, filled, want
+
+
 @pytest.mark.parametrize("k", [6, 8])
-@pytest.mark.parametrize("rows", ["usual", "every"])
+@pytest.mark.parametrize("rows", ["usual", "every", "slabs"])
 @pytest.mark.parametrize("routing", list(ROUTINGS))
 def test_the_tiled_sum_is_the_gathered_sum(routing, rows, k):
     """Forward value and the gradients to tokens, weights and the three expert matrices, the kernel's path against the
-    gathers', at a buffer that just holds the usual load and at the one that holds every pair (the fallback branch's)."""
+    gathers', at a buffer that just holds the usual load and at the one that holds every pair (the fallback branch's).
+    ``slabs``: the kernel over groups that begin at multiples of a slab (``_slabs``) against each token's sum of its
+    filled slots. The four routings there: three chips near their slab, some cut at it (``uniform``); the first chip's
+    512 rows cut at 384, a tile's 256 past its first window of 128, and two rows a token for the third chip, cut too
+    (``one_held_expert``, ``none_here``, whose experts 6 and 7 the third chip holds); 586 rows cut at 384 with every
+    seventh token twice in the first chip's, ONE row in the second's and an empty third (``unequal``)."""
     idx = ROUTINGS[routing](k).astype(jnp.int32)
+    if rows == "slabs":
+        dest, slots, tok_of_row, w_row, filled, want = _slabs(idx, k)
+        assert (routing != "unequal" or not filled[2 * SLAB:].any()) and (routing == "unequal" or filled[2 * SLAB:].sum() > 256)
+        spans = moe_sum_rows.spans(jnp.asarray(dest), CHIPS, k, CHIPS * SLAB, SLAB)
+        return _same([moe_sum_rows.sum_rows(jnp.asarray(slots), jnp.asarray(tok_of_row), jnp.asarray(w_row), spans, N, interpret=True)], [want])
     rows = N * k if rows == "every" else N * 3  # 1,536 hold the 1,024 or so that 8 a token send to 4 of 16
     operands, cot = _operands(k)
     got, routed, dropped = _value_and_grads(idx, rows, True, operands, cot)
@@ -270,11 +304,23 @@ def _shapes(jaxpr, seen):
     return seen
 
 
-def test_the_kernels_path_builds_no_tokens_by_k_by_d_array():
-    """The usual branch, forward and backward: with the kernel no equation's result is (N, k, d); with the gathers two are."""
+@pytest.mark.parametrize("part", ["held_experts", "exchanged_experts"])
+def test_the_kernels_path_builds_no_tokens_by_k_by_d_array(part):
+    """The usual branch, forward and backward: with the kernel no equation's result is (N, k, d); with the gathers two are.
+    ``exchanged_experts``: each of four virtual devices its own N tokens, one held expert a chip and slabs of 256 slots
+    (the receiving side's ``held_experts`` has 1,024 tokens of ONE pair each: no shape of the sender's)."""
     k, idx = 6, _uniform(6).astype(jnp.int32)
     operands, cot = _operands(k)
-    loss = lambda kernel: lambda *a: jnp.sum(held_experts(a[0], idx, *a[1:], FIRST, 1024, kernel)[0] * cot)
+    if part == "held_experts":
+        loss = lambda kernel: lambda *a: jnp.sum(held_experts(a[0], idx, *a[1:], FIRST, 1024, kernel)[0] * cot)
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("fsdp",))
+        own, whole = P(), P("fsdp")  # every chip the same tokens and its own expert of the four
+        each = lambda kernel: jax.shard_map(lambda t, w, *held: exchanged_experts(t, idx, w, *held, FIRST, 1024, kernel, slab=256)[0], mesh=mesh,
+                                            in_specs=(own, own, whole, whole, whole), out_specs=own, check_vma=False)
+        loss = lambda kernel: lambda *a: jnp.sum(each(kernel)(*a) * cot)
     shapes = {kernel: _shapes(jax.make_jaxpr(jax.grad(loss(kernel), argnums=(0, 1)))(*operands).jaxpr, set()) for kernel in (True, False)}
     assert (N, k, D) in shapes[False] and (N, k, D) not in shapes[True]
     assert (moe_sum_rows.TOKENS, D) in shapes[True]  # the kernel's own accumulator, inside its ``pallas_call``
@@ -306,6 +352,22 @@ def test_the_conditionals_form_is_counted_once_a_trace_and_has_its_word_for_the_
         jax.eval_shape(lambda *a: routed_part(a[0], ONE_HELD, *a[1:], FIRST, experts, False), *shapes)
         rose.append(tuple(now - was for now, was in zip(engine._paths_traced()["moe_cond"], before)))
     assert rose == [(1, 0), (1, 0), (0, 0)] and engine._PATH_WORDS["moe_cond"] == "fallback_keeps_nothing"
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_spans_of_slabs_are_where_each_tile_and_chip_lies_from_its_slabs_first_slot(routing):
+    """``spans`` told a slab: a (tile, chip)'s rows are the filled slots of that chip's slab whose tokens are the
+    tile's, one run, whatever the other chips hold; an empty chip's spans are empty AT its slab, a full one's end with it."""
+    k, idx = 6, ROUTINGS[routing](6)
+    dest, _, tok_of_row, _, filled, _ = _slabs(idx, k)
+    lo, hi = np.asarray(moe_sum_rows.spans(jnp.asarray(dest), CHIPS, k, CHIPS * SLAB, SLAB)).reshape(2, N // moe_sum_rows.TOKENS, CHIPS)
+    for tile in range(N // moe_sum_rows.TOKENS):
+        for c in range(CHIPS):
+            mine = [r for r in range(c * SLAB, (c + 1) * SLAB) if filled[r] and tok_of_row[r] // moe_sum_rows.TOKENS == tile]
+            assert hi[tile, c] - lo[tile, c] == len(mine) and c * SLAB <= lo[tile, c] <= hi[tile, c] <= (c + 1) * SLAB
+            assert not mine or (lo[tile, c], hi[tile, c]) == (mine[0], mine[-1] + 1)
+    if routing == "unequal":  # the first chip's 586 rows cut at its slab, the second's one row (the last token's), the third's none
+        assert (lo[:, 0].tolist(), hi[:, 0].tolist(), lo[1, 1], hi[1, 1], lo[:, 2].tolist(), hi[:, 2].tolist()) == ([0, 293], [293, 384], 384, 385, [768, 768], [768, 768])
 
 
 def test_spans_are_where_each_tile_and_expert_lies_in_the_sorted_buffer():
