@@ -8,6 +8,10 @@ Top-level API parity (reference ``deepspeed/__init__.py``):
 - ``zero.Init`` for sharded model construction
 """
 
+import time as _time
+
+_T0 = _time.perf_counter()  # ``import_seconds``, at the bottom: this file top to bottom, whatever it is the first to import
+
 from . import comm
 from .accelerator import get_accelerator
 from .comm import init_distributed  # reference deepspeed.init_distributed (deepspeed/__init__.py)
@@ -104,3 +108,8 @@ def __getattr__(name):
         mod, attr = _LAZY_NAMES[name]
         return getattr(importlib.import_module(mod), attr)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+from .telemetry.registry import get_registry as _get_registry  # noqa: E402
+
+_get_registry().gauge("import_seconds").set(_time.perf_counter() - _T0)

@@ -343,7 +343,7 @@ declare("DS_TPU_FLIGHT_PROFILE_MAX_MB", "64", "float",
         "the manifest) and only the parsed waterfall summary survives.",
         "telemetry/flight.py")
 declare("DS_TPU_PROFILE", "0", "str",
-        "A word: 0 (off), 1, or stall. "
+        "A word: 0 (off), 1, stall or setup. "
         "1 arms a one-shot device-timeline capture at engine construction: "
         "the next DS_TPU_PROFILE_QUANTA serving quanta, or training "
         "steps, are wrapped in a jax.profiler trace and parsed into a "
@@ -352,7 +352,9 @@ declare("DS_TPU_PROFILE", "0", "str",
         "its device time by region and phase. stall hunts for a stalled "
         "step: captures back to back, each dropped unread unless one of "
         "its quanta took 1.5 medians; the first that holds one is kept, "
-        "with a `stall` section in its summary, and the hunt ends.",
+        "with a `stall` section in its summary, and the hunt ends. setup "
+        "captures set-up as one quantum: from the trainer's construction "
+        "to the end of the first step that made no first call.",
         "telemetry/profiler.py")
 declare("DS_TPU_PROFILE_DIR", "profile_captures", "str",
         "Directory for device-timeline capture output (raw trace plus "

@@ -21,6 +21,8 @@ fp16 with overflow-skip (``stage_1_and_2.py:1995``).
 """
 
 import collections
+import contextlib
+import functools
 import json
 import os
 import time
@@ -177,9 +179,53 @@ def _batch_tokens(batch) -> int:
     return 0
 
 
+_NOTHING = contextlib.nullcontext()  # what ``_first`` hands a program that has had its first call
+
+
+def _rooted(init):
+    """An engine class's ``__init__`` under ONE ``init/engine`` span, whichever class of the family is built (a subclass's
+    constructor opens it and ``DeepSpeedEngine``'s, called from inside, finds it open): the three ``init/*`` spans are its
+    children, its self time is the rest of construction, and as it closes ``engine_init_seconds_total{part="rest"}`` rises
+    by that (``_init_part`` raises the other parts: the four sum to the span's seconds). Ahead of the span: the listeners of
+    the first calls' seconds, and the device profiler's arming (``DS_TPU_PROFILE=setup`` starts its capture here)."""
+
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs):
+        if getattr(self, "_init_parts_s", None) is not None:  # a subclass's constructor holds the root
+            return init(self, *args, **kwargs)
+        register_cache_metrics(jax)  # seconds of every first call, by phase (program_*_seconds_total)
+        device_profiler.maybe_arm_profiler()  # DS_TPU_PROFILE=1: the first steps that make no first call are captured
+        self._init_parts_s = 0.0
+        t0 = time.perf_counter()
+        with telemetry_span("init/engine"):
+            init(self, *args, **kwargs)
+        rest = time.perf_counter() - t0 - self._init_parts_s
+        get_telemetry_registry().counter("engine_init_seconds_total", part="rest").inc(rest)
+        self._init_parts_s = None
+
+    return __init__
+
+
 class DeepSpeedEngine:
     """Wraps a model (loss function + params) with distributed training state."""
 
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if "__init__" in cls.__dict__:
+            cls.__init__ = _rooted(cls.__dict__["__init__"])
+
+    @contextlib.contextmanager
+    def _init_part(self, part):
+        """The span ``init/<part>`` of construction; as it closes, ``engine_init_seconds_total{part}`` rises by its wall
+        seconds. Nothing here waits for the device: a part that only dispatches is timed as its dispatch."""
+        t0 = time.perf_counter()
+        with telemetry_span(f"init/{part}") as sp:
+            yield sp
+        took = time.perf_counter() - t0
+        self._init_parts_s += took
+        get_telemetry_registry().counter("engine_init_seconds_total", part=part).inc(took)
+
+    @_rooted
     def __init__(self,
                  args=None,
                  model=None,
@@ -193,9 +239,7 @@ class DeepSpeedEngine:
                  collate_fn=None,
                  config=None,
                  dont_change_device: bool = False):
-        register_cache_metrics(jax)  # seconds of every first call, by phase (program_*_seconds_total)
-        device_profiler.maybe_arm_profiler()  # DS_TPU_PROFILE=1: the first steps that make no first call are captured
-        with telemetry_span("init/mesh"):
+        with self._init_part("mesh"):
             if dist_init_required is None or dist_init_required:
                 dist.init_distributed(verbose=False)
 
@@ -222,88 +266,99 @@ class DeepSpeedEngine:
             raise TypeError("model must be callable (params, batch, rng) -> loss, or expose .loss_fn")
 
         # --- parameters (fp32 master, sharded per plan) ---
-        with telemetry_span("init/shard_state"):
+        # every program construction sends to the backend is inside a first call of family ``init`` (the init fn's, the
+        # casts', whatever a divided put lowers, the optimizer state's): the same span, seconds by phase and log line as a
+        # step program's, so that what reached the backend in NO span is the caller's alone
+        with self._init_part("shard_state") as sp:
             if model_parameters is None:
                 raise ValueError("model_parameters (the parameter pytree, or an init fn taking a PRNG key) is required")
             if callable(model_parameters) and not hasattr(model_parameters, "keys"):
                 # documented init-fn form, resolved HERE so every engine class
                 # (pipeline/hybrid subclasses included) honors it with the
                 # accelerator's configured seed
-                model_parameters = model_parameters(jax.random.PRNGKey(get_accelerator().initial_seed()))
+                with sp.phase("cast"), first_call("init", "model_init"):
+                    model_parameters = model_parameters(jax.random.PRNGKey(get_accelerator().initial_seed()))
             params_host = model_parameters
             tp_rules = model.partition_rules() if hasattr(model, "partition_rules") else []
             self._tp_rules = tp_rules
-            params_host = _cast_tree(params_host, jnp.float32)
-            param_shapes = jax.eval_shape(lambda: params_host)
-            self.param_specs = plan_param_specs(param_shapes, self.config, self.topology, tp_rules)
-            self.param_shardings = specs_to_shardings(self.param_specs, self.topology)
+            with sp.phase("cast"), first_call("init", "cast"):
+                params_host = _cast_tree(params_host, jnp.float32)
+            with sp.phase("plan"), first_call("init", "plan"):  # no program: the tree's abstract evaluation is a trace JAX times
+                param_shapes = jax.eval_shape(lambda: params_host)
+                self.param_specs = plan_param_specs(param_shapes, self.config, self.topology, tp_rules)
+                self.param_shardings = specs_to_shardings(self.param_specs, self.topology)
 
-            # ZeRO-3 parameter offload: large leaves stored in pinned host
-            # memory, streamed to HBM inside each compiled step (reference
-            # partitioned_param_swapper.py:36, wired at stage3.py:583)
-            from .zero.param_offload import maybe_enable_param_offload
-            from .zero.zeropp import zeropp_applicable as _zpp_applicable
+                # ZeRO-3 parameter offload: large leaves stored in pinned host
+                # memory, streamed to HBM inside each compiled step (reference
+                # partitioned_param_swapper.py:36, wired at stage3.py:583)
+                from .zero.param_offload import maybe_enable_param_offload
+                from .zero.zeropp import zeropp_applicable as _zpp_applicable
 
-            # gate on the path that will actually run: merely *requesting* ZeRO++
-            # on an ineligible topology falls back to GSPMD, where offload works
-            _zpp_active = (_zpp_applicable(self.config, self.topology)[0]
-                           and not self.config.compression_config)
-            if _zpp_active and self.config.zero_config.offload_param.device in ("cpu", "nvme"):
-                logger.warning("offload_param is incompatible with the ZeRO++ manual shard_map path — "
-                               "parameters stay in device memory")
-                self.param_store_shardings, self._param_offload = self.param_shardings, False
-            else:
-                self.param_store_shardings, self._param_offload = maybe_enable_param_offload(
-                    self.config, self.topology, self.param_shardings, param_shapes)
+                # gate on the path that will actually run: merely *requesting* ZeRO++
+                # on an ineligible topology falls back to GSPMD, where offload works
+                _zpp_active = (_zpp_applicable(self.config, self.topology)[0]
+                               and not self.config.compression_config)
+                if _zpp_active and self.config.zero_config.offload_param.device in ("cpu", "nvme"):
+                    logger.warning("offload_param is incompatible with the ZeRO++ manual shard_map path — "
+                                   "parameters stay in device memory")
+                    self.param_store_shardings, self._param_offload = self.param_shardings, False
+                else:
+                    self.param_store_shardings, self._param_offload = maybe_enable_param_offload(
+                        self.config, self.topology, self.param_shardings, param_shapes)
             # the tree handed in is the engine's from here on, as the reference's ZeRO-3 partitions a module's parameters in
             # place: where it is DIVIDED (a tree that lies on one chip, put over several), each leaf's whole copy is let go
             # as its shards stand, so the first chip never holds the tree beside its share of it, the moments and the carried
             # copy (``_put_divided``). On one chip the put is an alias and nothing is let go
-            self.params = _put_divided(params_host, self.param_store_shardings, release=self.config.zero_config.stage == 3)
+            with sp.phase("place"), first_call("init", "place"):
+                self.params = _put_divided(params_host, self.param_store_shardings, release=self.config.zero_config.stage == 3)
             del params_host
 
-            self.grad_specs = plan_grad_specs(param_shapes, self.param_specs, self.config, self.topology)
-            self.grad_shardings = specs_to_shardings(self.grad_specs, self.topology)
+            with sp.phase("plan"):  # the gradients' plan: the phase's seconds are the two stretches' sum
+                self.grad_specs = plan_grad_specs(param_shapes, self.param_specs, self.config, self.topology)
+                self.grad_shardings = specs_to_shardings(self.grad_specs, self.topology)
 
         # --- optimizer ---
-        with telemetry_span("init/optimizer"):
-            if optimizer is not None and not isinstance(optimizer, optax.GradientTransformation):
-                raise TypeError("client optimizer must be an optax.GradientTransformation")
-            self.optimizer = optimizer if optimizer is not None else create_optimizer(
-                self.config.optimizer.type, self.config.optimizer.params)
+        with self._init_part("optimizer") as sp:
+            with sp.phase("build"):  # the transformation; with the optimizer offloaded, the host's copy of the tree (a wait: `device_get`)
+                if optimizer is not None and not isinstance(optimizer, optax.GradientTransformation):
+                    raise TypeError("client optimizer must be an optax.GradientTransformation")
+                self.optimizer = optimizer if optimizer is not None else create_optimizer(
+                    self.config.optimizer.type, self.config.optimizer.params)
 
-            # ZeRO-Offload: optimizer states leave the device entirely
-            # (reference stage_1_and_2.py:1182-1277 cpu, stage3.py:1877 nvme)
-            self._host_offload = None
-            off = self.config.zero_config.offload_optimizer
-            if self.config.zero_enabled and off.device in ("cpu", "nvme"):
-                opt_name = (self.config.optimizer.type or "adamw").lower()
-                if optimizer is not None:
-                    logger.warning("offload_optimizer requires a config-defined adam-family optimizer; a client "
-                                   "optimizer object was passed — keeping optimizer states on device")
-                elif "adam" not in opt_name:
-                    logger.warning(f"offload_optimizer supports adam-family optimizers; got {opt_name} — "
-                                   "keeping optimizer states on device")
+                # ZeRO-Offload: optimizer states leave the device entirely
+                # (reference stage_1_and_2.py:1182-1277 cpu, stage3.py:1877 nvme)
+                self._host_offload = None
+                off = self.config.zero_config.offload_optimizer
+                if self.config.zero_enabled and off.device in ("cpu", "nvme"):
+                    opt_name = (self.config.optimizer.type or "adamw").lower()
+                    if optimizer is not None:
+                        logger.warning("offload_optimizer requires a config-defined adam-family optimizer; a client "
+                                       "optimizer object was passed — keeping optimizer states on device")
+                    elif "adam" not in opt_name:
+                        logger.warning(f"offload_optimizer supports adam-family optimizers; got {opt_name} — "
+                                       "keeping optimizer states on device")
+                    else:
+                        from .zero.offload import HostOffloadOptimizer
+
+                        off_p = self.config.zero_config.offload_param
+                        self._host_offload = HostOffloadOptimizer(jax.device_get(self.params),
+                                                                  self.config.optimizer.params, offload_device=off.device,
+                                                                  nvme_path=off.nvme_path,
+                                                                  aio_threads=self.config.aio.thread_count,
+                                                                  pipeline=off.pipeline_read or off.pipeline_write,
+                                                                  params_on_nvme=(off_p.device == "nvme"
+                                                                                  and bool(self._param_offload)),
+                                                                  params_nvme_path=off_p.nvme_path)
+            with sp.phase("init_state"):
+                if self._host_offload is None:
+                    with first_call("init", "optimizer_state"):  # the state's plan (an abstract ``init``: a trace) and its program
+                        opt_specs, _ = plan_opt_state_specs(self.optimizer, param_shapes, self.param_specs, self.config,
+                                                            self.topology)
+                        self.opt_state_shardings = specs_to_shardings(opt_specs, self.topology)
+                        self.opt_state = jax.jit(self.optimizer.init, out_shardings=self.opt_state_shardings)(self.params)
                 else:
-                    from .zero.offload import HostOffloadOptimizer
-
-                    off_p = self.config.zero_config.offload_param
-                    self._host_offload = HostOffloadOptimizer(jax.device_get(self.params),
-                                                              self.config.optimizer.params, offload_device=off.device,
-                                                              nvme_path=off.nvme_path,
-                                                              aio_threads=self.config.aio.thread_count,
-                                                              pipeline=off.pipeline_read or off.pipeline_write,
-                                                              params_on_nvme=(off_p.device == "nvme"
-                                                                              and bool(self._param_offload)),
-                                                              params_nvme_path=off_p.nvme_path)
-            if self._host_offload is None:
-                opt_specs, _ = plan_opt_state_specs(self.optimizer, param_shapes, self.param_specs, self.config,
-                                                    self.topology)
-                self.opt_state_shardings = specs_to_shardings(opt_specs, self.topology)
-                self.opt_state = jax.jit(self.optimizer.init, out_shardings=self.opt_state_shardings)(self.params)
-            else:
-                self.opt_state_shardings = None
-                self.opt_state = None
+                    self.opt_state_shardings = None
+                    self.opt_state = None
 
         # --- lr scheduler ---
         self.lr_scheduler = lr_scheduler
@@ -490,8 +545,30 @@ class DeepSpeedEngine:
         """What a step differentiates at and an evaluation runs on: the carried compute copy, cast from the master once
         when there is none yet; the master itself where no copy is carried (the program then casts it)."""
         if self._params_c is None and self._cast_copy is not None:
-            self._params_c = self._cast_copy(self._params)
+            with self._first("compute_copy", family="init"):
+                self._params_c = self._cast_copy(self._params)
         return self._params if self._params_c is None else self._params_c
+
+    def _first(self, name, family="train"):
+        """The first call of one of the engine's small programs beside ``_step_program``'s: a ``program/first_call`` span, so
+        that no program of the engine's reaches the backend outside one, and the step it falls in is passed by as one that
+        made a first call. Family ``train``: a step that is not fused (the accumulator's cast and add, the update) and an
+        evaluation. Family ``init``: what sets the engine's own state up once it is built (the first compute copy's cast in
+        the first forward, the overflow count's cast and sum in the first two steps): its wall seconds are
+        ``engine_init_seconds_total{part="after"}``, and the first line of family ``train`` stays the step's. Afterwards
+        nothing: one look into a set."""
+        if name in self._step_programs_seen:
+            return _NOTHING
+        self._step_programs_seen.add(name)
+        self._made_first_call = True
+        return first_call("train", name) if family == "train" else self._after(name)
+
+    @contextlib.contextmanager
+    def _after(self, name):
+        t0 = time.perf_counter()
+        with first_call("init", name):
+            yield
+        get_telemetry_registry().counter("engine_init_seconds_total", part="after").inc(time.perf_counter() - t0)
 
     # ------------------------------------------------------------------
     # compiled functions
@@ -834,7 +911,8 @@ class DeepSpeedEngine:
         copy_before = {label: regions_traced_by("optimizer", label) for label in ("path", "grads")}
         notes = {}
         with first_call("train", name, notes):
-            self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
+            with open_span("program/first_call").phase("flops_count"):
+                self._count_step_flops(program, args)  # the one Python trace of the model: jax.jit keeps it for the call
             # what share of the executed products is the second forward (``remat``): the FLOPs the gauge's walk counted,
             # by the phase each equation's name stack says
             notes.update({f"flops_{phase}": int(self._step_flops_by_phase.get(phase, 0)) for phase in PHASES}
@@ -937,10 +1015,14 @@ class DeepSpeedEngine:
             if self._cached_grads is _FUSED:
                 pass  # grads were consumed inside the fused forward dispatch
             elif self._grad_acc is None:
-                self._grad_acc = self._cached_grads if self._to_acc_dtype is None \
-                    else self._to_acc_dtype(self._cached_grads)
+                if self._to_acc_dtype is None:
+                    self._grad_acc = self._cached_grads
+                else:
+                    with self._first("to_acc_dtype"):
+                        self._grad_acc = self._to_acc_dtype(self._cached_grads)
             else:
-                self._grad_acc = self._accumulate(self._grad_acc, self._cached_grads)
+                with self._first("accumulate"):
+                    self._grad_acc = self._accumulate(self._grad_acc, self._cached_grads)
             self._cached_grads = None
             self.micro_steps += 1
             self.global_samples += self.train_micro_batch_size_per_gpu * self.topology.data_parallel_size
@@ -989,8 +1071,9 @@ class DeepSpeedEngine:
                             self.params = new_params
                     else:
                         self._compute_params()  # a copy dropped since the forward is made again: the update takes its buffer
-                        params, params_c, self.opt_state, gnorm, overflow = self._apply_updates(
-                            self.params, self._params_c, self.opt_state, self._grad_acc, inv_scale, lr)
+                        with self._first("apply_updates"):
+                            params, params_c, self.opt_state, gnorm, overflow = self._apply_updates(
+                                self.params, self._params_c, self.opt_state, self._grad_acc, inv_scale, lr)
                         self._install(params, params_c)
             self._grad_acc = None
             self._global_grad_norm = gnorm
@@ -1012,8 +1095,9 @@ class DeepSpeedEngine:
                 # per-step device->host readback (a scalar sync drains the whole
                 # queue of dispatched steps). The skip-on-overflow happens in-graph;
                 # the counter folds lazily (see skipped_steps property).
-                self._skipped_dev = overflow.astype(jnp.int32) if self._skipped_dev is None \
-                    else self._skipped_dev + overflow.astype(jnp.int32)
+                with self._first("overflow_count" if self._skipped_dev is None else "overflow_sum", family="init"):  # two eager programs: the cast, the add
+                    self._skipped_dev = overflow.astype(jnp.int32) if self._skipped_dev is None \
+                        else self._skipped_dev + overflow.astype(jnp.int32)
             self.global_steps += 1
             if self.random_ltd_scheduler is not None:
                 self.random_ltd_scheduler.update_seq(self.global_steps)
@@ -1169,8 +1253,10 @@ class DeepSpeedEngine:
         batch = self._put_batch(batch)
         # disjoint from the train-step folds, which use micro_steps directly
         # (fold_in data must be non-negative: it coerces to uint32)
-        rng = rng if rng is not None else jax.random.fold_in(self._rng, (1 << 30) + self.micro_steps)
-        return self._eval_loss(self._compute_params(), batch, rng)
+        params = self._compute_params()
+        with self._first(("eval_loss", leaf_signature(batch))):
+            rng = rng if rng is not None else jax.random.fold_in(self._rng, (1 << 30) + self.micro_steps)
+            return self._eval_loss(params, batch, rng)
 
     def zero_grad(self):
         if self._fused_pending is not None:

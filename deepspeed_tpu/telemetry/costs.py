@@ -42,7 +42,7 @@ from typing import Any, Dict, Iterable, Optional, Tuple
 from ..analysis import knobs
 from ..utils.logging import logger
 from .registry import get_registry
-from .tracing import current_span, span
+from .tracing import _NULL_SPAN, current_span, open_span, span
 
 __all__ = [
     "CostCard",
@@ -101,7 +101,9 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
     after) and one log line of the same. The line is what a run that is cut
     before ``dump_trace`` leaves behind, and what an operator greps for when
     a step recompiles in production. Yields a dict for phases of the
-    caller's own (``cost_card``). ``notes`` is the caller's too: what else
+    caller's own (``cost_card``); a part that code under it times by name
+    (``open_span("program/first_call").phase("flops_count")``, an annotation
+    too) is one more. ``notes`` is the caller's too: what else
     it knows of the program by the time the call is over (the trainer: what
     the step does with gradients); both end up on the span and the line.
     ``programs`` counts what reached the
@@ -109,7 +111,16 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
     block's Python body ran (``program_regions_traced_total{region="block"}``:
     one a kind of block and traced program, not one a layer). The regions
     traced inside add ``region_trace_s`` (``telemetry/tracing.py::region``:
-    Python seconds by region, on the span and in short on the line)."""
+    Python seconds by region, on the span and in short on the line).
+
+    As it closes it raises ``program_first_call_seconds_total{family, phase}``
+    by the seconds it puts on the span (``trace``, ``lower``, ``compile``,
+    ``cache_fetch``, ``other`` and the caller's own: ``flops_count``,
+    ``cost_card``), so that over the phases but ``cache_fetch`` a family's
+    series sum to its spans' ``total_s``, and the process-wide
+    ``program_*_seconds_total`` less every family's are the seconds of programs
+    that reached the backend in NO first-call span: the caller's. A first call
+    inside another's is part of the outer one's seconds and raises nothing."""
     import jax
 
     from ..utils.compile_cache import PHASE_COUNTERS, PHASES, block_traces, register_cache_metrics
@@ -117,6 +128,7 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
     register_cache_metrics(jax)
     reg = get_registry()
     up = current_span()
+    nested = open_span("program/first_call") is not _NULL_SPAN
     inherited = {k: up.attrs[k] for k in ("q", "steps") if k in up.attrs} if up is not None and up.attrs else {}
     counters = PHASE_COUNTERS + ("program_first_calls_total",)
     before = [reg.peek(c) or 0.0 for c in counters]
@@ -128,12 +140,16 @@ def first_call(family: str, bucket: Any, notes: Optional[Dict[str, Any]] = None)
         total = time.perf_counter() - t0
         *seconds, programs = [(reg.peek(c) or 0.0) - b for c, b in zip(counters, before)]
         phases.update(zip(PHASES, seconds))
+        phases.update((sp.attrs or {}).pop("phase_s", {}))  # parts the code under it timed by name (``sp.phase``: the trainer's ``flops_count``)
         # JAX's compile event spans the persistent-cache fetch: "other" is what no phase covers
         phases["other"] = total - sum(v for k, v in phases.items() if k != "cache_fetch")
         # programs: how many reached the backend in here (this one, and helper programs it called first)
         traced = block_traces() - traces_before
         sp.set(programs=int(programs), block_traces=traced, total_s=total,
                **{k + "_s": v for k, v in phases.items()}, **(notes or {}))
+        if not nested:
+            for phase, took in phases.items():
+                reg.counter("program_first_call_seconds_total", family=family, phase=phase).inc(took)
     if sp.attrs is not None:  # the tracer is on
         by_region = sorted(sp.attrs.get("region_trace_s", {}).items(), key=lambda kv: -kv[1])
         # the regions' Python seconds in all and the three dearest: a kernel that traces slowly at many sites shows here

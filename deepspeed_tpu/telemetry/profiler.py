@@ -21,6 +21,14 @@ with bounded structured capture windows:
   than ``health.STALL_X`` medians by the host's stamps; the first that holds
   one is kept, the hunt ends, and its summary gains ``stall``
   (``stall_section``: the stalled quantum's name, as far as a trace has one).
+- ``DS_TPU_PROFILE=setup`` captures SET-UP: the one-shot capture starts at the
+  trainer's construction, ahead of ``init/engine``, and stops at the end of
+  the first ``step()`` that made no first call, as ONE quantum. Construction's
+  spans and phases and every ``program/first_call`` are annotations, so
+  ``idle_by_span`` puts the first device's idle seconds of set-up down to them
+  or to ``between spans`` (the caller), and the summary gains ``setup``
+  (``module_executions``: each program's first execution beside its later
+  ones). It reads no regions and leaves the compile cache's key alone.
 - The trace is read from the ``.xplane.pb`` through
   ``jax.profiler.ProfileData`` (four chips make 240,000 device events a
   second and the Chrome JSON is an export of it that may be cut), with the
@@ -132,13 +140,15 @@ def idle_intervals(trace: Dict, at_least_ns: float = 0.0) -> List[Tuple[float, f
     return [(lo, hi) for lo, hi in _subtract([(busy[0][0], busy[-1][1])], busy) if hi - lo >= at_least_ns]
 
 
-def load_xplane(path: str) -> Dict:
+def load_xplane(path: str, runtime_over_idle: bool = True) -> Dict:
     """An ``.xplane.pb`` as plain data, ``{"planes": [{"name", "lines":
     [{"name", "events": [[name, start_ns, dur_ns, {stat: value}], ...]}]}]}``:
     every line of a device plane, and of the host's threads the events named
     as the program's spans are, and every other event (the runtime's own
     threads) that lies over an idle stretch of ``LONG_IDLE_S`` of the first
-    device: what a stall's name is read from, and nothing in a clean trace."""
+    device: what a stall's name is read from, and nothing in a clean trace
+    (``runtime_over_idle=False``: the spans alone; set-up is idle stretches
+    from end to end and the compiler's threads are not what it is read for)."""
     from jax.profiler import ProfileData
 
     planes, hosts = [], []
@@ -149,7 +159,7 @@ def load_xplane(path: str) -> Dict:
             planes.append({"name": plane.name, "lines": [line for line in lines if line["events"]]})
         elif plane.name.startswith("/host:"):
             hosts.append(plane)
-    idle = idle_intervals({"planes": planes}, LONG_IDLE_S * 1e9)
+    idle = idle_intervals({"planes": planes}, LONG_IDLE_S * 1e9) if runtime_over_idle else []
     for plane in hosts:
         lines = []
         for line in plane.lines:
@@ -653,6 +663,24 @@ def idle_by_span(trace: Dict, within: Optional[Tuple[float, float]] = None) -> D
     return {k: round(v, 9) for k, v in sorted(out.items(), key=lambda kv: -kv[1])}
 
 
+def module_executions(trace: Dict) -> List[list]:
+    """The first device's programs (``XLA Modules``) in the order they first
+    ran: [name, executions, when the first began (seconds from the device's
+    first program), the first execution's seconds, the median of the later
+    ones' or None]. A step whose first execution is long beside its later ones
+    paid something once on the device (a set-up capture's question)."""
+    runs: Dict[str, List[Tuple[float, float]]] = {}
+    for plane in _device_planes(trace)[:1]:
+        for line in plane["lines"]:
+            if line["name"] == MODULES_LINE:
+                for name, start, dur, *_ in sorted(line["events"], key=lambda ev: ev[1]):
+                    runs.setdefault(name, []).append((start, dur))
+    t0 = min((r[0][0] for r in runs.values()), default=0.0)
+    return [[name[:120], len(r), round((r[0][0] - t0) / 1e9, 6), round(r[0][1] / 1e9, 6),
+             round(statistics.median(d for _, d in r[1:]) / 1e9, 6) if len(r) > 1 else None]
+            for name, r in sorted(runs.items(), key=lambda kv: kv[1][0][0])]
+
+
 def stalled_quantum(periods: List[float]) -> Optional[Tuple[int, float]]:
     """(index, median) of the longest of ``periods`` that exceeds ``STALL_X``
     medians, the first and the last apart (a capture's own waits for the
@@ -739,14 +767,17 @@ class DeviceProfiler:
     called when a capture is reduced and not before. With ``hunt`` a capture
     that holds no stalled quantum is dropped unread and the profiler arms
     itself again; the first that holds one is kept and ends the hunt
-    (``hunted``: every capture's cost, kept or dropped)."""
+    (``hunted``: every capture's cost, kept or dropped). With ``setup`` the
+    capture is of set-up: ``begin()`` starts the trace where it is armed (the
+    trainer's construction) and the first quantum noted ends it."""
 
     def __init__(self, out_dir: Optional[str] = None,
-                 quanta: Optional[int] = None, hunt: bool = False):
+                 quanta: Optional[int] = None, hunt: bool = False, setup: bool = False):
         self.out_dir = str(out_dir
                            or knobs.get_str("DS_TPU_PROFILE_DIR", "")
                            or "profile_captures")
-        self.quanta_target = max(1, int(
+        self.setup = setup
+        self.quanta_target = 1 if setup else max(1, int(
             quanta if quanta is not None
             else knobs.get_int("DS_TPU_PROFILE_QUANTA")))
         self.state = "idle"
@@ -792,6 +823,12 @@ class DeviceProfiler:
             self._markers = []
             self.state = "armed"
         return True
+
+    def begin(self) -> None:
+        """Start an armed capture now, ahead of any quantum (a capture of set-up)."""
+        with self._lock:
+            if self.state == "armed":
+                self._begin_locked()
 
     def note_quantum(self, program: str, **attrs) -> None:
         """Dispatch-site hook, called at each quantum's readback boundary
@@ -901,7 +938,7 @@ class DeviceProfiler:
         path = find_xplane(self._trace_dir) if trace_state == "ok" and self._trace_dir else None
         if path is not None:
             try:
-                trace = load_xplane(path)
+                trace = load_xplane(path, runtime_over_idle=not self.setup)
                 parsed = parse_trace_events(trace)
                 self._markers_on_the_trace_clock(trace, parsed)
             except Exception:
@@ -919,7 +956,9 @@ class DeviceProfiler:
             if stalled is not None:
                 bounds = [parsed["t0_ns"] + m["rel_s"] * 1e9 for m in [{"rel_s": 0.0}] + self._markers]
                 summary["stall"] = stall_section(trace, bounds, *stalled)
-            if self._program_text is not None:
+            if self.setup:
+                summary["setup"] = {"programs": module_executions(trace)}
+            elif self._program_text is not None:
                 try:  # the text of the executable that ran, asked for now and not at set-up; kept beside the raw trace
                     text = self._program_text()
                     with open(os.path.join(self._trace_dir, "program.hlo.txt"), "w") as f:
@@ -1039,22 +1078,27 @@ def maybe_arm_profiler() -> Optional[DeviceProfiler]:
     read; set, it creates the singleton and arms the one-shot
     capture (only if it has never fired — a finished capture is not
     re-armed by the next engine build; ``request_capture`` re-arms).
-    ``DS_TPU_PROFILE=stall``: the hunt (``DeviceProfiler``)."""
+    ``DS_TPU_PROFILE=stall``: the hunt; ``setup``: the capture starts here,
+    at the trainer's construction (``DeviceProfiler``)."""
     global _PROFILER
-    hunt = (knobs.get_str("DS_TPU_PROFILE") or "").strip().lower() == "stall"
-    if not hunt and not knobs.get_bool("DS_TPU_PROFILE"):
+    word = (knobs.get_str("DS_TPU_PROFILE") or "").strip().lower()
+    hunt, setup = word == "stall", word == "setup"
+    if not hunt and not setup and not knobs.get_bool("DS_TPU_PROFILE"):
         return _PROFILER
     with _PROFILER_LOCK:
         if _PROFILER is None:
-            _PROFILER = DeviceProfiler(hunt=hunt)
-            # JAX leaves metadata out of the persistent compile cache's key, so a cache written by another tree can hand
-            # back an executable whose instructions carry other names or none; a process that is to read regions off its
-            # executables keys the cache on them (one compile the first time, a fetch after)
-            import jax
+            _PROFILER = DeviceProfiler(hunt=hunt, setup=setup)
+            if not setup:  # a capture of set-up reads no region, and must find the cache the run would have found
+                # JAX leaves metadata out of the persistent compile cache's key, so a cache written by another tree can hand
+                # back an executable whose instructions carry other names or none; a process that is to read regions off its
+                # executables keys the cache on them (one compile the first time, a fetch after)
+                import jax
 
-            jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+                jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if _PROFILER.captures == 0 and _PROFILER.state == "idle":
         _PROFILER.arm()
+        if _PROFILER.setup:
+            _PROFILER.begin()
     return _PROFILER
 
 

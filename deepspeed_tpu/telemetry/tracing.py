@@ -99,7 +99,7 @@ class _Phase:
 
 
 class _ActiveSpan:
-    __slots__ = ("_tracer", "name", "attrs", "t0", "depth", "id", "parent", "_up", "_ann")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "id", "parent", "_up", "_ann")
 
     def __init__(self, tracer, name, attrs):
         self._tracer = tracer
@@ -119,10 +119,7 @@ class _ActiveSpan:
 
     def __enter__(self):
         up = self._up = getattr(_TLS, "top", None)
-        if up is None:
-            self.depth, self.parent = 0, 0
-        else:
-            self.depth, self.parent = up.depth + 1, up.id
+        self.parent = 0 if up is None else up.id
         self.id = next(_IDS)
         _TLS.top = self
         ann = self._ann = TraceAnnotation(self.name, **self.attrs) if self.attrs else TraceAnnotation(self.name)
@@ -139,13 +136,13 @@ class _ActiveSpan:
         if len(ring) == ring.maxlen:
             tr._m_dropped.inc()  # oldest span about to fall off the ring
         ring.append((self.name, self.t0, t1 - self.t0, threading.get_ident(),
-                     self.depth, self.attrs, self.id, self.parent))
+                     self.attrs, self.id, self.parent))
         return False
 
 
 def _record(rec) -> Dict:
-    name, t0, dur, tid, depth, attrs, sid, parent = rec
-    return {"name": name, "start_s": t0, "dur_s": dur, "tid": tid, "depth": depth,
+    name, t0, dur, tid, attrs, sid, parent = rec
+    return {"name": name, "start_s": t0, "dur_s": dur, "tid": tid,
             "attrs": attrs or {}, "id": sid, "parent": parent}
 
 

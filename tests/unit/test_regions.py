@@ -31,6 +31,18 @@ REGIONS = {"embed", "norm", "mixer/proj", "mixer/rope", "mixer/kernel", "mixer/i
            "zero/regather", "block"}
 REGIONS |= {"exit_gate", "loop_step"}  # PR 63: a looped stack's passes (the one body of the scan over them) and its gate
 REGIONS |= {"ffn/exchange"}  # PR 66: how a routed layer's held experts meet their rows on a mesh: the rows' two exchanges, or the parts' sum
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compiled_programs_dropped():
+    """A worker that has compiled enough large CPU programs dies inside XLA's CPU compiler at whichever test compiles the
+    next (``tests/unit/test_moe_sum_rows.py`` has the story; PR 68's whole run lost one of this module's the same way, in
+    the hybrid cell's step): what the process holds is dropped before this module and after it."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 HEAVY = ("dot", "convolution", "custom-call")
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute", "all-to-all")
 
@@ -268,7 +280,7 @@ def test_a_trainers_capture_is_reduced_with_its_programs_regions(tmp_path, monke
         text = f.read()
     asked = []
     prof.describe(lambda: asked.append(1) or text)
-    monkeypatch.setattr(profiler, "load_xplane", lambda path: trace)
+    monkeypatch.setattr(profiler, "load_xplane", lambda path, **how: trace)
     monkeypatch.setattr(profiler, "find_xplane", lambda root: "recorded")
     monkeypatch.setattr(tracing, "_REGIONS_SEEN", set(REGIONS))
     prof.arm()
@@ -421,7 +433,7 @@ def test_the_first_call_line_says_where_the_compute_copy_came_from_and_the_gradi
         reset_mesh()
     assert (series() - before, every() - all_before) == (1.0, 1.0)  # one trace of one step program, and no other series rose
     assert (engine._params_c is not None) == (copy == "carried")
-    lines = [l for l in handler.lines if l.startswith("program first call: family=train")]
-    assert len(lines) == 1 and f"bucket={bucket} " in lines[0] and f" compute_copy={copy} grads={grads} grad_reduce=xla " in lines[0], lines
-    said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("family") == "train"][-1]
-    assert (said["bucket"], said["compute_copy"], said["grads"]) == want
+    lines = [l for l in handler.lines if l.startswith(f"program first call: family=train bucket={bucket} ")]  # a step that is not fused has the accumulator's and the update's beside it
+    assert len(lines) == 1 and f" compute_copy={copy} grads={grads} grad_reduce=xla " in lines[0], lines
+    said = [s["attrs"] for s in get_tracer().spans() if s["name"] == "program/first_call" and s["attrs"].get("bucket") == bucket][-1]
+    assert (said["family"], said["bucket"], said["compute_copy"], said["grads"]) == ("train",) + want

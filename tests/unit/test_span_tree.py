@@ -32,7 +32,7 @@ def test_spans_carry_ids_parents_and_self_times():
             time.sleep(0.002)
         with tr.span("fused/dispatch", q=7) as disp:
             with tr.span("program/first_call") as first:
-                assert (first.parent, first.depth) == (disp.id, 2)
+                assert (first.parent, disp.parent) == (disp.id, outer.id)
                 time.sleep(0.002)
     assert current_span() is None
     spans = {s["name"]: s for s in tr.spans()}

@@ -226,7 +226,7 @@ def test_concurrent_creation_single_handle():
 
 # ----------------------------------------------------------------- tracing
 
-def test_span_nesting_depth_and_ring_eviction():
+def test_span_nesting_and_ring_eviction():
     tr = SpanTracer(capacity=3)
     with tr.span("train/step"):
         with tr.span("train/forward", micro=0):
@@ -235,7 +235,7 @@ def test_span_nesting_depth_and_ring_eviction():
             pass
     spans = tr.spans()
     assert [s["name"] for s in spans] == ["train/forward", "train/backward", "train/step"]
-    assert [s["depth"] for s in spans] == [1, 1, 0]
+    assert [s["parent"] for s in spans] == [spans[2]["id"], spans[2]["id"], 0]
     assert spans[0]["attrs"] == {"micro": 0}
     assert all(s["dur_s"] >= 0 for s in spans)
     # step started before its children and outlived them
@@ -254,10 +254,10 @@ def test_span_exception_still_recorded():
         with tr.span("boom"):
             raise RuntimeError("x")
     assert [s["name"] for s in tr.spans()] == ["boom"]
-    # depth restored for the next span
+    # the next span is a root again
     with tr.span("after"):
         pass
-    assert tr.spans()[-1]["depth"] == 0
+    assert tr.spans()[-1]["parent"] == 0
 
 
 def test_dump_trace_chrome_and_jsonl(tmp_path):
@@ -833,6 +833,7 @@ _EXTRA_METRICS = {"last_step_completed_unix", "tp_degree", "sparse_keys_chosen_t
                   "moe_rows_routed_here_total", "moe_rows_dropped_total", "moe_expert_rows_max", "moe_expert_rows_min",
                   "moe_fallback_layers_total", "moe_buffer_rung_layers_total", "moe_rows_over_uniform_max", "moe_rows_sent_total", "moe_chip_rows_max", "moe_chip_rows_min",
                   "diffusion_masked_positions_total", "diffusion_positions_total", "diffusion_weight_sum",
+                  "engine_init_seconds_total", "import_seconds",
                   "profile_captures_total", "profile_captures_dropped_total",
                   "profile_collective_exposed_fraction",
                   "profile_device_busy_fraction",

@@ -18,7 +18,8 @@ operations, the exposed-collective summary cross-checked against the
 ``tp_all_reduce`` ledger and, for a capture of a training run
 (``DS_TPU_PROFILE=1``: docs/OBSERVABILITY.md, "Regions"), the compiled
 step's device time by region and phase, and for a capture that a hunt kept
-(``DS_TPU_PROFILE=stall``) the stalled step. ``--json`` dumps the summary
+(``DS_TPU_PROFILE=stall``) the stalled step, for a capture of set-up
+(``DS_TPU_PROFILE=setup``) its programs' first executions. ``--json`` dumps the summary
 document instead.
 
 ``smoke`` captures an 8-request fused serving run end-to-end (arm →
@@ -125,6 +126,9 @@ def render(summary, top=8):
     if summary.get("hunted"):
         lines.append(f"the hunt: {sum(not h['kept'] for h in summary['hunted'])} captures dropped before this one; each capture's cost (s): "
                      + "; ".join(", ".join(f"{k} {v}" for k, v in h.items()) for h in summary["hunted"][-top:]))
+    if (summary.get("setup") or {}).get("programs"):  # a capture of set-up (``DS_TPU_PROFILE=setup``)
+        lines += ["", "set-up's programs on the first device, in the order they first ran (name, executions, first began at s, first took s, later median s):"]
+        lines += ["  " + "  ".join(str(v) for v in row) for row in summary["setup"]["programs"]]
     if summary.get("stall"):
         lines += ["", render_stall(summary["stall"])]
     if summary.get("regions"):
