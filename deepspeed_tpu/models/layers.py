@@ -262,9 +262,10 @@ class Attention(LayerKind, nn.Module):
     rotates: bool = True  # False (the kind ``nope``): q and k are not rotated, whatever ``pos_emb`` says of the others
     keeps, stackable = (FLASH_SAVED, SAVED), True
     # the first-call line, a kind: how its call was traced (``op`` is the kind's own name, counted at the call below) and,
-    # of a window layer's call, the window and the tiles the forward's walk visits of the square's
+    # of a window layer's call, the window, the tiles the forward's walk visits of the square's and the walk's tile
     paths = {f"{op}_path": ("mixer/kernel", {"op": op, "pass": "fwd"}) for op in ("full", "window")}
-    joined = {**TILES_A_TRIP, **ROPE_FORM, "window_keys": ("mixer/kernel", None, "window"), "window_tiles": ("mixer/kernel", None, "window_tiles")}
+    joined = {**TILES_A_TRIP, **ROPE_FORM, "window_keys": ("mixer/kernel", None, "window"), "window_tiles": ("mixer/kernel", None, "window_tiles"),
+              "window_tile": ("mixer/kernel", None, "window_tile")}
 
     @classmethod
     def from_config(cls, cfg, kind):

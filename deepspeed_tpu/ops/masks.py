@@ -36,10 +36,20 @@ class _Record:
         """The kernels' tile along a sequence of ``seq``: ``divides(n, want)``, the largest tile that divides n."""
         return divides(seq, want)
 
-    def walk_labels(self, tiles: str) -> dict:
-        """What a forward call under this mask adds to its count (``program_regions_traced_total{region="mixer/kernel"}``),
-        given ``tiles``, "visited/of the square" at the call's shapes: nothing, for a mask whose walk is the whole or half
-        the square; a mask with a walk of its own says it, so a walk that visits more shows without a capture."""
+    def strip(self, tile: int) -> int:
+        """The tile of the kernels' WALK along an axis whose program holds ``tile`` rows: the program's own, but under a
+        band narrower than it (``Causal.strip``)."""
+        return tile
+
+    def band(self, *, tile, seq_q, seq_k):
+        """A walk without a loop (``Causal.band``): none."""
+        return None
+
+    def walk_labels(self, tile: str, tiles: str = "") -> dict:
+        """What a call under this mask adds to its count (``program_regions_traced_total{region="mixer/kernel"}``), given
+        ``tile``, "BQxBK" of its walk, and, a forward call, ``tiles``, "visited/of the square" at its shapes: nothing,
+        for a mask whose walk is the whole or half the square; a mask with a walk of its own says it, so a walk that
+        visits more shows without a capture."""
         return {}
 
 
@@ -72,8 +82,34 @@ class Causal(_Record):
             mask = mask & (cols > rows - self.window)
         return mask
 
-    def walk_labels(self, tiles: str) -> dict:
-        return {"window_tiles": tiles} if self.window > 0 else {}  # a band's walk, under a label of its own
+    def walk_labels(self, tile: str, tiles: str = "") -> dict:  # a band's walk, under labels of its own
+        return {"window_tile": tile, **({"window_tiles": tiles} if tiles else {})} if self.window > 0 else {}
+
+    def strip(self, tile: int) -> int:
+        return band_strip(self.window, tile)
+
+    def band(self, *, tile, seq_q, seq_k):
+        """Under a band no wider than a ``tile`` (of both axes) whose queries begin at a tile's edge: ``(shift, n)``,
+        static: q tile i's keys lie in the n kv tiles that end with tile ``i + shift`` (the diagonal's and the one
+        before it), kv tile j's queries in the n q tiles from ``j - shift`` on. None where the band is wider, the queries
+        begin before the keys or inside a tile, or a sequence has fewer tiles than n."""
+        off, n = seq_k - seq_q, min(self.window, 2)
+        if not 0 < self.window <= tile or off < 0 or off % tile or seq_q % tile or seq_k % tile or n > seq_q // tile:
+            return None
+        return off // tile, n
+
+    def kv_band(self, qi, *, tile, seq_q, seq_k):
+        """``(first, n)``: the n kv tiles q tile ``qi`` walks where ``band`` holds, each across an edge of the mask (the
+        first q tile's n tiles begin with the first kv tile, and the test throws its spare one away). ``qi`` is traced,
+        n is not: a walk with no loop."""
+        shift, n = self.band(tile=tile, seq_q=seq_q, seq_k=seq_k)
+        return jnp.maximum(qi + shift - (n - 1), 0), n
+
+    def q_band(self, kj, *, tile, seq_q, seq_k):
+        """``kv_band`` seen from a kv tile: the q tiles that visit it (a kv tile ahead of the first query, or the last:
+        n tiles inside the sequence, which the test throws away where they are not its own)."""
+        shift, n = self.band(tile=tile, seq_q=seq_q, seq_k=seq_k)
+        return jnp.clip(kj - shift, 0, seq_q // tile - n), n
 
     def kv_runs(self, qi, *, bq, bk, seq_q, seq_k):
         """Only a block that crosses the diagonal (or, with a window, the window's far edge) has a masked element; the
@@ -187,8 +223,33 @@ class BlockDiffusion(_Record):
     def pairs(self) -> int:
         return self.seq_len**2 + self.seq_len * self.block
 
-    def walk_labels(self, tiles: str) -> dict:
-        return {"tiles": tiles, "pairs": str(self.pairs)}
+    def walk_labels(self, tile: str, tiles: str = "") -> dict:
+        return {"tiles": tiles, "pairs": str(self.pairs)} if tiles else {}
+
+
+STRIP = 128  # the narrowest strip: the lanes of the kernels' row statistics and of a (bk, bq) score tile
+
+
+def band_strip(window: int, tile: int) -> int:
+    """The tile of the kernels' walk along an axis whose programs hold ``tile`` rows, under a causal band of ``window``
+    keys: where ``0 < window <= tile // 2`` the power of two that holds the band, never under ``STRIP``; else ``tile``, the
+    walk as it was. It reads the window and the block, nothing else. A program keeps its block and walks it as strips
+    (``ops/pallas/flash_attention.py::_tiles``), each against the two tiles the band crosses (``Causal.band``).
+
+    Written from this table (TPU v5e, PR 69: the window call alone at K-EXAONE's shape, ``(1, 8192, 64 / 8, 128)`` bf16
+    under 128 keys, ms forward | fused backward, twenty calls a program; kept pairs need 0.2 | 0.4 at the peak).
+    Programs AND tiles by the two knobs, the kernels as they were: (512, 512) **3.34 | 6.31**; (512, 128) 3.46 | 7.36;
+    (256, 128) 3.72 | 7.36; (128, 128) 4.47 | 6.08; (128, 256) 4.31 | 5.70; (256, 256) 3.65 | 6.07; (512, 256) 3.22 |
+    5.84; (256, 512) 4.13 | 6.15: a masked tile costs 820 | 1,360 | 2,530 cycles at a side of 128 | 256 | 512 forward,
+    its side and not its pairs, so fewer pairs in more tiles buy nothing. Blocks of 512 walked as strips under a ROLLED
+    loop, each strip the walk of ``kv_runs`` / ``q_runs`` with its three loops: strips of (128, 128) 4.45 | 5.40; (128,
+    256) 4.29 | 5.35; (256, 128) 3.75 | 6.57; (256, 256) 3.66 | 5.70. Strips of (128, 128) whose walk is two tiles and
+    no loop (``Causal.band``): the strips under a rolled loop 2.71 | 4.80; **in straight-line text 1.55 | 4.45, kept**.
+    Under 64 keys the same strips read 1.54 | 4.45; under 256 keys the strips are (256, 256), two tiles of 256 where
+    three of 128 would cross the band: 2.15 | 4.15 (the blocks' walk 3.34 | 6.31 under either). The same error against
+    float32 as the blocks' walk (max and rms equal to four digits, o, dq, dk, dv: PERF.md section 6, PR 69)."""
+    strip = max(STRIP, 1 << max(window - 1, 0).bit_length())  # the power of two that holds the band
+    return strip if 0 < window and 2 * strip <= tile and tile % strip == 0 else tile
 
 
 def of(causal: bool, window=None):

@@ -74,6 +74,24 @@ its unmasked tile is 2,517 bundles for five products (2,560 MXU cycles), two
 abreast 2,297 each, and on the chip two ran no faster (21.78 | 22.06 ms at
 SDAR's shape, 7.996 | 7.91 at Kimi-VL's, 0.840 | 0.918 at OLMo's): it is
 bound by its products as Mosaic lowers them, not by its chain.
+
+**A band narrower than a block.** A tile's time follows its SIDE, not its
+pairs: a masked forward tile costs 820 cycles at (128, 128), 1,360 at (256,
+256) and 2,530 at (512, 512) (the chip, PR 69: the window call of 64 heads x
+8,192 x 128 under 128 keys by the two knobs), since every link of the chain
+waits for the one before whatever it holds. So a window of 128 keys, whose
+512 x 128 kept pairs a q block lie in two (512, 512) tiles today, gains
+nothing from smaller programs (forward | backward 3.34 | 6.31 ms at (512,
+512), 4.47 | 6.08 at (128, 128), 3.22 | 5.84 at best) and little from a
+rolled loop over strips inside a program (4.45 | 5.40): four times fewer
+pairs in tiles a third as long, one after the other. What follows the band
+is a walk with NO loop (``_tiles``, ``masks.band_strip``, ``Causal.band``):
+a program keeps its block of 512 rows and walks it as four strips of 128,
+each against the diagonal's tile and the one before it, all eight tiles in
+one basic block, where the scheduler lays the strips' chains side by side
+(1,306 bundles a forward program for the described v5e where two masked (512,
+512) tiles are 4,870). The mask's test, ``_scores``, the online softmax and
+``_dkv_accumulate`` are the ones every other walk uses.
 """
 
 import functools
@@ -188,6 +206,15 @@ def _walk(runs, body, carry, pair=None):
     return carry
 
 
+def _walk_band(band, body, carry):
+    """``body(block, carry, masked=True)`` over the ``n`` blocks from ``first`` on, ``band = (first, n)`` with n static:
+    straight-line text, so the tiles' products stand side by side for Mosaic's scheduler."""
+    first, n = band
+    for d in range(n):
+        carry = body(first + d, carry, masked=True)
+    return carry
+
+
 def _needs_empty_guard(seq_q: int, seq_k: int, has_bias: bool) -> bool:
     """Whether ``p`` must be zeroed where ``s <= NEG_INF``. A row with no
     visible column has m == lse == NEG_INF, so exp(s - m) is 1 on its masked
@@ -220,7 +247,13 @@ def _bias_bh_fn(bias_meta, H: int):
     return bias_bh
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq: int, bk: int, seq_q: int,
+def _part(t, rows: int, whole: bool):
+    """Rows ``[t * rows, (t + 1) * rows)`` of a program's block ``ref[0]``, as an index; the block itself where the
+    program walks it ``whole`` (``t`` is 0 then, and the kernel's text is what it was before a block had strips)."""
+    return (0,) if whole else (0, pl.dslice(t * rows, rows), slice(None))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq: int, bk: int, strips: int, seq_q: int,
                 seq_k: int, scale: float, mask, has_alibi: bool, has_bias: bool, sqb1: bool, tiles_a_trip: int):
     """One q block against the kv blocks it sees, on kv-major (bk, bq) tiles
     (``_scores``): the running max and sum are (1, bq) rows that reduce and
@@ -228,48 +261,72 @@ def _fwd_kernel(q_ref, k_ref, v_ref, slopes_ref, bias_ref, o_ref, lse_ref, *, bq
     the end, and lse leaves as the row the backward reads (``_rows``). With
     ``tiles_a_trip`` = 2 a trip of an unmasked run takes two tiles (the
     module's docstring): both score products stand first in the text, then the
-    two online-softmax updates, one after the other as two trips make them."""
-    qi = pl.program_id(1)
-    q = q_ref[0]  # (bq, D) input dtype — MXU runs bf16 operands w/ fp32 accumulation
+    two online-softmax updates, one after the other as two trips make them.
+    Under a band narrower than half the program's block (the module's
+    docstring; ``masks.band_strip``) the block is ``strips`` q tiles of ``bq``
+    rows, each against the two kv tiles the band crosses: the same ``scored``
+    and ``update``, in straight-line text."""
+    block = pl.program_id(1)
     D = v_ref.shape[-1]  # the value head size: q and k may have another (latent attention: 192 beside 128)
-    slope = slopes_ref[0, 0, 0]
-    row0 = seq_k - seq_q + qi * bq
     guard = _needs_empty_guard(seq_q, seq_k, has_bias)
+    whole = strips == 1
 
-    def scored(j, masked):
-        k = k_ref[0, pl.dslice(j * bk, bk), :]
-        btile = None
-        if has_bias:  # the (bk, 1) column all rows share, or the (bq, bk) tile turned kv-major
-            btile = bias_ref[0, pl.dslice(j * bk, bk), :] if sqb1 else bias_ref[0, :, pl.dslice(j * bk, bk)].T
-        return _scores(q, k, slope, row0, j * bk, scale, mask, has_alibi, btile, masked=masked, kv_major=True)
+    def q_tile(t, qi):  # the program's t-th q tile, the call's qi-th
+        q = q_ref[_part(t, bq, whole)]  # (bq, D) input dtype — MXU runs bf16 operands w/ fp32 accumulation
+        slope = slopes_ref[0, 0, 0]
+        row0 = seq_k - seq_q + qi * bq
 
-    def update(j, s, carry, masked):
-        acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
-        v = v_ref[0, pl.dslice(j * bk, bk), :]
-        new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - new_m)
-        if guard and (masked or has_bias):
-            p = jnp.where(s <= NEG_INF, 0.0, p)
-        corr = jnp.exp(m - new_m)
-        new_l = l * corr + jnp.sum(p, axis=0, keepdims=True)
-        new_acc = acc * corr + jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
-        return new_acc, new_m, new_l
+        def scored(j, masked):
+            k = k_ref[0, pl.dslice(j * bk, bk), :]
+            btile = None
+            if has_bias:  # the (bk, 1) column all rows share, or the (bq, bk) tile turned kv-major
+                btile = bias_ref[0, pl.dslice(j * bk, bk), :] if sqb1 else bias_ref[0, :, pl.dslice(j * bk, bk)].T
+            return _scores(q, k, slope, row0, j * bk, scale, mask, has_alibi, btile, masked=masked, kv_major=True)
 
-    def body(j, carry, masked):
-        return update(j, scored(j, masked), carry, masked)
+        def update(j, s, carry, masked):
+            acc, m, l = carry  # (D, bq), (1, bq), (1, bq)
+            v = v_ref[0, pl.dslice(j * bk, bk), :]
+            new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - new_m)
+            if guard and (masked or has_bias):
+                p = jnp.where(s <= NEG_INF, 0.0, p)
+            corr = jnp.exp(m - new_m)
+            new_l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+            new_acc = acc * corr + jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+            return new_acc, new_m, new_l
 
-    def pair(j, carry):  # tiles j and j + 1 of an unmasked run: the second score product beside the first chain
-        s0, s1 = scored(j, False), scored(j + 1, False)
-        return update(j + 1, s1, update(j, s0, carry, False), False)
+        def body(j, carry, masked):
+            return update(j, scored(j, masked), carry, masked)
 
-    acc0 = jnp.zeros((D, bq), jnp.float32)
-    m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((1, bq), jnp.float32)
-    runs = mask.kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
-    acc, m, l = _walk(runs, body, (acc0, m0, l0), pair if tiles_a_trip == 2 else None)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l_safe)
+        def pair(j, carry):  # tiles j and j + 1 of an unmasked run: the second score product beside the first chain
+            s0, s1 = scored(j, False), scored(j + 1, False)
+            return update(j + 1, s1, update(j, s0, carry, False), False)
+
+        acc0 = jnp.zeros((D, bq), jnp.float32)
+        m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((1, bq), jnp.float32)
+        if not whole:  # a band: the same few kv tiles a q tile, each across an edge, and no loop (``masks.Causal.kv_band``)
+            acc, m, l = _walk_band(mask.kv_band(qi, tile=bq, seq_q=seq_q, seq_k=seq_k), body, (acc0, m0, l0))
+        else:
+            runs = mask.kv_runs(qi, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
+            acc, m, l = _walk(runs, body, (acc0, m0, l0), pair if tiles_a_trip == 2 else None)
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[_part(t, bq, whole)] = (acc / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0, t] = m + jnp.log(l_safe)
+
+    _tiles_of(block, strips, q_tile)
+
+
+def _tiles_of(block, tiles: int, body):
+    """``body(t, tile)`` for each of a program's ``tiles`` walk tiles, ``tile`` its number in the call: the block's own
+    (no arithmetic on the program's index) where the program walks its block whole; else the strips one after the other
+    in the program's text, with no loop between them (a strip's walk has none either: ``_walk_band``), so that Mosaic's
+    scheduler, which overlaps what stands in one basic block, runs their chains side by side. Under ``lax.fori_loop`` the
+    same strips took 2.71 | 4.80 ms forward | backward where these take 1.55 | 4.45 (``masks.band_strip`` has the
+    table), and the text is SHORTER than the blocks' walk it replaces: eight tiles of (128, 128) in 1,306 bundles where
+    the three runs' bodies of (512, 512) are 5,232 (forward, the described v5e), so set-up does not pay for it."""
+    for t in range(tiles):
+        body(t, block if tiles == 1 else block * tiles + t)
 
 
 def _kv_of_fn(H: int, KVH: int):
@@ -296,15 +353,30 @@ def _count_traced(pass_: str, path: str, unequal_heads: bool = False, mask=masks
     placement.count(masks.counted_op(mask, unequal_heads), path if mask.op == "flash" else "kernel", pass_, **choice)
 
 
+def _tiles(mask, Sq: int, Sk: int, has_bias: bool):
+    """``(bq, bk, sq, sk)``: the rows of a program's block along q and k (the grids' steps, and what a program holds of
+    the operands it takes by the block), and the tiles of the kernels' walks. They are the same but under a band
+    narrower than a block (``masks.band_strip``), where a program walks its block as strips: the forward a q block as q
+    tiles of ``sq`` rows against kv tiles of ``sk``, the fused backward a kv block as kv tiles of ``sk`` against q tiles of
+    ``sq``. A bias comes by the block, so its kernels keep the block."""
+    bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
+    sq, sk = mask.strip(bq), mask.strip(bk)
+    if has_bias or sq != sk or mask.band(tile=sq, seq_q=Sq, seq_k=Sk) is None:
+        return bq, bk, bq, bk
+    return bq, bk, sq, sk
+
+
 def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_alibi: bool, bias_meta, H: int, KVH: int):
     BH, Sq, D = q.shape
     Sk, Dv = k.shape[1], v.shape[-1]
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
-    bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
+    bq, bk, sq, sk = _tiles(mask, Sq, Sk, has_bias)
+    strips = bq // sq
     # a mask with a walk of its own also says, static at trace time, what the walk visits (``masks.py::walk_labels``): a
     # walk that visits more shows on the trainer's first-call line without a capture
-    walk = mask.walk_labels(f"{masks.tiles_visited(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk)}/{(Sq // bq) * (Sk // bk)}")
+    visited = (Sq // sq) * mask.band(tile=sq, seq_q=Sq, seq_k=Sk)[1] if strips > 1 else masks.tiles_visited(mask, bq=sq, bk=sk, seq_q=Sq, seq_k=Sk)
+    walk = mask.walk_labels(f"{sq}x{sk}", f"{visited}/{(Sq // sq) * (Sk // sk)}")
     # without bias a (1,1,LANES) dummy rides along so the kernel arity is
     # fixed; with bias, broadcast dims stay COLLAPSED in HBM and the index
     # map routes every program to its shared block
@@ -317,12 +389,12 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_a
         bias_spec = pl.BlockSpec((1, bq, Sk), lambda b, i: (bias_bh(b), i, 0))
     else:
         bias_spec = pl.BlockSpec((1, 1, LANES), lambda b, i: (0, 0, 0))
-    vmem = (2 * (bq * (D + Dv) + Sk * (D + Dv)) * q.dtype.itemsize + _tile_bytes(bq, bk)
+    vmem = (2 * (bq * (D + Dv) + Sk * (D + Dv)) * q.dtype.itemsize + _tile_bytes(sq, sk)
             + (2 * (LANES if sqb1 else bq) * Sk * 4 if has_bias else 0))
-    tiles = tiles_a_trip(masks.longest_whole_run(mask, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk), vmem)
+    tiles = 1 if strips > 1 else tiles_a_trip(masks.longest_whole_run(mask, bq=sq, bk=sk, seq_q=Sq, seq_k=Sk), vmem)
     _count_traced("fwd", "single", Dv != D, mask, tiles_a_trip=str(tiles), **walk)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask,
+        functools.partial(_fwd_kernel, bq=sq, bk=sk, strips=strips, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask,
                           has_alibi=has_alibi, has_bias=has_bias, sqb1=sqb1, tiles_a_trip=tiles),
         grid=(BH, Sq // bq),
         in_specs=[
@@ -334,11 +406,11 @@ def _flash_fwd(q, k, v, slopes, bias, scale: float, mask, interpret: bool, has_a
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec((1, bq // sq, 1, sq), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq // bq, 1, bq), jnp.float32),  # one row a q block: _rows
+            jax.ShapeDtypeStruct((BH, Sq // sq, 1, sq), jnp.float32),  # one row a q tile: _rows
         ],
         interpret=interpret,
         name=f"{mask.kernel}_fwd",  # the custom call's name on the device's clock
@@ -424,7 +496,7 @@ def _dq_kernel_collapsed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes
 
 
 def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj, on_ds=None, *,
-                    bq, bk, seq_q, seq_k, scale, mask, has_alibi, has_bias=False):
+                    bq, bk, seq_q, seq_k, scale, mask, has_alibi, has_bias=False, band=False):
     """(bk, D) dk/dv for one kv block — the ONE definition of the dkv
     gradient algebra (visible-q-block runs + ds formula), shared by the
     fused and the per-q-head (bias) kernels so they can never drift apart.
@@ -454,8 +526,11 @@ def _dkv_accumulate(q_ref, k, v, do_ref, lse_ref, delta_ref, slope, btile_fn, kj
             on_ds(i, ds)
         return dk, dv
 
-    runs = mask.q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k)
-    dk, dv = _walk(runs, body, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
+    if band:  # the same few q tiles a kv tile, and no loop (``masks.Causal.q_band``)
+        walk = functools.partial(_walk_band, mask.q_band(kj, tile=bk, seq_q=seq_q, seq_k=seq_k))
+    else:
+        walk = functools.partial(_walk, mask.q_runs(kj, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k))
+    dk, dv = walk(body, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     return dk * scale, dv
 
 
@@ -475,7 +550,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, bia
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_ref, dq_ref, dk_ref, dv_ref,
-                      dq_acc, *kv_acc, n_rep: int, **statics):
+                      dq_acc, *kv_acc, n_rep: int, strips: int, **statics):
     """dq, dk and dv in ONE walk: grid (B*KVH, n_rep, Sk//bk), kv blocks
     innermost. A head's q, do, lse and delta stay in VMEM over the walk (as
     in the dkv kernels) and so does its dq, as a float32 scratch that every
@@ -483,46 +558,63 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slopes_re
     dtype: s, p, dp and ds are formed once a block instead of once in each of
     two kernels. With n_rep == 1 each dk/dv block has one visit and leaves in
     the input dtype; a GQA group adds its heads up in float32 scratch over
-    the whole (Sk, D) and writes once, after its last head."""
-    rep, kj = pl.program_id(1), pl.program_id(2)
-    last_kj = pl.num_programs(2) - 1
+    the whole (Sk, D) and writes once, after its last head. Under a band
+    narrower than half the program's block (``masks.band_strip``) the block is
+    ``strips`` kv tiles of ``bk`` rows, each against the two q tiles that see
+    it: the same ``_dkv_accumulate``, in straight-line text."""
+    rep, block = pl.program_id(1), pl.program_id(2)
+    last = pl.num_programs(2) - 1
     bq, bk = statics["bq"], statics["bk"]
+    whole = strips == 1
 
-    @pl.when(kj == 0)
+    @pl.when(block == 0)
     def _zero():  # a window or seq_q < seq_k leaves q blocks that kv block 0 never visits
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    k = k_ref[0]
+    def kv_tile(t, kj):  # the program's t-th kv tile, the call's kj-th
+        k = k_ref[_part(t, bk, whole)]
 
-    def on_ds(i, ds):
-        rows = pl.dslice(i * bq, bq)
-        dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
+        def on_ds(i, ds):
+            rows = pl.dslice(i * bq, bq)
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(ds, k, _TN, preferred_element_type=jnp.float32)
 
-    dk, dv = _dkv_accumulate(q_ref, k, v_ref[0], do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0],
-                             lambda i: None, kj, on_ds, **statics)
+        return _dkv_accumulate(q_ref, k, v_ref[_part(t, bk, whole)], do_ref, lse_ref, delta_ref, slopes_ref[0, 0, 0],
+                               lambda i: None, kj, on_ds, band=not whole, **statics)
 
-    @pl.when(kj == last_kj)
-    def _dq_out():
-        dq_ref[0] = (dq_acc[...] * statics["scale"]).astype(dq_ref.dtype)
+    def dq_out():
+        @pl.when(block == last)
+        def _dq_out():
+            dq_ref[0] = (dq_acc[...] * statics["scale"]).astype(dq_ref.dtype)
 
+    def dkv_out(t, kj, dk, dv):
+        if n_rep == 1:
+            dk_ref[_part(t, bk, whole)] = dk.astype(dk_ref.dtype)
+            dv_ref[_part(t, bk, whole)] = dv.astype(dv_ref.dtype)
+            return
+        # the float32 sum over the group's q heads: the first assigns (no zero fill), the rest add
+        rows = pl.dslice(kj * bk, bk)
+
+        @pl.when(rep == 0)
+        def _first():
+            for acc, value in zip(kv_acc, (dk, dv)):
+                acc[rows, :] = value
+
+        @pl.when(rep > 0)
+        def _add():
+            for acc, value in zip(kv_acc, (dk, dv)):
+                acc[rows, :] = acc[rows, :] + value
+
+    if whole:  # (the text of the kernel before a block had strips: dq leaves ahead of the block's dk and dv)
+        dk, dv = kv_tile(0, block)
+        dq_out()
+        dkv_out(0, block, dk, dv)
+    else:
+        _tiles_of(block, strips, lambda t, kj: dkv_out(t, kj, *kv_tile(t, kj)))
+        dq_out()
     if n_rep == 1:
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
         return
-    # the float32 sum over the group's q heads: the first assigns (no zero fill), the rest add
-    rows = pl.dslice(kj * bk, bk)
 
-    @pl.when(rep == 0)
-    def _first():
-        for acc, value in zip(kv_acc, (dk, dv)):
-            acc[rows, :] = value
-
-    @pl.when(rep > 0)
-    def _add():
-        for acc, value in zip(kv_acc, (dk, dv)):
-            acc[rows, :] = acc[rows, :] + value
-
-    @pl.when(jnp.logical_and(rep == n_rep - 1, kj == last_kj))
+    @pl.when(jnp.logical_and(rep == n_rep - 1, block == last))
     def _dkv_out():
         for ref, acc in zip((dk_ref, dv_ref), kv_acc):
             ref[0] = acc[...].astype(ref.dtype)
@@ -582,12 +674,12 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret:
     has_bias = bias_meta is not None
     kv_of = _kv_of_fn(H, KVH)
     n_rep = H // KVH
-    bq, bk = mask.tile(Sq, DEFAULT_BQ, _blk), mask.tile(Sk, DEFAULT_BK, _blk)
+    bq, bk, sq, sk = _tiles(mask, Sq, Sk, has_bias)  # (under a bias the walk's tiles are the programs' blocks: bq, bk below)
     item = q.dtype.itemsize
-    statics = dict(bq=bq, bk=bk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask, has_alibi=has_alibi)
+    statics = dict(bq=sq, bk=sk, seq_q=Sq, seq_k=Sk, scale=scale, mask=mask, has_alibi=has_alibi)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)  # (BH, Sq)
-    lse_rows, delta_rows = _rows(lse, bq), _rows(delta, bq)
-    nq = Sq // bq
+    lse_rows, delta_rows = _rows(lse, sq), _rows(delta, sq)
+    nq = Sq // sq
 
     def q_of(bkv, rep):
         return (bkv // KVH) * H + (bkv % KVH) * n_rep + rep
@@ -608,7 +700,7 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret:
                 f"flash_attention backward: a head's q, do and dq at seq_q={Sq}, seq_k={Sk}, D={D}, {q.dtype.name}, "
                 f"{n_rep} q heads a KV head take {fused_vmem >> 20} MiB of VMEM, over this device's budget of "
                 f"{vmem_budget() >> 20} MiB: split the sequence over the mesh (sequence or context parallelism)")
-        _count_traced("bwd", "fused", Dv != D, mask, tiles_a_trip="1")
+        _count_traced("bwd", "fused", Dv != D, mask, tiles_a_trip="1", **mask.walk_labels(f"{sq}x{sk}"))
         whole_q = lambda b, r, j: (q_of(b, r), 0, 0)
         rows_q = lambda b, r, j: (q_of(b, r), 0, 0, 0)
         kv_blk = [pl.BlockSpec((1, bk, d), lambda b, r, j: (b, j, 0)) for d in (D, Dv)]
@@ -618,14 +710,14 @@ def _flash_bwd(q, k, v, o, lse, do, slopes, bias, scale: float, mask, interpret:
             kv_out = [pl.BlockSpec((1, Sk, d), lambda b, r, j: (b, 0, 0)) for d in (D, Dv)]
             kv_scratch = [pltpu.VMEM((Sk, d), jnp.float32) for d in (D, Dv)]
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, n_rep=n_rep, **statics),
+            functools.partial(_bwd_fused_kernel, n_rep=n_rep, strips=bk // sk, **statics),
             grid=(BKV, n_rep, Sk // bk),
             in_specs=[
                 pl.BlockSpec((1, Sq, D), whole_q),
                 *kv_blk,
                 pl.BlockSpec((1, Sq, Dv), whole_q),
-                pl.BlockSpec((1, nq, 1, bq), rows_q),
-                pl.BlockSpec((1, nq, 1, bq), rows_q),
+                pl.BlockSpec((1, nq, 1, sq), rows_q),
+                pl.BlockSpec((1, nq, 1, sq), rows_q),
                 pl.BlockSpec((1, 1, LANES), whole_q),
             ],
             out_specs=[pl.BlockSpec((1, Sq, D), whole_q), *kv_out],

@@ -362,6 +362,8 @@ CASES = {
     "conv_silu_b1_s8192_c10304_at4096_4096_1024_1024_k4": lambda: _conv_silu((1, 8192, 10304, 4), 4096, (4096, 1024, 1024)),  # ... their convolution, bias and SiLU: x, B and C from [z, xBC, dt]
     "ssd_scan_b2_s1000_h4_p64_g2_n128": lambda: _ssd((2, 1000, 4, 64, 2, 128)),  # a length that is padded to chunks, two heads a group (one tile)
     "flash_gqa_b1_s8192_h32_kvh2_d128": lambda: _flash((1, 8192, 32, 2, 128)),  # ... its one attention layer: SIXTEEN query heads a key head
+    "flash_gqa_b1_s8192_h64_kvh8_d128": lambda: _flash((1, 8192, 64, 8, 128)),  # k-exaone-236b-l5e8's full layer (a chip's call under ``shard_map``)
+    "flash_gqa_b1_s8192_h64_kvh8_d128_w128": lambda: _flash((1, 8192, 64, 8, 128), window=128),  # ... and its four window layers: blocks of 512 walked in strips of 128 (PR 69)
     "moe_sum_rows_t8192_d2688_e8_r6144": lambda: _moe_sum_rows((8192, 2688, 8, 6144)),  # ... its routed layers' first rung: 384 rows an expert, twice over
     "moe_sum_rows_t8192_d2688_e8_r49152": lambda: _moe_sum_rows((8192, 2688, 8, 49152)),  # ... and every pair
     "moe_sum_rows_t8192_d6144_e4_r16384": lambda: _moe_sum_rows((8192, 6144, 4, 16384)),  # k-exaone-236b-l5e8's SENDER: the groups are the four chips, a slab of 4,096 slots each
@@ -408,8 +410,50 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     # ... in the new form (PR 50): the forward takes two tiles a trip over its unmasked runs at every cell's shape but
     # under Phi-4's window of one tile's width, which has no unmasked run (and at GPT-2's 1,024 positions, two tiles a
     # side: no run of two); the backward keeps one
-    pairs = 0.0 if case.endswith("_w512") or "_s1024_" in case else 1.0
+    # (and under K-EXAONE's of 128, a band whose walk has no loop: PR 69)
+    pairs = 0.0 if case.endswith(("_w512", "_w128")) or "_s1024_" in case else 1.0
     assert [now - was for now, was in zip(trips(), trips_before)] == [1.0 - pairs, pairs, 1.0, 0.0]
+
+
+def _mosaic_bodies(lowered_text: str):
+    """Every Mosaic call's body in a lowered program, printed WITHOUT locations: the serialized module holds the source's
+    line numbers, so the raw bytes differ after any edit of the kernels' file and prove nothing (PERF.md section 6, PR 49)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    bodies = []
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            bodies.append(ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False))
+    return bodies
+
+
+# sha256 (first 16 hex digits) of the forward's and the backward's Mosaic bodies AT PR 68, the parent of the PR that gave
+# a narrow band its strips: from ``_mosaic_bodies`` run in a checkout of that commit. A PR that edits these kernels' text
+# for every mask pins its own
+PARENTS_BODIES = {
+    "flash_diff_b1_s8192_h20_kvh10_d64_v128_w512": ("e17d62aa94483d76", "993a748ab9a6bebb"),    # phi4-mini-flash-l6's window layer: a window of one block
+    "flash_gqa_b1_s16384_h28_kvh4_d128_w4096": ("28aec4bf0cf9b062", "c915f8b9ac53fe3f"),       # smallthinker-21b-l4e8's three: of eight
+    "flash_gqa_b1_s8192_h64_kvh8_d128": ("e8b5a670d1e1e087", "be20a0a2413d5915"),               # k-exaone-236b-l5e8's full layer
+    "flash_blockdiff_b1_s16384_h32_kvh4_d128_blk4": ("2601baae981bae6e", "9260160424018e18"),  # sdar-30b-a3b-l4e16's
+}
+
+
+@pytest.mark.parametrize("case", list(PARENTS_BODIES))
+def test_a_band_wider_than_half_a_block_lowers_to_the_parents_kernels(case, one_chip):
+    """The rule that gives a narrow band its strips (``ops/masks.py::band_strip``) reads the window and the block alone:
+    Phi-4's window of 512, SmallThinker's of 4,096, a causal call and SDAR's block mask never reach the strips, and their
+    calls' Mosaic bodies are, operation for operation, what they were before the rule stood."""
+    import hashlib
+
+    fn, shapes, *_ = CASES[case]()
+    args = jax.tree_util.tree_map(lambda s: S(s.shape, s.dtype, sharding=one_chip), shapes)
+    bodies = _mosaic_bodies(jax.jit(fn).lower(*args).as_text())
+    assert tuple(hashlib.sha256(body.encode()).hexdigest()[:16] for body in bodies) == PARENTS_BODIES[case]
 
 
 def test_the_rotation_is_one_pass_over_q_at_its_full_width(one_chip):

@@ -140,7 +140,7 @@ WHEN = {"moe_cond": "the buffer is smaller than every pair", "kda_heads_a_step":
         "tiles_a_trip_fwd": "the attention is the flash kernel (tests/unit/test_pallas_ops.py, test_regions.py)", "tiles_a_trip_bwd": "the same",
         # (PR 53) softmax attention's kinds share one record, each key its own kind's; the window's walk is the kernel's
         "full_path": "the layer is of the kind full", "window_path": "the layer is of the kind window", "window_keys": "the same",
-        "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)",
+        "window_tiles": "the attention is the flash kernel under a window (tests/unit/test_mixed_attention_layers.py)", "window_tile": "the same",
         "moe_activation": "the experts' gate is relu (activation reglu) or they have none (relu2: tests/unit/test_mamba2_layers.py)",
         # (PR 58) XLA differentiates the plain lines: the backward is a call site of its own on the kernels' path alone
         "scan_operands_bwd": "the scan's operands are made by the kernels (tests/unit/test_scan_operands.py)",

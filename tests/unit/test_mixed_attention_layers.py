@@ -239,18 +239,20 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(ref):
 
 
 def test_a_window_calls_walk_says_its_window_and_its_tiles():
-    """``program_regions_traced_total{region="mixer/kernel", window_tiles}``: the flash forward under a window counts the tiles
-    its walk visits of the square's (a band: at 16,384 rows under 4,096 keys 252 of 1,024 where the causal mask visits
-    528); a causal call says none. The mixer's own count says the window."""
+    """``program_regions_traced_total{region="mixer/kernel", window_tiles, window_tile}``: the flash forward under a window
+    counts the tiles its walk visits of the square's (a band: at 16,384 rows under 4,096 keys 252 of 1,024 where the causal
+    mask visits 528) and, as the backward does, the walk's tile (PR 69: under a band narrower than a block the strips', 128
+    x 128 where the blocks are 512); a causal call says none. The mixer's own count says the window."""
     from deepspeed_tpu.ops import masks
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     assert masks.tiles_visited(masks.Causal(4096), bq=512, bk=512, seq_q=16384, seq_k=16384) == 252 == sum(min(i + 1, 9) for i in range(32))
     assert masks.tiles_visited(masks.Causal(), bq=512, bk=512, seq_q=16384, seq_k=16384) == 528
     # a record says what its walk is counted under (``masks.py::walk_labels``): nothing, the band's label, the block mask's two
-    assert masks.Causal().walk_labels("528/1024") == masks.Full().walk_labels("1024/1024") == {}
-    assert masks.Causal(4096).walk_labels("252/1024") == {"window_tiles": "252/1024"}
-    assert masks.BlockDiffusion(4, 64).walk_labels("3/4") == {"tiles": "3/4", "pairs": str(64 * 64 + 64 * 4)}
+    assert masks.Causal().walk_labels("512x512", "528/1024") == masks.Full().walk_labels("512x512", "1024/1024") == {}
+    assert masks.Causal(4096).walk_labels("512x512", "252/1024") == {"window_tile": "512x512", "window_tiles": "252/1024"}
+    assert masks.Causal(4096).walk_labels("512x512") == {"window_tile": "512x512"}  # a backward call: the tile alone
+    assert masks.BlockDiffusion(4, 64).walk_labels("64x64", "3/4") == {"tiles": "3/4", "pairs": str(64 * 64 + 64 * 4)} and masks.BlockDiffusion(4, 64).walk_labels("64x64") == {}
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 2048, 1, 16), jnp.float32)
     before = dict(regions_traced_by("mixer/kernel", "window_tiles"))
     flash_attention(q, q, q, causal=True, interpret=True)
@@ -258,8 +260,11 @@ def test_a_window_calls_walk_says_its_window_and_its_tiles():
     flash_attention(q, q, q, causal=True, window=64, interpret=True)
     rose = {k: v - before.get(k, 0) for k, v in regions_traced_by("mixer/kernel", "window_tiles").items() if v != before.get(k, 0)}
     assert len(rose) == 1 and list(rose.values()) == [1.0]
-    assert next(iter(rose)) == "7/16"  # four q tiles of 512: 1 + 2 + 2 + 2 where the causal mask visits 10
+    # (PR 69) a band of 64 keys under blocks of 512 is walked in strips of 128: sixteen q tiles, two kv tiles each, where four
+    # q blocks of 512 visited 1 + 2 + 2 + 2 = 7 of 16 (eight times the pairs a visit); the causal mask visits 10 of 16
+    assert next(iter(rose)) == "32/256" and regions_traced_by("mixer/kernel", "window_tile").get("128x128", 0) >= 1
     assert Attention.joined["window_tiles"] == ("mixer/kernel", None, "window_tiles") and Attention.joined["window_keys"] == ("mixer/kernel", None, "window")
+    assert Attention.joined["window_tile"] == ("mixer/kernel", None, "window_tile")
     assert UnrotatedAttention.paths == {"nope_path": ("mixer/kernel", {"op": "nope", "pass": "fwd"})}
     assert EarlyRoutedMoE.joined["moe_router_input"] == ("ffn/router", ("mixer_input",), "input") and RoutedMoE.joined["moe_activation"] == ("ffn/experts", ("relu", "relu2"), "act")  # (PR 59) and the ungated experts' word
     assert "moe_router_input" not in RoutedMoE.joined

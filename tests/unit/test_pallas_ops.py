@@ -250,6 +250,96 @@ def test_the_rule_takes_two_tiles_where_a_whole_run_has_two_and_they_fit():
     assert fa._tile_bytes(512, 512) == 8 << 20  # ``ops/pallas/indexed_attention.py`` counts with it too
 
 
+# a band narrower than a program's block (PR 69): window x query heads a key head x (seq_q, seq_k), at the blocks the
+# cells run (512 x 512): one q block, one and two kv blocks
+_BAND_CASES = {f"w{window}_rep{n_rep}_q{Sq}_k{Sk}": (window, n_rep, Sq, Sk)
+               for window in (64, 128, 256) for n_rep in (1, 4, 8) for Sq, Sk in ((512, 512), (512, 1024))}
+
+
+@pytest.mark.parametrize("case", list(_BAND_CASES))
+def test_a_band_narrower_than_a_block_is_walked_in_strips_and_matches_xla(case):
+    """``flash_attention`` under ``Causal(window)`` with ``window <= 256``, whose programs walk their block of 512 rows as
+    strips (``masks.band_strip``: 128 rows under 64 and 128 keys, 256 under 256): o, dq, dk and dv in float32 against XLA's
+    form, under GQA in the kernel's own sums too, with queries that begin at key 512 as well. The walk's tile rides on
+    both passes' series, the walk's count on the forward's."""
+    from deepspeed_tpu.ops import masks
+    from deepspeed_tpu.telemetry.tracing import regions_traced
+
+    window, n_rep, Sq, Sk = _BAND_CASES[case]
+    strip = 256 if window == 256 else 128
+    ks = jax.random.split(jax.random.PRNGKey(window + n_rep), 4)
+    q, do = (jax.random.normal(key, (1, Sq, n_rep, 32), jnp.float32) for key in ks[:2])
+    k, v = (jax.random.normal(key, (1, Sk, 1, 32), jnp.float32) for key in ks[2:])
+    tile = f"{strip}x{strip}"
+    visited = 2 * Sq // strip  # the walk without a loop: two kv tiles a q tile (the first one's second lies past its diagonal: the test throws it away)
+    assert visited - masks.tiles_visited(masks.Causal(window), bq=strip, bk=strip, seq_q=Sq, seq_k=Sk) == int(Sq == Sk)
+    series = lambda pass_, **labels: regions_traced("mixer/kernel", op="flash", window_tile=tile, **{"pass": pass_}, **labels)
+    before = series("fwd", window_tiles=f"{visited}/{(Sq // strip) * (Sk // strip)}"), series("bwd", path="fused")
+
+    def both(attn):
+        o, pull = jax.vjp(lambda q, k, v: attn(q, k, v, causal=True, window=window), q, k, v)
+        return (o,) + pull(do)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = both(lambda *a, **kw: flash_attention(*a, interpret=True, **kw)), both(attention_xla)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, err_msg=name)
+    after = series("fwd", window_tiles=f"{visited}/{(Sq // strip) * (Sk // strip)}"), series("bwd", path="fused")
+    assert (after[0] - before[0], after[1] - before[1]) == (1.0, 1.0)
+
+
+def _band_rule_cases():
+    """The rule as data: name -> (mask, seq, ``_tiles``: a program's block along q and k, then the walk's tiles)."""
+    from deepspeed_tpu.ops import masks
+
+    whole, cases = (512, 512, 512, 512), {}
+    for seq in (2048, 8192, 16384):  # today's tiles for every mask a listed cell has but K-EXAONE's band
+        for name, mask in (("causal", masks.Causal()), ("phi4_w512", masks.Causal(512)), ("smallthinker_w4096", masks.Causal(4096)),
+                           ("sdar_blockdiff", masks.BlockDiffusion(4, seq // 2)), ("w257", masks.Causal(257)), ("full", masks.Full())):
+            cases[f"{name}_s{seq}"] = (mask, seq, whole)
+        cases[f"kexaone_w128_s{seq}"] = (masks.Causal(128), seq, (512, 512, 128, 128))
+        cases[f"w64_s{seq}"] = (masks.Causal(64), seq, (512, 512, 128, 128))  # never under the lanes' 128
+        cases[f"w256_s{seq}"] = (masks.Causal(256), seq, (512, 512, 256, 256))  # half a block: the widest band with strips
+    cases["w128_one_block"] = (masks.Causal(128), 512, (512, 512, 128, 128))
+    cases["w128_s256"] = (masks.Causal(128), 256, (256, 256, 128, 128))
+    cases["w128_s128"] = (masks.Causal(128), 128, (128, 128, 128, 128))  # one tile: nothing to walk in strips
+    return cases
+
+
+@pytest.mark.parametrize("case", list(_band_rule_cases()))
+def test_the_walks_tiles_follow_the_band_and_nothing_else(case):
+    """``_tiles`` (``masks.band_strip``): a program's blocks are what they were for every mask; the WALK's tiles are the
+    blocks but under ``0 < window <= block // 2``, where they are the power of two that holds the band, never under 128.
+    The rule reads the window and the block: Phi-4's window of 512, SmallThinker's of 4,096 and SDAR's mask cannot drift."""
+    import deepspeed_tpu.ops.pallas.flash_attention as fa
+
+    mask, seq, want = _band_rule_cases()[case]
+    assert fa._tiles(mask, seq, seq, False) == want
+    assert fa._tiles(mask, seq, seq, True) == want[:2] * 2  # a bias comes by the block
+
+
+def test_the_bands_walk_at_k_exaones_shape():
+    """8,192 x 8,192 under a window of 128 (PERF.md section 6, PR 69): what a walk visits by its tile, in tiles and in
+    pairs a head, beside the 1,040,448 pairs the band keeps; the walk without a loop visits two kv tiles a q tile, one
+    more than the band crosses (the first q tile's spare one), and has no unmasked run to pair."""
+    from deepspeed_tpu.ops import masks
+
+    band, S = masks.Causal(128), 8192
+    visited = lambda bq, bk: masks.tiles_visited(band, bq=bq, bk=bk, seq_q=S, seq_k=S)
+    assert [(visited(bq, bk), (S // bq) * (S // bk)) for bq, bk in ((512, 512), (512, 128), (256, 128), (128, 128))] == [(31, 256), (79, 1024), (95, 2048), (127, 4096)]
+    assert [visited(bq, bk) * bq * bk for bq, bk in ((512, 512), (512, 128), (256, 128), (128, 128))] == [8126464, 5177344, 3112960, 2080768]
+    assert sum(min(r + 1, 128) for r in range(S)) == 1040448 and S * (S + 1) // 2 == 33558528
+    assert masks.longest_whole_run(band, bq=128, bk=128, seq_q=S, seq_k=S) == masks.longest_whole_run(band, bq=512, bk=512, seq_q=S, seq_k=S) == 0
+    assert band.band(tile=128, seq_q=S, seq_k=S) == (0, 2) and band.band(tile=128, seq_q=512, seq_k=1024) == (4, 2)
+    assert masks.Causal(1).band(tile=128, seq_q=S, seq_k=S) == (0, 1)  # a band of one key: the diagonal's tile alone
+    # no walk without a loop: a wider band, queries ahead of the keys, fewer tiles than the walk takes, any other mask
+    assert masks.Causal(129).band(tile=128, seq_q=S, seq_k=S) is None and band.band(tile=128, seq_q=1024, seq_k=512) is None
+    assert band.band(tile=128, seq_q=128, seq_k=S) is None and masks.Causal().band(tile=128, seq_q=S, seq_k=S) is None
+    assert masks.Full().band(tile=128, seq_q=S, seq_k=S) is None and masks.BlockDiffusion(4, S // 2).band(tile=128, seq_q=S, seq_k=S) is None
+    assert [masks.band_strip(w, 512) for w in (0, 1, 64, 128, 129, 256, 257, 512, 4096)] == [512, 128, 128, 128, 256, 256, 512, 512, 512]
+    assert masks.band_strip(128, 256) == 128 and masks.band_strip(128, 128) == 128 and masks.band_strip(64, 64) == 64 and masks.band_strip(16, 16) == 16
+
+
 def test_fused_adam_matches_reference():
     rng = np.random.RandomState(0)
     n = 1000

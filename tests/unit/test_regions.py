@@ -344,7 +344,7 @@ def test_the_flash_kernels_say_how_many_tiles_a_trip_takes_and_the_first_call_li
     assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "2", "tiles_a_trip_bwd": "1"}
     before = trainer._paths_traced()
     grad(q, window=16)  # a window of a tile's width: every visited tile crosses an edge, one tile a trip
-    assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "1", "tiles_a_trip_bwd": "1", "window_tiles": "7/16"}  # (PR 53) the band's walk: 1 + 2 + 2 + 2
+    assert notes_since(cfg, before) == {"tiles_a_trip_fwd": "1", "tiles_a_trip_bwd": "1", "window_tiles": "7/16", "window_tile": "16x16"}  # (PR 53) the band's walk: 1 + 2 + 2 + 2; (PR 69) and its tile
     before, blockdiff = trainer._paths_traced(), series("fwd", "2", op="blockdiff")
     grad(jnp.ones((1, 128, 2, 8), jnp.bfloat16), mask=masks.BlockDiffusion(4, 64))  # under a mask of its own walk the label rides on that op's series
     grad(q[:, :32], bias=jnp.zeros((1, 1, 32, 32)))  # a bias of two tiles a side: no run of two, and the split backward

@@ -160,7 +160,7 @@ def test_the_report_and_the_monitors_read_of_a_loss_are_phases_of_train_step(eng
 
     batch = {"input_ids": np.zeros((2, 16), np.int32)}
     monkeypatch.setattr(engine.config, "steps_per_print", 1)
-    for _ in range(2):  # the second step of this shape makes no first call
+    for _ in range(3):  # the third step of this shape makes no first call (the second makes ``overflow_sum``'s: a ``program/first_call`` span)
         get_tracer().clear()
         engine.backward(engine.forward(batch))
         engine.step()
