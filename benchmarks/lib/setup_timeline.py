@@ -1,4 +1,6 @@
-"""Set-up's timeline, for the three ``setup_*`` readers: where the seconds from
+"""Set-up's timeline, for the four ``setup_*`` readers that read it
+(``setup_engine_init_s``, ``setup_step_trace_s``, ``setup_step_compile_s``,
+``setup_callers_programs_s``): where the seconds from
 the process's start to the window's start went, by what the PROGRAM counted
 where it spent them.
 
@@ -37,7 +39,10 @@ and who reads it"):
 in the warm-up steps), ``import``, ``callers_programs`` (wall seconds outside
 every span of the program's) and ``harness``: ``setup_s`` less all of them (the
 runtime's start, the batches, the reference's own execution, in a traced run
-the profiler's start and the traced steps), which is why it is no metric.
+the profiler's start and the traced steps), which is why it is no metric: a
+remainder is no measurement. Four of the parts are metrics, so on a result's
+line ``harness`` is ``setup_s`` less the four readers' sum and the ``import``
+gauge, and a difference in ``setup_s`` that none of the four shows lies there.
 """
 
 import re

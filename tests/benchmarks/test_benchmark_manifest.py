@@ -1,11 +1,15 @@
 """BENCHMARK.json and the files it names: the rules a later PR breaks most
 easily, and that each kind of piece can be added as files of its own."""
 
+import glob
+import inspect
+import itertools
 import json
 import os
 import shutil
 import subprocess
 import sys
+import traceback
 
 import pytest
 
@@ -83,6 +87,106 @@ def test_the_manifest_as_it_stands_has_room_by_the_rule_and_by_no_count(cell):
         m = _with_four_chip_copies(cell, 1)
         metric = _named_in_one_more_reader(m, "more.0")
         assert mf.problems(m) == [] and (metric is None or metric in mf.metrics_of(m, "more.0", "per_layer"))
+
+
+def _a_checkout_grown_by_a_cell(root, of, config):
+    """A checkout at ``root`` as a PR that adds a cell leaves one: ``benchmarks/`` as it stands and ``BENCHMARK.json`` with one
+    cell more, the cell ``of`` under the configuration name ``config``. What such a PR appends and nothing else: the
+    configuration's entry and its three files under the new name, the cell LAST in ``workloads`` and last in the list of every
+    metric that lists ``of`` (so of every metric that lists all cells), and one per-layer metric more, LAST, that lists the new
+    cell alone (a copy of the newest reader that lists ``of``, so that its file states what its entry says). The copy asks for
+    the chips of ``of`` where the quarter rule admits them. The new cell's name."""
+    shutil.copytree(mf.BENCH, os.path.join(root, "benchmarks"), ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(mf.ROOT, "deepspeed_tpu"), os.path.join(root, "deepspeed_tpu"))  # a reader's layer names a module of the program: a test looks it up
+    m = json.loads(json.dumps(MANIFEST))
+    original = next(w for w in m["workloads"] if w["name"] == of)
+    entry = next(c for c in m["configs"] if c["name"] == original["config"])
+    cell = dict(original, name=f"{config}.{original['traffic']}", config=config)
+    if sum(w["chips"] == 4 for w in m["workloads"]) + 1 > max(1, (len(m["workloads"]) + 1) // 4):
+        cell["chips"] = 1  # the quarter rule has no room for a four-chip cell more: the copy keeps the original's lists and asks for one chip
+    for file in glob.glob(os.path.join(mf.BENCH, "configs", entry["name"] + ".*")):  # the configuration, its plain reference, its FLOP module
+        with open(file) as f, open(os.path.join(root, "benchmarks", "configs", config + os.path.basename(file)[len(entry["name"]):]), "w") as g:
+            g.write(f.read().replace(f"configs/{entry['name']}.", f"configs/{config}."))
+    m["configs"].append(dict(entry, name=config, file=f"benchmarks/configs/{config}.json"))
+    m["workloads"].append(cell)
+    for metric in m["end_to_end"] + m["per_layer"]:
+        if of in metric.get("workloads", []):
+            metric["workloads"].append(cell["name"])
+    reader = [x for x in m["per_layer"] if of in x.get("workloads", []) and x["moves"] != "setup_s"][-1]
+    m["per_layer"].append(dict(reader, name="a_later_prs_" + reader["name"], workloads=[cell["name"]]))
+    shutil.copy(os.path.join(mf.BENCH, "metrics", reader["name"] + ".py"), os.path.join(root, "benchmarks", "metrics", "a_later_prs_" + reader["name"] + ".py"))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    return cell["name"]
+
+
+def _calls_pytest_would_make(fn):
+    """``fn``'s arguments, a dict a case (the product of its ``parametrize`` marks); None where it takes anything its marks do
+    not give it: a fixture (a temporary directory, a clean environment: what a test that writes files or rehearses asks for)."""
+    names, values = [], []
+    for mark in getattr(fn, "pytestmark", []):
+        if mark.name == "skip" or (mark.name == "skipif" and mark.args and mark.args[0] is True):
+            return []
+        if mark.name == "parametrize":
+            args = [a.strip() for a in mark.args[0].split(",")] if isinstance(mark.args[0], str) else list(mark.args[0])
+            rows = [getattr(v, "values", v if len(args) > 1 else (v,)) for v in mark.args[1]]  # ``pytest.param(...)`` keeps a tuple there
+            names += args
+            values.append([dict(zip(args, row)) for row in rows])
+    if set(inspect.signature(fn).parameters) != set(names):
+        return None
+    return [{k: v for part in case for k, v in part.items()} for case in itertools.product(*values)]
+
+
+@pytest.mark.parametrize("of,config", [
+    ("k-exaone-236b-l5e8.pretrain-8k-ep4", "thirteenth-l5e8"),  # (a) one more cell of four chips, while a quarter of the cells has room for it (3 of 13 today)
+    ("smallthinker-21b-l4e8.pretrain-16k", "thirteenth-l4e8"),  # (b) one of one chip, in a reader that four cells share
+])
+def test_a_checkout_with_one_cell_more_keeps_every_rule_a_test_here_states_of_the_manifest(of, config, tmp_path, monkeypatch):
+    """THE RULE: a test under ``tests/benchmarks/`` states a rule of the manifest, never its count or an entry's place. A PR that
+    adds a cell may append entries and add files and may edit no file here, so an assertion that holds only of the cells
+    there are today stops that PR and nobody else. This test finds such an assertion in the tier-1 run of the PR that writes
+    it: in a checkout grown by a cell, every test function of this directory that names the manifest and takes no fixture
+    (no rehearsal, no file written) is called as pytest would call it, and none may fail. The checkout is handed over where
+    every module takes it from: ``benchmarks.lib.manifest``'s ``ROOT``, ``BENCH`` and the default roots of its functions."""
+    root = str(tmp_path)
+    new = _a_checkout_grown_by_a_cell(root, of, config)
+    for fn in [f for f in vars(mf).values() if inspect.isfunction(f) and mf.ROOT in (f.__defaults__ or ())]:
+        monkeypatch.setattr(fn, "__defaults__", tuple(root if d == mf.ROOT else d for d in fn.__defaults__))
+    monkeypatch.setattr(mf, "ROOT", root)
+    monkeypatch.setattr(mf, "BENCH", os.path.join(root, "benchmarks"))
+    grown = mf.load_manifest()
+    assert len(grown["workloads"]) == len(MANIFEST["workloads"]) + 1 and grown["workloads"][-1]["name"] == new and mf.problems(grown) == []
+    assert grown["per_layer"][-1]["workloads"] == [new] and len(mf.metrics_of(grown, new, "per_layer")) == len(mf.metrics_of(grown, of, "per_layer")) + 1
+
+    before, held, broken = set(sys.modules), [], []
+    try:
+        for path in sorted(glob.glob(os.path.join(os.path.dirname(os.path.abspath(__file__)), "test_*.py"))):
+            with open(path) as f:
+                if "load_manifest" not in f.read():
+                    continue
+            module = mf.load_module(path)  # a copy of its own: its ``MANIFEST`` is the grown one's, and so is every parametrisation made from it
+            for name, fn in sorted(vars(module).items()):
+                if not (name.startswith("test_") and inspect.isfunction(fn) and fn.__module__ == module.__name__):
+                    continue
+                source = inspect.getsource(fn)
+                calls = _calls_pytest_would_make(fn) if "MANIFEST" in source or "load_manifest" in source else None
+                for kwargs in calls or []:
+                    case = f"{os.path.basename(path)}::{name}{sorted(kwargs.items()) if kwargs else ''}"[:300]
+                    try:
+                        fn(**kwargs)
+                        held.append(case)
+                    except pytest.skip.Exception:
+                        pass
+                    except Exception as e:  # every broken rule is reported, not the first: the list is what the PR's writer needs
+                        at = traceback.extract_tb(e.__traceback__)[-1]  # these modules' asserts are not rewritten: the line says what the message cannot
+                        broken.append(f"{case}: {type(e).__name__}: {str(e)[:300]} (line {at.lineno}: {at.line})")
+    finally:
+        for name in set(sys.modules) - before:
+            if name.startswith("tests.benchmarks."):  # a helper module first imported here took the grown manifest: the next importer gets its own
+                del sys.modules[name]
+    assert broken == [], "\n".join(broken)
+    assert any(case.startswith(os.path.basename(__file__) + "::test_manifest_has_no_problems") for case in held)  # it reached this file's own, at the least
+    assert any(new in case for case in held)  # and a case that pytest would make of the new cell
 
 
 @pytest.mark.parametrize("config,key", [(c, k) for c, keys in PUBLISHED.items() for k in keys])
